@@ -3,11 +3,11 @@
 Collectives are stringly-typed the same way PartitionSpecs are (SHD):
 ``jax.lax.psum(x, "modle")`` raises nothing until trace time inside a
 real mapped region, and ``shard_map`` out_specs that disagree with the
-callee's return structure fail as opaque pytree errors. Worse, on the
-pinned jax 0.4.37 the old ``shard_map`` manualizes EVERY mesh axis, so a
-raw ``jax.lax.with_sharding_constraint`` inside any mapped body dies at
-*lowering* time ("Axis ... is also found in manual_axes") — the exact
-failure that kept tests/test_pp_engine.py red since seed. The fix routes
+callee's return structure fail as opaque pytree errors. Worse, a mapped
+region's axes are Manual, so a raw ``jax.lax.with_sharding_constraint``
+naming one inside a mapped body dies at *lowering* time ("can only refer
+to Auto axes of the mesh"), and outside any mesh context it raises too —
+the failure that kept tests/test_pp_engine.py red since seed. The fix routes
 every constraint through ``utils/jax_compat.with_sharding_constraint``
 (which drops manual axes); MSH003 pins that routing so the next
 refactor cannot silently reintroduce the raw call.
@@ -18,8 +18,8 @@ refactor cannot silently reintroduce the raw call.
   MSH002  shard_map out_specs tuple length differs from the callee's
           literal tuple return (both fully literal; a single spec is a
           legal pytree prefix and is never flagged)
-  MSH003  raw ``jax.lax.with_sharding_constraint`` call — on jax 0.4.x
-          this cannot be expressed inside shard_map regions; route
+  MSH003  raw ``jax.lax.with_sharding_constraint`` call — it cannot name
+          a Manual axis inside shard_map regions; route
           through areal_tpu.utils.jax_compat.with_sharding_constraint
 
 Only names that resolve to jax (``jax.lax.*`` / ``lax.*`` dotted paths,
@@ -108,7 +108,7 @@ class MeshCollectiveChecker:
     RULES = {
         "MSH001": "collective axis name not in the mesh vocabulary",
         "MSH002": "shard_map out_specs length differs from callee return",
-        "MSH003": "raw with_sharding_constraint (manual-axes-unsafe on 0.4.x)",
+        "MSH003": "raw with_sharding_constraint (manual-axes-unsafe)",
     }
 
     def check(self, sf: SourceFile, ctx: ProjectContext) -> Iterator[Finding]:
@@ -281,10 +281,10 @@ class MeshCollectiveChecker:
                 path=sf.relpath,
                 line=call.lineno,
                 message=(
-                    "raw jax.lax.with_sharding_constraint: on jax 0.4.x "
-                    "the old shard_map manualizes every mesh axis and this "
-                    "call fails at LOWERING time inside any mapped region "
-                    "(the pp_engine failure class); route through "
+                    "raw jax.lax.with_sharding_constraint: a spec naming a "
+                    "Manual axis fails at LOWERING time inside a shard_map "
+                    "region (the pp_engine failure class), and any spec "
+                    "raises outside a mesh context; route through "
                     "areal_tpu.utils.jax_compat.with_sharding_constraint"
                 ),
                 key=make_key(

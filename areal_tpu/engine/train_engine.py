@@ -49,7 +49,7 @@ from areal_tpu.models import qwen
 from areal_tpu.models.hf import load_params_from_hf, save_params_to_hf
 from areal_tpu.observability.step_timeline import engine_phase
 from areal_tpu.parallel import mesh as mesh_lib
-from areal_tpu.utils.jax_compat import set_mesh, shard_map
+from jax import set_mesh, shard_map
 from areal_tpu.utils import logging as alog
 from areal_tpu.utils.data import TensorDict, seqlens_of
 from areal_tpu.utils.grid import Grid, pack_grid
@@ -218,6 +218,12 @@ class JaxTrainEngine(TrainEngine):
                 f"jax.distributed up: process {dist['process_id']}/"
                 f"{dist['num_processes']} @ {dist['coordinator_address']}"
             )
+        # before the first compile, in the process that owns the devices
+        # (a trainer driving remote workers never touches jax): the
+        # persistent compile cache, TPU-only (utils/compile_cache.py)
+        from areal_tpu.utils.compile_cache import enable_persistent_cache
+
+        enable_persistent_cache()
         self.mesh = kwargs.get("mesh") or mesh_lib.make_mesh(cfg.mesh)
         mcfg = self._model_config
         if mcfg is None:
@@ -1196,7 +1202,7 @@ class JaxTrainEngine(TrainEngine):
                 fn = self._get_fused_step_fn(loss_fn, _shape_key(batch))
                 # the fused jit folds the optimizer apply into the same
                 # program, so this span carries BOTH fwd/bwd and the
-                # update (docs/observability.md phase taxonomy note)
+                # update (docs/observability.md phase vocabulary note)
                 with engine_phase("forward_backward"):
                     self.params, self.opt_state, gnorm, loss, stats = fn(
                         self.params, self.opt_state, batch, jnp.float32(weights[0] / total_w)

@@ -63,7 +63,7 @@ from areal_tpu.observability import hw_accounting as hw
 from areal_tpu.observability import kernel_probe
 from areal_tpu.observability import timeline as tl_mod
 from areal_tpu.parallel import mesh as mesh_lib
-from areal_tpu.utils.jax_compat import set_mesh
+from jax import set_mesh
 from areal_tpu.utils import logging as alog
 from areal_tpu.utils.data import round_up_to_bucket
 
@@ -345,9 +345,17 @@ class DecodeEngine:
         cfg = self.config
         # serving-side compile visibility: a recompile storm (drifting
         # chunk/scatter shape keys) shows as areal_xla_compiles_total climb
-        from areal_tpu.utils.compile_cache import install_compile_counters
+        from areal_tpu.utils.compile_cache import (
+            enable_persistent_cache,
+            install_compile_counters,
+        )
 
         install_compile_counters()
+        # before the first compile: precompile() warms via AOT
+        # lower().compile() and the serving path replays those programs
+        # through the persistent compile cache (TPU-only gating and the
+        # placement rule live in utils/compile_cache.py)
+        enable_persistent_cache()
         if self.mesh is None:
             self.mesh = mesh_lib.make_mesh(cfg.mesh)
         if self.params is None:
@@ -453,26 +461,26 @@ class DecodeEngine:
         # gated so default fleets pay neither the memory nor new variants.
         self._freq_enabled = bool(cfg.enable_frequency_penalty)
         self._pending_count_restore: list[tuple[int, np.ndarray]] = []
-        with set_mesh(self.mesh):
-            self._dev_state = {k: jnp.asarray(v) for k, v in self._state.items()}
-            if self._freq_enabled:
-                self._dev_state["freq_counts"] = jnp.zeros(
-                    (S, self.model_cfg.vocab_size), jnp.uint16
-                )
+        # COMMITTED to the mesh (replicated) from the start: every jitted
+        # serving fn hands its state/rng outputs back committed, and an
+        # uncommitted first generation would make the first call (and every
+        # program precompile() lowers from these arrays) a different cache
+        # key from all later calls — each program compiled twice
+        repl = NamedSharding(self.mesh, P())
+        self._dev_state = {
+            k: jax.device_put(v, repl) for k, v in self._state.items()
+        }
+        if self._freq_enabled:
+            self._dev_state["freq_counts"] = jax.device_put(
+                jnp.zeros((S, self.model_cfg.vocab_size), jnp.uint16), repl
+            )
         seed = self.config.seed
         if seed is None:
             seed = int(time.time_ns()) % (2**31)
-        self._rng = jax.random.PRNGKey(seed)
-        # precompile() warms via AOT lower().compile(); the serving path
-        # replays those programs through the persistent compile cache, so
-        # make sure one is configured (TPU-only gating + the cross-round
-        # repo-local default live in utils/compile_cache.py)
-        from areal_tpu.utils.compile_cache import enable_persistent_cache
-
-        enable_persistent_cache()
-        # kernel observatory: init-time construction (an unknown chip kind
-        # triggers a one-time host peak calibration — device work + host
-        # pulls that must never run on the decode hot path)
+        self._rng = jax.device_put(jax.random.PRNGKey(seed), repl)
+        # kernel observatory: init-time construction (peaks resolve from
+        # the chip table; an unknown TPU kind is an error, raised here and
+        # not on the decode hot path)
         self.kprobe = kernel_probe.KernelProbe(
             model_cfg=self.model_cfg,
             n_chips=int(getattr(self.mesh, "size", 1) or 1),
@@ -489,7 +497,7 @@ class DecodeEngine:
         logger.info(
             f"decode engine ready: {S} slots × {T} ctx, "
             f"{self.pool.n_pages} KV pages × {cfg.page_size} tokens, "
-            f"mesh {dict(self.mesh.shape)}"
+            f"mesh {dict(self.mesh.shape)}, attention {self.attention_impl()}"
         )
 
     def _place(self, path: str, arr) -> jax.Array:
@@ -571,17 +579,32 @@ class DecodeEngine:
             if mcfg.num_kv_heads % max(tp, 1) == 0
             else {k: P() for k in paged_kv.paged_cache_specs(quant=kv_quant)}
         )
-        # the Pallas paged kernel runs single-device; under TP the engine
-        # falls back to the gather+einsum path which GSPMD shards over the
-        # KV-head axis like the dense engine did
-        self._use_kernel = (
-            jax.devices()[0].platform == "tpu"
+        # the Pallas paged kernels run single-device; under TP the engine
+        # takes the gather+einsum path, which GSPMD shards over the KV-head
+        # axis like the dense engine did. Kernel or gather is decided HERE,
+        # once, from the platform, the mesh and the shapes: on a TPU the
+        # kernels are compiled, and one the chip's compiler refuses is an
+        # error — nothing catches it, falls back to interpret mode or
+        # swaps in the XLA path.
+        from areal_tpu.ops.paged_attention_q8 import paged_kernel_ok
+
+        one_tpu = (
+            jax.default_backend() == "tpu"
             and int(np.prod(list(self.mesh.shape.values()))) == 1
         )
+        shapes_ok = paged_kernel_ok(mcfg.head_dim_, psz, bool(kv_quant))
+        if one_tpu and not shapes_ok:
+            logger.warning(
+                f"head_dim {mcfg.head_dim_} / page_size {psz} / kv "
+                f"{kv_quant or 'bf16'} is outside the Pallas paged kernels' "
+                "tiling (ops/paged_attention_q8.py paged_kernel_ok): "
+                "decode, suffix prefill and verify take the gather path"
+            )
+        self._use_kernel = one_tpu and shapes_ok
         # suffix-prefill / tree-verify Pallas kernel
-        # (ops/paged_suffix_attention.py): same single-device condition,
-        # overridable at runtime for kernel-vs-XLA A/B (bench decode phase;
-        # off-TPU the kernel runs in interpret mode)
+        # (ops/paged_suffix_attention.py): same condition, overridable at
+        # runtime for kernel-vs-XLA A/B (off-TPU the kernel runs in
+        # interpret mode)
         self._suffix_kernel_override: bool | None = None
         with set_mesh(self.mesh):
             self.cache = jax.jit(
@@ -707,7 +730,10 @@ class DecodeEngine:
         t0 = time.monotonic()
 
         def sds(x):
-            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+            # WITH the live array's sharding: the runtime call lowers from
+            # committed arrays, and a program lowered from unplaced shapes
+            # is a different cache key — it would be compiled twice
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
 
         params_s = jax.tree.map(sds, self.params)
         cache_s = jax.tree.map(sds, self.cache)
@@ -3252,6 +3278,28 @@ class DecodeEngine:
         if self._suffix_kernel_override is not None:
             return self._suffix_kernel_override
         return self._use_kernel
+
+    def attention_impl(self) -> dict[str, str]:
+        """Which attention implementation each serving path uses — logged
+        once at start-up and read by chip_smoke.py. ``pallas`` is the
+        compiled TPU kernel, ``pallas-interpret`` the same body under the
+        Pallas interpreter (off-TPU, kernel forced on), ``xla`` the
+        gather + einsum path."""
+        tpu = jax.default_backend() == "tpu"
+        kern = "pallas" if tpu else "pallas-interpret"
+        return {
+            "decode": kern if self._use_kernel else "xla",
+            # cold prefill is plain causal attention over the prompt bucket
+            "prefill": "xla",
+            "suffix_prefill": kern if self._suffix_kernel() else "xla",
+            "verify": (
+                "off"
+                if self._spec_cfg is None
+                else kern
+                if self._suffix_kernel()
+                else "xla"
+            ),
+        }
 
     def set_suffix_kernel(self, on: bool | None) -> None:
         """Force the paged suffix-attention kernel on/off (None restores

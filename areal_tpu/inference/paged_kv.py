@@ -35,9 +35,8 @@ import numpy as np
 from areal_tpu.utils.private_api import pin_signature
 
 # the library paged_attention launch wrapper is a PRIVATE pallas op called
-# positionally below (q, pages, lengths, page table); audited against jax
-# 0.4.37, verified at first use, re-checked against the installed jax by
-# arealint PVT002
+# positionally below (q, pages, lengths, page table); verified at first
+# use, re-checked against the installed jax by arealint PVT002
 _EXPECTED_PAGED_ATTENTION_PARAMS = (
     "q",
     "k_pages",
@@ -336,14 +335,18 @@ class RadixPrefixCache:
         return freed
 
 
-# KV quantization convention — matches the library paged-attention
-# kernel's quantization_utils (scales = max|x| over head_dim, q = rint(
-# x * 127.5 / scale)), so quantized pages feed the TPU kernel directly as
-# QuantizedTensor(weight, scales). fp8 (float8_e4m3fn) pages keep the SAME
-# stored-value semantics (q = x * 127.5 / scale, no rounding clip — the
-# values sit well inside e4m3's ±448 range), so ONE dequant formula
-# ``q.astype(f32) * scale / 127.5`` serves both dtypes through every
-# kernel (the library body's from_int8 is dtype-generic on q).
+# KV quantization convention: scale = max|x| over head_dim, q = rint(
+# x * 127.5 / scale). fp8 (float8_e4m3fn) pages keep the SAME stored-value
+# semantics (q = x * 127.5 / scale, no rounding clip — the values sit well
+# inside e4m3's ±448 range), so ONE dequant formula
+# ``q.astype(f32) * scale / 127.5`` serves both dtypes through every kernel.
+#
+# Scales in the page pool are LANE-MAJOR: [..., n_pages, 1, page_size], one
+# f32 per token vector with the page's tokens as the minor dimension. The
+# natural trailing-1 shape ([..., page_size, 1]) pads each scale to a whole
+# 128-lane row in HBM and cannot be DMA-sliced by a TPU kernel; lane-major
+# is compact and lets the kernels scale logits/probabilities by column
+# (ops/paged_attention_q8.py).
 _MAX_INT8 = 127.5
 _QUANT_DTYPES = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
 
@@ -376,6 +379,13 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
     return (q.astype(jnp.float32) * (scale / _MAX_INT8)).astype(dtype)
 
 
+def quantize_pages(pages: jax.Array, dtype=jnp.int8) -> tuple[jax.Array, jax.Array]:
+    """[..., psz, hd] float pages -> (int8/fp8 pages, f32 scales in the
+    pool's lane-major layout [..., 1, psz])."""
+    q, scale = quantize_kv(pages, dtype)
+    return q, jnp.swapaxes(scale, -1, -2)
+
+
 def n_pages_for_budget(
     budget_bytes: int, n_layers: int, num_kv_heads: int, page_size: int,
     head_dim: int, itemsize: int, quant=False,
@@ -393,14 +403,14 @@ def init_paged_cache(
 ) -> dict:
     """k/v page pools: [n_layers, KH, n_pages, page_size, hd]. With
     ``quant`` (True/"int8" or "fp8") the pages are int8 or float8_e4m3fn
-    plus per-token-vector f32 scales ([..., psz, 1]) — halved KV HBM
-    traffic, the decode bottleneck at long context."""
+    plus per-token-vector f32 scales, lane-major ([..., 1, psz]) — halved
+    KV HBM traffic, the decode bottleneck at long context."""
     dtype = dtype or cfg.jax_dtype
     shape = (cfg.num_layers, cfg.num_kv_heads, n_pages, page_size, cfg.head_dim_)
     qdtype = quant_dtype(quant)
     if qdtype is None:
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-    sshape = shape[:-1] + (1,)
+    sshape = shape[:-2] + (1, page_size)
     return {
         "k": jnp.zeros(shape, qdtype),
         "v": jnp.zeros(shape, qdtype),
@@ -443,7 +453,7 @@ def scatter_prefill(cache: dict, ks: jax.Array, vs: jax.Array, flat_pages: jax.A
             L, KH, A * npg, page_size, hd
         )
         if quant:
-            q, s = quantize_kv(r, dtype=cache[name].dtype)
+            q, s = quantize_pages(r, dtype=cache[name].dtype)
             cache[name] = cache[name].at[:, :, flat_pages].set(q)
             cache[f"{name}_scale"] = cache[f"{name}_scale"].at[:, :, flat_pages].set(s)
         else:
@@ -476,7 +486,9 @@ def scatter_token_rows(
             q, s = quantize_kv(r, dtype=cache[name].dtype)
             cache[name] = cache[name].at[:, :, flat_pages, flat_rows].set(q)
             cache[f"{name}_scale"] = (
-                cache[f"{name}_scale"].at[:, :, flat_pages, flat_rows].set(s)
+                cache[f"{name}_scale"]
+                .at[:, :, flat_pages, 0, flat_rows]
+                .set(s[..., 0])
             )
         else:
             cache[name] = cache[name].at[:, :, flat_pages, flat_rows].set(
@@ -507,7 +519,7 @@ def paged_attention_xla(
     v_pages: jax.Array,
     lengths: jax.Array,  # [S] int32 valid rows per slot
     page_table: jax.Array,  # [S, wp] int32 (window's pages)
-    k_scales: jax.Array | None = None,  # [KH, N, psz, 1] (int8 KV)
+    k_scales: jax.Array | None = None,  # [KH, N, 1, psz] (int8/fp8 KV)
     v_scales: jax.Array | None = None,
 ) -> jax.Array:
     """Reference/CPU path: gather the window's pages, grouped masked einsum —
@@ -525,10 +537,11 @@ def paged_attention_xla(
         S, W, KH, hd
     )
     if k_scales is not None:
-        ks_g = jnp.transpose(k_scales[:, page_table], (1, 2, 3, 0, 4)).reshape(
+        # lane-major [KH, S, wp, 1, psz] -> [S, wp, psz, KH, 1] -> [S, W, KH, 1]
+        ks_g = jnp.transpose(k_scales[:, page_table], (1, 2, 4, 0, 3)).reshape(
             S, W, KH, 1
         )
-        vs_g = jnp.transpose(v_scales[:, page_table], (1, 2, 3, 0, 4)).reshape(
+        vs_g = jnp.transpose(v_scales[:, page_table], (1, 2, 4, 0, 3)).reshape(
             S, W, KH, 1
         )
         kk = dequantize_kv(kk, ks_g, q.dtype)
@@ -548,20 +561,19 @@ def paged_attention_tpu(
     lengths: jax.Array,  # [S] int32
     page_table: jax.Array,  # [S, wp] int32
     pages_per_compute_block: int = 4,
-    k_scales: jax.Array | None = None,  # [KH, N, psz, 1] (int8 KV)
+    k_scales: jax.Array | None = None,  # [KH, N, 1, psz] (int8/fp8 KV)
     v_scales: jax.Array | None = None,
 ) -> jax.Array:
     """jax's Pallas TPU paged-attention kernel (grouped-query flash over the
-    page table; reads only each sequence's pages). int8 pages go through
-    the NARROW-scales fork (ops/paged_attention_q8.py): the library wrapper
-    would broadcast the [..., 1] scales to head_dim, inverting the
-    halved-HBM premise; the fork keeps them narrow end to end and
-    dequantizes in VMEM."""
+    page table; reads only each sequence's pages). Quantized pages go
+    through the repo's own kernel (ops/paged_attention_q8.py): the library
+    wrapper would broadcast the scales to head_dim, inverting the
+    halved-HBM premise."""
     ppcb = choose_ppcb(page_table.shape[1], pages_per_compute_block)
     if k_scales is not None:
         from areal_tpu.ops.paged_attention_q8 import paged_attention_q8
 
-        # the fork takes RAW q (applies 1/sqrt(hd) internally)
+        # takes RAW q (applies 1/sqrt(hd) internally)
         return paged_attention_q8(
             q,
             k_pages,

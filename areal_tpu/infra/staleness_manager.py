@@ -21,7 +21,7 @@ from areal_tpu.api.io_struct import RolloutStat
 from areal_tpu.observability import catalog
 
 # ---------------------------------------------------------------------------
-# Version-lag bucket taxonomy (docs/observability.md "Learning-health
+# Version-lag bucket vocabulary (docs/observability.md "Learning-health
 # observatory"). ONE definition shared by the loss-side bucket stats
 # (trainer/ppo.py), the metric catalog's ``lag_bucket`` label values, the
 # autopilot's learning-health guard signal, and the dashboard panel — the

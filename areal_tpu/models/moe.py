@@ -25,17 +25,15 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from areal_tpu.utils.jax_compat import (
-    get_abstract_mesh,
-    shard_map,
-    with_sharding_constraint,
-)
+from jax.sharding import get_abstract_mesh
+
+from areal_tpu.utils.jax_compat import with_sharding_constraint
 from areal_tpu.utils.private_api import pin_signature
 
-# megablox gmm is a PRIVATE pallas op called positionally below; audited
-# against jax 0.4.37, verified at first use, re-checked against the
-# installed jax by arealint PVT002
+# megablox gmm is a PRIVATE pallas op called positionally below; verified
+# at first use, re-checked against the installed jax by arealint PVT002
 _EXPECTED_GMM_PARAMS = (
     "lhs",
     "rhs",
@@ -49,11 +47,17 @@ _EXPECTED_GMM_PARAMS = (
 )
 
 
+def pinned_gmm():
+    """jax's megablox grouped matmul, signature-verified at first use."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    return pin_signature(gmm, _EXPECTED_GMM_PARAMS)
+
+
 def _shard(x, spec):
-    # jax_compat's constraint drops manual axes (old shard_map manualizes
-    # every mesh axis) and no-ops outside a mesh — a raw
-    # jax.lax.with_sharding_constraint here dies at lowering inside the
-    # EP shard_map region on jax 0.4.x (arealint MSH003)
+    # jax_compat's constraint drops manual axes and no-ops outside a mesh —
+    # a raw jax.lax.with_sharding_constraint here dies at lowering inside
+    # the EP shard_map region, whose axes are Manual (arealint MSH003)
     return with_sharding_constraint(x, spec)
 
 
@@ -143,18 +147,13 @@ def moe_ffn_dropless(h: jax.Array, layer: dict, cfg) -> tuple[jax.Array, jax.Arr
     zero-3 per-use gather shard_map's in_specs perform; TP *within* expert
     FFNs is not sharded on this path (EP takes the expert axis; meshes
     that want both should use the capacity path)."""
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
     from areal_tpu.models.qwen import BATCH_AXES
 
-    pin_signature(gmm, _EXPECTED_GMM_PARAMS)
+    gmm = pinned_gmm()
 
     G, L, D = h.shape
     E, K = cfg.num_experts, cfg.num_experts_per_tok
-    try:
-        mesh = get_abstract_mesh()
-        axes = dict(mesh.shape) if mesh is not None else {}
-    except Exception:  # noqa: BLE001
-        axes = {}
+    axes = dict(get_abstract_mesh().shape)  # empty outside a mesh context
     e_sz = axes.get("expert", 1)
     d_sz = max(axes.get("data", 1) * axes.get("fsdp", 1), 1)
     s_sz = max(axes.get("seq", 1), 1)
@@ -181,7 +180,9 @@ def moe_ffn_dropless(h: jax.Array, layer: dict, cfg) -> tuple[jax.Array, jax.Arr
         # truly unshardable: run replicated — every device computes all
         # tokens. Loud, because on a big mesh this is a real perf cliff.
         _warn_replicated_once((G, L, d_sz, s_sz, e_sz))
-    interpret = jax.devices()[0].platform != "tpu"
+    # platform decides compiled-or-interpret, nothing else: on a TPU gmm is
+    # always compiled and a kernel the chip refuses is an error
+    interpret = jax.default_backend() != "tpu"
     tile_m0 = 16 if interpret else 128
 
     def block(h_blk, wr, wg, wu, wd):

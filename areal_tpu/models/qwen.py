@@ -35,7 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from areal_tpu.utils.jax_compat import get_abstract_mesh, shard_map
+from jax import shard_map
+from jax.sharding import get_abstract_mesh
 
 # mesh axes over which the microbatch rows (G dim) shard
 BATCH_AXES = ("data", "fsdp")
@@ -461,11 +462,7 @@ def _embed_lookup(
     Batch dims of ``ids`` stay sharded over ("data","fsdp")/"seq" throughout
     — no replication anywhere. Falls back to ``jnp.take`` when no mesh is
     active (single-chip serving, CPU tests)."""
-    try:
-        mesh = get_abstract_mesh()
-        axes = dict(mesh.shape) if mesh is not None else {}
-    except Exception:  # noqa: BLE001 — no mesh context
-        axes = {}
+    axes = dict(get_abstract_mesh().shape)  # empty outside a mesh context
     f_sz, m_sz = axes.get("fsdp", 1), axes.get("model", 1)
     if f_sz * m_sz == 1 or embed.shape[0] % (f_sz * m_sz):
         return jnp.take(embed, ids, axis=0).astype(dtype)
@@ -669,8 +666,8 @@ def _decoder_layer(cfg: ModelConfig, x, layer, mask, positions, impl=None):
 def _shard(x: jax.Array, spec: P) -> jax.Array:
     """Sharding constraint that is a no-op outside a mesh context and
     drops manual axes inside shard_map regions (the PP path wraps the
-    layer stack in shard_map over ``pipe``; on jax 0.4.x that manualizes
-    every mesh axis, and a raw constraint naming one dies at lowering)."""
+    layer stack in shard_map over ``pipe``; a raw constraint naming a
+    Manual axis dies at lowering)."""
     from areal_tpu.utils.jax_compat import with_sharding_constraint
 
     return with_sharding_constraint(x, spec)
@@ -941,6 +938,19 @@ def forward_prefill(
     return hidden, ks, vs
 
 
+def _gather_window(cache: dict, name: str, li, page_table: jax.Array) -> jax.Array:
+    """Layer ``li``'s window pages of ``cache[name]``, gathered dense:
+    [A, wp] page ids -> [A, wp * psz, KH, d] (d = head_dim for pages, 1 for
+    scales, which the pool stores lane-major, [.., 1, psz])."""
+    lay = jax.lax.dynamic_index_in_dim(cache[name], li, 0, keepdims=False)
+    g = lay[:, page_table]  # [KH, A, wp, psz, d]
+    if name.endswith("_scale"):
+        g = jnp.swapaxes(g, -1, -2)
+    A, wp = page_table.shape
+    KH, psz, d = g.shape[0], g.shape[3], g.shape[4]
+    return jnp.transpose(g, (1, 2, 3, 0, 4)).reshape(A, wp * psz, KH, d)
+
+
 def forward_prefill_paged(
     params: dict,
     cfg: ModelConfig,
@@ -984,10 +994,7 @@ def forward_prefill_paged(
     )  # [A, B, W]
 
     def gather(name, li):
-        lay = jax.lax.dynamic_index_in_dim(cache[name], li, 0, keepdims=False)
-        # [KH, A, wp, psz, d] -> [A, W, KH, d]
-        g = jnp.transpose(lay[:, page_table], (1, 2, 3, 0, 4))
-        return g.reshape(A, W, KH, g.shape[-1])
+        return _gather_window(cache, name, li, page_table)
 
     def body(x, scanned):
         layer, li = scanned
@@ -1114,10 +1121,7 @@ def forward_verify_paged(
     suf_mask = tree_mask[:, None]  # [S, 1, B, B]
 
     def gather(name, li):
-        lay = jax.lax.dynamic_index_in_dim(cache[name], li, 0, keepdims=False)
-        # [KH, S, wp, psz, d] -> [S, W, KH, d]
-        g = jnp.transpose(lay[:, page_table], (1, 2, 3, 0, 4))
-        return g.reshape(S, W, KH, g.shape[-1])
+        return _gather_window(cache, name, li, page_table)
 
     def body(x, scanned):
         layer, li = scanned
@@ -1239,20 +1243,29 @@ def forward_decode_paged(
         q = _rope(q, pos1, cfg.rope_theta)[:, 0]  # [S, H, hd]
         k = _rope(k, pos1, cfg.rope_theta)[:, 0]  # [S, KH, hd]
         v = v[:, 0]
-        # write the step's rows into (li, :, page[s], offset[s]). The traced
-        # ``li`` makes all three advanced indices broadcast together and the
-        # slice dim (KH) stay behind them -> value layout [S, KH, hd].
+        # write the step's rows into (li, h, page[s], offset[s]), ONE
+        # SCATTER PER KV HEAD. A single scatter over all heads has (KH, hd)
+        # update windows, for which the TPU compiler lays the whole carried
+        # cache out KH-minor — and the Pallas kernel below needs the default
+        # layout, so it would re-lay the ENTIRE cache out twice per layer
+        # per step (compiled for a described v5e: a cache-sized temp and two
+        # cache-sized copies in the layer loop; per head: none).
         c = dict(c)
         if kv_quant:
             kq, ksc = paged_kv.quantize_kv(k, dtype=cache["k"].dtype)
             vq, vsc = paged_kv.quantize_kv(v, dtype=cache["v"].dtype)
-            writes = (("k", kq), ("k_scale", ksc), ("v", vq), ("v_scale", vsc))
-        else:
-            writes = (("k", k), ("v", v))
-        for name, val in writes:
-            c[name] = c[name].at[li, :, write_page, write_off].set(
-                val.astype(c[name].dtype)
-            )
+            # scales are lane-major in the pool: [L, KH, N, 1, psz]
+            for name, sc in (("k_scale", ksc), ("v_scale", vsc)):
+                for h in range(KH):
+                    c[name] = c[name].at[li, h, write_page, 0, write_off].set(
+                        sc[:, h, 0]
+                    )
+            k, v = kq, vq
+        for name, val in (("k", k), ("v", v)):
+            for h in range(KH):
+                c[name] = c[name].at[li, h, write_page, write_off].set(
+                    val[:, h].astype(c[name].dtype)
+                )
         if use_kernel:
             # STACKED launch: the kernel slices ref.at[li] internally. A
             # dynamic_index_in_dim layer slice here would force XLA to

@@ -2,10 +2,13 @@
 
 The image ships no pybind11 and nothing may be pip-installed, so the
 binding is ctypes over a g++-built shared object (the toolchain IS baked
-in). The build is lazy and cached under ``AREAL_NATIVE_CACHE`` (default
-``~/.cache/areal_tpu/native``); any failure — no compiler, read-only cache,
-load error — degrades silently to the pure-Python implementations, which
-remain the semantic reference.
+in). The build is lazy, from the committed ``datapack.cc`` alone, and
+cached under ``AREAL_NATIVE_CACHE`` (default ``~/.cache/areal_tpu/native``)
+keyed by the source hash, so a fresh machine builds it once, inside its
+run. Any failure — no compiler, read-only cache, load error — falls back
+to the pure-Python implementations, which remain the semantic reference;
+the fallback is logged, and ``implementation()`` says which one is active
+(chip_smoke.py prints it in its ``device`` line).
 """
 
 from __future__ import annotations
@@ -84,7 +87,15 @@ def datapack_lib() -> ctypes.CDLL | None:
                 i64p,
             ]
             _lib = lib
+            logger.info(f"native datapack loaded from {path}")
         except Exception as e:  # noqa: BLE001 — fall back to pure Python
             _lib_failed = True
             logger.warning(f"native datapack unavailable ({e}); using Python")
     return _lib
+
+
+def implementation() -> str:
+    """Which datapack implementation this process uses: ``native`` (the
+    g++-built shared object) or ``python`` (the fallback). Builds on first
+    call, like any other use."""
+    return "native" if datapack_lib() is not None else "python"

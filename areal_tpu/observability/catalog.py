@@ -436,7 +436,7 @@ def train_obs_metrics(reg: Registry | None = None) -> SimpleNamespace:
     """Trainer goodput observatory (observability/step_timeline.py +
     hw_accounting.py): step-phase attribution, utilization, HBM ledger,
     and XLA compile visibility. The phase histogram labels by the step
-    phase taxonomy (rollout_wait | host_prep | forward_backward |
+    phase vocabulary (rollout_wait | host_prep | forward_backward |
     optimizer | weight_publish | ckpt_eval | other)."""
     r = reg or get_registry()
     return SimpleNamespace(
@@ -502,7 +502,7 @@ def learning_health_metrics(reg: Registry | None = None) -> SimpleNamespace:
     """Learning-health observatory (docs/observability.md): decoupled-PPO
     loss diagnostics conditioned on per-token version lag, computed in-jit
     by ``grpo_loss_fn`` and exported once per ``ppo_update``. The
-    ``lag_bucket`` label values are the staleness_manager taxonomy
+    ``lag_bucket`` label values are the staleness_manager vocabulary
     (``0 | 1 | 2 | 4+``); gauges carry the last step's view for dashboards
     while the ``*_total`` counters give the autopilot's signal plane a
     windowable (bucket-delta) view, per the PR 13 convention."""

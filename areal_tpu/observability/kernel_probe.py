@@ -7,7 +7,7 @@ exact-sum identity contract: named phases plus an explicit ``other_s``
 residual sum EXACTLY to the step's wall time. "The loop spends its time in
 X" is then an assertion about measured accumulators, never a vibe.
 
-Phase taxonomy (docs/perf.md "Kernel observatory"):
+Phase vocabulary (docs/perf.md "Kernel observatory"):
 
     admission     lifecycle reaping, queue pops, slot updates, dup admits
     radix_match   prefix-cache lookups for newly admitted primaries
@@ -296,18 +296,17 @@ class KernelProbe:
         if peak_flops is not None:
             self.peak_flops, self.peak_membw = peak_flops, peak_membw
             self.peak_source = "override"
+        elif calibrate:
+            # a TPU resolves from the chip table or raises; the CPU backend
+            # measures the host once so the roofline fraction is still a
+            # real number (init-time only: device work)
+            self.peak_flops, self.peak_membw, self.peak_source = (
+                hw.resolve_chip_peaks(device)
+            )
         else:
             self.peak_flops = hw.chip_peak_flops(device)
             self.peak_membw = hw.chip_peak_membw(device)
-            self.peak_source = "spec"
-            if self.peak_flops is None and calibrate:
-                # unknown chip (CPU): measure the host once so the roofline
-                # fraction is still a real number, not null (init-time only
-                # — this does device work and host pulls)
-                self.peak_flops, self.peak_membw = hw.calibrate_host_peaks()
-                self.peak_source = "calibrated"
-            elif self.peak_flops is None:
-                self.peak_source = "unknown"
+            self.peak_source = "unknown" if self.peak_flops is None else "spec"
 
     # -- cost registry -----------------------------------------------------
 

@@ -21,14 +21,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from areal_tpu.utils import logging as alog
 from areal_tpu.utils.private_api import pin_signature
+
+logger = alog.getLogger("attention")
 
 # flash_attention is a PRIVATE pallas op we call with keyword args whose
 # names (and the positional q/k/v order) a jax bump can silently change;
 # verified at first use, re-checked against the installed jax by arealint
-# PVT002. Audited against jax 0.4.37.
+# PVT002.
 _EXPECTED_FLASH_ATTENTION_PARAMS = (
     "q",
     "k",
@@ -49,13 +53,6 @@ def sdpa_xla(q, k, v, mask, head_dim: int):
     logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("ghqk,gkhd->gqhd", probs, v)
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
 
 
 def flash_ok(L: int, head_dim: int, block: int = 128) -> bool:
@@ -158,15 +155,6 @@ def _flash_fwd_kernel(
         o_ref[0, 0, :, :] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-try:  # pallas imports fail gracefully off-TPU builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # noqa: BLE001
-    _HAS_PALLAS = False
-
-
 def flash_fwd_pallas(
     q,
     k,
@@ -180,7 +168,6 @@ def flash_fwd_pallas(
     pre-replicated); segment_ids [G, L]. Causal by column index.
     ``interpret=True`` runs the kernel through the Pallas interpreter so
     CPU tier-1 and tools/kernelcheck.py can cover it (arealint KRN005)."""
-    assert _HAS_PALLAS
     G, L, H, d = q.shape
     assert L % blk_q == 0 and L % blk_k == 0, (L, blk_q, blk_k)
     scale = d**-0.5
@@ -221,20 +208,34 @@ def flash_fwd_pallas(
 FLASH_MIN_LEN = 4096
 
 
+@functools.lru_cache(maxsize=None)
+def _log_xla_instead(L: int, head_dim: int, backend: str) -> None:
+    # cached: one line per (shape, backend), however often it is traced
+    logger.info(
+        f"attn_impl=pallas runs as xla at L={L}, head_dim={head_dim} on "
+        f"{backend}: the flash kernel needs a TPU, head_dim % 128 == 0 and "
+        f"L a multiple of 128 >= {FLASH_MIN_LEN}"
+    )
+
+
 def resolve_impl(requested: str, L: int, head_dim: int) -> str:
-    """Static (trace-time) choice: 'pallas' only when the TPU kernel
-    supports the shape AND the sequence is long enough to win; anything else
-    degrades to 'xla'. 'ring' passes through (the ring wrapper itself falls
-    back off-mesh)."""
+    """Static (trace-time) choice, by platform and shape: 'pallas' only on
+    a TPU, when the kernel supports the shape AND the sequence is long
+    enough to win; anything else runs 'xla', logged once per shape. A
+    kernel the chip's compiler refuses is an error — nothing here catches
+    one. 'ring' passes through (the ring wrapper itself falls back
+    off-mesh)."""
     if requested == "ring":
         return "ring"
+    if requested != "pallas":
+        return "xla"
     if (
-        requested == "pallas"
-        and _on_tpu()
+        jax.default_backend() == "tpu"
         and flash_ok(L, head_dim)
         and L >= FLASH_MIN_LEN
     ):
         return "pallas"
+    _log_xla_instead(L, head_dim, jax.default_backend())
     return "xla"
 
 

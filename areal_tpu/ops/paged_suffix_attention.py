@@ -1,6 +1,6 @@
 """Paged suffix-attention kernel family: suffix-prefill + tree-verify.
 
-The decode path (q_len=1) rides the stacked paged-attention fork
+The decode path (q_len=1) rides the stacked paged decode kernel
 (ops/paged_attention_q8.py), but the two *batched-suffix* paths —
 radix-warm suffix prefill (``qwen.forward_prefill_paged``) and
 spec-decode tree verify (``qwen.forward_verify_paged``) — gathered every
@@ -8,26 +8,31 @@ prefix page into a dense [A, W, KH, hd] array and ran batched matmuls:
 a full HBM read + write of the windowed prefix per layer on exactly the
 paths every spec round and every radix-hit admission pays.
 
-This module is a repo-native Pallas kernel (not another fork of a private
-jax kernel) computing a block of suffix queries against page-table-indexed
-prefix KV plus the causal/tree-masked in-flight suffix:
+This module is a repo-native Pallas kernel computing a block of suffix
+queries against page-table-indexed prefix KV plus the causal/tree-masked
+in-flight suffix:
 
-  - grid over (slot, kv_head); all of a slot's suffix rows x group heads
-    form one [B*G, hd] query block per cell
-  - per-slot ``page_indices``/``prefix_lens`` arrive via scalar prefetch;
-    prefix pages are DMA-ed HBM->VMEM in double-buffered blocks of
+  - grid over (slot, kv_head, query tile, suffix key block). A query tile
+    is ``bq`` suffix rows x the kv head's G group heads — at most
+    ``_MAX_ROWS`` rows, so the tile, its f32 accumulator and its logits
+    fit VMEM at every suffix bucket the engine produces (one untiled
+    [B*G, hd] block did not from B=256 up)
+  - the first key step of a tile walks the prefix: per-slot
+    ``page_indices``/``prefix_lens`` arrive via scalar prefetch; prefix
+    pages are DMA-ed HBM->VMEM in double-buffered blocks of
     ``pages_per_compute_block`` pages, so the gathered prefix never
     materializes in HBM
-  - flash-style online softmax across prefix blocks, then one masked
-    suffix block — the mask operand is the ONLY thing distinguishing the
-    two launch variants: a causal chain mask gives suffix-prefill, an
-    ancestor tree mask gives tree-verify (subsuming ops/tree_attention.py
-    semantics on the paged pool)
-  - int8 / float8_e4m3fn pages dequantize IN VMEM with trailing-1
-    per-vector scales end to end (the paged_attention_q8 discipline:
-    4/head_dim the scale traffic); both dtypes share one dequant formula
-    ``x.astype(f32) * scale / 127.5`` because fp8 pages store
-    ``x * 127.5 / scale`` (inference/paged_kv.py quantize_kv)
+  - flash-style online softmax across prefix blocks and then across the
+    suffix key blocks (running max / sum / accumulator in VMEM scratch) —
+    the mask operand is the ONLY thing distinguishing the two launch
+    variants: a causal chain mask gives suffix-prefill, an ancestor tree
+    mask gives tree-verify (subsuming ops/tree_attention.py semantics on
+    the paged pool)
+  - int8 / float8_e4m3fn pages carry lane-major per-vector scales
+    ([..., 1, psz], the ops/paged_attention_q8.py discipline: compact in
+    HBM, DMA-sliceable, and applied to logit / probability COLUMNS so no
+    in-VMEM relayout is needed); both dtypes share one formula because
+    fp8 pages store ``x * 127.5 / scale`` (inference/paged_kv.py)
 
 Row-validity convention: a suffix row attends the prefix iff its mask
 DIAGONAL bit is set (mask[s, r, r]). ``qwen._attention_mask`` is
@@ -39,10 +44,11 @@ model's dense ``_sdpa`` instead emits a garbage uniform average on such
 rows; callers discard them either way, but the parity harness needs a
 reference with identical semantics).
 
-``interpret=None`` auto-selects interpret mode off-TPU so CPU tests and
-microbenches exercise the real kernel body; the TPU-compiled win is
-measured on hardware via the standing kernel-probe roofline phases
-(docs/perf.md for the honesty note).
+``interpret=None`` selects interpret mode off-TPU, so CPU tests exercise
+the real kernel body. On a TPU the kernel is always compiled: a kernel
+the chip's compiler refuses is an error, and callers choose kernel or
+gather statically from the shapes (``paged_kernel_ok`` in
+ops/paged_attention_q8.py), never from a caught compile error.
 """
 
 from __future__ import annotations
@@ -56,202 +62,172 @@ from jax.experimental.pallas import tpu as pltpu
 
 # shared with inference/paged_kv.py quantize_kv: scale = max|x| over
 # head_dim, stored value = x * 127.5 / scale (rint+clip for int8, raw cast
-# for float8_e4m3fn) -> one in-VMEM dequant formula for both page dtypes
+# for float8_e4m3fn) -> one dequant formula for both page dtypes
 _MAX_INT8 = 127.5
 _NEG_INF = -1e30
+# most query rows (suffix rows x group heads) one grid cell keeps in VMEM,
+# and most suffix keys one key step brings in
+_MAX_ROWS = 512
+_MAX_KEYS = 512
 
 
-def _interp(interpret):
+def _interp(interpret: bool | None) -> bool:
     if interpret is None:
-        return jax.devices()[0].platform != "tpu"
+        return jax.default_backend() != "tpu"
     return interpret
+
+
+def _tiles(B: int, G: int) -> tuple[int, int, int]:
+    """(Bp, bq, bk): the suffix length padded to the tiling (masked rows
+    and columns, sliced off again), suffix rows per query tile and suffix
+    keys per key step. ``bq`` is a sublane multiple dividing Bp with
+    bq*G <= _MAX_ROWS; ``bk`` is Bp itself up to _MAX_KEYS, beyond that a
+    lane-aligned divisor — so every B the engine produces (256-token
+    buckets, a cap at max_seq_len, a handful of verify nodes) tiles."""
+    if G * 8 > _MAX_ROWS:
+        raise ValueError(f"group size {G} exceeds the kernel's {_MAX_ROWS} rows")
+    step = 8 if B <= _MAX_KEYS else 128
+    Bp = -(-B // step) * step
+    bq = max(d for d in range(8, min(Bp, _MAX_ROWS // G) + 1, 8) if Bp % d == 0)
+    if Bp <= _MAX_KEYS:
+        return Bp, bq, Bp
+    bk = max(d for d in range(128, _MAX_KEYS + 1, 128) if Bp % d == 0)
+    return Bp, bq, bk
 
 
 def _suffix_kernel(
     plens_ref,  # SMEM [S] int32 — prefix tokens per slot
     pidx_ref,  # SMEM [S * wp] int32 — flat page table
     layer_ref,  # SMEM [1] int32 — which layer's pages to read
-    q_ref,  # [BG, hd] f32 — this cell's query rows (pre-scaled)
-    ks_ref,  # [B, hd] f32 — in-flight suffix K for this kv head
-    vs_ref,  # [B, hd] f32
-    mask_ref,  # [BG, B] int32 — suffix validity (chain or tree)
-    k_hbm,  # ANY [L, KH, N, psz, hd] — paged prefix K
-    k_scales_hbm,  # ANY [L, KH, N, psz, 1] f32 (quant launch only)
-    v_hbm,
-    v_scales_hbm,
-    o_ref,  # [BG, hd] f32
-    k_vmem,  # VMEM [2, ppcb, psz, hd] — double-buffered page landing
-    k_scales_vmem,  # VMEM [2, ppcb, psz, 1] (quant launch only)
-    v_vmem,
-    v_scales_vmem,
-    sem,  # one DMA semaphore shared by all page copies
-    *,
+    q_ref,  # [G*bq, hd] f32 — this tile's query rows, pre-scaled, g-major
+    ks_ref,  # [bk, hd] — this key step's in-flight suffix K
+    vs_ref,  # [bk, hd]
+    mask_ref,  # [bq, bk] int32 — suffix validity (chain or tree)
+    valid_ref,  # [bq, 128] int32 — row attends the prefix (lane-broadcast)
+    *refs,
     wp: int,
     ppcb: int,
-    page_size: int,
     num_groups: int,
-    b_suffix: int,
-    head_dim: int,
+    quant: bool,
 ):
-    s = pl.program_id(0)
-    h = pl.program_id(1)
+    if quant:
+        (k_hbm, ks_hbm, v_hbm, vs_hbm, o_ref,
+         k_vmem, ks_vmem, v_vmem, vs_vmem, sems, m_scr, l_scr, acc_scr) = refs
+    else:
+        k_hbm, v_hbm, o_ref, k_vmem, v_vmem, sems, m_scr, l_scr, acc_scr = refs
+        ks_hbm = vs_hbm = ks_vmem = vs_vmem = None
+    s, h, ik = pl.program_id(0), pl.program_id(1), pl.program_id(3)
     li = layer_ref[0]
     plen = plens_ref[s]
-    quant = k_scales_hbm is not None
-    bg = b_suffix * num_groups
+    _, _, _, page_size, head_dim = k_hbm.shape
+    rows = q_ref.shape[0]
     bs = ppcb * page_size  # tokens per prefix block
     nb = (plen + bs - 1) // bs  # prefix blocks this slot actually needs
+    q = q_ref[...].astype(jnp.float32)  # [rows, hd]
 
-    def _block_copies(blk, slot):
+    def tile_rows(x):
+        # [bq, n] -> [G*bq, n]: row g*bq + i is suffix row i for every head g
+        return jnp.concatenate([x] * num_groups, axis=0)
+
+    def online_update(logits, valid, v, v_col_scale=None):
+        """One flash step over a key block: fold [rows, n] logits (valid
+        where ``valid``) and their values into the running scratch."""
+        logits = jnp.where(valid, logits, _NEG_INF)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if v_col_scale is not None:
+            p = p * v_col_scale
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    def block_copies(blk, slot):
         """Async-copy descriptors for prefix block ``blk`` -> buffer
-        ``slot`` — built identically at start() and wait() time (the
-        semaphore counts bytes; copies complete in issue order)."""
+        ``slot`` — built identically at start() and wait() time. A buffer's
+        copies share that buffer's semaphore (it counts bytes), so block
+        i+1's in-flight copies can never satisfy a wait on block i."""
         copies = []
         for j in range(ppcb):  # static unroll
             page = pidx_ref[s * wp + blk * ppcb + j]
-            copies.append(
-                pltpu.make_async_copy(
-                    k_hbm.at[li, h, page], k_vmem.at[slot, j], sem
-                )
-            )
-            copies.append(
-                pltpu.make_async_copy(
-                    v_hbm.at[li, h, page], v_vmem.at[slot, j], sem
-                )
-            )
+            pairs = [(k_hbm, k_vmem), (v_hbm, v_vmem)]
             if quant:
+                pairs += [(ks_hbm, ks_vmem), (vs_hbm, vs_vmem)]
+            for hbm, vmem in pairs:
                 copies.append(
                     pltpu.make_async_copy(
-                        k_scales_hbm.at[li, h, page],
-                        k_scales_vmem.at[slot, j],
-                        sem,
-                    )
-                )
-                copies.append(
-                    pltpu.make_async_copy(
-                        v_scales_hbm.at[li, h, page],
-                        v_scales_vmem.at[slot, j],
-                        sem,
+                        hbm.at[li, h, page], vmem.at[slot, j], sems.at[slot]
                     )
                 )
         return copies
 
-    q = q_ref[...].astype(jnp.float32)  # [BG, hd]
-    mask_s = mask_ref[...] > 0  # [BG, B]
-    # row attends the prefix iff its SELF bit is set: row r = i*G + g maps
-    # to suffix row i, so select column i of the mask per row
-    self_col = (
-        jax.lax.broadcasted_iota(jnp.int32, (bg, b_suffix), 0) // num_groups
-    )
-    col_id = jax.lax.broadcasted_iota(jnp.int32, (bg, b_suffix), 1)
-    row_valid = jnp.sum(
-        jnp.where((col_id == self_col) & mask_s, 1, 0), axis=1, keepdims=True
-    ) > 0  # [BG, 1]
+    def scale_row(buf, slot):
+        # [ppcb, 1, psz] -> [1, bs]: the pages' lane-major scales side by side
+        sc = buf[slot].astype(jnp.float32)
+        return jnp.concatenate([sc[j] for j in range(ppcb)], axis=-1) / _MAX_INT8
 
-    @pl.when(nb > 0)
-    def _prologue():
-        for c in _block_copies(0, 0):
-            c.start()
+    @pl.when(ik == 0)
+    def _prefix():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        # row attends the prefix iff its SELF bit is set
+        row_valid = tile_rows(valid_ref[:, :1]) > 0  # [rows, 1]
 
-    def _prefix_block(i, carry):
-        m_prev, l_prev, acc = carry
-        slot = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < nb)
-        def _next():  # overlap block i's compute with block i+1's DMA
-            for c in _block_copies(i + 1, jax.lax.rem(i + 1, 2)):
+        @pl.when(nb > 0)
+        def _prologue():
+            for c in block_copies(0, 0):
                 c.start()
 
-        for c in _block_copies(i, slot):
-            c.wait()
-        k_blk = k_vmem[slot].astype(jnp.float32)  # [ppcb, psz, hd]
-        v_blk = v_vmem[slot].astype(jnp.float32)
-        if quant:
-            k_blk = k_blk * (
-                k_scales_vmem[slot].astype(jnp.float32) / _MAX_INT8
-            )
-            v_blk = v_blk * (
-                v_scales_vmem[slot].astype(jnp.float32) / _MAX_INT8
-            )
-        k2 = k_blk.reshape(bs, head_dim)
-        v2 = v_blk.reshape(bs, head_dim)
-        logits = jax.lax.dot_general(
-            q, k2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [BG, bs]
-        col = jax.lax.broadcasted_iota(jnp.int32, (bg, bs), 1) + i * bs
-        valid = (col < plen) & row_valid
-        logits = jnp.where(valid, logits, _NEG_INF)
-        m_blk = jnp.max(logits, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_blk)
-        p = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + jax.lax.dot_general(
-            p, v2, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        return m_new, l_new, acc
+        def prefix_block(i, _):
+            slot = jax.lax.rem(i, 2)
 
-    init = (
-        jnp.full((bg, 1), _NEG_INF, jnp.float32),
-        jnp.zeros((bg, 1), jnp.float32),
-        jnp.zeros((bg, head_dim), jnp.float32),
-    )
-    m, l, acc = jax.lax.fori_loop(0, nb, _prefix_block, init)
+            @pl.when(i + 1 < nb)
+            def _next():  # overlap block i's compute with block i+1's DMA
+                for c in block_copies(i + 1, 1 - slot):
+                    c.start()
 
-    # the in-flight suffix: one block, gated entirely by the mask operand
-    ks = ks_ref[...].astype(jnp.float32)  # [B, hd]
-    vs = vs_ref[...].astype(jnp.float32)
+            for c in block_copies(i, slot):
+                c.wait()
+            k2 = k_vmem[slot].astype(jnp.float32).reshape(bs, head_dim)
+            v2 = v_vmem[slot].astype(jnp.float32).reshape(bs, head_dim)
+            logits = jax.lax.dot_general(
+                q, k2, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [rows, bs]
+            if quant:
+                logits = logits * scale_row(ks_vmem, slot)
+            col = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1) + i * bs
+            online_update(
+                logits,
+                (col < plen) & row_valid,
+                v2,
+                scale_row(vs_vmem, slot) if quant else None,
+            )
+            return ()
+
+        jax.lax.fori_loop(0, nb, prefix_block, ())
+
+    # the in-flight suffix, one key block per grid step, gated entirely by
+    # the mask operand
     logits = jax.lax.dot_general(
-        q, ks, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [BG, B]
-    logits = jnp.where(mask_s, logits, _NEG_INF)
-    m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
-    p = jnp.where(mask_s, jnp.exp(logits - m_new), 0.0)
-    corr = jnp.exp(m - m_new)
-    l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc = acc * corr + jax.lax.dot_general(
-        p, vs, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        q, ks_ref[...].astype(jnp.float32), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # [rows, bk]
+    online_update(
+        logits, tile_rows(mask_ref[...]) > 0, vs_ref[...].astype(jnp.float32)
     )
-    # all-masked rows have l == 0 and acc == 0 -> exact zero output
-    o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
-
-def _suffix_kernel_noscale(
-    plens_ref,
-    pidx_ref,
-    layer_ref,
-    q_ref,
-    ks_ref,
-    vs_ref,
-    mask_ref,
-    k_hbm,
-    v_hbm,
-    o_ref,
-    k_vmem,
-    v_vmem,
-    sem,
-    **kw,
-):
-    _suffix_kernel(
-        plens_ref,
-        pidx_ref,
-        layer_ref,
-        q_ref,
-        ks_ref,
-        vs_ref,
-        mask_ref,
-        k_hbm,
-        None,
-        v_hbm,
-        None,
-        o_ref,
-        k_vmem,
-        None,
-        v_vmem,
-        None,
-        sem,
-        **kw,
-    )
+    @pl.when(ik == pl.num_programs(3) - 1)
+    def _finalize():
+        # all-masked rows have l == 0 and acc == 0 -> exact zero output
+        o_ref[...] = (
+            acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
+        ).astype(o_ref.dtype)
 
 
 def paged_suffix_attention(
@@ -265,7 +241,7 @@ def paged_suffix_attention(
     page_indices: jax.Array,  # [S, wp] int32 — window's pages per slot
     suffix_mask: jax.Array,  # [S, B, B] bool — row attends col (chain/tree)
     *,
-    k_scales: jax.Array | None = None,  # f32 [L, KH, N, psz, 1] (quant pages)
+    k_scales: jax.Array | None = None,  # f32 [L, KH, N, 1, psz] (quant pages)
     v_scales: jax.Array | None = None,
     pages_per_compute_block: int | None = None,
     interpret: bool | None = None,
@@ -274,13 +250,12 @@ def paged_suffix_attention(
     -> [S, B, H, hd]. One kernel body, two launch variants: a causal chain
     ``suffix_mask`` is suffix-prefill, an ancestor tree mask is
     spec-decode verify. Reads layer ``layer`` of the FULL stacked cache
-    (sliced inside the kernel — the paged_attention_q8 r04 discipline:
-    a host-side layer slice would make XLA materialize every layer's
-    pages per scan step). Scales, when given, stay NARROW ([..., 1])."""
+    (sliced inside the kernel: a host-side layer slice would make XLA
+    materialize every layer's pages per scan step). Scales, when given,
+    are lane-major ([..., 1, psz])."""
     S, B, H, hd = q.shape
     L, KH, N, psz, hd_k = k_pages.shape
     wp = page_indices.shape[1]
-    orig_dtype = q.dtype
     if k_pages.shape != v_pages.shape:
         raise ValueError(f"k/v page shapes differ: {k_pages.shape} {v_pages.shape}")
     if hd_k != hd:
@@ -294,8 +269,10 @@ def paged_suffix_attention(
     quant = k_scales is not None
     if quant != (v_scales is not None):
         raise ValueError("k_scales and v_scales must be given together")
-    if quant and k_scales.shape != (*k_pages.shape[:-1], 1):
-        raise ValueError(f"narrow scales expected, got {k_scales.shape}")
+    if quant and k_scales.shape != (*k_pages.shape[:-2], 1, psz):
+        raise ValueError(
+            f"lane-major scales [..., 1, {psz}] expected, got {k_scales.shape}"
+        )
     ppcb = pages_per_compute_block
     if ppcb is None:
         ppcb = next(d for d in range(min(wp, 8), 0, -1) if wp % d == 0)
@@ -303,55 +280,87 @@ def paged_suffix_attention(
         raise ValueError(f"wp={wp} not divisible by ppcb={ppcb}")
 
     G = H // KH
-    BG = B * G
-    # row order i*G + g: suffix row-major, group heads minor — the mask
-    # expansion below must (and does) match
+    B_in = B
+    B, bq, bk = _tiles(B_in, G)
+    if B != B_in:  # pad to the tiling: masked-out rows and columns
+        pad = B - B_in
+        q, k_suffix, v_suffix = (
+            jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            for x in (q, k_suffix, v_suffix)
+        )
+        suffix_mask = jnp.pad(suffix_mask, ((0, 0), (0, pad), (0, pad)))
+    nq, nk = B // bq, B // bk
+    rows = G * bq
+    # query rows of one tile are g-major (row g*bq + i): the [bq, bk] mask
+    # tile then expands to the G heads by plain sublane concatenation
     qt = (
         (q.astype(jnp.float32) * hd**-0.5)
-        .reshape(S, B, KH, G, hd)
-        .transpose(0, 2, 1, 3, 4)
-        .reshape(S, KH, BG, hd)
+        .reshape(S, nq, bq, KH, G, hd)
+        .transpose(0, 3, 1, 4, 2, 5)
+        .reshape(S, KH, nq, rows, hd)
     )
-    ks = jnp.transpose(k_suffix, (0, 2, 1, 3)).astype(jnp.float32)  # [S,KH,B,hd]
-    vs = jnp.transpose(v_suffix, (0, 2, 1, 3)).astype(jnp.float32)
-    mask = jnp.broadcast_to(
-        suffix_mask[:, :, None, :], (S, B, G, B)
-    ).reshape(S, BG, B).astype(jnp.int32)
+    ks = jnp.transpose(k_suffix, (0, 2, 1, 3))  # [S, KH, B, hd]
+    vs = jnp.transpose(v_suffix, (0, 2, 1, 3))
+    mask = suffix_mask.astype(jnp.int32)
+    row_valid = jnp.broadcast_to(
+        suffix_mask[:, jnp.arange(B), jnp.arange(B)].astype(jnp.int32)[..., None],
+        (S, B, 128),
+    )  # the diagonal, lane-broadcast like flash_fwd_pallas's segment ids
 
-    kernel = functools.partial(
-        _suffix_kernel if quant else _suffix_kernel_noscale,
-        wp=wp,
-        ppcb=ppcb,
-        page_size=psz,
-        num_groups=G,
-        b_suffix=B,
-        head_dim=hd,
+    q_spec = pl.BlockSpec(
+        (None, None, None, rows, hd), lambda s, h, iq, ik, *_: (s, h, iq, 0, 0)
     )
-    in_specs = [
-        pl.BlockSpec((None, None, BG, hd), lambda s, h, *_: (s, h, 0, 0)),
-        pl.BlockSpec((None, None, B, hd), lambda s, h, *_: (s, h, 0, 0)),
-        pl.BlockSpec((None, None, B, hd), lambda s, h, *_: (s, h, 0, 0)),
-        pl.BlockSpec((None, BG, B), lambda s, h, *_: (s, 0, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),  # k_pages
+    kv_spec = pl.BlockSpec(
+        (None, None, bk, hd), lambda s, h, iq, ik, *_: (s, h, ik, 0)
+    )
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+
+    def page_buf(dtype):
+        return pltpu.VMEM((2, ppcb, psz, hd), dtype)
+
+    def scale_buf(dtype):
+        return pltpu.VMEM((2, ppcb, 1, psz), dtype)
+
+    if quant:
+        pages = [k_pages, k_scales, v_pages, v_scales]
+        scratch = [
+            page_buf(k_pages.dtype), scale_buf(k_scales.dtype),
+            page_buf(v_pages.dtype), scale_buf(v_scales.dtype),
+        ]
+    else:
+        pages = [k_pages, v_pages]
+        scratch = [page_buf(k_pages.dtype), page_buf(v_pages.dtype)]
+    scratch += [
+        pltpu.SemaphoreType.DMA((2,)),  # one per landing buffer
+        pltpu.VMEM((rows, 128), jnp.float32),  # running max (lane-broadcast)
+        pltpu.VMEM((rows, 128), jnp.float32),  # running sum
+        pltpu.VMEM((rows, hd), jnp.float32),  # accumulator
     ]
-    if quant:
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))  # k_scales
-    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))  # v_pages
-    if quant:
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))  # v_scales
 
-    def kv_vmem(dtype, trailing):
-        return pltpu.VMEM((2, ppcb, psz, trailing), dtype)
-
-    scratch_shapes = [kv_vmem(k_pages.dtype, hd)]
-    if quant:
-        scratch_shapes.append(kv_vmem(k_scales.dtype, 1))
-    scratch_shapes.append(kv_vmem(v_pages.dtype, hd))
-    if quant:
-        scratch_shapes.append(kv_vmem(v_scales.dtype, 1))
-    scratch_shapes.append(pltpu.SemaphoreType.DMA)
-
-    operands = [
+    out = pl.pallas_call(
+        functools.partial(
+            _suffix_kernel, wp=wp, ppcb=ppcb, num_groups=G, quant=quant
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            in_specs=[
+                q_spec,
+                kv_spec,
+                kv_spec,
+                pl.BlockSpec((None, bq, bk), lambda s, h, iq, ik, *_: (s, iq, ik)),
+                pl.BlockSpec((None, bq, 128), lambda s, h, iq, ik, *_: (s, iq, 0)),
+            ]
+            + [any_spec] * len(pages),
+            out_specs=q_spec,
+            grid=(S, KH, nq, nk),
+            scratch_shapes=tuple(scratch),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 4
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, KH, nq, rows, hd), jnp.float32),
+        interpret=_interp(interpret),
+    )(
         prefix_lens.astype(jnp.int32),
         page_indices.reshape(-1).astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1),
@@ -359,36 +368,14 @@ def paged_suffix_attention(
         ks,
         vs,
         mask,
-        k_pages,
-    ]
-    if quant:
-        operands.append(k_scales)
-    operands.append(v_pages)
-    if quant:
-        operands.append(v_scales)
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (None, None, BG, hd), lambda s, h, *_: (s, h, 0, 0)
-            ),
-            grid=(S, KH),
-            scratch_shapes=tuple(scratch_shapes),
-        ),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")
-        ),
-        out_shape=jax.ShapeDtypeStruct((S, KH, BG, hd), jnp.float32),
-        interpret=_interp(interpret),
-    )(*operands)
+        row_valid,
+        *pages,
+    )
     return (
-        out.reshape(S, KH, B, G, hd)
-        .transpose(0, 2, 1, 3, 4)
-        .reshape(S, B, H, hd)
-        .astype(orig_dtype)
+        out.reshape(S, KH, nq, G, bq, hd)
+        .transpose(0, 2, 4, 1, 3, 5)
+        .reshape(S, B, H, hd)[:, :B_in]
+        .astype(q.dtype)
     )
 
 
@@ -409,23 +396,26 @@ def paged_suffix_attention_xla(
     """Pure-XLA reference with the kernel's EXACT semantics (gather +
     grouped einsum, f32, zero output on all-masked rows, prefix gated by
     the mask diagonal) — kernelcheck's ground truth and the fallback the
-    model paths keep behind ``use_kernel=False``."""
+    model paths take when ``use_kernel=False``."""
     S, B, H, hd = q.shape
     KH, psz = k_pages.shape[1], k_pages.shape[3]
     G = H // KH
     wp = page_indices.shape[1]
     W = wp * psz
 
-    def gather(pages):
+    def gather(pages, scales=False):
         lay = jax.lax.dynamic_index_in_dim(pages, layer, 0, keepdims=False)
-        g = jnp.transpose(lay[:, page_indices], (1, 2, 3, 0, 4))
+        g = lay[:, page_indices]
+        if scales:  # lane-major [.., 1, psz] -> [.., psz, 1]
+            g = jnp.swapaxes(g, -1, -2)
+        g = jnp.transpose(g, (1, 2, 3, 0, 4))
         return g.reshape(S, W, KH, g.shape[-1])
 
     kp = gather(k_pages).astype(jnp.float32)
     vp = gather(v_pages).astype(jnp.float32)
     if k_scales is not None:
-        kp = kp * (gather(k_scales).astype(jnp.float32) / _MAX_INT8)
-        vp = vp * (gather(v_scales).astype(jnp.float32) / _MAX_INT8)
+        kp = kp * (gather(k_scales, scales=True) / _MAX_INT8)
+        vp = vp * (gather(v_scales, scales=True) / _MAX_INT8)
     k_full = jnp.concatenate(
         [kp, k_suffix.astype(jnp.float32)], axis=1
     )  # [S, W+B, KH, hd]

@@ -21,7 +21,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from areal_tpu.utils.jax_compat import axis_size, get_abstract_mesh, shard_map
+from jax import shard_map
+from jax.lax import axis_size
+from jax.sharding import get_abstract_mesh
 
 
 def _block_attn(q, k, v, seg_q, seg_k, idx_q, idx_k, scale):
@@ -67,12 +69,10 @@ def _ring_shard_fn(q, k, v, seg, idx, axis_name: str, scale: float, vary_axes=()
     # initial accumulators must carry the same varying-manual-axes type as
     # the loop outputs (which depend on mesh-varying q/k/v)
     axes = tuple(vary_axes) or (axis_name,)
-    if hasattr(jax.lax, "pcast"):
-        _vary = lambda x: jax.lax.pcast(x, axes, to="varying")  # noqa: E731
-    elif hasattr(jax.lax, "pvary"):
-        _vary = lambda x: jax.lax.pvary(x, axes)  # noqa: E731
-    else:  # pre-varying-types jax: no manual-axes type system to satisfy
-        _vary = lambda x: x  # noqa: E731
+
+    def _vary(x):
+        return jax.lax.pcast(x, axes, to="varying")
+
     o0 = _vary(jnp.zeros((B, H, Lc, d), jnp.float32))
     m0 = _vary(jnp.full((B, H, Lc), -jnp.inf, jnp.float32))
     l0 = _vary(jnp.zeros((B, H, Lc), jnp.float32))
@@ -94,7 +94,7 @@ def ring_attention(
     """Context-parallel causal attention for packed grids. Call inside jit
     with a mesh context; outside a mesh it falls back to single-device."""
     mesh = mesh or get_abstract_mesh()
-    if mesh is None or axis_name not in mesh.shape or mesh.shape[axis_name] == 1:
+    if axis_name not in mesh.shape or mesh.shape[axis_name] == 1:
         scale = q.shape[-1] ** -0.5
         logits = _block_attn(q, k, v, segment_ids, segment_ids, col_index, col_index, scale)
         m = jnp.max(logits, axis=-1, keepdims=True)

@@ -1,33 +1,42 @@
-"""kernelcheck — standing interpret-vs-XLA parity harness over ops/ kernels.
+"""kernelcheck — standing kernel-vs-XLA parity harness over ops/ kernels.
 
-Every Pallas kernel in ``areal_tpu/ops/`` registers a *case grid* here:
-closures that run the kernel in interpret mode (CPU) and an independent
-pure-XLA reference over a spread of shapes/dtypes/quantization variants.
-``python -m areal_tpu.tools.kernelcheck`` runs the whole grid and exits
-nonzero on any divergence — so the next kernel PR (ROADMAP item 2) lands
-onto a standing differential harness instead of ad-hoc parity tests, and
-a jax bump that changes kernel semantics (not just signatures — PVT
-covers those) fails loudly in CI.
+Every Pallas kernel in ``areal_tpu/ops/`` (and each private jax kernel the
+main path calls) registers a *case grid* here: the kernel and an
+independent pure-XLA reference over a spread of shapes/dtypes/quantization
+variants. ``python -m areal_tpu.tools.kernelcheck`` runs the whole grid in
+interpret mode on the CPU and exits nonzero on any divergence — so a
+kernel PR lands onto a standing differential harness instead of ad-hoc
+parity tests, and a jax bump that changes kernel semantics (not just
+signatures — PVT covers those) fails loudly in CI. ``--compiled`` runs the
+same closures at Qwen2.5-1.5B head shapes with ``interpret=False`` on a
+TPU: chip_smoke.py's ``kernels`` phase.
 
 Registering a kernel:
 
     @register_kernel("my_kernel")
-    def _cases():
+    def _cases(compiled: bool = False):
         yield {
-            "case": "f32-basic",        # unique within the kernel
-            "kernel": lambda: ...,      # interpret-mode launch -> array
-            "reference": lambda: ...,   # pure-XLA ground truth -> array
-            "tol": 2e-2,                # max |kernel - reference| allowed
+            "case": "f32-basic",            # unique within the kernel
+            "build": lambda: {...},         # seeded inputs, as a pytree
+            "kernel": lambda inp: ...,      # the launch -> array
+            "reference": lambda inp: ...,   # pure-XLA ground truth -> array
+            "tol": 2e-2,                    # max |kernel - reference| allowed
         }
 
-The harness materializes both sides, compares max-abs-diff against the
-case tolerance, and reports per-case PASS/FAIL. Closures build their own
-inputs deterministically (seeded numpy) so runs are reproducible.
+The harness jits ``build``, ``kernel`` and ``reference`` — three programs
+a case, not one per eager op: on a TPU every tiny program is a compile of
+a few tenths of a second, and a grid built from eager ops spent five
+minutes compiling six hundred of them — then compares max-abs-diff
+against the case tolerance and reports per-case PASS/FAIL. Inputs come
+from seeds (``jax.random`` on the device; small host tables from seeded
+numpy), so runs are reproducible. A case without ``build`` is two plain
+zero-argument closures, run as they are.
 
 CLI:
   --list            enumerate registered kernels and their case counts
   --kernel NAME     run one kernel's grid only
   --json            machine-readable report on stdout
+  --compiled        the on-chip grid (needs a TPU)
 """
 
 from __future__ import annotations
@@ -39,7 +48,12 @@ from typing import Any, Callable, Dict, Iterator
 
 import numpy as np
 
-REGISTRY: Dict[str, Callable[[], "Iterator[dict]"]] = {}
+REGISTRY: Dict[str, Callable[..., "Iterator[dict]"]] = {}
+
+# tolerance of every compiled (on-chip) case: bf16 operands, f32
+# accumulation, outputs of magnitude <= 1 — a wrong mask, scale or page
+# shows as 1e-1 and more
+CHIP_TOL = 3e-2
 
 
 def register_kernel(name: str) -> Callable:
@@ -50,95 +64,180 @@ def register_kernel(name: str) -> Callable:
     return deco
 
 
-# ---------------------------------------------------------------------------
-# paged attention (ops/paged_attention_q8.py): int8 narrow scales + stacked
-# ---------------------------------------------------------------------------
-
-
-def _paged_inputs(S=4, KH=2, G=6, hd=128, psz=16, wp=4, layers=1, seed=0):
+def _normal(seed: int, shape, dtype=None):
+    """Seeded N(0, 1); traced inside a case's ``build`` (the chip-size pools
+    are too large to build in numpy and ship over)."""
+    import jax
     import jax.numpy as jnp
 
+    return jax.random.normal(jax.random.PRNGKey(seed), shape, dtype or jnp.float32)
+
+
+# Every generator takes ``compiled``: False is the interpret-mode grid at
+# small shapes (CPU tier-1), True the SAME closures at Qwen2.5-1.5B head
+# shapes (12 heads / 2 KV heads / head_dim 128, 128-token pages) with
+# interpret=False — what chip_smoke.py's ``kernels`` phase runs on the TPU.
+
+# ---------------------------------------------------------------------------
+# paged decode attention (ops/paged_attention_q8.py): bf16 / int8 / fp8
+# pages, lane-major scales, stacked cache
+# ---------------------------------------------------------------------------
+
+_PAGED_CHIP = dict(S=32, KH=2, G=6, hd=128, psz=128, wp=16)
+
+
+def _paged_build(S=4, KH=2, G=6, hd=128, psz=16, wp=4, layers=1, seed=0,
+                 q_dtype=None, pages=None):
+    """build() for the decode cases: q, one stacked K/V pool (float, or
+    quantized to ``pages`` with lane-major scales), ragged lengths with a
+    full slot and an empty one, and a page table."""
+    import jax.numpy as jnp
+
+    from areal_tpu.inference import paged_kv
+
     rng = np.random.default_rng(seed)
-    H = KH * G
     N = S * wp + 1
-    q = jnp.asarray(rng.normal(0, 1, (S, H, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(0, 1, (layers, KH, N, psz, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(0, 1, (layers, KH, N, psz, hd)), jnp.float32)
-    pt = jnp.asarray(1 + np.arange(S * wp).reshape(S, wp), jnp.int32)
-    lengths = jnp.asarray(rng.integers(1, wp * psz + 1, S), jnp.int32)
-    return q, k, v, lengths, pt
+    pt = 1 + np.arange(S * wp, dtype=np.int32).reshape(S, wp)
+    lens = rng.integers(1, wp * psz + 1, S).astype(np.int32)
+    lens[0], lens[-1] = wp * psz, 0
+
+    def build():
+        inp = {
+            "q": _normal(seed, (S, KH * G, hd), q_dtype),
+            "k": _normal(seed + 100, (layers, KH, N, psz, hd)),
+            "v": _normal(seed + 200, (layers, KH, N, psz, hd)),
+            "lengths": jnp.asarray(lens),
+            "pt": jnp.asarray(pt),
+        }
+        if pages in (jnp.int8, jnp.float8_e4m3fn):
+            inp["k"], inp["ks"] = paged_kv.quantize_pages(inp["k"], dtype=pages)
+            inp["v"], inp["vs"] = paged_kv.quantize_pages(inp["v"], dtype=pages)
+        elif pages is not None:
+            inp["k"], inp["v"] = inp["k"].astype(pages), inp["v"].astype(pages)
+        return inp
+
+    return build
+
+
+def _live(out, lengths):
+    """An empty slot's row is unspecified (the kernel writes zeros, the
+    gather path a uniform average): compare live slots only. The empty
+    slot still rides in the batch — the kernel must step over it."""
+    import jax.numpy as jnp
+
+    return jnp.where(lengths[:, None, None] > 0, out, 0)
+
+
+def _paged_reference(layer):
+    from areal_tpu.inference import paged_kv
+
+    def reference(inp):
+        scales = (inp["ks"][layer], inp["vs"][layer]) if "ks" in inp else ()
+        return _live(
+            paged_kv.paged_attention_xla(
+                inp["q"], inp["k"][layer], inp["v"][layer],
+                inp["lengths"], inp["pt"], *scales,
+            ),
+            inp["lengths"],
+        )
+
+    return reference
 
 
 @register_kernel("paged_attention_q8")
-def _cases_paged_q8() -> Iterator[dict]:
-    from areal_tpu.inference import paged_kv
+def _cases_paged_q8(compiled: bool = False) -> Iterator[dict]:
+    import jax.numpy as jnp
+
     from areal_tpu.ops.paged_attention_q8 import paged_attention_q8
 
-    for S, KH, G, label in ((4, 2, 6, "int8-S4-gqa6"), (2, 1, 8, "int8-S2-mha8")):
-        q, k, v, lengths, pt = _paged_inputs(S=S, KH=KH, G=G, seed=S)
-        kq, ks = paged_kv.quantize_kv(k[0])
-        vq, vs = paged_kv.quantize_kv(v[0])
+    if compiled:
+        grid = [(dict(_PAGED_CHIP), "int8-1p5b-heads", 4)]
+    else:
+        grid = [
+            (dict(S=4, KH=2, G=6), "int8-S4-gqa6", 2),
+            (dict(S=2, KH=1, G=8), "int8-S2-mha8", 2),
+        ]
+    for shape, label, ppcb in grid:
         yield {
             "case": label,
-            # the fork takes RAW q (applies 1/sqrt(hd) internally)
-            "kernel": lambda q=q, kq=kq, ks=ks, vq=vq, vs=vs, le=lengths, pt=pt: (
+            "build": _paged_build(seed=shape["S"], pages=jnp.int8, **shape),
+            # takes RAW q (applies 1/sqrt(hd) internally)
+            "kernel": lambda inp, ppcb=ppcb: _live(
                 paged_attention_q8(
-                    q, kq, ks, vq, vs, le, pt,
-                    pages_per_compute_block=2,
-                    interpret=True,
-                )
+                    inp["q"], inp["k"][0], inp["ks"][0], inp["v"][0], inp["vs"][0],
+                    inp["lengths"], inp["pt"],
+                    pages_per_compute_block=ppcb,
+                    interpret=not compiled,
+                ),
+                inp["lengths"],
             ),
-            "reference": lambda q=q, kq=kq, ks=ks, vq=vq, vs=vs, le=lengths, pt=pt: (
-                paged_kv.paged_attention_xla(q, kq, vq, le, pt, ks, vs)
-            ),
+            "reference": _paged_reference(0),
             "tol": 3e-2,
         }
 
 
 @register_kernel("paged_attention_stacked")
-def _cases_paged_stacked() -> Iterator[dict]:
+def _cases_paged_stacked(compiled: bool = False) -> Iterator[dict]:
     import jax.numpy as jnp
 
     from areal_tpu.inference import paged_kv
     from areal_tpu.ops.paged_attention_q8 import paged_attention_stacked
 
     L = 3
-    q, k, v, lengths, pt = _paged_inputs(layers=L, seed=7)
+    shape, ppcb = (_PAGED_CHIP, 4) if compiled else ({}, 2)
+    q_dtype = jnp.bfloat16 if compiled else None  # the serving dtype
 
-    # bf16 stacked cache (no scales), first and last layer indices
-    kb, vb = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
-    for layer in (0, L - 1):
-        yield {
-            "case": f"stacked-bf16-layer{layer}",
-            "kernel": lambda layer=layer: paged_attention_stacked(
-                q, kb, vb, jnp.int32(layer), lengths, pt,
-                pages_per_compute_block=2,
-                interpret=True,
+    def case(label, layer, pages):
+        def kernel(inp):
+            scales = (
+                dict(k_scales=inp["ks"], v_scales=inp["vs"]) if "ks" in inp else {}
+            )
+            return _live(
+                paged_attention_stacked(
+                    inp["q"], inp["k"], inp["v"], jnp.int32(layer),
+                    inp["lengths"], inp["pt"],
+                    pages_per_compute_block=ppcb,
+                    interpret=not compiled, **scales,
+                ),
+                inp["lengths"],
+            )
+
+        return {
+            "case": label,
+            "build": _paged_build(
+                layers=L, seed=7, q_dtype=q_dtype, pages=pages, **shape
             ),
-            "reference": lambda layer=layer: paged_kv.paged_attention_xla(
-                q, kb[layer], vb[layer], lengths, pt
-            ),
+            "kernel": kernel,
+            "reference": _paged_reference(layer),
             "tol": 3e-2,
         }
 
-    # int8 stacked cache with narrow scales
-    kq = jnp.stack([paged_kv.quantize_kv(k[i])[0] for i in range(L)])
-    ks = jnp.stack([paged_kv.quantize_kv(k[i])[1] for i in range(L)])
-    vq = jnp.stack([paged_kv.quantize_kv(v[i])[0] for i in range(L)])
-    vs = jnp.stack([paged_kv.quantize_kv(v[i])[1] for i in range(L)])
+    # bf16 stacked cache (no scales), first and last layer indices
+    for layer in (0, L - 1):
+        yield case(f"stacked-bf16-layer{layer}", layer, jnp.bfloat16)
+    # quantized stacked cache with lane-major scales
     for layer in (1, L - 1):
+        yield case(f"stacked-int8-layer{layer}", layer, jnp.int8)
+    yield case("stacked-fp8-layer1", 1, jnp.float8_e4m3fn)
+    if compiled:
+        # jax's library kernel behind paged_kv.paged_attention_tpu has no
+        # interpret switch, so it is an on-chip case only. It walks every
+        # slot's first block: no empty slots
+        def live(inp):
+            return jnp.maximum(inp["lengths"], 1)
+
         yield {
-            "case": f"stacked-int8-layer{layer}",
-            "kernel": lambda layer=layer: paged_attention_stacked(
-                q, kq, vq, jnp.int32(layer), lengths, pt,
-                pages_per_compute_block=2,
-                k_scales=ks, v_scales=vs,
-                interpret=True,
+            "case": "library-bf16-layer0",
+            "build": _paged_build(
+                layers=L, seed=7, q_dtype=q_dtype, pages=jnp.bfloat16, **shape
             ),
-            "reference": lambda layer=layer: paged_kv.paged_attention_xla(
-                q, kq[layer], vq[layer], lengths, pt, ks[layer], vs[layer]
+            "kernel": lambda inp: paged_kv.paged_attention_tpu(
+                inp["q"], inp["k"][0], inp["v"][0], live(inp), inp["pt"]
             ),
-            "tol": 3e-2,
+            "reference": lambda inp: paged_kv.paged_attention_xla(
+                inp["q"], inp["k"][0], inp["v"][0], live(inp), inp["pt"]
+            ),
+            "tol": CHIP_TOL,
         }
 
 
@@ -151,9 +250,11 @@ def _cases_paged_stacked() -> Iterator[dict]:
 def _suffix_case(
     S=3, B=6, KH=2, G=2, hd=16, psz=4, wp=4, L=2, layer=1,
     mask="chain", pages="f32", lens="ragged", ppcb=None, seed=0,
+    compiled=False,
 ):
     """Build one paged_suffix_attention parity case. Returns (params,
-    kernel_fn, reference_fn); the params dict is what --case repro wants."""
+    build, kernel_fn, reference_fn); the params dict is what --case repro
+    wants."""
     import jax.numpy as jnp
 
     from areal_tpu.inference import paged_kv
@@ -165,22 +266,16 @@ def _suffix_case(
     rng = np.random.default_rng(seed)
     H = KH * G
     N = S * wp + 1
-    q = jnp.asarray(rng.normal(0, 1, (S, B, H, hd)), jnp.float32)
-    ksf = jnp.asarray(rng.normal(0, 1, (S, B, KH, hd)), jnp.float32)
-    vsf = jnp.asarray(rng.normal(0, 1, (S, B, KH, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(0, 1, (L, KH, N, psz, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(0, 1, (L, KH, N, psz, hd)), jnp.float32)
-    pt = jnp.asarray(1 + np.arange(S * wp).reshape(S, wp), jnp.int32)
+    act = jnp.bfloat16 if compiled else jnp.float32  # activations' dtype
+    pt = 1 + np.arange(S * wp, dtype=np.int32).reshape(S, wp)
     W = wp * psz
     if lens == "ragged":
         # 0, full, and page-boundary-straddling lengths (NOT multiples of
         # psz or of the ppcb*psz block) in one batch
         pool = [0, W] + [int(x) for x in rng.integers(1, W, max(S, 2))]
-        plens = jnp.asarray(pool[:S], jnp.int32)
+        plens = np.asarray(pool[:S], np.int32)
     else:  # "aligned": page-multiple lengths (radix prefixes)
-        plens = jnp.asarray(
-            psz * rng.integers(0, wp + 1, S), jnp.int32
-        )
+        plens = (psz * rng.integers(0, wp + 1, S)).astype(np.int32)
     if mask == "chain":
         seg = np.ones((S, B), np.int32)
         seg[:, B - 1] = 0  # one padded suffix row
@@ -197,61 +292,97 @@ def _suffix_case(
             for r in range(1, B):
                 p = int(rng.integers(0, r))
                 m[s, r] |= m[s, p]
-    m = jnp.asarray(m)
 
-    scales = {}
-    if pages in ("int8", "fp8"):
-        dt = jnp.int8 if pages == "int8" else jnp.float8_e4m3fn
-        kq, ks = paged_kv.quantize_kv(k, dtype=dt)
-        vq, vs = paged_kv.quantize_kv(v, dtype=dt)
-        k, v = kq, vq
-        scales = dict(k_scales=ks, v_scales=vs)
-    elif pages == "bf16":
-        k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+    def build():
+        inp = {
+            "q": _normal(seed, (S, B, H, hd), act),
+            "ks": _normal(seed + 100, (S, B, KH, hd), act),
+            "vs": _normal(seed + 200, (S, B, KH, hd), act),
+            "k": _normal(seed + 300, (L, KH, N, psz, hd)),
+            "v": _normal(seed + 400, (L, KH, N, psz, hd)),
+            "plens": jnp.asarray(plens),
+            "pt": jnp.asarray(pt),
+            "mask": jnp.asarray(m),
+        }
+        if pages in ("int8", "fp8"):
+            dt = jnp.int8 if pages == "int8" else jnp.float8_e4m3fn
+            inp["k"], inp["k_scales"] = paged_kv.quantize_pages(inp["k"], dtype=dt)
+            inp["v"], inp["v_scales"] = paged_kv.quantize_pages(inp["v"], dtype=dt)
+        elif pages == "bf16":
+            inp["k"] = inp["k"].astype(jnp.bfloat16)
+            inp["v"] = inp["v"].astype(jnp.bfloat16)
+        return inp
 
-    li = jnp.int32(layer)
-
-    def kernel():
-        return psa.paged_suffix_attention(
-            q, ksf, vsf, k, v, li, plens, pt, m,
-            pages_per_compute_block=ppcb, interpret=True, **scales,
+    def call(fn, inp, **kw):
+        scales = {k: inp[k] for k in ("k_scales", "v_scales") if k in inp}
+        return fn(
+            inp["q"], inp["ks"], inp["vs"], inp["k"], inp["v"], jnp.int32(layer),
+            inp["plens"], inp["pt"], inp["mask"], **scales, **kw,
         )
 
-    def reference():
-        return psa.paged_suffix_attention_xla(
-            q, ksf, vsf, k, v, li, plens, pt, m, **scales,
+    def kernel(inp):
+        return call(
+            psa.paged_suffix_attention, inp,
+            pages_per_compute_block=ppcb, interpret=not compiled,
         )
 
-    return params, kernel, reference
+    def reference(inp):
+        return call(psa.paged_suffix_attention_xla, inp)
+
+    return params, build, kernel, reference
 
 
 @register_kernel("paged_suffix_attention")
-def _cases_paged_suffix() -> Iterator[dict]:
-    grid = [
-        # (label, overrides, tol) — GQA ratios x ragged/aligned lengths x
-        # bf16/int8/fp8 pages x chain/tree masks, page-straddling blocks
-        ("chain-f32-gqa2-ragged", dict(), 2e-4),
-        ("chain-bf16-mha1-aligned",
-         dict(KH=4, G=1, pages="bf16", lens="aligned", seed=1), 2e-2),
-        ("chain-f32-gqa4-straddle-ppcb2",
-         dict(KH=1, G=4, wp=6, ppcb=2, seed=2), 2e-4),
-        ("tree-f32-gqa2-ragged", dict(mask="tree", seed=3), 2e-4),
-        ("tree-bf16-gqa2-layer0",
-         dict(mask="tree", pages="bf16", layer=0, seed=4), 2e-2),
-        ("chain-int8-gqa2-ragged", dict(pages="int8", seed=5), 2e-4),
-        ("tree-int8-mha1-straddle",
-         dict(mask="tree", pages="int8", KH=4, G=1, wp=6, ppcb=3, seed=6),
-         2e-4),
-        ("chain-fp8-gqa2-ragged", dict(pages="fp8", seed=7), 2e-4),
-        ("tree-fp8-gqa4-aligned",
-         dict(mask="tree", pages="fp8", KH=1, G=4, lens="aligned", seed=8),
-         2e-4),
-    ]
+def _cases_paged_suffix(compiled: bool = False) -> Iterator[dict]:
+    if compiled:
+        chip = dict(KH=2, G=6, hd=128, psz=128, wp=8, L=3, compiled=True)
+        grid = [
+            # the engine's smallest and largest suffix buckets at
+            # max_seq_len 2048, and a verify-sized tree, at 1.5B heads
+            ("chain-bf16-B256", dict(S=4, B=256, pages="bf16", seed=1)),
+            ("chain-bf16-B2048", dict(S=2, B=2048, pages="bf16", seed=2)),
+            ("chain-bf16-B300-padded",
+             dict(S=2, B=300, pages="bf16", lens="aligned", seed=3)),
+            ("tree-bf16-B9-verify",
+             dict(S=32, B=9, mask="tree", pages="bf16", layer=0, seed=4)),
+            ("chain-int8-B256", dict(S=4, B=256, pages="int8", seed=5)),
+            ("tree-fp8-B16", dict(S=8, B=16, mask="tree", pages="fp8", seed=6)),
+        ]
+        grid = [(label, {**chip, **o}, CHIP_TOL) for label, o in grid]
+    else:
+        grid = [
+            # (label, overrides, tol) — GQA ratios x ragged/aligned lengths
+            # x bf16/int8/fp8 pages x chain/tree masks, page-straddling
+            # blocks, and suffixes long enough to tile (several query
+            # tiles: B*G > 512; several key steps: B > 512)
+            ("chain-f32-gqa2-ragged", dict(), 2e-4),
+            ("chain-bf16-mha1-aligned",
+             dict(KH=4, G=1, pages="bf16", lens="aligned", seed=1), 2e-2),
+            ("chain-f32-gqa4-straddle-ppcb2",
+             dict(KH=1, G=4, wp=6, ppcb=2, seed=2), 2e-4),
+            ("tree-f32-gqa2-ragged", dict(mask="tree", seed=3), 2e-4),
+            ("tree-bf16-gqa2-layer0",
+             dict(mask="tree", pages="bf16", layer=0, seed=4), 2e-2),
+            ("chain-int8-gqa2-ragged", dict(pages="int8", seed=5), 2e-4),
+            ("tree-int8-mha1-straddle",
+             dict(mask="tree", pages="int8", KH=4, G=1, wp=6, ppcb=3, seed=6),
+             2e-4),
+            ("chain-fp8-gqa2-ragged", dict(pages="fp8", seed=7), 2e-4),
+            ("tree-fp8-gqa4-aligned",
+             dict(mask="tree", pages="fp8", KH=1, G=4, lens="aligned", seed=8),
+             2e-4),
+            ("chain-f32-gqa8-B128-qtiles",
+             dict(S=2, B=128, KH=1, G=8, seed=9), 2e-4),
+            ("tree-int8-gqa2-B640-ktiles",
+             dict(S=1, B=640, KH=1, G=2, mask="tree", pages="int8", seed=10),
+             2e-4),
+        ]
     for label, overrides, tol in grid:
-        params, kernel, reference = _suffix_case(**overrides)
+        params, build, kernel, reference = _suffix_case(**overrides)
         yield {
             "case": label,
             "params": params,
+            "build": build,
             "kernel": kernel,
             "reference": reference,
             "tol": tol,
@@ -259,48 +390,97 @@ def _cases_paged_suffix() -> Iterator[dict]:
 
 
 # ---------------------------------------------------------------------------
-# forward-only flash attention (ops/attention.py)
+# flash attention (ops/attention.py): the repo's forward kernel and jax's
+# differentiable library kernel behind flash_train
 # ---------------------------------------------------------------------------
 
 
+def _packed_mask(seg_np):
+    """The flash kernels' semantics as a dense mask: causal AND same
+    segment AND seg != 0 -> [G, 1, L, L]."""
+    L = seg_np.shape[-1]
+    qi = np.arange(L)[:, None]
+    ki = np.arange(L)[None, :]
+    return (
+        (qi >= ki)
+        & (seg_np[:, :, None] == seg_np[:, None, :])
+        & (seg_np[:, :, None] != 0)
+    )[:, None]
+
+
+def _out_and_grads(attn, q, k, v, w):
+    """attn's output and its q/k/v gradients under the fixed cotangent
+    ``w``, stacked into one array."""
+    import jax
+    import jax.numpy as jnp
+
+    def loss(q, k, v):
+        out = attn(q, k, v).astype(jnp.float32)
+        return (out * w).sum(), out
+
+    (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+        q, k, v
+    )
+    return jnp.stack([out, *(g.astype(jnp.float32) for g in grads)])
+
+
 @register_kernel("flash_fwd")
-def _cases_flash_fwd() -> Iterator[dict]:
+def _cases_flash_fwd(compiled: bool = False) -> Iterator[dict]:
     import jax.numpy as jnp
 
     from areal_tpu.ops import attention
 
-    rng = np.random.default_rng(11)
-    G, L, H, d = 1, 128, 2, 128
-    q = jnp.asarray(rng.normal(0, 1, (G, L, H, d)), jnp.float32)
-    k = jnp.asarray(rng.normal(0, 1, (G, L, H, d)), jnp.float32)
-    v = jnp.asarray(rng.normal(0, 1, (G, L, H, d)), jnp.float32)
+    G, L, H, d = (1, 2048, 12, 128) if compiled else (1, 128, 2, 128)
+    dt = jnp.bfloat16 if compiled else jnp.float32
     grids = {
-        "f32-one-segment": np.ones((G, L), np.int32),
-        "f32-packed-two-segments": np.concatenate(
+        "one-segment": np.ones((G, L), np.int32),
+        "packed-two-segments": np.concatenate(
             [np.ones((G, L // 2), np.int32), 2 * np.ones((G, L // 2), np.int32)],
             axis=1,
         ),
     }
-    for label, seg_np in grids.items():
-        seg = jnp.asarray(seg_np)
-        # same semantics as the kernel: causal AND same segment AND seg != 0
-        qi = np.arange(L)[:, None]
-        ki = np.arange(L)[None, :]
-        mask = (
-            (qi >= ki)
-            & (seg_np[:, :, None] == seg_np[:, None, :])
-            & (seg_np[:, :, None] != 0)
-        )[:, None]  # [G, 1, L, L]
-        yield {
-            "case": label,
-            "kernel": lambda seg=seg: attention.flash_fwd_pallas(
-                q, k, v, seg, interpret=True
-            ),
-            "reference": lambda mask=mask: attention.sdpa_xla(
-                q, k, v, jnp.asarray(mask), d
-            ),
-            "tol": 2e-4,
+
+    def build(seg_np):
+        return lambda: {
+            "q": _normal(11, (G, L, H, d), dt),
+            "k": _normal(12, (G, L, H, d), dt),
+            "v": _normal(13, (G, L, H, d), dt),
+            "w": _normal(23, (G, L, H, d)),
+            "seg": jnp.asarray(seg_np),
+            "mask": jnp.asarray(_packed_mask(seg_np)),
         }
+
+    for label, seg_np in grids.items():
+        yield {
+            "case": ("bf16-" if compiled else "f32-") + label,
+            "build": build(seg_np),
+            "kernel": lambda inp: attention.flash_fwd_pallas(
+                inp["q"], inp["k"], inp["v"], inp["seg"], interpret=not compiled
+            ),
+            "reference": lambda inp: attention.sdpa_xla(
+                inp["q"], inp["k"], inp["v"], inp["mask"], d
+            ),
+            "tol": CHIP_TOL if compiled else 2e-4,
+        }
+    if not compiled:
+        return
+    # jax's library flash attention behind flash_train (forward AND
+    # gradients) against XLA sdpa. It has no interpret switch, so it is an
+    # on-chip case only.
+    yield {
+        "case": "library-train-bf16-packed-fwd-and-grads",
+        "build": build(grids["packed-two-segments"]),
+        "kernel": lambda inp: _out_and_grads(
+            lambda q, k, v: attention.flash_train(q, k, v, inp["seg"]),
+            inp["q"], inp["k"], inp["v"], inp["w"],
+        ),
+        "reference": lambda inp: _out_and_grads(
+            lambda q, k, v: attention.sdpa_xla(q, k, v, inp["mask"], d),
+            inp["q"], inp["k"], inp["v"], inp["w"],
+        ),
+        # gradients sum bf16-rounded probabilities over up to 1024 keys
+        "tol": 1e-1,
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -309,17 +489,14 @@ def _cases_flash_fwd() -> Iterator[dict]:
 
 
 @register_kernel("tree_attention")
-def _cases_tree_attention() -> Iterator[dict]:
+def _cases_tree_attention(compiled: bool = False) -> Iterator[dict]:
     import jax
     import jax.numpy as jnp
 
     from areal_tpu.ops import tree_attention as ta
 
-    rng = np.random.default_rng(13)
-    N, H, d = 128, 2, 128
-    q = jnp.asarray(rng.normal(0, 1, (N, H, d)), jnp.float32)
-    k = jnp.asarray(rng.normal(0, 1, (N, H, d)), jnp.float32)
-    v = jnp.asarray(rng.normal(0, 1, (N, H, d)), jnp.float32)
+    N, H, d = (1024, 12, 128) if compiled else (ta.BLOCK, 2, 128)
+    dt = jnp.bfloat16 if compiled else jnp.float32
 
     # a chain tree (parent = i-1) makes the ancestor mask exactly causal;
     # a branching tree exercises the sparse-block path
@@ -328,27 +505,111 @@ def _cases_tree_attention() -> Iterator[dict]:
                        np.arange(N) - 1).astype(np.int64)
     for label, parent in (("chain-causal", chain), ("branching", branchy)):
         words_np, block_any_np = ta.pack_ancestor_bits(parent)
-        words = jnp.asarray(words_np)
-        block_any = jnp.asarray(block_any_np)
-        # dense reference from the same ancestor bits
-        bits = np.unpackbits(
-            words_np.view(np.uint8), bitorder="little", axis=1
-        )[:, :N].astype(bool)  # [N, N] ancestor mask
-        mask = jnp.asarray(bits)[None]  # [1, N, N], broadcast over heads
+        # dense reference mask, built from the parent pointers independently
+        # of the packed words
+        bits = np.zeros((N, N), bool)
+        for i in range(N):
+            if parent[i] >= 0:
+                bits[i] = bits[parent[i]]
+            bits[i, i] = True
 
-        def ref(mask=mask):
-            logits = jnp.einsum("qhd,khd->hqk", q, k) * d**-0.5
-            probs = jax.nn.softmax(jnp.where(mask, logits, -1e30), axis=-1)
-            return jnp.einsum("hqk,khd->qhd", probs, v)
+        def build(words_np=words_np, block_any_np=block_any_np, bits=bits):
+            return {
+                "q": _normal(13, (N, H, d), dt),
+                "k": _normal(14, (N, H, d), dt),
+                "v": _normal(15, (N, H, d), dt),
+                "w": _normal(29, (N, H, d)),  # cotangent for the backward
+                "words": jnp.asarray(words_np),
+                "block_any": jnp.asarray(block_any_np),
+                "mask": jnp.asarray(bits)[None],  # [1, N, N] over heads
+            }
+
+        def ref(inp, q, k, v):
+            logits = jnp.einsum("qhd,khd->hqk", q, k).astype(jnp.float32) * d**-0.5
+            probs = jax.nn.softmax(jnp.where(inp["mask"], logits, -1e30), axis=-1)
+            return jnp.einsum("hqk,khd->qhd", probs.astype(v.dtype), v)
+
+        def kern(inp, q, k, v):
+            return ta.tree_attention(
+                q, k, v, inp["words"], inp["block_any"], not compiled
+            )
+
+        def fwd(attn):
+            return lambda inp: attn(inp, inp["q"], inp["k"], inp["v"])
+
+        def grads(attn):
+            def run(inp):
+                g = jax.grad(
+                    lambda q, k, v: (
+                        attn(inp, q, k, v).astype(jnp.float32) * inp["w"]
+                    ).sum(),
+                    argnums=(0, 1, 2),
+                )(inp["q"], inp["k"], inp["v"])
+                return jnp.stack([x.astype(jnp.float32) for x in g])
+
+            return run
 
         yield {
             "case": label,
-            "kernel": lambda w=words, b=block_any: ta.tree_attention(
-                q, k, v, w, b, interpret=True
-            ),
-            "reference": ref,
-            "tol": 2e-4,
+            "build": build,
+            "kernel": fwd(kern),
+            "reference": fwd(ref),
+            "tol": CHIP_TOL if compiled else 2e-4,
         }
+        yield {
+            "case": f"{label}-grads",
+            "build": build,
+            "kernel": grads(kern),
+            "reference": grads(ref),
+            "tol": 1e-1 if compiled else 2e-3,
+        }
+
+
+# ---------------------------------------------------------------------------
+# megablox grouped matmul (the MoE expert FFN, models/moe.py)
+# ---------------------------------------------------------------------------
+
+
+@register_kernel("gmm")
+def _cases_gmm(compiled: bool = False) -> Iterator[dict]:
+    import jax.numpy as jnp
+
+    from areal_tpu.models.moe import pinned_gmm
+
+    gmm = pinned_gmm()
+    # rows x hidden x expert width, experts, m tile: moe.py takes the TPU
+    # tile of 128 on the chip and 16 under the interpreter
+    M, D, F, E, tm = (2048, 2048, 1024, 8, 128) if compiled else (64, 128, 128, 4, 16)
+    dt = jnp.bfloat16 if compiled else jnp.float32
+    rng = np.random.default_rng(41)
+    cuts = np.sort(rng.integers(0, M + 1, E - 1))
+    sizes = np.diff(np.concatenate([[0], cuts, [M]])).astype(np.int32)
+    group_of_row = np.repeat(np.arange(E), sizes)
+
+    def reference(inp):
+        # one plain matmul per group over its own rows (not ragged_dot: on
+        # the TPU that is itself a grouped-matmul kernel)
+        x, w = inp["x"].astype(jnp.float32), inp["w"].astype(jnp.float32)
+        out = jnp.zeros((M, F), jnp.float32)
+        for e in range(E):
+            out = jnp.where((inp["group"] == e)[:, None], x @ w[e], out)
+        return out
+
+    yield {
+        "case": "bf16-ragged-groups" if compiled else "f32-ragged-groups",
+        "build": lambda: {
+            "x": _normal(31, (M, D), dt),
+            "w": _normal(37, (E, D, F), dt) * D**-0.5,
+            "sizes": jnp.asarray(sizes),
+            "group": jnp.asarray(group_of_row),
+        },
+        "kernel": lambda inp: gmm(
+            inp["x"], inp["w"], inp["sizes"],
+            tiling=(tm, 128, 128), interpret=not compiled,
+        ),
+        "reference": reference,
+        "tol": CHIP_TOL if compiled else 2e-4,
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -356,13 +617,43 @@ def _cases_tree_attention() -> Iterator[dict]:
 # ---------------------------------------------------------------------------
 
 
-def run_kernel(name: str, case: "int | str | None" = None) -> list[dict]:
+def _cases_of(name: str, compiled: bool):
+    # the on-chip flag is passed only when set, so a plain zero-argument
+    # generator (tests register those) serves the default grid
+    return REGISTRY[name](compiled=True) if compiled else REGISTRY[name]()
+
+
+def _evaluate(spec: dict) -> "tuple[np.ndarray, np.ndarray]":
+    """(kernel output, reference output) of one case, as f32 host arrays.
+    Three programs a case — its inputs, the kernel, the reference — each
+    compiled once for the one call it gets."""
+    import jax
+
+    if "build" not in spec:  # two plain zero-argument closures
+        return (
+            np.asarray(spec["kernel"](), np.float32),
+            np.asarray(spec["reference"](), np.float32),
+        )
+    inputs = jax.jit(spec["build"])()
+    got = np.asarray(jax.jit(spec["kernel"])(inputs), np.float32)
+    # the TPU's default f32 matmul is a single bf16 pass: hold the
+    # reference to full precision there
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(spec["reference"])(inputs)
+    return got, np.asarray(want, np.float32)
+
+
+def run_kernel(
+    name: str, case: "int | str | None" = None, compiled: bool = False
+) -> list[dict]:
     """Run one kernel's case grid; never raises on divergence — every
     case reports {kernel, index, case, max_abs_diff, tol, ok, error?,
     params?}. ``case`` filters to a single grid point by index or label
-    (repro of one failing case without re-running the grid)."""
+    (repro of one failing case without re-running the grid). ``compiled``
+    runs the on-chip grid (interpret=False, Qwen2.5-1.5B head shapes) and
+    needs a TPU."""
     results: list[dict] = []
-    for idx, spec in enumerate(REGISTRY[name]()):
+    for idx, spec in enumerate(_cases_of(name, compiled)):
         if case is not None and case != idx and case != spec["case"]:
             continue
         rec: dict[str, Any] = {
@@ -372,8 +663,7 @@ def run_kernel(name: str, case: "int | str | None" = None) -> list[dict]:
         if "params" in spec:
             rec["params"] = spec["params"]
         try:
-            got = np.asarray(spec["kernel"](), np.float32)
-            want = np.asarray(spec["reference"](), np.float32)
+            got, want = _evaluate(spec)
             if got.shape != want.shape:
                 rec.update(ok=False, error=f"shape {got.shape} vs {want.shape}")
             else:
@@ -386,12 +676,14 @@ def run_kernel(name: str, case: "int | str | None" = None) -> list[dict]:
 
 
 def run_all(
-    only: str | None = None, case: "int | str | None" = None
+    only: str | None = None,
+    case: "int | str | None" = None,
+    compiled: bool = False,
 ) -> list[dict]:
     names = [only] if only else sorted(REGISTRY)
     out: list[dict] = []
     for name in names:
-        out.extend(run_kernel(name, case=case))
+        out.extend(run_kernel(name, case=case, compiled=compiled))
     return out
 
 
@@ -408,11 +700,23 @@ def main(argv: list[str] | None = None) -> int:
         "re-run one failing case in isolation",
     )
     ap.add_argument("--json", action="store_true", help="JSON report")
+    ap.add_argument(
+        "--compiled",
+        action="store_true",
+        help="run the on-chip grid: interpret=False at Qwen2.5-1.5B head "
+        "shapes (needs a TPU; chip_smoke.py's kernels phase)",
+    )
     args = ap.parse_args(argv)
+    if args.compiled:
+        import jax
+
+        if jax.default_backend() != "tpu":
+            print("--compiled needs a TPU backend", file=sys.stderr)
+            return 2
 
     if args.list:
         for name in sorted(REGISTRY):
-            n = sum(1 for _ in REGISTRY[name]())
+            n = sum(1 for _ in _cases_of(name, args.compiled))
             print(f"{name}: {n} case(s)")
         return 0
     if args.kernel and args.kernel not in REGISTRY:
@@ -425,7 +729,7 @@ def main(argv: list[str] | None = None) -> int:
             print("--case requires --kernel", file=sys.stderr)
             return 2
         case = int(args.case) if args.case.isdigit() else args.case
-        known = list(REGISTRY[args.kernel]())
+        known = list(_cases_of(args.kernel, args.compiled))
         if not any(
             case == i or case == c["case"] for i, c in enumerate(known)
         ):
@@ -436,7 +740,7 @@ def main(argv: list[str] | None = None) -> int:
             )
             return 2
 
-    results = run_all(args.kernel, case=case)
+    results = run_all(args.kernel, case=case, compiled=args.compiled)
     if args.json:
         print(json.dumps({"results": results}, indent=1))
     else:

@@ -132,10 +132,10 @@ def _min_per_target(snap: FleetSnapshot, name: str) -> float | None:
     return min(per.values())
 
 
-# learning-health lag-bucket taxonomy (infra/staleness_manager.py)
+# learning-health lag-bucket vocabulary (infra/staleness_manager.py)
 from areal_tpu.infra.staleness_manager import LAG_BUCKET_LABELS as _LAG_BUCKETS
 
-# decode-step phase taxonomy (observability/kernel_probe.py) + the
+# decode-step phase vocabulary (observability/kernel_probe.py) + the
 # identity remainder bucket
 _DECODE_PHASES = (
     "admission",
@@ -149,7 +149,7 @@ _DECODE_PHASES = (
     "other",
 )
 
-# trainer observatory phase taxonomy (observability/step_timeline.py)
+# trainer observatory phase vocabulary (observability/step_timeline.py)
 _TRAIN_PHASES = (
     "rollout_wait",
     "host_prep",

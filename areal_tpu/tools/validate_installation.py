@@ -72,7 +72,7 @@ roofline fraction via the calibrated CPU peak fallback.
 acceptance-friendly repetitive workload (docs/serving.md "Speculative
 decoding") and asserts acceptance rate > 0, zero leaked KV pages after
 settling, draft/verify stage coverage in the request timelines, and the
-kernel probe's exact-sum identity over the widened phase taxonomy.
+kernel probe's exact-sum identity over the widened phase vocabulary.
 
 ``--kernelcheck`` runs every registered ops/ Pallas kernel's full
 differential case grid in interpret mode against its XLA reference
@@ -219,7 +219,7 @@ def main(argv=None) -> int:
         "and assert the speculative-decoding contract: acceptance rate "
         "> 0, zero leaked KV pages after settling, draft/verify stages "
         "in the request timelines, and the kernel probe's exact-sum "
-        "identity over the widened phase taxonomy",
+        "identity over the widened phase vocabulary",
     )
     p.add_argument(
         "--gateway-tier-self-test",
@@ -1939,7 +1939,7 @@ def spec_decode_self_test() -> str:
     total: rejected tails were rolled back through the refcounted pool);
     (3) request timelines carry the draft/verify stages and the kernel
     probe's per-step exact-sum identity holds with the two new phases
-    in the taxonomy."""
+    in the vocabulary."""
     import threading
     import time
 
@@ -2015,7 +2015,7 @@ def spec_decode_self_test() -> str:
             assert want in staged, (
                 f"timeline missing the {want} stage (saw {sorted(staged)})"
             )
-        # kernel-probe exact-sum identity over the widened phase taxonomy
+        # kernel-probe exact-sum identity over the widened phase vocabulary
         recs = eng.kprobe.recent()
         assert recs, "no decode steps recorded by the kernel probe"
         worst = 0.0

@@ -550,7 +550,7 @@ class PPOTrainer:
             # backward-compatible timing keys (the per-block ad-hoc
             # record_timing scopes these replace; the dropped keys —
             # critic_values/recompute_logp/ref_logp/compute_advantages/
-            # critic_train_step — are folded into the phase taxonomy)
+            # critic_train_step — are folded into the phase vocabulary)
             stats["timing/rollout"] = bd["rollout_wait_s"]
             stats["timing/train_step"] = train_step_secs
             stats["timing/update_weights"] = bd["weight_publish_s"]

@@ -1,33 +1,22 @@
-"""Version-bridging shims for jax APIs the engines rely on.
+"""The one jax seam the engines still need on the supported jax (0.9.0).
 
-Newer jax promoted several experimental APIs to the top level and renamed
-kwargs; this image ships 0.4.37 where they live in their old homes. The
-engines/models/kernels route through these shims so the same code runs on
-both:
+``jax.set_mesh``, ``jax.shard_map``, ``jax.lax.axis_size`` and
+``jax.sharding.get_abstract_mesh`` are used directly at their call sites.
+What remains here is a sharding constraint that model code can issue
+without knowing where it is traced:
 
-- ``set_mesh(mesh)``: newer ``jax.set_mesh`` context manager; on <= 0.4.x
-  the ``Mesh`` itself has been the ambient-mesh context since the pjit
-  era. Without this, every engine initialize dies with ``AttributeError:
-  module 'jax' has no attribute 'set_mesh'``.
-- ``shard_map(...)``: newer ``jax.shard_map`` (kwarg ``check_vma``); old
-  home is ``jax.experimental.shard_map.shard_map`` (kwarg ``check_rep``).
-- ``get_abstract_mesh()``: newer ambient-mesh query; the old equivalent is
-  the resource env's physical mesh (empty mesh when no context is active,
-  which callers already treat as "no mesh").
-- ``with_sharding_constraint(x, spec)``: manual-axes-aware constraint.
-  Old ``shard_map`` makes EVERY mesh axis manual inside the mapped body,
-  and ``jax.lax.with_sharding_constraint`` there rejects any spec naming
-  a manual axis at lowering time ("Axis ... is also found in
-  manual_axes") — the pp_engine failure class. Newer jax only
-  manualizes the mapped axes, so GSPMD constraints keep working inside
-  a partially-manual region. This shim recovers that behavior on 0.4.x
-  by dropping manual axes from the spec (inside a full-manual region the
-  array is already a local slice, so the constraint is meaningless for
-  those axes) and becoming a no-op when nothing survives. All model/
-  engine code must route constraints through this shim, not
-  ``jax.lax.with_sharding_constraint`` directly (arealint MSH003).
-- ``jax_threefry_partitionable``: flipped on at import (the newer-jax
-  default) so seeded init is identical on every mesh topology.
+- outside any mesh context (single-device tests, a bare ``jit``) the
+  constraint is a no-op — ``jax.lax.with_sharding_constraint`` raises
+  there when given a ``PartitionSpec``;
+- inside a ``shard_map`` region the mapped axes are Manual, and a spec may
+  only name Auto axes ("can only refer to Auto axes of the mesh"): manual
+  axes are dropped from the spec — the array is already a local slice
+  along them — and a spec with nothing left is a no-op.
+
+Any other refusal (an axis the mesh does not have, a dimension the axis
+does not divide) is an error and is raised. All model/engine code routes
+constraints through this function, not ``jax.lax.with_sharding_constraint``
+directly (arealint MSH003).
 """
 
 from __future__ import annotations
@@ -35,84 +24,12 @@ from __future__ import annotations
 import jax
 from jax.sharding import PartitionSpec as _P
 
-# Newer jax defaults the partitionable threefry lowering ON, which makes
-# jax.random generation invariant to the output sharding. 0.4.x defaults
-# it OFF, so ``jit(init_params, out_shardings=...)`` yields *mesh-dependent*
-# initial params — the pp-vs-plain engine parity failure class. Align 0.4.x
-# with the new default so the same seed gives the same params on any mesh.
-if not jax.config.jax_threefry_partitionable:
-    jax.config.update("jax_threefry_partitionable", True)
-
-if hasattr(jax, "set_mesh"):
-    set_mesh = jax.set_mesh
-else:
-
-    def set_mesh(mesh):
-        """jax<=0.4 fallback: a Mesh is itself the ambient-mesh context."""
-        return mesh
-
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, *, mesh=None, in_specs=None, out_specs=None, **kw):
-        """jax<=0.4 fallback: experimental home, check_vma -> check_rep,
-        and mesh=None resolved from the ambient context (the new API does
-        that implicitly; the old one requires an explicit mesh)."""
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        else:
-            # the old replication checker has known false positives on
-            # scan carries (its own error message says to turn it off);
-            # the new API's varying-types system replaced it entirely, so
-            # code written for the new API gets it disabled by default
-            kw.setdefault("check_rep", False)
-        if mesh is None:
-            mesh = get_abstract_mesh()
-            if mesh is not None and not mesh.shape:
-                mesh = None  # empty mesh = no ambient context
-        return _shard_map_old(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-
-
-if hasattr(jax.lax, "axis_size"):
-    axis_size = jax.lax.axis_size
-else:
-
-    def axis_size(axis_name):
-        """jax<=0.4 fallback: psum of 1 constant-folds to a python int
-        inside shard_map/pmap bodies (usable as a static loop bound)."""
-        return jax.lax.psum(1, axis_name)
-
-
-def manual_axis_names() -> frozenset[str]:
-    """Mesh axes that are MANUAL at the current trace point (bound by an
-    enclosing shard_map/pmap). Empty outside any manual region."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        # newer jax: the abstract mesh knows each axis's type
-        mesh = jax.sharding.get_abstract_mesh()
-        manual = getattr(mesh, "manual_axes", None)
-        if manual is not None:
-            return frozenset(manual)
-    try:
-        # 0.4.x: shard_map extends the axis env with every manual axis
-        from jax._src.core import get_axis_env  # noqa: PVT — pinned below
-
-        return frozenset(get_axis_env().axis_sizes)
-    except (ImportError, AttributeError):  # pragma: no cover — layout drift
-        return frozenset()
-
 
 def with_sharding_constraint(x, spec):
-    """``jax.lax.with_sharding_constraint`` that survives manual regions:
-    axes currently bound manual (old shard_map manualizes ALL mesh axes)
-    are dropped from ``spec``; a fully-dropped spec is a no-op. Outside
-    any mesh context the constraint is also a no-op (same contract as
-    qwen's historical ``_shard`` helper)."""
-    manual = manual_axis_names()
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return x  # no ambient mesh
+    manual = frozenset(mesh.manual_axes)
     if manual:
         def keep(entry):
             if entry is None:
@@ -124,20 +41,5 @@ def with_sharding_constraint(x, spec):
         spec = _P(*(keep(e) for e in spec))
         if all(e is None for e in spec):
             return x
-    try:
-        # arealint: disable-next=MSH003 this IS the shim every other raw call must route through
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (ValueError, RuntimeError):
-        return x  # no ambient mesh (single-process tests, CPU smoke)
-
-
-def get_abstract_mesh():
-    """The ambient mesh, or an empty/None mesh outside any mesh context."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    try:
-        from jax._src.mesh import thread_resources
-    except ImportError:  # pragma: no cover — very old/new private layout
-        return None
-    env = getattr(thread_resources, "env", None)
-    return getattr(env, "physical_mesh", None)
+    # arealint: disable-next=MSH003 this IS the shim every other raw call must route through
+    return jax.lax.with_sharding_constraint(x, spec)

@@ -1,10 +1,10 @@
 """Pinned-signature guard for private jax APIs (the arealint PVT idiom).
 
 The repo calls several private jax internals positionally (flash
-attention, megablox gmm, the paged-attention launch wrapper, and the
-kernel body forked in ``ops/paged_attention_q8.py``). A jax bump can
-silently reorder or extend those signatures, after which positional call
-sites feed the wrong argument into the wrong parameter with no error.
+attention, megablox gmm, the library paged-attention launch wrapper). A
+jax bump can silently reorder or extend those signatures, after which
+positional call sites feed the wrong argument into the wrong parameter
+with no error.
 
 Each call site declares the parameter tuple it was audited against as a
 module-level ``_EXPECTED_*`` literal and verifies it via
@@ -17,15 +17,16 @@ literal:
   against the *installed* jax, so the drift surfaces during the jax bump
   itself as a lint finding.
 
-``AUDITED_JAX`` is the version the pins were last audited against; keep
-it (and pyproject's documented range) in lockstep when re-auditing.
+``AUDITED_JAX`` is the version the pins were last audited against — the
+installed one; keep it (and pyproject's documented version) in lockstep
+when re-auditing.
 """
 
 from __future__ import annotations
 
 import inspect
 
-AUDITED_JAX = "0.4.37"
+AUDITED_JAX = "0.9.0"
 
 _verified: set[tuple[int, tuple[str, ...]]] = set()
 

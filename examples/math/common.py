@@ -4,6 +4,8 @@ single-host server spin-up cannot drift between entries."""
 
 from __future__ import annotations
 
+import sys
+
 from areal_tpu.reward.gsm8k import gsm8k_reward_fn
 
 
@@ -17,7 +19,10 @@ def load_tokenizer(path: str):
 
         return AutoTokenizer.from_pretrained(path)
     except Exception as e:  # noqa: BLE001
-        print(f"warning: no tokenizer at {path} ({e}); continuing without one")
+        print(
+            f"warning: no tokenizer at {path} ({e}); continuing without one",
+            file=sys.stderr,
+        )
         return None
 
 
@@ -79,11 +84,21 @@ def load_processor(path: str, dataset_type: str = ""):
 
 
 def start_single_host_stack(config, dataset_size: int):
-    """Single-host RL bootstrap shared by the RL entries: build the trainer
-    engine first, then an in-process server SHARING its weights (zero-copy
-    "mem" updates). Returns (actor_engine, server)."""
+    """Single-host RL bootstrap shared by the RL entries: ONE process holds
+    this host's chips, with the trainer engine and an in-process server
+    side by side. The trainer engine is built first and the server starts
+    from a device-to-device copy of its weights (no second checkpoint
+    load, no host round trip). Returns (actor_engine, server).
+
+    The copy is needed: the trainer's step DONATES its parameter buffers
+    (engine/train_engine.py), so an aliased tree would be deleted under
+    the server at the first train step — and an asynchronous server keeps
+    decoding under the old policy while the trainer moves on, so two
+    parameter generations are live by design. Later "mem" updates travel
+    the normal client path (stage over loopback HTTP, fenced pointer-swap
+    commit), which carries the version tags and the commit fence."""
     import jax
-    import numpy as np
+    import jax.numpy as jnp
 
     from areal_tpu.api.io_struct import FinetuneSpec
     from areal_tpu.engine.train_engine import JaxTrainEngine
@@ -100,9 +115,14 @@ def start_single_host_stack(config, dataset_size: int):
     )
     scfg = config.server
     scfg.model_path = scfg.model_path or config.actor.path
+    serve_dtype = jnp.dtype(scfg.dtype)
     server = start_local_server(
         scfg,
-        params=jax.tree.map(np.asarray, actor_engine.params),
+        # cast-and-copy on the device: always a fresh buffer the server owns
+        params=jax.tree.map(
+            lambda x: jnp.array(x, dtype=serve_dtype, copy=True),
+            actor_engine.params,
+        ),
         model_cfg=actor_engine.model_cfg,
     )
     return actor_engine, server
@@ -110,8 +130,9 @@ def start_single_host_stack(config, dataset_size: int):
 
 def start_local_server(server_cfg, params=None, model_cfg=None):
     """Single-host mode: in-process DecodeEngine + HTTP server on this
-    host's chips. With ``params`` the server shares the caller's weights
-    (zero-copy mem updates); otherwise it loads ``server_cfg.model_path``."""
+    host's chips. With ``params`` the server serves the caller's tree as
+    placed (device arrays stay on the device); otherwise it loads
+    ``server_cfg.model_path``."""
     from areal_tpu.inference.decode_engine import DecodeEngine
     from areal_tpu.inference.server import ServerThread
 

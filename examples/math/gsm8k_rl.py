@@ -5,9 +5,9 @@ Two deployment shapes:
   ``python -m areal_tpu.inference.server --config ...`` or a scheduler);
   their addresses arrive through ``AREAL_TPU_SERVER_ADDRS`` or name_resolve.
 - **single-host mode** (default when no addresses are found): spin an
-  in-process DecodeEngine+ServerThread sharing this host's TPU chips —
-  rollout and training time-share the mesh, weight updates are zero-copy
-  ("mem" mode).
+  in-process DecodeEngine+ServerThread in THIS process — one process holds
+  the host's TPU chips, rollout and training time-share them, and weight
+  updates use the "mem" mode through the normal client path.
 
 Usage:
     python examples/math/gsm8k_rl.py --config examples/math/gsm8k_grpo.yaml \
@@ -51,8 +51,8 @@ def main(argv):
     actor_engine = None
     addrs = [a for a in os.environ.get("AREAL_TPU_SERVER_ADDRS", "").split(",") if a]
     if not addrs:
-        # single-host: build the trainer engine first so the server shares
-        # its weights (no double HF load, zero-copy mem updates)
+        # single-host: build the trainer engine first; the server starts
+        # from a device copy of its weights (no double HF load)
         actor_engine, server = start_single_host_stack(config, len(train_dataset))
         addrs = [server.address]
     rollout = RemoteJaxEngine(config.rollout, addresses=addrs)

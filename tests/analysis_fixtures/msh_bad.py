@@ -1,12 +1,12 @@
 """MSH bad fixture: a collective naming an axis outside the mesh
 vocabulary (MSH001), shard_map out_specs drifted from the callee's return
 structure (MSH002), and a raw with_sharding_constraint that dies at
-lowering inside 0.4.x shard_map manual regions (MSH003)."""
+lowering inside shard_map manual regions (MSH003)."""
 
 import jax
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def body(x):

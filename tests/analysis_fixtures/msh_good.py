@@ -5,7 +5,9 @@ return structure, and constraints routed through the jax_compat shim."""
 import jax
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.utils.jax_compat import shard_map, with_sharding_constraint
+from jax import shard_map
+
+from areal_tpu.utils.jax_compat import with_sharding_constraint
 
 
 def body(x):

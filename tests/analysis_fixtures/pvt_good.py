@@ -1,8 +1,8 @@
 """PVT good fixture: every sanctioned shape of private-jax use — a
 try/except-ImportError-gated import (graceful degradation, jax_compat
-style), the inline inspect.signature pin (paged_attention_q8 style), and
-the utils.private_api.pin_signature helper idiom. All pins match the
-installed jax 0.4.37, so the file stays silent."""
+style), the inline inspect.signature pin, and the
+utils.private_api.pin_signature helper idiom. All pins match the
+installed jax 0.9.0, so the file stays silent."""
 
 import inspect
 
@@ -13,7 +13,7 @@ try:  # gated: degrades gracefully when the private layout moves
 except ImportError:
     get_axis_env = None
 
-# inline pin idiom, matching the installed jax 0.4.37 signature
+# inline pin idiom, matching the installed jax 0.9.0 signature
 from jax.experimental.pallas.ops.tpu.paged_attention.paged_attention_kernel import (
     paged_flash_attention_kernel_inline_seq_dim as _kernel,
 )
@@ -22,7 +22,7 @@ _EXPECTED_KERNEL_PARAMS = (
     "lengths_ref",
     "page_indices_ref",
     "buffer_index_ref",
-    "step_ref",
+    "init_flag_ref",
     "q_ref",
     "k_pages_hbm_ref",
     "k_scales_pages_hbm_ref",
@@ -35,7 +35,8 @@ _EXPECTED_KERNEL_PARAMS = (
     "k_scales_vmem_buffer",
     "v_vmem_buffer",
     "v_scales_vmem_buffer",
-    "sem",
+    "k_sems",
+    "v_sems",
     "batch_size",
     "pages_per_compute_block",
     "pages_per_sequence",

@@ -4,7 +4,7 @@ parallel/mesh.py)."""
 
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 ROW = P("data", "modle")  # SHD001: 'modle' is a typo of 'model'
 DUP = P("model", ("model", None))  # SHD003: 'model' consumed twice

@@ -5,7 +5,7 @@ must not be mistaken for a spec."""
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from areal_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 ROW = P("data", ("fsdp", "seq"), None)
 FULL = P(("data", "fsdp"))

@@ -295,7 +295,7 @@ def test_pvt_bad_fixture():
     # the finding carries the parameter diff, naming a really-removed pin
     # entry and a really-present installed parameter
     assert "a_param_jax_renamed" in drift.message
-    assert "step_ref" in drift.message
+    assert "init_flag_ref" in drift.message
 
 
 def test_pvt_good_fixture():

@@ -50,13 +50,14 @@ def test_paged_attention_xla_int8_close():
     q = jnp.asarray(rng.normal(0, 1, (S, H, hd)).astype(np.float32))
     k = jnp.asarray(rng.normal(0, 1, (KH, N, psz, hd)).astype(np.float32))
     v = jnp.asarray(rng.normal(0, 1, (KH, N, psz, hd)).astype(np.float32))
-    kq, ks = paged_kv.quantize_kv(k)
-    vq, vs = paged_kv.quantize_kv(v)
+    kq, ks = paged_kv.quantize_pages(k)  # lane-major scales [KH, N, 1, psz]
+    vq, vs = paged_kv.quantize_pages(v)
+    assert ks.shape == (KH, N, 1, psz)
     lengths = jnp.asarray([5, 8, 3], jnp.int32)
     table = jnp.asarray(rng.integers(0, N, (S, wp)), jnp.int32)
     got = paged_kv.paged_attention_xla(q, kq, vq, lengths, table, ks, vs)
-    kd = paged_kv.dequantize_kv(kq, ks, jnp.float32)
-    vd = paged_kv.dequantize_kv(vq, vs, jnp.float32)
+    kd = paged_kv.dequantize_kv(kq, jnp.swapaxes(ks, -1, -2), jnp.float32)
+    vd = paged_kv.dequantize_kv(vq, jnp.swapaxes(vs, -1, -2), jnp.float32)
     want = paged_kv.paged_attention_xla(q, kd, vd, lengths, table)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
@@ -82,7 +83,8 @@ def test_engine_serves_with_int8_kv():
         eng.initialize()
         if kvq == "int8":
             assert eng.cache["k"].dtype == jnp.int8
-            assert eng.cache["k_scale"].shape[-1] == 1
+            # lane-major: one f32 per token vector, tokens along the lanes
+            assert eng.cache["k_scale"].shape[-2:] == (1, eng.config.page_size)
         eng.start()
         try:
             r = eng.generate_sync(

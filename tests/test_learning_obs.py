@@ -151,7 +151,7 @@ def _assert_bucket_identity(s):
 def test_lag_bucket_stats_recompose_batch_scalars(actor):
     s = _run_update(actor, _mixed_version_batch(v_theta=6))
     share = _assert_bucket_identity(s)
-    # the four populations land where the taxonomy says: the split row
+    # the four populations land where the vocabulary says: the split row
     # feeds BOTH the lag-3 (bucket "2") and lag-1 populations
     assert share["0"] > 0 and share["1"] > 0 and share["2"] > 0
     assert share["4+"] > 0

@@ -16,7 +16,7 @@ from areal_tpu.api.config import (
 from areal_tpu.api.io_struct import FinetuneSpec
 from areal_tpu.engine.train_engine import JaxTrainEngine
 from areal_tpu.models import qwen
-from areal_tpu.utils.jax_compat import set_mesh
+from jax import set_mesh
 
 MODEL_KW = dict(
     vocab_size=128,

@@ -9,7 +9,7 @@ import pytest
 
 from areal_tpu.models import qwen
 from areal_tpu.models.moe import moe_ffn
-from areal_tpu.utils.jax_compat import set_mesh
+from jax import set_mesh
 
 MOE_CFG = qwen.ModelConfig(
     vocab_size=256,

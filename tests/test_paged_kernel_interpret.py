@@ -3,8 +3,9 @@
 These caught a real on-chip bug: jax's library kernel applies NO 1/sqrt(hd)
 logit scaling (callers pre-scale q), while the XLA gather path scales
 internally — so the TPU kernel path served over-peaked attention until
-paged_attention_tpu gained the pre-scale. tests_tpu/ re-checks on real
-hardware; this file keeps the parity under CI without a chip.
+paged_attention_tpu gained the pre-scale. chip_smoke.py's kernels phase
+re-checks on real hardware; this file keeps the parity under CI without a
+chip.
 """
 
 import functools
@@ -46,16 +47,16 @@ def test_xla_path_matches_dense_reference():
 
 
 def test_q8_kernel_interpret_matches_xla():
-    """The narrow-scales int8 fork (ops/paged_attention_q8.py) against the
-    gather+dequant XLA path, through the paged_attention_tpu entry point."""
+    """The quantized decode kernel (ops/paged_attention_q8.py, lane-major
+    scales) against the gather+dequant XLA path."""
     import areal_tpu.ops.paged_attention_q8 as q8mod
 
     q, k, v, lengths, pt = _setup()
-    kq, ks = paged_kv.quantize_kv(k)
-    vq, vs = paged_kv.quantize_kv(v)
+    kq, ks = paged_kv.quantize_pages(k)
+    vq, vs = paged_kv.quantize_pages(v)
     ref = paged_kv.paged_attention_xla(q, kq, vq, lengths, pt, ks, vs)
     out = q8mod.paged_attention_q8(
-        q,  # RAW: the fork applies 1/sqrt(hd) internally
+        q,  # RAW: the wrapper applies 1/sqrt(hd) internally
         kq,
         ks,
         vq,
@@ -94,8 +95,8 @@ def test_stacked_kernel_interpret_matches_xla():
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=3e-2
         )
-    kq, ks = paged_kv.quantize_kv(k)
-    vq, vs = paged_kv.quantize_kv(v)
+    kq, ks = paged_kv.quantize_pages(k)
+    vq, vs = paged_kv.quantize_pages(v)
     ref = paged_kv.paged_attention_xla(q, kq[1], vq[1], lengths, pt, ks[1], vs[1])
     out = paged_attention_stacked(
         q, kq, vq, jnp.int32(1), lengths, pt,

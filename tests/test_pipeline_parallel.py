@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from areal_tpu.parallel.pipeline import gpipe
 
@@ -50,7 +50,7 @@ def _pipelined(ws, bs, x, mesh):
         mesh=mesh,
         in_specs=((P("stage"), P("stage")), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return mapped((ws, bs), x)
 
@@ -90,7 +90,7 @@ def test_uneven_microbatches_and_stages(setup):
         mesh=mesh,
         in_specs=((P("stage"), P("stage")), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )((ws, bs), x)
     want = _reference(ws, bs, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)

@@ -14,7 +14,7 @@ from areal_tpu.models import qwen
 from areal_tpu.models.hf import load_params_from_hf, save_params_to_hf
 from areal_tpu.parallel import make_mesh
 from areal_tpu.api.config import MeshConfig
-from areal_tpu.utils.jax_compat import set_mesh
+from jax import set_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from tpu_testing import TINY_QWEN2, TINY_QWEN3

@@ -186,7 +186,10 @@ def test_generate_sync_timeout_cancels_server_side(engine):
     (preferred) or raises TimeoutError with the slot reclaimed."""
     cancelled_before = engine.stats["cancelled"]
     # saturate both slots + queue so the timed request cannot complete
-    # inside its timeout (it is either still queued or mid-decode)
+    # inside its timeout (it is either still queued or mid-decode). Every
+    # request here ends at max_seq_len after ~250 tokens, a fraction of a
+    # second on a fast host: the timeout must be shorter than ONE such
+    # request, not than the whole queue
     fills = []
     for _ in range(4):
         done = threading.Event()
@@ -196,7 +199,7 @@ def test_generate_sync_timeout_cancels_server_side(engine):
     try:
         try:
             resp = engine.generate_sync(
-                ModelRequest(input_ids=[2, 7, 1], gconfig=_long()), timeout=1.0
+                ModelRequest(input_ids=[2, 7, 1], gconfig=_long()), timeout=0.1
             )
             assert resp.stop_reason == StopReason.CANCEL.value
         except TimeoutError:

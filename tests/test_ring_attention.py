@@ -10,7 +10,7 @@ from areal_tpu.api.config import MeshConfig
 from areal_tpu.models import qwen
 from areal_tpu.parallel.mesh import make_mesh
 from areal_tpu.parallel.ring_attention import ring_attention, zigzag_indices
-from areal_tpu.utils.jax_compat import set_mesh
+from jax import set_mesh
 
 from tpu_testing import TINY_QWEN2
 

@@ -249,7 +249,7 @@ def test_rl_trainer_stats_carry_compat_and_phase_keys(rl_trainer, tmp_path):
         "timing/eval",
     ):
         assert k in stats, sorted(stats)
-    # the new phase taxonomy rides the same per-step stats surface
+    # the new phase vocabulary rides the same per-step stats surface
     for p in PHASES:
         assert f"phase/{p}_s" in stats
     assert stats["timing/rollout"] == stats["phase/rollout_wait_s"]

@@ -1,0 +1,244 @@
+"""Compile the main-path Pallas kernels for a DESCRIBED TPU v5e.
+
+No chip is attached: the TPU compiler that ships with jaxlib compiles for a
+topology description (rehearsal 3 of the on-chip-measurement guide), and
+raises exactly what the chip's compiler would raise — a slice not aligned
+to the tiling, more VMEM than a kernel may use, a block shape the lowering
+refuses. Interpret mode sees none of these; every failure this file guards
+against passed its interpret-mode tests first.
+
+Each case lowers with ``interpret=False`` at Qwen2.5-1.5B head shapes (12
+heads / 2 KV heads / head_dim 128, 28 layers, 128-token pages) and asserts
+the compiled module holds a ``tpu_custom_call``. About two seconds a case;
+no whole-model program is compiled here (tier-1 has no room for one).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+L, KH, H, HD, PSZ = 28, 2, 12, 128, 128
+SLOTS, WP = 128, 16  # decode: 128 slots x 2048-token windows
+N_PAGES = SLOTS * WP + 1
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """ShapeDtypeStruct factory placed on one described v5e chip; the
+    persistent compile cache is off around the module (an entry compiled
+    for a described chip cannot be read back without one)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this jaxlib
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    one = SingleDeviceSharding(topo.devices[0])
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _decode(page_dtype):
+    from areal_tpu.ops.paged_attention_q8 import paged_attention_stacked
+
+    quant = page_dtype != jnp.bfloat16
+
+    def fn(q, k, v, li, lengths, pt, *scales):
+        kw = dict(k_scales=scales[0], v_scales=scales[1]) if scales else {}
+        return paged_attention_stacked(
+            q, k, v, li, lengths, pt, pages_per_compute_block=4, **kw
+        )
+
+    def args(S):
+        pages = S((L, KH, N_PAGES, PSZ, HD), page_dtype)
+        a = [
+            S((SLOTS, H, HD), jnp.bfloat16), pages, pages, S((), jnp.int32),
+            S((SLOTS,), jnp.int32), S((SLOTS, WP), jnp.int32),
+        ]
+        if quant:  # lane-major scales
+            a += [S((L, KH, N_PAGES, 1, PSZ), jnp.float32)] * 2
+        return a
+
+    return fn, args
+
+
+def _suffix(B, A, page_dtype=jnp.bfloat16):
+    """Suffix prefill (chain mask) and tree verify (tree mask) are ONE
+    kernel: the mask is an operand, so the block size and the page dtype
+    are what the compiler sees."""
+    from areal_tpu.ops.paged_suffix_attention import paged_suffix_attention
+
+    quant = page_dtype != jnp.bfloat16
+
+    def fn(q, ks, vs, k, v, li, plens, pt, mask, *scales):
+        kw = dict(k_scales=scales[0], v_scales=scales[1]) if scales else {}
+        return paged_suffix_attention(
+            q, ks, vs, k, v, li, plens, pt, mask, interpret=False, **kw
+        )
+
+    def args(S):
+        pages = S((L, KH, N_PAGES, PSZ, HD), page_dtype)
+        a = [
+            S((A, B, H, HD), jnp.bfloat16), S((A, B, KH, HD), jnp.bfloat16),
+            S((A, B, KH, HD), jnp.bfloat16), pages, pages, S((), jnp.int32),
+            S((A,), jnp.int32), S((A, 8), jnp.int32), S((A, B, B), jnp.bool_),
+        ]
+        if quant:
+            a += [S((L, KH, N_PAGES, 1, PSZ), jnp.float32)] * 2
+        return a
+
+    return fn, args
+
+
+def _flash_fwd():
+    from areal_tpu.ops import attention
+
+    def args(S):
+        x = S((1, 2048, H, HD), jnp.bfloat16)
+        return [x, x, x, S((1, 2048), jnp.int32)]
+
+    return (lambda q, k, v, seg: attention.flash_fwd_pallas(q, k, v, seg)), args
+
+
+def _flash_train_grad():
+    from areal_tpu.ops import attention
+
+    def fn(q, k, v, seg):
+        return jax.grad(
+            lambda q, k, v: attention.flash_train(q, k, v, seg)
+            .astype(jnp.float32)
+            .sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    return fn, _flash_fwd()[1]
+
+
+def _library_paged():
+    from areal_tpu.inference import paged_kv
+
+    def args(S):
+        pages = S((KH, N_PAGES, PSZ, HD), jnp.bfloat16)
+        return [
+            S((SLOTS, H, HD), jnp.bfloat16), pages, pages,
+            S((SLOTS,), jnp.int32), S((SLOTS, WP), jnp.int32),
+        ]
+
+    return paged_kv.paged_attention_tpu, args
+
+
+def _tree(grad: bool):
+    from areal_tpu.ops import tree_attention as ta
+
+    n = 1024
+
+    def fwd(q, k, v, words, block_any):
+        return ta.tree_attention(q, k, v, words, block_any, False)
+
+    def bwd(q, k, v, words, block_any):
+        return jax.grad(
+            lambda q, k, v: fwd(q, k, v, words, block_any).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    def args(S):
+        x = S((n, H, HD), jnp.bfloat16)
+        return [
+            x, x, x, S((n // ta.WORD, n), jnp.uint32),
+            S((n // ta.BLOCK, n // ta.BLOCK_K), jnp.int32),
+        ]
+
+    return (bwd if grad else fwd), args
+
+
+def _gmm():
+    from areal_tpu.models.moe import pinned_gmm
+
+    gmm = pinned_gmm()
+
+    def args(S):  # the TPU m tile of models/moe.py
+        return [
+            S((4096, 2048), jnp.bfloat16), S((8, 2048, 1024), jnp.bfloat16),
+            S((8,), jnp.int32),
+        ]
+
+    return (lambda x, w, gs: gmm(x, w, gs, tiling=(128, 128, 128), interpret=False)), args
+
+
+CASES = {
+    "paged_decode_bf16": lambda: _decode(jnp.bfloat16),
+    "paged_decode_int8": lambda: _decode(jnp.int8),
+    "paged_decode_fp8": lambda: _decode(jnp.float8_e4m3fn),
+    # the engine's smallest and largest suffix buckets at max_seq_len 2048
+    "suffix_prefill_B256": lambda: _suffix(256, 4),
+    "suffix_prefill_B2048": lambda: _suffix(2048, 2),
+    "suffix_prefill_B256_int8": lambda: _suffix(256, 4, jnp.int8),
+    "suffix_prefill_B256_fp8": lambda: _suffix(256, 4, jnp.float8_e4m3fn),
+    # spec-decode verify: every slot, a handful of tree nodes (padded to 16)
+    "tree_verify_B9": lambda: _suffix(9, SLOTS),
+    "flash_fwd_pallas": _flash_fwd,
+    "flash_train_grad": _flash_train_grad,
+    "library_paged_attention": _library_paged,
+    "tree_attention_fwd": lambda: _tree(False),
+    "tree_attention_bwd": lambda: _tree(True),
+    "megablox_gmm": _gmm,
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(chip, name):
+    fn, args = CASES[name]()
+    compiled = jax.jit(fn).lower(*args(chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_static_shape_rule_matches_the_compiler(chip):
+    """``paged_kernel_ok`` is what sends an engine to the gather path: it
+    must say no exactly where the chip's compiler does (head_dim 64, the
+    Qwen2.5-0.5B shape; quantized pages under 128 tokens)."""
+    from areal_tpu.ops.paged_attention_q8 import (
+        paged_attention_stacked,
+        paged_kernel_ok,
+    )
+
+    def compiles(hd, psz, page_dtype):
+        quant = page_dtype != jnp.bfloat16
+        pages = chip((2, KH, 65, psz, hd), page_dtype)
+        a = [
+            chip((8, H, hd), jnp.bfloat16), pages, pages, chip((), jnp.int32),
+            chip((8,), jnp.int32), chip((8, 8), jnp.int32),
+        ]
+        if quant:
+            a += [chip((2, KH, 65, 1, psz), jnp.float32)] * 2
+
+        def fn(q, k, v, li, le, pt, *sc):
+            kw = dict(k_scales=sc[0], v_scales=sc[1]) if sc else {}
+            return paged_attention_stacked(
+                q, k, v, li, le, pt, pages_per_compute_block=4, **kw
+            )
+
+        try:
+            jax.jit(fn).lower(*a).compile()
+            return True
+        except Exception:  # noqa: BLE001 — Mosaic's refusal is the answer
+            return False
+
+    for hd, psz, dt in (
+        (128, 16, jnp.bfloat16),
+        (64, 128, jnp.bfloat16),
+        (128, 64, jnp.int8),
+        (128, 256, jnp.int8),
+    ):
+        assert compiles(hd, psz, dt) == paged_kernel_ok(
+            hd, psz, dt != jnp.bfloat16
+        ), (hd, psz, dt)

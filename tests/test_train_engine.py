@@ -14,7 +14,7 @@ from areal_tpu.api.config import (
 )
 from areal_tpu.api.io_struct import FinetuneSpec, SaveLoadMeta
 from areal_tpu.engine.train_engine import JaxTrainEngine
-from areal_tpu.utils.jax_compat import set_mesh
+from jax import set_mesh
 
 from tpu_testing import TINY_QWEN2, random_batch
 

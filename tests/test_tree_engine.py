@@ -20,7 +20,7 @@ from areal_tpu.engine.train_engine import JaxTrainEngine
 from areal_tpu.models import qwen, tree
 from areal_tpu.ops import functional as F
 from areal_tpu.utils.data import pad_sequences_to_tensors
-from areal_tpu.utils.jax_compat import set_mesh
+from jax import set_mesh
 
 from tpu_testing import TINY_QWEN2
 

@@ -95,19 +95,23 @@ def test_pack_ancestor_bits():
     import numpy as np
 
     from areal_tpu.models.tree import build_tree
-    from areal_tpu.ops.tree_attention import BLOCK, pack_ancestor_bits
+    from areal_tpu.ops.tree_attention import BLOCK, BLOCK_K, pack_ancestor_bits
 
     pack = build_tree([[1, 2, 3], [1, 2, 4], [5, 6]])
     words, block_any = pack_ancestor_bits(pack.parent)
-    assert words.shape == (BLOCK, BLOCK // 32)
+    # packed along the QUERY axis: bit i % 32 of words[i // 32, j]
+    assert words.shape == (BLOCK // 32, BLOCK)
     mask = pack.ancestor_mask()
     for i in range(pack.n_nodes):
         for j in range(pack.n_nodes):
-            bit = (int(words[i, j // 32]) >> (j % 32)) & 1
+            bit = (int(words[i // 32, j]) >> (i % 32)) & 1
             assert bool(bit) == bool(mask[i, j]), (i, j)
-    # padded rows carry no bits
-    assert words[pack.n_nodes :].sum() == 0
-    assert block_any.shape == (1, 1) and block_any[0, 0] == 1
+    # padded rows and columns carry no bits
+    dense = (words[:, None, :] >> np.arange(32, dtype=np.uint32)[None, :, None]) & 1
+    dense = dense.reshape(BLOCK, BLOCK)
+    assert dense[pack.n_nodes :].sum() == 0 and dense[:, pack.n_nodes :].sum() == 0
+    assert block_any.shape == (1, BLOCK // BLOCK_K)
+    assert block_any[0, 0] == 1 and block_any[0, 1:].sum() == 0
 
 
 def test_tree_attention_kernel_matches_dense():
@@ -116,7 +120,11 @@ def test_tree_attention_kernel_matches_dense():
     import jax.numpy as jnp
 
     from areal_tpu.models.tree import build_tree
-    from areal_tpu.ops.tree_attention import pack_ancestor_bits, tree_attention
+    from areal_tpu.ops.tree_attention import (
+        BLOCK,
+        pack_ancestor_bits,
+        tree_attention,
+    )
 
     rng = np.random.default_rng(0)
     seqs = [list(rng.integers(1, 50, rng.integers(20, 60))) for _ in range(8)]
@@ -125,7 +133,7 @@ def test_tree_attention_kernel_matches_dense():
         seqs[i] = seqs[i - 4][:15] + seqs[i]
     pack = build_tree(seqs)
     N = pack.n_nodes
-    n_pad = -(-N // 128) * 128
+    n_pad = -(-N // BLOCK) * BLOCK
     H, d = 4, 128
     q = rng.normal(0, 1, (n_pad, H, d)).astype(np.float32)
     k = rng.normal(0, 1, (n_pad, H, d)).astype(np.float32)
