@@ -774,14 +774,15 @@ class JaxTrainEngine(TrainEngine):
                 "gld,d->gl", hidden.astype(jnp.float32), cparams["value_head"].astype(jnp.float32)
             )
         else:
-            logp, ent = qwen.chunked_logprobs_entropy(
-                cparams,
-                mcfg,
-                hidden,
-                batch["labels"],
-                chunk_size=self.config.logprob_chunk_size,
-                temperature=self._logit_temperature,
-            )
+            with jax.named_scope("loss"):
+                logp, ent = qwen.chunked_logprobs_entropy(
+                    cparams,
+                    mcfg,
+                    hidden,
+                    batch["labels"],
+                    chunk_size=self.config.logprob_chunk_size,
+                    temperature=self._logit_temperature,
+                )
             outputs["logprobs"] = logp
             outputs["entropy"] = ent
         return outputs
@@ -885,15 +886,16 @@ class JaxTrainEngine(TrainEngine):
         # one chunked-vocab pass, EDGE-aligned: row parent(j) scored against
         # token(j) gives log p(node j | ancestors); the entropy from the
         # same row is exactly the label-aligned entropy convention
-        edge_hidden = jnp.take(hidden, batch["edge_rows"], axis=0)
-        logp, ent = qwen.chunked_logprobs_entropy(
-            cparams,
-            mcfg,
-            edge_hidden[None],
-            batch["edge_labels"][None],
-            chunk_size=self.config.logprob_chunk_size,
-            temperature=self._logit_temperature,
-        )
+        with jax.named_scope("loss"):
+            edge_hidden = jnp.take(hidden, batch["edge_rows"], axis=0)
+            logp, ent = qwen.chunked_logprobs_entropy(
+                cparams,
+                mcfg,
+                edge_hidden[None],
+                batch["edge_labels"][None],
+                chunk_size=self.config.logprob_chunk_size,
+                temperature=self._logit_temperature,
+            )
         gather = batch["gather_idx"]  # [B, T] -> edge index of token t+1
         outputs = {
             "logprobs": logp[0][gather],
@@ -914,8 +916,9 @@ class JaxTrainEngine(TrainEngine):
             def compute(params, batch, scale):
                 def lf(p):
                     outputs = ofn(p, batch)
-                    loss, stats = loss_fn(outputs, batch)
-                    return loss * scale, stats
+                    with jax.named_scope("loss"):
+                        loss, stats = loss_fn(outputs, batch)
+                        return loss * scale, stats
 
                 (loss, stats), grads = jax.value_and_grad(lf, has_aux=True)(params)
                 return grads, loss, stats
@@ -965,14 +968,16 @@ class JaxTrainEngine(TrainEngine):
             def step(params, opt_state, batch, scale):
                 def lf(p):
                     outputs = ofn(p, batch)
-                    loss, stats = loss_fn(outputs, batch)
-                    return loss * scale, stats
+                    with jax.named_scope("loss"):
+                        loss, stats = loss_fn(outputs, batch)
+                        return loss * scale, stats
 
 
                 (loss, stats), grads = jax.value_and_grad(lf, has_aux=True)(params)
-                gnorm = self._grad_norm(grads)
-                updates, opt_state = self._tx.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
+                with jax.named_scope("optimizer"):
+                    gnorm = self._grad_norm(grads)
+                    updates, opt_state = self._tx.update(grads, opt_state, params)
+                    params = optax.apply_updates(params, updates)
                 return params, opt_state, gnorm, loss, stats
 
             # params/opt_state are rebound by every caller (DON001 contract)
@@ -985,9 +990,10 @@ class JaxTrainEngine(TrainEngine):
         if key not in self._fn_cache:
 
             def apply(params, opt_state, grads):
-                gnorm = self._grad_norm(grads)
-                updates, opt_state = self._tx.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
+                with jax.named_scope("optimizer"):
+                    gnorm = self._grad_norm(grads)
+                    updates, opt_state = self._tx.update(grads, opt_state, params)
+                    params = optax.apply_updates(params, updates)
                 return params, opt_state, gnorm
 
             # grads are dead after the apply (the accumulate loop rebinds
@@ -1212,17 +1218,21 @@ class JaxTrainEngine(TrainEngine):
                     # per stat — PRF burn-down, docs/static_analysis.md)
                     # arealint: disable-next=PRF001 designed step-boundary sync: single batched pull, nothing left to overlap
                     host = jax.device_get({**stats, "loss": loss, "grad_norm": gnorm})
-            seq_arrays = _split_seq_stats(host)
-            if seq_arrays:
-                self._collect_seq_stats(
-                    [(grids[0], seq_arrays)],
-                    int(np.asarray(input_["attention_mask"]).shape[0]),
-                )
-            agg = {k: float(v) for k, v in host.items()}
-            agg["lr"] = float(self._lr_schedule(step_before))
-            agg["n_microbatches"] = 1.0
-            agg["train_batch_secs"] = time.monotonic() - t0
-            self._count_opt_step()
+            # the step's stats are host work too (the schedule's value is a
+            # handful of tiny device programs and a pull): the device idles
+            # through it, so it carries a phase and not the residual
+            with engine_phase("host_prep"):
+                seq_arrays = _split_seq_stats(host)
+                if seq_arrays:
+                    self._collect_seq_stats(
+                        [(grids[0], seq_arrays)],
+                        int(np.asarray(input_["attention_mask"]).shape[0]),
+                    )
+                agg = {k: float(v) for k, v in host.items()}
+                agg["lr"] = float(self._lr_schedule(step_before))
+                agg["n_microbatches"] = 1.0
+                agg["train_batch_secs"] = time.monotonic() - t0
+                self._count_opt_step()
             return agg
         pending_stats: list[dict] = []  # per-microbatch DEVICE stat trees
         with set_mesh(self.mesh):
@@ -1249,19 +1259,20 @@ class JaxTrainEngine(TrainEngine):
                 # microbatch's stats (was: one sync per microbatch)
                 # arealint: disable-next=PRF001 designed step-boundary sync: single batched pull, nothing left to overlap
                 gnorm_h, mb_host = jax.device_get((gnorm, pending_stats))
-        seq_pairs = [
-            (g, _split_seq_stats(s)) for g, s in zip(grids, mb_host)
-        ]
-        if any(arrs for _, arrs in seq_pairs):
-            self._collect_seq_stats(
-                seq_pairs, int(np.asarray(input_["attention_mask"]).shape[0])
-            )
-        _fold_weighted_stats(agg, mb_host, weights, total_w)
-        agg["grad_norm"] = float(gnorm_h)
-        agg["lr"] = float(self._lr_schedule(step_before))
-        agg["n_microbatches"] = float(len(grids))
-        agg["train_batch_secs"] = time.monotonic() - t0
-        self._count_opt_step()
+        with engine_phase("host_prep"):  # the step's stats, as above
+            seq_pairs = [
+                (g, _split_seq_stats(s)) for g, s in zip(grids, mb_host)
+            ]
+            if any(arrs for _, arrs in seq_pairs):
+                self._collect_seq_stats(
+                    seq_pairs, int(np.asarray(input_["attention_mask"]).shape[0])
+                )
+            _fold_weighted_stats(agg, mb_host, weights, total_w)
+            agg["grad_norm"] = float(gnorm_h)
+            agg["lr"] = float(self._lr_schedule(step_before))
+            agg["n_microbatches"] = float(len(grids))
+            agg["train_batch_secs"] = time.monotonic() - t0
+            self._count_opt_step()
         return agg
 
     def _collect_seq_stats(

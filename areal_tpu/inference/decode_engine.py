@@ -65,6 +65,7 @@ from areal_tpu.observability import timeline as tl_mod
 from areal_tpu.parallel import mesh as mesh_lib
 from jax import set_mesh
 from areal_tpu.utils import logging as alog
+from areal_tpu.utils import perf_tracer
 from areal_tpu.utils.data import round_up_to_bucket
 
 logger = alog.getLogger("decode_engine")
@@ -845,8 +846,6 @@ class DecodeEngine:
         # timeline starts at submission; the x-areal-trace ids are whatever
         # the calling context carries (the HTTP server seats them before
         # submitting), so cross-process postmortems correlate on them
-        from areal_tpu.utils import perf_tracer
-
         task_id, session_id = perf_tracer.get_task_context()
         tl = self.timeline.start(
             req.rid,
@@ -1847,7 +1846,8 @@ class DecodeEngine:
                     params, mcfg, ids, positions, seg, image_embeds=img
                 )
                 # ks/vs: [n_layers, A, bucket, KH, hd] -> page scatter
-                return paged_kv.scatter_prefill(cache, ks, vs, flat_pages, psz)
+                with jax.named_scope("kv_write"):
+                    return paged_kv.scatter_prefill(cache, ks, vs, flat_pages, psz)
 
             self._fn_cache[key] = kernel_probe.ProbedFn(
                 jax.jit(prefill, donate_argnames=("cache",)),
@@ -1883,7 +1883,8 @@ class DecodeEngine:
                     params, mcfg, ids, positions, seg, cache, ppt, offs,
                     use_kernel=use_kernel,
                 )
-                return paged_kv.scatter_prefill(cache, ks, vs, flat_pages, psz)
+                with jax.named_scope("kv_write"):
+                    return paged_kv.scatter_prefill(cache, ks, vs, flat_pages, psz)
 
             self._fn_cache[key] = kernel_probe.ProbedFn(
                 jax.jit(prefill, donate_argnames=("cache",)),
@@ -2008,18 +2009,20 @@ class DecodeEngine:
                         page_size=psz,
                         use_kernel=use_kernel,
                     )
-                    logits = qwen.compute_logits(params, mcfg, hidden)
-                    if freq_any:
-                        # OpenAI-style frequency penalty on raw logits,
-                        # proportional to this slot's generated-token counts
-                        logits = logits - (
-                            state["freq_pen"][:, None]
-                            * counts.astype(jnp.float32)
+                    with jax.named_scope("lm_head"):
+                        logits = qwen.compute_logits(params, mcfg, hidden)
+                    with jax.named_scope("sampler"):
+                        if freq_any:
+                            # OpenAI-style frequency penalty on raw logits,
+                            # proportional to this slot's generated-token counts
+                            logits = logits - (
+                                state["freq_pen"][:, None]
+                                * counts.astype(jnp.float32)
+                            )
+                        rng, sub = jax.random.split(rng)
+                        next_ids, logp = _sample_step(
+                            logits, sub, state, capped, greedy_any
                         )
-                    rng, sub = jax.random.split(rng)
-                    next_ids, logp = _sample_step(
-                        logits, sub, state, capped, greedy_any
-                    )
                     if freq_any:
                         # saturating (uint16 .add would wrap at 65535 —
                         # reachable at max_seq_len > 64k, and negative
@@ -2168,7 +2171,8 @@ class DecodeEngine:
                     pos0,
                     use_kernel=use_kernel,
                 )
-                logits = qwen.compute_logits(params, mcfg, hidden)  # [S,B,V]
+                with jax.named_scope("lm_head"):
+                    logits = qwen.compute_logits(params, mcfg, hidden)  # [S,B,V]
                 row_valid = (
                     jnp.arange(1, B, dtype=jnp.int32)[None, :]
                     <= d_count[:, None]
@@ -2188,10 +2192,11 @@ class DecodeEngine:
                     lg = jnp.take_along_axis(
                         logits, cur[:, None, None], axis=1
                     )[:, 0]  # [S, V]
-                    rng, sub = jax.random.split(rng)
-                    t_j, logp_j = _sample_step(
-                        lg, sub, state, capped, greedy_any
-                    )
+                    with jax.named_scope("sampler"):
+                        rng, sub = jax.random.split(rng)
+                        t_j, logp_j = _sample_step(
+                            lg, sub, state, capped, greedy_any
+                        )
                     emit_rows.append(cont)
                     toks_rows.append(t_j)
                     logp_rows.append(logp_j)
@@ -2234,13 +2239,14 @@ class DecodeEngine:
                 rows = positions % psz
                 L = ks.shape[0]
                 KH, hd = ks.shape[3], ks.shape[4]
-                cache = paged_kv.scatter_token_rows(
-                    cache,
-                    ks.reshape(L, S * B, KH, hd),
-                    vs.reshape(L, S * B, KH, hd),
-                    pages.reshape(-1),
-                    rows.reshape(-1),
-                )
+                with jax.named_scope("kv_write"):
+                    cache = paged_kv.scatter_token_rows(
+                        cache,
+                        ks.reshape(L, S * B, KH, hd),
+                        vs.reshape(L, S * B, KH, hd),
+                        pages.reshape(-1),
+                        rows.reshape(-1),
+                    )
                 packed = jnp.concatenate(
                     [
                         jnp.stack(toks_rows).astype(jnp.int32),  # [B, S]
@@ -3560,6 +3566,73 @@ class DecodeEngine:
             return {}
         return self.kprobe.stats()
 
+    def _run_pass(self, pending: dict | None, step_tl, span) -> tuple[dict | None, bool]:
+        """One pass of the loop with the cache live: reap, admit, then either
+        one speculative round or dispatch-then-drain. Returns the chunk now
+        in flight and whether the loop has nothing to do until a wakeup.
+
+        ``span`` is the pass's ``areal.decode.pass`` span (None for an idle
+        poll); the phases inside are its children. It ends with the slots
+        active after the pass and the tokens the pass credited. (A pass
+        that started with requests queued and admitted none of them, all
+        expired, still leaves its span, with 0 and 0.)"""
+        # lifecycle reaping BETWEEN chunks: cancellations, expired
+        # deadlines (queued and decoding), per-slot watchdog — the
+        # overload-safety half of interruptible generation. When a reap
+        # fires, the in-flight chunk is drained first (tokens credited)
+        # and None comes back; the fast path returns pending untouched.
+        with self._kphase("admission"):
+            pending = self._reap_lifecycle(pending)
+            # admissions enqueue prefills + ONE packed state scatter; the
+            # in-flight chunk (if any) ordered before them touches only
+            # previously-active slots, so there is no dataflow hazard
+            rows = self._admit_pending()
+            self._apply_slot_updates(rows)
+        spec_on = self._spec_cfg is not None and self._drafter is not None
+        if spec_on and self._freq_enabled:
+            st = self._state
+            # the in-round count updates the freq penalty needs are
+            # incompatible with parallel verify scoring — fall back to
+            # the sequential chunk path while any active slot uses it
+            spec_on = not bool((st["freq_pen"] != 0.0)[st["active"]].any())
+        drained_key = pending["key"] if pending is not None else None
+        if spec_on:
+            # SYNCHRONOUS speculative pass: drain the pipelined chunk
+            # first (covers the spec-off -> spec-on transition), then
+            # draft + verify + accept in one round. A weight commit
+            # always applies at the top of the pass, so draft and
+            # verify run under ONE version — a commit landing "between
+            # draft and verify" is impossible by construction, and
+            # drafts are version-free host proposals anyway.
+            tokens = self._drain(pending)
+            n_spec, cost_key = self._spec_round()
+            tokens += n_spec
+            pending = None
+            worked = cost_key is not None
+            cost_key = cost_key or drained_key
+        else:
+            # speculatively dispatch the next chunk, then pay the previous
+            # chunk's download while this one computes
+            with self._kphase("dispatch"):
+                dispatched = self._dispatch_chunk()
+            tokens = self._drain(pending)
+            pending = dispatched
+            worked = dispatched is not None
+            cost_key = drained_key
+        if span is not None:
+            span.set(active=int(self._state["active"].sum()), tokens=tokens)
+        if step_tl is not None:
+            # a pass that drained, dispatched, or admitted is a real
+            # step; a bare poll (no slots, empty queue) is not
+            if drained_key is not None or worked or rows:
+                self._ktl = None
+                self.kprobe.complete_step(step_tl, tokens=tokens, cost_key=cost_key)
+            else:
+                self._abandon_kstep()
+        return pending, not worked and not any(
+            t is not None for t in self._slot_task
+        )
+
     def _loop(self) -> None:
         pending: dict | None = None
         while not self._shutdown.is_set():
@@ -3664,73 +3737,25 @@ class DecodeEngine:
                 self._wakeup.clear()
                 continue
             self._hold_marked = False  # next hold window marks afresh
-            # lifecycle reaping BETWEEN chunks: cancellations, expired
-            # deadlines (queued and decoding), per-slot watchdog — the
-            # overload-safety half of interruptible generation. When a reap
-            # fires, the in-flight chunk is drained first (tokens credited)
-            # and None comes back; the fast path returns pending untouched.
-            with self._kphase("admission"):
-                pending = self._reap_lifecycle(pending)
-                # admissions enqueue prefills + ONE packed state scatter; the
-                # in-flight chunk (if any) ordered before them touches only
-                # previously-active slots, so there is no dataflow hazard
-                rows = self._admit_pending()
-                self._apply_slot_updates(rows)
-            spec_on = self._spec_cfg is not None and self._drafter is not None
-            if spec_on and self._freq_enabled:
-                st = self._state
-                # the in-round count updates the freq penalty needs are
-                # incompatible with parallel verify scoring — fall back to
-                # the sequential chunk path while any active slot uses it
-                spec_on = not bool((st["freq_pen"] != 0.0)[st["active"]].any())
-            if spec_on:
-                # SYNCHRONOUS speculative pass: drain the pipelined chunk
-                # first (covers the spec-off -> spec-on transition), then
-                # draft + verify + accept in one round. A weight commit
-                # always applies at the top of the pass, so draft and
-                # verify run under ONE version — a commit landing "between
-                # draft and verify" is impossible by construction, and
-                # drafts are version-free host proposals anyway.
-                drained_key = pending["key"] if pending is not None else None
-                n_pipe = self._drain(pending)
-                pending = None
-                n_spec, spec_key = self._spec_round()
-                if step_tl is not None:
-                    if drained_key is not None or spec_key is not None or rows:
-                        self._ktl = None
-                        self.kprobe.complete_step(
-                            step_tl,
-                            tokens=n_pipe + n_spec,
-                            cost_key=spec_key or drained_key,
-                        )
-                    else:
-                        self._abandon_kstep()
-                if spec_key is None:
-                    if not any(t is not None for t in self._slot_task):
-                        self._wakeup.wait(timeout=0.05)
-                        self._wakeup.clear()
-                continue
-            # speculatively dispatch the next chunk, then pay the previous
-            # chunk's download while this one computes
-            with self._kphase("dispatch"):
-                dispatched = self._dispatch_chunk()
-            drained_key = pending["key"] if pending is not None else None
-            n_drained = self._drain(pending)
-            pending = dispatched
-            if step_tl is not None:
-                # a pass that drained, dispatched, or admitted is a real
-                # step; a bare poll (no slots, empty queue) is not
-                if drained_key is not None or dispatched is not None or rows:
-                    self._ktl = None
-                    self.kprobe.complete_step(
-                        step_tl, tokens=n_drained, cost_key=drained_key
-                    )
-                else:
-                    self._abandon_kstep()
-            if pending is None:
-                if not any(t is not None for t in self._slot_task):
-                    self._wakeup.wait(timeout=0.05)
-                    self._wakeup.clear()
+            if not (
+                pending is not None
+                or self._backlog
+                or not self._queue.empty()
+                or any(t is not None for t in self._slot_task)
+            ):
+                # idle poll: nothing in flight, queued or decoding. Abandoned
+                # before it runs, so it leaves no span and no step record
+                self._abandon_kstep()
+                step_tl = None
+            with (
+                perf_tracer.trace_scope("areal.decode.pass")
+                if step_tl is not None
+                else contextlib.nullcontext()
+            ) as span:
+                pending, idle = self._run_pass(pending, step_tl, span)
+            if idle:
+                self._wakeup.wait(timeout=0.05)
+                self._wakeup.clear()
         self._ktl = None
         self._drain(pending)
         self._abort_all()

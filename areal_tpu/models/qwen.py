@@ -42,6 +42,13 @@ from jax.sharding import get_abstract_mesh
 BATCH_AXES = ("data", "fsdp")
 
 
+# ``jax.named_scope`` names every forward below gives its ops, so that a
+# device trace reads in the model's own terms (docs/observability.md "Spans
+# and scopes"). Norms go with the block they feed. The engines add
+# ``sampler`` (decode chunk), ``loss`` and ``optimizer`` (train step).
+SCOPES = ("embed", "attn_proj", "kv_write", "attn", "mlp", "lm_head")
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     vocab_size: int = 151936
@@ -595,72 +602,76 @@ def _decoder_layer(cfg: ModelConfig, x, layer, mask, positions, impl=None):
     G, L, D = x.shape
     H, KH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
 
-    h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
-    q = _proj(cfg, layer, "wq", h)
-    k = _proj(cfg, layer, "wk", h)
-    v = _proj(cfg, layer, "wv", h)
-    if cfg.attention_bias:
-        q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
-    q = q.reshape(G, L, H, hd)
-    k = k.reshape(G, L, KH, hd)
-    v = v.reshape(G, L, KH, hd)
-    if cfg.qk_norm:
-        q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
-        k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
-    if KH != H:
-        k = jnp.repeat(k, H // KH, axis=2)
-        v = jnp.repeat(v, H // KH, axis=2)
-    if impl is None:
-        from areal_tpu.ops.attention import resolve_impl
+    with jax.named_scope("attn_proj"):
+        h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+        q = _proj(cfg, layer, "wq", h)
+        k = _proj(cfg, layer, "wk", h)
+        v = _proj(cfg, layer, "wv", h)
+        if cfg.attention_bias:
+            q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
+        q = q.reshape(G, L, H, hd)
+        k = k.reshape(G, L, KH, hd)
+        v = v.reshape(G, L, KH, hd)
+        if cfg.qk_norm:
+            q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
+            k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attn"):
+        if KH != H:
+            k = jnp.repeat(k, H // KH, axis=2)
+            v = jnp.repeat(v, H // KH, axis=2)
+        if impl is None:
+            from areal_tpu.ops.attention import resolve_impl
 
-        impl = resolve_impl(cfg.attn_impl, L, hd)
-    if impl == "ring":
-        # context parallelism: q/k/v stay seq-sharded; K/V rotate the ring
-        # (parallel/ring_attention.py). mask here is (segment_ids, col_index).
-        from areal_tpu.parallel.ring_attention import ring_attention
+            impl = resolve_impl(cfg.attn_impl, L, hd)
+        if impl == "ring":
+            # context parallelism: q/k/v stay seq-sharded; K/V rotate the ring
+            # (parallel/ring_attention.py). mask here is (segment_ids, col_index).
+            from areal_tpu.parallel.ring_attention import ring_attention
 
-        seg, col = mask
-        q = _shard(q, P(BATCH_AXES, "seq", "model", None))
-        k = _shard(k, P(BATCH_AXES, "seq", "model", None))
-        v = _shard(v, P(BATCH_AXES, "seq", "model", None))
-        attn = ring_attention(q, k, v, seg, col)
-    else:
-        # Ulysses region (reference models/fsdp/ulysses.py:44-202): outside
-        # attention, activations are seq-sharded; inside, heads are sharded
-        # over model×seq and the sequence is whole. GSPMD lowers the
-        # [L/sp, H] -> [L, H/sp] reshard to the head<->seq all-to-all — the
-        # a2a moves 1/sp of the activation vs. a full all-gather. kv heads
-        # were already replicated to H above (the GQA sp>kv_heads case,
-        # ulyssess_patch.py:43-47).
-        q = _shard(q, P(BATCH_AXES, None, ("model", "seq"), None))
-        k = _shard(k, P(BATCH_AXES, None, ("model", "seq"), None))
-        v = _shard(v, P(BATCH_AXES, None, ("model", "seq"), None))
-        if impl == "pallas":
-            from areal_tpu.ops.attention import flash_train
-
-            attn = flash_train(q, k, v, mask)  # mask is segment_ids here
-        elif impl == "pallas_fwd":
-            # leaner forward-only kernel (no VJP residuals) for the no-grad
-            # hot paths: logprob recompute, ref/prox forward, eval
-            from areal_tpu.ops.attention import flash_fwd_pallas
-
-            attn = flash_fwd_pallas(q, k, v, mask)  # mask is segment_ids
+            seg, col = mask
+            q = _shard(q, P(BATCH_AXES, "seq", "model", None))
+            k = _shard(k, P(BATCH_AXES, "seq", "model", None))
+            v = _shard(v, P(BATCH_AXES, "seq", "model", None))
+            attn = ring_attention(q, k, v, seg, col)
         else:
-            attn = _sdpa(q, k, v, mask, hd)
-    attn = attn.reshape(G, L, H * hd)
-    x = x + _shard(_proj(cfg, layer, "wo", attn), P(BATCH_AXES, "seq", None))
+            # Ulysses region (reference models/fsdp/ulysses.py:44-202): outside
+            # attention, activations are seq-sharded; inside, heads are sharded
+            # over model×seq and the sequence is whole. GSPMD lowers the
+            # [L/sp, H] -> [L, H/sp] reshard to the head<->seq all-to-all — the
+            # a2a moves 1/sp of the activation vs. a full all-gather. kv heads
+            # were already replicated to H above (the GQA sp>kv_heads case,
+            # ulyssess_patch.py:43-47).
+            q = _shard(q, P(BATCH_AXES, None, ("model", "seq"), None))
+            k = _shard(k, P(BATCH_AXES, None, ("model", "seq"), None))
+            v = _shard(v, P(BATCH_AXES, None, ("model", "seq"), None))
+            if impl == "pallas":
+                from areal_tpu.ops.attention import flash_train
 
-    h = _rms_norm(x, layer["post_attn_norm"], cfg.rms_norm_eps)
-    if cfg.num_experts > 0:
-        from areal_tpu.models.moe import moe_ffn
+                attn = flash_train(q, k, v, mask)  # mask is segment_ids here
+            elif impl == "pallas_fwd":
+                # leaner forward-only kernel (no VJP residuals) for the no-grad
+                # hot paths: logprob recompute, ref/prox forward, eval
+                from areal_tpu.ops.attention import flash_fwd_pallas
 
-        ff_out, aux = moe_ffn(h, layer, cfg)
-        return x + ff_out, aux
-    ff = jax.nn.silu(_proj(cfg, layer, "w_gate", h)) * _proj(cfg, layer, "w_up", h)
-    x = x + _shard(_proj(cfg, layer, "w_down", ff), P(BATCH_AXES, "seq", None))
-    return x, jnp.float32(0.0)
+                attn = flash_fwd_pallas(q, k, v, mask)  # mask is segment_ids
+            else:
+                attn = _sdpa(q, k, v, mask, hd)
+    with jax.named_scope("attn_proj"):
+        attn = attn.reshape(G, L, H * hd)
+        x = x + _shard(_proj(cfg, layer, "wo", attn), P(BATCH_AXES, "seq", None))
+
+    with jax.named_scope("mlp"):
+        h = _rms_norm(x, layer["post_attn_norm"], cfg.rms_norm_eps)
+        if cfg.num_experts > 0:
+            from areal_tpu.models.moe import moe_ffn
+
+            ff_out, aux = moe_ffn(h, layer, cfg)
+            return x + ff_out, aux
+        ff = jax.nn.silu(_proj(cfg, layer, "w_gate", h)) * _proj(cfg, layer, "w_up", h)
+        x = x + _shard(_proj(cfg, layer, "w_down", ff), P(BATCH_AXES, "seq", None))
+        return x, jnp.float32(0.0)
 
 
 def _shard(x: jax.Array, spec: P) -> jax.Array:
@@ -685,12 +696,13 @@ def forward(
     image_embeds: jax.Array | None = None,  # [G, L, D] precomputed vision embeds
 ) -> jax.Array:
     """Decoder body -> final hidden states [G, L, D] (+ aux when asked)."""
-    x = _embed_lookup(params["embed"], input_ids, cfg.jax_dtype)
-    if image_embeds is not None and cfg.image_token_id >= 0:
-        # VLM: <|image_pad|> positions take the vision tower's output
-        # (precomputed and positioned by the caller; models/vision.py)
-        img_pos = (input_ids == cfg.image_token_id)[..., None]
-        x = jnp.where(img_pos, image_embeds.astype(cfg.jax_dtype), x)
+    with jax.named_scope("embed"):
+        x = _embed_lookup(params["embed"], input_ids, cfg.jax_dtype)
+        if image_embeds is not None and cfg.image_token_id >= 0:
+            # VLM: <|image_pad|> positions take the vision tower's output
+            # (precomputed and positioned by the caller; models/vision.py)
+            img_pos = (input_ids == cfg.image_token_id)[..., None]
+            x = jnp.where(img_pos, image_embeds.astype(cfg.jax_dtype), x)
     x = _shard(x, P(BATCH_AXES, "seq", None))
     from areal_tpu.ops.attention import resolve_impl
 
@@ -731,7 +743,8 @@ def forward(
         return x, aux
 
     x, aux = jax.lax.scan(body, x, params["layers"])
-    hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("lm_head"):
+        hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     if with_aux:
         return hidden, aux.sum()
     return hidden
@@ -900,41 +913,47 @@ def forward_prefill(
         seg = jnp.ones_like(input_ids)
     # serving prefill runs replicated over any spare mesh axes (the decode
     # engine's data axis absorbs leftover devices) — ids are not sharded
-    x = _embed_lookup(params["embed"], input_ids, cfg.jax_dtype, batch_sharded=False)
-    if image_embeds is not None and cfg.image_token_id >= 0:
-        img_pos = (input_ids == cfg.image_token_id)[..., None]
-        x = jnp.where(img_pos, image_embeds.astype(cfg.jax_dtype), x)
+    with jax.named_scope("embed"):
+        x = _embed_lookup(params["embed"], input_ids, cfg.jax_dtype, batch_sharded=False)
+        if image_embeds is not None and cfg.image_token_id >= 0:
+            img_pos = (input_ids == cfg.image_token_id)[..., None]
+            x = jnp.where(img_pos, image_embeds.astype(cfg.jax_dtype), x)
     mask = _attention_mask(seg)
     H, KH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
 
     def body(x, layer):
         G, L, D = x.shape
-        h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
-        q = _proj(cfg, layer, "wq", h)
-        k = _proj(cfg, layer, "wk", h)
-        v = _proj(cfg, layer, "wv", h)
-        if cfg.attention_bias:
-            q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
-        q = q.reshape(G, L, H, hd)
-        k = k.reshape(G, L, KH, hd)
-        v = v.reshape(G, L, KH, hd)
-        if cfg.qk_norm:
-            q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
-            k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("attn_proj"):
+            h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+            q = _proj(cfg, layer, "wq", h)
+            k = _proj(cfg, layer, "wk", h)
+            v = _proj(cfg, layer, "wv", h)
+            if cfg.attention_bias:
+                q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
+            q = q.reshape(G, L, H, hd)
+            k = k.reshape(G, L, KH, hd)
+            v = v.reshape(G, L, KH, hd)
+            if cfg.qk_norm:
+                q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
+                k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
         k_cache, v_cache = k, v
-        if KH != H:
-            k = jnp.repeat(k, H // KH, axis=2)
-            v = jnp.repeat(v, H // KH, axis=2)
-        attn = _sdpa(q, k, v, mask, hd).reshape(G, L, H * hd)
-        x = x + _proj(cfg, layer, "wo", attn)
-        h = _rms_norm(x, layer["post_attn_norm"], cfg.rms_norm_eps)
-        x = x + _ffn(cfg, h, layer)
+        with jax.named_scope("attn"):
+            if KH != H:
+                k = jnp.repeat(k, H // KH, axis=2)
+                v = jnp.repeat(v, H // KH, axis=2)
+            attn = _sdpa(q, k, v, mask, hd).reshape(G, L, H * hd)
+        with jax.named_scope("attn_proj"):
+            x = x + _proj(cfg, layer, "wo", attn)
+        with jax.named_scope("mlp"):
+            h = _rms_norm(x, layer["post_attn_norm"], cfg.rms_norm_eps)
+            x = x + _ffn(cfg, h, layer)
         return x, (k_cache, v_cache)
 
     x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
-    hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("lm_head"):
+        hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     return hidden, ks, vs
 
 
@@ -977,7 +996,8 @@ def forward_prefill_paged(
     never materializes; padded rows output zeros instead of the dense
     path's discarded garbage (their KV lands in trash page 0 either way).
     """
-    x = _embed_lookup(params["embed"], input_ids, cfg.jax_dtype, batch_sharded=False)
+    with jax.named_scope("embed"):
+        x = _embed_lookup(params["embed"], input_ids, cfg.jax_dtype, batch_sharded=False)
     suf_mask = _attention_mask(seg)  # [A, 1, B, B] causal-within-suffix
     H, KH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     G = H // KH
@@ -998,79 +1018,87 @@ def forward_prefill_paged(
 
     def body(x, scanned):
         layer, li = scanned
-        h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
-        q = _proj(cfg, layer, "wq", h)
-        k = _proj(cfg, layer, "wk", h)
-        v = _proj(cfg, layer, "wv", h)
-        if cfg.attention_bias:
-            q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
-        q = q.reshape(A, B, H, hd)
-        k = k.reshape(A, B, KH, hd)
-        v = v.reshape(A, B, KH, hd)
-        if cfg.qk_norm:
-            q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
-            k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("attn_proj"):
+            h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+            q = _proj(cfg, layer, "wq", h)
+            k = _proj(cfg, layer, "wk", h)
+            v = _proj(cfg, layer, "wv", h)
+            if cfg.attention_bias:
+                q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
+            q = q.reshape(A, B, H, hd)
+            k = k.reshape(A, B, KH, hd)
+            v = v.reshape(A, B, KH, hd)
+            if cfg.qk_norm:
+                q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
+                k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
         k_cache, v_cache = k, v
         if use_kernel:
-            # Pallas chain-mask launch: the prefix streams through VMEM
-            # (double-buffered page DMA + online softmax), quantized pages
-            # dequantize in-kernel with narrow scales
-            from areal_tpu.ops.paged_suffix_attention import (
-                paged_suffix_attention,
-            )
+            with jax.named_scope("attn"):
+                # Pallas chain-mask launch: the prefix streams through VMEM
+                # (double-buffered page DMA + online softmax), quantized pages
+                # dequantize in-kernel with narrow scales
+                from areal_tpu.ops.paged_suffix_attention import (
+                    paged_suffix_attention,
+                )
 
-            attn = paged_suffix_attention(
-                q,
-                k,
-                v,
-                cache["k"],
-                cache["v"],
-                li,
-                prefix_lens,
-                page_table,
-                suf_mask[:, 0],  # [A, B, B] causal & row/col validity
-                k_scales=cache.get("k_scale"),
-                v_scales=cache.get("v_scale"),
-            ).reshape(A, B, H * hd)
-            x = x + _proj(cfg, layer, "wo", attn.astype(x.dtype))
+                attn = paged_suffix_attention(
+                    q,
+                    k,
+                    v,
+                    cache["k"],
+                    cache["v"],
+                    li,
+                    prefix_lens,
+                    page_table,
+                    suf_mask[:, 0],  # [A, B, B] causal & row/col validity
+                    k_scales=cache.get("k_scale"),
+                    v_scales=cache.get("v_scale"),
+                ).reshape(A, B, H * hd)
+            with jax.named_scope("attn_proj"):
+                x = x + _proj(cfg, layer, "wo", attn.astype(x.dtype))
+            with jax.named_scope("mlp"):
+                h = _rms_norm(x, layer["post_attn_norm"], cfg.rms_norm_eps)
+                x = x + _ffn(cfg, h, layer)
+            return x, (k_cache, v_cache)
+        with jax.named_scope("attn"):
+            kp = gather("k", li)  # [A, W, KH, hd]
+            vp = gather("v", li)
+            if kv_quant:
+                from areal_tpu.inference.paged_kv import dequantize_kv
+
+                kp = dequantize_kv(kp, gather("k_scale", li), q.dtype)
+                vp = dequantize_kv(vp, gather("v_scale", li), q.dtype)
+            # GQA repeat + concat(prefix, suffix) along the KV length, then the
+            # same batched-matmul einsum layout as sdpa_xla — grouped 5D
+            # einsums with split batch axes lower an order of magnitude slower
+            if KH != H:
+                kp = jnp.repeat(kp, G, axis=2)
+                vp = jnp.repeat(vp, G, axis=2)
+                k_r = jnp.repeat(k, G, axis=2)
+                v_r = jnp.repeat(v, G, axis=2)
+            else:
+                k_r, v_r = k, v
+            k_full = jnp.concatenate([kp, k_r], axis=1)  # [A, W + B, H, hd]
+            v_full = jnp.concatenate([vp, v_r], axis=1)
+            mask = jnp.concatenate(
+                [pre_valid[:, None], suf_mask], axis=-1
+            )  # [A, 1, B, W + B]
+            attn = _sdpa(q, k_full, v_full, mask, hd).reshape(A, B, H * hd)
+        with jax.named_scope("attn_proj"):
+            x = x + _proj(cfg, layer, "wo", attn)
+        with jax.named_scope("mlp"):
             h = _rms_norm(x, layer["post_attn_norm"], cfg.rms_norm_eps)
             x = x + _ffn(cfg, h, layer)
-            return x, (k_cache, v_cache)
-        kp = gather("k", li)  # [A, W, KH, hd]
-        vp = gather("v", li)
-        if kv_quant:
-            from areal_tpu.inference.paged_kv import dequantize_kv
-
-            kp = dequantize_kv(kp, gather("k_scale", li), q.dtype)
-            vp = dequantize_kv(vp, gather("v_scale", li), q.dtype)
-        # GQA repeat + concat(prefix, suffix) along the KV length, then the
-        # same batched-matmul einsum layout as sdpa_xla — grouped 5D
-        # einsums with split batch axes lower an order of magnitude slower
-        if KH != H:
-            kp = jnp.repeat(kp, G, axis=2)
-            vp = jnp.repeat(vp, G, axis=2)
-            k_r = jnp.repeat(k, G, axis=2)
-            v_r = jnp.repeat(v, G, axis=2)
-        else:
-            k_r, v_r = k, v
-        k_full = jnp.concatenate([kp, k_r], axis=1)  # [A, W + B, H, hd]
-        v_full = jnp.concatenate([vp, v_r], axis=1)
-        mask = jnp.concatenate(
-            [pre_valid[:, None], suf_mask], axis=-1
-        )  # [A, 1, B, W + B]
-        attn = _sdpa(q, k_full, v_full, mask, hd).reshape(A, B, H * hd)
-        x = x + _proj(cfg, layer, "wo", attn)
-        h = _rms_norm(x, layer["post_attn_norm"], cfg.rms_norm_eps)
-        x = x + _ffn(cfg, h, layer)
         return x, (k_cache, v_cache)
 
     n_layers = cfg.num_layers
     x, (ks, vs) = jax.lax.scan(
         body, x, (params["layers"], jnp.arange(n_layers))
     )
-    hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("lm_head"):
+        hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     return hidden, ks, vs
 
 
@@ -1104,7 +1132,8 @@ def forward_verify_paged(
     diagonal row-validity rule admits every row to the committed prefix,
     matching this function's broadcast ``pre_valid`` exactly.
     """
-    x = _embed_lookup(params["embed"], input_ids, cfg.jax_dtype, batch_sharded=False)
+    with jax.named_scope("embed"):
+        x = _embed_lookup(params["embed"], input_ids, cfg.jax_dtype, batch_sharded=False)
     H, KH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     G = H // KH
     S, B = input_ids.shape
@@ -1125,70 +1154,78 @@ def forward_verify_paged(
 
     def body(x, scanned):
         layer, li = scanned
-        h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
-        q = _proj(cfg, layer, "wq", h)
-        k = _proj(cfg, layer, "wk", h)
-        v = _proj(cfg, layer, "wv", h)
-        if cfg.attention_bias:
-            q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
-        q = q.reshape(S, B, H, hd)
-        k = k.reshape(S, B, KH, hd)
-        v = v.reshape(S, B, KH, hd)
-        if cfg.qk_norm:
-            q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
-            k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("attn_proj"):
+            h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+            q = _proj(cfg, layer, "wq", h)
+            k = _proj(cfg, layer, "wk", h)
+            v = _proj(cfg, layer, "wv", h)
+            if cfg.attention_bias:
+                q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
+            q = q.reshape(S, B, H, hd)
+            k = k.reshape(S, B, KH, hd)
+            v = v.reshape(S, B, KH, hd)
+            if cfg.qk_norm:
+                q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
+                k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
         k_cache, v_cache = k, v
         if use_kernel:
-            from areal_tpu.ops.paged_suffix_attention import (
-                paged_suffix_attention,
-            )
+            with jax.named_scope("attn"):
+                from areal_tpu.ops.paged_suffix_attention import (
+                    paged_suffix_attention,
+                )
 
-            attn = paged_suffix_attention(
-                q,
-                k,
-                v,
-                cache["k"],
-                cache["v"],
-                li,
-                prefix_lens,
-                page_table,
-                tree_mask,  # [S, B, B] ancestor-or-self
-                k_scales=cache.get("k_scale"),
-                v_scales=cache.get("v_scale"),
-            ).reshape(S, B, H * hd)
-            x = x + _proj(cfg, layer, "wo", attn.astype(x.dtype))
+                attn = paged_suffix_attention(
+                    q,
+                    k,
+                    v,
+                    cache["k"],
+                    cache["v"],
+                    li,
+                    prefix_lens,
+                    page_table,
+                    tree_mask,  # [S, B, B] ancestor-or-self
+                    k_scales=cache.get("k_scale"),
+                    v_scales=cache.get("v_scale"),
+                ).reshape(S, B, H * hd)
+            with jax.named_scope("attn_proj"):
+                x = x + _proj(cfg, layer, "wo", attn.astype(x.dtype))
+            with jax.named_scope("mlp"):
+                h = _rms_norm(x, layer["post_attn_norm"], cfg.rms_norm_eps)
+                x = x + _ffn(cfg, h, layer)
+            return x, (k_cache, v_cache)
+        with jax.named_scope("attn"):
+            kp = gather("k", li)  # [S, W, KH, hd]
+            vp = gather("v", li)
+            if kv_quant:
+                from areal_tpu.inference.paged_kv import dequantize_kv
+
+                kp = dequantize_kv(kp, gather("k_scale", li), q.dtype)
+                vp = dequantize_kv(vp, gather("v_scale", li), q.dtype)
+            if KH != H:
+                kp = jnp.repeat(kp, G, axis=2)
+                vp = jnp.repeat(vp, G, axis=2)
+                k_r = jnp.repeat(k, G, axis=2)
+                v_r = jnp.repeat(v, G, axis=2)
+            else:
+                k_r, v_r = k, v
+            k_full = jnp.concatenate([kp, k_r], axis=1)  # [S, W + B, H, hd]
+            v_full = jnp.concatenate([vp, v_r], axis=1)
+            mask = jnp.concatenate([pre_valid, suf_mask], axis=-1)  # [S,1,B,W+B]
+            attn = _sdpa(q, k_full, v_full, mask, hd).reshape(S, B, H * hd)
+        with jax.named_scope("attn_proj"):
+            x = x + _proj(cfg, layer, "wo", attn)
+        with jax.named_scope("mlp"):
             h = _rms_norm(x, layer["post_attn_norm"], cfg.rms_norm_eps)
             x = x + _ffn(cfg, h, layer)
-            return x, (k_cache, v_cache)
-        kp = gather("k", li)  # [S, W, KH, hd]
-        vp = gather("v", li)
-        if kv_quant:
-            from areal_tpu.inference.paged_kv import dequantize_kv
-
-            kp = dequantize_kv(kp, gather("k_scale", li), q.dtype)
-            vp = dequantize_kv(vp, gather("v_scale", li), q.dtype)
-        if KH != H:
-            kp = jnp.repeat(kp, G, axis=2)
-            vp = jnp.repeat(vp, G, axis=2)
-            k_r = jnp.repeat(k, G, axis=2)
-            v_r = jnp.repeat(v, G, axis=2)
-        else:
-            k_r, v_r = k, v
-        k_full = jnp.concatenate([kp, k_r], axis=1)  # [S, W + B, H, hd]
-        v_full = jnp.concatenate([vp, v_r], axis=1)
-        mask = jnp.concatenate([pre_valid, suf_mask], axis=-1)  # [S,1,B,W+B]
-        attn = _sdpa(q, k_full, v_full, mask, hd).reshape(S, B, H * hd)
-        x = x + _proj(cfg, layer, "wo", attn)
-        h = _rms_norm(x, layer["post_attn_norm"], cfg.rms_norm_eps)
-        x = x + _ffn(cfg, h, layer)
         return x, (k_cache, v_cache)
 
     x, (ks, vs) = jax.lax.scan(
         body, x, (params["layers"], jnp.arange(cfg.num_layers))
     )
-    hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("lm_head"):
+        hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     return hidden, ks, vs
 
 
@@ -1217,7 +1254,8 @@ def forward_decode_paged(
 
     S = ids.shape[0]
     H, KH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
-    x = _embed_lookup(params["embed"], ids, cfg.jax_dtype)  # [S, D]
+    with jax.named_scope("embed"):
+        x = _embed_lookup(params["embed"], ids, cfg.jax_dtype)  # [S, D]
     pos1 = positions[:, None]
     lengths = (positions + 1).astype(jnp.int32)
     slot = jnp.arange(S)
@@ -1228,81 +1266,86 @@ def forward_decode_paged(
     def body(carry, scanned):
         x, c = carry
         layer, li = scanned
-        h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
-        q = _proj(cfg, layer, "wq", h)
-        k = _proj(cfg, layer, "wk", h)
-        v = _proj(cfg, layer, "wv", h)
-        if cfg.attention_bias:
-            q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
-        q = q.reshape(S, 1, H, hd)
-        k = k.reshape(S, 1, KH, hd)
-        v = v.reshape(S, 1, KH, hd)
-        if cfg.qk_norm:
-            q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
-            k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
-        q = _rope(q, pos1, cfg.rope_theta)[:, 0]  # [S, H, hd]
-        k = _rope(k, pos1, cfg.rope_theta)[:, 0]  # [S, KH, hd]
-        v = v[:, 0]
-        # write the step's rows into (li, h, page[s], offset[s]), ONE
-        # SCATTER PER KV HEAD. A single scatter over all heads has (KH, hd)
-        # update windows, for which the TPU compiler lays the whole carried
-        # cache out KH-minor — and the Pallas kernel below needs the default
-        # layout, so it would re-lay the ENTIRE cache out twice per layer
-        # per step (compiled for a described v5e: a cache-sized temp and two
-        # cache-sized copies in the layer loop; per head: none).
-        c = dict(c)
-        if kv_quant:
-            kq, ksc = paged_kv.quantize_kv(k, dtype=cache["k"].dtype)
-            vq, vsc = paged_kv.quantize_kv(v, dtype=cache["v"].dtype)
-            # scales are lane-major in the pool: [L, KH, N, 1, psz]
-            for name, sc in (("k_scale", ksc), ("v_scale", vsc)):
+        with jax.named_scope("attn_proj"):
+            h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+            q = _proj(cfg, layer, "wq", h)
+            k = _proj(cfg, layer, "wk", h)
+            v = _proj(cfg, layer, "wv", h)
+            if cfg.attention_bias:
+                q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
+            q = q.reshape(S, 1, H, hd)
+            k = k.reshape(S, 1, KH, hd)
+            v = v.reshape(S, 1, KH, hd)
+            if cfg.qk_norm:
+                q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
+                k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
+            q = _rope(q, pos1, cfg.rope_theta)[:, 0]  # [S, H, hd]
+            k = _rope(k, pos1, cfg.rope_theta)[:, 0]  # [S, KH, hd]
+            v = v[:, 0]
+        with jax.named_scope("kv_write"):
+            # write the step's rows into (li, h, page[s], offset[s]), ONE
+            # SCATTER PER KV HEAD. A single scatter over all heads has (KH, hd)
+            # update windows, for which the TPU compiler lays the whole carried
+            # cache out KH-minor — and the Pallas kernel below needs the default
+            # layout, so it would re-lay the ENTIRE cache out twice per layer
+            # per step (compiled for a described v5e: a cache-sized temp and two
+            # cache-sized copies in the layer loop; per head: none).
+            c = dict(c)
+            if kv_quant:
+                kq, ksc = paged_kv.quantize_kv(k, dtype=cache["k"].dtype)
+                vq, vsc = paged_kv.quantize_kv(v, dtype=cache["v"].dtype)
+                # scales are lane-major in the pool: [L, KH, N, 1, psz]
+                for name, sc in (("k_scale", ksc), ("v_scale", vsc)):
+                    for h in range(KH):
+                        c[name] = c[name].at[li, h, write_page, 0, write_off].set(
+                            sc[:, h, 0]
+                        )
+                k, v = kq, vq
+            for name, val in (("k", k), ("v", v)):
                 for h in range(KH):
-                    c[name] = c[name].at[li, h, write_page, 0, write_off].set(
-                        sc[:, h, 0]
+                    c[name] = c[name].at[li, h, write_page, write_off].set(
+                        val[:, h].astype(c[name].dtype)
                     )
-            k, v = kq, vq
-        for name, val in (("k", k), ("v", v)):
-            for h in range(KH):
-                c[name] = c[name].at[li, h, write_page, write_off].set(
-                    val[:, h].astype(c[name].dtype)
-                )
-        if use_kernel:
-            # STACKED launch: the kernel slices ref.at[li] internally. A
-            # dynamic_index_in_dim layer slice here would force XLA to
-            # materialize a copy of every layer's pages every step (a
-            # pallas operand must be a real buffer) — measured as
-            # full-cache r/w traffic per decode step (docstring of
-            # ops/paged_attention_q8.py)
-            from areal_tpu.ops.paged_attention_q8 import paged_attention_stacked
+        with jax.named_scope("attn"):
+            if use_kernel:
+                # STACKED launch: the kernel slices ref.at[li] internally. A
+                # dynamic_index_in_dim layer slice here would force XLA to
+                # materialize a copy of every layer's pages every step (a
+                # pallas operand must be a real buffer) — measured as
+                # full-cache r/w traffic per decode step (docstring of
+                # ops/paged_attention_q8.py)
+                from areal_tpu.ops.paged_attention_q8 import paged_attention_stacked
 
-            attn = paged_attention_stacked(
-                q,
-                c["k"],
-                c["v"],
-                li,
-                lengths,
-                page_table,
-                pages_per_compute_block=paged_kv.choose_ppcb(page_table.shape[1]),
-                k_scales=c.get("k_scale"),
-                v_scales=c.get("v_scale"),
-            )
-        else:
-            sl = {
-                name: jax.lax.dynamic_index_in_dim(c[name], li, 0, keepdims=False)
-                for name in c
-            }
-            scales = (
-                dict(k_scales=sl["k_scale"], v_scales=sl["v_scale"])
-                if kv_quant
-                else {}
-            )
-            attn = paged_kv.paged_attention_xla(
-                q, sl["k"], sl["v"], lengths, page_table, **scales
-            )
-        attn = attn.reshape(S, H * hd).astype(x.dtype)
-        x = x + _proj(cfg, layer, "wo", attn)
-        h = _rms_norm(x, layer["post_attn_norm"], cfg.rms_norm_eps)
-        x = x + _ffn(cfg, h, layer)
+                attn = paged_attention_stacked(
+                    q,
+                    c["k"],
+                    c["v"],
+                    li,
+                    lengths,
+                    page_table,
+                    pages_per_compute_block=paged_kv.choose_ppcb(page_table.shape[1]),
+                    k_scales=c.get("k_scale"),
+                    v_scales=c.get("v_scale"),
+                )
+            else:
+                sl = {
+                    name: jax.lax.dynamic_index_in_dim(c[name], li, 0, keepdims=False)
+                    for name in c
+                }
+                scales = (
+                    dict(k_scales=sl["k_scale"], v_scales=sl["v_scale"])
+                    if kv_quant
+                    else {}
+                )
+                attn = paged_kv.paged_attention_xla(
+                    q, sl["k"], sl["v"], lengths, page_table, **scales
+                )
+            attn = attn.reshape(S, H * hd).astype(x.dtype)
+        with jax.named_scope("attn_proj"):
+            x = x + _proj(cfg, layer, "wo", attn)
+        with jax.named_scope("mlp"):
+            h = _rms_norm(x, layer["post_attn_norm"], cfg.rms_norm_eps)
+            x = x + _ffn(cfg, h, layer)
         return (x, c), None
 
     (x, out_cache), _ = jax.lax.scan(
@@ -1310,7 +1353,8 @@ def forward_decode_paged(
         (x, dict(cache)),
         (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)),
     )
-    hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("lm_head"):
+        hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     return hidden, out_cache
 
 

@@ -250,31 +250,6 @@ def prefill_costs(mcfg, n_tokens: float) -> dict[str, float]:
     return {"flops": flops, "bytes": nbytes, "tokens": T}
 
 
-def decode_device_attribution(mcfg, ctx_len: float = 512.0) -> dict[str, float]:
-    """FLOP-share split of the fused decode chunk's device window into the
-    phases the host cannot time without a sync: page gather (KV reads —
-    bandwidth work, reported as its byte share of a step), attention+MLP
-    forward, and sampling (logits softmax/top-k — vocab-sized). Shares sum
-    to 1.0; they attribute the measured ``dispatch``+``device_wait``
-    window analytically (docs/perf.md)."""
-    pc = transformer_param_counts(mcfg)
-    L = mcfg.num_layers
-    q_dim = mcfg.num_heads * mcfg.head_dim_
-    attn = 4.0 * L * float(ctx_len) * q_dim
-    forward = 2.0 * pc["matmul"] + attn
-    sampling = 6.0 * mcfg.vocab_size  # softmax + transform + select, ~O(V)
-    costs = decode_step_costs(mcfg, 1, 1, ctx_len)
-    gather_bytes = costs["bytes"] - pc["matmul"] * _param_dtype_bytes(mcfg)
-    total = forward + sampling
-    return {
-        "attention_mlp_forward": forward / total,
-        "sampling": sampling / total,
-        "page_gather_byte_share": (
-            gather_bytes / costs["bytes"] if costs["bytes"] else 0.0
-        ),
-    }
-
-
 # one-time measured host peaks per backend (CPU has no CHIP_SPECS row);
 # process-lifetime cache so repeated engine constructions don't re-pay it
 _CALIBRATED: dict[str, tuple[float, float]] = {}

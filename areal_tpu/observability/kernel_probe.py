@@ -16,14 +16,13 @@ Phase vocabulary (docs/perf.md "Kernel observatory"):
     device_wait   blocking host pull of the PREVIOUS chunk's packed output
     bookkeeping   per-token credit: stop checks, streaming, stats
 
-All six are HOST wall-clock spans on the decode thread — the loop dispatches
-chunk N and only then drains chunk N-1, so the device executes behind the
-host and the *visible* device time is exactly ``device_wait``. The device-side
-sub-phases the roofline cares about (page gather, attention+MLP forward,
-sampling) run inside one fused jitted scan and cannot be host-timed without
-adding a device sync to the hot loop (forbidden: arealint PRF); they are
-instead attributed analytically from the chunk's FLOP/byte cost — see
-``KernelProbe.stats()['device_attribution']``.
+All of them are HOST spans on the decode thread — the loop dispatches chunk
+N and only then drains chunk N-1, so the device executes behind the host and
+the *visible* device time is exactly ``device_wait``. Every phase goes through
+``perf_tracer.trace_scope`` as ``areal.decode.<phase>`` (and each productive
+pass as the parent span ``areal.decode.pass``), so a device profile shows them
+on the device trace's clock, beside the ops of the fused chunk, which carry the
+model's ``jax.named_scope`` names (docs/observability.md "Spans and scopes").
 
 Costs come from the compiled executable itself: :class:`ProbedFn` wraps each
 jitted decode/prefill function, obtains the executable via
@@ -56,6 +55,7 @@ from typing import Any, Callable, Iterator
 
 from areal_tpu.observability import catalog as obs_catalog
 from areal_tpu.observability import hw_accounting as hw
+from areal_tpu.utils import perf_tracer
 
 # canonical phase order (docs/perf.md "Kernel observatory"); breakdown()
 # also carries any ad-hoc phase a caller added, so the identity never
@@ -84,7 +84,9 @@ class DecodeStepTimeline:
     ``radix_match`` inside ``admission`` and ``prefill`` inside the admit
     path each own their own span and the named sum still can never exceed
     the wall clock. All marks are ``time.monotonic()`` reads on the decode
-    thread — no device sync, no host pulls.
+    thread — no device sync, no host pulls. Each phase is also one
+    ``areal.decode.<phase>`` span (``perf_tracer.trace_scope``); the spans
+    nest by time, an enclosing phase's span contains its inner ones.
     """
 
     __slots__ = ("started_ts", "phases", "_stack", "_t0")
@@ -108,7 +110,8 @@ class DecodeStepTimeline:
         self._stack.append(name)
         self._t0 = now
         try:
-            yield
+            with perf_tracer.trace_scope("areal.decode." + name):
+                yield
         finally:
             now = time.monotonic()
             self.add(name, now - self._t0)
@@ -450,16 +453,4 @@ class KernelProbe:
             },
             "costs": costs,
         }
-        # analytic sub-attribution of the device window: the fused chunk's
-        # page-gather / attention+MLP forward / sampling cannot be host-timed
-        # without a sync, but their FLOP/byte shares are known from the
-        # analytic model — report the shares so the ISSUE's device-side
-        # phases are visible even though only their sum is measured
-        if self.model_cfg is not None:
-            try:
-                out["device_attribution"] = hw.decode_device_attribution(
-                    self.model_cfg
-                )
-            except Exception:  # noqa: BLE001 — attribution is advisory
-                pass
         return out

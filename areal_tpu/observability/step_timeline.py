@@ -15,7 +15,8 @@ hides gaps.
 Phases (docs/observability.md "Trainer observatory"):
 
     rollout_wait       blocking in prepare_batch — THE async bubble
-    host_prep          grid packing, device puts, advantage computation
+    host_prep          grid packing, device puts, advantage computation,
+                       the step's stats after the update
     forward_backward   jitted device compute (fwd passes + fused fwd/bwd;
                        the single-microbatch fused path folds the optimizer
                        apply into this phase — see train_engine)
@@ -41,6 +42,7 @@ from collections import deque
 from typing import Any, Iterator
 
 from areal_tpu.observability import catalog as obs_catalog
+from areal_tpu.utils import perf_tracer
 
 # canonical phase order (docs/observability.md); breakdown() also carries
 # any ad-hoc phase a caller added, so the identity never silently drops one
@@ -86,11 +88,14 @@ class StepTimeline:
         self.phases[name] = self.phases.get(name, 0.0) + max(0.0, seconds)
 
     @contextlib.contextmanager
-    def phase(self, name: str) -> Iterator[None]:
+    def phase(self, name: str, category=perf_tracer.Category.COMPUTE, args: dict | None = None) -> Iterator[None]:
+        """Accumulate the enclosed span under ``name``; it is also the span
+        ``areal.train.<name>`` (``perf_tracer.trace_scope``)."""
         t0 = time.monotonic()
         self._open_depth += 1
         try:
-            yield
+            with perf_tracer.trace_scope("areal.train." + name, category, args):
+                yield
         finally:
             self._open_depth -= 1
             self.add(name, time.monotonic() - t0)
@@ -132,15 +137,16 @@ def _set_current(tl: StepTimeline | None) -> None:
 
 @contextlib.contextmanager
 def engine_phase(name: str) -> Iterator[None]:
-    """Attribute the enclosed span to the calling thread's open step
-    timeline; a no-op (zero overhead beyond one getattr) outside a step —
-    the engine is also used standalone (bench phases, tests). Inside an
+    """The span ``areal.train.<name>``, always; and, where the calling
+    thread has a step timeline open, the same seconds attributed to it (the
+    engine is also used standalone: bench cells, tests). Inside an
     explicitly-opened trainer phase (``tl.phase(...)``) the contribution
     is suppressed: that span already owns the wall time, so e.g. eval
     forwards under ``ckpt_eval`` must not ALSO land in forward_backward."""
     tl = current_step_timeline()
     if tl is None or tl._open_depth > 0:
-        yield
+        with perf_tracer.trace_scope("areal.train." + name):
+            yield
     else:
         with tl.phase(name):
             yield

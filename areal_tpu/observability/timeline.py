@@ -37,6 +37,7 @@ from typing import Any
 
 from areal_tpu.observability import catalog as obs_catalog
 from areal_tpu.utils import logging as alog
+from areal_tpu.utils import perf_tracer
 
 logger = alog.getLogger("timeline")
 
@@ -107,7 +108,28 @@ class RequestTimeline:
         if len(self.events) >= MAX_EVENTS_PER_TIMELINE and stage != TERMINAL:
             self.dropped_events += 1
             return
-        self.events.append((stage, time.monotonic(), args or None))
+        ts = time.monotonic()
+        self.events.append((stage, ts, args or None))
+        if stage in (ADMITTED, FIRST_TOKEN):
+            self._emit_stage_event(stage, ts)
+
+    def _emit_stage_event(self, stage: str, ts: float) -> None:
+        """``areal.request.admitted`` / ``areal.request.first_token``: the
+        request's waits so far as one point event on the profiler's clock
+        (``perf_tracer.instant``), from the marks already taken — where a
+        time to first token went: queue, prefill dispatch, or the wait from
+        there to the drain of the chunk that held the token."""
+        us = lambda a, b: int((b - a) * 1e6)  # noqa: E731
+        t_admit = ts if stage == ADMITTED else (self.ts_of(ADMITTED) or ts)
+        ev = {"queue_wait_us": us(self.queued_ts, t_admit)}
+        if stage == FIRST_TOKEN:
+            t_ps, t_pe = self.ts_of(PREFILL_START), self.ts_of(PREFILL_END)
+            prefilled = t_ps is not None and t_pe is not None
+            ev["prefill_us"] = us(t_ps, t_pe) if prefilled else 0
+            ev["since_prefill_end_us"] = us(t_pe if prefilled else t_admit, ts)
+            if self.task_id:
+                ev["task_id"] = self.task_id
+        perf_tracer.instant(f"areal.request.{stage}", args=ev)
 
     def ts_of(self, stage: str) -> float | None:
         """Monotonic timestamp of the FIRST occurrence of ``stage``."""

@@ -197,6 +197,7 @@ def flash_fwd_pallas(
             pltpu.VMEM((blk_q, 128), jnp.float32),
             pltpu.VMEM((blk_q, d), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(seg_q_in, seg_k_in, qt, kt, vt)
     return jnp.transpose(out, (0, 2, 1, 3))

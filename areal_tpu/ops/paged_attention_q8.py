@@ -315,6 +315,7 @@ def paged_attention_stacked(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         out_shape=jax.ShapeDtypeStruct(qg.shape, jnp.float32),
+        name="paged_decode_attn",
         interpret=interpret,
     )(
         lengths.astype(jnp.int32),

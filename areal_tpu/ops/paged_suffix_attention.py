@@ -359,6 +359,7 @@ def paged_suffix_attention(
             dimension_semantics=("arbitrary",) * 4
         ),
         out_shape=jax.ShapeDtypeStruct((S, KH, nq, rows, hd), jnp.float32),
+        name="paged_suffix_attn",
         interpret=_interp(interpret),
     )(
         prefix_lens.astype(jnp.int32),

@@ -218,6 +218,7 @@ def _fwd_pallas(q, k, v, mask_words, block_any, interpret):
             jax.ShapeDtypeStruct((H, N, d), q.dtype),
             jax.ShapeDtypeStruct((H, N, _LANES), jnp.float32),
         ],
+        name="tree_attn_fwd",
         interpret=interpret,
     )(skip, qt, kt, vt, words)
     return jnp.transpose(out, (1, 0, 2)), lse
@@ -390,6 +391,7 @@ def _tree_attn_bwd(interpret, res, dout):
             scratch_shapes=[pltpu.VMEM((BLOCK, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((H, N, d), q.dtype),
+        name="tree_attn_bwd_dq",
         interpret=interpret,
     )(*operands)
     # dK/dV: outer loop over k tiles, reduce over q tiles
@@ -410,6 +412,7 @@ def _tree_attn_bwd(interpret, res, dout):
             jax.ShapeDtypeStruct((H, N, d), k.dtype),
             jax.ShapeDtypeStruct((H, N, d), v.dtype),
         ],
+        name="tree_attn_bwd_dkv",
         interpret=interpret,
     )(*operands)
     t = lambda x: jnp.transpose(x, (1, 0, 2))

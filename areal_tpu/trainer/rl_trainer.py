@@ -421,9 +421,7 @@ class PPOTrainer:
 
             tl = self.step_recorder.start(global_step)
             try:
-                with tl.phase("rollout_wait"), perf_tracer.trace_scope(
-                    "train.rollout", Category.COMPUTE, {"global_step": global_step}
-                ):
+                with tl.phase("rollout_wait", args={"global_step": global_step}):
                     batch = self.rollout.prepare_batch(
                         self.train_dataloader,
                         workflow=workflow,
@@ -471,9 +469,7 @@ class PPOTrainer:
                     batch["ref_logp"] = self.ref.compute_logp(batch)
                 n_extra_fwd += 1
 
-            with tl.phase("host_prep"), perf_tracer.trace_scope(
-                "train.compute_advantages", Category.COMPUTE
-            ):
+            with tl.phase("host_prep"):
                 adv_batch = self.actor.compute_advantages(batch)
 
             t_train = time.monotonic()
@@ -484,9 +480,7 @@ class PPOTrainer:
             train_step_secs = time.monotonic() - t_train
 
             # §3.4 protocol: stop submissions, push weights, advance version
-            with tl.phase("weight_publish"), perf_tracer.trace_scope(
-                "train.update_weights", Category.COMM
-            ):
+            with tl.phase("weight_publish", Category.COMM):
                 self.rollout.pause()
                 t_update = time.monotonic()
                 new_version = global_step + 1
@@ -501,9 +495,7 @@ class PPOTrainer:
             self._obs.version.set(new_version)
 
             t_save = time.monotonic()
-            with tl.phase("ckpt_eval"), perf_tracer.trace_scope(
-                "train.save", Category.IO
-            ):
+            with tl.phase("ckpt_eval", Category.IO):
                 self.saver.maybe_save(
                     self.actor_engine, epoch, step, global_step, self.tokenizer
                 )

@@ -57,6 +57,11 @@ def enable_persistent_cache() -> str | None:
     # serving path replays dozens of small chunk/scatter variants whose
     # compiles sum to the cold-start cost
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # op names are part of the program: by default jax strips them from the
+    # cache key, and an executable cached before a ``jax.named_scope`` or a
+    # kernel name changed would then be served with the old names in every
+    # device trace (docs/observability.md "Spans and scopes")
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return jax.config.jax_compilation_cache_dir
 
 

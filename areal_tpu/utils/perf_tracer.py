@@ -6,9 +6,17 @@ a JSONL of rollout-session lifecycles. Cross-async propagation uses
 ContextVars, so events recorded inside workflow coroutines attach to the
 right task/session (reference :28-38).
 
+``trace_scope`` is the program's ONE span primitive (docs/observability.md
+"Spans and scopes"): every span also enters a ``jax.profiler.TraceAnnotation``
+(a TraceMe: a flag check while no profiler session runs), so a device profile
+(``start_device_profile`` / ``POST /debug/profile``) carries the program's
+spans on the same clock as the device's ops. The Chrome event is written only
+while the ``PerfTracer`` is enabled. A process that has not imported jax emits
+no annotation and is not made to import it.
+
 Surface:
     configure(cfg, rank=..., role=...)      process-level setup
-    trace_scope(name, category=..., args=)  sync context manager
+    trace_scope(name, category=..., args=)  sync context manager (``Span``)
     atrace_scope(name, ...)                 async context manager
     instant(name, ...)                      point event
     counter(name, **values)                 counter track
@@ -24,6 +32,7 @@ import contextvars
 import functools
 import json
 import os
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -110,34 +119,24 @@ class PerfTracer:
             if cap and len(self._events) > cap:
                 del self._events[: len(self._events) - cap]
 
-    @contextlib.contextmanager
-    def trace_scope(self, name: str, category=Category.COMPUTE, args: dict | None = None):
-        if not self.enabled:
-            yield
-            return
-        ev = self._base(name, "X", category)
-        if args or _task_id_var.get() or _session_id_var.get():
-            ev["args"] = {**(args or {})}
-            if _task_id_var.get():
-                ev["args"]["task_id"] = _task_id_var.get()
-            # session ids are the cross-process join key: merge_traces
-            # output correlates trainer/controller/server spans on them
-            if _session_id_var.get():
-                ev["args"]["session_id"] = _session_id_var.get()
-        t0 = self._ts_us()
-        try:
-            yield
-        finally:
-            ev["ts"] = t0
-            ev["dur"] = self._ts_us() - t0
-            self._push(ev)
+    def trace_scope(self, name: str, category=Category.COMPUTE, args: dict | None = None) -> "Span":
+        return Span(self, name, category, args)
 
     @contextlib.asynccontextmanager
     async def atrace_scope(self, name: str, category=Category.COMPUTE, args: dict | None = None):
-        with self.trace_scope(name, category, args):
+        # Chrome event only: a coroutine's span stays open across awaits and
+        # overlaps its siblings on the loop's thread, which is not what a
+        # TraceMe (one thread's nested activity) records
+        with Span(self, name, category, args, annotate=False):
             yield
 
     def instant(self, name: str, category=Category.INSTR, args: dict | None = None) -> None:
+        """Point event: a zero-length TraceMe, and a Chrome "i" event while
+        the tracer is enabled."""
+        ann = _annotation(name, args)
+        if ann is not None:
+            ann.__enter__()
+            ann.__exit__(None, None, None)
         if not self.enabled:
             return
         ev = self._base(name, "i", category)
@@ -175,6 +174,79 @@ class PerfTracer:
     def clear(self) -> None:
         with self._lock:
             self._events.clear()
+
+
+def _annotation(name: str, args: dict | None):
+    """A ``jax.profiler.TraceAnnotation`` for one span or event, or None in a
+    process that has not imported jax: a span must never be what imports it
+    (trainer classes drive remote workers and stay off jax). Values become
+    the event's stats; ``,`` and ``#`` delimit TraceMe metadata and are
+    dropped from strings."""
+    prof = sys.modules.get("jax.profiler")
+    cls = getattr(prof, "TraceAnnotation", None)
+    if cls is None:
+        return None
+    return cls(name, **_stats(args)) if args else cls(name)
+
+
+def _stats(args: dict) -> dict:
+    return {
+        k: v if isinstance(v, (bool, int, float)) else str(v).replace(",", ";").replace("#", "")
+        for k, v in args.items()
+    }
+
+
+class Span:
+    """One span of the program: name, start, end, args, and the calling
+    context's ``x-areal-trace`` ids as the shared identifier.
+
+    Entering it always enters a TraceMe (``_annotation``), so the span lands
+    in a running profiler session on the device trace's clock; the Chrome
+    "X" event is written only while the tracer is enabled. ``set`` adds args
+    that are known only at the end (a pass's credited tokens)."""
+
+    __slots__ = ("_tracer", "_ann", "_t0", "name", "category", "args")
+
+    def __init__(self, tracer: PerfTracer, name: str, category=Category.COMPUTE, args: dict | None = None, annotate: bool = True):
+        self._tracer = tracer
+        self.name = name
+        self.category = category
+        task, session = _task_id_var.get(), _session_id_var.get()
+        if task or session:
+            args = dict(args or {})
+            if task:
+                args["task_id"] = task
+            # session ids are the cross-process join key: merge_traces
+            # output correlates trainer/controller/server spans on them
+            if session:
+                args["session_id"] = session
+        self.args = args
+        self._ann = _annotation(name, args) if annotate else None
+        self._t0: float | None = None
+
+    def set(self, **args: Any) -> None:
+        self.args = {**(self.args or {}), **args}
+        if self._ann is not None:
+            self._ann.set_metadata(**_stats(args))
+
+    def __enter__(self) -> "Span":
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._tracer.enabled:
+            self._t0 = self._tracer._ts_us()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._t0 is not None:
+            tr = self._tracer
+            ev = tr._base(self.name, "X", self.category)
+            ev["ts"] = self._t0
+            ev["dur"] = tr._ts_us() - self._t0
+            if self.args:
+                ev["args"] = self.args
+            tr._push(ev)
 
 
 @dataclass

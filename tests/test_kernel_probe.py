@@ -296,7 +296,9 @@ def test_engine_phase_identity_radix_hit_and_hold_fence():
         # (what /statusz serves as the "kernels" section)
         ks = eng.kernel_stats()
         assert ks["steps"] == st["steps"]
-        assert "device_attribution" in ks
+        # the chunk's interior is measured from the device trace by scope
+        # name (benchmarks/chip), no longer guessed from an analytic model
+        assert "device_attribution" not in ks
     finally:
         eng.stop()
 

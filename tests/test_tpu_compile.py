@@ -202,6 +202,24 @@ def test_kernel_compiles_for_v5e(chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# the repo's own kernels by the ``name=`` of their ``pl.pallas_call``: what a
+# device trace shows, and what a reduction finds them by
+KERNEL_NAMES = {
+    "paged_decode_bf16": ("paged_decode_attn",),
+    "suffix_prefill_B256": ("paged_suffix_attn",),
+    "flash_fwd_pallas": ("flash_fwd",),
+    "tree_attention_bwd": ("tree_attn_fwd", "tree_attn_bwd_dq", "tree_attn_bwd_dkv"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_NAMES))
+def test_kernel_carries_its_name_on_v5e(chip, name):
+    fn, args = CASES[name]()
+    text = jax.jit(fn).lower(*args(chip)).compile().as_text()
+    for kernel in KERNEL_NAMES[name]:
+        assert kernel in text, f"{kernel} not in the compiled {name}"
+
+
 def test_static_shape_rule_matches_the_compiler(chip):
     """``paged_kernel_ok`` is what sends an engine to the gather path: it
     must say no exactly where the chip's compiler does (head_dim 64, the
