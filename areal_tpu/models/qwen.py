@@ -1262,6 +1262,20 @@ def forward_decode_paged(
     write_page = page_table[slot, positions // page_size]  # [S]
     write_off = positions % page_size  # [S]
     kv_quant = "k_scale" in cache  # int8 pages + per-vector scales
+    if use_kernel:
+        from areal_tpu.ops.paged_attention_q8 import (
+            decode_schedule,
+            paged_attention_stacked,
+        )
+
+        # a slot whose request has ended keeps its last position, and the
+        # engine points its whole table row at the pool's trash page 0 (never
+        # allocated): it has nothing to attend over, and its row of the
+        # output is not read. The kernel's work list is the same for every
+        # layer, so it is made here, once a step.
+        attn_lengths = jnp.where(page_table[:, 0] == 0, 0, lengths)
+        ppcb = paged_kv.choose_ppcb(page_table.shape[1])
+        schedule = decode_schedule(attn_lengths, page_table.shape[1], page_size, ppcb)
 
     def body(carry, scanned):
         x, c = carry
@@ -1314,16 +1328,15 @@ def forward_decode_paged(
                 # pallas operand must be a real buffer) — measured as
                 # full-cache r/w traffic per decode step (docstring of
                 # ops/paged_attention_q8.py)
-                from areal_tpu.ops.paged_attention_q8 import paged_attention_stacked
-
                 attn = paged_attention_stacked(
                     q,
                     c["k"],
                     c["v"],
                     li,
-                    lengths,
+                    attn_lengths,
                     page_table,
-                    pages_per_compute_block=paged_kv.choose_ppcb(page_table.shape[1]),
+                    pages_per_compute_block=ppcb,
+                    schedule=schedule,
                     k_scales=c.get("k_scale"),
                     v_scales=c.get("v_scale"),
                 )

@@ -9,14 +9,32 @@ layers scan makes XLA materialize a copy of every layer's pages every
 step: full-cache read+write traffic per decode step. In-kernel slicing
 DMAs only the pages attention actually reads.
 
-The algorithm is the one jax's library kernel uses
-(jax.experimental.pallas.ops.tpu.paged_attention, Apache-2.0): a grid over
-(slot, kv_head), the sequence walked inline in blocks of
-``pages_per_compute_block`` pages, double-buffered HBM->VMEM page DMA in
-which every block prefetches the NEXT block — across cell boundaries, so
-a cell never starts on a cold buffer — and flash-style online softmax. The
-body is written here rather than imported because the library's private
-body cannot serve quantized pages on the chip:
+The launch is one kernel invocation with no grid to walk. Its work list
+(``decode_schedule``: one item per live slot and block of
+``pages_per_compute_block`` pages that holds tokens of it, slot-major) is the
+same for every layer, so a decode step computes it once and hands it to each
+layer's launch by scalar prefetch; a slot of length 0 is in no item, costs
+nothing and returns exact zeros. An item covers EVERY KV head of its block:
+the heads' pages are fetched together into one of three VMEM buffers, and
+while item t is computed the copies of items t+1 and t+2 are in flight —
+across slot boundaries, so no item but the first starts on a cold buffer
+(measured on the v5e: the third buffer is worth 5-10%, a fourth nothing). A
+slot's last block fetches only the pages that hold tokens. Flash-style
+online softmax runs per head in f32; its state is carried through the item
+loop and reset at a slot's first block.
+
+Both matmuls take f32 operands (pages and queries upcast in VMEM; the
+softmax scale and the K scale row multiply the f32 logits). Measured on the
+v5e at both benchmark shapes (PERF.md, Findings, PR 25): bf16 operands with
+f32 accumulation — the probabilities as one bf16 term or as two stacked on
+the rows — take the same time to within 4%, because with 6-8 query rows a
+head the launch is bound by its copies and by a fixed cost an item, not by
+the array; so the kernel keeps the one form that is exact for every page
+dtype.
+
+The body is written here rather than taken from jax's library kernel
+(jax.experimental.pallas.ops.tpu.paged_attention) because that one cannot
+serve quantized pages on the chip:
 
   - scales are stored LANE-MAJOR, [n_layers, KH, N, 1, psz] (one f32 per
     token vector, the page's tokens along the lanes). A trailing-1 layout
@@ -44,6 +62,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 _MAX_INT8 = 127.5
 _MASK_VALUE = -1e30
+# blocks of pages in VMEM at once: one computed on, two in flight (measured
+# on the v5e: a third buffer is worth 5-10%, a fourth nothing)
+_NBUF = 3
 
 
 def paged_kernel_ok(head_dim: int, page_size: int, quant: bool) -> bool:
@@ -57,144 +78,163 @@ def paged_kernel_ok(head_dim: int, page_size: int, quant: bool) -> bool:
     return head_dim % 128 == 0 and page_size % (128 if quant else 8) == 0
 
 
+def decode_schedule(
+    lengths: jax.Array,  # i32 [S]
+    pages_per_sequence: int,
+    page_size: int,
+    pages_per_compute_block: int,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The launch's work list, the same for every layer of a decode step (so
+    a step computes it once and hands it to each launch): one item per
+    (live slot, block of its tokens), slot-major. Returns (slot of item t,
+    block of item t, [number of items]); entries past the count are never
+    read."""
+    ppcb = pages_per_compute_block
+    bk = ppcb * page_size
+    num_slots = lengths.shape[0]
+    nblk = (lengths.astype(jnp.int32) + bk - 1) // bk
+    end = jnp.cumsum(nblk)
+    t = jnp.arange(num_slots * (pages_per_sequence // ppcb), dtype=jnp.int32)
+    slot = jnp.minimum(jnp.sum(t[:, None] >= end[None, :], axis=1), num_slots - 1)
+    block = t - (end - nblk)[slot]
+    return slot, block, end[-1:]
+
+
 def _decode_kernel(
     lengths_ref,  # SMEM [S] int32 — valid tokens per slot
     pidx_ref,  # SMEM [S * pps] int32 — flat page table
     layer_ref,  # SMEM [1] int32 — which layer's pages to read
-    q_ref,  # [G, hd] — this cell's query rows (pre-scaled)
+    item_slot_ref,  # SMEM [S * pps / ppcb] int32 — decode_schedule()
+    item_block_ref,
+    num_items_ref,  # SMEM [1] int32
+    q_ref,  # VMEM [S, KH, G, hd] — raw queries (1/sqrt(hd) is applied here)
     *refs,
-    batch_size: int,
     ppcb: int,
     pps: int,
     quant: bool,
 ):
     if quant:
         (k_hbm, ks_hbm, v_hbm, vs_hbm, o_ref,
-         k_buf, ks_buf, v_buf, vs_buf, k_sems, v_sems, state) = refs
+         k_buf, ks_buf, v_buf, vs_buf, k_sems, v_sems) = refs
     else:
-        k_hbm, v_hbm, o_ref, k_buf, v_buf, k_sems, v_sems, state = refs
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, k_sems, v_sems = refs
         ks_hbm = vs_hbm = ks_buf = vs_buf = None
-    b, h = pl.program_id(0), pl.program_id(1)
     li = layer_ref[0]
+    num_items = num_items_ref[0]
     _, num_kv_heads, _, psz, hd = k_hbm.shape
+    nbuf = k_buf.shape[0]
+    G = q_ref.shape[2]
     bk = ppcb * psz  # tokens per compute block
-    length = lengths_ref[b]
 
-    # state[0]: VMEM buffer holding the CURRENT block; state[1]: 1 until the
-    # first block of the whole grid has issued its own copy. SMEM scratch
-    # persists across grid cells (both axes are sequential).
-    @pl.when((b == 0) & (h == 0))
-    def _reset():
-        state[0] = 0
-        state[1] = 1
+    # a slot no item names (length 0) keeps these zeros
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+    if ppcb > 1:
+        # a slot's last block fetches only the pages that hold tokens and
+        # computes over the whole block: what the other pages' buffers hold
+        # meets a probability of exactly 0 and must be finite for that
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        if quant:
+            vs_buf[...] = jnp.zeros(vs_buf.shape, vs_buf.dtype)
 
-    def copies(b_, h_, i_, slot):
-        """(K copies, V copies) of block ``i_`` of cell (b_, h_) into buffer
-        ``slot`` — built identically at start() and wait() time; a slot's
-        copies share one semaphore (it counts bytes)."""
-        kc, vc = [], []
-        for j in range(ppcb):  # static unroll
-            page = pidx_ref[b_ * pps + i_ * ppcb + j]
-            kc.append(pltpu.make_async_copy(
-                k_hbm.at[li, h_, page], k_buf.at[slot, j], k_sems.at[slot]))
-            vc.append(pltpu.make_async_copy(
-                v_hbm.at[li, h_, page], v_buf.at[slot, j], v_sems.at[slot]))
-            if quant:
-                kc.append(pltpu.make_async_copy(
-                    ks_hbm.at[li, h_, page], ks_buf.at[slot, j], k_sems.at[slot]))
-                vc.append(pltpu.make_async_copy(
-                    vs_hbm.at[li, h_, page], vs_buf.at[slot, j], v_sems.at[slot]))
-        return kc, vc
+    k_pool = (k_hbm, k_buf, ks_hbm, ks_buf, k_sems)
+    v_pool = (v_hbm, v_buf, vs_hbm, vs_buf, v_sems)
 
-    def next_block(i):
-        """Grid-order successor of block ``i`` of this cell: the cell's next
-        block, else block 0 of the next kv head, else of the next slot with
-        a nonzero length (``batch_size`` when there is none)."""
+    def start(copy):
+        copy.start()
 
-        def next_slot():
-            nb = jax.lax.fori_loop(
-                b + 1,
-                batch_size,
-                lambda s, cur: jnp.where(
-                    (cur == s) & (lengths_ref[s] == 0), s + 1, cur
-                ),
-                b + 1,
-            )
-            return nb, jnp.int32(0), jnp.int32(0)
+    def wait(copy):
+        copy.wait()
 
-        def next_head():
-            return jax.lax.cond(
-                h + 1 < num_kv_heads,
-                lambda: (b, h + 1, jnp.int32(0)),
-                next_slot,
-            )
+    def copies(t, go, *pools):
+        """Apply ``go`` (``start`` or ``wait``) to item t's copies from
+        ``pools`` — built identically both times; a buffer's copies of one
+        pool share one semaphore (it counts bytes)."""
+        b, i, buf = item_slot_ref[t], item_block_ref[t], t % nbuf
+        held = (lengths_ref[b] - i * bk + psz - 1) // psz  # pages with tokens
 
-        return jax.lax.cond(
-            (i + 1) * bk < length, lambda: (b, h, i + 1), next_head
-        )
+        def page(j, hbm, vmem, scale_hbm, scale_vmem, sems):
+            pg = pidx_ref[b * pps + i * ppcb + j]
+            for h in range(num_kv_heads):  # static unroll
+                go(pltpu.make_async_copy(
+                    hbm.at[li, h, pg], vmem.at[buf, h, j], sems.at[buf]))
+                if quant:
+                    go(pltpu.make_async_copy(
+                        scale_hbm.at[li, h, pg], scale_vmem.at[buf, h, j],
+                        sems.at[buf]))
 
-    def scale_row(buf, slot):
+        for pool in pools:
+            page(0, *pool)
+            for j in range(1, ppcb):
+                pl.when(j < held)(functools.partial(page, j, *pool))
+
+    def scale_row(buf_ref, buf, h):
         # [ppcb, 1, psz] -> [1, bk]: the pages' lane-major scales side by side
-        s = buf[slot].astype(jnp.float32)
+        s = buf_ref[buf, h].astype(jnp.float32)
         return jnp.concatenate([s[j] for j in range(ppcb)], axis=-1) / _MAX_INT8
 
-    q = q_ref[...].astype(jnp.float32)  # [G, hd]
-    G = q.shape[0]
+    for t in range(nbuf - 1):  # fill the ring but for the slot item 0 frees
 
-    def block(i, carry):
-        m_prev, l_prev, acc = carry
-        slot = state[0]
+        @pl.when(t < num_items)
+        def _warm(t=t):
+            copies(t, start, k_pool, v_pool)
 
-        @pl.when(state[1] == 1)
-        def _first():  # nobody prefetched the grid's very first block
-            kc, vc = copies(b, h, i, slot)
-            for c in kc + vc:
-                c.start()
+    def item(t, carry):
+        @pl.when(t + nbuf - 1 < num_items)
+        def _prefetch():  # into the buffer item t-1 has just left
+            copies(t + nbuf - 1, start, k_pool, v_pool)
 
-        state[1] = 0
-        nb, nh, ni = next_block(i)
-
-        @pl.when(nb < batch_size)
-        def _prefetch():  # overlaps this block's compute, across cells too
-            kc, vc = copies(nb, nh, ni, 1 - slot)
-            for c in kc + vc:
-                c.start()
-
-        state[0] = jnp.where(nb < batch_size, 1 - slot, slot)
-
-        kc, vc = copies(b, h, i, slot)
-        for c in kc:
-            c.wait()
-        k = k_buf[slot].astype(jnp.float32).reshape(bk, hd)
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [G, bk]
-        if quant:
-            logits = logits * scale_row(ks_buf, slot)
+        b, i, buf = item_slot_ref[t], item_block_ref[t], t % nbuf
+        length = lengths_ref[b]
+        first = i == 0
         col = i * bk + jax.lax.broadcasted_iota(jnp.int32, (G, bk), 1)
-        logits = jnp.where(col < length, logits, _MASK_VALUE)
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-        p = jnp.exp(logits - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        for c in vc:
-            c.wait()
-        v = v_buf[slot].astype(jnp.float32).reshape(bk, hd)
-        if quant:
-            p = p * scale_row(vs_buf, slot)
-        acc = acc * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        return m_new, l_new, acc
+        valid = col < length
+        probs = []
+        copies(t, wait, k_pool)
+        for h in range(num_kv_heads):
+            m_prev, l_prev, acc = carry[h]
+            m_prev = jnp.where(first, _MASK_VALUE, m_prev)
+            l_prev = jnp.where(first, 0.0, l_prev)
+            acc = jnp.where(first, 0.0, acc)
+            q = q_ref[b, h].astype(jnp.float32)  # [G, hd]
+            k = k_buf[buf, h].astype(jnp.float32).reshape(bk, hd)
+            logits = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * (hd**-0.5)  # [G, bk]
+            if quant:
+                logits = logits * scale_row(ks_buf, buf, h)
+            logits = jnp.where(valid, logits, _MASK_VALUE)
+            m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+            p = jnp.exp(logits - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+            if quant:
+                p = p * scale_row(vs_buf, buf, h)
+            probs.append((m_new, l_new, acc * corr, p))
+        out = []
+        copies(t, wait, v_pool)
+        for h, (m_new, l_new, acc, p) in enumerate(probs):
+            v = v_buf[buf, h].astype(jnp.float32).reshape(bk, hd)
+            pv = jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            out.append((m_new, l_new, acc + pv))
 
-    init = (
-        jnp.full((G, 1), _MASK_VALUE, jnp.float32),
-        jnp.zeros((G, 1), jnp.float32),
-        jnp.zeros((G, hd), jnp.float32),
+        @pl.when(i == (length + bk - 1) // bk - 1)
+        def _store():  # the slot's last block
+            for h, (_, l_new, acc) in enumerate(out):
+                o_ref[b, h] = (acc / l_new).astype(o_ref.dtype)
+
+        return tuple(out)
+
+    init = tuple(
+        (
+            jnp.full((G, 1), _MASK_VALUE, jnp.float32),
+            jnp.zeros((G, 1), jnp.float32),
+            jnp.zeros((G, hd), jnp.float32),
+        )
+        for _ in range(num_kv_heads)
     )
-    _, l, acc = jax.lax.fori_loop(0, (length + bk - 1) // bk, block, init)
-    # a zero-length slot never enters the loop: l == 0, acc == 0 -> zeros
-    o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, num_items, item, init)
 
 
 def paged_attention_q8(
@@ -226,7 +266,7 @@ def paged_attention_q8(
 
 
 def paged_attention_stacked(
-    q: jax.Array,  # [S, H, hd] — RAW (this wrapper applies 1/sqrt(hd))
+    q: jax.Array,  # [S, H, hd] — RAW (the kernel applies 1/sqrt(hd))
     k_pages: jax.Array,  # [n_layers, KH, N, psz, hd] (bf16, int8 or fp8)
     v_pages: jax.Array,
     layer: jax.Array,  # scalar int32 — which layer's pages to read
@@ -234,13 +274,16 @@ def paged_attention_stacked(
     page_indices: jax.Array,  # i32 [S, pages_per_sequence]
     *,
     pages_per_compute_block: int,
+    schedule: tuple[jax.Array, jax.Array, jax.Array] | None = None,
     k_scales: jax.Array | None = None,  # f32 [n_layers, KH, N, 1, psz]
     v_scales: jax.Array | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Paged attention reading layer ``layer`` of the FULL stacked cache —
     zero layer-slice copies (see module docstring). Scales, when given,
-    are lane-major ([..., 1, psz]) end to end."""
+    are lane-major ([..., 1, psz]) end to end. ``schedule`` is
+    ``decode_schedule()`` of the same lengths, table width and block size,
+    for a caller that launches once per layer; computed here otherwise."""
     batch_size, num_q_heads, head_dim = q.shape
     n_layers, num_kv_heads, _, page_size, head_dim_k = k_pages.shape
     _, pages_per_sequence = page_indices.shape
@@ -263,21 +306,26 @@ def paged_attention_stacked(
             f"pages_per_sequence={pages_per_sequence} not divisible by "
             f"pages_per_compute_block={ppcb}"
         )
+    if schedule is None:
+        schedule = decode_schedule(lengths, pages_per_sequence, page_size, ppcb)
+    max_items = batch_size * (pages_per_sequence // ppcb)
+    if schedule[0].shape != (max_items,):
+        raise ValueError(
+            f"schedule of {schedule[0].shape[0]} items, {max_items} expected for "
+            f"{batch_size} slots x {pages_per_sequence // ppcb} blocks"
+        )
 
     G = num_q_heads // num_kv_heads
-    # [S, H, hd] -> [S, KH, G, hd] is a free reshape, and a (G, hd) block is
-    # the array's full trailing dims — legal for any group size
-    qg = (q.astype(jnp.float32) * head_dim**-0.5).reshape(
-        batch_size, num_kv_heads, G, head_dim
-    )
-    q_spec = pl.BlockSpec((None, None, G, head_dim), lambda b, h, *_: (b, h, 0, 0))
+    # [S, H, hd] -> [S, KH, G, hd] is a free reshape
+    qg = q.reshape(batch_size, num_kv_heads, G, head_dim)
+    vmem_spec = pl.BlockSpec(memory_space=pltpu.VMEM)
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     def page_buf(dtype):
-        return pltpu.VMEM((2, ppcb, page_size, head_dim), dtype)
+        return pltpu.VMEM((_NBUF, num_kv_heads, ppcb, page_size, head_dim), dtype)
 
     def scale_buf(dtype):
-        return pltpu.VMEM((2, ppcb, 1, page_size), dtype)
+        return pltpu.VMEM((_NBUF, num_kv_heads, ppcb, 1, page_size), dtype)
 
     if quant:
         pages = [k_pages, k_scales, v_pages, v_scales]
@@ -289,30 +337,30 @@ def paged_attention_stacked(
         pages = [k_pages, v_pages]
         scratch = [page_buf(k_pages.dtype), page_buf(v_pages.dtype)]
     scratch += [
-        pltpu.SemaphoreType.DMA((2,)),  # K copies, one per buffer
-        pltpu.SemaphoreType.DMA((2,)),  # V copies
-        pltpu.SMEM((2,), jnp.int32),  # (current buffer, first-block flag)
+        pltpu.SemaphoreType.DMA((_NBUF,)),  # K copies, one per buffer
+        pltpu.SemaphoreType.DMA((_NBUF,)),  # V copies
     ]
+    # the ring holds every KV head of three blocks; past the compiler's
+    # default budget (16 MiB) with 16 and more KV heads, far inside the 128
+    # MiB the core has
+    ring_bytes = 2 * _NBUF * num_kv_heads * ppcb * page_size * head_dim * k_pages.dtype.itemsize
 
     out = pl.pallas_call(
         functools.partial(
             _decode_kernel,
-            batch_size=batch_size,
             ppcb=ppcb,
             pps=pages_per_sequence,
             quant=quant,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            in_specs=[q_spec] + [any_spec] * len(pages),
-            out_specs=q_spec,
-            grid=(batch_size, num_kv_heads),
+            num_scalar_prefetch=6,
+            in_specs=[vmem_spec] + [any_spec] * len(pages),
+            out_specs=vmem_spec,
+            grid=(1,),
             scratch_shapes=tuple(scratch),
         ),
         compiler_params=pltpu.CompilerParams(
-            # sequential on purpose: a block prefetches its grid-order
-            # successor, so cells must run in order on one core
-            dimension_semantics=("arbitrary", "arbitrary")
+            vmem_limit_bytes=min(100 << 20, max(16 << 20, 2 * ring_bytes + (8 << 20)))
         ),
         out_shape=jax.ShapeDtypeStruct(qg.shape, jnp.float32),
         name="paged_decode_attn",
@@ -321,6 +369,7 @@ def paged_attention_stacked(
         lengths.astype(jnp.int32),
         page_indices.reshape(-1).astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1),
+        *schedule,
         qg,
         *pages,
     )

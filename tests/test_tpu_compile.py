@@ -48,7 +48,10 @@ def chip():
     compilation_cache.reset_cache()
 
 
-def _decode(page_dtype):
+def _decode(page_dtype, slots=SLOTS, kh=KH, g=H // KH, layers=L):
+    """The decode kernel as a benchmark cell launches it: a 32-page table
+    (4096-token window), 4 pages a block; by default ``rollout-1.5b-grpo``'s
+    128 slots x 2 KV heads x group 6."""
     from areal_tpu.ops.paged_attention_q8 import paged_attention_stacked
 
     quant = page_dtype != jnp.bfloat16
@@ -60,13 +63,13 @@ def _decode(page_dtype):
         )
 
     def args(S):
-        pages = S((L, KH, N_PAGES, PSZ, HD), page_dtype)
+        pages = S((layers, kh, N_PAGES, PSZ, HD), page_dtype)
         a = [
-            S((SLOTS, H, HD), jnp.bfloat16), pages, pages, S((), jnp.int32),
-            S((SLOTS,), jnp.int32), S((SLOTS, WP), jnp.int32),
+            S((slots, kh * g, HD), jnp.bfloat16), pages, pages, S((), jnp.int32),
+            S((slots,), jnp.int32), S((slots, 32), jnp.int32),
         ]
         if quant:  # lane-major scales
-            a += [S((L, KH, N_PAGES, 1, PSZ), jnp.float32)] * 2
+            a += [S((layers, kh, N_PAGES, 1, PSZ), jnp.float32)] * 2
         return a
 
     return fn, args
@@ -179,6 +182,9 @@ CASES = {
     "paged_decode_bf16": lambda: _decode(jnp.bfloat16),
     "paged_decode_int8": lambda: _decode(jnp.int8),
     "paged_decode_fp8": lambda: _decode(jnp.float8_e4m3fn),
+    # rollout-7b-d14-grpo: 64 slots x 4 KV heads x group 7, 14 layers
+    "paged_decode_7b_bf16": lambda: _decode(jnp.bfloat16, 64, 4, 7, 14),
+    "paged_decode_7b_int8": lambda: _decode(jnp.int8, 64, 4, 7, 14),
     # the engine's smallest and largest suffix buckets at max_seq_len 2048
     "suffix_prefill_B256": lambda: _suffix(256, 4),
     "suffix_prefill_B2048": lambda: _suffix(2048, 2),
@@ -206,6 +212,7 @@ def test_kernel_compiles_for_v5e(chip, name):
 # device trace shows, and what a reduction finds them by
 KERNEL_NAMES = {
     "paged_decode_bf16": ("paged_decode_attn",),
+    "paged_decode_7b_int8": ("paged_decode_attn",),
     "suffix_prefill_B256": ("paged_suffix_attn",),
     "flash_fwd_pallas": ("flash_fwd",),
     "tree_attention_bwd": ("tree_attn_fwd", "tree_attn_bwd_dq", "tree_attn_bwd_dkv"),
