@@ -56,6 +56,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from areal_tpu.api.config import ServerConfig
 from areal_tpu.api import io_struct
 from areal_tpu.api.io_struct import ModelRequest, ModelResponse, StopReason
+from areal_tpu import models
 from areal_tpu.models import qwen
 from areal_tpu.models.hf import load_params_from_hf
 from areal_tpu.observability import catalog as obs_catalog
@@ -242,12 +243,15 @@ class DecodeEngine:
         self,
         config: ServerConfig,
         params: dict | None = None,
-        model_cfg: qwen.ModelConfig | None = None,
+        model_cfg: "qwen.ModelConfig | models.hybrid.HybridConfig | None" = None,
         mesh=None,
     ):
         self.config = config
         self.params = params
         self.model_cfg = model_cfg
+        # the module of model_cfg's family (models.family_of): every forward
+        # the engine runs is called through it. Set in initialize()
+        self.model = None
         self.mesh = mesh
         self._version = 0
         self._paused = threading.Event()  # set = paused (aborts in-flight)
@@ -268,6 +272,9 @@ class DecodeEngine:
         self._wakeup = threading.Event()
         self._backlog: deque[_Task] = deque()  # tasks popped but not admitted
         self._parked: dict[str, _Parked] = {}  # rid -> retained-KV slot
+        # rids whose slot (KV and recurrent state) was dropped under them: a
+        # prefill of one of these rebuilds a state (state_prefills counter)
+        self._state_dropped: set[str] = set()
         self._staged_flat: dict[str, Any] | None = None  # streamed-update staging
         self._stage_target = "device"  # per-update: "device" | "host"
         self.last_update_gen_tokens = 0  # tokens emitted during last update
@@ -361,12 +368,12 @@ class DecodeEngine:
             self.mesh = mesh_lib.make_mesh(cfg.mesh)
         if self.params is None:
             assert cfg.model_path, "ServerConfig.model_path required"
-            self.model_cfg = qwen.ModelConfig.from_hf_path(cfg.model_path)
-            self.model_cfg = qwen.ModelConfig(
-                **{**self.model_cfg.__dict__, "dtype": cfg.dtype, "remat": False}
-            )
+            self.model_cfg = models.config_from_hf_path(cfg.model_path)
+            self.model = models.family_of(self.model_cfg)
+            self.model_cfg = self.model.serving_config(self.model_cfg, cfg.dtype)
+            self._check_recurrent_config()
             self.param_shardings = mesh_lib.param_sharding(
-                self.mesh, qwen.param_partition_specs(self.model_cfg)
+                self.mesh, self.model.param_partition_specs(self.model_cfg)
             )
 
             self.params, _ = load_params_from_hf(
@@ -397,8 +404,10 @@ class DecodeEngine:
                     )(jax.random.PRNGKey(0))
         else:
             assert self.model_cfg is not None
+            self.model = models.family_of(self.model_cfg)
+            self._check_recurrent_config()
             self.param_shardings = mesh_lib.param_sharding(
-                self.mesh, qwen.param_partition_specs(self.model_cfg)
+                self.mesh, self.model.param_partition_specs(self.model_cfg)
             )
             # caller-provided params (colocated trainers, tests) arrive with
             # whatever placement the caller had — often replicated or
@@ -421,7 +430,7 @@ class DecodeEngine:
             # shardings for the SERVED (quantized) structure — offload/onload
             # walks self.params paths, which carry _q8/_scale names
             self._serving_shardings = mesh_lib.param_sharding(
-                self.mesh, qwen.quant_partition_specs(self.model_cfg)
+                self.mesh, self.model.quant_partition_specs(self.model_cfg)
             )
         elif cfg.quantization not in (None, "", "none"):
             raise ValueError(f"unknown quantization {cfg.quantization!r}")
@@ -501,6 +510,33 @@ class DecodeEngine:
             f"mesh {dict(self.mesh.shape)}, attention {self.attention_impl()}"
         )
 
+    def _check_recurrent_config(self) -> None:
+        """What a model with recurrent (state-space) layers cannot be served
+        with, refused when the engine is configured and not at the first
+        request that would need it: a recurrent state cannot be cut back to
+        a token boundary, so nothing may roll it back or hand out a prefix
+        of it (inference/paged_kv.py STATE_LEAVES)."""
+        if not self.model_cfg.has_recurrent_state:
+            return
+        cfg = self.config
+        spec = getattr(cfg, "speculative", None)
+        if spec is not None and spec.enabled:
+            raise ValueError(
+                "speculative decoding cannot serve a model with recurrent "
+                "(state-space) layers: a rejected draft would have to roll "
+                "the slot's state back, and no state snapshot exists"
+            )
+        if cfg.quantization == "int8":
+            raise ValueError(
+                "int8 weight quantization is not implemented for the "
+                "state-space mixer; serve this model with quantization='none'"
+            )
+        if int(np.prod(list(self.mesh.shape.values()))) != 1:
+            raise ValueError(
+                "a model with recurrent (state-space) layers serves on one "
+                "chip a replica: its mixer and state are not sharded"
+            )
+
     def _place(self, path: str, arr) -> jax.Array:
         """THE placement policy for incoming weights. Base-named leaves cast
         to the serving dtype toward the base param shardings; served-form
@@ -537,7 +573,7 @@ class DecodeEngine:
         weight-update pause window."""
         fn = getattr(self, "_quantize_jit", None)
         if fn is None:
-            fn = self._quantize_jit = jax.jit(qwen.quantize_params_int8)
+            fn = self._quantize_jit = jax.jit(self.model.quantize_params_int8)
         with set_mesh(self.mesh):
             return fn(params)
 
@@ -564,10 +600,10 @@ class DecodeEngine:
         if cfg.kv_hbm_gb is not None:
             n_pages = paged_kv.n_pages_for_budget(
                 int(cfg.kv_hbm_gb * (1 << 30)),
-                mcfg.num_layers,
+                mcfg.num_kv_layers,
                 mcfg.num_kv_heads,
                 psz,
-                mcfg.head_dim_,
+                mcfg.kv_head_dim,
                 jnp.dtype(mcfg.jax_dtype).itemsize,
                 quant=kv_quant,
             )
@@ -580,6 +616,7 @@ class DecodeEngine:
             if mcfg.num_kv_heads % max(tp, 1) == 0
             else {k: P() for k in paged_kv.paged_cache_specs(quant=kv_quant)}
         )
+        kv_spec.update({name: P() for name in mcfg.state_shapes(S)})
         # the Pallas paged kernels run single-device; under TP the engine
         # takes the gather+einsum path, which GSPMD shards over the KV-head
         # axis like the dense engine did. Kernel or gather is decided HERE,
@@ -593,10 +630,10 @@ class DecodeEngine:
             jax.default_backend() == "tpu"
             and int(np.prod(list(self.mesh.shape.values()))) == 1
         )
-        shapes_ok = paged_kernel_ok(mcfg.head_dim_, psz, bool(kv_quant))
+        shapes_ok = paged_kernel_ok(mcfg.kv_head_dim, psz, bool(kv_quant))
         if one_tpu and not shapes_ok:
             logger.warning(
-                f"head_dim {mcfg.head_dim_} / page_size {psz} / kv "
+                f"head_dim {mcfg.kv_head_dim} / page_size {psz} / kv "
                 f"{kv_quant or 'bf16'} is outside the Pallas paged kernels' "
                 "tiling (ops/paged_attention_q8.py paged_kernel_ok): "
                 "decode, suffix prefill and verify take the gather path"
@@ -609,7 +646,9 @@ class DecodeEngine:
         self._suffix_kernel_override: bool | None = None
         with set_mesh(self.mesh):
             self.cache = jax.jit(
-                lambda: paged_kv.init_paged_cache(mcfg, n_pages, psz, quant=kv_quant),
+                lambda: paged_kv.init_paged_cache(
+                    mcfg, n_pages, psz, quant=kv_quant, slots=S
+                ),
                 out_shardings={
                     k: NamedSharding(self.mesh, s) for k, s in kv_spec.items()
                 },
@@ -620,8 +659,19 @@ class DecodeEngine:
         # default flush-on-commit policy
         self._slot_page_versions: list[list[int]] = [[] for _ in range(S)]
         self._pt_host = np.zeros((S, self._maxp), np.int32)
+        self._obs.state_bytes.set(self._state_bytes())
         pc = getattr(cfg, "prefix_cache", None)
-        if pc is not None and pc.enabled and cfg.enable_prefix_caching:
+        if mcfg.has_recurrent_state:
+            # a page prefix says nothing of the recurrent state behind it
+            # (state snapshots at page boundaries: ROADMAP Reach A.7), so the
+            # radix cache neither matches nor inserts for such a model
+            if pc is not None and pc.enabled and cfg.enable_prefix_caching:
+                logger.info(
+                    "prefix cache off: the model has recurrent (state-space) "
+                    "layers and a cached page prefix carries no state"
+                )
+            self._radix = None
+        elif pc is not None and pc.enabled and cfg.enable_prefix_caching:
             cap = pc.max_pages
             if cap is None:
                 cap = int((n_pages - 1) * pc.max_fraction)
@@ -791,9 +841,7 @@ class DecodeEngine:
                         paged_kv.copy_pages, donate_argnames=("cache",)
                     )
                 self._fn_cache[key].lower(
-                    cache_s,
-                    jax.ShapeDtypeStruct((n,), jnp.int32),
-                    jax.ShapeDtypeStruct((n,), jnp.int32),
+                    cache_s, *[jax.ShapeDtypeStruct((n,), jnp.int32)] * 4
                 ).compile()
 
             tasks.append(warm_pagecopy)
@@ -809,6 +857,7 @@ class DecodeEngine:
                         jax.ShapeDtypeStruct((A, bucket), jnp.int32),
                         jax.ShapeDtypeStruct((A,), jnp.int32),
                         jax.ShapeDtypeStruct((A * -(-bucket // psz),), jnp.int32),
+                        jax.ShapeDtypeStruct((A,), jnp.int32),
                     ).compile()
                 )
 
@@ -1742,7 +1791,8 @@ class DecodeEngine:
         them; analytic byte sums on CPU. Exported on /statusz."""
         from areal_tpu.observability import hw_accounting as hw
 
-        kv_bytes = hw.tree_bytes(getattr(self, "cache", None))
+        state_bytes = self._state_bytes()
+        kv_bytes = hw.tree_bytes(getattr(self, "cache", None)) - state_bytes
         pool = getattr(self, "pool", None)
         page_bytes = (
             kv_bytes / pool.n_pages if pool is not None and pool.n_pages else 0
@@ -1751,6 +1801,7 @@ class DecodeEngine:
         components = {
             "params": hw.tree_bytes(self.params),
             "kv_page_pool": kv_bytes,
+            "recurrent_state": state_bytes,
             "radix_cache": int(radix_pages * page_bytes),
             "staged_update": hw.tree_bytes(
                 getattr(self, "_staged_flat", None)
@@ -1762,10 +1813,20 @@ class DecodeEngine:
             exclude_from_total=("radix_cache",),
         )
 
+    def _state_bytes(self) -> int:
+        """Device bytes of the slot-indexed recurrent state (0 for a model
+        without recurrent layers, or while the cache is released)."""
+        from areal_tpu.inference import paged_kv
+
+        cache = getattr(self, "cache", None) or {}
+        return hw.tree_bytes({k: cache[k] for k in paged_kv.STATE_LEAVES if k in cache})
+
     # -- prefix cache (cross-request radix reuse) --------------------------
     def prefix_cache_stats(self) -> dict:
         """Point-in-time radix-cache state for /statusz and tests."""
         if self._radix is None:
+            if self.model_cfg is not None and self.model_cfg.has_recurrent_state:
+                return {"enabled": False, "disabled_by": "recurrent_state"}
             return {"enabled": False}
         return {
             "enabled": True,
@@ -1823,31 +1884,25 @@ class DecodeEngine:
     # -- jitted kernels ---------------------------------------------------
     def _prefill_fn(self, n_prompts: int, bucket: int, with_images: bool = False):
         """Batched prefill: A prompts (padded to ``bucket``) in one forward,
-        KV scattered into the A target slots. Amortises the full-parameter
-        read across admits; no gather/merge — rows at/after each prompt's
-        last token are overwritten by decode before they become readable.
+        their KV scattered into the A rows' pages and, for a model with
+        recurrent layers, each row's post-prompt state into its slot (what
+        exactly: ``prefill_into_cache`` of the model's family). Amortises
+        the full-parameter read across admits.
         ``with_images`` adds a positioned [A, bucket, D] vision-embed input
         (VLM serving; embeds computed by _image_embeds_for at admission)."""
         key = ("prefill", n_prompts, bucket, with_images)
         if key not in self._fn_cache:
             mcfg = self.model_cfg
             psz = self.config.page_size
-            from areal_tpu.inference import paged_kv
+            model = self.model
 
-            def prefill(params, cache, ids, plens, flat_pages, img=None):
-                # ids [A, bucket], plens [A], flat_pages [A * bucket/psz]
-                positions = jnp.broadcast_to(
-                    jnp.arange(bucket, dtype=jnp.int32)[None], ids.shape
+            def prefill(params, cache, ids, plens, flat_pages, slots, img=None):
+                # ids [A, bucket], plens [A], flat_pages [A * bucket/psz],
+                # slots [A] (a padding row: one past the last slot)
+                return model.prefill_into_cache(
+                    params, mcfg, cache, ids, plens, flat_pages, slots,
+                    page_size=psz, image_embeds=img,
                 )
-                seg = (
-                    jnp.arange(bucket, dtype=jnp.int32)[None] < plens[:, None]
-                ).astype(jnp.int32)
-                _, ks, vs = qwen.forward_prefill(
-                    params, mcfg, ids, positions, seg, image_embeds=img
-                )
-                # ks/vs: [n_layers, A, bucket, KH, hd] -> page scatter
-                with jax.named_scope("kv_write"):
-                    return paged_kv.scatter_prefill(cache, ks, vs, flat_pages, psz)
 
             self._fn_cache[key] = kernel_probe.ProbedFn(
                 jax.jit(prefill, donate_argnames=("cache",)),
@@ -1879,7 +1934,7 @@ class DecodeEngine:
                 seg = (
                     jnp.arange(bucket, dtype=jnp.int32)[None] < plens[:, None]
                 ).astype(jnp.int32)
-                _, ks, vs = qwen.forward_prefill_paged(
+                _, ks, vs = self.model.forward_prefill_paged(
                     params, mcfg, ids, positions, seg, cache, ppt, offs,
                     use_kernel=use_kernel,
                 )
@@ -1995,11 +2050,12 @@ class DecodeEngine:
             T = self.config.max_seq_len
             psz = self.config.page_size
             use_kernel = self._use_kernel
+            model = self.model
 
             def chunk(params, cache, page_table, state, rng):
                 def step(carry, _):
                     ids, pos, active, remaining, counts, cache, rng = carry
-                    hidden, cache = qwen.forward_decode_paged(
+                    hidden, cache = model.forward_decode_paged(
                         params,
                         mcfg,
                         ids,
@@ -2007,10 +2063,11 @@ class DecodeEngine:
                         cache,
                         page_table,
                         page_size=psz,
+                        active=active,
                         use_kernel=use_kernel,
                     )
                     with jax.named_scope("lm_head"):
-                        logits = qwen.compute_logits(params, mcfg, hidden)
+                        logits = model.compute_logits(params, mcfg, hidden)
                     with jax.named_scope("sampler"):
                         if freq_any:
                             # OpenAI-style frequency penalty on raw logits,
@@ -2099,8 +2156,8 @@ class DecodeEngine:
         fallback (hw_accounting) for backends that report nothing (CPU).
         Mean context is taken as half the max window; the roofline wants
         the right order of magnitude, not token-exact attention FLOPs."""
-        if self.model_cfg is None:
-            return None
+        if self.model_cfg is None or self.model_cfg.has_recurrent_state:
+            return None  # hw_accounting counts a transformer
         c = hw.decode_step_costs(
             self.model_cfg,
             n_steps,
@@ -2110,7 +2167,7 @@ class DecodeEngine:
         return (c["flops"], c["bytes"])
 
     def _analytic_prefill_cost(self, n_tokens: int) -> tuple[float, float] | None:
-        if self.model_cfg is None:
+        if self.model_cfg is None or self.model_cfg.has_recurrent_state:
             return None
         c = hw.prefill_costs(self.model_cfg, n_tokens)
         return (c["flops"], c["bytes"])
@@ -2160,7 +2217,7 @@ class DecodeEngine:
                 # slots with stale pos; their page-table rows are zeroed so
                 # everything lands in trash anyway
                 positions = jnp.minimum(pos0[:, None] + depth_full, T - 1)
-                hidden, ks, vs = qwen.forward_verify_paged(
+                hidden, ks, vs = self.model.forward_verify_paged(
                     params,
                     mcfg,
                     ids_nodes,
@@ -2172,7 +2229,7 @@ class DecodeEngine:
                     use_kernel=use_kernel,
                 )
                 with jax.named_scope("lm_head"):
-                    logits = qwen.compute_logits(params, mcfg, hidden)  # [S,B,V]
+                    logits = self.model.compute_logits(params, mcfg, hidden)  # [S,B,V]
                 row_valid = (
                     jnp.arange(1, B, dtype=jnp.int32)[None, :]
                     <= d_count[:, None]
@@ -2273,8 +2330,8 @@ class DecodeEngine:
     def _analytic_spec_cost(self, B: int) -> tuple[float, float] | None:
         """Verify forward ~ one decode step with B tokens per slot: B x the
         activation FLOPs, ~1x the weight HBM read (the speculative win)."""
-        if self.model_cfg is None:
-            return None
+        if self.model_cfg is None or self.model_cfg.has_recurrent_state:
+            return None  # hw_accounting counts a transformer
         c = hw.decode_step_costs(
             self.model_cfg,
             1,
@@ -2338,6 +2395,7 @@ class DecodeEngine:
             return None
         rid = min(self._parked, key=lambda r: self._parked[r].park_time)
         p = self._parked.pop(rid)
+        self._state_dropped.add(rid)
         self.pool.free(p.pages)
         self._slot_pages[p.slot] = []
         self._slot_page_versions[p.slot] = []
@@ -2483,6 +2541,7 @@ class DecodeEngine:
             # and release its pages (the slot's own list was emptied at
             # park time, so nothing else frees them)
             del self._parked[rid]
+            self._state_dropped.add(rid)
             self.pool.free(p.pages)
             return None
         del self._parked[rid]
@@ -2781,6 +2840,8 @@ class DecodeEngine:
         rows: list[np.ndarray] = []
         copy_dst: list[int] = []
         copy_src: list[int] = []
+        slot_dst: list[int] = []  # the same pairs by slot: the recurrent
+        slot_src: list[int] = []  # state, where there is one, is copied too
         for task, slot, src_slot in pairs:
             ids = list(task.req.input_ids)
             plen = len(ids)
@@ -2803,6 +2864,8 @@ class DecodeEngine:
             pages = list(shared) + priv
             copy_dst.append(priv[0])
             copy_src.append(prim[n_shared])
+            slot_dst.append(slot)
+            slot_src.append(src_slot)
             self._slot_pages[slot] = pages
             # the private page is a byte COPY of prim[n_shared], so it
             # inherits that page's KV version, not the current one — under
@@ -2827,17 +2890,20 @@ class DecodeEngine:
             while n < len(copy_dst):
                 n *= 2
             pad = n - len(copy_dst)
-            dst = np.asarray(copy_dst + copy_dst[:1] * pad, np.int32)
-            src = np.asarray(copy_src + copy_src[:1] * pad, np.int32)
+            # padding repeats the first pair: the same copy twice
+            pairs_np = [
+                jnp.asarray(np.asarray(x + x[:1] * pad, np.int32))
+                for x in (copy_dst, copy_src, slot_dst, slot_src)
+            ]
             key = ("pagecopy", n)
             if key not in self._fn_cache:
                 self._fn_cache[key] = jax.jit(
                     paged_kv.copy_pages, donate_argnames=("cache",)
                 )
             with set_mesh(self.mesh):
-                self.cache = self._fn_cache[key](
-                    self.cache, jnp.asarray(dst), jnp.asarray(src)
-                )
+                self.cache = self._fn_cache[key](self.cache, *pairs_np)
+            if self.model_cfg.has_recurrent_state:
+                self._obs.state_copies.inc(len(copy_dst))
         self.stats["prefix_shared"] = self.stats.get("prefix_shared", 0) + len(
             copy_dst
         )
@@ -2881,6 +2947,8 @@ class DecodeEngine:
             ids = list(task.req.input_ids)
             ids_np[j, : len(ids)] = ids
             plens[j] = len(ids)
+        # target slot per row; a padding row's is one past the last slot
+        slots_np = np.asarray([slot for _task, slot in admitted], np.int32)
         img = self._image_embeds_for(admitted, ids_np, bucket)
         # prefill group sizes are compiled variants; re-bucket A after any
         # allocation drops by padding rows (trash-page scatter, plen 1)
@@ -2891,6 +2959,9 @@ class DecodeEngine:
             ids_np[A:, 0] = 1
             plens = np.pad(plens, (0, A_pad - A), constant_values=1)
             flat_pages = np.pad(flat_pages, ((0, A_pad - A), (0, 0)))
+            slots_np = np.pad(
+                slots_np, (0, A_pad - A), constant_values=self.config.max_batch_size
+            )
             if img is not None:
                 img = np.pad(img, ((0, A_pad - A), (0, 0), (0, 0)))
         with set_mesh(self.mesh):
@@ -2900,6 +2971,7 @@ class DecodeEngine:
                 jnp.asarray(ids_np),
                 jnp.asarray(plens),
                 jnp.asarray(flat_pages.reshape(-1)),
+                jnp.asarray(slots_np),
             ]
             if img is None:
                 self.cache = self._prefill_fn(A_pad, bucket)(*args)
@@ -2929,6 +3001,12 @@ class DecodeEngine:
         self.stats["prefill_tokens"] += int(plens[:A].sum())  # pad rows excluded
         self._obs.prefills.inc(A)
         self._obs.prefill_tokens.inc(int(plens[:A].sum()))
+        if self.model_cfg.has_recurrent_state:
+            rebuilt = [t.req.rid for t, _ in admitted if t.req.rid in self._state_dropped]
+            self._state_dropped.difference_update(rebuilt)
+            self._obs.state_prefills.inc(len(rebuilt))
+        if len(self._state_dropped) > 4096:  # rids that never came back
+            self._state_dropped.clear()
         return rows
 
     def _apply_slot_updates(self, rows: list[np.ndarray]) -> None:
@@ -3221,6 +3299,8 @@ class DecodeEngine:
         self.flight.record(
             "preempt", severity="warn", slot=slot, rid=task.req.rid
         )
+        if task.req.rid:
+            self._state_dropped.add(task.req.rid)
         self._finish(task, StopReason.ABORT.value)
         self.stats["preempted"] = self.stats.get("preempted", 0) + 1
         return row
@@ -3325,6 +3405,11 @@ class DecodeEngine:
         if spec is None:
             spec = SpeculativeConfig()
             self.config.speculative = spec
+        if enabled and self.model_cfg is not None and self.model_cfg.has_recurrent_state:
+            raise ValueError(
+                "speculative decoding cannot serve a model with recurrent "
+                "(state-space) layers (no state rollback exists)"
+            )
         spec.enabled = bool(enabled)
         if enabled:
             from areal_tpu.inference import speculative as spec_mod

@@ -398,25 +398,49 @@ def n_pages_for_budget(
     return max(2, budget_bytes // page_bytes)
 
 
+# The cache holds two kinds of thing under one dict, donated and returned
+# whole by every serving program. K and V PAGES (``k``, ``v`` and their
+# scales) exist for the layers that attend (``cfg.num_kv_layers``): paged,
+# aliased between requests by refcount, harmless to write twice. The
+# slot-indexed recurrent STATE of a model with state-space layers
+# (``cfg.state_shapes(slots)``; none for a model that only attends) is none
+# of these: one row per decode slot, never aliased, and it cannot be cut back
+# to a token boundary after the fact. The invariant every program keeps:
+#
+#     a slot's state is the state after exactly the tokens the host believes
+#     the slot has consumed.
+#
+# So prefill masks what it feeds the state instead of relying on overwrite,
+# a decode step changes live slots only, a GRPO sibling gets a COPY of its
+# primary's post-prompt state, and a parked slot keeps its rows untouched.
+STATE_LEAVES = ("ssm", "conv")
+
+
 def init_paged_cache(
-    cfg, n_pages: int, page_size: int, dtype=None, quant=False
+    cfg, n_pages: int, page_size: int, dtype=None, quant=False, slots: int = 0
 ) -> dict:
-    """k/v page pools: [n_layers, KH, n_pages, page_size, hd]. With
+    """k/v page pools: [n_kv_layers, KH, n_pages, page_size, hd]. With
     ``quant`` (True/"int8" or "fp8") the pages are int8 or float8_e4m3fn
     plus per-token-vector f32 scales, lane-major ([..., 1, psz]) — halved
-    KV HBM traffic, the decode bottleneck at long context."""
+    KV HBM traffic, the decode bottleneck at long context. Beside them the
+    zeroed recurrent state of ``slots`` decode slots, where the model has
+    any (see STATE_LEAVES above)."""
     dtype = dtype or cfg.jax_dtype
-    shape = (cfg.num_layers, cfg.num_kv_heads, n_pages, page_size, cfg.head_dim_)
+    shape = (cfg.num_kv_layers, cfg.num_kv_heads, n_pages, page_size, cfg.kv_head_dim)
     qdtype = quant_dtype(quant)
     if qdtype is None:
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-    sshape = shape[:-2] + (1, page_size)
-    return {
-        "k": jnp.zeros(shape, qdtype),
-        "v": jnp.zeros(shape, qdtype),
-        "k_scale": jnp.ones(sshape, jnp.float32),
-        "v_scale": jnp.ones(sshape, jnp.float32),
-    }
+        cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    else:
+        sshape = shape[:-2] + (1, page_size)
+        cache = {
+            "k": jnp.zeros(shape, qdtype),
+            "v": jnp.zeros(shape, qdtype),
+            "k_scale": jnp.ones(sshape, jnp.float32),
+            "v_scale": jnp.ones(sshape, jnp.float32),
+        }
+    for name, (sshape, sdtype) in cfg.state_shapes(slots).items():
+        cache[name] = jnp.zeros(sshape, sdtype)
+    return cache
 
 
 def paged_cache_specs(quant: bool = False):
@@ -497,11 +521,30 @@ def scatter_token_rows(
     return cache
 
 
-def copy_pages(cache: dict, dst: jax.Array, src: jax.Array) -> dict:
-    """Copy page contents src[i] -> dst[i] (partial-page duplication for
-    prefix sharing; a few pages, all layers at once)."""
-    for name in cache:  # k/v (+ k_scale/v_scale under int8 KV)
-        cache[name] = cache[name].at[:, :, dst].set(cache[name][:, :, src])
+def copy_pages(
+    cache: dict,
+    dst: jax.Array,
+    src: jax.Array,
+    dst_slots: jax.Array,
+    src_slots: jax.Array,
+) -> dict:
+    """What a request admitted beside an identical prompt takes from its
+    primary: page contents src[i] -> dst[i] (the one page decode writes
+    into; a few pages, all layers at once) and, where the model keeps a
+    recurrent state, the primary slot's post-prompt state
+    src_slots[i] -> dst_slots[i] (a state cannot be shared by reference)."""
+    for name in cache:
+        if name in STATE_LEAVES:
+            with jax.named_scope("state_write"):
+                # a slice in, a slice out a pair: no gather or scatter over
+                # the state arrays (models/hybrid.py prefill_into_cache)
+                def one(i, leaf):
+                    row = jax.lax.dynamic_slice_in_dim(leaf, src_slots[i], 1, axis=1)
+                    return jax.lax.dynamic_update_slice_in_dim(leaf, row, dst_slots[i], axis=1)
+
+                cache[name] = jax.lax.fori_loop(0, dst_slots.shape[0], one, cache[name])
+        else:  # k/v (+ k_scale/v_scale under int8 KV)
+            cache[name] = cache[name].at[:, :, dst].set(cache[name][:, :, src])
     return cache
 
 
@@ -521,6 +564,7 @@ def paged_attention_xla(
     page_table: jax.Array,  # [S, wp] int32 (window's pages)
     k_scales: jax.Array | None = None,  # [KH, N, 1, psz] (int8/fp8 KV)
     v_scales: jax.Array | None = None,
+    sm_scale: float | None = None,  # softmax scale; default 1/sqrt(hd)
 ) -> jax.Array:
     """Reference/CPU path: gather the window's pages, grouped masked einsum —
     numerically identical to the dense engine's attention."""
@@ -547,7 +591,9 @@ def paged_attention_xla(
         kk = dequantize_kv(kk, ks_g, q.dtype)
         vv = dequantize_kv(vv, vs_g, q.dtype)
     qg = q.reshape(S, KH, G, hd)
-    logits = jnp.einsum("skgd,stkd->skgt", qg, kk).astype(jnp.float32) * hd**-0.5
+    logits = jnp.einsum("skgd,stkd->skgt", qg, kk).astype(jnp.float32) * (
+        hd**-0.5 if sm_scale is None else sm_scale
+    )
     valid = jnp.arange(W)[None, :] < lengths[:, None]
     logits = jnp.where(valid[:, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(vv.dtype)

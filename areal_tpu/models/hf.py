@@ -20,7 +20,8 @@ import numpy as np
 from safetensors import safe_open
 from safetensors.numpy import save_file
 
-from areal_tpu.models.qwen import ModelConfig, _layer_shapes, hf_name_map
+from areal_tpu.models import config_from_hf_path, family_of
+from areal_tpu.models.qwen import ModelConfig
 
 
 def _open_shards(path: str) -> dict[str, str]:
@@ -41,16 +42,20 @@ def load_params_from_hf(
     dtype: Any = None,
     put: Callable[[str, np.ndarray], jax.Array] | None = None,
 ) -> tuple[dict, ModelConfig]:
-    """Load an HF Qwen2/Qwen3 checkpoint directory into our param pytree.
+    """Load an HF checkpoint directory into the param pytree of its model
+    family (``models.family_of``): the family's ``hf_name_map`` says which
+    checkpoint tensor each leaf is. A stacked leaf is named
+    ``<stack>/<i>/<leaf>`` there (one tensor per layer), or
+    ``<stack>/<i>/<leaf>/<e>`` (one per layer and expert).
 
     ``put(param_path, host_array) -> device_array`` lets the engine place each
     stacked tensor with its target sharding (sharded device_put); default is a
     plain jnp.asarray.
     """
-    cfg = cfg or ModelConfig.from_hf_path(path)
+    cfg = cfg or config_from_hf_path(path)
     dtype = dtype or cfg.jax_dtype
     shards = _open_shards(path)
-    name_map = hf_name_map(cfg)
+    name_map = family_of(cfg).hf_name_map(cfg)
     handles: dict[str, Any] = {}
 
     def read(hf_name: str) -> np.ndarray:
@@ -73,35 +78,33 @@ def load_params_from_hf(
 
     put = put or (lambda p, a: jnp.asarray(a, dtype=dtype))
 
-    layers: dict[str, Any] = {}
-    for name in _layer_shapes(cfg):
-        if name in ("we_gate", "we_up", "we_down"):
-            # MoE expert leaves: HF ships one tensor per (layer, expert);
-            # stacked [L, E, ...] here
-            per_layer = [
-                np.stack(
-                    [
-                        to_np(*name_map[f"layers/{i}/{name}/{e}"])
-                        for e in range(cfg.num_experts)
-                    ]
-                )
-                for i in range(cfg.num_layers)
-            ]
-        else:
-            per_layer = [
-                to_np(*name_map[f"layers/{i}/{name}"]) for i in range(cfg.num_layers)
-            ]
-        layers[name] = put(f"layers/{name}", np.stack(per_layer))
-    params = {
+    # (stack, leaf) -> {layer index: {expert index or None}}
+    stacked: dict[tuple[str, str], dict[int, set]] = {}
+    for our_path in name_map:
+        parts = our_path.split("/")
+        if len(parts) >= 3:
+            per = stacked.setdefault((parts[0], parts[2]), {})
+            per.setdefault(int(parts[1]), set()).add(int(parts[3]) if len(parts) == 4 else None)
+    params: dict[str, Any] = {
         "embed": put("embed", to_np(*name_map["embed"])),
-        "layers": layers,
         "final_norm": put("final_norm", to_np(*name_map["final_norm"])),
     }
+    for (stack, name), per in stacked.items():
+        per_layer = []
+        for i in range(len(per)):
+            if None in per[i]:
+                per_layer.append(to_np(*name_map[f"{stack}/{i}/{name}"]))
+            else:  # one checkpoint tensor per (layer, expert): stacked [L, E, ...]
+                per_layer.append(
+                    np.stack([to_np(*name_map[f"{stack}/{i}/{name}/{e}"]) for e in range(len(per[i]))])
+                )
+        leaf_path = "/".join((stack, name))  # the path ``put`` places the stacked leaf by
+        params.setdefault(stack, {})[name] = put(leaf_path, np.stack(per_layer))
     if not cfg.tie_word_embeddings:
         if "lm_head.weight" in shards:
             params["lm_head"] = put("lm_head", to_np(*name_map["lm_head"]))
         else:  # some exports tie silently
-            params["lm_head"] = put("lm_head", to_np("model.embed_tokens.weight", False))
+            params["lm_head"] = put("lm_head", to_np(*name_map["embed"]))
     if cfg.vision is not None and "visual.patch_embed.proj.weight" in shards:
         params["vision"] = _load_vision_params(cfg.vision, shards, to_np, put)
     return params, cfg
@@ -138,12 +141,17 @@ def _load_vision_params(vcfg, shards, to_np, put) -> dict:
     return out
 
 
-def write_hf_config(cfg: "ModelConfig", path: str) -> None:
-    """Inverse of ModelConfig.from_hf_dict: write a loadable config.json so
-    a saved checkpoint dir is self-contained (launcher/server subprocess
+def write_hf_config(cfg, path: str) -> None:
+    """Inverse of the family's ``from_hf_dict``: write a loadable config.json
+    so a saved checkpoint dir is self-contained (launcher/server subprocess
     tests; scratch-trained exports)."""
     import json
 
+    if not isinstance(cfg, ModelConfig):
+        os.makedirs(path, exist_ok=True)
+        with open(os.path.join(path, "config.json"), "w") as f:
+            json.dump(cfg.to_hf_dict(), f, indent=2)
+        return
     assert cfg.vision is None, (
         "write_hf_config cannot reconstruct a vision_config — export VLM "
         "checkpoints with base_model_path pointing at the source model dir"
@@ -190,7 +198,7 @@ def save_params_to_hf(
     copied from ``base_model_path``) — the disk weight-update format
     (reference fsdp_engine.py:1139-1204)."""
     os.makedirs(path, exist_ok=True)
-    name_map = hf_name_map(cfg)
+    name_map = family_of(cfg).hf_name_map(cfg)
     flat: dict[str, np.ndarray] = {}
 
     def host(x) -> np.ndarray:
@@ -203,19 +211,20 @@ def save_params_to_hf(
     # device slices would multiply transfers on the disk weight-update path
     host_cache: dict[str, np.ndarray] = {}
 
-    def leaf(name: str) -> np.ndarray:
-        if name not in host_cache:
-            host_cache[name] = host(
-                params["layers"][name] if name in params["layers"] else params[name]
-            )
-        return host_cache[name]
+    def leaf(*keys: str) -> np.ndarray:
+        if keys not in host_cache:
+            x = params
+            for k in keys:
+                x = x[k]
+            host_cache[keys] = host(x)
+        return host_cache[keys]
 
     for our_path, (hf_name, transpose) in name_map.items():
         parts = our_path.split("/")
-        if parts[0] == "layers" and len(parts) == 4:  # layers/<l>/<name>/<e>
-            t = leaf(parts[2])[int(parts[1]), int(parts[3])]
-        elif parts[0] == "layers":
-            t = leaf(parts[2])[int(parts[1])]
+        if len(parts) == 4:  # <stack>/<l>/<name>/<e>
+            t = leaf(parts[0], parts[2])[int(parts[1]), int(parts[3])]
+        elif len(parts) == 3:  # <stack>/<l>/<name>
+            t = leaf(parts[0], parts[2])[int(parts[1])]
         else:
             t = leaf(parts[0])
         flat[hf_name] = np.ascontiguousarray(t.T) if transpose else t

@@ -48,6 +48,9 @@ BATCH_AXES = ("data", "fsdp")
 # ``sampler`` (decode chunk), ``loss`` and ``optimizer`` (train step).
 SCOPES = ("embed", "attn_proj", "kv_write", "attn", "mlp", "lm_head")
 
+# ``model_type``s of a published config.json that this module implements
+MODEL_TYPES = ("qwen2", "qwen3", "qwen3_moe", "qwen2_vl", "qwen2_5_vl", "llama")
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
@@ -112,12 +115,35 @@ class ModelConfig:
     def jax_dtype(self):
         return jnp.dtype(self.dtype)
 
+    # -- what the serving cache holds for this family (inference/paged_kv.py):
+    # K and V pages for every layer, as wide as a head; no recurrent state
+    @property
+    def num_kv_layers(self) -> int:
+        return self.num_layers
+
+    @property
+    def kv_head_dim(self) -> int:
+        return self.head_dim_
+
+    has_recurrent_state = False
+
+    def state_shapes(self, slots: int) -> dict:
+        return {}
+
     @classmethod
     def from_hf_dict(cls, d: dict[str, Any]) -> "ModelConfig":
         """Build from an HF ``config.json`` dict (qwen2 / qwen3 model types,
         plus qwen2-vl-style VLMs whose text fields may nest under
-        ``text_config``)."""
+        ``text_config``, and llama, which is the same decoder). Any other
+        ``model_type`` is refused: the keys this reads are common enough
+        that another architecture's config would build a dense Qwen under
+        its name."""
         mt = d.get("model_type", "qwen2")
+        if mt not in MODEL_TYPES and mt != "qwen2_moe":
+            raise ValueError(
+                f"model_type {mt!r} is not implemented by models/qwen.py "
+                f"({', '.join(MODEL_TYPES)}); see models.config_from_hf_dict"
+            )
         if mt == "qwen2_moe":
             raise ValueError(
                 "qwen2_moe checkpoints use always-active SHARED experts, "
@@ -175,6 +201,11 @@ class ModelConfig:
     def from_hf_path(cls, path: str) -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
             return cls.from_hf_dict(json.load(f))
+
+
+def serving_config(cfg: ModelConfig, dtype: str) -> ModelConfig:
+    """``cfg`` as a decode engine serves it."""
+    return dataclasses.replace(cfg, dtype=dtype, remat=False)
 
 
 # ---------------------------------------------------------------------------
@@ -957,6 +988,34 @@ def forward_prefill(
     return hidden, ks, vs
 
 
+def prefill_into_cache(
+    params: dict,
+    cfg: ModelConfig,
+    cache: dict,
+    ids: jax.Array,  # [A, bucket]
+    plens: jax.Array,  # [A]
+    flat_pages: jax.Array,  # [A * bucket/psz]
+    slots: jax.Array,  # [A] target slots: nothing of this family is slot-indexed
+    *,
+    page_size: int,
+    image_embeds: jax.Array | None = None,
+) -> dict:
+    """What the engine's prefill program does for this family: the K and V
+    of every prompt token, padding included, scattered into the rows' pages.
+    No gather or merge: rows at and after each prompt's last token are
+    overwritten by decode before they become readable."""
+    from areal_tpu.inference import paged_kv
+
+    del slots
+    bucket = ids.shape[1]
+    positions = jnp.broadcast_to(jnp.arange(bucket, dtype=jnp.int32)[None], ids.shape)
+    seg = (jnp.arange(bucket, dtype=jnp.int32)[None] < plens[:, None]).astype(jnp.int32)
+    _, ks, vs = forward_prefill(params, cfg, ids, positions, seg, image_embeds=image_embeds)
+    # ks/vs: [n_layers, A, bucket, KH, hd] -> page scatter
+    with jax.named_scope("kv_write"):
+        return paged_kv.scatter_prefill(cache, ks, vs, flat_pages, page_size)
+
+
 def _gather_window(cache: dict, name: str, li, page_table: jax.Array) -> jax.Array:
     """Layer ``li``'s window pages of ``cache[name]``, gathered dense:
     [A, wp] page ids -> [A, wp * psz, KH, d] (d = head_dim for pages, 1 for
@@ -1238,9 +1297,15 @@ def forward_decode_paged(
     page_table: jax.Array,  # [S, wp] int32 page ids covering the window
     *,
     page_size: int,
+    active: jax.Array | None = None,  # [S] live slots: not needed, see below
     use_kernel: bool = True,
 ) -> tuple[jax.Array, dict]:
     """One incremental step for all S slots over the *paged* KV cache.
+
+    Every slot is stepped, ended ones too: what they write lands in the
+    trash page or in a row decode rewrites before reading, so ``active``
+    (which a family with recurrent state needs, models/hybrid.py) changes
+    nothing here.
 
     The current token's k/v lands at page ``table[s, pos//psz]`` row
     ``pos % psz``; attention reads each slot's pages via the TPU
@@ -1252,6 +1317,7 @@ def forward_decode_paged(
     """
     from areal_tpu.inference import paged_kv
 
+    del active
     S = ids.shape[0]
     H, KH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     with jax.named_scope("embed"):

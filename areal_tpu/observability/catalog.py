@@ -139,6 +139,22 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "areal_decode_batch_occupancy",
             "Active decode slots (of ServerConfig.max_batch_size).",
         ),
+        # slot-indexed recurrent state of a model with state-space layers
+        # (inference/paged_kv.py STATE_LEAVES); all zero for other models
+        state_copies=r.counter(
+            "areal_decode_state_copies_total",
+            "Recurrent states copied on the device from a primary's slot to "
+            "a sibling admitted with the same prompt.",
+        ),
+        state_prefills=r.counter(
+            "areal_decode_state_prefills_total",
+            "Recurrent states rebuilt by prefill for a request seen before "
+            "(after a preemption, an evicted or a dropped parking).",
+        ),
+        state_bytes=r.gauge(
+            "areal_decode_state_bytes",
+            "Device bytes of the slot-indexed recurrent state.",
+        ),
     )
 
 

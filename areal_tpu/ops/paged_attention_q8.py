@@ -107,11 +107,12 @@ def _decode_kernel(
     item_slot_ref,  # SMEM [S * pps / ppcb] int32 — decode_schedule()
     item_block_ref,
     num_items_ref,  # SMEM [1] int32
-    q_ref,  # VMEM [S, KH, G, hd] — raw queries (1/sqrt(hd) is applied here)
+    q_ref,  # VMEM [S, KH, G, hd] — raw queries (``sm_scale`` is applied here)
     *refs,
     ppcb: int,
     pps: int,
     quant: bool,
+    sm_scale: float,
 ):
     if quant:
         (k_hbm, ks_hbm, v_hbm, vs_hbm, o_ref,
@@ -199,7 +200,7 @@ def _decode_kernel(
             k = k_buf[buf, h].astype(jnp.float32).reshape(bk, hd)
             logits = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            ) * (hd**-0.5)  # [G, bk]
+            ) * sm_scale  # [G, bk]
             if quant:
                 logits = logits * scale_row(ks_buf, buf, h)
             logits = jnp.where(valid, logits, _MASK_VALUE)
@@ -266,7 +267,7 @@ def paged_attention_q8(
 
 
 def paged_attention_stacked(
-    q: jax.Array,  # [S, H, hd] — RAW (the kernel applies 1/sqrt(hd))
+    q: jax.Array,  # [S, H, hd] — RAW (the kernel applies ``sm_scale``)
     k_pages: jax.Array,  # [n_layers, KH, N, psz, hd] (bf16, int8 or fp8)
     v_pages: jax.Array,
     layer: jax.Array,  # scalar int32 — which layer's pages to read
@@ -277,6 +278,7 @@ def paged_attention_stacked(
     schedule: tuple[jax.Array, jax.Array, jax.Array] | None = None,
     k_scales: jax.Array | None = None,  # f32 [n_layers, KH, N, 1, psz]
     v_scales: jax.Array | None = None,
+    sm_scale: float | None = None,  # softmax scale; default 1/sqrt(hd)
     interpret: bool = False,
 ) -> jax.Array:
     """Paged attention reading layer ``layer`` of the FULL stacked cache —
@@ -351,6 +353,7 @@ def paged_attention_stacked(
             ppcb=ppcb,
             pps=pages_per_sequence,
             quant=quant,
+            sm_scale=head_dim**-0.5 if sm_scale is None else float(sm_scale),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,

@@ -48,7 +48,7 @@ def chip():
     compilation_cache.reset_cache()
 
 
-def _decode(page_dtype, slots=SLOTS, kh=KH, g=H // KH, layers=L):
+def _decode(page_dtype, slots=SLOTS, kh=KH, g=H // KH, layers=L, sm_scale=None):
     """The decode kernel as a benchmark cell launches it: a 32-page table
     (4096-token window), 4 pages a block; by default ``rollout-1.5b-grpo``'s
     128 slots x 2 KV heads x group 6."""
@@ -59,7 +59,7 @@ def _decode(page_dtype, slots=SLOTS, kh=KH, g=H // KH, layers=L):
     def fn(q, k, v, li, lengths, pt, *scales):
         kw = dict(k_scales=scales[0], v_scales=scales[1]) if scales else {}
         return paged_attention_stacked(
-            q, k, v, li, lengths, pt, pages_per_compute_block=4, **kw
+            q, k, v, li, lengths, pt, pages_per_compute_block=4, sm_scale=sm_scale, **kw
         )
 
     def args(S):
@@ -99,6 +99,25 @@ def _suffix(B, A, page_dtype=jnp.bfloat16):
         if quant:
             a += [S((L, KH, N_PAGES, 1, PSZ), jnp.float32)] * 2
         return a
+
+    return fn, args
+
+
+def _ssm_state(dtype):
+    """The recurrent-state update of one Mamba-2 layer at the published sizes
+    of ``rollout-granite-h-micro-grpo``: 36 layers x 64 slots of 64 heads x
+    64 x 128, updated in place for the live slots."""
+    from areal_tpu.ops.ssm_state_update import live_order, ssm_state_update_stacked
+
+    def fn(ssm, li, x, b, c, dt, a, active):
+        return ssm_state_update_stacked(ssm, li, x, b, c, dt, a, *live_order(active))
+
+    def args(S):
+        f32 = jnp.float32
+        return [
+            S((36, 64, 64, 64, 128), dtype), S((), jnp.int32), S((64, 64, 64), f32), S((64, 1, 128), f32),
+            S((64, 1, 128), f32), S((64, 64), f32), S((64,), f32), S((64,), jnp.bool_),
+        ]
 
     return fn, args
 
@@ -185,6 +204,13 @@ CASES = {
     # rollout-7b-d14-grpo: 64 slots x 4 KV heads x group 7, 14 layers
     "paged_decode_7b_bf16": lambda: _decode(jnp.bfloat16, 64, 4, 7, 14),
     "paged_decode_7b_int8": lambda: _decode(jnp.int8, 64, 4, 7, 14),
+    # rollout-granite-h-micro-grpo: 64 slots x 8 KV heads x group 4 over 4
+    # attention layers, heads of 64 zero-padded to 128 lanes, softmax scale
+    # 1/64 from the configuration; int8 pages are its control
+    "paged_decode_h64pad_bf16": lambda: _decode(jnp.bfloat16, 64, 8, 4, 4, sm_scale=1 / 64),
+    "paged_decode_h64pad_int8": lambda: _decode(jnp.int8, 64, 8, 4, 4, sm_scale=1 / 64),
+    "ssm_state_update_f32": lambda: _ssm_state(jnp.float32),
+    "ssm_state_update_bf16": lambda: _ssm_state(jnp.bfloat16),
     # the engine's smallest and largest suffix buckets at max_seq_len 2048
     "suffix_prefill_B256": lambda: _suffix(256, 4),
     "suffix_prefill_B2048": lambda: _suffix(2048, 2),
@@ -215,6 +241,7 @@ KERNEL_NAMES = {
     "paged_decode_7b_int8": ("paged_decode_attn",),
     "suffix_prefill_B256": ("paged_suffix_attn",),
     "flash_fwd_pallas": ("flash_fwd",),
+    "ssm_state_update_f32": ("ssm_state_update",),
     "tree_attention_bwd": ("tree_attn_fwd", "tree_attn_bwd_dq", "tree_attn_bwd_dkv"),
 }
 

@@ -241,7 +241,9 @@ def _lower_prefill(eng):
     fn = eng._prefill_fn(1, bucket)
     ids = jnp.zeros((1, bucket), jnp.int32)
     with jax.set_mesh(eng.mesh):
-        return fn.lower(eng.params, eng.cache, ids, jnp.array([5], jnp.int32), jnp.array([1], jnp.int32))
+        return fn.lower(
+            eng.params, eng.cache, ids, jnp.array([5], jnp.int32), jnp.array([1], jnp.int32), jnp.array([0], jnp.int32)
+        )
 
 
 def _lower_train_step(eng, monkeypatch):
