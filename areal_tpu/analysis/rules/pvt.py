@@ -3,14 +3,16 @@
 The repo leans on private jax internals in exactly two sanctioned ways:
 kernel launch forks that call a private Pallas kernel positionally
 (``ops/paged_attention_q8.py``), and lazy imports of private library
-kernels (flash attention, megablox gmm, the paged-attention wrapper).
+kernels (flash attention and the ``BlockSizes`` dataclass its tiles go
+in, megablox gmm, the paged-attention wrapper).
 A jax upgrade can silently reorder/extend those signatures — positional
 call sites then pass the wrong argument into the wrong parameter with no
 error at all. The defense is the pinned-signature idiom: an
 ``_EXPECTED_*`` tuple of parameter names compared against
 ``inspect.signature(...)`` at import/first-use (as in
 ``ops/paged_attention_q8.py``), or the equivalent
-``utils.private_api.pin_signature(symbol, _EXPECTED_*)`` helper.
+``utils.private_api.pin_signature(symbol, _EXPECTED_*)`` helper. A pinned
+symbol is a function or a class: a dataclass's signature is its fields.
 
 PVT both enforces the idiom and *executes* it at lint time: every pin on
 a ``jax.*`` symbol is checked against the **installed** jax, so signature

@@ -441,11 +441,12 @@ def _cases_flash_fwd(compiled: bool = False) -> Iterator[dict]:
     }
 
     def build(seg_np):
+        shape = (*seg_np.shape, H, d)
         return lambda: {
-            "q": _normal(11, (G, L, H, d), dt),
-            "k": _normal(12, (G, L, H, d), dt),
-            "v": _normal(13, (G, L, H, d), dt),
-            "w": _normal(23, (G, L, H, d)),
+            "q": _normal(11, shape, dt),
+            "k": _normal(12, shape, dt),
+            "v": _normal(13, shape, dt),
+            "w": _normal(23, shape),
             "seg": jnp.asarray(seg_np),
             "mask": jnp.asarray(_packed_mask(seg_np)),
         }
@@ -466,21 +467,37 @@ def _cases_flash_fwd(compiled: bool = False) -> Iterator[dict]:
         return
     # jax's library flash attention behind flash_train (forward AND
     # gradients) against XLA sdpa. It has no interpret switch, so it is an
-    # on-chip case only.
-    yield {
-        "case": "library-train-bf16-packed-fwd-and-grads",
-        "build": build(grids["packed-two-segments"]),
-        "kernel": lambda inp: _out_and_grads(
-            lambda q, k, v: attention.flash_train(q, k, v, inp["seg"]),
-            inp["q"], inp["k"], inp["v"], inp["w"],
-        ),
-        "reference": lambda inp: _out_and_grads(
-            lambda q, k, v: attention.sdpa_xla(q, k, v, inp["mask"], d),
-            inp["q"], inp["k"], inp["v"], inp["w"],
-        ),
-        # gradients sum bf16-rounded probabilities over up to 1024 keys
-        "tol": 1e-1,
-    }
+    # on-chip case only. Rows of padding (segment 0) carry no loss in the
+    # trainer: their output is zeroed on both sides, and with it what they
+    # send back.
+    def live(attn, inp):
+        keep = (inp["seg"] != 0)[:, :, None, None]
+        return lambda q, k, v: jnp.where(keep, attn(q, k, v), 0)
+
+    # the train cell's row length, so its tiles (1024 and 512): five
+    # segments whose boundaries fall inside tiles, then a padded tail
+    cuts = [0, 700, 1500, 2300, 3000, 3900]
+    seg_4k = np.zeros((1, 4096), np.int32)
+    for i, (a, b) in enumerate(zip(cuts, cuts[1:])):
+        seg_4k[:, a:b] = i + 1
+    for label, seg_np in (
+        ("library-train-bf16-packed-fwd-and-grads", grids["packed-two-segments"]),
+        ("library-train-bf16-L4096-five-segments-padded-tail", seg_4k),
+    ):
+        yield {
+            "case": label,
+            "build": build(seg_np),
+            "kernel": lambda inp: _out_and_grads(
+                live(lambda q, k, v: attention.flash_train(q, k, v, inp["seg"]), inp),
+                inp["q"], inp["k"], inp["v"], inp["w"],
+            ),
+            "reference": lambda inp: _out_and_grads(
+                live(lambda q, k, v: attention.sdpa_xla(q, k, v, inp["mask"], d), inp),
+                inp["q"], inp["k"], inp["v"], inp["w"],
+            ),
+            # gradients sum bf16-rounded probabilities over up to 1024 keys
+            "tol": 1e-1,
+        }
 
 
 # ---------------------------------------------------------------------------

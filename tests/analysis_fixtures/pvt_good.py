@@ -1,7 +1,8 @@
 """PVT good fixture: every sanctioned shape of private-jax use — a
 try/except-ImportError-gated import (graceful degradation, jax_compat
 style), the inline inspect.signature pin, and the
-utils.private_api.pin_signature helper idiom. All pins match the
+utils.private_api.pin_signature helper idiom, on a function and on a
+dataclass (whose signature is its fields). All pins match the
 installed jax 0.9.0, so the file stays silent."""
 
 import inspect
@@ -62,3 +63,21 @@ _EXPECTED_GMM_PARAMS = (
     "interpret",
 )
 pin_signature(gmm, _EXPECTED_GMM_PARAMS)
+
+# the helper idiom on a private dataclass: every field a call site fills
+from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
+
+_EXPECTED_BLOCK_SIZES_FIELDS = (
+    "block_q",
+    "block_k_major",
+    "block_k",
+    "block_b",
+    "block_q_major_dkv",
+    "block_k_major_dkv",
+    "block_k_dkv",
+    "block_q_dkv",
+    "block_k_major_dq",
+    "block_k_dq",
+    "block_q_dq",
+)
+pin_signature(BlockSizes, _EXPECTED_BLOCK_SIZES_FIELDS)

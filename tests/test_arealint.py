@@ -304,6 +304,31 @@ def test_pvt_good_fixture():
     assert rules_in(FIXTURES / "pvt_good.py", ["PVT"]) == []
 
 
+def test_block_sizes_pin_names_every_field_the_call_fills():
+    """``ops/attention.py`` pins the flash kernels' private ``BlockSizes``
+    beside ``flash_attention`` (PVT002 re-checks both at lint time): the
+    pin is the dataclass's own field list in the installed jax, and
+    ``flash_block_sizes`` leaves no field at a default nobody chose."""
+    import dataclasses
+
+    from areal_tpu.ops import attention
+
+    src = Path(attention.__file__)
+    assert rules_in(src, ["PVT"]) == []
+    cls = attention.pinned_block_sizes()
+    names = tuple(f.name for f in dataclasses.fields(cls))
+    assert names == attention._EXPECTED_BLOCK_SIZES_FIELDS
+    filled = attention.flash_block_sizes(attention.FlashTiles(512, 256, 128))
+    assert dataclasses.asdict(filled) == {
+        **{n: 512 for n in ("block_q", "block_k_major", "block_k")},
+        "block_b": 1,
+        **{n: 256 for n in names if n.endswith("_dkv")},
+        **{n: 128 for n in names if n.endswith("_dq")},
+    }
+    with pytest.raises(RuntimeError, match="drifted"):
+        attention.pin_signature(cls, names[:-1])
+
+
 def test_msh_bad_fixture():
     rules = rules_in(FIXTURES / "msh_bad.py", ["MSH"])
     assert {"MSH001", "MSH002", "MSH003"} == set(rules)

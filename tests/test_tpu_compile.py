@@ -122,17 +122,18 @@ def _ssm_state(dtype):
     return fn, args
 
 
-def _flash_fwd():
+def _flash_fwd(G=1, L=2048):
     from areal_tpu.ops import attention
 
     def args(S):
-        x = S((1, 2048, H, HD), jnp.bfloat16)
-        return [x, x, x, S((1, 2048), jnp.int32)]
+        x = S((G, L, H, HD), jnp.bfloat16)
+        return [x, x, x, S((G, L), jnp.int32)]
 
     return (lambda q, k, v, seg: attention.flash_fwd_pallas(q, k, v, seg)), args
 
 
-def _flash_train_grad():
+def _flash_train_grad(G=1, L=2048):
+    """``flash_train`` at the tiles ``flash_tiles`` gives the row length."""
     from areal_tpu.ops import attention
 
     def fn(q, k, v, seg):
@@ -143,7 +144,7 @@ def _flash_train_grad():
             argnums=(0, 1, 2),
         )(q, k, v)
 
-    return fn, _flash_fwd()[1]
+    return fn, _flash_fwd(G, L)[1]
 
 
 def _library_paged():
@@ -220,6 +221,9 @@ CASES = {
     "tree_verify_B9": lambda: _suffix(9, SLOTS),
     "flash_fwd_pallas": _flash_fwd,
     "flash_train_grad": _flash_train_grad,
+    # train-1.5b-packed4k: 3 packed rows of 4096 a layer
+    "flash_fwd_pallas_3x4096": lambda: _flash_fwd(3, 4096),
+    "flash_train_grad_3x4096": lambda: _flash_train_grad(3, 4096),
     "library_paged_attention": _library_paged,
     "tree_attention_fwd": lambda: _tree(False),
     "tree_attention_bwd": lambda: _tree(True),
