@@ -342,9 +342,8 @@ class DecodeEngine:
         self._autopilot_knobs: dict[str, float] = {}
         self._autopilot_applied_at: float | None = None
         # kernel observatory (observability/kernel_probe.py): per-pass phase
-        # timeline + compiled-cost registry. Built in initialize() (peak
-        # resolution may calibrate the host backend); None until then, and
-        # _ktl holds the current pass's open timeline on the decode thread
+        # timeline. Built in initialize(); None until then, and _ktl holds
+        # the current pass's open timeline on the decode thread
         self.kprobe: kernel_probe.KernelProbe | None = None
         self._ktl: kernel_probe.DecodeStepTimeline | None = None
 
@@ -488,13 +487,7 @@ class DecodeEngine:
         if seed is None:
             seed = int(time.time_ns()) % (2**31)
         self._rng = jax.device_put(jax.random.PRNGKey(seed), repl)
-        # kernel observatory: init-time construction (peaks resolve from
-        # the chip table; an unknown TPU kind is an error, raised here and
-        # not on the decode hot path)
-        self.kprobe = kernel_probe.KernelProbe(
-            model_cfg=self.model_cfg,
-            n_chips=int(getattr(self.mesh, "size", 1) or 1),
-        )
+        self.kprobe = kernel_probe.KernelProbe()
         # speculative decoding (getattr: configs serialized before the knob
         # existed deserialize without it)
         spec = getattr(cfg, "speculative", None)
@@ -1904,12 +1897,7 @@ class DecodeEngine:
                     page_size=psz, image_embeds=img,
                 )
 
-            self._fn_cache[key] = kernel_probe.ProbedFn(
-                jax.jit(prefill, donate_argnames=("cache",)),
-                self.kprobe,
-                key,
-                analytic=self._analytic_prefill_cost(n_prompts * bucket),
-            )
+            self._fn_cache[key] = jax.jit(prefill, donate_argnames=("cache",))
         return self._fn_cache[key]
 
     def _prefill_paged_fn(self, n_prompts: int, bucket: int, wp: int):
@@ -1941,12 +1929,7 @@ class DecodeEngine:
                 with jax.named_scope("kv_write"):
                     return paged_kv.scatter_prefill(cache, ks, vs, flat_pages, psz)
 
-            self._fn_cache[key] = kernel_probe.ProbedFn(
-                jax.jit(prefill, donate_argnames=("cache",)),
-                self.kprobe,
-                key,
-                analytic=self._analytic_prefill_cost(n_prompts * bucket),
-            )
+            self._fn_cache[key] = jax.jit(prefill, donate_argnames=("cache",))
         return self._fn_cache[key]
 
     def _image_embeds_for(self, group: list[tuple[_Task, int]], ids_np, bucket: int):
@@ -2143,34 +2126,8 @@ class DecodeEngine:
                 )
                 return cache, out_state, rng, packed
 
-            self._fn_cache[key] = kernel_probe.ProbedFn(
-                jax.jit(chunk, donate_argnames=("cache", "state")),
-                self.kprobe,
-                key,
-                analytic=self._analytic_chunk_cost(n_steps),
-            )
+            self._fn_cache[key] = jax.jit(chunk, donate_argnames=("cache", "state"))
         return self._fn_cache[key]
-
-    def _analytic_chunk_cost(self, n_steps: int) -> tuple[float, float] | None:
-        """Analytic FLOPs/bytes of one decode chunk — the cost_analysis
-        fallback (hw_accounting) for backends that report nothing (CPU).
-        Mean context is taken as half the max window; the roofline wants
-        the right order of magnitude, not token-exact attention FLOPs."""
-        if self.model_cfg is None or self.model_cfg.has_recurrent_state:
-            return None  # hw_accounting counts a transformer
-        c = hw.decode_step_costs(
-            self.model_cfg,
-            n_steps,
-            self.config.max_batch_size,
-            self.config.max_seq_len / 2.0,
-        )
-        return (c["flops"], c["bytes"])
-
-    def _analytic_prefill_cost(self, n_tokens: int) -> tuple[float, float] | None:
-        if self.model_cfg is None or self.model_cfg.has_recurrent_state:
-            return None
-        c = hw.prefill_costs(self.model_cfg, n_tokens)
-        return (c["flops"], c["bytes"])
 
     def _spec_fn(self, B: int, wp: int, capped: bool, greedy_any: bool = True):
         """One speculative verify+accept round in a single jitted call.
@@ -2319,26 +2276,8 @@ class DecodeEngine:
                 )
                 return cache, out_state, rng, packed
 
-            self._fn_cache[key] = kernel_probe.ProbedFn(
-                jax.jit(spec, donate_argnames=("cache", "state")),
-                self.kprobe,
-                key,
-                analytic=self._analytic_spec_cost(B),
-            )
+            self._fn_cache[key] = jax.jit(spec, donate_argnames=("cache", "state"))
         return self._fn_cache[key]
-
-    def _analytic_spec_cost(self, B: int) -> tuple[float, float] | None:
-        """Verify forward ~ one decode step with B tokens per slot: B x the
-        activation FLOPs, ~1x the weight HBM read (the speculative win)."""
-        if self.model_cfg is None or self.model_cfg.has_recurrent_state:
-            return None  # hw_accounting counts a transformer
-        c = hw.decode_step_costs(
-            self.model_cfg,
-            1,
-            self.config.max_batch_size * B,
-            self.config.max_seq_len / 2.0,
-        )
-        return (c["flops"], c["bytes"])
 
     def _update_fn(self, n: int):
         """Jitted slot-state scatter: one packed fp32 [n, 11+_MAX_STOP] upload
@@ -3346,10 +3285,6 @@ class DecodeEngine:
         return {
             "packed": packed,
             "n_steps": n_steps,
-            # fn-cache key of the chunk program: the kernel probe attributes
-            # this chunk's registered FLOP/byte cost to the pass that DRAINS
-            # it (steady state drains exactly one chunk per pass)
-            "key": ("chunk", n_steps, wp, capped, greedy_any, freq_any),
             "version": self._version,
             "was_active": active.copy(),
             # task identity per slot at dispatch: a slot can turn over
@@ -3389,13 +3324,13 @@ class DecodeEngine:
 
     def set_suffix_kernel(self, on: bool | None) -> None:
         """Force the paged suffix-attention kernel on/off (None restores
-        the platform default). Used by bench's kernel-vs-XLA A/B; takes
+        the platform default). Used by chip_smoke's kernel-vs-XLA check; takes
         effect on the next compiled prefill/verify fn (the fn-cache key
         carries the flag, so both variants can coexist warm)."""
         self._suffix_kernel_override = on
 
     def set_speculative(self, enabled: bool) -> None:
-        """Runtime toggle for speculative decoding (bench A/B without an
+        """Runtime toggle for speculative decoding (on/off runs without an
         engine rebuild); applies from the next loop pass. Safe from any
         thread: the loop reads ``_spec_cfg`` once per pass and a spec pass
         always drains the pipelined chunk before its own round."""
@@ -3421,19 +3356,19 @@ class DecodeEngine:
             self._drafter = None
         self._wakeup.set()
 
-    def _spec_round(self) -> tuple[int, tuple | None]:
+    def _spec_round(self) -> tuple[int, bool]:
         """One SYNCHRONOUS speculative round: host drafter proposes, one
         jitted verify+accept call scores and commits, the packed result
         drains through the normal bookkeeping, then over-allocated pages
         roll back through the pool. Synchronous because the accept decision
         gates the next round's drafts — the pipelined-chunk overlap trick
         cannot apply; the round itself must beat ``accepted+1`` sequential
-        steps to win. Returns (credited tokens, the round's cost key)."""
+        steps to win. Returns (credited tokens, whether a round ran)."""
         cfg = self.config
         spec = self._spec_cfg
         st = self._state
         if not st["active"].any():
-            return 0, None
+            return 0, False
         psz = cfg.page_size
         T = cfg.max_seq_len
         B = spec.max_nodes()
@@ -3444,7 +3379,7 @@ class DecodeEngine:
         self._ensure_pages(ahead=B)
         active = st["active"]
         if not active.any():
-            return 0, None
+            return 0, False
         with self._kphase("draft"):
             from areal_tpu.inference import speculative as spec_mod
 
@@ -3471,7 +3406,6 @@ class DecodeEngine:
         wp = min(self._maxp, -(-window // psz))
         capped = bool(((st["top_k"] > 0) | (st["top_p"] < 1.0))[active].any())
         greedy_any = bool(st["greedy"][active].any())
-        key = ("spec", B, wp, capped, greedy_any)
         fn = self._spec_fn(B, wp, capped, greedy_any)
         with self._kphase("dispatch"):
             with set_mesh(self.mesh):
@@ -3493,7 +3427,6 @@ class DecodeEngine:
         pending = {
             "packed": packed_np,
             "n_steps": B,
-            "key": key,
             "version": self._version,
             "was_active": active.copy(),
             "tasks": list(self._slot_task),
@@ -3529,7 +3462,7 @@ class DecodeEngine:
         if rolled:
             self.stats["spec_rollback_pages"] += rolled
             self._obs_spec.rollback_pages.inc(rolled)
-        return credited, key
+        return credited, True
 
     def _rollback_spec_pages(self) -> int:
         """Free speculation-allocated pages beyond each live slot's
@@ -3645,8 +3578,8 @@ class DecodeEngine:
         self._ktl = None
 
     def kernel_stats(self) -> dict:
-        """Kernel-observatory summary for /statusz ``kernels`` and bench
-        ``detail.kernels`` (None-safe before initialize())."""
+        """Kernel-observatory summary for /statusz ``kernels`` (None-safe
+        before initialize())."""
         if self.kprobe is None:
             return {}
         return self.kprobe.stats()
@@ -3680,7 +3613,7 @@ class DecodeEngine:
             # incompatible with parallel verify scoring — fall back to
             # the sequential chunk path while any active slot uses it
             spec_on = not bool((st["freq_pen"] != 0.0)[st["active"]].any())
-        drained_key = pending["key"] if pending is not None else None
+        drained = pending is not None
         if spec_on:
             # SYNCHRONOUS speculative pass: drain the pipelined chunk
             # first (covers the spec-off -> spec-on transition), then
@@ -3690,11 +3623,9 @@ class DecodeEngine:
             # draft and verify" is impossible by construction, and
             # drafts are version-free host proposals anyway.
             tokens = self._drain(pending)
-            n_spec, cost_key = self._spec_round()
+            n_spec, worked = self._spec_round()
             tokens += n_spec
             pending = None
-            worked = cost_key is not None
-            cost_key = cost_key or drained_key
         else:
             # speculatively dispatch the next chunk, then pay the previous
             # chunk's download while this one computes
@@ -3703,15 +3634,14 @@ class DecodeEngine:
             tokens = self._drain(pending)
             pending = dispatched
             worked = dispatched is not None
-            cost_key = drained_key
         if span is not None:
             span.set(active=int(self._state["active"].sum()), tokens=tokens)
         if step_tl is not None:
             # a pass that drained, dispatched, or admitted is a real
             # step; a bare poll (no slots, empty queue) is not
-            if drained_key is not None or worked or rows:
+            if drained or worked or rows:
                 self._ktl = None
-                self.kprobe.complete_step(step_tl, tokens=tokens, cost_key=cost_key)
+                self.kprobe.complete_step(step_tl, tokens=tokens)
             else:
                 self._abandon_kstep()
         return pending, not worked and not any(

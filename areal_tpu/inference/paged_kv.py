@@ -20,10 +20,11 @@ paged/radix allocator plays for the reference
   writes land at ``pos >= prompt_len``, so shared full pages are immutable).
 
 Attention over pages:
-- TPU: ``jax.experimental.pallas.ops.tpu.paged_attention`` (flash-style
-  kernel reading only each sequence's pages).
-- elsewhere (CPU tests / TP fallback): gather the window's pages and run the
-  same grouped masked einsum the dense engine used — identical numerics.
+- TPU: ``ops/paged_attention_q8.py paged_attention_stacked`` (flash-style
+  kernel reading only each sequence's pages, over the layer-stacked pool).
+- elsewhere (CPU tests / TP fallback): ``paged_attention_xla`` gathers the
+  window's pages and runs the same grouped masked einsum the dense engine
+  used — identical numerics.
 """
 
 from __future__ import annotations
@@ -31,24 +32,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from areal_tpu.utils.private_api import pin_signature
-
-# the library paged_attention launch wrapper is a PRIVATE pallas op called
-# positionally below (q, pages, lengths, page table); verified at first
-# use, re-checked against the installed jax by arealint PVT002
-_EXPECTED_PAGED_ATTENTION_PARAMS = (
-    "q",
-    "k_pages",
-    "v_pages",
-    "lengths",
-    "page_indices",
-    "mask_value",
-    "attn_logits_soft_cap",
-    "pages_per_compute_block",
-    "megacore_mode",
-    "inline_seq_dim",
-)
 
 
 class PagePool:
@@ -598,49 +581,3 @@ def paged_attention_xla(
     logits = jnp.where(valid[:, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(vv.dtype)
     return jnp.einsum("skgt,stkd->skgd", probs, vv).reshape(S, H, hd)
-
-
-def paged_attention_tpu(
-    q: jax.Array,  # [S, H, hd]
-    k_pages: jax.Array,  # [KH, N, psz, hd]
-    v_pages: jax.Array,
-    lengths: jax.Array,  # [S] int32
-    page_table: jax.Array,  # [S, wp] int32
-    pages_per_compute_block: int = 4,
-    k_scales: jax.Array | None = None,  # [KH, N, 1, psz] (int8/fp8 KV)
-    v_scales: jax.Array | None = None,
-) -> jax.Array:
-    """jax's Pallas TPU paged-attention kernel (grouped-query flash over the
-    page table; reads only each sequence's pages). Quantized pages go
-    through the repo's own kernel (ops/paged_attention_q8.py): the library
-    wrapper would broadcast the scales to head_dim, inverting the
-    halved-HBM premise."""
-    ppcb = choose_ppcb(page_table.shape[1], pages_per_compute_block)
-    if k_scales is not None:
-        from areal_tpu.ops.paged_attention_q8 import paged_attention_q8
-
-        # takes RAW q (applies 1/sqrt(hd) internally)
-        return paged_attention_q8(
-            q,
-            k_pages,
-            k_scales,
-            v_pages,
-            v_scales,
-            lengths,
-            page_table,
-            pages_per_compute_block=ppcb,
-        )
-    from jax.experimental.pallas.ops.tpu.paged_attention import paged_attention
-
-    pin_signature(paged_attention, _EXPECTED_PAGED_ATTENTION_PARAMS)
-    # the library kernel applies NO 1/sqrt(hd) to the logits — callers
-    # pre-scale q (verified against a dense reference in interpret mode;
-    # the XLA path above scales internally)
-    return paged_attention(
-        q * (q.shape[-1] ** -0.5),
-        k_pages,
-        v_pages,
-        lengths,
-        page_table,
-        pages_per_compute_block=ppcb,
-    )

@@ -252,9 +252,8 @@ class InferenceServer:
             out["timeline_stats"] = tl.stats()
         ks = getattr(self.engine, "kernel_stats", None)
         if ks is not None:
-            # kernel observatory (docs/perf.md "Kernel observatory"):
-            # per-pass phase means, dominant phase, roofline fraction, and
-            # the compiled-program cost registry with source provenance
+            # decode-step phases (docs/observability.md "Decode-step
+            # phases"): step counts, per-pass phase means, dominant phase
             out["kernels"] = ks()
         hb = getattr(self.engine, "hbm_ledger", None)
         if hb is not None:

@@ -832,8 +832,8 @@ def aggregator_metrics(reg: Registry | None = None) -> SimpleNamespace:
 
 
 def kernel_metrics(reg: Registry | None = None) -> SimpleNamespace:
-    """Kernel observatory (observability/kernel_probe.py): per-decode-step
-    phase attribution + roofline join (docs/perf.md "Kernel observatory")."""
+    """Decode-step observatory (observability/kernel_probe.py): per-pass
+    phase attribution (docs/observability.md "Decode-step phases")."""
     r = reg or get_registry()
     return SimpleNamespace(
         phase_seconds=r.histogram(
@@ -844,16 +844,6 @@ def kernel_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "step wall.",
             label_names=("phase",),
             buckets=FAST_BUCKETS,
-        ),
-        step_flops=r.gauge(
-            "areal_decode_step_flops",
-            "Model FLOPs of the last drained decode chunk, from the "
-            "compiled executable's cost_analysis or the analytic fallback.",
-        ),
-        roofline_fraction=r.gauge(
-            "areal_decode_roofline_fraction",
-            "Achieved over attainable FLOP/s of the last completed decode "
-            "step: attainable = min(peak FLOPs, intensity x peak HBM bw).",
         ),
     )
 

@@ -27,7 +27,6 @@ Formulas (documented in docs/observability.md "Trainer observatory"):
 
 from __future__ import annotations
 
-import time
 from typing import Any
 
 import numpy as np
@@ -47,7 +46,7 @@ CHIP_SPECS: tuple[tuple[str, float, float], ...] = (
 )
 
 # peak HBM bandwidth (bytes/s) per chip, same key scheme + match order as
-# CHIP_SPECS; the roofline's memory ceiling (kernel_probe)
+# CHIP_SPECS
 CHIP_MEMBW: tuple[tuple[str, float], ...] = (
     ("v6e", 1640e9),
     ("v6 lite", 1640e9),
@@ -96,9 +95,8 @@ def chip_hbm_bytes(
 def chip_peak_membw(
     device: Any | None = None, override_gbps: float | None = None
 ) -> float | None:
-    """Peak HBM bandwidth (bytes/s) of one chip; the roofline memory
-    ceiling. Unknown kinds return None — the roofline then degrades to a
-    compute-only ceiling rather than inventing a bandwidth."""
+    """Peak HBM bandwidth (bytes/s) of one chip. Unknown kinds return None
+    rather than an invented bandwidth."""
     if override_gbps is not None and override_gbps > 0:
         return float(override_gbps) * 1e9
     kind = _device_kind(device)
@@ -110,12 +108,13 @@ def chip_peak_membw(
     return None
 
 
-def resolve_chip_peaks(device: Any | None = None) -> tuple[float, float, str]:
-    """(peak FLOPs/s, peak bytes/s, source) for the roofline. A TPU must
-    resolve from the table above — an unknown TPU ``device_kind`` is an
-    error, never a default and never a measured stand-in. Only a non-TPU
-    backend (the CPU tests) gets host peaks measured once
-    (``calibrate_host_peaks``), so its roofline fraction is a real number."""
+def resolve_chip_peaks(
+    device: Any | None = None,
+) -> tuple[float | None, float | None, str]:
+    """(peak FLOPs/s, peak bytes/s, source) of one chip. A TPU must resolve
+    from the table above — an unknown TPU ``device_kind`` is an error, never
+    a default and never a measured stand-in. A backend that is not a TPU has
+    no peak to take a share of: ``(None, None, "none")``."""
     if device is None:
         import jax
 
@@ -129,7 +128,7 @@ def resolve_chip_peaks(device: Any | None = None) -> tuple[float, float, str]:
             "add its published peaks to CHIP_SPECS / CHIP_MEMBW "
             "(observability/hw_accounting.py)"
         )
-    return (*calibrate_host_peaks(), "calibrated")
+    return None, None, "none"
 
 
 def _device_kind(device: Any | None) -> str | None:
@@ -187,107 +186,6 @@ def train_step_flops(
     m = transformer_param_counts(mcfg)["matmul"]
     per_tok = (6 + (2 if remat else 0) + 2 * max(0, n_extra_forwards)) * m
     return float(per_tok) * float(n_tokens)
-
-
-# ---------------------------------------------------------------------------
-# decode-side analytic costs (kernel_probe fallback when the backend's
-# cost_analysis returns nothing, e.g. CPU) + host peak calibration
-# ---------------------------------------------------------------------------
-
-_DTYPE_BYTES = {
-    "float32": 4,
-    "bfloat16": 2,
-    "float16": 2,
-    "float8_e4m3fn": 1,
-    "int8": 1,
-}
-
-
-def _param_dtype_bytes(mcfg) -> int:
-    return _DTYPE_BYTES.get(str(getattr(mcfg, "dtype", "bfloat16")), 2)
-
-
-def decode_step_costs(
-    mcfg,
-    n_steps: int,
-    n_slots: int,
-    ctx_len: float,
-    kv_bytes_per_elem: int | None = None,
-) -> dict[str, float]:
-    """Analytic FLOPs + HBM bytes of one fused decode chunk (``n_steps``
-    sampling steps over ``n_slots`` batch slots at mean context
-    ``ctx_len``). Per token: 2·M matmul FLOPs + 4·L·ctx·q_dim attention
-    (QKᵀ + PV, 2 FLOPs each); bytes = the full matmul weight read once per
-    *step* (batch slots share it) + each token's KV history read."""
-    pc = transformer_param_counts(mcfg)
-    L = mcfg.num_layers
-    q_dim = mcfg.num_heads * mcfg.head_dim_
-    kv_dim = mcfg.num_kv_heads * mcfg.head_dim_
-    kvb = kv_bytes_per_elem or _param_dtype_bytes(mcfg)
-    tokens = float(n_steps) * float(n_slots)
-    attn_flops = 4.0 * L * float(ctx_len) * q_dim
-    flops = tokens * (2.0 * pc["matmul"] + attn_flops)
-    kv_read = float(ctx_len) * kv_dim * 2.0 * kvb * L
-    nbytes = (
-        float(n_steps) * pc["matmul"] * _param_dtype_bytes(mcfg)
-        + tokens * kv_read
-    )
-    return {"flops": flops, "bytes": nbytes, "tokens": tokens}
-
-
-def prefill_costs(mcfg, n_tokens: float) -> dict[str, float]:
-    """Analytic FLOPs + bytes of prefilling ``n_tokens`` prompt tokens:
-    2·M per token + causal attention 2·L·T²·q_dim; bytes = one weight
-    read + the KV write."""
-    pc = transformer_param_counts(mcfg)
-    L = mcfg.num_layers
-    q_dim = mcfg.num_heads * mcfg.head_dim_
-    kv_dim = mcfg.num_kv_heads * mcfg.head_dim_
-    T = float(n_tokens)
-    flops = 2.0 * pc["matmul"] * T + 2.0 * L * T * T * q_dim
-    b = _param_dtype_bytes(mcfg)
-    nbytes = pc["matmul"] * b + T * kv_dim * 2.0 * b * L
-    return {"flops": flops, "bytes": nbytes, "tokens": T}
-
-
-# one-time measured host peaks per backend (CPU has no CHIP_SPECS row);
-# process-lifetime cache so repeated engine constructions don't re-pay it
-_CALIBRATED: dict[str, tuple[float, float]] = {}
-
-
-def calibrate_host_peaks(force: bool = False) -> tuple[float, float]:
-    """Measure the current NON-TPU backend's achievable peak FLOPs/s (small
-    f32 matmul) and memory bandwidth (large array copy, read+write), best
-    of three after a warm-up. Init-time only — this does real device work
-    and must never be called from the decode hot path. A TPU never comes
-    here: its peaks are published numbers (``resolve_chip_peaks``)."""
-    import jax
-    import jax.numpy as jnp
-
-    backend = jax.default_backend()
-    if not force and backend in _CALIBRATED:
-        return _CALIBRATED[backend]
-    n = 384
-    a = jnp.ones((n, n), jnp.float32)
-    mm = jax.jit(lambda x, y: x @ y)
-    mm(a, a).block_until_ready()  # compile + warm
-    best_f = 0.0
-    for _i in range(3):
-        t0 = time.monotonic()
-        mm(a, a).block_until_ready()
-        dt = max(1e-9, time.monotonic() - t0)
-        best_f = max(best_f, 2.0 * n * n * n / dt)
-    big = jnp.ones((4 * 1024 * 1024,), jnp.float32)  # 16 MiB
-    cp = jax.jit(lambda x: x + 1.0)
-    cp(big).block_until_ready()
-    best_b = 0.0
-    for _i in range(3):
-        t0 = time.monotonic()
-        cp(big).block_until_ready()
-        dt = max(1e-9, time.monotonic() - t0)
-        best_b = max(best_b, 2.0 * big.nbytes / dt)
-    _CALIBRATED[backend] = (best_f, best_b)
-    return _CALIBRATED[backend]
 
 
 # ---------------------------------------------------------------------------

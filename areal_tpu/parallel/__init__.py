@@ -2,7 +2,6 @@ from areal_tpu.parallel.mesh import (  # noqa: F401
     MESH_AXES,
     BATCH_AXES,
     make_mesh,
-    mesh_from_parallel_strategy,
     batch_sharding,
     replicated,
 )

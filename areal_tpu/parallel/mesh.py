@@ -26,7 +26,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from areal_tpu.api.alloc_mode import ParallelStrategy
 from areal_tpu.api.config import MeshConfig
 
 MESH_AXES = ("data", "fsdp", "seq", "model", "expert", "pipe")
@@ -57,15 +56,6 @@ def make_mesh(cfg: MeshConfig | None = None, devices=None) -> Mesh:
     shape = tuple(sizes[a] for a in MESH_AXES)
     dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, MESH_AXES)
-
-
-def mesh_from_parallel_strategy(ps: ParallelStrategy, devices=None) -> Mesh:
-    """AllocationMode DSL strategy -> mesh: dp→fsdp (ZeRO sharding is the
-    TPU default for DP), tp→model, cp→seq, ep→expert. pp is asserted 1 —
-    GSPMD covers TPU pipelining needs (SURVEY §2.4 PP row)."""
-    assert ps.pp == 1, "pipeline parallelism: use GSPMD stage sharding (pp must be 1)"
-    cfg = MeshConfig(data=1, fsdp=ps.dp, seq=ps.cp, model=ps.tp, expert=ps.ep)
-    return make_mesh(cfg, devices)
 
 
 def batch_sharding(mesh: Mesh, extra: tuple = ()) -> NamedSharding:

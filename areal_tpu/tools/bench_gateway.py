@@ -1082,7 +1082,7 @@ async def run_local_bench(
         report["router"] = fleet.client.router.stats()
         report["router_hit_rate"] = report["fleet"]["prefix_hit_rate"]
         # the control plane's scoreboard entry: active setpoints + the
-        # decision ledger (bench.py folds this into detail.autopilot)
+        # decision ledger
         report["autopilot"] = (
             fleet.autopilot.status() if fleet.autopilot is not None else None
         )

@@ -219,26 +219,6 @@ def _cases_paged_stacked(compiled: bool = False) -> Iterator[dict]:
     for layer in (1, L - 1):
         yield case(f"stacked-int8-layer{layer}", layer, jnp.int8)
     yield case("stacked-fp8-layer1", 1, jnp.float8_e4m3fn)
-    if compiled:
-        # jax's library kernel behind paged_kv.paged_attention_tpu has no
-        # interpret switch, so it is an on-chip case only. It walks every
-        # slot's first block: no empty slots
-        def live(inp):
-            return jnp.maximum(inp["lengths"], 1)
-
-        yield {
-            "case": "library-bf16-layer0",
-            "build": _paged_build(
-                layers=L, seed=7, q_dtype=q_dtype, pages=jnp.bfloat16, **shape
-            ),
-            "kernel": lambda inp: paged_kv.paged_attention_tpu(
-                inp["q"], inp["k"][0], inp["v"][0], live(inp), inp["pt"]
-            ),
-            "reference": lambda inp: paged_kv.paged_attention_xla(
-                inp["q"], inp["k"][0], inp["v"][0], live(inp), inp["pt"]
-            ),
-            "tol": CHIP_TOL,
-        }
 
 
 # ---------------------------------------------------------------------------

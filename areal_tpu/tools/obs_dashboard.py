@@ -380,10 +380,8 @@ def render_frame(
             f"{'journal replay/stale':<24} "
             f"{_fmt(replayed or 0):>6} / {_fmt(dropped or 0)}"
         )
-    # kernel observatory (docs/perf.md "Kernel observatory"): decode-step
-    # phase means with the dominant phase highlighted, plus the fleet's
-    # achieved-roofline fraction (mean across targets — a per-engine
-    # fact like MFU, never fleet-summed)
+    # decode-step phases (docs/observability.md "Decode-step phases"):
+    # phase means with the dominant phase highlighted
     dphase_rows = []
     for ph in _DECODE_PHASES:
         s = _merged_value_labeled(
@@ -401,9 +399,6 @@ def render_frame(
         for ph, v in dphase_rows:
             label = "  " + ph + (" (dominant)" if ph == dominant else "")
             lines.append(f"{label:<24} {v:>12.6f}")
-    roofline = _mean_per_target(snap, "areal_decode_roofline_fraction")
-    if roofline is not None:
-        lines.append(f"{'decode roofline frac':<24} {roofline:>11.1%}")
     # speculative decoding (docs/serving.md "Speculative decoding"):
     # acceptance economics — drafted vs accepted tokens, the per-round
     # accepted-length mean, and allocator-level rollback churn
@@ -678,9 +673,6 @@ areal_decode_phase_seconds_count{phase="dispatch"} 10
 areal_decode_phase_seconds_bucket{phase="device_wait",le="+Inf"} 10
 areal_decode_phase_seconds_sum{phase="device_wait"} 0.2
 areal_decode_phase_seconds_count{phase="device_wait"} 10
-# HELP areal_decode_roofline_fraction Achieved fraction of the roofline ceiling.
-# TYPE areal_decode_roofline_fraction gauge
-areal_decode_roofline_fraction 0.42
 # HELP areal_spec_rounds_total Speculative draft/verify rounds executed.
 # TYPE areal_spec_rounds_total counter
 areal_spec_rounds_total 50
@@ -813,11 +805,6 @@ def self_test() -> int:
                 "dispatch (dominant)" in frame,
                 "dispatch (0.05 mean) should be highlighted as the "
                 "dominant decode phase over device_wait (0.02)",
-            ),
-            (
-                "decode roofline frac" in frame and "42.0%" in frame,
-                "frame missing fleet roofline row (0.42 per target means "
-                "to 42.0%)",
             ),
             (
                 "ttft p50/p99 (s)" in frame,

@@ -8,17 +8,6 @@ inference fleet (tiny model, CPU) behind a seeded FaultInjector dropping
 retrying transport — a one-command smoke test of the fault-tolerance
 layer for CI.
 
-``--weight-sync-self-test`` streams full weight updates against a
-2-replica in-process fleet while generation runs, and asserts the
-zero-pause property (docs/weight_sync.md): the commit fence is >= 5x
-smaller than the unpaused staging window and no in-flight request aborts.
-
-``--prefix-cache-self-test`` runs the shared-prefix workload of
-``tools/bench_prefix_cache`` and asserts cross-request radix reuse: a warm
-admission wave prefills only suffix tokens at >= 2x the cold prefill
-throughput, refcounts return to baseline, and a weight commit under the
-default policy leaves no stale-version pages matchable.
-
 ``--overload-self-test`` drives a small in-process fleet at ~2x its
 sustained capacity with the chaos stall injector running, and asserts the
 overload-safety contract (docs/request_lifecycle.md): shed requests get
@@ -60,14 +49,6 @@ surviving shards (failovers observed, the keyspace the victim owned is
 served by survivors), and the membership view converges to the two
 survivors.
 
-``--microbench-self-test`` exercises the kernel observatory (docs/perf.md
-"Kernel observatory") on CPU: the fast microbench registry runs end to
-end with non-null analytic rooflines, the compare gate stays silent on a
-self-compare and flags a seeded 2x regression on every bench, and a live
-tiny engine's per-step phase breakdown obeys the exact-sum identity
-(named phases + other_s == step wall) with a non-null steady-state
-roofline fraction via the calibrated CPU peak fallback.
-
 ``--spec-decode-self-test`` runs a spec-enabled tiny engine over an
 acceptance-friendly repetitive workload (docs/serving.md "Speculative
 decoding") and asserts acceptance rate > 0, zero leaked KV pages after
@@ -80,10 +61,9 @@ differential case grid in interpret mode against its XLA reference
 companion to the always-on static ``kernel_lint`` check.
 
 Usage: python -m areal_tpu.tools.validate_installation [--tpu]
-    [--chaos-self-test] [--weight-sync-self-test] [--prefix-cache-self-test]
-    [--overload-self-test] [--timeline-self-test] [--train-obs-self-test]
+    [--chaos-self-test] [--overload-self-test] [--timeline-self-test] [--train-obs-self-test]
     [--learning-obs-self-test] [--preemption-self-test] [--routing-self-test]
-    [--microbench-self-test] [--spec-decode-self-test]
+    [--spec-decode-self-test]
     [--gateway-tier-self-test] [--kernelcheck]
 """
 
@@ -128,21 +108,6 @@ def main(argv=None) -> int:
         action="store_true",
         help="run a 3-replica local fleet under 10%% injected faults and "
         "assert a rollout batch completes",
-    )
-    p.add_argument(
-        "--weight-sync-self-test",
-        action="store_true",
-        help="run streamed weight updates against a 2-replica local fleet "
-        "under live generation load and assert the zero-pause property "
-        "(commit fence >= 5x smaller than the staging window, no aborts)",
-    )
-    p.add_argument(
-        "--prefix-cache-self-test",
-        action="store_true",
-        help="run the shared-prefix workload (tools/bench_prefix_cache) and "
-        "assert radix reuse: warm admission prefills suffixes only at >= 2x "
-        "cold throughput, zero refcount leaks, and a weight commit leaves "
-        "no stale pages matchable",
     )
     p.add_argument(
         "--overload-self-test",
@@ -193,14 +158,6 @@ def main(argv=None) -> int:
         "above lag-0, non-zero behave-cap tail mass, and lineage records "
         "joining journal frames to step loss stats by trace id — all "
         "measured, deterministic under seeded chaos",
-    )
-    p.add_argument(
-        "--microbench-self-test",
-        action="store_true",
-        help="run the fast microbench registry on CPU (non-null analytic "
-        "rooflines), assert the compare gate flags a seeded 2x regression "
-        "per bench and stays silent on self-compare, and assert the live "
-        "engine's decode phase breakdown obeys the exact-sum identity",
     )
     p.add_argument(
         "--kernelcheck",
@@ -445,24 +402,6 @@ def main(argv=None) -> int:
     if args.chaos_self_test:
         _check("chaos", chaos_self_test, results)
 
-    if args.weight_sync_self_test:
-
-        def weight_sync():
-            from areal_tpu.tools.bench_weight_sync import self_test
-
-            return self_test()
-
-        _check("weight_sync", weight_sync, results)
-
-    if args.prefix_cache_self_test:
-
-        def prefix_cache():
-            from areal_tpu.tools.bench_prefix_cache import self_test
-
-            return self_test()
-
-        _check("prefix_cache", prefix_cache, results)
-
     if args.overload_self_test:
         _check("overload", overload_self_test, results)
 
@@ -483,9 +422,6 @@ def main(argv=None) -> int:
 
     if args.autopilot_self_test:
         _check("autopilot", autopilot_self_test, results)
-
-    if args.microbench_self_test:
-        _check("microbench", microbench_self_test, results)
 
     if args.spec_decode_self_test:
         _check("spec_decode", spec_decode_self_test, results)
@@ -1808,123 +1744,6 @@ def autopilot_self_test(
         f"gateway: interactive sheds {sheds[0]} -> {sheds[1]} after the "
         f"controller widened headroom 0 -> {headroom}; "
         f"{len(evs)} audited decisions in the flight ring"
-    )
-
-
-def microbench_self_test() -> str:
-    """Kernel-observatory smoke (docs/perf.md "Kernel observatory"):
-
-    - the fast microbench registry runs end to end on CPU, every entry
-      with a positive wall and — where the bench declares FLOPs — a
-      non-null roofline fraction (the calibrated CPU peak fallback);
-    - the compare gate is silent on a self-compare, flags a seeded 2x
-      regression on EVERY bench, and treats new/missing entries as
-      warnings, not failures;
-    - a live tiny engine's per-step phase breakdown obeys the exact-sum
-      identity (named phases + other_s == step wall) on every recorded
-      step, and its steady-state roofline fraction is non-null."""
-    import copy
-    import threading
-
-    import jax
-
-    from areal_tpu.api.config import MeshConfig, ServerConfig
-    from areal_tpu.api.io_struct import GenerationHyperparameters, ModelRequest
-    from areal_tpu.inference.decode_engine import DecodeEngine
-    from areal_tpu.models import qwen
-    from areal_tpu.observability.kernel_probe import DECODE_PHASES
-    from areal_tpu.tools import microbench as mb
-
-    # 1. fast registry end to end
-    names = mb.fast_names()
-    res = mb.run_suite(names, iters=3, warmup=1)
-    rooflines = 0
-    for name in names:
-        e = res["benches"][name]
-        assert e["wall_s"] > 0, f"{name}: non-positive wall {e['wall_s']}"
-        if e["flops"]:
-            assert e["roofline_frac"] is not None, (
-                f"{name}: declared FLOPs but null roofline (peak fallback "
-                "broken?)"
-            )
-            rooflines += 1
-    assert rooflines >= 3, f"only {rooflines} benches produced a roofline"
-
-    # 2. compare gate semantics
-    r = mb.compare(res, res)
-    assert not r["regressions"] and not r["new"] and not r["missing"], (
-        f"self-compare must be silent: {r}"
-    )
-    seeded = copy.deepcopy(res)
-    for e in seeded["benches"].values():
-        e["wall_s"] *= 2.0
-    r2 = mb.compare(seeded, res)
-    flagged = {x["bench"] for x in r2["regressions"]}
-    assert flagged == set(names), (
-        f"seeded 2x must flag every bench: {flagged} vs {set(names)}"
-    )
-    renamed = copy.deepcopy(res)
-    renamed["benches"]["brand_new"] = renamed["benches"].pop(names[0])
-    r3 = mb.compare(renamed, res)
-    assert not r3["regressions"] and r3["new"] == ["brand_new"], (
-        f"rename must warn, not fail: {r3}"
-    )
-
-    # 3. live-engine phase-sum identity + steady-state roofline
-    tiny = tiny_model_config()
-    params = qwen.init_params(jax.random.PRNGKey(0), tiny)
-    cfg = ServerConfig(
-        max_batch_size=4,
-        max_seq_len=256,
-        decode_steps_per_call=4,
-        seed=1,
-        mesh=MeshConfig(data=-1, fsdp=1, seq=1, model=1),
-    )
-    eng = DecodeEngine(cfg, params=params, model_cfg=tiny)
-    eng.initialize()
-    eng.start()
-    try:
-        done = threading.Event()
-        got: list = []
-        lock = threading.Lock()
-
-        def cb(resp):
-            with lock:
-                got.append(resp)
-                if len(got) == 4:
-                    done.set()
-
-        for i in range(4):
-            eng.submit(
-                ModelRequest(
-                    input_ids=[3 + i, 7, 9, 11],
-                    gconfig=GenerationHyperparameters(
-                        max_new_tokens=12, greedy=True
-                    ),
-                ),
-                cb,
-            )
-        assert done.wait(timeout=300.0), f"only {len(got)}/4 finished"
-        recs = eng.kprobe.recent()
-        assert recs, "no decode steps recorded by the kernel probe"
-        worst = 0.0
-        for rec in recs:
-            bd = rec["breakdown"]
-            named = sum(bd[f"{p}_s"] for p in DECODE_PHASES)
-            worst = max(worst, abs(named + bd["other_s"] - bd["total_s"]))
-        assert worst < 1e-9, f"phase-sum identity violated by {worst:.3e}s"
-        ks = eng.kernel_stats()
-        assert ks["roofline_fraction"] is not None, (
-            "steady-state roofline must be non-null on CPU (calibrated "
-            "peak fallback)"
-        )
-    finally:
-        eng.stop()
-    return (
-        f"{len(names)} benches ({rooflines} rooflines), seeded 2x flagged "
-        f"{len(flagged)}/{len(names)}, identity residual {worst:.1e}s over "
-        f"{len(recs)} steps, steady roofline "
-        f"{ks['roofline_fraction']:.4f}"
     )
 
 

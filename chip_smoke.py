@@ -176,7 +176,7 @@ def phase_device(args, on_tpu: bool) -> dict:
             len(devs) == args.chips,
             f"--chips {args.chips} but jax reports {len(devs)} devices",
         )
-    flops, membw, source = hw.resolve_chip_peaks(d)  # unknown TPU kind raises
+    flops, membw, source = hw.resolve_chip_peaks(d)  # unknown TPU kind raises; a CPU has none
     info = {
         "platform": d.platform,
         "kind": d.device_kind,
@@ -187,8 +187,8 @@ def phase_device(args, on_tpu: bool) -> dict:
         **info,
         jax=jax.__version__,
         hbm_limit_gb=round((d.memory_stats() or {}).get("bytes_limit", 0) / 1e9, 2),
-        peak_tflops=round(flops / 1e12, 1),
-        peak_membw_gbps=round(membw / 1e9, 1),
+        peak_tflops=flops and round(flops / 1e12, 1),
+        peak_membw_gbps=membw and round(membw / 1e9, 1),
         peaks_source=source,
         compile_cache_dir=cache_dir,
         datapack=native.implementation(),
@@ -420,7 +420,7 @@ def phase_serve(args, on_tpu: bool) -> None:
 
 
 def _grpo_batch(mcfg, sz: dict, seed: int):
-    """Seeded packed GRPO batch, the bench.py phase_train recipe."""
+    """Seeded packed GRPO batch."""
     import numpy as np
 
     from areal_tpu.utils.data import pad_sequences_to_tensors
@@ -473,7 +473,7 @@ def _loss_weight(d) -> float:
 
 
 def _train_engine(mcfg, sz: dict, seed: int, mesh_cfg=None, devices=None):
-    """JaxTrainEngine with the bench.py phase_train settings: bf16 params
+    """JaxTrainEngine with the train cell's settings: bf16 params
     and AdamW state, remat, one microbatch."""
     from areal_tpu.api.config import (
         MeshConfig,

@@ -7,8 +7,10 @@ trainer in this process, the real HTTP client + staleness-gated executor +
 PPO actor + mem weight updates between them.
 
 eta=0 serializes every step (generate -> train -> update); eta=2 lets
-generation for future steps overlap training. Methodology + numbers:
-docs/perf.md. Reference bar: 2.77x at fleet scale (blog/AReaL_v0_3.md)."""
+generation for future steps overlap training. A CPU timing of the overlap
+MECHANISM, not a speed: the ratio on a chip is not measured (PERF.md section
+7, `rl-async-1.5b-4chip`). Reference bar: 2.77x at fleet scale
+(blog/AReaL_v0_3.md)."""
 
 import os
 import subprocess

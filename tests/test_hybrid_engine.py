@@ -278,7 +278,5 @@ def test_refused_configurations():
     with pytest.raises(ValueError, match="speculative"):
         eng.set_speculative(True)
     assert eng._spec_cfg is None
-    # the kernel probe gets no analytic cost for this family (Qwen's count would be wrong)
-    assert eng._analytic_chunk_cost(4) is None and eng._analytic_prefill_cost(64) is None
     with pytest.raises(NotImplementedError):
         eng.model.forward_verify_paged()

@@ -147,19 +147,6 @@ def _flash_train_grad(G=1, L=2048):
     return fn, _flash_fwd(G, L)[1]
 
 
-def _library_paged():
-    from areal_tpu.inference import paged_kv
-
-    def args(S):
-        pages = S((KH, N_PAGES, PSZ, HD), jnp.bfloat16)
-        return [
-            S((SLOTS, H, HD), jnp.bfloat16), pages, pages,
-            S((SLOTS,), jnp.int32), S((SLOTS, WP), jnp.int32),
-        ]
-
-    return paged_kv.paged_attention_tpu, args
-
-
 def _tree(grad: bool):
     from areal_tpu.ops import tree_attention as ta
 
@@ -224,7 +211,6 @@ CASES = {
     # train-1.5b-packed4k: 3 packed rows of 4096 a layer
     "flash_fwd_pallas_3x4096": lambda: _flash_fwd(3, 4096),
     "flash_train_grad_3x4096": lambda: _flash_train_grad(3, 4096),
-    "library_paged_attention": _library_paged,
     "tree_attention_fwd": lambda: _tree(False),
     "tree_attention_bwd": lambda: _tree(True),
     "megablox_gmm": _gmm,

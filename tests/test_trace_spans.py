@@ -280,3 +280,6 @@ def test_lowered_programs_hold_their_scopes(program, scopes, request, monkeypatc
         lowered = (_lower_chunk if program == "chunk" else _lower_prefill)(eng)
     missing = set(scopes) - _scopes_in(lowered)
     assert not missing, f"{program} lost the scopes {sorted(missing)}"
+    # the module name is what a device trace shows and the benchmark's readers match
+    module = {"train_step": "jit_step"}.get(program, "jit_" + program)
+    assert f"module @{module} " in lowered.as_text()

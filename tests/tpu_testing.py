@@ -53,3 +53,30 @@ def random_batch(
             t["rewards"] = np.float32(rng.uniform(0, 1))
         trajs.append(t)
     return pad_sequences_to_tensors(trajs)
+
+
+def tiny_decode_engine(model_cfg=TINY_QWEN2, params=None, **server_kw):
+    """An initialized (not started) ``DecodeEngine`` on ONE CPU device: 4
+    slots x 512 context, pages of 16, 4 steps a call, one decode-chunk window
+    (``attn_window_step`` = the context), radix cache off, seed 0, seeded
+    float32 weights; ``server_kw`` overrides any ``ServerConfig`` field."""
+    import jax
+
+    from areal_tpu.api.config import MeshConfig, PrefixCacheConfig, ServerConfig
+    from areal_tpu.inference.decode_engine import DecodeEngine
+    from areal_tpu.parallel import mesh as mesh_lib
+
+    kw = dict(
+        max_batch_size=4, max_seq_len=512, page_size=16, decode_steps_per_call=4, seed=0,
+        mesh=MeshConfig(data=1, fsdp=1, seq=1, model=1), prefix_cache=PrefixCacheConfig(enabled=False),
+    )
+    kw.update(server_kw)
+    kw.setdefault("attn_window_step", kw["max_seq_len"])
+    cfg = ServerConfig(**kw)
+    if params is None:
+        params = qwen.init_params(jax.random.PRNGKey(0), model_cfg)
+    eng = DecodeEngine(
+        cfg, params=params, model_cfg=model_cfg, mesh=mesh_lib.make_mesh(cfg.mesh, devices=jax.devices()[:1])
+    )
+    eng.initialize()
+    return eng
