@@ -3301,7 +3301,8 @@ class DecodeEngine:
         return self._use_kernel
 
     def attention_impl(self) -> dict[str, str]:
-        """Which attention implementation each serving path uses — logged
+        """Which attention implementation each serving path uses (and which
+        writer the chunk program puts a decode step's KV rows with) — logged
         once at start-up and read by chip_smoke.py. ``pallas`` is the
         compiled TPU kernel, ``pallas-interpret`` the same body under the
         Pallas interpreter (off-TPU, kernel forced on), ``xla`` the
@@ -3310,6 +3311,10 @@ class DecodeEngine:
         kern = "pallas" if tpu else "pallas-interpret"
         return {
             "decode": kern if self._use_kernel else "xla",
+            # a decode step's KV rows: no choice of its own, it goes with
+            # ``decode`` (ops/paged_kv_write.py beside the decode kernel, per-head
+            # scatters beside the gather path; prefill and verify always scatter)
+            "kv_write": kern if self._use_kernel else "xla",
             # cold prefill is plain causal attention over the prompt bucket
             "prefill": "xla",
             "suffix_prefill": kern if self._suffix_kernel() else "xla",

@@ -504,6 +504,61 @@ def scatter_token_rows(
     return cache
 
 
+def write_decode_rows(
+    cache: dict,
+    layer: jax.Array,  # scalar int32
+    k: jax.Array,  # [S, KH, hd]: this step's token of every slot
+    v: jax.Array,
+    write_page: jax.Array,  # [S] int32
+    write_off: jax.Array,  # [S] int32
+    live: tuple[jax.Array, jax.Array] | None = None,
+) -> dict:
+    """A decode step's KV write of one layer: slot s's row lands at
+    cache[layer, :, write_page[s], write_off[s]], quantized with its scale
+    where the cache holds quantized pages.
+
+    ``live`` = (slots with the live ones first, how many are live) puts the
+    write on the Pallas launch (ops/paged_kv_write.py): the live slots' rows
+    only, every pool in place. Without it: XLA scatters over every slot (an
+    ended one's row goes to the trash page), ONE PER KV HEAD, because a
+    scatter whose update window spans (KH, hd) makes the TPU compiler lay the
+    whole carried pool out KH-minor, and the paged kernels need the default
+    layout (compiled for a described v5e: a pool-sized temporary and two
+    pool-sized copies a layer; per head: none). That path serves off the TPU
+    and under tensor parallelism, and is what the tests hold the kernel to."""
+    cache = dict(cache)
+    rows = {"k": k, "v": v}
+    pages, scales = ("k", "v"), ()
+    if "k_scale" in cache:
+        scales = ("k_scale", "v_scale")
+        for name in pages:
+            rows[name], scale = quantize_kv(rows[name], dtype=cache[name].dtype)
+            rows[f"{name}_scale"] = scale[..., 0]  # [S, KH]
+    rows = {name: new.astype(cache[name].dtype) for name, new in rows.items()}
+    if live is not None:
+        from areal_tpu.ops.paged_kv_write import paged_kv_write
+
+        new_pages, new_scales = paged_kv_write(
+            tuple(cache[n] for n in pages),
+            tuple(rows[n] for n in pages),
+            layer,
+            write_page,
+            write_off,
+            *live,
+            scales=tuple(cache[n] for n in scales),
+            scale_rows=tuple(rows[n] for n in scales),
+        )
+        cache.update(zip(pages, new_pages))
+        cache.update(zip(scales, new_scales))
+        return cache
+    for h in range(k.shape[1]):
+        for n in pages:
+            cache[n] = cache[n].at[layer, h, write_page, write_off].set(rows[n][:, h])
+        for n in scales:  # lane-major in the pool: [L, KH, N, 1, psz]
+            cache[n] = cache[n].at[layer, h, write_page, 0, write_off].set(rows[n][:, h])
+    return cache
+
+
 def copy_pages(
     cache: dict,
     dst: jax.Array,

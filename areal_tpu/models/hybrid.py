@@ -495,7 +495,7 @@ def mamba_decode(cfg: HybridConfig, layer: dict, h, state: dict, j, active, live
     oldest first), of which this is layer ``j``. Returns (out [S, D], the
     state with layer j advanced); rows that are not ``active`` keep theirs.
 
-    ``live`` = ``ssm_state_update.live_order(active)`` runs the recurrence
+    ``live`` = ``paged_attention_q8.live_order(active)`` runs the recurrence
     in the Pallas kernel, which reads and writes the live slots' SSM state
     only and in place; without it ``ssm_decode_step`` passes over all slots
     under a mask (off a TPU, and the form the tests hold the kernel to)."""
@@ -785,23 +785,23 @@ def forward_decode_paged(
     from areal_tpu.inference import paged_kv
 
     S = ids.shape[0]
-    H, KH = cfg.num_heads, cfg.num_kv_heads
+    H = cfg.num_heads
     lengths = (positions + 1).astype(jnp.int32)
     slot = jnp.arange(S)
     write_page = page_table[slot, positions // page_size]
     write_off = positions % page_size
     kv_quant = "k_scale" in cache
     if use_kernel:
-        from areal_tpu.ops.paged_attention_q8 import decode_schedule, paged_attention_stacked
-
-        from areal_tpu.ops.ssm_state_update import live_order
+        from areal_tpu.ops.paged_attention_q8 import decode_schedule, live_order, paged_attention_stacked
 
         attn_lengths = jnp.where(page_table[:, 0] == 0, 0, lengths)  # see qwen.forward_decode_paged
         ppcb = paged_kv.choose_ppcb(page_table.shape[1])
         schedule = decode_schedule(attn_lengths, page_table.shape[1], page_size, ppcb)
         live = live_order(active)  # the state kernel's work list, made once a step
+        with jax.named_scope("kv_write"):
+            kv_live = live_order(page_table[:, 0] != 0)  # the KV writer's: qwen.forward_decode_paged
     else:
-        live = None
+        live = kv_live = None
     rm = cfg.residual_multiplier
 
     def step(kind, carry, layer, j):
@@ -815,17 +815,8 @@ def forward_decode_paged(
             with jax.named_scope("attn_proj"):
                 h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
                 q, k, v = (_lane_pad(cfg, t) for t in _qkv(cfg, layer, h))
-            with jax.named_scope("kv_write"):  # one scatter per KV head: qwen.forward_decode_paged
-                if kv_quant:
-                    kq, ksc = paged_kv.quantize_kv(k, dtype=c["k"].dtype)
-                    vq, vsc = paged_kv.quantize_kv(v, dtype=c["v"].dtype)
-                    for name, sc in (("k_scale", ksc), ("v_scale", vsc)):
-                        for hh in range(KH):
-                            c[name] = c[name].at[j, hh, write_page, 0, write_off].set(sc[:, hh, 0])
-                    k, v = kq, vq
-                for name, val in (("k", k), ("v", v)):
-                    for hh in range(KH):
-                        c[name] = c[name].at[j, hh, write_page, write_off].set(val[:, hh].astype(c[name].dtype))
+            with jax.named_scope("kv_write"):
+                c = paged_kv.write_decode_rows(c, j, k, v, write_page, write_off, kv_live)
             with jax.named_scope("attn"):
                 if use_kernel:
                     attn = paged_attention_stacked(

@@ -1303,13 +1303,16 @@ def forward_decode_paged(
     """One incremental step for all S slots over the *paged* KV cache.
 
     Every slot is stepped, ended ones too: what they write lands in the
-    trash page or in a row decode rewrites before reading, so ``active``
-    (which a family with recurrent state needs, models/hybrid.py) changes
-    nothing here.
+    trash page (gather path), nowhere (kernel path: a slot whose table row
+    starts at the trash page is in neither kernel's work list) or in a row
+    decode rewrites before reading, so ``active`` (which a family with
+    recurrent state needs, models/hybrid.py) changes nothing here.
 
     The current token's k/v lands at page ``table[s, pos//psz]`` row
-    ``pos % psz``; attention reads each slot's pages via the TPU
-    paged-attention kernel (inference/paged_kv.py), or a gather + grouped
+    ``pos % psz`` (``paged_kv.write_decode_rows``: one Pallas launch a layer
+    over the live slots under ``use_kernel``, per-head XLA scatters
+    otherwise); attention reads each slot's pages via the TPU
+    paged-attention kernel (ops/paged_attention_q8.py), or a gather + grouped
     einsum off-TPU. This is the serving design SURVEY §7.1 specifies in
     place of the reference's SGLang paged/radix attention
     (reference blog/AReaL_v0_3.md:266): KV HBM ∝ used tokens, so 4K–32K
@@ -1331,6 +1334,7 @@ def forward_decode_paged(
     if use_kernel:
         from areal_tpu.ops.paged_attention_q8 import (
             decode_schedule,
+            live_order,
             paged_attention_stacked,
         )
 
@@ -1342,6 +1346,13 @@ def forward_decode_paged(
         attn_lengths = jnp.where(page_table[:, 0] == 0, 0, lengths)
         ppcb = paged_kv.choose_ppcb(page_table.shape[1])
         schedule = decode_schedule(attn_lengths, page_table.shape[1], page_size, ppcb)
+        # the same slots are the ones whose row is written (one Pallas launch
+        # a layer, ops/paged_kv_write.py): an ended slot's row, which the
+        # scatters send to the trash page, is not written at all
+        with jax.named_scope("kv_write"):
+            kv_live = live_order(page_table[:, 0] != 0)
+    else:
+        kv_live = None
 
     def body(carry, scanned):
         x, c = carry
@@ -1363,29 +1374,7 @@ def forward_decode_paged(
             k = _rope(k, pos1, cfg.rope_theta)[:, 0]  # [S, KH, hd]
             v = v[:, 0]
         with jax.named_scope("kv_write"):
-            # write the step's rows into (li, h, page[s], offset[s]), ONE
-            # SCATTER PER KV HEAD. A single scatter over all heads has (KH, hd)
-            # update windows, for which the TPU compiler lays the whole carried
-            # cache out KH-minor — and the Pallas kernel below needs the default
-            # layout, so it would re-lay the ENTIRE cache out twice per layer
-            # per step (compiled for a described v5e: a cache-sized temp and two
-            # cache-sized copies in the layer loop; per head: none).
-            c = dict(c)
-            if kv_quant:
-                kq, ksc = paged_kv.quantize_kv(k, dtype=cache["k"].dtype)
-                vq, vsc = paged_kv.quantize_kv(v, dtype=cache["v"].dtype)
-                # scales are lane-major in the pool: [L, KH, N, 1, psz]
-                for name, sc in (("k_scale", ksc), ("v_scale", vsc)):
-                    for h in range(KH):
-                        c[name] = c[name].at[li, h, write_page, 0, write_off].set(
-                            sc[:, h, 0]
-                        )
-                k, v = kq, vq
-            for name, val in (("k", k), ("v", v)):
-                for h in range(KH):
-                    c[name] = c[name].at[li, h, write_page, write_off].set(
-                        val[:, h].astype(c[name].dtype)
-                    )
+            c = paged_kv.write_decode_rows(c, li, k, v, write_page, write_off, kv_live)
         with jax.named_scope("attn"):
             if use_kernel:
                 # STACKED launch: the kernel slices ref.at[li] internally. A

@@ -100,6 +100,15 @@ def decode_schedule(
     return slot, block, end[-1:]
 
 
+def live_order(live: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(slot indices with the live ones first, how many are live) of a [S]
+    bool mask: the work list of the kernels that visit a slot once
+    (ops/paged_kv_write.py, ops/ssm_state_update.py), the same for every
+    layer of a step."""
+    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+    return order, jnp.sum(live).astype(jnp.int32)
+
+
 def _decode_kernel(
     lengths_ref,  # SMEM [S] int32 — valid tokens per slot
     pidx_ref,  # SMEM [S * pps] int32 — flat page table

@@ -153,10 +153,3 @@ def ssm_state_update_stacked(
         ssm,
     )
     return out, jnp.swapaxes(y_t, 1, 2)
-
-
-def live_order(active: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """(slot indices with the live ones first, how many are live) of a [S]
-    bool mask: the kernel's work list, the same for every layer of a step."""
-    order = jnp.argsort(~active, stable=True).astype(jnp.int32)
-    return order, jnp.sum(active).astype(jnp.int32)

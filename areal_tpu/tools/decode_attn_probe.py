@@ -1,9 +1,10 @@
-"""Time ``paged_decode_attn`` alone on the chip at the rollout cells' shapes.
+"""Time the decode step's two paged kernels alone on the chip at the rollout
+cells' shapes: ``paged_decode_attn`` and ``paged_kv_write``.
 
     chiprun -- python -m areal_tpu.tools.decode_attn_probe
 
-One launch per layer over a stacked bf16 pool, as the decode step makes
-them (128-token pages, a 32-page table), for three sets of lengths:
+``paged_decode_attn``: one launch per layer over a stacked bf16 pool, as the decode step
+makes them (128-token pages, a 32-page table), for three sets of lengths:
 
   ``mix``     36% of the slots live, lengths drawn like the benchmark's
               ``grpo-reasoning`` traffic (prompt log-uniform 128-1024 plus a
@@ -13,7 +14,16 @@ them (128-token pages, a 32-page table), for three sets of lengths:
               512-token blocks cost against ``mix``
 
 and prints microseconds a launch, the KV bytes the lengths need and their
-share of the chip's memory roofline. TPU only: a CPU time is no speed.
+share of the chip's memory roofline.
+
+``paged_kv_write``: a step's KV rows of every layer written into the stacked
+pools (``paged_kv.write_decode_rows``) by the per-head XLA scatters over every
+slot and by the one ``paged_kv_write`` launch over the live slots, at 0 / 25 /
+50 / 100% of the slots live, bf16 and int8 pages: microseconds a layer, and
+whether both left the same bits in every page but the trash page (the pools
+are random, so a tile of another layer, head, page or slot put back shows).
+
+TPU only: a CPU time is no speed.
 """
 
 from __future__ import annotations
@@ -28,7 +38,10 @@ SHAPES = {  # the benchmark's rollout cells (BENCHMARK.json)
     "rollout-1.5b-grpo": dict(S=128, KH=2, G=6, L=28),
     "rollout-7b-d14-grpo": dict(S=64, KH=4, G=7, L=14),
 }
+# the hybrid cell's 4 attention layers: 8 KV heads of 64 padded to 128 lanes
+WRITE_SHAPES = {**SHAPES, "rollout-granite-h-micro-grpo": dict(S=64, KH=8, G=4, L=4)}
 HD, PSZ, WP, LIVE = 128, 128, 32, 0.36
+STEPS = 32  # decode steps a chunk program (the cells' ``steps_per_call``)
 HBM_BYTES_S = 819e9  # TPU v5e, as benchmarks/chip/benchlib/peaks.py
 
 
@@ -89,6 +102,74 @@ def probe(name: str, *, seed: int, reps: int, ppcb: int, pages: int = 1200) -> d
     return res
 
 
+def probe_write(name: str, *, seed: int, reps: int, quant: bool, pages: int = 400) -> dict:
+    """us a layer of a decode step's KV write, scatters against the kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.ops.paged_attention_q8 import live_order
+
+    S, KH, L = (WRITE_SHAPES[name][k] for k in ("S", "KH", "L"))
+    rng = np.random.default_rng(seed)
+    write_page = jnp.asarray(1 + rng.permutation(pages - 1)[:S], jnp.int32)  # a page of its own a slot
+    write_off = jnp.asarray(rng.integers(0, PSZ, S), jnp.int32)
+    kk, kv = jax.random.split(jax.random.PRNGKey(seed))
+    k = jax.random.normal(kk, (L, S, KH, HD), jnp.bfloat16)
+    v = jax.random.normal(kv, (L, S, KH, HD), jnp.bfloat16)
+
+    @jax.jit
+    def fresh():
+        """Pools in which no two tiles are alike, whatever their layer, head,
+        page or rows: a tile that goes back stale, or to another place, shows."""
+        keys = jax.random.split(jax.random.PRNGKey(seed + 1), 4)
+        shape = (L, KH, pages, PSZ, HD)
+        if not quant:
+            return {n: jax.random.normal(key, shape, jnp.bfloat16) for n, key in zip("kv", keys)}
+        cache = {n: jax.random.randint(key, shape, -127, 128, jnp.int8) for n, key in zip("kv", keys)}
+        for n, key in zip("kv", keys[2:]):
+            cache[f"{n}_scale"] = jax.random.uniform(key, (L, KH, pages, 1, PSZ), jnp.float32, 0.5, 1.5)
+        return cache
+
+    def chunk(cache, k, v, table_head, kernel):
+        """``STEPS`` decode steps' writes in one program, as the engine's chunk
+        program makes them (a call's dispatch is 0.25 ms on the host)."""
+        page = jnp.where(table_head == 0, 0, write_page)  # an ended slot's table row is the trash page
+
+        def step(c, _):
+            live = live_order(table_head != 0) if kernel else None  # once a step
+
+            def layer(c, xs):
+                li, kl, vl = xs
+                return paged_kv.write_decode_rows(c, li, kl, vl, page, write_off, live), None
+
+            return jax.lax.scan(layer, c, (jnp.arange(L, dtype=jnp.int32), k, v))[0], None
+
+        return jax.lax.scan(step, cache, None, length=STEPS)[0]
+
+    steps = {
+        "scatter": jax.jit(lambda c, k, v, t: chunk(c, k, v, t, False), donate_argnums=0),
+        "kernel": jax.jit(lambda c, k, v, t: chunk(c, k, v, t, True), donate_argnums=0),
+    }
+    same = jax.jit(lambda a, b: jnp.array_equal(a[:, :, 1:], b[:, :, 1:]))  # every page but the trash page
+    res = {"shape": name, "pages": "int8" if quant else "bf16", "slots": S, "kv_heads": KH, "layers": L}
+    for share in (0.0, 0.25, 0.5, 1.0):
+        head = np.zeros(S, np.int32)
+        head[rng.permutation(S)[: round(share * S)]] = 1
+        head = jnp.asarray(head)
+        caches = {label: fn(fresh(), k, v, head) for label, fn in steps.items()}
+        res[f"same_bits_{int(100 * share)}"] = all(bool(same(caches["scatter"][n], caches["kernel"][n])) for n in caches["scatter"])
+        for label, fn in steps.items():
+            cache = caches.pop(label)
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                cache = fn(cache, k, v, head)
+            jax.block_until_ready(cache)
+            res[f"{label}_us_{int(100 * share)}"] = (time.perf_counter() - t0) / (reps * STEPS * L) * 1e6
+            del cache
+    return res
+
+
 def main() -> int:
     import jax
 
@@ -101,7 +182,10 @@ def main() -> int:
         print("decode_attn_probe: needs a TPU (a CPU time is no speed)")
         return 2
     for name in SHAPES:
-        print(json.dumps(probe(name, seed=args.seed, reps=args.reps, ppcb=args.ppcb)))
+        print(json.dumps(probe(name, seed=args.seed, reps=args.reps, ppcb=args.ppcb)), flush=True)
+    for name in WRITE_SHAPES:
+        for quant in (False, True):
+            print(json.dumps(probe_write(name, seed=args.seed, reps=args.reps, quant=quant)), flush=True)
     return 0
 
 

@@ -222,6 +222,94 @@ def _cases_paged_stacked(compiled: bool = False) -> Iterator[dict]:
 
 
 # ---------------------------------------------------------------------------
+# a decode step's KV rows (ops/paged_kv_write.py): one launch over the live
+# slots against the per-head scatters, BIT-EQUAL over the whole pool
+# ---------------------------------------------------------------------------
+
+
+@register_kernel("paged_kv_write")
+def _cases_paged_kv_write(compiled: bool = False) -> Iterator[dict]:
+    import jax.numpy as jnp
+
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.ops.paged_kv_write import paged_kv_write
+    from areal_tpu.ops.paged_attention_q8 import live_order
+
+    L = 3
+    S, psz, hd = (64, 128, 128) if compiled else (6, 16, 128)
+    N = S + 1
+
+    def case(label, layer, pages, KH, live_share=0.5, seed=11):
+        rng = np.random.default_rng(seed)
+        live = rng.permutation(S) < round(live_share * S)
+        page = np.where(live, 1 + rng.permutation(S), 0).astype(np.int32)  # a page of its own a live slot
+        off = rng.integers(0, psz, S).astype(np.int32)
+        off[:3] = (0, psz - 1, 7)
+        quant = pages != jnp.bfloat16
+
+        def build():
+            cache = {"k": _normal(seed, (L, KH, N, psz, hd)), "v": _normal(seed + 1, (L, KH, N, psz, hd))}
+            rows = {"k": _normal(seed + 2, (S, KH, hd)), "v": _normal(seed + 3, (S, KH, hd))}
+            for n in ("k", "v"):
+                if quant:
+                    cache[n], cache[f"{n}_scale"] = paged_kv.quantize_pages(cache[n], dtype=pages)
+                    rows[n], scale = paged_kv.quantize_kv(rows[n], dtype=pages)
+                    rows[f"{n}_scale"] = scale[..., 0]
+                else:
+                    cache[n], rows[n] = cache[n].astype(pages), rows[n].astype(pages)
+            return {
+                "cache": cache, "rows": rows,
+                "live": jnp.asarray(live), "page": jnp.asarray(page), "off": jnp.asarray(off),
+            }
+
+        def flat(cache):  # every page but the trash page, where the scatters send an ended slot's row
+            return jnp.concatenate([cache[n][:, :, 1:].astype(jnp.float32).reshape(-1) for n in sorted(cache)])
+
+        # two launches back to back, as a step's layers are: the first, on the
+        # layer before and with the slots in another order, leaves other tiles
+        # in the kernel's VMEM, so that one the second used before it had
+        # landed would go back with the wrong rows
+        before = (layer - 1) % L
+
+        def kernel(inp):
+            cache, rows = inp["cache"], inp["rows"]
+            scales = tuple(n for n in sorted(cache) if n.endswith("_scale"))
+            order, n_live = live_order(inp["live"])
+            out = cache
+            for li, first in ((before, True), (layer, False)):
+                new_pages, new_scales = paged_kv_write(
+                    (out["k"], out["v"]), (rows["k"], rows["v"]), jnp.int32(li), inp["page"], inp["off"],
+                    jnp.roll(order, -n_live)[::-1] if first else order, n_live,  # live slots first in both
+                    scales=tuple(out[n] for n in scales), scale_rows=tuple(rows[n] for n in scales),
+                    interpret=not compiled,
+                )
+                out = dict(zip(("k", "v", *scales), (*new_pages, *new_scales)))
+            # the trash page is as it was
+            same_trash = jnp.stack([(out[n][:, :, 0] == cache[n][:, :, 0]).all() for n in out]).all()
+            return flat(out) + jnp.where(same_trash, 0.0, jnp.inf)
+
+        def reference(inp):
+            out = dict(inp["cache"])
+            for li in (before, layer):
+                for n, new in inp["rows"].items():
+                    for h in range(KH):
+                        at = (li, h, inp["page"], 0, inp["off"]) if n.endswith("_scale") else (li, h, inp["page"], inp["off"])
+                        out[n] = out[n].at[at].set(new[:, h])
+            return flat(out)
+
+        return {"case": label, "build": build, "kernel": kernel, "reference": reference, "tol": 0.0}
+
+    for KH in (2, 4, 8):  # the three rollout cells
+        yield case(f"bf16-kh{KH}-layer0", 0, jnp.bfloat16, KH)
+    yield case(f"bf16-kh2-layer{L - 1}", L - 1, jnp.bfloat16, 2)
+    yield case("bf16-kh2-all-live", 1, jnp.bfloat16, 2, live_share=1.0)
+    yield case("bf16-kh2-none-live", 1, jnp.bfloat16, 2, live_share=0.0)
+    yield case("int8-kh2-layer1", 1, jnp.int8, 2)
+    yield case("int8-kh8-layer0", 0, jnp.int8, 8)
+    yield case("fp8-kh4-layer1", 1, jnp.float8_e4m3fn, 4)
+
+
+# ---------------------------------------------------------------------------
 # paged suffix attention (ops/paged_suffix_attention.py): suffix-prefill
 # (chain mask) + tree-verify (ancestor mask) over bf16/int8/fp8 pages
 # ---------------------------------------------------------------------------

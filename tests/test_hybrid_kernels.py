@@ -26,6 +26,7 @@ import chipbench_hybrid_util as hu  # noqa: E402
 from areal_tpu.inference import paged_kv  # noqa: E402
 from areal_tpu.models import hybrid  # noqa: E402
 from areal_tpu.ops import ssm_state_update as ssu  # noqa: E402
+from areal_tpu.ops.paged_attention_q8 import live_order  # noqa: E402
 
 
 @pytest.mark.parametrize("dtype,groups", [("float32", 1), ("float32", 2), ("bfloat16", 1)])
@@ -42,7 +43,7 @@ def test_state_kernel_matches_the_masked_recurrence(dtype, groups):
     tol = dict(rtol=1e-5, atol=1e-5) if dtype == "float32" else dict(rtol=1e-2, atol=1e-2)
     for mask in ([1, 0, 1, 1, 0, 0], [0] * 6, [1] * 6, [0, 0, 0, 0, 1, 0]):
         active = jnp.asarray(mask, bool)
-        out, y = ssu.ssm_state_update_stacked(ssm, 1, x, b, c, dt, a, *ssu.live_order(active), interpret=True)
+        out, y = ssu.ssm_state_update_stacked(ssm, 1, x, b, c, dt, a, *live_order(active), interpret=True)
         assert out.dtype == ssm.dtype
         np.testing.assert_allclose(np.asarray(out[1][active], np.float32), np.asarray(new[active]), **tol)
         np.testing.assert_allclose(np.asarray(y[active]), np.asarray(y_all[active]), rtol=1e-4, atol=1e-4)
@@ -54,12 +55,14 @@ def test_state_kernel_matches_the_masked_recurrence(dtype, groups):
 
 def test_decode_step_with_both_kernels_matches_the_gather_path(monkeypatch):
     import areal_tpu.ops.paged_attention_q8 as q8mod
+    import areal_tpu.ops.paged_kv_write as kvw
 
     hu.load_run()
     from benchlib import hybrid_weights
 
     monkeypatch.setattr(q8mod, "paged_attention_stacked", functools.partial(q8mod.paged_attention_stacked, interpret=True))
     monkeypatch.setattr(ssu, "ssm_state_update_stacked", functools.partial(ssu.ssm_state_update_stacked, interpret=True))
+    monkeypatch.setattr(kvw, "paged_kv_write", functools.partial(kvw.paged_kv_write, interpret=True))
     cfg = hu.tiny_model(("mamba", "attention", "mamba"))
     cfg.update(mamba_d_state=128)  # the kernel's tile has the state dimension on the 128 lanes
     mcfg = hu.model_config(cfg)

@@ -74,6 +74,7 @@ def test_decode_step_leaves_ended_slots_out(monkeypatch):
     the trash page: the decode step hands the kernel length 0 for it (no
     item in the work list), and live slots read as on the gather path."""
     import areal_tpu.ops.paged_attention_q8 as q8mod
+    import areal_tpu.ops.paged_kv_write as kvw
     from areal_tpu.models import qwen
 
     seen = {}
@@ -84,6 +85,7 @@ def test_decode_step_leaves_ended_slots_out(monkeypatch):
 
     real = q8mod.paged_attention_stacked
     monkeypatch.setattr(q8mod, "paged_attention_stacked", spy)
+    monkeypatch.setattr(kvw, "paged_kv_write", functools.partial(kvw.paged_kv_write, interpret=True))
     cfg = qwen.ModelConfig(
         vocab_size=256, hidden_size=128, intermediate_size=256, num_layers=2,
         num_heads=8, num_kv_heads=2, head_dim=16, dtype="float32",

@@ -111,6 +111,7 @@ def test_full_decode_step_composition_interpret(quant, monkeypatch):
     import functools
 
     import areal_tpu.ops.paged_attention_q8 as q8mod
+    import areal_tpu.ops.paged_kv_write as kvw
     from areal_tpu.models import qwen
 
     monkeypatch.setattr(
@@ -118,6 +119,7 @@ def test_full_decode_step_composition_interpret(quant, monkeypatch):
         "paged_attention_stacked",
         functools.partial(q8mod.paged_attention_stacked, interpret=True),
     )
+    monkeypatch.setattr(kvw, "paged_kv_write", functools.partial(kvw.paged_kv_write, interpret=True))
     cfg = qwen.ModelConfig(
         vocab_size=256,
         hidden_size=128,

@@ -181,6 +181,9 @@ def test_engine_kernel_on_greedy_parity_twin():
         eng = DecodeEngine(cfg, params=params, model_cfg=TINY_QWEN2)
         eng.initialize()
         eng.set_suffix_kernel(use_kernel)
+        # the decode step's kernel and the writer of its KV rows go together
+        impl = eng.attention_impl()
+        assert impl["kv_write"] == impl["decode"] == "xla", impl
         eng.start()
         out = {}
         try:

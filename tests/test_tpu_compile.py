@@ -10,9 +10,11 @@ against passed its interpret-mode tests first.
 Each case lowers with ``interpret=False`` at Qwen2.5-1.5B head shapes (12
 heads / 2 KV heads / head_dim 128, 28 layers, 128-token pages) and asserts
 the compiled module holds a ``tpu_custom_call``. About two seconds a case;
-no whole-model program is compiled here (tier-1 has no room for one).
+the one whole-model program compiled here is two decode steps over scanned
+layers (4 s): tier-1 has no room for an engine's programs.
 """
 
+import functools
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
@@ -107,7 +109,8 @@ def _ssm_state(dtype):
     """The recurrent-state update of one Mamba-2 layer at the published sizes
     of ``rollout-granite-h-micro-grpo``: 36 layers x 64 slots of 64 heads x
     64 x 128, updated in place for the live slots."""
-    from areal_tpu.ops.ssm_state_update import live_order, ssm_state_update_stacked
+    from areal_tpu.ops.paged_attention_q8 import live_order
+    from areal_tpu.ops.ssm_state_update import ssm_state_update_stacked
 
     def fn(ssm, li, x, b, c, dt, a, active):
         return ssm_state_update_stacked(ssm, li, x, b, c, dt, a, *live_order(active))
@@ -118,6 +121,26 @@ def _ssm_state(dtype):
             S((36, 64, 64, 64, 128), dtype), S((), jnp.int32), S((64, 64, 64), f32), S((64, 1, 128), f32),
             S((64, 1, 128), f32), S((64, 64), f32), S((64,), f32), S((64,), jnp.bool_),
         ]
+
+    return fn, args
+
+
+def _kv_write(page_dtype, slots=SLOTS, kh=KH, layers=L, pages=2340):
+    """A decode step's KV rows of one layer written by the one launch, at a
+    benchmark cell's pool and slots (default: ``rollout-1.5b-grpo``'s
+    ``[28, 2, 2340, 128, 128]`` x 128); int8 pages bring their scale rows."""
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.ops.paged_attention_q8 import live_order
+
+    def fn(cache, li, k, v, page, off, live):
+        return paged_kv.write_decode_rows(cache, li, k, v, page, off, live_order(live))
+
+    def args(S):
+        cache = {n: S((layers, kh, pages, PSZ, HD), page_dtype) for n in ("k", "v")}
+        if page_dtype != jnp.bfloat16:
+            cache.update({f"{n}_scale": S((layers, kh, pages, 1, PSZ), jnp.float32) for n in ("k", "v")})
+        rows = S((slots, kh, HD), jnp.bfloat16)
+        return [cache, S((), jnp.int32), rows, rows, S((slots,), jnp.int32), S((slots,), jnp.int32), S((slots,), jnp.bool_)]
 
     return fn, args
 
@@ -197,6 +220,13 @@ CASES = {
     # 1/64 from the configuration; int8 pages are its control
     "paged_decode_h64pad_bf16": lambda: _decode(jnp.bfloat16, 64, 8, 4, 4, sm_scale=1 / 64),
     "paged_decode_h64pad_int8": lambda: _decode(jnp.int8, 64, 8, 4, 4, sm_scale=1 / 64),
+    # the decode step's KV write at the three rollout cells' pools and slots
+    "paged_kv_write_bf16": lambda: _kv_write(jnp.bfloat16),
+    "paged_kv_write_int8": lambda: _kv_write(jnp.int8),
+    "paged_kv_write_7b_bf16": lambda: _kv_write(jnp.bfloat16, 64, 4, 14, 1170),
+    "paged_kv_write_7b_int8": lambda: _kv_write(jnp.int8, 64, 4, 14, 1170),
+    "paged_kv_write_h64pad_bf16": lambda: _kv_write(jnp.bfloat16, 64, 8, 4, 1280),
+    "paged_kv_write_h64pad_int8": lambda: _kv_write(jnp.int8, 64, 8, 4, 1280),
     "ssm_state_update_f32": lambda: _ssm_state(jnp.float32),
     "ssm_state_update_bf16": lambda: _ssm_state(jnp.bfloat16),
     # the engine's smallest and largest suffix buckets at max_seq_len 2048
@@ -232,6 +262,7 @@ KERNEL_NAMES = {
     "suffix_prefill_B256": ("paged_suffix_attn",),
     "flash_fwd_pallas": ("flash_fwd",),
     "ssm_state_update_f32": ("ssm_state_update",),
+    "paged_kv_write_int8": ("paged_kv_write",),
     "tree_attention_bwd": ("tree_attn_fwd", "tree_attn_bwd_dq", "tree_attn_bwd_dkv"),
 }
 
@@ -242,6 +273,49 @@ def test_kernel_carries_its_name_on_v5e(chip, name):
     text = jax.jit(fn).lower(*args(chip)).compile().as_text()
     for kernel in KERNEL_NAMES[name]:
         assert kernel in text, f"{kernel} not in the compiled {name}"
+
+
+@pytest.mark.parametrize("kv_quant", [False, "int8"], ids=["bf16", "int8"])
+def test_decode_steps_update_the_pool_in_place(chip, kv_quant):
+    """The chunk program's loop at ``rollout-1.5b-grpo``'s sizes (28 scanned
+    layers, 128 slots, the 8 GB pool donated, two steps fed back greedily):
+    both kernels are in it by name, no scatter touches the pool, and the
+    compiled program holds no pool-sized temporary or copy (the check PR 21
+    made for the all-heads scatter, which cost a temporary and two copies a
+    layer)."""
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.models import qwen
+
+    cfg = qwen.ModelConfig(
+        vocab_size=151936, hidden_size=1536, intermediate_size=8960, num_layers=L, num_heads=H, num_kv_heads=KH,
+        head_dim=HD, dtype="bfloat16", tie_word_embeddings=True, attention_bias=True,
+    )
+
+    def described(tree):
+        return jax.tree.map(lambda x: chip(x.shape, x.dtype), tree)
+
+    params = described(jax.eval_shape(lambda: qwen.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = described(jax.eval_shape(lambda: paged_kv.init_paged_cache(cfg, 2340, PSZ, quant=kv_quant)))
+
+    def steps(params, cache, ids, pos, table):
+        def step(carry, _):
+            ids, pos, cache = carry
+            hid, cache = qwen.forward_decode_paged(params, cfg, ids, pos, cache, table, page_size=PSZ, use_kernel=True)
+            ids = jnp.argmax(qwen.compute_logits(params, cfg, hid), -1).astype(jnp.int32)
+            return (ids, pos + 1, cache), ids
+
+        (_, _, cache), toks = jax.lax.scan(step, (ids, pos, cache), None, length=2)
+        return cache, toks
+
+    i32 = functools.partial(chip, dtype=jnp.int32)
+    compiled = jax.jit(steps, donate_argnums=1).lower(params, cache, i32((SLOTS,)), i32((SLOTS,)), i32((SLOTS, 32))).compile()
+    text = compiled.as_text()
+    assert "paged_kv_write" in text and "paged_decode_attn" in text
+    pool = f"[{L},{KH},2340,{PSZ},{HD}]"
+    touched = [line for line in text.splitlines() if pool in line and (" scatter(" in line or " copy(" in line)]
+    assert not touched, touched[:3]
+    pool_bytes = max(x.size * x.dtype.itemsize for x in cache.values())
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8  # the [128, 151936] logits, not a pool
 
 
 def test_static_shape_rule_matches_the_compiler(chip):
