@@ -522,7 +522,8 @@ class DecodeEngine:
         if cfg.quantization == "int8":
             raise ValueError(
                 "int8 weight quantization is not implemented for the "
-                "state-space mixer; serve this model with quantization='none'"
+                "hybrid family's mixers and experts; serve this model with "
+                "quantization='none'"
             )
         if int(np.prod(list(self.mesh.shape.values()))) != 1:
             raise ValueError(
@@ -653,6 +654,8 @@ class DecodeEngine:
         self._slot_page_versions: list[list[int]] = [[] for _ in range(S)]
         self._pt_host = np.zeros((S, self._maxp), np.int32)
         self._obs.state_bytes.set(self._state_bytes())
+        load_shape = mcfg.moe_count_shapes.get("moe_load")
+        self._moe_load = np.zeros(load_shape, np.int64) if load_shape else None
         pc = getattr(cfg, "prefix_cache", None)
         if mcfg.has_recurrent_state:
             # a page prefix says nothing of the recurrent state behind it
@@ -1806,6 +1809,25 @@ class DecodeEngine:
             exclude_from_total=("radix_cache",),
         )
 
+    def _credit_moe_counts(self, flat: np.ndarray) -> None:
+        """A drained chunk's expert-load counts (the order of
+        ``model_cfg.moe_count_shapes``) into the host's running sums."""
+        shapes = self.model_cfg.moe_count_shapes
+        n_load = int(np.prod(shapes["moe_load"]))
+        load = flat[:n_load].reshape(shapes["moe_load"]).astype(np.int64)
+        touched = int(flat[n_load : n_load + shapes["moe_touched"][0]].sum())
+        # arealint: disable-next=THR001 single writer (the decode loop, at a drain); /statusz reads whichever whole array the name holds: a rebind, never an in-place add
+        self._moe_load = self._moe_load + load
+        self._obs.moe_assignments.inc(int(load.sum()))
+        self._obs.moe_experts_touched.inc(touched)
+
+    def moe_status(self) -> dict | None:
+        """/statusz ``moe``: ``load`` = rows of live slots every expert of
+        every expert layer got from decode steps since the engine started,
+        [expert layers][experts]; None for a model without experts."""
+        load = getattr(self, "_moe_load", None)  # None before initialize()
+        return None if load is None else {"load": load.tolist()}
+
     def _state_bytes(self) -> int:
         """Device bytes of the slot-indexed recurrent state (0 for a model
         without recurrent layers, or while the cache is released)."""
@@ -2023,7 +2045,9 @@ class DecodeEngine:
         Returns (cache, state, rng, packed) where ``packed`` is ONE int32
         array [2*n_steps + 3, S] — token rows, logprob-bit rows (fp32
         bitcast), then emit_count / final-active / final-pos rows — so the
-        host pays a single device->host transfer per chunk. Emission is
+        host pays a single device->host transfer per chunk. A model with
+        sparse experts appends its expert-load counts of the chunk
+        (``model_cfg.moe_count_shapes``, flat, in whole rows of S). Emission is
         monotone within a chunk (a stopped slot never re-activates; admits
         happen between chunks), so per-slot counts fully describe the
         emit mask."""
@@ -2035,7 +2059,14 @@ class DecodeEngine:
             use_kernel = self._use_kernel
             model = self.model
 
+            counts_of = dict(mcfg.moe_count_shapes)
+
             def chunk(params, cache, page_table, state, rng):
+                # expert-load counts of this chunk's steps: zeroed here, added
+                # to by the model's forward for the active slots only, and
+                # handed back in ``packed`` (they are no part of the cache)
+                cache = {**cache, **{k: jnp.zeros(shp, jnp.int32) for k, shp in counts_of.items()}}
+
                 def step(carry, _):
                     ids, pos, active, remaining, counts, cache, rng = carry
                     hidden, cache = model.forward_decode_paged(
@@ -2112,6 +2143,12 @@ class DecodeEngine:
                 out_state.update(ids=ids, pos=pos, active=active, remaining=remaining)
                 if freq_any:
                     out_state["freq_counts"] = counts
+                cache = dict(cache)
+                extra = [cache.pop(k).reshape(-1) for k in counts_of]
+                if extra:  # after the slots' rows, flat, padded to whole rows
+                    flat = jnp.concatenate(extra)
+                    S = toks.shape[1]
+                    extra = [jnp.pad(flat, (0, -flat.size % S)).reshape(-1, S)]
                 packed = jnp.concatenate(
                     [
                         toks.astype(jnp.int32),  # [n_steps, S]
@@ -2121,6 +2158,7 @@ class DecodeEngine:
                         emit.sum(0, dtype=jnp.int32)[None],  # emit_count [1, S]
                         active.astype(jnp.int32)[None],  # [1, S]
                         pos.astype(jnp.int32)[None],  # [1, S]
+                        *extra,
                     ],
                     axis=0,
                 )
@@ -3516,6 +3554,8 @@ class DecodeEngine:
             emit_count = packed[2 * n_steps]
             active = packed[2 * n_steps + 1].astype(bool)
             pos = packed[2 * n_steps + 2]
+            if self._moe_load is not None:
+                self._credit_moe_counts(packed[2 * n_steps + 3 :].reshape(-1))
             st = self._state
             now = time.monotonic()
             for slot, task in enumerate(pending["tasks"]):

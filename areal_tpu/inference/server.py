@@ -250,6 +250,11 @@ class InferenceServer:
             # same key as /debug/flight's stats section — over THERE
             # "timelines" is the list of timeline records
             out["timeline_stats"] = tl.stats()
+        ms = getattr(self.engine, "moe_status", None)
+        if ms is not None and (moe_view := ms()) is not None:
+            # expert-load counts of a model with sparse experts
+            # (docs/observability.md): one nested list, not a series a cell
+            out["moe"] = moe_view
         ks = getattr(self.engine, "kernel_stats", None)
         if ks is not None:
             # decode-step phases (docs/observability.md "Decode-step

@@ -1,20 +1,28 @@
-"""Hybrid decoder: state-space (Mamba-2) layers beside GQA attention layers.
+"""Hybrid decoder: layers whose mixer is one of several kinds, in the order
+of the published ``layer_types``, each followed by a feed-forward block of
+its own kind.
 
-The ``granitemoehybrid`` family with no experts: every layer is a mixer (one
-of two kinds, in the order of the published ``layer_types``) and a dense
-SwiGLU MLP, each behind an RMSNorm and a residual multiplier; the attention
-layers carry no rotary embedding and take their softmax scale from the
-configuration. Serving only (prefill, paged decode); training through the
-chunked scan is ROADMAP Reach A.4.
+Mixer kinds: ``mamba`` (a Mamba-2 state-space layer), ``attention`` (GQA;
+with or without a rotary embedding and per-head q/k RMSNorm, as the
+configuration says) and ``conv`` (a gated short convolution). FFN kinds:
+``dense`` (SwiGLU) and ``moe`` (sparse experts, ``models/moe.py``). Two
+published families are built from these (``from_hf_dict``):
+``granitemoehybrid`` without experts (Mamba-2 beside NoPE attention, dense
+MLPs, residual and logit multipliers) and ``lfm2_moe`` (short convolutions
+beside rotary attention with q/k norms; the first ``num_dense_layers`` FFNs
+dense, the rest 32 experts behind a sigmoid router with a selection bias).
+Serving only (prefill, paged decode); training is ROADMAP Reach A.4.
 
 The module has the entry points the decode engine uses of ``models/qwen.py``
 (``models.family_of`` picks one of the two from the model configuration), and
-shares with it the RMSNorm, the projection (``_proj``), the embedding lookup,
-the logits matmul and the scope names.
+shares with it the RMSNorm, the projection (``_proj``), the rotary embedding,
+the embedding lookup, the logits matmul and the scope names.
 
-Params are stacked PER KIND: ``params["mamba"][name]`` is ``[n_mamba, ...]``,
-``params["attention"][name]`` is ``[n_attention, ...]``; a run of consecutive
-layers of one kind is one ``lax.scan`` over its indices.
+Params are stacked PER KIND OF LAYER, a layer's kind being its mixer and its
+FFN (``stack_name``): ``params["mamba"][name]`` is ``[n_mamba, ...]``,
+``params["conv_moe"][name]`` is ``[layers with a conv mixer and experts,
+...]``; a run of consecutive layers of one kind is one ``lax.scan`` over its
+indices (``_scan_layers``).
 
 The Mamba-2 mixer comes in two forms of one recurrence (per head, state
 ``S`` in R^{P x N}): ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``,
@@ -24,6 +32,12 @@ whole prompt (quadratic inside a chunk of ``mamba_chunk_size`` tokens, the
 state carried between chunks). tests/test_hybrid_model.py holds them to each
 other. Both compute in float32 whatever the model's dtype: the state is what
 thousands of decode steps accumulate into.
+
+The short-conv mixer: ``[B | C | x] = W_in u``, ``g_t = B_t * x_t``,
+``c_t = sum_j w_j g_{t-K+1+j}`` per channel (no bias, no activation),
+``y_t = W_out (C_t * c_t)``; a slot's state is its last ``K - 1`` values of
+``g``. ``conv_prefill`` and ``conv_decode`` are its two forms
+(tests/test_lfm2_model.py holds them to each other).
 
 What a slot's recurrent state is, and who may write it, is in
 ``inference/paged_kv.py`` (STATE_LEAVES).
@@ -40,13 +54,26 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.models import qwen
-from areal_tpu.models.qwen import _embed_lookup, _proj, _rms_norm
+from areal_tpu.models import moe, qwen
+from areal_tpu.models.qwen import _embed_lookup, _proj, _rms_norm, _rope
 
-MODEL_TYPES = ("granitemoehybrid",)
-KINDS = ("mamba", "attention")
-# scopes this family adds to qwen.SCOPES (docs/observability.md)
+MODEL_TYPES = ("granitemoehybrid", "lfm2_moe")
+KINDS = ("mamba", "attention", "conv")  # mixers
+FFNS = ("dense", "moe")
+# scopes this family adds to qwen.SCOPES (docs/observability.md): the
+# state-space mixer's, the short-conv mixer's, and models/moe.py's
 SCOPES = ("ssm_proj", "ssm_conv", "ssm_state", "state_write")
+CONV_SCOPES = ("conv_proj", "conv_mix", "state_write")
+MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
+# what a decode chunk may ask the forward to count into (not part of the
+# cache the engine keeps): rows of live slots each expert got, [expert
+# layers, experts], and experts with at least one such row, [expert layers]
+COUNT_LEAVES = ("moe_load", "moe_touched")
+
+
+def stack_name(kind: str, ffn: str) -> str:
+    """The params stack of layers with this mixer and this FFN."""
+    return kind if ffn == "dense" else f"{kind}_{ffn}"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,7 +81,7 @@ class HybridConfig:
     vocab_size: int
     hidden_size: int
     intermediate_size: int
-    layer_types: tuple[str, ...]
+    layer_types: tuple[str, ...]  # the mixer of every layer, of KINDS
     num_heads: int
     num_kv_heads: int
     head_dim: int | None = None  # default hidden_size // num_heads
@@ -82,6 +109,26 @@ class HybridConfig:
     # what the serving stack asks of any model configuration
     vision: Any = None
     image_token_id: int = -1
+    # the checkpoint format (hf_name_map, to_hf_dict), nothing of the forward
+    model_type: str = "granitemoehybrid"
+    # attention layers: rotary embedding (None: none) and RMSNorm over each
+    # head of q and k before it
+    rope_theta: float | None = None
+    qk_norm: bool = False
+    # the short-conv mixer: taps of its depthwise causal conv
+    conv_L_cache: int = 3
+    # the FFN of every layer, of FFNS; None: dense everywhere
+    ffn_types: tuple[str, ...] | None = None
+    fused_gate_up: bool = True  # dense FFN: one [gate | up] matrix, or two
+    # sparse experts (models/moe.py reads these off the configuration)
+    num_experts: int = 0
+    num_experts_per_tok: int = 1
+    moe_intermediate_size: int | None = None
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    router_score: str = "softmax"  # or "sigmoid"
+    router_bias: bool = False  # a selection bias a layer (gates stay unbiased)
+    router_norm_eps: float | None = None
 
     @property
     def num_layers(self) -> int:
@@ -89,6 +136,14 @@ class HybridConfig:
 
     def count(self, kind: str) -> int:
         return sum(1 for t in self.layer_types if t == kind)
+
+    @property
+    def ffns(self) -> tuple[str, ...]:
+        return self.ffn_types or ("dense",) * self.num_layers
+
+    @property
+    def num_moe_layers(self) -> int:
+        return sum(1 for f in self.ffns if f == "moe")
 
     @property
     def head_dim_(self) -> int:
@@ -132,75 +187,62 @@ class HybridConfig:
 
     @property
     def has_recurrent_state(self) -> bool:
-        return self.count("mamba") > 0
+        return self.count("mamba") + self.count("conv") > 0
+
+    @property
+    def moe_count_shapes(self) -> dict[str, tuple[int, ...]]:
+        """{leaf: shape} of the int32 counts a decode chunk takes back beside
+        its tokens (COUNT_LEAVES); none for a model without experts."""
+        n = self.num_moe_layers
+        return {"moe_load": (n, self.num_experts), "moe_touched": (n,)} if n else {}
 
     def state_shapes(self, slots: int) -> dict[str, tuple[tuple[int, ...], Any]]:
-        """{leaf: (shape, dtype)} of the slot-indexed recurrent state. The
-        conv window is stored token-major and flat, ``(d_conv - 1) *
-        conv_dim`` wide: with the 3 tokens as the minor dimension the TPU
-        would pad every channel's 3 values to a 128-lane row."""
-        n = self.count("mamba")
-        if not n:
-            return {}
-        return {
-            "ssm": (
-                (n, slots, self.mamba_n_heads, self.mamba_d_head, self.mamba_d_state),
-                jnp.dtype(self.ssm_state_dtype),
-            ),
-            "conv": (
-                (n, slots, (self.mamba_d_conv - 1) * self.conv_dim),
-                jnp.dtype(self.conv_state_dtype or self.dtype),
-            ),
-        }
+        """{leaf: (shape, dtype)} of the slot-indexed recurrent state. A conv
+        window is stored token-major and flat, ``(taps - 1) * channels``
+        wide: with the tokens as the minor dimension the TPU would pad every
+        channel's 2 or 3 values to a 128-lane row. A model has state-space
+        layers or short-conv layers, not both: ``conv`` is either's window."""
+        conv_dtype = jnp.dtype(self.conv_state_dtype or self.dtype)
+        if n := self.count("mamba"):
+            return {
+                "ssm": (
+                    (n, slots, self.mamba_n_heads, self.mamba_d_head, self.mamba_d_state),
+                    jnp.dtype(self.ssm_state_dtype),
+                ),
+                "conv": ((n, slots, (self.mamba_d_conv - 1) * self.conv_dim), conv_dtype),
+            }
+        if n := self.count("conv"):
+            return {"conv": ((n, slots, (self.conv_L_cache - 1) * self.hidden_size), conv_dtype)}
+        return {}
 
     @classmethod
     def from_hf_dict(cls, d: dict[str, Any]) -> "HybridConfig":
-        """From a published ``granitemoehybrid`` ``config.json``. Raises on
+        """From a published ``config.json`` of one of MODEL_TYPES. Raises on
         what this module does not implement rather than serving something
         else under the model's name."""
         mt = d.get("model_type")
         if mt not in MODEL_TYPES:
             raise ValueError(f"model_type {mt!r} is not of the hybrid family {MODEL_TYPES}")
-        if d.get("num_local_experts", 0):
-            raise ValueError("granitemoehybrid with routed experts is not implemented (dense shared MLP only)")
-        if d.get("position_embedding_type", "nope") != "nope":
-            raise ValueError("granitemoehybrid with rotary attention layers is not implemented (NoPE only)")
-        if d.get("attention_bias") or d.get("mamba_proj_bias"):
-            raise ValueError("projection biases are not implemented for the hybrid family")
-        if not d.get("mamba_conv_bias", True):
-            raise ValueError("a conv without bias is not implemented for the hybrid family")
         if d.get("hidden_act", "silu") != "silu":
             raise ValueError(f"hidden_act {d['hidden_act']!r} is not implemented")
-        kinds = tuple(d["layer_types"])
-        if set(kinds) - set(KINDS) or len(kinds) != d["num_hidden_layers"]:
-            raise ValueError(f"layer_types {sorted(set(kinds))} x {len(kinds)} for {d['num_hidden_layers']} layers")
-        n_heads = d["mamba_n_heads"]
-        if n_heads * d["mamba_d_head"] != d.get("mamba_expand", 2) * d["hidden_size"]:
-            raise ValueError("mamba_n_heads * mamba_d_head must equal mamba_expand * hidden_size")
         extra = {
             k: d[k]
             for k in ("dtype", "ssm_state_dtype", "conv_state_dtype", "kv_lane_pad", "head_dim")
             if k in d
         }
+        build = _granite_fields if mt == "granitemoehybrid" else _lfm2_fields
+        fields = build(d)
+        kinds = fields["layer_types"]
+        if set(kinds) - set(KINDS) or len(kinds) != d["num_hidden_layers"]:
+            raise ValueError(f"layer_types {sorted(set(kinds))} x {len(kinds)} for {d['num_hidden_layers']} layers")
         return cls(
+            model_type=mt,
             vocab_size=d["vocab_size"],
             hidden_size=d["hidden_size"],
-            intermediate_size=d.get("shared_intermediate_size", d["intermediate_size"]),
-            layer_types=kinds,
             num_heads=d["num_attention_heads"],
             num_kv_heads=d.get("num_key_value_heads", d["num_attention_heads"]),
-            rms_norm_eps=d.get("rms_norm_eps", 1e-5),
             tie_word_embeddings=d.get("tie_word_embeddings", True),
-            embedding_multiplier=d.get("embedding_multiplier", 1.0),
-            residual_multiplier=d.get("residual_multiplier", 1.0),
-            attention_multiplier=d.get("attention_multiplier"),
-            logits_scaling=d.get("logits_scaling", 1.0),
-            mamba_n_heads=n_heads,
-            mamba_d_head=d["mamba_d_head"],
-            mamba_d_state=d["mamba_d_state"],
-            mamba_n_groups=d.get("mamba_n_groups", 1),
-            mamba_d_conv=d.get("mamba_d_conv", 4),
-            mamba_chunk_size=d.get("mamba_chunk_size", 256),
+            **fields,
             **extra,
         )
 
@@ -211,18 +253,37 @@ class HybridConfig:
 
     def to_hf_dict(self) -> dict[str, Any]:
         """Inverse of ``from_hf_dict`` (a saved checkpoint's config.json)."""
-        return {
-            "model_type": MODEL_TYPES[0],
+        shared = {
+            "model_type": self.model_type,
             "vocab_size": self.vocab_size,
             "hidden_size": self.hidden_size,
             "intermediate_size": self.intermediate_size,
-            "shared_intermediate_size": self.intermediate_size,
             "num_hidden_layers": self.num_layers,
-            "layer_types": list(self.layer_types),
             "num_attention_heads": self.num_heads,
             "num_key_value_heads": self.num_kv_heads,
-            "rms_norm_eps": self.rms_norm_eps,
             "tie_word_embeddings": self.tie_word_embeddings,
+        }
+        if self.model_type == "lfm2_moe":
+            return {
+                **shared,
+                "layer_types": ["full_attention" if t == "attention" else t for t in self.layer_types],
+                "norm_eps": self.rms_norm_eps,
+                "rope_theta": self.rope_theta,
+                "conv_L_cache": self.conv_L_cache,
+                "conv_bias": False,
+                "num_dense_layers": sum(1 for f in self.ffns if f == "dense"),
+                "num_experts": self.num_experts,
+                "num_experts_per_tok": self.num_experts_per_tok,
+                "moe_intermediate_size": self.moe_intermediate_size,
+                "norm_topk_prob": self.norm_topk_prob,
+                "routed_scaling_factor": self.routed_scaling_factor,
+                "use_expert_bias": self.router_bias,
+            }
+        return {
+            **shared,
+            "shared_intermediate_size": self.intermediate_size,
+            "layer_types": list(self.layer_types),
+            "rms_norm_eps": self.rms_norm_eps,
             "embedding_multiplier": self.embedding_multiplier,
             "residual_multiplier": self.residual_multiplier,
             "attention_multiplier": self.sm_scale,
@@ -243,6 +304,79 @@ class HybridConfig:
         }
 
 
+def _granite_fields(d: dict[str, Any]) -> dict[str, Any]:
+    """``granitemoehybrid`` without experts: Mamba-2 beside NoPE attention."""
+    if d.get("num_local_experts", 0):
+        raise ValueError("granitemoehybrid with routed experts is not implemented (dense shared MLP only)")
+    if d.get("position_embedding_type", "nope") != "nope":
+        raise ValueError("granitemoehybrid with rotary attention layers is not implemented (NoPE only)")
+    if d.get("attention_bias") or d.get("mamba_proj_bias"):
+        raise ValueError("projection biases are not implemented for the hybrid family")
+    if not d.get("mamba_conv_bias", True):
+        raise ValueError("a conv without bias is not implemented for the state-space mixer")
+    if d["mamba_n_heads"] * d["mamba_d_head"] != d.get("mamba_expand", 2) * d["hidden_size"]:
+        raise ValueError("mamba_n_heads * mamba_d_head must equal mamba_expand * hidden_size")
+    if "conv" in d["layer_types"]:
+        raise ValueError("granitemoehybrid has no short-conv layers")
+    return dict(
+        intermediate_size=d.get("shared_intermediate_size", d["intermediate_size"]),
+        layer_types=tuple(d["layer_types"]),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+        embedding_multiplier=d.get("embedding_multiplier", 1.0),
+        residual_multiplier=d.get("residual_multiplier", 1.0),
+        attention_multiplier=d.get("attention_multiplier"),
+        logits_scaling=d.get("logits_scaling", 1.0),
+        mamba_n_heads=d["mamba_n_heads"],
+        mamba_d_head=d["mamba_d_head"],
+        mamba_d_state=d["mamba_d_state"],
+        mamba_n_groups=d.get("mamba_n_groups", 1),
+        mamba_d_conv=d.get("mamba_d_conv", 4),
+        mamba_chunk_size=d.get("mamba_chunk_size", 256),
+    )
+
+
+def _lfm2_fields(d: dict[str, Any]) -> dict[str, Any]:
+    """``lfm2_moe``: short convolutions beside rotary attention with q/k
+    norms; ``num_dense_layers`` dense FFNs, then experts behind a sigmoid
+    router whose selection (not its gates) takes a bias a layer. The score
+    function, the biased selection and the 1e-6 of the normalisation are the
+    family's published implementation (``Lfm2MoeSparseMoeBlock``): its
+    ``config.json`` has no key for them."""
+    if d.get("conv_bias"):
+        raise ValueError("lfm2_moe with a conv bias is not implemented")
+    if d.get("rope_scaling") or (d.get("rope_parameters") or {}).get("rope_type", "default") != "default":
+        raise ValueError("lfm2_moe with a scaled rotary embedding is not implemented")
+    if d.get("num_shared_experts", 0):
+        raise ValueError("lfm2_moe with shared experts is not implemented")
+    kinds = tuple("attention" if t == "full_attention" else t for t in d["layer_types"])
+    if set(kinds) - {"attention", "conv"}:
+        raise ValueError(f"lfm2_moe layer_types {sorted(set(d['layer_types']))}: only conv and full_attention")
+    n_dense = int(d.get("num_dense_layers", 0))
+    n = len(kinds)
+    experts = int(d.get("num_experts", 0)) if n_dense < n else 0
+    if n_dense < n and experts < 1:
+        raise ValueError("lfm2_moe layers past num_dense_layers need num_experts")
+    theta = d.get("rope_theta") or (d.get("rope_parameters") or {}).get("rope_theta", 1e6)
+    return dict(
+        intermediate_size=d["intermediate_size"],
+        layer_types=kinds,
+        rms_norm_eps=d.get("norm_eps", 1e-5),
+        rope_theta=float(theta),
+        qk_norm=True,
+        conv_L_cache=int(d.get("conv_L_cache", 3)),
+        ffn_types=tuple("dense" if i < n_dense else "moe" for i in range(n)),
+        fused_gate_up=False,
+        num_experts=experts,
+        num_experts_per_tok=int(d.get("num_experts_per_tok", 1)),
+        moe_intermediate_size=d.get("moe_intermediate_size"),
+        norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+        router_score="sigmoid",
+        router_bias=bool(d.get("use_expert_bias", True)),
+        router_norm_eps=1e-6,
+    )
+
+
 def serving_config(cfg: HybridConfig, dtype: str) -> HybridConfig:
     """``cfg`` as a decode engine serves it."""
     return dataclasses.replace(cfg, dtype=dtype)
@@ -253,18 +387,19 @@ def serving_config(cfg: HybridConfig, dtype: str) -> HybridConfig:
 # ---------------------------------------------------------------------------
 
 
+def _layer_kinds(cfg: HybridConfig) -> list[tuple[str, str]]:
+    """(mixer, FFN) of every layer, in model order."""
+    return list(zip(cfg.layer_types, cfg.ffns))
+
+
 def _layer_shapes(cfg: HybridConfig) -> dict[str, dict[str, tuple[int, ...]]]:
+    """{stack: {leaf: shape of one layer}} for the kinds of layer the model
+    has, in the order they first appear."""
     D, F = cfg.hidden_size, cfg.intermediate_size
     H, C = cfg.mamba_n_heads, cfg.conv_dim
-    shared = {
-        "input_norm": (D,),
-        "post_norm": (D,),
-        "w_gate_up": (D, 2 * F),  # [gate | up], the checkpoint's fused input_linear
-        "w_down": (F, D),
-    }
-    return {
+    norms = {"input_norm": (D,), "post_norm": (D,)}
+    mixers = {
         "mamba": {
-            **shared,
             "in_proj": (D, 2 * cfg.d_inner + 2 * cfg.mamba_n_groups * cfg.mamba_d_state + H),
             # the checkpoint's depthwise [C, 1, K] weight, reversed: tap k of
             # channel c is conv_w[k, 0, c]
@@ -277,21 +412,51 @@ def _layer_shapes(cfg: HybridConfig) -> dict[str, dict[str, tuple[int, ...]]]:
             "out_proj": (cfg.d_inner, D),
         },
         "attention": {
-            **shared,
             "wq": (D, cfg.q_dim),
             "wk": (D, cfg.kv_dim),
             "wv": (D, cfg.kv_dim),
             "wo": (cfg.q_dim, D),
+            **({"q_norm": (cfg.head_dim_,), "k_norm": (cfg.head_dim_,)} if cfg.qk_norm else {}),
+        },
+        "conv": {
+            "in_proj": (D, 3 * D),  # [B | C | x]
+            "conv_w": (cfg.conv_L_cache, 1, D),  # as the Mamba mixer's: tap k of channel c
+            "out_proj": (D, D),
         },
     }
+    E, Fe = cfg.num_experts, cfg.moe_intermediate_size
+    ffns = {
+        # [gate | up] is the granitemoehybrid checkpoint's fused input_linear
+        "dense": {"w_gate_up": (D, 2 * F), "w_down": (F, D)}
+        if cfg.fused_gate_up
+        else {"w_gate": (D, F), "w_up": (D, F), "w_down": (F, D)},
+        "moe": {
+            "w_router": (D, E),
+            **({"router_bias": (E,)} if cfg.router_bias else {}),
+            "we_gate": (E, D, Fe),
+            "we_up": (E, D, Fe),
+            "we_down": (E, Fe, D),
+        },
+    }
+    out: dict[str, dict[str, tuple[int, ...]]] = {}
+    for kind, ffn in _layer_kinds(cfg):
+        out.setdefault(stack_name(kind, ffn), {**norms, **ffns[ffn], **mixers[kind]})
+    return out
+
+
+def _stack_sizes(cfg: HybridConfig) -> dict[str, int]:
+    sizes: dict[str, int] = {}
+    for kind, ffn in _layer_kinds(cfg):
+        sizes[stack_name(kind, ffn)] = sizes.get(stack_name(kind, ffn), 0) + 1
+    return sizes
 
 
 def init_params(rng: jax.Array, cfg: HybridConfig, dtype=None) -> dict:
-    """Random init, stacked per kind. ``A``, ``dt`` and ``D`` as the
+    """Random init, stacked per kind of layer. ``A``, ``dt`` and ``D`` as the
     published Mamba-2 initialisation draws them (A uniform in 1-16, dt
     log-uniform in 0.001-0.1 through the inverse softplus, D = 1)."""
     dtype = dtype or cfg.jax_dtype
-    keys = iter(jax.random.split(rng, 64))
+    keys = iter(jax.random.split(rng, 128))
 
     def dense(shape):
         return (0.02 * jax.random.truncated_normal(next(keys), -2, 2, shape, jnp.float32)).astype(dtype)
@@ -302,79 +467,116 @@ def init_params(rng: jax.Array, cfg: HybridConfig, dtype=None) -> dict:
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = dense((cfg.vocab_size, cfg.hidden_size))
-    for kind, shapes in _layer_shapes(cfg).items():
-        n = cfg.count(kind)
-        if not n:
-            continue
+    sizes = _stack_sizes(cfg)
+    for name, shapes in _layer_shapes(cfg).items():
         stack = {}
-        for name, shape in shapes.items():
-            full = (n, *shape)
-            if name.endswith("norm") or name == "D":
-                stack[name] = jnp.ones(full, dtype)
-            elif name == "conv_b":
-                stack[name] = jnp.zeros(full, dtype)
-            elif name == "A_log":
-                stack[name] = jnp.log(jax.random.uniform(next(keys), full, jnp.float32, 1.0, 16.0)).astype(dtype)
-            elif name == "dt_bias":
+        for leaf, shape in shapes.items():
+            full = (sizes[name], *shape)
+            if leaf.endswith("norm") or leaf == "D":
+                stack[leaf] = jnp.ones(full, dtype)
+            elif leaf == "conv_b":
+                stack[leaf] = jnp.zeros(full, dtype)
+            elif leaf == "A_log":
+                stack[leaf] = jnp.log(jax.random.uniform(next(keys), full, jnp.float32, 1.0, 16.0)).astype(dtype)
+            elif leaf == "dt_bias":
                 dt = jnp.exp(jax.random.uniform(next(keys), full, jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
-                stack[name] = (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+                stack[leaf] = (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
             else:
-                stack[name] = dense(full)
-        params[kind] = stack
+                stack[leaf] = dense(full)
+        params[name] = stack
     return params
 
 
 def param_partition_specs(cfg: HybridConfig, fsdp_axis: str | None = "fsdp") -> dict:
     """Every leaf replicated: this family serves on one chip per replica. A
-    mixer and a recurrent state sharded over the ``model`` axis (heads) is
-    ROADMAP Reach A.7."""
+    mixer and a recurrent state sharded over the ``model`` axis (heads), or
+    experts over chips, is ROADMAP Reach A.7."""
     del fsdp_axis
     specs: dict[str, Any] = {"embed": P(), "final_norm": P()}
     if not cfg.tie_word_embeddings:
         specs["lm_head"] = P()
-    for kind, shapes in _layer_shapes(cfg).items():
-        if cfg.count(kind):
-            specs[kind] = {name: P() for name in shapes}
+    for name, shapes in _layer_shapes(cfg).items():
+        specs[name] = {leaf: P() for leaf in shapes}
     return specs
 
 
-_HF_LAYER_MAP = {
-    "input_norm": ("input_layernorm.weight", False),
-    "post_norm": ("post_attention_layernorm.weight", False),
-    "w_gate_up": ("shared_mlp.input_linear.weight", True),
-    "w_down": ("shared_mlp.output_linear.weight", True),
-    "in_proj": ("mamba.in_proj.weight", True),
-    "conv_w": ("mamba.conv1d.weight", True),
-    "conv_b": ("mamba.conv1d.bias", False),
-    "dt_bias": ("mamba.dt_bias", False),
-    "A_log": ("mamba.A_log", False),
-    "D": ("mamba.D", False),
-    "ssm_norm": ("mamba.norm.weight", False),
-    "out_proj": ("mamba.out_proj.weight", True),
-    "wq": ("self_attn.q_proj.weight", True),
-    "wk": ("self_attn.k_proj.weight", True),
-    "wv": ("self_attn.v_proj.weight", True),
-    "wo": ("self_attn.o_proj.weight", True),
+# our leaf -> (checkpoint name within ``model.layers.N.``, transpose), by
+# model_type. ``lfm2_moe``: the mixers, norms, dense MLP and tied head are
+# held to ``transformers``' ``Lfm2`` classes (tests/test_lfm2_hf_parity.py);
+# the names under ``feed_forward.gate`` / ``.expert_bias`` / ``.experts.E``
+# could NOT be checked here (no network, and the installed transformers has
+# ``lfm2`` but not ``lfm2_moe``): they are the published repository's as the
+# ISSUE of PR 30 wrote them down.
+_HF_LAYER_MAPS = {
+    "granitemoehybrid": {
+        "input_norm": ("input_layernorm.weight", False),
+        "post_norm": ("post_attention_layernorm.weight", False),
+        "w_gate_up": ("shared_mlp.input_linear.weight", True),
+        "w_down": ("shared_mlp.output_linear.weight", True),
+        "in_proj": ("mamba.in_proj.weight", True),
+        "conv_w": ("mamba.conv1d.weight", True),
+        "conv_b": ("mamba.conv1d.bias", False),
+        "dt_bias": ("mamba.dt_bias", False),
+        "A_log": ("mamba.A_log", False),
+        "D": ("mamba.D", False),
+        "ssm_norm": ("mamba.norm.weight", False),
+        "out_proj": ("mamba.out_proj.weight", True),
+        "wq": ("self_attn.q_proj.weight", True),
+        "wk": ("self_attn.k_proj.weight", True),
+        "wv": ("self_attn.v_proj.weight", True),
+        "wo": ("self_attn.o_proj.weight", True),
+    },
+    "lfm2_moe": {
+        "input_norm": ("operator_norm.weight", False),
+        "post_norm": ("ffn_norm.weight", False),
+        "w_gate": ("feed_forward.w1.weight", True),
+        "w_up": ("feed_forward.w3.weight", True),
+        "w_down": ("feed_forward.w2.weight", True),
+        "in_proj": ("conv.in_proj.weight", True),
+        "conv_w": ("conv.conv.weight", True),
+        "out_proj": ("conv.out_proj.weight", True),
+        "wq": ("self_attn.q_proj.weight", True),
+        "wk": ("self_attn.k_proj.weight", True),
+        "wv": ("self_attn.v_proj.weight", True),
+        "wo": ("self_attn.out_proj.weight", True),
+        "q_norm": ("self_attn.q_layernorm.weight", False),
+        "k_norm": ("self_attn.k_layernorm.weight", False),
+        "w_router": ("feed_forward.gate.weight", True),
+        "router_bias": ("feed_forward.expert_bias", False),
+        # one tensor per expert: feed_forward.experts.E.<name>
+        "we_gate": ("feed_forward.experts.{e}.w1.weight", True),
+        "we_up": ("feed_forward.experts.{e}.w3.weight", True),
+        "we_down": ("feed_forward.experts.{e}.w2.weight", True),
+    },
+}
+_HF_TOP = {
+    "granitemoehybrid": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
+    "lfm2_moe": {"embed": "model.embed_tokens.weight", "final_norm": "model.embedding_norm.weight"},
 }
 
 
 def hf_name_map(cfg: HybridConfig) -> dict[str, tuple[str, bool]]:
-    """Our param path -> (``granitemoehybrid`` checkpoint name, transpose).
-    A stacked leaf maps as ``<kind>/<index within the kind>/<name>``; the
-    checkpoint numbers layers in the order of ``layer_types``."""
-    out: dict[str, tuple[str, bool]] = {
-        "embed": ("model.embed_tokens.weight", False),
-        "final_norm": ("model.norm.weight", False),
-    }
+    """Our param path -> (checkpoint name of ``cfg.model_type``, transpose).
+    A stacked leaf maps as ``<stack>/<index within the stack>/<name>``, an
+    expert's as ``.../<name>/<expert>``; the checkpoint numbers layers in the
+    order of ``layer_types``."""
+    out: dict[str, tuple[str, bool]] = {k: (v, False) for k, v in _HF_TOP[cfg.model_type].items()}
     if not cfg.tie_word_embeddings:
         out["lm_head"] = ("lm_head.weight", False)
-    seen = dict.fromkeys(KINDS, 0)
+    layer_map = _HF_LAYER_MAPS[cfg.model_type]
+    seen: dict[str, int] = {}
     shapes = _layer_shapes(cfg)
-    for i, kind in enumerate(cfg.layer_types):
-        for name in shapes[kind]:
-            suffix, transpose = _HF_LAYER_MAP[name]
-            out[f"{kind}/{seen[kind]}/{name}"] = (f"model.layers.{i}.{suffix}", transpose)
-        seen[kind] += 1
+    for i, (kind, ffn) in enumerate(_layer_kinds(cfg)):
+        stack = stack_name(kind, ffn)
+        n = seen.get(stack, 0)
+        for name in shapes[stack]:
+            suffix, transpose = layer_map[name]
+            if "{e}" in suffix:
+                for e in range(cfg.num_experts):
+                    out[f"{stack}/{n}/{name}/{e}"] = (f"model.layers.{i}.{suffix.format(e=e)}", transpose)
+            else:
+                out[f"{stack}/{n}/{name}"] = (f"model.layers.{i}.{suffix}", transpose)
+        seen[stack] = n + 1
     return out
 
 
@@ -470,6 +672,18 @@ def ssm_chunked_scan(cfg: HybridConfig, layer: dict, xbc, dt_raw, n_state, state
     return s_fin.astype(state_dtype), y
 
 
+def _window_after(padded, n_state, K: int):
+    """The conv inputs of the last K-1 of each row's first ``n_state`` tokens,
+    flat [A, (K-1) * channels] float32, from ``padded`` [A, K-1+L, channels]
+    (position t at row t + K - 1, zeros before the prompt): tokens
+    n_state-K+1 .. n_state-1 = padded rows n_state .. n_state+K-2, picked by
+    a one-hot product (exact: one term a sum) and not by a gather."""
+    A, rows_n, _ = padded.shape
+    rows = n_state[:, None] + jnp.arange(K - 1)[None, :]
+    pick = (rows[:, :, None] == jnp.arange(rows_n)[None, None, :]).astype(padded.dtype)
+    return jnp.einsum("akt,atc->akc", pick, padded, preferred_element_type=jnp.float32).reshape(A, -1)
+
+
 def _conv_taps(layer: dict):
     return layer["conv_w"][:, 0, :].astype(jnp.float32), layer["conv_b"].astype(jnp.float32)
 
@@ -544,11 +758,7 @@ def mamba_prefill(cfg: HybridConfig, layer: dict, h, n_state, state_dtypes):
         for k in range(K):
             acc = acc + padded[:, k : k + L].astype(jnp.float32) * w[k]
         xbc = jax.nn.silu(acc)
-        # raw inputs of tokens n_state-K+1 .. n_state-1 = padded rows n_state .. n_state+K-2,
-        # picked by a one-hot product (exact: one term a sum) and not by a gather
-        rows = n_state[:, None] + jnp.arange(K - 1)[None, :]
-        pick = (rows[:, :, None] == jnp.arange(L + K - 1)[None, None, :]).astype(raw.dtype)
-        conv = jnp.einsum("akt,atc->akc", pick, padded, preferred_element_type=jnp.float32).reshape(A, -1)
+        conv = _window_after(padded, n_state, K)
     with jax.named_scope("ssm_state"):
         ssm, y = ssm_chunked_scan(cfg, layer, xbc, dt_raw, n_state, state_dtypes[0])
         g = _gated_out(cfg, layer, y, z, h.dtype)
@@ -557,42 +767,113 @@ def mamba_prefill(cfg: HybridConfig, layer: dict, h, n_state, state_dtypes):
 
 
 # ---------------------------------------------------------------------------
+# the short-conv mixer
+# ---------------------------------------------------------------------------
+
+
+def _conv_gates(cfg: HybridConfig, layer: dict, h, state_dtype):
+    """(g = B * x rounded to the type the window keeps it in, C), both
+    [..., D]: both forms convolve the values a slot's state would hold."""
+    with jax.named_scope("conv_proj"):
+        b, c, x = jnp.split(_proj(cfg, layer, "in_proj", h), 3, axis=-1)
+    return (b.astype(jnp.float32) * x.astype(jnp.float32)).astype(state_dtype), c
+
+
+def conv_decode(cfg: HybridConfig, layer: dict, h, conv_all, j, active):
+    """Mixer for one token a slot. h [S, D] (normed); ``conv_all`` [n, S,
+    (K-1) * D] holds every conv layer's window (a slot's last K-1 values of
+    ``g``, oldest first), of which this is layer ``j``. Returns (out [S, D],
+    the windows with layer j advanced); rows that are not ``active`` keep
+    theirs bit for bit."""
+    S, D = h.shape
+    K = cfg.conv_L_cache
+    conv = jax.lax.dynamic_index_in_dim(conv_all, j, 0, keepdims=False)
+    g, c = _conv_gates(cfg, layer, h, conv.dtype)
+    with jax.named_scope("conv_mix"):
+        window = jnp.concatenate([conv.reshape(S, K - 1, D), g[:, None, :]], axis=1)
+        w = layer["conv_w"][:, 0, :].astype(jnp.float32)
+        y = (c.astype(jnp.float32) * jnp.sum(window.astype(jnp.float32) * w[None], axis=1)).astype(h.dtype)
+        new_conv = jnp.where(active[:, None], window[:, 1:].reshape(S, -1), conv)
+    with jax.named_scope("state_write"):
+        conv_all = jax.lax.dynamic_update_index_in_dim(conv_all, new_conv, j, 0)
+    with jax.named_scope("conv_proj"):
+        return _proj(cfg, layer, "out_proj", y), conv_all
+
+
+def conv_prefill(cfg: HybridConfig, layer: dict, h, n_state, state_dtype):
+    """Mixer over whole prompts. h [A, L, D] (normed), n_state [A]. Returns
+    (out [A, L, D], the window after each row's first n_state tokens
+    [A, (K-1) * D]; positions before the prompt count as zeros)."""
+    A, L, D = h.shape
+    K = cfg.conv_L_cache
+    g, c = _conv_gates(cfg, layer, h, state_dtype)
+    with jax.named_scope("conv_mix"):
+        w = layer["conv_w"][:, 0, :].astype(jnp.float32)
+        padded = jnp.pad(g, ((0, 0), (K - 1, 0), (0, 0)))  # position t at row t + K - 1
+        acc = 0.0
+        for k in range(K):
+            acc = acc + padded[:, k : k + L].astype(jnp.float32) * w[k]
+        y = (c.astype(jnp.float32) * acc).astype(h.dtype)
+        conv = _window_after(padded, n_state, K).astype(state_dtype)
+    with jax.named_scope("conv_proj"):
+        return _proj(cfg, layer, "out_proj", y), conv
+
+
+# ---------------------------------------------------------------------------
 # the layer stack
 # ---------------------------------------------------------------------------
 
 
-def _mlp(cfg: HybridConfig, layer: dict, x):
-    with jax.named_scope("mlp"):
+def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None):
+    """x + the layer's feed-forward block on rmsnorm(x); for an expert block
+    also the rows of ``live`` (default: all) each expert got, [E] int32."""
+    rm = cfg.residual_multiplier
+    if ffn == "dense":
+        with jax.named_scope("mlp"):
+            h = _rms_norm(x, layer["post_norm"], cfg.rms_norm_eps)
+            if cfg.fused_gate_up:
+                g, u = jnp.split(_proj(cfg, layer, "w_gate_up", h), 2, axis=-1)
+            else:
+                g, u = _proj(cfg, layer, "w_gate", h), _proj(cfg, layer, "w_up", h)
+            return x + rm * _proj(cfg, layer, "w_down", jax.nn.silu(g) * u), None
+    with jax.named_scope("moe_router"):
         h = _rms_norm(x, layer["post_norm"], cfg.rms_norm_eps)
-        g, u = jnp.split(_proj(cfg, layer, "w_gate_up", h), 2, axis=-1)
-        return x + cfg.residual_multiplier * _proj(cfg, layer, "w_down", jax.nn.silu(g) * u)
+    out, _, _, load = moe.expert_ffn(
+        h.reshape(-1, h.shape[-1]), layer, cfg, live=None if live is None else live.reshape(-1)
+    )
+    with jax.named_scope("moe_combine"):
+        return x + rm * out.reshape(x.shape).astype(x.dtype), load
 
 
-def _runs(layer_types) -> list[tuple[str, int, int]]:
-    """Runs of consecutive layers of one kind: (kind, first index within the
-    kind, count), in model order."""
-    out: list[tuple[str, int, int]] = []
-    seen = dict.fromkeys(KINDS, 0)
-    for kind in layer_types:
-        if out and out[-1][0] == kind:
-            out[-1] = (kind, out[-1][1], out[-1][2] + 1)
+def _runs(cfg: HybridConfig) -> list[tuple[str, str, int, int, int, int]]:
+    """Runs of consecutive layers of one kind, in model order: (mixer, FFN,
+    first index within the params stack, within the mixer kind, within the
+    FFN kind, count)."""
+    out: list[tuple[str, str, int, int, int, int]] = []
+    seen: dict[str, int] = {}
+    for kind, ffn in _layer_kinds(cfg):
+        stack = stack_name(kind, ffn)
+        if out and out[-1][:2] == (kind, ffn):
+            out[-1] = (*out[-1][:5], out[-1][5] + 1)
         else:
-            out.append((kind, seen[kind], 1))
-        seen[kind] += 1
+            out.append((kind, ffn, seen.get(stack, 0), seen.get(kind, 0), seen.get(ffn, 0), 1))
+        for key in {stack, kind, ffn}:
+            seen[key] = seen.get(key, 0) + 1
     return out
 
 
 def _scan_layers(cfg: HybridConfig, params: dict, carry, step):
-    """Run ``step(kind, carry, layer, j) -> carry`` over the layers in the
-    order of ``layer_types``; ``j`` is the layer's index within its kind
-    (traced) and ``layer`` its slice of the kind's stack. One ``lax.scan``
-    per run of one kind."""
-    for kind, lo, n in _runs(cfg.layer_types):
-        stack = params[kind]
+    """Run ``step(kind, ffn, carry, layer, j, f) -> carry`` over the layers in
+    model order; ``layer`` is the layer's slice of its stack, ``j`` its index
+    among the layers of its mixer kind (the state's and the KV pool's layer
+    axis) and ``f`` among those of its FFN kind, both traced. One
+    ``lax.scan`` per run of one kind."""
+    for kind, ffn, lo, lo_kind, lo_ffn, n in _runs(cfg):
+        stack = params[stack_name(kind, ffn)]
 
-        def body(c, j, kind=kind, stack=stack):
-            layer = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, j, 0, keepdims=False), stack)
-            return step(kind, c, layer, j), None
+        def body(c, i, kind=kind, ffn=ffn, stack=stack, dj=lo_kind - lo, df=lo_ffn - lo):
+            layer = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), stack)
+            return step(kind, ffn, c, layer, i + dj if dj else i, i + df if df else i), None
 
         carry, _ = jax.lax.scan(body, carry, jnp.arange(lo, lo + n, dtype=jnp.int32))
     return carry
@@ -601,14 +882,25 @@ def _scan_layers(cfg: HybridConfig, params: dict, carry, step):
 def _embed(params: dict, cfg: HybridConfig, ids):
     with jax.named_scope("embed"):
         x = _embed_lookup(params["embed"], ids, cfg.jax_dtype, batch_sharded=False)
-        return x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+        return x
 
 
-def _qkv(cfg: HybridConfig, layer: dict, h):
+def _qkv(cfg: HybridConfig, layer: dict, h, positions):
+    """q [..., H, hd], k and v [..., KH, hd] of normed h [..., D] at
+    ``positions`` [...]: per-head RMSNorm of q and k, then the rotary
+    embedding, where the configuration has them."""
     lead = h.shape[:-1]
     q = _proj(cfg, layer, "wq", h).reshape(*lead, cfg.num_heads, cfg.head_dim_)
     k = _proj(cfg, layer, "wk", h).reshape(*lead, cfg.num_kv_heads, cfg.head_dim_)
     v = _proj(cfg, layer, "wv", h).reshape(*lead, cfg.num_kv_heads, cfg.head_dim_)
+    if cfg.qk_norm:
+        q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
+        k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
+    if cfg.rope_theta is not None:
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -632,28 +924,30 @@ def forward_prefill(
     """Batched prompt pass. Returns (hidden [A, L, D], ks, vs
     [n_attention, A, L, KH, kv_head_dim], state) where ``state`` is the
     recurrent state after each row's first ``n_state`` tokens, stacked per
-    Mamba layer ({"ssm": [n, A, H, P, N], "conv": [n, A, ...]}).
+    layer of its mixer kind ({leaf: [n, A, ...]}, ``cfg.state_shapes``).
 
     ``sink = (arrays, write)`` replaces the stacked state: ``arrays`` is
-    carried through the layers and ``write(arrays, j, ssm, conv)`` stores
-    Mamba layer j's state into it (the engine writes straight into its
-    cache's slot rows, so no second copy of A states exists)."""
+    carried through the layers and ``write(arrays, j, {leaf: new})`` stores
+    layer j's state into it (the engine writes straight into its cache's
+    slot rows, so no second copy of A states exists)."""
     A, L = input_ids.shape
     if n_state is None:
         n_state = jnp.sum(seg, axis=-1)
     n_state = n_state.astype(jnp.int32)
     shapes = cfg.state_shapes(A)
-    dtypes = tuple(shapes[k][1] for k in ("ssm", "conv")) if shapes else (jnp.float32, cfg.jax_dtype)
+    dtypes = {k: d for k, (_, d) in shapes.items()}
     if sink is None:
-        arrays = {k: jnp.zeros(s, d) for k, (s, d) in shapes.items()}
+        arrays = {k: jnp.zeros(shp, d) for k, (shp, d) in shapes.items()}
 
-        def write(arr, j, ssm, conv):
-            return {"ssm": arr["ssm"].at[j].set(ssm), "conv": arr["conv"].at[j].set(conv)}
+        def write(arr, j, new):
+            return {**arr, **{k: arr[k].at[j].set(v) for k, v in new.items()}}
     else:
         arrays, write = sink
     n_kv = cfg.num_kv_layers
     kv_shape = (n_kv, A, L, cfg.num_kv_heads, cfg.kv_head_dim)
     mask = qwen._attention_mask(seg)  # [A, 1, L, L]
+    positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (A, L))
+    live = seg.astype(bool)
     rm = cfg.residual_multiplier
 
     def attend(args):  # one row at a time: [H, L, L] logits, not [A, H, L, L]
@@ -665,24 +959,30 @@ def forward_prefill(
         probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
         return jnp.einsum("kgts,skd->tkgd", probs, v).reshape(L, cfg.q_dim)
 
-    def step(kind, carry, layer, j):
+    def step(kind, ffn, carry, layer, j, f):
         x, ks, vs, arr = carry
         if kind == "mamba":
             h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
-            out, ssm, conv = mamba_prefill(cfg, layer, h, n_state, dtypes)
+            out, ssm, conv = mamba_prefill(cfg, layer, h, n_state, (dtypes["ssm"], dtypes["conv"]))
             with jax.named_scope("state_write"):
-                arr = write(arr, j, ssm, conv)
+                arr = write(arr, j, {"ssm": ssm, "conv": conv})
+        elif kind == "conv":
+            with jax.named_scope("conv_proj"):
+                h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+            out, conv = conv_prefill(cfg, layer, h, n_state, dtypes["conv"])
+            with jax.named_scope("state_write"):
+                arr = write(arr, j, {"conv": conv})
         else:
             with jax.named_scope("attn_proj"):
                 h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
-                q, k, v = _qkv(cfg, layer, h)
+                q, k, v = _qkv(cfg, layer, h, positions)
                 ks = ks.at[j].set(_lane_pad(cfg, k))
                 vs = vs.at[j].set(_lane_pad(cfg, v))
             with jax.named_scope("attn"):
                 attn = jax.lax.map(attend, (q, k, v, mask))
             with jax.named_scope("attn_proj"):
                 out = _proj(cfg, layer, "wo", attn)
-        x = _mlp(cfg, layer, x + rm * out)
+        x, _ = _ffn(cfg, ffn, layer, x + rm * out, live)
         return x, ks, vs, arr
 
     x = _embed(params, cfg, input_ids)
@@ -717,10 +1017,10 @@ def prefill_into_cache(
     assert image_embeds is None, "the hybrid family has no vision tower"
     bucket = ids.shape[1]
     seg = (jnp.arange(bucket, dtype=jnp.int32)[None] < plens[:, None]).astype(jnp.int32)
+    state = {k: cache[k] for k in paged_kv.STATE_LEAVES if k in cache}
+    n_slots = next(iter(state.values())).shape[1] if state else 0
 
-    n_slots = cache["ssm"].shape[1]
-
-    def write(arr, j, ssm, conv):
+    def write(arr, j, new):
         # one dynamic-update-slice a row, a padding row rewriting what its
         # (clamped) slot holds. Not a scatter: on the v5e a prefill of 4 rows
         # of 1024 with `.at[j, slots].set(mode="drop")` here never ended once
@@ -728,14 +1028,13 @@ def prefill_into_cache(
         arr = dict(arr)
         for i in range(ids.shape[0]):
             at = jnp.minimum(slots[i], n_slots - 1)
-            for name, new in (("ssm", ssm), ("conv", conv)):
-                start = (j, at) + (0,) * (new.ndim - 1)
-                old = jax.lax.dynamic_slice(arr[name], start, (1, 1) + new.shape[1:])
-                row = jnp.where(slots[i] < n_slots, new[i][None, None].astype(old.dtype), old)
+            for name, rows in new.items():
+                start = (j, at) + (0,) * (rows.ndim - 1)
+                old = jax.lax.dynamic_slice(arr[name], start, (1, 1) + rows.shape[1:])
+                row = jnp.where(slots[i] < n_slots, rows[i][None, None].astype(old.dtype), old)
                 arr[name] = jax.lax.dynamic_update_slice(arr[name], row, start)
         return arr
 
-    state = {k: cache[k] for k in paged_kv.STATE_LEAVES}
     _, ks, vs, state = forward_prefill(params, cfg, ids, seg, n_state=plens - 1, sink=(state, write))
     with jax.named_scope("kv_write"):
         cache = paged_kv.scatter_prefill(
@@ -748,7 +1047,7 @@ def _refuse(what: str):
     def refuse(*_a, **_k):
         raise NotImplementedError(
             f"{what} needs a recurrent state cut back to a token boundary, which does not "
-            "exist for state-space layers (ROADMAP Reach A.7: state snapshots at page boundaries)"
+            "exist for state-space or short-conv layers (ROADMAP Reach A.7: state snapshots at page boundaries)"
         )
 
     return refuse
@@ -777,11 +1076,15 @@ def forward_decode_paged(
     """One incremental step for all S slots. The attention layers write the
     token's K and V into its page row and read the slot's pages as
     ``qwen.forward_decode_paged`` does (the Pallas kernel over lane-padded
-    heads, or the gather path); the Mamba layers advance the recurrent state
-    of the ``active`` slots only: an ended, parked or held slot's state is
-    what it was, bit for bit. ``use_kernel`` also puts the recurrence on its
-    Pallas kernel (ops/ssm_state_update.py), which does not even read the
-    state of a slot that is not live."""
+    heads, or the gather path); the Mamba and short-conv layers advance the
+    recurrent state of the ``active`` slots only: an ended, parked or held
+    slot's state is what it was, bit for bit. ``use_kernel`` also puts the
+    Mamba recurrence on its Pallas kernel (ops/ssm_state_update.py), which
+    does not even read the state of a slot that is not live.
+
+    Where ``cache`` carries COUNT_LEAVES (a decode chunk puts them there for
+    its own length), every expert layer adds to them what its experts got
+    from the ``active`` slots; a slot that is not active counts as no load."""
     from areal_tpu.inference import paged_kv
 
     S = ids.shape[0]
@@ -791,30 +1094,35 @@ def forward_decode_paged(
     write_page = page_table[slot, positions // page_size]
     write_off = positions % page_size
     kv_quant = "k_scale" in cache
+    not_pages = paged_kv.STATE_LEAVES + COUNT_LEAVES
     if use_kernel:
         from areal_tpu.ops.paged_attention_q8 import decode_schedule, live_order, paged_attention_stacked
 
         attn_lengths = jnp.where(page_table[:, 0] == 0, 0, lengths)  # see qwen.forward_decode_paged
         ppcb = paged_kv.choose_ppcb(page_table.shape[1])
         schedule = decode_schedule(attn_lengths, page_table.shape[1], page_size, ppcb)
-        live = live_order(active)  # the state kernel's work list, made once a step
+        live = live_order(active) if cfg.count("mamba") else None  # the state kernel's work list, made once a step
         with jax.named_scope("kv_write"):
             kv_live = live_order(page_table[:, 0] != 0)  # the KV writer's: qwen.forward_decode_paged
     else:
         live = kv_live = None
     rm = cfg.residual_multiplier
 
-    def step(kind, carry, layer, j):
+    def step(kind, ffn, carry, layer, j, f):
         x, c = carry
         c = dict(c)
         if kind == "mamba":
             h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
             out, state = mamba_decode(cfg, layer, h, {k: c[k] for k in paged_kv.STATE_LEAVES}, j, active, live)
             c.update(state)
+        elif kind == "conv":
+            with jax.named_scope("conv_proj"):
+                h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+            out, c["conv"] = conv_decode(cfg, layer, h, c["conv"], j, active)
         else:
             with jax.named_scope("attn_proj"):
                 h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
-                q, k, v = (_lane_pad(cfg, t) for t in _qkv(cfg, layer, h))
+                q, k, v = (_lane_pad(cfg, t) for t in _qkv(cfg, layer, h, positions))
             with jax.named_scope("kv_write"):
                 c = paged_kv.write_decode_rows(c, j, k, v, write_page, write_off, kv_live)
             with jax.named_scope("attn"):
@@ -829,7 +1137,7 @@ def forward_decode_paged(
                     sl = {
                         name: jax.lax.dynamic_index_in_dim(c[name], j, 0, keepdims=False)
                         for name in c
-                        if name not in paged_kv.STATE_LEAVES
+                        if name not in not_pages
                     }
                     scales = dict(k_scales=sl["k_scale"], v_scales=sl["v_scale"]) if kv_quant else {}
                     attn = paged_kv.paged_attention_xla(
@@ -838,7 +1146,11 @@ def forward_decode_paged(
                 attn = attn[..., : cfg.head_dim_].reshape(S, H * cfg.head_dim_).astype(x.dtype)
             with jax.named_scope("attn_proj"):
                 out = _proj(cfg, layer, "wo", attn)
-        x = _mlp(cfg, layer, x + rm * out)
+        x, load = _ffn(cfg, ffn, layer, x + rm * out, active)
+        if load is not None and "moe_load" in c:
+            with jax.named_scope("moe_router"):
+                c["moe_load"] = c["moe_load"].at[f].add(load)
+                c["moe_touched"] = c["moe_touched"].at[f].add(jnp.sum(load > 0, dtype=jnp.int32))
         return x, c
 
     x = _embed(params, cfg, ids)

@@ -1,22 +1,36 @@
-"""Mixture-of-Experts FFN with expert parallelism (qwen3-moe family).
+"""Mixture-of-Experts FFN with expert parallelism.
 
 Reference: archon MoE stack — router (experimental/models/archon/moe/
 router.py), grouped experts (grouped_experts.py), token-dispatch Triton
 kernels (kernels.py:1-228), ExpertParallel (expert_parallel.py:1-512).
 
+Used by ``models/qwen.py`` (``qwen3_moe``: softmax router, experts over the
+mesh ``expert`` axis) and by ``models/hybrid.py`` (a sigmoid router with a
+selection bias, served on one chip). The router is data of the
+configuration (``route``), never a model's name.
+
 Two dispatch strategies, selected by ``cfg.moe_dropless``:
 
-- **dropless (default)**: sort-based grouped dispatch. Per EP shard, the
-  (token, k) assignments targeting local experts are stably sorted by
-  expert id and fed through ``megablox.gmm`` — jax's Pallas grouped-matmul
-  TPU kernel — so every routed token is computed (no capacity drop; the
-  reference's Triton token-shuffle kernels play this role,
-  archon/moe/kernels.py:1-228). Combine is a segment scatter-add weighted
-  by the router gates + psum over the mesh ``expert`` axis.
+- **dropless (default)**: every routed token is computed (``expert_ffn``),
+  in one of two forms chosen by the shapes alone (``takes_dense_form``). Up
+  to ``DENSE_ROWS`` rows (a decode step, a short prompt) every local expert
+  runs on every row with gate 0 for the unchosen: each weight streamed once by a plain matmul. Above that
+  (a prefill, a train step) the (token, k) assignments targeting local
+  experts are stably sorted by expert id and fed through ``megablox.gmm`` —
+  jax's Pallas grouped-matmul TPU kernel, with tiles chosen from the shapes
+  (``gmm_tiles``); the reference's Triton token-shuffle kernels play this
+  role, archon/moe/kernels.py:1-228. Each token gathers its K outputs back
+  by the inverse permutation and sums them under its gates; a psum over the
+  mesh ``expert`` axis assembles the shards.
 - **capacity**: dense one-hot dispatch/combine einsums (mesh-transformer /
   GSPMD formulation); tokens over an expert's ``capacity_factor`` buffer
   are dropped, the residual stream carries them unchanged. Cheaper mask
   bookkeeping, but wrong for training parity when routing is imbalanced.
+
+Scopes (docs/observability.md): ``moe_router`` (scores, bias, top-k, gates,
+load), ``moe_dispatch`` (sort, counts, gather; the dense form's gate
+matrix), ``moe_experts`` (the three matmuls and the gating product),
+``moe_combine`` (the routed form's gather back and sum).
 """
 
 from __future__ import annotations
@@ -81,11 +95,7 @@ def _moe_ffn_capacity(h: jax.Array, layer: dict, cfg) -> tuple[jax.Array, jax.Ar
     C = min(C, L)
 
     # --- routing (fp32 for numerics) ---
-    router_logits = (h.astype(jnp.float32) @ layer["w_router"].astype(jnp.float32))
-    probs = jax.nn.softmax(router_logits, axis=-1)  # [G, L, E]
-    top_p, top_e = jax.lax.top_k(probs, K)  # [G, L, K]
-    if cfg.norm_topk_prob:
-        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    probs, top_p, top_e = route(h, layer["w_router"], cfg, layer.get("router_bias"))  # [G, L, E], [G, L, K]
 
     # --- capacity assignment ---
     # one-hot expert choice per (token, k): [G, L, K, E]
@@ -122,25 +132,199 @@ def _moe_ffn_capacity(h: jax.Array, layer: dict, cfg) -> tuple[jax.Array, jax.Ar
     return out.astype(h.dtype), aux.astype(jnp.float32)
 
 
-def _router(h32, w_router, K: int, norm_topk: bool):
-    """fp32 routing: -> (probs [T, E], top_p [T, K], top_e [T, K])."""
-    logits = h32 @ w_router.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, K)
-    if norm_topk:
-        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
-    return probs, top_p, top_e
+def route(x: jax.Array, w_router: jax.Array, cfg, bias: jax.Array | None = None):
+    """The router, as data of the configuration: x [T, D] -> (scores [T, E],
+    gates [T, K], experts [T, K]), float32.
+
+    ``cfg.router_score`` is ``softmax`` (the default) or ``sigmoid``. The K
+    experts of a token are the top-K of ``scores + bias`` where the layer
+    has a selection ``bias`` (a buffer of the checkpoint, not a trained
+    weight); their gates are the UNBIASED scores, divided where
+    ``cfg.norm_topk_prob`` by their sum (``+ cfg.router_norm_eps`` where the
+    configuration gives one), times ``cfg.routed_scaling_factor``. The
+    router's matmul is float32 in name: on a TPU its bfloat16 inputs multiply
+    exactly either way, and a bfloat16 matmul read the same selections to
+    the digit (PERF.md, PR 30)."""
+    K = cfg.num_experts_per_tok
+    logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)
+    if getattr(cfg, "router_score", "softmax") == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    if bias is None:
+        top_p, top_e = jax.lax.top_k(scores, K)
+    else:
+        _, top_e = jax.lax.top_k(scores + bias.astype(jnp.float32), K)
+        top_p = jnp.take_along_axis(scores, top_e, axis=-1)
+    if cfg.norm_topk_prob:
+        total = top_p.sum(-1, keepdims=True)
+        eps = getattr(cfg, "router_norm_eps", None)
+        top_p = top_p / (jnp.maximum(total, 1e-9) if eps is None else total + eps)
+    scale = getattr(cfg, "routed_scaling_factor", 1.0)
+    if scale != 1.0:
+        top_p = top_p * scale
+    return scores, top_p, top_e
+
+
+# Up to this many rows, every local expert runs on every row (``_experts_dense``).
+# Reading an expert's three matrices costs what ~240 rows of arithmetic on
+# them cost (v5e: 197 TFLOP/s over 819 GB/s), and a decode step of a hundred
+# rows touches nearly every expert anyway: there the dense form streams each
+# weight once at the rate of a plain matmul (90% of 819 GB/s at 128 rows),
+# with no sort, no gather and no grid of small tiles, and its 8-fold
+# arithmetic hides under the bytes. Above 256 rows the arithmetic shows, but
+# the routed form has a floor of its own (it copies a layer's matrices out of
+# the stack for the kernel, and revisits an expert once an m tile). Measured
+# on a v5e at 32 experts of [2048, 1792], top-4, ms a layer, forward only
+# (tools/moe_probe, PR 30): 128 rows 0.95 dense / 4.2 routed; 512: 2.1 / 4.5;
+# 1024: 3.8 / 4.0; 2048: 7.9 / 4.8. So the rule, by shape alone:
+DENSE_ROWS = 1024
+# ... and never more rows x local experts than that measurement had. The dense
+# form's arithmetic, and the [experts, rows, width] activations a backward
+# pass keeps, grow with the product: 128 local experts take it up to 256 rows
+# only, so a train step's shard of a thousand rows goes through the grouped
+# matmuls as it did before this rule.
+DENSE_EXPERTS = 32
+
+
+def takes_dense_form(rows: int, local_experts: int) -> bool:
+    return rows <= DENSE_ROWS and rows * local_experts <= DENSE_ROWS * DENSE_EXPERTS
+
+
+def gmm_tiles(m: int, k: int, n: int, groups: int = 1) -> tuple[int, int, int]:
+    """(tm, tk, tn) for ``megablox.gmm`` from the shapes alone, as
+    ``ops/attention.py flash_tiles`` does for flash: k and n tiles of up to
+    1024 that divide the edge (the library's 128 x 128 walks one expert's
+    [2048, 1792] matrix in 224 grid steps of 32 KB: 5 times slower at every
+    row count, tools/moe_probe), and an m tile of 256, or 512 once a group
+    has a thousand rows and more (an expert is visited once for every m tile
+    its rows touch, and computes the whole tile each time: at 128 rows a
+    group a tile of 512 is 3/4 waste). Double-buffered blocks stay inside
+    the chip's default scoped VMEM (11 MB at 512 x 1024 x 896). gmm needs
+    ``m % tm == 0``; k and n tiles that do not divide are masked."""
+
+    def edge(size: int, cap: int = 1024) -> int:
+        if size <= cap:
+            return size
+        fits = [t for t in range(cap, 127, -128) if size % t == 0]
+        return fits[0] if fits else 128
+
+    return math.gcd(m, 512 if m >= 1024 * groups else 256), edge(k), edge(n)
+
+
+def _local_gates(top_e, gates, e0, n_local: int) -> jax.Array:
+    """[T, n_local] float32: each local expert's gate for each row, 0 where
+    the row did not choose it."""
+    ids = e0 + jnp.arange(n_local, dtype=top_e.dtype)
+    hit = top_e[:, :, None] == ids[None, None, :]
+    return jnp.sum(jnp.where(hit, gates[:, :, None], 0.0), axis=1)
+
+
+def _experts_dense(x, wg, wu, wd, top_e, gates, e0):
+    """Every local expert on every row, the gate (0 for the unchosen) folded
+    in before the down projection, which then contracts over experts and
+    width at once: the exact sum, three plain matmuls."""
+    E_loc = wg.shape[0]
+    with jax.named_scope("moe_dispatch"):
+        ge = _local_gates(top_e, gates, e0, E_loc).T  # [E_loc, T]
+    with jax.named_scope("moe_experts"):
+        # the rows broadcast over the experts make ``e`` a batch dimension of
+        # the matmul: as "td,edf->etf" XLA:TPU wants the weights' contracted
+        # dimension minor and COPIES the whole layer stack of them into that
+        # layout, once a program (5.3 GB at 12 layers of 32 experts)
+        xe = jnp.broadcast_to(x[None], (E_loc, *x.shape))
+        g1 = jnp.einsum("etd,edf->etf", xe, wg)
+        u1 = jnp.einsum("etd,edf->etf", xe, wu)
+        y = jax.nn.silu(g1.astype(jnp.float32)) * u1.astype(jnp.float32) * ge[:, :, None]
+        return jnp.einsum("etf,efd->td", y.astype(x.dtype), wd, preferred_element_type=jnp.float32)
+
+
+def _experts_routed(x, wg, wu, wd, top_e, gates, e0, interpret: bool):
+    """Sort-based dropless dispatch: the (row, k) assignments that hit a
+    local expert, stably sorted by expert, through three grouped matmuls;
+    each row then gathers its K outputs back by the inverse permutation and
+    sums them under its gates (no scatter-add: on a v5e that walks its rows
+    one by one)."""
+    gmm = pinned_gmm()
+    T, D = x.shape
+    K = top_e.shape[1]
+    E_loc, _, F = wg.shape
+    with jax.named_scope("moe_dispatch"):
+        ek = top_e.reshape(T * K)
+        tok = jnp.arange(T * K, dtype=jnp.int32) // K
+        local = (ek >= e0) & (ek < e0 + E_loc)
+        key = jnp.where(local, ek - e0, E_loc)  # non-local sorts last
+        order = jnp.argsort(key, stable=True)
+        group_sizes = jnp.bincount(key, length=E_loc + 1).astype(jnp.int32)[:E_loc]
+        # non-local rows sort past sum(group_sizes): gmm never computes
+        # them (per-shard FLOPs stay ~1/e_sz of the fleet's). Their output
+        # AND vjp-cotangent rows are uninitialized, so (a) they gather from
+        # a phantom zero row T, and (b) every gmm output is masked so
+        # garbage can't ride the elementwise ops into the accumulated
+        # gradients or the combine.
+        computed = jnp.arange(T * K) < group_sizes.sum()
+        s_tok = jnp.where(computed, tok[order], T)
+        xs = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])[s_tok]  # [T*K, D] grouped by local expert
+        cm = computed[:, None]
+    with jax.named_scope("moe_experts"):
+        m = T * K
+        t_in, t_out = gmm_tiles(m, D, F, E_loc), gmm_tiles(m, F, D, E_loc)
+        if t_in[0] < min(m, 128):
+            _warn_small_tile_once((T, K, t_in[0]))
+        # outputs in the rows' type (the kernel accumulates in float32): at
+        # 32k assignment rows three float32 outputs are 0.75 GB a layer
+        kw = dict(preferred_element_type=x.dtype, interpret=interpret)
+        g1 = jnp.where(cm, gmm(xs, wg, group_sizes, tiling=t_in, **kw), 0)
+        u1 = jnp.where(cm, gmm(xs, wu, group_sizes, tiling=t_in, **kw), 0)
+        y = (jax.nn.silu(g1.astype(jnp.float32)) * u1.astype(jnp.float32)).astype(x.dtype)
+        yd = jnp.where(cm, gmm(y, wd, group_sizes, tiling=t_out, **kw), 0)
+    with jax.named_scope("moe_combine"):
+        back = jnp.argsort(order).reshape(T, K)  # where assignment (row, k) sorted to
+        g = (gates * local.reshape(T, K)).astype(jnp.float32)
+        return jnp.sum(yd[back].astype(jnp.float32) * g[:, :, None], axis=1)
+
+
+def expert_ffn(x, layer: dict, cfg, *, live=None, e0=0):
+    """The sparse-expert FFN on rows x [T, D] for the experts this block
+    holds (``layer["we_gate"]`` [E_loc, D, F], global ids from ``e0``).
+    Returns (the rows' outputs [T, D] float32, router scores [T, E], chosen
+    experts [T, K], load [E] int32: rows that chose each expert).
+
+    The form is chosen by shape, never by model (``takes_dense_form``): up
+    to ``DENSE_ROWS`` rows every expert runs on every row, above that rows
+    are routed through grouped matmuls. In both forms the gate and up
+    projections come out in the rows' type, as every dense MLP's do here
+    (silu and the product are float32 on those rounded values; the down
+    projection accumulates in float32): before PR 30 the routed form kept
+    them float32, which matters to a bfloat16 train step of ``qwen3_moe``
+    by one rounding of each ([T*K, F] x 2 x 4 B a layer is what it cost). ``live`` [T] bool marks the rows that hold a request;
+    the others count as no load and, where it is free (the dense form),
+    take no part."""
+    with jax.named_scope("moe_router"):
+        scores, gates, top_e = route(x, layer["w_router"], cfg, layer.get("router_bias"))
+        chose = jax.nn.one_hot(top_e, scores.shape[-1], dtype=jnp.int32).sum(1)  # [T, E]
+        if live is not None:
+            gates = gates * live[:, None]
+            chose = chose * live[:, None]
+        load = chose.sum(0)
+    wg, wu, wd = layer["we_gate"], layer["we_up"], layer["we_down"]
+    if takes_dense_form(x.shape[0], wg.shape[0]):
+        out = _experts_dense(x, wg, wu, wd, top_e, gates, e0)
+    else:
+        # platform decides compiled-or-interpret, nothing else: on a TPU gmm
+        # is always compiled and a kernel the chip refuses is an error
+        out = _experts_routed(x, wg, wu, wd, top_e, gates, e0, jax.default_backend() != "tpu")
+    return out, scores, top_e, load
 
 
 def moe_ffn_dropless(h: jax.Array, layer: dict, cfg) -> tuple[jax.Array, jax.Array]:
-    """Sort-based dropless MoE dispatch over the mesh ``expert`` axis.
+    """Dropless MoE over the mesh ``expert`` axis.
 
-    Inside a shard_map block (token shard x expert shard), the (token, k)
-    assignments hitting this shard's experts are stably sorted by local
-    expert id, run through grouped matmuls (``megablox.gmm`` — interpret
-    mode off-TPU, so CPU tests exercise the same code), and scattered back
-    with their gates; a psum over "expert" assembles each token's K expert
-    outputs. Every assignment is computed — token conservation is exact
+    Inside a shard_map block (token shard x expert shard), ``expert_ffn``
+    computes this shard's experts for its rows (``megablox.gmm`` in
+    interpret mode off-TPU, so CPU tests exercise the same code); a psum
+    over "expert" assembles each token's K expert outputs. Every assignment
+    is computed — token conservation is exact
     (tests/test_moe.py::test_dropless_token_conservation).
 
     Expert weights enter the block gathered over (fsdp, model) — the
@@ -149,10 +333,8 @@ def moe_ffn_dropless(h: jax.Array, layer: dict, cfg) -> tuple[jax.Array, jax.Arr
     that want both should use the capacity path)."""
     from areal_tpu.models.qwen import BATCH_AXES
 
-    gmm = pinned_gmm()
-
     G, L, D = h.shape
-    E, K = cfg.num_experts, cfg.num_experts_per_tok
+    E = cfg.num_experts
     axes = dict(get_abstract_mesh().shape)  # empty outside a mesh context
     e_sz = axes.get("expert", 1)
     d_sz = max(axes.get("data", 1) * axes.get("fsdp", 1), 1)
@@ -180,67 +362,21 @@ def moe_ffn_dropless(h: jax.Array, layer: dict, cfg) -> tuple[jax.Array, jax.Arr
         # truly unshardable: run replicated — every device computes all
         # tokens. Loud, because on a big mesh this is a real perf cliff.
         _warn_replicated_once((G, L, d_sz, s_sz, e_sz))
-    # platform decides compiled-or-interpret, nothing else: on a TPU gmm is
-    # always compiled and a kernel the chip refuses is an error
-    interpret = jax.default_backend() != "tpu"
-    tile_m0 = 16 if interpret else 128
+    bias = layer.get("router_bias")
 
     def block(h_blk, wr, wg, wu, wd):
         # h_blk [G_, L_, D]; wg/wu [E_loc, D, F]; wd [E_loc, F, D]
         G_, L_, _ = h_blk.shape
-        E_loc = wg.shape[0]
-        T = G_ * L_
-        # gmm requires its m dim (T*K) divisible by the m tile; tiny
-        # per-shard token counts (decode chunks, the forest's replicated
-        # fallback) take a smaller tile instead of failing. LARGE
-        # non-divisible shapes also land here — warn, because a collapsed
-        # m tile on a hot path is a silent perf cliff
-        tm = math.gcd(T * K, tile_m0)
-        if T * K >= tile_m0 and tm < tile_m0:
-            _warn_small_tile_once((T, K, tm, tile_m0))
-        tile = (tm, 128, 128)
-        x = h_blk.reshape(T, D)
-        probs, top_p, top_e = _router(
-            x.astype(jnp.float32), wr, K, cfg.norm_topk_prob
-        )
-        e0 = jax.lax.axis_index("expert") * E_loc if in_mesh else 0
-        ek = top_e.reshape(T * K)
-        gk = top_p.reshape(T * K)
-        tok = jnp.arange(T * K, dtype=jnp.int32) // K
-        local = (ek >= e0) & (ek < e0 + E_loc)
-        key = jnp.where(local, ek - e0, E_loc)  # non-local sorts last
-        order = jnp.argsort(key, stable=True)
-        sizes = jnp.bincount(key, length=E_loc + 1).astype(jnp.int32)
-        # non-local rows sort past sum(group_sizes): gmm never computes
-        # them (per-shard FLOPs stay ~1/e_sz of the fleet's). Their output
-        # AND vjp-cotangent rows are uninitialized, so (a) they gather from
-        # / scatter to a phantom zero token row T, keeping garbage out of
-        # real tokens in both directions, and (b) every gmm output is
-        # masked so garbage can't ride the elementwise ops into the
-        # accumulated gradients.
-        group_sizes = sizes[:E_loc]
-        n_local = group_sizes.sum()
-        computed = jnp.arange(T * K) < n_local
-        s_tok = jnp.where(computed, tok[order], T)  # phantom row for tail
-        x_ext = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])
-        xs = x_ext[s_tok]  # [T*K, D] grouped by local expert
-        cm = computed[:, None]
-        g1 = jnp.where(cm, gmm(xs, wg, group_sizes, tiling=tile, interpret=interpret), 0)
-        u1 = jnp.where(cm, gmm(xs, wu, group_sizes, tiling=tile, interpret=interpret), 0)
-        y = (jax.nn.silu(g1) * u1).astype(x.dtype)
-        yd = jnp.where(cm, gmm(y, wd, group_sizes, tiling=tile, interpret=interpret), 0)
-        gates = (gk * local)[order].astype(jnp.float32)
-        contrib = yd.astype(jnp.float32) * gates[:, None]
-        out = (
-            jnp.zeros((T + 1, D), jnp.float32).at[s_tok].add(contrib)[:T]
-        )
+        blk = {"w_router": wr, "we_gate": wg, "we_up": wu, "we_down": wd}
+        if bias is not None:
+            blk["router_bias"] = bias
+        e0 = jax.lax.axis_index("expert") * wg.shape[0] if in_mesh else 0
+        out, probs, top_e, _ = expert_ffn(h_blk.reshape(G_ * L_, D), blk, cfg, e0=e0)
         if in_mesh:
             out = jax.lax.psum(out, "expert")
         # switch-style aux from the (replicated-over-expert) global routing
-        onehot = jax.nn.one_hot(top_e, E, dtype=jnp.float32)
-        frac = onehot.reshape(T * K, E).mean(0)
-        mean_prob = probs.mean(0)
-        aux = (frac * mean_prob).sum() * E
+        frac = jax.nn.one_hot(top_e, E, dtype=jnp.float32).reshape(-1, E).mean(0)
+        aux = (frac * probs.mean(0)).sum() * E
         if in_mesh:
             aux = jax.lax.pmean(aux, ("data", "fsdp", "seq"))
         return out.reshape(G_, L_, D).astype(h_blk.dtype), aux
@@ -278,15 +414,18 @@ _SMALL_TILE_WARNED: set = set()
 
 
 def _warn_small_tile_once(key: tuple) -> None:
+    """The routed form with a row count no wide m tile divides: a collapsed
+    m tile on a prefill or a train step is a silent perf cliff. (A decode
+    step's few rows never come here: they take the dense form.)"""
     if key in _SMALL_TILE_WARNED:
         return
     _SMALL_TILE_WARNED.add(key)
     from areal_tpu.utils import logging as alog
 
     alog.getLogger("moe").warning(
-        "moe gmm m dim T*K=%s*%s is not divisible by the %s tile; running "
-        "with m tile %s — pad the token count to the tile for full "
-        "throughput" % (key[0], key[1], key[3], key[2])
+        "moe gmm m dim T*K=%s*%s has no m tile of 128 or more dividing it; "
+        "running with m tile %s — pad the token count to a multiple of 128 "
+        "for full throughput" % key
     )
 
 

@@ -29,7 +29,7 @@ import dataclasses
 import json
 import os
 from functools import partial
-from typing import Any
+from typing import Any, ClassVar
 
 import jax
 import jax.numpy as jnp
@@ -77,7 +77,8 @@ class ModelConfig:
     # "pallas" (fused flash kernel; falls back to xla off-TPU)
     attn_impl: str = "xla"
     # MoE (qwen3-moe family; 0 experts = dense FFN). Experts shard over the
-    # mesh "expert" axis; dispatch is capacity-based einsum (models/moe.py)
+    # mesh "expert" axis; dispatch is dropless unless moe_dropless is off,
+    # then a capacity-bounded einsum (models/moe.py)
     num_experts: int = 0
     num_experts_per_tok: int = 2
     moe_intermediate_size: int | None = None
@@ -126,6 +127,8 @@ class ModelConfig:
         return self.head_dim_
 
     has_recurrent_state = False
+    # expert-load counts a decode chunk would hand back (models/hybrid.py): none
+    moe_count_shapes: ClassVar[dict] = {}
 
     def state_shapes(self, slots: int) -> dict:
         return {}

@@ -155,6 +155,20 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "areal_decode_state_bytes",
             "Device bytes of the slot-indexed recurrent state.",
         ),
+        # a model with sparse experts (models/moe.py); counted on the device
+        # inside the decode chunk, for live slots only, and brought back with
+        # the chunk's tokens. Per-expert counts: /statusz ``moe.load``
+        moe_assignments=r.counter(
+            "areal_decode_moe_assignments_total",
+            "(row, expert) assignments of decode steps: live slots x experts "
+            "per token x expert layers.",
+        ),
+        moe_experts_touched=r.counter(
+            "areal_decode_moe_experts_touched_total",
+            "Experts that got at least one live slot's row, summed over "
+            "decode steps and expert layers (each costs one read of its "
+            "weights).",
+        ),
     )
 
 

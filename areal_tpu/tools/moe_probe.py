@@ -1,0 +1,127 @@
+"""Time one sparse-expert layer alone on the chip at the widths of the
+benchmark's ``rollout-lfm2-8b-a1b-d14-grpo`` (32 experts of [2048, 1792],
+top-4), in both forms of ``models/moe.py`` and at the tiles it would choose.
+
+    chiprun -- python -m areal_tpu.tools.moe_probe
+
+For each row count (128: a decode step of 128 slots; 256 to 1024: a short
+prompt's prefill, up to ``moe.DENSE_ROWS``; 4096: a batched prefill) it scans ``--layers`` stacked
+layers, as a serving program does, and prints microseconds a layer for
+
+  ``dense``          every expert on every row (``moe._experts_dense``)
+  ``routed``         sort, ``megablox.gmm`` at ``moe.gmm_tiles``, gather back
+  ``routed_128``     the same at the library's 128 x 128 x 128 tiles (what
+                     ``models/moe.py`` ran before PR 30)
+  ``routed_wide``    (small row counts only) gmm with the whole contraction
+                     a tile: tiles (128, k, n/2)
+  ``routed_tm<n>``   (``--tm``) the chosen tiles with another m tile
+
+with the share of the memory roofline the bytes of the touched experts'
+weights reach (819 GB/s) and, for large row counts, of the MXU's peak.
+Routing is drawn at random (uniform over experts, as seeded weights route).
+TPU only: a CPU time is no speed.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import time
+
+E, K, D, F = 32, 4, 2048, 1792
+HBM_BYTES_S, FLOPS = 819e9, 197e12  # TPU v5e, as benchmarks/chip/benchlib/peaks.py
+
+
+def probe(rows: int, layers: int, reps: int, seed: int, tms=()) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from areal_tpu.models import moe
+
+    key = jax.random.PRNGKey(seed)
+    ks = jax.random.split(key, 6)
+    x = jax.random.normal(ks[0], (rows, D), jnp.bfloat16)
+    wg = 0.02 * jax.random.normal(ks[1], (layers, E, D, F), jnp.bfloat16)
+    wu = 0.02 * jax.random.normal(ks[2], (layers, E, D, F), jnp.bfloat16)
+    wd = 0.02 * jax.random.normal(ks[3], (layers, E, F, D), jnp.bfloat16)
+    top_e = jnp.argsort(jax.random.uniform(ks[4], (rows, E)), axis=-1)[:, :K].astype(jnp.int32)
+    gates = jnp.full((rows, K), 1.0 / K, jnp.float32)
+    touched = int(np.unique(np.asarray(top_e)).size)
+    chosen = (moe.gmm_tiles(rows * K, D, F, E), moe.gmm_tiles(rows * K, F, D, E))
+
+    def with_tiles(t_in, t_out):
+        def patched(m, k, n, groups=1):
+            return t_in if (k, n) == (D, F) else t_out
+
+        return patched
+
+    def run(form):
+        def layer(h, w):
+            a, b, c = w
+            if form == "dense":
+                out = moe._experts_dense(h, a, b, c, top_e, gates, 0)
+            else:
+                out = moe._experts_routed(h, a, b, c, top_e, gates, 0, False)
+            return (h + out.astype(h.dtype) * 0.01), None
+
+        def model(h, wg, wu, wd):
+            return jax.lax.scan(layer, h, (wg, wu, wd))[0]
+
+        tiles = {
+            "routed_128": with_tiles((128, 128, 128), (128, 128, 128)),
+            "routed_wide": with_tiles((min(rows * K, 128), D, F // 2), (min(rows * K, 128), F, D // 2)),
+            **{f"routed_tm{tm}": with_tiles((tm, *chosen[0][1:]), (tm, *chosen[1][1:])) for tm in tms},
+        }.get(form)
+        keep = moe.gmm_tiles
+        if tiles is not None:
+            moe.gmm_tiles = tiles
+        try:
+            fn = jax.jit(model)
+            fn(x, wg, wu, wd).block_until_ready()
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                out = fn(x, wg, wu, wd)
+            out.block_until_ready()
+            return (time.perf_counter() - t0) / (reps * layers) * 1e6
+        finally:
+            moe.gmm_tiles = keep
+
+    forms = ["dense", "routed", "routed_128"] + (["routed_wide"] if rows <= 512 else []) + [f"routed_tm{tm}" for tm in tms]
+    if rows > 2048:
+        forms.remove("dense")  # 8 x the rows' arithmetic: nobody's path
+    res = {"rows": rows, "experts_touched": touched, "tiles": [list(t) for t in chosen]}
+    least_bytes = touched * 3 * D * F * 2 / HBM_BYTES_S * 1e6
+    least_flops = rows * K * 3 * 2 * D * F / FLOPS * 1e6
+    for form in forms:
+        try:
+            us = run(form)
+        except Exception as e:  # noqa: BLE001 — a tile the chip's compiler refuses is a finding, not a crash
+            res[form] = f"refused: {str(e)[:200]}"
+            continue
+        res[f"{form}_us"] = round(us, 1)
+        res[f"{form}_roofline_pct"] = round(100 * max(least_bytes, least_flops) / us, 1)
+    res["least_us"] = {"bytes": round(least_bytes, 1), "flops": round(least_flops, 1)}
+    return res
+
+
+def main(argv=None) -> int:
+    import jax
+
+    p = argparse.ArgumentParser()
+    p.add_argument("--rows", default="128,256,512,4096")
+    p.add_argument("--layers", type=int, default=4)
+    p.add_argument("--reps", type=int, default=20)
+    p.add_argument("--seed", type=int, default=0)
+    p.add_argument("--tm", default="", help="more routed variants: the chosen tiles with these m tiles")
+    a = p.parse_args(argv)
+    if jax.default_backend() != "tpu":
+        print("moe_probe needs a TPU: a CPU time is no speed")
+        return 2
+    for rows in [int(r) for r in a.rows.split(",")]:
+        print(json.dumps(probe(rows, a.layers, a.reps, a.seed, [int(t) for t in a.tm.split(",") if t])), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -41,7 +41,7 @@ def _seeded(layer_types=("mamba", "attention", "mamba"), seed=11):
 def test_full_forward_matches_reference(layer_types):
     cfg, mcfg, params = _seeded(layer_types)
     from benchlib import hybrid_reference
-    assert [k for k, _, _ in hybrid._runs(mcfg.layer_types)] == [
+    assert [run[0] for run in hybrid._runs(mcfg)] == [
         k for i, k in enumerate(layer_types) if i == 0 or layer_types[i - 1] != k
     ]
     ids = np.random.default_rng(0).integers(0, cfg["vocab_size"], 53)  # 3 chunks of 16 and 5 more

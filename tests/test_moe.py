@@ -162,14 +162,29 @@ def test_moe_train_step():
     assert losses[-1] < losses[0], losses
 
 
-def test_dropless_token_conservation():
+@pytest.mark.parametrize(
+    "form,dtype,tol",
+    [("dense", "float32", 1e-6), ("routed", "float32", 1e-6), ("dense", "bfloat16", 3e-5), ("routed", "bfloat16", 3e-5)],
+)
+def test_dropless_token_conservation(form, dtype, tol, monkeypatch):
     """Dropless dispatch computes EVERY routed (token, k) assignment even
     under routing imbalance that would overflow any capacity buffer —
     output equals an explicit per-token loop over the top-k experts
     (reference parity target: archon/moe token-shuffle kernels compute all
-    assignments, kernels.py:1-228)."""
+    assignments, kernels.py:1-228). In both forms of ``moe.expert_ffn``
+    (32 rows take the dense one; the routed one is what a train step's
+    shard runs), and in bfloat16, where the gate and up projections come
+    out rounded to the rows' type. The loop is float64 on the same weights
+    and rows; the outputs are up to 1e-3 in size (weights N(0, 0.02)) and
+    read 4e-10 off in float32, 7e-6 in bfloat16: the tolerances are 1e-6
+    and 3e-5 of absolute error."""
+    from areal_tpu.models import moe
+
+    if form == "routed":
+        monkeypatch.setattr(moe, "DENSE_ROWS", 0)
+    assert moe.takes_dense_form(32, 4) == (form == "dense")
     cfg = qwen.ModelConfig(
-        **{**MOE_CFG.__dict__, "moe_dropless": True, "norm_topk_prob": True}
+        **{**MOE_CFG.__dict__, "moe_dropless": True, "norm_topk_prob": True, "dtype": dtype}
     )
     params = qwen.init_params(jax.random.PRNGKey(3), cfg)
     layer = jax.tree.map(lambda x: x[0], params["layers"])
@@ -177,10 +192,10 @@ def test_dropless_token_conservation():
     # near-identical tokens -> all route to the same experts (max imbalance)
     base = rng.normal(0, 1, 32)
     h = jnp.asarray(
-        base[None, None, :] + 0.01 * rng.normal(0, 1, (2, 16, 32)), jnp.float32
+        base[None, None, :] + 0.01 * rng.normal(0, 1, (2, 16, 32)), cfg.jax_dtype
     )
     out, aux = moe_ffn(h, layer, cfg)
-    assert np.isfinite(float(aux))
+    assert np.isfinite(float(aux)) and out.dtype == cfg.jax_dtype
 
     # explicit per-token reference
     E, K = cfg.num_experts, cfg.num_experts_per_tok
@@ -200,9 +215,10 @@ def test_dropless_token_conservation():
                 up = x @ np.asarray(layer["we_up"][e], np.float64)
                 y = (gg / (1 + np.exp(-gg))) * up
                 want[g, t] += gate * (y @ np.asarray(layer["we_down"][e], np.float64))
-    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-3, atol=2e-3)
+    np.testing.assert_allclose(np.asarray(out, np.float64), want, rtol=0, atol=tol)
+    assert np.abs(want).max() > 5e-4
     # and every token got nonzero expert output (nothing dropped)
-    assert (np.abs(np.asarray(out)).sum(-1) > 1e-7).all()
+    assert (np.abs(np.asarray(out, np.float64)).sum(-1) > 1e-7).all()
 
 
 def test_dropless_ep_sharded_matches_single_device():

@@ -358,3 +358,75 @@ def test_static_shape_rule_matches_the_compiler(chip):
         assert compiles(hd, psz, dt) == paged_kernel_ok(
             hd, psz, dt != jnp.bfloat16
         ), (hd, psz, dt)
+
+
+def _lfm2(chip, monkeypatch):
+    """The ``lfm2_moe`` family at the benchmark's published widths (32
+    experts of [2048, 1792], heads of 64 padded to 128 lanes, 65k
+    vocabulary) and 128 slots, cut to one layer of each kind it has (conv +
+    dense FFN, attention + experts, conv + experts) so that tier-1 can hold
+    the compile; weights and cache are shapes on the described chip."""
+    import json
+
+    from areal_tpu import models
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "chip", "configs", "lfm2-8b-a1b-d14.json")) as f:
+        cfg = json.load(f)
+    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "reduced_from", "assumed", "assumed_notes", "stands_for")}
+    hf.update(cfg["assumed"], num_hidden_layers=3, num_dense_layers=1, layer_types=["conv", "full_attention", "conv"], dtype="bfloat16")
+    mcfg = models.config_from_hf_dict(hf)
+    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
+    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, 2225, PSZ, slots=SLOTS))
+    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
+    # the kernels and gmm ask the platform whether to compile or interpret: the described chip is a TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return mcfg, place(params), place(cache)
+
+
+def test_lfm2_decode_steps_compile_for_v5e(chip, monkeypatch):
+    """Two decode steps as the engine's chunk runs them (the expert layer's
+    dense form at 128 rows, the paged kernels on padded heads, the load
+    counts in the carry): no copy of a whole expert stack into another
+    layout (XLA:TPU made one of 5 GB for the un-batched einsum)."""
+    from areal_tpu.models import hybrid
+
+    mcfg, params, cache = _lfm2(chip, monkeypatch)
+
+    def two_steps(params, cache, pt, ids, pos, active):
+        cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.moe_count_shapes.items()}}
+
+        def step(c, _):
+            ids, pos, cache = c
+            h, cache = hybrid.forward_decode_paged(params, mcfg, ids, pos, cache, pt, page_size=PSZ, active=active, use_kernel=True)
+            return (jnp.argmax(hybrid.compute_logits(params, mcfg, h), -1).astype(jnp.int32), pos + 1, cache), None
+
+        (ids, _, cache), _ = jax.lax.scan(step, (ids, pos, cache), None, length=2)
+        return ids, cache
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(SLOTS, 32), i32(SLOTS), i32(SLOTS), chip((SLOTS,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "paged_decode_attn" in text and "paged_kv_write" in text
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and "bf16[1,32,2048,1792]" in ln]
+    assert compiled.memory_analysis().temp_size_in_bytes < 200e6
+
+
+def test_lfm2_prefill_compiles_for_v5e(chip, monkeypatch):
+    """A batched prefill of 4 x 1024 tokens: the routed expert form (16k
+    assignment rows through ``megablox.gmm`` at ``moe.gmm_tiles``, which the
+    chip's compiler must accept inside its default scoped VMEM), the masked
+    conv window, the state's slot writes and the KV scatter."""
+    from areal_tpu.models import hybrid, moe
+
+    mcfg, params, cache = _lfm2(chip, monkeypatch)
+    assert not moe.takes_dense_form(4 * 1024, 32)
+
+    def prefill(params, cache, ids, plens, flat_pages, slots):
+        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(4, 1024), i32(4), i32(4 * 1024 // PSZ), i32(4)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3  # the three grouped matmuls of the expert layer
