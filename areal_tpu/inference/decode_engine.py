@@ -11,7 +11,10 @@ with a JAX decode engine built for the async-RL protocol (SURVEY §7.1):
   (KV scattered into their pages), then all slots step together in a jitted
   multi-token ``lax.scan`` decode chunk (``decode_steps_per_call``) running
   the Pallas paged-attention kernel — static shapes everywhere, a bounded
-  set of compiled programs (windows bucketed in pages).
+  set of compiled programs (windows bucketed in pages). The device queue is
+  at most two chunks deep: the running one and, from the pass's commit
+  point part-way through it (inference/commit_point.py), the next; what
+  arrives before that point is admitted into the next chunk.
 - **GRPO prefix sharing by page aliasing**: a group's identical prompts
   prefill once; duplicates share the full prompt pages (refcount++) and
   copy only the final partial page. Pool exhaustion evicts parked KV, then
@@ -57,6 +60,7 @@ from areal_tpu.api.config import ServerConfig
 from areal_tpu.api import io_struct
 from areal_tpu.api.io_struct import ModelRequest, ModelResponse, StopReason
 from areal_tpu import models
+from areal_tpu.inference import commit_point
 from areal_tpu.models import qwen
 from areal_tpu.models.hf import load_params_from_hf
 from areal_tpu.observability import catalog as obs_catalog
@@ -346,6 +350,14 @@ class DecodeEngine:
         # the current pass's open timeline on the decode thread
         self.kprobe: kernel_probe.KernelProbe | None = None
         self._ktl: kernel_probe.DecodeStepTimeline | None = None
+        # the commit point of the next chunk's batch (inference/commit_point.py):
+        # the chunk-time estimate, and the clock and the interruptible wait it
+        # is kept on (tests put a simulated clock and wait in their place)
+        self._pacer = commit_point.ChunkPacer()
+        self._pace_clock: Callable[[], float] = time.monotonic
+        self._pace_wait: Callable[[float], bool] = self._wakeup.wait
+        self._pull_s = 0.0  # seconds of the current pass inside blocking pulls
+        self._pass_start = 0.0  # time.monotonic() at the top of the pass
 
     # -- lifecycle --------------------------------------------------------
     def initialize(self) -> None:
@@ -2572,6 +2584,7 @@ class DecodeEngine:
         rows: list[np.ndarray] = []
         to_prefill: list[tuple[_Task, int]] = []  # (task, slot)
         free = self._free_slots()
+        in_wait = 0  # admitted here, submitted after this pass began
         while not self._paused.is_set():
             if self._backlog:
                 task = self._backlog.popleft()
@@ -2598,6 +2611,7 @@ class DecodeEngine:
             row = self._try_resume(task)
             if row is not None:
                 rows.append(row)
+                in_wait += task.submit_time > self._pass_start
                 continue
             if not free:
                 evicted = self._evict_oldest_parked()
@@ -2606,6 +2620,11 @@ class DecodeEngine:
                     break
                 free.append(evicted)
             to_prefill.append((task, free.pop(0)))
+            in_wait += task.submit_time > self._pass_start
+        if in_wait:
+            # arrived after the pass began: a commit at the pass's start
+            # would have left them a chunk behind
+            self._obs.admitted_in_wait.inc(in_wait)
 
         # split identical-prompt duplicates off (vision requests excluded —
         # their KV depends on image data too)
@@ -3325,6 +3344,8 @@ class DecodeEngine:
             "n_steps": n_steps,
             "version": self._version,
             "was_active": active.copy(),
+            # the chunk program's key: another program, another chunk time
+            "key": (wp, capped, greedy_any, freq_any),
             # task identity per slot at dispatch: a slot can turn over
             # between dispatch and drain (its task finished in an earlier
             # drain, a new task admitted) — results then belong to the OLD
@@ -3543,7 +3564,13 @@ class DecodeEngine:
         with self._kphase("device_wait"):
             # the one device->host pull: blocks until the chunk's compute
             # finishes, so its span IS the visible device time of the pass
-            packed = np.asarray(pending["packed"])
+            t_pull = self._pace_clock()
+            packed = self._pull(pending["packed"])
+            t_back = self._pace_clock()
+        self._pull_s += t_back - t_pull
+        if "key" in pending:
+            # two consecutive returns are one chunk's wall time apart
+            self._pacer.pulled(t_back, pending["key"])
         credited = 0
         with self._kphase("bookkeeping"):
             n_steps = pending["n_steps"]
@@ -3604,6 +3631,49 @@ class DecodeEngine:
             self._obs.chunks.inc()
         return credited
 
+    def _pull(self, packed) -> np.ndarray:
+        """A chunk's one device->host transfer: returns when the chunk's
+        compute has ended."""
+        return np.asarray(packed)
+
+    def _loop_needed(self) -> bool:
+        """Whether something waits that the loop serves at its top or at a
+        pass's reap: the hold for the commit point ends for it at once."""
+        return (
+            self._shutdown.is_set()
+            or self._paused.is_set()
+            or self._held.is_set()
+            or self._draining.is_set()
+            or self._pending_weight_update is not None
+            or self._radix_flush_req is not None
+            or bool(self._abort_rids)
+        )
+
+    def _hold_for_commit(self, pending: dict | None) -> float:
+        """With a chunk in flight, wait until the commit point inside it
+        (inference/commit_point.py) so that what arrives until then is
+        admitted into the next chunk, not the one after. Returns the seconds
+        held; 0 without a chunk in flight or without an estimate of its time.
+
+        The time is spent waiting for the device, so it goes under the
+        ``device_wait`` phase. A submit wakes the wait and does not end it
+        (one admission a pass); anything the loop must look at ends it."""
+        if pending is None:
+            self._pacer.reset()
+            return 0.0
+        at = self._pacer.commit_point(pending["key"])
+        t0 = self._pace_clock()
+        if at is None or at <= t0:
+            return 0.0
+        with self._kphase("device_wait"):
+            while not self._loop_needed():
+                left = at - self._pace_clock()
+                if left <= 0:
+                    break
+                self._pace_wait(left)
+                self._wakeup.clear()
+        return self._pace_clock() - t0
+
     def _kphase(self, name: str):
         """Phase span on the current pass's kernel-probe timeline
         (observability/kernel_probe.py); a no-op null context outside a
@@ -3639,13 +3709,22 @@ class DecodeEngine:
         active after the pass and the tokens the pass credited. (A pass
         that started with requests queued and admitted none of them, all
         expired, still leaves its span, with 0 and 0.)"""
+        self._pass_start = time.monotonic()
+        t_pass = self._pace_clock()
+        self._pull_s = 0.0
+        # the chunk in flight has only just begun: commit the next one's
+        # batch part-way through it, with everything that arrives until then
+        held = self._hold_for_commit(pending)
         # lifecycle reaping BETWEEN chunks: cancellations, expired
         # deadlines (queued and decoding), per-slot watchdog — the
         # overload-safety half of interruptible generation. When a reap
         # fires, the in-flight chunk is drained first (tokens credited)
         # and None comes back; the fast path returns pending untouched.
         with self._kphase("admission"):
+            in_flight = pending
             pending = self._reap_lifecycle(pending)
+            if pending is not in_flight:
+                self._pacer.reset()  # the device idles through the reap
             # admissions enqueue prefills + ONE packed state scatter; the
             # in-flight chunk (if any) ordered before them touches only
             # previously-active slots, so there is no dataflow hazard
@@ -3679,8 +3758,14 @@ class DecodeEngine:
             tokens = self._drain(pending)
             pending = dispatched
             worked = dispatched is not None
+        if drained or worked or rows:  # a bare poll says nothing of a pass's host work
+            self._pacer.host_work(self._pace_clock() - t_pass - held - self._pull_s)
         if span is not None:
-            span.set(active=int(self._state["active"].sum()), tokens=tokens)
+            span.set(
+                active=int(self._state["active"].sum()),
+                tokens=tokens,
+                held_us=int(held * 1e6),
+            )
         if step_tl is not None:
             # a pass that drained, dispatched, or admitted is a real
             # step; a bare poll (no slots, empty queue) is not

@@ -127,6 +127,12 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
         prefills=r.counter(
             "areal_decode_prefills_total", "Sequences prefilled."
         ),
+        admitted_in_wait=r.counter(
+            "areal_decode_admitted_in_wait_total",
+            "Requests admitted in a decode pass that were submitted after the "
+            "pass began: those its hold for the commit point let into the "
+            "next chunk, not the one after.",
+        ),
         prefill_tokens=r.counter(
             "areal_decode_prefill_tokens_total",
             "Prompt tokens actually prefilled (radix-cached prefix tokens "
