@@ -516,7 +516,7 @@ class DecodeEngine:
         )
 
     def _check_recurrent_config(self) -> None:
-        """What a model with recurrent (state-space) layers cannot be served
+        """What a model with recurrent (state-space, short-conv or delta-rule) layers cannot be served
         with, refused when the engine is configured and not at the first
         request that would need it: a recurrent state cannot be cut back to
         a token boundary, so nothing may roll it back or hand out a prefix
@@ -1821,17 +1821,22 @@ class DecodeEngine:
             exclude_from_total=("radix_cache",),
         )
 
-    def _credit_moe_counts(self, flat: np.ndarray) -> None:
-        """A drained chunk's expert-load counts (the order of
-        ``model_cfg.moe_count_shapes``) into the host's running sums."""
-        shapes = self.model_cfg.moe_count_shapes
-        n_load = int(np.prod(shapes["moe_load"]))
-        load = flat[:n_load].reshape(shapes["moe_load"]).astype(np.int64)
-        touched = int(flat[n_load : n_load + shapes["moe_touched"][0]].sum())
-        # arealint: disable-next=THR001 single writer (the decode loop, at a drain); /statusz reads whichever whole array the name holds: a rebind, never an in-place add
-        self._moe_load = self._moe_load + load
-        self._obs.moe_assignments.inc(int(load.sum()))
-        self._obs.moe_experts_touched.inc(touched)
+    def _credit_counts(self, flat: np.ndarray) -> None:
+        """A drained chunk's device-side counts (the leaves and the order of
+        ``model_cfg.count_shapes``, flat) into the host's running sums."""
+        at = 0
+        counts = {}
+        for name, shape in self.model_cfg.count_shapes.items():
+            n = int(np.prod(shape))
+            counts[name] = flat[at : at + n].reshape(shape).astype(np.int64)
+            at += n
+        if "moe_load" in counts:
+            # arealint: disable-next=THR001 single writer (the decode loop, at a drain); /statusz reads whichever whole array the name holds: a rebind, never an in-place add
+            self._moe_load = self._moe_load + counts["moe_load"]
+            self._obs.moe_assignments.inc(int(counts["moe_load"].sum()))
+            self._obs.moe_experts_touched.inc(int(counts["moe_touched"].sum()))
+        if "gdn_updates" in counts:
+            self._obs.gdn_state_updates.inc(int(counts["gdn_updates"].sum()))
 
     def moe_status(self) -> dict | None:
         """/statusz ``moe``: ``load`` = rows of live slots every expert of
@@ -2058,8 +2063,8 @@ class DecodeEngine:
         array [2*n_steps + 3, S] — token rows, logprob-bit rows (fp32
         bitcast), then emit_count / final-active / final-pos rows — so the
         host pays a single device->host transfer per chunk. A model with
-        sparse experts appends its expert-load counts of the chunk
-        (``model_cfg.moe_count_shapes``, flat, in whole rows of S). Emission is
+        sparse experts or delta-rule layers appends its counts of the chunk
+        (``model_cfg.count_shapes``, flat, in whole rows of S). Emission is
         monotone within a chunk (a stopped slot never re-activates; admits
         happen between chunks), so per-slot counts fully describe the
         emit mask."""
@@ -2071,10 +2076,10 @@ class DecodeEngine:
             use_kernel = self._use_kernel
             model = self.model
 
-            counts_of = dict(mcfg.moe_count_shapes)
+            counts_of = dict(mcfg.count_shapes)
 
             def chunk(params, cache, page_table, state, rng):
-                # expert-load counts of this chunk's steps: zeroed here, added
+                # the model's counts of this chunk's steps: zeroed here, added
                 # to by the model's forward for the active slots only, and
                 # handed back in ``packed`` (they are no part of the cache)
                 cache = {**cache, **{k: jnp.zeros(shp, jnp.int32) for k, shp in counts_of.items()}}
@@ -3581,8 +3586,8 @@ class DecodeEngine:
             emit_count = packed[2 * n_steps]
             active = packed[2 * n_steps + 1].astype(bool)
             pos = packed[2 * n_steps + 2]
-            if self._moe_load is not None:
-                self._credit_moe_counts(packed[2 * n_steps + 3 :].reshape(-1))
+            if self.model_cfg.count_shapes:
+                self._credit_counts(packed[2 * n_steps + 3 :].reshape(-1))
             st = self._state
             now = time.monotonic()
             for slot, task in enumerate(pending["tasks"]):

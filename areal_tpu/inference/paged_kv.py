@@ -396,11 +396,14 @@ def n_pages_for_budget(
 # So prefill masks what it feeds the state instead of relying on overwrite,
 # a decode step changes live slots only, a GRPO sibling gets a COPY of its
 # primary's post-prompt state, and a parked slot keeps its rows untouched.
-# Two tenants (models/hybrid.py): a Mamba-2 layer's SSM state and conv window
-# (``ssm`` + ``conv``, 76 MB a slot at granite-4.0-h-micro), and a short-conv
+# Three tenants (models/hybrid.py): a Mamba-2 layer's SSM state and conv window
+# (``ssm`` + ``conv``, 76 MB a slot at granite-4.0-h-micro), a short-conv
 # layer's window alone (``conv``: the last two gate products, 90 KB a slot at
-# LFM2-8B-A1B's 11 conv layers). Both go through the same programs.
-STATE_LEAVES = ("ssm", "conv")
+# LFM2-8B-A1B's 11 conv layers), and a gated-delta-rule layer's matrix state
+# and its three conv windows (``gdn`` + ``conv``: 30 heads of 96 x 192 in
+# float32, 2.2 MB a layer and slot at Olmo-Hybrid-7B). All go through the same
+# programs.
+STATE_LEAVES = ("ssm", "conv", "gdn")
 
 
 def init_paged_cache(

@@ -11,8 +11,8 @@ from areal_tpu.models.qwen import (  # noqa: F401
 def family_of(model_cfg):
     """The module that implements ``model_cfg``'s model family: the one place
     where the serving stack picks between them (``models/qwen.py``: the
-    Qwen2/3 and llama decoders; ``models/hybrid.py``: ``granitemoehybrid``
-    and ``lfm2_moe``). Both have the entry points
+    Qwen2/3 and llama decoders; ``models/hybrid.py``: ``granitemoehybrid``,
+    ``lfm2_moe`` and ``olmo_hybrid``). Both have the entry points
     the decode engine calls (``param_partition_specs``, ``hf_name_map``,
     ``prefill_into_cache``, ``forward_prefill_paged``,
     ``forward_decode_paged``, ``forward_verify_paged``, ``compute_logits``,

@@ -3,14 +3,19 @@ of the published ``layer_types``, each followed by a feed-forward block of
 its own kind.
 
 Mixer kinds: ``mamba`` (a Mamba-2 state-space layer), ``attention`` (GQA;
-with or without a rotary embedding and per-head q/k RMSNorm, as the
-configuration says) and ``conv`` (a gated short convolution). FFN kinds:
-``dense`` (SwiGLU) and ``moe`` (sparse experts, ``models/moe.py``). Two
-published families are built from these (``from_hf_dict``):
-``granitemoehybrid`` without experts (Mamba-2 beside NoPE attention, dense
-MLPs, residual and logit multipliers) and ``lfm2_moe`` (short convolutions
-beside rotary attention with q/k norms; the first ``num_dense_layers`` FFNs
-dense, the rest 32 experts behind a sigmoid router with a selection bias).
+with or without a rotary embedding and a q/k RMSNorm over each head or over
+the whole projection, as the configuration says), ``conv`` (a gated short
+convolution) and ``gdn`` (a gated delta rule: linear attention with a matrix
+state a head). FFN kinds: ``dense`` (SwiGLU) and ``moe`` (sparse experts,
+``models/moe.py``). A block's two RMSNorms stand on its sublayers' inputs
+(``norm_placement`` ``pre``) or on their outputs (``post``). Three published
+families are built from these (``from_hf_dict``): ``granitemoehybrid``
+without experts (Mamba-2 beside NoPE attention, dense MLPs, residual and
+logit multipliers), ``lfm2_moe`` (short convolutions beside rotary attention
+with q/k norms; the first ``num_dense_layers`` FFNs dense, the rest 32
+experts behind a sigmoid router with a selection bias) and ``olmo_hybrid``
+(gated-delta-rule layers beside NoPE attention with whole-projection q/k
+norms, post-sublayer norms, dense MLPs).
 Serving only (prefill, paged decode); training is ROADMAP Reach A.4.
 
 The module has the entry points the decode engine uses of ``models/qwen.py``
@@ -39,6 +44,20 @@ The short-conv mixer: ``[B | C | x] = W_in u``, ``g_t = B_t * x_t``,
 ``g``. ``conv_prefill`` and ``conv_decode`` are its two forms
 (tests/test_lfm2_model.py holds them to each other).
 
+The gated-delta-rule mixer (the ``fla`` library's ``GatedDeltaNet``): q, k,
+v each through a projection, a depthwise causal conv of its own and SiLU; q
+and k L2-normalised a head; per head a decay ``alpha_t = exp(-exp(A_log)
+softplus(W_a x_t + dt_bias))`` and a write strength ``beta_t`` in (0, 1) or
+(0, 2); state ``S`` in R^{K x V}: ``S' = alpha_t S_{t-1}``, ``u_t = beta_t
+(v_t - S'^T k_t)``, ``S_t = S' + k_t u_t^T``, ``o_t = S_t^T q_t``: a read of
+the decayed state BEFORE a rank-one write. ``gdn_decode_step`` is the
+recurrence itself (on a TPU the Pallas kernel ``ops/gdn_state_update.py``
+over the live slots, in place); ``gdn_chunked_scan`` is the chunked (WY)
+algorithm over a whole prompt: inside a chunk of ``GDN_CHUNK`` tokens a
+unit lower-triangular system, across chunks the state carried in float32.
+tests/test_olmo_hybrid_model.py holds them to each other and to the
+token-by-token reference.
+
 What a slot's recurrent state is, and who may write it, is in
 ``inference/paged_kv.py`` (STATE_LEAVES).
 """
@@ -57,18 +76,22 @@ from jax.sharding import PartitionSpec as P
 from areal_tpu.models import moe, qwen
 from areal_tpu.models.qwen import _embed_lookup, _proj, _rms_norm, _rope
 
-MODEL_TYPES = ("granitemoehybrid", "lfm2_moe")
-KINDS = ("mamba", "attention", "conv")  # mixers
+MODEL_TYPES = ("granitemoehybrid", "lfm2_moe", "olmo_hybrid")
+KINDS = ("mamba", "attention", "conv", "gdn")  # mixers
 FFNS = ("dense", "moe")
 # scopes this family adds to qwen.SCOPES (docs/observability.md): the
 # state-space mixer's, the short-conv mixer's, and models/moe.py's
 SCOPES = ("ssm_proj", "ssm_conv", "ssm_state", "state_write")
 CONV_SCOPES = ("conv_proj", "conv_mix", "state_write")
 MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
+GDN_SCOPES = ("gdn_proj", "gdn_conv", "gdn_state", "state_write")
+# tokens a chunk of the delta rule's prefill scan: 16 x 2^2, as ``_unit_lower_inverse`` builds its inverse
+GDN_CHUNK = 64
 # what a decode chunk may ask the forward to count into (not part of the
 # cache the engine keeps): rows of live slots each expert got, [expert
-# layers, experts], and experts with at least one such row, [expert layers]
-COUNT_LEAVES = ("moe_load", "moe_touched")
+# layers, experts], and experts with at least one such row, [expert layers];
+# live slots whose delta-rule state a step advanced, [gdn layers]
+COUNT_LEAVES = ("moe_load", "moe_touched", "gdn_updates")
 
 
 def stack_name(kind: str, ffn: str) -> str:
@@ -129,6 +152,19 @@ class HybridConfig:
     router_score: str = "softmax"  # or "sigmoid"
     router_bias: bool = False  # a selection bias a layer (gates stay unbiased)
     router_norm_eps: float | None = None
+    # where a block's two RMSNorms stand: "pre", on a sublayer's input, or
+    # "post", on its output before the residual add (the Olmo family's)
+    norm_placement: str = "pre"
+    # q/k RMSNorm over each head ("head") or the whole projection ("whole")
+    qk_norm_over: str = "head"
+    # the gated-delta-rule mixer: heads, a head's key and value size, the
+    # taps of its three depthwise convs, beta in (0, 2) rather than (0, 1)
+    gdn_n_heads: int = 0
+    gdn_k_dim: int = 0
+    gdn_v_dim: int = 0
+    gdn_d_conv: int = 4
+    gdn_neg_eigval: bool = False
+    gdn_state_dtype: str = "float32"
 
     @property
     def num_layers(self) -> int:
@@ -172,6 +208,19 @@ class HybridConfig:
         return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
 
     @property
+    def gdn_conv_dim(self) -> int:
+        """Channels of the delta-rule mixer's conv window: [q | k | v]."""
+        return self.gdn_n_heads * (2 * self.gdn_k_dim + self.gdn_v_dim)
+
+    @property
+    def gdn_head_pack(self) -> int:
+        """Heads side by side in one tile of the delta-rule state
+        (ops/gdn_state_update.py): 2 at a value size of 192."""
+        from areal_tpu.ops.gdn_state_update import head_pack
+
+        return head_pack(self.gdn_n_heads, self.gdn_v_dim)
+
+    @property
     def jax_dtype(self):
         return jnp.dtype(self.dtype)
 
@@ -187,7 +236,14 @@ class HybridConfig:
 
     @property
     def has_recurrent_state(self) -> bool:
-        return self.count("mamba") + self.count("conv") > 0
+        return self.count("mamba") + self.count("conv") + self.count("gdn") > 0
+
+    @property
+    def count_shapes(self) -> dict[str, tuple[int, ...]]:
+        """{leaf: shape} of every int32 count a decode chunk takes back
+        beside its tokens (COUNT_LEAVES, in that order)."""
+        n = self.count("gdn")
+        return {**self.moe_count_shapes, **({"gdn_updates": (n,)} if n else {})}
 
     @property
     def moe_count_shapes(self) -> dict[str, tuple[int, ...]]:
@@ -200,8 +256,10 @@ class HybridConfig:
         """{leaf: (shape, dtype)} of the slot-indexed recurrent state. A conv
         window is stored token-major and flat, ``(taps - 1) * channels``
         wide: with the tokens as the minor dimension the TPU would pad every
-        channel's 2 or 3 values to a 128-lane row. A model has state-space
-        layers or short-conv layers, not both: ``conv`` is either's window."""
+        channel's 2 or 3 values to a 128-lane row. A model has state-space,
+        short-conv or delta-rule layers, one of the three: ``conv`` is its
+        window (a delta-rule layer's three, side by side: [q | k | v]). The
+        delta-rule state ``gdn`` holds ``gdn_head_pack`` heads a tile."""
         conv_dtype = jnp.dtype(self.conv_state_dtype or self.dtype)
         if n := self.count("mamba"):
             return {
@@ -213,6 +271,15 @@ class HybridConfig:
             }
         if n := self.count("conv"):
             return {"conv": ((n, slots, (self.conv_L_cache - 1) * self.hidden_size), conv_dtype)}
+        if n := self.count("gdn"):
+            p = self.gdn_head_pack
+            return {
+                "gdn": (
+                    (n, slots, self.gdn_n_heads // p, self.gdn_k_dim, p * self.gdn_v_dim),
+                    jnp.dtype(self.gdn_state_dtype),
+                ),
+                "conv": ((n, slots, (self.gdn_d_conv - 1) * self.gdn_conv_dim), conv_dtype),
+            }
         return {}
 
     @classmethod
@@ -227,11 +294,10 @@ class HybridConfig:
             raise ValueError(f"hidden_act {d['hidden_act']!r} is not implemented")
         extra = {
             k: d[k]
-            for k in ("dtype", "ssm_state_dtype", "conv_state_dtype", "kv_lane_pad", "head_dim")
+            for k in ("dtype", "ssm_state_dtype", "conv_state_dtype", "gdn_state_dtype", "kv_lane_pad", "head_dim")
             if k in d
         }
-        build = _granite_fields if mt == "granitemoehybrid" else _lfm2_fields
-        fields = build(d)
+        fields = _FIELDS[mt](d)
         kinds = fields["layer_types"]
         if set(kinds) - set(KINDS) or len(kinds) != d["num_hidden_layers"]:
             raise ValueError(f"layer_types {sorted(set(kinds))} x {len(kinds)} for {d['num_hidden_layers']} layers")
@@ -263,6 +329,21 @@ class HybridConfig:
             "num_key_value_heads": self.num_kv_heads,
             "tie_word_embeddings": self.tie_word_embeddings,
         }
+        if self.model_type == "olmo_hybrid":
+            return {
+                **shared,
+                "layer_types": [_OLMO_KINDS_OUT[t] for t in self.layer_types],
+                "rms_norm_eps": self.rms_norm_eps,
+                "hidden_act": "silu",
+                "attention_bias": False,
+                "rope_parameters": {"rope_theta": self.rope_theta},
+                "linear_num_key_heads": self.gdn_n_heads,
+                "linear_num_value_heads": self.gdn_n_heads,
+                "linear_key_head_dim": self.gdn_k_dim,
+                "linear_value_head_dim": self.gdn_v_dim,
+                "linear_conv_kernel_dim": self.gdn_d_conv,
+                "linear_allow_neg_eigval": self.gdn_neg_eigval,
+            }
         if self.model_type == "lfm2_moe":
             return {
                 **shared,
@@ -377,6 +458,52 @@ def _lfm2_fields(d: dict[str, Any]) -> dict[str, Any]:
     )
 
 
+_OLMO_KINDS = {"linear_attention": "gdn", "full_attention": "attention"}
+_OLMO_KINDS_OUT = {v: k for k, v in _OLMO_KINDS.items()}
+
+
+def _olmo_hybrid_fields(d: dict[str, Any]) -> dict[str, Any]:
+    """``olmo_hybrid``: gated-delta-rule layers beside full attention without
+    a rotary embedding, the Olmo family's block (an RMSNorm on each
+    sublayer's OUTPUT, q and k normed over the whole projection). The layer
+    form is the ``fla`` library's ``GatedDeltaNet`` (separate q/k/v
+    projections and convs without bias, SiLU, L2-normed q and k, a gate
+    and a gated norm on the output): its ``config.json`` names the sizes,
+    not the form."""
+    if d.get("attention_bias"):
+        raise ValueError("projection biases are not implemented for the hybrid family")
+    rope = d.get("rope_parameters") or {}
+    if d.get("rope_theta") is not None or rope.get("rope_theta") is not None or d.get("rope_scaling"):
+        raise ValueError("olmo_hybrid with a rotary embedding is not implemented (its published rope_theta is null)")
+    if d.get("sliding_window"):
+        raise ValueError("olmo_hybrid with sliding-window attention is not implemented")
+    if set(d["layer_types"]) - set(_OLMO_KINDS):
+        raise ValueError(f"olmo_hybrid layer_types {sorted(set(d['layer_types']))}: only {sorted(_OLMO_KINDS)}")
+    if d.get("norm_placement", "post") != "post" or d.get("qk_norm_over", "whole") != "whole":
+        raise ValueError("olmo_hybrid is implemented with post-sublayer norms and whole-projection q/k norms only")
+    heads = int(d["linear_num_value_heads"])
+    if int(d.get("linear_num_key_heads", heads)) != heads:
+        raise ValueError("a delta-rule layer with fewer key heads than value heads is not implemented")
+    return dict(
+        intermediate_size=d["intermediate_size"],
+        layer_types=tuple(_OLMO_KINDS[t] for t in d["layer_types"]),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+        rope_theta=None,
+        qk_norm=True,
+        qk_norm_over="whole",
+        norm_placement="post",
+        fused_gate_up=False,
+        gdn_n_heads=heads,
+        gdn_k_dim=int(d["linear_key_head_dim"]),
+        gdn_v_dim=int(d["linear_value_head_dim"]),
+        gdn_d_conv=int(d.get("linear_conv_kernel_dim", 4)),
+        gdn_neg_eigval=bool(d.get("linear_allow_neg_eigval", False)),
+    )
+
+
+_FIELDS = {"granitemoehybrid": _granite_fields, "lfm2_moe": _lfm2_fields, "olmo_hybrid": _olmo_hybrid_fields}
+
+
 def serving_config(cfg: HybridConfig, dtype: str) -> HybridConfig:
     """``cfg`` as a decode engine serves it."""
     return dataclasses.replace(cfg, dtype=dtype)
@@ -416,13 +543,20 @@ def _layer_shapes(cfg: HybridConfig) -> dict[str, dict[str, tuple[int, ...]]]:
             "wk": (D, cfg.kv_dim),
             "wv": (D, cfg.kv_dim),
             "wo": (cfg.q_dim, D),
-            **({"q_norm": (cfg.head_dim_,), "k_norm": (cfg.head_dim_,)} if cfg.qk_norm else {}),
+            **(
+                {}
+                if not cfg.qk_norm
+                else {"q_norm": (cfg.head_dim_,), "k_norm": (cfg.head_dim_,)}
+                if cfg.qk_norm_over == "head"
+                else {"q_norm": (cfg.q_dim,), "k_norm": (cfg.kv_dim,)}
+            ),
         },
         "conv": {
             "in_proj": (D, 3 * D),  # [B | C | x]
             "conv_w": (cfg.conv_L_cache, 1, D),  # as the Mamba mixer's: tap k of channel c
             "out_proj": (D, D),
         },
+        "gdn": _gdn_shapes(cfg),
     }
     E, Fe = cfg.num_experts, cfg.moe_intermediate_size
     ffns = {
@@ -442,6 +576,31 @@ def _layer_shapes(cfg: HybridConfig) -> dict[str, dict[str, tuple[int, ...]]]:
     for kind, ffn in _layer_kinds(cfg):
         out.setdefault(stack_name(kind, ffn), {**norms, **ffns[ffn], **mixers[kind]})
     return out
+
+
+def _gdn_shapes(cfg: HybridConfig) -> dict[str, tuple[int, ...]]:
+    """One delta-rule mixer's leaves, as the ``fla`` layer has them: three
+    projections each with a depthwise conv of its own (taps as the Mamba
+    mixer's: tap k of channel c is ``*_conv_w[k, 0, c]``), the decay's and
+    the write strength's projections a head, the output gate's, the norm
+    over a head's values, the output projection."""
+    D, H = cfg.hidden_size, cfg.gdn_n_heads
+    qk, vd, kc = H * cfg.gdn_k_dim, H * cfg.gdn_v_dim, cfg.gdn_d_conv
+    return {
+        "q_proj": (D, qk),
+        "k_proj": (D, qk),
+        "v_proj": (D, vd),
+        "a_proj": (D, H),
+        "b_proj": (D, H),
+        "g_proj": (D, vd),
+        "q_conv_w": (kc, 1, qk),
+        "k_conv_w": (kc, 1, qk),
+        "v_conv_w": (kc, 1, vd),
+        "A_log": (H,),
+        "dt_bias": (H,),
+        "o_norm": (cfg.gdn_v_dim,),
+        "o_proj": (vd, D),
+    }
 
 
 def _stack_sizes(cfg: HybridConfig) -> dict[str, int]:
@@ -501,7 +660,11 @@ def param_partition_specs(cfg: HybridConfig, fsdp_axis: str | None = "fsdp") -> 
 
 
 # our leaf -> (checkpoint name within ``model.layers.N.``, transpose), by
-# model_type. ``lfm2_moe``: the mixers, norms, dense MLP and tied head are
+# model_type. ``olmo_hybrid``: the block's and the attention layer's names are
+# ``transformers``' ``Olmo3`` classes' (tests/test_olmo_hybrid_parity.py holds
+# the block to ``Olmo3DecoderLayer``); the names under ``linear_attn.`` are the
+# ``fla`` layer's attributes and could NOT be checked here (no network, and the
+# installed transformers 4.57.6 has no ``olmo_hybrid``). ``lfm2_moe``: the mixers, norms, dense MLP and tied head are
 # held to ``transformers``' ``Lfm2`` classes (tests/test_lfm2_hf_parity.py);
 # the names under ``feed_forward.gate`` / ``.expert_bias`` / ``.experts.E``
 # could NOT be checked here (no network, and the installed transformers has
@@ -548,10 +711,38 @@ _HF_LAYER_MAPS = {
         "we_up": ("feed_forward.experts.{e}.w3.weight", True),
         "we_down": ("feed_forward.experts.{e}.w2.weight", True),
     },
+    "olmo_hybrid": {
+        # the block's two norms stand on the sublayers' outputs (norm_placement)
+        "input_norm": ("post_attention_layernorm.weight", False),
+        "post_norm": ("post_feedforward_layernorm.weight", False),
+        "w_gate": ("mlp.gate_proj.weight", True),
+        "w_up": ("mlp.up_proj.weight", True),
+        "w_down": ("mlp.down_proj.weight", True),
+        "wq": ("self_attn.q_proj.weight", True),
+        "wk": ("self_attn.k_proj.weight", True),
+        "wv": ("self_attn.v_proj.weight", True),
+        "wo": ("self_attn.o_proj.weight", True),
+        "q_norm": ("self_attn.q_norm.weight", False),
+        "k_norm": ("self_attn.k_norm.weight", False),
+        "q_proj": ("linear_attn.q_proj.weight", True),
+        "k_proj": ("linear_attn.k_proj.weight", True),
+        "v_proj": ("linear_attn.v_proj.weight", True),
+        "a_proj": ("linear_attn.a_proj.weight", True),
+        "b_proj": ("linear_attn.b_proj.weight", True),
+        "g_proj": ("linear_attn.g_proj.weight", True),
+        "q_conv_w": ("linear_attn.q_conv1d.weight", True),
+        "k_conv_w": ("linear_attn.k_conv1d.weight", True),
+        "v_conv_w": ("linear_attn.v_conv1d.weight", True),
+        "A_log": ("linear_attn.A_log", False),
+        "dt_bias": ("linear_attn.dt_bias", False),
+        "o_norm": ("linear_attn.o_norm.weight", False),
+        "o_proj": ("linear_attn.o_proj.weight", True),
+    },
 }
 _HF_TOP = {
     "granitemoehybrid": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "lfm2_moe": {"embed": "model.embed_tokens.weight", "final_norm": "model.embedding_norm.weight"},
+    "olmo_hybrid": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
 }
 
 
@@ -820,29 +1011,264 @@ def conv_prefill(cfg: HybridConfig, layer: dict, h, n_state, state_dtype):
 
 
 # ---------------------------------------------------------------------------
+# the gated-delta-rule mixer
+# ---------------------------------------------------------------------------
+
+
+def _gdn_in(cfg: HybridConfig, layer: dict, h):
+    """The projections of h [..., D]: (the convs' input [q | k | v]
+    [..., gdn_conv_dim], the output gate's [..., H * V], the log decay g
+    [..., H] <= 0 and the write strength beta [..., H], both float32)."""
+    with jax.named_scope("gdn_proj"):
+        raw = jnp.concatenate([_proj(cfg, layer, n, h) for n in ("q_proj", "k_proj", "v_proj")], axis=-1)
+        z = _proj(cfg, layer, "g_proj", h)
+        a = _proj(cfg, layer, "a_proj", h).astype(jnp.float32)
+        b = _proj(cfg, layer, "b_proj", h).astype(jnp.float32)
+        g = -jnp.exp(layer["A_log"].astype(jnp.float32)) * jax.nn.softplus(a + layer["dt_bias"].astype(jnp.float32))
+        beta = jax.nn.sigmoid(b) * (2.0 if cfg.gdn_neg_eigval else 1.0)
+    return raw, z, g, beta
+
+
+def _gdn_taps(layer: dict):
+    """The three convs' taps side by side, [taps, gdn_conv_dim] float32."""
+    return jnp.concatenate([layer[n][:, 0, :] for n in ("q_conv_w", "k_conv_w", "v_conv_w")], axis=-1).astype(jnp.float32)
+
+
+def _gdn_heads(cfg: HybridConfig, qkv):
+    """[..., gdn_conv_dim] float32 after conv and SiLU -> q and k [..., H, K]
+    L2-normalised (q also scaled by K^-1/2), v [..., H, V]."""
+    H, K, V = cfg.gdn_n_heads, cfg.gdn_k_dim, cfg.gdn_v_dim
+    q, k, v = jnp.split(qkv, [H * K, 2 * H * K], axis=-1)
+    lead = qkv.shape[:-1]
+    q, k, v = q.reshape(*lead, H, K), k.reshape(*lead, H, K), v.reshape(*lead, H, V)
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * K**-0.5
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+    return q, k, v
+
+
+def _gdn_out(cfg: HybridConfig, layer: dict, o, z, dtype):
+    """rmsnorm over each head's values, times silu(gate): [..., H * V]."""
+    lead = o.shape[:-2]
+    z = z.astype(jnp.float32).reshape(*lead, cfg.gdn_n_heads, cfg.gdn_v_dim)
+    y = _rms_norm(o, layer["o_norm"].astype(jnp.float32), cfg.rms_norm_eps) * jax.nn.silu(z)
+    return y.reshape(*lead, -1).astype(dtype)
+
+
+def gdn_decode_step(state, q, k, v, alpha, beta, active):
+    """The recurrence, one token for each of S slots: ``S' = alpha S``,
+    ``u = beta (v - S'^T k)``, ``S = S' + k u^T``, ``o = S^T q``.
+
+    state [S, H, K, V] (its own dtype, computed in float32), q and k
+    [S, H, K], v [S, H, V], alpha and beta [S, H]. Returns (new state,
+    o [S, H, V] float32). A slot that is not ``active`` keeps its state bit
+    for bit."""
+    decayed = state.astype(jnp.float32) * alpha[..., None, None]
+    u = beta[..., None] * (v - jnp.sum(decayed * k[..., :, None], axis=-2))
+    new = decayed + k[..., :, None] * u[..., None, :]
+    o = jnp.sum(new * q[..., :, None], axis=-2)
+    return jnp.where(active[:, None, None, None], new.astype(state.dtype), state), o
+
+
+def _unit_lower_inverse(m, base: int = 16):
+    """(I + strictly-lower(m))^-1 for m [..., C, C], C a multiple of ``base``
+    a power of two times: forward substitution row by row inside the
+    ``base`` x ``base`` diagonal blocks, then [[A, 0], [B, D]]^-1 =
+    [[A^-1, 0], [-D^-1 B A^-1, D^-1]] block by block, so that most of the
+    work is matmuls. Both are the substitution itself, not a power series
+    in the nilpotent part (whose terms grow with the keys' overlap and
+    cancel)."""
+    C = m.shape[-1]
+    hi = jax.lax.Precision.HIGHEST
+    low = jnp.tril(m, -1)
+    nb = C // base
+    blocks = low.reshape(*m.shape[:-2], nb, base, nb, base)
+    idx = jnp.arange(nb)
+    n = -jnp.moveaxis(blocks[..., idx, :, idx, :], 0, -3)  # [..., nb, base, base]: -L of the diagonal blocks
+    for i in range(1, base):  # row i of N = -L_i + (-L_i) N, rows above it final
+        row = n[..., i, :]
+        n = n.at[..., i, :].set(row + jnp.einsum("...j,...jk->...k", row, n, precision=hi))
+    inv = n + jnp.eye(base, dtype=m.dtype)  # [..., nb, base, base]
+    size = base
+    while size < C:
+        nb //= 2
+        pairs = inv.reshape(*inv.shape[:-3], nb, 2, size, size)
+        a_inv, d_inv = pairs[..., 0, :, :], pairs[..., 1, :, :]
+        lo = low.reshape(*m.shape[:-2], nb, 2 * size, nb, 2 * size)
+        b = jnp.moveaxis(lo[..., idx[:nb], size:, idx[:nb], :size], 0, -3)  # below-left block of each pair
+        c = -jnp.einsum("...ij,...jk,...kl->...il", d_inv, b, a_inv, precision=hi)
+        top = jnp.concatenate([a_inv, jnp.zeros_like(a_inv)], axis=-1)
+        inv = jnp.concatenate([top, jnp.concatenate([c, d_inv], axis=-1)], axis=-2)
+        size *= 2
+    return inv[..., 0, :, :]
+
+
+def gdn_chunked_scan(q, k, v, g, beta, n_state, state_dtype=jnp.float32):
+    """The chunked (WY) algorithm for the same recurrence over whole prompts.
+
+    q and k [A, L, H, K], v [A, L, H, V], g and beta [A, L, H], n_state [A]:
+    only the first ``n_state`` tokens of a row enter its state (``beta`` and
+    ``g`` are 0 from there on, so the state neither decays nor is written;
+    ``o`` at those positions is then not the model's and must not be used).
+    Inside a chunk of ``GDN_CHUNK`` tokens the writes depend on each
+    other through the unit lower-triangular system
+    ``(I + tril(diag(beta) (K K^T * decay), -1)) U = diag(beta) (V - decayed
+    reads of the carried state)``; across chunks the state is carried in
+    float32. Starts from the zero state. Returns (state after n_state tokens
+    [A, H, K, V], o [A, L, H, V] float32)."""
+    A, L, H, K = q.shape
+    V = v.shape[-1]
+    C = GDN_CHUNK
+    pad = (-L) % C
+    keep = (jnp.arange(L)[None, :, None] < n_state[:, None, None]).astype(jnp.float32)
+    g, beta = g * keep, beta * keep
+    if pad:
+        q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)) for t in (q, k, v, g, beta))
+    nc = (L + pad) // C
+    hi = jax.lax.Precision.HIGHEST
+    # [A, nc, H, C, ...]: a chunk's tokens next to the feature axis
+    q, k, v = (jnp.swapaxes(t.reshape(A, nc, C, H, -1), 2, 3) for t in (q, k, v))
+    g, beta = (jnp.swapaxes(t.reshape(A, nc, C, H), 2, 3) for t in (g, beta))
+    g_cum = jnp.cumsum(g, axis=-1)  # [A, nc, H, C], <= 0 and falling
+    tril = jnp.tril(jnp.ones((C, C), bool))
+    decay = jnp.exp(jnp.where(tril, g_cum[..., :, None] - g_cum[..., None, :], -jnp.inf))  # [.., t, s]
+    k_beta, v_beta = k * beta[..., None], v * beta[..., None]
+    t_inv = _unit_lower_inverse(jnp.einsum("...tk,...sk->...ts", k_beta, k, precision=hi) * decay)
+    w = jnp.einsum("...ts,...sv->...tv", t_inv, v_beta, precision=hi)  # the writes, had the chunk begun at zero
+    k_cum = jnp.einsum("...ts,...sk->...tk", t_inv, k_beta * jnp.exp(g_cum)[..., None], precision=hi)
+    qk = jnp.einsum("...tk,...sk->...ts", q, k, precision=hi) * decay
+    q_in = q * jnp.exp(g_cum)[..., None]  # reads of the carried state
+    k_out = k * jnp.exp(g_cum[..., -1:] - g_cum)[..., None]  # writes as the chunk's end sees them
+    g_end = jnp.exp(g_cum[..., -1])  # [A, nc, H]
+
+    def chunk(s, xs):  # s [A, H, K, V]
+        w_c, k_cum_c, qk_c, q_in_c, k_out_c, g_end_c = xs
+        u = w_c - jnp.einsum("ahtk,ahkv->ahtv", k_cum_c, s, precision=hi)
+        o = jnp.einsum("ahtk,ahkv->ahtv", q_in_c, s, precision=hi) + jnp.einsum("ahts,ahsv->ahtv", qk_c, u, precision=hi)
+        s = s * g_end_c[..., None, None] + jnp.einsum("ahtk,ahtv->ahkv", k_out_c, u, precision=hi)
+        return s, o
+
+    xs = tuple(jnp.moveaxis(t, 1, 0) for t in (w, k_cum, qk, q_in, k_out, g_end))
+    s_fin, o = jax.lax.scan(chunk, jnp.zeros((A, H, K, V), jnp.float32), xs)
+    o = jnp.swapaxes(jnp.moveaxis(o, 0, 1), 2, 3).reshape(A, L + pad, H, V)[:, :L]
+    return s_fin.astype(state_dtype), o
+
+
+def gdn_decode(cfg: HybridConfig, layer: dict, h, state: dict, j, active, live=None):
+    """Mixer for one token a slot. h [S, D]; ``state`` holds every
+    delta-rule layer's slot state, ``gdn`` [n, S, H / p, K, p * V] (p heads a
+    tile: ``cfg.gdn_head_pack``) and ``conv`` [n, S, (taps-1) *
+    gdn_conv_dim] (the raw conv inputs [q | k | v] of the last taps-1 tokens,
+    oldest first), of which this is layer ``j``. Returns (out [S, D], the
+    state with layer j advanced); rows that are not ``active`` keep theirs.
+
+    ``live`` = ``paged_attention_q8.live_order(active)`` runs the recurrence
+    in the Pallas kernel (ops/gdn_state_update.py), which reads and writes
+    the live slots' state only and in place; without it ``gdn_decode_step``
+    passes over all slots under a mask (off a TPU, and the form the tests
+    hold the kernel to)."""
+    from areal_tpu.ops import gdn_state_update as gsu
+
+    S = h.shape[0]
+    taps, p = cfg.gdn_d_conv, cfg.gdn_head_pack
+    conv = jax.lax.dynamic_index_in_dim(state["conv"], j, 0, keepdims=False)
+    raw, z, g, beta = _gdn_in(cfg, layer, h)
+    with jax.named_scope("gdn_conv"):
+        window = jnp.concatenate([conv.reshape(S, taps - 1, -1), raw[:, None, :].astype(conv.dtype)], axis=1)
+        qkv = jax.nn.silu(jnp.sum(window.astype(jnp.float32) * _gdn_taps(layer)[None], axis=1))
+        new_conv = jnp.where(active[:, None], window[:, 1:].reshape(S, -1), conv)
+        q, k, v = _gdn_heads(cfg, qkv)
+    with jax.named_scope("gdn_state"):
+        if live is None:
+            old = jax.lax.dynamic_index_in_dim(state["gdn"], j, 0, keepdims=False)
+            new, o = gdn_decode_step(gsu.unpack_state(old, p), q, k, v, jnp.exp(g), beta, active)
+            new = gsu.pack_state(new, p)
+        else:
+            gdn_all, o = gsu.gdn_state_update_stacked(state["gdn"], j, q, k, v, jnp.exp(g), beta, *live)
+        y = _gdn_out(cfg, layer, o, z, h.dtype)
+    with jax.named_scope("state_write"):
+        if live is None:
+            gdn_all = jax.lax.dynamic_update_index_in_dim(state["gdn"], new, j, 0)
+        state = {"gdn": gdn_all, "conv": jax.lax.dynamic_update_index_in_dim(state["conv"], new_conv, j, 0)}
+    with jax.named_scope("gdn_proj"):
+        return _proj(cfg, layer, "o_proj", y), state
+
+
+def gdn_prefill(cfg: HybridConfig, layer: dict, h, n_state, state_dtypes):
+    """Mixer over whole prompts. h [A, L, D], n_state [A]. Returns (out
+    [A, L, D], the packed delta-rule state after n_state tokens, the conv
+    window of the last taps-1 of those tokens; positions before the prompt
+    count as zeros). Between the projections the rows go one at a time: the
+    float32 q, k, v and the scan's chunk matrices of one row are a few
+    hundred MB at 1,024 tokens, and a batch of 8 has no room for 8 of them
+    beside the weights."""
+    from areal_tpu.ops.gdn_state_update import pack_state
+
+    L = h.shape[1]
+    taps = cfg.gdn_d_conv
+    raw, z, g, beta = _gdn_in(cfg, layer, h)
+    w = _gdn_taps(layer)
+
+    def row(args):
+        raw_r, z_r, g_r, beta_r, n_r = (a[None] for a in args)
+        with jax.named_scope("gdn_conv"):
+            raw_r = raw_r.astype(state_dtypes[1])  # both forms convolve the values a slot's window would hold
+            padded = jnp.pad(raw_r, ((0, 0), (taps - 1, 0), (0, 0)))  # position t at row t + taps - 1
+            acc = 0.0
+            for i in range(taps):
+                acc = acc + padded[:, i : i + L].astype(jnp.float32) * w[i]
+            q, k, v = _gdn_heads(cfg, jax.nn.silu(acc))
+            conv = _window_after(padded, n_r, taps)
+        with jax.named_scope("gdn_state"):
+            s, o = gdn_chunked_scan(q, k, v, g_r, beta_r, n_r, state_dtypes[0])
+            y = _gdn_out(cfg, layer, o, z_r, h.dtype)
+        return y[0], pack_state(s[0], cfg.gdn_head_pack), conv[0].astype(state_dtypes[1])
+
+    y, s, conv = jax.lax.map(row, (raw, z, g, beta, n_state))
+    with jax.named_scope("gdn_proj"):
+        return _proj(cfg, layer, "o_proj", y), s, conv
+
+
+# ---------------------------------------------------------------------------
 # the layer stack
 # ---------------------------------------------------------------------------
 
 
+def _norm_in(cfg: HybridConfig, layer: dict, name: str, x):
+    """What a sublayer reads: rmsnorm(x) in a pre-norm block, x itself where
+    the norm stands on the sublayer's output."""
+    return _rms_norm(x, layer[name], cfg.rms_norm_eps) if cfg.norm_placement == "pre" else x
+
+
+def _norm_out(cfg: HybridConfig, layer: dict, name: str, out):
+    """What a sublayer adds to the residual stream: its output, normed where
+    the block's norms stand there (``h = x + rmsnorm(sublayer(x))``)."""
+    return out if cfg.norm_placement == "pre" else _rms_norm(out, layer[name], cfg.rms_norm_eps)
+
+
 def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None):
-    """x + the layer's feed-forward block on rmsnorm(x); for an expert block
-    also the rows of ``live`` (default: all) each expert got, [E] int32."""
+    """x + the layer's feed-forward block, its RMSNorm where the block has
+    it (on the input, or on the output); for an expert block also the rows
+    of ``live`` (default: all) each expert got, [E] int32."""
     rm = cfg.residual_multiplier
     if ffn == "dense":
         with jax.named_scope("mlp"):
-            h = _rms_norm(x, layer["post_norm"], cfg.rms_norm_eps)
+            h = _norm_in(cfg, layer, "post_norm", x)
             if cfg.fused_gate_up:
                 g, u = jnp.split(_proj(cfg, layer, "w_gate_up", h), 2, axis=-1)
             else:
                 g, u = _proj(cfg, layer, "w_gate", h), _proj(cfg, layer, "w_up", h)
-            return x + rm * _proj(cfg, layer, "w_down", jax.nn.silu(g) * u), None
+            return x + rm * _norm_out(cfg, layer, "post_norm", _proj(cfg, layer, "w_down", jax.nn.silu(g) * u)), None
     with jax.named_scope("moe_router"):
-        h = _rms_norm(x, layer["post_norm"], cfg.rms_norm_eps)
+        h = _norm_in(cfg, layer, "post_norm", x)
     out, _, _, load = moe.expert_ffn(
         h.reshape(-1, h.shape[-1]), layer, cfg, live=None if live is None else live.reshape(-1)
     )
     with jax.named_scope("moe_combine"):
-        return x + rm * out.reshape(x.shape).astype(x.dtype), load
+        return x + rm * _norm_out(cfg, layer, "post_norm", out.reshape(x.shape).astype(x.dtype)), load
+
+
+# the scope a mixer's norm counts under (its projections')
+_MIXER_SCOPE = {"mamba": "ssm_proj", "gdn": "gdn_proj", "conv": "conv_proj", "attention": "attn_proj"}
 
 
 def _runs(cfg: HybridConfig) -> list[tuple[str, str, int, int, int, int]]:
@@ -888,14 +1314,23 @@ def _embed(params: dict, cfg: HybridConfig, ids):
 
 
 def _qkv(cfg: HybridConfig, layer: dict, h, positions):
-    """q [..., H, hd], k and v [..., KH, hd] of normed h [..., D] at
-    ``positions`` [...]: per-head RMSNorm of q and k, then the rotary
-    embedding, where the configuration has them."""
+    """q [..., H, hd], k and v [..., KH, hd] of the layer's input h [..., D]
+    at ``positions`` [...]: RMSNorm of q and k (over each head, or over the
+    whole projection), then the rotary embedding, where the configuration
+    has them."""
     lead = h.shape[:-1]
-    q = _proj(cfg, layer, "wq", h).reshape(*lead, cfg.num_heads, cfg.head_dim_)
-    k = _proj(cfg, layer, "wk", h).reshape(*lead, cfg.num_kv_heads, cfg.head_dim_)
-    v = _proj(cfg, layer, "wv", h).reshape(*lead, cfg.num_kv_heads, cfg.head_dim_)
-    if cfg.qk_norm:
+    whole = cfg.qk_norm and cfg.qk_norm_over == "whole"
+
+    def heads(w, norm, n):  # a projection, normed over its whole width where the configuration says so, split into heads
+        x = _proj(cfg, layer, w, h)
+        if whole and norm:
+            x = _rms_norm(x, layer[norm], cfg.rms_norm_eps)
+        return x.reshape(*lead, n, cfg.head_dim_)
+
+    q = heads("wq", "q_norm", cfg.num_heads)
+    k = heads("wk", "k_norm", cfg.num_kv_heads)
+    v = heads("wv", None, cfg.num_kv_heads)
+    if cfg.qk_norm and cfg.qk_norm_over == "head":
         q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
         k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
     if cfg.rope_theta is not None:
@@ -962,19 +1397,25 @@ def forward_prefill(
     def step(kind, ffn, carry, layer, j, f):
         x, ks, vs, arr = carry
         if kind == "mamba":
-            h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+            h = _norm_in(cfg, layer, "input_norm", x)
             out, ssm, conv = mamba_prefill(cfg, layer, h, n_state, (dtypes["ssm"], dtypes["conv"]))
             with jax.named_scope("state_write"):
                 arr = write(arr, j, {"ssm": ssm, "conv": conv})
+        elif kind == "gdn":
+            with jax.named_scope("gdn_proj"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+            out, gdn, conv = gdn_prefill(cfg, layer, h, n_state, (dtypes["gdn"], dtypes["conv"]))
+            with jax.named_scope("state_write"):
+                arr = write(arr, j, {"gdn": gdn, "conv": conv})
         elif kind == "conv":
             with jax.named_scope("conv_proj"):
-                h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+                h = _norm_in(cfg, layer, "input_norm", x)
             out, conv = conv_prefill(cfg, layer, h, n_state, dtypes["conv"])
             with jax.named_scope("state_write"):
                 arr = write(arr, j, {"conv": conv})
         else:
             with jax.named_scope("attn_proj"):
-                h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+                h = _norm_in(cfg, layer, "input_norm", x)
                 q, k, v = _qkv(cfg, layer, h, positions)
                 ks = ks.at[j].set(_lane_pad(cfg, k))
                 vs = vs.at[j].set(_lane_pad(cfg, v))
@@ -982,6 +1423,8 @@ def forward_prefill(
                 attn = jax.lax.map(attend, (q, k, v, mask))
             with jax.named_scope("attn_proj"):
                 out = _proj(cfg, layer, "wo", attn)
+        with jax.named_scope(_MIXER_SCOPE[kind]):
+            out = _norm_out(cfg, layer, "input_norm", out)
         x, _ = _ffn(cfg, ffn, layer, x + rm * out, live)
         return x, ks, vs, arr
 
@@ -1043,11 +1486,22 @@ def prefill_into_cache(
     return {**cache, **state}
 
 
+def slot_state_view(cfg: HybridConfig, leaf: str, rows: jax.Array) -> jax.Array:
+    """Rows [..., slot state] of the cache's state leaf ``leaf`` in the
+    mixer's own order of axes: the delta-rule state unpacked to [..., H, K,
+    V]; the other leaves lie as their mixer reads them."""
+    if leaf == "gdn":
+        from areal_tpu.ops.gdn_state_update import unpack_state
+
+        return unpack_state(rows, cfg.gdn_head_pack)
+    return rows
+
+
 def _refuse(what: str):
     def refuse(*_a, **_k):
         raise NotImplementedError(
             f"{what} needs a recurrent state cut back to a token boundary, which does not "
-            "exist for state-space or short-conv layers (ROADMAP Reach A.7: state snapshots at page boundaries)"
+            "exist for state-space, short-conv or delta-rule layers (ROADMAP Reach A.7: state snapshots at page boundaries)"
         )
 
     return refuse
@@ -1101,7 +1555,8 @@ def forward_decode_paged(
         attn_lengths = jnp.where(page_table[:, 0] == 0, 0, lengths)  # see qwen.forward_decode_paged
         ppcb = paged_kv.choose_ppcb(page_table.shape[1])
         schedule = decode_schedule(attn_lengths, page_table.shape[1], page_size, ppcb)
-        live = live_order(active) if cfg.count("mamba") else None  # the state kernel's work list, made once a step
+        # the state kernel's work list, made once a step
+        live = live_order(active) if cfg.count("mamba") + cfg.count("gdn") else None
         with jax.named_scope("kv_write"):
             kv_live = live_order(page_table[:, 0] != 0)  # the KV writer's: qwen.forward_decode_paged
     else:
@@ -1112,16 +1567,24 @@ def forward_decode_paged(
         x, c = carry
         c = dict(c)
         if kind == "mamba":
-            h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
-            out, state = mamba_decode(cfg, layer, h, {k: c[k] for k in paged_kv.STATE_LEAVES}, j, active, live)
+            h = _norm_in(cfg, layer, "input_norm", x)
+            out, state = mamba_decode(cfg, layer, h, {k: c[k] for k in ("ssm", "conv")}, j, active, live)
             c.update(state)
+        elif kind == "gdn":
+            with jax.named_scope("gdn_proj"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+            out, state = gdn_decode(cfg, layer, h, {k: c[k] for k in ("gdn", "conv")}, j, active, live)
+            c.update(state)
+            if "gdn_updates" in c:
+                with jax.named_scope("gdn_state"):
+                    c["gdn_updates"] = c["gdn_updates"].at[j].add(jnp.sum(active, dtype=jnp.int32))
         elif kind == "conv":
             with jax.named_scope("conv_proj"):
-                h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+                h = _norm_in(cfg, layer, "input_norm", x)
             out, c["conv"] = conv_decode(cfg, layer, h, c["conv"], j, active)
         else:
             with jax.named_scope("attn_proj"):
-                h = _rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+                h = _norm_in(cfg, layer, "input_norm", x)
                 q, k, v = (_lane_pad(cfg, t) for t in _qkv(cfg, layer, h, positions))
             with jax.named_scope("kv_write"):
                 c = paged_kv.write_decode_rows(c, j, k, v, write_page, write_off, kv_live)
@@ -1146,6 +1609,8 @@ def forward_decode_paged(
                 attn = attn[..., : cfg.head_dim_].reshape(S, H * cfg.head_dim_).astype(x.dtype)
             with jax.named_scope("attn_proj"):
                 out = _proj(cfg, layer, "wo", attn)
+        with jax.named_scope(_MIXER_SCOPE[kind]):
+            out = _norm_out(cfg, layer, "input_norm", out)
         x, load = _ffn(cfg, ffn, layer, x + rm * out, active)
         if load is not None and "moe_load" in c:
             with jax.named_scope("moe_router"):

@@ -129,6 +129,7 @@ class ModelConfig:
     has_recurrent_state = False
     # expert-load counts a decode chunk would hand back (models/hybrid.py): none
     moe_count_shapes: ClassVar[dict] = {}
+    count_shapes: ClassVar[dict] = {}
 
     def state_shapes(self, slots: int) -> dict:
         return {}

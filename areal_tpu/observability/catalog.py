@@ -161,6 +161,14 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "areal_decode_state_bytes",
             "Device bytes of the slot-indexed recurrent state.",
         ),
+        # a model with gated-delta-rule layers (models/hybrid.py); counted on
+        # the device inside the decode chunk as the expert counts below are
+        gdn_state_updates=r.counter(
+            "areal_decode_gdn_state_updates_total",
+            "(slot, layer) updates of a delta-rule state by decode steps: "
+            "live slots x delta-rule layers (each reads and writes the "
+            "slot's state of that layer once).",
+        ),
         # a model with sparse experts (models/moe.py); counted on the device
         # inside the decode chunk, for live slots only, and brought back with
         # the chunk's tokens. Per-expert counts: /statusz ``moe.load``

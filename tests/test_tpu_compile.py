@@ -50,7 +50,7 @@ def chip():
     compilation_cache.reset_cache()
 
 
-def _decode(page_dtype, slots=SLOTS, kh=KH, g=H // KH, layers=L, sm_scale=None):
+def _decode(page_dtype, slots=SLOTS, kh=KH, g=H // KH, layers=L, sm_scale=None, n_pages=N_PAGES):
     """The decode kernel as a benchmark cell launches it: a 32-page table
     (4096-token window), 4 pages a block; by default ``rollout-1.5b-grpo``'s
     128 slots x 2 KV heads x group 6."""
@@ -65,13 +65,13 @@ def _decode(page_dtype, slots=SLOTS, kh=KH, g=H // KH, layers=L, sm_scale=None):
         )
 
     def args(S):
-        pages = S((layers, kh, N_PAGES, PSZ, HD), page_dtype)
+        pages = S((layers, kh, n_pages, PSZ, HD), page_dtype)
         a = [
             S((slots, kh * g, HD), jnp.bfloat16), pages, pages, S((), jnp.int32),
             S((slots,), jnp.int32), S((slots, 32), jnp.int32),
         ]
         if quant:  # lane-major scales
-            a += [S((layers, kh, N_PAGES, 1, PSZ), jnp.float32)] * 2
+            a += [S((layers, kh, n_pages, 1, PSZ), jnp.float32)] * 2
         return a
 
     return fn, args
@@ -120,6 +120,27 @@ def _ssm_state(dtype):
         return [
             S((36, 64, 64, 64, 128), dtype), S((), jnp.int32), S((64, 64, 64), f32), S((64, 1, 128), f32),
             S((64, 1, 128), f32), S((64, 64), f32), S((64,), f32), S((64,), jnp.bool_),
+        ]
+
+    return fn, args
+
+
+def _gdn_state(dtype):
+    """The delta-rule state update of one gated-delta-rule layer at the
+    published sizes of ``rollout-olmo-hybrid-7b-d16-grpo``: 12 layers x 64
+    slots of 30 heads x 96 x 192, two heads a tile, updated in place for the
+    live slots."""
+    from areal_tpu.ops.gdn_state_update import gdn_state_update_stacked
+    from areal_tpu.ops.paged_attention_q8 import live_order
+
+    def fn(state, li, q, k, v, alpha, beta, active):
+        return gdn_state_update_stacked(state, li, q, k, v, alpha, beta, *live_order(active))
+
+    def args(S):
+        f32 = jnp.float32
+        return [
+            S((12, 64, 15, 96, 384), dtype), S((), jnp.int32), S((64, 30, 96), f32), S((64, 30, 96), f32),
+            S((64, 30, 192), f32), S((64, 30), f32), S((64, 30), f32), S((64,), jnp.bool_),
         ]
 
     return fn, args
@@ -229,6 +250,12 @@ CASES = {
     "paged_kv_write_h64pad_int8": lambda: _kv_write(jnp.int8, 64, 8, 4, 1280),
     "ssm_state_update_f32": lambda: _ssm_state(jnp.float32),
     "ssm_state_update_bf16": lambda: _ssm_state(jnp.bfloat16),
+    "gdn_state_update_f32": lambda: _gdn_state(jnp.float32),
+    "gdn_state_update_bf16": lambda: _gdn_state(jnp.bfloat16),
+    # Olmo-Hybrid-7B's attention layers: 30 KV heads, a query group of 1, 4 layers, 64 slots x 4096-token windows
+    "paged_decode_mha30_bf16": lambda: _decode(jnp.bfloat16, 64, 30, 1, 4, n_pages=490),
+    "paged_decode_mha30_int8": lambda: _decode(jnp.int8, 64, 30, 1, 4, n_pages=490),
+    "paged_kv_write_mha30_bf16": lambda: _kv_write(jnp.bfloat16, 64, 30, 4, 490),
     # the engine's smallest and largest suffix buckets at max_seq_len 2048
     "suffix_prefill_B256": lambda: _suffix(256, 4),
     "suffix_prefill_B2048": lambda: _suffix(2048, 2),
@@ -262,6 +289,7 @@ KERNEL_NAMES = {
     "suffix_prefill_B256": ("paged_suffix_attn",),
     "flash_fwd_pallas": ("flash_fwd",),
     "ssm_state_update_f32": ("ssm_state_update",),
+    "gdn_state_update_f32": ("gdn_state_update",),
     "paged_kv_write_int8": ("paged_kv_write",),
     "tree_attention_bwd": ("tree_attn_fwd", "tree_attn_bwd_dq", "tree_attn_bwd_dkv"),
 }
@@ -430,3 +458,73 @@ def test_lfm2_prefill_compiles_for_v5e(chip, monkeypatch):
     i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
     compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(4, 1024), i32(4), i32(4 * 1024 // PSZ), i32(4)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3  # the three grouped matmuls of the expert layer
+
+
+def _olmo(chip, monkeypatch):
+    """The ``olmo_hybrid`` family at the benchmark's published widths (30
+    delta-rule heads of 96 x 192, 30 / 30 attention heads of 128, hidden
+    3840, 100k vocabulary) and 64 slots, cut to one period (three
+    delta-rule layers and one attention layer) so that tier-1 can hold the
+    compile; weights and cache are shapes on the described chip."""
+    import json
+
+    from areal_tpu import models
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "chip", "configs", "olmo-hybrid-7b-d16.json")) as f:
+        cfg = json.load(f)
+    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "reduced_from", "assumed", "assumed_notes", "stands_for")}
+    assumed = {k: v for k, v in cfg["assumed"].items() if k not in ("initializer_range", "linear_attention_form")}
+    hf.update(assumed, num_hidden_layers=4, layer_types=cfg["layer_types"][:4], dtype="bfloat16")
+    mcfg = models.config_from_hf_dict(hf)
+    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
+    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, 1900, PSZ, slots=64))
+    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels ask the platform whether to compile or interpret
+    return mcfg, place(params), place(cache)
+
+
+def test_olmo_decode_steps_compile_for_v5e(chip, monkeypatch):
+    """Two decode steps as the engine's chunk runs them: the delta-rule
+    state kernel on the packed float32 state in place, the paged kernels at
+    30 KV heads and a query group of 1, the update counts in the carry."""
+    from areal_tpu.models import hybrid
+
+    mcfg, params, cache = _olmo(chip, monkeypatch)
+    assert cache["gdn"].shape == (3, 64, 15, 96, 384) and cache["gdn"].dtype == jnp.float32
+
+    def two_steps(params, cache, pt, ids, pos, active):
+        cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
+
+        def step(c, _):
+            ids, pos, cache = c
+            h, cache = hybrid.forward_decode_paged(params, mcfg, ids, pos, cache, pt, page_size=PSZ, active=active, use_kernel=True)
+            return (jnp.argmax(hybrid.compute_logits(params, mcfg, h), -1).astype(jnp.int32), pos + 1, cache), None
+
+        (ids, _, cache), _ = jax.lax.scan(step, (ids, pos, cache), None, length=2)
+        return ids, cache
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(64, 32), i32(64), i32(64), chip((64,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "gdn_state_update" in text and "paged_decode_attn" in text and "paged_kv_write" in text
+    # the state is advanced where it lies: no second copy of the stacked state (3 x 64 x 2.2 MB = 425 MB here) among the temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < 200e6
+
+
+def test_olmo_prefill_compiles_for_v5e(chip, monkeypatch):
+    """A batched prefill of 4 x 1024 tokens: the chunked delta-rule scan one
+    row at a time, the masked conv windows, the state's slot writes and the
+    KV scatter, within the memory the cell leaves beside its weights."""
+    from areal_tpu.models import hybrid
+
+    mcfg, params, cache = _olmo(chip, monkeypatch)
+
+    def prefill(params, cache, ids, plens, flat_pages, slots):
+        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(4, 1024), i32(4), i32(4 * 1024 // PSZ), i32(4)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
