@@ -3,8 +3,7 @@
 The repo leans on private jax internals in exactly two sanctioned ways:
 kernel launch forks that call a private Pallas kernel positionally
 (``ops/paged_attention_q8.py``), and lazy imports of private library
-kernels (flash attention and the ``BlockSizes`` dataclass its tiles go
-in, megablox gmm, the paged-attention wrapper).
+kernels (megablox gmm, the paged-attention wrapper).
 A jax upgrade can silently reorder/extend those signatures — positional
 call sites then pass the wrong argument into the wrong parameter with no
 error at all. The defense is the pinned-signature idiom: an

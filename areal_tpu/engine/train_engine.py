@@ -47,6 +47,7 @@ from areal_tpu.api.engine_api import InferenceEngine, TrainEngine
 from areal_tpu.api.io_struct import FinetuneSpec, SaveLoadMeta, WeightUpdateMeta
 from areal_tpu.models import qwen
 from areal_tpu.models.hf import load_params_from_hf, save_params_to_hf
+from areal_tpu.observability import catalog as obs_catalog
 from areal_tpu.observability.step_timeline import engine_phase
 from areal_tpu.parallel import mesh as mesh_lib
 from jax import set_mesh, shard_map
@@ -192,6 +193,7 @@ class JaxTrainEngine(TrainEngine):
         # emitted no seq__* stats. Read by PPOActor.ppo_update to join
         # loss stats onto the trajectory lineage ring.
         self.last_seq_stats: dict[str, np.ndarray] | None = None
+        self._obs = obs_catalog.train_obs_metrics()
 
     # -- lifecycle --------------------------------------------------------
     def initialize(self, ft_spec: FinetuneSpec | None = None, **kwargs) -> None:
@@ -643,6 +645,24 @@ class JaxTrainEngine(TrainEngine):
             out.append(g)
         return out
 
+    def _count_attn_tiles(self, grids: list[Grid]) -> None:
+        """Credit ``areal_train_attn_tiles_{run,causal}_total`` with a step's
+        grids: how far ``flash_train``'s segment skip engages. Numpy over
+        [G, L] ids; nothing where the rows' attention is not the flash
+        kernel (``resolve_impl``: off a TPU, short or odd rows)."""
+        from areal_tpu.ops import attention
+
+        mcfg = self.model_cfg
+        for grid in grids:
+            seg = np.asarray(grid.data["segment_ids"])
+            L = seg.shape[-1]
+            if attention.resolve_impl(mcfg.attn_impl, L, mcfg.head_dim_) != "pallas":
+                continue
+            blocks = attention.flash_block_sizes(attention.flash_tiles(L, mcfg.head_dim_))
+            for kernel, (run, causal) in attention.flash_tile_counts(seg, blocks).items():
+                self._obs.attn_tiles_run.labels(kernel=kernel).inc(run)
+                self._obs.attn_tiles_causal.labels(kernel=kernel).inc(causal)
+
     def _grid_to_device(
         self, grid: Grid, seq_attribution: bool = False
     ) -> dict[str, jax.Array]:
@@ -820,7 +840,7 @@ class JaxTrainEngine(TrainEngine):
 
         # honor the configured attention impl like qwen.forward does; ring
         # attention needs the seq axis (excluded by the PP-path mesh assert)
-        from areal_tpu.ops.attention import resolve_impl
+        from areal_tpu.ops.attention import flash_mask, resolve_impl
 
         impl = resolve_impl(mcfg.attn_impl, L, mcfg.head_dim_)
         if impl == "ring":
@@ -828,7 +848,7 @@ class JaxTrainEngine(TrainEngine):
 
         def layer_fn(carry, layer):
             h, sg, ps = carry
-            mask = sg if impl.startswith("pallas") else qwen._attention_mask(sg)
+            mask = flash_mask(sg, mcfg.head_dim_) if impl == "pallas" else qwen._attention_mask(sg)
             h, _ = qwen._decoder_layer(mcfg, h, layer, mask, ps, impl=impl)
             return h, sg, ps
 
@@ -1195,6 +1215,7 @@ class JaxTrainEngine(TrainEngine):
         with engine_phase("host_prep"):
             grids = self._make_grids(input_, mb_spec=mb_spec)
             weights = [float(loss_weight_fn(g.data)) for g in grids]
+            self._count_attn_tiles(grids)
         total_w = sum(weights) or 1.0
 
         grads = None

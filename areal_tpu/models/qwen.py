@@ -684,7 +684,7 @@ def _decoder_layer(cfg: ModelConfig, x, layer, mask, positions, impl=None):
             if impl == "pallas":
                 from areal_tpu.ops.attention import flash_train
 
-                attn = flash_train(q, k, v, mask)  # mask is segment_ids here
+                attn = flash_train(q, k, v, mask)  # mask is a FlashMask here
             elif impl == "pallas_fwd":
                 # leaner forward-only kernel (no VJP residuals) for the no-grad
                 # hot paths: logprob recompute, ref/prox forward, eval
@@ -756,7 +756,12 @@ def forward(
         elif impl == "pallas":
             if no_grad:
                 impl = "pallas_fwd"
-            mask = segment_ids  # flash kernels mask from segment ids alone
+                mask = segment_ids  # the forward kernel masks from segment ids alone
+            else:
+                from areal_tpu.ops.attention import flash_mask
+
+                # once a forward pass, not once a layer inside the scan below
+                mask = flash_mask(segment_ids, cfg.head_dim_)
         else:
             mask = _attention_mask(segment_ids)
 

@@ -539,6 +539,24 @@ def train_obs_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "Compilations served from the persistent XLA compile cache "
             "instead of a fresh backend compile.",
         ),
+        attn_tiles_run=r.counter(
+            "areal_train_attn_tiles_run_total",
+            "(query tile, key tile) pairs the train step's flash kernels "
+            "ran, a head and a layer, by kernel (fwd | dkv | dq): the "
+            "causal tiles that hold a same-segment pair by their segment "
+            "ranges (ops/flash_kernels.py). Counted on the host from each "
+            "step's packed grids; 0 where the step's attention is not the "
+            "flash kernel.",
+            label_names=("kernel",),
+        ),
+        attn_tiles_causal=r.counter(
+            "areal_train_attn_tiles_causal_total",
+            "Tiles on or below the causal diagonal over the same grids, by "
+            "kernel: what the kernels ran before they skipped by segment. "
+            "run / causal is the share of attention tiles still computed; "
+            "1.0 on rows of one sequence.",
+            label_names=("kernel",),
+        ),
     )
 
 

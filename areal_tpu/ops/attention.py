@@ -4,16 +4,17 @@ Replaces the reference's flash-attn dependency (SURVEY §2.8.4). Three impls:
 
 - ``xla``: masked einsum+softmax — XLA fuses/tiles onto the MXU; reference
   numerics for tests and the CPU mesh.
-- ``pallas``: TPU flash attention. Training uses jax's battle-tested
-  ``pallas.ops.tpu.flash_attention`` (full custom VJP); the forward-only
-  hot path (logprob recompute, ref/prox forward) uses our own leaner
-  forward kernel below (``flash_fwd_pallas``). Packed-segment + causal
-  masking via SegmentIds/col-index — same semantics as the grid mask.
-  Both take their tile edges from ``flash_tiles(L, head_dim)``: the
-  library's default of 128 everywhere makes a kernel pay for tens of
-  thousands of near-empty grid steps a layer. The chosen tiles are logged
-  once per shape, and the library writes them into its backward kernels'
-  names, which a device trace shows.
+- ``pallas``: TPU flash attention. Training uses the kernels of
+  ``ops/flash_kernels.py`` (forward, dK/dV, dQ and their custom VJP: a fork
+  of jax's library kernels that also skips the tiles of a packed row that
+  hold no same-segment pair); the forward-only hot path (logprob recompute,
+  ref/prox forward) uses the leaner forward kernel below
+  (``flash_fwd_pallas``), which skips the same tiles. Packed-segment +
+  causal masking via segment ids/col-index — same semantics as the grid
+  mask. Both take their tile edges from ``flash_tiles(L, head_dim)``: at
+  edges of 128 a kernel pays for tens of thousands of near-empty grid steps
+  a layer. The chosen tiles are logged once per shape and written into the
+  kernels' names, which a device trace shows.
 - ring attention lives in parallel/ring_attention.py (context parallelism).
 
 All entry points take [G, L, H, d] (model layout) and handle the transpose
@@ -22,6 +23,7 @@ to the kernels' [G, H, L, d].
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import NamedTuple
 
@@ -30,42 +32,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from areal_tpu.ops import flash_kernels
+from areal_tpu.ops.flash_kernels import FlashBlocks
 from areal_tpu.utils import logging as alog
-from areal_tpu.utils.private_api import pin_signature
 
 logger = alog.getLogger("attention")
-
-# flash_attention is a PRIVATE pallas op we call with keyword args whose
-# names (and the positional q/k/v order) a jax bump can silently change;
-# verified at first use, re-checked against the installed jax by arealint
-# PVT002.
-_EXPECTED_FLASH_ATTENTION_PARAMS = (
-    "q",
-    "k",
-    "v",
-    "ab",
-    "segment_ids",
-    "causal",
-    "sm_scale",
-    "block_sizes",
-    "debug",
-)
-# every field of the library's BlockSizes that flash_block_sizes fills, in the
-# dataclass's own order: a bump that adds a field leaves it at a default
-# nobody chose
-_EXPECTED_BLOCK_SIZES_FIELDS = (
-    "block_q",
-    "block_k_major",
-    "block_k",
-    "block_b",
-    "block_q_major_dkv",
-    "block_k_major_dkv",
-    "block_k_dkv",
-    "block_q_dkv",
-    "block_k_major_dq",
-    "block_k_dq",
-    "block_q_dq",
-)
 
 
 def sdpa_xla(q, k, v, mask, head_dim: int):
@@ -89,15 +60,15 @@ def flash_ok(L: int, head_dim: int, block: int = 128) -> bool:
 # the chip; its table is in PERF.md (PR 27). 2048 overflows VMEM at head_dim
 # 128, as 1024 does at head_dim 256.
 FLASH_TILE_EDGES = (1024, 512, 256, 128)
-# the library's dq wrapper broadcasts the row sums of dO*O to a float32
-# [G, H, L, block_k_major] in HBM before the kernel runs: past 512 that costs
+# the dq wrapper broadcasts the row sums of dO*O to a float32
+# [G, H, L, block_k] in HBM before the kernel runs: past 512 that costs
 # more than the kernel gains (2.39 + 0.46 ms a layer at 512, 1.95 + 1.03 at 1024)
 _DQ_EDGE_CAP = 512
 
 
 class FlashTiles(NamedTuple):
-    """Tile edge (query and key axes, major and minor alike) of each of the
-    library's three kernels."""
+    """Tile edge (query and key axes alike) of each of the three training
+    kernels."""
 
     fwd: int
     dkv: int
@@ -107,7 +78,7 @@ class FlashTiles(NamedTuple):
 def flash_tiles(L: int, head_dim: int) -> FlashTiles:
     """Tiles from what the call can see: for each kernel the largest edge
     that divides the row length, capped by what VMEM holds at this
-    head_dim; 128, the library's own default, at worst."""
+    head_dim; 128 at worst."""
     cap = FLASH_TILE_EDGES[0] * 128 // max(head_dim, 128)
 
     def edge(at_most: int) -> int:
@@ -116,63 +87,75 @@ def flash_tiles(L: int, head_dim: int) -> FlashTiles:
     return FlashTiles(fwd=edge(cap), dkv=edge(cap), dq=edge(min(cap, _DQ_EDGE_CAP)))
 
 
-def pinned_block_sizes():
-    """The library's ``BlockSizes``, its fields checked against the pin."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
-
-    return pin_signature(BlockSizes, _EXPECTED_BLOCK_SIZES_FIELDS)
-
-
-def flash_block_sizes(tiles: FlashTiles):
-    """``BlockSizes`` with every field filled from ``tiles``."""
-    return pinned_block_sizes()(
-        block_q=tiles.fwd,
-        block_k_major=tiles.fwd,
-        block_k=tiles.fwd,
-        block_b=1,
-        block_q_major_dkv=tiles.dkv,
-        block_k_major_dkv=tiles.dkv,
-        block_k_dkv=tiles.dkv,
-        block_q_dkv=tiles.dkv,
-        block_k_major_dq=tiles.dq,
-        block_k_dq=tiles.dq,
-        block_q_dq=tiles.dq,
-    )
+def flash_block_sizes(tiles: FlashTiles) -> FlashBlocks:
+    """What ``flash_kernels.flash_mha`` takes: each kernel's (query, key)
+    edges, both ``tiles``' edge for that kernel."""
+    return FlashBlocks(*((edge, edge) for edge in tiles))
 
 
 @functools.lru_cache(maxsize=None)
-def _log_tiles(shape: tuple, tiles: FlashTiles) -> None:
+def _log_tiles(shape: tuple, blocks: FlashBlocks) -> None:
     # cached: one line per (shape, tiles), however often it is traced
-    logger.info(f"flash_train at [G, L, H, d]={list(shape)}: tiles {tiles._asdict()}")
+    logger.info(f"flash_train at [G, L, H, d]={list(shape)}: tiles {blocks._asdict()}")
 
 
-def flash_train(q, k, v, segment_ids, block_sizes=None):
-    """Differentiable flash attention (jax pallas TPU kernel, causal +
-    segment masking). q,k,v: [G, L, H, d] with kv heads pre-replicated.
-    ``block_sizes`` (the library's ``BlockSizes``) is for the probe's sweep;
-    the program leaves it to ``flash_tiles``."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        SegmentIds,
-        flash_attention,
-    )
+@functools.partial(jax.tree_util.register_dataclass, data_fields=["segment_ids", "skips"], meta_fields=["blocks"])
+@dataclasses.dataclass(frozen=True)
+class FlashMask:
+    """What ``flash_train`` masks and skips by: a grid's segment ids, the
+    kernels' tile edges (static) and their skip tables for both."""
 
-    pin_signature(flash_attention, _EXPECTED_FLASH_ATTENTION_PARAMS)
+    segment_ids: jax.Array
+    skips: flash_kernels.FlashSkips
+    blocks: FlashBlocks
+
+
+def flash_mask(segment_ids, head_dim: int, block_sizes: FlashBlocks | None = None) -> FlashMask:
+    """``flash_train``'s mask for ``segment_ids`` [G, L]. A model builds it
+    once a forward pass, outside its scan over layers: the skip tables are
+    a few dozen tiny ops that XLA would otherwise run again in every layer,
+    forward, recomputed and backward. ``block_sizes`` is for the probe's
+    sweep; the program leaves it to ``flash_tiles``."""
     if block_sizes is None:
-        tiles = flash_tiles(q.shape[1], q.shape[-1])
-        _log_tiles(tuple(q.shape), tiles)
-        block_sizes = flash_block_sizes(tiles)
+        block_sizes = flash_block_sizes(flash_tiles(segment_ids.shape[-1], head_dim))
+    # the barrier keeps the tables where they are built: XLA otherwise sinks
+    # their last cheap ops back into the loop over layers
+    skips = jax.lax.optimization_barrier(flash_kernels.flash_skips(segment_ids, block_sizes))
+    return FlashMask(segment_ids, skips, block_sizes)
+
+
+def flash_train(q, k, v, mask: FlashMask, interpret: bool = False):
+    """Differentiable flash attention (``ops/flash_kernels.py``: causal +
+    segment masking, tiles without a same-segment pair skipped). q,k,v:
+    [G, L, H, d] with kv heads pre-replicated; ``mask`` from
+    ``flash_mask``. ``interpret=True`` runs the kernels through the Pallas
+    interpreter (CPU tests, tools/kernelcheck.py)."""
+    _log_tiles(tuple(q.shape), mask.blocks)
     qt, kt, vt = (jnp.transpose(x, (0, 2, 1, 3)) for x in (q, k, v))
-    seg = SegmentIds(q=segment_ids, kv=segment_ids)
-    out = flash_attention(
-        qt,
-        kt,
-        vt,
-        segment_ids=seg,
-        causal=True,
-        sm_scale=q.shape[-1] ** -0.5,
-        block_sizes=block_sizes,
+    out = flash_kernels.flash_mha(
+        qt, kt, vt, mask.segment_ids, mask.skips, sm_scale=q.shape[-1] ** -0.5, blocks=mask.blocks, interpret=interpret
     )
     return jnp.transpose(out, (0, 2, 1, 3))
+
+
+@functools.lru_cache(maxsize=None)
+def _log_tile_counts(shape: tuple, counts: tuple) -> None:
+    # cached: one line per (shape, counts); a layout that runs other tiles logs again
+    logger.info(f"flash_train at [G, L]={list(shape)}: tiles run of causal tiles {dict(counts)}")
+
+
+def flash_tile_counts(segment_ids, blocks: FlashBlocks) -> dict[str, tuple[int, int]]:
+    """{kernel: (tiles run, causal tiles)} of ``flash_train`` over a grid's
+    rows, a head and a layer: how far the segment skip engages. On the
+    host, from numpy ``segment_ids`` [G, L]."""
+    G, L = segment_ids.shape
+    by_edges = {
+        edges: (int(flash_kernels.live_tiles(segment_ids, *edges).sum()), G * int(flash_kernels.causal_tiles(L, *edges).sum()))
+        for edges in set(blocks)
+    }
+    counts = {kernel: by_edges[edges] for kernel, edges in blocks._asdict().items()}
+    _log_tile_counts(tuple(segment_ids.shape), tuple((k, "%d/%d" % v) for k, v in counts.items()))
+    return counts
 
 
 # ---------------------------------------------------------------------------
@@ -181,6 +164,8 @@ def flash_train(q, k, v, segment_ids, block_sizes=None):
 
 
 def _flash_fwd_kernel(
+    run_ref,  # scalar prefetch: flash_kernels.skip_operands
+    block_ref,
     seg_q_ref,  # [1, blk_q, 128] (seg ids broadcast along lanes)
     seg_k_ref,  # [1, 8, blk_k] (seg ids broadcast along sublanes)
     q_ref,  # [1, 1, blk_q, d]
@@ -195,6 +180,8 @@ def _flash_fwd_kernel(
     blk_q: int,
     blk_k: int,
 ):
+    del block_ref
+    g = pl.program_id(0)
     iq = pl.program_id(2)
     ik = pl.program_id(3)
 
@@ -204,9 +191,9 @@ def _flash_fwd_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # skip fully-future kv blocks (causal): only compute when ik*blk_k could
-    # contain keys <= the last query of this block
-    @pl.when(ik * blk_k <= iq * blk_q + blk_q - 1)
+    # skip fully-future kv blocks (causal) and blocks that share no segment
+    # id with the query block: both contribute exact zeros
+    @pl.when(run_ref[g, iq * pl.num_programs(3) + ik] != 0)
     def _compute():
         q = q_ref[0, 0, :, :]
         k = k_ref[0, 0, :, :]
@@ -259,8 +246,10 @@ def flash_fwd_pallas(
     interpret: bool = False,
 ):
     """Forward-only packed flash attention. q,k,v: [G, L, H, d] (kv heads
-    pre-replicated); segment_ids [G, L]. Causal by column index. Tiles
-    default to ``flash_tiles``' forward edge, as ``flash_train``'s do.
+    pre-replicated); segment_ids [G, L]. Causal by column index; a tile
+    without a same-segment pair is skipped, and fetches nothing, as in
+    ``flash_train``'s kernels. Tiles default to ``flash_tiles``' forward
+    edge, as ``flash_train``'s do.
     ``interpret=True`` runs the kernel through the Pallas interpreter so
     CPU tier-1 and tools/kernelcheck.py can cover it (arealint KRN005)."""
     G, L, H, d = q.shape
@@ -277,26 +266,31 @@ def flash_fwd_pallas(
     # segment ids broadcast into lane/sublane dims to satisfy TPU tiling
     seg_q_in = jnp.broadcast_to(segment_ids[:, :, None], (G, L, 128))
     seg_k_in = jnp.broadcast_to(segment_ids[:, None, :], (G, 8, L))
+    prefetch = flash_kernels.skip_operands(segment_ids, blk_q, blk_k, outer="q")
+    n_k = L // blk_k
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, blk_q, 128), lambda g, h, iq, ik: (g, iq, 0)),
-            pl.BlockSpec((1, 8, blk_k), lambda g, h, iq, ik: (g, 0, ik)),
-            pl.BlockSpec((1, 1, blk_q, d), lambda g, h, iq, ik: (g, h, iq, 0)),
-            pl.BlockSpec((1, 1, blk_k, d), lambda g, h, iq, ik: (g, h, ik, 0)),
-            pl.BlockSpec((1, 1, blk_k, d), lambda g, h, iq, ik: (g, h, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, blk_q, d), lambda g, h, iq, ik: (g, h, iq, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # skip_operands
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, blk_q, 128), lambda g, h, iq, ik, *_: (g, iq, 0)),
+                pl.BlockSpec((1, 8, blk_k), lambda g, h, iq, ik, run, block: (g, 0, block[g, iq * n_k + ik])),
+                pl.BlockSpec((1, 1, blk_q, d), lambda g, h, iq, ik, *_: (g, h, iq, 0)),
+                pl.BlockSpec((1, 1, blk_k, d), lambda g, h, iq, ik, run, block: (g, h, block[g, iq * n_k + ik], 0)),
+                pl.BlockSpec((1, 1, blk_k, d), lambda g, h, iq, ik, run, block: (g, h, block[g, iq * n_k + ik], 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, blk_q, d), lambda g, h, iq, ik, *_: (g, h, iq, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((blk_q, 128), jnp.float32),
+                pltpu.VMEM((blk_q, 128), jnp.float32),
+                pltpu.VMEM((blk_q, d), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((G, H, L, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((blk_q, 128), jnp.float32),
-            pltpu.VMEM((blk_q, 128), jnp.float32),
-            pltpu.VMEM((blk_q, d), jnp.float32),
-        ],
         name="flash_fwd",
         interpret=interpret,
-    )(seg_q_in, seg_k_in, qt, kt, vt)
+    )(*prefetch, seg_q_in, seg_k_in, qt, kt, vt)
     return jnp.transpose(out, (0, 2, 1, 3))
 
 

@@ -458,8 +458,8 @@ def _cases_paged_suffix(compiled: bool = False) -> Iterator[dict]:
 
 
 # ---------------------------------------------------------------------------
-# flash attention (ops/attention.py): the repo's forward kernel and jax's
-# differentiable library kernel behind flash_train
+# flash attention (ops/attention.py): the repo's forward kernel and the
+# differentiable kernels behind flash_train (ops/flash_kernels.py)
 # ---------------------------------------------------------------------------
 
 
@@ -531,40 +531,77 @@ def _cases_flash_fwd(compiled: bool = False) -> Iterator[dict]:
             ),
             "tol": CHIP_TOL if compiled else 2e-4,
         }
-    if not compiled:
-        return
-    # jax's library flash attention behind flash_train (forward AND
-    # gradients) against XLA sdpa. It has no interpret switch, so it is an
-    # on-chip case only. Rows of padding (segment 0) carry no loss in the
-    # trainer: their output is zeroed on both sides, and with it what they
-    # send back.
+
+
+@register_kernel("flash_train")
+def _cases_flash_train(compiled: bool = False) -> Iterator[dict]:
+    """``flash_train`` (forward AND gradients, ops/flash_kernels.py)
+    against XLA sdpa. Rows of padding (segment 0) carry no loss in the
+    trainer: their output is zeroed on both sides, and with it what they
+    send back."""
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import attention
+
+    H, d = (12, 128) if compiled else (2, 128)
+    dt = jnp.bfloat16 if compiled else jnp.float32
+
+    def segments(L, cuts):
+        seg = np.zeros((1, L), np.int32)
+        for i, (a, b) in enumerate(zip(cuts, cuts[1:])):
+            seg[:, a:b] = i + 1
+        return seg
+
+    if compiled:
+        # the train cell's row length, so its tiles (1024 and 512): five
+        # segments whose boundaries fall inside tiles, then a padded tail
+        grids = {
+            "bf16-L2048-two-segments": (segments(2048, [0, 1024, 2048]), None),
+            "bf16-L4096-five-segments-padded-tail": (segments(4096, [0, 700, 1500, 2300, 3000, 3900]), None),
+        }
+    else:
+        # tiles of 128 on a row of 512, so that some are skipped: a boundary
+        # inside a tile, one on a tile's edge, a padded tail
+        edges = attention.FlashBlocks((128, 128), (128, 128), (128, 128))
+        grids = {
+            "f32-L512-three-segments-padded-tail": (segments(512, [0, 100, 256, 450]), edges),
+            "f32-L512-one-segment": (segments(512, [0, 512]), edges),
+        }
+
+    def build(seg_np):
+        shape = (*seg_np.shape, H, d)
+        return lambda: {
+            "q": _normal(11, shape, dt),
+            "k": _normal(12, shape, dt),
+            "v": _normal(13, shape, dt),
+            "w": _normal(23, shape),
+            "seg": jnp.asarray(seg_np),
+            "mask": jnp.asarray(_packed_mask(seg_np)),
+        }
+
     def live(attn, inp):
         keep = (inp["seg"] != 0)[:, :, None, None]
         return lambda q, k, v: jnp.where(keep, attn(q, k, v), 0)
 
-    # the train cell's row length, so its tiles (1024 and 512): five
-    # segments whose boundaries fall inside tiles, then a padded tail
-    cuts = [0, 700, 1500, 2300, 3000, 3900]
-    seg_4k = np.zeros((1, 4096), np.int32)
-    for i, (a, b) in enumerate(zip(cuts, cuts[1:])):
-        seg_4k[:, a:b] = i + 1
-    for label, seg_np in (
-        ("library-train-bf16-packed-fwd-and-grads", grids["packed-two-segments"]),
-        ("library-train-bf16-L4096-five-segments-padded-tail", seg_4k),
-    ):
+    for label, (seg_np, blocks) in grids.items():
         yield {
             "case": label,
             "build": build(seg_np),
-            "kernel": lambda inp: _out_and_grads(
-                live(lambda q, k, v: attention.flash_train(q, k, v, inp["seg"]), inp),
+            "kernel": lambda inp, blocks=blocks: _out_and_grads(
+                live(
+                    lambda q, k, v: attention.flash_train(
+                        q, k, v, attention.flash_mask(inp["seg"], d, blocks), interpret=not compiled
+                    ),
+                    inp,
+                ),
                 inp["q"], inp["k"], inp["v"], inp["w"],
             ),
             "reference": lambda inp: _out_and_grads(
                 live(lambda q, k, v: attention.sdpa_xla(q, k, v, inp["mask"], d), inp),
                 inp["q"], inp["k"], inp["v"], inp["w"],
             ),
-            # gradients sum bf16-rounded probabilities over up to 1024 keys
-            "tol": 1e-1,
+            # bf16 gradients sum rounded probabilities over up to 1024 keys
+            "tol": 1e-1 if compiled else 2e-4,
         }
 
 

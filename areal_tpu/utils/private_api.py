@@ -1,12 +1,11 @@
 """Pinned-signature guard for private jax APIs (the arealint PVT idiom).
 
-The repo calls several private jax internals positionally (flash
-attention, megablox gmm, the library paged-attention launch wrapper) and
-fills one private dataclass field by field (the flash kernels'
-``BlockSizes``: its constructor's parameters are its fields). A jax bump
-can silently reorder or extend those signatures, after which positional
-call sites feed the wrong argument into the wrong parameter with no
-error, and a field nobody fills keeps a default nobody chose.
+The repo calls private jax internals positionally (megablox gmm, the
+library paged-attention launch wrapper). A jax bump can silently reorder
+or extend those signatures, after which positional call sites feed the
+wrong argument into the wrong parameter with no error. A pinned symbol may
+be a class too: a dataclass's constructor parameters are its fields, and a
+field nobody fills keeps a default nobody chose.
 
 Each call site declares the parameter tuple it was audited against as a
 module-level ``_EXPECTED_*`` literal and verifies it via

@@ -304,29 +304,23 @@ def test_pvt_good_fixture():
     assert rules_in(FIXTURES / "pvt_good.py", ["PVT"]) == []
 
 
-def test_block_sizes_pin_names_every_field_the_call_fills():
-    """``ops/attention.py`` pins the flash kernels' private ``BlockSizes``
-    beside ``flash_attention`` (PVT002 re-checks both at lint time): the
-    pin is the dataclass's own field list in the installed jax, and
-    ``flash_block_sizes`` leaves no field at a default nobody chose."""
-    import dataclasses
+def test_flash_kernels_are_the_repos_own_and_need_no_pin():
+    """The training flash kernels are a fork in ``ops/flash_kernels.py``
+    (ISSUE 33): neither it nor ``ops/attention.py`` imports a private jax
+    module any more, so there is no pin for PVT002 to re-check, and the
+    fork's three launches pass the KRN geometry rules (index-map arity with
+    the scalar-prefetch refs, ``interpret=`` exposed)."""
+    from areal_tpu.ops import attention, flash_kernels
 
-    from areal_tpu.ops import attention
-
-    src = Path(attention.__file__)
-    assert rules_in(src, ["PVT"]) == []
-    cls = attention.pinned_block_sizes()
-    names = tuple(f.name for f in dataclasses.fields(cls))
-    assert names == attention._EXPECTED_BLOCK_SIZES_FIELDS
-    filled = attention.flash_block_sizes(attention.FlashTiles(512, 256, 128))
-    assert dataclasses.asdict(filled) == {
-        **{n: 512 for n in ("block_q", "block_k_major", "block_k")},
-        "block_b": 1,
-        **{n: 256 for n in names if n.endswith("_dkv")},
-        **{n: 128 for n in names if n.endswith("_dq")},
-    }
-    with pytest.raises(RuntimeError, match="drifted"):
-        attention.pin_signature(cls, names[:-1])
+    for mod in (attention, flash_kernels):
+        src = Path(mod.__file__)
+        assert rules_in(src, ["PVT", "KRN"]) == []
+        assert "pallas.ops" not in src.read_text() and "_EXPECTED_" not in src.read_text()
+    assert not hasattr(attention, "pinned_block_sizes")
+    blocks = attention.flash_block_sizes(attention.FlashTiles(512, 256, 128))
+    assert blocks == flash_kernels.FlashBlocks((512, 512), (256, 256), (128, 128))
+    with pytest.raises(ValueError, match="tile edge"):
+        flash_kernels._check_blocks("forward", 1024, 384, 128)
 
 
 def test_msh_bad_fixture():

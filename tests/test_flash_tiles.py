@@ -1,15 +1,13 @@
 """Tiles of the train step's flash kernels (ops/attention.py, ISSUE 27):
-chosen from the row length and head_dim alone, valid for the library's
-``BlockSizes``, shared by ``flash_train`` and ``flash_fwd_pallas``."""
-
-import dataclasses
+chosen from the row length and head_dim alone, valid for the kernels of
+``ops/flash_kernels.py``, shared by ``flash_train`` and ``flash_fwd_pallas``."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from areal_tpu.ops import attention
+from areal_tpu.ops import attention, flash_kernels
 from areal_tpu.tools.kernelcheck import _packed_mask
 
 
@@ -20,17 +18,16 @@ def test_tiles_divide_the_row_and_build_block_sizes(L, monkeypatch):
         cap = attention.FLASH_TILE_EDGES[0] * 128 // head_dim
         for edge in tiles:
             assert L % edge == 0 and 128 <= edge <= cap, (L, head_dim, tiles)
-        # the constructor refuses minor > major and a minor that does not
-        # divide its major; the library's kernels want edges that divide L
+        # the kernels want (query, key) edges, multiples of 128 that divide L
         bs = attention.flash_block_sizes(tiles)
-        fields = dataclasses.asdict(bs)
-        assert fields.pop("block_b") == 1
-        assert all(v is not None and L % v == 0 for v in fields.values()), fields
-        assert bs.has_backward_blocks
+        assert bs._fields == tiles._fields == ("fwd", "dkv", "dq")
+        for kernel, (block_q, block_k) in bs._asdict().items():
+            assert block_q == block_k == getattr(tiles, kernel)
+            flash_kernels._check_blocks(kernel, L, block_q, block_k)
     # a default-configured row (bucket_step 512) never gets the library's 128
     if L % 512 == 0:
         assert min(attention.flash_tiles(L, 128)) >= 512
-    # a length that is only a multiple of 128 gets today's tiles at worst
+    # a length that is only a multiple of 128 gets tiles of 128 at worst
     if L % 256:
         assert set(attention.flash_tiles(L, 128)) == {128}
     # the kernel is chosen where the probe saw it win: from 512 at tiles of

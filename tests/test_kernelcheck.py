@@ -15,6 +15,7 @@ def test_registered_kernels_enumerate():
         "paged_attention_q8",
         "paged_attention_stacked",
         "flash_fwd",
+        "flash_train",
         "tree_attention",
         "paged_suffix_attention",
     } <= set(kernelcheck.REGISTRY)

@@ -177,12 +177,14 @@ def _flash_fwd(G=1, L=2048):
 
 
 def _flash_train_grad(G=1, L=2048):
-    """``flash_train`` at the tiles ``flash_tiles`` gives the row length."""
+    """``flash_train`` (ops/flash_kernels.py: forward, dK/dV, dQ with their
+    scalar-prefetched segment ranges) at the tiles ``flash_tiles`` gives
+    the row length."""
     from areal_tpu.ops import attention
 
     def fn(q, k, v, seg):
         return jax.grad(
-            lambda q, k, v: attention.flash_train(q, k, v, seg)
+            lambda q, k, v: attention.flash_train(q, k, v, attention.flash_mask(seg, HD))
             .astype(jnp.float32)
             .sum(),
             argnums=(0, 1, 2),
@@ -288,6 +290,12 @@ KERNEL_NAMES = {
     "paged_decode_7b_int8": ("paged_decode_attn",),
     "suffix_prefill_B256": ("paged_suffix_attn",),
     "flash_fwd_pallas": ("flash_fwd",),
+    # the library's names for these tiles: what the ledger's breakdown lists
+    "flash_train_grad_3x4096": (
+        "flash_mha_fwd_block_q_1024_block_k_major_1024_block_k_1024",
+        "flash_mha_bwd_dkv_block_q_major_1024_block_q_1024_block_k_major_1024_block_k_1024",
+        "flash_mha_bwd_dq_block_q_major_512_block_k_major_512_block_k_512",
+    ),
     "ssm_state_update_f32": ("ssm_state_update",),
     "gdn_state_update_f32": ("gdn_state_update",),
     "paged_kv_write_int8": ("paged_kv_write",),
