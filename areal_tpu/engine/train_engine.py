@@ -52,6 +52,8 @@ from areal_tpu.observability.step_timeline import engine_phase
 from areal_tpu.parallel import mesh as mesh_lib
 from jax import set_mesh, shard_map
 from areal_tpu.utils import logging as alog
+from areal_tpu.utils import perf_tracer
+from areal_tpu.utils.compile_cache import FirstCall
 from areal_tpu.utils.data import TensorDict, seqlens_of
 from areal_tpu.utils.grid import Grid, pack_grid
 from areal_tpu.utils.data import round_up_to_bucket
@@ -194,9 +196,24 @@ class JaxTrainEngine(TrainEngine):
         # loss stats onto the trajectory lineage ring.
         self.last_seq_stats: dict[str, np.ndarray] | None = None
         self._obs = obs_catalog.train_obs_metrics()
+        # one WARNING line for a train step over 3 x the median of the last
+        # 64, with what the span record holds of it
+        self._step_watch = perf_tracer.SlowSpanWatch("areal.train.step")
 
     # -- lifecycle --------------------------------------------------------
     def initialize(self, ft_spec: FinetuneSpec | None = None, **kwargs) -> None:
+        from areal_tpu.observability import hw_accounting as hw
+
+        with perf_tracer.trace_scope(
+            "areal.setup.engine_init", args={"engine": "train"}
+        ) as span:
+            self._initialize(ft_spec, **kwargs)
+            span.set(
+                param_bytes=hw.tree_bytes(self.params),
+                opt_state_bytes=hw.tree_bytes(self.opt_state),
+            )
+
+    def _initialize(self, ft_spec: FinetuneSpec | None = None, **kwargs) -> None:
         cfg = self.config
         self.ft_spec = ft_spec
         # re-read the logit temperature: trainers sync config.actor fields
@@ -222,9 +239,15 @@ class JaxTrainEngine(TrainEngine):
             )
         # before the first compile, in the process that owns the devices
         # (a trainer driving remote workers never touches jax): the
-        # persistent compile cache, TPU-only (utils/compile_cache.py)
-        from areal_tpu.utils.compile_cache import enable_persistent_cache
+        # persistent compile cache, TPU-only, and the listener that counts
+        # compilations and writes them into the span record
+        # (utils/compile_cache.py)
+        from areal_tpu.utils.compile_cache import (
+            enable_persistent_cache,
+            install_compile_counters,
+        )
 
+        install_compile_counters()
         enable_persistent_cache()
         self.mesh = kwargs.get("mesh") or mesh_lib.make_mesh(cfg.mesh)
         mcfg = self._model_config
@@ -948,6 +971,7 @@ class JaxTrainEngine(TrainEngine):
             # free as the forward consumes them instead of surviving the
             # whole fwd/bwd
             self._fn_cache[key] = jax.jit(compute, donate_argnums=(1,))
+            return FirstCall(self._fn_cache[key], key)
         return self._fn_cache[key]
 
     def _get_forward_fn(self, shape: tuple, post_hook: Callable | None = None):
@@ -961,6 +985,7 @@ class JaxTrainEngine(TrainEngine):
                 return outputs
 
             self._fn_cache[key] = jax.jit(compute)
+            return FirstCall(self._fn_cache[key], key)
         return self._fn_cache[key]
 
     def _get_accum_fn(self):
@@ -973,6 +998,7 @@ class JaxTrainEngine(TrainEngine):
             self._fn_cache[key] = jax.jit(
                 lambda a, b: jax.tree.map(jnp.add, a, b), donate_argnums=(0, 1)
             )
+            return FirstCall(self._fn_cache[key], key)
         return self._fn_cache[key]
 
     def _get_fused_step_fn(
@@ -1003,6 +1029,7 @@ class JaxTrainEngine(TrainEngine):
             # params/opt_state are rebound by every caller (DON001 contract)
             # and the batch is single-use — donate all three
             self._fn_cache[key] = jax.jit(step, donate_argnums=(0, 1, 2))
+            return FirstCall(self._fn_cache[key], key)
         return self._fn_cache[key]
 
     def _get_apply_fn(self):
@@ -1022,6 +1049,7 @@ class JaxTrainEngine(TrainEngine):
             # third params-sized transient (DON burn-down; the HBM ledger's
             # step_transient component accounts for exactly this)
             self._fn_cache[key] = jax.jit(apply, donate_argnums=(0, 1, 2))
+            return FirstCall(self._fn_cache[key], key)
         return self._fn_cache[key]
 
     # -- tree training ----------------------------------------------------
@@ -1202,6 +1230,20 @@ class JaxTrainEngine(TrainEngine):
         loss_fn: Callable,
         loss_weight_fn: Callable[[TensorDict], float],
         mb_spec: MicroBatchSpec | None = None,
+    ) -> dict[str, float]:
+        """One optimizer step: the span ``areal.train.step``, whose children
+        are the step's ``engine_phase`` spans."""
+        with perf_tracer.trace_scope("areal.train.step", cpu=True) as span:
+            out = self._train_batch(input_, loss_fn, loss_weight_fn, mb_spec)
+        self._step_watch.observe(span)
+        return out
+
+    def _train_batch(
+        self,
+        input_: TensorDict,
+        loss_fn: Callable,
+        loss_weight_fn: Callable[[TensorDict], float],
+        mb_spec: MicroBatchSpec | None,
     ) -> dict[str, float]:
         assert self.params is not None, "engine not initialized"
         self.last_seq_stats = None

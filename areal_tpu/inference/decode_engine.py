@@ -71,6 +71,7 @@ from areal_tpu.parallel import mesh as mesh_lib
 from jax import set_mesh
 from areal_tpu.utils import logging as alog
 from areal_tpu.utils import perf_tracer
+from areal_tpu.utils.compile_cache import FirstCall
 from areal_tpu.utils.data import round_up_to_bucket
 
 logger = alog.getLogger("decode_engine")
@@ -358,9 +359,24 @@ class DecodeEngine:
         self._pace_wait: Callable[[float], bool] = self._wakeup.wait
         self._pull_s = 0.0  # seconds of the current pass inside blocking pulls
         self._pass_start = 0.0  # time.monotonic() at the top of the pass
+        # one WARNING line for a productive pass over 3 x the median of the
+        # last 64, with what the span record holds of it
+        self._pass_watch = perf_tracer.SlowSpanWatch("areal.decode.pass")
+        self._pass_productive = False  # did the current pass drain, dispatch or admit
 
     # -- lifecycle --------------------------------------------------------
     def initialize(self) -> None:
+        with perf_tracer.trace_scope(
+            "areal.setup.engine_init", args={"engine": "decode"}
+        ) as span:
+            self._initialize()
+            span.set(
+                param_bytes=hw.tree_bytes(self.params),
+                recurrent_state_bytes=self._state_bytes(),
+                kv_page_bytes=hw.tree_bytes(self.cache) - self._state_bytes(),
+            )
+
+    def _initialize(self) -> None:
         cfg = self.config
         # serving-side compile visibility: a recompile storm (drifting
         # chunk/scatter shape keys) shows as areal_xla_compiles_total climb
@@ -801,8 +817,6 @@ class DecodeEngine:
         psz = cfg.page_size
         if prompt_buckets is None:
             prompt_buckets = self._reachable_prompt_buckets()
-        from areal_tpu.inference import paged_kv
-
         tasks: list[Callable[[], Any]] = []
         freq_variants = (False, True) if cfg.enable_frequency_penalty else (False,)
         for wp in self._reachable_chunk_wps():
@@ -842,17 +856,11 @@ class DecodeEngine:
         n = 1
         while True:
 
-            def warm_pagecopy(n=n):
-                key = ("pagecopy", n)
-                if key not in self._fn_cache:
-                    self._fn_cache[key] = jax.jit(
-                        paged_kv.copy_pages, donate_argnames=("cache",)
-                    )
-                self._fn_cache[key].lower(
+            tasks.append(
+                lambda n=n: self._pagecopy_fn(n).lower(
                     cache_s, *[jax.ShapeDtypeStruct((n,), jnp.int32)] * 4
                 ).compile()
-
-            tasks.append(warm_pagecopy)
+            )
             if n >= max(1, cfg.max_batch_size - 1):
                 break
             n *= 2
@@ -870,7 +878,9 @@ class DecodeEngine:
                 )
 
         n_prog = 0
-        with set_mesh(self.mesh):
+        with set_mesh(self.mesh), perf_tracer.trace_scope(
+            "areal.setup.precompile", args={"programs": len(tasks)}
+        ):
             for task in tasks:
                 if budget_s is not None and time.monotonic() - t0 > budget_s:
                     logger.warning(
@@ -1937,6 +1947,7 @@ class DecodeEngine:
                 )
 
             self._fn_cache[key] = jax.jit(prefill, donate_argnames=("cache",))
+            return FirstCall(self._fn_cache[key], key)
         return self._fn_cache[key]
 
     def _prefill_paged_fn(self, n_prompts: int, bucket: int, wp: int):
@@ -1969,6 +1980,7 @@ class DecodeEngine:
                     return paged_kv.scatter_prefill(cache, ks, vs, flat_pages, psz)
 
             self._fn_cache[key] = jax.jit(prefill, donate_argnames=("cache",))
+            return FirstCall(self._fn_cache[key], key)
         return self._fn_cache[key]
 
     def _image_embeds_for(self, group: list[tuple[_Task, int]], ids_np, bucket: int):
@@ -2182,6 +2194,7 @@ class DecodeEngine:
                 return cache, out_state, rng, packed
 
             self._fn_cache[key] = jax.jit(chunk, donate_argnames=("cache", "state"))
+            return FirstCall(self._fn_cache[key], key)
         return self._fn_cache[key]
 
     def _spec_fn(self, B: int, wp: int, capped: bool, greedy_any: bool = True):
@@ -2332,6 +2345,7 @@ class DecodeEngine:
                 return cache, out_state, rng, packed
 
             self._fn_cache[key] = jax.jit(spec, donate_argnames=("cache", "state"))
+            return FirstCall(self._fn_cache[key], key)
         return self._fn_cache[key]
 
     def _update_fn(self, n: int):
@@ -2368,6 +2382,7 @@ class DecodeEngine:
                 return state
 
             self._fn_cache[key] = jax.jit(apply, donate_argnames=("state",))
+            return FirstCall(self._fn_cache[key], key)
         return self._fn_cache[key]
 
     # -- decode loop ------------------------------------------------------
@@ -2885,8 +2900,6 @@ class DecodeEngine:
                 )
             )
         if copy_dst:
-            from areal_tpu.inference import paged_kv
-
             n = 1
             while n < len(copy_dst):
                 n *= 2
@@ -2896,13 +2909,8 @@ class DecodeEngine:
                 jnp.asarray(np.asarray(x + x[:1] * pad, np.int32))
                 for x in (copy_dst, copy_src, slot_dst, slot_src)
             ]
-            key = ("pagecopy", n)
-            if key not in self._fn_cache:
-                self._fn_cache[key] = jax.jit(
-                    paged_kv.copy_pages, donate_argnames=("cache",)
-                )
             with set_mesh(self.mesh):
-                self.cache = self._fn_cache[key](self.cache, *pairs_np)
+                self.cache = self._pagecopy_fn(n)(self.cache, *pairs_np)
             if self.model_cfg.has_recurrent_state:
                 self._obs.state_copies.inc(len(copy_dst))
         self.stats["prefix_shared"] = self.stats.get("prefix_shared", 0) + len(
@@ -3239,6 +3247,19 @@ class DecodeEngine:
         if clamp_rows:
             self._apply_remaining_clamp(clamp_rows)
 
+    def _pagecopy_fn(self, n: int):
+        """Jitted copy of n (page, slot-state) pairs: the pages and the
+        recurrent state a GRPO group's siblings share with their primary."""
+        from areal_tpu.inference import paged_kv
+
+        key = ("pagecopy", n)
+        if key not in self._fn_cache:
+            self._fn_cache[key] = jax.jit(
+                paged_kv.copy_pages, donate_argnames=("cache",)
+            )
+            return FirstCall(self._fn_cache[key], key)
+        return self._fn_cache[key]
+
     def _clamp_fn(self, n: int):
         """Jitted remaining-only scatter: remaining := min(remaining, cap)
         for n (slot, cap) rows, touching nothing else (pos/ids stay
@@ -3266,6 +3287,7 @@ class DecodeEngine:
                 return state
 
             self._fn_cache[key] = jax.jit(clamp, donate_argnames=("state",))
+            return FirstCall(self._fn_cache[key], key)
         return self._fn_cache[key]
 
     def _apply_remaining_clamp(self, rows: list[tuple[int, int]]) -> None:
@@ -3763,7 +3785,8 @@ class DecodeEngine:
             tokens = self._drain(pending)
             pending = dispatched
             worked = dispatched is not None
-        if drained or worked or rows:  # a bare poll says nothing of a pass's host work
+        self._pass_productive = bool(drained or worked or rows)
+        if self._pass_productive:  # a bare poll says nothing of a pass's host work
             self._pacer.host_work(self._pace_clock() - t_pass - held - self._pull_s)
         if span is not None:
             span.set(
@@ -3774,7 +3797,7 @@ class DecodeEngine:
         if step_tl is not None:
             # a pass that drained, dispatched, or admitted is a real
             # step; a bare poll (no slots, empty queue) is not
-            if drained or worked or rows:
+            if self._pass_productive:
                 self._ktl = None
                 self.kprobe.complete_step(step_tl, tokens=tokens)
             else:
@@ -3897,12 +3920,15 @@ class DecodeEngine:
                 # before it runs, so it leaves no span and no step record
                 self._abandon_kstep()
                 step_tl = None
+            self._pass_productive = False
             with (
-                perf_tracer.trace_scope("areal.decode.pass")
+                perf_tracer.trace_scope("areal.decode.pass", cpu=True)
                 if step_tl is not None
                 else contextlib.nullcontext()
             ) as span:
                 pending, idle = self._run_pass(pending, step_tl, span)
+            if self._pass_productive and span is not None:
+                self._pass_watch.observe(span)
             if idle:
                 self._wakeup.wait(timeout=0.05)
                 self._wakeup.clear()

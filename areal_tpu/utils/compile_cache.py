@@ -20,10 +20,30 @@ backend compilation into ``areal_xla_compiles_total`` /
 storms — a drifting jit shape key recompiling the train program every
 step — show up as a climbing counter on the trainer dashboard instead of
 as mystery wall time (docs/observability.md "Trainer observatory").
+
+The same listener writes what it hears into the span record
+(``utils/perf_tracer.py``; docs/observability.md "Spans and scopes"), each
+duration event as a span that has just ended on the calling thread:
+
+    areal.xla.trace       a function traced to a jaxpr
+    areal.xla.lower       the jaxpr lowered to an MLIR module
+    areal.xla.compile     XLA's backend compile OR, on a hit, the read of the
+                          persistent cache in its place (jax 0.9.0 times
+                          ``compile_or_get_cached`` as a whole)
+    areal.xla.cache_load  the persistent cache's read, on a hit only, and
+                          then inside the ``areal.xla.compile`` it served
+
+A program's first call fires trace, lower and compile whether or not the
+persistent cache serves it; a later call of the same shapes fires none.
+``FirstCall`` puts that first call inside the span ``areal.program.build``,
+named by the program and its shape key, so the four nest under it by time.
 """
 
 import os
 import threading
+import time
+
+from areal_tpu.utils import perf_tracer
 
 _DEFAULT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
@@ -34,6 +54,13 @@ _DEFAULT_DIR = os.path.join(
 # point event per persistent-cache hit
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+# every duration event that becomes a span of the record
+_SPAN_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "areal.xla.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "areal.xla.lower",
+    _COMPILE_EVENT: "areal.xla.compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "areal.xla.cache_load",
+}
 
 _stats_lock = threading.Lock()
 _COMPILE_STATS = {"compiles": 0, "compile_seconds": 0.0, "cache_hits": 0}
@@ -80,7 +107,15 @@ def install_compile_counters() -> bool:
 
     obs = obs_catalog.train_obs_metrics()
 
-    def _on_duration(event: str, duration: float, **_kw) -> None:
+    def _on_duration(event: str, duration: float, **kw) -> None:
+        name = _SPAN_OF_EVENT.get(event)
+        if name is None:
+            return
+        end = time.monotonic_ns()
+        fun = kw.get("fun_name")
+        perf_tracer.get_tracer().add_span(
+            name, end - int(duration * 1e9), end, perf_tracer.Category.INSTR, {"fun": fun} if fun else None
+        )
         if event != _COMPILE_EVENT:
             return
         with _stats_lock:
@@ -103,6 +138,31 @@ def install_compile_counters() -> bool:
         return False
     _INSTALLED = True
     return True
+
+
+class FirstCall:
+    """A jitted program (``fn``, just cached under ``key``, its kind first) as
+    its builder hands it to the caller that saw the miss: the call through it
+    is the program's first, the one that traces, lowers and compiles or loads
+    it, and runs inside the span ``areal.program.build``. Inside a measured
+    window that span says which program was built, by name. Everything else
+    (``lower`` for an ahead-of-time compile) is the jitted function's own;
+    the cache keeps the plain jitted function."""
+
+    __slots__ = ("_fn", "_key", "__weakref__")  # jax keys its caches by weak references to callables
+
+    def __init__(self, fn, key: tuple):
+        self._fn, self._key = fn, key
+
+    def __call__(self, *args, **kwargs):
+        key = self._key
+        with perf_tracer.trace_scope(
+            "areal.program.build", perf_tracer.Category.INSTR, {"program": str(key[0]), "key": repr(key[1:])}
+        ):
+            return self._fn(*args, **kwargs)
+
+    def __getattr__(self, name):
+        return getattr(self._fn, name)
 
 
 def compile_stats() -> dict:

@@ -10,34 +10,43 @@ right task/session (reference :28-38).
 "Spans and scopes"): every span also enters a ``jax.profiler.TraceAnnotation``
 (a TraceMe: a flag check while no profiler session runs), so a device profile
 (``start_device_profile`` / ``POST /debug/profile``) carries the program's
-spans on the same clock as the device's ops. The Chrome event is written only
-while the ``PerfTracer`` is enabled. A process that has not imported jax emits
-no annotation and is not made to import it.
+spans on the same clock as the device's ops. A process that has not imported
+jax emits no annotation and is not made to import it.
+
+Every span and instant also lands in the tracer's bounded in-memory RECORD
+(one tuple appended to a ``deque(maxlen=max_events)``), for the whole life of
+the process and whether or not a profiler session runs: set-up, and every
+pass and step of a run, can be read after the fact (``record()``; the chip
+benchmark's ``span_record`` reader; ``SlowSpanWatch``'s WARNING line).
+``PerfTracerConfig.enabled`` decides only whether ``save()`` writes the
+record out as a Chrome trace.
 
 Surface:
-    configure(cfg, rank=..., role=...)      process-level setup
+    configure(cfg, rank=..., role=...)      process-level setup (keeps the record)
     trace_scope(name, category=..., args=)  sync context manager (``Span``)
     atrace_scope(name, ...)                 async context manager
     instant(name, ...)                      point event
-    counter(name, **values)                 counter track
-    trace_perf(name, category=...)          decorator
+    record()                                snapshot of the record (``SpanRecord``)
+    SlowSpanWatch(name)                     WARNING line for a span over 3 x its median
     save(step=..., force=...)               periodic/final flush
-    SessionTracer / trace_session("phase")  rollout lifecycle records
+    SessionTracer                           rollout lifecycle records
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
-import functools
+import gc
 import json
 import os
+import statistics
 import sys
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field
 from enum import Enum
-from typing import Any
+from typing import Any, NamedTuple
 
 from areal_tpu.api.config import PerfTracerConfig
 from areal_tpu.utils import logging as alog
@@ -83,76 +92,126 @@ def clear_task_context() -> None:
     _session_id_var.set(None)
 
 
-class PerfTracer:
-    """Catapult JSON event collector for one process."""
+def _process_start_ns() -> int:
+    """The process's start on ``time.monotonic_ns()``'s clock: its
+    ``starttime`` in ``/proc/self/stat`` (clock ticks after boot, so the
+    interpreter's own start and the imports before the first span count),
+    carried over by the boot-time clock. Where that cannot be read, now."""
+    now = time.monotonic_ns()
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = time.clock_gettime_ns(time.CLOCK_BOOTTIME) - ticks * 1_000_000_000 // os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError, AttributeError):
+        return now
+    return now - age if 0 <= age <= now else now
 
-    def __init__(self, config: PerfTracerConfig, rank: int = 0, role: str | None = None):
+
+PROCESS_START_NS = _process_start_ns()
+
+
+class RecordEntry(NamedTuple):
+    """One entry of the record. Times are ``time.monotonic_ns()``; an
+    instant (``ph`` "i") has ``end_ns == start_ns``; ``thread`` is
+    ``threading.get_ident()`` of the thread that ended it."""
+
+    name: str
+    start_ns: int
+    end_ns: int
+    thread: int
+    args: dict | None
+    category: Any
+    ph: str
+
+
+@dataclass
+class SpanRecord:
+    """A snapshot of a tracer's record (``PerfTracer.record()``)."""
+
+    process_start_ns: int
+    entries: list[RecordEntry]  # in the order they ended
+    threads: dict[int, str]  # names of the threads alive at the snapshot
+
+
+class PerfTracer:
+    """One process's span record, and its Chrome-trace (catapult JSON) writer."""
+
+    def __init__(self, config: PerfTracerConfig, rank: int = 0, role: str | None = None, events: deque | None = None):
         self.config = config
         self.enabled = config.enabled
         self.rank = rank
         self.role = role
-        self._events: list[dict[str, Any]] = []
-        self._lock = threading.Lock()
+        # the record: RecordEntry-shaped plain tuples, appended without a lock
+        # (deque.append is atomic) and bounded: the newest max_events stay
+        cap = config.max_events or None
+        self._events: deque[tuple] = events if events is not None and events.maxlen == cap else deque(events or (), maxlen=cap)
         self._pid = os.getpid()
         self._last_save_step = -1
 
     # -- event emission ----------------------------------------------------
-    def _ts_us(self) -> float:
-        return time.perf_counter_ns() / 1e3
-
-    def _base(self, name: str, ph: str, category) -> dict[str, Any]:
-        cat = category.value if isinstance(category, Category) else (category or "instr")
-        return {
-            "name": name,
-            "ph": ph,
-            "pid": self._pid,
-            "tid": threading.get_ident() % 2**31,
-            "ts": self._ts_us(),
-            "cat": cat,
-        }
-
-    def _push(self, ev: dict[str, Any]) -> None:
-        with self._lock:
-            self._events.append(ev)
-            # bound memory on long runs: keep the newest max_events
-            cap = getattr(self.config, "max_events", 200_000)
-            if cap and len(self._events) > cap:
-                del self._events[: len(self._events) - cap]
-
-    def trace_scope(self, name: str, category=Category.COMPUTE, args: dict | None = None) -> "Span":
-        return Span(self, name, category, args)
+    def trace_scope(self, name: str, category=Category.COMPUTE, args: dict | None = None, cpu: bool = False) -> "Span":
+        return Span(self, name, category, args, cpu=cpu)
 
     @contextlib.asynccontextmanager
     async def atrace_scope(self, name: str, category=Category.COMPUTE, args: dict | None = None):
-        # Chrome event only: a coroutine's span stays open across awaits and
+        # no TraceMe: a coroutine's span stays open across awaits and
         # overlaps its siblings on the loop's thread, which is not what a
         # TraceMe (one thread's nested activity) records
         with Span(self, name, category, args, annotate=False):
             yield
 
     def instant(self, name: str, category=Category.INSTR, args: dict | None = None) -> None:
-        """Point event: a zero-length TraceMe, and a Chrome "i" event while
-        the tracer is enabled."""
+        """Point event: a zero-length TraceMe and a record entry."""
         ann = _annotation(name, args)
         if ann is not None:
             ann.__enter__()
             ann.__exit__(None, None, None)
-        if not self.enabled:
-            return
-        ev = self._base(name, "i", category)
-        ev["s"] = "t"
-        if args:
-            ev["args"] = args
-        self._push(ev)
+        t = time.monotonic_ns()
+        self._events.append((name, t, t, threading.get_ident(), args, category, "i"))
 
-    def counter(self, name: str, **values: float) -> None:
-        if not self.enabled:
-            return
-        ev = self._base(name, "C", Category.INSTR)
-        ev["args"] = values
-        self._push(ev)
+    def add_span(self, name: str, start_ns: int, end_ns: int, category=Category.COMPUTE, args: dict | None = None) -> None:
+        """A span that has already ended, timed by someone else (the jax
+        monitoring listener, ``utils/compile_cache.py``), on the calling
+        thread. Record only: its TraceMe's time has passed."""
+        self._events.append((name, start_ns, end_ns, threading.get_ident(), args, category, "X"))
+
+    def _raw(self) -> list[tuple]:
+        while True:
+            try:
+                return list(self._events)
+            except RuntimeError:  # another thread appended during the copy
+                continue
+
+    def record(self) -> SpanRecord:
+        return SpanRecord(
+            PROCESS_START_NS,
+            [RecordEntry(*e) for e in self._raw()],
+            {t.ident: t.name for t in threading.enumerate()},
+        )
 
     # -- persistence -------------------------------------------------------
+    def chrome_events(self) -> list[dict[str, Any]]:
+        """The record as Chrome trace events ("X" and "i"), built here and
+        not on the hot path."""
+        out = []
+        for name, t0, t1, tid, args, category, ph in self._raw():
+            ev = {
+                "name": name,
+                "ph": ph,
+                "pid": self._pid,
+                "tid": tid % 2**31,
+                "ts": t0 / 1e3,
+                "cat": category.value if isinstance(category, Category) else (category or "instr"),
+            }
+            if ph == "i":
+                ev["s"] = "t"
+            else:
+                ev["dur"] = (t1 - t0) / 1e3
+            if args:
+                ev["args"] = args
+            out.append(ev)
+        return out
+
     def _path(self) -> str:
         out = self.config.output_dir or "/tmp/areal_tpu/traces"
         os.makedirs(out, exist_ok=True)
@@ -166,14 +225,11 @@ class PerfTracer:
             if step - self._last_save_step < max(1, self.config.save_freq_steps):
                 return
             self._last_save_step = step
-        with self._lock:
-            events = list(self._events)
         with open(self._path(), "w") as f:
-            json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
+            json.dump({"traceEvents": self.chrome_events(), "displayTimeUnit": "ms"}, f)
 
     def clear(self) -> None:
-        with self._lock:
-            self._events.clear()
+        self._events.clear()
 
 
 def _annotation(name: str, args: dict | None):
@@ -201,13 +257,15 @@ class Span:
     context's ``x-areal-trace`` ids as the shared identifier.
 
     Entering it always enters a TraceMe (``_annotation``), so the span lands
-    in a running profiler session on the device trace's clock; the Chrome
-    "X" event is written only while the tracer is enabled. ``set`` adds args
-    that are known only at the end (a pass's credited tokens)."""
+    in a running profiler session on the device trace's clock; leaving it
+    always appends one tuple to the tracer's record. ``set`` adds args that
+    are known only at the end (a pass's credited tokens). ``cpu`` adds the
+    arg ``cpu_us``, the calling thread's CPU time over the span: a span that
+    waited can then be told from one that worked."""
 
-    __slots__ = ("_tracer", "_ann", "_t0", "name", "category", "args")
+    __slots__ = ("_tracer", "_ann", "_cpu0", "name", "category", "args", "start_ns", "end_ns")
 
-    def __init__(self, tracer: PerfTracer, name: str, category=Category.COMPUTE, args: dict | None = None, annotate: bool = True):
+    def __init__(self, tracer: PerfTracer, name: str, category=Category.COMPUTE, args: dict | None = None, annotate: bool = True, cpu: bool = False):
         self._tracer = tracer
         self.name = name
         self.category = category
@@ -222,7 +280,8 @@ class Span:
                 args["session_id"] = session
         self.args = args
         self._ann = _annotation(name, args) if annotate else None
-        self._t0: float | None = None
+        self._cpu0: int | None = 0 if cpu else None
+        self.start_ns = self.end_ns = 0
 
     def set(self, **args: Any) -> None:
         self.args = {**(self.args or {}), **args}
@@ -230,23 +289,116 @@ class Span:
             self._ann.set_metadata(**_stats(args))
 
     def __enter__(self) -> "Span":
+        # the CPU clock is read outside the TraceMe and the record's times, so
+        # that both hold the same span to within a microsecond or two
+        if self._cpu0 is not None:
+            self._cpu0 = time.thread_time_ns()
         if self._ann is not None:
             self._ann.__enter__()
-        if self._tracer.enabled:
-            self._t0 = self._tracer._ts_us()
+        self.start_ns = time.monotonic_ns()
         return self
 
     def __exit__(self, *exc) -> None:
+        self.end_ns = time.monotonic_ns()
+        if self._cpu0 is not None:
+            self.set(cpu_us=(time.thread_time_ns() - self._cpu0) // 1000)
         if self._ann is not None:
             self._ann.__exit__(*exc)
-        if self._t0 is not None:
-            tr = self._tracer
-            ev = tr._base(self.name, "X", self.category)
-            ev["ts"] = self._t0
-            ev["dur"] = tr._ts_us() - self._t0
-            if self.args:
-                ev["args"] = self.args
-            tr._push(ev)
+        self._tracer._events.append(
+            (self.name, self.start_ns, self.end_ns, threading.get_ident(), self.args, self.category, "X")
+        )
+
+
+_gc_span: Span | None = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks``: a generation-2 collection is the span ``areal.gc`` on
+    the thread it ran on (it holds every thread up); nothing for the young
+    generations, which take microseconds and run thousands of times."""
+    global _gc_span
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        _gc_span = Span(_TRACER, "areal.gc", Category.INSTR).__enter__()
+    elif _gc_span is not None:
+        span, _gc_span = _gc_span, None
+        span.set(collected=info["collected"], uncollectable=info["uncollectable"])
+        span.__exit__(None, None, None)
+
+
+class SlowSpanWatch:
+    """The program's own reader of the record, for an operator: told every
+    span of one name as it ends, it writes ONE WARNING line for a span that
+    took over ``factor`` x the median of the last ``window`` before it, with
+    what the record holds of that time: the span's args (``cpu_us``: did it
+    work or wait), its children's self times, and every entry of any thread
+    that overlaps it (a collection, a program built, request events, a weight
+    update). No counter and no gauge: the line is the whole of it."""
+
+    def __init__(self, name: str, window: int = 64, factor: float = 3.0, min_samples: int = 8, floor_ms: float = 50.0):
+        self.name = name
+        self.factor = factor
+        self.min_samples = min_samples
+        # a span under the floor is never reported: at toy sizes a pass of
+        # 9 ms among passes of 3 is the host's noise and nobody's stall
+        self.floor_ns = int(floor_ms * 1e6)
+        self._durs: deque[int] = deque(maxlen=window)
+
+    def observe(self, span: Span) -> str | None:
+        """The warning line where ``span`` (ended) was slow, else None."""
+        dur = span.end_ns - span.start_ns
+        durs = self._durs
+        slow = None
+        if len(durs) >= self.min_samples:
+            med = statistics.median(durs)
+            if dur > self.factor * med and dur >= self.floor_ns:
+                slow = self._line(span, dur, med)
+                logger.warning(slow)
+        durs.append(dur)
+        return slow
+
+    def _line(self, span: Span, dur: int, med: float) -> str:
+        t0, t1, me = span.start_ns, span.end_ns, threading.get_ident()
+        inside, beside = [], []
+        for e in reversed(span._tracer._raw()):
+            if e[2] < t0:  # entries lie in the order they ended
+                break
+            if e[1] > t1 or (e[0] == span.name and e[1] == t0 and e[3] == me):
+                continue
+            (inside if e[3] == me and e[1] >= t0 else beside).append(e)
+        ms = lambda ns: f"{ns / 1e6:.3f}"  # noqa: E731
+        phases = ", ".join(f"{k.removeprefix('areal.')} {ms(v)}" for k, v in sorted(self_times(inside).items(), key=lambda kv: -kv[1]))
+        by: dict[str, list] = {}
+        for e in beside:
+            by.setdefault(e[0], []).append(e)
+        others = []
+        for name, es in sorted(by.items(), key=lambda kv: -max(e[2] - e[1] for e in kv[1])):
+            if len(es) > 3:  # the requests in flight: a count and the longest
+                others.append(f"{name} x {len(es)} (longest {ms(max(e[2] - e[1] for e in es))} ms)")
+            else:
+                others += [f"{name} {ms(e[2] - e[1])} ms at {(e[1] - t0) / 1e6:+.3f} (thread {e[3]}) {e[4] or ''}".rstrip() for e in es]
+        return (
+            f"slow {self.name}: {ms(dur)} ms, {dur / med:.1f} x the median {ms(med)} ms of the last {len(self._durs)}; "
+            f"{span.args or {}}; self ms by phase: {phases or 'no child span'}; "
+            f"{len(beside)} overlapping entries of other spans and threads: {'; '.join(others[:16]) or 'none'}"
+        )
+
+
+def self_times(entries) -> dict[str, int]:
+    """ns by name of one thread's nested spans, each less what its children
+    cover (``entries``: record tuples or ``RecordEntry``s of ONE thread)."""
+    out: dict[str, int] = {}
+    stack: list[tuple[str, int]] = []  # (name, end) of the spans open at the sweep's instant
+    for e in sorted((e for e in entries if e[6] == "X"), key=lambda e: (e[1], -e[2])):
+        while stack and stack[-1][1] <= e[1]:
+            stack.pop()
+        dur = e[2] - e[1]
+        out[e[0]] = out.get(e[0], 0) + dur
+        if stack:
+            out[stack[-1][0]] -= dur
+        stack.append((e[0], e[2]))
+    return out
 
 
 @dataclass
@@ -341,9 +493,14 @@ _TRACER = PerfTracer(PerfTracerConfig(enabled=False))
 _SESSIONS = SessionTracer(enabled=False)
 
 
+gc.callbacks.append(_on_gc)
+
+
 def configure(config: PerfTracerConfig, rank: int = 0, role: str | None = None) -> None:
     global _TRACER, _SESSIONS
-    _TRACER = PerfTracer(config, rank=rank, role=role)
+    # the new tracer takes the record over: what ran before the trainer
+    # configured its tracer (imports, engine set-up) stays readable
+    _TRACER = PerfTracer(config, rank=rank, role=role, events=_TRACER._events)
     # session tracing follows its own sub-config when given (reference
     # SessionTracerConfig), else the perf tracer's enabled flag with
     # per-record writes (the pre-knob behavior)
@@ -447,8 +604,8 @@ def get_session_tracer() -> SessionTracer:
     return _SESSIONS
 
 
-def trace_scope(name: str, category=Category.COMPUTE, args: dict | None = None):
-    return _TRACER.trace_scope(name, category, args)
+def trace_scope(name: str, category=Category.COMPUTE, args: dict | None = None, cpu: bool = False):
+    return Span(_TRACER, name, category, args, cpu=cpu)
 
 
 def atrace_scope(name: str, category=Category.COMPUTE, args: dict | None = None):
@@ -459,66 +616,9 @@ def instant(name: str, category=Category.INSTR, args: dict | None = None) -> Non
     _TRACER.instant(name, category, args)
 
 
-def counter(name: str, **values: float) -> None:
-    _TRACER.counter(name, **values)
-
-
 def save(step: int | None = None, force: bool = False) -> None:
     _TRACER.save(step=step, force=force)
     _SESSIONS.flush()  # buffered session records ride the same cadence
-
-
-def trace_perf(name: str, category=Category.COMPUTE):
-    """Decorator tracing every call of a function (sync or async)."""
-
-    def deco(fn):
-        if _is_coroutine_fn(fn):
-
-            @functools.wraps(fn)
-            async def awrapper(*a, **kw):
-                with _TRACER.trace_scope(name, category):
-                    return await fn(*a, **kw)
-
-            return awrapper
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            with _TRACER.trace_scope(name, category):
-                return fn(*a, **kw)
-
-        return wrapper
-
-    return deco
-
-
-def trace_session(phase_name: str):
-    """Decorator recording a session phase (reference @trace_session use in
-    workflow/rlvr.py:77,124)."""
-
-    def deco(fn):
-        if _is_coroutine_fn(fn):
-
-            @functools.wraps(fn)
-            async def awrapper(*a, **kw):
-                with _SESSIONS.phase(phase_name):
-                    return await fn(*a, **kw)
-
-            return awrapper
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            with _SESSIONS.phase(phase_name):
-                return fn(*a, **kw)
-
-        return wrapper
-
-    return deco
-
-
-def _is_coroutine_fn(fn) -> bool:
-    import asyncio
-
-    return asyncio.iscoroutinefunction(fn)
 
 
 def merge_traces(paths: list[str], out_path: str) -> None:

@@ -14,3 +14,16 @@ if "xla_force_host_platform_device_count" not in flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _no_trace_context_from_the_test_before():
+    """``SessionTracer.start_session`` and ``set_task_context`` set context
+    variables that outlive a test on a worker's main thread, and every span
+    carries them in its args: start each test without."""
+    from areal_tpu.utils import perf_tracer
+
+    perf_tracer.clear_task_context()

@@ -132,7 +132,7 @@ def test_decode_programs_are_built_with_jax_jit_and_the_probe_knows_no_peak():
                     jitted[node.name] = ast.unparse(call.args[0])
     assert jitted == {
         "_prefill_fn": "prefill", "_prefill_paged_fn": "prefill", "_chunk_fn": "chunk", "_spec_fn": "spec",
-        "_update_fn": "apply", "_clamp_fn": "clamp",
+        "_update_fn": "apply", "_clamp_fn": "clamp", "_pagecopy_fn": "paged_kv.copy_pages",
     }
     assert inspect.signature(kernel_probe.KernelProbe.complete_step).parameters.keys() == {"self", "tl", "tokens"}
 
