@@ -875,6 +875,28 @@ def _window_after(padded, n_state, K: int):
     return jnp.einsum("akt,atc->akc", pick, padded, preferred_element_type=jnp.float32).reshape(A, -1)
 
 
+def _conv_window_step(conv, raw, w, bias, active):
+    """One decode step of a depthwise conv over a slot's window, in one pass
+    over the row as the state stores it. ``conv`` [S, (K-1) * C] (a slot's
+    last K-1 conv inputs, oldest first, in the state's type), ``raw`` [S, C]
+    (this token's input, rounded here to the window's type: both forms
+    convolve the values a slot's window holds), ``w`` [K, C] and ``bias``
+    [C] or None, float32. Returns (acc [S, C] float32, accumulated in the
+    prefill forms' order, and the window shifted by one token; rows that are
+    not ``active`` keep theirs bit for bit).
+
+    The taps are lane slices of the row, never a [S, K, C] array: a second-
+    minor dimension of K is padded to the chip's tile and every element
+    moves, twice a layer (tests/test_tpu_compile.py holds the compiled text
+    to that)."""
+    C = raw.shape[-1]
+    taps = [conv[:, k * C : (k + 1) * C] for k in range(w.shape[0] - 1)] + [raw.astype(conv.dtype)]
+    acc = 0.0 if bias is None else bias
+    for k, tap in enumerate(taps):
+        acc = acc + tap.astype(jnp.float32) * w[k]
+    return acc, jnp.where(active[:, None], jnp.concatenate(taps[1:], axis=-1), conv)
+
+
 def _conv_taps(layer: dict):
     return layer["conv_w"][:, 0, :].astype(jnp.float32), layer["conv_b"].astype(jnp.float32)
 
@@ -905,15 +927,12 @@ def mamba_decode(cfg: HybridConfig, layer: dict, h, state: dict, j, active, live
     only and in place; without it ``ssm_decode_step`` passes over all slots
     under a mask (off a TPU, and the form the tests hold the kernel to)."""
     S = h.shape[0]
-    K = cfg.mamba_d_conv
     conv = jax.lax.dynamic_index_in_dim(state["conv"], j, 0, keepdims=False)
     with jax.named_scope("ssm_proj"):
         z, raw, dt_raw = _mamba_in(cfg, layer, h)
     with jax.named_scope("ssm_conv"):
-        window = jnp.concatenate([conv.reshape(S, K - 1, cfg.conv_dim), raw[:, None, :].astype(conv.dtype)], axis=1)
-        w, bias = _conv_taps(layer)
-        xbc = jax.nn.silu(jnp.sum(window.astype(jnp.float32) * w[None], axis=1) + bias)
-        new_conv = jnp.where(active[:, None], window[:, 1:].reshape(S, -1), conv)
+        acc, new_conv = _conv_window_step(conv, raw, *_conv_taps(layer), active)
+        xbc = jax.nn.silu(acc)
     with jax.named_scope("ssm_state"):
         if live is None:
             ssm = jax.lax.dynamic_index_in_dim(state["ssm"], j, 0, keepdims=False)
@@ -976,15 +995,11 @@ def conv_decode(cfg: HybridConfig, layer: dict, h, conv_all, j, active):
     ``g``, oldest first), of which this is layer ``j``. Returns (out [S, D],
     the windows with layer j advanced); rows that are not ``active`` keep
     theirs bit for bit."""
-    S, D = h.shape
-    K = cfg.conv_L_cache
     conv = jax.lax.dynamic_index_in_dim(conv_all, j, 0, keepdims=False)
     g, c = _conv_gates(cfg, layer, h, conv.dtype)
     with jax.named_scope("conv_mix"):
-        window = jnp.concatenate([conv.reshape(S, K - 1, D), g[:, None, :]], axis=1)
-        w = layer["conv_w"][:, 0, :].astype(jnp.float32)
-        y = (c.astype(jnp.float32) * jnp.sum(window.astype(jnp.float32) * w[None], axis=1)).astype(h.dtype)
-        new_conv = jnp.where(active[:, None], window[:, 1:].reshape(S, -1), conv)
+        acc, new_conv = _conv_window_step(conv, g, layer["conv_w"][:, 0, :].astype(jnp.float32), None, active)
+        y = (c.astype(jnp.float32) * acc).astype(h.dtype)
     with jax.named_scope("state_write"):
         conv_all = jax.lax.dynamic_update_index_in_dim(conv_all, new_conv, j, 0)
     with jax.named_scope("conv_proj"):
@@ -1168,15 +1183,12 @@ def gdn_decode(cfg: HybridConfig, layer: dict, h, state: dict, j, active, live=N
     hold the kernel to)."""
     from areal_tpu.ops import gdn_state_update as gsu
 
-    S = h.shape[0]
-    taps, p = cfg.gdn_d_conv, cfg.gdn_head_pack
+    p = cfg.gdn_head_pack
     conv = jax.lax.dynamic_index_in_dim(state["conv"], j, 0, keepdims=False)
     raw, z, g, beta = _gdn_in(cfg, layer, h)
     with jax.named_scope("gdn_conv"):
-        window = jnp.concatenate([conv.reshape(S, taps - 1, -1), raw[:, None, :].astype(conv.dtype)], axis=1)
-        qkv = jax.nn.silu(jnp.sum(window.astype(jnp.float32) * _gdn_taps(layer)[None], axis=1))
-        new_conv = jnp.where(active[:, None], window[:, 1:].reshape(S, -1), conv)
-        q, k, v = _gdn_heads(cfg, qkv)
+        acc, new_conv = _conv_window_step(conv, raw, _gdn_taps(layer), None, active)
+        q, k, v = _gdn_heads(cfg, jax.nn.silu(acc))
     with jax.named_scope("gdn_state"):
         if live is None:
             old = jax.lax.dynamic_index_in_dim(state["gdn"], j, 0, keepdims=False)

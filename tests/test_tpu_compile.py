@@ -16,6 +16,7 @@ layers (4 s): tier-1 has no room for an engine's programs.
 
 import functools
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -466,6 +467,58 @@ def test_lfm2_prefill_compiles_for_v5e(chip, monkeypatch):
     i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
     compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(4, 1024), i32(4), i32(4 * 1024 // PSZ), i32(4)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3  # the three grouped matmuls of the expert layer
+
+
+def _window_relayouts(text, taps):
+    """The compiled ops that lay a bfloat16 window out again: a ``reshape``
+    or ``copy`` whose result has the tap count (or the window's K-1) among
+    its dimensions."""
+    found = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = bf16\[([\d,]+)\]\S* (reshape|copy)\(", ln)
+        if m and {taps, taps - 1} & {int(d) for d in m.group(1).split(",")}:
+            found.append(ln.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("layers,channels", [(36, 4352), (12, 11520)], ids=["granite", "olmo"])
+def test_conv_window_step_stays_in_the_rows_tiling(chip, layers, channels):
+    """A decode step's conv window under a scan over layers at the shapes
+    of ``rollout-granite-h-micro-grpo`` and ``rollout-olmo-hybrid-7b-d16-grpo``
+    (64 slots x 3 taps x 4,352 and 11,520 channels, bfloat16): the row stays
+    ``[64, 3 * C]`` in the tiling the state is stored in. A window shaped
+    ``[slots, taps, channels]`` puts 3 into the second-minor dimension, which
+    the chip pads to its tile: a reshape, a copy of the new token and a
+    reshape back a layer (PR 35: 1.0 ms of a 15.7 ms step); the idiom as it
+    was, written out here, must trip the same check."""
+    from areal_tpu.models import hybrid
+
+    slots, taps = 64, 4
+
+    def as_it_was(conv, raw, w, bias, active):
+        window = jnp.concatenate([conv.reshape(slots, taps - 1, channels), raw[:, None, :].astype(conv.dtype)], axis=1)
+        acc = jnp.sum(window.astype(jnp.float32) * w[None], axis=1) + bias
+        return acc, jnp.where(active[:, None], window[:, 1:].reshape(slots, -1), conv)
+
+    def program(step):
+        def fn(conv_all, raw_all, w_all, bias_all, active):
+            def body(carry, i):
+                conv_all, total = carry
+                conv = jax.lax.dynamic_index_in_dim(conv_all, i, 0, keepdims=False)
+                acc, new = step(conv, raw_all[i], w_all[i], bias_all[i], active)
+                conv_all = jax.lax.dynamic_update_index_in_dim(conv_all, new, i, 0)
+                return (conv_all, total + jax.nn.silu(acc).astype(jnp.bfloat16)), None
+
+            return jax.lax.scan(body, (conv_all, jnp.zeros(raw_all.shape[1:], jnp.bfloat16)), jnp.arange(layers))[0]
+
+        args = (
+            chip((layers, slots, (taps - 1) * channels), jnp.bfloat16), chip((layers, slots, channels), jnp.bfloat16),
+            chip((layers, taps, channels), jnp.float32), chip((layers, channels), jnp.float32), chip((slots,), jnp.bool_),
+        )
+        return jax.jit(fn, donate_argnums=(0,)).lower(*args).compile().as_text()
+
+    assert not _window_relayouts(program(hybrid._conv_window_step), taps)
+    assert _window_relayouts(program(as_it_was), taps)
 
 
 def _olmo(chip, monkeypatch):
