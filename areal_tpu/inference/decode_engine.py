@@ -398,7 +398,7 @@ class DecodeEngine:
             self.model_cfg = models.config_from_hf_path(cfg.model_path)
             self.model = models.family_of(self.model_cfg)
             self.model_cfg = self.model.serving_config(self.model_cfg, cfg.dtype)
-            self._check_recurrent_config()
+            self._check_model_limits()
             self.param_shardings = mesh_lib.param_sharding(
                 self.mesh, self.model.param_partition_specs(self.model_cfg)
             )
@@ -432,7 +432,7 @@ class DecodeEngine:
         else:
             assert self.model_cfg is not None
             self.model = models.family_of(self.model_cfg)
-            self._check_recurrent_config()
+            self._check_model_limits()
             self.param_shardings = mesh_lib.param_sharding(
                 self.mesh, self.model.param_partition_specs(self.model_cfg)
             )
@@ -531,33 +531,30 @@ class DecodeEngine:
             f"mesh {dict(self.mesh.shape)}, attention {self.attention_impl()}"
         )
 
-    def _check_recurrent_config(self) -> None:
-        """What a model with recurrent (state-space, short-conv or delta-rule) layers cannot be served
-        with, refused when the engine is configured and not at the first
-        request that would need it: a recurrent state cannot be cut back to
-        a token boundary, so nothing may roll it back or hand out a prefix
-        of it (inference/paged_kv.py STATE_LEAVES)."""
-        if not self.model_cfg.has_recurrent_state:
-            return
+    def _model_limits(self) -> dict[str, str]:
+        """{feature: why} of what the model's module does not implement for
+        this model (``models/*.serving_limits``; none before the engine is
+        initialized), beside ``reason``, the word ``/statusz`` gives for it."""
+        return self.model.serving_limits(self.model_cfg) if self.model is not None else {}
+
+    def _check_model_limits(self) -> None:
+        """What the model's module does not implement, refused when the
+        engine is configured and not at the first request that would need
+        it. The list is the module's, by feature (a recurrent state cannot be
+        cut back to a token boundary, so nothing may roll it back or hand out
+        a prefix of it: inference/paged_kv.py STATE_LEAVES; latent pages have
+        no suffix kernel): nothing here asks what kind of model it is."""
+        limits = self._model_limits()
         cfg = self.config
         spec = getattr(cfg, "speculative", None)
-        if spec is not None and spec.enabled:
-            raise ValueError(
-                "speculative decoding cannot serve a model with recurrent "
-                "(state-space) layers: a rejected draft would have to roll "
-                "the slot's state back, and no state snapshot exists"
-            )
-        if cfg.quantization == "int8":
-            raise ValueError(
-                "int8 weight quantization is not implemented for the "
-                "hybrid family's mixers and experts; serve this model with "
-                "quantization='none'"
-            )
-        if int(np.prod(list(self.mesh.shape.values()))) != 1:
-            raise ValueError(
-                "a model with recurrent (state-space) layers serves on one "
-                "chip a replica: its mixer and state are not sharded"
-            )
+        if spec is not None and spec.enabled and "speculative" in limits:
+            raise ValueError(limits["speculative"])
+        if cfg.quantization == "int8" and "int8_weights" in limits:
+            raise ValueError(limits["int8_weights"])
+        if cfg.kv_quantization in ("int8", "fp8") and "int8_pages" in limits:
+            raise ValueError(limits["int8_pages"])
+        if int(np.prod(list(self.mesh.shape.values()))) != 1 and "sharded" in limits:
+            raise ValueError(limits["sharded"])
 
     def _place(self, path: str, arr) -> jax.Array:
         """THE placement policy for incoming weights. Base-named leaves cast
@@ -620,24 +617,26 @@ class DecodeEngine:
             else False
         )
         if cfg.kv_hbm_gb is not None:
+            # what a page row is, is the model configuration's to say
+            heads, lanes = mcfg.kv_pools["k"]
             n_pages = paged_kv.n_pages_for_budget(
                 int(cfg.kv_hbm_gb * (1 << 30)),
                 mcfg.num_kv_layers,
-                mcfg.num_kv_heads,
+                heads,
                 psz,
-                mcfg.kv_head_dim,
+                lanes,
                 jnp.dtype(mcfg.jax_dtype).itemsize,
                 quant=kv_quant,
+                pools=len(mcfg.kv_pools),
             )
         else:
             n_pages = S * self._maxp + 1  # +1: trash page 0
         self.pool = paged_kv.PagePool(n_pages)
         tp = self.mesh.shape["model"]
-        kv_spec = (
-            paged_kv.paged_cache_specs(quant=kv_quant)
-            if mcfg.num_kv_heads % max(tp, 1) == 0
-            else {k: P() for k in paged_kv.paged_cache_specs(quant=kv_quant)}
-        )
+        # the pools this model has (a latent model: no V pool)
+        kv_spec = paged_kv.paged_cache_specs(quant=kv_quant, pools=tuple(mcfg.kv_pools))
+        if mcfg.num_kv_heads % max(tp, 1):
+            kv_spec = dict.fromkeys(kv_spec, P())
         kv_spec.update({name: P() for name in mcfg.state_shapes(S)})
         # the Pallas paged kernels run single-device; under TP the engine
         # takes the gather+einsum path, which GSPMD shards over the KV-head
@@ -685,15 +684,14 @@ class DecodeEngine:
         load_shape = mcfg.moe_count_shapes.get("moe_load")
         self._moe_load = np.zeros(load_shape, np.int64) if load_shape else None
         pc = getattr(cfg, "prefix_cache", None)
-        if mcfg.has_recurrent_state:
-            # a page prefix says nothing of the recurrent state behind it
-            # (state snapshots at page boundaries: ROADMAP Reach A.7), so the
-            # radix cache neither matches nor inserts for such a model
+        no_prefix = self._model_limits().get("prefix_cache")
+        if no_prefix:
+            # what the model's module cannot serve a cached prefix of (a page
+            # prefix says nothing of a recurrent state behind it: ROADMAP
+            # Reach A.7; latent pages have no suffix prefill: A.5): the radix
+            # cache neither matches nor inserts for such a model
             if pc is not None and pc.enabled and cfg.enable_prefix_caching:
-                logger.info(
-                    "prefix cache off: the model has recurrent (state-space) "
-                    "layers and a cached page prefix carries no state"
-                )
+                logger.info(f"prefix cache off: {no_prefix}")
             self._radix = None
         elif pc is not None and pc.enabled and cfg.enable_prefix_caching:
             cap = pc.max_pages
@@ -1847,13 +1845,21 @@ class DecodeEngine:
             self._obs.moe_experts_touched.inc(int(counts["moe_touched"].sum()))
         if "gdn_updates" in counts:
             self._obs.gdn_state_updates.inc(int(counts["gdn_updates"].sum()))
+        if "latent_tokens_read" in counts:
+            self._obs.latent_tokens_read.inc(int(counts["latent_tokens_read"].sum()))
 
     def moe_status(self) -> dict | None:
         """/statusz ``moe``: ``load`` = rows of live slots every expert of
         every expert layer got from decode steps since the engine started,
-        [expert layers][experts]; None for a model without experts."""
+        [expert layers][experts the router scores]; ``held`` = [first, last +
+        1] of the experts whose weights this replica holds, where that is a
+        share of them; None for a model without experts."""
         load = getattr(self, "_moe_load", None)  # None before initialize()
-        return None if load is None else {"load": load.tolist()}
+        if load is None:
+            return None
+        first = self.model_cfg.expert_first
+        held = [first, first + self.model_cfg.num_experts]
+        return {"load": load.tolist(), **({"held": held} if held != [0, load.shape[1]] else {})}
 
     def _state_bytes(self) -> int:
         """Device bytes of the slot-indexed recurrent state (0 for a model
@@ -1867,8 +1873,9 @@ class DecodeEngine:
     def prefix_cache_stats(self) -> dict:
         """Point-in-time radix-cache state for /statusz and tests."""
         if self._radix is None:
-            if self.model_cfg is not None and self.model_cfg.has_recurrent_state:
-                return {"enabled": False, "disabled_by": "recurrent_state"}
+            limits = self._model_limits()
+            if "prefix_cache" in limits:
+                return {"enabled": False, "disabled_by": limits["reason"]}
             return {"enabled": False}
         return {
             "enabled": True,
@@ -3431,11 +3438,9 @@ class DecodeEngine:
         if spec is None:
             spec = SpeculativeConfig()
             self.config.speculative = spec
-        if enabled and self.model_cfg is not None and self.model_cfg.has_recurrent_state:
-            raise ValueError(
-                "speculative decoding cannot serve a model with recurrent "
-                "(state-space) layers (no state rollback exists)"
-            )
+        refused = self._model_limits().get("speculative")
+        if enabled and refused:
+            raise ValueError(refused)
         spec.enabled = bool(enabled)
         if enabled:
             from areal_tpu.inference import speculative as spec_mod

@@ -371,20 +371,26 @@ def quantize_pages(pages: jax.Array, dtype=jnp.int8) -> tuple[jax.Array, jax.Arr
 
 def n_pages_for_budget(
     budget_bytes: int, n_layers: int, num_kv_heads: int, page_size: int,
-    head_dim: int, itemsize: int, quant=False,
+    head_dim: int, itemsize: int, quant=False, pools: int = 2,
 ) -> int:
-    """Pages fitting a KV HBM budget (k+v across all layers per page).
-    ``quant`` (bool | "int8" | "fp8"): both quantized dtypes are 1 byte
-    per element plus a 4-byte f32 scale per token vector."""
+    """Pages fitting a KV HBM budget (every pool across all layers per
+    page: K and V, or with ``pools`` 1 the one latent row a token,
+    ``cfg.kv_pools``). ``quant`` (bool | "int8" | "fp8"): both quantized
+    dtypes are 1 byte per element plus a 4-byte f32 scale per token vector."""
     vec_bytes = head_dim * (1 if quant else itemsize) + (4 if quant else 0)
-    page_bytes = 2 * n_layers * num_kv_heads * page_size * vec_bytes
+    page_bytes = pools * n_layers * num_kv_heads * page_size * vec_bytes
     return max(2, budget_bytes // page_bytes)
 
 
 # The cache holds two kinds of thing under one dict, donated and returned
 # whole by every serving program. K and V PAGES (``k``, ``v`` and their
 # scales) exist for the layers that attend (``cfg.num_kv_layers``): paged,
-# aliased between requests by refcount, harmless to write twice. The
+# aliased between requests by refcount, harmless to write twice. A
+# latent-attention model (``cfg.kv_pools`` names ``k`` alone) keeps ONE row a
+# token and layer there, ``[c | k_r | 0]`` under a single "head": the key all
+# query heads share, whose first ``kv_lora_rank`` lanes are its value too;
+# the pool, the refcounts, a group's aliasing and the copy of the last
+# partial page are the same code on a row of another width. The
 # slot-indexed recurrent STATE of a model with state-space layers
 # (``cfg.state_shapes(slots)``; none for a model that only attends) is none
 # of these: one row per decode slot, never aliased, and it cannot be cut back
@@ -409,59 +415,54 @@ STATE_LEAVES = ("ssm", "conv", "gdn")
 def init_paged_cache(
     cfg, n_pages: int, page_size: int, dtype=None, quant=False, slots: int = 0
 ) -> dict:
-    """k/v page pools: [n_kv_layers, KH, n_pages, page_size, hd]. With
+    """The page pools ``cfg.kv_pools`` names, each [n_kv_layers, heads,
+    n_pages, page_size, lanes]: k and v, or a latent model's k alone. With
     ``quant`` (True/"int8" or "fp8") the pages are int8 or float8_e4m3fn
     plus per-token-vector f32 scales, lane-major ([..., 1, psz]) — halved
     KV HBM traffic, the decode bottleneck at long context. Beside them the
     zeroed recurrent state of ``slots`` decode slots, where the model has
     any (see STATE_LEAVES above)."""
     dtype = dtype or cfg.jax_dtype
-    shape = (cfg.num_kv_layers, cfg.num_kv_heads, n_pages, page_size, cfg.kv_head_dim)
     qdtype = quant_dtype(quant)
-    if qdtype is None:
-        cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-    else:
-        sshape = shape[:-2] + (1, page_size)
-        cache = {
-            "k": jnp.zeros(shape, qdtype),
-            "v": jnp.zeros(shape, qdtype),
-            "k_scale": jnp.ones(sshape, jnp.float32),
-            "v_scale": jnp.ones(sshape, jnp.float32),
-        }
+    cache = {}
+    for name, (heads, lanes) in cfg.kv_pools.items():
+        shape = (cfg.num_kv_layers, heads, n_pages, page_size, lanes)
+        cache[name] = jnp.zeros(shape, qdtype or dtype)
+        if qdtype is not None:
+            cache[f"{name}_scale"] = jnp.ones(shape[:-2] + (1, page_size), jnp.float32)
     for name, (sshape, sdtype) in cfg.state_shapes(slots).items():
         cache[name] = jnp.zeros(sshape, sdtype)
     return cache
 
 
-def paged_cache_specs(quant: bool = False):
-    """PartitionSpecs: KV heads shard over the TP axis when they divide."""
+def paged_cache_specs(quant: bool = False, pools=("k", "v")):
+    """PartitionSpecs of the page pools ``pools`` (``cfg.kv_pools``) and,
+    under ``quant``, their scales: KV heads shard over the TP axis when they
+    divide."""
     from jax.sharding import PartitionSpec as P
 
     spec = P(None, "model", None, None, None)
-    out = {"k": spec, "v": spec}
-    if quant:
-        out["k_scale"] = spec
-        out["v_scale"] = spec
-    return out
+    return {name: spec for pool in pools for name in ((pool, f"{pool}_scale") if quant else (pool,))}
 
 
-def scatter_prefill(cache: dict, ks: jax.Array, vs: jax.Array, flat_pages: jax.Array, page_size: int) -> dict:
+def scatter_prefill(cache: dict, ks: jax.Array, vs: jax.Array | None, flat_pages: jax.Array, page_size: int) -> dict:
     """Write a batched prefill's KV into pages.
 
-    ks/vs: [n_layers, A, bucket, KH, hd] from qwen.forward_prefill;
+    ks/vs: [n_layers, A, bucket, KH, hd] from qwen.forward_prefill (vs None
+    for a latent model: its rows are ks alone);
     flat_pages: [A * ceil(bucket/page_size)] int32 page ids row-major per
     prompt (padded positions -> trash page 0; duplicate trash writes are
     benign). A bucket shorter than one page (tiny max_seq_len) pads up.
     """
     L, A, bucket, KH, hd = ks.shape
+    rows = {"k": ks} if vs is None else {"k": ks, "v": vs}
     if bucket % page_size:
         pad = page_size - bucket % page_size
-        ks = jnp.pad(ks, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-        vs = jnp.pad(vs, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+        rows = {n: jnp.pad(r, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))) for n, r in rows.items()}
         bucket += pad
     npg = bucket // page_size
     quant = "k_scale" in cache
-    for name, new in (("k", ks), ("v", vs)):
+    for name, new in rows.items():
         # [L, A, bucket, KH, hd] -> [L, KH, A*npg, page_size, hd]
         r = jnp.transpose(new, (0, 3, 1, 2, 4)).reshape(
             L, KH, A * npg, page_size, hd
@@ -475,6 +476,17 @@ def scatter_prefill(cache: dict, ks: jax.Array, vs: jax.Array, flat_pages: jax.A
                 r.astype(cache[name].dtype)
             )
     return cache
+
+
+def scatter_prefill_layer(pool: jax.Array, layer: jax.Array, rows: jax.Array, flat_pages: jax.Array, page_size: int) -> jax.Array:
+    """One layer of ``scatter_prefill`` for a pool of ONE row a token (a
+    latent model's): ``rows`` [A, bucket, 1, lanes] of layer ``layer`` (traced)
+    into ``pool`` [n_layers, 1, N, page_size, lanes] at the rows' pages."""
+    A, bucket, one, lanes = rows.shape
+    if bucket % page_size:
+        rows = jnp.pad(rows, ((0, 0), (0, page_size - bucket % page_size), (0, 0), (0, 0)))
+    r = rows.reshape(-1, page_size, one, lanes).swapaxes(1, 2)  # [A * npg, 1, page_size, lanes]
+    return pool.at[layer, :, flat_pages].set(r.astype(pool.dtype))
 
 
 def scatter_token_rows(
@@ -515,7 +527,7 @@ def write_decode_rows(
     cache: dict,
     layer: jax.Array,  # scalar int32
     k: jax.Array,  # [S, KH, hd]: this step's token of every slot
-    v: jax.Array,
+    v: jax.Array | None,  # None: a latent model's one row is ``k``
     write_page: jax.Array,  # [S] int32
     write_off: jax.Array,  # [S] int32
     live: tuple[jax.Array, jax.Array] | None = None,
@@ -534,10 +546,10 @@ def write_decode_rows(
     pool-sized copies a layer; per head: none). That path serves off the TPU
     and under tensor parallelism, and is what the tests hold the kernel to."""
     cache = dict(cache)
-    rows = {"k": k, "v": v}
-    pages, scales = ("k", "v"), ()
+    rows = {"k": k} if v is None else {"k": k, "v": v}
+    pages, scales = tuple(rows), ()
     if "k_scale" in cache:
-        scales = ("k_scale", "v_scale")
+        scales = tuple(f"{n}_scale" for n in pages)
         for name in pages:
             rows[name], scale = quantize_kv(rows[name], dtype=cache[name].dtype)
             rows[f"{name}_scale"] = scale[..., 0]  # [S, KH]
@@ -578,18 +590,24 @@ def copy_pages(
     into; a few pages, all layers at once) and, where the model keeps a
     recurrent state, the primary slot's post-prompt state
     src_slots[i] -> dst_slots[i] (a state cannot be shared by reference)."""
+    # a slice in, a slice out a pair, every leaf in place: no gather or
+    # scatter over the state arrays (models/hybrid.py prefill_into_cache),
+    # and none over a page pool either: as ``pool.at[:, :, dst].set(pool[:,
+    # :, src])`` the program reserves a second pool (3 GB for a latent
+    # model's one pool, which the chip did not have: PERF.md, PR 37)
+    def copied(leaf, axis, dst_at, src_at):
+        def one(i, leaf):
+            row = jax.lax.dynamic_slice_in_dim(leaf, src_at[i], 1, axis=axis)
+            return jax.lax.dynamic_update_slice_in_dim(leaf, row, dst_at[i], axis=axis)
+
+        return jax.lax.fori_loop(0, dst_at.shape[0], one, leaf)
+
     for name in cache:
         if name in STATE_LEAVES:
             with jax.named_scope("state_write"):
-                # a slice in, a slice out a pair: no gather or scatter over
-                # the state arrays (models/hybrid.py prefill_into_cache)
-                def one(i, leaf):
-                    row = jax.lax.dynamic_slice_in_dim(leaf, src_slots[i], 1, axis=1)
-                    return jax.lax.dynamic_update_slice_in_dim(leaf, row, dst_slots[i], axis=1)
-
-                cache[name] = jax.lax.fori_loop(0, dst_slots.shape[0], one, cache[name])
-        else:  # k/v (+ k_scale/v_scale under int8 KV)
-            cache[name] = cache[name].at[:, :, dst].set(cache[name][:, :, src])
+                cache[name] = copied(cache[name], 1, dst_slots, src_slots)
+        else:  # k/v (+ k_scale/v_scale under int8 KV), or a latent model's k alone
+            cache[name] = copied(cache[name], 2, dst, src)
     return cache
 
 

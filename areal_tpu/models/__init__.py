@@ -12,11 +12,12 @@ def family_of(model_cfg):
     """The module that implements ``model_cfg``'s model family: the one place
     where the serving stack picks between them (``models/qwen.py``: the
     Qwen2/3 and llama decoders; ``models/hybrid.py``: ``granitemoehybrid``,
-    ``lfm2_moe`` and ``olmo_hybrid``). Both have the entry points
+    ``lfm2_moe``, ``olmo_hybrid`` and ``deepseek_v3``). Both have the entry points
     the decode engine calls (``param_partition_specs``, ``hf_name_map``,
     ``prefill_into_cache``, ``forward_prefill_paged``,
     ``forward_decode_paged``, ``forward_verify_paged``, ``compute_logits``,
-    ``quantize_params_int8``)."""
+    ``quantize_params_int8``) and say what of them they do not implement for
+    a model (``serving_limits``)."""
     from areal_tpu.models import hybrid, qwen
 
     return hybrid if isinstance(model_cfg, hybrid.HybridConfig) else qwen

@@ -5,17 +5,23 @@ its own kind.
 Mixer kinds: ``mamba`` (a Mamba-2 state-space layer), ``attention`` (GQA;
 with or without a rotary embedding and a q/k RMSNorm over each head or over
 the whole projection, as the configuration says), ``conv`` (a gated short
-convolution) and ``gdn`` (a gated delta rule: linear attention with a matrix
-state a head). FFN kinds: ``dense`` (SwiGLU) and ``moe`` (sparse experts,
-``models/moe.py``). A block's two RMSNorms stand on its sublayers' inputs
-(``norm_placement`` ``pre``) or on their outputs (``post``). Three published
+convolution), ``gdn`` (a gated delta rule: linear attention with a matrix
+state a head) and ``mla`` (latent attention: a token leaves ONE row ``[c |
+k_r]`` behind, a key/value latent and a rotary key shared by every head).
+FFN kinds: ``dense`` (SwiGLU) and ``moe`` (sparse experts, ``models/moe.py``,
+with or without an always-active shared block beside them; the stack may hold
+a SHARE of the experts the router scores: ``router_experts``,
+``expert_first``). A block's two RMSNorms stand on its sublayers' inputs
+(``norm_placement`` ``pre``) or on their outputs (``post``). Four published
 families are built from these (``from_hf_dict``): ``granitemoehybrid``
 without experts (Mamba-2 beside NoPE attention, dense MLPs, residual and
 logit multipliers), ``lfm2_moe`` (short convolutions beside rotary attention
 with q/k norms; the first ``num_dense_layers`` FFNs dense, the rest 32
 experts behind a sigmoid router with a selection bias) and ``olmo_hybrid``
 (gated-delta-rule layers beside NoPE attention with whole-projection q/k
-norms, post-sublayer norms, dense MLPs).
+norms, post-sublayer norms, dense MLPs) and ``deepseek_v3`` (latent attention
+in every layer, a leading dense FFN, then sigmoid-routed experts with a
+selection bias beside shared experts).
 Serving only (prefill, paged decode); training is ROADMAP Reach A.4.
 
 The module has the entry points the decode engine uses of ``models/qwen.py``
@@ -58,8 +64,17 @@ unit lower-triangular system, across chunks the state carried in float32.
 tests/test_olmo_hybrid_model.py holds them to each other and to the
 token-by-token reference.
 
+The latent-attention mixer comes in two forms of one layer's weights, and the
+entry point chooses, never an option: ``forward_prefill`` computes the PLAIN
+form (``[k_nope_h | v_h] = W_kvb,h c`` for the prompt's own tokens, every
+head's key ``[k_nope_h | k_r]``), ``forward_decode_paged`` the ABSORBED form
+over the cached rows (``q^_h = W_UK,h^T q_nope_h``, scores ``q^_h . c_s +
+q_rope_h . k_r,s``, ``o_h = W_UV,h sum_s p_s c_s``: the up-projection moved
+across both sums, so that a cached token is read as 576 values and not as
+32 x 320). tests/test_kanana2_model.py holds them to each other.
+
 What a slot's recurrent state is, and who may write it, is in
-``inference/paged_kv.py`` (STATE_LEAVES).
+``inference/paged_kv.py`` (STATE_LEAVES); what a page row is, in ``kv_pools``.
 """
 
 from __future__ import annotations
@@ -76,8 +91,8 @@ from jax.sharding import PartitionSpec as P
 from areal_tpu.models import moe, qwen
 from areal_tpu.models.qwen import _embed_lookup, _proj, _rms_norm, _rope
 
-MODEL_TYPES = ("granitemoehybrid", "lfm2_moe", "olmo_hybrid")
-KINDS = ("mamba", "attention", "conv", "gdn")  # mixers
+MODEL_TYPES = ("granitemoehybrid", "lfm2_moe", "olmo_hybrid", "deepseek_v3")
+KINDS = ("mamba", "attention", "conv", "gdn", "mla")  # mixers
 FFNS = ("dense", "moe")
 # scopes this family adds to qwen.SCOPES (docs/observability.md): the
 # state-space mixer's, the short-conv mixer's, and models/moe.py's
@@ -85,13 +100,16 @@ SCOPES = ("ssm_proj", "ssm_conv", "ssm_state", "state_write")
 CONV_SCOPES = ("conv_proj", "conv_mix", "state_write")
 MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
 GDN_SCOPES = ("gdn_proj", "gdn_conv", "gdn_state", "state_write")
+MLA_SCOPES = ("mla_proj", "attn", "kv_write")  # latent attention: its projections beside the shared two
+MOE_SHARED_SCOPE = "moe_shared"  # the always-active block beside the routed experts
 # tokens a chunk of the delta rule's prefill scan: 16 x 2^2, as ``_unit_lower_inverse`` builds its inverse
 GDN_CHUNK = 64
 # what a decode chunk may ask the forward to count into (not part of the
 # cache the engine keeps): rows of live slots each expert got, [expert
 # layers, experts], and experts with at least one such row, [expert layers];
-# live slots whose delta-rule state a step advanced, [gdn layers]
-COUNT_LEAVES = ("moe_load", "moe_touched", "gdn_updates")
+# live slots whose delta-rule state a step advanced, [gdn layers]; cached
+# tokens of live slots a latent-attention layer read, [mla layers]
+COUNT_LEAVES = ("moe_load", "moe_touched", "gdn_updates", "latent_tokens_read")
 
 
 def stack_name(kind: str, ffn: str) -> str:
@@ -165,6 +183,24 @@ class HybridConfig:
     gdn_d_conv: int = 4
     gdn_neg_eigval: bool = False
     gdn_state_dtype: str = "float32"
+    # the latent-attention mixer (``mla``): the rank of the latent a token
+    # leaves behind, a head's key without and with rotary embedding, a head's
+    # value; the rotary part stored as (even, odd) pairs; lanes a latent row
+    # [c | k_r] is stored in (0: the next multiple of 128)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
+    latent_row_lanes: int = 0
+    # an always-active SwiGLU beside the routed experts, of this width (0: none)
+    moe_shared_intermediate_size: int = 0
+    # the share of an expert layer held here: the router scores
+    # ``router_experts`` experts (None: num_experts), of which this stack
+    # holds ``num_experts``, global ids from ``expert_first``; what the others
+    # would add is left out of the layer's sum
+    router_experts: int | None = None
+    expert_first: int = 0
 
     @property
     def num_layers(self) -> int:
@@ -195,9 +231,25 @@ class HybridConfig:
 
     @property
     def sm_scale(self) -> float:
-        if self.attention_multiplier is None:
-            return self.head_dim_**-0.5
-        return float(self.attention_multiplier)
+        if self.attention_multiplier is not None:
+            return float(self.attention_multiplier)
+        if self.count("mla"):
+            return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        return self.head_dim_**-0.5
+
+    @property
+    def router_width(self) -> int:
+        return self.router_experts or self.num_experts
+
+    @property
+    def latent_dim(self) -> int:
+        """Values a token leaves behind in a latent-attention layer: [c | k_r]."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_lanes(self) -> int:
+        """Lanes a latent row is stored in: whole 128-lane tiles."""
+        return self.latent_row_lanes or -(-self.latent_dim // 128) * 128
 
     @property
     def d_inner(self) -> int:
@@ -227,12 +279,24 @@ class HybridConfig:
     # -- what the serving cache holds for this family (paged_kv.py) --------
     @property
     def num_kv_layers(self) -> int:
-        return self.count("attention")
+        return self.count("attention") + self.count("mla")
 
     @property
     def kv_head_dim(self) -> int:
+        if self.count("mla"):
+            return self.latent_lanes
         pad = max(1, self.kv_lane_pad)
         return -(-self.head_dim_ // pad) * pad
+
+    @property
+    def kv_pools(self) -> dict[str, tuple[int, int]]:
+        """{page pool: (heads, lanes)} of what a token leaves behind in a
+        layer that attends (inference/paged_kv.py): a K and a V row a KV
+        head, or ONE latent row [c | k_r | 0] for all heads, whose first
+        ``kv_lora_rank`` lanes are its value too."""
+        if self.count("mla"):
+            return {"k": (1, self.latent_lanes)}
+        return {"k": (self.num_kv_heads, self.kv_head_dim), "v": (self.num_kv_heads, self.kv_head_dim)}
 
     @property
     def has_recurrent_state(self) -> bool:
@@ -242,15 +306,20 @@ class HybridConfig:
     def count_shapes(self) -> dict[str, tuple[int, ...]]:
         """{leaf: shape} of every int32 count a decode chunk takes back
         beside its tokens (COUNT_LEAVES, in that order)."""
-        n = self.count("gdn")
-        return {**self.moe_count_shapes, **({"gdn_updates": (n,)} if n else {})}
+        out = dict(self.moe_count_shapes)
+        if n := self.count("gdn"):
+            out["gdn_updates"] = (n,)
+        if n := self.count("mla"):
+            out["latent_tokens_read"] = (n,)
+        return out
 
     @property
     def moe_count_shapes(self) -> dict[str, tuple[int, ...]]:
         """{leaf: shape} of the int32 counts a decode chunk takes back beside
-        its tokens (COUNT_LEAVES); none for a model without experts."""
+        its tokens (COUNT_LEAVES); none for a model without experts. The load
+        has the router's width; ``moe_touched`` counts experts held here."""
         n = self.num_moe_layers
-        return {"moe_load": (n, self.num_experts), "moe_touched": (n,)} if n else {}
+        return {"moe_load": (n, self.router_width), "moe_touched": (n,)} if n else {}
 
     def state_shapes(self, slots: int) -> dict[str, tuple[tuple[int, ...], Any]]:
         """{leaf: (shape, dtype)} of the slot-indexed recurrent state. A conv
@@ -294,13 +363,22 @@ class HybridConfig:
             raise ValueError(f"hidden_act {d['hidden_act']!r} is not implemented")
         extra = {
             k: d[k]
-            for k in ("dtype", "ssm_state_dtype", "conv_state_dtype", "gdn_state_dtype", "kv_lane_pad", "head_dim")
+            for k in (
+                "dtype", "ssm_state_dtype", "conv_state_dtype", "gdn_state_dtype", "kv_lane_pad", "head_dim",
+                "latent_row_lanes", "router_experts", "expert_first",
+            )
             if k in d
         }
         fields = _FIELDS[mt](d)
         kinds = fields["layer_types"]
         if set(kinds) - set(KINDS) or len(kinds) != d["num_hidden_layers"]:
             raise ValueError(f"layer_types {sorted(set(kinds))} x {len(kinds)} for {d['num_hidden_layers']} layers")
+        held, first = fields.get("num_experts", 0), int(extra.get("expert_first", 0))
+        width = int(extra.get("router_experts") or held)
+        if first < 0 or first + held > width:
+            raise ValueError(f"experts {first}..{first + held - 1} are not among the router's {width}")
+        if (width != held or first) and not held:
+            raise ValueError("router_experts / expert_first describe a share of an expert layer: the model has none")
         return cls(
             model_type=mt,
             vocab_size=d["vocab_size"],
@@ -343,6 +421,35 @@ class HybridConfig:
                 "linear_value_head_dim": self.gdn_v_dim,
                 "linear_conv_kernel_dim": self.gdn_d_conv,
                 "linear_allow_neg_eigval": self.gdn_neg_eigval,
+            }
+        if self.model_type == "deepseek_v3":
+            n_dense = sum(1 for f in self.ffns if f == "dense")
+            return {
+                **shared,
+                "rms_norm_eps": self.rms_norm_eps,
+                "hidden_act": "silu",
+                "attention_bias": False,
+                "rope_theta": self.rope_theta,
+                "rope_scaling": None,
+                "rope_interleave": self.rope_interleave,
+                "q_lora_rank": None,
+                "kv_lora_rank": self.kv_lora_rank,
+                "qk_nope_head_dim": self.qk_nope_head_dim,
+                "qk_rope_head_dim": self.qk_rope_head_dim,
+                "v_head_dim": self.v_head_dim,
+                "first_k_dense_replace": n_dense,
+                "moe_layer_freq": 1,
+                "n_routed_experts": self.num_experts,
+                "n_shared_experts": self.moe_shared_intermediate_size // max(1, self.moe_intermediate_size or 1),
+                "num_experts_per_tok": self.num_experts_per_tok,
+                "moe_intermediate_size": self.moe_intermediate_size,
+                "norm_topk_prob": self.norm_topk_prob,
+                "routed_scaling_factor": self.routed_scaling_factor,
+                "scoring_func": "sigmoid",
+                "topk_method": "noaux_tc",
+                "n_group": 1,
+                "topk_group": 1,
+                **({"router_experts": self.router_experts, "expert_first": self.expert_first} if self.router_experts else {}),
             }
         if self.model_type == "lfm2_moe":
             return {
@@ -501,7 +608,63 @@ def _olmo_hybrid_fields(d: dict[str, Any]) -> dict[str, Any]:
     )
 
 
-_FIELDS = {"granitemoehybrid": _granite_fields, "lfm2_moe": _lfm2_fields, "olmo_hybrid": _olmo_hybrid_fields}
+def _deepseek_v3_fields(d: dict[str, Any]) -> dict[str, Any]:
+    """``deepseek_v3``: latent attention in every layer (a full-rank query, a
+    key/value latent of ``kv_lora_rank`` beside ONE rotary key of
+    ``qk_rope_head_dim`` for all heads), the first ``first_k_dense_replace``
+    FFNs dense, the rest ``n_routed_experts`` experts behind a sigmoid router
+    whose selection (not its gates) takes ``e_score_correction_bias``, beside
+    ``n_shared_experts`` always-active ones fused into one SwiGLU. The 1e-20
+    of the gates' normalisation is the family's published implementation
+    (``DeepseekV3TopkRouter``). What this module does not implement is
+    refused, never ignored."""
+    if d.get("q_lora_rank") is not None:
+        raise ValueError("deepseek_v3 with a low-rank query path (q_lora_rank) is not implemented")
+    if int(d.get("n_group") or 1) != 1 or int(d.get("topk_group") or 1) != 1:
+        raise ValueError("deepseek_v3 with group-limited routing (n_group / topk_group other than 1) is not implemented")
+    if d.get("rope_scaling") is not None:
+        raise ValueError("deepseek_v3 with a scaled rotary embedding (rope_scaling) is not implemented")
+    if d.get("attention_bias"):
+        raise ValueError("projection biases are not implemented for the hybrid family")
+    if d.get("scoring_func", "sigmoid") != "sigmoid" or d.get("topk_method", "noaux_tc") != "noaux_tc":
+        raise ValueError(f"deepseek_v3 router {d.get('scoring_func')!r} / {d.get('topk_method')!r}: only sigmoid / noaux_tc")
+    if int(d.get("moe_layer_freq", 1)) != 1:
+        raise ValueError("deepseek_v3 with moe_layer_freq other than 1 is not implemented")
+    n = int(d["num_hidden_layers"])
+    n_dense = min(n, int(d.get("first_k_dense_replace", 0)))
+    experts = int(d.get("n_routed_experts") or 0) if n_dense < n else 0
+    if n_dense < n and experts < 1:
+        raise ValueError("deepseek_v3 layers past first_k_dense_replace need n_routed_experts")
+    return dict(
+        intermediate_size=d["intermediate_size"],
+        layer_types=("mla",) * n,
+        rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rope_interleave=bool(d.get("rope_interleave", True)),
+        kv_lora_rank=int(d["kv_lora_rank"]),
+        qk_nope_head_dim=int(d["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(d["qk_rope_head_dim"]),
+        v_head_dim=int(d["v_head_dim"]),
+        ffn_types=tuple("dense" if i < n_dense else "moe" for i in range(n)),
+        fused_gate_up=False,
+        num_experts=experts,
+        num_experts_per_tok=int(d.get("num_experts_per_tok", 1)),
+        moe_intermediate_size=d.get("moe_intermediate_size"),
+        moe_shared_intermediate_size=int(d.get("n_shared_experts") or 0) * int(d.get("moe_intermediate_size") or 0),
+        norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+        router_score="sigmoid",
+        router_bias=True,
+        router_norm_eps=1e-20,
+    )
+
+
+_FIELDS = {
+    "granitemoehybrid": _granite_fields,
+    "lfm2_moe": _lfm2_fields,
+    "olmo_hybrid": _olmo_hybrid_fields,
+    "deepseek_v3": _deepseek_v3_fields,
+}
 
 
 def serving_config(cfg: HybridConfig, dtype: str) -> HybridConfig:
@@ -557,19 +720,29 @@ def _layer_shapes(cfg: HybridConfig) -> dict[str, dict[str, tuple[int, ...]]]:
             "out_proj": (D, D),
         },
         "gdn": _gdn_shapes(cfg),
+        "mla": {
+            "wq": (D, cfg.num_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)),
+            "w_kva": (D, cfg.latent_dim),  # [c | k_r]: the latent and the one rotary key
+            "kv_norm": (cfg.kv_lora_rank,),
+            # [k_nope | v] a head; the absorbed form slices W_UK and W_UV out of it
+            "w_kvb": (cfg.kv_lora_rank, cfg.num_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            "wo": (cfg.num_heads * cfg.v_head_dim, D),
+        },
     }
-    E, Fe = cfg.num_experts, cfg.moe_intermediate_size
+    E, Fe, Fs = cfg.num_experts, cfg.moe_intermediate_size, cfg.moe_shared_intermediate_size
     ffns = {
         # [gate | up] is the granitemoehybrid checkpoint's fused input_linear
         "dense": {"w_gate_up": (D, 2 * F), "w_down": (F, D)}
         if cfg.fused_gate_up
         else {"w_gate": (D, F), "w_up": (D, F), "w_down": (F, D)},
+        # the router scores every expert of the layer; the stack holds this block's share
         "moe": {
-            "w_router": (D, E),
-            **({"router_bias": (E,)} if cfg.router_bias else {}),
+            "w_router": (D, cfg.router_width),
+            **({"router_bias": (cfg.router_width,)} if cfg.router_bias else {}),
             "we_gate": (E, D, Fe),
             "we_up": (E, D, Fe),
             "we_down": (E, Fe, D),
+            **({"ws_gate": (D, Fs), "ws_up": (D, Fs), "ws_down": (Fs, D)} if Fs else {}),
         },
     }
     out: dict[str, dict[str, tuple[int, ...]]] = {}
@@ -738,8 +911,30 @@ _HF_LAYER_MAPS = {
         "o_norm": ("linear_attn.o_norm.weight", False),
         "o_proj": ("linear_attn.o_proj.weight", True),
     },
+    "deepseek_v3": {
+        # held to ``transformers``' ``DeepseekV3ForCausalLM`` (tests/test_kanana2_hf_parity.py)
+        "input_norm": ("input_layernorm.weight", False),
+        "post_norm": ("post_attention_layernorm.weight", False),
+        "wq": ("self_attn.q_proj.weight", True),
+        "w_kva": ("self_attn.kv_a_proj_with_mqa.weight", True),
+        "kv_norm": ("self_attn.kv_a_layernorm.weight", False),
+        "w_kvb": ("self_attn.kv_b_proj.weight", True),
+        "wo": ("self_attn.o_proj.weight", True),
+        "w_gate": ("mlp.gate_proj.weight", True),
+        "w_up": ("mlp.up_proj.weight", True),
+        "w_down": ("mlp.down_proj.weight", True),
+        "w_router": ("mlp.gate.weight", True),
+        "router_bias": ("mlp.gate.e_score_correction_bias", False),
+        "we_gate": ("mlp.experts.{e}.gate_proj.weight", True),
+        "we_up": ("mlp.experts.{e}.up_proj.weight", True),
+        "we_down": ("mlp.experts.{e}.down_proj.weight", True),
+        "ws_gate": ("mlp.shared_experts.gate_proj.weight", True),
+        "ws_up": ("mlp.shared_experts.up_proj.weight", True),
+        "ws_down": ("mlp.shared_experts.down_proj.weight", True),
+    },
 }
 _HF_TOP = {
+    "deepseek_v3": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "granitemoehybrid": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "lfm2_moe": {"embed": "model.embed_tokens.weight", "final_norm": "model.embedding_norm.weight"},
     "olmo_hybrid": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
@@ -749,7 +944,8 @@ _HF_TOP = {
 def hf_name_map(cfg: HybridConfig) -> dict[str, tuple[str, bool]]:
     """Our param path -> (checkpoint name of ``cfg.model_type``, transpose).
     A stacked leaf maps as ``<stack>/<index within the stack>/<name>``, an
-    expert's as ``.../<name>/<expert>``; the checkpoint numbers layers in the
+    expert's as ``.../<name>/<expert within the stack>`` (the checkpoint's
+    expert ``cfg.expert_first`` + that); the checkpoint numbers layers in the
     order of ``layer_types``."""
     out: dict[str, tuple[str, bool]] = {k: (v, False) for k, v in _HF_TOP[cfg.model_type].items()}
     if not cfg.tie_word_embeddings:
@@ -764,7 +960,8 @@ def hf_name_map(cfg: HybridConfig) -> dict[str, tuple[str, bool]]:
             suffix, transpose = layer_map[name]
             if "{e}" in suffix:
                 for e in range(cfg.num_experts):
-                    out[f"{stack}/{n}/{name}/{e}"] = (f"model.layers.{i}.{suffix.format(e=e)}", transpose)
+                    ckpt = suffix.format(e=cfg.expert_first + e)
+                    out[f"{stack}/{n}/{name}/{e}"] = (f"model.layers.{i}.{ckpt}", transpose)
             else:
                 out[f"{stack}/{n}/{name}"] = (f"model.layers.{i}.{suffix}", transpose)
         seen[stack] = n + 1
@@ -1241,6 +1438,92 @@ def gdn_prefill(cfg: HybridConfig, layer: dict, h, n_state, state_dtypes):
 
 
 # ---------------------------------------------------------------------------
+# the latent-attention mixer
+# ---------------------------------------------------------------------------
+
+
+def _pairs_to_halves(x):
+    """(even, odd) pairs along the last axis -> [evens | odds]: the published
+    checkpoints keep the rotary part interleaved, ``_rope`` rotates halves."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+def _mla_in(cfg: HybridConfig, layer: dict, h, positions):
+    """The projections of h [..., D] at ``positions`` [...]: (q_nope
+    [..., H, nope], q_rope [..., H, rope] rotated, the normed latent c
+    [..., rank], the ONE rotary key k_r [..., rope] rotated). ``[c | k_r]``
+    is what the token leaves behind."""
+    H, dn, dr, r = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    lead = h.shape[:-1]
+    # each projection is ONE matmul whose output is split afterwards: left to
+    # itself XLA:TPU pushes the splits into the weights, wants those in
+    # another layout and copies the whole layer stack of them, once a program
+    # (1.2 GB for W_q at 47 layers; tests/test_tpu_compile.py)
+    q, kva = jax.lax.optimization_barrier((_proj(cfg, layer, "wq", h), _proj(cfg, layer, "w_kva", h)))
+    q = q.reshape(*lead, H, dn + dr)
+    q_nope, q_rope = q[..., :dn], q[..., dn:]
+    c = _rms_norm(kva[..., :r], layer["kv_norm"], cfg.rms_norm_eps)
+    k_r = kva[..., r:][..., None, :]  # one "head"
+    if cfg.rope_interleave:
+        q_rope, k_r = _pairs_to_halves(q_rope), _pairs_to_halves(k_r)
+    q_rope = _rope(q_rope, positions, cfg.rope_theta)
+    k_r = _rope(k_r, positions, cfg.rope_theta)[..., 0, :]
+    return q_nope, q_rope, c, k_r
+
+
+def _latent_row(cfg: HybridConfig, c, k_r):
+    """[c | k_r | 0] as a page stores it: [..., 1, latent_lanes]."""
+    row = jnp.concatenate([c, k_r], axis=-1)
+    pad = cfg.latent_lanes - cfg.latent_dim
+    row = jnp.pad(row, ((0, 0),) * (row.ndim - 1) + ((0, pad),)) if pad else row
+    return row[..., None, :]
+
+
+def _w_kvb_heads(cfg: HybridConfig, layer: dict):
+    """W_kvb as (W_UK [rank, H, nope], W_UV [rank, H, v]): slices, never a second copy kept."""
+    w = layer["w_kvb"].reshape(cfg.kv_lora_rank, cfg.num_heads, cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., : cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim :]
+
+
+def mla_prefill_attend(cfg: HybridConfig, layer: dict, q_nope, q_rope, c, k_r, mask):
+    """The PLAIN form over whole prompts: every head's key and value made
+    from the prompt's own latent (``[k_nope | v] = W_kvb c``, the rotary key
+    shared by all heads), causal softmax of q k^T / sqrt(nope + rope). q_*
+    [A, L, H, .], c [A, L, rank], k_r [A, L, rope], mask [A, 1, L, L].
+    Returns [A, L, H * v]."""
+    L = c.shape[1]
+    H, dn, dv = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+
+    def attend(args):  # one row at a time: [H, L, L] logits
+        qn, qr, c_r, kr, m = args
+        kv = (c_r @ layer["w_kvb"]).reshape(L, H, dn + dv)
+        k_nope, v = kv[..., :dn], kv[..., dn:]
+        logits = jnp.einsum("thd,shd->hts", qn, k_nope) + jnp.einsum("thd,sd->hts", qr, kr)
+        logits = jnp.where(m[0][None], logits.astype(jnp.float32) * cfg.sm_scale, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+        return jnp.einsum("hts,shd->thd", probs, v).reshape(L, H * dv)
+
+    return jax.lax.map(attend, (q_nope, q_rope, c, k_r, mask))
+
+
+def mla_absorbed_query(cfg: HybridConfig, layer: dict, q_nope, q_rope):
+    """The ABSORBED form's query over latent rows: ``[W_UK^T q_nope | q_rope
+    | 0]`` [S, H, latent_lanes], so that its product with a stored row
+    ``[c | k_r | 0]`` is the plain form's q . k."""
+    w_uk, _ = _w_kvb_heads(cfg, layer)
+    q_lat = jnp.einsum("shd,rhd->shr", q_nope, w_uk, preferred_element_type=jnp.float32).astype(q_nope.dtype)
+    return _latent_row(cfg, q_lat, q_rope)[..., 0, :]
+
+
+def mla_absorbed_out(cfg: HybridConfig, layer: dict, o_lat):
+    """o_lat [S, H, rank] (the probabilities' sum of latents) -> [S, H * v]:
+    the value up-projection moved across the sum."""
+    _, w_uv = _w_kvb_heads(cfg, layer)
+    o = jnp.einsum("shr,rhd->shd", o_lat.astype(w_uv.dtype), w_uv, preferred_element_type=jnp.float32)
+    return o.reshape(o.shape[0], -1).astype(o_lat.dtype)
+
+
+# ---------------------------------------------------------------------------
 # the layer stack
 # ---------------------------------------------------------------------------
 
@@ -1272,15 +1555,20 @@ def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None):
             return x + rm * _norm_out(cfg, layer, "post_norm", _proj(cfg, layer, "w_down", jax.nn.silu(g) * u)), None
     with jax.named_scope("moe_router"):
         h = _norm_in(cfg, layer, "post_norm", x)
+    rows = h.reshape(-1, h.shape[-1])
     out, _, _, load = moe.expert_ffn(
-        h.reshape(-1, h.shape[-1]), layer, cfg, live=None if live is None else live.reshape(-1)
+        rows, layer, cfg, live=None if live is None else live.reshape(-1), e0=cfg.expert_first
     )
+    if "ws_gate" in layer:  # the always-active block: every row, gate 1
+        with jax.named_scope(MOE_SHARED_SCOPE):
+            shared = jax.nn.silu(_proj(cfg, layer, "ws_gate", rows)) * _proj(cfg, layer, "ws_up", rows)
+            out = out + _proj(cfg, layer, "ws_down", shared).astype(out.dtype)
     with jax.named_scope("moe_combine"):
         return x + rm * _norm_out(cfg, layer, "post_norm", out.reshape(x.shape).astype(x.dtype)), load
 
 
 # the scope a mixer's norm counts under (its projections')
-_MIXER_SCOPE = {"mamba": "ssm_proj", "gdn": "gdn_proj", "conv": "conv_proj", "attention": "attn_proj"}
+_MIXER_SCOPE = {"mamba": "ssm_proj", "gdn": "gdn_proj", "conv": "conv_proj", "attention": "attn_proj", "mla": "mla_proj"}
 
 
 def _runs(cfg: HybridConfig) -> list[tuple[str, str, int, int, int, int]]:
@@ -1369,14 +1657,19 @@ def forward_prefill(
     sink: tuple | None = None,
 ):
     """Batched prompt pass. Returns (hidden [A, L, D], ks, vs
-    [n_attention, A, L, KH, kv_head_dim], state) where ``state`` is the
+    [n_attention, A, L, KH, kv_head_dim], state); for a latent-attention
+    model ks is the latent rows [n_mla, A, L, 1, latent_lanes] (computed in
+    the PLAIN form: per-head keys and values from the prompt's own latent)
+    and vs None. ``state`` is the
     recurrent state after each row's first ``n_state`` tokens, stacked per
     layer of its mixer kind ({leaf: [n, A, ...]}, ``cfg.state_shapes``).
 
     ``sink = (arrays, write)`` replaces the stacked state: ``arrays`` is
     carried through the layers and ``write(arrays, j, {leaf: new})`` stores
     layer j's state into it (the engine writes straight into its cache's
-    slot rows, so no second copy of A states exists)."""
+    slot rows, so no second copy of A states exists). A sink that holds
+    ``k`` also takes a latent model's rows layer by layer, ``{"k": rows
+    [A, L, 1, latent_lanes]}``, and ks is then None."""
     A, L = input_ids.shape
     if n_state is None:
         n_state = jnp.sum(seg, axis=-1)
@@ -1390,8 +1683,11 @@ def forward_prefill(
             return {**arr, **{k: arr[k].at[j].set(v) for k, v in new.items()}}
     else:
         arrays, write = sink
-    n_kv = cfg.num_kv_layers
-    kv_shape = (n_kv, A, L, cfg.num_kv_heads, cfg.kv_head_dim)
+    # what every token leaves behind in the layers that attend, pool by pool
+    kv_heads, kv_lanes = cfg.kv_pools["k"]
+    kv_shape = (cfg.num_kv_layers, A, L, kv_heads, kv_lanes)
+    latent = "v" not in cfg.kv_pools
+    rows_to_sink = latent and "k" in arrays  # the sink takes the latent rows too
     mask = qwen._attention_mask(seg)  # [A, 1, L, L]
     positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (A, L))
     live = seg.astype(bool)
@@ -1425,6 +1721,20 @@ def forward_prefill(
             out, conv = conv_prefill(cfg, layer, h, n_state, dtypes["conv"])
             with jax.named_scope("state_write"):
                 arr = write(arr, j, {"conv": conv})
+        elif kind == "mla":
+            with jax.named_scope("mla_proj"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+                q_nope, q_rope, c, k_r = _mla_in(cfg, layer, h, positions)
+                rows = _latent_row(cfg, c, k_r)
+            if rows_to_sink:  # layer by layer into the caller's pages: no [layers, A, L, lanes] buffer beside them
+                with jax.named_scope("kv_write"):
+                    arr = write(arr, j, {"k": rows})
+            else:
+                ks = ks.at[j].set(rows)
+            with jax.named_scope("attn"):
+                attn = mla_prefill_attend(cfg, layer, q_nope, q_rope, c, k_r, mask)
+            with jax.named_scope("mla_proj"):
+                out = _proj(cfg, layer, "wo", attn)
         else:
             with jax.named_scope("attn_proj"):
                 h = _norm_in(cfg, layer, "input_norm", x)
@@ -1441,11 +1751,18 @@ def forward_prefill(
         return x, ks, vs, arr
 
     x = _embed(params, cfg, input_ids)
-    carry = (x, jnp.zeros(kv_shape, cfg.jax_dtype), jnp.zeros(kv_shape, cfg.jax_dtype), arrays)
+    # a latent model has no V rows, and none to collect where the sink takes
+    # them: a scalar rides in their place
+    carry = (
+        x,
+        jnp.zeros(() if rows_to_sink else kv_shape, cfg.jax_dtype),
+        jnp.zeros(() if latent else kv_shape, cfg.jax_dtype),
+        arrays,
+    )
     x, ks, vs, arrays = _scan_layers(cfg, params, carry, step)
     with jax.named_scope("lm_head"):
         hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    return hidden, ks, vs, arrays
+    return hidden, (None if rows_to_sink else ks), (None if latent else vs), arrays
 
 
 def prefill_into_cache(
@@ -1472,6 +1789,15 @@ def prefill_into_cache(
     assert image_embeds is None, "the hybrid family has no vision tower"
     bucket = ids.shape[1]
     seg = (jnp.arange(bucket, dtype=jnp.int32)[None] < plens[:, None]).astype(jnp.int32)
+    if "v" not in cfg.kv_pools:
+        # a latent model (no slot state): its rows go into their pages layer
+        # by layer; 48 layers of 8 x 1024 rows collected first are 0.5 GB, and
+        # as much again to scatter
+        def put(arr, j, new):
+            return {"k": paged_kv.scatter_prefill_layer(arr["k"], j, new["k"], flat_pages, page_size)}
+
+        *_, pages = forward_prefill(params, cfg, ids, seg, sink=({"k": cache["k"]}, put))
+        return {**cache, **pages}
     state = {k: cache[k] for k in paged_kv.STATE_LEAVES if k in cache}
     n_slots = next(iter(state.values())).shape[1] if state else 0
 
@@ -1509,11 +1835,21 @@ def slot_state_view(cfg: HybridConfig, leaf: str, rows: jax.Array) -> jax.Array:
     return rows
 
 
+_NO_STATE_CUT = (
+    "needs a recurrent state cut back to a token boundary, which does not exist for state-space, "
+    "short-conv or delta-rule layers (ROADMAP Reach A.7: state snapshots at page boundaries)"
+)
+_NO_LATENT_SUFFIX = (
+    "needs an attention pass of new tokens over cached LATENT pages, which this module does not "
+    "have (ROADMAP Reach A.5: a suffix kernel over latent pages)"
+)
+
+
 def _refuse(what: str):
     def refuse(*_a, **_k):
         raise NotImplementedError(
-            f"{what} needs a recurrent state cut back to a token boundary, which does not "
-            "exist for state-space, short-conv or delta-rule layers (ROADMAP Reach A.7: state snapshots at page boundaries)"
+            f"{what} is not implemented for the hybrid family: over a recurrent state it {_NO_STATE_CUT}; "
+            f"over latent pages it {_NO_LATENT_SUFFIX}"
         )
 
     return refuse
@@ -1525,6 +1861,44 @@ forward_verify_paged = _refuse("speculative verification")
 
 def quantize_params_int8(params: dict) -> dict:
     raise NotImplementedError("int8 weight quantization is not implemented for the hybrid family's mixer")
+
+
+def serving_limits(cfg: HybridConfig) -> dict[str, str]:
+    """What this module does not implement for ``cfg``, for the decode engine
+    to refuse when it is configured: {feature: why} over ``prefix_cache``
+    (radix matching, and the suffix prefill behind a hit), ``speculative``,
+    ``int8_weights`` and ``sharded``, beside ``reason``, the one word
+    ``/statusz`` gives for it. Every model of this family has all four: the
+    module has no ``forward_prefill_paged`` / ``forward_verify_paged``, no
+    int8 form of its mixers and experts, and no sharded form."""
+    if cfg.has_recurrent_state:
+        return {
+            "reason": "recurrent_state",
+            "prefix_cache": "the model has recurrent (state-space) layers and a cached page prefix carries no state",
+            "speculative": (
+                "speculative decoding cannot serve a model with recurrent (state-space) layers: a rejected "
+                "draft would have to roll the slot's state back, and no state snapshot exists"
+            ),
+            "int8_weights": (
+                "int8 weight quantization is not implemented for the hybrid family's mixers and experts; "
+                "serve this model with quantization='none'"
+            ),
+            "sharded": (
+                "a model with recurrent (state-space) layers serves on one chip a replica: its mixer and "
+                "state are not sharded"
+            ),
+        }
+    return {
+        "reason": "latent_pages",
+        "prefix_cache": f"a hit on cached latent pages {_NO_LATENT_SUFFIX}",
+        "speculative": f"speculative decoding cannot serve a latent-attention model: verification {_NO_LATENT_SUFFIX}",
+        "int8_weights": (
+            "int8 weight quantization is not implemented for the hybrid family's mixers and experts; "
+            "serve this model with quantization='none'"
+        ),
+        "int8_pages": "quantized latent pages are not implemented; serve this model with kv_quantization='none'",
+        "sharded": "a latent-attention model serves on one chip a replica: its mixer and its pages are not sharded",
+    }
 
 
 def forward_decode_paged(
@@ -1542,7 +1916,9 @@ def forward_decode_paged(
     """One incremental step for all S slots. The attention layers write the
     token's K and V into its page row and read the slot's pages as
     ``qwen.forward_decode_paged`` does (the Pallas kernel over lane-padded
-    heads, or the gather path); the Mamba and short-conv layers advance the
+    heads, or the gather path); a latent-attention layer writes the token's
+    ONE latent row and reads the slot's pages in the absorbed form
+    (ops/paged_latent_attention.py, or the gather path); the Mamba and short-conv layers advance the
     recurrent state of the ``active`` slots only: an ended, parked or held
     slot's state is what it was, bit for bit. ``use_kernel`` also puts the
     Mamba recurrence on its Pallas kernel (ops/ssm_state_update.py), which
@@ -1569,6 +1945,8 @@ def forward_decode_paged(
         schedule = decode_schedule(attn_lengths, page_table.shape[1], page_size, ppcb)
         # the state kernel's work list, made once a step
         live = live_order(active) if cfg.count("mamba") + cfg.count("gdn") else None
+        if cfg.count("mla"):
+            from areal_tpu.ops.paged_latent_attention import paged_latent_attention_stacked
         with jax.named_scope("kv_write"):
             kv_live = live_order(page_table[:, 0] != 0)  # the KV writer's: qwen.forward_decode_paged
     else:
@@ -1594,6 +1972,31 @@ def forward_decode_paged(
             with jax.named_scope("conv_proj"):
                 h = _norm_in(cfg, layer, "input_norm", x)
             out, c["conv"] = conv_decode(cfg, layer, h, c["conv"], j, active)
+        elif kind == "mla":
+            # the ABSORBED form: H query rows over ONE latent row a cached token
+            with jax.named_scope("mla_proj"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+                q_nope, q_rope, lat, k_r = _mla_in(cfg, layer, h, positions)
+                q = mla_absorbed_query(cfg, layer, q_nope, q_rope)
+            with jax.named_scope("kv_write"):
+                row = _latent_row(cfg, lat, k_r)
+                c = paged_kv.write_decode_rows(c, j, row, None, write_page, write_off, kv_live)
+            with jax.named_scope("attn"):
+                if use_kernel:
+                    o_lat = paged_latent_attention_stacked(
+                        q, c["k"], j, attn_lengths, page_table, value_lanes=cfg.kv_lora_rank,
+                        pages_per_compute_block=ppcb, schedule=schedule, sm_scale=cfg.sm_scale,
+                    )
+                else:
+                    pool = jax.lax.dynamic_index_in_dim(c["k"], j, 0, keepdims=False)
+                    o_lat = paged_kv.paged_attention_xla(q, pool, pool, lengths, page_table, sm_scale=cfg.sm_scale)
+                    o_lat = o_lat[..., : cfg.kv_lora_rank]
+                if "latent_tokens_read" in c:
+                    c["latent_tokens_read"] = c["latent_tokens_read"].at[j].add(
+                        jnp.sum(jnp.where(active, lengths, 0), dtype=jnp.int32)
+                    )
+            with jax.named_scope("mla_proj"):
+                out = _proj(cfg, layer, "wo", mla_absorbed_out(cfg, layer, o_lat.astype(x.dtype)))
         else:
             with jax.named_scope("attn_proj"):
                 h = _norm_in(cfg, layer, "input_norm", x)
@@ -1627,7 +2030,8 @@ def forward_decode_paged(
         if load is not None and "moe_load" in c:
             with jax.named_scope("moe_router"):
                 c["moe_load"] = c["moe_load"].at[f].add(load)
-                c["moe_touched"] = c["moe_touched"].at[f].add(jnp.sum(load > 0, dtype=jnp.int32))
+                held = load[cfg.expert_first : cfg.expert_first + cfg.num_experts]  # whose weights are here
+                c["moe_touched"] = c["moe_touched"].at[f].add(jnp.sum(held > 0, dtype=jnp.int32))
         return x, c
 
     x = _embed(params, cfg, ids)

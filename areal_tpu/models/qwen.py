@@ -126,6 +126,12 @@ class ModelConfig:
     def kv_head_dim(self) -> int:
         return self.head_dim_
 
+    @property
+    def kv_pools(self) -> dict[str, tuple[int, int]]:
+        """{page pool: (heads, lanes)} of what a token leaves behind in a
+        layer (inference/paged_kv.py): a K and a V row a KV head."""
+        return {"k": (self.num_kv_heads, self.kv_head_dim), "v": (self.num_kv_heads, self.kv_head_dim)}
+
     has_recurrent_state = False
     # expert-load counts a decode chunk would hand back (models/hybrid.py): none
     moe_count_shapes: ClassVar[dict] = {}
@@ -337,6 +343,15 @@ def _proj(cfg: ModelConfig, layer: dict, name: str, x: jax.Array) -> jax.Array:
         scale = cfg.lora_alpha / cfg.lora_rank
         out = out + ((x @ a) @ layer[f"{name}_lora_b"]) * scale
     return out
+
+
+def serving_limits(cfg) -> dict[str, str]:
+    """What this module does not implement for ``cfg``, for the decode engine
+    to refuse when it is configured ({feature: why}, ``models/hybrid.py``):
+    nothing. Radix reuse with suffix prefill, speculative verification, int8
+    weights and pages and tensor parallelism all serve this family."""
+    del cfg
+    return {}
 
 
 # int8 weight-only serving quantization. The reference reaches serving

@@ -169,6 +169,14 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "live slots x delta-rule layers (each reads and writes the "
             "slot's state of that layer once).",
         ),
+        # a latent-attention model (models/hybrid.py ``mla``); counted on the
+        # device inside the decode chunk as the counts around it are
+        latent_tokens_read=r.counter(
+            "areal_decode_latent_tokens_read_total",
+            "(cached token, layer) latent rows read by decode steps: the "
+            "cached tokens of live slots x latent-attention layers (each "
+            "row is fetched once a step and layer).",
+        ),
         # a model with sparse experts (models/moe.py); counted on the device
         # inside the decode chunk, for live slots only, and brought back with
         # the chunk's tokens. Per-expert counts: /statusz ``moe.load``
@@ -179,9 +187,9 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
         ),
         moe_experts_touched=r.counter(
             "areal_decode_moe_experts_touched_total",
-            "Experts that got at least one live slot's row, summed over "
-            "decode steps and expert layers (each costs one read of its "
-            "weights).",
+            "Experts held by this replica that got at least one live slot's "
+            "row, summed over decode steps and expert layers (each costs one "
+            "read of its weights).",
         ),
     )
 

@@ -16,6 +16,14 @@ makes them (128-token pages, a 32-page table), for three sets of lengths:
 and prints microseconds a launch, the KV bytes the lengths need and their
 share of the chip's memory roofline.
 
+``paged_latent_attn`` (``--only latent`` runs it alone): the latent-attention
+cell's launch, 32 query rows of 640 lanes over ONE stacked pool of 1,280 B
+rows (576 published values in 640 lanes), 48 layers, by live slots (16 / 24 /
+48 of 64) and by cached tokens (the ``mix`` lengths, and every live slot at
+1k / 4k), against the bytes and operations of the PUBLISHED row (1,152 B and
+69,632 operations a cached token and layer), and the row write of the same
+pool (one ``paged_kv_write`` launch a layer over the live slots).
+
 ``paged_kv_write``: a step's KV rows of every layer written into the stacked
 pools (``paged_kv.write_decode_rows``) by the per-head XLA scatters over every
 slot and by the one ``paged_kv_write`` launch over the live slots, at 0 / 25 /
@@ -102,6 +110,84 @@ def probe(name: str, *, seed: int, reps: int, ppcb: int, pages: int = 1200) -> d
     return res
 
 
+LATENT = dict(S=64, H=32, L=48, lanes=640, row=576, value=512)  # rollout-kanana-2-30b-a3b-ep8-grpo
+FLOPS_S = 197e12  # TPU v5e bf16, as benchmarks/chip/benchlib/peaks.py
+
+
+def probe_latent(*, seed: int, reps: int, ppcb: int, pages: int = 409) -> list[dict]:
+    """us a launch of ``paged_latent_attn`` and of the latent row's write, by
+    live slots and cached tokens, and the launch's share of its roofline."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.ops.paged_attention_q8 import decode_schedule, live_order
+    from areal_tpu.ops.paged_latent_attention import paged_latent_attention_stacked
+
+    S, H, L, lanes, row, value = (LATENT[k] for k in ("S", "H", "L", "lanes", "row", "value"))
+    kq, kp, kr = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(kq, (S, H, lanes), jnp.bfloat16)
+    pool = jax.random.normal(kp, (L, 1, pages, PSZ, lanes), jnp.bfloat16)
+    new_rows = jax.random.normal(kr, (L, S, 1, lanes), jnp.bfloat16)
+    rng = np.random.default_rng(seed)
+    table = jnp.asarray(rng.integers(1, pages, (S, WP)), jnp.int32)
+
+    @jax.jit
+    def attend(q, pool, lengths, table):
+        schedule = decode_schedule(lengths, WP, PSZ, ppcb)  # once a step
+
+        def layer(acc, li):
+            out = paged_latent_attention_stacked(
+                q, pool, li, lengths, table, value_lanes=value, pages_per_compute_block=ppcb, schedule=schedule, sm_scale=192**-0.5
+            )
+            return acc + out, None
+
+        return jax.lax.scan(layer, jnp.zeros((S, H, value), jnp.float32), jnp.arange(L))[0]
+
+    def write(pool, rows, lengths, table):
+        live = live_order(lengths > 0)
+        slot = jnp.arange(S)
+        page, off = table[slot, lengths // PSZ], lengths % PSZ
+
+        def layer(c, xs):
+            li, r = xs
+            return paged_kv.write_decode_rows(c, li, r, None, page, off, live), None
+
+        return jax.lax.scan(layer, {"k": pool}, (jnp.arange(L, dtype=jnp.int32), rows))[0]["k"]
+
+    write = jax.jit(write, donate_argnums=0)
+    mix = draw_lengths(S, seed)
+    sets = {"mix": mix, "empty": np.zeros_like(mix)}
+    for n_live in (16, 24, 48):
+        for tokens in (1024, 4000):
+            lengths = np.zeros(S, np.int32)
+            lengths[rng.permutation(S)[:n_live]] = tokens
+            sets[f"{n_live}x{tokens}"] = lengths
+    out = []
+    for label, lengths in sets.items():
+        cached = int(lengths.sum())
+        lengths = jnp.asarray(lengths)
+        attend(q, pool, lengths, table).block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            o = attend(q, pool, lengths, table)
+        o.block_until_ready()
+        us = (time.perf_counter() - t0) / (reps * L) * 1e6
+        pool = write(pool, new_rows, lengths, table)
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            pool = write(pool, new_rows, lengths, table)
+        pool.block_until_ready()
+        write_us = (time.perf_counter() - t0) / (reps * L) * 1e6
+        floor_us = max(row * 2 * cached / HBM_BYTES_S, 2 * H * (row + value) * cached / FLOPS_S) * 1e6
+        out.append({
+            "kernel": "paged_latent_attn", "lengths": label, "live_slots": int((np.asarray(lengths) > 0).sum()), "cached_tokens": cached,
+            "us": us, "roofline_pct": 100 * floor_us / us if cached else None, "stored_bytes_pct": 100 * lanes * 2 * cached / HBM_BYTES_S * 1e6 / us,
+            "row_write_us": write_us,
+        })
+    return out
+
+
 def probe_write(name: str, *, seed: int, reps: int, quant: bool, pages: int = 400) -> dict:
     """us a layer of a decode step's KV write, scatters against the kernel."""
     import jax
@@ -177,10 +263,15 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--ppcb", type=int, default=4, help="pages a compute block (the decode step's choice at this table: 4)")
+    ap.add_argument("--only", choices=("latent",), help="the latent-attention cell's launches alone")
     args = ap.parse_args()
     if jax.default_backend() != "tpu":
         print("decode_attn_probe: needs a TPU (a CPU time is no speed)")
         return 2
+    for res in probe_latent(seed=args.seed, reps=args.reps, ppcb=args.ppcb):
+        print(json.dumps(res), flush=True)
+    if args.only:
+        return 0
     for name in SHAPES:
         print(json.dumps(probe(name, seed=args.seed, reps=args.reps, ppcb=args.ppcb)), flush=True)
     for name in WRITE_SHAPES:

@@ -222,6 +222,60 @@ def _cases_paged_stacked(compiled: bool = False) -> Iterator[dict]:
 
 
 # ---------------------------------------------------------------------------
+# latent (MLA) decode attention (ops/paged_latent_attention.py): ONE pool of
+# rows [c | k_r | 0] that are keys over all lanes and values over the first
+# ``value_lanes``, against the gather path on the same pool given twice
+# ---------------------------------------------------------------------------
+
+
+@register_kernel("paged_latent_attention")
+def _cases_paged_latent(compiled: bool = False) -> Iterator[dict]:
+    import jax.numpy as jnp
+
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.ops.paged_latent_attention import paged_latent_attention_stacked
+
+    L = 3
+    # the latent cell's launch (64 slots, 32 heads, 576 values in 640 lanes), or a size the interpreter holds
+    S, H, lanes, value, psz, wp, ppcb = (64, 32, 640, 512, 128, 32, 4) if compiled else (5, 4, 256, 128, 8, 4, 2)
+    N = S * wp + 1
+
+    def case(label, layer, pages, seed=5):
+        def build():
+            rng = np.random.default_rng(seed)
+            lengths = rng.integers(1, wp * psz + 1, S).astype(np.int32)
+            lengths[0], lengths[-1] = 0, wp * psz  # a slot no item names, and a full window
+            return {
+                "q": _normal(seed, (S, H, lanes), pages),
+                "pool": _normal(seed + 1, (L, 1, N, psz, lanes), pages),
+                "lengths": jnp.asarray(lengths),
+                "pt": jnp.asarray(1 + rng.permutation(N - 1)[: S * wp].reshape(S, wp), jnp.int32),
+            }
+
+        def kernel(inp):
+            return _live(
+                paged_latent_attention_stacked(
+                    inp["q"], inp["pool"], jnp.int32(layer), inp["lengths"], inp["pt"], value_lanes=value,
+                    pages_per_compute_block=ppcb, sm_scale=lanes**-0.5, interpret=not compiled,
+                ),
+                inp["lengths"],
+            )
+
+        def reference(inp):
+            pool = inp["pool"][layer].astype(jnp.float32)
+            out = paged_kv.paged_attention_xla(inp["q"].astype(jnp.float32), pool, pool, inp["lengths"], inp["pt"], sm_scale=lanes**-0.5)
+            return _live(out[..., :value], inp["lengths"])
+
+        # bfloat16 pages: the probabilities are rounded to the pages' type before they meet the values (2^-9 a term)
+        return {"case": label, "build": build, "kernel": kernel, "reference": reference, "tol": 1e-5 if pages == jnp.float32 else 3e-2}
+
+    for layer in (0, L - 1):
+        yield case(f"latent-bf16-layer{layer}", layer, jnp.bfloat16)
+    if not compiled:
+        yield case("latent-f32-layer1", 1, jnp.float32)
+
+
+# ---------------------------------------------------------------------------
 # a decode step's KV rows (ops/paged_kv_write.py): one launch over the live
 # slots against the per-head scatters, BIT-EQUAL over the whole pool
 # ---------------------------------------------------------------------------

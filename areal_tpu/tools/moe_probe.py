@@ -1,8 +1,12 @@
 """Time one sparse-expert layer alone on the chip at the widths of the
 benchmark's ``rollout-lfm2-8b-a1b-d14-grpo`` (32 experts of [2048, 1792],
-top-4), in both forms of ``models/moe.py`` and at the tiles it would choose.
+top-4) or, with ``--shape kanana2``, of ``rollout-kanana-2-30b-a3b-ep8-grpo``
+(the 16 experts of [2048, 768] a chip holds of the 128 its router scores,
+top-6: a row's choices that fall on the other 112 are no work here), in both
+forms of ``models/moe.py`` and at the tiles it would choose.
 
     chiprun -- python -m areal_tpu.tools.moe_probe
+    chiprun -- python -m areal_tpu.tools.moe_probe --shape kanana2 --rows 24,64,512,1024
 
 For each row count (128: a decode step of 128 slots; 256 to 1024: a short
 prompt's prefill, up to ``moe.DENSE_ROWS``; 4096: a batched prefill) it scans ``--layers`` stacked
@@ -28,26 +32,29 @@ import argparse
 import json
 import time
 
-E, K, D, F = 32, 4, 2048, 1792
+# experts held (E) of those the router scores (E_ALL), experts a token, hidden and expert width
+SHAPES = {"lfm2": (32, 32, 4, 2048, 1792), "kanana2": (16, 128, 6, 2048, 768)}
 HBM_BYTES_S, FLOPS = 819e9, 197e12  # TPU v5e, as benchmarks/chip/benchlib/peaks.py
 
 
-def probe(rows: int, layers: int, reps: int, seed: int, tms=()) -> dict:
+def probe(rows: int, layers: int, reps: int, seed: int, tms=(), shape: str = "lfm2") -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from areal_tpu.models import moe
 
+    E, E_ALL, K, D, F = SHAPES[shape]
     key = jax.random.PRNGKey(seed)
     ks = jax.random.split(key, 6)
     x = jax.random.normal(ks[0], (rows, D), jnp.bfloat16)
     wg = 0.02 * jax.random.normal(ks[1], (layers, E, D, F), jnp.bfloat16)
     wu = 0.02 * jax.random.normal(ks[2], (layers, E, D, F), jnp.bfloat16)
     wd = 0.02 * jax.random.normal(ks[3], (layers, E, F, D), jnp.bfloat16)
-    top_e = jnp.argsort(jax.random.uniform(ks[4], (rows, E)), axis=-1)[:, :K].astype(jnp.int32)
+    top_e = jnp.argsort(jax.random.uniform(ks[4], (rows, E_ALL)), axis=-1)[:, :K].astype(jnp.int32)
     gates = jnp.full((rows, K), 1.0 / K, jnp.float32)
-    touched = int(np.unique(np.asarray(top_e)).size)
+    here = np.asarray(top_e)[np.asarray(top_e) < E]  # the choices that fall on the experts held (ids from 0)
+    touched = int(np.unique(here).size)
     chosen = (moe.gmm_tiles(rows * K, D, F, E), moe.gmm_tiles(rows * K, F, D, E))
 
     def with_tiles(t_in, t_out):
@@ -90,9 +97,9 @@ def probe(rows: int, layers: int, reps: int, seed: int, tms=()) -> dict:
     forms = ["dense", "routed", "routed_128"] + (["routed_wide"] if rows <= 512 else []) + [f"routed_tm{tm}" for tm in tms]
     if rows > 2048:
         forms.remove("dense")  # 8 x the rows' arithmetic: nobody's path
-    res = {"rows": rows, "experts_touched": touched, "tiles": [list(t) for t in chosen]}
+    res = {"shape": shape, "rows": rows, "experts_touched": touched, "assignments_here": int(here.size), "tiles": [list(t) for t in chosen]}
     least_bytes = touched * 3 * D * F * 2 / HBM_BYTES_S * 1e6
-    least_flops = rows * K * 3 * 2 * D * F / FLOPS * 1e6
+    least_flops = int(here.size) * 3 * 2 * D * F / FLOPS * 1e6
     for form in forms:
         try:
             us = run(form)
@@ -114,12 +121,13 @@ def main(argv=None) -> int:
     p.add_argument("--reps", type=int, default=20)
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--tm", default="", help="more routed variants: the chosen tiles with these m tiles")
+    p.add_argument("--shape", choices=sorted(SHAPES), default="lfm2")
     a = p.parse_args(argv)
     if jax.default_backend() != "tpu":
         print("moe_probe needs a TPU: a CPU time is no speed")
         return 2
     for rows in [int(r) for r in a.rows.split(",")]:
-        print(json.dumps(probe(rows, a.layers, a.reps, a.seed, [int(t) for t in a.tm.split(",") if t])), flush=True)
+        print(json.dumps(probe(rows, a.layers, a.reps, a.seed, [int(t) for t in a.tm.split(",") if t], a.shape)), flush=True)
     return 0
 
 

@@ -589,3 +589,103 @@ def test_olmo_prefill_compiles_for_v5e(chip, monkeypatch):
     i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
     compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(4, 1024), i32(4), i32(4 * 1024 // PSZ), i32(4)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def _kanana2(chip, monkeypatch, layers: int = 3):
+    """The ``deepseek_v3`` family at the benchmark's published widths (latent
+    rows of 576 in 640 lanes, 32 heads, 16 held experts of [2048, 768] under
+    a router of 128, the shared block, a vocabulary of 16,032) and 64 slots,
+    cut to the leading dense layer and two expert layers so that tier-1 can
+    hold the compile; weights and cache are shapes on the described chip."""
+    import json
+
+    from areal_tpu import models
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "chip", "configs", "kanana-2-30b-a3b-ep8.json")) as f:
+        cfg = json.load(f)
+    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "reduced_from", "assumed", "assumed_notes", "stands_for")}
+    hf.update({k: v for k, v in cfg["assumed"].items() if k not in ("initializer_range", "latent_page_dtype")}, num_hidden_layers=layers, dtype="bfloat16")
+    mcfg = models.config_from_hf_dict(hf)
+    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
+    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, 477, PSZ, slots=64))
+    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return mcfg, place(params), place(cache)
+
+
+def test_kanana2_decode_steps_compile_for_v5e(chip, monkeypatch):
+    """Two decode steps as the engine's chunk runs them: the latent kernel
+    and the row writer as custom calls on the stacked pool where it lies (no
+    copy of the page pool, no layer slice of it), the absorbed products
+    without a second copy of ``W_kvb``'s stack, the counts in the carry."""
+    from areal_tpu.models import hybrid
+
+    mcfg, params, cache = _kanana2(chip, monkeypatch)
+    assert set(cache) == {"k"} and cache["k"].shape == (3, 1, 477, PSZ, 640)
+
+    def two_steps(params, cache, pt, ids, pos, active):
+        cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
+
+        def step(c, _):
+            ids, pos, cache = c
+            h, cache = hybrid.forward_decode_paged(params, mcfg, ids, pos, cache, pt, page_size=PSZ, active=active, use_kernel=True)
+            return (jnp.argmax(hybrid.compute_logits(params, mcfg, h), -1).astype(jnp.int32), pos + 1, cache), None
+
+        (ids, _, cache), _ = jax.lax.scan(step, (ids, pos, cache), None, length=2)
+        return ids, cache
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(64, 32), i32(64), i32(64), chip((64,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "paged_latent_attn" in text and "paged_kv_write" in text and "paged_decode_attn" not in text
+    pool = "bf16[3,1,477,128,640]"
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and (pool in ln or "bf16[1,477,128,640]" in ln)]
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and "bf16[2,16,2048,768]" in ln]  # nor of an expert stack
+    # nor of W_q's stack, which XLA re-lays out whole where the projection's output is split without a barrier
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and "bf16[2,2048,6144]" in ln]
+    # the pool is 234 MB here: nothing of its size among the temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < 200e6
+
+
+def test_kanana2_prefill_compiles_for_v5e(chip, monkeypatch):
+    """A batched prefill of 2 x 1024 tokens in the plain form: per-head keys
+    and values from the prompt's own latent one row at a time, the routed
+    expert form over the held experts only (the router's other 112 sort past
+    the grouped matmuls), the shared block, the latent rows' scatter."""
+    from areal_tpu.models import hybrid, moe
+
+    mcfg, params, cache = _kanana2(chip, monkeypatch)
+    assert not moe.takes_dense_form(2 * 1024, 16) and moe.takes_dense_form(64, 16)
+
+    def prefill(params, cache, ids, plens, flat_pages, slots):
+        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(2, 1024), i32(2), i32(2 * 1024 // PSZ), i32(2)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3  # the three grouped matmuls of the expert layer
+    # the latent rows go into their pages layer by layer: no [layers, 2, 1024, 640] buffer, no pool-sized temporary
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
+
+
+@pytest.mark.parametrize(
+    "pools",
+    [{"k": (48, 1, 477, PSZ, 640)}, {"k": (14, 4, 1170, PSZ, HD), "v": (14, 4, 1170, PSZ, HD)}],
+    ids=["latent-pool-3.75GB", "kv-pools-2x2.1GB"],
+)
+def test_page_copy_reserves_no_second_pool_on_v5e(chip, pools):
+    """A group's page copy at the cells' real pool sizes (the latent cell's
+    one pool of 3.75 GB, the 7B cell's two of 2.1 GB), 8 pairs: every leaf
+    copied in place on the donated cache. As a gather and a scatter over the
+    pool the program reserved a second pool for the single-head latent shape
+    (3.75 GB of temporaries by this count; 0.2 MB for the K/V shapes), which
+    the chip did not have beside the weights (PERF.md, PR 37: the first chip
+    call)."""
+    from areal_tpu.inference import paged_kv
+
+    cache = {name: chip(shape, jnp.bfloat16) for name, shape in pools.items()}
+    pairs = chip((8,), jnp.int32)
+    compiled = jax.jit(paged_kv.copy_pages, donate_argnums=(0,)).lower(cache, pairs, pairs, pairs, pairs).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e6
