@@ -1843,6 +1843,7 @@ class DecodeEngine:
             self._moe_load = self._moe_load + counts["moe_load"]
             self._obs.moe_assignments.inc(int(counts["moe_load"].sum()))
             self._obs.moe_experts_touched.inc(int(counts["moe_touched"].sum()))
+            self._obs.moe_experts_streamed.inc(int(counts["moe_streamed"].sum()))
         if "gdn_updates" in counts:
             self._obs.gdn_state_updates.inc(int(counts["gdn_updates"].sum()))
         if "latent_tokens_read" in counts:

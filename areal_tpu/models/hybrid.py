@@ -80,6 +80,7 @@ What a slot's recurrent state is, and who may write it, is in
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 from typing import Any
@@ -109,7 +110,7 @@ GDN_CHUNK = 64
 # layers, experts], and experts with at least one such row, [expert layers];
 # live slots whose delta-rule state a step advanced, [gdn layers]; cached
 # tokens of live slots a latent-attention layer read, [mla layers]
-COUNT_LEAVES = ("moe_load", "moe_touched", "gdn_updates", "latent_tokens_read")
+COUNT_LEAVES = ("moe_load", "moe_touched", "moe_streamed", "gdn_updates", "latent_tokens_read")
 
 
 def stack_name(kind: str, ffn: str) -> str:
@@ -317,9 +318,10 @@ class HybridConfig:
     def moe_count_shapes(self) -> dict[str, tuple[int, ...]]:
         """{leaf: shape} of the int32 counts a decode chunk takes back beside
         its tokens (COUNT_LEAVES); none for a model without experts. The load
-        has the router's width; ``moe_touched`` counts experts held here."""
+        has the router's width; ``moe_touched`` counts experts held here that
+        got a live row, ``moe_streamed`` those whose weights a step read."""
         n = self.num_moe_layers
-        return {"moe_load": (n, self.router_width), "moe_touched": (n,)} if n else {}
+        return {"moe_load": (n, self.router_width), "moe_touched": (n,), "moe_streamed": (n,)} if n else {}
 
     def state_shapes(self, slots: int) -> dict[str, tuple[tuple[int, ...], Any]]:
         """{leaf: (shape, dtype)} of the slot-indexed recurrent state. A conv
@@ -1543,7 +1545,9 @@ def _norm_out(cfg: HybridConfig, layer: dict, name: str, out):
 def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None):
     """x + the layer's feed-forward block, its RMSNorm where the block has
     it (on the input, or on the output); for an expert block also the rows
-    of ``live`` (default: all) each expert got, [E] int32."""
+    of ``live`` (default: all) each expert got, [E] int32. Where the expert
+    leaves come as their stacks (``_scan_layers`` ``whole``), the touched
+    experts alone are read."""
     rm = cfg.residual_multiplier
     if ffn == "dense":
         with jax.named_scope("mlp"):
@@ -1588,17 +1592,22 @@ def _runs(cfg: HybridConfig) -> list[tuple[str, str, int, int, int, int]]:
     return out
 
 
-def _scan_layers(cfg: HybridConfig, params: dict, carry, step):
+def _scan_layers(cfg: HybridConfig, params: dict, carry, step, whole: tuple[str, ...] = ()):
     """Run ``step(kind, ffn, carry, layer, j, f) -> carry`` over the layers in
     model order; ``layer`` is the layer's slice of its stack, ``j`` its index
     among the layers of its mixer kind (the state's and the KV pool's layer
     axis) and ``f`` among those of its FFN kind, both traced. One
-    ``lax.scan`` per run of one kind."""
+    ``lax.scan`` per run of one kind.
+
+    A leaf named in ``whole`` is handed over as ``moe.Stacked``, the stack
+    and the layer's index in it, as the page pools and the recurrent state go
+    by ``j``: for a Pallas launch that reads its layer where it lies."""
     for kind, ffn, lo, lo_kind, lo_ffn, n in _runs(cfg):
         stack = params[stack_name(kind, ffn)]
 
         def body(c, i, kind=kind, ffn=ffn, stack=stack, dj=lo_kind - lo, df=lo_ffn - lo):
-            layer = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), stack)
+            sliced = functools.partial(jax.lax.dynamic_index_in_dim, index=i, axis=0, keepdims=False)
+            layer = {k: moe.Stacked(a, i) if k in whole else jax.tree.map(sliced, a) for k, a in stack.items()}
             return step(kind, ffn, c, layer, i + dj if dj else i, i + df if df else i), None
 
         carry, _ = jax.lax.scan(body, carry, jnp.arange(lo, lo + n, dtype=jnp.int32))
@@ -1922,7 +1931,10 @@ def forward_decode_paged(
     recurrent state of the ``active`` slots only: an ended, parked or held
     slot's state is what it was, bit for bit. ``use_kernel`` also puts the
     Mamba recurrence on its Pallas kernel (ops/ssm_state_update.py), which
-    does not even read the state of a slot that is not live.
+    does not even read the state of a slot that is not live, and, on a TPU
+    where a full batch gives an expert a handful of rows
+    (``moe.takes_touched_form``), the expert matmuls on the launch that reads
+    the touched experts only (ops/moe_touched_experts.py).
 
     Where ``cache`` carries COUNT_LEAVES (a decode chunk puts them there for
     its own length), every expert layer adds to them what its experts got
@@ -1952,6 +1964,12 @@ def forward_decode_paged(
     else:
         live = kv_live = None
     rm = cfg.residual_multiplier
+    # the expert matmuls read the touched experts only where a full batch gives an expert a handful of rows
+    # (a Pallas launch over the expert stacks; off a TPU, XLA's form whatever the shapes: moe.takes_touched_form)
+    touched_form = (
+        use_kernel and cfg.num_moe_layers > 0 and jax.default_backend() == "tpu"
+        and moe.takes_touched_form(S, cfg.num_experts_per_tok, cfg.router_width, cfg.num_experts)
+    )
 
     def step(kind, ffn, carry, layer, j, f):
         x, c = carry
@@ -1975,6 +1993,8 @@ def forward_decode_paged(
         elif kind == "mla":
             # the ABSORBED form: H query rows over ONE latent row a cached token
             with jax.named_scope("mla_proj"):
+                # sliced HERE: XLA copies a layer's ``W_kvb`` out of the stack into fast memory, and names the copy after the slice
+                layer = {**layer, "w_kvb": jax.lax.dynamic_index_in_dim(*layer["w_kvb"], 0, keepdims=False)}
                 h = _norm_in(cfg, layer, "input_norm", x)
                 q_nope, q_rope, lat, k_r = _mla_in(cfg, layer, h, positions)
                 q = mla_absorbed_query(cfg, layer, q_nope, q_rope)
@@ -2035,7 +2055,13 @@ def forward_decode_paged(
         return x, c
 
     x = _embed(params, cfg, ids)
-    x, out_cache = _scan_layers(cfg, params, (x, dict(cache)), step)
+    # the expert stacks for the launch that reads them where they lie; ``W_kvb`` to be sliced under its own scope
+    whole = (moe.EXPERT_LEAVES if touched_form else ()) + ("w_kvb",)
+    x, out_cache = _scan_layers(cfg, params, (x, dict(cache)), step, whole=whole)
+    if "moe_streamed" in cache:  # the experts whose weights this step read: the touched ones, or every one held
+        with jax.named_scope("moe_router"):
+            read = out_cache["moe_touched"] - cache["moe_touched"] if touched_form else cfg.num_experts
+            out_cache["moe_streamed"] = cache["moe_streamed"] + read
     with jax.named_scope("lm_head"):
         hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     return hidden, out_cache

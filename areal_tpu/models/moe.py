@@ -14,7 +14,11 @@ Two dispatch strategies, selected by ``cfg.moe_dropless``:
 - **dropless (default)**: every routed token is computed (``expert_ffn``),
   in one of two forms chosen by the shapes alone (``takes_dense_form``). Up
   to ``DENSE_ROWS`` rows (a decode step, a short prompt) every local expert
-  runs on every row with gate 0 for the unchosen: each weight streamed once by a plain matmul. Above that
+  runs on every row with gate 0 for the unchosen: each weight streamed once by a plain matmul
+  (or, where a full batch gives an expert a handful of rows and experts go
+  untouched, the same sum over the touched experts only, their weights read
+  out of the layer stack by ``ops/moe_touched_experts.py``:
+  ``takes_touched_form``). Above that
   (a prefill, a train step) the (token, k) assignments targeting local
   experts are stably sorted by expert id and fed through ``megablox.gmm`` —
   jax's Pallas grouped-matmul TPU kernel, with tiles chosen from the shapes
@@ -29,13 +33,15 @@ Two dispatch strategies, selected by ``cfg.moe_dropless``:
 
 Scopes (docs/observability.md): ``moe_router`` (scores, bias, top-k, gates,
 load), ``moe_dispatch`` (sort, counts, gather; the dense form's gate
-matrix), ``moe_experts`` (the three matmuls and the gating product),
+matrix, the touched form's list), ``moe_experts`` (the three matmuls and the gating product,
+or the touched-expert launch),
 ``moe_combine`` (the routed form's gather back and sum).
 """
 
 from __future__ import annotations
 
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +72,20 @@ def pinned_gmm():
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     return pin_signature(gmm, _EXPECTED_GMM_PARAMS)
+
+
+# an expert block's three stacked matrices: [E_loc, D, F] x 2 and [E_loc, F, D] a layer
+EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
+
+
+class Stacked(NamedTuple):
+    """A layer's leaf handed over as the whole stack ``[layers, ...]`` and the
+    layer's index in it: a Pallas launch reads its layer out of the stack
+    where it lies, while a slice handed to a custom call is a COPY of the
+    layer (XLA fuses the slice into its own matmuls only)."""
+
+    stack: jax.Array
+    index: jax.Array
 
 
 def _shard(x, spec):
@@ -191,6 +211,47 @@ def takes_dense_form(rows: int, local_experts: int) -> bool:
     return rows <= DENSE_ROWS and rows * local_experts <= DENSE_ROWS * DENSE_EXPERTS
 
 
+# Up to this many assignments an expert gets from a FULL batch (rows x experts a
+# token over the experts the router scores), a decode step on a TPU reads the
+# weights of the touched experts only (``ops/moe_touched_experts.py``: the dense
+# form's arithmetic over a compacted list, one Pallas launch on the expert
+# STACKS). What it can win is the share of held experts no live row chose:
+# about exp(-assignments x occupancy) under an even router, more under a skewed
+# one. At 3 (64 rows x top-6 over 128) a third-full batch leaves 35% untouched
+# by that count and 52% in the cell (7.7 of 16 held experts touched a layer and
+# step at 22 live rows, PERF.md PR 37); at 6 a tenth; at 12 and more (128 rows
+# x top-4 over 32: 16; a prefill of 256 rows and more: 12-48) under 2% whatever
+# the batch, and XLA's matmuls sit at 91% of the weights' bytes' time: nothing
+# to skip. Measured on a v5e, us a layer (tools/moe_probe --touched, PR 38):
+# 16 experts of [2048, 768] at 64 rows, 4 / 8 / 12 / 16 of 16 touched: 60.9 /
+# 110.6 / 161.1 / 209.7 against XLA's 210.5 whatever was touched (76 / 83 / 86
+# / 88% of the touched bytes' time; 22 live rows touching 10: 135.0 against
+# 210.3, the routed form 363.0); 32 experts of [2048, 1792] at 128 rows, 8 / 16
+# / 32 of 32: 248.8 / 480.2 / 945.7 against 948.1. The launch never read slower
+# than XLA's form, so the constant marks where it stops winning, not a crossing:
+TOUCHED_ASSIGNMENTS = 6
+
+
+def takes_touched_form(rows: int, experts_per_row: int, router_experts: int, local_experts: int) -> bool:
+    """Whether a step of ``rows`` rows should read only the experts its live
+    rows chose: by shapes alone, beside ``takes_dense_form``."""
+    return takes_dense_form(rows, local_experts) and rows * experts_per_row <= TOUCHED_ASSIGNMENTS * router_experts
+
+
+def touched_list(load: jax.Array, e0, n_local: int) -> tuple[jax.Array, jax.Array]:
+    """(local ids [n_local] int32 of the held experts that got a row,
+    compacted to the front in their order, and how many they are) from
+    ``load`` [E], the rows that chose each expert: the slice the counter of
+    touched experts reads. Compares and sums over [n_local, n_local], no
+    sort and no scatter: the argsort of a step's live list is 15 us on a v5e
+    (PERF.md, PR 29), and this runs once a layer."""
+    held = jax.lax.dynamic_slice_in_dim(load, e0, n_local) > 0
+    ids = jnp.arange(n_local, dtype=jnp.int32)
+    rank = jnp.sum(held[None, :] & (ids[None, :] < ids[:, None]), axis=1, dtype=jnp.int32)  # touched experts before each
+    at = held[None, :] & (rank[None, :] == ids[:, None])  # [place in the list, expert]
+    return jnp.sum(jnp.where(at, ids[None, :], 0), axis=1, dtype=jnp.int32), jnp.sum(held, dtype=jnp.int32)
+
+
 def gmm_tiles(m: int, k: int, n: int, groups: int = 1) -> tuple[int, int, int]:
     """(tm, tk, tn) for ``megablox.gmm`` from the shapes alone, as
     ``ops/attention.py flash_tiles`` does for flash: k and n tiles of up to
@@ -237,6 +298,21 @@ def _experts_dense(x, wg, wu, wd, top_e, gates, e0):
         u1 = jnp.einsum("etd,edf->etf", xe, wu)
         y = jax.nn.silu(g1.astype(jnp.float32)) * u1.astype(jnp.float32) * ge[:, :, None]
         return jnp.einsum("etf,efd->td", y.astype(x.dtype), wd, preferred_element_type=jnp.float32)
+
+
+def _experts_touched(x, wg: Stacked, wu: Stacked, wd: Stacked, top_e, gates, load, e0):
+    """The dense form's sum over the experts that got a live row, their
+    weights read out of the stacks ``[layers, E_loc, ...]`` by one Pallas
+    launch; an expert with no live row has gate 0 on every row and adds
+    exactly 0, so leaving it out is the same result."""
+    from areal_tpu.ops.moe_touched_experts import touched_expert_ffn
+
+    E_loc = wg.stack.shape[1]
+    with jax.named_scope("moe_dispatch"):
+        ge = _local_gates(top_e, gates, e0, E_loc)
+        touched, n_touched = touched_list(load, e0, E_loc)
+    with jax.named_scope("moe_experts"):
+        return touched_expert_ffn(x, ge, wg.stack, wu.stack, wd.stack, wg.index, touched, n_touched)
 
 
 def _experts_routed(x, wg, wu, wd, top_e, gates, e0, interpret: bool):
@@ -299,7 +375,10 @@ def expert_ffn(x, layer: dict, cfg, *, live=None, e0=0):
     them float32, which matters to a bfloat16 train step of ``qwen3_moe``
     by one rounding of each ([T*K, F] x 2 x 4 B a layer is what it cost). ``live`` [T] bool marks the rows that hold a request;
     the others count as no load and, where it is free (the dense form),
-    take no part."""
+    take no part. Where the three expert leaves come ``Stacked`` (the caller's
+    choice by ``takes_touched_form``, since the caller keeps the stacks
+    unsliced), the touched form reads the experts ``load`` says got a row and
+    no other."""
     with jax.named_scope("moe_router"):
         scores, gates, top_e = route(x, layer["w_router"], cfg, layer.get("router_bias"))
         chose = jax.nn.one_hot(top_e, scores.shape[-1], dtype=jnp.int32).sum(1)  # [T, E]
@@ -308,7 +387,9 @@ def expert_ffn(x, layer: dict, cfg, *, live=None, e0=0):
             chose = chose * live[:, None]
         load = chose.sum(0)
     wg, wu, wd = layer["we_gate"], layer["we_up"], layer["we_down"]
-    if takes_dense_form(x.shape[0], wg.shape[0]):
+    if isinstance(wg, Stacked):
+        out = _experts_touched(x, wg, wu, wd, top_e, gates, load, e0)
+    elif takes_dense_form(x.shape[0], wg.shape[0]):
         out = _experts_dense(x, wg, wu, wd, top_e, gates, e0)
     else:
         # platform decides compiled-or-interpret, nothing else: on a TPU gmm

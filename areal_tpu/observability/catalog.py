@@ -191,6 +191,12 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "row, summed over decode steps and expert layers (each costs one "
             "read of its weights).",
         ),
+        moe_experts_streamed=r.counter(
+            "areal_decode_moe_experts_streamed_total",
+            "Experts held by this replica whose weights a decode step READ, "
+            "summed over steps and expert layers: every held expert under "
+            "XLA's matmuls, the touched ones under the touched-expert kernel.",
+        ),
     )
 
 

@@ -276,6 +276,71 @@ def _cases_paged_latent(compiled: bool = False) -> Iterator[dict]:
 
 
 # ---------------------------------------------------------------------------
+# the expert FFN over the touched experts only (ops/moe_touched_experts.py)
+# against a loop over the listed experts in float32, rounded to the rows' type
+# where the dense form rounds. The skip is shown by POISON: every expert off the list, and every other layer, is NaN (XLA's
+# every-expert form gives NaN there: gate 0 x NaN)
+# ---------------------------------------------------------------------------
+
+
+@register_kernel("moe_touched_experts")
+def _cases_moe_touched(compiled: bool = False) -> Iterator[dict]:
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops.moe_touched_experts import touched_expert_ffn
+
+    # the latent cell's launch (64 slots, 16 held experts of [2048, 768]), or a size the interpreter holds
+    L, E, D, F, T = (3, 16, 2048, 768, 64) if compiled else (3, 6, 128, 256, 12)
+
+    def case(label, listed, dtype, layer=1, tol=1e-5):
+        listed = list(listed)
+
+        def build():
+            on = jnp.zeros((L, E), bool).at[layer, jnp.asarray(listed, jnp.int32)].set(True)[:, :, None, None]
+            rows_on = jnp.zeros((T, E), bool).at[:, jnp.asarray(listed, jnp.int32)].set(True)
+            return {
+                "x": _normal(3, (T, D), dtype),
+                # a row chooses about half the listed experts; no other has a gate (outputs of magnitude <= 1)
+                "gate": jnp.where(rows_on & (_normal(4, (T, E)) > 0), jnp.abs(_normal(5, (T, E))) / max(len(listed), 1), 0.0),
+                "wg": jnp.where(on, _normal(6, (L, E, D, F), dtype) * D**-0.5, jnp.nan),
+                "wu": jnp.where(on, _normal(7, (L, E, D, F), dtype) * D**-0.5, jnp.nan),
+                "wd": jnp.where(on, _normal(8, (L, E, F, D), dtype) * F**-0.5, jnp.nan),
+            }
+
+        ids = jnp.asarray(listed + [0] * (E - len(listed)), jnp.int32)
+
+        def kernel(inp):
+            return touched_expert_ffn(
+                inp["x"], inp["gate"], inp["wg"], inp["wu"], inp["wd"], jnp.int32(layer), ids, jnp.int32(len(listed)), interpret=not compiled
+            )
+
+        def reference(inp):
+            x = inp["x"].astype(jnp.float32)
+            rounded = lambda a: a.astype(dtype).astype(jnp.float32)  # noqa: E731
+            out = jnp.zeros((T, D), jnp.float32)
+            for e in listed:
+                wg, wu, wd = (inp[k][layer, e].astype(jnp.float32) for k in ("wg", "wu", "wd"))
+                out = out + rounded(jax.nn.silu(rounded(x @ wg)) * rounded(x @ wu) * inp["gate"][:, e : e + 1]) @ wd
+            return out
+
+        return {"case": label, "build": build, "kernel": kernel, "reference": reference, "tol": tol}
+
+    if compiled:
+        yield case("bf16-half-touched", [1, 2, 5, 7, 8, 11, 12, 15], jnp.bfloat16, tol=CHIP_TOL)
+        yield case("bf16-all-touched", range(E), jnp.bfloat16, layer=2, tol=CHIP_TOL)
+        yield case("bf16-none-touched", [], jnp.bfloat16, layer=0, tol=0.0)
+        return
+    yield case("f32-all-touched", range(E), jnp.float32)
+    yield case("f32-one-touched", [2], jnp.float32, layer=0)
+    yield case("f32-last-only", [E - 1], jnp.float32, layer=L - 1)
+    yield case("f32-more-than-the-ring", [4, 0, 3, 5], jnp.float32)  # any order: the list is the kernel's to walk, not to sort
+    yield case("f32-none-touched", [], jnp.float32, tol=0.0)
+    # a product one bfloat16 step off where the two sides add in another order moves an output by 1e-3
+    yield case("bf16-three-touched", [0, 3, 4], jnp.bfloat16, tol=5e-3)
+
+
+# ---------------------------------------------------------------------------
 # a decode step's KV rows (ops/paged_kv_write.py): one launch over the live
 # slots against the per-head scatters, BIT-EQUAL over the whole pool
 # ---------------------------------------------------------------------------

@@ -176,6 +176,7 @@ def test_counts_of_a_decode_chunk_and_the_status_page(served):
     _held(eng)
     load0 = np.asarray(eng.moe_status()["load"])
     r0, t0, a0 = eng._obs.latent_tokens_read.get(), eng._obs.moe_experts_touched.get(), eng._obs.moe_assignments.get()
+    s0 = eng._obs.moe_experts_streamed.get()
     eng.continue_generation()
     prompt = np.random.default_rng(7).integers(0, cfg["vocab_size"], 21).tolist()
     r = _gen(eng, prompt, 24)
@@ -188,6 +189,9 @@ def test_counts_of_a_decode_chunk_and_the_status_page(served):
         load = np.asarray(status["load"]) - load0
         assert status["held"] == [0, 4] and load.shape == (3, 8) and load.sum() == eng._obs.moe_assignments.get() - a0 == 24 * 3 * 3
         assert eng._obs.moe_experts_touched.get() - t0 == int((load[:, :4]).sum())  # one live row: an expert's rows are its touches
+        # XLA's form (no TPU here) reads all 4 held experts a step and layer, live rows or none: whole chunks of steps
+        streamed = eng._obs.moe_experts_streamed.get() - s0
+        assert streamed >= 24 * 3 * 4 and streamed % (3 * 4) == 0
         assert 0 < int(load[:, 4:].sum())  # some choices fell on experts that are not here: left out, still counted as load
         assert set(eng.cache) == {"k"}  # the counts are no part of the cache
     finally:

@@ -167,7 +167,7 @@ def test_moe_touched_counts_held_experts_only():
     S = 3
     cache = paged_kv.init_paged_cache(mcfg, S * WP + 1, PSZ, slots=S)
     cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
-    assert mcfg.moe_count_shapes == {"moe_load": (3, 8), "moe_touched": (3,)}
+    assert mcfg.moe_count_shapes == {"moe_load": (3, 8), "moe_touched": (3,), "moe_streamed": (3,)}
     pt = np.zeros((S, WP), np.int32)
     pt[0], pt[1] = np.arange(1, WP + 1), np.arange(WP + 1, 2 * WP + 1)
     for t in range(2):

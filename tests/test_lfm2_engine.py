@@ -171,7 +171,7 @@ def test_expert_load_counts_live_rows_only(served):
     eng, cfg = served
     _held(eng)
     load0 = np.asarray(eng.moe_status()["load"])
-    a0, t0 = eng._obs.moe_assignments.get(), eng._obs.moe_experts_touched.get()
+    a0, t0, s0 = eng._obs.moe_assignments.get(), eng._obs.moe_experts_touched.get(), eng._obs.moe_experts_streamed.get()
     chunks0 = eng.stats["chunks"]
     eng.continue_generation()
     prompt = np.random.default_rng(7).integers(0, cfg["vocab_size"], 21).tolist()
@@ -184,6 +184,8 @@ def test_expert_load_counts_live_rows_only(served):
         # every decode step the slot was active in: 24 tokens (the first from the prompt's last token)
         assert steps == 24 * 3 * 5 and (load.sum(axis=1) == 24 * 3).all()
         assert eng._obs.moe_experts_touched.get() - t0 == 24 * 3 * 5  # one live row touches exactly top-k experts a layer
+        streamed = eng._obs.moe_experts_streamed.get() - s0  # and XLA's form reads all 8 a layer on every step of a chunk, live rows or none
+        assert streamed >= 24 * 8 * 5 and streamed % (8 * 5) == 0
         assert eng.stats["chunks"] - chunks0 >= 6
         assert len(r.output_tokens) == 24
         conv = np.asarray(eng.cache["conv"])

@@ -88,7 +88,7 @@ def test_config_from_a_published_config_and_what_it_refuses():
     assert (mcfg.router_score, mcfg.router_bias, mcfg.router_norm_eps, mcfg.qk_norm, mcfg.rope_theta) == ("sigmoid", True, 1e-6, True, 1e6)
     assert mcfg.has_recurrent_state and mcfg.num_kv_layers == 2 and mcfg.num_moe_layers == 5
     assert mcfg.state_shapes(4) == {"conv": ((5, 4, 2 * 64), jnp.dtype("bfloat16"))}
-    assert mcfg.moe_count_shapes == {"moe_load": (5, 8), "moe_touched": (5,)}
+    assert mcfg.moe_count_shapes == {"moe_load": (5, 8), "moe_touched": (5,), "moe_streamed": (5,)}
     assert models.config_from_hf_dict(mcfg.to_hf_dict()) == mcfg  # a saved checkpoint's config.json reads back
     assert set(hybrid.param_partition_specs(mcfg)) == {"embed", "final_norm", "conv", "attention_moe", "conv_moe"}
     names = hybrid.hf_name_map(mcfg)

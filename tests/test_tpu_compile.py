@@ -232,6 +232,22 @@ def _gmm():
     return (lambda x, w, gs: gmm(x, w, gs, tiling=(128, 128, 128), interpret=False)), args
 
 
+def _moe_touched(slots=64, experts=16, width=768, layers=47):
+    """The touched-expert launch as cell 7's decode step makes it: 64 rows
+    on the stacks of 47 layers x 16 held experts of [2048, 768] (two experts'
+    matrices in VMEM: 18.9 MB, past the default scoped limit)."""
+    from areal_tpu.ops.moe_touched_experts import touched_expert_ffn
+
+    def args(S):
+        return [
+            S((slots, 2048), jnp.bfloat16), S((slots, experts), jnp.float32),
+            S((layers, experts, 2048, width), jnp.bfloat16), S((layers, experts, 2048, width), jnp.bfloat16),
+            S((layers, experts, width, 2048), jnp.bfloat16), S((), jnp.int32), S((experts,), jnp.int32), S((), jnp.int32),
+        ]
+
+    return touched_expert_ffn, args
+
+
 CASES = {
     "paged_decode_bf16": lambda: _decode(jnp.bfloat16),
     "paged_decode_int8": lambda: _decode(jnp.int8),
@@ -274,6 +290,9 @@ CASES = {
     "tree_attention_fwd": lambda: _tree(False),
     "tree_attention_bwd": lambda: _tree(True),
     "megablox_gmm": _gmm,
+    "moe_touched_experts_kanana2": _moe_touched,
+    # 32 experts of [2048, 1792] (lfm2's, which the shape rule leaves on XLA's form): 44 MB of buffers
+    "moe_touched_experts_wide": lambda: _moe_touched(128, 32, 1792, 12),
 }
 
 
@@ -300,6 +319,7 @@ KERNEL_NAMES = {
     "ssm_state_update_f32": ("ssm_state_update",),
     "gdn_state_update_f32": ("gdn_state_update",),
     "paged_kv_write_int8": ("paged_kv_write",),
+    "moe_touched_experts_kanana2": ("moe_touched_experts",),
     "tree_attention_bwd": ("tree_attn_fwd", "tree_attn_bwd_dq", "tree_attn_bwd_dkv"),
 }
 
@@ -449,6 +469,8 @@ def test_lfm2_decode_steps_compile_for_v5e(chip, monkeypatch):
     assert "paged_decode_attn" in text and "paged_kv_write" in text
     assert not [ln for ln in text.splitlines() if " copy(" in ln and "bf16[1,32,2048,1792]" in ln]
     assert compiled.memory_analysis().temp_size_in_bytes < 200e6
+    # 128 rows x top-4 over 32 experts: 16 assignments an expert, every expert touched: XLA's matmuls, not the touched-expert launch
+    assert "moe_touched_experts" not in text
 
 
 def test_lfm2_prefill_compiles_for_v5e(chip, monkeypatch):
@@ -620,7 +642,10 @@ def test_kanana2_decode_steps_compile_for_v5e(chip, monkeypatch):
     """Two decode steps as the engine's chunk runs them: the latent kernel
     and the row writer as custom calls on the stacked pool where it lies (no
     copy of the page pool, no layer slice of it), the absorbed products
-    without a second copy of ``W_kvb``'s stack, the counts in the carry."""
+    without a second copy of ``W_kvb``'s stack, the counts in the carry; the
+    expert matmuls as the touched-expert launch on the expert STACKS (64 rows
+    x top-6 over a router of 128: 3 assignments an expert), with no layer of
+    them sliced or copied out for it (151 MB a layer)."""
     from areal_tpu.models import hybrid
 
     mcfg, params, cache = _kanana2(chip, monkeypatch)
@@ -643,9 +668,15 @@ def test_kanana2_decode_steps_compile_for_v5e(chip, monkeypatch):
     assert "paged_latent_attn" in text and "paged_kv_write" in text and "paged_decode_attn" not in text
     pool = "bf16[3,1,477,128,640]"
     assert not [ln for ln in text.splitlines() if " copy(" in ln and (pool in ln or "bf16[1,477,128,640]" in ln)]
-    assert not [ln for ln in text.splitlines() if " copy(" in ln and "bf16[2,16,2048,768]" in ln]  # nor of an expert stack
+    assert "moe_touched_experts" in text
+    # no op's RESULT is a layer of an expert stack, or the stack: no copy, dynamic-slice or fusion of them
+    made = re.compile(r"= bf16\[(2,|1,)?16,(2048,768|768,2048)\]\S* (?!parameter|get-tuple-element)")
+    assert not [ln for ln in text.splitlines() if made.search(ln)]
     # nor of W_q's stack, which XLA re-lays out whole where the projection's output is split without a barrier
     assert not [ln for ln in text.splitlines() if " copy(" in ln and "bf16[2,2048,6144]" in ln]
+    # XLA copies a layer's W_kvb out of the stack into fast memory and names the copy after the slice: made under the scope that reads it
+    kvb = [ln for ln in text.splitlines() if "= bf16[1,512,8192]" in ln and "dynamic_slice" in ln]
+    assert kvb and all("mla_proj/dynamic_slice" in ln for ln in kvb)
     # the pool is 234 MB here: nothing of its size among the temporaries
     assert compiled.memory_analysis().temp_size_in_bytes < 200e6
 
