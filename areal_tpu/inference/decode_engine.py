@@ -84,6 +84,12 @@ _SERVED_FORM_LEAVES = frozenset(
 )
 _TOPK_CAP = 1024  # static candidate-set size for per-slot top-k/top-p
 _PREFILL_SIZES = (8, 4, 2, 1)  # batched-prefill group sizes (compile variants)
+# bytes of residual stream (rows x bucket x hidden) a prefill program may hold:
+# every group size at 8 x 1,024 tokens of hidden 4,096; ONE row a program
+# where a prompt alone is past it (4,096 tokens of hidden 6,144 are 50 MB),
+# since a long prompt amortises the weights by itself and a second row would
+# only add its temporaries
+_PREFILL_STREAM_BYTES = 64 << 20
 
 
 @dataclass
@@ -617,7 +623,8 @@ class DecodeEngine:
             else False
         )
         if cfg.kv_hbm_gb is not None:
-            # what a page row is, is the model configuration's to say
+            # what a page row is, is the model configuration's to say: its
+            # pools may differ in width (a latent row beside an index key)
             heads, lanes = mcfg.kv_pools["k"]
             n_pages = paged_kv.n_pages_for_budget(
                 int(cfg.kv_hbm_gb * (1 << 30)),
@@ -627,7 +634,7 @@ class DecodeEngine:
                 lanes,
                 jnp.dtype(mcfg.jax_dtype).itemsize,
                 quant=kv_quant,
-                pools=len(mcfg.kv_pools),
+                pools=mcfg.kv_pools,
             )
         else:
             n_pages = S * self._maxp + 1  # +1: trash page 0
@@ -708,6 +715,13 @@ class DecodeEngine:
     # every 256-multiple (512 prefill programs; a ~10x startup blowup).
     # Buckets outside the warmed set still work; they compile on first hit.
     _WARM_DENSE_CAP = 4096
+
+    def _prefill_sizes(self, bucket: int) -> tuple[int, ...]:
+        """The group sizes a prefill program of this bucket comes in: those of
+        ``_PREFILL_SIZES`` whose residual stream stays inside
+        ``_PREFILL_STREAM_BYTES``, at least (1,)."""
+        row = bucket * self.model_cfg.hidden_size * jnp.dtype(self.model_cfg.jax_dtype).itemsize
+        return tuple(a for a in _PREFILL_SIZES if a * row <= _PREFILL_STREAM_BYTES) or (1,)
 
     def _reachable_prompt_buckets(self) -> list[int]:
         """Values ``min(T, round_up_to_bucket(plen, 256))`` the admission
@@ -863,7 +877,7 @@ class DecodeEngine:
                 break
             n *= 2
         for bucket in prompt_buckets:
-            for A in _PREFILL_SIZES:
+            for A in self._prefill_sizes(bucket):
                 tasks.append(
                     lambda A=A, bucket=bucket: self._prefill_fn(A, bucket).lower(
                         params_s,
@@ -1848,6 +1862,20 @@ class DecodeEngine:
             self._obs.gdn_state_updates.inc(int(counts["gdn_updates"].sum()))
         if "latent_tokens_read" in counts:
             self._obs.latent_tokens_read.inc(int(counts["latent_tokens_read"].sum()))
+        if "index_tokens_scored" in counts:
+            self._obs.index_tokens_scored.inc(int(counts["index_tokens_scored"].sum()))
+            self._obs.latent_tokens_selected.inc(int(counts["latent_tokens_selected"].sum()))
+
+    def sparse_attention_status(self) -> dict | None:
+        """/statusz ``sparse_attention``: what a learned index selects for a
+        decode step's queries (``index_topk`` cached tokens a slot and
+        layer) and the form the selected rows are read in: ``masked`` =
+        every page that holds tokens of the slot is fetched and the
+        unselected rows meet a probability of 0 (the one form this engine
+        has: PERF.md has what a gather of the selected rows cost). None for
+        a model without an index."""
+        topk = getattr(self.model_cfg, "index_topk", 0) if self.model_cfg is not None else 0
+        return {"index_topk": int(topk), "read_form": "masked"} if topk else None
 
     def moe_status(self) -> dict | None:
         """/statusz ``moe``: ``load`` = rows of live slots every expert of
@@ -2689,8 +2717,9 @@ class DecodeEngine:
         with self._kphase("prefill"):
             for bucket, group in sorted(by_bucket.items()):
                 i = 0
+                sizes = self._prefill_sizes(bucket)
                 while i < len(group):
-                    A = next(a for a in _PREFILL_SIZES if a <= len(group) - i)
+                    A = next(a for a in sizes if a <= len(group) - i)
                     rows.extend(self._prefill_group(group[i : i + A], bucket))
                     i += A
         # warm admissions group by SUFFIX bucket (the only tokens prefilled)
@@ -2933,12 +2962,24 @@ class DecodeEngine:
         npg = -(-bucket // psz)  # ceil: tiny max_seq_len can make bucket < psz
         admitted: list[tuple[_Task, int]] = []
         page_rows: list[np.ndarray] = []
+        # what the slots already decoding, and those admitted here, take for
+        # their next chunks (one in flight, one ahead) stays free: a wave of
+        # long prompts that took the pool's last page would have the first
+        # decode step preempt one of them for it
+        per_slot = -(-(2 * self.config.decode_steps_per_call + 1) // psz)
+        decoding = int(np.count_nonzero(self._state["active"]))
         for task, slot in group:
             plen = len(task.req.input_ids)
             need = -(-plen // psz)
-            pages = self.pool.alloc(need)
-            while pages is None and self._reclaim_pages(need):
-                pages = self.pool.alloc(need)
+            # nothing decodes and nothing was admitted: nobody to wait for, so nothing to keep
+            keep = (decoding + len(admitted) + 1) * per_slot if decoding or admitted else 0
+
+            def take():
+                return self.pool.alloc(need) if self.pool.available >= need + keep else None
+
+            pages = take()
+            while pages is None and self._reclaim_pages(need + keep):
+                pages = take()
             if pages is None:
                 self._backlog.append(task)  # pool pressure: retry later
                 continue

@@ -369,28 +369,39 @@ def quantize_pages(pages: jax.Array, dtype=jnp.int8) -> tuple[jax.Array, jax.Arr
     return q, jnp.swapaxes(scale, -1, -2)
 
 
+def kv_token_bytes(pools: dict[str, tuple[int, int]], n_layers: int, itemsize: int, quant=False) -> int:
+    """Bytes a cached token holds across ``n_layers`` layers in the pools
+    ``{name: (heads, lanes)}`` of ``cfg.kv_pools``: K and V, a latent row, or
+    a latent row and an index key of another width beside it. ``quant``
+    (bool | "int8" | "fp8"): both quantized dtypes are 1 byte per element
+    plus a 4-byte f32 scale per token vector."""
+    return n_layers * sum(heads * (lanes * (1 if quant else itemsize) + (4 if quant else 0)) for heads, lanes in pools.values())
+
+
 def n_pages_for_budget(
     budget_bytes: int, n_layers: int, num_kv_heads: int, page_size: int,
-    head_dim: int, itemsize: int, quant=False, pools: int = 2,
+    head_dim: int, itemsize: int, quant=False, pools: "int | dict[str, tuple[int, int]]" = 2,
 ) -> int:
     """Pages fitting a KV HBM budget (every pool across all layers per
-    page: K and V, or with ``pools`` 1 the one latent row a token,
-    ``cfg.kv_pools``). ``quant`` (bool | "int8" | "fp8"): both quantized
-    dtypes are 1 byte per element plus a 4-byte f32 scale per token vector."""
-    vec_bytes = head_dim * (1 if quant else itemsize) + (4 if quant else 0)
-    page_bytes = pools * n_layers * num_kv_heads * page_size * vec_bytes
-    return max(2, budget_bytes // page_bytes)
+    page). ``pools`` is ``cfg.kv_pools``, whose rows may differ in width, or
+    a count of pools of ``num_kv_heads`` x ``head_dim`` each (K and V: 2)."""
+    if isinstance(pools, int):
+        pools = {str(i): (num_kv_heads, head_dim) for i in range(pools)}
+    return max(2, budget_bytes // (page_size * kv_token_bytes(pools, n_layers, itemsize, quant)))
 
 
 # The cache holds two kinds of thing under one dict, donated and returned
 # whole by every serving program. K and V PAGES (``k``, ``v`` and their
 # scales) exist for the layers that attend (``cfg.num_kv_layers``): paged,
 # aliased between requests by refcount, harmless to write twice. A
-# latent-attention model (``cfg.kv_pools`` names ``k`` alone) keeps ONE row a
+# latent-attention model (``cfg.kv_pools`` names no ``v``) keeps ONE row a
 # token and layer there, ``[c | k_r | 0]`` under a single "head": the key all
 # query heads share, whose first ``kv_lora_rank`` lanes are its value too;
 # the pool, the refcounts, a group's aliasing and the copy of the last
-# partial page are the same code on a row of another width. The
+# partial page are the same code on a row of another width. Where its layers
+# have a learned index, the token's index key lies in a second pool ``idx`` of
+# its own width (128 lanes beside 640) under the SAME page ids: one page
+# table, one refcount, every program writes and copies both. The
 # slot-indexed recurrent STATE of a model with state-space layers
 # (``cfg.state_shapes(slots)``; none for a model that only attends) is none
 # of these: one row per decode slot, never aliased, and it cannot be cut back
@@ -480,8 +491,9 @@ def scatter_prefill(cache: dict, ks: jax.Array, vs: jax.Array | None, flat_pages
 
 def scatter_prefill_layer(pool: jax.Array, layer: jax.Array, rows: jax.Array, flat_pages: jax.Array, page_size: int) -> jax.Array:
     """One layer of ``scatter_prefill`` for a pool of ONE row a token (a
-    latent model's): ``rows`` [A, bucket, 1, lanes] of layer ``layer`` (traced)
-    into ``pool`` [n_layers, 1, N, page_size, lanes] at the rows' pages."""
+    latent model's rows, or its index keys: the lanes are the pool's own):
+    ``rows`` [A, bucket, 1, lanes] of layer ``layer`` (traced) into ``pool``
+    [n_layers, 1, N, page_size, lanes] at the rows' pages."""
     A, bucket, one, lanes = rows.shape
     if bucket % page_size:
         rows = jnp.pad(rows, ((0, 0), (0, page_size - bucket % page_size), (0, 0), (0, 0)))
@@ -531,10 +543,13 @@ def write_decode_rows(
     write_page: jax.Array,  # [S] int32
     write_off: jax.Array,  # [S] int32
     live: tuple[jax.Array, jax.Array] | None = None,
+    more: dict[str, jax.Array] | None = None,
 ) -> dict:
     """A decode step's KV write of one layer: slot s's row lands at
     cache[layer, :, write_page[s], write_off[s]], quantized with its scale
-    where the cache holds quantized pages.
+    where the cache holds quantized pages. ``more`` = {pool: rows [S, heads,
+    lanes]} names what else the token leaves behind on the same page and row
+    (a latent model's index key, in a pool of its own width): the same launch.
 
     ``live`` = (slots with the live ones first, how many are live) puts the
     write on the Pallas launch (ops/paged_kv_write.py): the live slots' rows
@@ -546,7 +561,7 @@ def write_decode_rows(
     pool-sized copies a layer; per head: none). That path serves off the TPU
     and under tensor parallelism, and is what the tests hold the kernel to."""
     cache = dict(cache)
-    rows = {"k": k} if v is None else {"k": k, "v": v}
+    rows = {"k": k, **(more or {})} if v is None else {"k": k, "v": v}
     pages, scales = tuple(rows), ()
     if "k_scale" in cache:
         scales = tuple(f"{n}_scale" for n in pages)
@@ -570,10 +585,11 @@ def write_decode_rows(
         cache.update(zip(pages, new_pages))
         cache.update(zip(scales, new_scales))
         return cache
-    for h in range(k.shape[1]):
-        for n in pages:
+    for n in pages:
+        for h in range(rows[n].shape[1]):
             cache[n] = cache[n].at[layer, h, write_page, write_off].set(rows[n][:, h])
-        for n in scales:  # lane-major in the pool: [L, KH, N, 1, psz]
+    for n in scales:  # lane-major in the pool: [L, KH, N, 1, psz]
+        for h in range(rows[n].shape[1]):
             cache[n] = cache[n].at[layer, h, write_page, 0, write_off].set(rows[n][:, h])
     return cache
 
@@ -606,7 +622,7 @@ def copy_pages(
         if name in STATE_LEAVES:
             with jax.named_scope("state_write"):
                 cache[name] = copied(cache[name], 1, dst_slots, src_slots)
-        else:  # k/v (+ k_scale/v_scale under int8 KV), or a latent model's k alone
+        else:  # k/v (+ k_scale/v_scale under int8 KV), or a latent model's k (and its index keys, idx)
             cache[name] = copied(cache[name], 2, dst, src)
     return cache
 
@@ -628,6 +644,7 @@ def paged_attention_xla(
     k_scales: jax.Array | None = None,  # [KH, N, 1, psz] (int8/fp8 KV)
     v_scales: jax.Array | None = None,
     sm_scale: float | None = None,  # softmax scale; default 1/sqrt(hd)
+    select: jax.Array | None = None,  # [S, W] bool: the cached tokens each slot attends to; default all
 ) -> jax.Array:
     """Reference/CPU path: gather the window's pages, grouped masked einsum —
     numerically identical to the dense engine's attention."""
@@ -658,6 +675,8 @@ def paged_attention_xla(
         hd**-0.5 if sm_scale is None else sm_scale
     )
     valid = jnp.arange(W)[None, :] < lengths[:, None]
+    if select is not None:
+        valid = valid & select
     logits = jnp.where(valid[:, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(vv.dtype)
     return jnp.einsum("skgt,stkd->skgd", probs, vv).reshape(S, H, hd)

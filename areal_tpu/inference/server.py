@@ -255,6 +255,10 @@ class InferenceServer:
             # expert-load counts of a model with sparse experts
             # (docs/observability.md): one nested list, not a series a cell
             out["moe"] = moe_view
+        sa = getattr(self.engine, "sparse_attention_status", None)
+        if sa is not None and (sparse_view := sa()) is not None:
+            # a learned index's selection and the form its rows are read in
+            out["sparse_attention"] = sparse_view
         ks = getattr(self.engine, "kernel_stats", None)
         if ks is not None:
             # decode-step phases (docs/observability.md "Decode-step

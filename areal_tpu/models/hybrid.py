@@ -71,7 +71,24 @@ head's key ``[k_nope_h | k_r]``), ``forward_decode_paged`` the ABSORBED form
 over the cached rows (``q^_h = W_UK,h^T q_nope_h``, scores ``q^_h . c_s +
 q_rope_h . k_r,s``, ``o_h = W_UV,h sum_s p_s c_s``: the up-projection moved
 across both sums, so that a cached token is read as 576 values and not as
-32 x 320). tests/test_kanana2_model.py holds them to each other.
+32 x 320). tests/test_kanana2_model.py holds them to each other. The prompt
+pass is BLOCKED over queries (``mla_prefill_attend``): a block of queries
+meets a block of keys at a time under a running softmax, key blocks past the
+diagonal are never visited, and nothing of [H, L, L] exists (69 GB at 64
+heads and 16k tokens).
+
+Where the layer has a learned index (``index_topk``), a token also leaves ONE
+index key behind, in a page pool of its own width on the same page ids
+(``kv_pools``), and every query, in both forms and at every context length,
+attends to the min(index_topk, t + 1) cached tokens of largest ``I[t, s] =
+sum_j w[t, j] relu(q^I[t, j] . k^I[s])`` and to no other. The selection is
+EXACT (``select_top``: the k-th largest score found by counting, no sort and
+no approximate top-k: S_t is part of the mathematics). A decode step scores
+every cached token of every live slot (``index_select``), and reads the
+selected rows in the MASKED form: the latent launch fetches every page that
+holds tokens and the unselected rows meet a probability of 0
+(tests/test_glm5_model.py holds both forms to the reference; PERF.md has what
+a gather of the selected rows costs on the chip).
 
 What a slot's recurrent state is, and who may write it, is in
 ``inference/paged_kv.py`` (STATE_LEAVES); what a page row is, in ``kv_pools``.
@@ -82,6 +99,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import json
+import math
 import os
 from typing import Any
 
@@ -92,7 +110,7 @@ from jax.sharding import PartitionSpec as P
 from areal_tpu.models import moe, qwen
 from areal_tpu.models.qwen import _embed_lookup, _proj, _rms_norm, _rope
 
-MODEL_TYPES = ("granitemoehybrid", "lfm2_moe", "olmo_hybrid", "deepseek_v3")
+MODEL_TYPES = ("granitemoehybrid", "lfm2_moe", "olmo_hybrid", "deepseek_v3", "glm_moe_dsa")
 KINDS = ("mamba", "attention", "conv", "gdn", "mla")  # mixers
 FFNS = ("dense", "moe")
 # scopes this family adds to qwen.SCOPES (docs/observability.md): the
@@ -102,6 +120,8 @@ CONV_SCOPES = ("conv_proj", "conv_mix", "state_write")
 MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
 GDN_SCOPES = ("gdn_proj", "gdn_conv", "gdn_state", "state_write")
 MLA_SCOPES = ("mla_proj", "attn", "kv_write")  # latent attention: its projections beside the shared two
+# a low-rank query path, and the learned index that picks the cached tokens a query attends to
+DSA_SCOPES = ("mla_q_lora", "dsa_index_proj", "dsa_index_score", "dsa_select")
 MOE_SHARED_SCOPE = "moe_shared"  # the always-active block beside the routed experts
 # tokens a chunk of the delta rule's prefill scan: 16 x 2^2, as ``_unit_lower_inverse`` builds its inverse
 GDN_CHUNK = 64
@@ -109,8 +129,13 @@ GDN_CHUNK = 64
 # cache the engine keeps): rows of live slots each expert got, [expert
 # layers, experts], and experts with at least one such row, [expert layers];
 # live slots whose delta-rule state a step advanced, [gdn layers]; cached
-# tokens of live slots a latent-attention layer read, [mla layers]
-COUNT_LEAVES = ("moe_load", "moe_touched", "moe_streamed", "gdn_updates", "latent_tokens_read")
+# tokens of live slots a latent-attention layer read, [mla layers]; where the
+# layer has an index, the cached tokens it scored and the tokens the
+# mathematics selects of them (min(index_topk, cached) a live slot), [mla layers]
+COUNT_LEAVES = (
+    "moe_load", "moe_touched", "moe_streamed", "gdn_updates", "latent_tokens_read",
+    "index_tokens_scored", "latent_tokens_selected",
+)
 
 
 def stack_name(kind: str, ffn: str) -> str:
@@ -194,6 +219,19 @@ class HybridConfig:
     v_head_dim: int = 0
     rope_interleave: bool = False
     latent_row_lanes: int = 0
+    # ... its query through a normed low-rank bottleneck of this rank (0: one full-rank matrix)
+    q_lora_rank: int = 0
+    # ... and its learned index (0 heads: none, every query attends to every
+    # cached token): ``index_n_heads`` index queries of ``index_head_dim`` read
+    # the normed low-rank query, ONE index key a token (a LayerNorm with bias
+    # over it, eps ``index_norm_eps``), the first ``qk_rope_head_dim`` values
+    # of both rotated; a query attends to the ``index_topk`` cached tokens of
+    # largest sum_j w_j relu(q_j . k)
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_rope_interleave: bool = True
+    index_norm_eps: float = 1e-6
     # an always-active SwiGLU beside the routed experts, of this width (0: none)
     moe_shared_intermediate_size: int = 0
     # the share of an expert layer held here: the router scores
@@ -296,7 +334,8 @@ class HybridConfig:
         head, or ONE latent row [c | k_r | 0] for all heads, whose first
         ``kv_lora_rank`` lanes are its value too."""
         if self.count("mla"):
-            return {"k": (1, self.latent_lanes)}
+            # the index's key of a token lies beside its latent row: a second pool of another width on the same pages
+            return {"k": (1, self.latent_lanes), **({"idx": (1, self.index_head_dim)} if self.index_topk else {})}
         return {"k": (self.num_kv_heads, self.kv_head_dim), "v": (self.num_kv_heads, self.kv_head_dim)}
 
     @property
@@ -312,6 +351,9 @@ class HybridConfig:
             out["gdn_updates"] = (n,)
         if n := self.count("mla"):
             out["latent_tokens_read"] = (n,)
+            if self.index_topk:
+                out["index_tokens_scored"] = (n,)
+                out["latent_tokens_selected"] = (n,)
         return out
 
     @property
@@ -367,7 +409,7 @@ class HybridConfig:
             k: d[k]
             for k in (
                 "dtype", "ssm_state_dtype", "conv_state_dtype", "gdn_state_dtype", "kv_lane_pad", "head_dim",
-                "latent_row_lanes", "router_experts", "expert_first",
+                "latent_row_lanes", "router_experts", "expert_first", "index_norm_eps",
             )
             if k in d
         }
@@ -424,8 +466,15 @@ class HybridConfig:
                 "linear_conv_kernel_dim": self.gdn_d_conv,
                 "linear_allow_neg_eigval": self.gdn_neg_eigval,
             }
-        if self.model_type == "deepseek_v3":
+        if self.model_type in ("deepseek_v3", "glm_moe_dsa"):
             n_dense = sum(1 for f in self.ffns if f == "dense")
+            index = {
+                "index_n_heads": self.index_n_heads,
+                "index_head_dim": self.index_head_dim,
+                "index_topk": self.index_topk,
+                "indexer_rope_interleave": self.index_rope_interleave,
+                "index_norm_eps": self.index_norm_eps,
+            }
             return {
                 **shared,
                 "rms_norm_eps": self.rms_norm_eps,
@@ -434,7 +483,8 @@ class HybridConfig:
                 "rope_theta": self.rope_theta,
                 "rope_scaling": None,
                 "rope_interleave": self.rope_interleave,
-                "q_lora_rank": None,
+                "q_lora_rank": self.q_lora_rank or None,
+                **(index if self.index_topk else {}),
                 "kv_lora_rank": self.kv_lora_rank,
                 "qk_nope_head_dim": self.qk_nope_head_dim,
                 "qk_rope_head_dim": self.qk_rope_head_dim,
@@ -611,42 +661,59 @@ def _olmo_hybrid_fields(d: dict[str, Any]) -> dict[str, Any]:
 
 
 def _deepseek_v3_fields(d: dict[str, Any]) -> dict[str, Any]:
-    """``deepseek_v3``: latent attention in every layer (a full-rank query, a
-    key/value latent of ``kv_lora_rank`` beside ONE rotary key of
-    ``qk_rope_head_dim`` for all heads), the first ``first_k_dense_replace``
-    FFNs dense, the rest ``n_routed_experts`` experts behind a sigmoid router
-    whose selection (not its gates) takes ``e_score_correction_bias``, beside
-    ``n_shared_experts`` always-active ones fused into one SwiGLU. The 1e-20
-    of the gates' normalisation is the family's published implementation
-    (``DeepseekV3TopkRouter``). What this module does not implement is
-    refused, never ignored."""
-    if d.get("q_lora_rank") is not None:
-        raise ValueError("deepseek_v3 with a low-rank query path (q_lora_rank) is not implemented")
+    """``deepseek_v3`` and ``glm_moe_dsa``: latent attention in every layer (a
+    full-rank query or, with ``q_lora_rank``, one through a normed low-rank
+    bottleneck; a key/value latent of ``kv_lora_rank`` beside ONE rotary key
+    of ``qk_rope_head_dim`` for all heads), the first
+    ``first_k_dense_replace`` FFNs dense, the rest ``n_routed_experts``
+    experts behind a sigmoid router whose selection (not its gates) takes
+    ``e_score_correction_bias``, beside ``n_shared_experts`` always-active
+    ones fused into one SwiGLU. The 1e-20 of the gates' normalisation is the
+    family's published implementation (``DeepseekV3TopkRouter``).
+    ``glm_moe_dsa`` adds DeepSeek-V3.2's learned index (``index_n_heads``,
+    ``index_head_dim``, ``index_topk``; it reads the low-rank query, so it
+    needs one). What this module does not implement is refused, never
+    ignored."""
+    rope = d.get("rope_parameters") or {}
     if int(d.get("n_group") or 1) != 1 or int(d.get("topk_group") or 1) != 1:
-        raise ValueError("deepseek_v3 with group-limited routing (n_group / topk_group other than 1) is not implemented")
-    if d.get("rope_scaling") is not None:
-        raise ValueError("deepseek_v3 with a scaled rotary embedding (rope_scaling) is not implemented")
+        raise ValueError(f"{d['model_type']} with group-limited routing (n_group / topk_group other than 1) is not implemented")
+    if d.get("rope_scaling") is not None or rope.get("rope_type", "default") != "default":
+        raise ValueError(f"{d['model_type']} with a scaled rotary embedding (rope_scaling / rope_type) is not implemented")
     if d.get("attention_bias"):
         raise ValueError("projection biases are not implemented for the hybrid family")
     if d.get("scoring_func", "sigmoid") != "sigmoid" or d.get("topk_method", "noaux_tc") != "noaux_tc":
-        raise ValueError(f"deepseek_v3 router {d.get('scoring_func')!r} / {d.get('topk_method')!r}: only sigmoid / noaux_tc")
+        raise ValueError(f"{d['model_type']} router {d.get('scoring_func')!r} / {d.get('topk_method')!r}: only sigmoid / noaux_tc")
     if int(d.get("moe_layer_freq", 1)) != 1:
-        raise ValueError("deepseek_v3 with moe_layer_freq other than 1 is not implemented")
+        raise ValueError(f"{d['model_type']} with moe_layer_freq other than 1 is not implemented")
+    if int(d.get("num_nextn_predict_layers") or 0):
+        raise ValueError(
+            f"{d['model_type']} with num_nextn_predict_layers > 0 is not implemented: a multi-token-prediction "
+            "layer served as a draft needs verification over latent pages (ROADMAP Reach A.5); set it to 0 to serve without"
+        )
+    index = {k: int(d.get(k) or 0) for k in ("index_n_heads", "index_head_dim", "index_topk")}
+    if any(index.values()):
+        if not all(index.values()) or not d.get("q_lora_rank"):
+            raise ValueError(f"an index needs index_n_heads, index_head_dim, index_topk and a low-rank query (q_lora_rank): {index}")
+        if index["index_head_dim"] < int(d["qk_rope_head_dim"]) or index["index_head_dim"] % 128:
+            raise ValueError(f"index_head_dim {index['index_head_dim']}: whole 128-lane tiles that hold the rotary part are implemented")
     n = int(d["num_hidden_layers"])
     n_dense = min(n, int(d.get("first_k_dense_replace", 0)))
     experts = int(d.get("n_routed_experts") or 0) if n_dense < n else 0
     if n_dense < n and experts < 1:
-        raise ValueError("deepseek_v3 layers past first_k_dense_replace need n_routed_experts")
+        raise ValueError(f"{d['model_type']} layers past first_k_dense_replace need n_routed_experts")
     return dict(
         intermediate_size=d["intermediate_size"],
         layer_types=("mla",) * n,
         rms_norm_eps=d.get("rms_norm_eps", 1e-6),
-        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rope_theta=float(d.get("rope_theta") or rope.get("rope_theta") or 10000.0),
         rope_interleave=bool(d.get("rope_interleave", True)),
+        q_lora_rank=int(d.get("q_lora_rank") or 0),
         kv_lora_rank=int(d["kv_lora_rank"]),
         qk_nope_head_dim=int(d["qk_nope_head_dim"]),
         qk_rope_head_dim=int(d["qk_rope_head_dim"]),
         v_head_dim=int(d["v_head_dim"]),
+        **index,
+        index_rope_interleave=bool(d.get("indexer_rope_interleave", True)),
         ffn_types=tuple("dense" if i < n_dense else "moe" for i in range(n)),
         fused_gate_up=False,
         num_experts=experts,
@@ -666,6 +733,7 @@ _FIELDS = {
     "lfm2_moe": _lfm2_fields,
     "olmo_hybrid": _olmo_hybrid_fields,
     "deepseek_v3": _deepseek_v3_fields,
+    "glm_moe_dsa": _deepseek_v3_fields,
 }
 
 
@@ -723,7 +791,27 @@ def _layer_shapes(cfg: HybridConfig) -> dict[str, dict[str, tuple[int, ...]]]:
         },
         "gdn": _gdn_shapes(cfg),
         "mla": {
-            "wq": (D, cfg.num_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)),
+            **(
+                {
+                    "w_qa": (D, cfg.q_lora_rank),
+                    "q_a_norm": (cfg.q_lora_rank,),
+                    "w_qb": (cfg.q_lora_rank, cfg.num_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)),
+                }
+                if cfg.q_lora_rank
+                else {"wq": (D, cfg.num_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))}
+            ),
+            **(
+                {
+                    # the index: its queries read the normed low-rank query, its ONE key and its head weights the layer's input
+                    "wi_qb": (cfg.q_lora_rank, cfg.index_n_heads * cfg.index_head_dim),
+                    "wi_k": (D, cfg.index_head_dim),
+                    "wi_k_norm": (cfg.index_head_dim,),
+                    "wi_k_norm_bias": (cfg.index_head_dim,),
+                    "wi_w": (D, cfg.index_n_heads),
+                }
+                if cfg.index_topk
+                else {}
+            ),
             "w_kva": (D, cfg.latent_dim),  # [c | k_r]: the latent and the one rotary key
             "kv_norm": (cfg.kv_lora_rank,),
             # [k_nope | v] a head; the absorbed form slices W_UK and W_UV out of it
@@ -808,7 +896,7 @@ def init_params(rng: jax.Array, cfg: HybridConfig, dtype=None) -> dict:
             full = (sizes[name], *shape)
             if leaf.endswith("norm") or leaf == "D":
                 stack[leaf] = jnp.ones(full, dtype)
-            elif leaf == "conv_b":
+            elif leaf in ("conv_b", "wi_k_norm_bias"):
                 stack[leaf] = jnp.zeros(full, dtype)
             elif leaf == "A_log":
                 stack[leaf] = jnp.log(jax.random.uniform(next(keys), full, jnp.float32, 1.0, 16.0)).astype(dtype)
@@ -918,6 +1006,15 @@ _HF_LAYER_MAPS = {
         "input_norm": ("input_layernorm.weight", False),
         "post_norm": ("post_attention_layernorm.weight", False),
         "wq": ("self_attn.q_proj.weight", True),
+        "w_qa": ("self_attn.q_a_proj.weight", True),
+        "q_a_norm": ("self_attn.q_a_layernorm.weight", False),
+        "w_qb": ("self_attn.q_b_proj.weight", True),
+        # the index, as DeepSeek-V3.2's published code names it (no checkpoint could be read here)
+        "wi_qb": ("self_attn.indexer.wq_b.weight", True),
+        "wi_k": ("self_attn.indexer.wk.weight", True),
+        "wi_k_norm": ("self_attn.indexer.k_norm.weight", False),
+        "wi_k_norm_bias": ("self_attn.indexer.k_norm.bias", False),
+        "wi_w": ("self_attn.indexer.weights_proj.weight", True),
         "w_kva": ("self_attn.kv_a_proj_with_mqa.weight", True),
         "kv_norm": ("self_attn.kv_a_layernorm.weight", False),
         "w_kvb": ("self_attn.kv_b_proj.weight", True),
@@ -935,8 +1032,10 @@ _HF_LAYER_MAPS = {
         "ws_down": ("mlp.shared_experts.down_proj.weight", True),
     },
 }
+_HF_LAYER_MAPS["glm_moe_dsa"] = _HF_LAYER_MAPS["deepseek_v3"]  # the same block; the index's names are in it
 _HF_TOP = {
     "deepseek_v3": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
+    "glm_moe_dsa": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "granitemoehybrid": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "lfm2_moe": {"embed": "model.embed_tokens.weight", "final_norm": "model.embedding_norm.weight"},
     "olmo_hybrid": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
@@ -1450,27 +1549,158 @@ def _pairs_to_halves(x):
     return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
 
 
-def _mla_in(cfg: HybridConfig, layer: dict, h, positions):
+def _mla_in(cfg: HybridConfig, layer: dict, h, positions, query: bool = True):
     """The projections of h [..., D] at ``positions`` [...]: (q_nope
     [..., H, nope], q_rope [..., H, rope] rotated, the normed latent c
-    [..., rank], the ONE rotary key k_r [..., rope] rotated). ``[c | k_r]``
-    is what the token leaves behind."""
-    H, dn, dr, r = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
-    lead = h.shape[:-1]
+    [..., rank], the ONE rotary key k_r [..., rope] rotated, the normed
+    low-rank query q_r [..., q_lora_rank] or None where the query is one
+    full-rank matrix). ``[c | k_r]`` is what the token leaves behind.
+    ``query`` False leaves q_nope and q_rope None where there is a q_r to
+    make them from later (``mla_query``: a long prompt's, a block at a time)."""
+    r = cfg.kv_lora_rank
     # each projection is ONE matmul whose output is split afterwards: left to
     # itself XLA:TPU pushes the splits into the weights, wants those in
     # another layout and copies the whole layer stack of them, once a program
     # (1.2 GB for W_q at 47 layers; tests/test_tpu_compile.py)
-    q, kva = jax.lax.optimization_barrier((_proj(cfg, layer, "wq", h), _proj(cfg, layer, "w_kva", h)))
-    q = q.reshape(*lead, H, dn + dr)
-    q_nope, q_rope = q[..., :dn], q[..., dn:]
-    c = _rms_norm(kva[..., :r], layer["kv_norm"], cfg.rms_norm_eps)
-    k_r = kva[..., r:][..., None, :]  # one "head"
-    if cfg.rope_interleave:
-        q_rope, k_r = _pairs_to_halves(q_rope), _pairs_to_halves(k_r)
-    q_rope = _rope(q_rope, positions, cfg.rope_theta)
-    k_r = _rope(k_r, positions, cfg.rope_theta)[..., 0, :]
-    return q_nope, q_rope, c, k_r
+    if cfg.q_lora_rank:
+        with jax.named_scope("mla_q_lora"):
+            qa, kva = jax.lax.optimization_barrier((_proj(cfg, layer, "w_qa", h), _proj(cfg, layer, "w_kva", h)))
+            q_r = _rms_norm(qa, layer["q_a_norm"], cfg.rms_norm_eps)
+        q_nope, q_rope = mla_query(cfg, layer, q_r, positions) if query else (None, None)
+    else:
+        with jax.named_scope("mla_proj"):
+            q, kva = jax.lax.optimization_barrier((_proj(cfg, layer, "wq", h), _proj(cfg, layer, "w_kva", h)))
+        q_r = None
+        q_nope, q_rope = _split_query(cfg, q, positions)
+    with jax.named_scope("mla_proj"):
+        c = _rms_norm(kva[..., :r], layer["kv_norm"], cfg.rms_norm_eps)
+        k_r = kva[..., r:][..., None, :]  # one "head"
+        if cfg.rope_interleave:
+            k_r = _pairs_to_halves(k_r)
+        k_r = _rope(k_r, positions, cfg.rope_theta)[..., 0, :]
+    return q_nope, q_rope, c, k_r, q_r
+
+
+def _split_query(cfg: HybridConfig, q, positions):
+    """q [..., H * (nope + rope)] -> (q_nope [..., H, nope], q_rope [..., H, rope] rotated)."""
+    with jax.named_scope("mla_proj"):
+        dn = cfg.qk_nope_head_dim
+        q = q.reshape(*q.shape[:-1], cfg.num_heads, dn + cfg.qk_rope_head_dim)
+        q_nope, q_rope = q[..., :dn], q[..., dn:]
+        if cfg.rope_interleave:
+            q_rope = _pairs_to_halves(q_rope)
+        return q_nope, _rope(q_rope, positions, cfg.rope_theta)
+
+
+def mla_query(cfg: HybridConfig, layer: dict, q_r, positions):
+    """The heads' queries from the normed low-rank query q_r [..., q_lora_rank]."""
+    with jax.named_scope("mla_q_lora"):
+        q = jax.lax.optimization_barrier(_proj(cfg, layer, "w_qb", q_r))  # ONE matmul, split afterwards: see _mla_in
+    return _split_query(cfg, q, positions)
+
+
+def _rope_head(cfg: HybridConfig, x, positions):
+    """The index's rotary embedding: the FIRST ``qk_rope_head_dim`` values of
+    x [..., heads, index_head_dim] turn, the rest pass."""
+    dr = cfg.qk_rope_head_dim
+    turn = _pairs_to_halves(x[..., :dr]) if cfg.index_rope_interleave else x[..., :dr]
+    return jnp.concatenate([_rope(turn, positions, cfg.rope_theta), x[..., dr:]], axis=-1)
+
+
+def index_key(cfg: HybridConfig, layer: dict, h, positions):
+    """The ONE index key a token leaves behind, [..., index_head_dim]: a
+    LayerNorm (weight and bias) over ``W^I_k h``, its rotary part turned."""
+    with jax.named_scope("dsa_index_proj"):
+        k = _proj(cfg, layer, "wi_k", h).astype(jnp.float32)
+        k = k - jnp.mean(k, axis=-1, keepdims=True)
+        k = k * jax.lax.rsqrt(jnp.mean(jnp.square(k), axis=-1, keepdims=True) + cfg.index_norm_eps)
+        k = (k * layer["wi_k_norm"] + layer["wi_k_norm_bias"]).astype(h.dtype)
+        return _rope_head(cfg, k[..., None, :], positions)[..., 0, :]
+
+
+def index_key_view(cfg: HybridConfig, rows):
+    """Cached index keys [..., index_head_dim] in the PUBLISHED order of
+    values: a page holds the rotary part as [evens | odds] halves
+    (``_pairs_to_halves``, as queries meet it), the published code as (even,
+    odd) pairs."""
+    dr = cfg.qk_rope_head_dim
+    if not cfg.index_rope_interleave:
+        return rows
+    pairs = jnp.stack([rows[..., : dr // 2], rows[..., dr // 2 : dr]], axis=-1).reshape(*rows.shape[:-1], dr)
+    return jnp.concatenate([pairs, rows[..., dr:]], axis=-1)
+
+
+def index_query(cfg: HybridConfig, layer: dict, h, q_r, positions):
+    """(the index's queries [..., Hi, index_head_dim] from the normed low-rank
+    query q_r, rotated as the key is; the heads' weights [..., Hi] float32
+    from the layer's input). The published ``Hi^-1/2 x index_head_dim^-1/2``
+    is a positive constant on every score and moves no selection: left out."""
+    with jax.named_scope("dsa_index_proj"):
+        q = jax.lax.optimization_barrier(_proj(cfg, layer, "wi_qb", q_r))  # ONE matmul, split afterwards: see _mla_in
+        q = q.reshape(*q_r.shape[:-1], cfg.index_n_heads, cfg.index_head_dim)
+        return _rope_head(cfg, q, positions), _proj(cfg, layer, "wi_w", h).astype(jnp.float32)
+
+
+def index_scores(q_i, w, k_i):
+    """I[t, s] = sum_j w[t, j] relu(q_i[t, j] . k_i[s]) float32: q_i [T, Hi,
+    d], w [T, Hi], k_i [S, d] -> [T, S]; with a leading batch axis on k_i (a
+    slot's own keys, [T, S, d]) each query meets its own."""
+    keys = "tsd" if k_i.ndim == 3 else "sd"
+    dots = jnp.einsum(f"thd,{keys}->ths", q_i, k_i, preferred_element_type=jnp.float32)
+    return jnp.einsum("ths,th->ts", jax.nn.relu(dots), w)
+
+
+def select_top(scores, valid, topk: int):
+    """bool [T, S]: of each row's ``valid`` positions the min(topk, how many
+    are valid) of largest ``scores`` (float32), EXACTLY: between equal scores
+    the lower position wins, as ``jax.lax.top_k`` orders them. No sort: the
+    k-th largest score of a row is found bit by bit (32 counting passes over
+    the scores' order-preserving integers), then everything above it is in
+    and of its equals the first few."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    # order-preserving: flip a negative float's magnitude bits, then the sign bit of all
+    key = jax.lax.bitcast_convert_type(bits ^ ((bits >> 31) & 0x7FFFFFFF), jnp.uint32) ^ jnp.uint32(0x80000000)
+    key = jnp.where(valid, key, jnp.uint32(0))
+    k = jnp.minimum(topk, jnp.sum(valid, axis=-1, dtype=jnp.int32))
+
+    def bit(i, t):
+        cand = t | (jnp.uint32(0x80000000) >> i.astype(jnp.uint32))
+        return jnp.where(jnp.sum(key >= cand[:, None], axis=-1, dtype=jnp.int32) >= k, cand, t)
+
+    kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros(scores.shape[:1], jnp.uint32))[:, None]
+    above, equal = key > kth, (key == kth) & valid
+    left = (k - jnp.sum(above, axis=-1, dtype=jnp.int32))[:, None]
+    # all of the k-th score's equals fit (float32 scores: nearly always the k-th alone), or the first few of
+    # them do: the running count over a row is a pass of its own, made only where some row needs it
+    chosen = jax.lax.cond(
+        jnp.any(jnp.sum(equal, axis=-1, dtype=jnp.int32)[:, None] > left),
+        lambda: above | (equal & (jnp.cumsum(equal, axis=-1, dtype=jnp.int32) <= left)),
+        lambda: above | equal,
+    )
+    return chosen & valid
+
+
+def index_select(cfg: HybridConfig, q_i, w_i, idx_pages, j, lengths, page_table, page_size: int, kernel: dict | None = None):
+    """What a decode step's queries select: bool [S, window] over each
+    slot's cached tokens (``lengths`` [S] of them, this step's own among
+    them), the min(index_topk, cached) of largest index score, exactly.
+    Every cached token of the slot is scored by its ONE index key in layer
+    ``j`` of the index pool ``idx_pages`` [layers, 1, N, psz, d]: with
+    ``kernel`` (the launch's block and its work list for these lengths) by
+    ``ops/paged_latent_attention.py paged_index_scores_stacked`` over the
+    pages that hold tokens, else over the gathered window."""
+    S, window = page_table.shape[0], page_table.shape[1] * page_size
+    with jax.named_scope("dsa_index_score"):
+        if kernel is not None:
+            from areal_tpu.ops.paged_latent_attention import paged_index_scores_stacked
+
+            scores = paged_index_scores_stacked(q_i, w_i, idx_pages, j, lengths, page_table, **kernel)
+        else:
+            keys = jax.lax.dynamic_index_in_dim(idx_pages, j, 0, keepdims=False)[0][page_table]  # [S, wp, psz, d]
+            scores = index_scores(q_i, w_i, keys.reshape(S, window, keys.shape[-1]))
+    with jax.named_scope("dsa_select"):
+        cached = jnp.arange(window, dtype=jnp.int32)[None, :] < lengths[:, None]
+        return select_top(scores, cached, cfg.index_topk)
 
 
 def _latent_row(cfg: HybridConfig, c, k_r):
@@ -1487,25 +1717,91 @@ def _w_kvb_heads(cfg: HybridConfig, layer: dict):
     return w[..., : cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim :]
 
 
-def mla_prefill_attend(cfg: HybridConfig, layer: dict, q_nope, q_rope, c, k_r, mask):
-    """The PLAIN form over whole prompts: every head's key and value made
-    from the prompt's own latent (``[k_nope | v] = W_kvb c``, the rotary key
-    shared by all heads), causal softmax of q k^T / sqrt(nope + rope). q_*
-    [A, L, H, .], c [A, L, rank], k_r [A, L, rope], mask [A, 1, L, L].
-    Returns [A, L, H * v]."""
-    L = c.shape[1]
+# float32 logits a block of queries may hold against a block of keys, every head at once
+_PREFILL_LOGIT_BYTES = 128 << 20
+_PREFILL_KEY_BLOCK = 2048
+
+
+def prefill_blocks(cfg: HybridConfig, L: int) -> tuple[int, int]:
+    """(queries, keys) a block of the prompt pass's attention, from the
+    shapes alone: keys in blocks of up to 2,048 that divide the prompt,
+    queries in as many as keep [H, queries, keys] float32 logits inside 128
+    MB (32 heads x 1,024 x 1,024: a short prompt is one block, as before the
+    blocks; 64 heads against 2,048 keys: 256 queries)."""
+    tk = math.gcd(L, _PREFILL_KEY_BLOCK)
+    fit = max(8, _PREFILL_LOGIT_BYTES // (4 * cfg.num_heads * tk))
+    return math.gcd(L, 1 << (fit.bit_length() - 1)), tk
+
+
+def mla_prefill_attend(cfg: HybridConfig, layer: dict, q, c, k_r, index=None):
+    """The PLAIN form over ONE prompt, blocked over queries: every head's key
+    and value made from the prompt's own latent (``[k_nope | v] = W_kvb c``,
+    the rotary key shared by all heads), causal softmax of q k^T / sqrt(nope
+    + rope) over the keys each query SELECTS, then ``W_o``. ``q`` is
+    (q_nope, q_rope) [L, H, .] or, where the query has a low-rank path, the
+    normed q_r [L, q_lora_rank] (a block's heads are then made in the block:
+    64 heads of 256 for 16k tokens are 0.5 GB); c [L, rank], k_r [L, rope].
+    ``index`` = (h [L, D] the layer's input, k_i [L, index_head_dim]): a
+    query block scores every key up to its diagonal, selects
+    (``select_top``), and attends under that mask; without it every key up
+    to the query. No [H, L, L]: a block of queries meets a block of keys at a
+    time with a running softmax, and key blocks past the diagonal are never
+    visited. Returns [L, D]."""
+    L = c.shape[0]
     H, dn, dv = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    tq, tk = prefill_blocks(cfg, L)
+    with jax.named_scope("attn"):
+        kv = (c @ layer["w_kvb"]).reshape(L, H, dn + dv)
+    k_pos = jnp.arange(tk, dtype=jnp.int32)
 
-    def attend(args):  # one row at a time: [H, L, L] logits
-        qn, qr, c_r, kr, m = args
-        kv = (c_r @ layer["w_kvb"]).reshape(L, H, dn + dv)
-        k_nope, v = kv[..., :dn], kv[..., dn:]
-        logits = jnp.einsum("thd,shd->hts", qn, k_nope) + jnp.einsum("thd,sd->hts", qr, kr)
-        logits = jnp.where(m[0][None], logits.astype(jnp.float32) * cfg.sm_scale, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-        return jnp.einsum("hts,shd->thd", probs, v).reshape(L, H * dv)
+    def block(i):
+        pos = i * tq + jnp.arange(tq, dtype=jnp.int32)
+        n_kb = ((i + 1) * tq + tk - 1) // tk  # key blocks up to the diagonal
+        rows = functools.partial(jax.lax.dynamic_slice_in_dim, start_index=i * tq, slice_size=tq, axis=0)
+        if isinstance(q, tuple):
+            qn, qr = rows(q[0]), rows(q[1])
+        else:
+            qn, qr = mla_query(cfg, layer, rows(q), pos)
+        if index is not None:
+            h, k_i = index
+            q_i, w = index_query(cfg, layer, rows(h), rows(q), pos)
 
-    return jax.lax.map(attend, (q_nope, q_rope, c, k_r, mask))
+            def score(kb, sc):
+                with jax.named_scope("dsa_index_score"):
+                    blk = index_scores(q_i, w, jax.lax.dynamic_slice_in_dim(k_i, kb * tk, tk, axis=0))
+                    return jax.lax.dynamic_update_slice_in_dim(sc, blk, kb * tk, axis=1)
+
+            with jax.named_scope("dsa_index_score"):
+                scores = jax.lax.fori_loop(0, n_kb, score, jnp.zeros((tq, L), jnp.float32))
+            with jax.named_scope("dsa_select"):
+                chosen = select_top(scores, pos[:, None] >= jnp.arange(L, dtype=jnp.int32)[None, :], cfg.index_topk)
+
+        def attend(kb, carry):
+            m_prev, l_prev, acc = carry
+            with jax.named_scope("attn"):
+                kvb = jax.lax.dynamic_slice_in_dim(kv, kb * tk, tk, axis=0)
+                krb = jax.lax.dynamic_slice_in_dim(k_r, kb * tk, tk, axis=0)
+                logits = jnp.einsum("thd,shd->hts", qn, kvb[..., :dn], preferred_element_type=jnp.float32)
+                logits = logits + jnp.einsum("thd,sd->hts", qr, krb, preferred_element_type=jnp.float32)
+                if index is not None:
+                    seen = jax.lax.dynamic_slice_in_dim(chosen, kb * tk, tk, axis=1)
+                else:
+                    seen = pos[:, None] >= (kb * tk + k_pos)[None, :]
+                logits = jnp.where(seen[None], logits * cfg.sm_scale, -1e30)
+                m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+                p = jnp.where(seen[None], jnp.exp(logits - m_new), 0.0)
+                corr = jnp.exp(m_prev - m_new)
+                pv = jnp.einsum("hts,shd->htd", p.astype(kv.dtype), kvb[..., dn:], preferred_element_type=jnp.float32)
+                return m_new, l_prev * corr + jnp.sum(p, axis=-1, keepdims=True), acc * corr + pv
+
+        init = (jnp.full((H, tq, 1), -1e30, jnp.float32), jnp.zeros((H, tq, 1), jnp.float32), jnp.zeros((H, tq, dv), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, n_kb, attend, init)
+        with jax.named_scope("attn"):
+            o = jnp.swapaxes(acc / l, 0, 1).reshape(tq, H * dv).astype(kv.dtype)
+        with jax.named_scope("mla_proj"):
+            return _proj(cfg, layer, "wo", o)
+
+    return jax.lax.map(block, jnp.arange(L // tq, dtype=jnp.int32)).reshape(L, -1)
 
 
 def mla_absorbed_query(cfg: HybridConfig, layer: dict, q_nope, q_rope):
@@ -1542,13 +1838,42 @@ def _norm_out(cfg: HybridConfig, layer: dict, name: str, out):
     return out if cfg.norm_placement == "pre" else _rms_norm(out, layer[name], cfg.rms_norm_eps)
 
 
+# bytes of a feed-forward block's widest activations over all rows at once: the
+# rows an expert block gathers for its grouped matmuls (rows x experts a token
+# x hidden), a dense block's gate and up. Past them the rows go through in
+# blocks (a 16k-token prompt of hidden 6144 x top-8 would gather 1.6 GB)
+_FFN_BYTES = {"moe": 256 << 20, "dense": 512 << 20}
+
+
+def ffn_block_rows(cfg: HybridConfig, ffn: str, rows: int) -> int:
+    """Rows of a feed-forward block's input that go through at once, from the
+    shapes alone: all of them while the widest activations stay inside
+    ``_FFN_BYTES`` (every prompt pass of 8 x 1,024 tokens at hidden 2,048 to
+    4,096 does), else the largest power-of-two part of ``rows`` that does."""
+    wide = cfg.num_experts_per_tok * cfg.hidden_size if ffn == "moe" else 2 * cfg.intermediate_size
+    fit = max(1, _FFN_BYTES[ffn] // (wide * jnp.dtype(cfg.dtype).itemsize))
+    if rows <= fit:
+        return rows
+    block = 1 << (fit.bit_length() - 1)
+    return block if rows % block == 0 else rows
+
+
 def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None):
     """x + the layer's feed-forward block, its RMSNorm where the block has
     it (on the input, or on the output); for an expert block also the rows
     of ``live`` (default: all) each expert got, [E] int32. Where the expert
     leaves come as their stacks (``_scan_layers`` ``whole``), the touched
-    experts alone are read."""
+    experts alone are read. A long prompt's rows go through in blocks
+    (``ffn_block_rows``): the block is row-wise, so the result is the same."""
     rm = cfg.residual_multiplier
+    n_rows = x.size // x.shape[-1]
+    block = ffn_block_rows(cfg, ffn, n_rows)
+    if block < n_rows:
+        lv = jnp.ones((n_rows,), bool) if live is None else live.reshape(-1)
+        out, load = jax.lax.map(
+            lambda a: _ffn(cfg, ffn, layer, a[0], a[1]), (x.reshape(-1, block, x.shape[-1]), lv.reshape(-1, block))
+        )
+        return out.reshape(x.shape), (None if load is None else load.sum(0))
     if ffn == "dense":
         with jax.named_scope("mlp"):
             h = _norm_in(cfg, layer, "post_norm", x)
@@ -1669,7 +1994,8 @@ def forward_prefill(
     [n_attention, A, L, KH, kv_head_dim], state); for a latent-attention
     model ks is the latent rows [n_mla, A, L, 1, latent_lanes] (computed in
     the PLAIN form: per-head keys and values from the prompt's own latent)
-    and vs None. ``state`` is the
+    and vs None, or where the layers have an index its keys [n_mla, A, L, 1,
+    index_head_dim]. ``state`` is the
     recurrent state after each row's first ``n_state`` tokens, stacked per
     layer of its mixer kind ({leaf: [n, A, ...]}, ``cfg.state_shapes``).
 
@@ -1678,7 +2004,8 @@ def forward_prefill(
     layer j's state into it (the engine writes straight into its cache's
     slot rows, so no second copy of A states exists). A sink that holds
     ``k`` also takes a latent model's rows layer by layer, ``{"k": rows
-    [A, L, 1, latent_lanes]}``, and ks is then None."""
+    [A, L, 1, latent_lanes]}`` (and ``"idx"``: the index keys), and ks and vs
+    are then None."""
     A, L = input_ids.shape
     if n_state is None:
         n_state = jnp.sum(seg, axis=-1)
@@ -1692,12 +2019,15 @@ def forward_prefill(
             return {**arr, **{k: arr[k].at[j].set(v) for k, v in new.items()}}
     else:
         arrays, write = sink
-    # what every token leaves behind in the layers that attend, pool by pool
-    kv_heads, kv_lanes = cfg.kv_pools["k"]
-    kv_shape = (cfg.num_kv_layers, A, L, kv_heads, kv_lanes)
+    # what every token leaves behind in the layers that attend, pool by pool:
+    # K and V rows, or a latent row (and, where the layer has an index, its
+    # key: it rides in the V rows' place)
     latent = "v" not in cfg.kv_pools
+    second = cfg.kv_pools.get("idx" if latent else "v")
+    k_shape, v_shape = ((cfg.num_kv_layers, A, L, *pool) if pool else () for pool in (cfg.kv_pools["k"], second))
     rows_to_sink = latent and "k" in arrays  # the sink takes the latent rows too
-    mask = qwen._attention_mask(seg)  # [A, 1, L, L]
+    # a latent layer makes its own masks a block at a time: no [A, 1, L, L] for a 16k prompt
+    mask = None if latent else qwen._attention_mask(seg)
     positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (A, L))
     live = seg.astype(bool)
     rm = cfg.residual_multiplier
@@ -1733,17 +2063,21 @@ def forward_prefill(
         elif kind == "mla":
             with jax.named_scope("mla_proj"):
                 h = _norm_in(cfg, layer, "input_norm", x)
-                q_nope, q_rope, c, k_r = _mla_in(cfg, layer, h, positions)
-                rows = _latent_row(cfg, c, k_r)
+            # a low-rank query's heads are made a block of queries at a time (mla_prefill_attend)
+            q_nope, q_rope, c, k_r, q_r = _mla_in(cfg, layer, h, positions, query=not cfg.q_lora_rank)
+            with jax.named_scope("mla_proj"):
+                new = {"k": _latent_row(cfg, c, k_r)}
+            if cfg.index_topk:
+                k_i = index_key(cfg, layer, h, positions)
+                new["idx"] = k_i[..., None, :]
             if rows_to_sink:  # layer by layer into the caller's pages: no [layers, A, L, lanes] buffer beside them
                 with jax.named_scope("kv_write"):
-                    arr = write(arr, j, {"k": rows})
+                    arr = write(arr, j, new)
             else:
-                ks = ks.at[j].set(rows)
-            with jax.named_scope("attn"):
-                attn = mla_prefill_attend(cfg, layer, q_nope, q_rope, c, k_r, mask)
-            with jax.named_scope("mla_proj"):
-                out = _proj(cfg, layer, "wo", attn)
+                ks = ks.at[j].set(new["k"])
+                vs = vs.at[j].set(new["idx"]) if cfg.index_topk else vs
+            rows = (q_r if cfg.q_lora_rank else (q_nope, q_rope), c, k_r) + (((h, k_i),) if cfg.index_topk else ())
+            out = jax.lax.map(lambda a: mla_prefill_attend(cfg, layer, *a), rows)  # one prompt at a time
         else:
             with jax.named_scope("attn_proj"):
                 h = _norm_in(cfg, layer, "input_norm", x)
@@ -1764,14 +2098,17 @@ def forward_prefill(
     # them: a scalar rides in their place
     carry = (
         x,
-        jnp.zeros(() if rows_to_sink else kv_shape, cfg.jax_dtype),
-        jnp.zeros(() if latent else kv_shape, cfg.jax_dtype),
+        jnp.zeros(() if rows_to_sink else k_shape, cfg.jax_dtype),
+        jnp.zeros(() if rows_to_sink else v_shape, cfg.jax_dtype),
         arrays,
     )
-    x, ks, vs, arrays = _scan_layers(cfg, params, carry, step)
+    # a prompt pass's rows go through grouped matmuls (moe.takes_dense_form says when), which read the expert
+    # stacks where they lie: a layer's three matrices sliced out for them would be copied, once a layer
+    routed = cfg.num_moe_layers and not moe.takes_dense_form(ffn_block_rows(cfg, "moe", A * L), cfg.num_experts)
+    x, ks, vs, arrays = _scan_layers(cfg, params, carry, step, whole=moe.EXPERT_LEAVES if routed else ())
     with jax.named_scope("lm_head"):
         hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    return hidden, (None if rows_to_sink else ks), (None if latent else vs), arrays
+    return hidden, (None if rows_to_sink else ks), (vs if v_shape and not rows_to_sink else None), arrays
 
 
 def prefill_into_cache(
@@ -1802,10 +2139,10 @@ def prefill_into_cache(
         # a latent model (no slot state): its rows go into their pages layer
         # by layer; 48 layers of 8 x 1024 rows collected first are 0.5 GB, and
         # as much again to scatter
-        def put(arr, j, new):
-            return {"k": paged_kv.scatter_prefill_layer(arr["k"], j, new["k"], flat_pages, page_size)}
+        def put(arr, j, new):  # every pool of the layer: the latent rows and, beside them, the index keys
+            return {n: paged_kv.scatter_prefill_layer(arr[n], j, rows, flat_pages, page_size) for n, rows in new.items()}
 
-        *_, pages = forward_prefill(params, cfg, ids, seg, sink=({"k": cache["k"]}, put))
+        *_, pages = forward_prefill(params, cfg, ids, seg, sink=({n: cache[n] for n in cfg.kv_pools}, put))
         return {**cache, **pages}
     state = {k: cache[k] for k in paged_kv.STATE_LEAVES if k in cache}
     n_slots = next(iter(state.values())).shape[1] if state else 0
@@ -1842,6 +2179,28 @@ def slot_state_view(cfg: HybridConfig, leaf: str, rows: jax.Array) -> jax.Array:
 
         return unpack_state(rows, cfg.gdn_head_pack)
     return rows
+
+
+def first_layer_selection(params: dict, cfg: HybridConfig, ids, positions, cache: dict, page_table, *, page_size: int, use_kernel: bool):
+    """What the FIRST layer's index selects for the tokens ``ids`` [S] at
+    ``positions`` [S] over each row's cached index keys (``page_table`` [S,
+    wp]: the pages that hold them, ``positions + 1`` tokens each): bool [S,
+    wp * page_size], by the routine a decode step runs (``index_select``).
+    The first layer reads the token's embedding alone, so nothing but the
+    cached keys enters from the past: what a check of the selection needs."""
+    layer = jax.tree.map(lambda a: a[0], params[stack_name(*_layer_kinds(cfg)[0])])
+    lengths = (positions + 1).astype(jnp.int32)
+    h = _norm_in(cfg, layer, "input_norm", _embed(params, cfg, ids))
+    *_, q_r = _mla_in(cfg, layer, h, positions, query=False)
+    q_i, w_i = index_query(cfg, layer, h, q_r, positions)
+    kernel = None
+    if use_kernel:
+        from areal_tpu.inference import paged_kv
+        from areal_tpu.ops.paged_attention_q8 import decode_schedule
+
+        ppcb = paged_kv.choose_ppcb(page_table.shape[1])
+        kernel = dict(pages_per_compute_block=ppcb, schedule=decode_schedule(lengths, page_table.shape[1], page_size, ppcb))
+    return index_select(cfg, q_i, w_i, cache["idx"], jnp.int32(0), lengths, page_table, page_size, kernel)
 
 
 _NO_STATE_CUT = (
@@ -1959,10 +2318,11 @@ def forward_decode_paged(
         live = live_order(active) if cfg.count("mamba") + cfg.count("gdn") else None
         if cfg.count("mla"):
             from areal_tpu.ops.paged_latent_attention import paged_latent_attention_stacked
+        kernel = dict(pages_per_compute_block=ppcb, schedule=schedule)  # the index's launch: the latent one's work list
         with jax.named_scope("kv_write"):
             kv_live = live_order(page_table[:, 0] != 0)  # the KV writer's: qwen.forward_decode_paged
     else:
-        live = kv_live = None
+        live = kv_live = kernel = None
     rm = cfg.residual_multiplier
     # the expert matmuls read the touched experts only where a full batch gives an expert a handful of rows
     # (a Pallas launch over the expert stacks; off a TPU, XLA's form whatever the shapes: moe.takes_touched_form)
@@ -1996,25 +2356,39 @@ def forward_decode_paged(
                 # sliced HERE: XLA copies a layer's ``W_kvb`` out of the stack into fast memory, and names the copy after the slice
                 layer = {**layer, "w_kvb": jax.lax.dynamic_index_in_dim(*layer["w_kvb"], 0, keepdims=False)}
                 h = _norm_in(cfg, layer, "input_norm", x)
-                q_nope, q_rope, lat, k_r = _mla_in(cfg, layer, h, positions)
+            q_nope, q_rope, lat, k_r, q_r = _mla_in(cfg, layer, h, positions)
+            with jax.named_scope("mla_proj"):
                 q = mla_absorbed_query(cfg, layer, q_nope, q_rope)
+            more = None
+            if cfg.index_topk:
+                q_i, w_i = index_query(cfg, layer, h, q_r, positions)
+                more = {"idx": index_key(cfg, layer, h, positions)[:, None, :]}
             with jax.named_scope("kv_write"):
                 row = _latent_row(cfg, lat, k_r)
-                c = paged_kv.write_decode_rows(c, j, row, None, write_page, write_off, kv_live)
+                c = paged_kv.write_decode_rows(c, j, row, None, write_page, write_off, kv_live, more=more)
+            chosen = None
+            if cfg.index_topk:
+                # a slot without pages holds no cached token (its position is stale): nothing of it is scored or chosen
+                chosen = index_select(cfg, q_i, w_i, c["idx"], j, attn_lengths if use_kernel else lengths, page_table, page_size, kernel)
             with jax.named_scope("attn"):
                 if use_kernel:
                     o_lat = paged_latent_attention_stacked(
                         q, c["k"], j, attn_lengths, page_table, value_lanes=cfg.kv_lora_rank,
-                        pages_per_compute_block=ppcb, schedule=schedule, sm_scale=cfg.sm_scale,
+                        pages_per_compute_block=ppcb, schedule=schedule, sm_scale=cfg.sm_scale, select=chosen,
                     )
                 else:
                     pool = jax.lax.dynamic_index_in_dim(c["k"], j, 0, keepdims=False)
-                    o_lat = paged_kv.paged_attention_xla(q, pool, pool, lengths, page_table, sm_scale=cfg.sm_scale)
+                    o_lat = paged_kv.paged_attention_xla(q, pool, pool, lengths, page_table, sm_scale=cfg.sm_scale, select=chosen)
                     o_lat = o_lat[..., : cfg.kv_lora_rank]
                 if "latent_tokens_read" in c:
-                    c["latent_tokens_read"] = c["latent_tokens_read"].at[j].add(
-                        jnp.sum(jnp.where(active, lengths, 0), dtype=jnp.int32)
-                    )
+                    # the rows the read fetched: every cached row of a live slot (the unselected are fetched and masked)
+                    n_cached = jnp.sum(jnp.where(active, lengths, 0), dtype=jnp.int32)
+                    c["latent_tokens_read"] = c["latent_tokens_read"].at[j].add(n_cached)
+                    if cfg.index_topk:
+                        c["index_tokens_scored"] = c["index_tokens_scored"].at[j].add(n_cached)
+                        c["latent_tokens_selected"] = c["latent_tokens_selected"].at[j].add(
+                            jnp.sum(jnp.where(active[:, None], chosen, False), dtype=jnp.int32)
+                        )
             with jax.named_scope("mla_proj"):
                 out = _proj(cfg, layer, "wo", mla_absorbed_out(cfg, layer, o_lat.astype(x.dtype)))
         else:

@@ -320,11 +320,16 @@ def _experts_routed(x, wg, wu, wd, top_e, gates, e0, interpret: bool):
     local expert, stably sorted by expert, through three grouped matmuls;
     each row then gathers its K outputs back by the inverse permutation and
     sums them under its gates (no scatter-add: on a v5e that walks its rows
-    one by one)."""
+    one by one). Where the three leaves come ``Stacked``, the grouped matmuls
+    take the whole stack as ``layers x E_loc`` groups of which only this
+    layer's have rows: the kernel visits no empty group, and no layer's
+    matrices are copied out of the stack for it (1.15 GB a layer at 16
+    experts of [6144, 2048])."""
     gmm = pinned_gmm()
     T, D = x.shape
     K = top_e.shape[1]
-    E_loc, _, F = wg.shape
+    stacked = isinstance(wg, Stacked)
+    E_loc, _, F = wg.stack.shape[1:] if stacked else wg.shape
     with jax.named_scope("moe_dispatch"):
         ek = top_e.reshape(T * K)
         tok = jnp.arange(T * K, dtype=jnp.int32) // K
@@ -342,6 +347,10 @@ def _experts_routed(x, wg, wu, wd, top_e, gates, e0, interpret: bool):
         s_tok = jnp.where(computed, tok[order], T)
         xs = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])[s_tok]  # [T*K, D] grouped by local expert
         cm = computed[:, None]
+        if stacked:  # this layer's groups among every layer's: the others are empty
+            n_layers = wg.stack.shape[0]
+            group_sizes = jax.lax.dynamic_update_slice(jnp.zeros((n_layers * E_loc,), jnp.int32), group_sizes, (wg.index * E_loc,))
+            wg, wu, wd = (w.stack.reshape(n_layers * E_loc, *w.stack.shape[2:]) for w in (wg, wu, wd))
     with jax.named_scope("moe_experts"):
         m = T * K
         t_in, t_out = gmm_tiles(m, D, F, E_loc), gmm_tiles(m, F, D, E_loc)
@@ -378,7 +387,8 @@ def expert_ffn(x, layer: dict, cfg, *, live=None, e0=0):
     take no part. Where the three expert leaves come ``Stacked`` (the caller's
     choice by ``takes_touched_form``, since the caller keeps the stacks
     unsliced), the touched form reads the experts ``load`` says got a row and
-    no other."""
+    no other; above ``DENSE_ROWS`` rows (a prompt pass) stacked leaves go
+    through the grouped matmuls where they lie."""
     with jax.named_scope("moe_router"):
         scores, gates, top_e = route(x, layer["w_router"], cfg, layer.get("router_bias"))
         chose = jax.nn.one_hot(top_e, scores.shape[-1], dtype=jnp.int32).sum(1)  # [T, E]
@@ -387,8 +397,10 @@ def expert_ffn(x, layer: dict, cfg, *, live=None, e0=0):
             chose = chose * live[:, None]
         load = chose.sum(0)
     wg, wu, wd = layer["we_gate"], layer["we_up"], layer["we_down"]
-    if isinstance(wg, Stacked):
+    if isinstance(wg, Stacked) and takes_dense_form(x.shape[0], wg.stack.shape[1]):
         out = _experts_touched(x, wg, wu, wd, top_e, gates, load, e0)
+    elif isinstance(wg, Stacked):
+        out = _experts_routed(x, wg, wu, wd, top_e, gates, e0, jax.default_backend() != "tpu")
     elif takes_dense_form(x.shape[0], wg.shape[0]):
         out = _experts_dense(x, wg, wu, wd, top_e, gates, e0)
     else:

@@ -177,6 +177,22 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "cached tokens of live slots x latent-attention layers (each "
             "row is fetched once a step and layer).",
         ),
+        # ... whose layers have a learned index (``index_topk``): what the
+        # index scored, what the mathematics selects of it, beside what the
+        # read fetched (above): equal to selected in a form that gathers the
+        # selected rows, to scored in one that fetches every page and masks
+        index_tokens_scored=r.counter(
+            "areal_decode_index_tokens_scored_total",
+            "(cached token, layer) index keys scored by decode steps: the "
+            "cached tokens of live slots x latent-attention layers with an "
+            "index (one 128-value key of every cached token a step and layer).",
+        ),
+        latent_tokens_selected=r.counter(
+            "areal_decode_latent_tokens_selected_total",
+            "(cached token, layer) latent rows the index selected for decode "
+            "steps' queries: min(index_topk, cached tokens) a live slot, "
+            "layer and step, counted from the selection itself.",
+        ),
         # a model with sparse experts (models/moe.py); counted on the device
         # inside the decode chunk, for live slots only, and brought back with
         # the chunk's tokens. Per-expert counts: /statusz ``moe.load``

@@ -29,9 +29,18 @@ experts' copies are in flight while one multiplies, a semaphore a matrix so
 that the gate product starts when ``Wg`` is there. All ``T`` rows go through
 every listed expert: at 64 rows an expert is 0.6 GFLOP (3 us on a v5e) under
 9.44 MB (11.5 us), so compacting rows is worth no code.
+
+An expert whose three matrices do not fit the ring twice (6144 x 2048: 75 MB
+an expert, 128 MB of VMEM) goes through in equal PARTS of its width
+(``width_parts``): the gated product is a sum over the width, so a part is a
+smaller expert with the same gate, ``Wg[:, part]``, ``Wu[:, part]``,
+``Wd[part, :]``, and the list is walked part by part. One part (every expert
+that fits) is the launch as it was.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +53,18 @@ from jax.experimental.pallas import tpu as pltpu
 # against 60.9 / 110.6 / 161.1 / 209.7): one copy of 9.4 MB in flight fills the
 # memory's bandwidth, and an expert's arithmetic is a quarter of its copy
 _NBUF = 2
+# bytes of expert matrices the ring may hold: under the launch's 100 MB beside rows, gates and activations
+_RING_BYTES = 48 << 20
+
+
+def width_parts(D: int, F: int, itemsize: int, nbuf: int = _NBUF) -> int:
+    """Equal parts of an expert's width F that go through the ring one at a
+    time: the fewest whose three matrices fit it ``nbuf`` times, each whole
+    lane tiles. 1 for [2048, 768] (18.9 MB), 4 for [6144, 2048] (151 MB)."""
+    for parts in range(1, F // 128 + 1):
+        if F % (parts * 128) == 0 and nbuf * 3 * D * (F // parts) * itemsize <= _RING_BYTES:
+            return parts
+    return max(1, F // 128) if F % 128 == 0 else 1
 
 
 def _touched_kernel(
@@ -56,19 +77,27 @@ def _touched_kernel(
     wu_hbm,  # ANY [layers, E_loc, D, F]
     wd_hbm,  # ANY [layers, E_loc, F, D]
     o_ref,  # VMEM [T, D] f32
-    bg,  # VMEM [nbuf, D, F]
-    bu,  # VMEM [nbuf, D, F]
-    bd,  # VMEM [nbuf, F, D]
+    bg,  # VMEM [nbuf, D, F / parts]
+    bu,  # VMEM [nbuf, D, F / parts]
+    bd,  # VMEM [nbuf, F / parts, D]
     sems,  # DMA [3, nbuf]
+    *,
+    parts: int,  # equal parts of the width an expert goes through in (``width_parts``)
 ):
-    li, n = layer_ref[0], n_ref[0]
+    li, n = layer_ref[0], n_ref[0] * parts
     nbuf = bg.shape[0]
     stacks, bufs = (wg_hbm, wu_hbm, wd_hbm), (bg, bu, bd)
+    Fp = bg.shape[2]
 
     def copy(i, m):
-        """The copy of matrix ``m`` of the list's i-th expert, built
+        """The copy of matrix ``m`` of the walk's i-th item (the list's
+        ``i // parts``-th expert, part ``i % parts`` of its width), built
         identically to start it and to wait for it."""
-        return pltpu.make_async_copy(stacks[m].at[li, ids_ref[i]], bufs[m].at[i % nbuf], sems.at[m, i % nbuf])
+        src = stacks[m].at[li, ids_ref[i // parts]]
+        if parts > 1:
+            part = pl.ds(pl.multiple_of((i % parts) * Fp, 128), Fp)
+            src = src.at[part, :] if m == 2 else src.at[:, part]
+        return pltpu.make_async_copy(src, bufs[m].at[i % nbuf], sems.at[m, i % nbuf])
 
     def start(i):
         for m in range(3):
@@ -90,7 +119,7 @@ def _touched_kernel(
         g = jnp.dot(x, bg[slot], preferred_element_type=jnp.float32).astype(x.dtype)
         copy(i, 1).wait()
         u = jnp.dot(x, bu[slot], preferred_element_type=jnp.float32).astype(x.dtype)
-        y = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32) * gate_ref[ids_ref[i]]
+        y = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32) * gate_ref[ids_ref[i // parts]]
         copy(i, 2).wait()
         o_ref[...] += jnp.dot(y.astype(x.dtype), bd[slot], preferred_element_type=jnp.float32)
 
@@ -127,20 +156,22 @@ def touched_expert_ffn(
     rows = T + pad
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    w_bytes = nbuf * 3 * D * F * wg.dtype.itemsize
+    parts = width_parts(D, F, wg.dtype.itemsize, nbuf)
+    Fp = F // parts
+    w_bytes = nbuf * 3 * D * Fp * wg.dtype.itemsize
     # rows, output, gates (a lane tile an expert and row) and the activations
-    io_bytes = rows * (D * (x.dtype.itemsize + 4) + E_loc * 128 * 4 + 4 * F * 4)
+    io_bytes = rows * (D * (x.dtype.itemsize + 4) + E_loc * 128 * 4 + 4 * Fp * 4)
     out = pl.pallas_call(
-        _touched_kernel,
+        functools.partial(_touched_kernel, parts=parts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             in_specs=[vmem, vmem, hbm, hbm, hbm],
             out_specs=vmem,
             grid=(1,),
             scratch_shapes=(
-                pltpu.VMEM((nbuf, D, F), wg.dtype),
-                pltpu.VMEM((nbuf, D, F), wu.dtype),
-                pltpu.VMEM((nbuf, F, D), wd.dtype),
+                pltpu.VMEM((nbuf, D, Fp), wg.dtype),
+                pltpu.VMEM((nbuf, D, Fp), wu.dtype),
+                pltpu.VMEM((nbuf, Fp, D), wd.dtype),
                 pltpu.SemaphoreType.DMA((3, nbuf)),
             ),
         ),

@@ -24,6 +24,16 @@ rows (576 published values in 640 lanes), 48 layers, by live slots (16 / 24 /
 69,632 operations a cached token and layer), and the row write of the same
 pool (one ``paged_kv_write`` launch a layer over the live slots).
 
+``--only dsa``: the sparse read of a latent-attention layer with a learned
+index, at the long-context cell's shapes (64 slots, 64 query heads, 6 layers,
+a 160-page table, 2,048 of up to 20k cached tokens selected): the index's
+launch (``paged_index_scores``) against its keys' bytes, the exact top-2,048
+two ways (``hybrid.select_top``'s 32 counting passes, and ``jax.lax.top_k``),
+and the read two ways: MASKED (``paged_latent_attn`` over every page that
+holds tokens, the unselected masked) and GATHERED (the selected rows fetched
+by index into [slots, 2048, 640] and the absorbed products over them, in XLA:
+the form the program does not keep), by cached tokens a slot.
+
 ``paged_kv_write``: a step's KV rows of every layer written into the stacked
 pools (``paged_kv.write_decode_rows``) by the per-head XLA scatters over every
 slot and by the one ``paged_kv_write`` launch over the live slots, at 0 / 25 /
@@ -188,6 +198,106 @@ def probe_latent(*, seed: int, reps: int, ppcb: int, pages: int = 409) -> list[d
     return out
 
 
+DSA = dict(S=64, H=64, L=6, lanes=640, row=576, value=512, Hi=32, d=128, topk=2048, WP=160)  # rollout-glm-5-ep16-d6-longctx-grpo
+
+
+def probe_dsa(*, seed: int, reps: int, ppcb: int, pages: int = 2730) -> list[dict]:
+    """us a layer of each piece of a decode step's sparse read, by cached tokens a slot."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.models import hybrid
+    from areal_tpu.ops.paged_attention_q8 import decode_schedule
+    from areal_tpu.ops.paged_latent_attention import paged_index_scores_stacked, paged_latent_attention_stacked
+
+    S, H, L, lanes, row, value, Hi, d, topk, wp = (DSA[k] for k in ("S", "H", "L", "lanes", "row", "value", "Hi", "d", "topk", "WP"))
+    W = wp * PSZ
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(ks[0], (S, H, lanes), jnp.bfloat16)
+    pool = jax.random.normal(ks[1], (L, 1, pages, PSZ, lanes), jnp.bfloat16)
+    idx = jax.random.normal(ks[2], (L, 1, pages, PSZ, d), jnp.bfloat16)
+    q_i = jax.random.normal(ks[3], (S, Hi, d), jnp.bfloat16)
+    w_i = jax.random.normal(ks[4], (S, Hi), jnp.float32)
+    rng = np.random.default_rng(seed)
+    table = jnp.asarray(rng.integers(1, pages, (S, wp)), jnp.int32)
+    cols = jnp.arange(W, dtype=jnp.int32)[None, :]
+
+    def over_layers(fn, init):
+        return jax.lax.scan(lambda acc, li: (acc + fn(li), None), init, jnp.arange(L, dtype=jnp.int32))[0]
+
+    # the pools ride in as arguments: closed over they would be constants of the program (2.7 GB of them)
+    @jax.jit
+    def score(idx, lengths):
+        sched = decode_schedule(lengths, wp, PSZ, ppcb)
+        return over_layers(lambda li: paged_index_scores_stacked(q_i, w_i, idx, li, lengths, table, pages_per_compute_block=ppcb, schedule=sched), jnp.zeros((S, W), jnp.float32))
+
+    @jax.jit
+    def select_bits(scores, lengths):
+        return over_layers(lambda li: hybrid.select_top(scores + li, cols < lengths[:, None], topk).astype(jnp.int32), jnp.zeros((S, W), jnp.int32))
+
+    @jax.jit
+    def select_sort(scores, lengths):
+        def one(li):
+            return jax.lax.top_k(jnp.where(cols < lengths[:, None], scores + li, -jnp.inf), topk)[1]
+
+        return over_layers(one, jnp.zeros((S, topk), jnp.int32))
+
+    @jax.jit
+    def read_masked(pool, lengths, chosen):
+        sched = decode_schedule(lengths, wp, PSZ, ppcb)
+        return over_layers(
+            lambda li: paged_latent_attention_stacked(
+                q, pool, li, lengths, table, value_lanes=value, pages_per_compute_block=ppcb, schedule=sched, sm_scale=256**-0.5, select=chosen
+            ),
+            jnp.zeros((S, H, value), jnp.float32),
+        )
+
+    @jax.jit
+    def read_gathered(pool, lengths, picks):
+        """The selected rows by index (``picks`` [S, topk] positions, the first min(topk, cached) valid)."""
+        flat = jnp.take_along_axis(table, picks // PSZ, axis=1) * PSZ + picks % PSZ  # [S, topk] rows of the pool
+        valid = jnp.arange(topk)[None, :] < jnp.minimum(lengths, topk)[:, None]
+
+        def one(li):
+            rows = jax.lax.dynamic_index_in_dim(pool, li, 0, keepdims=False)[0].reshape(pages * PSZ, lanes)[flat]  # [S, topk, lanes]
+            logits = jnp.einsum("shl,stl->sht", q, rows, preferred_element_type=jnp.float32) * 256**-0.5
+            p = jax.nn.softmax(jnp.where(valid[:, None, :], logits, -1e30), axis=-1)
+            return jnp.einsum("sht,stv->shv", p.astype(rows.dtype), rows[..., :value], preferred_element_type=jnp.float32)
+
+        return over_layers(one, jnp.zeros((S, H, value), jnp.float32))
+
+    def timed(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / (reps * L) * 1e6
+
+    out = []
+    mix = np.exp(rng.uniform(np.log(4096), np.log(16384), S)).astype(np.int32) + rng.integers(0, 1500, S).astype(np.int32)
+    for label, lengths in (("mix", mix), ("4608", np.full(S, 4608, np.int32)), ("9600", np.full(S, 9600, np.int32)), ("19200", np.full(S, 19200, np.int32)), ("40of64-mix", np.where(np.arange(S) < 40, mix, 0).astype(np.int32))):
+        cached = int(lengths.sum())
+        selected = int(np.minimum(lengths, topk).sum())
+        lengths = jnp.asarray(lengths)
+        scores = score(idx, lengths) / L
+        chosen = hybrid.select_top(scores, cols < lengths[:, None], topk)
+        picks = jax.lax.top_k(jnp.where(cols < lengths[:, None], scores, -jnp.inf), topk)[1]
+        masked, gathered = read_masked(pool, lengths, chosen), read_gathered(pool, lengths, picks)
+        res = {
+            "probe": "dsa", "lengths": label, "live_slots": int((np.asarray(lengths) > 0).sum()), "cached_tokens": cached, "selected_tokens": selected,
+            "index_score_us": timed(score, idx, lengths), "select_bits_us": timed(select_bits, scores, lengths), "select_top_k_us": timed(select_sort, scores, lengths),
+            "read_masked_us": timed(read_masked, pool, lengths, chosen), "read_gathered_us": timed(read_gathered, pool, lengths, picks),
+            "read_all_rows_us": timed(read_masked, pool, lengths, None),
+            "masked_vs_gathered_max_abs": float(jnp.max(jnp.abs(masked - gathered))),
+        }
+        res["index_roofline_pct"] = 100 * d * 2 * cached / HBM_BYTES_S * 1e6 / res["index_score_us"]
+        res["masked_selected_roofline_pct"] = 100 * row * 2 * selected / HBM_BYTES_S * 1e6 / res["read_masked_us"]
+        res["gathered_selected_roofline_pct"] = 100 * row * 2 * selected / HBM_BYTES_S * 1e6 / res["read_gathered_us"]
+        out.append(res)
+    return out
+
+
 def probe_write(name: str, *, seed: int, reps: int, quant: bool, pages: int = 400) -> dict:
     """us a layer of a decode step's KV write, scatters against the kernel."""
     import jax
@@ -263,11 +373,15 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--ppcb", type=int, default=4, help="pages a compute block (the decode step's choice at this table: 4)")
-    ap.add_argument("--only", choices=("latent",), help="the latent-attention cell's launches alone")
+    ap.add_argument("--only", choices=("latent", "dsa"), help="the latent-attention cell's launches alone, or the sparse read's pieces")
     args = ap.parse_args()
     if jax.default_backend() != "tpu":
         print("decode_attn_probe: needs a TPU (a CPU time is no speed)")
         return 2
+    if args.only == "dsa":
+        for res in probe_dsa(seed=args.seed, reps=args.reps, ppcb=args.ppcb):
+            print(json.dumps(res), flush=True)
+        return 0
     for res in probe_latent(seed=args.seed, reps=args.reps, ppcb=args.ppcb):
         print(json.dumps(res), flush=True)
     if args.only:

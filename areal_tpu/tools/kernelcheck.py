@@ -276,6 +276,93 @@ def _cases_paged_latent(compiled: bool = False) -> Iterator[dict]:
 
 
 # ---------------------------------------------------------------------------
+# a learned index over latent pages (ops/paged_latent_attention.py): the index's
+# launch over the pool of index keys against the scores of the gathered window,
+# and the latent launch under a selection (the MASKED sparse read) against the
+# gather path under the same mask. Selections leave whole blocks of a slot
+# without a chosen token: the running softmax must step over them
+# ---------------------------------------------------------------------------
+
+
+@register_kernel("paged_index_select")
+def _cases_paged_index(compiled: bool = False) -> Iterator[dict]:
+    import jax.numpy as jnp
+
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.models import hybrid
+    from areal_tpu.ops.paged_latent_attention import paged_index_scores_stacked, paged_latent_attention_stacked
+
+    L = 3
+    # the long-context cell's launches (64 slots, 64 heads over 640 lanes, 32 index heads of 128, a 160-page table), or sizes the interpreter holds
+    S, H, lanes, value, Hi, d, psz, wp, ppcb, topk = (64, 64, 640, 512, 32, 128, 128, 160, 4, 2048) if compiled else (5, 4, 256, 128, 3, 128, 8, 6, 2, 7)
+    N = min(S * wp, 3000) + 1
+
+    def build(pages, seed=7):
+        rng = np.random.default_rng(seed)
+        lengths = rng.integers(1, wp * psz + 1, S).astype(np.int32)
+        lengths[0], lengths[-1] = 0, wp * psz  # a slot no item names, and a full window
+        return {
+            "q": _normal(seed, (S, H, lanes), pages),
+            "pool": _normal(seed + 1, (L, 1, N, psz, lanes), pages),
+            "idx": _normal(seed + 2, (L, 1, N, psz, d), pages),
+            "q_i": _normal(seed + 3, (S, Hi, d), pages),
+            "w_i": _normal(seed + 4, (S, Hi)),
+            "lengths": jnp.asarray(lengths),
+            "pt": jnp.asarray(1 + rng.integers(0, N - 1, (S, wp)), jnp.int32),
+        }
+
+    def cached(inp):
+        return jnp.arange(wp * psz)[None, :] < inp["lengths"][:, None]
+
+    def scores_of(inp, layer):
+        keys = inp["idx"][layer, 0][inp["pt"]].reshape(S, wp * psz, d)
+        return hybrid.index_scores(inp["q_i"].astype(jnp.float32), inp["w_i"], keys.astype(jnp.float32))
+
+    def score_case(label, layer, pages):
+        def kernel(inp):
+            out = paged_index_scores_stacked(
+                inp["q_i"], inp["w_i"], inp["idx"], jnp.int32(layer), inp["lengths"], inp["pt"], pages_per_compute_block=ppcb, interpret=not compiled
+            )
+            return jnp.where(cached(inp), out, 0)  # past a slot's length the launch leaves what was there
+
+        def reference(inp):
+            return jnp.where(cached(inp), scores_of(inp, layer), 0)
+
+        # scores are sums of Hi x d products of N(0, 1): of order sqrt(Hi * d); bfloat16 operands are exact, the sum float32
+        return {"case": label, "build": lambda: build(pages), "kernel": kernel, "reference": reference, "tol": 1e-3 if pages == jnp.float32 else 5e-2}
+
+    def read_case(label, layer, pages, clustered=False):
+        def chosen(inp):
+            if clustered:  # the last topk tokens alone: every earlier block of a long slot holds no chosen token
+                return cached(inp) & (jnp.arange(wp * psz)[None, :] >= inp["lengths"][:, None] - topk)
+            return hybrid.select_top(scores_of(inp, layer), cached(inp), topk)
+
+        def kernel(inp):
+            out = paged_latent_attention_stacked(
+                inp["q"], inp["pool"], jnp.int32(layer), inp["lengths"], inp["pt"], value_lanes=value,
+                pages_per_compute_block=ppcb, sm_scale=lanes**-0.5, select=chosen(inp), interpret=not compiled,
+            )
+            return _live(out, inp["lengths"])
+
+        def reference(inp):
+            pool = inp["pool"][layer].astype(jnp.float32)
+            out = paged_kv.paged_attention_xla(
+                inp["q"].astype(jnp.float32), pool, pool, inp["lengths"], inp["pt"], sm_scale=lanes**-0.5, select=chosen(inp)
+            )
+            return _live(out[..., :value], inp["lengths"])
+
+        return {"case": label, "build": lambda: build(pages), "kernel": kernel, "reference": reference, "tol": 1e-5 if pages == jnp.float32 else 3e-2}
+
+    yield score_case("scores-bf16-layer0", 0, jnp.bfloat16)
+    yield read_case("masked-bf16-layer2", L - 1, jnp.bfloat16)
+    yield read_case("masked-bf16-last-tokens", 1, jnp.bfloat16, clustered=True)
+    if not compiled:
+        yield score_case("scores-f32-layer1", 1, jnp.float32)
+        yield read_case("masked-f32-layer1", 1, jnp.float32)
+        yield read_case("masked-f32-last-tokens", 0, jnp.float32, clustered=True)
+
+
+# ---------------------------------------------------------------------------
 # the expert FFN over the touched experts only (ops/moe_touched_experts.py)
 # against a loop over the listed experts in float32, rounded to the rows' type
 # where the dense form rounds. The skip is shown by POISON: every expert off the list, and every other layer, is NaN (XLA's
@@ -338,6 +425,67 @@ def _cases_moe_touched(compiled: bool = False) -> Iterator[dict]:
     yield case("f32-none-touched", [], jnp.float32, tol=0.0)
     # a product one bfloat16 step off where the two sides add in another order moves an output by 1e-3
     yield case("bf16-three-touched", [0, 3, 4], jnp.bfloat16, tol=5e-3)
+
+
+@register_kernel("moe_touched_experts_parts")
+def _cases_moe_touched_parts(compiled: bool = False) -> Iterator[dict]:
+    """The touched-expert launch where an expert goes through the ring in
+    parts of its width (``width_parts`` > 1: [6144, 2048] on the chip; off
+    it the ring is shrunk so that [128, 512] goes in 2 or 4), against the
+    same loop: a part is a smaller expert under the same gate."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import moe_touched_experts as mte
+
+    L, E, D, F, T = (2, 16, 6144, 2048, 64) if compiled else (2, 5, 128, 512, 12)
+
+    def case(label, listed, dtype, ring_bytes=None, tol=1e-5, layer=1):
+        listed = list(listed)
+
+        def build():
+            on = jnp.zeros((L, E), bool).at[layer, jnp.asarray(listed, jnp.int32)].set(True)[:, :, None, None]
+            rows_on = jnp.zeros((T, E), bool).at[:, jnp.asarray(listed, jnp.int32)].set(True)
+            return {
+                "x": _normal(3, (T, D), dtype),
+                "gate": jnp.where(rows_on & (_normal(4, (T, E)) > 0), jnp.abs(_normal(5, (T, E))) / max(len(listed), 1), 0.0),
+                "wg": jnp.where(on, _normal(6, (L, E, D, F), dtype) * D**-0.5, jnp.nan),
+                "wu": jnp.where(on, _normal(7, (L, E, D, F), dtype) * D**-0.5, jnp.nan),
+                "wd": jnp.where(on, _normal(8, (L, E, F, D), dtype) * F**-0.5, jnp.nan),
+            }
+
+        ids = jnp.asarray(listed + [0] * (E - len(listed)), jnp.int32)
+
+        def kernel(inp):
+            was = mte._RING_BYTES
+            mte._RING_BYTES = ring_bytes or was
+            try:
+                assert mte.width_parts(D, F, jnp.dtype(dtype).itemsize) > 1
+                return mte.touched_expert_ffn(
+                    inp["x"], inp["gate"], inp["wg"], inp["wu"], inp["wd"], jnp.int32(layer), ids, jnp.int32(len(listed)), interpret=not compiled
+                )
+            finally:
+                mte._RING_BYTES = was
+
+        def reference(inp):
+            x = inp["x"].astype(jnp.float32)
+            rounded = lambda a: a.astype(dtype).astype(jnp.float32)  # noqa: E731
+            out = jnp.zeros((T, D), jnp.float32)
+            for e in listed:
+                wg, wu, wd = (inp[k][layer, e].astype(jnp.float32) for k in ("wg", "wu", "wd"))
+                out = out + rounded(jax.nn.silu(rounded(x @ wg)) * rounded(x @ wu) * inp["gate"][:, e : e + 1]) @ wd
+            return out
+
+        return {"case": label, "build": build, "kernel": kernel, "reference": reference, "tol": tol}
+
+    if compiled:
+        yield case("bf16-4-parts-half-touched", [1, 2, 5, 7, 8, 11, 12, 15], jnp.bfloat16, tol=CHIP_TOL)
+        yield case("bf16-4-parts-none-touched", [], jnp.bfloat16, layer=0, tol=0.0)
+        return
+    one_part = 2 * 3 * 128 * 512 * 4  # the ring's bytes for one whole [128, 512] expert in float32, twice
+    yield case("f32-2-parts-three-touched", [4, 0, 3], jnp.float32, ring_bytes=one_part // 2)
+    yield case("f32-4-parts-all-touched", range(E), jnp.float32, ring_bytes=one_part // 4, layer=0)
+    yield case("f32-4-parts-none-touched", [], jnp.float32, ring_bytes=one_part // 4, tol=0.0)
 
 
 # ---------------------------------------------------------------------------

@@ -112,14 +112,14 @@ def test_absorbed_form_equals_plain_form_on_one_layer(model):
     L = 29
     h = jax.random.normal(jax.random.PRNGKey(3), (1, L, mcfg.hidden_size), jnp.float32)
     positions = jnp.arange(L, dtype=jnp.int32)[None]
-    q_nope, q_rope, c, k_r = hybrid._mla_in(mcfg, layer, h, positions)
-    mask = jnp.tril(jnp.ones((L, L), bool))[None, None]
-    plain = hybrid.mla_prefill_attend(mcfg, layer, q_nope, q_rope, c, k_r, mask)[0, -1]
+    q_nope, q_rope, c, k_r, q_r = hybrid._mla_in(mcfg, layer, h, positions)
+    assert q_r is None  # a full-rank query: no low-rank path in this family's published configuration
+    plain = hybrid.mla_prefill_attend(mcfg, layer, (q_nope[0], q_rope[0]), c[0], k_r[0])[-1]  # [D]: the output projection is in the block
     rows = hybrid._latent_row(mcfg, c, k_r)[0, :, 0, :]  # what the pages hold: [L, 256], 136 values and zeros
     assert rows.shape == (L, 256) and not np.asarray(rows[:, 136:]).any()
     q = hybrid.mla_absorbed_query(mcfg, layer, q_nope[0, -1:], q_rope[0, -1:])  # [1, H, 256]
     probs = jax.nn.softmax(jnp.einsum("shl,tl->sht", q, rows) * mcfg.sm_scale, axis=-1)
-    absorbed = hybrid.mla_absorbed_out(mcfg, layer, jnp.einsum("sht,tr->shr", probs, rows[:, : mcfg.kv_lora_rank]))[0]
+    absorbed = hybrid.mla_absorbed_out(mcfg, layer, jnp.einsum("sht,tr->shr", probs, rows[:, : mcfg.kv_lora_rank]))[0] @ layer["wo"]
     assert float(jnp.abs(plain).max()) > 1e-3
     np.testing.assert_allclose(np.asarray(absorbed), np.asarray(plain), atol=2e-6, rtol=0)
 
@@ -183,7 +183,6 @@ def test_moe_touched_counts_held_experts_only():
 def test_configuration_refuses_what_the_module_does_not_implement():
     base = {k: v for k, v in ku.tiny_model().items() if k != "assumed"}
     for change, msg in (
-        ({"q_lora_rank": 64}, "q_lora_rank"),
         ({"n_group": 2}, "group-limited"),
         ({"topk_group": 2}, "group-limited"),
         ({"rope_scaling": {"type": "yarn", "factor": 4}}, "rope_scaling"),

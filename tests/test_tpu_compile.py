@@ -720,3 +720,102 @@ def test_page_copy_reserves_no_second_pool_on_v5e(chip, pools):
     pairs = chip((8,), jnp.int32)
     compiled = jax.jit(paged_kv.copy_pages, donate_argnums=(0,)).lower(cache, pairs, pairs, pairs, pairs).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16e6
+
+
+def _glm5(chip, monkeypatch, layers: int = 2, kv_gb: float = 3.0):
+    """The ``glm_moe_dsa`` family at the benchmark's published widths (hidden
+    6144, 64 heads, latent rows of 576 in 640 lanes beside index keys of 128,
+    16 held experts of [6144, 2048] under a router of 256, a vocabulary of
+    19,360) and 64 slots, cut to the leading dense layer and one expert layer
+    so that tier-1 can hold the compile (the layers are scanned: a program's
+    temporaries are one layer's); the page pools are the cell's 2,730 pages."""
+    import json
+
+    from areal_tpu import models
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "chip", "configs", "glm-5-ep16-d6.json")) as f:
+        cfg = json.load(f)
+    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "reduced_from", "assumed", "assumed_notes", "stands_for")}
+    keep = ("router_experts", "expert_first", "latent_row_lanes", "index_norm_eps")
+    hf.update({k: cfg["assumed"][k] for k in keep}, num_hidden_layers=layers, dtype="bfloat16")
+    mcfg = models.config_from_hf_dict(hf)
+    n_pages = paged_kv.n_pages_for_budget(int(kv_gb * 2**30), 6, 1, PSZ, 640, 2, pools=mcfg.kv_pools)
+    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
+    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, n_pages, PSZ, slots=64))
+    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return mcfg, place(params), place(cache), n_pages
+
+
+def test_glm5_decode_steps_compile_for_v5e_at_the_longest_window(chip, monkeypatch):
+    """Two decode steps as the engine's chunk runs them at the cell's ONE
+    window (160 pages: 20,480 tokens, 64 slots): the index's launch over the
+    pool of index keys, the selection's 32 counting passes over [64, 20480],
+    the latent launch under the selection, both pools written by one launch a
+    layer, the expert matmuls as the touched-expert launch on the stacks (64
+    rows x top-8 over 256: 2 assignments an expert) with an expert of [6144,
+    2048] going through the ring in 4 parts. No page pool copied, no layer of
+    the expert stacks sliced out, the low-rank query's and the index's
+    matrices not re-laid out whole, 0.25 GB of temporaries."""
+    from areal_tpu.models import hybrid
+
+    mcfg, params, cache, n_pages = _glm5(chip, monkeypatch)
+    assert n_pages == 2730 and {k: v.shape for k, v in cache.items()} == {"k": (2, 1, 2730, PSZ, 640), "idx": (2, 1, 2730, PSZ, 128)}
+
+    def two_steps(params, cache, pt, ids, pos, active):
+        cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
+
+        def step(c, _):
+            ids, pos, cache = c
+            h, cache = hybrid.forward_decode_paged(params, mcfg, ids, pos, cache, pt, page_size=PSZ, active=active, use_kernel=True)
+            return (jnp.argmax(hybrid.compute_logits(params, mcfg, h), -1).astype(jnp.int32), pos + 1, cache), None
+
+        (ids, _, cache), _ = jax.lax.scan(step, (ids, pos, cache), None, length=2)
+        return ids, cache
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(64, 160), i32(64), i32(64), chip((64,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    for name in ("paged_latent_attn", "paged_index_scores", "paged_kv_write", "moe_touched_experts"):
+        assert name in text, name
+    for pool in ("bf16[2,1,2730,128,640]", "bf16[2,1,2730,128,128]", "bf16[1,2730,128,640]", "bf16[1,2730,128,128]"):
+        assert not [ln for ln in text.splitlines() if " copy(" in ln and pool in ln], pool
+    # no op's RESULT is a layer of an expert stack: no copy, dynamic-slice or fusion of 75 MB matrices
+    made = re.compile(r"= bf16\[(1,)?16,(6144,2048|2048,6144)\]\S* (?!parameter|get-tuple-element)")
+    assert not [ln for ln in text.splitlines() if made.search(ln)]
+    # W_qb's and W^I_qb's stacks are not re-laid out whole (their outputs are split behind a barrier)
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and ("bf16[2,2048,16384]" in ln or "bf16[1,2048,16384]" in ln or "bf16[1,2048,4096]" in ln)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+def test_glm5_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypatch):
+    """ONE prompt of 16,384 tokens, the cell's longest bucket, into both
+    pools: attention blocked over 256 queries against 2,048 keys (no [H, L,
+    L]: that would be 69 GB), the selection made a query block at a time,
+    every head's keys and values of the prompt made once a layer (0.9 GB),
+    the expert rows through the grouped matmuls 2,048 at a time ON THE STACK
+    (a layer's three matrices copied out for them were 1.15 GB; 16k rows
+    gathered at once 1.6 GB). 2.6 GB of temporaries by this count, which
+    over-counts a donated program (the compiler's own: 2.12 GB): with 9.46
+    GB of weights and a pool of 3.22 GB, under 15 GB."""
+    from areal_tpu.models import hybrid, moe
+
+    # three layers: the last one's feed-forward block feeds no page and is compiled away, the middle one's stays
+    mcfg, params, cache, _ = _glm5(chip, monkeypatch, layers=3)
+    assert hybrid.prefill_blocks(mcfg, 16384) == (256, 2048) and hybrid.ffn_block_rows(mcfg, "moe", 16384) == 2048
+    assert not moe.takes_dense_form(2048, 16)
+
+    def prefill(params, cache, ids, plens, flat_pages, slots):
+        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(1, 16384), i32(1), i32(16384 // PSZ), i32(1)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3  # the three grouped matmuls of the expert layer
+    made = re.compile(r"= bf16\[(1,)?16,(6144,2048|2048,6144)\]\S* (?!parameter|get-tuple-element)")
+    assert not [ln for ln in text.splitlines() if made.search(ln)]  # the grouped matmuls read the stacks where they lie
+    assert "bf16[131072,6144]" not in text  # 16k rows x top-8 are never gathered at once
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.7e9
