@@ -3056,9 +3056,12 @@ class DecodeEngine:
             )
         self.stats["prefills"] += A
         self.stats["prefill_batches"] += 1
-        self.stats["prefill_tokens"] += int(plens[:A].sum())  # pad rows excluded
+        prompt_tokens = int(plens[:A].sum())  # pad rows excluded
+        self.stats["prefill_tokens"] += prompt_tokens
         self._obs.prefills.inc(A)
-        self._obs.prefill_tokens.inc(int(plens[:A].sum()))
+        self._obs.prefill_tokens.inc(prompt_tokens)
+        if self.model.prefill_attn_launch(self.model_cfg, bucket):
+            self._obs.prefill_attn_launch_tokens.inc(prompt_tokens)
         if self.model_cfg.has_recurrent_state:
             rebuilt = [t.req.rid for t, _ in admitted if t.req.rid in self._state_dropped]
             self._state_dropped.difference_update(rebuilt)
@@ -3450,8 +3453,9 @@ class DecodeEngine:
             # ``decode`` (ops/paged_kv_write.py beside the decode kernel, per-head
             # scatters beside the gather path; prefill and verify always scatter)
             "kv_write": kern if self._use_kernel else "xla",
-            # cold prefill is plain causal attention over the prompt bucket
-            "prefill": "xla",
+            # cold prefill is plain causal attention over the prompt bucket: XLA's, but for the latent-attention
+            # layers of a prompt of 1,024 tokens or more on a TPU (ops/latent_prefill_attention.py)
+            "prefill": kern if self.model is not None and self.model.prefill_attn_launch(self.model_cfg, 1024) else "xla",
             "suffix_prefill": kern if self._suffix_kernel() else "xla",
             "verify": (
                 "off"

@@ -75,7 +75,11 @@ across both sums, so that a cached token is read as 576 values and not as
 pass is BLOCKED over queries (``mla_prefill_attend``): a block of queries
 meets a block of keys at a time under a running softmax, key blocks past the
 diagonal are never visited, and nothing of [H, L, L] exists (69 GB at 64
-heads and 16k tokens).
+heads and 16k tokens). On a TPU that walk over the key blocks is ONE Pallas
+launch a query block (``ops/latent_prefill_attention.py mla_prefill_flash``:
+the logits of a head and key block in VMEM, the selection a mask operand),
+chosen from the shapes (``prefill_takes_launch``); the XLA loop is the CPU
+path and the launch's oracle (tests/test_latent_prefill_attention.py).
 
 Where the layer has a learned index (``index_topk``), a token also leaves ONE
 index key behind, in a page pool of its own width on the same page ids
@@ -1717,23 +1721,111 @@ def _w_kvb_heads(cfg: HybridConfig, layer: dict):
     return w[..., : cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim :]
 
 
-# float32 logits a block of queries may hold against a block of keys, every head at once
+# float32 logits a block of queries may hold against a block of keys, every head at once (the XLA form)
 _PREFILL_LOGIT_BYTES = 128 << 20
 _PREFILL_KEY_BLOCK = 2048
+# under the launch (ops/latent_prefill_attention.py) the logits live in VMEM, and what holds a block of queries
+# is the index's float32 scores against every key of the prompt, [queries, L], and the selection's passes over them
+_PREFILL_SCORE_BYTES = 32 << 20
+_PREFILL_LAUNCH_BLOCKS = (1024, 1024)
+_PREFILL_LAUNCH_TOKENS = 1024  # the shortest prompt that takes the launch
+# float32 [queries, index heads, keys] dots of the index a block of queries may hold against a block of keys
+_PREFILL_INDEX_DOT_BYTES = 64 << 20
 
 
-def prefill_blocks(cfg: HybridConfig, L: int) -> tuple[int, int]:
+def prefill_takes_launch(cfg: HybridConfig, L: int) -> bool:
+    """Whether a prompt of ``L`` tokens (its program's bucket) attends under
+    ``mla_prefill_flash`` on a TPU, from the shapes alone: a prompt of 1,024
+    tokens or more whose blocks are whole lane tiles (a bucket is a multiple
+    of 256; a head's values whole tiles). Under 1,024 the XLA loop is one
+    block whose logits (75 MB at 32 heads x 768) still pass at XLA's speed,
+    and a launch's steps of one small block a head cost more than they save:
+    the probe reads 334 / 340 / 503 us a layer against XLA's 265 / 240 / 341
+    at 32 heads x 256 / 512 / 768, and 413 against 1,215 at 1,024 (PERF.md,
+    PR 40)."""
+    return L >= _PREFILL_LAUNCH_TOKENS and L % 128 == 0 and cfg.v_head_dim % 128 == 0
+
+
+def prefill_attn_launch(cfg: HybridConfig, L: int) -> bool:
+    """Whether the prefill program of bucket ``L`` runs its latent-attention
+    layers' attention under the launch: what ``mla_prefill_attend`` goes by,
+    and what the engine counts
+    ``areal_decode_prefill_attn_launch_tokens_total`` by."""
+    return "mla" in cfg.layer_types and jax.default_backend() == "tpu" and prefill_takes_launch(cfg, L)
+
+
+def _pow2_part(L: int, fit: int) -> int:
+    """The largest power of two up to ``fit`` that divides ``L``."""
+    return math.gcd(L, 1 << (max(1, fit).bit_length() - 1))
+
+
+def prefill_blocks(cfg: HybridConfig, L: int, launch: bool = False) -> tuple[int, int]:
     """(queries, keys) a block of the prompt pass's attention, from the
-    shapes alone: keys in blocks of up to 2,048 that divide the prompt,
-    queries in as many as keep [H, queries, keys] float32 logits inside 128
-    MB (32 heads x 1,024 x 1,024: a short prompt is one block, as before the
-    blocks; 64 heads against 2,048 keys: 256 queries)."""
+    shapes alone. The XLA form: keys in blocks of up to 2,048 that divide
+    the prompt, queries in as many as keep [H, queries, keys] float32 logits
+    inside 128 MB (32 heads x 1,024 x 1,024: a short prompt is one block; 64
+    heads against 2,048 keys: 256 queries). Under the launch the logits
+    never leave VMEM: blocks of up to 1,024 x 1,024 that divide the prompt
+    (the probe's fastest at 32 heads and within 4% of it at 64; a head's key
+    and value block is then fetched for 512-1,024 operations a byte, two to
+    four times the chip's ridge), the queries also held to 32 MB of the
+    index's float32 [queries, L] scores where the layer has an index: 1,024
+    up to 8k tokens, 512 at 12k and 16k, where 1,024 would put the 16k
+    program's temporaries 25 MB over the XLA form's (PERF.md, PR 40)."""
+    if launch:
+        tq, tk = _PREFILL_LAUNCH_BLOCKS
+        if cfg.index_topk:
+            tq = min(tq, max(32, _PREFILL_SCORE_BYTES // (4 * L)))
+        return _pow2_part(L, tq), _pow2_part(L, tk)
     tk = math.gcd(L, _PREFILL_KEY_BLOCK)
-    fit = max(8, _PREFILL_LOGIT_BYTES // (4 * cfg.num_heads * tk))
-    return math.gcd(L, 1 << (fit.bit_length() - 1)), tk
+    return _pow2_part(L, max(8, _PREFILL_LOGIT_BYTES // (4 * cfg.num_heads * tk))), tk
 
 
-def mla_prefill_attend(cfg: HybridConfig, layer: dict, q, c, k_r, index=None):
+def prefill_attend_block(cfg: HybridConfig, qn, qr, kv, k_r, i, blocks: tuple[int, int], chosen=None, launch: bool = False):
+    """Block ``i`` of ``blocks[0]`` queries (qn [tq, H, nope], qr [tq, H,
+    rope]) over the key blocks of ``blocks[1]`` up to its diagonal, under a
+    running softmax: [tq, H * v]. ``chosen`` bool [tq, L]: the keys each
+    query attends to (else every key up to the query). The XLA loop takes
+    ``kv`` [L, H, nope + v] and ``k_r`` [L, rope]; with ``launch`` the walk
+    is ONE Pallas launch (``ops/latent_prefill_attention.py``) over ``kv``
+    [L, H * (key lanes + v)] as ``padded_w_kvb`` lays it out."""
+    tq, tk = blocks
+    H, dn, dv = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    if launch:
+        from areal_tpu.ops import latent_prefill_attention as flash
+
+        with jax.named_scope("attn"):
+            q_lanes, kr_lanes = flash.prefill_operands(qn, qr, k_r)
+            return flash.mla_prefill_flash(q_lanes, kv, kr_lanes, i, chosen, heads=H, block_k=tk, sm_scale=cfg.sm_scale)
+    pos = i * tq + jnp.arange(tq, dtype=jnp.int32)
+    k_pos = jnp.arange(tk, dtype=jnp.int32)
+
+    def attend(kb, carry):
+        m_prev, l_prev, acc = carry
+        with jax.named_scope("attn"):
+            kvb = jax.lax.dynamic_slice_in_dim(kv, kb * tk, tk, axis=0)
+            krb = jax.lax.dynamic_slice_in_dim(k_r, kb * tk, tk, axis=0)
+            logits = jnp.einsum("thd,shd->hts", qn, kvb[..., :dn], preferred_element_type=jnp.float32)
+            logits = logits + jnp.einsum("thd,sd->hts", qr, krb, preferred_element_type=jnp.float32)
+            if chosen is not None:
+                seen = jax.lax.dynamic_slice_in_dim(chosen, kb * tk, tk, axis=1)
+            else:
+                seen = pos[:, None] >= (kb * tk + k_pos)[None, :]
+            logits = jnp.where(seen[None], logits * cfg.sm_scale, -1e30)
+            m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+            p = jnp.where(seen[None], jnp.exp(logits - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            pv = jnp.einsum("hts,shd->htd", p.astype(kv.dtype), kvb[..., dn:], preferred_element_type=jnp.float32)
+            return m_new, l_prev * corr + jnp.sum(p, axis=-1, keepdims=True), acc * corr + pv
+
+    init = (jnp.full((H, tq, 1), -1e30, jnp.float32), jnp.zeros((H, tq, 1), jnp.float32), jnp.zeros((H, tq, dv), jnp.float32))
+    n_kb = ((i + 1) * tq + tk - 1) // tk  # key blocks up to the diagonal
+    _, l, acc = jax.lax.fori_loop(0, n_kb, attend, init)
+    with jax.named_scope("attn"):
+        return jnp.swapaxes(acc / l, 0, 1).reshape(tq, H * dv).astype(kv.dtype)
+
+
+def mla_prefill_attend(cfg: HybridConfig, layer: dict, q, c, k_r, index=None, launch: bool | None = None):
     """The PLAIN form over ONE prompt, blocked over queries: every head's key
     and value made from the prompt's own latent (``[k_nope | v] = W_kvb c``,
     the rotary key shared by all heads), causal softmax of q k^T / sqrt(nope
@@ -1746,58 +1838,46 @@ def mla_prefill_attend(cfg: HybridConfig, layer: dict, q, c, k_r, index=None):
     (``select_top``), and attends under that mask; without it every key up
     to the query. No [H, L, L]: a block of queries meets a block of keys at a
     time with a running softmax, and key blocks past the diagonal are never
-    visited. Returns [L, D]."""
+    visited (``prefill_attend_block``): ONE Pallas launch a query block,
+    the logits in VMEM, on a TPU where the shapes allow
+    (``prefill_attn_launch``; ``launch`` says so for a test), else the XLA
+    loop, the launch's oracle. Returns [L, D]."""
     L = c.shape[0]
-    H, dn, dv = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
-    tq, tk = prefill_blocks(cfg, L)
+    if launch is None:
+        launch = prefill_attn_launch(cfg, L)
+    tq, tk = prefill_blocks(cfg, L, launch)
     with jax.named_scope("attn"):
-        kv = (c @ layer["w_kvb"]).reshape(L, H, dn + dv)
-    k_pos = jnp.arange(tk, dtype=jnp.int32)
+        if launch:  # a head's [k_nope | 0 | v] as a column block: W_kvb's columns laid out so, once a layer
+            from areal_tpu.ops.latent_prefill_attention import padded_w_kvb
+
+            kv = c @ padded_w_kvb(layer["w_kvb"], cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim)
+        else:
+            kv = (c @ layer["w_kvb"]).reshape(L, cfg.num_heads, cfg.qk_nope_head_dim + cfg.v_head_dim)
 
     def block(i):
         pos = i * tq + jnp.arange(tq, dtype=jnp.int32)
-        n_kb = ((i + 1) * tq + tk - 1) // tk  # key blocks up to the diagonal
         rows = functools.partial(jax.lax.dynamic_slice_in_dim, start_index=i * tq, slice_size=tq, axis=0)
         if isinstance(q, tuple):
             qn, qr = rows(q[0]), rows(q[1])
         else:
             qn, qr = mla_query(cfg, layer, rows(q), pos)
+        chosen = None
         if index is not None:
             h, k_i = index
             q_i, w = index_query(cfg, layer, rows(h), rows(q), pos)
+            # keys a scored block: the index's float32 [queries, heads, keys] dots inside their bytes
+            ts = _pow2_part(L, max(128, _PREFILL_INDEX_DOT_BYTES // (4 * cfg.index_n_heads * tq)))
 
             def score(kb, sc):
                 with jax.named_scope("dsa_index_score"):
-                    blk = index_scores(q_i, w, jax.lax.dynamic_slice_in_dim(k_i, kb * tk, tk, axis=0))
-                    return jax.lax.dynamic_update_slice_in_dim(sc, blk, kb * tk, axis=1)
+                    blk = index_scores(q_i, w, jax.lax.dynamic_slice_in_dim(k_i, kb * ts, ts, axis=0))
+                    return jax.lax.dynamic_update_slice_in_dim(sc, blk, kb * ts, axis=1)
 
             with jax.named_scope("dsa_index_score"):
-                scores = jax.lax.fori_loop(0, n_kb, score, jnp.zeros((tq, L), jnp.float32))
+                scores = jax.lax.fori_loop(0, ((i + 1) * tq + ts - 1) // ts, score, jnp.zeros((tq, L), jnp.float32))
             with jax.named_scope("dsa_select"):
                 chosen = select_top(scores, pos[:, None] >= jnp.arange(L, dtype=jnp.int32)[None, :], cfg.index_topk)
-
-        def attend(kb, carry):
-            m_prev, l_prev, acc = carry
-            with jax.named_scope("attn"):
-                kvb = jax.lax.dynamic_slice_in_dim(kv, kb * tk, tk, axis=0)
-                krb = jax.lax.dynamic_slice_in_dim(k_r, kb * tk, tk, axis=0)
-                logits = jnp.einsum("thd,shd->hts", qn, kvb[..., :dn], preferred_element_type=jnp.float32)
-                logits = logits + jnp.einsum("thd,sd->hts", qr, krb, preferred_element_type=jnp.float32)
-                if index is not None:
-                    seen = jax.lax.dynamic_slice_in_dim(chosen, kb * tk, tk, axis=1)
-                else:
-                    seen = pos[:, None] >= (kb * tk + k_pos)[None, :]
-                logits = jnp.where(seen[None], logits * cfg.sm_scale, -1e30)
-                m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-                p = jnp.where(seen[None], jnp.exp(logits - m_new), 0.0)
-                corr = jnp.exp(m_prev - m_new)
-                pv = jnp.einsum("hts,shd->htd", p.astype(kv.dtype), kvb[..., dn:], preferred_element_type=jnp.float32)
-                return m_new, l_prev * corr + jnp.sum(p, axis=-1, keepdims=True), acc * corr + pv
-
-        init = (jnp.full((H, tq, 1), -1e30, jnp.float32), jnp.zeros((H, tq, 1), jnp.float32), jnp.zeros((H, tq, dv), jnp.float32))
-        _, l, acc = jax.lax.fori_loop(0, n_kb, attend, init)
-        with jax.named_scope("attn"):
-            o = jnp.swapaxes(acc / l, 0, 1).reshape(tq, H * dv).astype(kv.dtype)
+        o = prefill_attend_block(cfg, qn, qr, kv, k_r, i, (tq, tk), chosen, launch)
         with jax.named_scope("mla_proj"):
             return _proj(cfg, layer, "wo", o)
 

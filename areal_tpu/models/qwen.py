@@ -354,6 +354,14 @@ def serving_limits(cfg) -> dict[str, str]:
     return {}
 
 
+def prefill_attn_launch(cfg, L: int) -> bool:
+    """Whether the prefill program of bucket ``L`` attends under the
+    latent-attention prompt launch (``models/hybrid.py``): this family has
+    no latent attention."""
+    del cfg, L
+    return False
+
+
 # int8 weight-only serving quantization. The reference reaches serving
 # quantization through SGLang/vLLM deployment options; the TPU-native engine
 # provides it as a first-class transform. Dense projection weights only —

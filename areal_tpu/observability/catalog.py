@@ -138,6 +138,14 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "Prompt tokens actually prefilled (radix-cached prefix tokens "
             "excluded — the denominator's complement for prefix hit rate).",
         ),
+        prefill_attn_launch_tokens=r.counter(
+            "areal_decode_prefill_attn_launch_tokens_total",
+            "Prompt tokens prefilled by a program whose latent-attention "
+            "layers attend under the mla_prefill_flash launch (ops/"
+            "latent_prefill_attention.py), counted where the engine "
+            "dispatches the program: over areal_decode_prefill_tokens_total, "
+            "the share of prompt tokens the launch served.",
+        ),
         chunks=r.counter(
             "areal_decode_chunks_total", "Jitted decode chunks executed."
         ),

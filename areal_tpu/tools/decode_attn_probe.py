@@ -34,6 +34,17 @@ holds tokens, the unselected masked) and GATHERED (the selected rows fetched
 by index into [slots, 2048, 640] and the absorbed products over them, in XLA:
 the form the program does not keep), by cached tokens a slot.
 
+``--only latent-prefill``: the PROMPT pass's attention of a latent-attention
+layer alone (``hybrid.prefill_attend_block`` over every query block of one
+prompt, the keys and values already made): the XLA loop in its own blocks
+against the one launch a query block (``mla_prefill_flash``), at 1,024 /
+4,096 / 8,192 / 16,384 tokens, 32 heads (128 + 64 | 128: the kanana cell's)
+and 64 (192 + 64 | 256: the GLM-5 cell's), causal and with a selection of
+2,048 keys a query as a mask operand, by (queries, keys) a block: us a layer
+and TFLOP/s over the operations under the causal mask, L (L + 1) / 2 x
+heads x 2 x (nope + rope + v). The table that sets ``hybrid.prefill_blocks``
+and ``prefill_takes_launch``.
+
 ``paged_kv_write``: a step's KV rows of every layer written into the stacked
 pools (``paged_kv.write_decode_rows``) by the per-head XLA scatters over every
 slot and by the one ``paged_kv_write`` launch over the live slots, at 0 / 25 /
@@ -298,6 +309,86 @@ def probe_dsa(*, seed: int, reps: int, ppcb: int, pages: int = 2730) -> list[dic
     return out
 
 
+PREFILL_HEADS = {32: dict(dn=128, dr=64, dv=128), 64: dict(dn=192, dr=64, dv=256)}  # the kanana cell's heads, the GLM-5 cell's
+PREFILL_LAUNCH_BLOCKS = ((256, 512), (512, 512), (512, 1024), (1024, 512), (1024, 1024), (512, 2048), (1024, 2048))
+
+
+def _prefill_attention(cfg, L: int, blocks: tuple[int, int], launch: bool):
+    """The jitted attention of one prompt: ``hybrid.prefill_attend_block`` over every query block."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.models import hybrid
+
+    tq = blocks[0]
+
+    def attention(qn, qr, kv, k_r, chosen):
+        def block(i):
+            rows = lambda a: jax.lax.dynamic_slice_in_dim(a, i * tq, tq, axis=0)  # noqa: E731
+            o = hybrid.prefill_attend_block(cfg, rows(qn), rows(qr), kv, k_r, i, blocks, None if chosen is None else rows(chosen), launch)
+            # behind a barrier, as the output projection stands behind it in the model: written straight into the map's
+            # stacked result the launch is wrapped in a fusion that drops its VMEM limit
+            return jax.lax.optimization_barrier(o)
+
+        return jax.lax.map(block, jnp.arange(L // tq, dtype=jnp.int32)).reshape(L, -1)
+
+    return jax.jit(attention)
+
+
+def probe_latent_prefill(H: int, L: int, *, seed: int, reps: int, topk: int = 2048) -> list[dict]:
+    """us a layer and TFLOP/s of the attention of one prompt of ``L`` tokens at ``H`` heads, the XLA loop and
+    the launch by its blocks, causal and under a selection of ``topk`` keys a query."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.models import hybrid
+    from areal_tpu.ops.latent_prefill_attention import padded_w_kvb
+
+    dn, dr, dv = (PREFILL_HEADS[H][k] for k in ("dn", "dr", "dv"))
+    ks = jax.random.split(jax.random.PRNGKey(seed + L + H), 6)
+    c = jax.random.normal(ks[0], (L, 512), jnp.bfloat16)
+    w = (jax.random.normal(ks[1], (512, H * (dn + dv)), jnp.float32) * 512**-0.5).astype(jnp.bfloat16)
+    qn = jax.random.normal(ks[2], (L, H, dn), jnp.bfloat16)
+    qr = jax.random.normal(ks[3], (L, H, dr), jnp.bfloat16)
+    k_r = jax.random.normal(ks[4], (L, dr), jnp.bfloat16)
+    kv = {False: jax.jit(lambda c, w: (c @ w).reshape(L, H, dn + dv))(c, w), True: jax.jit(lambda c, w: c @ padded_w_kvb(w, H, dn, dr))(c, w)}
+    pos = jnp.arange(L, dtype=jnp.int32)
+    # about min(topk, visible) keys a query, its own among them
+    drawn = jax.jit(lambda k: (jax.random.uniform(k, (L, L)) * (pos[:, None] + 1) < topk) & (pos[:, None] >= pos[None, :]) | (pos[:, None] == pos[None, :]))(ks[5])
+    flops = L * (L + 1) // 2 * H * 2 * (dn + dr + dv)
+
+    def timed(cfg, blocks, launch, chosen):
+        fn, args = _prefill_attention(cfg, L, blocks, launch), (qn, qr, kv[launch], k_r, chosen)
+        got = jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            res = fn(*args)
+        jax.block_until_ready(res)
+        return (time.perf_counter() - t0) / reps * 1e6, got
+
+    out = []
+    for masked in (False, True):
+        cfg = types.SimpleNamespace(num_heads=H, qk_nope_head_dim=dn, v_head_dim=dv, sm_scale=(dn + dr) ** -0.5, index_topk=topk if masked else 0)
+        chosen = drawn if masked else None
+        xla_blocks, chosen_blocks = hybrid.prefill_blocks(cfg, L), hybrid.prefill_blocks(cfg, L, True)
+        xla_us, want = timed(cfg, xla_blocks, False, chosen)
+        row = {
+            "probe": "latent-prefill", "heads": H, "tokens": L, "mask_operand": masked, "xla_blocks": list(xla_blocks),
+            "xla_us": xla_us, "xla_tflops": flops / xla_us / 1e6, "takes_launch": hybrid.prefill_takes_launch(cfg, L), "chosen_blocks": list(chosen_blocks), "launch": {},
+        }
+        for blocks in sorted({*PREFILL_LAUNCH_BLOCKS, chosen_blocks}):
+            if L % blocks[0] or L % blocks[1]:
+                continue
+            us, got = timed(cfg, blocks, True, chosen)
+            row["launch"][f"{blocks[0]}x{blocks[1]}"] = {
+                "us": us, "tflops": flops / us / 1e6, "max_abs_vs_xla": float(jnp.max(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32)))),
+            }
+        out.append(row)
+    return out
+
+
 def probe_write(name: str, *, seed: int, reps: int, quant: bool, pages: int = 400) -> dict:
     """us a layer of a decode step's KV write, scatters against the kernel."""
     import jax
@@ -373,11 +464,19 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--ppcb", type=int, default=4, help="pages a compute block (the decode step's choice at this table: 4)")
-    ap.add_argument("--only", choices=("latent", "dsa"), help="the latent-attention cell's launches alone, or the sparse read's pieces")
+    ap.add_argument("--only", choices=("latent", "dsa", "latent-prefill"), help="the latent-attention cell's launches alone, the sparse read's pieces, or the prompt pass's attention")
+    ap.add_argument("--tokens", default="1024,4096,8192,16384", help="latent-prefill: the prompt lengths")
+    ap.add_argument("--heads", default="32,64", help="latent-prefill: 32 (the kanana cell's heads) and / or 64 (the GLM-5 cell's)")
     args = ap.parse_args()
     if jax.default_backend() != "tpu":
         print("decode_attn_probe: needs a TPU (a CPU time is no speed)")
         return 2
+    if args.only == "latent-prefill":
+        for H in args.heads.split(","):
+            for L in args.tokens.split(","):
+                for res in probe_latent_prefill(int(H), int(L), seed=args.seed, reps=args.reps):
+                    print(json.dumps(res), flush=True)
+        return 0
     if args.only == "dsa":
         for res in probe_dsa(seed=args.seed, reps=args.reps, ppcb=args.ppcb):
             print(json.dumps(res), flush=True)

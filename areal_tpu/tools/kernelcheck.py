@@ -363,6 +363,68 @@ def _cases_paged_index(compiled: bool = False) -> Iterator[dict]:
 
 
 # ---------------------------------------------------------------------------
+# the prompt pass's latent attention (ops/latent_prefill_attention.py): one
+# query block's launch over the key blocks up to its diagonal against the whole
+# [H, queries, L] softmax in float32, causal and under a selection handed over
+# as a mask operand (one that leaves key blocks without a pick among them)
+# ---------------------------------------------------------------------------
+
+
+@register_kernel("mla_prefill_flash")
+def _cases_mla_prefill(compiled: bool = False) -> Iterator[dict]:
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops.latent_prefill_attention import mla_prefill_flash, padded_w_kvb, prefill_operands
+
+    rank = 64
+
+    def case(label, H, L, dn, dv, tq, tk, block, picks, dtype, seed=9):
+        dr, scale = 64, (dn + 64) ** -0.5
+        pos = block * tq + jnp.arange(tq)
+
+        def build():
+            w = (rank**-0.5 * _normal(seed, (rank, H * (dn + dv)))).astype(dtype)
+            inp = {
+                "c": _normal(seed + 1, (L, rank), dtype), "w": w, "k_r": _normal(seed + 2, (L, dr), dtype),
+                "qn": _normal(seed + 3, (tq, H, dn), dtype), "qr": _normal(seed + 4, (tq, H, dr), dtype),
+            }
+            seen = pos[:, None] >= jnp.arange(L)[None, :]
+            if picks:  # about ``picks`` keys a query and its own position; with ``picks`` < 0 its own and key 0 alone
+                draw = jax.random.uniform(jax.random.PRNGKey(seed + 5), (tq, L)) * (pos[:, None] + 1) < picks
+                seen = (seen & draw) | (pos[:, None] == jnp.arange(L)[None, :]) | (jnp.arange(L)[None, :] == 0)
+            return {**inp, "seen": seen}
+
+        def kernel(inp):
+            q, kr = prefill_operands(inp["qn"], inp["qr"], inp["k_r"])
+            kv = inp["c"] @ padded_w_kvb(inp["w"], H, dn, dr)
+            return mla_prefill_flash(q, kv, kr, jnp.int32(block), inp["seen"] if picks else None, heads=H, block_k=tk, sm_scale=scale, interpret=not compiled)
+
+        def reference(inp):
+            kv = (inp["c"] @ inp["w"]).reshape(L, H, dn + dv).astype(jnp.float32)  # rounded to the operands' type, as the launch reads it
+            logits = jnp.einsum("thd,shd->hts", inp["qn"].astype(jnp.float32), kv[..., :dn])
+            logits = logits + jnp.einsum("thd,sd->hts", inp["qr"].astype(jnp.float32), inp["k_r"].astype(jnp.float32))
+            p = jax.nn.softmax(jnp.where(inp["seen"][None], logits * scale, -1e30), axis=-1)
+            return jnp.einsum("hts,shd->thd", p, kv[..., dn:]).reshape(tq, H * dv)
+
+        # bfloat16: the probabilities are rounded to the values' type before they meet them, and the output to it
+        return {"case": label, "build": build, "kernel": kernel, "reference": reference, "tol": 1e-5 if dtype == jnp.float32 else CHIP_TOL}
+
+    if compiled:  # the two cells' launches: 64 heads of 192 + 64 | 256 under a selection, 32 heads of 128 + 64 | 128 causal
+        yield case("64-heads-selection-4096-block7", 64, 4096, 192, 256, 512, 1024, 7, 2048, jnp.bfloat16)
+        yield case("64-heads-selection-4096-block2", 64, 4096, 192, 256, 512, 1024, 2, 2048, jnp.bfloat16)
+        yield case("64-heads-sparse-picks-4096-block3", 64, 4096, 192, 256, 1024, 1024, 3, -1, jnp.bfloat16)
+        yield case("32-heads-causal-1024", 32, 1024, 128, 128, 1024, 1024, 0, 0, jnp.bfloat16)
+        yield case("32-heads-causal-2048-block1", 32, 2048, 128, 128, 1024, 1024, 1, 0, jnp.bfloat16)
+        return
+    yield case("selection-f32-last-block", 4, 512, 192, 256, 128, 128, 3, 48, jnp.float32)
+    yield case("sparse-picks-f32-block2", 4, 512, 192, 256, 128, 128, 2, -1, jnp.float32)
+    yield case("causal-f32-keys-wider", 4, 512, 128, 128, 128, 256, 1, 0, jnp.float32)
+    yield case("selection-bf16-block1", 4, 512, 192, 256, 256, 128, 1, 48, jnp.bfloat16)
+    yield case("causal-bf16-first-block", 4, 256, 128, 128, 128, 128, 0, 0, jnp.bfloat16)
+
+
+# ---------------------------------------------------------------------------
 # the expert FFN over the touched experts only (ops/moe_touched_experts.py)
 # against a loop over the listed experts in float32, rounded to the rows' type
 # where the dense form rounds. The skip is shown by POISON: every expert off the list, and every other layer, is NaN (XLA's

@@ -295,3 +295,21 @@ def test_the_recurrent_families_are_still_refused_the_same(family):
     from areal_tpu.models import qwen
 
     assert qwen.serving_limits(None) == {}  # a module that implements all of it refuses nothing
+
+
+def test_prompt_tokens_under_the_prefill_launch_are_counted_where_the_program_is_dispatched(served, monkeypatch):
+    """``areal_decode_prefill_attn_launch_tokens_total`` beside
+    ``areal_decode_prefill_tokens_total``: nothing on this backend (the launch
+    is a TPU's, and these heads are no whole lane tiles); where the model's
+    module says a bucket's program attends under the launch, every prompt
+    token that program prefills."""
+    eng, cfg = served
+    rng = np.random.default_rng(41)
+    launched, prefilled = eng._obs.prefill_attn_launch_tokens.get(), eng._obs.prefill_tokens.get()
+    _gen(eng, rng.integers(0, cfg["vocab_size"], 19).tolist(), 2)
+    assert eng._obs.prefill_tokens.get() == prefilled + 19 and eng._obs.prefill_attn_launch_tokens.get() == launched
+    buckets = []
+    monkeypatch.setattr(eng.model, "prefill_attn_launch", lambda mcfg, bucket: buckets.append(bucket) or mcfg is eng.model_cfg)
+    _gen(eng, rng.integers(0, cfg["vocab_size"], 23).tolist(), 2)
+    assert eng._obs.prefill_tokens.get() == prefilled + 42 and eng._obs.prefill_attn_launch_tokens.get() == launched + 23
+    assert buckets == [256]  # asked once a dispatched program, by its bucket

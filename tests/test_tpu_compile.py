@@ -248,6 +248,26 @@ def _moe_touched(slots=64, experts=16, width=768, layers=47):
     return touched_expert_ffn, args
 
 
+def _mla_prefill(heads=64, L=16384, dn=192, dv=256, tq=512, tk=1024, masked=True):
+    """One query block's launch of the prompt pass's latent attention as the
+    long-context cell's 16k program makes it (64 heads of 192 + 64 | 256, the
+    selection as a mask operand, 512 queries against key blocks of 1,024:
+    ``hybrid.prefill_blocks``), or cell 7's (32 heads of 128 + 64 | 128,
+    causal, one block of 1,024)."""
+    from areal_tpu.ops.latent_prefill_attention import key_lanes, mla_prefill_flash
+
+    dk = key_lanes(dn, 64)
+
+    def fn(q, kv, k_r, block, *mask):
+        return mla_prefill_flash(q, kv, k_r, block, *mask, heads=heads, block_k=tk, sm_scale=(dn + 64) ** -0.5, interpret=False)
+
+    def args(S):
+        out = [S((tq, heads * dk), jnp.bfloat16), S((L, heads * (dk + dv)), jnp.bfloat16), S((L, dk), jnp.bfloat16), S((), jnp.int32)]
+        return out + ([S((tq, L), jnp.bool_)] if masked else [])
+
+    return fn, args
+
+
 CASES = {
     "paged_decode_bf16": lambda: _decode(jnp.bfloat16),
     "paged_decode_int8": lambda: _decode(jnp.int8),
@@ -291,6 +311,9 @@ CASES = {
     "tree_attention_bwd": lambda: _tree(True),
     "megablox_gmm": _gmm,
     "moe_touched_experts_kanana2": _moe_touched,
+    "mla_prefill_flash_glm5_16k": _mla_prefill,
+    "mla_prefill_flash_glm5_8k_1024_queries": lambda: _mla_prefill(L=8192, tq=1024),
+    "mla_prefill_flash_kanana2_1k": lambda: _mla_prefill(32, 1024, 128, 128, 1024, 1024, masked=False),
     # 32 experts of [2048, 1792] (lfm2's, which the shape rule leaves on XLA's form): 44 MB of buffers
     "moe_touched_experts_wide": lambda: _moe_touched(128, 32, 1792, 12),
 }
@@ -320,6 +343,7 @@ KERNEL_NAMES = {
     "gdn_state_update_f32": ("gdn_state_update",),
     "paged_kv_write_int8": ("paged_kv_write",),
     "moe_touched_experts_kanana2": ("moe_touched_experts",),
+    "mla_prefill_flash_glm5_16k": ("mla_prefill_flash",),
     "tree_attention_bwd": ("tree_attn_fwd", "tree_attn_bwd_dq", "tree_attn_bwd_dkv"),
 }
 
@@ -696,7 +720,11 @@ def test_kanana2_prefill_compiles_for_v5e(chip, monkeypatch):
 
     i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
     compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(2, 1024), i32(2), i32(2 * 1024 // PSZ), i32(2)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3  # the three grouped matmuls of the expert layer
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 4  # the three grouped matmuls of the expert layer, and the prompt pass's attention
+    assert hybrid.prefill_takes_launch(mcfg, 1024) and "mla_prefill_flash" in text and "f32[32,1024,1024]" not in text  # no logits in HBM
+    # W_kvb's stack of 47 layers is not laid out for the launch whole: a layer's slice is, once a layer
+    assert not [ln for ln in text.splitlines() if re.search(r"= bf16\[\d+,512,12288\]\S* (?!parameter|get-tuple-element)", ln)]
     # the latent rows go into their pages layer by layer: no [layers, 2, 1024, 640] buffer, no pool-sized temporary
     assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
 
@@ -793,19 +821,24 @@ def test_glm5_decode_steps_compile_for_v5e_at_the_longest_window(chip, monkeypat
 
 def test_glm5_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypatch):
     """ONE prompt of 16,384 tokens, the cell's longest bucket, into both
-    pools: attention blocked over 256 queries against 2,048 keys (no [H, L,
-    L]: that would be 69 GB), the selection made a query block at a time,
-    every head's keys and values of the prompt made once a layer (0.9 GB),
-    the expert rows through the grouped matmuls 2,048 at a time ON THE STACK
-    (a layer's three matrices copied out for them were 1.15 GB; 16k rows
-    gathered at once 1.6 GB). 2.6 GB of temporaries by this count, which
-    over-counts a donated program (the compiler's own: 2.12 GB): with 9.46
-    GB of weights and a pool of 3.22 GB, under 15 GB."""
+    pools: attention blocked over 512 queries, ONE ``mla_prefill_flash``
+    launch a block over key blocks of 1,024 (no [H, L, L]: that would be 69
+    GB; no [H, queries, keys] float32 logits in HBM either: the XLA loop's
+    were 134 MB a step), the selection made a query block at a time and
+    handed to the launch as a mask, every head's keys and values of the
+    prompt made once a layer in the launch's lane layout (1.07 GB), the
+    expert rows through the grouped matmuls 2,048 at a time ON THE STACK (a
+    layer's three matrices copied out for them were 1.15 GB; 16k rows
+    gathered at once 1.6 GB). 2.31 GB of temporaries by this count, where
+    the XLA loop's program counts 2.45 (which over-counts a donated program:
+    the compiler's own for that one was 2.12 GB): with 9.46 GB of weights
+    and a pool of 3.22 GB, under 15 GB."""
     from areal_tpu.models import hybrid, moe
 
     # three layers: the last one's feed-forward block feeds no page and is compiled away, the middle one's stays
     mcfg, params, cache, _ = _glm5(chip, monkeypatch, layers=3)
     assert hybrid.prefill_blocks(mcfg, 16384) == (256, 2048) and hybrid.ffn_block_rows(mcfg, "moe", 16384) == 2048
+    assert hybrid.prefill_takes_launch(mcfg, 16384) and hybrid.prefill_blocks(mcfg, 16384, launch=True) == (512, 1024)
     assert not moe.takes_dense_form(2048, 16)
 
     def prefill(params, cache, ids, plens, flat_pages, slots):
@@ -814,8 +847,11 @@ def test_glm5_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypatch):
     i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
     compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(1, 16384), i32(1), i32(16384 // PSZ), i32(1)).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 3  # the three grouped matmuls of the expert layer
+    assert text.count("tpu_custom_call") >= 4  # the three grouped matmuls of the expert layer, and the prompt pass's attention
+    assert "mla_prefill_flash" in text and "f32[64,256,2048]" not in text and "f32[64,512,1024]" not in text  # no logits in HBM
     made = re.compile(r"= bf16\[(1,)?16,(6144,2048|2048,6144)\]\S* (?!parameter|get-tuple-element)")
     assert not [ln for ln in text.splitlines() if made.search(ln)]  # the grouped matmuls read the stacks where they lie
     assert "bf16[131072,6144]" not in text  # 16k rows x top-8 are never gathered at once
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.7e9
+    # W_kvb's stack is not laid out for the launch whole: a layer's slice is, once a layer (29 MB)
+    assert not [ln for ln in text.splitlines() if re.search(r"= bf16\[3,512,(28672|32768)\]\S* (?!parameter|get-tuple-element)", ln)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.4e9  # under the XLA loop's 2.45
