@@ -15,22 +15,42 @@ The launch is one kernel invocation with no grid to walk. Its work list
 same for every layer, so a decode step computes it once and hands it to each
 layer's launch by scalar prefetch; a slot of length 0 is in no item, costs
 nothing and returns exact zeros. An item covers EVERY KV head of its block:
-the heads' pages are fetched together into one of three VMEM buffers, and
-while item t is computed the copies of items t+1 and t+2 are in flight —
-across slot boundaries, so no item but the first starts on a cold buffer
-(measured on the v5e: the third buffer is worth 5-10%, a fourth nothing). A
-slot's last block fetches only the pages that hold tokens. Flash-style
-online softmax runs per head in f32; its state is carried through the item
-loop and reset at a slot's first block.
+ONE copy a page and pool moves that page of all the heads (a strided window
+``[KH, psz, hd]`` of the pool) into one of three VMEM buffers. The loop body
+is instruction issue before it is anything else (PERF.md, Findings, PR 41:
+the scalar core builds a copy's descriptor and its bounds checks in about 21
+VLIW bundles, and with a copy a head they were half of the body's 711 at 2 KV
+heads and more at 4 and 30; the launch took bundles x items at 0.9 GHz, not
+its bytes' time). While item t is computed the copies of items t+1 and t+2
+are in flight — across slot boundaries, so no item but the first starts on a
+cold buffer — and item t+3's start at the end of item t's trip, into the
+buffer it has just left (measured on the v5e: the third buffer is worth
+5-10%, a fourth nothing; started at the end of the trip one item less ahead,
+the launch is 15% slower). A slot's last block fetches only the pages that
+hold tokens.
+
+A trip is ONE basic block but for the store at a slot's last block (every
+branch less is instructions less, and the compiler schedules within a block):
+the waits first; then QK of every head, and only then, head by head, the
+softmax and PV (alternating K's transposed and V's plain pushes into the MXU
+head by head costs 3% on bf16 pages and 20-40% on int8 at the same bundle
+count); then the prefetch, whose page conditions also say "the item exists".
+Each item computes its block's OWN flash-style statistics in f32 (the
+block's maximum, sum and accumulator, no read of the slot's running state)
+and merges them into the running state carried through the loop by the usual
+two-term rescale, reset at a slot's first block. Two items a trip, and QK of
+item t+1 under item t's softmax, were built and measured and are not here:
+no fewer bundles an item, and slower on the chip.
 
 Both matmuls take f32 operands (pages and queries upcast in VMEM; the
 softmax scale and the K scale row multiply the f32 logits). Measured on the
 v5e at both benchmark shapes (PERF.md, Findings, PR 25): bf16 operands with
 f32 accumulation — the probabilities as one bf16 term or as two stacked on
-the rows — take the same time to within 4%, because with 6-8 query rows a
-head the launch is bound by its copies and by a fixed cost an item, not by
-the array; so the kernel keeps the one form that is exact for every page
-dtype.
+the rows — take the same time to within 4%: with 6-8 query rows a head the
+matmuls are weight loads (every K and V tile is pushed into the array once)
+and the launch was bound by its instructions then and is by its copies now,
+not by the array; so the kernel keeps the one form that is exact for every
+page dtype.
 
 The body is written here rather than taken from jax's library kernel
 (jax.experimental.pallas.ops.tpu.paged_attention) because that one cannot
@@ -124,13 +144,15 @@ def _decode_kernel(
     sm_scale: float,
 ):
     if quant:
-        (k_hbm, ks_hbm, v_hbm, vs_hbm, o_ref,
-         k_buf, ks_buf, v_buf, vs_buf, k_sems, v_sems) = refs
+        k_hbm, ks_hbm, v_hbm, vs_hbm, o_ref, k_buf, ks_buf, v_buf, vs_buf, sems = refs
+        pools = ((k_hbm, k_buf), (ks_hbm, ks_buf), (v_hbm, v_buf), (vs_hbm, vs_buf))
     else:
-        k_hbm, v_hbm, o_ref, k_buf, v_buf, k_sems, v_sems = refs
-        ks_hbm = vs_hbm = ks_buf = vs_buf = None
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems = refs
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+        ks_buf = vs_buf = None
     li = layer_ref[0]
     num_items = num_items_ref[0]
+    max_items = item_slot_ref.shape[0]
     _, num_kv_heads, _, psz, hd = k_hbm.shape
     nbuf = k_buf.shape[0]
     G = q_ref.shape[2]
@@ -146,88 +168,79 @@ def _decode_kernel(
         if quant:
             vs_buf[...] = jnp.zeros(vs_buf.shape, vs_buf.dtype)
 
-    k_pool = (k_hbm, k_buf, ks_hbm, ks_buf, k_sems)
-    v_pool = (v_hbm, v_buf, vs_hbm, vs_buf, v_sems)
-
     def start(copy):
         copy.start()
 
     def wait(copy):
         copy.wait()
 
-    def copies(t, go, *pools):
-        """Apply ``go`` (``start`` or ``wait``) to item t's copies from
-        ``pools`` — built identically both times; a buffer's copies of one
-        pool share one semaphore (it counts bytes)."""
-        b, i, buf = item_slot_ref[t], item_block_ref[t], t % nbuf
-        held = (lengths_ref[b] - i * bk + psz - 1) // psz  # pages with tokens
+    def copies(t, go):
+        """Apply ``go`` (``start`` or ``wait``) to item t's copies — built
+        identically both times: ONE copy a page and pool, a strided window
+        over every KV head, all on the buffer's semaphore (it counts bytes).
+        An item past the list has no page: the condition of each page holds
+        that too, so the loop body needs no branch around its prefetch."""
+        live = t < num_items
+        t = jnp.minimum(t, max_items - 1)
+        b, buf = item_slot_ref[t], t % nbuf
+        i = jnp.where(live, item_block_ref[t], 0)  # no table entry past the slot's row is read
+        held = jnp.where(live, (lengths_ref[b] - i * bk + psz - 1) // psz, 0)  # pages with tokens
 
-        def page(j, hbm, vmem, scale_hbm, scale_vmem, sems):
+        def page(j):
             pg = pidx_ref[b * pps + i * ppcb + j]
-            for h in range(num_kv_heads):  # static unroll
-                go(pltpu.make_async_copy(
-                    hbm.at[li, h, pg], vmem.at[buf, h, j], sems.at[buf]))
-                if quant:
-                    go(pltpu.make_async_copy(
-                        scale_hbm.at[li, h, pg], scale_vmem.at[buf, h, j],
-                        sems.at[buf]))
+            for hbm, vmem in pools:
+                go(pltpu.make_async_copy(hbm.at[li, :, pg], vmem.at[buf, :, j], sems.at[buf]))
 
-        for pool in pools:
-            page(0, *pool)
-            for j in range(1, ppcb):
-                pl.when(j < held)(functools.partial(page, j, *pool))
+        for j in range(ppcb):
+            pl.when(j < held)(functools.partial(page, j))
 
     def scale_row(buf_ref, buf, h):
         # [ppcb, 1, psz] -> [1, bk]: the pages' lane-major scales side by side
         s = buf_ref[buf, h].astype(jnp.float32)
         return jnp.concatenate([s[j] for j in range(ppcb)], axis=-1) / _MAX_INT8
 
-    for t in range(nbuf - 1):  # fill the ring but for the slot item 0 frees
-
-        @pl.when(t < num_items)
-        def _warm(t=t):
-            copies(t, start, k_pool, v_pool)
+    for t in range(nbuf):  # fill the ring
+        copies(t, start)
 
     def item(t, carry):
-        @pl.when(t + nbuf - 1 < num_items)
-        def _prefetch():  # into the buffer item t-1 has just left
-            copies(t + nbuf - 1, start, k_pool, v_pool)
-
         b, i, buf = item_slot_ref[t], item_block_ref[t], t % nbuf
         length = lengths_ref[b]
         first = i == 0
         col = i * bk + jax.lax.broadcasted_iota(jnp.int32, (G, bk), 1)
         valid = col < length
-        probs = []
-        copies(t, wait, k_pool)
+        copies(t, wait)
+        logits = []
         for h in range(num_kv_heads):
-            m_prev, l_prev, acc = carry[h]
-            m_prev = jnp.where(first, _MASK_VALUE, m_prev)
-            l_prev = jnp.where(first, 0.0, l_prev)
-            acc = jnp.where(first, 0.0, acc)
             q = q_ref[b, h].astype(jnp.float32)  # [G, hd]
             k = k_buf[buf, h].astype(jnp.float32).reshape(bk, hd)
-            logits = jax.lax.dot_general(
+            s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
             ) * sm_scale  # [G, bk]
             if quant:
-                logits = logits * scale_row(ks_buf, buf, h)
-            logits = jnp.where(valid, logits, _MASK_VALUE)
-            m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-            p = jnp.exp(logits - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+                s = s * scale_row(ks_buf, buf, h)
+            logits.append(jnp.where(valid, s, _MASK_VALUE))
+        out = []
+        for h in range(num_kv_heads):
+            # the block's own statistics, against its own maximum: nothing
+            # here reads the slot's running state
+            m_blk = jnp.max(logits[h], axis=-1, keepdims=True)  # an item holds a valid token
+            p = jnp.exp(logits[h] - m_blk)
+            l_blk = jnp.sum(p, axis=-1, keepdims=True)
             if quant:
                 p = p * scale_row(vs_buf, buf, h)
-            probs.append((m_new, l_new, acc * corr, p))
-        out = []
-        copies(t, wait, v_pool)
-        for h, (m_new, l_new, acc, p) in enumerate(probs):
             v = v_buf[buf, h].astype(jnp.float32).reshape(bk, hd)
-            pv = jax.lax.dot_general(
+            acc_blk = jax.lax.dot_general(
                 p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
             )
-            out.append((m_new, l_new, acc + pv))
+            # merged into the running state after: the two-term rescale
+            m_prev, l_prev, acc_prev = carry[h]
+            m_prev = jnp.where(first, _MASK_VALUE, m_prev)  # exp(_MASK_VALUE - m) is exactly 0
+            m_new = jnp.maximum(m_prev, m_blk)
+            a = jnp.exp(m_prev - m_new)
+            c = jnp.exp(m_blk - m_new)
+            out.append((m_new, l_prev * a + l_blk * c, acc_prev * a + acc_blk * c))
+        # into the buffer this item has just left: two items stay in flight
+        copies(t + nbuf, start)
 
         @pl.when(i == (length + bk - 1) // bk - 1)
         def _store():  # the slot's last block
@@ -347,10 +360,7 @@ def paged_attention_stacked(
     else:
         pages = [k_pages, v_pages]
         scratch = [page_buf(k_pages.dtype), page_buf(v_pages.dtype)]
-    scratch += [
-        pltpu.SemaphoreType.DMA((_NBUF,)),  # K copies, one per buffer
-        pltpu.SemaphoreType.DMA((_NBUF,)),  # V copies
-    ]
+    scratch.append(pltpu.SemaphoreType.DMA((_NBUF,)))  # one per buffer
     # the ring holds every KV head of three blocks; past the compiler's
     # default budget (16 MiB) with 16 and more KV heads, far inside the 128
     # MiB the core has

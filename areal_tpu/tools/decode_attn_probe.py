@@ -14,7 +14,11 @@ makes them (128-token pages, a 32-page table), for three sets of lengths:
               512-token blocks cost against ``mix``
 
 and prints microseconds a launch, the KV bytes the lengths need and their
-share of the chip's memory roofline.
+share of the chip's memory roofline, and beside each ``mix`` / ``mix512`` row
+the items a launch walks ((live slot, 512-token block) pairs) and the
+microseconds an item the launch takes over its bytes' time. The shapes are
+the two Qwen cells' and the olmo cell's (64 slots, 30 KV heads of one query
+row: the widest item the kernel serves).
 
 ``paged_latent_attn`` (``--only latent`` runs it alone): the latent-attention
 cell's launch, 32 query rows of 640 lanes over ONE stacked pool of 1,280 B
@@ -69,6 +73,8 @@ SHAPES = {  # the benchmark's rollout cells (BENCHMARK.json)
 }
 # the hybrid cell's 4 attention layers: 8 KV heads of 64 padded to 128 lanes
 WRITE_SHAPES = {**SHAPES, "rollout-granite-h-micro-grpo": dict(S=64, KH=8, G=4, L=4)}
+# 4 attention layers of 30 KV heads, one query row each; a pool of 490 pages is 1.9 GB
+ATTN_SHAPES = {**SHAPES, "rollout-olmo-hybrid-7b-d16-grpo": dict(S=64, KH=30, G=1, L=4, pages=490)}
 HD, PSZ, WP, LIVE = 128, 128, 32, 0.36
 STEPS = 32  # decode steps a chunk program (the cells' ``steps_per_call``)
 HBM_BYTES_S = 819e9  # TPU v5e, as benchmarks/chip/benchlib/peaks.py
@@ -83,13 +89,14 @@ def draw_lengths(S: int, seed: int) -> np.ndarray:
     return lengths
 
 
-def probe(name: str, *, seed: int, reps: int, ppcb: int, pages: int = 1200) -> dict:
+def probe(name: str, *, seed: int, reps: int, ppcb: int) -> dict:
     import jax
     import jax.numpy as jnp
 
     from areal_tpu.ops.paged_attention_q8 import decode_schedule, paged_attention_stacked
 
-    S, KH, G, L = (SHAPES[name][k] for k in ("S", "KH", "G", "L"))
+    S, KH, G, L = (ATTN_SHAPES[name][k] for k in ("S", "KH", "G", "L"))
+    pages = ATTN_SHAPES[name].get("pages", 1200)
     key = jax.random.PRNGKey(seed)
     kq, kk, kv = jax.random.split(key, 3)
     q = jax.random.normal(kq, (S, KH * G, HD), jnp.bfloat16)
@@ -126,8 +133,11 @@ def probe(name: str, *, seed: int, reps: int, ppcb: int, pages: int = 1200) -> d
             out = step(q, k, v, lengths, table)
         out.block_until_ready()
         res[f"{label}_us"] = (time.perf_counter() - t0) / (reps * L) * 1e6
-    floor_us = 2 * KH * HD * 2 * int(mix.sum()) / HBM_BYTES_S * 1e6
-    res["mix_roofline_pct"] = 100 * floor_us / res["mix_us"]
+    for label in ("mix", "mix512"):
+        floor_us = 2 * KH * HD * 2 * int(sets[label].sum()) / HBM_BYTES_S * 1e6
+        res[f"{label}_roofline_pct"] = 100 * floor_us / res[f"{label}_us"]
+        res[f"{label}_items"] = items = int((-(-sets[label] // (ppcb * PSZ))).sum())
+        res[f"{label}_us_an_item_over_bytes"] = (res[f"{label}_us"] - floor_us) / items
     return res
 
 
@@ -485,7 +495,7 @@ def main() -> int:
         print(json.dumps(res), flush=True)
     if args.only:
         return 0
-    for name in SHAPES:
+    for name in ATTN_SHAPES:
         print(json.dumps(probe(name, seed=args.seed, reps=args.reps, ppcb=args.ppcb)), flush=True)
     for name in WRITE_SHAPES:
         for quant in (False, True):

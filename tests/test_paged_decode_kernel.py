@@ -1,10 +1,9 @@
 """``paged_decode_attn`` (ops/paged_attention_q8.py) in interpret mode against
 ``paged_kv.paged_attention_xla`` computed in float32 on the same inputs: the
 serving dtypes (bf16 queries on bf16, int8 and fp8 pages, bf16 MXU operands
-with f32 accumulation), both benchmark cells' head groupings, a narrow and a
-wide page table, and every length at which the block walk changes shape.
-
-At most 8 tests a file: xdist hands files out by test count.
+with f32 accumulation), the benchmark cells' head groupings (2 x 6, 4 x 7 and
+olmo's 30 KV heads of one query row), a narrow and a wide page table, and
+every length at which the block walk changes shape.
 """
 
 import numpy as np
@@ -73,12 +72,31 @@ def check(inp, ppcb, layer=1, atol=ATOL):
     assert not out[~live].any(), "a zero-length slot returns exact zeros"
 
 
-@pytest.mark.parametrize("G,KH", [(6, 2), (7, 4)])
+@pytest.mark.parametrize("G,KH", [(6, 2), (7, 4), (1, 30)])
 @pytest.mark.parametrize("pages", sorted(PAGES))
 def test_bf16_queries_match_the_float32_reference(pages, G, KH):
     wp = 4
     ppcb = paged_kv.choose_ppcb(wp)  # the decode step's own choice: 4
     check(build(G, KH, wp, PAGES[pages], edge_lengths(wp, ppcb * PSZ)), ppcb)
+
+
+# work lists by what the item loop meets in them, as (table width in pages,
+# tokens a block) -> lengths; the table holds at least two blocks
+WALKS = {
+    "edges": edge_lengths,
+    "odd_count": lambda wp, bk: [bk + 1, 0, 1],  # 3 items
+    "even_count": lambda wp, bk: [bk + 1, 0, 1, bk],  # 4 items
+    # consecutive items (0, 1) and (2, 3) each lie in two slots
+    "straddle": lambda wp, bk: [1, 2 * bk, 5],
+    "one_block": lambda wp, bk: [bk],
+    "one_token": lambda wp, bk: [1],
+    "full_table": lambda wp, bk: [wp * PSZ, wp * PSZ],
+    "dead_between": lambda wp, bk: [bk + 3, 0, 7],
+}
+
+
+def walk_lengths(walk: str, wp: int, bk: int) -> np.ndarray:
+    return np.asarray(WALKS[walk](wp, bk), np.int32)
 
 
 @pytest.mark.parametrize("pages,G,KH", [("bf16", 6, 2), ("int8", 7, 4)])

@@ -1,7 +1,5 @@
 """The launch of ``paged_decode_attn``: its work list, its block sizes, its
 float32 path, and the decode step's call site that keeps ended slots out.
-
-At most 8 tests a file: xdist hands files out by test count.
 """
 
 import functools
@@ -13,7 +11,7 @@ import pytest
 
 from areal_tpu.inference import paged_kv
 from areal_tpu.ops.paged_attention_q8 import decode_schedule, paged_attention_stacked
-from tests.test_paged_decode_kernel import PSZ, build, check, edge_lengths
+from tests.test_paged_decode_kernel import PAGES, PSZ, WALKS, build, check, edge_lengths, walk_lengths
 
 
 def test_all_empty_launch_returns_zeros():
@@ -34,14 +32,26 @@ def test_float32_queries_keep_the_float32_path():
 
 
 @pytest.mark.parametrize("ppcb", [1, 2, 4])
-def test_block_sizes_agree(ppcb):
+@pytest.mark.parametrize("walk", WALKS)
+def test_block_sizes_agree(walk, ppcb):
     """1, 2 and 4 pages a block over the same lengths, the last block of a
     slot partly fetched: what the unfetched pages' buffers hold (here the
-    trash page's 1e4) never reaches the output."""
-    wp = 4
+    trash page's 1e4) never reaches the output. By work list: an odd and an
+    even item count, consecutive items in two slots, a slot of one block, of
+    one token, of the whole table, a dead slot between two live ones."""
+    wp = 8
     check(
-        build(7, 4, wp, jnp.bfloat16, edge_lengths(wp, ppcb * PSZ), seed=ppcb), ppcb
+        build(7, 4, wp, jnp.bfloat16, walk_lengths(walk, wp, ppcb * PSZ), seed=ppcb), ppcb
     )
+
+
+@pytest.mark.parametrize("pages", ["int8", "fp8"])
+@pytest.mark.parametrize("walk", ["odd_count", "straddle"])
+def test_quantized_pages_walk_the_same_lists(walk, pages):
+    """The scale pools ride in the same copies: the lists that change the
+    loop's shape, over int8 and fp8 pages at 2 x 6."""
+    wp, ppcb = 8, 2
+    check(build(6, 2, wp, PAGES[pages], walk_lengths(walk, wp, ppcb * PSZ), seed=5), ppcb)
 
 
 def test_schedule_lists_the_live_blocks_in_slot_order():
