@@ -4,21 +4,29 @@ Replaces the external SGLang/vLLM servers the reference depends on
 (areal/engine/sglang_remote.py, vllm_remote.py + infra/launcher/*_server.py)
 with a JAX decode engine built for the async-RL protocol (SURVEY §7.1):
 
+Three owners serve a request, and the arrows point one way. This module is
+the public surface and the **pass scheduler**: the request queue, the loop
+(hold, reap, admit, one packed scatter, dispatch, drain), lifecycle, drain,
+weights. It asks ``decode_programs.DecodePrograms`` for callables (which
+jitted programs exist, under which key, which a start-up warms) and
+``slot_cache.SlotCache`` for pages (who owns a page: live slot, parked
+request, prefix tree), and threads the device arrays (weights, the paged
+cache, the slot state, the rng) through the donated programs itself.
+
 - **slot-based continuous batching over a paged KV cache**: S decode slots
-  draw fixed-size KV pages from a shared pool (inference/paged_kv.py) via
-  host-side block tables — KV HBM ∝ used tokens, so 4K-32K contexts fit at
-  real concurrency. Requests admit into free slots via a bucketed prefill
-  (KV scattered into their pages), then all slots step together in a jitted
-  multi-token ``lax.scan`` decode chunk (``decode_steps_per_call``) running
-  the Pallas paged-attention kernel — static shapes everywhere, a bounded
-  set of compiled programs (windows bucketed in pages). The device queue is
-  at most two chunks deep: the running one and, from the pass's commit
-  point part-way through it (inference/commit_point.py), the next; what
-  arrives before that point is admitted into the next chunk.
+  draw fixed-size KV pages from a shared pool via host-side block tables.
+  Requests admit into free slots via a bucketed prefill, then all slots
+  step together in a jitted multi-token decode chunk
+  (``decode_steps_per_call``) — static shapes everywhere, a bounded set of
+  compiled programs. The device queue is at most two chunks deep: the
+  running one and, from the pass's commit point part-way through it
+  (inference/commit_point.py), the next; what arrives before that point is
+  admitted into the next chunk.
 - **GRPO prefix sharing by page aliasing**: a group's identical prompts
-  prefill once; duplicates share the full prompt pages (refcount++) and
-  copy only the final partial page. Pool exhaustion evicts parked KV, then
-  preempts the highest-budget slots (abort + client retry).
+  prefill once; duplicates share the full prompt pages and copy only the
+  final partial page. Pool exhaustion evicts cached and parked KV (the
+  slot cache's ladder), then preempts the highest-budget slots (abort +
+  client retry; the scheduler's choice).
 - **interruptible generation** (the reference's crown jewel,
   remote_inf_engine.py:771-867 + §3.4 pause protocol):
   ``pause_generation("abort")`` completes all in-flight requests with
@@ -61,6 +69,8 @@ from areal_tpu.api import io_struct
 from areal_tpu.api.io_struct import ModelRequest, ModelResponse, StopReason
 from areal_tpu import models
 from areal_tpu.inference import commit_point
+from areal_tpu.inference.decode_programs import PREFILL_SIZES, TOPK_CAP, DecodePrograms, pack_row, slot_state
+from areal_tpu.inference.slot_cache import SlotCache
 from areal_tpu.models import qwen
 from areal_tpu.models.hf import load_params_from_hf
 from areal_tpu.observability import catalog as obs_catalog
@@ -71,25 +81,14 @@ from areal_tpu.parallel import mesh as mesh_lib
 from jax import set_mesh
 from areal_tpu.utils import logging as alog
 from areal_tpu.utils import perf_tracer
-from areal_tpu.utils.compile_cache import FirstCall
-from areal_tpu.utils.data import round_up_to_bucket
 
 logger = alog.getLogger("decode_engine")
 
-_MAX_STOP = 8  # stop-token-id slots per request (padded with -1)
 # the exact leaf names quantize_params_int8 produces — suffix matching would
 # misroute any future base param that happens to end in _scale (ADVICE r04)
 _SERVED_FORM_LEAVES = frozenset(
     f"{t}{suf}" for t in qwen.QUANT_TARGETS for suf in ("_q8", "_scale")
 )
-_TOPK_CAP = 1024  # static candidate-set size for per-slot top-k/top-p
-_PREFILL_SIZES = (8, 4, 2, 1)  # batched-prefill group sizes (compile variants)
-# bytes of residual stream (rows x bucket x hidden) a prefill program may hold:
-# every group size at 8 x 1,024 tokens of hidden 4,096; ONE row a program
-# where a prompt alone is past it (4,096 tokens of hidden 6,144 are 50 MB),
-# since a long prompt amortises the weights by itself and a second row would
-# only add its temporaries
-_PREFILL_STREAM_BYTES = 64 << 20
 
 
 @dataclass
@@ -111,27 +110,6 @@ class _Task:
     timeline: tl_mod.RequestTimeline | None = None
 
 
-@dataclass
-class _Parked:
-    """KV retained across abort/resume (rid affinity).
-
-    The client's interruptible-generation loop resubmits ``prompt + emitted``
-    with the same rid after continue_generation (client.py agenerate loop;
-    reference intent remote_inf_engine.py:753-763). If the slot's pages are
-    intact we restore decode state directly — zero re-prefill. The parked
-    entry owns the slot's KV pages until resume or eviction."""
-
-    slot: int
-    full_ids: list[int]  # prompt + emitted; cache holds all but the last
-    pos: int  # decode position of the pending (last) token
-    pages: list[int] = field(default_factory=list)  # owned KV pages
-    # policy version each page's KV was created under (parallel to pages;
-    # radix publication and the flush-on-commit staleness check need it)
-    page_versions: list[int] = field(default_factory=list)
-    n_emitted: int = 0  # completion tokens so far (freq-penalty restore)
-    park_time: float = field(default_factory=time.monotonic)
-
-
 def _iter_tree_paths(tree: dict, prefix: str = ""):
     for k, v in tree.items():
         key = f"{prefix}/{k}" if prefix else str(k)
@@ -139,112 +117,6 @@ def _iter_tree_paths(tree: dict, prefix: str = ""):
             yield from _iter_tree_paths(v, key)
         else:
             yield key, v
-
-
-def _sample_blocks(V: int) -> int:
-    """Block count for the hierarchical sampler: the largest divisor of V
-    that is <= 512. Qwen vocabs are 2^7-divisible (151936 = 128*1187);
-    tiny test vocabs divide exactly."""
-    for nb in range(min(V, 512), 0, -1):
-        if V % nb == 0:
-            return nb
-    return 1
-
-
-def _inverse_cdf_sample(scaled, rng):
-    """Exact categorical sampling with ONE uniform per row, in ~one HBM pass.
-
-    ``jax.random.categorical`` materializes gumbel noise for every vocab
-    entry — [S, 152k] of threefry bits per decode step, measured ~9 ms of
-    an 11 ms step at S=128 on v5e. The round-3 flat inverse-CDF replaced
-    that with ``cumsum`` over [S, V] fp32 — which XLA lowers to ~log2(V)
-    full-array passes (~2.5 GB of HBM traffic at S=128), nearly as slow.
-
-    This version factorizes the CDF hierarchically:
-      1. block_lse[S, NB] — one read pass over the logits, reshaped
-      2. tiny cumsum over NB block probabilities picks the block
-      3. the residual uniform picks the token inside the gathered
-         [S, V/NB] block (tiny)
-    The draw is exact (CDF decomposition); at both levels the uniform is
-    scaled by the realized total so fp32 cumsum undershoot spreads
-    proportionally instead of piling on the last index. Returns
-    (ids [S], logp [S], lse [S, 1]) with logp the exact log-softmax of the
-    drawn token."""
-    S, V = scaled.shape
-    NB = _sample_blocks(V)
-    inner = V // NB
-    blocks = scaled.reshape(S, NB, inner)
-    block_lse = jax.scipy.special.logsumexp(blocks, axis=-1)  # [S, NB]
-    lse = jax.scipy.special.logsumexp(block_lse, axis=-1, keepdims=True)
-    bprob = jnp.exp(block_lse - lse)  # [S, NB]
-    bcum = jnp.cumsum(bprob, axis=-1)
-    u = jax.random.uniform(rng, (S, 1), jnp.float32)
-    ut = u * bcum[:, -1:]
-    b = jnp.sum((bcum <= ut).astype(jnp.int32), axis=-1)
-    b = jnp.minimum(b, NB - 1)  # OOB guard
-    # residual mass inside the chosen block, renormalized to [0, 1)
-    cum_excl = jnp.where(
-        b > 0, jnp.take_along_axis(bcum, jnp.maximum(b - 1, 0)[:, None], axis=-1)[:, 0], 0.0
-    )
-    pb = jnp.take_along_axis(bprob, b[:, None], axis=-1)[:, 0]
-    u_in = (ut[:, 0] - cum_excl) / jnp.maximum(pb, 1e-30)
-    blk = jnp.take_along_axis(blocks, b[:, None, None], axis=1)[:, 0]  # [S, inner]
-    blk_lse = jnp.take_along_axis(block_lse, b[:, None], axis=-1)  # [S, 1]
-    icum = jnp.cumsum(jnp.exp(blk - blk_lse), axis=-1)  # [S, inner]
-    idx = jnp.sum((icum <= u_in[:, None] * icum[:, -1:]).astype(jnp.int32), axis=-1)
-    idx = jnp.minimum(idx, inner - 1)
-    ids = b * inner + idx
-    logp = (jnp.take_along_axis(scaled, ids[:, None], axis=-1) - lse)[:, 0]
-    return ids, logp, lse
-
-
-def _sample_step(logits, rng, state, capped: bool, greedy_any: bool = True):
-    """One sampling step. logits [S, V] fp32; all sampling knobs are
-    *per-slot arrays* in ``state`` (temp, greedy, top_k, top_p) so one
-    request's config can never leak into another slot (round-1 correctness
-    bug: engine-global top_k/top_p compiled into the chunk).
-
-    ``capped`` and ``greedy_any`` are static flags: when no active slot
-    filters (resp. decodes greedily), the top-k candidate machinery (resp.
-    the full-vocab argmax pass — a [S, V] fp32 HBM read per step) is
-    compiled out entirely."""
-    V = logits.shape[-1]
-    temp, greedy = state["temp"], state["greedy"]
-    safe_t = jnp.maximum(temp, 1e-6)[:, None]
-    scaled = logits / safe_t
-    rng_full, rng_cap = jax.random.split(rng)
-    sampled, samp_logp, lse = _inverse_cdf_sample(scaled, rng_full)
-    use_cap = None
-    if capped:
-        K = min(V, _TOPK_CAP)
-        top_vals, top_idx = jax.lax.top_k(scaled, K)  # sorted desc, [S, K]
-        eff_k = jnp.where(state["top_k"] > 0, state["top_k"], V)
-        mask_k = jnp.arange(K)[None, :] < eff_k[:, None]
-        probs = jax.nn.softmax(top_vals, axis=-1)
-        cum_excl = jnp.cumsum(probs, axis=-1) - probs
-        mask_p = cum_excl < state["top_p"][:, None]
-        keep = (mask_k & mask_p).at[:, 0].set(True)
-        cap_logits = jnp.where(keep, top_vals, -1e30)
-        cap_pos = jax.random.categorical(rng_cap, cap_logits, axis=-1)
-        cap_ids = jnp.take_along_axis(top_idx, cap_pos[:, None], axis=-1)[:, 0]
-        cap_logp = jnp.take_along_axis(
-            jax.nn.log_softmax(cap_logits, axis=-1), cap_pos[:, None], axis=-1
-        )[:, 0]
-        use_cap = (state["top_k"] > 0) | (state["top_p"] < 1.0)
-        sampled = jnp.where(use_cap, cap_ids, sampled)
-    if greedy_any:
-        arg = jnp.argmax(logits, axis=-1)
-        next_ids = jnp.where(greedy, arg, sampled).astype(jnp.int32)
-        greedy_logp = (
-            jnp.take_along_axis(scaled, arg[:, None], axis=-1) - lse
-        )[:, 0]
-        logp = jnp.where(greedy, greedy_logp, samp_logp)
-    else:
-        next_ids = sampled.astype(jnp.int32)
-        logp = samp_logp
-    if capped:
-        logp = jnp.where(use_cap & ~greedy, cap_logp, logp)
-    return next_ids, logp
 
 
 class DecodeEngine:
@@ -279,13 +151,13 @@ class DecodeEngine:
         self._pending_weight_update: tuple[str, Any, int] | None = None
         self._weight_lock = threading.Lock()
         self._thread: threading.Thread | None = None
-        self._fn_cache: dict[tuple, Callable] = {}
+        # the jitted programs (decode_programs.py) and the page-and-slot ledger
+        # (slot_cache.py: pool, prefix tree, slot page lists, page table, parked
+        # requests): built in initialize(), the ledger anew at resume_memory()
+        self.programs: DecodePrograms | None = None
+        self.slots: SlotCache | None = None
         self._wakeup = threading.Event()
         self._backlog: deque[_Task] = deque()  # tasks popped but not admitted
-        self._parked: dict[str, _Parked] = {}  # rid -> retained-KV slot
-        # rids whose slot (KV and recurrent state) was dropped under them: a
-        # prefill of one of these rebuilds a state (state_prefills counter)
-        self._state_dropped: set[str] = set()
         self._staged_flat: dict[str, Any] | None = None  # streamed-update staging
         self._stage_target = "device"  # per-update: "device" | "host"
         self.last_update_gen_tokens = 0  # tokens emitted during last update
@@ -320,10 +192,9 @@ class DecodeEngine:
         self._obs_spec = obs_catalog.speculative_metrics()
         # speculative decoding: non-None only while enabled (the loop's
         # per-pass mode switch); the drafter is built in initialize() /
-        # set_speculative() so it can see the radix tree
+        # set_speculative() so it can see the prefix tree
         self._spec_cfg = None
         self._drafter = None
-        self._radix = None  # cross-request prefix cache; built in initialize
         self._radix_flush_req: tuple[threading.Event, list[int]] | None = None
         # request lifecycle (docs/request_lifecycle.md): rids queued for
         # cancellation by any thread (/abort_request, generate_sync
@@ -376,10 +247,11 @@ class DecodeEngine:
             "areal.setup.engine_init", args={"engine": "decode"}
         ) as span:
             self._initialize()
+            state_bytes = SlotCache.state_bytes(self.cache)
             span.set(
                 param_bytes=hw.tree_bytes(self.params),
-                recurrent_state_bytes=self._state_bytes(),
-                kv_page_bytes=hw.tree_bytes(self.cache) - self._state_bytes(),
+                recurrent_state_bytes=state_bytes,
+                kv_page_bytes=hw.tree_bytes(self.cache) - state_bytes,
             )
 
     def _initialize(self) -> None:
@@ -471,6 +343,7 @@ class DecodeEngine:
             self._serving_shardings = self.param_shardings
 
         S, T = cfg.max_batch_size, cfg.max_seq_len
+        self.programs = DecodePrograms(self.model, self.model_cfg, cfg, self.mesh)
         self._init_paged_cache()
         # host mirror of per-slot state. The authoritative decode state lives
         # ON DEVICE (self._dev_state): the loop never round-trips it through
@@ -482,22 +355,7 @@ class DecodeEngine:
         # last time each slot made progress (admission or token emission);
         # the per-slot watchdog compares against lifecycle.watchdog_s
         self._slot_progress: list[float] = [0.0] * S
-        self._state = {
-            "ids": np.zeros(S, np.int32),
-            "pos": np.zeros(S, np.int32),
-            "active": np.zeros(S, bool),
-            "remaining": np.zeros(S, np.int32),
-            "temp": np.ones(S, np.float32),
-            "greedy": np.zeros(S, bool),
-            "top_k": np.full(S, -1, np.int32),
-            "top_p": np.ones(S, np.float32),
-            # stop tokens are honored only once remaining - 1 <= min_rem
-            # (the -1 accounts for the token being emitted), i.e. after
-            # gconfig.min_new_tokens tokens have been generated
-            "min_rem": np.zeros(S, np.int32),
-            "freq_pen": np.zeros(S, np.float32),
-            "stop_ids": np.full((S, _MAX_STOP), -1, np.int32),
-        }
+        self._state = slot_state(S)
         # per-slot generated-token counts (OpenAI frequency_penalty
         # semantics) live DEVICE-ONLY — the host never reads them back, so
         # no [S, V] host mirror. uint16 with saturating updates. Config-
@@ -522,18 +380,16 @@ class DecodeEngine:
             seed = int(time.time_ns()) % (2**31)
         self._rng = jax.device_put(jax.random.PRNGKey(seed), repl)
         self.kprobe = kernel_probe.KernelProbe()
-        # speculative decoding (getattr: configs serialized before the knob
-        # existed deserialize without it)
-        spec = getattr(cfg, "speculative", None)
-        if spec is not None and spec.enabled:
+        spec = cfg.speculative
+        if spec.enabled:
             from areal_tpu.inference import speculative as spec_mod
 
             self._spec_cfg = spec
-            self._drafter = spec_mod.build_drafter(spec, radix=self._radix)
+            self._drafter = spec_mod.build_drafter(spec, radix=self.slots.radix)
         self.initialized = True
         logger.info(
             f"decode engine ready: {S} slots × {T} ctx, "
-            f"{self.pool.n_pages} KV pages × {cfg.page_size} tokens, "
+            f"{self.slots.n_pages} KV pages × {cfg.page_size} tokens, "
             f"mesh {dict(self.mesh.shape)}, attention {self.attention_impl()}"
         )
 
@@ -552,8 +408,7 @@ class DecodeEngine:
         no suffix kernel): nothing here asks what kind of model it is."""
         limits = self._model_limits()
         cfg = self.config
-        spec = getattr(cfg, "speculative", None)
-        if spec is not None and spec.enabled and "speculative" in limits:
+        if cfg.speculative.enabled and "speculative" in limits:
             raise ValueError(limits["speculative"])
         if cfg.quantization == "int8" and "int8_weights" in limits:
             raise ValueError(limits["int8_weights"])
@@ -603,217 +458,64 @@ class DecodeEngine:
             return fn(params)
 
     def _init_paged_cache(self) -> None:
-        """Create the paged KV pool (inference/paged_kv.py): page arrays on
-        device, allocator + block tables on host. Pool size comes from
-        ``kv_hbm_gb`` when set (long-context serving: KV HBM ∝ used tokens),
-        else a dense-equivalent S×T tokens (short contexts, tests)."""
+        """Create the paged KV cache: the host's ledger of it
+        (inference/slot_cache.py: allocator, block tables, prefix tree; it
+        sizes the pool) and the page arrays on device (inference/paged_kv.py),
+        which stay HERE: the loop threads them, with the slot state and the
+        rng, through the donated programs."""
         from areal_tpu.inference import paged_kv
 
         cfg = self.config
         mcfg = self.model_cfg
-        S, T, psz = cfg.max_batch_size, cfg.max_seq_len, cfg.page_size
-        self._maxp = -(-T // psz)  # pages per sequence (ceil)
-        if cfg.kv_quantization not in (None, "", "none", "int8", "fp8"):
-            raise ValueError(f"unknown kv_quantization {cfg.kv_quantization!r}")
-        # "int8" -> int8 pages, "fp8" -> float8_e4m3fn pages; both carry
-        # narrow f32 scales and share one dequant formula (paged_kv)
-        kv_quant = (
-            cfg.kv_quantization
-            if cfg.kv_quantization in ("int8", "fp8")
-            else False
+        S, psz = cfg.max_batch_size, cfg.page_size
+        self.slots = SlotCache(
+            cfg, mcfg, no_prefix=self._model_limits().get("prefix_cache"), record=self.flight.record
         )
-        if cfg.kv_hbm_gb is not None:
-            # what a page row is, is the model configuration's to say: its
-            # pools may differ in width (a latent row beside an index key)
-            heads, lanes = mcfg.kv_pools["k"]
-            n_pages = paged_kv.n_pages_for_budget(
-                int(cfg.kv_hbm_gb * (1 << 30)),
-                mcfg.num_kv_layers,
-                heads,
-                psz,
-                lanes,
-                jnp.dtype(mcfg.jax_dtype).itemsize,
-                quant=kv_quant,
-                pools=mcfg.kv_pools,
-            )
-        else:
-            n_pages = S * self._maxp + 1  # +1: trash page 0
-        self.pool = paged_kv.PagePool(n_pages)
+        kv_quant = self.slots.kv_quant
         tp = self.mesh.shape["model"]
         # the pools this model has (a latent model: no V pool)
         kv_spec = paged_kv.paged_cache_specs(quant=kv_quant, pools=tuple(mcfg.kv_pools))
         if mcfg.num_kv_heads % max(tp, 1):
             kv_spec = dict.fromkeys(kv_spec, P())
         kv_spec.update({name: P() for name in mcfg.state_shapes(S)})
-        # the Pallas paged kernels run single-device; under TP the engine
-        # takes the gather+einsum path, which GSPMD shards over the KV-head
-        # axis like the dense engine did. Kernel or gather is decided HERE,
-        # once, from the platform, the mesh and the shapes: on a TPU the
-        # kernels are compiled, and one the chip's compiler refuses is an
-        # error — nothing catches it, falls back to interpret mode or
-        # swaps in the XLA path.
-        from areal_tpu.ops.paged_attention_q8 import paged_kernel_ok
-
-        one_tpu = (
-            jax.default_backend() == "tpu"
-            and int(np.prod(list(self.mesh.shape.values()))) == 1
-        )
-        shapes_ok = paged_kernel_ok(mcfg.kv_head_dim, psz, bool(kv_quant))
-        if one_tpu and not shapes_ok:
-            logger.warning(
-                f"head_dim {mcfg.kv_head_dim} / page_size {psz} / kv "
-                f"{kv_quant or 'bf16'} is outside the Pallas paged kernels' "
-                "tiling (ops/paged_attention_q8.py paged_kernel_ok): "
-                "decode, suffix prefill and verify take the gather path"
-            )
-        self._use_kernel = one_tpu and shapes_ok
-        # suffix-prefill / tree-verify Pallas kernel
-        # (ops/paged_suffix_attention.py): same condition, overridable at
-        # runtime for kernel-vs-XLA A/B (off-TPU the kernel runs in
-        # interpret mode)
-        self._suffix_kernel_override: bool | None = None
         with set_mesh(self.mesh):
             self.cache = jax.jit(
                 lambda: paged_kv.init_paged_cache(
-                    mcfg, n_pages, psz, quant=kv_quant, slots=S
+                    mcfg, self.slots.n_pages, psz, quant=kv_quant, slots=S
                 ),
                 out_shardings={
                     k: NamedSharding(self.mesh, s) for k, s in kv_spec.items()
                 },
             )()
-        self._slot_pages: list[list[int]] = [[] for _ in range(S)]
-        # policy version each slot page's KV was created under (parallel to
-        # _slot_pages): radix publication skips stale pages under the
-        # default flush-on-commit policy
-        self._slot_page_versions: list[list[int]] = [[] for _ in range(S)]
-        self._pt_host = np.zeros((S, self._maxp), np.int32)
-        self._obs.state_bytes.set(self._state_bytes())
+        self._obs.state_bytes.set(SlotCache.state_bytes(self.cache))
         load_shape = mcfg.moe_count_shapes.get("moe_load")
         self._moe_load = np.zeros(load_shape, np.int64) if load_shape else None
-        pc = getattr(cfg, "prefix_cache", None)
-        no_prefix = self._model_limits().get("prefix_cache")
-        if no_prefix:
-            # what the model's module cannot serve a cached prefix of (a page
-            # prefix says nothing of a recurrent state behind it: ROADMAP
-            # Reach A.7; latent pages have no suffix prefill: A.5): the radix
-            # cache neither matches nor inserts for such a model
-            if pc is not None and pc.enabled and cfg.enable_prefix_caching:
-                logger.info(f"prefix cache off: {no_prefix}")
-            self._radix = None
-        elif pc is not None and pc.enabled and cfg.enable_prefix_caching:
-            cap = pc.max_pages
-            if cap is None:
-                cap = int((n_pages - 1) * pc.max_fraction)
-            self._radix = paged_kv.RadixPrefixCache(
-                self.pool, psz, max(0, min(cap, n_pages - 1))
-            )
-        else:
-            self._radix = None
-
-    # prompt buckets above this warm only if on the round_up_to_bucket
-    # 2^k/3*2^k series — the exact-reachable set at T=32K would otherwise be
-    # every 256-multiple (512 prefill programs; a ~10x startup blowup).
-    # Buckets outside the warmed set still work; they compile on first hit.
-    _WARM_DENSE_CAP = 4096
-
-    def _prefill_sizes(self, bucket: int) -> tuple[int, ...]:
-        """The group sizes a prefill program of this bucket comes in: those of
-        ``_PREFILL_SIZES`` whose residual stream stays inside
-        ``_PREFILL_STREAM_BYTES``, at least (1,)."""
-        row = bucket * self.model_cfg.hidden_size * jnp.dtype(self.model_cfg.jax_dtype).itemsize
-        return tuple(a for a in _PREFILL_SIZES if a * row <= _PREFILL_STREAM_BYTES) or (1,)
-
-    def _reachable_prompt_buckets(self) -> list[int]:
-        """Values ``min(T, round_up_to_bucket(plen, 256))`` the admission
-        path can produce (round-2 warmed linear multiples instead — compiling
-        unreachable programs while missing the 3*2^k series and the T-cap;
-        ADVICE r02 #1), dense up to ``_WARM_DENSE_CAP`` then the sparse
-        series tail only."""
-        T = self.config.max_seq_len
-        exact = {
-            min(T, round_up_to_bucket(n, 256))
-            for n in range(1, max(2, min(T - 1, self._WARM_DENSE_CAP)))
-        }
-        b = self._WARM_DENSE_CAP
-        while b < T:
-            exact.add(min(T, round_up_to_bucket(b + 1, 256)))
-            b *= 2
-        exact.add(min(T, round_up_to_bucket(max(1, T - 2), 256)))
-        return sorted(exact)
-
-    def _reachable_chunk_wps(self) -> list[int]:
-        """Window page counts ``_dispatch_chunk`` can request — exact up to
-        ``_WARM_DENSE_CAP`` rows, then the sparse bucket-series tail."""
-        cfg = self.config
-        T, psz = cfg.max_seq_len, cfg.page_size
-        n_steps = cfg.decode_steps_per_call
-
-        def wp_of(max_pos: int) -> int:
-            window = min(
-                T,
-                round_up_to_bucket(
-                    max_pos + 1 + 2 * n_steps, cfg.attn_window_step
-                ),
-            )
-            return min(self._maxp, -(-window // psz))
-
-        wps = {wp_of(p) for p in range(min(T, self._WARM_DENSE_CAP))}
-        b = self._WARM_DENSE_CAP
-        while b < T:
-            wps.add(wp_of(b))
-            b *= 2
-        wps.add(wp_of(T - 1))
-        return sorted(wps)
-
-    def _reachable_scatter_sizes(self) -> list[int]:
-        """Exact set of bucketed row counts ``_apply_slot_updates`` uses:
-        powers of two up to S, plus S itself when S is not a power of two."""
-        S = self.config.max_batch_size
-        sizes = set()
-        n = 1
-        while n < S:
-            sizes.add(n)
-            n *= 2
-        sizes.add(S)
-        return sorted(sizes)
 
     def precompile(
         self,
         prompt_buckets: list[int] | None = None,
         budget_s: float | None = None,
     ) -> None:
-        """AOT compile-warm every jitted variant the serving loop can reach:
-        batched-prefill programs (``_PREFILL_SIZES`` group sizes x reachable
-        prompt buckets), the slot-scatter sizes, page-copy sizes, and every
-        reachable decode-chunk (window-pages, capped) combination.
+        """AOT compile-warm every jitted variant the serving loop can reach
+        (``DecodePrograms.warm_keys``: decode chunks, slot scatters, page
+        copies, prefill programs; ``prompt_buckets`` narrows the last).
 
         A compile stall mid-serving blocks ALL slots for tens of seconds;
         round-2 profiling showed cold prefill variants alone cost ~25% of
         measured decode throughput on the first request waves. Servers call
         this at startup (``ServerConfig.precompile``) — the role SGLang's
-        warmup phase plays for the reference's launchers.
-
-        Suffix-only prefill variants (radix prefix-cache hits) are NOT
-        pre-warmed: their (suffix bucket × prefix-table width) grid is
-        workload-dependent, so they lazy-compile on first hit and land in
-        the persistent cache — one admission-wave stall per shape, never a
-        mid-decode stall.
-
-        Warm sets are derived from ``round_up_to_bucket`` itself, and
-        warming uses ``jit(f).lower(...).compile()`` — compile cost only, no
-        device execution (ADVICE r02 #1/#2). The runtime path re-traces on
-        first hit and replays from the in-process/persistent compile cache.
+        warmup phase plays for the reference's launchers. Warming uses
+        ``jit(f).lower(...).compile()`` — compile cost only, no device
+        execution (ADVICE r02 #1/#2). The runtime path re-traces on first
+        hit and replays from the in-process/persistent compile cache.
 
         ``budget_s`` bounds wall-clock: compilation stops (with a log of the
         skipped count) once the budget is spent. Programs are ordered hot
-        loop first — decode chunks, then scatter/pagecopy/clamp, then
-        prefill variants — so an out-of-budget stop costs admission-wave
-        stalls, never mid-decode stalls. Fresh compiles land in the
-        persistent cache, so a budget-truncated run completes further on the
-        next start.
+        loop first, so an out-of-budget stop costs admission-wave stalls,
+        never mid-decode stalls. Fresh compiles land in the persistent
+        cache, so a budget-truncated run completes further on the next start.
         """
         assert self.initialized, "initialize() first"
-        cfg = self.config
         t0 = time.monotonic()
 
         def sds(x):
@@ -822,89 +524,26 @@ class DecodeEngine:
             # is a different cache key — it would be compiled twice
             return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
 
-        params_s = jax.tree.map(sds, self.params)
-        cache_s = jax.tree.map(sds, self.cache)
-        state_s = jax.tree.map(sds, self._dev_state)
-        rng_s = sds(self._rng)
-        psz = cfg.page_size
-        if prompt_buckets is None:
-            prompt_buckets = self._reachable_prompt_buckets()
-        tasks: list[Callable[[], Any]] = []
-        freq_variants = (False, True) if cfg.enable_frequency_penalty else (False,)
-        for wp in self._reachable_chunk_wps():
-            for capped, greedy_any in (
-                (False, False),  # the serving steady state (pure sampling)
-                (False, True),
-                (True, False),
-                (True, True),
-            ):
-              for freq_any in freq_variants:
-                tasks.append(
-                    lambda wp=wp, capped=capped, greedy_any=greedy_any, freq_any=freq_any: self._chunk_fn(
-                        cfg.decode_steps_per_call, wp, capped, greedy_any, freq_any
-                    ).lower(
-                        params_s,
-                        cache_s,
-                        jax.ShapeDtypeStruct((cfg.max_batch_size, wp), jnp.int32),
-                        state_s,
-                        rng_s,
-                    ).compile()
-                )
-        upd_row = 11 + _MAX_STOP  # _pack_row column count
-        for n in self._reachable_scatter_sizes():
-            tasks.append(
-                lambda n=n: self._update_fn(n).lower(
-                    state_s, jax.ShapeDtypeStruct((n, upd_row), jnp.float32)
-                ).compile()
-            )
-            tasks.append(
-                lambda n=n: self._clamp_fn(n).lower(
-                    state_s, jax.ShapeDtypeStruct((n, 2), jnp.int32)
-                ).compile()
-            )
-        # GRPO prefix-sharing page copies (dup counts pad to powers of two
-        # up to next_pow2(S-1)) — a cold compile would stall all slots
-        # mid-serving
-        n = 1
-        while True:
-
-            tasks.append(
-                lambda n=n: self._pagecopy_fn(n).lower(
-                    cache_s, *[jax.ShapeDtypeStruct((n,), jnp.int32)] * 4
-                ).compile()
-            )
-            if n >= max(1, cfg.max_batch_size - 1):
-                break
-            n *= 2
-        for bucket in prompt_buckets:
-            for A in self._prefill_sizes(bucket):
-                tasks.append(
-                    lambda A=A, bucket=bucket: self._prefill_fn(A, bucket).lower(
-                        params_s,
-                        cache_s,
-                        jax.ShapeDtypeStruct((A, bucket), jnp.int32),
-                        jax.ShapeDtypeStruct((A,), jnp.int32),
-                        jax.ShapeDtypeStruct((A * -(-bucket // psz),), jnp.int32),
-                        jax.ShapeDtypeStruct((A,), jnp.int32),
-                    ).compile()
-                )
-
+        shapes = jax.tree.map(sds, (self.params, self.cache, self._dev_state, self._rng))
+        # which programs, in which order, and how each is lowered: the
+        # programs' own (decode_programs.py warm_keys / lower)
+        keys = self.programs.warm_keys(prompt_buckets)
         n_prog = 0
         with set_mesh(self.mesh), perf_tracer.trace_scope(
-            "areal.setup.precompile", args={"programs": len(tasks)}
+            "areal.setup.precompile", args={"programs": len(keys)}
         ):
-            for task in tasks:
+            for key in keys:
                 if budget_s is not None and time.monotonic() - t0 > budget_s:
                     logger.warning(
                         f"precompile budget {budget_s:.0f}s spent after "
-                        f"{n_prog} programs; {len(tasks) - n_prog} deferred "
+                        f"{n_prog} programs; {len(keys) - n_prog} deferred "
                         "to lazy compile"
                     )
                     break
-                task()
+                self.programs.lower(key, *shapes).compile()
                 n_prog += 1
         logger.info(
-            f"precompiled {n_prog}/{len(tasks)} serving programs in "
+            f"precompiled {n_prog}/{len(keys)} serving programs in "
             f"{time.monotonic() - t0:.1f}s"
         )
 
@@ -982,14 +621,13 @@ class DecodeEngine:
         slot occupancy. Reads are racy-but-monotone (queue/backlog sizes),
         which is fine for a gate that only needs to be approximately
         right."""
-        radix_pages = self._radix.pages_held if self._radix is not None else 0
         return {
             "queue_depth": self._queue.qsize() + len(self._backlog),
-            "free_pages": self.pool.available if hasattr(self, "pool") else 0,
-            "radix_pages": radix_pages,
+            "free_pages": self.slots.free_pages,
+            "radix_pages": self.slots.radix_pages,
             # pool size so remote consumers (the routing snapshot poller)
             # can turn free_pages into a headroom fraction
-            "n_pages": self.pool.n_pages if hasattr(self, "pool") else 0,
+            "n_pages": self.slots.n_pages,
             "active_slots": sum(
                 1 for t in getattr(self, "_slot_task", ()) if t is not None
             ),
@@ -1038,18 +676,16 @@ class DecodeEngine:
                     setattr(lc, k, max(0, int(knobs[k])))
                     applied[k] = float(getattr(lc, k))
         frac = knobs.get("radix_max_fraction")
-        if frac is not None and self._radix is not None and hasattr(self, "pool"):
+        if frac is not None and self.slots.radix is not None:
             frac = max(0.0, min(1.0, float(frac)))
-            self._radix.max_pages = max(
-                0, min(int((self.pool.n_pages - 1) * frac), self.pool.n_pages - 1)
-            )
+            self.slots.set_prefix_fraction(frac)
             applied["radix_max_fraction"] = frac
             if self._thread is not None and self._thread.is_alive():
                 # the tree is decode-loop-private while the loop runs: it
                 # converges to the new cap between chunks
                 self._wakeup.set()
             else:
-                self._service_radix_cap()
+                self.slots.shrink_prefix_to_cap()
         if applied:
             with self._autopilot_lock:
                 self._autopilot_knobs.update(applied)
@@ -1064,16 +700,6 @@ class DecodeEngine:
                 "knobs": dict(self._autopilot_knobs),
                 "applied_at": self._autopilot_applied_at,
             }
-
-    def _service_radix_cap(self) -> None:
-        """Converge the radix tree onto a shrunk autopilot cap — runs on
-        the decode loop (tree/pool owner) between chunks, or inline when
-        the loop is down."""
-        r = self._radix
-        if r is not None and r.pages_held > r.max_pages:
-            freed = r.evict(r.pages_held - r.max_pages)
-            if freed:
-                self._obs_pc.evicted_pages.inc(freed)
 
     def is_wedged(self) -> bool:
         """True when the decode loop has made no pass for
@@ -1167,13 +793,9 @@ class DecodeEngine:
         # credit the in-flight chunk before any slot teardown
         self._drain(pending)
         pending = None
-        # queued work first: drain the submission queue into the backlog
-        # (same FIFO order _admit_pending uses) and filter both
-        while True:
-            try:
-                self._backlog.append(self._queue.get_nowait())
-            except queue.Empty:
-                break
+        # queued work first: the submission queue joins the backlog (same
+        # FIFO order _admit_pending uses) and both are filtered
+        self._queue_to_backlog()
         kept: deque[_Task] = deque()
         counted: set[str] = set()  # rids whose cancel _finish already counted
         for task in self._backlog:
@@ -1220,9 +842,7 @@ class DecodeEngine:
             if reason is None:
                 continue
             if st["active"][slot]:
-                rows.append(
-                    self._pack_row(slot, 0, int(st["pos"][slot]), False, 0)
-                )
+                rows.append(self._deact_row(slot))
             self._finish(task, reason)
         if rows and self.cache is not None:
             self._apply_slot_updates(rows)
@@ -1230,17 +850,11 @@ class DecodeEngine:
         # (deadlines leave parked KV alone — the rid owner may still resume
         # with time left on a fresh attempt; eviction pressure bounds it)
         for rid in aborts:
-            p = self._parked.pop(rid, None)
-            if p is not None:
-                self.pool.free(p.pages)
-                self._slot_pages[p.slot] = []
-                self._slot_page_versions[p.slot] = []
-                self._pt_host[p.slot] = 0
-                # a parked rid whose resume was reaped above already counted
-                # through _finish — one cancelled request, one increment
-                if rid not in counted:
-                    self.stats["cancelled"] += 1
-                    self._obs_lc.aborts.inc()
+            # a parked rid whose resume was reaped above already counted
+            # through _finish — one cancelled request, one increment
+            if self.slots.drop_parked(rid) and rid not in counted:
+                self.stats["cancelled"] += 1
+                self._obs_lc.aborts.inc()
         return None  # in-flight chunk was drained above
 
     # -- pause / weights (the §3.4 protocol) ------------------------------
@@ -1326,16 +940,19 @@ class DecodeEngine:
         self._draining.clear()
         return True
 
+    def _queue_to_backlog(self) -> None:
+        while True:
+            try:
+                self._backlog.append(self._queue.get_nowait())
+            except queue.Empty:
+                return
+
     def _abort_queued(self) -> None:
         """Finish every queued/backlogged task with stop_reason=abort —
         decode-loop-thread only (backlog ownership). A draining replica
         must leave no request without a terminal: the callback's partial
         response is what lets the client resubmit elsewhere."""
-        while True:
-            try:
-                self._backlog.append(self._queue.get_nowait())
-            except queue.Empty:
-                break
+        self._queue_to_backlog()
         while self._backlog:
             task = self._backlog.popleft()
             self._finish(task, StopReason.ABORT.value)
@@ -1387,17 +1004,14 @@ class DecodeEngine:
             # no loop: this thread owns the state — drain inline
             self._abort_all()
             self._abort_queued()
-        held = self._radix.pages_held if self._radix is not None else 0
-        parked_pages = sum(len(p.pages) for p in self._parked.values())
-        pool_used = self.pool.used if hasattr(self, "pool") else 0
         summary = {
             "draining": True,
             "drain_seconds": time.monotonic() - t0,
             "finished_in_budget": finished_in_budget,
             "budget_s": budget_s,
-            "parked": len(self._parked),
+            "parked": len(self.slots.parked),
             "aborted": self.stats["aborted"] - aborted_before,
-            "leaked_pages": int(pool_used - held - parked_pages),
+            "leaked_pages": self.slots.leaked_pages(),
             "unterminated_timelines": self.timeline.stats()["unterminated"],
         }
         self._drain_summary = summary
@@ -1723,22 +1337,14 @@ class DecodeEngine:
             if version is not None:
                 self._version = version
             if not self.config.kv_reuse_across_updates:
-                while self._evict_oldest_parked() is not None:
-                    pass
+                self.slots.evict_all_parked()
             # cross-request prefix cache: KV cached under the old policy is
             # stale after this commit. The default policy flushes the tree
             # (only the tree's own refs drop — pages aliased by live slots
             # survive until those slots free them); "keep" retains it for
             # the staleness-ablation arm, audited by per-token version tags.
-            policy = getattr(
-                getattr(self.config, "prefix_cache", None),
-                "across_updates",
-                "flush",
-            )
-            if self._radix is not None and policy == "flush":
-                freed = self._radix.flush()
-                if freed:
-                    self._obs_pc.evicted_pages.inc(freed)
+            if self.config.prefix_cache.across_updates == "flush":
+                self.slots.flush_prefix()
             self._pending_weight_update = None
             self.flight.record(
                 "weight_commit",
@@ -1762,7 +1368,7 @@ class DecodeEngine:
         # synchronize with the decode loop: pause_generation only sets an
         # event; a chunk may still be in flight (it would resurrect the KV
         # slab by assigning its donated result back) and _abort_all may not
-        # have parked yet (we'd clear _parked too early and the loop would
+        # have parked yet (we'd clear the parkings too early and the loop would
         # re-add entries pointing at the dropped cache)
         if self._thread is not None and not self._pause_ack.wait(timeout=120):
             raise TimeoutError("decode loop did not acknowledge pause")
@@ -1772,8 +1378,7 @@ class DecodeEngine:
         self.params, mode = offload_tree(self.params)
         self._offload_mode = mode
         self.cache = None  # pages are zeros-recreatable; parked KV is lost
-        while self._evict_oldest_parked() is not None:
-            pass
+        self.slots.evict_all_parked()
         logger.info(f"released memory ({mode}) in {time.monotonic()-t0:.2f}s")
 
     def resume_memory(self) -> None:
@@ -1819,23 +1424,10 @@ class DecodeEngine:
         the pool — excluded from the itemized total), and any staged
         weight-update buffers. Device memory_stats where the backend has
         them; analytic byte sums on CPU. Exported on /statusz."""
-        from areal_tpu.observability import hw_accounting as hw
-
-        state_bytes = self._state_bytes()
-        kv_bytes = hw.tree_bytes(getattr(self, "cache", None)) - state_bytes
-        pool = getattr(self, "pool", None)
-        page_bytes = (
-            kv_bytes / pool.n_pages if pool is not None and pool.n_pages else 0
-        )
-        radix_pages = self._radix.pages_held if self._radix is not None else 0
         components = {
             "params": hw.tree_bytes(self.params),
-            "kv_page_pool": kv_bytes,
-            "recurrent_state": state_bytes,
-            "radix_cache": int(radix_pages * page_bytes),
-            "staged_update": hw.tree_bytes(
-                getattr(self, "_staged_flat", None)
-            ),
+            **self.slots.hbm_rows(self.cache),
+            "staged_update": hw.tree_bytes(self._staged_flat),
         }
         return hw.build_hbm_ledger(
             components,
@@ -1890,30 +1482,18 @@ class DecodeEngine:
         held = [first, first + self.model_cfg.num_experts]
         return {"load": load.tolist(), **({"held": held} if held != [0, load.shape[1]] else {})}
 
-    def _state_bytes(self) -> int:
-        """Device bytes of the slot-indexed recurrent state (0 for a model
-        without recurrent layers, or while the cache is released)."""
-        from areal_tpu.inference import paged_kv
-
-        cache = getattr(self, "cache", None) or {}
-        return hw.tree_bytes({k: cache[k] for k in paged_kv.STATE_LEAVES if k in cache})
-
     # -- prefix cache (cross-request radix reuse) --------------------------
     def prefix_cache_stats(self) -> dict:
         """Point-in-time radix-cache state for /statusz and tests."""
-        if self._radix is None:
+        tree = self.slots.prefix_stats()
+        if tree is None:
             limits = self._model_limits()
             if "prefix_cache" in limits:
                 return {"enabled": False, "disabled_by": limits["reason"]}
             return {"enabled": False}
         return {
             "enabled": True,
-            "pages_held": self._radix.pages_held,
-            "max_pages": self._radix.max_pages,
-            # page granularity, so the client-side shadow prefix index
-            # (routing/shadow_index.py) keys its radix on the same pages
-            "page_size": self.config.page_size,
-            **self._radix.stats,
+            **tree,
             # hit accounting is engine-owned: counted once per ADMITTED
             # request, so backlog retries can't inflate the hit rate
             "hits": self.stats["prefix_cache_hits"],
@@ -1926,13 +1506,10 @@ class DecodeEngine:
         The tree is decode-loop-private, so a live loop performs the flush
         itself between chunks; we only marshal the request. Returns freed
         page count (0 on timeout or when the cache is disabled)."""
-        if self._radix is None:
+        if self.slots.radix is None:
             return 0
         if self._thread is None or not self._thread.is_alive():
-            freed = self._radix.flush()
-            if freed:
-                self._obs_pc.evicted_pages.inc(freed)
-            return freed
+            return self.slots.flush_prefix()
         with self._weight_lock:
             req = self._radix_flush_req
             if req is None:
@@ -1953,71 +1530,8 @@ class DecodeEngine:
         if req is None:
             return
         ev, box = req
-        freed = self._radix.flush() if self._radix is not None else 0
-        if freed:
-            self._obs_pc.evicted_pages.inc(freed)
-        box.append(freed)
+        box.append(self.slots.flush_prefix())
         ev.set()
-
-    # -- jitted kernels ---------------------------------------------------
-    def _prefill_fn(self, n_prompts: int, bucket: int, with_images: bool = False):
-        """Batched prefill: A prompts (padded to ``bucket``) in one forward,
-        their KV scattered into the A rows' pages and, for a model with
-        recurrent layers, each row's post-prompt state into its slot (what
-        exactly: ``prefill_into_cache`` of the model's family). Amortises
-        the full-parameter read across admits.
-        ``with_images`` adds a positioned [A, bucket, D] vision-embed input
-        (VLM serving; embeds computed by _image_embeds_for at admission)."""
-        key = ("prefill", n_prompts, bucket, with_images)
-        if key not in self._fn_cache:
-            mcfg = self.model_cfg
-            psz = self.config.page_size
-            model = self.model
-
-            def prefill(params, cache, ids, plens, flat_pages, slots, img=None):
-                # ids [A, bucket], plens [A], flat_pages [A * bucket/psz],
-                # slots [A] (a padding row: one past the last slot)
-                return model.prefill_into_cache(
-                    params, mcfg, cache, ids, plens, flat_pages, slots,
-                    page_size=psz, image_embeds=img,
-                )
-
-            self._fn_cache[key] = jax.jit(prefill, donate_argnames=("cache",))
-            return FirstCall(self._fn_cache[key], key)
-        return self._fn_cache[key]
-
-    def _prefill_paged_fn(self, n_prompts: int, bucket: int, wp: int):
-        """Suffix-only prefill over a radix-cached prefix: A suffixes
-        (padded to ``bucket``) in one forward, queries attending over each
-        row's cached prefix pages (``wp`` page-table columns) plus the
-        causal suffix; suffix KV scatters into fresh pages. The prefix
-        pages are read-only (aliased, possibly shared across requests)."""
-        use_kernel = self._suffix_kernel()
-        key = ("prefill_sfx", n_prompts, bucket, wp, use_kernel)
-        if key not in self._fn_cache:
-            mcfg = self.model_cfg
-            psz = self.config.page_size
-            from areal_tpu.inference import paged_kv
-
-            def prefill(params, cache, ids, plens, offs, flat_pages, ppt):
-                # ids [A, bucket] suffix tokens; plens [A] suffix lengths;
-                # offs [A] absolute start positions — page-aligned, so they
-                # double as the cached-prefix lengths; ppt [A, wp] prefix
-                # page table
-                positions = offs[:, None] + jnp.arange(bucket, dtype=jnp.int32)[None]
-                seg = (
-                    jnp.arange(bucket, dtype=jnp.int32)[None] < plens[:, None]
-                ).astype(jnp.int32)
-                _, ks, vs = self.model.forward_prefill_paged(
-                    params, mcfg, ids, positions, seg, cache, ppt, offs,
-                    use_kernel=use_kernel,
-                )
-                with jax.named_scope("kv_write"):
-                    return paged_kv.scatter_prefill(cache, ks, vs, flat_pages, psz)
-
-            self._fn_cache[key] = jax.jit(prefill, donate_argnames=("cache",))
-            return FirstCall(self._fn_cache[key], key)
-        return self._fn_cache[key]
 
     def _image_embeds_for(self, group: list[tuple[_Task, int]], ids_np, bucket: int):
         """VLM admission: run the vision tower over each request's pixel
@@ -2055,28 +1569,9 @@ class DecodeEngine:
                     "vision rope positions default to (0,0) per patch"
                 )
                 pos = np.zeros((P, 2), np.int32)
-            # bucket the padded patch count: distinct image sizes must not
-            # each compile a fresh ViT (the mask handles the padding); THE
-            # shared formula so serving and training embeds agree
-            from areal_tpu.models.vision import pad_patch_bucket
-
-            Ppad = pad_patch_bucket(P, merge2)
-            key = ("vision", Ppad)
-            if key not in self._fn_cache:
-                vcfg = mcfg.vision
-                self._fn_cache[key] = jax.jit(
-                    lambda vp, x, m, p: vis.vision_forward(vp, vcfg, x, m, p)
-                )
-            px_pad = np.pad(px, ((0, Ppad - P), (0, 0)))
-            pos_pad = np.pad(pos, ((0, Ppad - P), (0, 0)))
-            mask = np.arange(Ppad) < P
             with set_mesh(self.mesh):
-                out_dev = self._fn_cache[key](
-                    self.params["vision"],
-                    jnp.asarray(px_pad),
-                    jnp.asarray(mask),
-                    jnp.asarray(pos_pad),
-                )
+                vision, args = self.programs.vision_call(px, pos)
+                out_dev = vision(self.params["vision"], *args)
             pending.append((j, task, P, out_dev))
         if not pending:
             return emb
@@ -2096,411 +1591,16 @@ class DecodeEngine:
             emb[j, pos[:n]] = out[:n]
         return emb
 
-    def _chunk_fn(
-        self,
-        n_steps: int,
-        wp: int,
-        capped: bool,
-        greedy_any: bool = True,
-        freq_any: bool = False,
-    ):
-        """n_steps of decode for all slots in one jitted call, attending over
-        each slot's first ``wp`` KV pages (the window, bucketed in pages).
-
-        Returns (cache, state, rng, packed) where ``packed`` is ONE int32
-        array [2*n_steps + 3, S] — token rows, logprob-bit rows (fp32
-        bitcast), then emit_count / final-active / final-pos rows — so the
-        host pays a single device->host transfer per chunk. A model with
-        sparse experts or delta-rule layers appends its counts of the chunk
-        (``model_cfg.count_shapes``, flat, in whole rows of S). Emission is
-        monotone within a chunk (a stopped slot never re-activates; admits
-        happen between chunks), so per-slot counts fully describe the
-        emit mask."""
-        key = ("chunk", n_steps, wp, capped, greedy_any, freq_any)
-        if key not in self._fn_cache:
-            mcfg = self.model_cfg
-            T = self.config.max_seq_len
-            psz = self.config.page_size
-            use_kernel = self._use_kernel
-            model = self.model
-
-            counts_of = dict(mcfg.count_shapes)
-
-            def chunk(params, cache, page_table, state, rng):
-                # the model's counts of this chunk's steps: zeroed here, added
-                # to by the model's forward for the active slots only, and
-                # handed back in ``packed`` (they are no part of the cache)
-                cache = {**cache, **{k: jnp.zeros(shp, jnp.int32) for k, shp in counts_of.items()}}
-
-                def step(carry, _):
-                    ids, pos, active, remaining, counts, cache, rng = carry
-                    hidden, cache = model.forward_decode_paged(
-                        params,
-                        mcfg,
-                        ids,
-                        pos,
-                        cache,
-                        page_table,
-                        page_size=psz,
-                        active=active,
-                        use_kernel=use_kernel,
-                    )
-                    with jax.named_scope("lm_head"):
-                        logits = model.compute_logits(params, mcfg, hidden)
-                    with jax.named_scope("sampler"):
-                        if freq_any:
-                            # OpenAI-style frequency penalty on raw logits,
-                            # proportional to this slot's generated-token counts
-                            logits = logits - (
-                                state["freq_pen"][:, None]
-                                * counts.astype(jnp.float32)
-                            )
-                        rng, sub = jax.random.split(rng)
-                        next_ids, logp = _sample_step(
-                            logits, sub, state, capped, greedy_any
-                        )
-                    if freq_any:
-                        # saturating (uint16 .add would wrap at 65535 —
-                        # reachable at max_seq_len > 64k, and negative
-                        # penalties actively drive repeats toward it)
-                        sl = jnp.arange(counts.shape[0])
-                        cur = counts[sl, next_ids].astype(jnp.int32)
-                        counts = counts.at[sl, next_ids].set(
-                            jnp.minimum(
-                                cur + active.astype(jnp.int32), 65535
-                            ).astype(counts.dtype)
-                        )
-                    emitted = active
-                    hit_stop = jnp.any(
-                        next_ids[:, None] == state["stop_ids"], axis=-1
-                    ) & (remaining - 1 <= state["min_rem"])
-                    new_pos = pos + 1
-                    remaining = remaining - active.astype(jnp.int32)
-                    still = (
-                        active
-                        & ~hit_stop
-                        & (remaining > 0)
-                        & (new_pos < T - 1)
-                    )
-                    ids = jnp.where(active, next_ids, ids)
-                    pos = jnp.where(active, new_pos, pos)
-                    return (ids, pos, still, remaining, counts, cache, rng), (
-                        next_ids,
-                        logp,
-                        emitted,
-                    )
-
-                carry = (
-                    state["ids"],
-                    state["pos"],
-                    state["active"],
-                    state["remaining"],
-                    state["freq_counts"] if freq_any else jnp.zeros((), jnp.uint16),
-                    cache,
-                    rng,
-                )
-                (ids, pos, active, remaining, counts, cache, rng), (
-                    toks,
-                    logps,
-                    emit,
-                ) = jax.lax.scan(step, carry, None, length=n_steps)
-                out_state = dict(state)
-                out_state.update(ids=ids, pos=pos, active=active, remaining=remaining)
-                if freq_any:
-                    out_state["freq_counts"] = counts
-                cache = dict(cache)
-                extra = [cache.pop(k).reshape(-1) for k in counts_of]
-                if extra:  # after the slots' rows, flat, padded to whole rows
-                    flat = jnp.concatenate(extra)
-                    S = toks.shape[1]
-                    extra = [jnp.pad(flat, (0, -flat.size % S)).reshape(-1, S)]
-                packed = jnp.concatenate(
-                    [
-                        toks.astype(jnp.int32),  # [n_steps, S]
-                        jax.lax.bitcast_convert_type(
-                            logps.astype(jnp.float32), jnp.int32
-                        ),  # [n_steps, S]
-                        emit.sum(0, dtype=jnp.int32)[None],  # emit_count [1, S]
-                        active.astype(jnp.int32)[None],  # [1, S]
-                        pos.astype(jnp.int32)[None],  # [1, S]
-                        *extra,
-                    ],
-                    axis=0,
-                )
-                return cache, out_state, rng, packed
-
-            self._fn_cache[key] = jax.jit(chunk, donate_argnames=("cache", "state"))
-            return FirstCall(self._fn_cache[key], key)
-        return self._fn_cache[key]
-
-    def _spec_fn(self, B: int, wp: int, capped: bool, greedy_any: bool = True):
-        """One speculative verify+accept round in a single jitted call.
-
-        Row 0 per slot is the pending token, rows 1..B-1 the draft tree
-        nodes. ``forward_verify_paged`` scores all B nodes at once; an
-        unrolled accept walk then re-runs the TARGET sampler position by
-        position and follows the tree edge whose draft token equals the
-        sampled target — so every emitted token is exactly what the
-        sequential path would have produced (greedy byte-identity; sampled
-        slots draw from the true per-position conditional, the token-match
-        form of speculative rejection sampling). KV is scattered
-        row-granularly: only visited (accepted-path) rows land in real
-        pages, everything else routes to trash page 0, so rejected drafts
-        never exist in committed KV and radix publication stays safe.
-
-        ``packed`` has the exact _chunk_fn layout with n_steps = B, so the
-        normal ``_drain`` bookkeeping credits the round unchanged."""
-        use_kernel = self._suffix_kernel()
-        key = ("spec", B, wp, capped, greedy_any, use_kernel)
-        if key not in self._fn_cache:
-            from areal_tpu.inference import paged_kv
-
-            mcfg = self.model_cfg
-            T = self.config.max_seq_len
-            psz = self.config.page_size
-            K = B - 1
-
-            def spec(params, cache, page_table, state, rng, drafts):
-                d_tokens = drafts["tokens"]  # [S, K]
-                d_parent = drafts["parent_row"]  # [S, K] row of parent
-                d_depth = drafts["depth"]  # [S, K]
-                d_mask = drafts["mask"]  # [S, B, B]
-                d_count = drafts["n_draft"]  # [S]
-                S = state["ids"].shape[0]
-                pos0 = state["pos"]
-                ids_nodes = jnp.concatenate(
-                    [state["ids"][:, None], d_tokens], axis=1
-                )  # [S, B]
-                depth_full = jnp.concatenate(
-                    [jnp.zeros((S, 1), jnp.int32), d_depth], axis=1
-                )
-                # clamp keeps gather/scatter indices in range for inactive
-                # slots with stale pos; their page-table rows are zeroed so
-                # everything lands in trash anyway
-                positions = jnp.minimum(pos0[:, None] + depth_full, T - 1)
-                hidden, ks, vs = self.model.forward_verify_paged(
-                    params,
-                    mcfg,
-                    ids_nodes,
-                    positions,
-                    d_mask,
-                    cache,
-                    page_table,
-                    pos0,
-                    use_kernel=use_kernel,
-                )
-                with jax.named_scope("lm_head"):
-                    logits = self.model.compute_logits(params, mcfg, hidden)  # [S,B,V]
-                row_valid = (
-                    jnp.arange(1, B, dtype=jnp.int32)[None, :]
-                    <= d_count[:, None]
-                )  # [S, K]
-                cur = jnp.zeros((S,), jnp.int32)  # row the walk is at
-                cont = state["active"]  # still emitting THIS round
-                alive = state["active"]  # slot lives past the round
-                pos_c = pos0
-                rem_c = state["remaining"]
-                ids_c = state["ids"]
-                # rows whose KV becomes committed context = rows the walk
-                # visits (root + accepted path); matches the sequential
-                # path's write set exactly
-                row_ok = jnp.zeros((S, B), bool).at[:, 0].set(True)
-                toks_rows, logp_rows, emit_rows = [], [], []
-                for j in range(B):
-                    lg = jnp.take_along_axis(
-                        logits, cur[:, None, None], axis=1
-                    )[:, 0]  # [S, V]
-                    with jax.named_scope("sampler"):
-                        rng, sub = jax.random.split(rng)
-                        t_j, logp_j = _sample_step(
-                            lg, sub, state, capped, greedy_any
-                        )
-                    emit_rows.append(cont)
-                    toks_rows.append(t_j)
-                    logp_rows.append(logp_j)
-                    # exact _chunk_fn stop/budget semantics per emitted step
-                    hit_stop = jnp.any(
-                        t_j[:, None] == state["stop_ids"], axis=-1
-                    ) & (rem_c - 1 <= state["min_rem"])
-                    new_pos = pos_c + cont.astype(jnp.int32)
-                    rem_c = rem_c - cont.astype(jnp.int32)
-                    step_alive = (
-                        cont & ~hit_stop & (rem_c > 0) & (new_pos < T - 1)
-                    )
-                    alive = jnp.where(cont, step_alive, alive)
-                    ids_c = jnp.where(cont, t_j, ids_c)
-                    pos_c = new_pos
-                    if j < K:
-                        # follow the tree edge matching the target token
-                        match = (
-                            (d_parent == cur[:, None])
-                            & (d_tokens == t_j[:, None])
-                            & row_valid
-                        )  # [S, K] over rows 1..K
-                        has = match.any(axis=1)
-                        child = jnp.argmax(match, axis=1).astype(jnp.int32) + 1
-                        cont = step_alive & has
-                        cur = jnp.where(cont, child, cur)
-                        row_ok = row_ok | (
-                            (jnp.arange(B)[None, :] == child[:, None])
-                            & cont[:, None]
-                        )
-                out_state = dict(state)
-                out_state.update(
-                    ids=ids_c, pos=pos_c, active=alive, remaining=rem_c
-                )
-                # selective KV commit: visited rows -> their real page rows,
-                # everything else -> trash page 0
-                page_idx = jnp.clip(positions // psz, 0, wp - 1)
-                pages = jnp.take_along_axis(page_table, page_idx, axis=1)
-                pages = jnp.where(row_ok, pages, 0)
-                rows = positions % psz
-                L = ks.shape[0]
-                KH, hd = ks.shape[3], ks.shape[4]
-                with jax.named_scope("kv_write"):
-                    cache = paged_kv.scatter_token_rows(
-                        cache,
-                        ks.reshape(L, S * B, KH, hd),
-                        vs.reshape(L, S * B, KH, hd),
-                        pages.reshape(-1),
-                        rows.reshape(-1),
-                    )
-                packed = jnp.concatenate(
-                    [
-                        jnp.stack(toks_rows).astype(jnp.int32),  # [B, S]
-                        jax.lax.bitcast_convert_type(
-                            jnp.stack(logp_rows).astype(jnp.float32),
-                            jnp.int32,
-                        ),  # [B, S]
-                        jnp.stack(emit_rows).sum(0, dtype=jnp.int32)[None],
-                        alive.astype(jnp.int32)[None],
-                        pos_c.astype(jnp.int32)[None],
-                    ],
-                    axis=0,
-                )
-                return cache, out_state, rng, packed
-
-            self._fn_cache[key] = jax.jit(spec, donate_argnames=("cache", "state"))
-            return FirstCall(self._fn_cache[key], key)
-        return self._fn_cache[key]
-
-    def _update_fn(self, n: int):
-        """Jitted slot-state scatter: one packed fp32 [n, 11+_MAX_STOP] upload
-        (columns: slot, ids, pos, active, remaining, top_k, greedy, temp,
-        top_p, min_rem, freq_pen, stop_ids...) applied on device. All values fit fp32 exactly
-        (token ids < 2^24). Padded rows repeat row 0 (idempotent scatter)."""
-        key = ("upd", n)
-        if key not in self._fn_cache:
-
-            def apply(state, upd):
-                sl = upd[:, 0].astype(jnp.int32)
-                state = dict(state)
-                state["ids"] = state["ids"].at[sl].set(upd[:, 1].astype(jnp.int32))
-                state["pos"] = state["pos"].at[sl].set(upd[:, 2].astype(jnp.int32))
-                state["active"] = state["active"].at[sl].set(upd[:, 3] > 0)
-                state["remaining"] = (
-                    state["remaining"].at[sl].set(upd[:, 4].astype(jnp.int32))
-                )
-                state["top_k"] = state["top_k"].at[sl].set(upd[:, 5].astype(jnp.int32))
-                state["greedy"] = state["greedy"].at[sl].set(upd[:, 6] > 0)
-                state["temp"] = state["temp"].at[sl].set(upd[:, 7])
-                state["top_p"] = state["top_p"].at[sl].set(upd[:, 8])
-                state["min_rem"] = (
-                    state["min_rem"].at[sl].set(upd[:, 9].astype(jnp.int32))
-                )
-                state["freq_pen"] = state["freq_pen"].at[sl].set(upd[:, 10])
-                if "freq_counts" in state:
-                    # (re)admission resets the slot's repeat counts
-                    state["freq_counts"] = state["freq_counts"].at[sl].set(0)
-                state["stop_ids"] = (
-                    state["stop_ids"].at[sl].set(upd[:, 11 : 11 + _MAX_STOP].astype(jnp.int32))
-                )
-                return state
-
-            self._fn_cache[key] = jax.jit(apply, donate_argnames=("state",))
-            return FirstCall(self._fn_cache[key], key)
-        return self._fn_cache[key]
-
     # -- decode loop ------------------------------------------------------
-    def _parked_slots(self) -> set[int]:
-        return {p.slot for p in self._parked.values()}
+    @property
+    def _slot_pages(self) -> list[list[int]]:
+        # forwarding name: benchmarks/chip/benchlib/cells/rollout_family_select.py
+        # reads the pages of the slot a probe request decodes in
+        return [self.slots.pages(s) for s in range(self.config.max_batch_size)]
 
-    def _free_slots(self) -> list[int]:
-        parked = self._parked_slots()
-        return [
-            i
-            for i, t in enumerate(self._slot_task)
-            if t is None and i not in parked
-        ]
-
-    def _evict_oldest_parked(self) -> int | None:
-        """Free the least-recently-parked slot and its KV pages (a resume
-        for that rid falls back to prefill)."""
-        if not self._parked:
-            return None
-        rid = min(self._parked, key=lambda r: self._parked[r].park_time)
-        p = self._parked.pop(rid)
-        self._state_dropped.add(rid)
-        self.pool.free(p.pages)
-        self._slot_pages[p.slot] = []
-        self._slot_page_versions[p.slot] = []
-        self._pt_host[p.slot] = 0
-        return p.slot
-
-    def _reclaim_pages(self, n: int) -> bool:
-        """Eviction ladder below the free pool: radix LRU leaves first (pure
-        cache — any published page is re-creatable by a prefill), then
-        parked KV (rid-affinity state whose loss costs a re-prefill).
-        Returns True when anything was freed (the caller re-allocs)."""
-        if self._radix is not None:
-            freed = self._radix.evict(n)
-            if freed > 0:
-                self._obs_pc.evicted_pages.inc(freed)
-                self.flight.record("evict_radix", pages=freed)
-                return True
-        slot = self._evict_oldest_parked()
-        if slot is not None:
-            self.flight.record("evict_parked", severity="warn", slot=slot)
-        return slot is not None
-
-    def _pack_row(
-        self,
-        slot: int,
-        last_id: int,
-        pos: int,
-        active: bool,
-        remaining: int,
-        top_k: int = -1,
-        greedy: bool = False,
-        temp: float = 1.0,
-        top_p: float = 1.0,
-        stops: list[int] | None = None,
-        min_rem: int | None = None,
-        freq_pen: float = 0.0,
-    ) -> np.ndarray:
-        """The ONE place that knows the packed scatter-row column order (must
-        match ``_update_fn``): update the host mirror and build the fp32 row.
-        ``min_rem``: stops fire only once remaining-1 <= min_rem (the
-        min_new_tokens gate); default = remaining, i.e. always allowed."""
-        stops = (list(stops or []) + [-1] * _MAX_STOP)[:_MAX_STOP]
-        if min_rem is None:
-            min_rem = remaining
-        st = self._state
-        st["ids"][slot] = last_id
-        st["pos"][slot] = pos
-        st["active"][slot] = active
-        st["remaining"][slot] = remaining
-        st["temp"][slot] = temp
-        st["greedy"][slot] = greedy
-        st["top_k"][slot] = top_k
-        st["top_p"][slot] = top_p
-        st["min_rem"][slot] = min_rem
-        st["freq_pen"][slot] = freq_pen
-        st["stop_ids"][slot] = stops
-        return np.asarray(
-            [slot, last_id, pos, active, remaining, top_k, greedy, temp, top_p, min_rem, freq_pen, *stops],
-            np.float32,
-        )
+    def _deact_row(self, slot: int) -> np.ndarray:
+        """The scatter row that takes ``slot`` out of the batch where it stands."""
+        return pack_row(self._state, slot, 0, int(self._state["pos"][slot]), False, 0)
 
     def _slot_update_row(
         self, task: _Task, slot: int, last_id: int, pos: int, remaining: int
@@ -2519,16 +1619,17 @@ class DecodeEngine:
         temp = 0.0 if g.greedy else g.temperature
         greedy = bool(g.greedy or g.temperature == 0.0)
         top_k = g.top_k if g.top_k and g.top_k > 0 else -1
-        if top_k > _TOPK_CAP:
+        if top_k > TOPK_CAP:
             # the candidate set is statically capped; top_k beyond it (or a
             # top-p nucleus wider than the cap) samples from the top
-            # _TOPK_CAP tokens only — clamp loudly instead of silently
+            # TOPK_CAP tokens only — clamp loudly instead of silently
             logger.warning(
                 f"top_k={top_k} exceeds the static candidate cap "
-                f"{_TOPK_CAP}; clamping (rid={task.req.rid})"
+                f"{TOPK_CAP}; clamping (rid={task.req.rid})"
             )
-            top_k = _TOPK_CAP
-        return self._pack_row(
+            top_k = TOPK_CAP
+        return pack_row(
+            self._state,
             slot,
             last_id,
             pos,
@@ -2577,19 +1678,12 @@ class DecodeEngine:
         cache intact and the resubmitted ids are exactly prompt+emitted,
         restore decode state with zero prefill. Returns the slot-update row."""
         rid = task.req.rid
-        if not rid or rid not in self._parked:
-            return None
-        p = self._parked[rid]
         ids = list(task.req.input_ids)
-        if ids != p.full_ids:
-            # rid reused with different content — drop the stale parking
-            # and release its pages (the slot's own list was emptied at
-            # park time, so nothing else frees them)
-            del self._parked[rid]
-            self._state_dropped.add(rid)
-            self.pool.free(p.pages)
+        # page ownership + block-table row come back with the entry; a rid
+        # reused with different content drops its stale parking instead
+        p = self.slots.resume(rid, ids) if rid else None
+        if p is None:
             return None
-        del self._parked[rid]
         slot = p.slot
         P_len = len(ids)
         if task.timeline is not None:
@@ -2602,12 +1696,6 @@ class DecodeEngine:
         task.slot = slot
         task.prompt_len = P_len
         self._slot_task[slot] = task
-        # restore page ownership + block-table row (zeroed at park time so
-        # in-flight chunks couldn't write into retained pages)
-        self._slot_pages[slot] = p.pages
-        self._slot_page_versions[slot] = list(p.page_versions)
-        self._pt_host[slot] = 0
-        self._pt_host[slot, : len(p.pages)] = p.pages
         row = self._slot_update_row(
             task, slot, ids[-1], p.pos, self._budget(task, P_len)
         )
@@ -2639,7 +1727,7 @@ class DecodeEngine:
         T = self.config.max_seq_len
         rows: list[np.ndarray] = []
         to_prefill: list[tuple[_Task, int]] = []  # (task, slot)
-        free = self._free_slots()
+        free = self.slots.free_slots(t is not None for t in self._slot_task)
         in_wait = 0  # admitted here, submitted after this pass began
         while not self._paused.is_set():
             if self._backlog:
@@ -2670,7 +1758,7 @@ class DecodeEngine:
                 in_wait += task.submit_time > self._pass_start
                 continue
             if not free:
-                evicted = self._evict_oldest_parked()
+                evicted = self.slots.evict_oldest_parked()
                 if evicted is None:
                     self._backlog.appendleft(task)  # all slots busy
                     break
@@ -2709,15 +1797,15 @@ class DecodeEngine:
                 else:
                     warm.append((task, slot, m[0], m[1]))
 
-        # group by length bucket, prefill in batches of _PREFILL_SIZES
+        # group by length bucket, prefill in batches of PREFILL_SIZES
         by_bucket: dict[int, list[tuple[_Task, int]]] = {}
         for task, slot in cold:
-            bucket = min(T, round_up_to_bucket(len(task.req.input_ids), 256))
+            bucket = self.programs.prompt_bucket(len(task.req.input_ids))
             by_bucket.setdefault(bucket, []).append((task, slot))
         with self._kphase("prefill"):
             for bucket, group in sorted(by_bucket.items()):
                 i = 0
-                sizes = self._prefill_sizes(bucket)
+                sizes = self.programs.prefill_sizes(bucket)
                 while i < len(group):
                     A = next(a for a in sizes if a <= len(group) - i)
                     rows.extend(self._prefill_group(group[i : i + A], bucket))
@@ -2727,7 +1815,7 @@ class DecodeEngine:
         psz = self.config.page_size
         for task, slot, mpages, mvers in warm:
             sfx = len(task.req.input_ids) - len(mpages) * psz
-            bucket = min(T, round_up_to_bucket(sfx, 256))
+            bucket = self.programs.prompt_bucket(sfx)
             warm_by_bucket.setdefault(bucket, []).append(
                 (task, slot, mpages, mvers)
             )
@@ -2735,7 +1823,7 @@ class DecodeEngine:
             for bucket, group in sorted(warm_by_bucket.items()):
                 i = 0
                 while i < len(group):
-                    A = next(a for a in _PREFILL_SIZES if a <= len(group) - i)
+                    A = next(a for a in PREFILL_SIZES if a <= len(group) - i)
                     rows.extend(
                         self._prefill_group_prefixed(group[i : i + A], bucket)
                     )
@@ -2745,26 +1833,18 @@ class DecodeEngine:
         return rows
 
     def _radix_match(self, task: _Task) -> tuple[list[int], list[int]] | None:
-        """Longest cached page-aligned prefix for a fresh admission. Takes
-        the pool refs on the matched pages IMMEDIATELY (before any further
-        eviction-ladder activity in this admission wave could free them);
-        a task that later backlogs must release them (`_unmatch`). The page
-        holding row ``plen-1`` is never matched — the decode head writes
-        there, and aliased pages are immutable."""
-        if self._radix is None or task.req.image_data is not None:
+        """Longest cached page-aligned prefix for a fresh admission, with
+        the pool refs on its pages taken (``SlotCache.match``): a task that
+        later backlogs must release them (``unmatch``)."""
+        if self.slots.radix is None or task.req.image_data is not None:
             return None
-        ids = task.req.input_ids
-        limit = (len(ids) - 1) // self.config.page_size
-        pages, versions = self._radix.match(ids, max_pages=limit)
-        self._obs_pc.lookups.inc()
-        if not pages:
+        m = self.slots.match(task.req.input_ids)
+        if m is None:
             self.stats["prefix_cache_misses"] += 1
-            return None
-        self.pool.ref(pages)
         # hit stats are counted at ADMISSION (in _prefill_group_prefixed),
         # not here: a pool-pressure backlog retries the match every wave
         # and would inflate the hit rate with re-counted tokens
-        return pages, versions
+        return m
 
     def _prefill_group_prefixed(
         self, group: list[tuple[_Task, int, list[int], list[int]]], bucket: int
@@ -2782,22 +1862,16 @@ class DecodeEngine:
             plen = len(task.req.input_ids)
             sfx = plen - len(mpages) * psz
             need = -(-sfx // psz)
-            pages = self.pool.alloc(need)
-            while pages is None and self._reclaim_pages(need):
-                pages = self.pool.alloc(need)
+            pages = self.slots.take(need)
             if pages is None:
                 # pool pressure: release the match refs and retry the task
                 # as a fresh admission later
-                self.pool.free(mpages)
+                self.slots.unmatch(mpages)
                 self._backlog.append(task)
                 continue
-            all_pages = list(mpages) + pages
-            self._slot_pages[slot] = all_pages
-            self._slot_page_versions[slot] = list(mvers) + [self._version] * len(
-                pages
+            self.slots.assign(
+                slot, list(mpages) + pages, list(mvers) + [self._version] * len(pages)
             )
-            self._pt_host[slot] = 0
-            self._pt_host[slot, : len(all_pages)] = all_pages
             row = np.zeros(npg, np.int32)  # 0 = trash page for padded rows
             row[:need] = pages
             page_rows.append(row)
@@ -2817,41 +1891,20 @@ class DecodeEngine:
                 )
                 task.timeline.mark(tl_mod.PREFILL_START)
         A = len(admitted)
-        flat_pages = np.stack(page_rows)
         ids_np = np.zeros((A, bucket), np.int32)
         plens = np.zeros(A, np.int32)
         offs = np.zeros(A, np.int32)
-        max_mp = max(len(m) for _, _, m, _ in admitted)
-        wp = 1
-        while wp < max_mp:
-            wp *= 2
-        ppt = np.zeros((A, wp), np.int32)
         for j, (task, _slot, mpages, _mvers) in enumerate(admitted):
             ids = list(task.req.input_ids)
             n_tok = len(mpages) * psz
             ids_np[j, : len(ids) - n_tok] = ids[n_tok:]
             plens[j] = len(ids) - n_tok
             offs[j] = n_tok
-            ppt[j, : len(mpages)] = mpages
-        sizes = [a for a in _PREFILL_SIZES if a >= A]
-        A_pad = min(sizes) if sizes else A
-        if A_pad > A:
-            ids_np = np.pad(ids_np, ((0, A_pad - A), (0, 0)))
-            ids_np[A:, 0] = 1
-            plens = np.pad(plens, (0, A_pad - A), constant_values=1)
-            offs = np.pad(offs, (0, A_pad - A))
-            flat_pages = np.pad(flat_pages, ((0, A_pad - A), (0, 0)))
-            ppt = np.pad(ppt, ((0, A_pad - A), (0, 0)))
         with set_mesh(self.mesh):
-            self.cache = self._prefill_paged_fn(A_pad, bucket, wp)(
-                self.params,
-                self.cache,
-                jnp.asarray(ids_np),
-                jnp.asarray(plens),
-                jnp.asarray(offs),
-                jnp.asarray(flat_pages.reshape(-1)),
-                jnp.asarray(ppt),
+            prefill, args = self.programs.prefill_paged_call(
+                ids_np, plens, offs, np.stack(page_rows), [mpages for _, _, mpages, _ in admitted]
             )
+            self.cache = prefill(self.params, self.cache, *args)
         rows = []
         sfx_tokens = 0
         hit_tokens = 0
@@ -2898,36 +1951,19 @@ class DecodeEngine:
         for task, slot, src_slot in pairs:
             ids = list(task.req.input_ids)
             plen = len(ids)
-            prim = self._slot_pages[src_slot]
             n_shared = (plen - 1) // psz  # pages decode will never write
-            if len(prim) <= n_shared:
-                # primary wasn't admitted (pool pressure backlogged it in
-                # _prefill_group) — this duplicate has nothing to alias;
-                # retry it as a fresh admission next round
+            pair = self.slots.alias(slot, src_slot, n_shared)
+            if pair is None:
+                # the primary wasn't admitted (pool pressure backlogged it in
+                # _prefill_group), so this duplicate has nothing to alias, or
+                # no private page can be had: retry it as a fresh admission
+                # next round
                 self._backlog.append(task)
                 continue
-            priv = self.pool.alloc(1)
-            while priv is None and self._reclaim_pages(1):
-                priv = self.pool.alloc(1)
-            if priv is None:
-                self._backlog.append(task)
-                continue
-            shared = prim[:n_shared]
-            self.pool.ref(shared)
-            pages = list(shared) + priv
-            copy_dst.append(priv[0])
-            copy_src.append(prim[n_shared])
+            copy_dst.append(pair[0])
+            copy_src.append(pair[1])
             slot_dst.append(slot)
             slot_src.append(src_slot)
-            self._slot_pages[slot] = pages
-            # the private page is a byte COPY of prim[n_shared], so it
-            # inherits that page's KV version, not the current one — under
-            # the "keep" ablation the two can differ across a commit
-            self._slot_page_versions[slot] = list(
-                self._slot_page_versions[src_slot][: n_shared + 1]
-            )
-            self._pt_host[slot] = 0
-            self._pt_host[slot, : len(pages)] = pages
             task.slot = slot
             task.prompt_len = plen
             self._slot_task[slot] = task
@@ -2937,17 +1973,9 @@ class DecodeEngine:
                 )
             )
         if copy_dst:
-            n = 1
-            while n < len(copy_dst):
-                n *= 2
-            pad = n - len(copy_dst)
-            # padding repeats the first pair: the same copy twice
-            pairs_np = [
-                jnp.asarray(np.asarray(x + x[:1] * pad, np.int32))
-                for x in (copy_dst, copy_src, slot_dst, slot_src)
-            ]
             with set_mesh(self.mesh):
-                self.cache = self._pagecopy_fn(n)(self.cache, *pairs_np)
+                copy, args = self.programs.pagecopy_call(copy_dst, copy_src, slot_dst, slot_src)
+                self.cache = copy(self.cache, *args)
             if self.model_cfg.has_recurrent_state:
                 self._obs.state_copies.inc(len(copy_dst))
         self.stats["prefix_shared"] = self.stats.get("prefix_shared", 0) + len(
@@ -2973,20 +2001,11 @@ class DecodeEngine:
             need = -(-plen // psz)
             # nothing decodes and nothing was admitted: nobody to wait for, so nothing to keep
             keep = (decoding + len(admitted) + 1) * per_slot if decoding or admitted else 0
-
-            def take():
-                return self.pool.alloc(need) if self.pool.available >= need + keep else None
-
-            pages = take()
-            while pages is None and self._reclaim_pages(need + keep):
-                pages = take()
+            pages = self.slots.take(need, keep=keep)
             if pages is None:
                 self._backlog.append(task)  # pool pressure: retry later
                 continue
-            self._slot_pages[slot] = pages
-            self._slot_page_versions[slot] = [self._version] * need
-            self._pt_host[slot] = 0
-            self._pt_host[slot, :need] = pages
+            self.slots.assign(slot, pages, [self._version] * need)
             row = np.zeros(npg, np.int32)  # 0 = trash page for padded rows
             row[:need] = pages
             page_rows.append(row)
@@ -2998,45 +2017,17 @@ class DecodeEngine:
                 task.timeline.mark(tl_mod.ADMITTED, slot=slot)
                 task.timeline.mark(tl_mod.PREFILL_START)
         A = len(admitted)
-        flat_pages = np.stack(page_rows)
         ids_np = np.zeros((A, bucket), np.int32)
         plens = np.zeros(A, np.int32)
         for j, (task, _slot) in enumerate(admitted):
             ids = list(task.req.input_ids)
             ids_np[j, : len(ids)] = ids
             plens[j] = len(ids)
-        # target slot per row; a padding row's is one past the last slot
         slots_np = np.asarray([slot for _task, slot in admitted], np.int32)
         img = self._image_embeds_for(admitted, ids_np, bucket)
-        # prefill group sizes are compiled variants; re-bucket A after any
-        # allocation drops by padding rows (trash-page scatter, plen 1)
-        sizes = [a for a in _PREFILL_SIZES if a >= A]
-        A_pad = min(sizes) if sizes else A
-        if A_pad > A:
-            ids_np = np.pad(ids_np, ((0, A_pad - A), (0, 0)))
-            ids_np[A:, 0] = 1
-            plens = np.pad(plens, (0, A_pad - A), constant_values=1)
-            flat_pages = np.pad(flat_pages, ((0, A_pad - A), (0, 0)))
-            slots_np = np.pad(
-                slots_np, (0, A_pad - A), constant_values=self.config.max_batch_size
-            )
-            if img is not None:
-                img = np.pad(img, ((0, A_pad - A), (0, 0), (0, 0)))
         with set_mesh(self.mesh):
-            args = [
-                self.params,
-                self.cache,
-                jnp.asarray(ids_np),
-                jnp.asarray(plens),
-                jnp.asarray(flat_pages.reshape(-1)),
-                jnp.asarray(slots_np),
-            ]
-            if img is None:
-                self.cache = self._prefill_fn(A_pad, bucket)(*args)
-            else:
-                self.cache = self._prefill_fn(A_pad, bucket, with_images=True)(
-                    *args, jnp.asarray(img)
-                )
+            prefill, args = self.programs.prefill_call(ids_np, plens, np.stack(page_rows), slots_np, img)
+            self.cache = prefill(self.params, self.cache, *args)
         rows = []
         for j, (task, slot) in enumerate(admitted):
             P_len = int(plens[j])
@@ -3056,35 +2047,25 @@ class DecodeEngine:
             )
         self.stats["prefills"] += A
         self.stats["prefill_batches"] += 1
-        prompt_tokens = int(plens[:A].sum())  # pad rows excluded
+        prompt_tokens = int(plens.sum())
         self.stats["prefill_tokens"] += prompt_tokens
         self._obs.prefills.inc(A)
         self._obs.prefill_tokens.inc(prompt_tokens)
         if self.model.prefill_attn_launch(self.model_cfg, bucket):
             self._obs.prefill_attn_launch_tokens.inc(prompt_tokens)
+        rebuilt = self.slots.readmitted(t.req.rid for t, _ in admitted)
         if self.model_cfg.has_recurrent_state:
-            rebuilt = [t.req.rid for t, _ in admitted if t.req.rid in self._state_dropped]
-            self._state_dropped.difference_update(rebuilt)
-            self._obs.state_prefills.inc(len(rebuilt))
-        if len(self._state_dropped) > 4096:  # rids that never came back
-            self._state_dropped.clear()
+            self._obs.state_prefills.inc(rebuilt)
         return rows
 
     def _apply_slot_updates(self, rows: list[np.ndarray]) -> None:
         """Scatter admission rows into the device state: one upload, one
-        jitted execute. Row count is bucketed (padding repeats row 0, an
-        idempotent scatter) to bound compile variants."""
+        jitted execute (``DecodePrograms.update_call``)."""
         if not rows:
             return
-        n = 1
-        while n < len(rows):
-            n *= 2
-        n = min(n, self.config.max_batch_size)
-        upd = np.stack(rows + [rows[0]] * (n - len(rows)))
         with set_mesh(self.mesh):
-            self._dev_state = self._update_fn(n)(
-                self._dev_state, jnp.asarray(upd)
-            )
+            apply, args = self.programs.update_call(rows)
+            self._dev_state = apply(self._dev_state, *args)
             for slot, counts in self._pending_count_restore:
                 self._dev_state["freq_counts"] = (
                     self._dev_state["freq_counts"].at[slot].set(
@@ -3093,64 +2074,26 @@ class DecodeEngine:
                 )
             self._pending_count_restore.clear()
 
-    def _publish_prefix(
-        self,
-        full_ids: list[int],
-        pages: list[int],
-        versions: list[int],
-        pos: int,
-    ) -> None:
-        """Publish a request's full KV pages into the radix tree. Only pages
-        strictly below ``pos`` are publishable (the page holding ``pos``
-        still takes decode writes — possibly from an in-flight chunk).
-        Under the default flush-on-commit policy, pages stamped with an
-        older policy version are stale and the publishable prefix truncates
-        at the first one (prefixes cannot have holes)."""
-        if self._radix is None:
-            return
-        psz = self.config.page_size
-        n_pub = min(pos // psz, len(pages), len(full_ids) // psz)
-        policy = getattr(
-            getattr(self.config, "prefix_cache", None), "across_updates", "flush"
-        )
-        if policy == "flush":
-            k = 0
-            while k < n_pub and versions[k] == self._version:
-                k += 1
-            n_pub = k
-        if n_pub <= 0:
-            return
-        adopted = self._radix.insert(
-            full_ids[: n_pub * psz], pages[:n_pub], versions[:n_pub]
-        )
-        if adopted:
-            self._obs_pc.inserted_pages.inc(adopted)
-
     def _finish(self, task: _Task, reason: str) -> None:
         if task.slot >= 0:
             self._slot_task[task.slot] = None
             self._state["active"][task.slot] = False
             if reason != StopReason.ABORT.value:
                 # completed requests publish their prompt+output pages into
-                # the radix tree BEFORE the pool.free below — the tree's
+                # the radix tree BEFORE the release below — the tree's
                 # own refs keep published pages alive. Aborts don't publish
                 # here: parked rids publish in _abort_all (and keep page
                 # ownership), preemptions exist to free memory.
-                self._publish_prefix(
+                self.slots.publish(
                     list(task.req.input_ids) + list(task.out_tokens),
-                    self._slot_pages[task.slot],
-                    self._slot_page_versions[task.slot],
+                    self.slots.pages(task.slot),
+                    self.slots.page_versions(task.slot),
                     int(self._state["pos"][task.slot]),
+                    self._version,
                 )
             # release KV pages (a parked rid already transferred ownership
-            # to its _Parked entry, leaving this list empty). Zeroing the
-            # block-table row makes any in-flight chunk's stale write for
-            # this slot land in the trash page / a freed page that the next
-            # owner's prefill fully rewrites before reading.
-            self.pool.free(self._slot_pages[task.slot])
-            self._slot_pages[task.slot] = []
-            self._slot_page_versions[task.slot] = []
-            self._pt_host[task.slot] = 0
+            # to its parked entry, leaving this list empty)
+            self.slots.release(task.slot)
         bd: dict[str, float] = {}
         if task.timeline is not None:
             # terminal stage event + catalogued histogram observation; the
@@ -3200,29 +2143,19 @@ class DecodeEngine:
                     # prompt+emitted after continue_generation); page
                     # ownership moves to the parked entry so _finish below
                     # doesn't free them
-                    p = _Parked(
-                        slot=slot,
-                        full_ids=list(task.req.input_ids) + list(task.out_tokens),
-                        pos=int(st["pos"][slot]),
-                        pages=self._slot_pages[slot],
-                        page_versions=list(self._slot_page_versions[slot]),
-                        n_emitted=len(task.out_tokens),
+                    # (and the prefix is published at park time)
+                    self.slots.park(
+                        rid,
+                        slot,
+                        list(task.req.input_ids) + list(task.out_tokens),
+                        int(st["pos"][slot]),
+                        len(task.out_tokens),
+                        self._version,
                     )
-                    self._parked[rid] = p
                     if task.timeline is not None:
                         task.timeline.mark(
                             tl_mod.PARK, n_emitted=len(task.out_tokens)
                         )
-                    # park-time publication: if this parking is later
-                    # evicted (or the rid resubmits with EXTENDED content —
-                    # a multi-turn episode's next turn), the radix tree
-                    # still serves the prior turns' pages
-                    self._publish_prefix(
-                        p.full_ids, p.pages, p.page_versions, p.pos
-                    )
-                    self._slot_pages[slot] = []
-                    self._slot_page_versions[slot] = []
-                    self._pt_host[slot] = 0
                 if st["active"][slot]:
                     deact.append(slot)
                 self._finish(task, StopReason.ABORT.value)
@@ -3230,11 +2163,7 @@ class DecodeEngine:
         # aborted slots there too, or the next dispatched chunk would keep
         # decoding into parked/released caches
         if deact and self.cache is not None:
-            rows = [
-                self._pack_row(slot, 0, int(st["pos"][slot]), False, 0)
-                for slot in deact
-            ]
-            self._apply_slot_updates(rows)
+            self._apply_slot_updates([self._deact_row(slot) for slot in deact])
 
     def _ensure_pages(self, ahead: int | None = None) -> None:
         """Allocation-ahead: every active slot gets pages covering
@@ -3256,102 +2185,41 @@ class DecodeEngine:
             if not st["active"][slot]:  # preempted by an earlier iteration
                 continue
             need = min(
-                self._maxp, -(-(int(st["pos"][slot]) + ahead + 1) // psz)
+                self.slots.maxp, -(-(int(st["pos"][slot]) + ahead + 1) // psz)
             )
-            pages = self._slot_pages[slot]
-            while len(pages) < need:
-                got = self.pool.alloc(need - len(pages))
-                if got is None and self._reclaim_pages(need - len(pages)):
-                    continue
-                if got is None:
-                    victim = self._preempt_victim()
-                    if victim is None or victim == slot:
-                        # cannot free enough. If the pages this slot already
-                        # holds cover further decoding EVEN IF the device is
-                        # a full in-flight chunk ahead of the host view,
-                        # clamp its remaining budget to that coverage via a
-                        # remaining-only scatter (a full _pack_row would
-                        # rewind device pos/ids by up to n_steps — the
-                        # device state is authoritative); it then finishes
-                        # by length inside a chunk. Otherwise abort it.
-                        covered = (
-                            len(pages) * psz
-                            - 1
-                            - (int(st["pos"][slot]) + n_steps)
-                        )
-                        if covered <= 0:
-                            deact_rows.append(self._preempt(int(slot)))
-                            break
-                        st["remaining"][slot] = min(
-                            int(st["remaining"][slot]), covered
-                        )
-                        clamp_rows.append((int(slot), covered))
+            # the pool and the ladder below it are the slot cache's; once
+            # they are exhausted, whom to preempt is decided here
+            while not self.slots.extend(slot, need, self._version):
+                victim = self._preempt_victim()
+                if victim is None or victim == slot:
+                    # cannot free enough. If the pages this slot already
+                    # holds cover further decoding EVEN IF the device is
+                    # a full in-flight chunk ahead of the host view,
+                    # clamp its remaining budget to that coverage via a
+                    # remaining-only scatter (a full _pack_row would
+                    # rewind device pos/ids by up to n_steps — the
+                    # device state is authoritative); it then finishes
+                    # by length inside a chunk. Otherwise abort it.
+                    covered = (
+                        len(self.slots.pages(slot)) * psz
+                        - 1
+                        - (int(st["pos"][slot]) + n_steps)
+                    )
+                    if covered <= 0:
+                        deact_rows.append(self._preempt(int(slot)))
                         break
-                    deact_rows.append(self._preempt(victim))
-                    continue
-                self._pt_host[slot, len(pages) : len(pages) + len(got)] = got
-                pages.extend(got)
-                self._slot_page_versions[slot].extend(
-                    [self._version] * len(got)
-                )
+                    st["remaining"][slot] = min(
+                        int(st["remaining"][slot]), covered
+                    )
+                    clamp_rows.append((int(slot), covered))
+                    break
+                deact_rows.append(self._preempt(victim))
         if deact_rows:
             self._apply_slot_updates(deact_rows)
         if clamp_rows:
-            self._apply_remaining_clamp(clamp_rows)
-
-    def _pagecopy_fn(self, n: int):
-        """Jitted copy of n (page, slot-state) pairs: the pages and the
-        recurrent state a GRPO group's siblings share with their primary."""
-        from areal_tpu.inference import paged_kv
-
-        key = ("pagecopy", n)
-        if key not in self._fn_cache:
-            self._fn_cache[key] = jax.jit(
-                paged_kv.copy_pages, donate_argnames=("cache",)
-            )
-            return FirstCall(self._fn_cache[key], key)
-        return self._fn_cache[key]
-
-    def _clamp_fn(self, n: int):
-        """Jitted remaining-only scatter: remaining := min(remaining, cap)
-        for n (slot, cap) rows, touching nothing else (pos/ids stay
-        device-authoritative)."""
-        key = ("clamp", n)
-        if key not in self._fn_cache:
-
-            def clamp(state, upd):
-                sl = upd[:, 0]
-                cap = upd[:, 1]
-                state = dict(state)
-                old_rem = state["remaining"][sl]
-                new_rem = jnp.minimum(old_rem, cap)
-                state["remaining"] = state["remaining"].at[sl].set(new_rem)
-                # keep the min_new_tokens gate invariant: "tokens still
-                # needed before stops unlock" (= remaining - min_rem) must
-                # survive the budget clamp, or stops would fire immediately
-                new_min = jnp.maximum(
-                    0, state["min_rem"][sl] - (old_rem - new_rem)
-                )
-                state["min_rem"] = state["min_rem"].at[sl].set(new_min)
-                state["active"] = (
-                    state["active"].at[sl].set(state["active"][sl] & (new_rem > 0))
-                )
-                return state
-
-            self._fn_cache[key] = jax.jit(clamp, donate_argnames=("state",))
-            return FirstCall(self._fn_cache[key], key)
-        return self._fn_cache[key]
-
-    def _apply_remaining_clamp(self, rows: list[tuple[int, int]]) -> None:
-        """Padded rows repeat row 0 (idempotent: min with the same cap)."""
-        n = 1
-        while n < len(rows):
-            n *= 2
-        upd = np.asarray(rows + [rows[0]] * (n - len(rows)), np.int32)
-        with set_mesh(self.mesh):
-            self._dev_state = self._clamp_fn(n)(
-                self._dev_state, jnp.asarray(upd)
-            )
+            with set_mesh(self.mesh):
+                clamp, args = self.programs.clamp_call(clamp_rows)
+                self._dev_state = clamp(self._dev_state, *args)
 
     def _preempt_victim(self) -> int | None:
         """Active slot with the most remaining generation budget (frees the
@@ -3369,13 +2237,12 @@ class DecodeEngine:
         """Abort one active slot to reclaim its pages (no parking — the
         point is to free memory). Returns the deactivation scatter row."""
         task = self._slot_task[slot]
-        st = self._state
-        row = self._pack_row(slot, 0, int(st["pos"][slot]), False, 0)
+        row = self._deact_row(slot)
         self.flight.record(
             "preempt", severity="warn", slot=slot, rid=task.req.rid
         )
         if task.req.rid:
-            self._state_dropped.add(task.req.rid)
+            self.slots.mark_dropped(task.req.rid)
         self._finish(task, StopReason.ABORT.value)
         self.stats["preempted"] = self.stats.get("preempted", 0) + 1
         return row
@@ -3387,8 +2254,6 @@ class DecodeEngine:
         the previous chunk — over a high-latency link the download RTT is
         fully hidden behind device compute."""
         cfg = self.config
-        T = cfg.max_seq_len
-        psz = cfg.page_size
         st = self._state
         active = st["active"]
         if not active.any():
@@ -3399,22 +2264,15 @@ class DecodeEngine:
             return None
         n_steps = cfg.decode_steps_per_call
         # host pos can be one in-flight chunk stale -> widen by 2 chunks
-        max_pos = int(st["pos"][active].max())
-        window = min(
-            T,
-            round_up_to_bucket(
-                max_pos + 1 + 2 * n_steps, cfg.attn_window_step
-            ),
-        )
-        wp = min(self._maxp, -(-window // psz))
+        wp = self.programs.window_pages(int(st["pos"][active].max()), 2 * n_steps)
         capped = bool(((st["top_k"] > 0) | (st["top_p"] < 1.0))[active].any())
         greedy_any = bool(st["greedy"][active].any())
         freq_any = self._freq_enabled and bool(
             (st["freq_pen"] != 0.0)[active].any()
         )
-        chunk = self._chunk_fn(n_steps, wp, capped, greedy_any, freq_any)
+        chunk = self.programs.chunk_fn(n_steps, wp, capped, greedy_any, freq_any)
         with set_mesh(self.mesh):
-            pt = jnp.asarray(self._pt_host[:, :wp])
+            pt = jnp.asarray(self.slots.page_table(wp))
             self.cache, self._dev_state, self._rng, packed = chunk(
                 self.params, self.cache, pt, self._dev_state, self._rng
             )
@@ -3432,58 +2290,24 @@ class DecodeEngine:
             "tasks": list(self._slot_task),
         }
 
-    def _suffix_kernel(self) -> bool:
-        """Whether suffix-prefill / tree-verify runs the Pallas kernel."""
-        if self._suffix_kernel_override is not None:
-            return self._suffix_kernel_override
-        return self._use_kernel
-
     def attention_impl(self) -> dict[str, str]:
-        """Which attention implementation each serving path uses (and which
-        writer the chunk program puts a decode step's KV rows with) — logged
-        once at start-up and read by chip_smoke.py. ``pallas`` is the
-        compiled TPU kernel, ``pallas-interpret`` the same body under the
-        Pallas interpreter (off-TPU, kernel forced on), ``xla`` the
-        gather + einsum path."""
-        tpu = jax.default_backend() == "tpu"
-        kern = "pallas" if tpu else "pallas-interpret"
-        return {
-            "decode": kern if self._use_kernel else "xla",
-            # a decode step's KV rows: no choice of its own, it goes with
-            # ``decode`` (ops/paged_kv_write.py beside the decode kernel, per-head
-            # scatters beside the gather path; prefill and verify always scatter)
-            "kv_write": kern if self._use_kernel else "xla",
-            # cold prefill is plain causal attention over the prompt bucket: XLA's, but for the latent-attention
-            # layers of a prompt of 1,024 tokens or more on a TPU (ops/latent_prefill_attention.py)
-            "prefill": kern if self.model is not None and self.model.prefill_attn_launch(self.model_cfg, 1024) else "xla",
-            "suffix_prefill": kern if self._suffix_kernel() else "xla",
-            "verify": (
-                "off"
-                if self._spec_cfg is None
-                else kern
-                if self._suffix_kernel()
-                else "xla"
-            ),
-        }
+        """Which attention implementation each serving path uses
+        (``DecodePrograms.attention_impl``; ``verify`` is ``off`` while no
+        speculative round runs)."""
+        return self.programs.attention_impl(speculative=self._spec_cfg is not None)
 
-    def set_suffix_kernel(self, on: bool | None) -> None:
-        """Force the paged suffix-attention kernel on/off (None restores
-        the platform default). Used by chip_smoke's kernel-vs-XLA check; takes
-        effect on the next compiled prefill/verify fn (the fn-cache key
-        carries the flag, so both variants can coexist warm)."""
-        self._suffix_kernel_override = on
+    @property
+    def _use_kernel(self) -> bool:
+        # forwarding name: benchmarks/chip/benchlib/cells/rollout_family_select.py
+        # reads it to call the model's forward the way the chunk program does
+        return self.programs.use_kernel
 
     def set_speculative(self, enabled: bool) -> None:
         """Runtime toggle for speculative decoding (on/off runs without an
         engine rebuild); applies from the next loop pass. Safe from any
         thread: the loop reads ``_spec_cfg`` once per pass and a spec pass
         always drains the pipelined chunk before its own round."""
-        from areal_tpu.api.config import SpeculativeConfig
-
-        spec = getattr(self.config, "speculative", None)
-        if spec is None:
-            spec = SpeculativeConfig()
-            self.config.speculative = spec
+        spec = self.config.speculative
         refused = self._model_limits().get("speculative")
         if enabled and refused:
             raise ValueError(refused)
@@ -3491,7 +2315,7 @@ class DecodeEngine:
         if enabled:
             from areal_tpu.inference import speculative as spec_mod
 
-            self._drafter = spec_mod.build_drafter(spec, radix=self._radix)
+            self._drafter = spec_mod.build_drafter(spec, radix=self.slots.radix)
             self._spec_cfg = spec
         else:
             self._spec_cfg = None
@@ -3511,8 +2335,6 @@ class DecodeEngine:
         st = self._state
         if not st["active"].any():
             return 0, False
-        psz = cfg.page_size
-        T = cfg.max_seq_len
         B = spec.max_nodes()
         K = B - 1
         # exact coverage for this round's writes (rows pos..pos+K) plus the
@@ -3541,17 +2363,13 @@ class DecodeEngine:
                     task.timeline.mark(
                         tl_mod.DRAFT, n_draft=nd, source=bundle.sources[slot]
                     )
-        max_pos = int(st["pos"][active].max())
-        window = min(
-            T, round_up_to_bucket(max_pos + 1 + B, cfg.attn_window_step)
-        )
-        wp = min(self._maxp, -(-window // psz))
+        wp = self.programs.window_pages(int(st["pos"][active].max()), B)
         capped = bool(((st["top_k"] > 0) | (st["top_p"] < 1.0))[active].any())
         greedy_any = bool(st["greedy"][active].any())
-        fn = self._spec_fn(B, wp, capped, greedy_any)
+        fn = self.programs.spec_fn(B, wp, capped, greedy_any)
         with self._kphase("dispatch"):
             with set_mesh(self.mesh):
-                pt = jnp.asarray(self._pt_host[:, :wp])
+                pt = jnp.asarray(self.slots.page_table(wp))
                 drafts = {
                     "tokens": jnp.asarray(bundle.tokens),
                     "parent_row": jnp.asarray(bundle.parent_row),
@@ -3620,16 +2438,7 @@ class DecodeEngine:
         for slot in np.nonzero(st["active"])[0]:
             if self._slot_task[slot] is None:
                 continue
-            need = -(-(int(st["pos"][slot]) + 1) // psz)
-            pages = self._slot_pages[slot]
-            if len(pages) <= need:
-                continue
-            tail = pages[need:]
-            self.pool.free(tail)
-            self._slot_pages[slot] = pages[:need]
-            del self._slot_page_versions[slot][need:]
-            self._pt_host[slot, need : need + len(tail)] = 0
-            freed += len(tail)
+            freed += self.slots.trim(slot, -(-(int(st["pos"][slot]) + 1) // psz))
         return freed
 
     def _drain(self, pending: dict | None) -> int:
@@ -3871,7 +2680,8 @@ class DecodeEngine:
             self._ktl = step_tl
             self._apply_weight_update()
             self._service_radix_flush()
-            self._service_radix_cap()
+            # converge the prefix tree onto a shrunk autopilot cap
+            self.slots.shrink_prefix_to_cap()
             if self._paused.is_set():
                 self._abandon_kstep()
                 self._drain(pending)

@@ -1,0 +1,1034 @@
+"""The decode engine's jitted programs: which exist, under which key, and
+which of them a start-up warms.
+
+One of the three owners of what serves a request (docs/serving.md):
+``decode_engine.DecodeEngine`` schedules passes and asks this module for
+callables, ``slot_cache.SlotCache`` keeps the pages they read and write.
+``DecodePrograms`` is built from the model's module and configuration, the
+shape fields of ``ServerConfig`` and the mesh, and from nothing else: it
+knows no request, no task, no page list and no prefix tree, and what a
+program is handed (weights, the paged cache, a page table, the slot state)
+is the caller's to own and to thread through the donated arguments.
+
+- **the programs**: ``prefill`` (a group of prompts into their pages),
+  its suffix-only form over cached prefix pages, ``chunk`` (n decode steps
+  for all slots), ``spec`` (one speculative verify-and-accept round),
+  ``apply`` / ``clamp`` (slot-state scatters), ``copy_pages`` (a GRPO
+  group's private pages) and the vision tower. Each is a plain ``jax.jit``
+  callable under its Python name, which is the name a device trace shows
+  (``jit_chunk``, ``jit_prefill``, ``jit_spec``: PERF.md section 3), cached
+  under a key whose first entry is its kind.
+- **the sampler** every program that emits tokens shares (``_sample_step``).
+- **kernel or gather**: decided once, from the platform, the mesh and the
+  shapes (``use_kernel``), with the suffix kernel's override for a
+  kernel-against-XLA comparison.
+- **the warm set**: ``warm_keys()`` lists the key of every program the
+  serving loop can reach, hot loop first, and ``lower()`` lowers one of
+  them from abstract arguments; ``DecodeEngine.precompile()`` compiles them
+  in that order inside its budget.
+"""
+
+from __future__ import annotations
+
+from typing import Callable
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from areal_tpu.api.config import ServerConfig
+from areal_tpu.inference import paged_kv
+from areal_tpu.utils import logging as alog
+from areal_tpu.utils.compile_cache import FirstCall
+from areal_tpu.utils.data import round_up_to_bucket
+
+# one component, one logger: the three owners log as the decode engine
+logger = alog.getLogger("decode_engine")
+
+MAX_STOP = 8  # stop-token-id slots per request (padded with -1)
+UPDATE_COLS = 11 + MAX_STOP  # columns of a packed slot-update row (``update_fn``)
+TOPK_CAP = 1024  # static candidate-set size for per-slot top-k/top-p
+PREFILL_SIZES = (8, 4, 2, 1)  # batched-prefill group sizes (compile variants)
+# bytes of residual stream (rows x bucket x hidden) a prefill program may hold:
+# every group size at 8 x 1,024 tokens of hidden 4,096; ONE row a program
+# where a prompt alone is past it (4,096 tokens of hidden 6,144 are 50 MB),
+# since a long prompt amortises the weights by itself and a second row would
+# only add its temporaries
+_PREFILL_STREAM_BYTES = 64 << 20
+
+
+def slot_state(n_slots: int) -> dict[str, np.ndarray]:
+    """The per-slot decode state every program reads and hands back, all
+    slots idle: the host mirror's first value, and (put on the device) the
+    ``state`` argument of ``chunk``, ``spec``, ``apply`` and ``clamp``."""
+    S = n_slots
+    return {
+        "ids": np.zeros(S, np.int32),
+        "pos": np.zeros(S, np.int32),
+        "active": np.zeros(S, bool),
+        "remaining": np.zeros(S, np.int32),
+        "temp": np.ones(S, np.float32),
+        "greedy": np.zeros(S, bool),
+        "top_k": np.full(S, -1, np.int32),
+        "top_p": np.ones(S, np.float32),
+        # stop tokens are honored only once remaining - 1 <= min_rem
+        # (the -1 accounts for the token being emitted), i.e. after
+        # gconfig.min_new_tokens tokens have been generated
+        "min_rem": np.zeros(S, np.int32),
+        "freq_pen": np.zeros(S, np.float32),
+        "stop_ids": np.full((S, MAX_STOP), -1, np.int32),
+    }
+
+
+def pack_row(
+    state: dict[str, np.ndarray],
+    slot: int,
+    last_id: int,
+    pos: int,
+    active: bool,
+    remaining: int,
+    top_k: int = -1,
+    greedy: bool = False,
+    temp: float = 1.0,
+    top_p: float = 1.0,
+    stops: list[int] | None = None,
+    min_rem: int | None = None,
+    freq_pen: float = 0.0,
+) -> np.ndarray:
+    """The ONE place that knows the packed scatter-row column order (it
+    matches ``apply`` of ``update_fn``): update the host mirror ``state`` and
+    build the fp32 row. ``min_rem``: stops fire only once remaining-1 <=
+    min_rem (the min_new_tokens gate); default = remaining, i.e. always
+    allowed."""
+    stops = (list(stops or []) + [-1] * MAX_STOP)[:MAX_STOP]
+    if min_rem is None:
+        min_rem = remaining
+    st = state
+    st["ids"][slot] = last_id
+    st["pos"][slot] = pos
+    st["active"][slot] = active
+    st["remaining"][slot] = remaining
+    st["temp"][slot] = temp
+    st["greedy"][slot] = greedy
+    st["top_k"][slot] = top_k
+    st["top_p"][slot] = top_p
+    st["min_rem"][slot] = min_rem
+    st["freq_pen"][slot] = freq_pen
+    st["stop_ids"][slot] = stops
+    return np.asarray(
+        [slot, last_id, pos, active, remaining, top_k, greedy, temp, top_p, min_rem, freq_pen, *stops],
+        np.float32,
+    )
+
+
+def _pow2(n: int) -> int:
+    """The smallest power of two that is at least ``n``."""
+    p = 1
+    while p < n:
+        p *= 2
+    return p
+
+
+def _sample_blocks(V: int) -> int:
+    """Block count for the hierarchical sampler: the largest divisor of V
+    that is <= 512. Qwen vocabs are 2^7-divisible (151936 = 128*1187);
+    tiny test vocabs divide exactly."""
+    for nb in range(min(V, 512), 0, -1):
+        if V % nb == 0:
+            return nb
+    return 1
+
+
+def _inverse_cdf_sample(scaled, rng):
+    """Exact categorical sampling with ONE uniform per row, in ~one HBM pass.
+
+    ``jax.random.categorical`` materializes gumbel noise for every vocab
+    entry — [S, 152k] of threefry bits per decode step, measured ~9 ms of
+    an 11 ms step at S=128 on v5e. The round-3 flat inverse-CDF replaced
+    that with ``cumsum`` over [S, V] fp32 — which XLA lowers to ~log2(V)
+    full-array passes (~2.5 GB of HBM traffic at S=128), nearly as slow.
+
+    This version factorizes the CDF hierarchically:
+      1. block_lse[S, NB] — one read pass over the logits, reshaped
+      2. tiny cumsum over NB block probabilities picks the block
+      3. the residual uniform picks the token inside the gathered
+         [S, V/NB] block (tiny)
+    The draw is exact (CDF decomposition); at both levels the uniform is
+    scaled by the realized total so fp32 cumsum undershoot spreads
+    proportionally instead of piling on the last index. Returns
+    (ids [S], logp [S], lse [S, 1]) with logp the exact log-softmax of the
+    drawn token."""
+    S, V = scaled.shape
+    NB = _sample_blocks(V)
+    inner = V // NB
+    blocks = scaled.reshape(S, NB, inner)
+    block_lse = jax.scipy.special.logsumexp(blocks, axis=-1)  # [S, NB]
+    lse = jax.scipy.special.logsumexp(block_lse, axis=-1, keepdims=True)
+    bprob = jnp.exp(block_lse - lse)  # [S, NB]
+    bcum = jnp.cumsum(bprob, axis=-1)
+    u = jax.random.uniform(rng, (S, 1), jnp.float32)
+    ut = u * bcum[:, -1:]
+    b = jnp.sum((bcum <= ut).astype(jnp.int32), axis=-1)
+    b = jnp.minimum(b, NB - 1)  # OOB guard
+    # residual mass inside the chosen block, renormalized to [0, 1)
+    cum_excl = jnp.where(
+        b > 0, jnp.take_along_axis(bcum, jnp.maximum(b - 1, 0)[:, None], axis=-1)[:, 0], 0.0
+    )
+    pb = jnp.take_along_axis(bprob, b[:, None], axis=-1)[:, 0]
+    u_in = (ut[:, 0] - cum_excl) / jnp.maximum(pb, 1e-30)
+    blk = jnp.take_along_axis(blocks, b[:, None, None], axis=1)[:, 0]  # [S, inner]
+    blk_lse = jnp.take_along_axis(block_lse, b[:, None], axis=-1)  # [S, 1]
+    icum = jnp.cumsum(jnp.exp(blk - blk_lse), axis=-1)  # [S, inner]
+    idx = jnp.sum((icum <= u_in[:, None] * icum[:, -1:]).astype(jnp.int32), axis=-1)
+    idx = jnp.minimum(idx, inner - 1)
+    ids = b * inner + idx
+    logp = (jnp.take_along_axis(scaled, ids[:, None], axis=-1) - lse)[:, 0]
+    return ids, logp, lse
+
+
+def _sample_step(logits, rng, state, capped: bool, greedy_any: bool = True):
+    """One sampling step. logits [S, V] fp32; all sampling knobs are
+    *per-slot arrays* in ``state`` (temp, greedy, top_k, top_p) so one
+    request's config can never leak into another slot (round-1 correctness
+    bug: engine-global top_k/top_p compiled into the chunk).
+
+    ``capped`` and ``greedy_any`` are static flags: when no active slot
+    filters (resp. decodes greedily), the top-k candidate machinery (resp.
+    the full-vocab argmax pass — a [S, V] fp32 HBM read per step) is
+    compiled out entirely."""
+    V = logits.shape[-1]
+    temp, greedy = state["temp"], state["greedy"]
+    safe_t = jnp.maximum(temp, 1e-6)[:, None]
+    scaled = logits / safe_t
+    rng_full, rng_cap = jax.random.split(rng)
+    sampled, samp_logp, lse = _inverse_cdf_sample(scaled, rng_full)
+    use_cap = None
+    if capped:
+        K = min(V, TOPK_CAP)
+        top_vals, top_idx = jax.lax.top_k(scaled, K)  # sorted desc, [S, K]
+        eff_k = jnp.where(state["top_k"] > 0, state["top_k"], V)
+        mask_k = jnp.arange(K)[None, :] < eff_k[:, None]
+        probs = jax.nn.softmax(top_vals, axis=-1)
+        cum_excl = jnp.cumsum(probs, axis=-1) - probs
+        mask_p = cum_excl < state["top_p"][:, None]
+        keep = (mask_k & mask_p).at[:, 0].set(True)
+        cap_logits = jnp.where(keep, top_vals, -1e30)
+        cap_pos = jax.random.categorical(rng_cap, cap_logits, axis=-1)
+        cap_ids = jnp.take_along_axis(top_idx, cap_pos[:, None], axis=-1)[:, 0]
+        cap_logp = jnp.take_along_axis(
+            jax.nn.log_softmax(cap_logits, axis=-1), cap_pos[:, None], axis=-1
+        )[:, 0]
+        use_cap = (state["top_k"] > 0) | (state["top_p"] < 1.0)
+        sampled = jnp.where(use_cap, cap_ids, sampled)
+    if greedy_any:
+        arg = jnp.argmax(logits, axis=-1)
+        next_ids = jnp.where(greedy, arg, sampled).astype(jnp.int32)
+        greedy_logp = (
+            jnp.take_along_axis(scaled, arg[:, None], axis=-1) - lse
+        )[:, 0]
+        logp = jnp.where(greedy, greedy_logp, samp_logp)
+    else:
+        next_ids = sampled.astype(jnp.int32)
+        logp = samp_logp
+    if capped:
+        logp = jnp.where(use_cap & ~greedy, cap_logp, logp)
+    return next_ids, logp
+
+
+class DecodePrograms:
+    """The jitted programs of one model replica and the cache they live in."""
+
+    def __init__(self, model, model_cfg, config: ServerConfig, mesh):
+        self.model = model  # the module of model_cfg's family (models.family_of)
+        self.model_cfg = model_cfg
+        # read for its shape fields only: slots, context, page size, steps a
+        # call, the window step, and whether the penalised chunk variants exist
+        self.config = config
+        self.mesh = mesh
+        self._fn_cache: dict[tuple, Callable] = {}
+        psz = config.page_size
+        self._maxp = -(-config.max_seq_len // psz)  # pages per sequence (ceil)
+        # the Pallas paged kernels run single-device; under TP the engine
+        # takes the gather+einsum path, which GSPMD shards over the KV-head
+        # axis like the dense engine did. Kernel or gather is decided HERE,
+        # once, from the platform, the mesh and the shapes: on a TPU the
+        # kernels are compiled, and one the chip's compiler refuses is an
+        # error — nothing catches it, falls back to interpret mode or
+        # swaps in the XLA path.
+        # (imported here, inside ``areal.setup.engine_init``: the kernels'
+        # module brings Pallas in, a second of start-up that span has always held)
+        from areal_tpu.ops.paged_attention_q8 import paged_kernel_ok
+
+        kv_quant = paged_kv.page_quant(config.kv_quantization)
+        one_tpu = (
+            jax.default_backend() == "tpu"
+            and int(np.prod(list(mesh.shape.values()))) == 1
+        )
+        shapes_ok = paged_kernel_ok(model_cfg.kv_head_dim, psz, bool(kv_quant))
+        if one_tpu and not shapes_ok:
+            logger.warning(
+                f"head_dim {model_cfg.kv_head_dim} / page_size {psz} / kv "
+                f"{kv_quant or 'bf16'} is outside the Pallas paged kernels' "
+                "tiling (ops/paged_attention_q8.py paged_kernel_ok): "
+                "decode, suffix prefill and verify take the gather path"
+            )
+        self.use_kernel = one_tpu and shapes_ok
+        # suffix-prefill / tree-verify Pallas kernel
+        # (ops/paged_suffix_attention.py): same condition, overridable at
+        # runtime for kernel-vs-XLA A/B (off-TPU the kernel runs in
+        # interpret mode)
+        self._suffix_kernel_override: bool | None = None
+
+    def keys(self) -> set[tuple]:
+        """The keys of the programs built so far."""
+        return set(self._fn_cache)
+
+    # prompt buckets above this warm only if on the round_up_to_bucket
+    # 2^k/3*2^k series — the exact-reachable set at T=32K would otherwise be
+    # every 256-multiple (512 prefill programs; a ~10x startup blowup).
+    # Buckets outside the warmed set still work; they compile on first hit.
+    _WARM_DENSE_CAP = 4096
+
+    def prefill_sizes(self, bucket: int) -> tuple[int, ...]:
+        """The group sizes a prefill program of this bucket comes in: those of
+        ``PREFILL_SIZES`` whose residual stream stays inside
+        ``_PREFILL_STREAM_BYTES``, at least (1,)."""
+        row = bucket * self.model_cfg.hidden_size * jnp.dtype(self.model_cfg.jax_dtype).itemsize
+        return tuple(a for a in PREFILL_SIZES if a * row <= _PREFILL_STREAM_BYTES) or (1,)
+
+    # -- the shape rules: each is what the caller asks at run time AND what
+    # the reachable set below it enumerates, so the two cannot drift apart
+    def prompt_bucket(self, n_tokens: int) -> int:
+        """The length a prefill program is compiled for, for a prompt (or a
+        suffix behind a cached prefix) of ``n_tokens``."""
+        return min(self.config.max_seq_len, round_up_to_bucket(n_tokens, 256))
+
+    def window_pages(self, max_pos: int, ahead: int) -> int:
+        """Page-table columns of the attention window that covers position
+        ``max_pos`` and ``ahead`` more writes, bucketed by
+        ``attn_window_step`` (a chunk: two chunks of steps, since the host's
+        positions can be one in-flight chunk stale; a speculative round: its
+        nodes)."""
+        cfg = self.config
+        window = min(
+            cfg.max_seq_len,
+            round_up_to_bucket(max_pos + 1 + ahead, cfg.attn_window_step),
+        )
+        return min(self._maxp, -(-window // cfg.page_size))
+
+    def scatter_rows(self, n: int) -> int:
+        """Rows of the slot-state scatter that takes ``n`` updates."""
+        return min(_pow2(n), self.config.max_batch_size)
+
+    def reachable_prompt_buckets(self) -> list[int]:
+        """Values ``prompt_bucket`` can produce for the admission path
+        (round-2 warmed linear multiples instead — compiling unreachable
+        programs while missing the 3*2^k series and the T-cap; ADVICE r02
+        #1), dense up to ``_WARM_DENSE_CAP`` then the sparse series tail
+        only."""
+        T = self.config.max_seq_len
+        exact = {
+            self.prompt_bucket(n)
+            for n in range(1, max(2, min(T - 1, self._WARM_DENSE_CAP)))
+        }
+        b = self._WARM_DENSE_CAP
+        while b < T:
+            exact.add(self.prompt_bucket(b + 1))
+            b *= 2
+        exact.add(self.prompt_bucket(max(1, T - 2)))
+        return sorted(exact)
+
+    def reachable_chunk_wps(self) -> list[int]:
+        """Window page counts a decode chunk can ask for — exact up to
+        ``_WARM_DENSE_CAP`` rows, then the sparse bucket-series tail."""
+        T = self.config.max_seq_len
+        ahead = 2 * self.config.decode_steps_per_call
+        wps = {self.window_pages(p, ahead) for p in range(min(T, self._WARM_DENSE_CAP))}
+        b = self._WARM_DENSE_CAP
+        while b < T:
+            wps.add(self.window_pages(b, ahead))
+            b *= 2
+        wps.add(self.window_pages(T - 1, ahead))
+        return sorted(wps)
+
+    def reachable_scatter_sizes(self) -> list[int]:
+        """Exact set of row counts ``scatter_rows`` produces: powers of two
+        up to S, plus S itself when S is not a power of two."""
+        S = self.config.max_batch_size
+        return sorted({self.scatter_rows(n) for n in range(1, S + 1)})
+
+    def prefill_fn(self, n_prompts: int, bucket: int, with_images: bool = False):
+        """Batched prefill: A prompts (padded to ``bucket``) in one forward,
+        their KV scattered into the A rows' pages and, for a model with
+        recurrent layers, each row's post-prompt state into its slot (what
+        exactly: ``prefill_into_cache`` of the model's family). Amortises
+        the full-parameter read across admits.
+        ``with_images`` adds a positioned [A, bucket, D] vision-embed input
+        (VLM serving; embeds computed by _image_embeds_for at admission)."""
+        key = ("prefill", n_prompts, bucket, with_images)
+        if key not in self._fn_cache:
+            mcfg = self.model_cfg
+            psz = self.config.page_size
+            model = self.model
+
+            def prefill(params, cache, ids, plens, flat_pages, slots, img=None):
+                # ids [A, bucket], plens [A], flat_pages [A * bucket/psz],
+                # slots [A] (a padding row: one past the last slot)
+                return model.prefill_into_cache(
+                    params, mcfg, cache, ids, plens, flat_pages, slots,
+                    page_size=psz, image_embeds=img,
+                )
+
+            self._fn_cache[key] = jax.jit(prefill, donate_argnames=("cache",))
+            return FirstCall(self._fn_cache[key], key)
+        return self._fn_cache[key]
+
+    def prefill_paged_fn(self, n_prompts: int, bucket: int, wp: int):
+        """Suffix-only prefill over a radix-cached prefix: A suffixes
+        (padded to ``bucket``) in one forward, queries attending over each
+        row's cached prefix pages (``wp`` page-table columns) plus the
+        causal suffix; suffix KV scatters into fresh pages. The prefix
+        pages are read-only (aliased, possibly shared across requests)."""
+        use_kernel = self.suffix_kernel()
+        key = ("prefill_sfx", n_prompts, bucket, wp, use_kernel)
+        if key not in self._fn_cache:
+            mcfg = self.model_cfg
+            psz = self.config.page_size
+            def prefill(params, cache, ids, plens, offs, flat_pages, ppt):
+                # ids [A, bucket] suffix tokens; plens [A] suffix lengths;
+                # offs [A] absolute start positions — page-aligned, so they
+                # double as the cached-prefix lengths; ppt [A, wp] prefix
+                # page table
+                positions = offs[:, None] + jnp.arange(bucket, dtype=jnp.int32)[None]
+                seg = (
+                    jnp.arange(bucket, dtype=jnp.int32)[None] < plens[:, None]
+                ).astype(jnp.int32)
+                _, ks, vs = self.model.forward_prefill_paged(
+                    params, mcfg, ids, positions, seg, cache, ppt, offs,
+                    use_kernel=use_kernel,
+                )
+                with jax.named_scope("kv_write"):
+                    return paged_kv.scatter_prefill(cache, ks, vs, flat_pages, psz)
+
+            self._fn_cache[key] = jax.jit(prefill, donate_argnames=("cache",))
+            return FirstCall(self._fn_cache[key], key)
+        return self._fn_cache[key]
+
+    def chunk_fn(
+        self,
+        n_steps: int,
+        wp: int,
+        capped: bool,
+        greedy_any: bool = True,
+        freq_any: bool = False,
+    ):
+        """n_steps of decode for all slots in one jitted call, attending over
+        each slot's first ``wp`` KV pages (the window, bucketed in pages).
+
+        Returns (cache, state, rng, packed) where ``packed`` is ONE int32
+        array [2*n_steps + 3, S] — token rows, logprob-bit rows (fp32
+        bitcast), then emit_count / final-active / final-pos rows — so the
+        host pays a single device->host transfer per chunk. A model with
+        sparse experts or delta-rule layers appends its counts of the chunk
+        (``model_cfg.count_shapes``, flat, in whole rows of S). Emission is
+        monotone within a chunk (a stopped slot never re-activates; admits
+        happen between chunks), so per-slot counts fully describe the
+        emit mask."""
+        key = ("chunk", n_steps, wp, capped, greedy_any, freq_any)
+        if key not in self._fn_cache:
+            mcfg = self.model_cfg
+            T = self.config.max_seq_len
+            psz = self.config.page_size
+            use_kernel = self.use_kernel
+            model = self.model
+
+            counts_of = dict(mcfg.count_shapes)
+
+            def chunk(params, cache, page_table, state, rng):
+                # the model's counts of this chunk's steps: zeroed here, added
+                # to by the model's forward for the active slots only, and
+                # handed back in ``packed`` (they are no part of the cache)
+                cache = {**cache, **{k: jnp.zeros(shp, jnp.int32) for k, shp in counts_of.items()}}
+
+                def step(carry, _):
+                    ids, pos, active, remaining, counts, cache, rng = carry
+                    hidden, cache = model.forward_decode_paged(
+                        params,
+                        mcfg,
+                        ids,
+                        pos,
+                        cache,
+                        page_table,
+                        page_size=psz,
+                        active=active,
+                        use_kernel=use_kernel,
+                    )
+                    with jax.named_scope("lm_head"):
+                        logits = model.compute_logits(params, mcfg, hidden)
+                    with jax.named_scope("sampler"):
+                        if freq_any:
+                            # OpenAI-style frequency penalty on raw logits,
+                            # proportional to this slot's generated-token counts
+                            logits = logits - (
+                                state["freq_pen"][:, None]
+                                * counts.astype(jnp.float32)
+                            )
+                        rng, sub = jax.random.split(rng)
+                        next_ids, logp = _sample_step(
+                            logits, sub, state, capped, greedy_any
+                        )
+                    if freq_any:
+                        # saturating (uint16 .add would wrap at 65535 —
+                        # reachable at max_seq_len > 64k, and negative
+                        # penalties actively drive repeats toward it)
+                        sl = jnp.arange(counts.shape[0])
+                        cur = counts[sl, next_ids].astype(jnp.int32)
+                        counts = counts.at[sl, next_ids].set(
+                            jnp.minimum(
+                                cur + active.astype(jnp.int32), 65535
+                            ).astype(counts.dtype)
+                        )
+                    emitted = active
+                    hit_stop = jnp.any(
+                        next_ids[:, None] == state["stop_ids"], axis=-1
+                    ) & (remaining - 1 <= state["min_rem"])
+                    new_pos = pos + 1
+                    remaining = remaining - active.astype(jnp.int32)
+                    still = (
+                        active
+                        & ~hit_stop
+                        & (remaining > 0)
+                        & (new_pos < T - 1)
+                    )
+                    ids = jnp.where(active, next_ids, ids)
+                    pos = jnp.where(active, new_pos, pos)
+                    return (ids, pos, still, remaining, counts, cache, rng), (
+                        next_ids,
+                        logp,
+                        emitted,
+                    )
+
+                carry = (
+                    state["ids"],
+                    state["pos"],
+                    state["active"],
+                    state["remaining"],
+                    state["freq_counts"] if freq_any else jnp.zeros((), jnp.uint16),
+                    cache,
+                    rng,
+                )
+                (ids, pos, active, remaining, counts, cache, rng), (
+                    toks,
+                    logps,
+                    emit,
+                ) = jax.lax.scan(step, carry, None, length=n_steps)
+                out_state = dict(state)
+                out_state.update(ids=ids, pos=pos, active=active, remaining=remaining)
+                if freq_any:
+                    out_state["freq_counts"] = counts
+                cache = dict(cache)
+                extra = [cache.pop(k).reshape(-1) for k in counts_of]
+                if extra:  # after the slots' rows, flat, padded to whole rows
+                    flat = jnp.concatenate(extra)
+                    S = toks.shape[1]
+                    extra = [jnp.pad(flat, (0, -flat.size % S)).reshape(-1, S)]
+                packed = jnp.concatenate(
+                    [
+                        toks.astype(jnp.int32),  # [n_steps, S]
+                        jax.lax.bitcast_convert_type(
+                            logps.astype(jnp.float32), jnp.int32
+                        ),  # [n_steps, S]
+                        emit.sum(0, dtype=jnp.int32)[None],  # emit_count [1, S]
+                        active.astype(jnp.int32)[None],  # [1, S]
+                        pos.astype(jnp.int32)[None],  # [1, S]
+                        *extra,
+                    ],
+                    axis=0,
+                )
+                return cache, out_state, rng, packed
+
+            self._fn_cache[key] = jax.jit(chunk, donate_argnames=("cache", "state"))
+            return FirstCall(self._fn_cache[key], key)
+        return self._fn_cache[key]
+
+    def spec_fn(self, B: int, wp: int, capped: bool, greedy_any: bool = True):
+        """One speculative verify+accept round in a single jitted call.
+
+        Row 0 per slot is the pending token, rows 1..B-1 the draft tree
+        nodes. ``forward_verify_paged`` scores all B nodes at once; an
+        unrolled accept walk then re-runs the TARGET sampler position by
+        position and follows the tree edge whose draft token equals the
+        sampled target — so every emitted token is exactly what the
+        sequential path would have produced (greedy byte-identity; sampled
+        slots draw from the true per-position conditional, the token-match
+        form of speculative rejection sampling). KV is scattered
+        row-granularly: only visited (accepted-path) rows land in real
+        pages, everything else routes to trash page 0, so rejected drafts
+        never exist in committed KV and radix publication stays safe.
+
+        ``packed`` has the exact chunk_fn layout with n_steps = B, so the
+        normal ``_drain`` bookkeeping credits the round unchanged."""
+        use_kernel = self.suffix_kernel()
+        key = ("spec", B, wp, capped, greedy_any, use_kernel)
+        if key not in self._fn_cache:
+            mcfg = self.model_cfg
+            T = self.config.max_seq_len
+            psz = self.config.page_size
+            K = B - 1
+
+            def spec(params, cache, page_table, state, rng, drafts):
+                d_tokens = drafts["tokens"]  # [S, K]
+                d_parent = drafts["parent_row"]  # [S, K] row of parent
+                d_depth = drafts["depth"]  # [S, K]
+                d_mask = drafts["mask"]  # [S, B, B]
+                d_count = drafts["n_draft"]  # [S]
+                S = state["ids"].shape[0]
+                pos0 = state["pos"]
+                ids_nodes = jnp.concatenate(
+                    [state["ids"][:, None], d_tokens], axis=1
+                )  # [S, B]
+                depth_full = jnp.concatenate(
+                    [jnp.zeros((S, 1), jnp.int32), d_depth], axis=1
+                )
+                # clamp keeps gather/scatter indices in range for inactive
+                # slots with stale pos; their page-table rows are zeroed so
+                # everything lands in trash anyway
+                positions = jnp.minimum(pos0[:, None] + depth_full, T - 1)
+                hidden, ks, vs = self.model.forward_verify_paged(
+                    params,
+                    mcfg,
+                    ids_nodes,
+                    positions,
+                    d_mask,
+                    cache,
+                    page_table,
+                    pos0,
+                    use_kernel=use_kernel,
+                )
+                with jax.named_scope("lm_head"):
+                    logits = self.model.compute_logits(params, mcfg, hidden)  # [S,B,V]
+                row_valid = (
+                    jnp.arange(1, B, dtype=jnp.int32)[None, :]
+                    <= d_count[:, None]
+                )  # [S, K]
+                cur = jnp.zeros((S,), jnp.int32)  # row the walk is at
+                cont = state["active"]  # still emitting THIS round
+                alive = state["active"]  # slot lives past the round
+                pos_c = pos0
+                rem_c = state["remaining"]
+                ids_c = state["ids"]
+                # rows whose KV becomes committed context = rows the walk
+                # visits (root + accepted path); matches the sequential
+                # path's write set exactly
+                row_ok = jnp.zeros((S, B), bool).at[:, 0].set(True)
+                toks_rows, logp_rows, emit_rows = [], [], []
+                for j in range(B):
+                    lg = jnp.take_along_axis(
+                        logits, cur[:, None, None], axis=1
+                    )[:, 0]  # [S, V]
+                    with jax.named_scope("sampler"):
+                        rng, sub = jax.random.split(rng)
+                        t_j, logp_j = _sample_step(
+                            lg, sub, state, capped, greedy_any
+                        )
+                    emit_rows.append(cont)
+                    toks_rows.append(t_j)
+                    logp_rows.append(logp_j)
+                    # exact chunk_fn stop/budget semantics per emitted step
+                    hit_stop = jnp.any(
+                        t_j[:, None] == state["stop_ids"], axis=-1
+                    ) & (rem_c - 1 <= state["min_rem"])
+                    new_pos = pos_c + cont.astype(jnp.int32)
+                    rem_c = rem_c - cont.astype(jnp.int32)
+                    step_alive = (
+                        cont & ~hit_stop & (rem_c > 0) & (new_pos < T - 1)
+                    )
+                    alive = jnp.where(cont, step_alive, alive)
+                    ids_c = jnp.where(cont, t_j, ids_c)
+                    pos_c = new_pos
+                    if j < K:
+                        # follow the tree edge matching the target token
+                        match = (
+                            (d_parent == cur[:, None])
+                            & (d_tokens == t_j[:, None])
+                            & row_valid
+                        )  # [S, K] over rows 1..K
+                        has = match.any(axis=1)
+                        child = jnp.argmax(match, axis=1).astype(jnp.int32) + 1
+                        cont = step_alive & has
+                        cur = jnp.where(cont, child, cur)
+                        row_ok = row_ok | (
+                            (jnp.arange(B)[None, :] == child[:, None])
+                            & cont[:, None]
+                        )
+                out_state = dict(state)
+                out_state.update(
+                    ids=ids_c, pos=pos_c, active=alive, remaining=rem_c
+                )
+                # selective KV commit: visited rows -> their real page rows,
+                # everything else -> trash page 0
+                page_idx = jnp.clip(positions // psz, 0, wp - 1)
+                pages = jnp.take_along_axis(page_table, page_idx, axis=1)
+                pages = jnp.where(row_ok, pages, 0)
+                rows = positions % psz
+                L = ks.shape[0]
+                KH, hd = ks.shape[3], ks.shape[4]
+                with jax.named_scope("kv_write"):
+                    cache = paged_kv.scatter_token_rows(
+                        cache,
+                        ks.reshape(L, S * B, KH, hd),
+                        vs.reshape(L, S * B, KH, hd),
+                        pages.reshape(-1),
+                        rows.reshape(-1),
+                    )
+                packed = jnp.concatenate(
+                    [
+                        jnp.stack(toks_rows).astype(jnp.int32),  # [B, S]
+                        jax.lax.bitcast_convert_type(
+                            jnp.stack(logp_rows).astype(jnp.float32),
+                            jnp.int32,
+                        ),  # [B, S]
+                        jnp.stack(emit_rows).sum(0, dtype=jnp.int32)[None],
+                        alive.astype(jnp.int32)[None],
+                        pos_c.astype(jnp.int32)[None],
+                    ],
+                    axis=0,
+                )
+                return cache, out_state, rng, packed
+
+            self._fn_cache[key] = jax.jit(spec, donate_argnames=("cache", "state"))
+            return FirstCall(self._fn_cache[key], key)
+        return self._fn_cache[key]
+
+    def update_fn(self, n: int):
+        """Jitted slot-state scatter: one packed fp32 [n, 11+MAX_STOP] upload
+        (columns: slot, ids, pos, active, remaining, top_k, greedy, temp,
+        top_p, min_rem, freq_pen, stop_ids...) applied on device. All values fit fp32 exactly
+        (token ids < 2^24). Padded rows repeat row 0 (idempotent scatter)."""
+        key = ("upd", n)
+        if key not in self._fn_cache:
+
+            def apply(state, upd):
+                sl = upd[:, 0].astype(jnp.int32)
+                state = dict(state)
+                state["ids"] = state["ids"].at[sl].set(upd[:, 1].astype(jnp.int32))
+                state["pos"] = state["pos"].at[sl].set(upd[:, 2].astype(jnp.int32))
+                state["active"] = state["active"].at[sl].set(upd[:, 3] > 0)
+                state["remaining"] = (
+                    state["remaining"].at[sl].set(upd[:, 4].astype(jnp.int32))
+                )
+                state["top_k"] = state["top_k"].at[sl].set(upd[:, 5].astype(jnp.int32))
+                state["greedy"] = state["greedy"].at[sl].set(upd[:, 6] > 0)
+                state["temp"] = state["temp"].at[sl].set(upd[:, 7])
+                state["top_p"] = state["top_p"].at[sl].set(upd[:, 8])
+                state["min_rem"] = (
+                    state["min_rem"].at[sl].set(upd[:, 9].astype(jnp.int32))
+                )
+                state["freq_pen"] = state["freq_pen"].at[sl].set(upd[:, 10])
+                if "freq_counts" in state:
+                    # (re)admission resets the slot's repeat counts
+                    state["freq_counts"] = state["freq_counts"].at[sl].set(0)
+                state["stop_ids"] = (
+                    state["stop_ids"].at[sl].set(upd[:, 11 : 11 + MAX_STOP].astype(jnp.int32))
+                )
+                return state
+
+            self._fn_cache[key] = jax.jit(apply, donate_argnames=("state",))
+            return FirstCall(self._fn_cache[key], key)
+        return self._fn_cache[key]
+
+    def pagecopy_fn(self, n: int):
+        """Jitted copy of n (page, slot-state) pairs: the pages and the
+        recurrent state a GRPO group's siblings share with their primary."""
+        key = ("pagecopy", n)
+        if key not in self._fn_cache:
+            self._fn_cache[key] = jax.jit(
+                paged_kv.copy_pages, donate_argnames=("cache",)
+            )
+            return FirstCall(self._fn_cache[key], key)
+        return self._fn_cache[key]
+
+    def clamp_fn(self, n: int):
+        """Jitted remaining-only scatter: remaining := min(remaining, cap)
+        for n (slot, cap) rows, touching nothing else (pos/ids stay
+        device-authoritative)."""
+        key = ("clamp", n)
+        if key not in self._fn_cache:
+
+            def clamp(state, upd):
+                sl = upd[:, 0]
+                cap = upd[:, 1]
+                state = dict(state)
+                old_rem = state["remaining"][sl]
+                new_rem = jnp.minimum(old_rem, cap)
+                state["remaining"] = state["remaining"].at[sl].set(new_rem)
+                # keep the min_new_tokens gate invariant: "tokens still
+                # needed before stops unlock" (= remaining - min_rem) must
+                # survive the budget clamp, or stops would fire immediately
+                new_min = jnp.maximum(
+                    0, state["min_rem"][sl] - (old_rem - new_rem)
+                )
+                state["min_rem"] = state["min_rem"].at[sl].set(new_min)
+                state["active"] = (
+                    state["active"].at[sl].set(state["active"][sl] & (new_rem > 0))
+                )
+                return state
+
+            self._fn_cache[key] = jax.jit(clamp, donate_argnames=("state",))
+            return FirstCall(self._fn_cache[key], key)
+        return self._fn_cache[key]
+
+    def suffix_kernel(self) -> bool:
+        """Whether suffix-prefill / tree-verify runs the Pallas kernel."""
+        if self._suffix_kernel_override is not None:
+            return self._suffix_kernel_override
+        return self.use_kernel
+
+    def attention_impl(self, speculative: bool) -> dict[str, str]:
+        """Which attention implementation each serving path uses (and which
+        writer the chunk program puts a decode step's KV rows with) — logged
+        once at start-up and read by chip_smoke.py; ``speculative`` is whether
+        the scheduler runs verify rounds at all. ``pallas`` is the
+        compiled TPU kernel, ``pallas-interpret`` the same body under the
+        Pallas interpreter (off-TPU, kernel forced on), ``xla`` the
+        gather + einsum path."""
+        tpu = jax.default_backend() == "tpu"
+        kern = "pallas" if tpu else "pallas-interpret"
+        return {
+            "decode": kern if self.use_kernel else "xla",
+            # a decode step's KV rows: no choice of its own, it goes with
+            # ``decode`` (ops/paged_kv_write.py beside the decode kernel, per-head
+            # scatters beside the gather path; prefill and verify always scatter)
+            "kv_write": kern if self.use_kernel else "xla",
+            # cold prefill is plain causal attention over the prompt bucket: XLA's, but for the latent-attention
+            # layers of a prompt of 1,024 tokens or more on a TPU (ops/latent_prefill_attention.py)
+            "prefill": kern if self.model.prefill_attn_launch(self.model_cfg, 1024) else "xla",
+            "suffix_prefill": kern if self.suffix_kernel() else "xla",
+            "verify": (
+                "off"
+                if not speculative
+                else kern
+                if self.suffix_kernel()
+                else "xla"
+            ),
+        }
+
+    def set_suffix_kernel(self, on: bool | None) -> None:
+        """Force the paged suffix-attention kernel on/off (None restores
+        the platform default). Used by chip_smoke's kernel-vs-XLA check; takes
+        effect on the next compiled prefill/verify fn (the fn-cache key
+        carries the flag, so both variants can coexist warm)."""
+        self._suffix_kernel_override = on
+
+    def vision_fn(self, n_patches: int):
+        """The vision tower over one image's ``n_patches`` patches."""
+        key = ("vision", n_patches)
+        if key not in self._fn_cache:
+            from areal_tpu.models import vision as vis
+
+            vcfg = self.model_cfg.vision
+            self._fn_cache[key] = jax.jit(
+                lambda vp, x, m, p: vis.vision_forward(vp, vcfg, x, m, p)
+            )
+        return self._fn_cache[key]
+
+    # -- calls: host arrays in, a program and its padded arguments out -------
+    # Each returns (program, args): the caller, which owns the device arrays
+    # the program donates, makes the call itself, ``program(donated..., *args)``.
+    # Not a convenience: a program's first call traces it, and jax lowers
+    # every op's location from the Python stack of that moment. With ONE more
+    # frame between the decode loop and the jitted call, lowering a prefill
+    # program took three times as long on the chip (0.11 -> 0.31 s each, 16 of
+    # them a start-up: PERF.md section 6, PR 42).
+    def update_call(self, rows: list[np.ndarray]):
+        """Scatter slot-update rows (``pack_row``) into the device state: one
+        upload, one jitted execute, ``program(state, *args)``. Row count is
+        bucketed (padding repeats row 0, an idempotent scatter) to bound
+        compile variants."""
+        n = self.scatter_rows(len(rows))
+        upd = np.stack(rows + [rows[0]] * (n - len(rows)))
+        return self.update_fn(n), (jnp.asarray(upd),)
+
+    def clamp_call(self, rows: list[tuple[int, int]]):
+        """Cap the remaining budget of (slot, cap) rows, ``program(state,
+        *args)``. Padded rows repeat row 0 (idempotent: min with the same
+        cap)."""
+        n = _pow2(len(rows))
+        upd = np.asarray(rows + [rows[0]] * (n - len(rows)), np.int32)
+        return self.clamp_fn(n), (jnp.asarray(upd),)
+
+    def pagecopy_call(self, dst: list[int], src: list[int], slot_dst: list[int], slot_src: list[int]):
+        """Copy page ``src[i]`` to ``dst[i]`` and slot ``slot_src[i]``'s
+        recurrent state to ``slot_dst[i]``, ``program(cache, *args)``.
+        Padding repeats the first pair: the same copy twice."""
+        n = _pow2(len(dst))
+        pad = n - len(dst)
+        pairs = tuple(jnp.asarray(np.asarray(x + x[:1] * pad, np.int32)) for x in (dst, src, slot_dst, slot_src))
+        return self.pagecopy_fn(n), pairs
+
+    def vision_call(self, px: np.ndarray, pos: np.ndarray):
+        """One image's patches ``px`` [P, patch_dim] at rope positions
+        ``pos`` [P, 2] through the vision tower, ``program(vision_params,
+        *args)``. The patch count is bucketed: distinct image sizes must not
+        each compile a fresh ViT (the mask handles the padding), by THE
+        shared formula so serving and training embeds agree."""
+        from areal_tpu.models.vision import pad_patch_bucket
+
+        P = px.shape[0]
+        Ppad = pad_patch_bucket(P, self.model_cfg.vision.spatial_merge**2)
+        return self.vision_fn(Ppad), (
+            jnp.asarray(np.pad(px, ((0, Ppad - P), (0, 0)))),
+            jnp.asarray(np.arange(Ppad) < P),
+            jnp.asarray(np.pad(pos, ((0, Ppad - P), (0, 0)))),
+        )
+
+    @staticmethod
+    def _pad_group(ids, plens, page_rows):
+        """Prefill group sizes are compiled variants: pad a group of A
+        prompts to the smallest one that holds it, by rows of one token
+        that scatter to the trash page. Returns the rows added too."""
+        A = ids.shape[0]
+        sizes = [a for a in PREFILL_SIZES if a >= A]
+        pad = (min(sizes) if sizes else A) - A
+        if pad:
+            ids = np.pad(ids, ((0, pad), (0, 0)))
+            ids[A:, 0] = 1
+            plens = np.pad(plens, (0, pad), constant_values=1)
+            page_rows = np.pad(page_rows, ((0, pad), (0, 0)))
+        return pad, ids, plens, page_rows
+
+    def prefill_call(self, ids, plens, page_rows, slots, img=None):
+        """Prefill a group, ``program(params, cache, *args)``: ``ids`` [A,
+        bucket] prompts, ``plens`` [A] their lengths, ``page_rows`` [A, pages
+        a bucket] where each row's KV goes (0 = trash page past its prompt),
+        ``slots`` [A], ``img`` positioned vision embeds or None. A padding
+        row names the slot one past the last."""
+        pad, ids, plens, page_rows = self._pad_group(ids, plens, page_rows)
+        A, bucket = ids.shape
+        if pad:
+            slots = np.pad(slots, (0, pad), constant_values=self.config.max_batch_size)
+            if img is not None:
+                img = np.pad(img, ((0, pad), (0, 0), (0, 0)))
+        args = (
+            jnp.asarray(ids),
+            jnp.asarray(plens),
+            jnp.asarray(page_rows.reshape(-1)),
+            jnp.asarray(slots),
+        )
+        if img is None:
+            return self.prefill_fn(A, bucket), args
+        return self.prefill_fn(A, bucket, with_images=True), (*args, jnp.asarray(img))
+
+    def prefill_paged_call(self, ids, plens, offs, page_rows, prefix_pages: list[list[int]]):
+        """Prefill a group's suffixes over their cached prefixes,
+        ``program(params, cache, *args)``: ``ids`` [A, bucket] suffix tokens,
+        ``plens`` [A] suffix lengths, ``offs`` [A] where each suffix starts,
+        ``page_rows`` as in ``prefill_call``, ``prefix_pages`` each row's
+        cached pages (the prefix page table's width compiles per power of
+        two)."""
+        pad, ids, plens, page_rows = self._pad_group(ids, plens, page_rows)
+        A, bucket = ids.shape
+        wp = _pow2(max(len(m) for m in prefix_pages))
+        ppt = np.zeros((A, wp), np.int32)  # a padding row: no prefix
+        for j, m in enumerate(prefix_pages):
+            ppt[j, : len(m)] = m
+        return self.prefill_paged_fn(A, bucket, wp), (
+            jnp.asarray(ids),
+            jnp.asarray(plens),
+            jnp.asarray(np.pad(offs, (0, pad))),
+            jnp.asarray(page_rows.reshape(-1)),
+            jnp.asarray(ppt),
+        )
+
+    # -- the warm set ------------------------------------------------------
+    def warm_keys(self, prompt_buckets: list[int] | None = None) -> list[tuple]:
+        """The key of every program the serving loop can reach without a
+        prefix-cache hit or a speculative round, hot loop first: decode
+        chunks (reachable windows x the four (capped, greedy) variants, and
+        the penalised ones where the configuration has them), then the
+        slot-scatter, clamp and page-copy sizes, then the prefill programs
+        (``PREFILL_SIZES`` group sizes x ``prompt_buckets``, by default
+        every reachable one). The sets are derived from the shape rules
+        themselves, so nothing unreachable is compiled and nothing
+        reachable is missed.
+
+        Suffix-only prefill variants (radix prefix-cache hits) are NOT in
+        it: their (suffix bucket x prefix-table width) grid is
+        workload-dependent, so they lazy-compile on first hit and land in
+        the persistent cache — one admission-wave stall per shape, never a
+        mid-decode stall. Nor is any speculative program (PERF.md section
+        7)."""
+        cfg = self.config
+        if prompt_buckets is None:
+            prompt_buckets = self.reachable_prompt_buckets()
+        keys: list[tuple] = []
+        freq_variants = (False, True) if cfg.enable_frequency_penalty else (False,)
+        for wp in self.reachable_chunk_wps():
+            for capped, greedy_any in (
+                (False, False),  # the serving steady state (pure sampling)
+                (False, True),
+                (True, False),
+                (True, True),
+            ):
+                for freq_any in freq_variants:
+                    keys.append(
+                        ("chunk", cfg.decode_steps_per_call, wp, capped, greedy_any, freq_any)
+                    )
+        for n in self.reachable_scatter_sizes():
+            keys.append(("upd", n))
+            keys.append(("clamp", n))
+        # GRPO prefix-sharing page copies (dup counts pad to powers of two
+        # up to next_pow2(S-1)) — a cold compile would stall all slots
+        # mid-serving
+        n = 1
+        while True:
+            keys.append(("pagecopy", n))
+            if n >= max(1, cfg.max_batch_size - 1):
+                break
+            n *= 2
+        for bucket in prompt_buckets:
+            for A in self.prefill_sizes(bucket):
+                keys.append(("prefill", A, bucket, False))
+        return keys
+
+    def lower(self, key: tuple, params_s, cache_s, state_s, rng_s):
+        """Lower the program of one of ``warm_keys()`` from abstract
+        arguments: the trees of ``jax.ShapeDtypeStruct`` the caller derives
+        from its live weights, cache, slot state and rng, WITH their
+        shardings (a program lowered from unplaced shapes is a different
+        cache key from the runtime call on committed arrays: it would be
+        compiled twice). Builds the program if the key is new."""
+        cfg = self.config
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        kind, *rest = key
+        if kind == "chunk":
+            wp = rest[1]
+            return self.chunk_fn(*rest).lower(
+                params_s, cache_s, i32(cfg.max_batch_size, wp), state_s, rng_s
+            )
+        if kind == "upd":
+            (n,) = rest
+            return self.update_fn(n).lower(
+                state_s, jax.ShapeDtypeStruct((n, UPDATE_COLS), jnp.float32)
+            )
+        if kind == "clamp":
+            (n,) = rest
+            return self.clamp_fn(n).lower(state_s, i32(n, 2))
+        if kind == "pagecopy":
+            (n,) = rest
+            return self.pagecopy_fn(n).lower(cache_s, *[i32(n)] * 4)
+        if kind == "prefill":
+            A, bucket, with_images = rest
+            assert not with_images, key
+            return self.prefill_fn(A, bucket).lower(
+                params_s,
+                cache_s,
+                i32(A, bucket),
+                i32(A),
+                i32(A * -(-bucket // cfg.page_size)),
+                i32(A),
+            )
+        raise KeyError(f"no start-up warms a program of kind {kind!r}")

@@ -146,6 +146,16 @@ class RadixPrefixCache:
     def pages_held(self) -> int:
         return self._n_pages
 
+    def pages(self) -> list[int]:
+        """The page of every node: what the tree holds one reference each on."""
+        out = []
+        stack = list(self.root.children.values())
+        while stack:
+            n = stack.pop()
+            stack.extend(n.children.values())
+            out.append(n.page)
+        return out
+
     def _touch(self) -> int:
         self._tick += 1
         return self._tick
@@ -332,6 +342,16 @@ class RadixPrefixCache:
 # (ops/paged_attention_q8.py).
 _MAX_INT8 = 127.5
 _QUANT_DTYPES = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
+
+
+def page_quant(name) -> "str | bool":
+    """``ServerConfig.kv_quantization`` as the ``quant`` every function here
+    takes: "int8" -> int8 pages, "fp8" -> float8_e4m3fn pages (both carry
+    narrow f32 scales and share one dequant formula), False for pages in the
+    model's dtype. An unknown name is refused."""
+    if name not in (None, "", "none", "int8", "fp8"):
+        raise ValueError(f"unknown kv_quantization {name!r}")
+    return name if name in ("int8", "fp8") else False
 
 
 def quant_dtype(quant) -> "jnp.dtype | None":

@@ -5,7 +5,7 @@ prompt's KV pages?" per request, without an RPC per request. The shadow
 index answers it from the client's own routing history: every completed
 generation inserts the page-aligned token-id prefix of (prompt + output)
 under the replica it ran on — exactly the pages the engine publishes into
-its radix tree at completion (``DecodeEngine._publish_prefix``). A lookup
+its radix tree at completion (``SlotCache.publish``). A lookup
 then walks the replica's shadow tree for the longest cached page-aligned
 prefix, mirroring ``RadixPrefixCache.match``.
 

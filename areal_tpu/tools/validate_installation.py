@@ -637,10 +637,10 @@ def overload_self_test(
             time.sleep(0.05)
         held = (
             eng.prefix_cache_stats()["pages_held"]
-            if eng._radix is not None
+            if eng.slots.radix is not None
             else 0
         )
-        leaked = eng.pool.used - held
+        leaked = eng.slots.pool.used - held
         if leaked != 0:
             raise AssertionError(f"{leaked} KV pages leaked after overload")
         return (
@@ -1823,8 +1823,8 @@ def spec_decode_self_test() -> str:
             if snap["queue_depth"] == 0 and snap["active_slots"] == 0:
                 break
             time.sleep(0.05)
-        held = eng.prefix_cache_stats()["pages_held"] if eng._radix is not None else 0
-        leaked = eng.pool.used - held
+        held = eng.prefix_cache_stats()["pages_held"] if eng.slots.radix is not None else 0
+        leaked = eng.slots.pool.used - held
         assert leaked == 0, f"{leaked} leaked KV pages after settling"
         # timeline stage coverage: the spec rounds marked draft + verify
         staged = set()

@@ -342,7 +342,7 @@ def phase_serve(args, on_tpu: bool) -> None:
             f"engine does not report the Pallas kernels: {impl}",
         )
     else:
-        eng.set_suffix_kernel(True)  # rehearse the kernel body (interpreter)
+        eng.programs.set_suffix_kernel(True)  # rehearse the kernel body (interpreter)
         impl = eng.attention_impl()
     eng.precompile(prompt_buckets=sz["buckets"], budget_s=sz["precompile_budget_s"])
     warm = m.report()
@@ -380,11 +380,11 @@ def phase_serve(args, on_tpu: bool) -> None:
         )
         # 3. the same pair over the gather path
         _post(addr, "/flush_prefix_cache", {})
-        eng.set_suffix_kernel(False)
+        eng.programs.set_suffix_kernel(False)
         _generate(addr, first, n_new, True)
         b_xla = _generate(addr, second, n_new, True)
         check(b_xla["cached_prefix_tokens"] >= sz["prefix"], "no radix hit (gather)")
-        eng.set_suffix_kernel(None if on_tpu else True)
+        eng.programs.set_suffix_kernel(None if on_tpu else True)
         agree = _agree(b_kernel, b_xla, "suffix-prefill kernel vs gather")
         # 4. the last requests: same shapes, new tokens — nothing compiles
         last = Meter()

@@ -772,7 +772,7 @@ def test_knobs_endpoint_applies_and_reports(knob_server):
     eng = st.engine
     assert eng.config.lifecycle.max_queue_depth == 8
     assert eng.config.lifecycle.min_free_pages == 4
-    assert eng._radix.max_pages == int((eng.pool.n_pages - 1) * 0.25)
+    assert eng.slots.radix.max_pages == int((eng.slots.pool.n_pages - 1) * 0.25)
     # the admission gate consumes the pushed value
     admit, reason, snap = eng.check_admission()
     assert admit
@@ -913,4 +913,4 @@ def test_radix_cap_shrink_evicts_live(knob_server):
         eng._wakeup.set()
         time.sleep(0.05)
     assert eng.prefix_cache_stats()["pages_held"] == 0
-    assert eng._radix.max_pages == 0
+    assert eng.slots.radix.max_pages == 0

@@ -106,12 +106,12 @@ def test_hw_accounting_keeps_the_train_and_ledger_half():
 
 
 def test_decode_programs_are_built_with_jax_jit_and_the_probe_knows_no_peak():
-    """``decode_engine.py`` hands its program builders' functions straight to
+    """``decode_programs.py`` hands its program builders' functions straight to
     ``jax.jit``; ``kernel_probe.py`` times phases and imports no peak table."""
     import ast
     import inspect
 
-    from areal_tpu.inference import decode_engine
+    from areal_tpu.inference import decode_programs
     from areal_tpu.observability import kernel_probe
 
     imported = {
@@ -125,14 +125,15 @@ def test_decode_programs_are_built_with_jax_jit_and_the_probe_knows_no_peak():
         "DECODE_PHASES", "DEFAULT_RECENT_STEPS", "DecodeStepTimeline", "Iterator", "KernelProbe",
     ]
     jitted = {}
-    for node in ast.walk(ast.parse(inspect.getsource(decode_engine.DecodeEngine))):
+    for node in ast.walk(ast.parse(inspect.getsource(decode_programs))):
         if isinstance(node, ast.FunctionDef) and node.name.endswith("_fn"):
             for call in ast.walk(node):
                 if isinstance(call, ast.Call) and ast.unparse(call.func) == "jax.jit":
                     jitted[node.name] = ast.unparse(call.args[0])
     assert jitted == {
-        "_prefill_fn": "prefill", "_prefill_paged_fn": "prefill", "_chunk_fn": "chunk", "_spec_fn": "spec",
-        "_update_fn": "apply", "_clamp_fn": "clamp", "_pagecopy_fn": "paged_kv.copy_pages",
+        "prefill_fn": "prefill", "prefill_paged_fn": "prefill", "chunk_fn": "chunk", "spec_fn": "spec",
+        "update_fn": "apply", "clamp_fn": "clamp", "pagecopy_fn": "paged_kv.copy_pages",
+        "vision_fn": "lambda vp, x, m, p: vis.vision_forward(vp, vcfg, x, m, p)",
     }
     assert inspect.signature(kernel_probe.KernelProbe.complete_step).parameters.keys() == {"self", "tl", "tokens"}
 

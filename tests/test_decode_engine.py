@@ -345,7 +345,7 @@ def test_inverse_cdf_sampler_distribution():
     import jax
     import jax.numpy as jnp
 
-    from areal_tpu.inference.decode_engine import _inverse_cdf_sample
+    from areal_tpu.inference.decode_programs import _inverse_cdf_sample
 
     n = 4000
     logits = jnp.asarray([[2.0, 0.0, 1.0, -1.0, 0.5]] * n, jnp.float32)
@@ -364,7 +364,7 @@ def test_hierarchical_sampler_two_level_path():
     import jax
     import jax.numpy as jnp
 
-    from areal_tpu.inference.decode_engine import (
+    from areal_tpu.inference.decode_programs import (
         _inverse_cdf_sample,
         _sample_blocks,
     )

@@ -117,7 +117,7 @@ def test_verify_round_scores_max_nodes_rows_a_slot_and_counts_add_up(drafter, no
     drafts = {k: jnp.asarray(getattr(bundle, k)) for k in ("tokens", "parent_row", "depth", "mask", "n_draft")}
     with jax.set_mesh(eng.mesh):
         out = jax.eval_shape(
-            eng._spec_fn(nodes, wp, False, True), eng.params, eng.cache,
+            eng.programs.spec_fn(nodes, wp, False, True), eng.params, eng.cache,
             jax.ShapeDtypeStruct((S, wp), jnp.int32), eng._dev_state, eng._rng, drafts,
         )
     assert out[3].shape == (2 * nodes + 3, S) and out[3].dtype == jnp.int32

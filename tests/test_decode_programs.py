@@ -46,17 +46,17 @@ def _lower(eng: DecodeEngine, program: str):
     S, wp = eng.config.max_batch_size, 4
     pt = _i32(S, wp)
     if program == "chunk":
-        return eng._chunk_fn(4, wp, False, True, False).lower(eng.params, eng.cache, pt, eng._dev_state, eng._rng)
+        return eng.programs.chunk_fn(4, wp, False, True, False).lower(eng.params, eng.cache, pt, eng._dev_state, eng._rng)
     if program == "prefill":
-        return eng._prefill_fn(1, 256).lower(eng.params, eng.cache, _i32(1, 256), _i32(1), _i32(256 // PSZ), _i32(1))
+        return eng.programs.prefill_fn(1, 256).lower(eng.params, eng.cache, _i32(1, 256), _i32(1), _i32(256 // PSZ), _i32(1))
     if program == "prefill_sfx":
-        return eng._prefill_paged_fn(1, 256, wp).lower(
+        return eng.programs.prefill_paged_fn(1, 256, wp).lower(
             eng.params, eng.cache, _i32(1, 256), _i32(1), _i32(1), _i32(256 // PSZ), _i32(1, wp)
         )
     B = eng._spec_cfg.max_nodes()
     bundle = speculative.empty_bundle(S, B - 1)
     drafts = {k: jnp.asarray(getattr(bundle, k)) for k in ("tokens", "parent_row", "depth", "mask", "n_draft")}
-    return eng._spec_fn(B, wp, False, True).lower(eng.params, eng.cache, pt, eng._dev_state, eng._rng, drafts)
+    return eng.programs.spec_fn(B, wp, False, True).lower(eng.params, eng.cache, pt, eng._dev_state, eng._rng, drafts)
 
 
 @pytest.mark.parametrize(
@@ -128,16 +128,16 @@ def test_programs_survive_a_dtype_change_of_the_weights(leaves):
     try:
         first = served.generate_sync(req, timeout=120)
         assert rebuilt.generate_sync(req, timeout=120).output_logprobs == first.output_logprobs
-        programs = dict(served._fn_cache)
+        programs = dict(served.programs._fn_cache)
         assert {k[0] for k in programs} >= {"prefill", "chunk", "upd"}
         _commit_in_dtype(served, leaves, jnp.bfloat16, 1)
         _commit_in_dtype(rebuilt, leaves, jnp.bfloat16, 1)
-        rebuilt._fn_cache.clear()
+        rebuilt.programs._fn_cache.clear()
         flat = flatten_params(served.params)
         assert {k for k, v in flat.items() if v.dtype == jnp.bfloat16} == set(leaves or flat)
         after = served.generate_sync(req, timeout=120)
         want = rebuilt.generate_sync(req, timeout=120)
-        assert all(served._fn_cache[k] is fn for k, fn in programs.items())  # retraced, not rebuilt
+        assert all(served.programs._fn_cache[k] is fn for k, fn in programs.items())  # retraced, not rebuilt
         assert after.output_versions == [1] * 12
         assert after.output_tokens == want.output_tokens
         assert after.output_logprobs == want.output_logprobs and all(lp < 0 for lp in after.output_logprobs)

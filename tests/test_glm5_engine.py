@@ -94,7 +94,7 @@ def test_a_grpo_group_of_eight_aliases_and_copies_both_pools(served):
     eng, cfg = served
     prompt = np.random.default_rng(1).integers(0, cfg["vocab_size"], 37).tolist()
     g = GenerationHyperparameters(max_new_tokens=40, temperature=1.0, ignore_eos=True)
-    prefills, shared, used0 = eng.stats["prefills"], eng.stats.get("prefix_shared", 0), eng.pool.used
+    prefills, shared, used0 = eng.stats["prefills"], eng.stats.get("prefix_shared", 0), eng.slots.pool.used
     _held(eng)
     box, done = {}, threading.Event()
     for i in range(8):
@@ -106,10 +106,10 @@ def test_a_grpo_group_of_eight_aliases_and_copies_both_pools(served):
     try:
         slots = [i for i, t in enumerate(eng._slot_task) if t is not None]
         assert len(slots) == 8 and eng.stats["prefills"] == prefills + 1
-        pages = [eng._slot_pages[s] for s in slots]
+        pages = [eng.slots.pages(s) for s in slots]
         assert len({tuple(p[:2]) for p in pages}) == 1 and len({p[2] for p in pages}) == 8  # two aliased, the third each one's own
-        assert all(eng.pool._rc[p] == 8 for p in pages[0][:2])
-        assert 2 + 8 <= eng.pool.used - used0 <= 2 + 8 * 3
+        assert all(eng.slots.pool._rc[p] == 8 for p in pages[0][:2])
+        assert 2 + 8 <= eng.slots.pool.used - used0 <= 2 + 8 * 3
         assert set(eng.cache) == {"k", "idx"}
         k, idx = np.asarray(eng.cache["k"]), np.asarray(eng.cache["idx"])  # [3 layers, 1, pages, 16 rows, 256 | 128 lanes]
         first_k, first_i = k[:, 0, pages[0][2], :5], idx[:, 0, pages[0][2], :5]
@@ -159,9 +159,9 @@ def test_a_preempted_request_rebuilds_both_pools_by_prefill(served):
     def preempt(rid):
         _held(eng)  # the loop idles: its bookkeeping is ours for a moment
         slot = next(i for i, t in enumerate(eng._slot_task) if t is not None and t.req.rid == rid)
-        used = eng.pool.used
+        used = eng.slots.pool.used
         eng._apply_slot_updates([eng._preempt(slot)])
-        assert eng.pool.used < used  # its pages went back to the pool
+        assert eng.slots.pool.used < used  # its pages went back to the pool
         eng.continue_generation()
 
     resumes, prefills = eng.stats["kv_resumes"], eng.stats["prefills"]
@@ -199,10 +199,10 @@ def test_counts_of_a_decode_chunk_and_the_status_page(served):
 
 def test_the_ledger_and_the_budget_count_both_pools(served):
     eng, cfg = served
-    assert eng.config.prefix_cache.enabled and eng._radix is None
+    assert eng.config.prefix_cache.enabled and eng.slots.radix is None
     assert eng.prefix_cache_stats() == {"enabled": False, "disabled_by": "latent_pages"}
     led = eng.hbm_ledger()["components"]
-    assert led["recurrent_state"] == 0 and led["kv_page_pool"] == 3 * eng.pool.n_pages * 16 * (ROW + KEY)  # three layers, a row and a key a token
+    assert led["recurrent_state"] == 0 and led["kv_page_pool"] == 3 * eng.slots.pool.n_pages * 16 * (ROW + KEY)  # three layers, a row and a key a token
     impl = eng.attention_impl()
     assert impl["decode"] == impl["kv_write"] == "xla" and impl["prefill"] == "xla"  # off the TPU: the gather path
     # the budget by hand at the published sizes: 6 layers x 128 tokens x (640 + 128) lanes x 2 B a page = 9,216 B a token
@@ -214,8 +214,8 @@ def test_the_ledger_and_the_budget_count_both_pools(served):
     budget = _server_config(kv_hbm_gb=1e-3)
     e2 = DecodeEngine(budget, params=eng.params, model_cfg=eng.model_cfg, mesh=_mesh(budget))
     e2.initialize()
-    assert e2.pool.n_pages == int(1e-3 * 2**30) // (3 * 16 * (ROW + KEY))
-    assert e2.cache["k"].shape == (3, 1, e2.pool.n_pages, 16, 256) and e2.cache["idx"].shape == (3, 1, e2.pool.n_pages, 16, 128)
+    assert e2.slots.pool.n_pages == int(1e-3 * 2**30) // (3 * 16 * (ROW + KEY))
+    assert e2.cache["k"].shape == (3, 1, e2.slots.pool.n_pages, 16, 256) and e2.cache["idx"].shape == (3, 1, e2.slots.pool.n_pages, 16, 128)
 
 
 def test_a_wave_of_long_prompts_over_the_pool_waits_and_preempts_nothing():
@@ -224,7 +224,7 @@ def test_a_wave_of_long_prompts_over_the_pool_waits_and_preempts_nothing():
     rest of the wave waits in the backlog, and every request ends by length
     with all its tokens; the drain's leak audit finds every page back."""
     eng, cfg = _engine(max_batch_size=12, max_seq_len=256, attn_window_step=256, kv_hbm_gb=41 * 16 * 3 * (ROW + KEY) / 2**30)
-    assert eng.pool.n_pages == 41
+    assert eng.slots.pool.n_pages == 41
     eng.start()
     try:
         rng = np.random.default_rng(11)
@@ -239,7 +239,7 @@ def test_a_wave_of_long_prompts_over_the_pool_waits_and_preempts_nothing():
         assert all(r.stop_reason == StopReason.LENGTH.value and len(r.output_tokens) == 6 for r in box)
         assert eng.stats.get("preempted", 0) == 0
         summary = eng.drain(budget_s=5.0)
-        assert summary["leaked_pages"] == 0 and eng.pool.used == 0
+        assert summary["leaked_pages"] == 0 and eng.slots.pool.used == 0
     finally:
         eng.stop()
 
@@ -258,8 +258,8 @@ def test_lowered_programs_hold_the_familys_scopes(served):
         S, psz = eng.config.max_batch_size, eng.config.page_size
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
         with jax.set_mesh(eng.mesh):
-            chunk = eng._chunk_fn(4, 2, False, False, False).lower(eng.params, eng.cache, i32(S, 2), eng._dev_state, eng._rng)
-            prefill = eng._prefill_fn(2, 256).lower(eng.params, eng.cache, i32(2, 256), i32(2), i32(2 * 256 // psz), i32(2))
+            chunk = eng.programs.chunk_fn(4, 2, False, False, False).lower(eng.params, eng.cache, i32(S, 2), eng._dev_state, eng._rng)
+            prefill = eng.programs.prefill_fn(2, 256).lower(eng.params, eng.cache, i32(2, 256), i32(2), i32(2 * 256 // psz), i32(2))
     finally:
         eng.continue_generation()
     shared = ("embed", "mlp", hybrid.MOE_SHARED_SCOPE) + hybrid.MLA_SCOPES + hybrid.DSA_SCOPES
@@ -279,17 +279,18 @@ def test_prefill_programs_hold_one_long_prompt_at_a_time(served):
     bytes: every size at this tiny hidden; at hidden 6,144 in bfloat16 a
     bucket of 4,096 and more is one row a program, 1,024 still eight."""
     eng, _ = served
-    assert eng._prefill_sizes(256) == (8, 4, 2, 1)
+    progs = eng.programs
+    assert progs.prefill_sizes(256) == (8, 4, 2, 1)
     import dataclasses
 
-    was = eng.model_cfg
+    was = progs.model_cfg
     try:
-        eng.model_cfg = dataclasses.replace(was, hidden_size=6144, dtype="bfloat16")
-        assert eng._prefill_sizes(1024) == (4, 2, 1) and eng._prefill_sizes(4096) == eng._prefill_sizes(16384) == (1,)
-        eng.model_cfg = dataclasses.replace(was, hidden_size=4096, dtype="bfloat16")
-        assert eng._prefill_sizes(1024) == (8, 4, 2, 1)  # the cells of 8 x 1,024 tokens keep every size
+        progs.model_cfg = dataclasses.replace(was, hidden_size=6144, dtype="bfloat16")
+        assert progs.prefill_sizes(1024) == (4, 2, 1) and progs.prefill_sizes(4096) == progs.prefill_sizes(16384) == (1,)
+        progs.model_cfg = dataclasses.replace(was, hidden_size=4096, dtype="bfloat16")
+        assert progs.prefill_sizes(1024) == (8, 4, 2, 1)  # the cells of 8 x 1,024 tokens keep every size
     finally:
-        eng.model_cfg = was
+        progs.model_cfg = was
 
 
 def _refused(mcfg, **kw):
@@ -310,4 +311,4 @@ def test_this_model_is_refused_what_its_module_does_not_implement():
     eng, _ = _engine(max_batch_size=2, max_seq_len=64, attn_window_step=64)
     with pytest.raises(ValueError, match="latent"):
         eng.set_speculative(True)
-    assert eng._spec_cfg is None and eng._radix is None and eng.sparse_attention_status()["index_topk"] == 16
+    assert eng._spec_cfg is None and eng.slots.radix is None and eng.sparse_attention_status()["index_topk"] == 16

@@ -216,7 +216,7 @@ def test_interrupted_generation_equals_its_uninterrupted_twin(served):
 
 def test_radix_cache_serves_nothing_to_a_recurrent_model(served):
     eng, cfg = served
-    assert eng.config.prefix_cache.enabled and eng._radix is None
+    assert eng.config.prefix_cache.enabled and eng.slots.radix is None
     assert eng.prefix_cache_stats() == {"enabled": False, "disabled_by": "recurrent_state"}
     prompt = np.random.default_rng(5).integers(0, cfg["vocab_size"], 70).tolist()  # 4 whole pages
     first = _gen(eng, prompt, 4)
@@ -226,7 +226,7 @@ def test_radix_cache_serves_nothing_to_a_recurrent_model(served):
     led = eng.hbm_ledger()["components"]
     mcfg = eng.model_cfg
     assert led["recurrent_state"] == 2 * 6 * (8 * 16 * 16 * 4 + 3 * mcfg.conv_dim * 4)
-    assert led["kv_page_pool"] == 2 * 1 * 2 * eng.pool.n_pages * 16 * 128 * 4  # one attention layer, lane-padded
+    assert led["kv_page_pool"] == 2 * 1 * 2 * eng.slots.pool.n_pages * 16 * 128 * 4  # one attention layer, lane-padded
     assert _count(eng._obs.state_bytes) == led["recurrent_state"]
 
 
@@ -246,8 +246,8 @@ def test_lowered_programs_hold_the_familys_scopes(served):
         S, psz = eng.config.max_batch_size, eng.config.page_size
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
         with jax.set_mesh(eng.mesh):
-            chunk = eng._chunk_fn(4, 2, False, False, False).lower(eng.params, eng.cache, i32(S, 2), eng._dev_state, eng._rng)
-            prefill = eng._prefill_fn(2, 256).lower(eng.params, eng.cache, i32(2, 256), i32(2), i32(2 * 256 // psz), i32(2))
+            chunk = eng.programs.chunk_fn(4, 2, False, False, False).lower(eng.params, eng.cache, i32(S, 2), eng._dev_state, eng._rng)
+            prefill = eng.programs.prefill_fn(2, 256).lower(eng.params, eng.cache, i32(2, 256), i32(2), i32(2 * 256 // psz), i32(2))
             copy = jax.jit(paged_kv.copy_pages).lower(eng.cache, i32(1), i32(1), i32(1), i32(1))
     finally:
         eng.continue_generation()

@@ -91,7 +91,7 @@ def test_a_grpo_group_of_eight_matches_the_reference_and_aliases_latent_pages(se
     eng, cfg = served
     prompt = np.random.default_rng(1).integers(0, cfg["vocab_size"], 37).tolist()
     g = GenerationHyperparameters(max_new_tokens=40, temperature=1.0, ignore_eos=True)
-    prefills, shared, used0 = eng.stats["prefills"], eng.stats.get("prefix_shared", 0), eng.pool.used
+    prefills, shared, used0 = eng.stats["prefills"], eng.stats.get("prefix_shared", 0), eng.slots.pool.used
     _held(eng)
     box, done = {}, threading.Event()
     for i in range(8):
@@ -103,11 +103,11 @@ def test_a_grpo_group_of_eight_matches_the_reference_and_aliases_latent_pages(se
     try:
         slots = [i for i, t in enumerate(eng._slot_task) if t is not None]
         assert len(slots) == 8 and eng.stats["prefills"] == prefills + 1
-        pages = [eng._slot_pages[s] for s in slots]
+        pages = [eng.slots.pages(s) for s in slots]
         assert len({tuple(p[:2]) for p in pages}) == 1 and len({p[2] for p in pages}) == 8  # two aliased, the third each one's own
-        assert all(eng.pool._rc[p] == 8 for p in pages[0][:2])
+        assert all(eng.slots.pool._rc[p] == 8 for p in pages[0][:2])
         # at most 5 pages a request (37 + 40 tokens) of which 2 are shared: the group holds 2 + 8 x (1..3) pages
-        assert 2 + 8 <= eng.pool.used - used0 <= 2 + 8 * 3
+        assert 2 + 8 <= eng.slots.pool.used - used0 <= 2 + 8 * 3
         k = np.asarray(eng.cache["k"])  # [4 layers, 1, pages, 16 rows, 256 lanes]
         first = k[:, 0, pages[0][2], :5]
         assert np.abs(first[..., :136]).min(axis=-1).max() > 0 and not first[..., 136:].any()  # 136 values a row, zero lanes after
@@ -153,9 +153,9 @@ def test_a_preempted_request_rebuilds_its_latent_pages_by_prefill(served):
     def preempt(rid):
         _held(eng)  # the loop idles: its bookkeeping is ours for a moment
         slot = next(i for i, t in enumerate(eng._slot_task) if t is not None and t.req.rid == rid)
-        used = eng.pool.used
+        used = eng.slots.pool.used
         eng._apply_slot_updates([eng._preempt(slot)])
-        assert eng.pool.used < used  # its pages went back to the pool
+        assert eng.slots.pool.used < used  # its pages went back to the pool
         eng.continue_generation()
 
     resumes, prefills = eng.stats["kv_resumes"], eng.stats["prefills"]
@@ -200,14 +200,14 @@ def test_counts_of_a_decode_chunk_and_the_status_page(served):
 
 def test_the_ledger_and_the_budget_count_latent_rows(served):
     eng, cfg = served
-    assert eng.config.prefix_cache.enabled and eng._radix is None
+    assert eng.config.prefix_cache.enabled and eng.slots.radix is None
     assert eng.prefix_cache_stats() == {"enabled": False, "disabled_by": "latent_pages"}
     prompt = np.random.default_rng(5).integers(0, cfg["vocab_size"], 70).tolist()  # 4 whole pages
     first, again = _gen(eng, prompt, 4), _gen(eng, prompt, 4)
     assert again.output_tokens == first.output_tokens
     assert "cached_prefix_tokens" not in again.metadata and eng.stats["prefix_hit_tokens"] == 0
     led = eng.hbm_ledger()["components"]
-    assert led["recurrent_state"] == 0 and led["kv_page_pool"] == 4 * eng.pool.n_pages * 16 * ROW  # four layers, one row a token
+    assert led["recurrent_state"] == 0 and led["kv_page_pool"] == 4 * eng.slots.pool.n_pages * 16 * ROW  # four layers, one row a token
     impl = eng.attention_impl()
     assert impl["decode"] == impl["kv_write"] == "xla" and impl["prefill"] == "xla"  # off the TPU: the gather path
     # the budget by hand at the published sizes: 48 layers x 128 tokens x 640 lanes x 2 B a page, one pool
@@ -215,7 +215,7 @@ def test_the_ledger_and_the_budget_count_latent_rows(served):
     budget = _server_config(kv_hbm_gb=1e-3)
     e2 = DecodeEngine(budget, params=eng.params, model_cfg=eng.model_cfg, mesh=_mesh(budget))
     e2.initialize()
-    assert e2.pool.n_pages == int(1e-3 * 2**30) // (4 * 16 * ROW) and e2.cache["k"].shape == (4, 1, e2.pool.n_pages, 16, 256)
+    assert e2.slots.pool.n_pages == int(1e-3 * 2**30) // (4 * 16 * ROW) and e2.cache["k"].shape == (4, 1, e2.slots.pool.n_pages, 16, 256)
 
 
 def test_lowered_programs_hold_the_familys_scopes(served):
@@ -232,8 +232,8 @@ def test_lowered_programs_hold_the_familys_scopes(served):
         S, psz = eng.config.max_batch_size, eng.config.page_size
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
         with jax.set_mesh(eng.mesh):
-            chunk = eng._chunk_fn(4, 2, False, False, False).lower(eng.params, eng.cache, i32(S, 2), eng._dev_state, eng._rng)
-            prefill = eng._prefill_fn(2, 256).lower(eng.params, eng.cache, i32(2, 256), i32(2), i32(2 * 256 // psz), i32(2))
+            chunk = eng.programs.chunk_fn(4, 2, False, False, False).lower(eng.params, eng.cache, i32(S, 2), eng._dev_state, eng._rng)
+            prefill = eng.programs.prefill_fn(2, 256).lower(eng.params, eng.cache, i32(2, 256), i32(2), i32(2 * 256 // psz), i32(2))
     finally:
         eng.continue_generation()
     shared = ("embed", "mlp", hybrid.MOE_SHARED_SCOPE) + hybrid.MLA_SCOPES
@@ -267,7 +267,7 @@ def test_this_model_is_refused_what_its_module_does_not_implement():
     eng, _ = _engine(max_batch_size=2, max_seq_len=64, attn_window_step=64)
     with pytest.raises(ValueError, match="latent"):
         eng.set_speculative(True)
-    assert eng._spec_cfg is None and eng._radix is None and eng.moe_status() == {"load": [[0] * 8] * 3, "held": [0, 4]}
+    assert eng._spec_cfg is None and eng.slots.radix is None and eng.moe_status() == {"load": [[0] * 8] * 3, "held": [0, 4]}
     with pytest.raises(NotImplementedError):
         eng.model.quantize_params_int8({})
 

@@ -202,7 +202,7 @@ def test_expert_load_counts_live_rows_only(served):
 
 def test_radix_cache_serves_nothing_and_the_ledger_counts_the_windows(served):
     eng, cfg = served
-    assert eng.config.prefix_cache.enabled and eng._radix is None
+    assert eng.config.prefix_cache.enabled and eng.slots.radix is None
     assert eng.prefix_cache_stats() == {"enabled": False, "disabled_by": "recurrent_state"}
     prompt = np.random.default_rng(5).integers(0, cfg["vocab_size"], 70).tolist()  # 4 whole pages
     first, again = _gen(eng, prompt, 4), _gen(eng, prompt, 4)
@@ -210,7 +210,7 @@ def test_radix_cache_serves_nothing_and_the_ledger_counts_the_windows(served):
     assert "cached_prefix_tokens" not in again.metadata and eng.stats["prefix_hit_tokens"] == 0
     led = eng.hbm_ledger()["components"]
     assert led["recurrent_state"] == 5 * 6 * 2 * 64 * 4 == eng._obs.state_bytes.get()  # 5 conv layers x 6 slots x 2 values x 64 channels
-    assert led["kv_page_pool"] == 2 * 2 * 2 * eng.pool.n_pages * 16 * 128 * 4  # two attention layers, lane-padded heads
+    assert led["kv_page_pool"] == 2 * 2 * 2 * eng.slots.pool.n_pages * 16 * 128 * 4  # two attention layers, lane-padded heads
 
 
 def test_lowered_programs_hold_the_familys_scopes(served):
@@ -228,8 +228,8 @@ def test_lowered_programs_hold_the_familys_scopes(served):
         S, psz = eng.config.max_batch_size, eng.config.page_size
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
         with jax.set_mesh(eng.mesh):
-            chunk = eng._chunk_fn(4, 2, False, False, False).lower(eng.params, eng.cache, i32(S, 2), eng._dev_state, eng._rng)
-            prefill = eng._prefill_fn(2, 256).lower(eng.params, eng.cache, i32(2, 256), i32(2), i32(2 * 256 // psz), i32(2))
+            chunk = eng.programs.chunk_fn(4, 2, False, False, False).lower(eng.params, eng.cache, i32(S, 2), eng._dev_state, eng._rng)
+            prefill = eng.programs.prefill_fn(2, 256).lower(eng.params, eng.cache, i32(2, 256), i32(2), i32(2 * 256 // psz), i32(2))
             copy = jax.jit(paged_kv.copy_pages).lower(eng.cache, i32(1), i32(1), i32(1), i32(1))
     finally:
         eng.continue_generation()

@@ -120,9 +120,9 @@ def test_pool_pressure_preempts_and_recovers():
     # after all requests finish, the only pages still out are the radix
     # tree's own (completed prompts publish their full pages); flushing the
     # tree must drain the pool to zero — anything else is a refcount leak
-    assert eng.pool.used == eng.prefix_cache_stats().get("pages_held", 0)
+    assert eng.slots.pool.used == eng.prefix_cache_stats().get("pages_held", 0)
     eng.flush_prefix_cache()
-    assert eng.pool.used == 0, "pages leaked after all requests finished"
+    assert eng.slots.pool.used == 0, "pages leaked after all requests finished"
 
 
 def test_prefix_sharing_page_accounting():
@@ -147,16 +147,16 @@ def test_prefix_sharing_page_accounting():
         assert eng.stats["prefills"] < 4
     finally:
         eng.stop()
-    assert eng.pool.used == eng.prefix_cache_stats().get("pages_held", 0)
+    assert eng.slots.pool.used == eng.prefix_cache_stats().get("pages_held", 0)
     eng.flush_prefix_cache()
-    assert eng.pool.used == 0
+    assert eng.slots.pool.used == 0
 
 
 def test_budgeted_pool_sizes_from_hbm():
     """kv_hbm_gb produces a pool smaller than the dense equivalent."""
     eng = _engine(n_pages=4)
     dense_pages = 4 * (256 // 128) + 1
-    assert eng.pool.n_pages == 4 < dense_pages
+    assert eng.slots.pool.n_pages == 4 < dense_pages
 
 
 # -- refcount safety under aliasing ----------------------------------------
@@ -252,11 +252,11 @@ def test_radix_interior_eviction_never_orphans_children():
 def _audit_zero(eng):
     """Every page still out must be the radix tree's own claim; flushing the
     tree must drain the pool to zero."""
-    assert eng.pool.used == eng.prefix_cache_stats().get("pages_held", 0), (
+    assert eng.slots.pool.used == eng.prefix_cache_stats().get("pages_held", 0), (
         "pages out beyond the radix tree's claim"
     )
     eng.flush_prefix_cache()
-    assert eng.pool.used == 0, "pages leaked after abort"
+    assert eng.slots.pool.used == 0, "pages leaked after abort"
 
 
 def _submit_until_decoding(eng, req):
@@ -371,15 +371,15 @@ def test_abort_while_parked_returns_every_page():
         eng.pause_generation()  # abort-pause: rid parks, keeps its pages
         assert done.wait(30)
         assert box["r"].stop_reason == "abort"
-        assert "parked" in eng._parked
-        parked_pages = list(eng._parked["parked"].pages)
+        assert "parked" in eng.slots.parked
+        parked_pages = list(eng.slots.parked["parked"].pages)
         assert parked_pages, "nothing parked to audit"
         eng.abort_request("parked")
         eng.continue_generation()
         deadline = time.monotonic() + 30
-        while "parked" in eng._parked and time.monotonic() < deadline:
+        while "parked" in eng.slots.parked and time.monotonic() < deadline:
             time.sleep(0.02)
-        assert "parked" not in eng._parked
+        assert "parked" not in eng.slots.parked
     finally:
         eng.stop()
     _audit_zero(eng)

@@ -180,7 +180,7 @@ def test_engine_kernel_on_greedy_parity_twin():
         params = qwen.init_params(jax.random.PRNGKey(0), TINY_QWEN2)
         eng = DecodeEngine(cfg, params=params, model_cfg=TINY_QWEN2)
         eng.initialize()
-        eng.set_suffix_kernel(use_kernel)
+        eng.programs.set_suffix_kernel(use_kernel)
         # the decode step's kernel and the writer of its KV rows go together
         impl = eng.attention_impl()
         assert impl["kv_write"] == impl["decode"] == "xla", impl
@@ -221,10 +221,10 @@ def test_engine_kernel_on_greedy_parity_twin():
             assert eng.stats["spec_rounds"] > 0, "speculation never ran"
             held = (
                 eng.prefix_cache_stats()["pages_held"]
-                if eng._radix is not None
+                if eng.slots.radix is not None
                 else 0
             )
-            assert eng.pool.used - held == 0
+            assert eng.slots.pool.used - held == 0
         finally:
             eng.stop()
         return out
@@ -265,9 +265,9 @@ def test_engine_fp8_cache_serves_greedy():
         assert len(resp.output_tokens) == 8
         held = (
             eng.prefix_cache_stats()["pages_held"]
-            if eng._radix is not None
+            if eng.slots.radix is not None
             else 0
         )
-        assert eng.pool.used - held == 0
+        assert eng.slots.pool.used - held == 0
     finally:
         eng.stop()

@@ -112,7 +112,7 @@ def _serve_everything(eng: DecodeEngine, case: str) -> None:
         return rng.integers(1, 200, n).tolist()
 
     # every prompt bucket (256 and the 512 cap), each chunk variant
-    assert eng._reachable_prompt_buckets() == [256, 512]
+    assert eng.programs.reachable_prompt_buckets() == [256, 512]
     _wave(eng, [_request(prompt(100), 8, greedy=True)])
     _wave(eng, [_request(prompt(300), 8, greedy=True)])
     # the other three (capped, greedy) variants, one request each so that the
@@ -133,9 +133,9 @@ def _serve_everything(eng: DecodeEngine, case: str) -> None:
         assert eng.stats["prefix_hit_tokens"] - hit0 == 64
     # a preemption: the pool is held down to 24 pages, two requests want 17 each
     eng.flush_prefix_cache()  # or the radix tree's pages would be reclaimed first
-    hostage = eng.pool.alloc(eng.pool.available - 24)
+    hostage = eng.slots.pool.alloc(eng.slots.pool.available - 24)
     got = _wave(eng, [_request(prompt(60), 200, ignore_eos=True) for _ in range(2)])
-    eng.pool.free(hostage)
+    eng.slots.pool.free(hostage)
     assert eng.stats.get("preempted", 0) >= 1
     assert sorted(r.stop_reason for r in got).count(StopReason.ABORT.value) == eng.stats["preempted"]
     # a drain with requests in flight: they are parked or aborted, none is lost
@@ -147,9 +147,7 @@ def _serve_everything(eng: DecodeEngine, case: str) -> None:
         assert time.monotonic() < deadline, "the two requests were never admitted"
         time.sleep(0.01)
     summary = eng.drain(budget_s=0.05)
-    # with the radix tree on, the audit subtracts a page twice that a parked
-    # request and the tree both hold, and reads below zero (PERF.md section 7)
-    assert summary["leaked_pages"] == 0 or (case == "radix" and summary["leaked_pages"] < 0)
+    assert summary["leaked_pages"] == 0
     assert summary["unterminated_timelines"] == 0
     assert len(ended) == 2
 
@@ -177,11 +175,11 @@ def test_nothing_compiles_after_precompile(case, compiled_names):
         # shows (jit_chunk, jit_prefill: PERF.md section 3); 1 window x 4
         # (capped, greedy) chunks, 2 scatter + 2 clamp sizes, 1 page-copy size,
         # 2 prompt buckets x 4 group sizes
-        assert all(type(fn) is JITTED for fn in eng._fn_cache.values())
-        assert {(key[0], fn.__name__) for key, fn in eng._fn_cache.items()} == {
+        assert all(type(fn) is JITTED for fn in eng.programs._fn_cache.values())
+        assert {(key[0], fn.__name__) for key, fn in eng.programs._fn_cache.items()} == {
             ("chunk", "chunk"), ("prefill", "prefill"), ("upd", "apply"), ("clamp", "clamp"), ("pagecopy", "copy_pages"),
         }
-        assert collections.Counter(k[0] for k in eng._fn_cache) == {
+        assert collections.Counter(k[0] for k in eng.programs._fn_cache) == {
             "chunk": 4, "upd": 2, "clamp": 2, "pagecopy": 1, "prefill": 8,
         }
         before = compile_cache.compile_stats()["compiles"]

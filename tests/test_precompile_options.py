@@ -5,7 +5,7 @@ import logging
 
 import pytest
 
-from areal_tpu.inference.decode_engine import _PREFILL_SIZES
+from areal_tpu.inference.decode_programs import PREFILL_SIZES
 
 from tpu_testing import tiny_decode_engine as _engine
 
@@ -21,16 +21,17 @@ def test_a_spent_budget_compiles_nothing_and_says_what_it_deferred():
         eng.precompile(budget_s=0.0)
     finally:
         log.removeHandler(handler)
-    assert eng._fn_cache == {}
+    assert eng.programs._fn_cache == {}
     assert "precompile budget 0s spent after 0 programs; 21 deferred to lazy compile" in said
 
 
 def test_prompt_buckets_narrow_the_prefill_programs_only():
     eng = _engine()
     eng.precompile(prompt_buckets=[256])
-    kinds = collections.Counter(k[0] for k in eng._fn_cache)
-    assert kinds == {"chunk": 4, "upd": 3, "clamp": 3, "pagecopy": 3, "prefill": len(_PREFILL_SIZES)}
-    assert {k[2] for k in eng._fn_cache if k[0] == "prefill"} == {256}
+    kinds = collections.Counter(k[0] for k in eng.programs._fn_cache)
+    assert kinds == {"chunk": 4, "upd": 3, "clamp": 3, "pagecopy": 3, "prefill": len(PREFILL_SIZES)}
+    assert {k[2] for k in eng.programs._fn_cache if k[0] == "prefill"} == {256}
+    assert eng.programs.keys() == set(eng.programs.warm_keys(prompt_buckets=[256]))  # what was named is what was built
 
 
 @pytest.mark.parametrize(
@@ -45,6 +46,6 @@ def test_prompt_buckets_narrow_the_prefill_programs_only():
 )
 def test_reachable_sets_follow_the_configuration(cfg, buckets, wps, scatters):
     eng = _engine(**cfg)
-    assert eng._reachable_prompt_buckets() == buckets
-    assert eng._reachable_chunk_wps() == wps
-    assert eng._reachable_scatter_sizes() == scatters
+    assert eng.programs.reachable_prompt_buckets() == buckets
+    assert eng.programs.reachable_chunk_wps() == wps
+    assert eng.programs.reachable_scatter_sizes() == scatters

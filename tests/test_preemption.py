@@ -344,7 +344,7 @@ def test_engine_drain_finish_or_park(tiny_engine):
         assert len(done) == 1
         assert done[0].stop_reason == "abort"
         assert len(done[0].output_tokens) > 0
-        assert "drain-park" in eng._parked  # rid-affinity KV retained
+        assert "drain-park" in eng.slots.parked  # rid-affinity KV retained
         assert summary["parked"] >= 1
         # admission is closed with the draining reason (server turns it
         # into 429 + Retry-After)
@@ -361,9 +361,9 @@ def test_engine_drain_finish_or_park(tiny_engine):
         eng.continue_generation()
         eng.abort_request("drain-park")
         deadline = time.monotonic() + 10
-        while "drain-park" in eng._parked and time.monotonic() < deadline:
+        while "drain-park" in eng.slots.parked and time.monotonic() < deadline:
             time.sleep(0.01)
-        assert "drain-park" not in eng._parked
+        assert "drain-park" not in eng.slots.parked
 
 
 def test_engine_drain_aborts_queued(tiny_engine):

@@ -264,11 +264,11 @@ def test_multi_turn_readmission_after_parked_eviction_hits_radix():
     assert out and out[0].stop_reason == "abort"
     emitted = list(out[0].output_tokens)
     assert len(emitted) >= 16
-    assert "episode-1" in eng._parked
+    assert "episode-1" in eng.slots.parked
     published = eng.prefix_cache_stats()["pages_held"]
     assert published >= (70 + len(emitted) - 1) // PSZ - 1
     # pool pressure evicts the parked KV -> the rid-affinity fast path dies
-    assert eng._evict_oldest_parked() is not None
+    assert eng.slots.evict_oldest_parked() is not None
     eng.continue_generation()
     # turn 2: the episode resubmits prompt + turn-1 emission + feedback
     turn2 = list(prompt) + emitted + rng.integers(0, 256, 11).tolist()
@@ -313,7 +313,7 @@ def test_flush_policy_drops_cache_at_commit():
     _commit_update(eng, version=1)
     # default policy: the tree is empty and nothing stale is matchable
     assert eng.prefix_cache_stats()["pages_held"] == 0
-    assert eng.pool.used == 0
+    assert eng.slots.pool.used == 0
     eng.submit(ModelRequest(input_ids=list(prompt), gconfig=g), out.append)
     _drive(eng)
     assert eng.stats["prefix_cache_hits"] == 0
@@ -351,7 +351,7 @@ def test_disabled_cache_never_matches_or_publishes():
         _drive(eng)
     assert eng.prefix_cache_stats() == {"enabled": False}
     assert eng.stats["prefix_cache_hits"] == 0
-    assert eng.pool.used == 0
+    assert eng.slots.pool.used == 0
 
 
 # -- ops surface -------------------------------------------------------------

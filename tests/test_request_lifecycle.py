@@ -67,8 +67,8 @@ def _long(n=100_000) -> GenerationHyperparameters:
 def _leaked(eng: DecodeEngine) -> int:
     """PagePool refcount audit: pages in use that are NOT accounted for by
     the radix tree (the only legitimate holder once all requests ended)."""
-    held = eng.prefix_cache_stats()["pages_held"] if eng._radix is not None else 0
-    return eng.pool.used - held
+    held = eng.prefix_cache_stats()["pages_held"] if eng.slots.radix is not None else 0
+    return eng.slots.pool.used - held
 
 
 def _wait_decoding(eng: DecodeEngine, rid: str, timeout=30.0) -> None:
@@ -91,7 +91,7 @@ def _settle(eng: DecodeEngine, timeout=30.0) -> None:
         if (
             snap["queue_depth"] == 0
             and snap["active_slots"] == 0
-            and not eng._parked
+            and not eng.slots.parked
         ):
             return
         time.sleep(0.05)
@@ -168,13 +168,13 @@ def test_abort_request_while_parked(engine):
     _wait_decoding(engine, req.rid)
     engine.pause_generation()  # abort-pause: the rid parks with its KV
     assert done.wait(30)
-    assert req.rid in engine._parked
+    assert req.rid in engine.slots.parked
     engine.abort_request(req.rid)
     engine.continue_generation()
     deadline = time.monotonic() + 30
-    while req.rid in engine._parked and time.monotonic() < deadline:
+    while req.rid in engine.slots.parked and time.monotonic() < deadline:
         time.sleep(0.02)
-    assert req.rid not in engine._parked
+    assert req.rid not in engine.slots.parked
     _settle(engine)
     assert _leaked(engine) == 0
 

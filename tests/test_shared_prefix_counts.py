@@ -59,6 +59,6 @@ def test_warm_wave_prefills_the_tails_and_hits_the_prefix(page_size, n_requests,
     assert eng.stats["prefix_hit_tokens"] - hit == n_requests * prefix_tokens
     # with every request ended, each page still out is the tree's own
     held = eng.prefix_cache_stats()["pages_held"]
-    assert held >= prefix_pages and eng.pool.used == held
+    assert held >= prefix_pages and eng.slots.pool.used == held
     assert eng.flush_prefix_cache() == held
-    assert eng.pool.used == 0 and eng.prefix_cache_stats()["pages_held"] == 0
+    assert eng.slots.pool.used == 0 and eng.prefix_cache_stats()["pages_held"] == 0

@@ -232,7 +232,7 @@ def test_a_decode_run_leaves_its_set_up_and_every_pass_in_the_record(tracer):
     # one build a program of the engine first called, each inside a pass, the compile inside the build
     builds = _named(tracer, "areal.program.build")
     assert {b.args["program"] for b in builds} == {"prefill", "upd", "chunk"}
-    assert len(builds) == len({(b.args["program"], b.args["key"]) for b in builds}) == len(eng._fn_cache)
+    assert len(builds) == len({(b.args["program"], b.args["key"]) for b in builds}) == len(eng.programs._fn_cache)
     compiles = _named(tracer, "areal.xla.compile")
     for b in builds:
         assert any(p.start_ns <= b.start_ns and b.end_ns <= p.end_ns for p in passes)

@@ -70,8 +70,8 @@ def _greedy(n=24, **kw) -> GenerationHyperparameters:
 def _leaked(eng: DecodeEngine) -> int:
     """PagePool refcount audit: pages in use not accounted for by the
     radix tree (the only legitimate holder once all requests ended)."""
-    held = eng.prefix_cache_stats()["pages_held"] if eng._radix is not None else 0
-    return eng.pool.used - held
+    held = eng.prefix_cache_stats()["pages_held"] if eng.slots.radix is not None else 0
+    return eng.slots.pool.used - held
 
 
 def _settle(eng: DecodeEngine, timeout=30.0) -> None:
@@ -81,7 +81,7 @@ def _settle(eng: DecodeEngine, timeout=30.0) -> None:
         if (
             snap["queue_depth"] == 0
             and snap["active_slots"] == 0
-            and not eng._parked
+            and not eng.slots.parked
         ):
             return
         time.sleep(0.05)
@@ -256,7 +256,7 @@ def test_spec_twin_parked_resume(tiny_params, baseline):
         assert done.wait(30)
         part1 = box["r"].output_tokens
         assert box["r"].stop_reason == StopReason.ABORT.value
-        assert "parked" in eng._parked
+        assert "parked" in eng.slots.parked
         assert 0 < len(part1) < total, "pause landed outside the window"
         eng.continue_generation()
         resumed = _run_all(
@@ -350,7 +350,7 @@ def test_spec_rejected_drafts_roll_back_pages(tiny_params):
         _settle(eng)
         assert _leaked(eng) == 0
         held = eng.prefix_cache_stats()["pages_held"]
-        assert eng.pool.used == held  # free + held == total
+        assert eng.slots.pool.used == held  # free + held == total
     finally:
         eng.stop()
 
@@ -413,7 +413,7 @@ def test_spec_rollback_with_quantized_pages_no_leak(tiny_params):
         _settle(eng)
         assert _leaked(eng) == 0
         held = eng.prefix_cache_stats()["pages_held"]
-        assert eng.pool.used == held  # free + held == total
+        assert eng.slots.pool.used == held  # free + held == total
     finally:
         eng.stop()
 

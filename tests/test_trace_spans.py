@@ -234,14 +234,14 @@ def _scopes_in(lowered) -> set[str]:
 
 def _lower_chunk(eng):
     wp = 2
-    fn = eng._chunk_fn(8, wp, False, True, False)
+    fn = eng.programs.chunk_fn(8, wp, False, True, False)
     with jax.set_mesh(eng.mesh):
-        return fn.lower(eng.params, eng.cache, jnp.asarray(eng._pt_host[:, :wp]), eng._dev_state, eng._rng)
+        return fn.lower(eng.params, eng.cache, jnp.asarray(eng.slots.page_table(wp)), eng._dev_state, eng._rng)
 
 
 def _lower_prefill(eng):
     bucket = eng.config.page_size
-    fn = eng._prefill_fn(1, bucket)
+    fn = eng.programs.prefill_fn(1, bucket)
     ids = jnp.zeros((1, bucket), jnp.int32)
     with jax.set_mesh(eng.mesh):
         return fn.lower(
