@@ -477,7 +477,7 @@ class DecodeEngine:
         kv_spec = paged_kv.paged_cache_specs(quant=kv_quant, pools=tuple(mcfg.kv_pools))
         if mcfg.num_kv_heads % max(tp, 1):
             kv_spec = dict.fromkeys(kv_spec, P())
-        kv_spec.update({name: P() for name in mcfg.state_shapes(S)})
+        kv_spec.update({name: P() for name in (*mcfg.state_shapes(S), *mcfg.ring_shapes(S, psz))})
         with set_mesh(self.mesh):
             self.cache = jax.jit(
                 lambda: paged_kv.init_paged_cache(
@@ -1454,9 +1454,41 @@ class DecodeEngine:
             self._obs.gdn_state_updates.inc(int(counts["gdn_updates"].sum()))
         if "latent_tokens_read" in counts:
             self._obs.latent_tokens_read.inc(int(counts["latent_tokens_read"].sum()))
+        for leaf, counter in (
+            ("shared_kv_tokens_read", self._obs.shared_kv_tokens_read),
+            ("window_tokens_read", self._obs.window_tokens_read),
+            ("s6_updates", self._obs.s6_state_updates),
+        ):
+            if leaf in counts:
+                counter.inc(int(counts[leaf].sum()))
         if "index_tokens_scored" in counts:
             self._obs.index_tokens_scored.inc(int(counts["index_tokens_scored"].sum()))
             self._obs.latent_tokens_selected.inc(int(counts["latent_tokens_selected"].sum()))
+
+    def kv_pools_status(self) -> dict | None:
+        """/statusz ``kv_pools``: the groups of page pools the model's layers
+        are served from (``model_cfg.kv_groups``: which layers write and read
+        a group, how many tokens a slot keeps there) with what each holds
+        now: the full group's pages under the page table, the window group's
+        rings (a fixed ``pages_per_slot`` a window layer for every slot that
+        holds a request), and the bytes of recurrent state beside them. None
+        for a model whose attending layers all share one page table."""
+        groups = getattr(self.model_cfg, "kv_groups", None) if self.model_cfg is not None else None
+        if not groups or "window" not in groups or getattr(self, "slots", None) is None:
+            return None
+        out = {name: {k: (list(v) if isinstance(v, tuple) else v) for k, v in g.items()} for name, g in groups.items()}
+        pool = self.slots.pool
+        out["full"].update(pages_total=pool.n_pages - 1, pages_held=pool.used)
+        ring = self.model_cfg.ring_pages(self.config.page_size)
+        holding = sum(1 for i in range(self.config.max_batch_size) if self.slots.pages(i)) + len(self.slots.parked)
+        out["window"].update(
+            pages_per_slot=ring, layers=len(groups["window"]["writers"]),
+            pages_total=ring * self.config.max_batch_size, pages_held=ring * holding,
+        )
+        rows = self.slots.hbm_rows(self.cache)
+        out["state_bytes"] = rows["recurrent_state"]
+        out["window_bytes"] = rows.get("window_rings", 0)
+        return out
 
     def sparse_attention_status(self) -> dict | None:
         """/statusz ``sparse_attention``: what a learned index selects for a
@@ -2053,6 +2085,10 @@ class DecodeEngine:
         self._obs.prefill_tokens.inc(prompt_tokens)
         if self.model.prefill_attn_launch(self.model_cfg, bucket):
             self._obs.prefill_attn_launch_tokens.inc(prompt_tokens)
+        if "shared_kv_tokens_read" in self.model_cfg.count_shapes:
+            # the program's rows ended at the shared layer's K and V: a prompt's one row of the layers past it is the
+            # decode step's that feeds its last token again
+            self._obs.prefill_last_token_rows.inc(A)
         rebuilt = self.slots.readmitted(t.req.rid for t, _ in admitted)
         if self.model_cfg.has_recurrent_state:
             self._obs.state_prefills.inc(rebuilt)

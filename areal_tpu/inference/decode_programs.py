@@ -291,9 +291,10 @@ class DecodePrograms:
 
     def prefill_sizes(self, bucket: int) -> tuple[int, ...]:
         """The group sizes a prefill program of this bucket comes in: those of
-        ``PREFILL_SIZES`` whose residual stream stays inside
-        ``_PREFILL_STREAM_BYTES``, at least (1,)."""
-        row = bucket * self.model_cfg.hidden_size * jnp.dtype(self.model_cfg.jax_dtype).itemsize
+        ``PREFILL_SIZES`` whose rows' widest activations (the residual stream,
+        or what the model's module says a row holds: ``prefill_row_bytes``)
+        stay inside ``_PREFILL_STREAM_BYTES``, at least (1,)."""
+        row = self.model.prefill_row_bytes(self.model_cfg, bucket)
         return tuple(a for a in PREFILL_SIZES if a * row <= _PREFILL_STREAM_BYTES) or (1,)
 
     # -- the shape rules: each is what the caller asks at run time AND what

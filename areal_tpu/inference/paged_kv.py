@@ -440,7 +440,56 @@ def n_pages_for_budget(
 # and its three conv windows (``gdn`` + ``conv``: 30 heads of 96 x 192 in
 # float32, 2.2 MB a layer and slot at Olmo-Hybrid-7B). All go through the same
 # programs.
+# A fourth tenant is a Mamba-1 layer's state and window (``ssm`` [state size,
+# channels] + ``conv``: 0.36 MB a layer and slot at Phi-4-mini-flash).
 STATE_LEAVES = ("ssm", "conv", "gdn")
+# Between the two stand the RINGS of layers that attend to the last
+# ``sliding_window`` tokens only (``cfg.kv_groups``: which layers a group of
+# pools serves and how long they keep a token, is the model configuration's
+# to say): ``ring_k`` / ``ring_v`` [window layers, heads, slots + 1, pages a
+# ring, page, lanes]. A slot owns ``cfg.ring_pages(page_size)`` pages a
+# window layer, the same ones for as long as it lives, whatever its context:
+# token t lies at ring position t % sliding_window, so after a write at
+# position p the ring holds exactly tokens p - sliding_window + 1 .. p, the
+# keys the query at p attends to, and min(p + 1, sliding_window) of its
+# positions are valid. Without a positional embedding the order of the keys
+# under a softmax says nothing, so a read is ``paged attention`` over the
+# ring's pages at that length: no lower bound, no mask of its own. Like the
+# state a ring is slot-indexed, never aliased, written for live slots only,
+# COPIED to a group's siblings and left as it is while its request is
+# parked; like pages it takes a token's row twice without harm. The block
+# past the last slot takes a padding row's prefill and the decode writes of
+# slots that are not live. Merging axes 2 and 3 gives the pools' own layout
+# [layers, heads, pages, page, lanes] (``ring_pool``) under the page table
+# ``ring_table``.
+RING_LEAVES = ("ring_k", "ring_v")
+
+
+def ring_pool(ring: jax.Array) -> jax.Array:
+    """A ring leaf as a page pool [layers, heads, (slots + 1) * pages a ring,
+    page, lanes]: slot s's pages are s * pages .. (s + 1) * pages - 1."""
+    n, heads, blocks, pages, psz, lanes = ring.shape
+    return ring.reshape(n, heads, blocks * pages, psz, lanes)
+
+
+def ring_table(slots: int, pages: int) -> jax.Array:
+    """The rings' page table [slots, pages a ring] into ``ring_pool``."""
+    return (jnp.arange(slots, dtype=jnp.int32)[:, None] * pages + jnp.arange(pages, dtype=jnp.int32)[None, :])
+
+
+def ring_of_rows(rows: jax.Array, n_tokens: jax.Array, window: int, ring: tuple[int, int]) -> jax.Array:
+    """A slot's ring after a prompt: ``rows`` [L, heads, lanes] (a window
+    layer's K or V of every prompt position), of which the first ``n_tokens``
+    are real -> [heads, pages a ring, page, lanes] with, at ring position r,
+    the LAST real token t with t % window == r (positions no token has
+    reached yet hold what row 0 holds: a read's length leaves them out)."""
+    pages, psz = ring
+    r = jnp.arange(window, dtype=jnp.int32)
+    last = n_tokens.astype(jnp.int32) - 1
+    t = last - (last - r) % window  # the largest t <= last with t % window == r; negative where none
+    picked = rows[jnp.clip(t, 0, rows.shape[0] - 1)]  # [window, heads, lanes]
+    picked = jnp.pad(picked, ((0, pages * psz - window), (0, 0), (0, 0)))
+    return picked.reshape(pages, psz, *rows.shape[1:]).transpose(2, 0, 1, 3)
 
 
 def init_paged_cache(
@@ -452,7 +501,7 @@ def init_paged_cache(
     plus per-token-vector f32 scales, lane-major ([..., 1, psz]) — halved
     KV HBM traffic, the decode bottleneck at long context. Beside them the
     zeroed recurrent state of ``slots`` decode slots, where the model has
-    any (see STATE_LEAVES above)."""
+    any (see STATE_LEAVES above), and the window layers' rings (RING_LEAVES)."""
     dtype = dtype or cfg.jax_dtype
     qdtype = quant_dtype(quant)
     cache = {}
@@ -463,6 +512,8 @@ def init_paged_cache(
             cache[f"{name}_scale"] = jnp.ones(shape[:-2] + (1, page_size), jnp.float32)
     for name, (sshape, sdtype) in cfg.state_shapes(slots).items():
         cache[name] = jnp.zeros(sshape, sdtype)
+    for name, (rshape, rdtype) in cfg.ring_shapes(slots, page_size).items():
+        cache[name] = jnp.zeros(rshape, rdtype)
     return cache
 
 
@@ -564,12 +615,15 @@ def write_decode_rows(
     write_off: jax.Array,  # [S] int32
     live: tuple[jax.Array, jax.Array] | None = None,
     more: dict[str, jax.Array] | None = None,
+    pools: tuple[str, str] = ("k", "v"),
 ) -> dict:
     """A decode step's KV write of one layer: slot s's row lands at
     cache[layer, :, write_page[s], write_off[s]], quantized with its scale
     where the cache holds quantized pages. ``more`` = {pool: rows [S, heads,
     lanes]} names what else the token leaves behind on the same page and row
     (a latent model's index key, in a pool of its own width): the same launch.
+    ``pools`` names the leaves K and V go to (the window layers' rings, as
+    ``ring_pool`` lays them out, under their own pages and offsets).
 
     ``live`` = (slots with the live ones first, how many are live) puts the
     write on the Pallas launch (ops/paged_kv_write.py): the live slots' rows
@@ -581,9 +635,9 @@ def write_decode_rows(
     pool-sized copies a layer; per head: none). That path serves off the TPU
     and under tensor parallelism, and is what the tests hold the kernel to."""
     cache = dict(cache)
-    rows = {"k": k, **(more or {})} if v is None else {"k": k, "v": v}
+    rows = {pools[0]: k, **(more or {})} if v is None else {pools[0]: k, pools[1]: v}
     pages, scales = tuple(rows), ()
-    if "k_scale" in cache:
+    if f"{pools[0]}_scale" in cache:
         scales = tuple(f"{n}_scale" for n in pages)
         for name in pages:
             rows[name], scale = quantize_kv(rows[name], dtype=cache[name].dtype)
@@ -642,6 +696,9 @@ def copy_pages(
         if name in STATE_LEAVES:
             with jax.named_scope("state_write"):
                 cache[name] = copied(cache[name], 1, dst_slots, src_slots)
+        elif name in RING_LEAVES:  # a sibling's rings are its own: the primary's window of the prompt, copied
+            with jax.named_scope("kv_write"):
+                cache[name] = copied(cache[name], 2, dst_slots, src_slots)
         else:  # k/v (+ k_scale/v_scale under int8 KV), or a latent model's k (and its index keys, idx)
             cache[name] = copied(cache[name], 2, dst, src)
     return cache

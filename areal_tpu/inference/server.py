@@ -259,6 +259,11 @@ class InferenceServer:
         if sa is not None and (sparse_view := sa()) is not None:
             # a learned index's selection and the form its rows are read in
             out["sparse_attention"] = sparse_view
+        kp = getattr(self.engine, "kv_pools_status", None)
+        if kp is not None and (pools_view := kp()) is not None:
+            # which layers each group of page pools serves, how long a slot
+            # keeps a token there, and what each holds now
+            out["kv_pools"] = pools_view
         ks = getattr(self.engine, "kernel_stats", None)
         if ks is not None:
             # decode-step phases (docs/observability.md "Decode-step

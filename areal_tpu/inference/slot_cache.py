@@ -177,10 +177,13 @@ class SlotCache:
         state beside it, and the tree's held-page share (a view INTO the
         pool)."""
         state_bytes = self.state_bytes(cache)
-        kv_bytes = hw.tree_bytes(cache) - state_bytes
+        ring_bytes = hw.tree_bytes({k: cache[k] for k in paged_kv.RING_LEAVES if cache and k in cache})
+        kv_bytes = hw.tree_bytes(cache) - state_bytes - ring_bytes
         return {
             "kv_page_pool": kv_bytes,
             "recurrent_state": state_bytes,
+            # the window layers' rings: a fixed share a slot, under no page table (paged_kv.RING_LEAVES)
+            **({"window_rings": ring_bytes} if ring_bytes else {}),
             "radix_cache": int(self.radix_pages * (kv_bytes / self.n_pages)),
         }
 

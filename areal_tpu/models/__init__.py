@@ -12,9 +12,9 @@ def family_of(model_cfg):
     """The module that implements ``model_cfg``'s model family: the one place
     where the serving stack picks between them (``models/qwen.py``: the
     Qwen2/3 and llama decoders; ``models/hybrid.py``: ``granitemoehybrid``,
-    ``lfm2_moe``, ``olmo_hybrid`` and ``deepseek_v3``). Both have the entry points
+    ``lfm2_moe``, ``olmo_hybrid``, ``deepseek_v3``, ``glm_moe_dsa`` and ``phi4flash``). Both have the entry points
     the decode engine calls (``param_partition_specs``, ``hf_name_map``,
-    ``prefill_into_cache``, ``forward_prefill_paged``,
+    ``prefill_into_cache``, ``prefill_row_bytes``, ``forward_prefill_paged``,
     ``forward_decode_paged``, ``forward_verify_paged``, ``compute_logits``,
     ``quantize_params_int8``) and say what of them they do not implement for
     a model (``serving_limits``)."""

@@ -12,8 +12,20 @@ FFN kinds: ``dense`` (SwiGLU) and ``moe`` (sparse experts, ``models/moe.py``,
 with or without an always-active shared block beside them; the stack may hold
 a SHARE of the experts the router scores: ``router_experts``,
 ``expert_first``). A block's two RMSNorms stand on its sublayers' inputs
-(``norm_placement`` ``pre``) or on their outputs (``post``). Four published
-families are built from these (``from_hf_dict``): ``granitemoehybrid``
+(``norm_placement`` ``pre``) or on their outputs (``post``); ``norm_kind``
+``layer`` makes them LayerNorms with a bias. A decoder-hybrid-decoder
+(``phi4flash``: SambaY) adds four mixers: ``s6`` (Mamba-1's selective scan:
+a state [state size, channels] with a decay of its own every element),
+``swa`` (attention over the last ``sliding_window`` tokens, kept in a RING of
+the slot's own: ``inference/paged_kv.py`` RING_LEAVES), ``cross`` (queries of
+its own over the pages of the model's ONE ``attention`` layer) and ``gmu`` (a
+gate on the last ``s6`` layer's scan output of the same token, which rides
+down the stack with the cache), with differential attention in every
+attending layer (``_diff_pack_q``: 4 query heads of 2 x head_dim lanes to one
+row [k1 | k2], the paged kernels' own grouped-query shape) and a prompt pass
+that ends at the shared layer's K and V (``forward_prefill`` ``tail``). Five
+published families are built from these (``from_hf_dict``): ``phi4flash``,
+``granitemoehybrid``
 without experts (Mamba-2 beside NoPE attention, dense MLPs, residual and
 logit multipliers), ``lfm2_moe`` (short convolutions beside rotary attention
 with q/k norms; the first ``num_dense_layers`` FFNs dense, the rest 32
@@ -114,8 +126,10 @@ from jax.sharding import PartitionSpec as P
 from areal_tpu.models import moe, qwen
 from areal_tpu.models.qwen import _embed_lookup, _proj, _rms_norm, _rope
 
-MODEL_TYPES = ("granitemoehybrid", "lfm2_moe", "olmo_hybrid", "deepseek_v3", "glm_moe_dsa")
-KINDS = ("mamba", "attention", "conv", "gdn", "mla")  # mixers
+MODEL_TYPES = ("granitemoehybrid", "lfm2_moe", "olmo_hybrid", "deepseek_v3", "glm_moe_dsa", "phi4flash")
+# mixers; ``s6`` is Mamba-1's selective scan, ``swa`` attention over the last ``sliding_window`` tokens, ``cross``
+# queries of its own over the pages of the model's ONE ``attention`` layer, ``gmu`` a gated memory unit
+KINDS = ("mamba", "attention", "conv", "gdn", "mla", "s6", "swa", "cross", "gmu")
 FFNS = ("dense", "moe")
 # scopes this family adds to qwen.SCOPES (docs/observability.md): the
 # state-space mixer's, the short-conv mixer's, and models/moe.py's
@@ -127,6 +141,9 @@ MLA_SCOPES = ("mla_proj", "attn", "kv_write")  # latent attention: its projectio
 # a low-rank query path, and the learned index that picks the cached tokens a query attends to
 DSA_SCOPES = ("mla_q_lora", "dsa_index_proj", "dsa_index_score", "dsa_select")
 MOE_SHARED_SCOPE = "moe_shared"  # the always-active block beside the routed experts
+# a decoder-hybrid-decoder: the window layers' read, the cross layers' read of the shared pages, what differential
+# attention adds behind either read (the subtraction, the norm over a pair's values, lambda), the memory unit
+SAMBAY_SCOPES = ("attn_window", "attn_cross", "attn_diff", "gmu")
 # tokens a chunk of the delta rule's prefill scan: 16 x 2^2, as ``_unit_lower_inverse`` builds its inverse
 GDN_CHUNK = 64
 # what a decode chunk may ask the forward to count into (not part of the
@@ -136,9 +153,13 @@ GDN_CHUNK = 64
 # tokens of live slots a latent-attention layer read, [mla layers]; where the
 # layer has an index, the cached tokens it scored and the tokens the
 # mathematics selects of them (min(index_topk, cached) a live slot), [mla layers]
+# ... and of a decoder-hybrid-decoder, one number each a chunk: cached tokens of live slots x the layers that read
+# the shared pages; tokens a window layer read (at most ``sliding_window`` a live slot and layer); selective-scan
+# states advanced (live slots x s6 layers)
 COUNT_LEAVES = (
     "moe_load", "moe_touched", "moe_streamed", "gdn_updates", "latent_tokens_read",
     "index_tokens_scored", "latent_tokens_selected",
+    "shared_kv_tokens_read", "window_tokens_read", "s6_updates",
 )
 
 
@@ -244,6 +265,18 @@ class HybridConfig:
     # would add is left out of the layer's sum
     router_experts: int | None = None
     expert_first: int = 0
+    # a block's two norms and the final one: "rms", or "layer" (LayerNorm with weight and bias)
+    norm_kind: str = "rms"
+    # the selective-scan mixer (``s6``, Mamba-1): channels, the rank its step size is projected through
+    # (``mamba_d_state`` and ``mamba_d_conv`` are its state size and conv taps)
+    s6_d_inner: int = 0
+    s6_dt_rank: int = 0
+    # ``swa`` layers attend to the last ``sliding_window`` tokens, the query's own among them
+    sliding_window: int = 0
+    # differential attention in every attending layer: query heads (2p, 2p+1) are (q1, q2) of differential head p,
+    # K heads (2r, 2r+1) are (k1, k2) and V heads (v1 | v2) of pair r, head p reads pair p // 2
+    diff_attn: bool = False
+    attn_bias: bool = False  # biases on the attending layers' projections
 
     @property
     def num_layers(self) -> int:
@@ -328,8 +361,15 @@ class HybridConfig:
     def kv_head_dim(self) -> int:
         if self.count("mla"):
             return self.latent_lanes
+        if self.diff_attn:  # a pair's two heads side by side, [k1 | k2] and [v1 | v2]: nothing padded at a head of 64
+            return 2 * self.head_dim_
         pad = max(1, self.kv_lane_pad)
         return -(-self.head_dim_ // pad) * pad
+
+    @property
+    def kv_pool_heads(self) -> int:
+        """Heads a K or V row is stored as: the KV heads, or their pairs."""
+        return self.num_kv_heads // 2 if self.diff_attn else self.num_kv_heads
 
     @property
     def kv_pools(self) -> dict[str, tuple[int, int]]:
@@ -340,11 +380,53 @@ class HybridConfig:
         if self.count("mla"):
             # the index's key of a token lies beside its latent row: a second pool of another width on the same pages
             return {"k": (1, self.latent_lanes), **({"idx": (1, self.index_head_dim)} if self.index_topk else {})}
-        return {"k": (self.num_kv_heads, self.kv_head_dim), "v": (self.num_kv_heads, self.kv_head_dim)}
+        return {"k": (self.kv_pool_heads, self.kv_head_dim), "v": (self.kv_pool_heads, self.kv_head_dim)}
+
+    def layers_of(self, kind: str) -> tuple[int, ...]:
+        """Model indices of the layers whose mixer is ``kind``, in order."""
+        return tuple(i for i, t in enumerate(self.layer_types) if t == kind)
+
+    @property
+    def kv_groups(self) -> dict[str, dict[str, Any]]:
+        """Which LAYERS a group of page pools serves and how long they keep a
+        token (inference/paged_kv.py): {group: {"pools", "writers" (model
+        indices of the layers that leave a row there), "readers" (of those
+        that attend over it), "keeps" (tokens a slot holds a layer: None =
+        every one, under the page table; a number = the last that many, in a
+        ring of the slot's own)}}. ``full`` is what ``kv_pools`` describes."""
+        groups: dict[str, dict[str, Any]] = {}
+        if self.num_kv_layers:
+            own = self.layers_of("attention") + self.layers_of("mla")
+            groups["full"] = {
+                "pools": tuple(self.kv_pools), "writers": own,
+                "readers": tuple(sorted(own + self.layers_of("cross"))), "keeps": None,
+            }
+        if self.count("swa"):
+            from areal_tpu.inference.paged_kv import RING_LEAVES
+
+            swa = self.layers_of("swa")
+            groups["window"] = {"pools": RING_LEAVES, "writers": swa, "readers": swa, "keeps": self.sliding_window}
+        return groups
+
+    def ring_pages(self, page_size: int) -> int:
+        """Pages of a slot's ring in one window layer: ``sliding_window``
+        tokens, token t at ring position t % sliding_window."""
+        return -(-self.sliding_window // page_size) if self.count("swa") else 0
+
+    def ring_shapes(self, slots: int, page_size: int) -> dict[str, tuple[tuple[int, ...], Any]]:
+        """{leaf: (shape, dtype)} of the window layers' rings (paged_kv.py
+        RING_LEAVES): [window layers, heads, slots + 1, pages a ring, page,
+        lanes]; the block past the last slot takes a padding row's writes."""
+        if not self.count("swa"):
+            return {}
+        from areal_tpu.inference.paged_kv import RING_LEAVES
+
+        shape = (self.count("swa"), self.kv_pool_heads, slots + 1, self.ring_pages(page_size), page_size, self.kv_head_dim)
+        return {name: (shape, self.jax_dtype) for name in RING_LEAVES}
 
     @property
     def has_recurrent_state(self) -> bool:
-        return self.count("mamba") + self.count("conv") + self.count("gdn") > 0
+        return self.count("mamba") + self.count("conv") + self.count("gdn") + self.count("s6") > 0
 
     @property
     def count_shapes(self) -> dict[str, tuple[int, ...]]:
@@ -358,6 +440,12 @@ class HybridConfig:
             if self.index_topk:
                 out["index_tokens_scored"] = (n,)
                 out["latent_tokens_selected"] = (n,)
+        if self.count("cross"):
+            out["shared_kv_tokens_read"] = (1,)
+        if self.count("swa"):
+            out["window_tokens_read"] = (1,)
+        if self.count("s6"):
+            out["s6_updates"] = (1,)
         return out
 
     @property
@@ -374,10 +462,17 @@ class HybridConfig:
         window is stored token-major and flat, ``(taps - 1) * channels``
         wide: with the tokens as the minor dimension the TPU would pad every
         channel's 2 or 3 values to a 128-lane row. A model has state-space,
-        short-conv or delta-rule layers, one of the three: ``conv`` is its
-        window (a delta-rule layer's three, side by side: [q | k | v]). The
-        delta-rule state ``gdn`` holds ``gdn_head_pack`` heads a tile."""
+        short-conv, delta-rule or selective-scan layers, one of the four:
+        ``conv`` is its window (a delta-rule layer's three, side by side: [q |
+        k | v]). The delta-rule state ``gdn`` holds ``gdn_head_pack`` heads a
+        tile."""
         conv_dtype = jnp.dtype(self.conv_state_dtype or self.dtype)
+        if n := self.count("s6"):
+            # the state [state size, channels]: channels on the lanes, a decay of its own every element
+            return {
+                "ssm": ((n, slots, self.mamba_d_state, self.s6_d_inner), jnp.dtype(self.ssm_state_dtype)),
+                "conv": ((n, slots, (self.mamba_d_conv - 1) * self.s6_d_inner), conv_dtype),
+            }
         if n := self.count("mamba"):
             return {
                 "ssm": (
@@ -455,6 +550,22 @@ class HybridConfig:
             "num_key_value_heads": self.num_kv_heads,
             "tie_word_embeddings": self.tie_word_embeddings,
         }
+        if self.model_type == "phi4flash":
+            return {
+                **shared,
+                "hidden_act": "silu",
+                "layer_norm_eps": self.rms_norm_eps,
+                "mb_per_layer": 2,
+                "sliding_window": self.sliding_window,
+                "mlp_bias": False,
+                "lm_head_bias": False,
+                "head_dim": self.head_dim_,
+                "mamba_d_state": self.mamba_d_state,
+                "mamba_d_conv": self.mamba_d_conv,
+                "mamba_expand": self.s6_d_inner // self.hidden_size,
+                "mamba_dt_rank": self.s6_dt_rank,
+                "attn_bias": self.attn_bias,
+            }
         if self.model_type == "olmo_hybrid":
             return {
                 **shared,
@@ -732,13 +843,74 @@ def _deepseek_v3_fields(d: dict[str, Any]) -> dict[str, Any]:
     )
 
 
+def _phi4flash_fields(d: dict[str, Any]) -> dict[str, Any]:
+    """``phi4flash`` (SambaY, arXiv:2507.06607: a decoder-hybrid-decoder): a
+    self-decoder of ``num_hidden_layers // 2 + 2`` layers, Mamba-1 (``s6``) at
+    the even indices and attention at the odd ones, over the last
+    ``sliding_window`` tokens but for the LAST, which attends to every token
+    and whose keys and values are the one cache the cross-decoder reads; then
+    a cross-decoder that alternates gated memory units (``gmu``: a gate on
+    the last ``s6`` layer's scan output of the same token) and ``cross``
+    layers (queries of their own over that one cache). Differential
+    attention in every attending layer, LayerNorm with a bias, no positional
+    embedding. The published ``config.json`` gives ``mb_per_layer``,
+    ``sliding_window`` and the widths; the Mamba sizes (``mamba_d_state``,
+    ``mamba_d_conv``, ``mamba_expand``, ``mamba_dt_rank``), ``head_dim`` and
+    the projections' biases are the family's released code's, read here
+    where a configuration names them and refused where it names another form."""
+    n = int(d["num_hidden_layers"])
+    if int(d.get("mb_per_layer", 2)) != 2 or n % 4 or n < 8:
+        raise ValueError(f"phi4flash with mb_per_layer {d.get('mb_per_layer')} / {n} layers: Mamba at every second layer of a depth divisible by 4 is implemented")
+    if not d.get("sliding_window"):
+        raise ValueError("phi4flash needs sliding_window: its self-decoder's attention layers keep a window")
+    if d.get("mlp_bias") or d.get("lm_head_bias"):
+        raise ValueError("phi4flash with mlp_bias / lm_head_bias is not implemented")
+    if d.get("mamba_proj_bias") or not d.get("mamba_conv_bias", True):
+        raise ValueError("phi4flash: a conv with bias and projections without are implemented for the selective-scan mixer")
+    if d["num_attention_heads"] % 4 or d.get("num_key_value_heads", d["num_attention_heads"]) * 2 != d["num_attention_heads"]:
+        raise ValueError("differential attention is implemented for query heads in pairs, two pairs reading one pair of K/V heads")
+    half = n // 2
+    kinds = tuple(
+        ("s6" if i % 2 == 0 else "attention" if i == half + 1 else "swa") if i <= half + 1 else ("gmu" if i % 2 == 0 else "cross")
+        for i in range(n)
+    )
+    inner = int(d.get("mamba_expand", 2)) * int(d["hidden_size"])
+    return dict(
+        intermediate_size=d["intermediate_size"],
+        layer_types=kinds,
+        rms_norm_eps=d.get("layer_norm_eps", 1e-5),
+        norm_kind="layer",
+        rope_theta=None,
+        sliding_window=int(d["sliding_window"]),
+        diff_attn=True,
+        attn_bias=bool(d.get("attn_bias", True)),
+        s6_d_inner=inner,
+        s6_dt_rank=int(d.get("mamba_dt_rank") or -(-int(d["hidden_size"]) // 16)),
+        mamba_d_state=int(d.get("mamba_d_state", 16)),
+        mamba_d_conv=int(d.get("mamba_d_conv", 4)),
+    )
+
+
 _FIELDS = {
+    "phi4flash": _phi4flash_fields,
     "granitemoehybrid": _granite_fields,
     "lfm2_moe": _lfm2_fields,
     "olmo_hybrid": _olmo_hybrid_fields,
     "deepseek_v3": _deepseek_v3_fields,
     "glm_moe_dsa": _deepseek_v3_fields,
 }
+
+
+def prefill_row_bytes(cfg: HybridConfig, bucket: int) -> int:
+    """Bytes of the widest activations ONE row of a prefill program of
+    ``bucket`` tokens holds through the layers: the residual stream, or,
+    where the model has selective-scan layers, the scan's three float32
+    inputs and outputs a channel (c, d, y [bucket, channels]: 61 KB a token
+    at 5,120 channels against the stream's 5 KB). A long prompt of such a
+    model then goes through alone: ONE program a bucket, and the scan's
+    temporaries once."""
+    stream = qwen.prefill_row_bytes(cfg, bucket)
+    return max(stream, 3 * bucket * cfg.s6_d_inner * 4) if cfg.count("s6") else stream
 
 
 def serving_config(cfg: HybridConfig, dtype: str) -> HybridConfig:
@@ -762,7 +934,30 @@ def _layer_shapes(cfg: HybridConfig) -> dict[str, dict[str, tuple[int, ...]]]:
     D, F = cfg.hidden_size, cfg.intermediate_size
     H, C = cfg.mamba_n_heads, cfg.conv_dim
     norms = {"input_norm": (D,), "post_norm": (D,)}
+    if cfg.norm_kind == "layer":
+        norms.update(input_norm_bias=(D,), post_norm_bias=(D,))
+    di = cfg.s6_d_inner
+    # an attending layer's query and output side, and its key and value side (a ``cross`` layer has the first alone)
+    bias = cfg.attn_bias
+    q_side = {"wq": (D, cfg.q_dim), "wo": (cfg.q_dim, D), **({"wq_b": (cfg.q_dim,), "wo_b": (D,)} if bias else {})}
+    kv_side = {"wk": (D, cfg.kv_dim), "wv": (D, cfg.kv_dim), **({"wk_b": (cfg.kv_dim,), "wv_b": (cfg.kv_dim,)} if bias else {})}
+    if cfg.diff_attn:  # lambda's four vectors and the norm over a pair's values [v1 | v2]
+        q_side.update({n: (cfg.head_dim_,) for n in ("lq1", "lk1", "lq2", "lk2")}, sub_norm=(2 * cfg.head_dim_,))
     mixers = {
+        "s6": {
+            "in_proj": (D, 2 * di),  # [u | z]
+            "conv_w": (cfg.mamba_d_conv, 1, di),  # as the Mamba-2 mixer's: tap k of channel c
+            "conv_b": (di,),
+            "x_proj": (di, cfg.s6_dt_rank + 2 * cfg.mamba_d_state),  # [r | B | C]
+            "dt_proj": (cfg.s6_dt_rank, di),
+            "dt_bias": (di,),
+            "A_log": (cfg.mamba_d_state, di),  # the checkpoint's [channels, state], transposed: channels on the lanes
+            "D": (di,),
+            "out_proj": (di, D),
+        },
+        "swa": {**q_side, **kv_side},
+        "cross": dict(q_side),
+        "gmu": {"gmu_in": (D, di), "gmu_out": (di, D)},
         "mamba": {
             "in_proj": (D, 2 * cfg.d_inner + 2 * cfg.mamba_n_groups * cfg.mamba_d_state + H),
             # the checkpoint's depthwise [C, 1, K] weight, reversed: tap k of
@@ -775,7 +970,7 @@ def _layer_shapes(cfg: HybridConfig) -> dict[str, dict[str, tuple[int, ...]]]:
             "ssm_norm": (cfg.d_inner,),
             "out_proj": (cfg.d_inner, D),
         },
-        "attention": {
+        "attention": {**q_side, **kv_side} if cfg.diff_attn else {
             "wq": (D, cfg.q_dim),
             "wk": (D, cfg.kv_dim),
             "wv": (D, cfg.kv_dim),
@@ -891,6 +1086,8 @@ def init_params(rng: jax.Array, cfg: HybridConfig, dtype=None) -> dict:
         "embed": dense((cfg.vocab_size, cfg.hidden_size)),
         "final_norm": jnp.ones((cfg.hidden_size,), dtype),
     }
+    if cfg.norm_kind == "layer":
+        params["final_norm_bias"] = jnp.zeros((cfg.hidden_size,), dtype)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = dense((cfg.vocab_size, cfg.hidden_size))
     sizes = _stack_sizes(cfg)
@@ -900,8 +1097,10 @@ def init_params(rng: jax.Array, cfg: HybridConfig, dtype=None) -> dict:
             full = (sizes[name], *shape)
             if leaf.endswith("norm") or leaf == "D":
                 stack[leaf] = jnp.ones(full, dtype)
-            elif leaf in ("conv_b", "wi_k_norm_bias"):
+            elif leaf in ("conv_b", "wi_k_norm_bias") or leaf.endswith(("_norm_bias", "_b")):
                 stack[leaf] = jnp.zeros(full, dtype)
+            elif leaf in ("lq1", "lk1", "lq2", "lk2"):  # lambda's vectors: N(0, 0.1), as the Differential Transformer draws them
+                stack[leaf] = (0.1 * jax.random.normal(next(keys), full, jnp.float32)).astype(dtype)
             elif leaf == "A_log":
                 stack[leaf] = jnp.log(jax.random.uniform(next(keys), full, jnp.float32, 1.0, 16.0)).astype(dtype)
             elif leaf == "dt_bias":
@@ -919,6 +1118,8 @@ def param_partition_specs(cfg: HybridConfig, fsdp_axis: str | None = "fsdp") -> 
     experts over chips, is ROADMAP Reach A.7."""
     del fsdp_axis
     specs: dict[str, Any] = {"embed": P(), "final_norm": P()}
+    if cfg.norm_kind == "layer":
+        specs["final_norm_bias"] = P()
     if not cfg.tie_word_embeddings:
         specs["lm_head"] = P()
     for name, shapes in _layer_shapes(cfg).items():
@@ -1037,12 +1238,53 @@ _HF_LAYER_MAPS = {
     },
 }
 _HF_LAYER_MAPS["glm_moe_dsa"] = _HF_LAYER_MAPS["deepseek_v3"]  # the same block; the index's names are in it
+# ``phi4flash``: UNCHECKED (no network, and the installed transformers has no ``phi4flash``). The names are the
+# family's released ``modeling_phi4flash.py`` as ISSUE 43 reads it: ``attn`` (``Wqkv`` fused there: a loader splits
+# it into the three names below, the query's rows first), ``attn.inner_cross_attn.*`` for lambda's vectors and the
+# norm over a pair's values, ``attn`` again for a Mamba layer's mixer (the block calls either ``attn``), ``mlp.fc1``
+# the fused [gate | up]; a memory unit's two matrices are the layer's ``attn.in_proj`` / ``attn.out_proj``.
+_HF_LAYER_MAPS["phi4flash"] = {
+    "input_norm": ("input_layernorm.weight", False),
+    "input_norm_bias": ("input_layernorm.bias", False),
+    "post_norm": ("post_attention_layernorm.weight", False),
+    "post_norm_bias": ("post_attention_layernorm.bias", False),
+    "w_gate_up": ("mlp.fc1.weight", True),
+    "w_down": ("mlp.fc2.weight", True),
+    "in_proj": ("attn.in_proj.weight", True),
+    "conv_w": ("attn.conv1d.weight", True),
+    "conv_b": ("attn.conv1d.bias", False),
+    "x_proj": ("attn.x_proj.weight", True),
+    "dt_proj": ("attn.dt_proj.weight", True),
+    "dt_bias": ("attn.dt_proj.bias", False),
+    "A_log": ("attn.A_log", True),
+    "D": ("attn.D", False),
+    "out_proj": ("attn.out_proj.weight", True),
+    "wq": ("attn.Wqkv.q.weight", True),
+    "wk": ("attn.Wqkv.k.weight", True),
+    "wv": ("attn.Wqkv.v.weight", True),
+    "wq_b": ("attn.Wqkv.q.bias", False),
+    "wk_b": ("attn.Wqkv.k.bias", False),
+    "wv_b": ("attn.Wqkv.v.bias", False),
+    "wo": ("attn.out_proj.weight", True),
+    "wo_b": ("attn.out_proj.bias", False),
+    "lq1": ("attn.inner_cross_attn.lambda_q1", False),
+    "lk1": ("attn.inner_cross_attn.lambda_k1", False),
+    "lq2": ("attn.inner_cross_attn.lambda_q2", False),
+    "lk2": ("attn.inner_cross_attn.lambda_k2", False),
+    "sub_norm": ("attn.inner_cross_attn.subln.weight", False),
+    "gmu_in": ("attn.in_proj.weight", True),
+    "gmu_out": ("attn.out_proj.weight", True),
+}
 _HF_TOP = {
     "deepseek_v3": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "glm_moe_dsa": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "granitemoehybrid": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "lfm2_moe": {"embed": "model.embed_tokens.weight", "final_norm": "model.embedding_norm.weight"},
     "olmo_hybrid": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
+    "phi4flash": {
+        "embed": "model.embed_tokens.weight", "final_norm": "model.final_layernorm.weight",
+        "final_norm_bias": "model.final_layernorm.bias",
+    },
 }
 
 
@@ -1902,14 +2144,253 @@ def mla_absorbed_out(cfg: HybridConfig, layer: dict, o_lat):
 
 
 # ---------------------------------------------------------------------------
+# a decoder-hybrid-decoder's mixers: the selective scan (Mamba-1), the gated
+# memory unit, differential attention over a window, every token, or another
+# layer's pages
+# ---------------------------------------------------------------------------
+
+
+def _s6_gates(cfg: HybridConfig, layer: dict, c):
+    """The input-dependent parts of the scan from c [..., channels] (after
+    the conv and SiLU, float32): (step size d [..., channels] after its
+    softplus, B and C [..., state size]), float32. ``[r | B | C] = W_x c``,
+    ``d = softplus(W_dt r + dt_bias)``."""
+    R, N = cfg.s6_dt_rank, cfg.mamba_d_state
+    r, b, cc = jnp.split(_proj(cfg, layer, "x_proj", c.astype(cfg.jax_dtype)), [R, R + N], axis=-1)
+    d = jax.nn.softplus(_proj(cfg, layer, "dt_proj", r).astype(jnp.float32) + layer["dt_bias"].astype(jnp.float32))
+    return d, b.astype(jnp.float32), cc.astype(jnp.float32)
+
+
+def s6_decode_step(ssm, c, d, b, cc, a, active):
+    """The recurrence, one token for each of S slots: ``S = exp(d (x) A) * S
+    + (d * c) B^T``, ``y = S C``. ssm [S, N, channels] (its own dtype,
+    computed in float32), c and d [S, channels], b and cc [S, N], a = -exp(
+    A_log) [N, channels]. Returns (new state, y [S, channels] float32). A
+    slot that is not ``active`` keeps its state bit for bit."""
+    new = ssm.astype(jnp.float32) * jnp.exp(d[:, None, :] * a[None]) + (d * c)[:, None, :] * b[:, :, None]
+    y = jnp.sum(new * cc[:, :, None], axis=1)
+    return jnp.where(active[:, None, None], new.astype(ssm.dtype), ssm), y
+
+
+def s6_scan(c, d, b, cc, a, n_state, state_dtype=jnp.float32, unroll: int = 8):
+    """The same recurrence over whole prompts, token by token: c and d [A, L,
+    channels], b and cc [A, L, N], n_state [A]: only the first ``n_state``
+    tokens of a row enter its state (``d`` is 0 from there on: no decay, no
+    input; ``y`` there is not the model's). Starts from the zero state and
+    carries [A, N, channels] alone: nothing of [L, channels, N] exists.
+    Returns (state after n_state tokens, y [A, L, channels] float32)."""
+    A, L, C = c.shape
+    d = jnp.where(jnp.arange(L)[None, :, None] < n_state[:, None, None], d, 0.0)
+
+    def token(s, x):
+        d_t, c_t, b_t, cc_t = x
+        s = s * jnp.exp(d_t[:, None, :] * a[None]) + (d_t * c_t)[:, None, :] * b_t[:, :, None]
+        return s, jnp.sum(s * cc_t[:, :, None], axis=1)
+
+    s0 = jnp.zeros((A, a.shape[0], C), jnp.float32)
+    s, y = jax.lax.scan(token, s0, tuple(jnp.swapaxes(t, 0, 1) for t in (d, c, b, cc)), unroll=min(unroll, L))
+    return s.astype(state_dtype), jnp.swapaxes(y, 0, 1)
+
+
+def s6_decode(cfg: HybridConfig, layer: dict, h, state: dict, j, active):
+    """Mixer for one token a slot. h [S, D] (normed); ``state`` holds every
+    selective-scan layer's slot state, ``ssm`` [n, S, N, channels] and
+    ``conv`` [n, S, (K-1) * channels], of which this is layer ``j``. Returns
+    (out [S, D], the state with layer j advanced, the scan's output y [S,
+    channels] float32 BEFORE its gate: what a memory unit further up reads);
+    rows that are not ``active`` keep theirs."""
+    conv = jax.lax.dynamic_index_in_dim(state["conv"], j, 0, keepdims=False)
+    with jax.named_scope("ssm_proj"):
+        u, z = jnp.split(_proj(cfg, layer, "in_proj", h), 2, axis=-1)
+    with jax.named_scope("ssm_conv"):
+        acc, new_conv = _conv_window_step(conv, u, *_conv_taps(layer), active)
+        c = jax.nn.silu(acc)
+    with jax.named_scope("ssm_proj"):
+        d, b, cc = _s6_gates(cfg, layer, c)
+    with jax.named_scope("ssm_state"):
+        ssm = jax.lax.dynamic_index_in_dim(state["ssm"], j, 0, keepdims=False)
+        ssm, y = s6_decode_step(ssm, c, d, b, cc, -jnp.exp(layer["A_log"].astype(jnp.float32)), active)
+        y = y + layer["D"].astype(jnp.float32) * c
+        g = (y * jax.nn.silu(z.astype(jnp.float32))).astype(h.dtype)
+    with jax.named_scope("state_write"):
+        state = {
+            "ssm": jax.lax.dynamic_update_index_in_dim(state["ssm"], ssm, j, 0),
+            "conv": jax.lax.dynamic_update_index_in_dim(state["conv"], new_conv, j, 0),
+        }
+    with jax.named_scope("ssm_proj"):
+        return _proj(cfg, layer, "out_proj", g), state, y
+
+
+def s6_prefill(cfg: HybridConfig, layer: dict, h, n_state, state_dtypes):
+    """Mixer over whole prompts. h [A, L, D] (normed), n_state [A]. Returns
+    (out [A, L, D], state after n_state tokens, conv window of the last K-1
+    of those tokens, the scan's output y [A, L, channels] float32 before its
+    gate)."""
+    A, L, _ = h.shape
+    K = cfg.mamba_d_conv
+    with jax.named_scope("ssm_proj"):
+        u, z = jnp.split(_proj(cfg, layer, "in_proj", h), 2, axis=-1)
+    with jax.named_scope("ssm_conv"):
+        w, bias = _conv_taps(layer)
+        padded = jnp.pad(u, ((0, 0), (K - 1, 0), (0, 0)))  # position t at row t + K - 1
+        acc = bias
+        for k in range(K):
+            acc = acc + padded[:, k : k + L].astype(jnp.float32) * w[k]
+        c = jax.nn.silu(acc)
+        conv = _window_after(padded, n_state, K)
+    with jax.named_scope("ssm_proj"):
+        d, b, cc = _s6_gates(cfg, layer, c)
+    with jax.named_scope("ssm_state"):
+        ssm, y = s6_scan(c, d, b, cc, -jnp.exp(layer["A_log"].astype(jnp.float32)), n_state, state_dtypes[0])
+        y = y + layer["D"].astype(jnp.float32) * c
+        g = (y * jax.nn.silu(z.astype(jnp.float32))).astype(h.dtype)
+    with jax.named_scope("ssm_proj"):
+        return _proj(cfg, layer, "out_proj", g), ssm, conv.astype(state_dtypes[1]), y
+
+
+def gmu_mix(cfg: HybridConfig, layer: dict, h, m):
+    """A gated memory unit: ``W_o (m * silu(W_g h))``, ``m`` the scan output
+    (float32, before its gate) of the last selective-scan layer for the SAME
+    token. No state of its own."""
+    with jax.named_scope("gmu"):
+        g = jax.nn.silu(_proj(cfg, layer, "gmu_in", h).astype(jnp.float32))
+        return _proj(cfg, layer, "gmu_out", (m * g).astype(h.dtype))
+
+
+def _diff_proj(cfg: HybridConfig, layer: dict, name: str, h, heads: int, width: int):
+    out = _proj(cfg, layer, name, h)
+    if cfg.attn_bias:
+        out = out + layer[f"{name}_b"]
+    return out.reshape(*h.shape[:-1], heads, width)
+
+
+def _diff_qkv(cfg: HybridConfig, layer: dict, h, query: bool = True, kv: bool = True):
+    """q [..., H, hd] (heads 2p and 2p+1 are q1 and q2 of differential head
+    p) and k, v [..., KH / 2, 2 hd]: pair r's [k1 | k2] and [v1 | v2], which
+    is the projection's own order of heads, two to a row."""
+    q = _diff_proj(cfg, layer, "wq", h, cfg.num_heads, cfg.head_dim_) if query else None
+    if not kv:
+        return q, None, None
+    k = _diff_proj(cfg, layer, "wk", h, cfg.kv_pool_heads, cfg.kv_head_dim)
+    v = _diff_proj(cfg, layer, "wv", h, cfg.kv_pool_heads, cfg.kv_head_dim)
+    return q, k, v
+
+
+def _diff_pack_q(q):
+    """[..., H, hd] -> [..., H, 2 hd]: q1 of a differential head as [q1 | 0]
+    and q2 as [0 | q2], so that against a row [k1 | k2] each meets its own
+    key, and 4 consecutive query heads read one row: the paged kernels'
+    grouped-query shape at 128 lanes."""
+    *lead, H, hd = q.shape
+    qp = q.reshape(*lead, H // 2, 2, hd)
+    zero = jnp.zeros_like(qp[..., 0, :])
+    first = jnp.concatenate([qp[..., 0, :], zero], axis=-1)
+    second = jnp.concatenate([zero, qp[..., 1, :]], axis=-1)
+    return jnp.stack([first, second], axis=-2).reshape(*lead, H, 2 * hd)
+
+
+def _diff_merge(cfg: HybridConfig, layer: dict, o, depth, dtype):
+    """What differential attention does behind the two softmaxes. o [..., H,
+    2 hd]: head 2p read pair p // 2's [v1 | v2] under softmax(q1 k1^T), head
+    2p+1 under softmax(q2 k2^T). Returns ``(1 - l0) rmsnorm(o1 - lam o2)``
+    flat [..., H * hd], ``lam = exp(lq1 . lk1) - exp(lq2 . lk2) + l0``, ``l0 =
+    0.8 - 0.6 exp(-0.3 depth)``, ``depth`` the layer's index in the model."""
+    with jax.named_scope("attn_diff"):
+        f32 = jnp.float32
+        l0 = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(depth, f32))
+        lam = (
+            jnp.exp(jnp.sum(layer["lq1"].astype(f32) * layer["lk1"].astype(f32)))
+            - jnp.exp(jnp.sum(layer["lq2"].astype(f32) * layer["lk2"].astype(f32)))
+            + l0
+        )
+        *lead, H, wide = o.shape
+        o = o.astype(f32).reshape(*lead, H // 2, 2, wide)
+        diff = o[..., 0, :] - lam * o[..., 1, :]
+        diff = diff * jax.lax.rsqrt(jnp.mean(diff * diff, axis=-1, keepdims=True) + cfg.rms_norm_eps)
+        return (diff * layer["sub_norm"].astype(f32) * (1.0 - l0)).reshape(*lead, H // 2 * wide).astype(dtype)
+
+
+def _depths(cfg: HybridConfig) -> dict[str, jax.Array]:
+    """{attending kind: the model indices of its layers, by their index within
+    the kind}: what ``_diff_merge`` takes its ``depth`` from inside a scan."""
+    return {kind: jnp.asarray(cfg.layers_of(kind) or (0,), jnp.int32) for kind in ("swa", "attention", "cross")}
+
+
+def _diff_out(cfg: HybridConfig, layer: dict, o):
+    out = _proj(cfg, layer, "wo", o)
+    return out + layer["wo_b"] if cfg.attn_bias else out
+
+
+def diff_attend(cfg: HybridConfig, q, k, v, allowed):
+    """Both softmaxes of differential attention for one sequence, dense: q
+    [T, H, hd], k and v [U, KH / 2, 2 hd], ``allowed`` [T, U] bool. Returns
+    [T, H, 2 hd] float32 (``_diff_merge`` takes it from there). A query that
+    is allowed no key gets the mean of the values, never a NaN."""
+    T, U, hd = q.shape[0], k.shape[0], cfg.head_dim_
+    qp = q.reshape(T, cfg.kv_pool_heads, 2, 2, hd)  # [T, pair r, differential head 2r + a, s, hd]
+    kp = k.reshape(U, cfg.kv_pool_heads, 2, hd)  # [U, r, s, hd]
+    logits = jnp.einsum("trasd,ursd->rastu", qp, kp).astype(jnp.float32) * cfg.sm_scale
+    probs = jax.nn.softmax(jnp.where(allowed[None, None, None], logits, -1e30), axis=-1).astype(v.dtype)
+    out = jnp.einsum("rastu,ure->trase", probs, v, preferred_element_type=jnp.float32)
+    return out.reshape(T, cfg.num_heads, 2 * hd)
+
+
+def swa_attend(cfg: HybridConfig, q, k, v):
+    """Window attention over whole prompts, O(L x window): q [A, L, H, hd],
+    k and v [A, L, KH / 2, 2 hd]; a query at t attends to keys t - window + 1
+    .. t. Blocks of ``window`` queries each meet the block before and their
+    own, one block at a time. Returns [A, L, H, 2 hd] float32."""
+    A, L = q.shape[:2]
+    B = cfg.sliding_window
+    pad = (-L) % B
+    if pad:
+        q, k, v = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)) for t in (q, k, v))
+    nb = (L + pad) // B
+
+    def blocks(t):  # [A, L, ...] -> [A * nb, B, ...]
+        return t.reshape(A * nb, B, *t.shape[2:])
+
+    def with_before(t):  # a block's keys: the block before (zeros before the first) and its own, [A * nb, 2B, ...]
+        tb = t.reshape(A, nb, B, *t.shape[2:])
+        before = jnp.concatenate([jnp.zeros_like(tb[:, :1]), tb[:, :-1]], axis=1)
+        return jnp.concatenate([before, tb], axis=2).reshape(A * nb, 2 * B, *t.shape[2:])
+
+    t_at, u_at = jnp.arange(B)[:, None], jnp.arange(2 * B)[None, :] - B  # key u of the pair stands at u_at relative to the block's first query
+    band = (u_at <= t_at) & (t_at - u_at < B)
+
+    def one(args):
+        qi, ki, vi, first = args
+        return diff_attend(cfg, qi, ki, vi, band & (~first | (u_at >= 0)))
+
+    first = (jnp.arange(A * nb) % nb) == 0
+    out = jax.lax.map(one, (blocks(q), with_before(k), with_before(v), first))
+    return out.reshape(A, L + pad, *out.shape[2:])[:, :L]
+
+
+# ---------------------------------------------------------------------------
 # the layer stack
 # ---------------------------------------------------------------------------
 
 
+def _norm(cfg: HybridConfig, x, w, b=None):
+    """The model's norm: RMSNorm, or (``norm_kind`` ``layer``) LayerNorm with
+    weight ``w`` and bias ``b``, the statistics in float32."""
+    if cfg.norm_kind != "layer":
+        return _rms_norm(x, w, cfg.rms_norm_eps)
+    x32 = x.astype(jnp.float32)
+    mu = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mu), axis=-1, keepdims=True)
+    return ((x32 - mu) * jax.lax.rsqrt(var + cfg.rms_norm_eps)).astype(x.dtype) * w + b
+
+
+def _final_norm(params: dict, cfg: HybridConfig, x):
+    return _norm(cfg, x, params["final_norm"], params.get("final_norm_bias"))
+
+
 def _norm_in(cfg: HybridConfig, layer: dict, name: str, x):
-    """What a sublayer reads: rmsnorm(x) in a pre-norm block, x itself where
+    """What a sublayer reads: norm(x) in a pre-norm block, x itself where
     the norm stands on the sublayer's output."""
-    return _rms_norm(x, layer[name], cfg.rms_norm_eps) if cfg.norm_placement == "pre" else x
+    return _norm(cfg, x, layer[name], layer.get(f"{name}_bias")) if cfg.norm_placement == "pre" else x
 
 
 def _norm_out(cfg: HybridConfig, layer: dict, name: str, out):
@@ -1977,7 +2458,10 @@ def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None):
 
 
 # the scope a mixer's norm counts under (its projections')
-_MIXER_SCOPE = {"mamba": "ssm_proj", "gdn": "gdn_proj", "conv": "conv_proj", "attention": "attn_proj", "mla": "mla_proj"}
+_MIXER_SCOPE = {
+    "mamba": "ssm_proj", "gdn": "gdn_proj", "conv": "conv_proj", "attention": "attn_proj", "mla": "mla_proj",
+    "s6": "ssm_proj", "swa": "attn_proj", "cross": "attn_proj", "gmu": "gmu",
+}
 
 
 def _runs(cfg: HybridConfig) -> list[tuple[str, str, int, int, int, int]]:
@@ -1997,25 +2481,73 @@ def _runs(cfg: HybridConfig) -> list[tuple[str, str, int, int, int, int]]:
     return out
 
 
-def _scan_layers(cfg: HybridConfig, params: dict, carry, step, whole: tuple[str, ...] = ()):
+def _periods(cfg: HybridConfig) -> list[tuple[tuple[tuple[str, str, int, int, int, int], ...], int, int, int]]:
+    """The runs as ``_scan_layers`` walks them: (the runs of one period, how
+    many periods, the first layer's model index, layers). A run of one kind
+    is a period of itself. Where single layers of TWO kinds alternate (a, b,
+    a, b, ...) twice or more, the pairs are one group: one scan body of two
+    layers, not a body a layer (a decoder-hybrid-decoder alternates all the
+    way down: 32 bodies to trace and compile otherwise)."""
+    runs = _runs(cfg)
+    out, r, at = [], 0, 0
+    while r < len(runs):
+        pair = runs[r : r + 2]
+        n = 0
+        if len(pair) == 2 and pair[0][5] == pair[1][5] == 1 and pair[0][:2] != pair[1][:2]:
+            while all(
+                r + 2 * n + e < len(runs) and runs[r + 2 * n + e][5] == 1 and runs[r + 2 * n + e][:2] == pair[e][:2]
+                for e in (0, 1)
+            ):
+                n += 1
+        if n >= 2:
+            out.append((tuple(pair), n, at, 2 * n))
+            r, at = r + 2 * n, at + 2 * n
+        else:
+            out.append(((runs[r],), runs[r][5], at, runs[r][5]))
+            r, at = r + 1, at + runs[r][5]
+    return out
+
+
+def _scan_layers(cfg: HybridConfig, params: dict, carry, step, whole: tuple[str, ...] = (), layers: tuple[int, int] | None = None):
     """Run ``step(kind, ffn, carry, layer, j, f) -> carry`` over the layers in
     model order; ``layer`` is the layer's slice of its stack, ``j`` its index
     among the layers of its mixer kind (the state's and the KV pool's layer
     axis) and ``f`` among those of its FFN kind, both traced. One
-    ``lax.scan`` per run of one kind.
+    ``lax.scan`` per run of one kind, or per group of alternating pairs
+    (``_periods``). ``layers`` = (first, end) walks that range of model
+    indices alone; it may not cut a run.
 
     A leaf named in ``whole`` is handed over as ``moe.Stacked``, the stack
     and the layer's index in it, as the page pools and the recurrent state go
     by ``j``: for a Pallas launch that reads its layer where it lies."""
-    for kind, ffn, lo, lo_kind, lo_ffn, n in _runs(cfg):
-        stack = params[stack_name(kind, ffn)]
+    first, end = layers or (0, cfg.num_layers)
 
-        def body(c, i, kind=kind, ffn=ffn, stack=stack, dj=lo_kind - lo, df=lo_ffn - lo):
-            sliced = functools.partial(jax.lax.dynamic_index_in_dim, index=i, axis=0, keepdims=False)
-            layer = {k: moe.Stacked(a, i) if k in whole else jax.tree.map(sliced, a) for k, a in stack.items()}
-            return step(kind, ffn, c, layer, i + dj if dj else i, i + df if df else i), None
+    def layer_at(stack, i):
+        sliced = functools.partial(jax.lax.dynamic_index_in_dim, index=i, axis=0, keepdims=False)
+        return {k: moe.Stacked(a, i) if k in whole else jax.tree.map(sliced, a) for k, a in stack.items()}
 
-        carry, _ = jax.lax.scan(body, carry, jnp.arange(lo, lo + n, dtype=jnp.int32))
+    for group, n, at, span in _periods(cfg):
+        if at + span <= first or at >= end:
+            continue
+        assert first <= at and at + span <= end, f"layers {first}..{end} cut the run at {at}..{at + span}"
+        if len(group) == 1:
+            kind, ffn, lo, lo_kind, lo_ffn, _ = group[0]
+            stack = params[stack_name(kind, ffn)]
+
+            def body(c, i, kind=kind, ffn=ffn, stack=stack, dj=lo_kind - lo, df=lo_ffn - lo):
+                return step(kind, ffn, c, layer_at(stack, i), i + dj if dj else i, i + df if df else i), None
+
+            carry, _ = jax.lax.scan(body, carry, jnp.arange(lo, lo + n, dtype=jnp.int32))
+            continue
+        # how far a period moves each index: by its layers of that FFN kind (the mixers, and so the stacks, differ)
+        ffn_stride = {ffn: sum(1 for r in group if r[1] == ffn) for _, ffn, *_ in group}
+
+        def body(c, i, group=group, ffn_stride=ffn_stride):
+            for kind, ffn, lo, lo_kind, lo_ffn, _ in group:
+                c = step(kind, ffn, c, layer_at(params[stack_name(kind, ffn)], lo + i), lo_kind + i, lo_ffn + ffn_stride[ffn] * i)
+            return c, None
+
+        carry, _ = jax.lax.scan(body, carry, jnp.arange(n, dtype=jnp.int32))
     return carry
 
 
@@ -2069,6 +2601,7 @@ def forward_prefill(
     seg: jax.Array,  # [A, L] 1=valid 0=pad
     n_state: jax.Array | None = None,  # [A] tokens that enter the state; default all valid
     sink: tuple | None = None,
+    tail: str = "all",
 ):
     """Batched prompt pass. Returns (hidden [A, L, D], ks, vs
     [n_attention, A, L, KH, kv_head_dim], state); for a latent-attention
@@ -2085,7 +2618,19 @@ def forward_prefill(
     slot rows, so no second copy of A states exists). A sink that holds
     ``k`` also takes a latent model's rows layer by layer, ``{"k": rows
     [A, L, 1, latent_lanes]}`` (and ``"idx"``: the index keys), and ks and vs
-    are then None."""
+    are then None. A sink that holds the window layers' rings takes their K
+    and V rows layer by layer too (``{"ring_k", "ring_v"}: [A, L, heads,
+    lanes]``: which of them a ring keeps is the sink's to say).
+
+    ``tail`` is a decoder-hybrid-decoder's: what the layers PAST the one
+    whose keys and values they read compute. ``all``: every token, as every
+    other model (quadratic in L: tests and short prompts). ``last``: the
+    architecture's own prompt pass: the self-decoder and the shared layer's
+    K and V over every token, its attention and the cross-decoder over each
+    row's LAST valid token alone; hidden is [A, 1, D]. ``none``: the same
+    without the last token's row, hidden None: what the engine's prefill
+    program needs, since its first decode step feeds the last prompt token
+    again."""
     A, L = input_ids.shape
     if n_state is None:
         n_state = jnp.sum(seg, axis=-1)
@@ -2107,10 +2652,22 @@ def forward_prefill(
     k_shape, v_shape = ((cfg.num_kv_layers, A, L, *pool) if pool else () for pool in (cfg.kv_pools["k"], second))
     rows_to_sink = latent and "k" in arrays  # the sink takes the latent rows too
     # a latent layer makes its own masks a block at a time: no [A, 1, L, L] for a 16k prompt
-    mask = None if latent else qwen._attention_mask(seg)
+    mask = None if latent or cfg.diff_attn else qwen._attention_mask(seg)  # (differential attention masks by position, a row at a time)
     positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (A, L))
     live = seg.astype(bool)
     rm = cfg.residual_multiplier
+    # a decoder-hybrid-decoder: the one layer whose K and V the cross layers read, and where the rows narrow to each
+    # prompt's last token (``tail``)
+    shared = cfg.layers_of("attention")[0] if cfg.count("cross") else None
+    assert tail == "all" or shared is not None, "tail is a decoder-hybrid-decoder's"
+    depth_of = _depths(cfg)
+    last_at = jnp.maximum(jnp.sum(seg, axis=-1).astype(jnp.int32) - 1, 0)  # [A]
+
+    def at_last(t):  # [A, L, ...] -> [A, 1, ...]: each row's last valid token
+        return jnp.take_along_axis(t, last_at.reshape(A, *(1,) * (t.ndim - 1)), axis=1)
+
+    def diff_rows(q, k, v, q_pos):  # causal differential attention a row at a time: q [A, T, H, hd] at positions q_pos [A, T]
+        return jax.lax.map(lambda a: diff_attend(cfg, a[0], a[1], a[2], jnp.arange(L)[None, :] <= a[3][:, None]), (q, k, v, q_pos))
 
     def attend(args):  # one row at a time: [H, L, L] logits, not [A, H, L, L]
         q, k, v, m = args
@@ -2121,13 +2678,61 @@ def forward_prefill(
         probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
         return jnp.einsum("kgts,skd->tkgd", probs, v).reshape(L, cfg.q_dim)
 
-    def step(kind, ffn, carry, layer, j, f):
+    def step(kind, ffn, carry, layer, j, f, narrow=False):
         x, ks, vs, arr = carry
+        q_pos = last_at[:, None] if narrow else positions  # where the rows of x stand
         if kind == "mamba":
             h = _norm_in(cfg, layer, "input_norm", x)
             out, ssm, conv = mamba_prefill(cfg, layer, h, n_state, (dtypes["ssm"], dtypes["conv"]))
             with jax.named_scope("state_write"):
                 arr = write(arr, j, {"ssm": ssm, "conv": conv})
+        elif kind == "s6":
+            with jax.named_scope("ssm_proj"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+            out, ssm, conv, y = s6_prefill(cfg, layer, h, n_state, (dtypes["ssm"], dtypes["conv"]))
+            with jax.named_scope("state_write"):
+                arr = write(arr, j, {"ssm": ssm, "conv": conv})
+                if "gmu_m" in arr:  # the LAST such layer's stays: what the memory units read
+                    arr = {**arr, "gmu_m": y if tail == "all" else at_last(y)}
+        elif kind == "gmu":
+            with jax.named_scope("gmu"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+            out = gmu_mix(cfg, layer, h, arr["gmu_m"])
+        elif kind == "swa":
+            with jax.named_scope("attn_proj"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+                q, k, v = _diff_qkv(cfg, layer, h)
+            if "ring_k" in arr:
+                with jax.named_scope("kv_write"):
+                    arr = write(arr, j, {"ring_k": k, "ring_v": v})
+            with jax.named_scope("attn_window"):
+                o = swa_attend(cfg, q, k, v)
+            o = _diff_merge(cfg, layer, o, depth_of["swa"][j], x.dtype)
+            with jax.named_scope("attn_proj"):
+                out = _diff_out(cfg, layer, o)
+        elif kind == "cross":
+            with jax.named_scope("attn_proj"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+                q, _, _ = _diff_qkv(cfg, layer, h, kv=False)
+            with jax.named_scope("attn_cross"):
+                o = diff_rows(q, ks[0], vs[0], q_pos)
+            o = _diff_merge(cfg, layer, o, depth_of["cross"][j], x.dtype)
+            with jax.named_scope("attn_proj"):
+                out = _diff_out(cfg, layer, o)
+        elif kind == "attention" and cfg.diff_attn:
+            with jax.named_scope("attn_proj"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+                # the keys and values of EVERY token; the queries of the rows that go on (``narrow``: the last alone)
+                _, k, v = _diff_qkv(cfg, layer, h, query=False)
+                ks, vs = ks.at[j].set(k), vs.at[j].set(v)
+                if narrow:
+                    x, h = at_last(x), at_last(h)
+                q, _, _ = _diff_qkv(cfg, layer, h, kv=False)
+            with jax.named_scope("attn"):
+                o = diff_rows(q, k, v, q_pos)
+            o = _diff_merge(cfg, layer, o, depth_of["attention"][j], x.dtype)
+            with jax.named_scope("attn_proj"):
+                out = _diff_out(cfg, layer, o)
         elif kind == "gdn":
             with jax.named_scope("gdn_proj"):
                 h = _norm_in(cfg, layer, "input_norm", x)
@@ -2170,10 +2775,12 @@ def forward_prefill(
                 out = _proj(cfg, layer, "wo", attn)
         with jax.named_scope(_MIXER_SCOPE[kind]):
             out = _norm_out(cfg, layer, "input_norm", out)
-        x, _ = _ffn(cfg, ffn, layer, x + rm * out, live)
+        x, _ = _ffn(cfg, ffn, layer, x + rm * out, None if narrow else live)
         return x, ks, vs, arr
 
     x = _embed(params, cfg, input_ids)
+    if cfg.count("gmu") and tail != "none":  # the scan output the memory units read rides with the state
+        arrays = {**arrays, "gmu_m": jnp.zeros((A, L if tail == "all" else 1, cfg.s6_d_inner), jnp.float32)}
     # a latent model has no V rows, and none to collect where the sink takes
     # them: a scalar rides in their place
     carry = (
@@ -2185,9 +2792,22 @@ def forward_prefill(
     # a prompt pass's rows go through grouped matmuls (moe.takes_dense_form says when), which read the expert
     # stacks where they lie: a layer's three matrices sliced out for them would be copied, once a layer
     routed = cfg.num_moe_layers and not moe.takes_dense_form(ffn_block_rows(cfg, "moe", A * L), cfg.num_experts)
-    x, ks, vs, arrays = _scan_layers(cfg, params, carry, step, whole=moe.EXPERT_LEAVES if routed else ())
+    if tail == "all":
+        x, ks, vs, arrays = _scan_layers(cfg, params, carry, step, whole=moe.EXPERT_LEAVES if routed else ())
+    else:
+        # the self-decoder over every token; the shared layer's K and V over every token and, with the
+        # cross-decoder behind it, each row's last token alone
+        x, ks, vs, arrays = _scan_layers(cfg, params, carry, step, layers=(0, shared))
+        layer = jax.tree.map(lambda a: a[0], params[stack_name("attention", cfg.ffns[shared])])
+        if tail == "none":
+            with jax.named_scope("attn_proj"):
+                _, k, v = _diff_qkv(cfg, layer, _norm_in(cfg, layer, "input_norm", x), query=False)
+            return None, ks.at[0].set(k), vs.at[0].set(v), arrays
+        carry = step("attention", cfg.ffns[shared], (x, ks, vs, arrays), layer, 0, 0, narrow=True)
+        x, ks, vs, arrays = _scan_layers(cfg, params, carry, functools.partial(step, narrow=True), layers=(shared + 1, cfg.num_layers))
+    arrays = {k: v for k, v in arrays.items() if k != "gmu_m"}
     with jax.named_scope("lm_head"):
-        hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        hidden = _final_norm(params, cfg, x)
     return hidden, (None if rows_to_sink else ks), (vs if v_shape and not rows_to_sink else None), arrays
 
 
@@ -2224,8 +2844,8 @@ def prefill_into_cache(
 
         *_, pages = forward_prefill(params, cfg, ids, seg, sink=({n: cache[n] for n in cfg.kv_pools}, put))
         return {**cache, **pages}
-    state = {k: cache[k] for k in paged_kv.STATE_LEAVES if k in cache}
-    n_slots = next(iter(state.values())).shape[1] if state else 0
+    state = {k: cache[k] for k in paged_kv.STATE_LEAVES + paged_kv.RING_LEAVES if k in cache}
+    n_slots = next((v.shape[1] for k, v in state.items() if k in paged_kv.STATE_LEAVES), 0)
 
     def write(arr, j, new):
         # one dynamic-update-slice a row, a padding row rewriting what its
@@ -2236,13 +2856,27 @@ def prefill_into_cache(
         for i in range(ids.shape[0]):
             at = jnp.minimum(slots[i], n_slots - 1)
             for name, rows in new.items():
+                if name in paged_kv.RING_LEAVES:
+                    # a window layer's rows [A, L, heads, lanes]: the slot's ring takes the last ``sliding_window`` of
+                    # the prompt's tokens, token t at ring position t % sliding_window (a padding row: the block past
+                    # the last slot)
+                    # ONE HEAD AN UPDATE: a window that spans (heads, lanes) makes the TPU compiler lay the whole carried
+                    # ring out head-minor, and copy it in and out of the loop (paged_kv.write_decode_rows has the same)
+                    ring = paged_kv.ring_of_rows(rows[i], plens[i], cfg.sliding_window, arr[name].shape[3:5]).astype(arr[name].dtype)
+                    for head in range(ring.shape[0]):
+                        start = (j, head, jnp.minimum(slots[i], n_slots), 0, 0, 0)
+                        arr[name] = jax.lax.dynamic_update_slice(arr[name], ring[None, head : head + 1, None], start)
+                    continue
                 start = (j, at) + (0,) * (rows.ndim - 1)
                 old = jax.lax.dynamic_slice(arr[name], start, (1, 1) + rows.shape[1:])
                 row = jnp.where(slots[i] < n_slots, rows[i][None, None].astype(old.dtype), old)
                 arr[name] = jax.lax.dynamic_update_slice(arr[name], row, start)
         return arr
 
-    _, ks, vs, state = forward_prefill(params, cfg, ids, seg, n_state=plens - 1, sink=(state, write))
+    # a decoder-hybrid-decoder's prompt pass ends at the shared layer's K and V: the decode step that feeds the last
+    # prompt token again is the ONE row of its cross-decoder a prompt costs
+    tail = "none" if cfg.count("cross") else "all"
+    _, ks, vs, state = forward_prefill(params, cfg, ids, seg, n_state=plens - 1, sink=(state, write), tail=tail)
     with jax.named_scope("kv_write"):
         cache = paged_kv.scatter_prefill(
             {k: v for k, v in cache.items() if k not in state}, ks, vs, flat_pages, page_size
@@ -2320,7 +2954,13 @@ def serving_limits(cfg: HybridConfig) -> dict[str, str]:
     module has no ``forward_prefill_paged`` / ``forward_verify_paged``, no
     int8 form of its mixers and experts, and no sharded form."""
     if cfg.has_recurrent_state:
+        rings = (
+            {"int8_pages": "quantized pages are not implemented for the window layers' rings; serve this model with kv_quantization='none'"}
+            if cfg.count("swa")
+            else {}
+        )
         return {
+            **rings,
             "reason": "recurrent_state",
             "prefix_cache": "the model has recurrent (state-space) layers and a cached page prefix carries no state",
             "speculative": (
@@ -2403,6 +3043,45 @@ def forward_decode_paged(
             kv_live = live_order(page_table[:, 0] != 0)  # the KV writer's: qwen.forward_decode_paged
     else:
         live = kv_live = kernel = None
+    if cfg.diff_attn:
+        # the window layers' rings (paged_kv.RING_LEAVES): token t at ring position t % window, min(t + 1, window) of
+        # its positions valid, a slot that is not live neither written (its row goes to the block past the last slot)
+        # nor read; the work lists are the same for every window layer, so they are made here, once a step
+        depth_of = _depths(cfg)
+        W, R = cfg.sliding_window, cfg.ring_pages(page_size)
+        with jax.named_scope("attn_window"):
+            ring_at = positions % max(W, 1)
+            ring_page = jnp.where(active, slot * R + ring_at // page_size, S * R)
+            ring_off = ring_at % page_size
+            ring_len = jnp.where(active, jnp.minimum(lengths, W), 0)
+            ring_tbl = paged_kv.ring_table(S, R)
+            ring_kernel = ring_live = None
+            if use_kernel and R:
+                ring_ppcb = paged_kv.choose_ppcb(R)
+                ring_kernel = dict(pages_per_compute_block=ring_ppcb, schedule=decode_schedule(ring_len, R, page_size, ring_ppcb))
+                ring_live = live_order(active)
+
+        def diff_read(q, k_pool, v_pool, j, lens, table, kern):
+            """Both softmaxes of every differential head over the cached rows [k1 | k2], [v1 | v2]: [S, H, 2 hd]
+            float32 (the queries go in float32: the two reads are subtracted, and nothing rounds them in between)."""
+            qp = _diff_pack_q(q).astype(jnp.float32)
+            if kern is not None:
+                return paged_attention_stacked(qp, k_pool, v_pool, j, lens, table, sm_scale=cfg.sm_scale, **kern)
+            k_j, v_j = (jax.lax.dynamic_index_in_dim(t, j, 0, keepdims=False) for t in (k_pool, v_pool))
+            return paged_kv.paged_attention_xla(qp, k_j, v_j.astype(jnp.float32), lens, table, sm_scale=cfg.sm_scale)
+
+        full_kernel = kernel if use_kernel else None
+        full_len = attn_lengths if use_kernel else lengths
+        n_live = jnp.sum(active, dtype=jnp.int32)
+        cached = jnp.sum(jnp.where(active, lengths, 0), dtype=jnp.int32)
+        for leaf, scope, n in (
+            ("shared_kv_tokens_read", "attn_cross", cached * (1 + cfg.count("cross"))),
+            ("window_tokens_read", "attn_window", jnp.sum(ring_len, dtype=jnp.int32) * cfg.count("swa")),
+            ("s6_updates", "ssm_state", n_live * cfg.count("s6")),
+        ):
+            if leaf in cache:
+                with jax.named_scope(scope):
+                    cache = {**cache, leaf: cache[leaf] + n}
     rm = cfg.residual_multiplier
     # the expert matmuls read the touched experts only where a full batch gives an expert a handful of rows
     # (a Pallas launch over the expert stacks; off a TPU, XLA's form whatever the shapes: moe.takes_touched_form)
@@ -2418,6 +3097,39 @@ def forward_decode_paged(
             h = _norm_in(cfg, layer, "input_norm", x)
             out, state = mamba_decode(cfg, layer, h, {k: c[k] for k in ("ssm", "conv")}, j, active, live)
             c.update(state)
+        elif kind == "s6":
+            with jax.named_scope("ssm_proj"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+            out, state, y = s6_decode(cfg, layer, h, {k: c[k] for k in ("ssm", "conv")}, j, active)
+            c.update(state)
+            if "gmu_m" in c:  # the LAST such layer's stays: this step's value, for the memory units further up
+                c["gmu_m"] = y
+        elif kind == "gmu":
+            with jax.named_scope("gmu"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+            out = gmu_mix(cfg, layer, h, c["gmu_m"])
+        elif kind in ("swa", "cross") or (kind == "attention" and cfg.diff_attn):
+            with jax.named_scope("attn_proj"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+                q, k, v = _diff_qkv(cfg, layer, h, kv=kind != "cross")
+            if kind == "swa":
+                with jax.named_scope("kv_write"):
+                    pools = {n: paged_kv.ring_pool(c[n]) for n in paged_kv.RING_LEAVES}
+                    pools = paged_kv.write_decode_rows(pools, j, k, v, ring_page, ring_off, ring_live, pools=paged_kv.RING_LEAVES)
+                    c.update({n: pools[n].reshape(c[n].shape) for n in pools})
+                with jax.named_scope("attn_window"):
+                    o = diff_read(q, pools["ring_k"], pools["ring_v"], j, ring_len, ring_tbl, ring_kernel)
+            elif kind == "attention":
+                with jax.named_scope("kv_write"):
+                    c = paged_kv.write_decode_rows(c, j, k, v, write_page, write_off, kv_live)
+                with jax.named_scope("attn"):
+                    o = diff_read(q, c["k"], c["v"], j, full_len, page_table, full_kernel)
+            else:  # the one full layer's pages, read again with this layer's own queries
+                with jax.named_scope("attn_cross"):
+                    o = diff_read(q, c["k"], c["v"], jnp.int32(0), full_len, page_table, full_kernel)
+            o = _diff_merge(cfg, layer, o, depth_of[kind][j], x.dtype)
+            with jax.named_scope("attn_proj"):
+                out = _diff_out(cfg, layer, o)
         elif kind == "gdn":
             with jax.named_scope("gdn_proj"):
                 h = _norm_in(cfg, layer, "input_norm", x)
@@ -2511,11 +3223,14 @@ def forward_decode_paged(
     x = _embed(params, cfg, ids)
     # the expert stacks for the launch that reads them where they lie; ``W_kvb`` to be sliced under its own scope
     whole = (moe.EXPERT_LEAVES if touched_form else ()) + ("w_kvb",)
+    if cfg.count("gmu"):  # this step's scan output of the last selective-scan layer rides with the cache, and leaves it below
+        cache = {**cache, "gmu_m": jnp.zeros((S, cfg.s6_d_inner), jnp.float32)}
     x, out_cache = _scan_layers(cfg, params, (x, dict(cache)), step, whole=whole)
+    out_cache.pop("gmu_m", None)
     if "moe_streamed" in cache:  # the experts whose weights this step read: the touched ones, or every one held
         with jax.named_scope("moe_router"):
             read = out_cache["moe_touched"] - cache["moe_touched"] if touched_form else cfg.num_experts
             out_cache["moe_streamed"] = cache["moe_streamed"] + read
     with jax.named_scope("lm_head"):
-        hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        hidden = _final_norm(params, cfg, x)
     return hidden, out_cache

@@ -140,6 +140,10 @@ class ModelConfig:
     def state_shapes(self, slots: int) -> dict:
         return {}
 
+    def ring_shapes(self, slots: int, page_size: int) -> dict:
+        """No layer of this family keeps a window (models/hybrid.py)."""
+        return {}
+
     @classmethod
     def from_hf_dict(cls, d: dict[str, Any]) -> "ModelConfig":
         """Build from an HF ``config.json`` dict (qwen2 / qwen3 model types,
@@ -352,6 +356,13 @@ def serving_limits(cfg) -> dict[str, str]:
     weights and pages and tensor parallelism all serve this family."""
     del cfg
     return {}
+
+
+def prefill_row_bytes(cfg, bucket: int) -> int:
+    """Bytes of the widest activations ONE row of a prefill program of
+    ``bucket`` tokens holds through the layers: the residual stream. The
+    engine sizes a prefill group by it (``DecodePrograms.prefill_sizes``)."""
+    return bucket * cfg.hidden_size * jnp.dtype(cfg.jax_dtype).itemsize
 
 
 def prefill_attn_launch(cfg, L: int) -> bool:

@@ -185,6 +185,34 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "cached tokens of live slots x latent-attention layers (each "
             "row is fetched once a step and layer).",
         ),
+        # a decoder-hybrid-decoder (models/hybrid.py ``s6`` / ``swa`` / ``cross``
+        # / ``gmu``); the first three counted on the device inside the decode
+        # chunk, the last where the engine dispatches a prefill program
+        shared_kv_tokens_read=r.counter(
+            "areal_decode_shared_kv_tokens_read_total",
+            "(cached token, layer) K and V rows of the ONE full-attention "
+            "layer's pages read by decode steps: the cached tokens of live "
+            "slots x the layers that read them (the layer itself and every "
+            "cross-attention layer over its pages).",
+        ),
+        window_tokens_read=r.counter(
+            "areal_decode_window_tokens_read_total",
+            "(token, layer) K and V rows read from the window layers' rings "
+            "by decode steps: min(cached tokens, sliding_window) a live slot "
+            "x window layers.",
+        ),
+        s6_state_updates=r.counter(
+            "areal_decode_s6_state_updates_total",
+            "(slot, layer) updates of a selective-scan (Mamba-1) state by "
+            "decode steps: live slots x selective-scan layers.",
+        ),
+        prefill_last_token_rows=r.counter(
+            "areal_decode_prefill_last_token_rows_total",
+            "Rows of the cross-decoder (the layers past the one whose K and "
+            "V they read) a prompt pass costs: the last prompt token's, in "
+            "the decode step that follows the prefill program, whose own "
+            "rows end at that layer's K and V. One a prompt prefilled.",
+        ),
         # ... whose layers have a learned index (``index_topk``): what the
         # index scored, what the mathematics selects of it, beside what the
         # read fetched (above): equal to selected in a form that gathers the

@@ -178,6 +178,7 @@ def _cases_paged_q8(compiled: bool = False) -> Iterator[dict]:
 
 @register_kernel("paged_attention_stacked")
 def _cases_paged_stacked(compiled: bool = False) -> Iterator[dict]:
+    import jax
     import jax.numpy as jnp
 
     from areal_tpu.inference import paged_kv
@@ -219,6 +220,44 @@ def _cases_paged_stacked(compiled: bool = False) -> Iterator[dict]:
     for layer in (1, L - 1):
         yield case(f"stacked-int8-layer{layer}", layer, jnp.int8)
     yield case("stacked-fp8-layer1", 1, jnp.float8_e4m3fn)
+
+    # differential attention's shape (models/hybrid.py ``_diff_pack_q``): float32 queries [q1 | 0] and [0 | q2] of a
+    # differential head, 4 query heads to ONE row [k1 | k2] / [v1 | v2], over a window layer's ring of 4 pages a slot;
+    # against the two softmaxes computed apart, each over its own half of the key lanes. On the chip at the published
+    # widths: 64 slots x 10 rows of 128 lanes x groups of 4, softmax scale 1/8
+    ring = dict(S=64, KH=10, G=4, hd=128, psz=128, wp=4) if compiled else dict(S=4, KH=3, G=4, hd=32, psz=16, wp=2)
+    half, layer = ring["hd"] // 2, 1
+
+    def packed(inp):
+        own = (jnp.arange(ring["hd"])[None, :] // half) == (jnp.arange(ring["KH"] * ring["G"])[:, None] % 2)
+        return jnp.where(own[None], inp["q"], 0.0)
+
+    def two_softmaxes(inp):
+        q, S, W = packed(inp), ring["S"], ring["wp"] * ring["psz"]
+        k, v = (jnp.moveaxis(inp[n][layer][:, inp["pt"]], 0, 3).reshape(S, W, ring["KH"], ring["hd"]).astype(jnp.float32) for n in ("k", "v"))
+        qg = q.reshape(S, ring["KH"], ring["G"] // 2, 2, ring["hd"])  # [slot, row, differential head of the row, (q1, q2), lanes]
+        valid = (jnp.arange(W)[None, :] < inp["lengths"][:, None])[:, None, None, :]
+        outs = []
+        for s in (0, 1):
+            lanes = slice(s * half, (s + 1) * half)
+            logits = jnp.einsum("srad,swrd->sraw", qg[:, :, :, s, lanes], k[..., lanes]) * half**-0.5
+            p = jax.nn.softmax(jnp.where(valid, logits, -1e30), axis=-1)
+            outs.append(jnp.einsum("sraw,swre->srae", p, v))
+        return _live(jnp.stack(outs, axis=3).reshape(S, -1, ring["hd"]), inp["lengths"])
+
+    yield {
+        "case": "stacked-bf16-diff-pairs-f32q-ring",
+        "build": _paged_build(layers=2, seed=11, q_dtype=jnp.float32, pages=jnp.bfloat16, **ring),
+        "kernel": lambda inp: _live(
+            paged_attention_stacked(
+                packed(inp), inp["k"], inp["v"], jnp.int32(layer), inp["lengths"], inp["pt"],
+                pages_per_compute_block=ring["wp"] if compiled else 2, sm_scale=half**-0.5, interpret=not compiled,
+            ),
+            inp["lengths"],
+        ),
+        "reference": two_softmaxes,
+        "tol": 3e-2,
+    }
 
 
 # ---------------------------------------------------------------------------

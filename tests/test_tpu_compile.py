@@ -51,7 +51,7 @@ def chip():
     compilation_cache.reset_cache()
 
 
-def _decode(page_dtype, slots=SLOTS, kh=KH, g=H // KH, layers=L, sm_scale=None, n_pages=N_PAGES):
+def _decode(page_dtype, slots=SLOTS, kh=KH, g=H // KH, layers=L, sm_scale=None, n_pages=N_PAGES, wp=32, q_dtype=jnp.bfloat16):
     """The decode kernel as a benchmark cell launches it: a 32-page table
     (4096-token window), 4 pages a block; by default ``rollout-1.5b-grpo``'s
     128 slots x 2 KV heads x group 6."""
@@ -68,8 +68,8 @@ def _decode(page_dtype, slots=SLOTS, kh=KH, g=H // KH, layers=L, sm_scale=None, 
     def args(S):
         pages = S((layers, kh, n_pages, PSZ, HD), page_dtype)
         a = [
-            S((slots, kh * g, HD), jnp.bfloat16), pages, pages, S((), jnp.int32),
-            S((slots,), jnp.int32), S((slots, 32), jnp.int32),
+            S((slots, kh * g, HD), q_dtype), pages, pages, S((), jnp.int32),
+            S((slots,), jnp.int32), S((slots, wp), jnp.int32),
         ]
         if quant:  # lane-major scales
             a += [S((layers, kh, n_pages, 1, PSZ), jnp.float32)] * 2
@@ -295,6 +295,12 @@ CASES = {
     "paged_decode_mha30_bf16": lambda: _decode(jnp.bfloat16, 64, 30, 1, 4, n_pages=490),
     "paged_decode_mha30_int8": lambda: _decode(jnp.int8, 64, 30, 1, 4, n_pages=490),
     "paged_kv_write_mha30_bf16": lambda: _kv_write(jnp.bfloat16, 64, 30, 4, 490),
+    # Phi-4-mini-flash-reasoning's differential attention: float32 queries [q1 | 0] / [0 | q2], 40 heads of 128 lanes in
+    # groups of 4 over 10 rows [k1 | k2]; the ONE full layer's pages under the cell's whole window (160 pages, 20,480
+    # tokens), and the eight window layers' rings (65 blocks of 4 pages: a 4-page table a slot); the rings' writer
+    "paged_decode_diff_shared_f32q": lambda: _decode(jnp.bfloat16, 64, 10, 4, 1, sm_scale=1 / 8, n_pages=5734, wp=160, q_dtype=jnp.float32),
+    "paged_decode_diff_ring_f32q": lambda: _decode(jnp.bfloat16, 64, 10, 4, 8, sm_scale=1 / 8, n_pages=260, wp=4, q_dtype=jnp.float32),
+    "paged_kv_write_diff_ring_bf16": lambda: _kv_write(jnp.bfloat16, 64, 10, 8, 260),
     # the engine's smallest and largest suffix buckets at max_seq_len 2048
     "suffix_prefill_B256": lambda: _suffix(256, 4),
     "suffix_prefill_B2048": lambda: _suffix(2048, 2),
@@ -855,3 +861,98 @@ def test_glm5_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypatch):
     # W_kvb's stack is not laid out for the launch whole: a layer's slice is, once a layer (29 MB)
     assert not [ln for ln in text.splitlines() if re.search(r"= bf16\[3,512,(28672|32768)\]\S* (?!parameter|get-tuple-element)", ln)]
     assert compiled.memory_analysis().temp_size_in_bytes < 2.4e9  # under the XLA loop's 2.45
+
+
+def _phi4flash(chip, monkeypatch, kv_gb: float = 3.5):
+    """The ``phi4flash`` family at every published width and ALL 32 layers
+    (the layers are scanned in four bodies: a program's temporaries are one
+    pair's), 64 slots, the cell's pool of 5,734 pages under the one full
+    layer and 65 ring blocks of 4 pages under the eight window layers."""
+    import json
+
+    from areal_tpu import models
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "chip", "configs", "phi-4-mini-flash-reasoning.json")) as f:
+        cfg = json.load(f)
+    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "assumed", "assumed_notes", "stands_for")}
+    hf.update({k: v for k, v in cfg["assumed"].items() if not isinstance(v, str) or k.endswith("dtype")}, dtype="bfloat16")
+    mcfg = models.config_from_hf_dict(hf)
+    n_pages = paged_kv.n_pages_for_budget(int(kv_gb * 2**30), 1, 10, PSZ, 128, 2, pools=mcfg.kv_pools)
+    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
+    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, n_pages, PSZ, slots=64))
+    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return mcfg, place(params), place(cache), n_pages
+
+
+_PHI_POOLS = ("bf16[8,10,65,4,128,128]", "bf16[8,10,260,128,128]", "bf16[1,10,5734,128,128]", "f32[9,64,16,5120]")
+
+
+def test_phi4flash_decode_steps_compile_for_v5e_at_the_whole_window(chip, monkeypatch):
+    """Two decode steps as the engine's chunk runs them at the cell's ONE
+    window (160 pages, 64 slots): ``paged_decode_attn`` over the rings (a
+    4-page table) and over the full layer's pages, by that layer and by the
+    seven cross layers; ``paged_kv_write`` into the rings (the 6-axis leaf
+    merged to the pools' own layout and back: no copy) and into the pages;
+    the selective-scan state advanced by XLA in place. Five launches in the
+    text (the 32 layers are four scan bodies), no pool, ring or state copied
+    or re-laid out, 0.1 GB of temporaries."""
+    from areal_tpu.models import hybrid
+
+    mcfg, params, cache, n_pages = _phi4flash(chip, monkeypatch)
+    assert n_pages == 5734 and {k: v.shape for k, v in cache.items()} == {
+        "k": (1, 10, 5734, PSZ, 128), "v": (1, 10, 5734, PSZ, 128), "ssm": (9, 64, 16, 5120), "conv": (9, 64, 3 * 5120),
+        "ring_k": (8, 10, 65, 4, PSZ, 128), "ring_v": (8, 10, 65, 4, PSZ, 128),
+    }
+
+    def two_steps(params, cache, pt, ids, pos, active):
+        cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
+
+        def step(c, _):
+            ids, pos, cache = c
+            h, cache = hybrid.forward_decode_paged(params, mcfg, ids, pos, cache, pt, page_size=PSZ, active=active, use_kernel=True)
+            return (jnp.argmax(hybrid.compute_logits(params, mcfg, h), -1).astype(jnp.int32), pos + 1, cache), None
+
+        (ids, _, cache), _ = jax.lax.scan(step, (ids, pos, cache), None, length=2)
+        return ids, cache
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(64, 160), i32(64), i32(64), chip((64,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 5 and "paged_decode_attn" in text and "paged_kv_write" in text
+    for pool in _PHI_POOLS:
+        assert not [ln for ln in text.splitlines() if " copy(" in ln and pool in ln], pool
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+def test_phi4flash_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypatch):
+    """ONE prompt of 16,384 tokens, the cell's longest bucket: 17 of the 32
+    layers and layer 17's K and V (no cross layer, no memory unit, no full
+    attention: the decode step that follows is the prompt's one row of
+    those), the selective scan a token a step over a carried [1, 16, 5120]
+    (nothing of [16384, 5120, 16]: 5.4 GB), window attention a block of 512
+    queries against 1,024 keys (no [40, 16384, 16384]), the rings written a
+    head an update (a window over heads and lanes re-lays the carried ring
+    out: 2 x 1.36 GB copied in and out of the loop). 1.93 GB of temporaries:
+    with 7.70 GB of weights and 5.33 GB of cache, 14.96 of 15.75 GB. Two
+    prompts of 4,096 are never batched (``prefill_row_bytes``)."""
+    from areal_tpu.inference.decode_programs import _PREFILL_STREAM_BYTES
+    from areal_tpu.models import hybrid
+
+    mcfg, params, cache, _ = _phi4flash(chip, monkeypatch)
+    assert hybrid.prefill_row_bytes(mcfg, 4096) > _PREFILL_STREAM_BYTES > hybrid.prefill_row_bytes(mcfg, 256) * 4
+    assert hybrid.ffn_block_rows(mcfg, "dense", 16384) == 8192
+
+    def prefill(params, cache, ids, plens, flat_pages, slots):
+        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(1, 16384), i32(1), i32(16384 // PSZ), i32(1)).compile()
+    text = compiled.as_text()
+    for pool in _PHI_POOLS:
+        assert not [ln for ln in text.splitlines() if " copy(" in ln and pool in ln], pool
+    assert "f32[16384,16,5120]" not in text and "f32[16384,5120,16]" not in text and "16384,16384" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.1e9
