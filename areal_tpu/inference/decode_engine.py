@@ -490,6 +490,7 @@ class DecodeEngine:
         self._obs.state_bytes.set(SlotCache.state_bytes(self.cache))
         load_shape = mcfg.moe_count_shapes.get("moe_load")
         self._moe_load = np.zeros(load_shape, np.int64) if load_shape else None
+        self._attn_blocks = (0, 0)  # (listed, fetched) by the decode steps' attention launches: decode_attention_status
 
     def precompile(
         self,
@@ -1464,6 +1465,12 @@ class DecodeEngine:
         if "index_tokens_scored" in counts:
             self._obs.index_tokens_scored.inc(int(counts["index_tokens_scored"].sum()))
             self._obs.latent_tokens_selected.inc(int(counts["latent_tokens_selected"].sum()))
+        if "attn_blocks_listed" in counts:
+            listed, fetched = int(counts["attn_blocks_listed"].sum()), int(counts["attn_blocks_fetched"].sum())
+            self._obs.attn_blocks_listed.inc(listed)
+            self._obs.attn_blocks_fetched.inc(fetched)
+            # arealint: disable-next=THR001 single writer (the decode loop, at a drain); /statusz reads whichever pair the name holds
+            self._attn_blocks = (self._attn_blocks[0] + listed, self._attn_blocks[1] + fetched)
 
     def kv_pools_status(self) -> dict | None:
         """/statusz ``kv_pools``: the groups of page pools the model's layers
@@ -1489,6 +1496,19 @@ class DecodeEngine:
         out["state_bytes"] = rows["recurrent_state"]
         out["window_bytes"] = rows.get("window_rings", 0)
         return out
+
+    def decode_attention_status(self) -> dict | None:
+        """/statusz ``decode_attention``: blocks of pages the decode steps'
+        attention launches would fetch at one item a (live slot, block with
+        tokens), and the blocks they fetched (a block that several slots'
+        table rows name is one item), a step, since the engine started;
+        ``fetched_share`` of 1 says no live slots alias pages. None for a
+        model without K and V pages under the page table, and before a
+        chunk has run on the kernel path."""
+        listed, fetched = getattr(self, "_attn_blocks", (0, 0))
+        if not listed:
+            return None
+        return {"blocks_listed": listed, "blocks_fetched": fetched, "fetched_share": round(fetched / listed, 4)}
 
     def sparse_attention_status(self) -> dict | None:
         """/statusz ``sparse_attention``: what a learned index selects for a
@@ -2504,7 +2524,7 @@ class DecodeEngine:
             emit_count = packed[2 * n_steps]
             active = packed[2 * n_steps + 1].astype(bool)
             pos = packed[2 * n_steps + 2]
-            if self.model_cfg.count_shapes:
+            if packed.shape[0] > 2 * n_steps + 3:  # a chunk's counts (a speculative round brings none)
                 self._credit_counts(packed[2 * n_steps + 3 :].reshape(-1))
             st = self._state
             now = time.monotonic()

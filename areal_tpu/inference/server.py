@@ -259,6 +259,10 @@ class InferenceServer:
         if sa is not None and (sparse_view := sa()) is not None:
             # a learned index's selection and the form its rows are read in
             out["sparse_attention"] = sparse_view
+        da = getattr(self.engine, "decode_attention_status", None)
+        if da is not None and (attn_view := da()) is not None:
+            # blocks of pages the decode steps' attention listed and fetched
+            out["decode_attention"] = attn_view
         kp = getattr(self.engine, "kv_pools_status", None)
         if kp is not None and (pools_view := kp()) is not None:
             # which layers each group of page pools serves, how long a slot

@@ -156,10 +156,14 @@ GDN_CHUNK = 64
 # ... and of a decoder-hybrid-decoder, one number each a chunk: cached tokens of live slots x the layers that read
 # the shared pages; tokens a window layer read (at most ``sliding_window`` a live slot and layer); selective-scan
 # states advanced (live slots x s6 layers)
+# ... and of every model with K and V pages under the page table, one number each a chunk: the blocks of pages its
+# attention launches' work list would hold at one item a (live slot, block), and the items it holds (a block that
+# several slots' rows name is fetched once: ops/paged_attention_q8.py shared_decode_schedule)
 COUNT_LEAVES = (
     "moe_load", "moe_touched", "moe_streamed", "gdn_updates", "latent_tokens_read",
     "index_tokens_scored", "latent_tokens_selected",
     "shared_kv_tokens_read", "window_tokens_read", "s6_updates",
+    "attn_blocks_listed", "attn_blocks_fetched",
 )
 
 
@@ -446,6 +450,8 @@ class HybridConfig:
             out["window_tokens_read"] = (1,)
         if self.count("s6"):
             out["s6_updates"] = (1,)
+        if self.count("attention") + self.count("cross"):
+            out["attn_blocks_listed"] = out["attn_blocks_fetched"] = (1,)
         return out
 
     @property
@@ -3028,17 +3034,25 @@ def forward_decode_paged(
     write_off = positions % page_size
     kv_quant = "k_scale" in cache
     not_pages = paged_kv.STATE_LEAVES + COUNT_LEAVES
+    fetch = None  # what the attention launches' work list fetches, where it names a block several slots hold once
     if use_kernel:
-        from areal_tpu.ops.paged_attention_q8 import decode_schedule, live_order, paged_attention_stacked
+        from areal_tpu.ops.paged_attention_q8 import DecodeItems, decode_schedule, live_order, paged_attention_stacked, shared_decode_schedule
 
         attn_lengths = jnp.where(page_table[:, 0] == 0, 0, lengths)  # see qwen.forward_decode_paged
         ppcb = paged_kv.choose_ppcb(page_table.shape[1])
-        schedule = decode_schedule(attn_lengths, page_table.shape[1], page_size, ppcb)
-        # the state kernel's work list, made once a step
-        live = live_order(active) if cfg.count("mamba") + cfg.count("gdn") else None
         if cfg.count("mla"):
             from areal_tpu.ops.paged_latent_attention import paged_latent_attention_stacked
-        kernel = dict(pages_per_compute_block=ppcb, schedule=schedule)  # the index's launch: the latent one's work list
+
+            # the latent launch's work list and the index's: every block of a slot's row for that slot alone
+            schedule = decode_schedule(attn_lengths, page_table.shape[1], page_size, ppcb)
+        else:
+            # K and V pages: each distinct block once, with the slots whose rows name it (qwen.forward_decode_paged)
+            with jax.named_scope("attn"):
+                schedule, fetch = shared_decode_schedule(attn_lengths, page_table, page_size, ppcb)
+                cache = fetch.counted(cache)
+        # the state kernel's work list, made once a step
+        live = live_order(active) if cfg.count("mamba") + cfg.count("gdn") else None
+        kernel = dict(pages_per_compute_block=ppcb, schedule=schedule)
         with jax.named_scope("kv_write"):
             kv_live = live_order(page_table[:, 0] != 0)  # the KV writer's: qwen.forward_decode_paged
     else:
@@ -3058,7 +3072,9 @@ def forward_decode_paged(
             ring_kernel = ring_live = None
             if use_kernel and R:
                 ring_ppcb = paged_kv.choose_ppcb(R)
-                ring_kernel = dict(pages_per_compute_block=ring_ppcb, schedule=decode_schedule(ring_len, R, page_size, ring_ppcb))
+                # a slot's ring is its own: the list that names every block for its slot alone, in the launch's form
+                ring_items = DecodeItems.private(decode_schedule(ring_len, R, page_size, ring_ppcb))
+                ring_kernel = dict(pages_per_compute_block=ring_ppcb, schedule=ring_items)
                 ring_live = live_order(active)
 
         def diff_read(q, k_pool, v_pool, j, lens, table, kern):
@@ -3073,7 +3089,9 @@ def forward_decode_paged(
         full_kernel = kernel if use_kernel else None
         full_len = attn_lengths if use_kernel else lengths
         n_live = jnp.sum(active, dtype=jnp.int32)
-        cached = jnp.sum(jnp.where(active, lengths, 0), dtype=jnp.int32)
+        # the cached tokens a read of the shared pages fetches: the DISTINCT ones where the launch's list names a
+        # block several slots hold once, else every live slot's
+        cached = jnp.sum(jnp.where(active, lengths, 0), dtype=jnp.int32) if fetch is None else fetch.tokens
         for leaf, scope, n in (
             ("shared_kv_tokens_read", "attn_cross", cached * (1 + cfg.count("cross"))),
             ("window_tokens_read", "attn_window", jnp.sum(ring_len, dtype=jnp.int32) * cfg.count("swa")),

@@ -135,7 +135,8 @@ class ModelConfig:
     has_recurrent_state = False
     # expert-load counts a decode chunk would hand back (models/hybrid.py): none
     moe_count_shapes: ClassVar[dict] = {}
-    count_shapes: ClassVar[dict] = {}
+    # what a decode chunk hands back beside its tokens: the blocks of pages its attention launches listed and fetched
+    count_shapes: ClassVar[dict] = {"attn_blocks_listed": (1,), "attn_blocks_fetched": (1,)}
 
     def state_shapes(self, slots: int) -> dict:
         return {}
@@ -1366,6 +1367,9 @@ def forward_decode_paged(
     del active
     S = ids.shape[0]
     H, KH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    # a decode chunk's counts (``count_shapes``) ride with the cache and are no pool: out of the layers' way
+    counts = {name: cache[name] for name in cfg.count_shapes if name in cache}
+    cache = {name: leaf for name, leaf in cache.items() if name not in counts}
     with jax.named_scope("embed"):
         x = _embed_lookup(params["embed"], ids, cfg.jax_dtype)  # [S, D]
     pos1 = positions[:, None]
@@ -1376,19 +1380,23 @@ def forward_decode_paged(
     kv_quant = "k_scale" in cache  # int8 pages + per-vector scales
     if use_kernel:
         from areal_tpu.ops.paged_attention_q8 import (
-            decode_schedule,
             live_order,
             paged_attention_stacked,
+            shared_decode_schedule,
         )
 
         # a slot whose request has ended keeps its last position, and the
         # engine points its whole table row at the pool's trash page 0 (never
         # allocated): it has nothing to attend over, and its row of the
         # output is not read. The kernel's work list is the same for every
-        # layer, so it is made here, once a step.
+        # layer, so it is made here, once a step: each distinct block of
+        # pages once, with the slots whose rows name it (a group's siblings
+        # hold the first one's prompt pages, a prefix-cache hit the cached).
         attn_lengths = jnp.where(page_table[:, 0] == 0, 0, lengths)
         ppcb = paged_kv.choose_ppcb(page_table.shape[1])
-        schedule = decode_schedule(attn_lengths, page_table.shape[1], page_size, ppcb)
+        with jax.named_scope("attn"):
+            schedule, fetch = shared_decode_schedule(attn_lengths, page_table, page_size, ppcb)
+            counts = fetch.counted(counts)
         # the same slots are the ones whose row is written (one Pallas launch
         # a layer, ops/paged_kv_write.py): an ended slot's row, which the
         # scatters send to the trash page, is not written at all
@@ -1466,6 +1474,6 @@ def forward_decode_paged(
     )
     with jax.named_scope("lm_head"):
         hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    return hidden, out_cache
+    return hidden, {**out_cache, **counts}
 
 

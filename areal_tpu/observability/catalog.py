@@ -201,6 +201,22 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "by decode steps: min(cached tokens, sliding_window) a live slot "
             "x window layers.",
         ),
+        # every model with K and V pages under the page table; counted on the
+        # device inside the decode chunk, once a step (the work list is the
+        # same for every layer)
+        attn_blocks_listed=r.counter(
+            "areal_decode_attn_blocks_listed_total",
+            "Blocks of pages (pages_per_compute_block pages each) the live "
+            "slots' table rows hold tokens in, a decode step: what the "
+            "attention launch fetches at one item a (slot, block).",
+        ),
+        attn_blocks_fetched=r.counter(
+            "areal_decode_attn_blocks_fetched_total",
+            "Items of the attention launch's work list, a decode step: a "
+            "block that several live slots' rows name (a group's shared "
+            "prompt pages) is fetched once. Over ..._listed_total: the "
+            "share of the blocks fetched.",
+        ),
         s6_state_updates=r.counter(
             "areal_decode_s6_state_updates_total",
             "(slot, layer) updates of a selective-scan (Mamba-1) state by "

@@ -20,6 +20,17 @@ microseconds an item the launch takes over its bytes' time. The shapes are
 the two Qwen cells' and the olmo cell's (64 slots, 30 KV heads of one query
 row: the widest item the kernel serves).
 
+``--group 1,2,4,8`` (alone): ``paged_decode_attn`` over a table in which the
+live slots are groups of that many samples of one prompt, each sibling
+holding the first one's full prompt pages (``SlotCache.alias``), at the three
+shapes above under ``grpo-reasoning``'s lengths and at the long-context
+cell's (64 slots, 10 K/V rows of 128 under 4 float32 query rows, a 160-page
+table, 24 live slots over prompts of 4k-16k tokens): us a launch over the
+list that names each distinct block once (``shared_decode_schedule``) and
+over the list that fetches every block a slot (``decode_schedule``), blocks
+fetched of blocks listed, the bytes each fetches and the launch's share of
+the roofline of the bytes IT fetches, and the list's own microseconds a step.
+
 ``paged_latent_attn`` (``--only latent`` runs it alone): the latent-attention
 cell's launch, 32 query rows of 640 lanes over ONE stacked pool of 1,280 B
 rows (576 published values in 640 lanes), 48 layers, by live slots (16 / 24 /
@@ -62,6 +73,7 @@ TPU only: a CPU time is no speed.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import time
 
@@ -138,6 +150,92 @@ def probe(name: str, *, seed: int, reps: int, ppcb: int) -> dict:
         res[f"{label}_roofline_pct"] = 100 * floor_us / res[f"{label}_us"]
         res[f"{label}_items"] = items = int((-(-sets[label] // (ppcb * PSZ))).sum())
         res[f"{label}_us_an_item_over_bytes"] = (res[f"{label}_us"] - floor_us) / items
+    return res
+
+
+# 24 of 64 slots live, 8 samples a prompt of 4k-16k tokens, outputs to 3,072: the cross layers' read of layer 17's pages
+LONG = "rollout-phi-4-mini-flash-longctx-grpo"
+GROUP_SHAPES = {
+    **{name: dict(shape, wp=WP, prompt=(128, 1024), live=LIVE) for name, shape in ATTN_SHAPES.items()},
+    LONG: dict(S=64, KH=10, G=4, L=1, wp=160, prompt=(4096, 16384), live=24 / 64, q_dtype="float32", sm_scale=0.125),
+}
+
+
+def probe_group(name: str, group: int, *, seed: int, reps: int, ppcb: int) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops.paged_attention_q8 import decode_schedule, paged_attention_stacked, shared_decode_schedule
+
+    shape = GROUP_SHAPES[name]
+    S, KH, G, L, wp = (shape[k] for k in ("S", "KH", "G", "L", "wp"))
+    rng = np.random.default_rng(seed)
+    live = rng.permutation(S)[: round(shape["live"] * S) // group * group]
+    lengths, table = np.zeros(S, np.int32), np.zeros((S, wp), np.int32)
+    free = 1
+    for members in live.reshape(-1, group):  # a group: one prompt, the first member's full prompt pages in every row
+        prompt = int(np.exp(rng.uniform(*np.log(shape["prompt"]))))
+        for b in members:
+            out = np.clip(rng.lognormal(np.log(384), 1.0), 16, 3072) * rng.uniform(0, 1)
+            lengths[b] = min(prompt + 1 + int(out), wp * PSZ - 1)
+            shared = 0 if b == members[0] else prompt // PSZ
+            table[b, :shared] = table[members[0], :shared]
+            n = -(-int(lengths[b]) // PSZ) - shared
+            table[b, shared : shared + n] = np.arange(free, free + n)
+            free += n
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(kq, (S, KH * G, HD), jnp.dtype(shape.get("q_dtype", "bfloat16")))
+    k = jax.random.normal(kk, (L, KH, free, PSZ, HD), jnp.bfloat16)
+    v = jax.random.normal(kv, (L, KH, free, PSZ, HD), jnp.bfloat16)
+    lengths, table = jnp.asarray(lengths), jnp.asarray(table)
+
+    def lists(lengths, table):
+        return {"shared": shared_decode_schedule(lengths, table, PSZ, ppcb)[0], "a_slot": decode_schedule(lengths, wp, PSZ, ppcb)}
+
+    def step(which, q, k, v, lengths, table):
+        schedule = lists(lengths, table)[which]  # once a step
+
+        def layer(acc, li):
+            out = paged_attention_stacked(  # the queries differ a launch: one pool read four times is four launches
+                q * (1 + li).astype(q.dtype), k, v, li % L, lengths, table,
+                pages_per_compute_block=ppcb, schedule=schedule, sm_scale=shape.get("sm_scale"),
+            )
+            return acc + out.astype(jnp.float32), None
+
+        return jax.lax.scan(layer, jnp.zeros(q.shape, jnp.float32), jnp.arange(max(L, 4)))[0]
+
+    _, fetch = jax.jit(lambda le, t: shared_decode_schedule(le, t, PSZ, ppcb))(lengths, table)
+    res = {
+        "shape": name, "group": group, "live_slots": int(live.size), "cached_tokens": int(lengths.sum()),
+        "blocks_listed": int(fetch.blocks_listed), "blocks_fetched": int(fetch.blocks), "tokens_fetched": int(fetch.tokens),
+    }
+    outs = {}
+    steps = {"shared": jax.jit(functools.partial(step, "shared")), "a_slot": jax.jit(functools.partial(step, "a_slot"))}
+    for which, fn in steps.items():
+        outs[which] = fn(q, k, v, lengths, table).block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out = fn(q, k, v, lengths, table)
+        out.block_until_ready()
+        res[f"{which}_us"] = us = (time.perf_counter() - t0) / (reps * max(L, 4)) * 1e6
+        tokens = res["tokens_fetched"] if which == "shared" else res["cached_tokens"]
+        res[f"{which}_roofline_pct"] = 100 * (2 * KH * HD * 2 * tokens / HBM_BYTES_S * 1e6) / us
+    res["max_abs_diff"] = float(jnp.max(jnp.abs(outs["shared"] - outs["a_slot"])))
+    def many(which, lengths, table):  # the list 64 times in one program, every time of other lengths: no call's cost in it
+        def once(acc, i):
+            made = lists(jnp.where(lengths > 0, lengths + i, 0), table)[which]
+            return acc + sum(a.sum() for a in jax.tree.leaves(made)), None
+
+        return jax.lax.scan(once, jnp.int32(0), jnp.arange(64, dtype=jnp.int32))[0]
+
+    makers = {"shared": jax.jit(functools.partial(many, "shared")), "a_slot": jax.jit(functools.partial(many, "a_slot"))}
+    for which, fn in makers.items():
+        fn(lengths, table).block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out = fn(lengths, table)
+        out.block_until_ready()
+        res[f"{which}_list_us"] = (time.perf_counter() - t0) / (reps * 64) * 1e6
     return res
 
 
@@ -475,12 +573,18 @@ def main() -> int:
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--ppcb", type=int, default=4, help="pages a compute block (the decode step's choice at this table: 4)")
     ap.add_argument("--only", choices=("latent", "dsa", "latent-prefill"), help="the latent-attention cell's launches alone, the sparse read's pieces, or the prompt pass's attention")
+    ap.add_argument("--group", help="readers a prompt, e.g. 1,2,4,8: paged_decode_attn alone over a table whose live slots are such groups")
     ap.add_argument("--tokens", default="1024,4096,8192,16384", help="latent-prefill: the prompt lengths")
     ap.add_argument("--heads", default="32,64", help="latent-prefill: 32 (the kanana cell's heads) and / or 64 (the GLM-5 cell's)")
     args = ap.parse_args()
     if jax.default_backend() != "tpu":
         print("decode_attn_probe: needs a TPU (a CPU time is no speed)")
         return 2
+    if args.group:
+        for name in GROUP_SHAPES:
+            for group in args.group.split(","):
+                print(json.dumps(probe_group(name, int(group), seed=args.seed, reps=args.reps, ppcb=args.ppcb)), flush=True)
+        return 0
     if args.only == "latent-prefill":
         for H in args.heads.split(","):
             for L in args.tokens.split(","):

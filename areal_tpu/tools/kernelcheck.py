@@ -87,10 +87,13 @@ _PAGED_CHIP = dict(S=32, KH=2, G=6, hd=128, psz=128, wp=16)
 
 
 def _paged_build(S=4, KH=2, G=6, hd=128, psz=16, wp=4, layers=1, seed=0,
-                 q_dtype=None, pages=None):
+                 q_dtype=None, pages=None, alias=0):
     """build() for the decode cases: q, one stacked K/V pool (float, or
     quantized to ``pages`` with lane-major scales), ragged lengths with a
-    full slot and an empty one, and a page table."""
+    full slot and an empty one, and a page table. ``alias``: every run of
+    that many slots holds its first one's first ``wp // 2`` pages and
+    tokens past them (a group's shared prompt pages: the launch fetches
+    such a block once)."""
     import jax.numpy as jnp
 
     from areal_tpu.inference import paged_kv
@@ -100,6 +103,10 @@ def _paged_build(S=4, KH=2, G=6, hd=128, psz=16, wp=4, layers=1, seed=0,
     pt = 1 + np.arange(S * wp, dtype=np.int32).reshape(S, wp)
     lens = rng.integers(1, wp * psz + 1, S).astype(np.int32)
     lens[0], lens[-1] = wp * psz, 0
+    for first in range(0, S - 1, alias) if alias else ():
+        members = range(first, min(first + alias, S - 1))
+        lens[members] = np.maximum(lens[members], wp // 2 * psz + 1)
+        pt[members, : wp // 2] = pt[first, : wp // 2]
 
     def build():
         inp = {
@@ -188,7 +195,7 @@ def _cases_paged_stacked(compiled: bool = False) -> Iterator[dict]:
     shape, ppcb = (_PAGED_CHIP, 4) if compiled else ({}, 2)
     q_dtype = jnp.bfloat16 if compiled else None  # the serving dtype
 
-    def case(label, layer, pages):
+    def case(label, layer, pages, alias=0):
         def kernel(inp):
             scales = (
                 dict(k_scales=inp["ks"], v_scales=inp["vs"]) if "ks" in inp else {}
@@ -206,7 +213,7 @@ def _cases_paged_stacked(compiled: bool = False) -> Iterator[dict]:
         return {
             "case": label,
             "build": _paged_build(
-                layers=L, seed=7, q_dtype=q_dtype, pages=pages, **shape
+                layers=L, seed=7, q_dtype=q_dtype, pages=pages, alias=alias, **shape
             ),
             "kernel": kernel,
             "reference": _paged_reference(layer),
@@ -220,6 +227,9 @@ def _cases_paged_stacked(compiled: bool = False) -> Iterator[dict]:
     for layer in (1, L - 1):
         yield case(f"stacked-int8-layer{layer}", layer, jnp.int8)
     yield case("stacked-fp8-layer1", 1, jnp.float8_e4m3fn)
+    # groups whose rows name the same first pages: a block of them is one item, its readers' queries stacked
+    yield case("stacked-bf16-aliased-groups-of-3", 1, jnp.bfloat16, alias=3)
+    yield case("stacked-int8-aliased-groups-of-8", 1, jnp.int8, alias=8)
 
     # differential attention's shape (models/hybrid.py ``_diff_pack_q``): float32 queries [q1 | 0] and [0 | q2] of a
     # differential head, 4 query heads to ONE row [k1 | k2] / [v1 | v2], over a window layer's ring of 4 pages a slot;

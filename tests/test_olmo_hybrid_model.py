@@ -106,7 +106,7 @@ def test_config_from_a_published_config_and_what_it_refuses():
     assert mcfg.layer_types == ("gdn", "gdn", "gdn", "attention") * 2
     assert (mcfg.norm_placement, mcfg.qk_norm, mcfg.qk_norm_over, mcfg.rope_theta, mcfg.gdn_neg_eigval) == ("post", True, "whole", None, True)
     assert mcfg.has_recurrent_state and mcfg.num_kv_layers == 2 and not mcfg.tie_word_embeddings
-    assert mcfg.gdn_head_pack == 2 and mcfg.count_shapes == {"gdn_updates": (6,)}
+    assert mcfg.gdn_head_pack == 2 and mcfg.count_shapes == {"gdn_updates": (6,), "attn_blocks_listed": (1,), "attn_blocks_fetched": (1,)}
     assert mcfg.state_shapes(4) == {
         "gdn": ((6, 4, 2, 24, 128), jnp.dtype("float32")),
         "conv": ((6, 4, 3 * 4 * (24 + 24 + 64)), jnp.dtype("bfloat16")),

@@ -1,0 +1,87 @@
+"""The traced size of ``paged_decode_attn``'s launch, held under a budget.
+
+A start-up traces and lowers the decode chunk program once, and in it one
+launch a site (a run or a period of layers: 1-4 a model); jax's tracing and
+lowering is time no compile cache saves (PERF.md, section 5, "Set-up
+decomposed"). PR 44's kernel, which fetched a shared block once as this one
+does, was refused for the 0.4 s it added to every (site x KV head) of that
+trace: 48.5 s of set-up at olmo's 4 sites x 30 heads. So the launch's
+equations are counted here, through nested jaxprs, at the five cells' shapes,
+against the launch that fetched every block a slot (PR 43's, the figures
+beside each): at most 2.5 times as many (ISSUE 45's budget; this PR's launch
+holds 1.24 to 1.58 times), the per-head code (its two matmuls) in two shapes
+and no more, and no jitted function called inside the kernel, which is what
+the time followed on the chip's host (equations alone did not: 1.5 times the
+parent's cost 21.6 s of set-up at olmo's shape while the body used jnp's
+operators). Counts, never times.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from areal_tpu.ops.paged_attention_q8 import paged_attention_stacked
+
+# cell's shape -> (slots, KV heads, query rows a head, pages a row, query dtype, softmax scale),
+# equations of the launch at PR 43 (one item a slot and block), and a ceiling a dozen over this PR's own 2,122 / 978 /
+# 890 / 1,232 / 1,154 (fewer is welcome); both with the work list made inside the call
+SHAPES = {
+    "olmo-hybrid-7b": ((64, 30, 1, 32, jnp.bfloat16, None), 1712, 2135),
+    "qwen2.5-7b": ((64, 4, 7, 32, jnp.bfloat16, None), 646, 990),
+    "qwen2.5-1.5b": ((128, 2, 6, 32, jnp.bfloat16, None), 564, 900),
+    "phi-4-mini-flash": ((64, 10, 4, 160, jnp.float32, 0.125), 881, 1245),
+    "granite-h-micro": ((64, 8, 4, 32, jnp.bfloat16, None), 810, 1165),
+}
+PSZ = HD = 128
+
+
+def inner_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in v if isinstance(v, (list, tuple)) else [v]:
+            j = getattr(x, "jaxpr", x)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def count(jaxpr, primitive=None) -> int:
+    return sum(
+        (primitive is None or e.primitive.name == primitive) + sum(count(j, primitive) for j in inner_jaxprs(e))
+        for e in jaxpr.eqns
+    )
+
+
+def launch(shape):
+    S, KH, G, wp, q_dtype, sm_scale = shape
+    sds = jax.ShapeDtypeStruct
+    pages = sds((2, KH, 65, PSZ, HD), jnp.bfloat16)
+    args = [sds((S, KH * G, HD), q_dtype), pages, pages, sds((), jnp.int32), sds((S,), jnp.int32), sds((S, wp), jnp.int32)]
+
+    def fn(q, k, v, li, lengths, table):
+        return paged_attention_stacked(q, k, v, li, lengths, table, pages_per_compute_block=4, sm_scale=sm_scale)
+
+    return fn, args
+
+
+@pytest.mark.parametrize("cell", SHAPES)
+def test_the_launch_holds_its_equation_budget(cell):
+    shape, parent, ceiling = SHAPES[cell]
+    fn, args = launch(shape)
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    n = count(jaxpr)
+    assert n <= 2.5 * parent, f"{n} equations, over 2.5 x the {parent} of the launch that fetched a block a slot"
+    assert n <= ceiling, f"{n} equations: the launch has grown past what this PR pinned ({ceiling})"
+    # the per-head code in two shapes (an item of one slot, an item of stacked readers): QK and PV a head in each
+    assert count(jaxpr, "dot_general") == 2 * 2 * shape[1] + 2  # and the work list's two small products
+    assert count(jaxpr, "pallas_call") == 1
+    # no jitted jnp function inside the kernel (``jnp.where``, an operator of a traced value): each call of one is
+    # a trace of its own, a millisecond and more of a start-up's host time, and the per-head code made a dozen a head
+    kernel = next(e for e in jaxpr.eqns if e.primitive.name == "pallas_call").params["jaxpr"]
+    assert count(kernel, "jit") + count(kernel, "pjit") == 0
+
+
+def test_the_launch_lowers_for_a_tpu_from_the_cpu():
+    """At olmo's shape, the widest: what jax's lowering refuses it refuses
+    here, before any chip (the chip's compiler is tests/test_tpu_compile.py's)."""
+    fn, args = launch(SHAPES["olmo-hybrid-7b"][0])
+    text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text and "paged_decode_attn" in text

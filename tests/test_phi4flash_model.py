@@ -69,7 +69,9 @@ def test_from_hf_dict_builds_the_layer_table_and_says_which_layers_a_pool_serves
     assert mcfg.ring_pages(PSZ) == 2 and mcfg.ring_pages(3) == 3
     assert mcfg.ring_shapes(5, PSZ) == {n: ((3, 2, 6, 2, PSZ, 16), jnp.dtype("float32")) for n in paged_kv.RING_LEAVES}
     assert mcfg.state_shapes(5) == {"ssm": ((4, 5, 4, 128), jnp.dtype("float32")), "conv": ((4, 5, 3 * 128), jnp.dtype("float32"))}
-    assert mcfg.count_shapes == {"shared_kv_tokens_read": (1,), "window_tokens_read": (1,), "s6_updates": (1,)}
+    assert mcfg.count_shapes == {
+        "shared_kv_tokens_read": (1,), "window_tokens_read": (1,), "s6_updates": (1,), "attn_blocks_listed": (1,), "attn_blocks_fetched": (1,),
+    }
     assert mcfg.has_recurrent_state and set(mcfg.count_shapes) <= set(hybrid.COUNT_LEAVES)
     assert set(mcfg.layer_types) <= set(hybrid.KINDS) and set(hybrid._MIXER_SCOPE) == set(hybrid.KINDS)
 
