@@ -19,6 +19,13 @@ is the caller's to own and to thread through the donated arguments.
   (``jit_chunk``, ``jit_prefill``, ``jit_spec``: PERF.md section 3), cached
   under a key whose first entry is its kind.
 - **the sampler** every program that emits tokens shares (``_sample_step``).
+  The passes a step makes over its [S, V] float32 logits: the ``lm_head``
+  matmul writes them (the temperature divide fused in), the sampler reads
+  them once for its blocks' statistics and then one block a row
+  (``_inverse_cdf_at``: two launches of ``ops/vocab_block_stats.py`` on one
+  TPU; elsewhere ``jnp`` over a padded copy, a pass more); a top-k / top-p
+  slot adds ``lax.top_k``'s pass and a greedy slot ``argmax``'s, both
+  compiled out of the programs no such slot runs in.
 - **kernel or gather**: decided once, from the platform, the mesh and the
   shapes (``use_kernel``), with the suffix kernel's override for a
   kernel-against-XLA comparison.
@@ -129,18 +136,16 @@ def _pow2(n: int) -> int:
     return p
 
 
-def _sample_blocks(V: int) -> int:
-    """Block count for the hierarchical sampler: the largest divisor of V
-    that is <= 512. Qwen vocabs are 2^7-divisible (151936 = 128*1187);
-    tiny test vocabs divide exactly."""
-    for nb in range(min(V, 512), 0, -1):
-        if V % nb == 0:
-            return nb
-    return 1
+def _inverse_cdf_sample(scaled, rng, use_kernel: bool = False):
+    """Exact categorical sampling with ONE uniform per row: ``_inverse_cdf_at``
+    at a uniform drawn from ``rng``."""
+    u = jax.random.uniform(rng, (scaled.shape[0], 1), jnp.float32)
+    return _inverse_cdf_at(scaled, u, use_kernel)
 
 
-def _inverse_cdf_sample(scaled, rng):
-    """Exact categorical sampling with ONE uniform per row, in ~one HBM pass.
+def _inverse_cdf_at(scaled, u, use_kernel: bool = False):
+    """The token at quantile ``u`` [S, 1] of each row's softmax, in ONE pass
+    over the [S, V] float32 logits and one read of a block a row.
 
     ``jax.random.categorical`` materializes gumbel noise for every vocab
     entry — [S, 152k] of threefry bits per decode step, measured ~9 ms of
@@ -148,45 +153,77 @@ def _inverse_cdf_sample(scaled, rng):
     that with ``cumsum`` over [S, V] fp32 — which XLA lowers to ~log2(V)
     full-array passes (~2.5 GB of HBM traffic at S=128), nearly as slow.
 
-    This version factorizes the CDF hierarchically:
-      1. block_lse[S, NB] — one read pass over the logits, reshaped
-      2. tiny cumsum over NB block probabilities picks the block
-      3. the residual uniform picks the token inside the gathered
-         [S, V/NB] block (tiny)
-    The draw is exact (CDF decomposition); at both levels the uniform is
-    scaled by the realized total so fp32 cumsum undershoot spreads
-    proportionally instead of piling on the last index. Returns
-    (ids [S], logp [S], lse [S, 1]) with logp the exact log-softmax of the
-    drawn token."""
+    This version factorizes the CDF over blocks of ``W`` whole lane tiles
+    (``ops/vocab_block_stats.py``; ``W`` follows V alone, the last block is
+    partial):
+      1. each block's maximum and sum of exponentials, a row: the one pass
+         that reads all of ``scaled``, as the ``lm_head`` matmul laid it out
+         (``use_kernel``: the ``vocab_block_stats`` launch; else the same
+         blocks in ``jnp`` over a padded copy, for the CPU and a sharded
+         vocabulary). Until PR 46 the blocks were a divisor of V wide, and a
+         reshape to them cost the TPU two more passes and a relayout
+      2. tiny cumsum over the blocks' masses picks the block
+      3. the chosen block's W logits are the only other read of ``scaled``:
+         S x W values, through the ``vocab_block_pick`` launch (XLA's
+         gathers of a window of the row are an element gather or a loop over
+         the rows), else a row of that padded copy; a partial last block's
+         columns past V are masked
+      4. inside the block the same step twice, over its lane tiles' sums and
+         then over the chosen tile's 128 entries (one cumsum over W lanes is
+         itself several passes over [S, W])
+    The draw is exact (CDF decomposition): every level hands the position
+    inside the entry it chose down as the next level's uniform
+    (``_quantile_index``). Returns (ids [S], logp [S], lse [S, 1]) with logp
+    the exact log-softmax of the drawn token."""
+    # (imported here as the paged kernels are in ``DecodePrograms``: the module brings Pallas in)
+    from areal_tpu.ops import vocab_block_stats
+
     S, V = scaled.shape
-    NB = _sample_blocks(V)
-    inner = V // NB
-    blocks = scaled.reshape(S, NB, inner)
-    block_lse = jax.scipy.special.logsumexp(blocks, axis=-1)  # [S, NB]
-    lse = jax.scipy.special.logsumexp(block_lse, axis=-1, keepdims=True)
-    bprob = jnp.exp(block_lse - lse)  # [S, NB]
-    bcum = jnp.cumsum(bprob, axis=-1)
-    u = jax.random.uniform(rng, (S, 1), jnp.float32)
-    ut = u * bcum[:, -1:]
-    b = jnp.sum((bcum <= ut).astype(jnp.int32), axis=-1)
-    b = jnp.minimum(b, NB - 1)  # OOB guard
-    # residual mass inside the chosen block, renormalized to [0, 1)
-    cum_excl = jnp.where(
-        b > 0, jnp.take_along_axis(bcum, jnp.maximum(b - 1, 0)[:, None], axis=-1)[:, 0], 0.0
+    W = vocab_block_stats.block_width(V)
+    if use_kernel:
+        stats, pick = vocab_block_stats.vocab_block_stats, vocab_block_stats.vocab_block_pick
+    else:
+        stats, pick = vocab_block_stats.vocab_block_stats_xla, vocab_block_stats.vocab_block_pick_xla
+    bmax, bsum = stats(scaled)
+    # [S, 128]: a lane a block, the lanes past the last block without mass
+    row_max = jnp.max(bmax, axis=-1, keepdims=True)
+    row_max = jnp.where(jnp.isneginf(row_max), 0.0, row_max)
+    bmass = bsum * jnp.exp(bmax - row_max)
+    lse = jnp.log(jnp.sum(bmass, axis=-1, keepdims=True)) + row_max
+    b, u_in = _quantile_index(bmass, u[:, 0])
+    blk = pick(scaled, b)  # [S, W]
+    blk_max = jnp.take_along_axis(bmax, b[:, None], axis=-1)
+    in_row = (b * W)[:, None] + jnp.arange(W, dtype=jnp.int32)[None] < V  # a partial last block's columns
+    tiles = jnp.where(in_row, jnp.exp(blk - blk_max), 0.0).reshape(S, W // _LANES, _LANES)
+    t, u_in = _quantile_index(tiles.sum(-1), u_in)
+    i, _ = _quantile_index(jnp.take_along_axis(tiles, t[:, None, None], axis=1)[:, 0], u_in)
+    idx = t * _LANES + i
+    logp = (jnp.take_along_axis(blk, idx[:, None], axis=-1) - lse)[:, 0]
+    return b * W + idx, logp, lse
+
+
+_LANES = 128  # entries of the sampler's innermost level: one lane tile of a block
+
+
+def _quantile_index(mass, u):
+    """One level of the inverse CDF. ``mass`` [S, n] >= 0, ``u`` [S] in
+    [0, 1): (the entry of each row that the quantile ``u`` of the row's mass
+    falls in, where inside that entry it falls, in [0, 1]). The uniform is
+    scaled by the realized total, so fp32 cumsum undershoot spreads
+    proportionally instead of piling on the last index, and the entry is
+    never one after the last that adds mass."""
+    cum = jnp.cumsum(mass, axis=-1)
+    total = cum[:, -1:]
+    target = u[:, None] * total
+    idx = jnp.minimum(
+        jnp.sum(cum <= target, axis=-1, dtype=jnp.int32), jnp.sum(cum < total, axis=-1, dtype=jnp.int32)
     )
-    pb = jnp.take_along_axis(bprob, b[:, None], axis=-1)[:, 0]
-    u_in = (ut[:, 0] - cum_excl) / jnp.maximum(pb, 1e-30)
-    blk = jnp.take_along_axis(blocks, b[:, None, None], axis=1)[:, 0]  # [S, inner]
-    blk_lse = jnp.take_along_axis(block_lse, b[:, None], axis=-1)  # [S, 1]
-    icum = jnp.cumsum(jnp.exp(blk - blk_lse), axis=-1)  # [S, inner]
-    idx = jnp.sum((icum <= u_in[:, None] * icum[:, -1:]).astype(jnp.int32), axis=-1)
-    idx = jnp.minimum(idx, inner - 1)
-    ids = b * inner + idx
-    logp = (jnp.take_along_axis(scaled, ids[:, None], axis=-1) - lse)[:, 0]
-    return ids, logp, lse
+    below = jnp.where(idx > 0, jnp.take_along_axis(cum, jnp.maximum(idx - 1, 0)[:, None], axis=-1)[:, 0], 0.0)
+    here = jnp.take_along_axis(mass, idx[:, None], axis=-1)[:, 0]
+    return idx, (target[:, 0] - below) / jnp.maximum(here, 1e-30)
 
 
-def _sample_step(logits, rng, state, capped: bool, greedy_any: bool = True):
+def _sample_step(logits, rng, state, capped: bool, greedy_any: bool = True, use_kernel: bool = False):
     """One sampling step. logits [S, V] fp32; all sampling knobs are
     *per-slot arrays* in ``state`` (temp, greedy, top_k, top_p) so one
     request's config can never leak into another slot (round-1 correctness
@@ -195,13 +232,14 @@ def _sample_step(logits, rng, state, capped: bool, greedy_any: bool = True):
     ``capped`` and ``greedy_any`` are static flags: when no active slot
     filters (resp. decodes greedily), the top-k candidate machinery (resp.
     the full-vocab argmax pass — a [S, V] fp32 HBM read per step) is
-    compiled out entirely."""
+    compiled out entirely. ``use_kernel``: the sampler's pass over the
+    logits is the compiled ``vocab_block_stats`` launch."""
     V = logits.shape[-1]
     temp, greedy = state["temp"], state["greedy"]
     safe_t = jnp.maximum(temp, 1e-6)[:, None]
     scaled = logits / safe_t
     rng_full, rng_cap = jax.random.split(rng)
-    sampled, samp_logp, lse = _inverse_cdf_sample(scaled, rng_full)
+    sampled, samp_logp, lse = _inverse_cdf_sample(scaled, rng_full, use_kernel)
     use_cap = None
     if capped:
         K = min(V, TOPK_CAP)
@@ -273,6 +311,9 @@ class DecodePrograms:
                 "decode, suffix prefill and verify take the gather path"
             )
         self.use_kernel = one_tpu and shapes_ok
+        # the sampler's pass over the logits (ops/vocab_block_stats.py) is
+        # compiled where those kernels are, whatever the heads' shapes
+        self.sample_kernel = one_tpu
         # suffix-prefill / tree-verify Pallas kernel
         # (ops/paged_suffix_attention.py): same condition, overridable at
         # runtime for kernel-vs-XLA A/B (off-TPU the kernel runs in
@@ -441,6 +482,7 @@ class DecodePrograms:
             T = self.config.max_seq_len
             psz = self.config.page_size
             use_kernel = self.use_kernel
+            sample_kernel = self.sample_kernel
             model = self.model
 
             counts_of = dict(mcfg.count_shapes)
@@ -476,7 +518,7 @@ class DecodePrograms:
                             )
                         rng, sub = jax.random.split(rng)
                         next_ids, logp = _sample_step(
-                            logits, sub, state, capped, greedy_any
+                            logits, sub, state, capped, greedy_any, sample_kernel
                         )
                     if freq_any:
                         # saturating (uint16 .add would wrap at 65535 —
@@ -576,6 +618,7 @@ class DecodePrograms:
             T = self.config.max_seq_len
             psz = self.config.page_size
             K = B - 1
+            sample_kernel = self.sample_kernel
 
             def spec(params, cache, page_table, state, rng, drafts):
                 d_tokens = drafts["tokens"]  # [S, K]
@@ -630,7 +673,7 @@ class DecodePrograms:
                     with jax.named_scope("sampler"):
                         rng, sub = jax.random.split(rng)
                         t_j, logp_j = _sample_step(
-                            lg, sub, state, capped, greedy_any
+                            lg, sub, state, capped, greedy_any, sample_kernel
                         )
                     emit_rows.append(cont)
                     toks_rows.append(t_j)

@@ -1113,6 +1113,59 @@ def _cases_gmm(compiled: bool = False) -> Iterator[dict]:
 
 
 # ---------------------------------------------------------------------------
+# the sampler's two launches over a step's logits (ops/vocab_block_stats.py):
+# every block's maximum and sum of exponentials, then each row's chosen block,
+# against the same partition in jnp over a padded copy of the row
+# ---------------------------------------------------------------------------
+
+
+@register_kernel("vocab_blocks")
+def _cases_vocab_blocks(compiled: bool = False) -> Iterator[dict]:
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import vocab_block_stats as vbs
+
+    def case(label, slots, vocab, seed=3):
+        width = vbs.block_width(vocab)
+        nb = -(-vocab // width)
+
+        def build():
+            x = 3.0 * _normal(seed, (slots, vocab))
+            x = x.at[0].set(-1e30).at[1, : vocab // 2].set(-jnp.inf)  # a masked row, blocks without a finite logit
+            block = np.random.default_rng(seed).integers(0, nb, slots)
+            block[:2] = nb - 1, 0
+            return {"x": x, "block": jnp.asarray(block, jnp.int32)}
+
+        def flat(inp, stats, rows):
+            m, s = stats
+            held = (inp["block"] * width)[:, None] + jnp.arange(width)[None] < vocab
+            rows = jnp.where(held & jnp.isfinite(rows), rows, 0.0)
+            return jnp.concatenate([jnp.where(jnp.isfinite(m), m, -1.0).reshape(-1), s.reshape(-1), rows.reshape(-1)])
+
+        def kernel(inp):
+            return flat(
+                inp,
+                vbs.vocab_block_stats(inp["x"], interpret=not compiled),
+                vbs.vocab_block_pick(inp["x"], inp["block"], interpret=not compiled),
+            )
+
+        def reference(inp):
+            return flat(inp, vbs.vocab_block_stats_xla(inp["x"]), vbs.vocab_block_pick_xla(inp["x"], inp["block"]))
+
+        # sums of up to 4,096 exponentials in another order
+        return {"case": label, "build": build, "kernel": kernel, "reference": reference, "tol": 1e-2 if compiled else 1e-3}
+
+    if compiled:  # the cells' rows: Qwen2.5-1.5B, one chip's share of Kanana-2's, Phi-4-mini-flash
+        yield case("128x151936", 128, 151936)
+        yield case("64x19360", 64, 19360)
+        yield case("64x200064", 64, 200064)
+    else:
+        yield case("16x19360", 16, 19360)
+        yield case("8x4096-whole-blocks", 8, 4096)
+    yield case("5x300-one-block", 5, 300)
+
+
+# ---------------------------------------------------------------------------
 # harness
 # ---------------------------------------------------------------------------
 

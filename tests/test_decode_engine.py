@@ -358,26 +358,25 @@ def test_inverse_cdf_sampler_distribution():
 
 
 def test_hierarchical_sampler_two_level_path():
-    """V > 512 engages the two-level CDF decomposition (block pick +
-    in-block pick, crossing block boundaries); the draw must still follow
-    the exact softmax and report exact logprobs."""
+    """A row wider than one block of the sampler's partition engages the
+    two-level CDF decomposition (block pick + in-block pick, crossing block
+    boundaries, the last block partial); the draw must still follow the
+    exact softmax and report exact logprobs."""
     import jax
     import jax.numpy as jnp
 
-    from areal_tpu.inference.decode_programs import (
-        _inverse_cdf_sample,
-        _sample_blocks,
-    )
+    from areal_tpu.inference.decode_programs import _inverse_cdf_sample
+    from areal_tpu.ops.vocab_block_stats import block_width
 
-    V = 1024
-    assert V // _sample_blocks(V) > 1  # two-level path engaged
-    rng = np.random.default_rng(3)
-    base = np.full(V, -4.0, np.float32)
-    # peaks straddling block boundaries (inner=2 at V=1024 -> blocks {2k, 2k+1})
-    peaks = {7: 2.0, 8: 1.5, 511: 1.8, 512: 2.2, 1023: 1.0}
+    V = 4500
+    W = block_width(V)
+    assert W < V and V % W  # two-level path engaged: three blocks, the last of 404 columns
+    base = np.full(V, -6.0, np.float32)
+    # peaks straddling the block boundaries, and the row's two ends
+    peaks = {0: 1.0, W - 1: 2.0, W: 1.5, 2 * W - 1: 1.8, 2 * W: 2.2, V - 1: 1.0}
     for k, v in peaks.items():
         base[k] = v
-    n = 6000
+    n = 4000
     logits = jnp.asarray(np.tile(base, (n, 1)))
     want = np.asarray(jax.nn.softmax(jnp.asarray(base)))
     ids, logp, lse = jax.jit(_inverse_cdf_sample)(logits, jax.random.PRNGKey(1))

@@ -22,6 +22,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 L, KH, H, HD, PSZ = 28, 2, 12, 128, 128
@@ -218,6 +219,21 @@ def _tree(grad: bool):
     return (bwd if grad else fwd), args
 
 
+def _vocab_blocks(slots=SLOTS, vocab=151936):
+    """The sampler's two launches over a decode step's logits
+    (ops/vocab_block_stats.py): every block's statistics, then each row's
+    chosen block."""
+    from areal_tpu.ops import vocab_block_stats as vbs
+
+    def fn(scaled, block):
+        return vbs.vocab_block_stats(scaled), vbs.vocab_block_pick(scaled, block)
+
+    def args(S):
+        return [S((slots, vocab), jnp.float32), S((slots,), jnp.int32)]
+
+    return fn, args
+
+
 def _gmm():
     from areal_tpu.models.moe import pinned_gmm
 
@@ -322,6 +338,11 @@ CASES = {
     "mla_prefill_flash_kanana2_1k": lambda: _mla_prefill(32, 1024, 128, 128, 1024, 1024, masked=False),
     # 32 experts of [2048, 1792] (lfm2's, which the shape rule leaves on XLA's form): 44 MB of buffers
     "moe_touched_experts_wide": lambda: _moe_touched(128, 32, 1792, 12),
+    # the sampler over rollout-1.5b-grpo's logits, over a vocabulary that ends inside a lane tile (one chip's
+    # share of Kanana-2's) and over fewer rows than a tile and fewer columns than a block
+    "vocab_blocks_152k": _vocab_blocks,
+    "vocab_blocks_19360": lambda: _vocab_blocks(64, 19360),
+    "vocab_blocks_5x300": lambda: _vocab_blocks(5, 300),
 }
 
 
@@ -351,6 +372,7 @@ KERNEL_NAMES = {
     "moe_touched_experts_kanana2": ("moe_touched_experts",),
     "mla_prefill_flash_glm5_16k": ("mla_prefill_flash",),
     "tree_attention_bwd": ("tree_attn_fwd", "tree_attn_bwd_dq", "tree_attn_bwd_dkv"),
+    "vocab_blocks_152k": ("vocab_block_stats", "vocab_block_pick"),
 }
 
 
@@ -362,14 +384,11 @@ def test_kernel_carries_its_name_on_v5e(chip, name):
         assert kernel in text, f"{kernel} not in the compiled {name}"
 
 
-@pytest.mark.parametrize("kv_quant", [False, "int8"], ids=["bf16", "int8"])
-def test_decode_steps_update_the_pool_in_place(chip, kv_quant):
-    """The chunk program's loop at ``rollout-1.5b-grpo``'s sizes (28 scanned
-    layers, 128 slots, the 8 GB pool donated, two steps fed back greedily):
-    both kernels are in it by name, no scatter touches the pool, and the
-    compiled program holds no pool-sized temporary or copy (the check PR 21
-    made for the all-heads scatter, which cost a temporary and two copies a
-    layer)."""
+def _cell1_steps(chip, kv_quant, next_ids):
+    """The chunk program's loop at ``rollout-1.5b-grpo``'s sizes compiled for
+    the described chip (28 scanned layers, 128 slots, the 8 GB pool donated,
+    two steps fed back through ``next_ids(logits, step)``), and the cache's
+    shapes."""
     from areal_tpu.inference import paged_kv
     from areal_tpu.models import qwen
 
@@ -385,17 +404,27 @@ def test_decode_steps_update_the_pool_in_place(chip, kv_quant):
     cache = described(jax.eval_shape(lambda: paged_kv.init_paged_cache(cfg, 2340, PSZ, quant=kv_quant)))
 
     def steps(params, cache, ids, pos, table):
-        def step(carry, _):
+        def step(carry, i):
             ids, pos, cache = carry
             hid, cache = qwen.forward_decode_paged(params, cfg, ids, pos, cache, table, page_size=PSZ, use_kernel=True)
-            ids = jnp.argmax(qwen.compute_logits(params, cfg, hid), -1).astype(jnp.int32)
+            ids = next_ids(qwen.compute_logits(params, cfg, hid), i)
             return (ids, pos + 1, cache), ids
 
-        (_, _, cache), toks = jax.lax.scan(step, (ids, pos, cache), None, length=2)
+        (_, _, cache), toks = jax.lax.scan(step, (ids, pos, cache), jnp.arange(2))
         return cache, toks
 
     i32 = functools.partial(chip, dtype=jnp.int32)
     compiled = jax.jit(steps, donate_argnums=1).lower(params, cache, i32((SLOTS,)), i32((SLOTS,)), i32((SLOTS, 32))).compile()
+    return compiled, cache
+
+
+@pytest.mark.parametrize("kv_quant", [False, "int8"], ids=["bf16", "int8"])
+def test_decode_steps_update_the_pool_in_place(chip, kv_quant):
+    """Two greedy steps: both kernels are in the program by name, no scatter
+    touches the pool, and the compiled program holds no pool-sized temporary
+    or copy (the check PR 21 made for the all-heads scatter, which cost a
+    temporary and two copies a layer)."""
+    compiled, cache = _cell1_steps(chip, kv_quant, lambda logits, i: jnp.argmax(logits, -1).astype(jnp.int32))
     text = compiled.as_text()
     assert "paged_kv_write" in text and "paged_decode_attn" in text
     pool = f"[{L},{KH},2340,{PSZ},{HD}]"
@@ -403,6 +432,32 @@ def test_decode_steps_update_the_pool_in_place(chip, kv_quant):
     assert not touched, touched[:3]
     pool_bytes = max(x.size * x.dtype.itemsize for x in cache.values())
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8  # the [128, 151936] logits, not a pool
+
+
+def test_sampled_decode_steps_read_the_logits_once(chip):
+    """Two steps through the engine's own ``_sample_step`` as the rollout
+    cells run it (temperature, no top-k / top-p, no greedy slot): the
+    sampler's two launches are in the program by name, and no ``reshape``,
+    ``copy`` or ``while`` holds an array of slots x vocabulary float32
+    elements: until PR 46 a reshape of the logits to a divisor of the
+    vocabulary and its relayout were two passes of 78 MB a step."""
+    from areal_tpu.inference.decode_programs import _sample_step
+
+    state = {"temp": jnp.full((SLOTS,), 0.7, jnp.float32), "greedy": jnp.zeros((SLOTS,), bool)}
+
+    def sample(logits, i):
+        return _sample_step(logits, jax.random.fold_in(jax.random.PRNGKey(0), i), state, False, False, True)[0]
+
+    text = _cell1_steps(chip, False, sample)[0].as_text()
+    assert "vocab_block_stats" in text and "vocab_block_pick" in text
+    sized = re.compile(r"f32\[([0-9,]+)\]")
+    whole = [
+        line.strip()[:200]
+        for line in text.splitlines()
+        if re.search(r" (reshape|copy|while)\(", line)
+        and any(np.prod([int(d) for d in dims.split(",")]) >= SLOTS * 151936 for dims in sized.findall(line))
+    ]
+    assert not whole, whole[:3]
 
 
 def test_static_shape_rule_matches_the_compiler(chip):
