@@ -23,7 +23,12 @@ from typing import Hashable
 # outrun it. Chosen on the chip (PERF.md, PR 31), not a configuration field.
 COMMIT_FRACTION = 0.5
 # The estimate is the least of this many intervals: a prefill queued between
-# two chunks lengthens one interval, never all of them.
+# two chunks lengthens one interval, never all of them. Since PR 47 the loop
+# also says which intervals a prefill lengthened (``pulled(laden=True)``) and
+# those are left out: at the start of a long-context traffic EVERY early
+# interval holds prompt passes (eight prompts of 4k-16k tokens: 6.45 s where a
+# chunk is 0.34), the least of them was taken for a chunk's time, and the loop
+# held 3.2 + 1.6 + 0.8 s with the chip idle (PERF.md section 6, PR 47).
 KEPT_INTERVALS = 4
 # The slack left after the commit point has to cover the largest host time of
 # this many passes this many times over, or the loop does not hold at all (a
@@ -50,14 +55,15 @@ class ChunkPacer:
         self._last_pull = None
         self._intervals.clear()
 
-    def pulled(self, at: float, key: Hashable) -> None:
+    def pulled(self, at: float, key: Hashable, laden: bool = False) -> None:
         """The blocking pull of a chunk of program ``key`` returned at ``at``
-        while the next chunk was queued behind it."""
+        while the next chunk was queued behind it. ``laden``: a prefill was
+        queued before that chunk, so the interval it ends is no chunk's time."""
         if key != self._key:
             # another program (window, sampler variant): another time
             self._intervals.clear()
             self._key = key
-        elif self._last_pull is not None:
+        elif self._last_pull is not None and not laden:
             self._intervals.append(at - self._last_pull)
         self._last_pull = at
 

@@ -90,6 +90,15 @@ _SERVED_FORM_LEAVES = frozenset(
     f"{t}{suf}" for t in qwen.QUANT_TARGETS for suf in ("_q8", "_scale")
 )
 
+# a start from idle without a prefix cache (DecodeEngine._await_siblings): the
+# admission waits until no request has arrived for SIBLING_GAP_S_PER_TOKEN a
+# token of the longest queued prompt (a twentieth of what a prompt token costs
+# the slowest family served, 125 us on a v5e), SIBLING_GAP_S at most, and
+# SIBLING_WAIT_S in all
+SIBLING_GAP_S_PER_TOKEN = 5e-6
+SIBLING_GAP_S = 0.05
+SIBLING_WAIT_S = 0.5
+
 
 @dataclass
 class _Task:
@@ -1453,6 +1462,8 @@ class DecodeEngine:
             self._obs.moe_experts_streamed.inc(int(counts["moe_streamed"].sum()))
         if "gdn_updates" in counts:
             self._obs.gdn_state_updates.inc(int(counts["gdn_updates"].sum()))
+        if "kda_updates" in counts:
+            self._obs.kda_state_updates.inc(int(counts["kda_updates"].sum()))
         if "latent_tokens_read" in counts:
             self._obs.latent_tokens_read.inc(int(counts["latent_tokens_read"].sum()))
         for leaf, counter in (
@@ -2513,7 +2524,7 @@ class DecodeEngine:
         self._pull_s += t_back - t_pull
         if "key" in pending:
             # two consecutive returns are one chunk's wall time apart
-            self._pacer.pulled(t_back, pending["key"])
+            self._pacer.pulled(t_back, pending["key"], laden=pending.get("laden", False))
         credited = 0
         with self._kphase("bookkeeping"):
             n_steps = pending["n_steps"]
@@ -2617,6 +2628,38 @@ class DecodeEngine:
                 self._wakeup.clear()
         return self._pace_clock() - t0
 
+    def _await_siblings(self) -> float:
+        """A start from idle on a model with no prefix cache (a recurrent
+        state, latent pages: slot_cache.py says which): the requests of a
+        GRPO group arrive over some tens of milliseconds, the first of them
+        wakes the loop, and a sibling that misses its group's admission can
+        alias nothing afterwards, so it pays a prompt pass of its own (8
+        clients' first groups of 4k-16k tokens on one v5e: 4-7 of 8 groups
+        cut, 3.4-6.5 s of second prompt passes, another split every run:
+        PERF.md section 6, PR 47). So the first admission waits until no
+        request has arrived for a time that is small against the prompt pass
+        it may save. Nothing decodes meanwhile, by the caller's condition,
+        so no row waits on it but the queued ones. Returns the seconds
+        waited."""
+        t0 = seen_at = time.monotonic()
+        seen = -1
+        while not self._loop_needed():
+            with self._queue.mutex:
+                queued = [len(t.req.input_ids) for t in self._queue.queue]
+            queued += [len(t.req.input_ids) for t in self._backlog]
+            if not queued:
+                break
+            now = time.monotonic()
+            if len(queued) != seen:
+                seen, seen_at = len(queued), now
+            gap = min(SIBLING_GAP_S, SIBLING_GAP_S_PER_TOKEN * max(queued))
+            left = min(seen_at + gap, t0 + SIBLING_WAIT_S) - now
+            if left <= 0:
+                break
+            self._wakeup.wait(left)
+            self._wakeup.clear()
+        return time.monotonic() - t0
+
     def _kphase(self, name: str):
         """Phase span on the current pass's kernel-probe timeline
         (observability/kernel_probe.py); a no-op null context outside a
@@ -2658,6 +2701,15 @@ class DecodeEngine:
         # the chunk in flight has only just begun: commit the next one's
         # batch part-way through it, with everything that arrives until then
         held = self._hold_for_commit(pending)
+        if (
+            pending is None
+            and self.slots.radix is None
+            and not any(t is not None for t in self._slot_task)
+        ):
+            # from idle: the admission below is the only one that a group's
+            # siblings can share
+            with self._kphase("admission"):
+                held += self._await_siblings()
         # lifecycle reaping BETWEEN chunks: cancellations, expired
         # deadlines (queued and decoding), per-slot watchdog — the
         # overload-safety half of interruptible generation. When a reap
@@ -2698,6 +2750,8 @@ class DecodeEngine:
             # chunk's download while this one computes
             with self._kphase("dispatch"):
                 dispatched = self._dispatch_chunk()
+                if dispatched is not None:
+                    dispatched["laden"] = bool(rows)  # this pass's prefills run before it: its interval is no chunk's time
             tokens = self._drain(pending)
             pending = dispatched
             worked = dispatched is not None

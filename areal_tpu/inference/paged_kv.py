@@ -441,8 +441,13 @@ def n_pages_for_budget(
 # float32, 2.2 MB a layer and slot at Olmo-Hybrid-7B). All go through the same
 # programs.
 # A fourth tenant is a Mamba-1 layer's state and window (``ssm`` [state size,
-# channels] + ``conv``: 0.36 MB a layer and slot at Phi-4-mini-flash).
-STATE_LEAVES = ("ssm", "conv", "gdn")
+# channels] + ``conv``: 0.36 MB a layer and slot at Phi-4-mini-flash), a fifth
+# the matrix state of a delta rule whose decay is a vector over the key
+# channels, and its three conv windows (``kda`` [layers, slots, H, K, V] +
+# ``conv``: 64 heads of 128 x 128 in float32, 4.19 MB a layer and slot at
+# Solar-Open2, beside an expert share; the fan-out copy of a group of 8 moves
+# 7 x 25 MB of it).
+STATE_LEAVES = ("ssm", "conv", "gdn", "kda")
 # Between the two stand the RINGS of layers that attend to the last
 # ``sliding_window`` tokens only (``cfg.kv_groups``: which layers a group of
 # pools serves and how long they keep a token, is the model configuration's

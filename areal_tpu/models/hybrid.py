@@ -23,8 +23,14 @@ gate on the last ``s6`` layer's scan output of the same token, which rides
 down the stack with the cache), with differential attention in every
 attending layer (``_diff_pack_q``: 4 query heads of 2 x head_dim lanes to one
 row [k1 | k2], the paged kernels' own grouped-query shape) and a prompt pass
-that ends at the shared layer's K and V (``forward_prefill`` ``tail``). Five
-published families are built from these (``from_hf_dict``): ``phi4flash``,
+that ends at the shared layer's K and V (``forward_prefill`` ``tail``). A
+tenth mixer, ``kda``, is a delta rule whose state decays by a factor of its
+OWN every key channel (below), and the ``attention`` mixer may carry an
+output gate (``attn_gate``: ``o * sigmoid(W_g u)``, one gate a head and
+channel). Six published families are built from these (``from_hf_dict``):
+``solar_open2`` (``kda`` beside gated attention without a positional
+embedding, 3:1, and in EVERY layer sigmoid-routed experts with a selection
+bias beside a shared one: no leading dense layer), ``phi4flash``,
 ``granitemoehybrid``
 without experts (Mamba-2 beside NoPE attention, dense MLPs, residual and
 logit multipliers), ``lfm2_moe`` (short convolutions beside rotary attention
@@ -76,6 +82,36 @@ unit lower-triangular system, across chunks the state carried in float32.
 tests/test_olmo_hybrid_model.py holds them to each other and to the
 token-by-token reference.
 
+The ``kda`` mixer (Kimi Linear's ``KimiDeltaAttention``, arXiv 2510.26692;
+``solar_open2``'s linear-attention layer): q, k, v as the gated delta rule's
+(a projection, a depthwise causal conv of its own without bias, SiLU; q and k
+L2-normalised a head, q scaled by K^-1/2); a log decay a head AND key channel
+``a_t[h, :] = -exp(A_log[h]) softplus(W_fb W_fa u + dt_bias)[h, :]`` in R^K
+through a low rank; ``beta_t[h] = sigmoid(W_b u)[h]`` (x 2 with
+``kda_neg_eigval``); state ``S`` in R^{K x V} a head, float32: ``S' =
+diag(exp(a_t)) S_{t-1}``, ``w_t = beta_t (v_t - S'^T k_t)``, ``S_t = S' + k_t
+w_t^T``, ``o_t = S_t^T q_t``; ``y = W_o (rmsnorm_head(o_t) * sigmoid(W_gb W_ga
+u))``. It is NOT ``gdn`` with other numbers: the decay is a vector over a
+head's key channels, so the chunked prompt scan cannot factor it out of ``K
+K^T`` as one [C, C] matrix a head. Two forms of one recurrence, as ``gdn``
+has: ``kda_decode_step`` is the recurrence itself (on a TPU the Pallas kernel
+``ops/kda_state_update.py`` over the live slots, in place, its decay a column
+operand a head); ``kda_chunked_scan`` the WY algorithm over a prompt, every
+exponent a difference in the direction of time (its docstring says how), the
+state carried across chunks, and across the BLOCKS a long prompt goes through
+(``kda_prefill``), in float32. tests/test_solar_open2_model.py holds them to
+each other and to the token-by-token reference. The gated ``attention``
+mixer's prompt pass builds [H, L, L] float32 logits a row where they fit and
+attends under ``ops/attention.py flash_fwd_pallas`` where they do not (64
+heads past 1,024 tokens), chosen from the shapes (``gqa_prefill_launch``);
+the XLA form is the CPU path and the launch's oracle. The layers of a block
+(every line an ASSUMPTION the configuration file lists with its reason: the
+published ``config.json`` names sizes and switches, not forms): ``u =
+rmsnorm(x)``, ``h = x + Mixer(u)``, ``out = h + MoE(rmsnorm(h))``, eps 1e-5,
+no bias anywhere; MoE: ``s = sigmoid(W_r x)`` over the router's experts, the
+``num_experts_per_tok`` largest of ``s + b``, gates ``s_e / sum(picked s)``,
+routed SwiGLU experts beside ONE shared SwiGLU.
+
 The latent-attention mixer comes in two forms of one layer's weights, and the
 entry point chooses, never an option: ``forward_prefill`` computes the PLAIN
 form (``[k_nope_h | v_h] = W_kvb,h c`` for the prompt's own tokens, every
@@ -126,10 +162,11 @@ from jax.sharding import PartitionSpec as P
 from areal_tpu.models import moe, qwen
 from areal_tpu.models.qwen import _embed_lookup, _proj, _rms_norm, _rope
 
-MODEL_TYPES = ("granitemoehybrid", "lfm2_moe", "olmo_hybrid", "deepseek_v3", "glm_moe_dsa", "phi4flash")
+MODEL_TYPES = ("granitemoehybrid", "lfm2_moe", "olmo_hybrid", "deepseek_v3", "glm_moe_dsa", "phi4flash", "solar_open2")
 # mixers; ``s6`` is Mamba-1's selective scan, ``swa`` attention over the last ``sliding_window`` tokens, ``cross``
-# queries of its own over the pages of the model's ONE ``attention`` layer, ``gmu`` a gated memory unit
-KINDS = ("mamba", "attention", "conv", "gdn", "mla", "s6", "swa", "cross", "gmu")
+# queries of its own over the pages of the model's ONE ``attention`` layer, ``gmu`` a gated memory unit, ``kda`` a
+# delta rule whose state decays by a factor of its own every key channel
+KINDS = ("mamba", "attention", "conv", "gdn", "mla", "s6", "swa", "cross", "gmu", "kda")
 FFNS = ("dense", "moe")
 # scopes this family adds to qwen.SCOPES (docs/observability.md): the
 # state-space mixer's, the short-conv mixer's, and models/moe.py's
@@ -137,6 +174,9 @@ SCOPES = ("ssm_proj", "ssm_conv", "ssm_state", "state_write")
 CONV_SCOPES = ("conv_proj", "conv_mix", "state_write")
 MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
 GDN_SCOPES = ("gdn_proj", "gdn_conv", "gdn_state", "state_write")
+# the delta rule with a decay a key channel, and the output gate of an attention layer that has one
+KDA_SCOPES = ("kda_proj", "kda_conv", "kda_state", "state_write")
+ATTN_GATE_SCOPE = "attn_gate"
 MLA_SCOPES = ("mla_proj", "attn", "kv_write")  # latent attention: its projections beside the shared two
 # a low-rank query path, and the learned index that picks the cached tokens a query attends to
 DSA_SCOPES = ("mla_q_lora", "dsa_index_proj", "dsa_index_score", "dsa_select")
@@ -146,10 +186,14 @@ MOE_SHARED_SCOPE = "moe_shared"  # the always-active block beside the routed exp
 SAMBAY_SCOPES = ("attn_window", "attn_cross", "attn_diff", "gmu")
 # tokens a chunk of the delta rule's prefill scan: 16 x 2^2, as ``_unit_lower_inverse`` builds its inverse
 GDN_CHUNK = 64
+# the per-channel-decay delta rule's: the same chunk, in sub-blocks of 16 tokens each referred to its own first
+# token (``kda_chunked_scan``); a prompt goes through in blocks of up to this many tokens, the state carried between
+KDA_CHUNK, KDA_SUB = 64, 16
+_KDA_BLOCK_TOKENS = 1024
 # what a decode chunk may ask the forward to count into (not part of the
 # cache the engine keeps): rows of live slots each expert got, [expert
 # layers, experts], and experts with at least one such row, [expert layers];
-# live slots whose delta-rule state a step advanced, [gdn layers]; cached
+# live slots whose delta-rule state a step advanced, [gdn layers] ([kda layers] for ``kda_updates``); cached
 # tokens of live slots a latent-attention layer read, [mla layers]; where the
 # layer has an index, the cached tokens it scored and the tokens the
 # mathematics selects of them (min(index_topk, cached) a live slot), [mla layers]
@@ -163,7 +207,7 @@ COUNT_LEAVES = (
     "moe_load", "moe_touched", "moe_streamed", "gdn_updates", "latent_tokens_read",
     "index_tokens_scored", "latent_tokens_selected",
     "shared_kv_tokens_read", "window_tokens_read", "s6_updates",
-    "attn_blocks_listed", "attn_blocks_fetched",
+    "attn_blocks_listed", "attn_blocks_fetched", "kda_updates",
 )
 
 
@@ -281,6 +325,18 @@ class HybridConfig:
     # K heads (2r, 2r+1) are (k1, k2) and V heads (v1 | v2) of pair r, head p reads pair p // 2
     diff_attn: bool = False
     attn_bias: bool = False  # biases on the attending layers' projections
+    # an ``attention`` layer's output gate: ``o * sigmoid(W_g u)``, one gate a head and channel
+    attn_gate: bool = False
+    # the ``kda`` mixer (a delta rule with a decay of its own every key channel): heads, a head's key and value
+    # size, the taps of its three depthwise convs, the rank its decay and its output gate are projected through,
+    # beta in (0, 2) rather than (0, 1)
+    kda_n_heads: int = 0
+    kda_k_dim: int = 0
+    kda_v_dim: int = 0
+    kda_d_conv: int = 4
+    kda_rank: int = 0
+    kda_neg_eigval: bool = False
+    kda_state_dtype: str = "float32"
 
     @property
     def num_layers(self) -> int:
@@ -343,6 +399,11 @@ class HybridConfig:
     def gdn_conv_dim(self) -> int:
         """Channels of the delta-rule mixer's conv window: [q | k | v]."""
         return self.gdn_n_heads * (2 * self.gdn_k_dim + self.gdn_v_dim)
+
+    @property
+    def kda_conv_dim(self) -> int:
+        """Channels of the ``kda`` mixer's conv window: [q | k | v]."""
+        return self.kda_n_heads * (2 * self.kda_k_dim + self.kda_v_dim)
 
     @property
     def gdn_head_pack(self) -> int:
@@ -430,7 +491,7 @@ class HybridConfig:
 
     @property
     def has_recurrent_state(self) -> bool:
-        return self.count("mamba") + self.count("conv") + self.count("gdn") + self.count("s6") > 0
+        return self.count("mamba") + self.count("conv") + self.count("gdn") + self.count("s6") + self.count("kda") > 0
 
     @property
     def count_shapes(self) -> dict[str, tuple[int, ...]]:
@@ -452,6 +513,8 @@ class HybridConfig:
             out["s6_updates"] = (1,)
         if self.count("attention") + self.count("cross"):
             out["attn_blocks_listed"] = out["attn_blocks_fetched"] = (1,)
+        if n := self.count("kda"):
+            out["kda_updates"] = (n,)
         return out
 
     @property
@@ -468,10 +531,11 @@ class HybridConfig:
         window is stored token-major and flat, ``(taps - 1) * channels``
         wide: with the tokens as the minor dimension the TPU would pad every
         channel's 2 or 3 values to a 128-lane row. A model has state-space,
-        short-conv, delta-rule or selective-scan layers, one of the four:
-        ``conv`` is its window (a delta-rule layer's three, side by side: [q |
-        k | v]). The delta-rule state ``gdn`` holds ``gdn_head_pack`` heads a
-        tile."""
+        short-conv, delta-rule (``gdn`` or ``kda``) or selective-scan layers,
+        one of the five: ``conv`` is its window (a delta-rule layer's three,
+        side by side: [q | k | v]). The delta-rule state ``gdn`` holds
+        ``gdn_head_pack`` heads a tile; ``kda`` [layers, slots, H, K, V] a
+        head a tile (its value size is whole lane tiles as published)."""
         conv_dtype = jnp.dtype(self.conv_state_dtype or self.dtype)
         if n := self.count("s6"):
             # the state [state size, channels]: channels on the lanes, a decay of its own every element
@@ -498,6 +562,11 @@ class HybridConfig:
                 ),
                 "conv": ((n, slots, (self.gdn_d_conv - 1) * self.gdn_conv_dim), conv_dtype),
             }
+        if n := self.count("kda"):
+            return {
+                "kda": ((n, slots, self.kda_n_heads, self.kda_k_dim, self.kda_v_dim), jnp.dtype(self.kda_state_dtype)),
+                "conv": ((n, slots, (self.kda_d_conv - 1) * self.kda_conv_dim), conv_dtype),
+            }
         return {}
 
     @classmethod
@@ -513,7 +582,7 @@ class HybridConfig:
         extra = {
             k: d[k]
             for k in (
-                "dtype", "ssm_state_dtype", "conv_state_dtype", "gdn_state_dtype", "kv_lane_pad", "head_dim",
+                "dtype", "ssm_state_dtype", "conv_state_dtype", "gdn_state_dtype", "kda_state_dtype", "kv_lane_pad", "head_dim",
                 "latent_row_lanes", "router_experts", "expert_first", "index_norm_eps",
             )
             if k in d
@@ -571,6 +640,35 @@ class HybridConfig:
                 "mamba_expand": self.s6_d_inner // self.hidden_size,
                 "mamba_dt_rank": self.s6_dt_rank,
                 "attn_bias": self.attn_bias,
+            }
+        if self.model_type == "solar_open2":
+            gqa = list(self.layers_of("attention"))
+            return {
+                **shared,
+                "rms_norm_eps": self.rms_norm_eps,
+                "hidden_act": "silu",
+                "head_dim": self.head_dim_,
+                "use_rope": False,
+                "partial_rotary_factor": 1,
+                "gqa_layers": gqa,
+                "gqa_interval": (gqa[1] - gqa[0] - 1) if len(gqa) > 1 else max(0, self.num_layers - 1),
+                "use_gqa_gate": self.attn_gate,
+                "linear_attn_config": {
+                    "short_conv_kernel_size": self.kda_d_conv,
+                    "head_dim": self.kda_k_dim,
+                    "num_heads": self.kda_n_heads,
+                    "num_kv_heads": None,
+                },
+                "kda_use_full_proj": False,
+                "kda_allow_neg_eigval": self.kda_neg_eigval,
+                "first_k_dense_replace": sum(1 for f in self.ffns if f == "dense"),
+                "n_routed_experts": self.num_experts,
+                "n_shared_experts": self.moe_shared_intermediate_size // max(1, self.moe_intermediate_size or 1),
+                "num_experts_per_tok": self.num_experts_per_tok,
+                "moe_intermediate_size": self.moe_intermediate_size,
+                "norm_topk_prob": self.norm_topk_prob,
+                "routed_scaling_factor": self.routed_scaling_factor,
+                **({"router_experts": self.router_experts, "expert_first": self.expert_first} if self.router_experts else {}),
             }
         if self.model_type == "olmo_hybrid":
             return {
@@ -897,7 +995,71 @@ def _phi4flash_fields(d: dict[str, Any]) -> dict[str, Any]:
     )
 
 
+def _solar_open2_fields(d: dict[str, Any]) -> dict[str, Any]:
+    """``solar_open2``: gated softmax attention without a positional embedding
+    at the layers ``gqa_layers`` names (grouped queries, ``o * sigmoid(W_g
+    u)`` before the output projection), a delta rule with a decay of its own
+    every key channel (``kda``) everywhere else, and in EVERY layer past
+    ``first_k_dense_replace`` ``n_routed_experts`` experts behind a sigmoid
+    router with a selection bias beside ``n_shared_experts`` always-active
+    ones. The published ``config.json`` names sizes and switches
+    (``linear_attn_config``, ``use_gqa_gate``, ``kda_use_full_proj``,
+    ``kda_allow_neg_eigval``), not forms: the delta-rule layer is Kimi
+    Linear's ``KimiDeltaAttention`` (arXiv 2510.26692), the gate arXiv
+    2505.06708's elementwise one, the router the DeepSeek-V3 lineage's
+    (sigmoid scores, ``e_score_correction_bias``, gates normalised over the
+    chosen): ASSUMPTIONS a configuration file lists. What this module does
+    not implement is refused, never ignored."""
+    if d.get("use_rope"):
+        raise ValueError("solar_open2 with use_rope is not implemented (its attention layers carry no position)")
+    if d.get("kda_use_full_proj"):
+        raise ValueError("solar_open2 with kda_use_full_proj is not implemented (the decay's low-rank projection is)")
+    if not d.get("use_gqa_gate", True):
+        raise ValueError("solar_open2 without use_gqa_gate is not implemented")
+    if d.get("attention_bias") or int(d.get("n_group") or 1) != 1 or int(d.get("topk_group") or 1) != 1:
+        raise ValueError("solar_open2 with projection biases or group-limited routing is not implemented")
+    if d.get("scoring_func", "sigmoid") != "sigmoid":
+        raise ValueError(f"solar_open2 router {d.get('scoring_func')!r}: only sigmoid")
+    lin = d["linear_attn_config"]
+    heads, hd = int(lin["num_heads"]), int(lin["head_dim"])
+    if lin.get("num_kv_heads") not in (None, heads):
+        raise ValueError("a kda layer with fewer key/value heads than query heads is not implemented")
+    n = int(d["num_hidden_layers"])
+    gqa = {int(i) for i in d["gqa_layers"]}
+    if not gqa <= set(range(n)):
+        raise ValueError(f"gqa_layers {sorted(gqa)} are not among the model's {n} layers")
+    n_dense = min(n, int(d.get("first_k_dense_replace", 0)))
+    experts = int(d.get("n_routed_experts") or 0) if n_dense < n else 0
+    if n_dense < n and experts < 1:
+        raise ValueError("solar_open2 layers past first_k_dense_replace need n_routed_experts")
+    return dict(
+        intermediate_size=d["intermediate_size"],
+        layer_types=tuple("attention" if i in gqa else "kda" for i in range(n)),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+        rope_theta=None,
+        attn_gate=True,
+        kda_n_heads=heads,
+        kda_k_dim=hd,
+        kda_v_dim=hd,
+        kda_d_conv=int(lin.get("short_conv_kernel_size", 4)),
+        kda_rank=hd,  # Kimi Linear projects the decay and the output gate through the head size
+        kda_neg_eigval=bool(d.get("kda_allow_neg_eigval", False)),
+        ffn_types=tuple("dense" if i < n_dense else "moe" for i in range(n)),
+        fused_gate_up=False,
+        num_experts=experts,
+        num_experts_per_tok=int(d.get("num_experts_per_tok", 1)),
+        moe_intermediate_size=d.get("moe_intermediate_size"),
+        moe_shared_intermediate_size=int(d.get("n_shared_experts") or 0) * int(d.get("moe_intermediate_size") or 0),
+        norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+        router_score="sigmoid",
+        router_bias=True,
+        router_norm_eps=1e-20,
+    )
+
+
 _FIELDS = {
+    "solar_open2": _solar_open2_fields,
     "phi4flash": _phi4flash_fields,
     "granitemoehybrid": _granite_fields,
     "lfm2_moe": _lfm2_fields,
@@ -916,6 +1078,11 @@ def prefill_row_bytes(cfg: HybridConfig, bucket: int) -> int:
     model then goes through alone: ONE program a bucket, and the scan's
     temporaries once."""
     stream = qwen.prefill_row_bytes(cfg, bucket)
+    if cfg.count("kda"):
+        # a block of the prompt (``kda_prefill``): q, k, v, the log decay and its running sum in float32 [block,
+        # H, K], four masked copies of the keys a chunk's sub-blocks meet, and the chunk matrices beside them
+        block = min(bucket, _KDA_BLOCK_TOKENS)
+        return max(stream, 12 * block * cfg.kda_n_heads * cfg.kda_k_dim * 4)
     return max(stream, 3 * bucket * cfg.s6_d_inner * 4) if cfg.count("s6") else stream
 
 
@@ -981,6 +1148,7 @@ def _layer_shapes(cfg: HybridConfig) -> dict[str, dict[str, tuple[int, ...]]]:
             "wk": (D, cfg.kv_dim),
             "wv": (D, cfg.kv_dim),
             "wo": (cfg.q_dim, D),
+            **({"wg": (D, cfg.q_dim)} if cfg.attn_gate else {}),
             **(
                 {}
                 if not cfg.qk_norm
@@ -995,6 +1163,7 @@ def _layer_shapes(cfg: HybridConfig) -> dict[str, dict[str, tuple[int, ...]]]:
             "out_proj": (D, D),
         },
         "gdn": _gdn_shapes(cfg),
+        "kda": _kda_shapes(cfg),
         "mla": {
             **(
                 {
@@ -1067,6 +1236,34 @@ def _gdn_shapes(cfg: HybridConfig) -> dict[str, tuple[int, ...]]:
         "A_log": (H,),
         "dt_bias": (H,),
         "o_norm": (cfg.gdn_v_dim,),
+        "o_proj": (vd, D),
+    }
+
+
+def _kda_shapes(cfg: HybridConfig) -> dict[str, tuple[int, ...]]:
+    """One ``kda`` mixer's leaves, as Kimi Linear's ``KimiDeltaAttention`` has
+    them: three projections each with a depthwise conv of its own (taps as
+    the Mamba mixer's), the decay's low-rank pair ``f_a`` / ``f_b`` with its
+    bias a head and key channel and ``A_log`` a head, the write strength's
+    projection a head, the output gate's low-rank pair ``g_a`` / ``g_b``, the
+    norm over a head's values, the output projection."""
+    D, H, r = cfg.hidden_size, cfg.kda_n_heads, cfg.kda_rank
+    qk, vd, kc = H * cfg.kda_k_dim, H * cfg.kda_v_dim, cfg.kda_d_conv
+    return {
+        "q_proj": (D, qk),
+        "k_proj": (D, qk),
+        "v_proj": (D, vd),
+        "f_a": (D, r),
+        "f_b": (r, qk),
+        "b_proj": (D, H),
+        "g_a": (D, r),
+        "g_b": (r, vd),
+        "q_conv_w": (kc, 1, qk),
+        "k_conv_w": (kc, 1, qk),
+        "v_conv_w": (kc, 1, vd),
+        "A_log": (H,),
+        "dt_bias": (qk,),
+        "o_norm": (cfg.kda_v_dim,),
         "o_proj": (vd, D),
     }
 
@@ -1281,7 +1478,37 @@ _HF_LAYER_MAPS["phi4flash"] = {
     "gmu_in": ("attn.in_proj.weight", True),
     "gmu_out": ("attn.out_proj.weight", True),
 }
+# ``solar_open2``: UNCHECKED against a checkpoint (no network, no ``config.json`` or weights of the family on this
+# machine, and the installed transformers has no ``solar_open2``). The delta-rule layer's names are Kimi Linear's
+# ``KimiDeltaAttention`` attributes under ``self_attn``, the attention layer's gate ``self_attn.g_proj``, the expert
+# block's the DeepSeek-V3 lineage's, as ISSUE 47 reads the configuration's keys; ``q_proj`` / ``k_proj`` / ``v_proj``
+# / ``o_proj`` of a delta-rule layer and ``wq`` ... of an attention layer both sit under ``self_attn`` there.
+_HF_LAYER_MAPS["solar_open2"] = {
+    **{k: v for k, v in _HF_LAYER_MAPS["deepseek_v3"].items() if k in (
+        "input_norm", "post_norm", "wq", "wo", "w_gate", "w_up", "w_down", "w_router", "router_bias",
+        "we_gate", "we_up", "we_down", "ws_gate", "ws_up", "ws_down",
+    )},
+    "wk": ("self_attn.k_proj.weight", True),
+    "wv": ("self_attn.v_proj.weight", True),
+    "wg": ("self_attn.g_proj.weight", True),
+    "q_proj": ("self_attn.q_proj.weight", True),
+    "k_proj": ("self_attn.k_proj.weight", True),
+    "v_proj": ("self_attn.v_proj.weight", True),
+    "f_a": ("self_attn.f_a_proj.weight", True),
+    "f_b": ("self_attn.f_b_proj.weight", True),
+    "b_proj": ("self_attn.b_proj.weight", True),
+    "g_a": ("self_attn.g_a_proj.weight", True),
+    "g_b": ("self_attn.g_b_proj.weight", True),
+    "q_conv_w": ("self_attn.q_conv1d.weight", True),
+    "k_conv_w": ("self_attn.k_conv1d.weight", True),
+    "v_conv_w": ("self_attn.v_conv1d.weight", True),
+    "A_log": ("self_attn.A_log", False),
+    "dt_bias": ("self_attn.dt_bias", False),
+    "o_norm": ("self_attn.o_norm.weight", False),
+    "o_proj": ("self_attn.o_proj.weight", True),
+}
 _HF_TOP = {
+    "solar_open2": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "deepseek_v3": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "glm_moe_dsa": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "granitemoehybrid": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
@@ -1602,7 +1829,12 @@ def _gdn_taps(layer: dict):
 def _gdn_heads(cfg: HybridConfig, qkv):
     """[..., gdn_conv_dim] float32 after conv and SiLU -> q and k [..., H, K]
     L2-normalised (q also scaled by K^-1/2), v [..., H, V]."""
-    H, K, V = cfg.gdn_n_heads, cfg.gdn_k_dim, cfg.gdn_v_dim
+    return _delta_heads(qkv, cfg.gdn_n_heads, cfg.gdn_k_dim, cfg.gdn_v_dim)
+
+
+def _delta_heads(qkv, H: int, K: int, V: int):
+    """A delta-rule mixer's [q | k | v] after conv and SiLU, split into heads
+    (``_gdn_heads``, ``_kda_heads``)."""
     q, k, v = jnp.split(qkv, [H * K, 2 * H * K], axis=-1)
     lead = qkv.shape[:-1]
     q, k, v = q.reshape(*lead, H, K), k.reshape(*lead, H, K), v.reshape(*lead, H, V)
@@ -1788,6 +2020,234 @@ def gdn_prefill(cfg: HybridConfig, layer: dict, h, n_state, state_dtypes):
     y, s, conv = jax.lax.map(row, (raw, z, g, beta, n_state))
     with jax.named_scope("gdn_proj"):
         return _proj(cfg, layer, "o_proj", y), s, conv
+
+
+# ---------------------------------------------------------------------------
+# the delta rule with a decay of its own every key channel (``kda``)
+# ---------------------------------------------------------------------------
+
+
+def _kda_in(cfg: HybridConfig, layer: dict, h):
+    """The projections of h [..., D]: (the convs' input [q | k | v] [...,
+    kda_conv_dim], the output gate's logits [..., H * V], the log decay a
+    [..., H, K] <= 0 and the write strength beta [..., H], both float32).
+    ``a = -exp(A_log) softplus(W_fb W_fa h + dt_bias)``, ``beta = sigmoid(W_b
+    h)`` (x 2 with ``kda_neg_eigval``)."""
+    H, K = cfg.kda_n_heads, cfg.kda_k_dim
+    with jax.named_scope("kda_proj"):
+        raw = jnp.concatenate([_proj(cfg, layer, n, h) for n in ("q_proj", "k_proj", "v_proj")], axis=-1)
+        z = _proj(cfg, layer, "g_b", _proj(cfg, layer, "g_a", h))
+        f = _proj(cfg, layer, "f_b", _proj(cfg, layer, "f_a", h)).astype(jnp.float32)
+        b = _proj(cfg, layer, "b_proj", h).astype(jnp.float32)
+        a = jax.nn.softplus(f + layer["dt_bias"].astype(jnp.float32)).reshape(*h.shape[:-1], H, K)
+        a = -jnp.exp(layer["A_log"].astype(jnp.float32))[:, None] * a
+        beta = jax.nn.sigmoid(b) * (2.0 if cfg.kda_neg_eigval else 1.0)
+    return raw, z, a, beta
+
+
+def _kda_heads(cfg: HybridConfig, qkv):
+    """[..., kda_conv_dim] float32 after conv and SiLU -> q and k [..., H, K]
+    L2-normalised (q also scaled by K^-1/2), v [..., H, V]."""
+    return _delta_heads(qkv, cfg.kda_n_heads, cfg.kda_k_dim, cfg.kda_v_dim)
+
+
+def _kda_out(cfg: HybridConfig, layer: dict, o, z, dtype):
+    """rmsnorm over each head's values, times sigmoid(gate): [..., H * V]."""
+    lead = o.shape[:-2]
+    z = z.astype(jnp.float32).reshape(*lead, cfg.kda_n_heads, cfg.kda_v_dim)
+    y = _rms_norm(o, layer["o_norm"].astype(jnp.float32), cfg.rms_norm_eps) * jax.nn.sigmoid(z)
+    return y.reshape(*lead, -1).astype(dtype)
+
+
+def kda_decode_step(state, q, k, v, decay, beta, active):
+    """The recurrence, one token for each of S slots: ``S' = diag(decay) S``,
+    ``w = beta (v - S'^T k)``, ``S = S' + k w^T``, ``o = S^T q``.
+
+    state [S, H, K, V] (its own dtype, computed in float32), q, k and decay =
+    exp(a) [S, H, K], v [S, H, V], beta [S, H]. Returns (new state, o [S, H,
+    V] float32). A slot that is not ``active`` keeps its state bit for bit."""
+    decayed = state.astype(jnp.float32) * decay[..., :, None]
+    w = beta[..., None] * (v - jnp.sum(decayed * k[..., :, None], axis=-2))
+    new = decayed + k[..., :, None] * w[..., None, :]
+    o = jnp.sum(new * q[..., :, None], axis=-2)
+    return jnp.where(active[:, None, None, None], new.astype(state.dtype), state), o
+
+
+def kda_chunked_scan(q, k, v, a, beta, n_state, s0=None):
+    """The chunked (WY) algorithm for the same recurrence over ONE prompt, or
+    a block of one, from the state ``s0`` [H, K, V] float32 (default zero).
+
+    q, k and a [L, H, K], v [L, H, V], beta [L, H], n_state a scalar: only
+    the first ``n_state`` tokens enter the state (``beta`` and ``a`` are 0
+    from there on: the state neither decays nor is written; ``o`` there is
+    not the model's). With ``G_t`` the running sum of ``a`` inside a chunk
+    of ``KDA_CHUNK`` tokens the writes solve the unit lower-triangular system
+    ``(I + tril(diag(beta) A, -1)) W = diag(beta) (V - (K * exp(G)) S_0)``,
+    ``A[t, s] = sum_c k_t[c] k_s[c] exp(G_t[c] - G_s[c])``, and the reads are
+    ``o_t = S_0^T (q_t * exp(G_t)) + sum_{s <= t} B[t, s] w_s`` with ``B`` as
+    ``A`` with ``q_t`` for ``k_t``. The decay cannot be factored out of ``K
+    K^T`` as one matrix a head, and ``k exp(G)`` against ``k exp(-G)``
+    overflows float32 once a channel's log decay over a chunk passes -88. So
+    EVERY exponent taken here is <= 0, as ``fla``'s kernels arrange it: a
+    chunk is 4 sub-blocks of ``KDA_SUB`` = 16 tokens; a query in sub-block i
+    meets the keys of EARLIER sub-blocks through the sub-block's first token
+    r_i, ``(k_t exp(G_t - G_r))`` against ``(k_s exp(G_r - G_s))``, both
+    exponents differences in the direction of time; keys of its OWN sub-block
+    it meets directly, ``exp(G_t - G_s)`` for s <= t, one key offset at a
+    time. It therefore holds to NO bound on ``|a_t|``: a product that
+    underflows is a term whose true value is below float32's smallest too.
+    Across chunks the state is carried in float32. Returns (state after
+    min(n_state, L) tokens [H, K, V] float32, o [L, H, V] float32)."""
+    L, H, K = q.shape
+    V = v.shape[-1]
+    C, B = KDA_CHUNK, KDA_SUB
+    nb = C // B
+    pad = (-L) % C
+    keep = (jnp.arange(L) < n_state).astype(jnp.float32)
+    a, beta = a * keep[:, None, None], beta * keep[:, None]
+    if pad:
+        q, k, v, a, beta = (jnp.pad(t, ((0, pad),) + ((0, 0),) * (t.ndim - 1)) for t in (q, k, v, a, beta))
+    nc = (L + pad) // C
+    hi = jax.lax.Precision.HIGHEST
+    # [nc, H, C, .]: a chunk's tokens next to the feature axis
+    q, k, v, a = (jnp.swapaxes(t.reshape(nc, C, H, -1), 1, 2) for t in (q, k, v, a))
+    beta = jnp.swapaxes(beta.reshape(nc, C, H), 1, 2)
+    g = jnp.cumsum(a, axis=2)  # [nc, H, C, K], <= 0 and falling along C
+    sub = lambda t: t.reshape(nc, H, nb, B, K)  # noqa: E731  [nc, H, sub-block, token, K]
+    g_s, q_s, k_s = sub(g), sub(q), sub(k)
+    ref = g_s[:, :, :, :1, :]  # G at each sub-block's first token
+    own = jnp.exp(g_s - ref)  # a token against its sub-block's first: exponent <= 0
+    # the keys of EARLIER sub-blocks as sub-block i sees them, [nc, H, i, C, K]: zero from sub-block i on
+    earlier = (jnp.arange(C)[None, :] // B < jnp.arange(nb)[:, None])[None, None, :, :, None]
+    k_seen = k[:, :, None] * jnp.exp(jnp.where(earlier, ref - g[:, :, None], -jnp.inf))
+    a_mat = jnp.einsum("nhitk,nhisk->nhits", k_s * own, k_seen, precision=hi).reshape(nc, H, C, C)
+    b_mat = jnp.einsum("nhitk,nhisk->nhits", q_s * own, k_seen, precision=hi).reshape(nc, H, C, C)
+    # a sub-block against itself, one key offset j at a time: exp(G_t - G_j) for t >= j
+    t_at = jnp.arange(B)
+    a_own, b_own = [], []
+    for j in range(B):
+        e = jnp.exp(jnp.where((t_at >= j)[:, None], g_s - g_s[:, :, :, j : j + 1, :], -jnp.inf)) * k_s[:, :, :, j : j + 1, :]
+        a_own.append(jnp.sum(k_s * e, axis=-1))
+        b_own.append(jnp.sum(q_s * e, axis=-1))
+    diag = jnp.eye(nb, dtype=jnp.float32)[:, None, :, None]  # [i, t, i', s]: a sub-block's own keys
+
+    def placed(cols):  # [B (s)] of [nc, H, nb, B (t)] -> [nc, H, C, C], zero off the diagonal sub-blocks
+        m = jnp.stack(cols, axis=-1)  # [nc, H, nb, t, s]
+        return (m[:, :, :, :, None, :] * diag).reshape(nc, H, C, C)
+
+    a_mat, b_mat = a_mat + placed(a_own), b_mat + placed(b_own)
+    k_beta, v_beta = k * beta[..., None], v * beta[..., None]
+    t_inv = _unit_lower_inverse(a_mat * beta[..., None])
+    w0 = jnp.einsum("nhts,nhsv->nhtv", t_inv, v_beta, precision=hi)  # the writes, had the chunk begun at zero
+    k_cum = jnp.einsum("nhts,nhsk->nhtk", t_inv, k_beta * jnp.exp(g), precision=hi)
+    q_in = q * jnp.exp(g)  # reads of the carried state
+    k_out = k * jnp.exp(g[:, :, -1:, :] - g)  # writes as the chunk's end sees them
+    g_end = jnp.exp(g[:, :, -1, :])  # [nc, H, K]
+
+    def chunk(s, xs):  # s [H, K, V]
+        w0_c, k_cum_c, b_c, q_in_c, k_out_c, g_end_c = xs
+        w = w0_c - jnp.einsum("htk,hkv->htv", k_cum_c, s, precision=hi)
+        o = jnp.einsum("htk,hkv->htv", q_in_c, s, precision=hi) + jnp.einsum("hts,hsv->htv", b_c, w, precision=hi)
+        s = s * g_end_c[..., None] + jnp.einsum("htk,htv->hkv", k_out_c, w, precision=hi)
+        return s, o
+
+    s0 = jnp.zeros((H, K, V), jnp.float32) if s0 is None else s0.astype(jnp.float32)
+    s_fin, o = jax.lax.scan(chunk, s0, (w0, k_cum, b_mat, q_in, k_out, g_end))
+    o = jnp.swapaxes(o, 1, 2).reshape(L + pad, H, V)[:L]
+    return s_fin, o
+
+
+def kda_takes_launch(cfg: HybridConfig) -> bool:
+    """Whether a decode step's recurrence runs under ``ops/kda_state_update``
+    where the step runs its kernels, from the shapes alone: a head's state
+    [K, V] whole float32 tiles (128 x 128 as published)."""
+    return cfg.kda_v_dim % 128 == 0 and cfg.kda_k_dim % 8 == 0
+
+
+def kda_decode(cfg: HybridConfig, layer: dict, h, state: dict, j, active, live=None):
+    """Mixer for one token a slot. h [S, D]; ``state`` holds every ``kda``
+    layer's slot state, ``kda`` [n, S, H, K, V] and ``conv`` [n, S, (taps-1)
+    * kda_conv_dim] (the raw conv inputs [q | k | v] of the last taps-1
+    tokens, oldest first), of which this is layer ``j``. Returns (out [S,
+    D], the state with layer j advanced); rows that are not ``active`` keep
+    theirs.
+
+    ``live`` = ``paged_attention_q8.live_order(active)`` runs the recurrence
+    in the Pallas kernel (ops/kda_state_update.py), which reads and writes
+    the live slots' state only and in place; without it ``kda_decode_step``
+    passes over all slots under a mask (off a TPU, and the form the tests
+    hold the kernel to)."""
+    conv = jax.lax.dynamic_index_in_dim(state["conv"], j, 0, keepdims=False)
+    raw, z, a, beta = _kda_in(cfg, layer, h)
+    with jax.named_scope("kda_conv"):
+        acc, new_conv = _conv_window_step(conv, raw, _gdn_taps(layer), None, active)
+        q, k, v = _kda_heads(cfg, jax.nn.silu(acc))
+    with jax.named_scope("kda_state"):
+        if live is None:
+            old = jax.lax.dynamic_index_in_dim(state["kda"], j, 0, keepdims=False)
+            new, o = kda_decode_step(old, q, k, v, jnp.exp(a), beta, active)
+        else:
+            from areal_tpu.ops.kda_state_update import kda_state_update_stacked
+
+            kda_all, o = kda_state_update_stacked(state["kda"], j, q, k, v, jnp.exp(a), beta, *live)
+        y = _kda_out(cfg, layer, o, z, h.dtype)
+    with jax.named_scope("state_write"):
+        if live is None:
+            kda_all = jax.lax.dynamic_update_index_in_dim(state["kda"], new, j, 0)
+        state = {"kda": kda_all, "conv": jax.lax.dynamic_update_index_in_dim(state["conv"], new_conv, j, 0)}
+    with jax.named_scope("kda_proj"):
+        return _proj(cfg, layer, "o_proj", y), state
+
+
+def kda_prefill(cfg: HybridConfig, layer: dict, h, n_state, state_dtypes):
+    """Mixer over whole prompts. h [A, L, D], n_state [A]. Returns (out [A,
+    L, D], the state after n_state tokens [A, H, K, V], the conv window of
+    the last taps-1 of those tokens; positions before the prompt count as
+    zeros). The rows go one at a time, and a row in BLOCKS of up to
+    ``_KDA_BLOCK_TOKENS`` tokens with the float32 state carried between
+    them: a block's projections, convs (over the block and the taps-1 tokens
+    before it, whose projections are made again), gates, scan and output
+    gate are all its own, so that a 16k-token prompt holds the float32 q, k,
+    v, log decay and chunk matrices of 1,024 tokens (0.4 GB at 64 heads of
+    128) and never of the prompt (6 GB)."""
+    A, L, D = h.shape
+    taps = cfg.kda_d_conv
+    C = KDA_CHUNK
+    Lp = -(-L // C) * C
+    blk = C * _pow2_part(Lp // C, _KDA_BLOCK_TOKENS // C)
+    w = _gdn_taps(layer)
+    H, K, V = cfg.kda_n_heads, cfg.kda_k_dim, cfg.kda_v_dim
+
+    def row(args):
+        h_r, n_r = args  # [L, D], scalar
+        # position t at row t + taps - 1: zeros before the prompt (no bias anywhere: their projections are zeros too)
+        padded = jnp.pad(h_r, ((taps - 1, Lp - L), (0, 0)))
+
+        def block(s, i):
+            hb = jax.lax.dynamic_slice_in_dim(padded, i * blk, blk + taps - 1, axis=0)
+            raw, z, a, beta = _kda_in(cfg, layer, hb)
+            with jax.named_scope("kda_conv"):
+                raw = raw.astype(state_dtypes[1])  # both forms convolve the values a slot's window would hold
+                acc = 0.0
+                for t in range(taps):
+                    acc = acc + raw[t : t + blk].astype(jnp.float32) * w[t]
+                q, k, v = _kda_heads(cfg, jax.nn.silu(acc))
+            with jax.named_scope("kda_state"):
+                own = slice(taps - 1, None)  # the block's own tokens
+                s, o = kda_chunked_scan(q, k, v, a[own], beta[own], n_r - i * blk, s)
+                y = _kda_out(cfg, layer, o, z[own], h.dtype)
+            with jax.named_scope("kda_proj"):
+                return s, _proj(cfg, layer, "o_proj", y)
+
+        s, out = jax.lax.scan(block, jnp.zeros((H, K, V), jnp.float32), jnp.arange(Lp // blk, dtype=jnp.int32))
+        with jax.named_scope("kda_proj"):
+            # the window a slot keeps: the raw conv inputs of tokens n_r - taps + 1 .. n_r - 1 (padded rows n_r .. n_r +
+            # taps - 2), projected again: three rows
+            last = jax.lax.dynamic_slice_in_dim(padded, n_r, taps - 1, axis=0)
+            conv = jnp.concatenate([_proj(cfg, layer, n, last) for n in ("q_proj", "k_proj", "v_proj")], axis=-1)
+        return out.reshape(Lp, D)[:L], s.astype(state_dtypes[0]), conv.reshape(-1).astype(state_dtypes[1])
+
+    return jax.lax.map(row, (h, n_state))
 
 
 # ---------------------------------------------------------------------------
@@ -2466,7 +2926,7 @@ def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None):
 # the scope a mixer's norm counts under (its projections')
 _MIXER_SCOPE = {
     "mamba": "ssm_proj", "gdn": "gdn_proj", "conv": "conv_proj", "attention": "attn_proj", "mla": "mla_proj",
-    "s6": "ssm_proj", "swa": "attn_proj", "cross": "attn_proj", "gmu": "gmu",
+    "s6": "ssm_proj", "swa": "attn_proj", "cross": "attn_proj", "gmu": "gmu", "kda": "kda_proj",
 }
 
 
@@ -2596,6 +3056,75 @@ def _lane_pad(cfg: HybridConfig, t):
     return jnp.pad(t, ((0, 0),) * (t.ndim - 1) + ((0, pad),)) if pad else t
 
 
+# float32 [H, L, L] logits a row of the ``attention`` mixer's XLA prompt pass may hold: 64 heads pass it at 2,048
+# tokens (1 GB; 68 GB at 16k), 30 heads of a 1,024-token prompt (126 MB) do not
+_PREFILL_GQA_LOGIT_BYTES = 512 << 20
+
+
+def gqa_prefill_launch(cfg: HybridConfig, L: int) -> bool:
+    """Whether the ``attention`` mixer's prompt pass of bucket ``L`` attends
+    under ``ops/attention.py flash_fwd_pallas`` on a TPU, from the shapes
+    alone (``prefill_takes_launch``'s way): where a row's [H, L, L] float32
+    logits pass ``_PREFILL_GQA_LOGIT_BYTES`` and the kernel serves the shape
+    (heads of 128 lanes unpadded, a row of whole 256-token tiles). Below
+    that the XLA form stays: the CPU path, and the launch's oracle."""
+    return (
+        jax.default_backend() == "tpu"
+        and not cfg.diff_attn
+        and cfg.head_dim_ == cfg.kv_head_dim == 128
+        and L % 256 == 0
+        and 4 * cfg.num_heads * L * L > _PREFILL_GQA_LOGIT_BYTES
+    )
+
+
+def gqa_flash_attend(cfg: HybridConfig, q, k, v, seg, interpret: bool = False):
+    """Causal grouped-query attention over whole prompts under the flash
+    launch: q [A, L, H, hd], k and v [A, L, KH, hd], seg [A, L] (1 = a
+    prompt's token; a padding row reads zeros). One launch a row and KV
+    head, its group's queries against that head's keys and values handed
+    once a query head (the launch takes KV heads replicated: 8 x 4 MB at 16k
+    tokens a launch, never 64 heads of them). Nothing of [H, L, L] exists.
+    Returns [A, L, H * hd]."""
+    from areal_tpu.ops.attention import flash_fwd_pallas
+
+    A, L, H, hd = q.shape
+    KH = k.shape[2]
+    G = H // KH
+    qg = jnp.moveaxis(q.reshape(A, L, KH, G, hd), 2, 1).reshape(A * KH, L, G, hd)
+    kg, vg = (jnp.moveaxis(t, 2, 1).reshape(A * KH, L, 1, hd) for t in (k, v))
+    segs = jnp.repeat(seg.astype(jnp.int32), KH, axis=0)
+
+    def one(args):
+        q1, k1, v1, s1 = args
+        k1, v1 = (jnp.broadcast_to(t, (L, G, hd)) for t in (k1, v1))
+        return flash_fwd_pallas(q1[None], k1[None], v1[None], s1[None], interpret=interpret)[0]
+
+    out = jax.lax.map(one, (qg, kg, vg, segs)).reshape(A, KH, L, G, hd)
+    return jnp.moveaxis(out, 1, 2).reshape(A, L, H * hd)
+
+
+_ATTN_GATE_ROWS = 2048
+
+
+def _attn_gated(cfg: HybridConfig, layer: dict, attn, h):
+    """An ``attention`` layer's output before ``W_o``: ``attn * sigmoid(W_g
+    h)``, one gate a head and channel, where the configuration has the gate."""
+    if not cfg.attn_gate:
+        return attn
+
+    def gated(a):
+        gate = jax.nn.sigmoid(_proj(cfg, layer, "wg", a[1]).astype(jnp.float32))
+        return (a[0].astype(jnp.float32) * gate).astype(attn.dtype)
+
+    with jax.named_scope(ATTN_GATE_SCOPE):
+        rows = attn.size // attn.shape[-1]
+        if rows <= _ATTN_GATE_ROWS or rows % _ATTN_GATE_ROWS:
+            return gated((attn, h))
+        # a long prompt's rows in blocks: the float32 gate of 16k rows x 8,192 channels is 0.5 GB, and its product as much again
+        blocks = (attn.reshape(-1, _ATTN_GATE_ROWS, attn.shape[-1]), h.reshape(-1, _ATTN_GATE_ROWS, h.shape[-1]))
+        return jax.lax.map(gated, blocks).reshape(attn.shape)
+
+
 def compute_logits(params: dict, cfg: HybridConfig, hidden: jax.Array) -> jax.Array:
     return qwen.compute_logits(params, cfg, hidden) / cfg.logits_scaling
 
@@ -2658,7 +3187,9 @@ def forward_prefill(
     k_shape, v_shape = ((cfg.num_kv_layers, A, L, *pool) if pool else () for pool in (cfg.kv_pools["k"], second))
     rows_to_sink = latent and "k" in arrays  # the sink takes the latent rows too
     # a latent layer makes its own masks a block at a time: no [A, 1, L, L] for a 16k prompt
-    mask = None if latent or cfg.diff_attn else qwen._attention_mask(seg)  # (differential attention masks by position, a row at a time)
+    # the plain ``attention`` mixer under the flash launch where [H, L, L] would not fit: the launch masks by ``seg``
+    gqa_launch = bool(cfg.count("attention")) and gqa_prefill_launch(cfg, L)
+    mask = None if latent or cfg.diff_attn or gqa_launch else qwen._attention_mask(seg)  # (differential attention masks by position, a row at a time)
     positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (A, L))
     live = seg.astype(bool)
     rm = cfg.residual_multiplier
@@ -2745,6 +3276,12 @@ def forward_prefill(
             out, gdn, conv = gdn_prefill(cfg, layer, h, n_state, (dtypes["gdn"], dtypes["conv"]))
             with jax.named_scope("state_write"):
                 arr = write(arr, j, {"gdn": gdn, "conv": conv})
+        elif kind == "kda":
+            with jax.named_scope("kda_proj"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+            out, kda, conv = kda_prefill(cfg, layer, h, n_state, (dtypes["kda"], dtypes["conv"]))
+            with jax.named_scope("state_write"):
+                arr = write(arr, j, {"kda": kda, "conv": conv})
         elif kind == "conv":
             with jax.named_scope("conv_proj"):
                 h = _norm_in(cfg, layer, "input_norm", x)
@@ -2776,7 +3313,8 @@ def forward_prefill(
                 ks = ks.at[j].set(_lane_pad(cfg, k))
                 vs = vs.at[j].set(_lane_pad(cfg, v))
             with jax.named_scope("attn"):
-                attn = jax.lax.map(attend, (q, k, v, mask))
+                attn = gqa_flash_attend(cfg, q, k, v, seg) if gqa_launch else jax.lax.map(attend, (q, k, v, mask))
+            attn = _attn_gated(cfg, layer, attn, h)
             with jax.named_scope("attn_proj"):
                 out = _proj(cfg, layer, "wo", attn)
         with jax.named_scope(_MIXER_SCOPE[kind]):
@@ -2892,8 +3430,9 @@ def prefill_into_cache(
 
 def slot_state_view(cfg: HybridConfig, leaf: str, rows: jax.Array) -> jax.Array:
     """Rows [..., slot state] of the cache's state leaf ``leaf`` in the
-    mixer's own order of axes: the delta-rule state unpacked to [..., H, K,
-    V]; the other leaves lie as their mixer reads them."""
+    mixer's own order of axes: the delta-rule state ``gdn`` unpacked to
+    [..., H, K, V]; the other leaves (``kda`` [..., H, K, V] among them) lie
+    as their mixer reads them."""
     if leaf == "gdn":
         from areal_tpu.ops.gdn_state_update import unpack_state
 
@@ -3051,7 +3590,8 @@ def forward_decode_paged(
                 schedule, fetch = shared_decode_schedule(attn_lengths, page_table, page_size, ppcb)
                 cache = fetch.counted(cache)
         # the state kernel's work list, made once a step
-        live = live_order(active) if cfg.count("mamba") + cfg.count("gdn") else None
+        state_launch = cfg.count("mamba") or cfg.count("gdn") or (cfg.count("kda") and kda_takes_launch(cfg))
+        live = live_order(active) if state_launch else None
         kernel = dict(pages_per_compute_block=ppcb, schedule=schedule)
         with jax.named_scope("kv_write"):
             kv_live = live_order(page_table[:, 0] != 0)  # the KV writer's: qwen.forward_decode_paged
@@ -3156,6 +3696,14 @@ def forward_decode_paged(
             if "gdn_updates" in c:
                 with jax.named_scope("gdn_state"):
                     c["gdn_updates"] = c["gdn_updates"].at[j].add(jnp.sum(active, dtype=jnp.int32))
+        elif kind == "kda":
+            with jax.named_scope("kda_proj"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+            out, state = kda_decode(cfg, layer, h, {k: c[k] for k in ("kda", "conv")}, j, active, live)
+            c.update(state)
+            if "kda_updates" in c:
+                with jax.named_scope("kda_state"):
+                    c["kda_updates"] = c["kda_updates"].at[j].add(jnp.sum(active, dtype=jnp.int32))
         elif kind == "conv":
             with jax.named_scope("conv_proj"):
                 h = _norm_in(cfg, layer, "input_norm", x)
@@ -3226,6 +3774,7 @@ def forward_decode_paged(
                         q, sl["k"], sl["v"], lengths, page_table, sm_scale=cfg.sm_scale, **scales
                     )
                 attn = attn[..., : cfg.head_dim_].reshape(S, H * cfg.head_dim_).astype(x.dtype)
+            attn = _attn_gated(cfg, layer, attn, h)
             with jax.named_scope("attn_proj"):
                 out = _proj(cfg, layer, "wo", attn)
         with jax.named_scope(_MIXER_SCOPE[kind]):

@@ -177,6 +177,13 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "live slots x delta-rule layers (each reads and writes the "
             "slot's state of that layer once).",
         ),
+        # a model with ``kda`` layers (a delta rule with a decay a key channel)
+        kda_state_updates=r.counter(
+            "areal_decode_kda_state_updates_total",
+            "(slot, layer) updates of a kda (per-channel-decay delta-rule) "
+            "state by decode steps: live slots x kda layers (each reads and "
+            "writes the slot's state of that layer once).",
+        ),
         # a latent-attention model (models/hybrid.py ``mla``); counted on the
         # device inside the decode chunk as the counts around it are
         latent_tokens_read=r.counter(

@@ -320,3 +320,20 @@ def test_greedy_twins_with_and_without_the_hold():
         b = without[rid]
         assert a.output_tokens == b.output_tokens, rid
         assert a.output_logprobs == b.output_logprobs, rid
+
+
+def test_an_engine_with_a_prefix_cache_never_waits_for_siblings():
+    """``_await_siblings`` is for models whose late sibling can alias nothing
+    (no prefix cache); here a late sibling hits the radix tree, so a start
+    from idle admits at once."""
+    eng = _engine()
+    assert eng.slots.radix is not None
+    eng._await_siblings = lambda: pytest.fail("an engine with a prefix cache waited for siblings")
+    eng.start()
+    try:
+        got, done = {}, _done(2)
+        _submit_all(eng, [_req("a", 5, 1), _req("b", 5, 2)], got, done)
+        assert done.wait(120)
+        assert all(len(got[k].output_tokens) == 5 for k in "ab")
+    finally:
+        eng.stop()

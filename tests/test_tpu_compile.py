@@ -148,6 +148,27 @@ def _gdn_state(dtype):
     return fn, args
 
 
+def _kda_state(dtype):
+    """The state update of one ``kda`` layer at the published sizes of
+    ``rollout-solar-open2-ep16-d8-longctx-grpo``: 6 layers x 64 slots of 64
+    heads x 128 x 128, a decay a key channel, updated in place for the live
+    slots."""
+    from areal_tpu.ops.kda_state_update import kda_state_update_stacked
+    from areal_tpu.ops.paged_attention_q8 import live_order
+
+    def fn(state, li, q, k, v, decay, beta, active):
+        return kda_state_update_stacked(state, li, q, k, v, decay, beta, *live_order(active))
+
+    def args(S):
+        f32 = jnp.float32
+        return [
+            S((6, 64, 64, 128, 128), dtype), S((), jnp.int32), S((64, 64, 128), f32), S((64, 64, 128), f32),
+            S((64, 64, 128), f32), S((64, 64, 128), f32), S((64, 64), f32), S((64,), jnp.bool_),
+        ]
+
+    return fn, args
+
+
 def _kv_write(page_dtype, slots=SLOTS, kh=KH, layers=L, pages=2340):
     """A decode step's KV rows of one layer written by the one launch, at a
     benchmark cell's pool and slots (default: ``rollout-1.5b-grpo``'s
@@ -307,6 +328,8 @@ CASES = {
     "ssm_state_update_bf16": lambda: _ssm_state(jnp.bfloat16),
     "gdn_state_update_f32": lambda: _gdn_state(jnp.float32),
     "gdn_state_update_bf16": lambda: _gdn_state(jnp.bfloat16),
+    "kda_state_update_f32": lambda: _kda_state(jnp.float32),
+    "kda_state_update_bf16": lambda: _kda_state(jnp.bfloat16),
     # Olmo-Hybrid-7B's attention layers: 30 KV heads, a query group of 1, 4 layers, 64 slots x 4096-token windows
     "paged_decode_mha30_bf16": lambda: _decode(jnp.bfloat16, 64, 30, 1, 4, n_pages=490),
     "paged_decode_mha30_int8": lambda: _decode(jnp.int8, 64, 30, 1, 4, n_pages=490),
@@ -368,6 +391,7 @@ KERNEL_NAMES = {
     ),
     "ssm_state_update_f32": ("ssm_state_update",),
     "gdn_state_update_f32": ("gdn_state_update",),
+    "kda_state_update_f32": ("kda_state_update",),
     "paged_kv_write_int8": ("paged_kv_write",),
     "moe_touched_experts_kanana2": ("moe_touched_experts",),
     "mla_prefill_flash_glm5_16k": ("mla_prefill_flash",),
@@ -1011,3 +1035,98 @@ def test_phi4flash_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypatc
         assert not [ln for ln in text.splitlines() if " copy(" in ln and pool in ln], pool
     assert "f32[16384,16,5120]" not in text and "f32[16384,5120,16]" not in text and "16384,16384" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2.1e9
+
+
+def _solar_open2(chip, monkeypatch, kv_gb: float = 2.75):
+    """The ``solar_open2`` family at every published width, ONE whole period
+    G K K K of the cell's two (the layers are scanned, a run a body: a
+    program's temporaries are one layer's), 20 of 320 experts, 64 slots and
+    the cell's pool of 2,816 pages (sized for its two attention layers)."""
+    import json
+
+    from areal_tpu import models
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "chip", "configs", "solar-open2-250b-ep16-d8.json")) as f:
+        cfg = json.load(f)
+    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "reduced_from", "assumed", "assumed_notes", "stands_for")}
+    keep = ("router_experts", "expert_first", "kda_state_dtype", "conv_state_dtype")
+    hf.update({k: cfg["assumed"][k] for k in keep}, num_hidden_layers=4, gqa_layers=[0], dtype="bfloat16")
+    mcfg = models.config_from_hf_dict(hf)
+    n_pages = paged_kv.n_pages_for_budget(int(kv_gb * 2**30), 2, 8, PSZ, 128, 2, pools=mcfg.kv_pools)
+    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
+    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, n_pages, PSZ, slots=64))
+    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return mcfg, place(params), place(cache), n_pages
+
+
+def test_solar_open2_decode_steps_compile_for_v5e_at_the_whole_window(chip, monkeypatch):
+    """Two decode steps as the engine's chunk runs them at the cell's ONE
+    window (160 pages, 64 slots): the kda state advanced by the
+    ``kda_state_update`` launch in place (no copy of the 0.8 GB leaf, nor of
+    a layer of it), the attention layer on ``paged_decode_attn`` /
+    ``paged_kv_write``, the expert matmuls as the touched-expert launch on the
+    stacks (64 rows x top-8 over 320: 1.6 assignments an expert; an expert of
+    [4096, 1280] through the ring in 2 parts), 0.3 GB of temporaries."""
+    from areal_tpu.models import hybrid, moe
+    from areal_tpu.ops.moe_touched_experts import width_parts
+
+    mcfg, params, cache, n_pages = _solar_open2(chip, monkeypatch)
+    assert n_pages == 2816 and {k: v.shape for k, v in cache.items()} == {
+        "k": (1, 8, 2816, PSZ, 128), "v": (1, 8, 2816, PSZ, 128), "kda": (3, 64, 64, 128, 128), "conv": (3, 64, 3 * 24576),
+    }
+    assert moe.takes_touched_form(64, 8, 320, 20) and width_parts(4096, 1280, 2) == 2 and hybrid.kda_takes_launch(mcfg)
+
+    def two_steps(params, cache, pt, ids, pos, active):
+        cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
+
+        def step(c, _):
+            ids, pos, cache = c
+            h, cache = hybrid.forward_decode_paged(params, mcfg, ids, pos, cache, pt, page_size=PSZ, active=active, use_kernel=True)
+            return (jnp.argmax(hybrid.compute_logits(params, mcfg, h), -1).astype(jnp.int32), pos + 1, cache), None
+
+        (ids, _, cache), _ = jax.lax.scan(step, (ids, pos, cache), None, length=2)
+        return ids, cache
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(64, 160), i32(64), i32(64), chip((64,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    for name in ("kda_state_update", "paged_decode_attn", "paged_kv_write", "moe_touched_experts"):
+        assert name in text, name
+    for leaf in ("f32[3,64,64,128,128]", "f32[64,64,128,128]", "bf16[1,8,2816,128,128]", "bf16[3,64,73728]"):
+        assert not [ln for ln in text.splitlines() if " copy(" in ln and leaf in ln], leaf
+    made = re.compile(r"= bf16\[(1,)?20,(4096,1280|1280,4096)\]\S* (?!parameter|get-tuple-element)")
+    assert not [ln for ln in text.splitlines() if made.search(ln)]  # no layer of an expert stack sliced out
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+def test_solar_open2_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypatch):
+    """ONE prompt of 16,384 tokens, the cell's longest bucket: the attention
+    layer under ``flash_fwd`` a KV head at a time (no [64, 16384, 16384]: 68
+    GB; no K or V of 64 replicated heads either), its gate a block of 2,048
+    rows at a time, the kda layers a block of 1,024 tokens at a time with the
+    float32 state carried (nothing of [16384, 64, 128] float32), the expert
+    rows through the grouped matmuls 4,096 at a time on the stacks. Under 2.5
+    GB of temporaries (2.36 by this count at the cell's two periods): with
+    7.80 GB of weights and 4.62 GB of cache, 14.8 of 15.75 GB."""
+    from areal_tpu.inference.decode_programs import _PREFILL_STREAM_BYTES
+    from areal_tpu.models import hybrid
+
+    mcfg, params, cache, _ = _solar_open2(chip, monkeypatch)
+    assert hybrid.gqa_prefill_launch(mcfg, 16384) and hybrid.gqa_prefill_launch(mcfg, 4096) and not hybrid.gqa_prefill_launch(mcfg, 1024)
+    assert hybrid.prefill_row_bytes(mcfg, 256) > _PREFILL_STREAM_BYTES and hybrid.ffn_block_rows(mcfg, "moe", 16384) == 4096
+
+    def prefill(params, cache, ids, plens, flat_pages, slots):
+        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(1, 16384), i32(1), i32(16384 // PSZ), i32(1)).compile()
+    text = compiled.as_text()
+    assert "flash_fwd" in text and "f32[64,16384,16384]" not in text
+    assert "f32[16384,64,128]" not in text and "f32[16384,8192]" not in text  # neither the scan's inputs nor the gate over the whole prompt
+    for leaf in ("f32[3,64,64,128,128]", "bf16[1,8,2816,128,128]"):
+        assert not [ln for ln in text.splitlines() if " copy(" in ln and leaf in ln], leaf
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
