@@ -2116,6 +2116,8 @@ class DecodeEngine:
         self._obs.prefill_tokens.inc(prompt_tokens)
         if self.model.prefill_attn_launch(self.model_cfg, bucket):
             self._obs.prefill_attn_launch_tokens.inc(prompt_tokens)
+        if self.model.kda_prefill_launch(self.model_cfg, bucket):
+            self._obs.prefill_kda_launch_tokens.inc(prompt_tokens)
         if "shared_kv_tokens_read" in self.model_cfg.count_shapes:
             # the program's rows ended at the shared layer's K and V: a prompt's one row of the layers past it is the
             # decode step's that feeds its last token again

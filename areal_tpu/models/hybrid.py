@@ -99,8 +99,11 @@ has: ``kda_decode_step`` is the recurrence itself (on a TPU the Pallas kernel
 operand a head); ``kda_chunked_scan`` the WY algorithm over a prompt, every
 exponent a difference in the direction of time (its docstring says how), the
 state carried across chunks, and across the BLOCKS a long prompt goes through
-(``kda_prefill``), in float32. tests/test_solar_open2_model.py holds them to
-each other and to the token-by-token reference. The gated ``attention``
+(``kda_prefill``), in float32; on a TPU with heads of whole lane tiles a
+block's scan is ONE launch (``ops/kda_prompt_scan.py``, chosen from the
+shapes: ``kda_prefill_launch``) and the XLA form its oracle.
+tests/test_solar_open2_model.py holds them to each other and to the
+token-by-token reference. The gated ``attention``
 mixer's prompt pass builds [H, L, L] float32 logits a row where they fit and
 attends under ``ops/attention.py flash_fwd_pallas`` where they do not (64
 heads past 1,024 tokens), chosen from the shapes (``gqa_prefill_launch``);
@@ -2199,6 +2202,18 @@ def kda_decode(cfg: HybridConfig, layer: dict, h, state: dict, j, active, live=N
         return _proj(cfg, layer, "o_proj", y), state
 
 
+def kda_prefill_launch(cfg: HybridConfig, L: int) -> bool:
+    """Whether the prompt pass of bucket ``L`` scans its ``kda`` layers'
+    recurrence under ``ops/kda_prompt_scan`` on a TPU, from the shapes alone
+    (``prefill_takes_launch``'s way): a head's keys and values whole lane
+    tiles (128 x 128 as published), so that a ``(chunk, head)`` block of the
+    projections' own [L, H * K] layout is a head's chunk; ``kda_prefill``'s
+    blocks are whole chunks whatever ``L`` is. Elsewhere ``kda_chunked_scan``
+    stays: the CPU path, and the launch's oracle. What the engine counts
+    ``areal_decode_prefill_kda_launch_tokens_total`` by."""
+    return bool(cfg.count("kda")) and jax.default_backend() == "tpu" and cfg.kda_k_dim % 128 == 0 and cfg.kda_v_dim % 128 == 0
+
+
 def kda_prefill(cfg: HybridConfig, layer: dict, h, n_state, state_dtypes):
     """Mixer over whole prompts. h [A, L, D], n_state [A]. Returns (out [A,
     L, D], the state after n_state tokens [A, H, K, V], the conv window of
@@ -2209,7 +2224,9 @@ def kda_prefill(cfg: HybridConfig, layer: dict, h, n_state, state_dtypes):
     before it, whose projections are made again), gates, scan and output
     gate are all its own, so that a 16k-token prompt holds the float32 q, k,
     v, log decay and chunk matrices of 1,024 tokens (0.4 GB at 64 heads of
-    128) and never of the prompt (6 GB)."""
+    128) and never of the prompt (6 GB). Where ``kda_prefill_launch`` says so
+    a block's scan is ONE launch of ``kda_prompt_scan``, which keeps the
+    chunk matrices and the state in VMEM."""
     A, L, D = h.shape
     taps = cfg.kda_d_conv
     C = KDA_CHUNK
@@ -2217,6 +2234,9 @@ def kda_prefill(cfg: HybridConfig, layer: dict, h, n_state, state_dtypes):
     blk = C * _pow2_part(Lp // C, _KDA_BLOCK_TOKENS // C)
     w = _gdn_taps(layer)
     H, K, V = cfg.kda_n_heads, cfg.kda_k_dim, cfg.kda_v_dim
+    scan = kda_chunked_scan
+    if kda_prefill_launch(cfg, L):
+        from areal_tpu.ops.kda_prompt_scan import kda_prompt_scan as scan
 
     def row(args):
         h_r, n_r = args  # [L, D], scalar
@@ -2234,7 +2254,7 @@ def kda_prefill(cfg: HybridConfig, layer: dict, h, n_state, state_dtypes):
                 q, k, v = _kda_heads(cfg, jax.nn.silu(acc))
             with jax.named_scope("kda_state"):
                 own = slice(taps - 1, None)  # the block's own tokens
-                s, o = kda_chunked_scan(q, k, v, a[own], beta[own], n_r - i * blk, s)
+                s, o = scan(q, k, v, a[own], beta[own], n_r - i * blk, s)
                 y = _kda_out(cfg, layer, o, z[own], h.dtype)
             with jax.named_scope("kda_proj"):
                 return s, _proj(cfg, layer, "o_proj", y)

@@ -374,6 +374,13 @@ def prefill_attn_launch(cfg, L: int) -> bool:
     return False
 
 
+def kda_prefill_launch(cfg, L: int) -> bool:
+    """Whether the prefill program of bucket ``L`` scans ``kda`` layers under
+    the prompt-scan launch (``models/hybrid.py``): this family has none."""
+    del cfg, L
+    return False
+
+
 # int8 weight-only serving quantization. The reference reaches serving
 # quantization through SGLang/vLLM deployment options; the TPU-native engine
 # provides it as a first-class transform. Dense projection weights only —

@@ -146,6 +146,14 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "dispatches the program: over areal_decode_prefill_tokens_total, "
             "the share of prompt tokens the launch served.",
         ),
+        prefill_kda_launch_tokens=r.counter(
+            "areal_decode_prefill_kda_launch_tokens_total",
+            "Prompt tokens prefilled by a program whose kda layers scan "
+            "their recurrence under the kda_prompt_scan launch (ops/"
+            "kda_prompt_scan.py), counted where the engine dispatches the "
+            "program: over areal_decode_prefill_tokens_total, the share of "
+            "prompt tokens the launch served.",
+        ),
         chunks=r.counter(
             "areal_decode_chunks_total", "Jitted decode chunks executed."
         ),

@@ -85,3 +85,40 @@ def test_the_launch_lowers_for_a_tpu_from_the_cpu():
     fn, args = launch(SHAPES["olmo-hybrid-7b"][0])
     text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text and "paged_decode_attn" in text
+
+
+# ``kda_prompt_scan`` (PR 48) at the published sizes, a block of 1,024 tokens of 64 heads of 128 x 128: the XLA form
+# it stands in for is 577 equations a launch site (``hybrid.kda_chunked_scan``); the launch's walk over the 16 key
+# offsets is ONE traced body (written out 16 times, with 4 heads a grid step, it was 4,239)
+KDA_LAUNCH_CEILING = 1200
+
+
+def kda_launch():
+    from areal_tpu.ops.kda_prompt_scan import kda_prompt_scan
+
+    sds, f32 = jax.ShapeDtypeStruct, jnp.float32
+    return kda_prompt_scan, [sds((1024, 64, 128), f32)] * 4 + [sds((1024, 64), f32), sds((), jnp.int32), sds((64, 128, 128), f32)]
+
+
+def test_the_kda_prompt_scan_holds_its_equation_budget():
+    from areal_tpu.models import hybrid
+    from areal_tpu.ops.kda_prompt_scan import HEADS_PER_STEP
+
+    fn, args = kda_launch()
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    n = count(jaxpr)
+    assert n <= KDA_LAUNCH_CEILING, f"{n} equations a launch site: the launch has grown past what PR 48 pinned (1,132)"
+    assert n <= 2.5 * count(jax.make_jaxpr(hybrid.kda_chunked_scan)(*args).jaxpr)
+    assert count(jaxpr, "pallas_call") == 1
+    # a head's chunk: three products for the earlier sub-blocks, two a doubling of the inverse, the writes' two, the
+    # stacked read of the state, ``B w`` and the state's update
+    assert count(jaxpr, "dot_general") == 12 * HEADS_PER_STEP
+    kernel = next(e for e in jaxpr.eqns[0].params["jaxpr"].eqns if e.primitive.name == "pallas_call").params["jaxpr"]
+    assert count(kernel, "jit") + count(kernel, "pjit") == 0  # ``jax.lax`` primitives only in the body
+    assert count(kernel, "scan") == 1 and count(kernel, "exp") < 20 * HEADS_PER_STEP  # the 16 offsets are one traced body
+
+
+def test_the_kda_prompt_scan_lowers_for_a_tpu_from_the_cpu():
+    fn, args = kda_launch()
+    text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text and "kda_prompt_scan" in text

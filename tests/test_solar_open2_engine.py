@@ -262,6 +262,65 @@ def test_lowered_programs_hold_the_familys_scopes(served):
         assert not (set(hybrid.SCOPES[:3]) | set(hybrid.GDN_SCOPES[:3]) | {"mlp"}) & have
 
 
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_the_prefill_programs_scan_follows_kda_prefill_launch(served, monkeypatch, backend):
+    """``hybrid.kda_prefill_launch`` from the backend and the head's widths
+    alone; a prefill program's blocks scan under ``ops/kda_prompt_scan`` where
+    it says so and under ``kda_chunked_scan`` elsewhere (this backend, and
+    heads that are no whole lane tiles), and the engine counts
+    ``areal_decode_prefill_kda_launch_tokens_total`` by it where it
+    dispatches the program."""
+    import json
+
+    from chipbench_util import CHIP
+
+    from areal_tpu.models import hybrid, qwen
+    from areal_tpu.observability import catalog
+    from areal_tpu.ops import kda_prompt_scan as kps
+
+    eng, cfg = served
+    with open(os.path.join(CHIP, "configs", su.CONFIG + ".json")) as f:
+        full = su.model_config(json.load(f), dtype="bfloat16")
+    assert catalog.engine_metrics().prefill_kda_launch_tokens.name == "areal_decode_prefill_kda_launch_tokens_total"
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    tiny = eng.model_cfg
+    assert (tiny.kda_k_dim, tiny.kda_v_dim, full.kda_k_dim, full.kda_v_dim) == (16, 16, 128, 128)
+    assert hybrid.kda_prefill_launch(full, 16384) == hybrid.kda_prefill_launch(full, 256) == (backend == "tpu")
+    assert not hybrid.kda_prefill_launch(tiny, 256)  # heads of 16 x 16 keep the XLA form on any backend
+    assert not qwen.kda_prefill_launch(None, 256)
+    # the choice inside the program: the launch's entry is reached exactly where the predicate says
+    calls = []
+    monkeypatch.setattr(kps, "kda_prompt_scan", lambda *a, **kw: calls.append(a[0].shape) or hybrid.kda_chunked_scan(*a, **kw))
+    says = []
+    real = hybrid.kda_prefill_launch
+    monkeypatch.setattr(hybrid, "kda_prefill_launch", lambda c, L: says.append(L) or real(c, L) or backend == "tpu")
+    psz = eng.config.page_size
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    bucket = 128 if backend == "cpu" else 384  # a bucket no other test has traced
+    _held(eng)
+    try:
+        with jax.set_mesh(eng.mesh):
+            jax.make_jaxpr(
+                lambda *x: hybrid.prefill_into_cache(eng.params, tiny, *x, page_size=psz)
+            )(eng.cache, i32(1, bucket), i32(1), i32(bucket // psz), i32(1))
+    finally:
+        eng.continue_generation()
+    assert says and set(says) == {bucket}
+    # one launch site a run of kda layers, a block of whole chunks (384 tokens go in blocks of 128)
+    assert calls == ([(128, 8, 16)] * 2 if backend == "tpu" else [])
+    monkeypatch.undo()
+    # the counter: nothing on this backend; every prompt token of a program whose module says it scans under the launch
+    launched, prefilled = eng._obs.prefill_kda_launch_tokens.get(), eng._obs.prefill_tokens.get()
+    rng = np.random.default_rng(48)
+    _gen(eng, rng.integers(0, cfg["vocab_size"], 21).tolist(), 2)
+    assert eng._obs.prefill_tokens.get() == prefilled + 21 and eng._obs.prefill_kda_launch_tokens.get() == launched
+    if backend == "tpu":
+        buckets = []
+        monkeypatch.setattr(eng.model, "kda_prefill_launch", lambda mcfg, b: buckets.append(b) or mcfg is eng.model_cfg)
+        _gen(eng, rng.integers(0, cfg["vocab_size"], 23).tolist(), 2)
+        assert eng._obs.prefill_kda_launch_tokens.get() == launched + 23 and len(buckets) == 1
+
+
 def test_refused_configurations():
     mcfg = su.model_config(su.tiny_model())
     for kw, msg in (

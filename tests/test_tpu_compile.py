@@ -169,6 +169,19 @@ def _kda_state(dtype):
     return fn, args
 
 
+def _kda_prompt_scan():
+    """A block of 1,024 tokens of one ``kda`` layer's prompt pass at the
+    published sizes (64 heads of 128 x 128, 16 chunks, the state carried in):
+    ONE launch, the chunk matrices and the state in VMEM."""
+    from areal_tpu.ops.kda_prompt_scan import kda_prompt_scan
+
+    def args(S):
+        f32 = jnp.float32
+        return [S((1024, 64, 128), f32)] * 4 + [S((1024, 64), f32), S((), jnp.int32), S((64, 128, 128), f32)]
+
+    return kda_prompt_scan, args
+
+
 def _kv_write(page_dtype, slots=SLOTS, kh=KH, layers=L, pages=2340):
     """A decode step's KV rows of one layer written by the one launch, at a
     benchmark cell's pool and slots (default: ``rollout-1.5b-grpo``'s
@@ -330,6 +343,7 @@ CASES = {
     "gdn_state_update_bf16": lambda: _gdn_state(jnp.bfloat16),
     "kda_state_update_f32": lambda: _kda_state(jnp.float32),
     "kda_state_update_bf16": lambda: _kda_state(jnp.bfloat16),
+    "kda_prompt_scan_1k": _kda_prompt_scan,
     # Olmo-Hybrid-7B's attention layers: 30 KV heads, a query group of 1, 4 layers, 64 slots x 4096-token windows
     "paged_decode_mha30_bf16": lambda: _decode(jnp.bfloat16, 64, 30, 1, 4, n_pages=490),
     "paged_decode_mha30_int8": lambda: _decode(jnp.int8, 64, 30, 1, 4, n_pages=490),
@@ -392,6 +406,7 @@ KERNEL_NAMES = {
     "ssm_state_update_f32": ("ssm_state_update",),
     "gdn_state_update_f32": ("gdn_state_update",),
     "kda_state_update_f32": ("kda_state_update",),
+    "kda_prompt_scan_1k": ("kda_prompt_scan",),
     "paged_kv_write_int8": ("paged_kv_write",),
     "moe_touched_experts_kanana2": ("moe_touched_experts",),
     "mla_prefill_flash_glm5_16k": ("mla_prefill_flash",),
@@ -1126,6 +1141,9 @@ def test_solar_open2_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypa
     compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(1, 16384), i32(1), i32(16384 // PSZ), i32(1)).compile()
     text = compiled.as_text()
     assert "flash_fwd" in text and "f32[64,16384,16384]" not in text
+    # a block's scan is the launch: the XLA form's keys as each sub-block sees them and its chunk matrices are gone
+    assert hybrid.kda_prefill_launch(mcfg, 16384) and "kda_prompt_scan" in text
+    assert "f32[16,64,4,64,128]" not in text and "f32[16,64,64,64]" not in text
     assert "f32[16384,64,128]" not in text and "f32[16384,8192]" not in text  # neither the scan's inputs nor the gate over the whole prompt
     for leaf in ("f32[3,64,64,128,128]", "bf16[1,8,2816,128,128]"):
         assert not [ln for ln in text.splitlines() if " copy(" in ln and leaf in ln], leaf
