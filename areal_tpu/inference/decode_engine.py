@@ -99,6 +99,12 @@ SIBLING_GAP_S_PER_TOKEN = 5e-6
 SIBLING_GAP_S = 0.05
 SIBLING_WAIT_S = 0.5
 
+# the row ledger of a drained chunk (DecodeEngine._drain): the steps it ran,
+# the rows it stepped for, and the row-steps that were spent on a request
+# already ended or dropped with a request gone by the drain; the live ones are
+# the tokens credited, so tokens + spent + dropped == rows x steps
+LEDGER_KEYS = ("steps", "rows", "spent", "dropped")
+
 
 @dataclass
 class _Task:
@@ -244,6 +250,10 @@ class DecodeEngine:
         self._pace_clock: Callable[[], float] = time.monotonic
         self._pace_wait: Callable[[float], bool] = self._wakeup.wait
         self._pull_s = 0.0  # seconds of the current pass inside blocking pulls
+        # the row ledger (_drain): what the rows the chunks stepped for were
+        # doing, summed since the engine was built (/statusz ``row_steps``; a
+        # pass's span carries what the pass added)
+        self._row_steps = dict.fromkeys(LEDGER_KEYS, 0)
         self._pass_start = 0.0  # time.monotonic() at the top of the pass
         # one WARNING line for a productive pass over 3 x the median of the
         # last 64, with what the span record holds of it
@@ -1521,6 +1531,22 @@ class DecodeEngine:
             return None
         return {"blocks_listed": listed, "blocks_fetched": fetched, "fetched_share": round(fetched / listed, 4)}
 
+    def row_steps_status(self) -> dict:
+        """/statusz ``row_steps``: what the decode steps' rows were doing
+        since the engine was built, summed at every drain: ``steps`` the
+        chunk programs ran (x ``slots`` = every row-step paid for), ``live``
+        row-steps that emitted a credited token, ``spent`` ones run for a
+        request that had ended and ``dropped`` ones whose token went with a
+        request gone by the drain; the rest were empty rows."""
+        led = self._row_steps
+        return {
+            "slots": self.config.max_batch_size,
+            "steps": led["steps"],
+            "live": self.stats["generated_tokens"],
+            "spent": led["spent"],
+            "dropped": led["dropped"],
+        }
+
     def sparse_attention_status(self) -> dict | None:
         """/statusz ``sparse_attention``: what a learned index selects for a
         decode step's queries (``index_topk`` cached tokens a slot and
@@ -2459,6 +2485,7 @@ class DecodeEngine:
             "version": self._version,
             "was_active": active.copy(),
             "tasks": list(self._slot_task),
+            "spec": True,  # one verify forward, not n_steps steps: the row ledger counts it apart
         }
         # acceptance accounting BEFORE _drain (it mutates slot ownership)
         emit_count = packed_np[2 * B]
@@ -2585,6 +2612,23 @@ class DecodeEngine:
                     self._finish(task, reason)
             self.stats["chunks"] += 1
             self._obs.chunks.inc()
+            # the chunk's row ledger, from what this drain holds anyway: of the
+            # rows x steps the program stepped for, ``credited`` were live,
+            # ``spent`` ran under the device's mask for a request that had
+            # ended (earlier in this chunk, or in the one before: the
+            # dispatch's mask is a chunk stale), ``dropped`` emitted a token
+            # for a request gone by now (preempted, turned over). A
+            # speculative round is one step whose rows emit several tokens
+            emitted = int(emit_count[was_active].sum())
+            rows = int(was_active.sum())
+            steps, spent = (1, 0) if pending.get("spec") else (n_steps, rows * n_steps - emitted)
+            led = self._row_steps
+            led["steps"] += steps
+            led["rows"] += rows
+            led["spent"] += spent
+            led["dropped"] += emitted - credited
+            self._obs.steps.inc(steps)
+            self._obs.row_steps_spent.inc(spent)
         return credited
 
     def _pull(self, packed) -> np.ndarray:
@@ -2694,12 +2738,17 @@ class DecodeEngine:
 
         ``span`` is the pass's ``areal.decode.pass`` span (None for an idle
         poll); the phases inside are its children. It ends with the slots
-        active after the pass and the tokens the pass credited. (A pass
-        that started with requests queued and admitted none of them, all
-        expired, still leaves its span, with 0 and 0.)"""
+        active after the pass, the tokens the pass credited, the row ledger of
+        the chunk it drained (``LEDGER_KEYS``; ``spec=1`` on a speculative
+        pass, whose ledger is its round's) and what its admission did:
+        requests given a slot, prompt tokens handed to prefill programs,
+        requests left waiting. (A pass that started with requests queued and
+        admitted none of them, all expired, still leaves its span, with
+        zeros.)"""
         self._pass_start = time.monotonic()
         t_pass = self._pace_clock()
         self._pull_s = 0.0
+        ledger = dict(self._row_steps)  # before the pass's drain
         # the chunk in flight has only just begun: commit the next one's
         # batch part-way through it, with everything that arrives until then
         held = self._hold_for_commit(pending)
@@ -2725,8 +2774,11 @@ class DecodeEngine:
             # admissions enqueue prefills + ONE packed state scatter; the
             # in-flight chunk (if any) ordered before them touches only
             # previously-active slots, so there is no dataflow hazard
+            prefilled = self.stats["prefill_tokens"]
             rows = self._admit_pending()
             self._apply_slot_updates(rows)
+            # what the admission left waiting: empty rows with demand
+            queued = self._queue.qsize() + len(self._backlog)
         spec_on = self._spec_cfg is not None and self._drafter is not None
         if spec_on and self._freq_enabled:
             st = self._state
@@ -2765,6 +2817,11 @@ class DecodeEngine:
                 active=int(self._state["active"].sum()),
                 tokens=tokens,
                 held_us=int(held * 1e6),
+                **{k: self._row_steps[k] - ledger[k] for k in LEDGER_KEYS},  # of the chunk this pass drained
+                **({"spec": 1} if spec_on else {}),
+                admitted=len(rows),  # requests given a slot
+                prompt_tokens=self.stats["prefill_tokens"] - prefilled,
+                queued=queued,
             )
         if step_tl is not None:
             # a pass that drained, dispatched, or admitted is a real

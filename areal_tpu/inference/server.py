@@ -263,6 +263,10 @@ class InferenceServer:
         if da is not None and (attn_view := da()) is not None:
             # blocks of pages the decode steps' attention listed and fetched
             out["decode_attention"] = attn_view
+        rs = getattr(self.engine, "row_steps_status", None)
+        if rs is not None:
+            # the decode steps' rows: live, spent on an ended request, dropped
+            out["row_steps"] = rs()
         kp = getattr(self.engine, "kv_pools_status", None)
         if kp is not None and (pools_view := kp()) is not None:
             # which layers each group of page pools serves, how long a slot

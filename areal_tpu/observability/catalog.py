@@ -157,6 +157,20 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
         chunks=r.counter(
             "areal_decode_chunks_total", "Jitted decode chunks executed."
         ),
+        # a drained chunk's row ledger (DecodeEngine._drain): every row-step
+        # paid for is ..._steps_total x max_batch_size, the live ones are
+        # areal_decode_generated_tokens_total
+        steps=r.counter(
+            "areal_decode_steps_total",
+            "Decode steps the drained chunk programs ran (what ran, not "
+            "decode_steps_per_call; a speculative round is one).",
+        ),
+        row_steps_spent=r.counter(
+            "areal_decode_row_steps_spent_total",
+            "(row, step) pairs a chunk program ran under its mask for a slot "
+            "whose request had ended earlier in the chunk or in the chunk "
+            "before it (the dispatch's mask is a chunk stale).",
+        ),
         batch_occupancy=r.gauge(
             "areal_decode_batch_occupancy",
             "Active decode slots (of ServerConfig.max_batch_size).",

@@ -142,7 +142,6 @@ class KernelProbe:
         self._phase_sums: dict[str, float] = {p: 0.0 for p in DECODE_PHASES}
         self._other_sum = 0.0
         self._total_sum = 0.0
-        self._tokens_sum = 0.0
 
     # -- step lifecycle ----------------------------------------------------
 
@@ -172,7 +171,6 @@ class KernelProbe:
                 self._phase_sums[p] = self._phase_sums.get(p, 0.0) + v
             self._other_sum += bd["other_s"]
             self._total_sum += bd["total_s"]
-            self._tokens_sum += max(0, int(tokens))
             self._recent.append({"breakdown": bd, "tokens": int(tokens)})
         return bd
 
@@ -187,8 +185,9 @@ class KernelProbe:
 
     def stats(self) -> dict[str, Any]:
         """Steady-state summary for /statusz ``kernels``: per-phase mean
-        seconds (dominant phase named), step counts, and the loop's own
-        tokens per second of recorded step time."""
+        seconds (dominant phase named) and step counts. (Tokens a second are
+        not the probe's to guess: /statusz ``row_steps`` has the steps and
+        the live row-steps the drains counted.)"""
         with self._lock:
             n = self._completed
             phase_means = {
@@ -197,9 +196,6 @@ class KernelProbe:
             }
             other_mean = self._other_sum / n if n else 0.0
             total_mean = self._total_sum / n if n else 0.0
-            tok_s = (
-                self._tokens_sum / self._total_sum if self._total_sum else 0.0
-            )
             started, abandoned = self._started, self._abandoned
         dominant = None
         if n:
@@ -214,5 +210,4 @@ class KernelProbe:
             "other_mean_s": other_mean,
             "total_mean_s": total_mean,
             "dominant_phase": dominant,
-            "tok_s": tok_s,
         }

@@ -450,21 +450,21 @@ class PPOTrainer:
             n_extra_fwd = 0
             if self.critic is not None:
                 with perf_tracer.trace_scope(
-                    "train.compute_values", Category.COMPUTE
+                    "areal.train.compute_values", Category.COMPUTE
                 ):
                     batch["values"] = self.critic.compute_values(batch)
                 n_extra_fwd += 1
 
             if self.actor.should_compute_prox_logp():
                 with perf_tracer.trace_scope(
-                    "train.recompute_logp", Category.COMPUTE
+                    "areal.train.recompute_logp", Category.COMPUTE
                 ):
                     batch["prox_logp"] = self.actor.compute_logp(batch)
                 n_extra_fwd += 1
 
             if self.ref is not None:
                 with perf_tracer.trace_scope(
-                    "train.ref_logp", Category.COMPUTE
+                    "areal.train.ref_logp", Category.COMPUTE
                 ):
                     batch["ref_logp"] = self.ref.compute_logp(batch)
                 n_extra_fwd += 1
@@ -473,8 +473,7 @@ class PPOTrainer:
                 adv_batch = self.actor.compute_advantages(batch)
 
             t_train = time.monotonic()
-            with perf_tracer.trace_scope("train.ppo_update", Category.COMPUTE):
-                self.actor.ppo_update(adv_batch)
+            self.actor.ppo_update(adv_batch)
             if self.critic is not None:
                 self.critic.ppo_update(adv_batch)
             train_step_secs = time.monotonic() - t_train

@@ -87,7 +87,7 @@ def test_probe_complete_step_identity_and_stats():
     assert _identity_residual(rec["breakdown"]) < 1e-12
     assert rec["tokens"] == 8
     assert st["dominant_phase"] == "dispatch"
-    assert st["tok_s"] > 0
+    assert "tok_s" not in st  # a speed is not the probe's to guess: /statusz row_steps
 
 
 # ---------------------------------------------------------------------------
@@ -150,7 +150,6 @@ def test_engine_phase_identity_radix_hit_and_hold_fence():
         st = eng.kprobe.stats()
         # radix_match was actually timed on the warm admissions
         assert "radix_match" in st["phase_means_s"]
-        assert st["tok_s"] > 0
         # the engine surfaces the same stats through its public accessor
         # (what /statusz serves as the "kernels" section)
         ks = eng.kernel_stats()
