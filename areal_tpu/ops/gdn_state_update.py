@@ -11,10 +11,10 @@ update (``ops/ssm_state_update.py``) has not. It is some ten operations a
 byte: bound by reading and writing the state, 2.2 MB a slot and layer at
 Olmo-Hybrid-7B's 30 heads of 96 x 192 in float32. As there, the stacked state
 stays where it lies (``memory_space=ANY``, aliased to the output) and the
-kernel walks the list of live slots: a slot's state comes into one of two
-VMEM buffers while the slot before it is computed and goes back to the same
-rows from one of two more; a slot that is not on the list is neither read
-nor written.
+kernel walks the list of live slots (``ops/slot_walk.py``: a ring of four
+VMEM buffers updated in place, two slots' fetches in flight before the slot
+that is computed, one slot's store behind it); a slot that is not on the list
+is neither read nor written.
 
 Layout (what the chip asks for):
   - the state is held PACKED, ``[layers, slots, H / p, K, p * V]``: p heads
@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_NBUF = 2
+from areal_tpu.ops.slot_walk import ring_bytes, ring_scratch, walk_live_slots
 
 
 def head_pack(num_heads: int, v_dim: int) -> int:
@@ -77,45 +77,20 @@ def _kernel(
     state_hbm,  # ANY [L, S, G, K, p * V]
     o_ref,  # VMEM out [S, G, p * V] f32
     state_out,  # ANY, the same buffer as state_hbm
-    ibuf,  # VMEM [2, G, K, p * V]
-    obuf,  # VMEM [2, G, K, p * V]
+    ring,  # VMEM [RING, G, K, p * V]
     isem,
     osem,
 ):
-    _, groups, k_dim, pv = ibuf.shape
+    _, groups, k_dim, pv = ring.shape
     pack = alpha_ref.shape[1] // groups
     v_dim = pv // pack
-    n = n_live_ref[0]
-    li = layer_ref[0]
     o_ref[...] = jnp.zeros_like(o_ref)
-
-    def fetch(t):
-        return pltpu.make_async_copy(state_hbm.at[li, order_ref[t]], ibuf.at[t % _NBUF], isem.at[t % _NBUF])
-
-    def store(t):
-        return pltpu.make_async_copy(obuf.at[t % _NBUF], state_out.at[li, order_ref[t]], osem.at[t % _NBUF])
-
-    @pl.when(n > 0)
-    def _first():
-        fetch(0).start()
 
     # which of the group's heads a lane belongs to
     head_of_row = jax.lax.broadcasted_iota(jnp.int32, (1, pv), 1) // v_dim
     head_of = jax.lax.broadcasted_iota(jnp.int32, (k_dim, pv), 1) // v_dim
 
-    def item(t, carry):
-        buf = t % _NBUF
-        s = order_ref[t]
-        fetch(t).wait()
-
-        @pl.when(t + 1 < n)
-        def _next():
-            fetch(t + 1).start()
-
-        @pl.when(t >= _NBUF)
-        def _free():  # the copy that last left this output buffer
-            store(t - _NBUF).wait()
-
+    def slot(s, buf):
         q_t = q_t_ref[s]  # [K, H]
         k_t = k_t_ref[s]
         for g in range(groups):
@@ -130,21 +105,14 @@ def _kernel(
                 beta = jnp.where(head_of_row == j, beta_ref[s, h], beta)
                 kk = jnp.where(head_of == j, k_t[:, h : h + 1], kk)
                 qq = jnp.where(head_of == j, q_t[:, h : h + 1], qq)
-            decayed = ibuf[buf, g].astype(jnp.float32) * alpha  # [K, p V]
+            decayed = buf[g].astype(jnp.float32) * alpha  # [K, p V]
             read = jnp.sum(decayed * kk, axis=0, keepdims=True)  # [1, p V]: S'^T k
             u = beta * (v_ref[s, g : g + 1, :] - read)
             new = decayed + kk * u
-            obuf[buf, g] = new.astype(obuf.dtype)
+            buf[g] = new.astype(buf.dtype)
             o_ref[s, g : g + 1, :] = jnp.sum(new * qq, axis=0, keepdims=True)
-        store(t).start()
-        return carry
 
-    jax.lax.fori_loop(0, n, item, 0)
-    for back in range(_NBUF, 0, -1):  # the copies still in flight
-
-        @pl.when(n >= back)
-        def _drain(back=back):
-            store(n - back).wait()
+    walk_live_slots(order_ref, n_live_ref[0], layer_ref[0], state_hbm, state_out, ring, isem, osem, slot)
 
 
 def gdn_state_update_stacked(
@@ -169,8 +137,7 @@ def gdn_state_update_stacked(
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     anyspace = pl.BlockSpec(memory_space=pl.ANY)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    lanes = -(-PV // 128) * 128
-    buf_bytes = 2 * _NBUF * G * K * lanes * state.dtype.itemsize
+    buf_bytes = ring_bytes((G, K, PV), state.dtype)
     o, out = pl.pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -178,12 +145,7 @@ def gdn_state_update_stacked(
             in_specs=[smem, smem, vmem, vmem, vmem, anyspace],
             out_specs=[vmem, anyspace],
             grid=(1,),
-            scratch_shapes=(
-                pltpu.VMEM((_NBUF, G, K, PV), state.dtype),
-                pltpu.VMEM((_NBUF, G, K, PV), state.dtype),
-                pltpu.SemaphoreType.DMA((_NBUF,)),
-                pltpu.SemaphoreType.DMA((_NBUF,)),
-            ),
+            scratch_shapes=ring_scratch((G, K, PV), state.dtype),
         ),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=min(100 << 20, buf_bytes + (32 << 20))),
         out_shape=(jax.ShapeDtypeStruct((S, G, PV), jnp.float32), jax.ShapeDtypeStruct(state.shape, state.dtype)),

@@ -20,10 +20,10 @@ are held where they are (PERF.md section 6, PR 47).
 Some ten operations a byte: bound by reading and writing the state, 4.19 MB a
 slot and layer at Solar-Open2's 64 heads of 128 x 128 in float32. The stacked
 state stays where it lies (``memory_space=ANY``, aliased to the output) and
-the kernel walks the list of live slots: a slot's state comes into one of two
-VMEM buffers while the slot before it is computed and goes back to the same
-rows from one of two more; a slot that is not on the list is neither read nor
-written.
+the kernel walks the list of live slots (``ops/slot_walk.py``: a ring of four
+VMEM buffers updated in place, two slots' fetches in flight before the slot
+that is computed, one slot's store behind it); a slot that is not on the list
+is neither read nor written.
 
 Layout: the state ``[layers, slots, H, K, V]``; ``beta`` a scalar a head
 (SMEM); ``q``, ``k`` and ``d`` columns a head, so the caller hands them
@@ -45,7 +45,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_NBUF = 2
+from areal_tpu.ops.slot_walk import ring_bytes, ring_scratch, walk_live_slots
 
 
 def _kernel(
@@ -60,28 +60,13 @@ def _kernel(
     state_hbm,  # ANY [L, S, H, K, V]
     o_ref,  # VMEM out [S, H, V] f32
     state_out,  # ANY, the same buffer as state_hbm
-    ibuf,  # VMEM [2, H, K, V]
-    obuf,  # VMEM [2, H, K, V]
+    ring,  # VMEM [RING, H, K, V]
     isem,
     osem,
 ):
-    _, heads, k_dim, v_dim = ibuf.shape
+    _, heads, k_dim, v_dim = ring.shape
     tile = (k_dim, v_dim)
-    n = n_live_ref[0]
-    li = layer_ref[0]
     o_ref[...] = lax.full(o_ref.shape, 0.0, jnp.float32)
-
-    def fetch(t):
-        b = lax.rem(t, _NBUF)
-        return pltpu.make_async_copy(state_hbm.at[li, order_ref[t]], ibuf.at[b], isem.at[b])
-
-    def store(t):
-        b = lax.rem(t, _NBUF)
-        return pltpu.make_async_copy(obuf.at[b], state_out.at[li, order_ref[t]], osem.at[b])
-
-    @pl.when(lax.gt(n, 0))
-    def _first():
-        fetch(0).start()
 
     def column(ref, s, h):  # a head's column [K, 1] of a transposed operand, over the tile's lanes
         return lax.broadcast_in_dim(ref[s, :, h : h + 1], tile, (0, 1))
@@ -89,37 +74,18 @@ def _kernel(
     def row_sum(x):  # over the sublanes (K): [K, V] -> [1, V]
         return lax.expand_dims(lax.reduce_sum(x, (0,)), (0,))
 
-    def item(t, carry):
-        buf = lax.rem(t, _NBUF)
-        s = order_ref[t]
-        fetch(t).wait()
-
-        @pl.when(lax.lt(lax.add(t, 1), n))
-        def _next():
-            fetch(lax.add(t, 1)).start()
-
-        @pl.when(lax.ge(t, _NBUF))
-        def _free():  # the copy that last left this output buffer
-            store(lax.sub(t, _NBUF)).wait()
-
+    def slot(s, buf):
         for h in range(heads):
             kk, qq = column(k_t_ref, s, h), column(q_t_ref, s, h)
-            decayed = lax.mul(lax.convert_element_type(ibuf[buf, h], jnp.float32), column(d_t_ref, s, h))  # [K, V]: diag(d) S
+            decayed = lax.mul(lax.convert_element_type(buf[h], jnp.float32), column(d_t_ref, s, h))  # [K, V]: diag(d) S
             read = row_sum(lax.mul(decayed, kk))  # [1, V]: S'^T k
             beta = lax.full((1, v_dim), beta_ref[s, h], jnp.float32)
             w = lax.mul(beta, lax.sub(v_ref[s, h : h + 1, :], read))
             new = lax.add(decayed, lax.mul(kk, lax.broadcast_in_dim(w, tile, (0, 1))))
-            obuf[buf, h] = lax.convert_element_type(new, obuf.dtype)
+            buf[h] = lax.convert_element_type(new, buf.dtype)
             o_ref[s, h : h + 1, :] = row_sum(lax.mul(new, qq))
-        store(t).start()
-        return carry
 
-    lax.fori_loop(0, n, item, 0)
-    for back in range(_NBUF, 0, -1):  # the copies still in flight
-
-        @pl.when(lax.ge(n, back))
-        def _drain(back=back):
-            store(lax.sub(n, back)).wait()
+    walk_live_slots(order_ref, n_live_ref[0], layer_ref[0], state_hbm, state_out, ring, isem, osem, slot)
 
 
 def kda_state_update_stacked(
@@ -146,9 +112,9 @@ def kda_state_update_stacked(
     anyspace = pl.BlockSpec(memory_space=pl.ANY)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     lanes = -(-V // 128) * 128
-    # the two rings of a slot's state, and the operands that stay in VMEM for the whole launch (a transposed column
+    # the ring of slots' states, and the operands that stay in VMEM for the whole launch (a transposed column
     # operand's H lanes padded to whole tiles)
-    buf_bytes = 2 * _NBUF * H * K * lanes * state.dtype.itemsize
+    buf_bytes = ring_bytes((H, K, V), state.dtype)
     operand_bytes = 4 * S * (3 * K * (-(-H // 128) * 128) + 2 * H * lanes)
     f32 = jnp.float32
     o, out = pl.pallas_call(
@@ -158,12 +124,7 @@ def kda_state_update_stacked(
             in_specs=[smem, vmem, vmem, vmem, vmem, anyspace],
             out_specs=[vmem, anyspace],
             grid=(1,),
-            scratch_shapes=(
-                pltpu.VMEM((_NBUF, H, K, V), state.dtype),
-                pltpu.VMEM((_NBUF, H, K, V), state.dtype),
-                pltpu.SemaphoreType.DMA((_NBUF,)),
-                pltpu.SemaphoreType.DMA((_NBUF,)),
-            ),
+            scratch_shapes=ring_scratch((H, K, V), state.dtype),
         ),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=min(100 << 20, buf_bytes + operand_bytes + (16 << 20))),
         out_shape=(jax.ShapeDtypeStruct((S, H, V), f32), jax.ShapeDtypeStruct(state.shape, state.dtype)),

@@ -10,17 +10,22 @@ closed-loop GRPO traffic two slots in three hold no request (PERF.md, PR 26).
 
 This kernel takes the stacked state ``[n_layers, S, H, P, N]`` where it lies
 (``memory_space=ANY``, aliased to its output) with the layer index and the
-list of live slots as scalars, and walks that list: a slot's state comes into
-one of two VMEM buffers while the slot before it is computed, and goes back
-to the same rows from one of two more. A slot that is not on the list is
-neither read nor written: it keeps its state bit for bit.
+list of live slots as scalars, and walks that list (``ops/slot_walk.py``: a
+ring of four VMEM buffers updated in place, two slots' fetches in flight
+before the slot that is computed, one slot's store behind it). A slot that is
+not on the list is neither read nor written: it keeps its state bit for bit.
 
 Layout notes (what the chip's compiler asked for):
   - per head the tile is [P, N] with N on the lanes; ``exp(dt A)`` is a
     scalar a head (SMEM), ``dt x`` has to be a COLUMN [P, 1] a head, so the
     caller hands it transposed, [S, P, H], and a head's column is a static
     lane slice; ``y`` leaves the same way, [S, P, H], one lane a head;
-  - heads are a static loop: a lane slice at a traced offset does not lower.
+  - heads are a static loop: a lane slice at a traced offset does not lower;
+  - a slot's heads are updated in one pass and read out in a second, from a
+    float32 copy of the new state (what a bfloat16 state has rounded away):
+    spreading a head's ``dt x`` over the lanes and summing its ``S C`` over
+    them both run on the cross-lane units, and head by head each waited for
+    the other, 7.5 us a slot where the copies take 6.4 (PERF.md, PR 50).
 """
 
 from __future__ import annotations
@@ -30,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_NBUF = 2
+from areal_tpu.ops.slot_walk import ring_bytes, ring_scratch, walk_live_slots
 
 
 def _kernel(
@@ -44,61 +49,34 @@ def _kernel(
     ssm_hbm,  # ANY [L, S, H, P, N]
     y_t_ref,  # VMEM out [S, P, H] f32
     ssm_out,  # ANY, the same buffer as ssm_hbm
-    ibuf,  # VMEM [2, H, P, N]
-    obuf,  # VMEM [2, H, P, N]
+    ring,  # VMEM [RING, H, P, N]
     isem,
     osem,
+    fresh,  # VMEM [H, P, N] f32: the slot's new state before it is rounded to the state's dtype
 ):
-    _, num_heads, head_dim, _ = ibuf.shape
+    _, num_heads, head_dim, _ = ring.shape
     groups = b_ref.shape[1]
-    n = n_live_ref[0]
-    li = layer_ref[0]
     y_t_ref[...] = jnp.zeros_like(y_t_ref)
-
-    def fetch(t):
-        return pltpu.make_async_copy(ssm_hbm.at[li, order_ref[t]], ibuf.at[t % _NBUF], isem.at[t % _NBUF])
-
-    def store(t):
-        return pltpu.make_async_copy(obuf.at[t % _NBUF], ssm_out.at[li, order_ref[t]], osem.at[t % _NBUF])
-
-    @pl.when(n > 0)
-    def _first():
-        fetch(0).start()
-
     lane = jax.lax.broadcasted_iota(jnp.int32, (head_dim, num_heads), 1)
 
-    def item(t, carry):
-        buf = t % _NBUF
-        s = order_ref[t]
-        fetch(t).wait()
-
-        @pl.when(t + 1 < n)
-        def _next():
-            fetch(t + 1).start()
-
-        @pl.when(t >= _NBUF)
-        def _free():  # the copy that last left this output buffer
-            store(t - _NBUF).wait()
-
+    def slot(s, buf):
+        # every head's update, then every head's read-out (the module's layout notes): 3,697 bundles a slot head by
+        # head, 2,642 this way, the same expressions
         dtx_t = dtx_t_ref[s]  # [P, H]
+        for h in range(num_heads):
+            g = h // (num_heads // groups)
+            state = buf[h].astype(jnp.float32)  # [P, N]
+            new = state * decay_ref[s, h] + dtx_t[:, h : h + 1] * b_ref[s, g : g + 1, :]
+            buf[h] = new.astype(buf.dtype)
+            fresh[h] = new
         y_t = jnp.zeros((head_dim, num_heads), jnp.float32)
         for h in range(num_heads):
             g = h // (num_heads // groups)
-            state = ibuf[buf, h].astype(jnp.float32)  # [P, N]
-            new = state * decay_ref[s, h] + dtx_t[:, h : h + 1] * b_ref[s, g : g + 1, :]
-            obuf[buf, h] = new.astype(obuf.dtype)
-            col = jnp.sum(new * c_ref[s, g : g + 1, :], axis=-1, keepdims=True)  # [P, 1]
+            col = jnp.sum(fresh[h] * c_ref[s, g : g + 1, :], axis=-1, keepdims=True)  # [P, 1]
             y_t = jnp.where(lane == h, col, y_t)
         y_t_ref[s] = y_t
-        store(t).start()
-        return carry
 
-    jax.lax.fori_loop(0, n, item, 0)
-    for back in range(_NBUF, 0, -1):  # the copies still in flight
-
-        @pl.when(n >= back)
-        def _drain(back=back):
-            store(n - back).wait()
+    walk_live_slots(order_ref, n_live_ref[0], layer_ref[0], ssm_hbm, ssm_out, ring, isem, osem, slot)
 
 
 def ssm_state_update_stacked(
@@ -122,7 +100,7 @@ def ssm_state_update_stacked(
     dtx_t = jnp.swapaxes(dt[..., None] * x, 1, 2)  # [S, P, H]
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     anyspace = pl.BlockSpec(memory_space=pl.ANY)
-    buf_bytes = 2 * _NBUF * H * P * N * ssm.dtype.itemsize
+    buf_bytes = ring_bytes((H, P, N), ssm.dtype) + 4 * H * P * N
     y_t, out = pl.pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -130,12 +108,7 @@ def ssm_state_update_stacked(
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), vmem, vmem, vmem, anyspace],
             out_specs=[vmem, anyspace],
             grid=(1,),
-            scratch_shapes=(
-                pltpu.VMEM((_NBUF, H, P, N), ssm.dtype),
-                pltpu.VMEM((_NBUF, H, P, N), ssm.dtype),
-                pltpu.SemaphoreType.DMA((_NBUF,)),
-                pltpu.SemaphoreType.DMA((_NBUF,)),
-            ),
+            scratch_shapes=(*ring_scratch((H, P, N), ssm.dtype), pltpu.VMEM((H, P, N), jnp.float32)),
         ),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=min(100 << 20, buf_bytes + (24 << 20))),
         out_shape=(jax.ShapeDtypeStruct((S, P, H), jnp.float32), jax.ShapeDtypeStruct(ssm.shape, ssm.dtype)),

@@ -15,6 +15,7 @@ layers (4 s): tier-1 has no room for an engine's programs.
 """
 
 import functools
+import math
 import os
 import re
 
@@ -388,6 +389,40 @@ def test_kernel_compiles_for_v5e(chip, name):
     fn, args = CASES[name]()
     compiled = jax.jit(fn).lower(*args(chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _pallas_call(jaxpr):
+    """The first ``pallas_call`` equation of a jaxpr, looked for inside its calls too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn
+        for sub in eqn.params.values():
+            found = _pallas_call(sub.jaxpr) if hasattr(sub, "jaxpr") else None
+            if found is not None:
+                return found
+    return None
+
+
+@pytest.mark.parametrize("name", [f"{k}_state_update_{d}" for k in ("ssm", "gdn", "kda") for d in ("f32", "bf16")])
+def test_state_kernel_is_launched_with_the_walks_ring(chip, name):
+    """At its cell's shapes a recurrent state kernel holds the whole ring of
+    ``ops/slot_walk.py`` (four buffers of one slot's state each, a DMA
+    semaphore a buffer and direction) and asks for the VMEM that takes: a ring
+    that fell back to fewer buffers, or a limit the ring does not fit, fails
+    here and not in a cell."""
+    from areal_tpu.ops import slot_walk
+
+    fn, args = CASES[name]()
+    shapes = args(chip)
+    eqn = _pallas_call(jax.make_jaxpr(fn)(*shapes).jaxpr)
+    scratch = [v.aval for v in eqn.params["jaxpr"].invars[-eqn.params["grid_mapping"].num_scratch_operands :]]
+    ring, isem, osem = scratch[:3]
+    state = shapes[0]
+    assert ring.shape == (4, *state.shape[2:]) and ring.dtype == state.dtype and slot_walk.RING == 4 and slot_walk.AHEAD == 2
+    assert isem.shape == osem.shape == (4,)
+    limit = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    held = slot_walk.ring_bytes(ring.shape[1:], ring.dtype)
+    assert held >= math.prod(ring.shape) * ring.dtype.itemsize and held + (8 << 20) <= limit <= 100 << 20, (held, limit)
 
 
 # the repo's own kernels by the ``name=`` of their ``pl.pallas_call``: what a
