@@ -1516,6 +1516,7 @@ class DecodeEngine:
         rows = self.slots.hbm_rows(self.cache)
         out["state_bytes"] = rows["recurrent_state"]
         out["window_bytes"] = rows.get("window_rings", 0)
+        out["serving_limit"] = self._model_limits().get("reason")  # why no cached prefix is served: ``window_rings`` where a ring is the slot's only tenant
         return out
 
     def decode_attention_status(self) -> dict | None:
@@ -2065,7 +2066,9 @@ class DecodeEngine:
             with set_mesh(self.mesh):
                 copy, args = self.programs.pagecopy_call(copy_dst, copy_src, slot_dst, slot_src)
                 self.cache = copy(self.cache, *args)
-            if self.model_cfg.has_recurrent_state:
+            # what a sibling cannot share by reference: a recurrent state, or the window layers' rings (a ring may be
+            # the slot's ONLY tenant beside its pages)
+            if self.model_cfg.has_slot_tenant:
                 self._obs.state_copies.inc(len(copy_dst))
         self.stats["prefix_shared"] = self.stats.get("prefix_shared", 0) + len(
             copy_dst
@@ -2148,8 +2151,14 @@ class DecodeEngine:
             # the program's rows ended at the shared layer's K and V: a prompt's one row of the layers past it is the
             # decode step's that feeds its last token again
             self._obs.prefill_last_token_rows.inc(A)
+        if rings := (getattr(self.model_cfg, "kv_groups", None) or {}).get("window"):
+            # query-key pairs inside the band the window layers' prompt pass attended: sum_t min(t + 1, window) a
+            # row x window layers, from the rows' lengths, whatever computed the product
+            from areal_tpu.inference.paged_kv import band_pairs
+
+            self._obs.window_prompt_pairs.inc(len(rings["writers"]) * sum(band_pairs(int(n), rings["keeps"]) for n in plens))
         rebuilt = self.slots.readmitted(t.req.rid for t, _ in admitted)
-        if self.model_cfg.has_recurrent_state:
+        if self.model_cfg.has_slot_tenant:
             self._obs.state_prefills.inc(rebuilt)
         return rows
 

@@ -467,6 +467,18 @@ STATE_LEAVES = ("ssm", "conv", "gdn", "kda")
 # slots that are not live. Merging axes 2 and 3 gives the pools' own layout
 # [layers, heads, pages, page, lanes] (``ring_pool``) under the page table
 # ``ring_table``.
+# WITH a rotary embedding (``cohere2_moe``'s window layers) a key is written
+# ALREADY rotated at its own position: q_t . k_s then depends on t - s alone
+# wherever k_s lies in the ring, so the read is still paged attention over the
+# ring as it lies. A ring may be a slot's ONLY tenant beside its pages (no
+# STATE leaf at all: ``cfg.has_slot_tenant`` without ``has_recurrent_state``):
+# the sibling copy, parking, preemption and re-prefill run for it alone, the
+# radix cache stays off (``serving_limits`` reason ``window_rings``: a cached
+# prefix of the full layers' pages is no prefix of a ring) and
+# ``areal_decode_state_copies_total`` / ``_state_prefills_total`` count its
+# copies and rebuilds. At a window of 4,096 a ring is 32 pages of 128 tokens
+# a slot and layer (16.8 MB at 8 KV heads of 128; 50.3 MB a slot over three
+# window layers, copied to 7 siblings a group of 8).
 RING_LEAVES = ("ring_k", "ring_v")
 
 
@@ -495,6 +507,14 @@ def ring_of_rows(rows: jax.Array, n_tokens: jax.Array, window: int, ring: tuple[
     picked = rows[jnp.clip(t, 0, rows.shape[0] - 1)]  # [window, heads, lanes]
     picked = jnp.pad(picked, ((0, pages * psz - window), (0, 0), (0, 0)))
     return picked.reshape(pages, psz, *rows.shape[1:]).transpose(2, 0, 1, 3)
+
+
+def band_pairs(n_tokens: int, window: int) -> int:
+    """(query, key) pairs inside the band of a prompt of ``n_tokens`` in ONE
+    window layer: ``sum_t min(t + 1, window)``, the work of its prompt pass
+    whatever computes it (``areal_decode_window_prompt_pairs_total``)."""
+    full = min(n_tokens, window)
+    return full * (full + 1) // 2 + max(0, n_tokens - window) * window
 
 
 def init_paged_cache(

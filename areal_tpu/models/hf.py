@@ -84,7 +84,7 @@ def load_params_from_hf(
         parts = our_path.split("/")
         if len(parts) >= 3:
             per = stacked.setdefault((parts[0], parts[2]), {})
-            per.setdefault(int(parts[1]), set()).add(int(parts[3]) if len(parts) == 4 else None)
+            per.setdefault(int(parts[1]), set()).add(parts[3] if len(parts) == 4 else None)
     params: dict[str, Any] = {
         "embed": put("embed", to_np(*name_map["embed"])),
         "final_norm": put("final_norm", to_np(*name_map["final_norm"])),
@@ -94,6 +94,9 @@ def load_params_from_hf(
         for i in range(len(per)):
             if None in per[i]:
                 per_layer.append(to_np(*name_map[f"{stack}/{i}/{name}"]))
+            elif "s0" in per[i]:  # blocks side by side along the leaf's wide axis (several shared experts in one leaf)
+                blocks = [to_np(*name_map[f"{stack}/{i}/{name}/s{b}"]) for b in range(len(per[i]))]
+                per_layer.append(np.concatenate(blocks, axis=_wide_axis(name)))
             else:  # one checkpoint tensor per (layer, expert): stacked [L, E, ...]
                 per_layer.append(
                     np.stack([to_np(*name_map[f"{stack}/{i}/{name}/{e}"]) for e in range(len(per[i]))])
@@ -108,6 +111,12 @@ def load_params_from_hf(
     if cfg.vision is not None and "visual.patch_embed.proj.weight" in shards:
         params["vision"] = _load_vision_params(cfg.vision, shards, to_np, put)
     return params, cfg
+
+
+def _wide_axis(leaf: str) -> int:
+    """The axis along which a leaf's side-by-side blocks lie, in our layout
+    [in, out]: a gate or up projection's columns, a down projection's rows."""
+    return 0 if leaf.endswith("down") else 1
 
 
 def _load_vision_params(vcfg, shards, to_np, put) -> dict:
@@ -221,7 +230,11 @@ def save_params_to_hf(
 
     for our_path, (hf_name, transpose) in name_map.items():
         parts = our_path.split("/")
-        if len(parts) == 4:  # <stack>/<l>/<name>/<e>
+        if len(parts) == 4 and parts[3].startswith("s"):  # <stack>/<l>/<name>/s<b>: block b along the wide axis
+            whole = leaf(parts[0], parts[2])[int(parts[1])]
+            n_blocks = sum(1 for k in name_map if k.startswith("/".join(parts[:3]) + "/s"))
+            t = np.split(whole, n_blocks, axis=_wide_axis(parts[2]))[int(parts[3][1:])]
+        elif len(parts) == 4:  # <stack>/<l>/<name>/<e>
             t = leaf(parts[0], parts[2])[int(parts[1]), int(parts[3])]
         elif len(parts) == 3:  # <stack>/<l>/<name>
             t = leaf(parts[0], parts[2])[int(parts[1])]

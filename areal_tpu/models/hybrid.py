@@ -27,8 +27,32 @@ that ends at the shared layer's K and V (``forward_prefill`` ``tail``). A
 tenth mixer, ``kda``, is a delta rule whose state decays by a factor of its
 OWN every key channel (below), and the ``attention`` mixer may carry an
 output gate (``attn_gate``: ``o * sigmoid(W_g u)``, one gate a head and
-channel). Six published families are built from these (``from_hf_dict``):
-``solar_open2`` (``kda`` beside gated attention without a positional
+channel). A block comes in two forms (``block_form``): ``serial``, ``h = x
++ Mixer(norm(x))``, ``out = h + FFN(norm(h))``, two norms a layer; and
+``parallel``, ``u = norm(x)``, ``out = x + Mixer(u) + FFN(u)``: ONE norm, the
+FFN reads the mixer's INPUT and the two sublayers do not depend on each other
+(``_block_ffn``; scope ``block_sum``). The ``swa`` mixer comes in two forms
+as well, by the configuration's ``diff_attn``: differential pairs without a
+position (``phi4flash``), or PLAIN grouped queries (``_qkv``) whose q and k
+take a rotary embedding where ``rope_kinds`` names the mixer (``rotates``; in
+(2i, 2i+1) pairs with ``rope_interleave``; scope ``attn_rope``): a key goes
+into the slot's ring ALREADY rotated at its own position, so a read takes
+the ring as it lies (softmax over q_t . k_s depends on t - s alone). Its
+prompt pass attends inside the band ``0 <= t - s < sliding_window``: in XLA
+over blocks of ``sliding_window`` queries (``swa_attend``: the CPU path and
+the oracle) or, where such a block's [H, window, 2 window] float32 logits do
+not fit (17 GB at 128 heads and 4,096), under ONE Pallas launch whose grid IS
+the band (``ops/window_prefill_attention.py``, chosen from the shapes:
+``swa_prefill_launch``). The always-active block beside the routed experts
+may be several shared experts side by side whose outputs are AVERAGED
+(``moe_shared_mean_of``). Seven published families are built from these
+(``from_hf_dict``): ``cohere2_moe`` (command-a-plus: the parallel block under
+a LayerNorm with a weight and NO bias, ``norm_bias`` False; ``swa`` layers
+with the rotary embedding beside ``attention`` layers with none, 3:1, 128
+query heads over 8; in EVERY layer sigmoid-routed experts WITHOUT a selection
+bias beside four shared ones that are averaged; a tied head times
+``logit_scale``; every line an ASSUMPTION where the configuration names a
+word and not a formula, listed in the configuration file), ``solar_open2`` (``kda`` beside gated attention without a positional
 embedding, 3:1, and in EVERY layer sigmoid-routed experts with a selection
 bias beside a shared one: no leading dense layer), ``phi4flash``,
 ``granitemoehybrid``
@@ -165,7 +189,7 @@ from jax.sharding import PartitionSpec as P
 from areal_tpu.models import moe, qwen
 from areal_tpu.models.qwen import _embed_lookup, _proj, _rms_norm, _rope
 
-MODEL_TYPES = ("granitemoehybrid", "lfm2_moe", "olmo_hybrid", "deepseek_v3", "glm_moe_dsa", "phi4flash", "solar_open2")
+MODEL_TYPES = ("granitemoehybrid", "lfm2_moe", "olmo_hybrid", "deepseek_v3", "glm_moe_dsa", "phi4flash", "solar_open2", "cohere2_moe")
 # mixers; ``s6`` is Mamba-1's selective scan, ``swa`` attention over the last ``sliding_window`` tokens, ``cross``
 # queries of its own over the pages of the model's ONE ``attention`` layer, ``gmu`` a gated memory unit, ``kda`` a
 # delta rule whose state decays by a factor of its own every key channel
@@ -187,6 +211,9 @@ MOE_SHARED_SCOPE = "moe_shared"  # the always-active block beside the routed exp
 # a decoder-hybrid-decoder: the window layers' read, the cross layers' read of the shared pages, what differential
 # attention adds behind either read (the subtraction, the norm over a pair's values, lambda), the memory unit
 SAMBAY_SCOPES = ("attn_window", "attn_cross", "attn_diff", "gmu")
+# a parallel block with rotary window layers: the rotary embedding of the window layers' q and k (both entry points),
+# and the block's one residual sum ``x + Attn(u) + MoE(u)``
+PARALLEL_SCOPES = ("attn_rope", "block_sum")
 # tokens a chunk of the delta rule's prefill scan: 16 x 2^2, as ``_unit_lower_inverse`` builds its inverse
 GDN_CHUNK = 64
 # the per-channel-decay delta rule's: the same chunk, in sub-blocks of 16 tokens each referred to its own first
@@ -340,6 +367,17 @@ class HybridConfig:
     kda_rank: int = 0
     kda_neg_eigval: bool = False
     kda_state_dtype: str = "float32"
+    # the block: "serial" (``h = x + Mixer(norm(x))``, ``out = h + FFN(norm(h))``: two norms a layer) or "parallel"
+    # (``u = norm(x)``, ``out = x + Mixer(u) + FFN(u)``: ONE norm, the FFN reads the mixer's INPUT)
+    block_form: str = "serial"
+    # a ``norm_kind`` "layer" norm has a bias beside its weight (False: a weight alone)
+    norm_bias: bool = True
+    # the mixer kinds whose q and k take the rotary embedding ``rope_theta`` (None: every ``attention`` layer, as
+    # ever); ``rope_interleave`` then pairs channels (2i, 2i+1) there too
+    rope_kinds: tuple[str, ...] | None = None
+    # the always-active block is this many shared experts side by side whose outputs are AVERAGED: the
+    # down-projection's sum over all of their columns, divided by it (1: summed, one block)
+    moe_shared_mean_of: int = 1
 
     @property
     def num_layers(self) -> int:
@@ -497,6 +535,17 @@ class HybridConfig:
         return self.count("mamba") + self.count("conv") + self.count("gdn") + self.count("s6") + self.count("kda") > 0
 
     @property
+    def has_slot_tenant(self) -> bool:
+        """Whether a slot owns anything beside its pages: a recurrent state,
+        or the window layers' rings (what a sibling is handed a COPY of, and
+        what a re-prefill after a preemption rebuilds)."""
+        return self.has_recurrent_state or self.count("swa") > 0
+
+    def rotates(self, kind: str) -> bool:
+        """Whether the q and k of a ``kind`` layer take the rotary embedding."""
+        return self.rope_theta is not None and (kind in self.rope_kinds if self.rope_kinds is not None else kind == "attention")
+
+    @property
     def count_shapes(self) -> dict[str, tuple[int, ...]]:
         """{leaf: shape} of every int32 count a decode chunk takes back
         beside its tokens (COUNT_LEAVES, in that order)."""
@@ -628,6 +677,39 @@ class HybridConfig:
             "num_key_value_heads": self.num_kv_heads,
             "tie_word_embeddings": self.tie_word_embeddings,
         }
+        if self.model_type == "cohere2_moe":
+            full = [i for i, t in enumerate(self.layer_types) if t == "attention"]
+            return {
+                **shared,
+                "layer_types": [_COHERE_KINDS_OUT[t] for t in self.layer_types],
+                "layer_switch": (full[0] + 1) if full else self.num_layers + 1,
+                "order_of_interleaved_layers": "local_attn_first",
+                "hidden_act": "silu",
+                "use_gated_activation": True,
+                "head_dim": self.head_dim_,
+                "attention_bias": False,
+                "use_qk_norm": False,
+                "use_parallel_block": True,
+                "use_parallel_embedding": False,
+                "use_embedding_sharing": True,
+                "layer_norm_eps": self.rms_norm_eps,
+                "rms_norm_eps": None,
+                "logit_scale": 1.0 / self.logits_scaling,
+                "position_embedding_type": "rope_gptj",
+                "rope_theta": self.rope_theta,
+                "rope_parameters": {"rope_theta": self.rope_theta, "rope_type": "default"},
+                "rotary_pct": 1,
+                "sliding_window": self.sliding_window,
+                "first_k_dense_replace": 0,
+                "expert_selection_fn": "sigmoid",
+                "num_experts": self.num_experts,
+                "num_experts_per_tok": self.num_experts_per_tok,
+                "num_shared_experts": self.moe_shared_mean_of,
+                "shared_expert_combination_strategy": "average",
+                "moe_intermediate_size": self.moe_intermediate_size,
+                "norm_topk_prob": self.norm_topk_prob,
+                **({"router_experts": self.router_experts, "expert_first": self.expert_first} if self.router_experts else {}),
+            }
         if self.model_type == "phi4flash":
             return {
                 **shared,
@@ -1061,7 +1143,73 @@ def _solar_open2_fields(d: dict[str, Any]) -> dict[str, Any]:
     )
 
 
+_COHERE_KINDS = {"sliding_attention": "swa", "full_attention": "attention"}
+_COHERE_KINDS_OUT = {v: k for k, v in _COHERE_KINDS.items()}
+
+
+def _cohere2_moe_fields(d: dict[str, Any]) -> dict[str, Any]:
+    """``cohere2_moe`` (Command A+): a PARALLEL block under ONE LayerNorm
+    without bias (``use_parallel_block``: ``out = x + Attn(u) + MoE(u)``), the
+    layers ``layer_types`` calls ``sliding_attention`` attending to the last
+    ``sliding_window`` tokens with a rotary embedding in (2i, 2i+1) pairs
+    (``rope_gptj``), the ``full_attention`` layers to every token with NO
+    positional embedding, grouped queries without q/k norm in both; in every
+    layer (``first_k_dense_replace`` 0) ``num_experts`` experts of
+    ``intermediate_size`` behind a sigmoid router WITHOUT a selection bias,
+    beside ``num_shared_experts`` always-active ones whose outputs are
+    averaged (``shared_expert_combination_strategy``: the mean over the shared
+    experts, added to the routed sum: an ASSUMPTION a configuration file
+    lists). ``logit_scale`` multiplies the tied head's logits. What this
+    module does not implement is refused, never ignored; the keys
+    ``prefix_dense_*`` are read by no layer at ``first_k_dense_replace`` 0."""
+    rope = d.get("rope_parameters") or {}
+    for key, want in (
+        ("attention_bias", False), ("use_qk_norm", False), ("use_parallel_block", True), ("use_parallel_embedding", False),
+        ("use_gated_activation", True), ("expert_selection_fn", "sigmoid"), ("position_embedding_type", "rope_gptj"),
+        ("rotary_pct", 1), ("shared_expert_combination_strategy", "average"), ("tie_word_embeddings", True),
+    ):
+        if d.get(key, want) != want:
+            raise ValueError(f"cohere2_moe with {key} {d[key]!r} is not implemented (only {want!r})")
+    if int(d.get("first_k_dense_replace") or 0):
+        raise ValueError("cohere2_moe with leading dense layers (first_k_dense_replace > 0) is not implemented")
+    if d.get("rope_scaling") is not None or rope.get("rope_type", "default") != "default":
+        raise ValueError("cohere2_moe with a scaled rotary embedding is not implemented")
+    if set(d["layer_types"]) - set(_COHERE_KINDS):
+        raise ValueError(f"cohere2_moe layer_types {sorted(set(d['layer_types']))}: only {sorted(_COHERE_KINDS)}")
+    kinds = tuple(_COHERE_KINDS[t] for t in d["layer_types"])
+    if "swa" in kinds and not d.get("sliding_window"):
+        raise ValueError("cohere2_moe with sliding_attention layers needs sliding_window")
+    experts, shared = int(d.get("num_experts") or 0), int(d.get("num_shared_experts") or 0)
+    if experts < 1:
+        raise ValueError("cohere2_moe needs num_experts: every layer is an expert layer")
+    width = int(d.get("moe_intermediate_size") or d["intermediate_size"])  # no key of its own: the catalog's reading
+    return dict(
+        intermediate_size=d["intermediate_size"],
+        layer_types=kinds,
+        rms_norm_eps=float(d.get("layer_norm_eps", 1e-5)),
+        norm_kind="layer",
+        norm_bias=False,
+        block_form="parallel",
+        rope_theta=float(d.get("rope_theta") or rope.get("rope_theta") or 10000.0),
+        rope_kinds=("swa",),
+        rope_interleave=True,
+        sliding_window=int(d.get("sliding_window") or 0),
+        logits_scaling=1.0 / float(d.get("logit_scale", 1.0)),
+        ffn_types=("moe",) * len(kinds),
+        fused_gate_up=False,
+        num_experts=experts,
+        num_experts_per_tok=int(d.get("num_experts_per_tok", 1)),
+        moe_intermediate_size=width,
+        moe_shared_intermediate_size=shared * width,
+        moe_shared_mean_of=max(1, shared),
+        norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        router_score="sigmoid",
+        router_bias=False,
+    )
+
+
 _FIELDS = {
+    "cohere2_moe": _cohere2_moe_fields,
     "solar_open2": _solar_open2_fields,
     "phi4flash": _phi4flash_fields,
     "granitemoehybrid": _granite_fields,
@@ -1081,6 +1229,13 @@ def prefill_row_bytes(cfg: HybridConfig, bucket: int) -> int:
     model then goes through alone: ONE program a bucket, and the scan's
     temporaries once."""
     stream = qwen.prefill_row_bytes(cfg, bucket)
+    if cfg.block_form == "parallel":
+        # ONE norm's output feeds both sublayers, so it lives through the layer beside the stream; the queries, their
+        # rotated copy and the attention's output are each [bucket, heads * head_dim] (0.5 GB at 16k tokens of 128
+        # heads), and a block of ``ffn_block_rows`` rows holds the shared experts' gate, up and product
+        size = jnp.dtype(cfg.jax_dtype).itemsize
+        rows = ffn_block_rows(cfg, "moe", bucket)
+        return 2 * stream + 3 * bucket * cfg.q_dim * size + 3 * rows * cfg.moe_shared_intermediate_size * size
     if cfg.count("kda"):
         # a block of the prompt (``kda_prefill``): q, k, v, the log decay and its running sum in float32 [block,
         # H, K], four masked copies of the keys a chunk's sub-blocks meet, and the chunk matrices beside them
@@ -1109,9 +1264,10 @@ def _layer_shapes(cfg: HybridConfig) -> dict[str, dict[str, tuple[int, ...]]]:
     has, in the order they first appear."""
     D, F = cfg.hidden_size, cfg.intermediate_size
     H, C = cfg.mamba_n_heads, cfg.conv_dim
-    norms = {"input_norm": (D,), "post_norm": (D,)}
-    if cfg.norm_kind == "layer":
-        norms.update(input_norm_bias=(D,), post_norm_bias=(D,))
+    # a parallel block has ONE norm: what both of its sublayers read
+    norms = {"input_norm": (D,)} if cfg.block_form == "parallel" else {"input_norm": (D,), "post_norm": (D,)}
+    if cfg.norm_kind == "layer" and cfg.norm_bias:
+        norms.update({f"{n}_bias": (D,) for n in tuple(norms)})
     di = cfg.s6_d_inner
     # an attending layer's query and output side, and its key and value side (a ``cross`` layer has the first alone)
     bias = cfg.attn_bias
@@ -1131,7 +1287,7 @@ def _layer_shapes(cfg: HybridConfig) -> dict[str, dict[str, tuple[int, ...]]]:
             "D": (di,),
             "out_proj": (di, D),
         },
-        "swa": {**q_side, **kv_side},
+        "swa": {**q_side, **kv_side},  # differential pairs, or plain grouped queries: the same four matrices
         "cross": dict(q_side),
         "gmu": {"gmu_in": (D, di), "gmu_out": (di, D)},
         "mamba": {
@@ -1292,7 +1448,7 @@ def init_params(rng: jax.Array, cfg: HybridConfig, dtype=None) -> dict:
         "embed": dense((cfg.vocab_size, cfg.hidden_size)),
         "final_norm": jnp.ones((cfg.hidden_size,), dtype),
     }
-    if cfg.norm_kind == "layer":
+    if cfg.norm_kind == "layer" and cfg.norm_bias:
         params["final_norm_bias"] = jnp.zeros((cfg.hidden_size,), dtype)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = dense((cfg.vocab_size, cfg.hidden_size))
@@ -1324,7 +1480,7 @@ def param_partition_specs(cfg: HybridConfig, fsdp_axis: str | None = "fsdp") -> 
     experts over chips, is ROADMAP Reach A.7."""
     del fsdp_axis
     specs: dict[str, Any] = {"embed": P(), "final_norm": P()}
-    if cfg.norm_kind == "layer":
+    if cfg.norm_kind == "layer" and cfg.norm_bias:
         specs["final_norm_bias"] = P()
     if not cfg.tie_word_embeddings:
         specs["lm_head"] = P()
@@ -1510,7 +1666,28 @@ _HF_LAYER_MAPS["solar_open2"] = {
     "o_norm": ("self_attn.o_norm.weight", False),
     "o_proj": ("self_attn.o_proj.weight", True),
 }
+# ``cohere2_moe``: UNCHECKED against a checkpoint (no network, no ``config.json`` or weights of the family on this
+# machine, and the installed transformers has ``cohere2`` but no ``cohere2_moe``). The block's and the attention
+# layer's names are ``transformers``' ``Cohere2DecoderLayer`` / ``Cohere2Attention`` attributes (ONE
+# ``input_layernorm``, no second norm); the expert block's are the DeepSeek-V3 lineage's under ``mlp`` as ISSUE 51
+# reads the configuration's keys, with the FOUR shared experts as four blocks ``mlp.shared_experts.{s}.*`` (the leaf
+# holds them side by side: shared expert s is columns s * width .. of ``ws_gate`` / ``ws_up``, rows of ``ws_down``).
+_HF_LAYER_MAPS["cohere2_moe"] = {
+    "input_norm": ("input_layernorm.weight", False),
+    "wq": ("self_attn.q_proj.weight", True),
+    "wk": ("self_attn.k_proj.weight", True),
+    "wv": ("self_attn.v_proj.weight", True),
+    "wo": ("self_attn.o_proj.weight", True),
+    "w_router": ("mlp.gate.weight", True),
+    "we_gate": ("mlp.experts.{e}.gate_proj.weight", True),
+    "we_up": ("mlp.experts.{e}.up_proj.weight", True),
+    "we_down": ("mlp.experts.{e}.down_proj.weight", True),
+    "ws_gate": ("mlp.shared_experts.{s}.gate_proj.weight", True),
+    "ws_up": ("mlp.shared_experts.{s}.up_proj.weight", True),
+    "ws_down": ("mlp.shared_experts.{s}.down_proj.weight", True),
+}
 _HF_TOP = {
+    "cohere2_moe": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "solar_open2": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "deepseek_v3": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "glm_moe_dsa": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
@@ -1528,7 +1705,10 @@ def hf_name_map(cfg: HybridConfig) -> dict[str, tuple[str, bool]]:
     """Our param path -> (checkpoint name of ``cfg.model_type``, transpose).
     A stacked leaf maps as ``<stack>/<index within the stack>/<name>``, an
     expert's as ``.../<name>/<expert within the stack>`` (the checkpoint's
-    expert ``cfg.expert_first`` + that); the checkpoint numbers layers in the
+    expert ``cfg.expert_first`` + that), and where the always-active block
+    is several shared experts side by side each of them as
+    ``.../<name>/s<shared expert>`` (``models/hf.py`` lays those side by
+    side along the leaf's wide axis); the checkpoint numbers layers in the
     order of ``layer_types``."""
     out: dict[str, tuple[str, bool]] = {k: (v, False) for k, v in _HF_TOP[cfg.model_type].items()}
     if not cfg.tie_word_embeddings:
@@ -1545,6 +1725,9 @@ def hf_name_map(cfg: HybridConfig) -> dict[str, tuple[str, bool]]:
                 for e in range(cfg.num_experts):
                     ckpt = suffix.format(e=cfg.expert_first + e)
                     out[f"{stack}/{n}/{name}/{e}"] = (f"model.layers.{i}.{ckpt}", transpose)
+            elif "{s}" in suffix:  # the shared experts side by side in one leaf: block s of its columns (``ws_down``: rows)
+                for sh in range(cfg.moe_shared_mean_of):
+                    out[f"{stack}/{n}/{name}/s{sh}"] = (f"model.layers.{i}.{suffix.format(s=sh)}", transpose)
             else:
                 out[f"{stack}/{n}/{name}"] = (f"model.layers.{i}.{suffix}", transpose)
         seen[stack] = n + 1
@@ -2821,17 +3004,35 @@ def diff_attend(cfg: HybridConfig, q, k, v, allowed):
     return out.reshape(T, cfg.num_heads, 2 * hd)
 
 
+def gqa_attend(cfg: HybridConfig, q, k, v, allowed):
+    """Plain grouped-query softmax attention for one sequence, dense: q [T,
+    H, hd], k and v [U, KH, hd], ``allowed`` [T, U] bool. Returns [T, H *
+    hd] in v's type. A query that is allowed no key gets the mean of the
+    values, never a NaN."""
+    T, G = q.shape[0], cfg.num_heads // cfg.num_kv_heads
+    qg = q.reshape(T, cfg.num_kv_heads, G, q.shape[-1])
+    logits = jnp.einsum("tkgd,ukd->kgtu", qg, k).astype(jnp.float32) * cfg.sm_scale
+    probs = jax.nn.softmax(jnp.where(allowed[None, None], logits, -1e30), axis=-1).astype(v.dtype)
+    return jnp.einsum("kgtu,ukd->tkgd", probs, v).reshape(T, -1)
+
+
 def swa_attend(cfg: HybridConfig, q, k, v):
-    """Window attention over whole prompts, O(L x window): q [A, L, H, hd],
-    k and v [A, L, KH / 2, 2 hd]; a query at t attends to keys t - window + 1
-    .. t. Blocks of ``window`` queries each meet the block before and their
-    own, one block at a time. Returns [A, L, H, 2 hd] float32."""
+    """Window attention over whole prompts, O(L x window), the XLA form: a
+    query at t attends to keys t - window + 1 .. t. Blocks of ``window``
+    queries (of the prompt, where that is shorter) each meet the block before
+    and their own, one block at a time: [H, window, 2 window] float32 logits
+    a block. Differential attention: q [A, L, H, hd], k and v [A, L, KH / 2,
+    2 hd], returns [A, L, H, 2 hd] float32 (``diff_attend``); plain grouped
+    queries: k and v [A, L, KH, hd], returns [A, L, H * hd]
+    (``gqa_attend``). The CPU path, and the oracle of the launch that serves
+    a window whose block of logits does not fit (``swa_prefill_launch``)."""
     A, L = q.shape[:2]
-    B = cfg.sliding_window
+    B = min(cfg.sliding_window, L)
     pad = (-L) % B
     if pad:
         q, k, v = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)) for t in (q, k, v))
     nb = (L + pad) // B
+    attend = diff_attend if cfg.diff_attn else gqa_attend
 
     def blocks(t):  # [A, L, ...] -> [A * nb, B, ...]
         return t.reshape(A * nb, B, *t.shape[2:])
@@ -2842,15 +3043,49 @@ def swa_attend(cfg: HybridConfig, q, k, v):
         return jnp.concatenate([before, tb], axis=2).reshape(A * nb, 2 * B, *t.shape[2:])
 
     t_at, u_at = jnp.arange(B)[:, None], jnp.arange(2 * B)[None, :] - B  # key u of the pair stands at u_at relative to the block's first query
-    band = (u_at <= t_at) & (t_at - u_at < B)
+    band = (u_at <= t_at) & (t_at - u_at < cfg.sliding_window)
 
     def one(args):
         qi, ki, vi, first = args
-        return diff_attend(cfg, qi, ki, vi, band & (~first | (u_at >= 0)))
+        return attend(cfg, qi, ki, vi, band & (~first | (u_at >= 0)))
 
     first = (jnp.arange(A * nb) % nb) == 0
     out = jax.lax.map(one, (blocks(q), with_before(k), with_before(v), first))
     return out.reshape(A, L + pad, *out.shape[2:])[:, :L]
+
+
+def swa_prefill_launch(cfg: HybridConfig, L: int) -> bool:
+    """Whether the prompt pass of bucket ``L`` attends inside its window
+    layers' band under ``ops/window_prefill_attention.py`` on a TPU, from the
+    shapes alone (``gqa_prefill_launch``'s way): plain grouped queries whose
+    XLA block of logits ([H, window, 2 window] float32, the prompt's length
+    for the window where that is shorter) passes
+    ``_PREFILL_GQA_LOGIT_BYTES`` and whose shape the kernel serves (heads of
+    128 lanes unpadded, a row of whole 256-token tiles). Below that
+    ``swa_attend`` stays: the CPU path, and the launch's oracle."""
+    B = min(cfg.sliding_window, L)
+    return (
+        bool(cfg.count("swa"))
+        and jax.default_backend() == "tpu"
+        and not cfg.diff_attn
+        and cfg.head_dim_ == cfg.kv_head_dim == 128
+        and L % 256 == 0
+        and 8 * cfg.num_heads * B * B > _PREFILL_GQA_LOGIT_BYTES
+    )
+
+
+def swa_flash_attend(cfg: HybridConfig, q, k, v, interpret: bool = False, edge: int | None = None):
+    """``swa_attend`` for plain grouped queries under the banded launch: q
+    [A, L, H, hd], k and v [A, L, KH, hd] -> [A, L, H * hd]. The queries go
+    as the projection left them; K and V a head's tokens contiguous (32 MB
+    each at 16k tokens of 8 heads)."""
+    from areal_tpu.ops.window_prefill_attention import swa_prefill_flash
+
+    A, L, H, hd = q.shape
+    kt, vt = (jnp.swapaxes(t, 1, 2) for t in (k, v))
+    return swa_prefill_flash(
+        q.reshape(A, L, H * hd), kt, vt, heads=H, window=cfg.sliding_window, sm_scale=cfg.sm_scale, edge=edge, interpret=interpret
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -2860,13 +3095,15 @@ def swa_attend(cfg: HybridConfig, q, k, v):
 
 def _norm(cfg: HybridConfig, x, w, b=None):
     """The model's norm: RMSNorm, or (``norm_kind`` ``layer``) LayerNorm with
-    weight ``w`` and bias ``b``, the statistics in float32."""
+    weight ``w`` and, where the configuration has one (``norm_bias``), bias
+    ``b``, the statistics in float32."""
     if cfg.norm_kind != "layer":
         return _rms_norm(x, w, cfg.rms_norm_eps)
     x32 = x.astype(jnp.float32)
     mu = jnp.mean(x32, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(x32 - mu), axis=-1, keepdims=True)
-    return ((x32 - mu) * jax.lax.rsqrt(var + cfg.rms_norm_eps)).astype(x.dtype) * w + b
+    out = ((x32 - mu) * jax.lax.rsqrt(var + cfg.rms_norm_eps)).astype(x.dtype) * w
+    return out if b is None else out + b
 
 
 def _final_norm(params: dict, cfg: HybridConfig, x):
@@ -2905,23 +3142,26 @@ def ffn_block_rows(cfg: HybridConfig, ffn: str, rows: int) -> int:
     return block if rows % block == 0 else rows
 
 
-def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None):
+def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None, u=None):
     """x + the layer's feed-forward block, its RMSNorm where the block has
     it (on the input, or on the output); for an expert block also the rows
     of ``live`` (default: all) each expert got, [E] int32. Where the expert
     leaves come as their stacks (``_scan_layers`` ``whole``), the touched
     experts alone are read. A long prompt's rows go through in blocks
-    (``ffn_block_rows``): the block is row-wise, so the result is the same."""
+    (``ffn_block_rows``): the block is row-wise, so the result is the same.
+    ``u`` is a PARALLEL block's one normed input: the FFN reads it and no
+    norm of its own, and ``x`` (the residual stream with the mixer's output
+    already on it) takes the FFN's output in the block's one sum."""
     rm = cfg.residual_multiplier
     n_rows = x.size // x.shape[-1]
     block = ffn_block_rows(cfg, ffn, n_rows)
     if block < n_rows:
         lv = jnp.ones((n_rows,), bool) if live is None else live.reshape(-1)
-        out, load = jax.lax.map(
-            lambda a: _ffn(cfg, ffn, layer, a[0], a[1]), (x.reshape(-1, block, x.shape[-1]), lv.reshape(-1, block))
-        )
+        rows = (x.reshape(-1, block, x.shape[-1]), lv.reshape(-1, block)) + (() if u is None else (u.reshape(-1, block, u.shape[-1]),))
+        out, load = jax.lax.map(lambda a: _ffn(cfg, ffn, layer, *a), rows)
         return out.reshape(x.shape), (None if load is None else load.sum(0))
     if ffn == "dense":
+        assert u is None, "a parallel block with a dense FFN is not implemented"
         with jax.named_scope("mlp"):
             h = _norm_in(cfg, layer, "post_norm", x)
             if cfg.fused_gate_up:
@@ -2930,7 +3170,7 @@ def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None):
                 g, u = _proj(cfg, layer, "w_gate", h), _proj(cfg, layer, "w_up", h)
             return x + rm * _norm_out(cfg, layer, "post_norm", _proj(cfg, layer, "w_down", jax.nn.silu(g) * u)), None
     with jax.named_scope("moe_router"):
-        h = _norm_in(cfg, layer, "post_norm", x)
+        h = _norm_in(cfg, layer, "post_norm", x) if u is None else u
     rows = h.reshape(-1, h.shape[-1])
     out, _, _, load = moe.expert_ffn(
         rows, layer, cfg, live=None if live is None else live.reshape(-1), e0=cfg.expert_first
@@ -2938,12 +3178,28 @@ def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None):
     if "ws_gate" in layer:  # the always-active block: every row, gate 1
         with jax.named_scope(MOE_SHARED_SCOPE):
             shared = jax.nn.silu(_proj(cfg, layer, "ws_gate", rows)) * _proj(cfg, layer, "ws_up", rows)
-            out = out + _proj(cfg, layer, "ws_down", shared).astype(out.dtype)
+            shared = _proj(cfg, layer, "ws_down", shared).astype(out.dtype)
+            # several shared experts side by side: the down projection summed over all of their columns, their MEAN
+            out = out + (shared if cfg.moe_shared_mean_of == 1 else shared * (1.0 / cfg.moe_shared_mean_of))
+    if u is not None:
+        with jax.named_scope("block_sum"):  # x + Mixer(u) came in as ``x``: the block's one sum
+            return x + rm * out.reshape(x.shape).astype(x.dtype), load
     with jax.named_scope("moe_combine"):
         return x + rm * _norm_out(cfg, layer, "post_norm", out.reshape(x.shape).astype(x.dtype)), load
 
 
 # the scope a mixer's norm counts under (its projections')
+def _block_ffn(cfg: HybridConfig, ffn: str, layer: dict, x, out, h, live):
+    """A block's second half: ``x`` the block's input, ``out`` its mixer's
+    output, ``h`` what the mixer read. Serial: ``h' = x + out``, ``h' +
+    FFN(norm(h'))``. Parallel: ``x + out + FFN(h)``, ``h`` the block's ONE
+    normed input."""
+    rm = cfg.residual_multiplier
+    if cfg.block_form == "parallel":
+        return _ffn(cfg, ffn, layer, x + rm * out, live, u=h)
+    return _ffn(cfg, ffn, layer, x + rm * out, live)
+
+
 _MIXER_SCOPE = {
     "mamba": "ssm_proj", "gdn": "gdn_proj", "conv": "conv_proj", "attention": "attn_proj", "mla": "mla_proj",
     "s6": "ssm_proj", "swa": "attn_proj", "cross": "attn_proj", "gmu": "gmu", "kda": "kda_proj",
@@ -3045,16 +3301,38 @@ def _embed(params: dict, cfg: HybridConfig, ids):
         return x
 
 
-def _qkv(cfg: HybridConfig, layer: dict, h, positions):
+def _rotate_qk(cfg: HybridConfig, q, k, positions):
+    """The rotary embedding of q [..., H, hd] and k [..., KH, hd] at
+    ``positions`` [...]: halves, or with ``rope_interleave`` channels (2i,
+    2i+1), which leaves both as [evens | odds] (``_pairs_to_halves``): the
+    same permutation on both sides of every q . k, so a cached key is read as
+    it lies."""
+    if cfg.rope_interleave:
+        q, k = _pairs_to_halves(q), _pairs_to_halves(k)
+    return _rope(q, positions, cfg.rope_theta), _rope(k, positions, cfg.rope_theta)
+
+
+def _qkv(cfg: HybridConfig, layer: dict, h, positions, rotate: bool = True, fence: bool = False):
     """q [..., H, hd], k and v [..., KH, hd] of the layer's input h [..., D]
     at ``positions`` [...]: RMSNorm of q and k (over each head, or over the
     whole projection), then the rotary embedding, where the configuration
-    has them."""
+    has them for an ``attention`` layer (``rotates``; a window layer passes
+    ``rotate`` False and turns its own under a scope of their own,
+    ``attn_rope``). ``fence`` keeps each
+    projection ONE matmul whose output is split into heads afterwards: left
+    to itself XLA:TPU folds the split (and a rotary permutation behind it)
+    into ``W_q``, wants the weight in another layout and copies the whole
+    layer STACK of it, once a program (0.54 GB at four layers of [4096,
+    16384]: compiled for a described v5e, tests/test_tpu_compile.py;
+    ``_mla_in`` has the same). A parallel block's layers pass it; the serial
+    families' programs stay as their cells measured them."""
     lead = h.shape[:-1]
     whole = cfg.qk_norm and cfg.qk_norm_over == "whole"
 
     def heads(w, norm, n):  # a projection, normed over its whole width where the configuration says so, split into heads
         x = _proj(cfg, layer, w, h)
+        if fence:
+            x = jax.lax.optimization_barrier(x)
         if whole and norm:
             x = _rms_norm(x, layer[norm], cfg.rms_norm_eps)
         return x.reshape(*lead, n, cfg.head_dim_)
@@ -3065,9 +3343,8 @@ def _qkv(cfg: HybridConfig, layer: dict, h, positions):
     if cfg.qk_norm and cfg.qk_norm_over == "head":
         q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
         k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
-    if cfg.rope_theta is not None:
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+    if rotate and cfg.rotates("attention"):
+        q, k = _rotate_qk(cfg, q, k, positions)
     return q, k, v
 
 
@@ -3209,10 +3486,10 @@ def forward_prefill(
     # a latent layer makes its own masks a block at a time: no [A, 1, L, L] for a 16k prompt
     # the plain ``attention`` mixer under the flash launch where [H, L, L] would not fit: the launch masks by ``seg``
     gqa_launch = bool(cfg.count("attention")) and gqa_prefill_launch(cfg, L)
+    swa_launch = swa_prefill_launch(cfg, L)  # the window layers' band under its launch where a block's logits would not fit
     mask = None if latent or cfg.diff_attn or gqa_launch else qwen._attention_mask(seg)  # (differential attention masks by position, a row at a time)
     positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (A, L))
     live = seg.astype(bool)
-    rm = cfg.residual_multiplier
     # a decoder-hybrid-decoder: the one layer whose K and V the cross layers read, and where the rows narrow to each
     # prompt's last token (``tail``)
     shared = cfg.layers_of("attention")[0] if cfg.count("cross") else None
@@ -3255,6 +3532,21 @@ def forward_prefill(
             with jax.named_scope("gmu"):
                 h = _norm_in(cfg, layer, "input_norm", x)
             out = gmu_mix(cfg, layer, h, arr["gmu_m"])
+        elif kind == "swa" and not cfg.diff_attn:
+            # plain grouped queries over the band; the keys go into the ring ALREADY rotated, so a read takes them as they lie
+            with jax.named_scope("attn_proj"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+                q, k, v = _qkv(cfg, layer, h, positions, rotate=False, fence=True)
+            if cfg.rotates("swa"):
+                with jax.named_scope("attn_rope"):
+                    q, k = _rotate_qk(cfg, q, k, positions)
+            if "ring_k" in arr:
+                with jax.named_scope("kv_write"):
+                    arr = write(arr, j, {"ring_k": _lane_pad(cfg, k), "ring_v": _lane_pad(cfg, v)})
+            with jax.named_scope("attn_window"):
+                attn = (swa_flash_attend if swa_launch else swa_attend)(cfg, q, k, v)
+            with jax.named_scope("attn_proj"):
+                out = _proj(cfg, layer, "wo", attn)
         elif kind == "swa":
             with jax.named_scope("attn_proj"):
                 h = _norm_in(cfg, layer, "input_norm", x)
@@ -3329,7 +3621,7 @@ def forward_prefill(
         else:
             with jax.named_scope("attn_proj"):
                 h = _norm_in(cfg, layer, "input_norm", x)
-                q, k, v = _qkv(cfg, layer, h, positions)
+                q, k, v = _qkv(cfg, layer, h, positions, fence=cfg.block_form == "parallel")
                 ks = ks.at[j].set(_lane_pad(cfg, k))
                 vs = vs.at[j].set(_lane_pad(cfg, v))
             with jax.named_scope("attn"):
@@ -3339,7 +3631,7 @@ def forward_prefill(
                 out = _proj(cfg, layer, "wo", attn)
         with jax.named_scope(_MIXER_SCOPE[kind]):
             out = _norm_out(cfg, layer, "input_norm", out)
-        x, _ = _ffn(cfg, ffn, layer, x + rm * out, None if narrow else live)
+        x, _ = _block_ffn(cfg, ffn, layer, x, out, h, None if narrow else live)
         return x, ks, vs, arr
 
     x = _embed(params, cfg, input_ids)
@@ -3409,7 +3701,11 @@ def prefill_into_cache(
         *_, pages = forward_prefill(params, cfg, ids, seg, sink=({n: cache[n] for n in cfg.kv_pools}, put))
         return {**cache, **pages}
     state = {k: cache[k] for k in paged_kv.STATE_LEAVES + paged_kv.RING_LEAVES if k in cache}
-    n_slots = next((v.shape[1] for k, v in state.items() if k in paged_kv.STATE_LEAVES), 0)
+    # a state leaf is [layers, slots, ...], a ring [layers, heads, slots + 1, ...] (the block past the last slot takes
+    # a padding row's): a model may hold either alone
+    n_slots = next(
+        (v.shape[1] if k in paged_kv.STATE_LEAVES else v.shape[2] - 1 for k, v in state.items()), 0
+    )
 
     def write(arr, j, new):
         # one dynamic-update-slice a row, a padding row rewriting what its
@@ -3541,6 +3837,26 @@ def serving_limits(cfg: HybridConfig) -> dict[str, str]:
                 "state are not sharded"
             ),
         }
+    if cfg.count("swa"):
+        # the window layers' rings are the slot's ONLY tenant beside its pages: no state to roll back, but a cached
+        # prefix of the full layers' pages says nothing of the last ``sliding_window`` keys a ring must hold behind it
+        return {
+            "reason": "window_rings",
+            "prefix_cache": (
+                "the model's window layers keep their keys in a ring of the slot's own, and a cached page prefix of "
+                "the full layers carries no ring (ROADMAP Reach A.6: a ring rebuilt from a prefix's last window)"
+            ),
+            "speculative": (
+                "speculative decoding cannot serve a model with window rings: a rejected draft's keys have already "
+                "overwritten the ring positions of the tokens the window still needs"
+            ),
+            "int8_weights": (
+                "int8 weight quantization is not implemented for the hybrid family's mixers and experts; "
+                "serve this model with quantization='none'"
+            ),
+            "int8_pages": "quantized pages are not implemented for the window layers' rings; serve this model with kv_quantization='none'",
+            "sharded": "a model with window rings serves on one chip a replica: its mixer and its rings are not sharded",
+        }
     return {
         "reason": "latent_pages",
         "prefix_cache": f"a hit on cached latent pages {_NO_LATENT_SUFFIX}",
@@ -3592,7 +3908,7 @@ def forward_decode_paged(
     write_page = page_table[slot, positions // page_size]
     write_off = positions % page_size
     kv_quant = "k_scale" in cache
-    not_pages = paged_kv.STATE_LEAVES + COUNT_LEAVES
+    not_pages = paged_kv.STATE_LEAVES + paged_kv.RING_LEAVES + COUNT_LEAVES
     fetch = None  # what the attention launches' work list fetches, where it names a block several slots hold once
     if use_kernel:
         from areal_tpu.ops.paged_attention_q8 import DecodeItems, decode_schedule, live_order, paged_attention_stacked, shared_decode_schedule
@@ -3617,7 +3933,7 @@ def forward_decode_paged(
             kv_live = live_order(page_table[:, 0] != 0)  # the KV writer's: qwen.forward_decode_paged
     else:
         live = kv_live = kernel = None
-    if cfg.diff_attn:
+    if cfg.diff_attn or cfg.count("swa"):
         # the window layers' rings (paged_kv.RING_LEAVES): token t at ring position t % window, min(t + 1, window) of
         # its positions valid, a slot that is not live neither written (its row goes to the block past the last slot)
         # nor read; the work lists are the same for every window layer, so they are made here, once a step
@@ -3660,7 +3976,6 @@ def forward_decode_paged(
             if leaf in cache:
                 with jax.named_scope(scope):
                     cache = {**cache, leaf: cache[leaf] + n}
-    rm = cfg.residual_multiplier
     # the expert matmuls read the touched experts only where a full batch gives an expert a handful of rows
     # (a Pallas launch over the expert stacks; off a TPU, XLA's form whatever the shapes: moe.takes_touched_form)
     touched_form = (
@@ -3686,6 +4001,30 @@ def forward_decode_paged(
             with jax.named_scope("gmu"):
                 h = _norm_in(cfg, layer, "input_norm", x)
             out = gmu_mix(cfg, layer, h, c["gmu_m"])
+        elif kind == "swa" and not cfg.diff_attn:
+            # plain grouped queries over the slot's ring, whose keys lie there rotated: read as they lie
+            with jax.named_scope("attn_proj"):
+                h = _norm_in(cfg, layer, "input_norm", x)
+                q, k, v = _qkv(cfg, layer, h, positions, rotate=False, fence=True)
+            if cfg.rotates("swa"):
+                with jax.named_scope("attn_rope"):
+                    q, k = _rotate_qk(cfg, q, k, positions)
+            q, k, v = (_lane_pad(cfg, t) for t in (q, k, v))
+            with jax.named_scope("kv_write"):
+                pools = {n: paged_kv.ring_pool(c[n]) for n in paged_kv.RING_LEAVES}
+                pools = paged_kv.write_decode_rows(pools, j, k, v, ring_page, ring_off, ring_live, pools=paged_kv.RING_LEAVES)
+                c.update({n: pools[n].reshape(c[n].shape) for n in pools})
+            with jax.named_scope("attn_window"):
+                if ring_kernel is not None:
+                    attn = paged_attention_stacked(q, pools["ring_k"], pools["ring_v"], j, ring_len, ring_tbl, sm_scale=cfg.sm_scale, **ring_kernel)
+                else:
+                    k_j, v_j = (jax.lax.dynamic_index_in_dim(pools[n], j, 0, keepdims=False) for n in paged_kv.RING_LEAVES)
+                    attn = paged_kv.paged_attention_xla(q, k_j, v_j, ring_len, ring_tbl, sm_scale=cfg.sm_scale)
+                # ... and ``W_o`` as [heads, head_dim, D] where the read's [S, heads, head_dim] meets it unflattened: a copy of
+                # the layer's 134 MB every step
+                attn = jax.lax.optimization_barrier(attn[..., : cfg.head_dim_].reshape(S, H * cfg.head_dim_).astype(x.dtype))
+            with jax.named_scope("attn_proj"):
+                out = _proj(cfg, layer, "wo", attn)
         elif kind in ("swa", "cross") or (kind == "attention" and cfg.diff_attn):
             with jax.named_scope("attn_proj"):
                 h = _norm_in(cfg, layer, "input_norm", x)
@@ -3772,7 +4111,7 @@ def forward_decode_paged(
         else:
             with jax.named_scope("attn_proj"):
                 h = _norm_in(cfg, layer, "input_norm", x)
-                q, k, v = (_lane_pad(cfg, t) for t in _qkv(cfg, layer, h, positions))
+                q, k, v = (_lane_pad(cfg, t) for t in _qkv(cfg, layer, h, positions, fence=cfg.block_form == "parallel"))
             with jax.named_scope("kv_write"):
                 c = paged_kv.write_decode_rows(c, j, k, v, write_page, write_off, kv_live)
             with jax.named_scope("attn"):
@@ -3799,7 +4138,7 @@ def forward_decode_paged(
                 out = _proj(cfg, layer, "wo", attn)
         with jax.named_scope(_MIXER_SCOPE[kind]):
             out = _norm_out(cfg, layer, "input_norm", out)
-        x, load = _ffn(cfg, ffn, layer, x + rm * out, active)
+        x, load = _block_ffn(cfg, ffn, layer, x, out, h, active)
         if load is not None and "moe_load" in c:
             with jax.named_scope("moe_router"):
                 c["moe_load"] = c["moe_load"].at[f].add(load)

@@ -133,6 +133,7 @@ class ModelConfig:
         return {"k": (self.num_kv_heads, self.kv_head_dim), "v": (self.num_kv_heads, self.kv_head_dim)}
 
     has_recurrent_state = False
+    has_slot_tenant = False  # nothing of a slot's but its pages: no state, no window ring (models/hybrid.py)
     # expert-load counts a decode chunk would hand back (models/hybrid.py): none
     moe_count_shapes: ClassVar[dict] = {}
     # what a decode chunk hands back beside its tokens: the blocks of pages its attention launches listed and fetched

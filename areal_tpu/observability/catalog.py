@@ -179,13 +179,15 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
         # (inference/paged_kv.py STATE_LEAVES); all zero for other models
         state_copies=r.counter(
             "areal_decode_state_copies_total",
-            "Recurrent states copied on the device from a primary's slot to "
-            "a sibling admitted with the same prompt.",
+            "Recurrent states (and window rings, where a model has them) "
+            "copied on the device from a primary's slot to a sibling "
+            "admitted with the same prompt.",
         ),
         state_prefills=r.counter(
             "areal_decode_state_prefills_total",
-            "Recurrent states rebuilt by prefill for a request seen before "
-            "(after a preemption, an evicted or a dropped parking).",
+            "Recurrent states (and window rings) rebuilt by prefill for a "
+            "request seen before (after a preemption, an evicted or a "
+            "dropped parking).",
         ),
         state_bytes=r.gauge(
             "areal_decode_state_bytes",
@@ -229,6 +231,13 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "(token, layer) K and V rows read from the window layers' rings "
             "by decode steps: min(cached tokens, sliding_window) a live slot "
             "x window layers.",
+        ),
+        window_prompt_pairs=r.counter(
+            "areal_decode_window_prompt_pairs_total",
+            "(query, key) pairs inside the band that the window layers' "
+            "prompt passes attended: sum over a prompt's tokens t of min(t + "
+            "1, sliding_window), x window layers; from the rows' lengths at "
+            "the prefill, whatever computed the product.",
         ),
         # every model with K and V pages under the page table; counted on the
         # device inside the decode chunk, once a step (the work list is the

@@ -412,6 +412,56 @@ def _cases_paged_index(compiled: bool = False) -> Iterator[dict]:
 
 
 # ---------------------------------------------------------------------------
+# the prompt pass's window attention (ops/window_prefill_attention.py): grouped
+# queries inside the band ``0 <= t - s < window`` under ONE launch whose grid
+# is the band, against the plain mask over the full causal softmax in float32
+# ---------------------------------------------------------------------------
+
+
+@register_kernel("swa_prefill_flash")
+def _cases_swa_prefill(compiled: bool = False) -> Iterator[dict]:
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops.window_prefill_attention import swa_prefill_flash
+
+    def case(label, A, L, H, KH, hd, window, edge, dtype, seed=21):
+        scale = hd**-0.5
+
+        def build():
+            return {"q": _normal(seed, (A, L, H * hd), dtype), "k": _normal(seed + 1, (A, KH, L, hd), dtype), "v": _normal(seed + 2, (A, KH, L, hd), dtype)}
+
+        def kernel(inp):
+            return swa_prefill_flash(inp["q"], inp["k"], inp["v"], heads=H, window=window, sm_scale=scale, edge=edge, interpret=not compiled)
+
+        def reference(inp):
+            q = inp["q"].astype(jnp.float32).reshape(A, L, KH, H // KH, hd)
+            k, v = inp["k"].astype(jnp.float32), inp["v"].astype(jnp.float32)
+            behind = jnp.arange(L)[:, None] - jnp.arange(L)[None, :]
+            seen = (behind >= 0) & (behind < window)
+
+            def head(args):  # one row and KV head at a time: [group, L, L] logits
+                qh, kh, vh = args
+                p = jax.nn.softmax(jnp.where(seen[None], jnp.einsum("tgd,sd->gts", qh, kh) * scale, -1e30), axis=-1)
+                return jnp.einsum("gts,sd->tgd", p, vh)
+
+            rows = (jnp.moveaxis(q, 2, 1).reshape(A * KH, L, H // KH, hd), k.reshape(A * KH, L, hd), v.reshape(A * KH, L, hd))
+            out = jax.lax.map(head, rows).reshape(A, KH, L, H // KH, hd)
+            return jnp.moveaxis(out, 1, 2).reshape(A, L, H * hd)
+
+        return {"case": label, "build": build, "kernel": kernel, "reference": reference, "tol": 1e-5 if dtype == jnp.float32 else CHIP_TOL}
+
+    if compiled:  # the cell's launch: a query group of 16 at heads of 128, a window of 4,096 under tiles of 1,024 (five a query tile) and of 512
+        yield case("32-heads-over-2-window-4096-8192", 1, 8192, 32, 2, 128, 4096, 1024, jnp.bfloat16)
+        yield case("32-heads-over-2-window-4096-4096-tiles-512", 1, 4096, 32, 2, 128, 4096, 512, jnp.bfloat16)
+        yield case("16-heads-over-1-prompt-under-the-window-2048", 1, 2048, 16, 1, 128, 4096, 1024, jnp.bfloat16)
+        return
+    yield case("group-4-window-16-tiles-8-f32", 2, 64, 8, 2, 16, 16, 8, jnp.float32)
+    yield case("group-4-window-20-tiles-8-f32", 1, 64, 4, 1, 16, 20, 8, jnp.float32)
+    yield case("prompt-under-the-window-f32", 1, 32, 4, 2, 16, 64, 8, jnp.float32)
+
+
+# ---------------------------------------------------------------------------
 # the prompt pass's latent attention (ops/latent_prefill_attention.py): one
 # query block's launch over the key blocks up to its diagonal against the whole
 # [H, queries, L] softmax in float32, causal and under a selection handed over

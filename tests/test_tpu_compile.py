@@ -1183,3 +1183,99 @@ def test_solar_open2_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypa
     for leaf in ("f32[3,64,64,128,128]", "bf16[1,8,2816,128,128]"):
         assert not [ln for ln in text.splitlines() if " copy(" in ln and leaf in ln], leaf
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+def _cohere2_moe(chip, monkeypatch, kv_gb: float = 1.5):
+    """The ``cohere2_moe`` family as the cell serves it: every published
+    width, ONE whole period S S S F (the cell's four layers), 8 of 128
+    experts, 64 slots with their rings and the cell's pool of 3,072 pages
+    (sized for its one full layer)."""
+    import json
+
+    from areal_tpu import models
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "chip", "configs", "command-a-plus-ep16-d4.json")) as f:
+        cfg = json.load(f)
+    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "reduced_from", "assumed", "assumed_notes", "stands_for")}
+    hf.update({k: cfg["assumed"][k] for k in ("router_experts", "expert_first")}, dtype="bfloat16")
+    mcfg = models.config_from_hf_dict(hf)
+    n_pages = paged_kv.n_pages_for_budget(int(kv_gb * 2**30), 1, 8, PSZ, 128, 2, pools=mcfg.kv_pools)
+    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
+    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, n_pages, PSZ, slots=64))
+    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return mcfg, place(params), place(cache), n_pages
+
+
+def test_cohere2_moe_decode_steps_compile_for_v5e_at_the_whole_window(chip, monkeypatch):
+    """Two decode steps as the engine's chunk runs them at the cell's ONE
+    window (160 pages, 64 slots): the window layers read their rings (32
+    pages a slot) and the full layer the page table through
+    ``paged_decode_attn`` at a query group of 16, both written by
+    ``paged_kv_write`` in place (no copy of the 1.6 GB ring leaves, nor of a
+    layer of them), the expert matmuls as the touched-expert launch on the
+    stacks (64 rows x top-8 over 128: 4 assignments an expert; an expert of
+    [4096, 4096]), under 0.4 GB of temporaries."""
+    from areal_tpu.models import moe
+
+    mcfg, params, cache, n_pages = _cohere2_moe(chip, monkeypatch)
+    assert n_pages == 3072 and {k: v.shape for k, v in cache.items()} == {
+        "k": (1, 8, 3072, PSZ, 128), "v": (1, 8, 3072, PSZ, 128), "ring_k": (3, 8, 65, 32, PSZ, 128), "ring_v": (3, 8, 65, 32, PSZ, 128),
+    }
+    assert moe.takes_touched_form(64, 8, 128, 8)
+    from areal_tpu.models import hybrid
+
+    def two_steps(params, cache, pt, ids, pos, active):
+        cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
+
+        def step(c, _):
+            ids, pos, cache = c
+            h, cache = hybrid.forward_decode_paged(params, mcfg, ids, pos, cache, pt, page_size=PSZ, active=active, use_kernel=True)
+            return (jnp.argmax(hybrid.compute_logits(params, mcfg, h), -1).astype(jnp.int32), pos + 1, cache), None
+
+        (ids, _, cache), _ = jax.lax.scan(step, (ids, pos, cache), None, length=2)
+        return ids, cache
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(64, 160), i32(64), i32(64), chip((64,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    for name in ("paged_decode_attn", "paged_kv_write", "moe_touched_experts"):
+        assert name in text, name
+    for leaf in ("bf16[3,8,65,32,128,128]", "bf16[3,8,2080,128,128]", "bf16[8,2080,128,128]", "bf16[1,8,3072,128,128]"):
+        assert not [ln for ln in text.splitlines() if " copy(" in ln and leaf in ln], leaf
+    made = re.compile(r"= bf16\[(1,)?8,4096,4096\]\S* (?!parameter|get-tuple-element)")
+    assert not [ln for ln in text.splitlines() if made.search(ln)]  # no layer of an expert stack sliced out
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
+
+
+def test_cohere2_moe_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypatch):
+    """ONE prompt of 16,384 tokens, the cell's longest bucket: the window
+    layers inside their band under ``swa_prefill_flash`` (nothing of [128,
+    4096, 8192] float32: 17 GB a block of the XLA form; no query transposed),
+    the full layer under ``flash_fwd`` a KV head at a time, the expert rows
+    4,096 at a time (the shared block's gate and up of 16k rows x 16,384
+    columns are 1 GB), the rings written a head at a time in place. Under 3.2
+    GB of temporaries: with 6.25 GB of weights and 4.88 GB of cache, 14.3 of
+    15.75 GB."""
+    from areal_tpu.inference.decode_programs import _PREFILL_STREAM_BYTES
+    from areal_tpu.models import hybrid
+
+    mcfg, params, cache, _ = _cohere2_moe(chip, monkeypatch)
+    assert hybrid.swa_prefill_launch(mcfg, 16384) and hybrid.gqa_prefill_launch(mcfg, 16384) and hybrid.swa_prefill_launch(mcfg, 4096)
+    assert 2 * hybrid.prefill_row_bytes(mcfg, 256) > _PREFILL_STREAM_BYTES and hybrid.ffn_block_rows(mcfg, "moe", 16384) == 4096  # every prompt goes alone
+
+    def prefill(params, cache, ids, plens, flat_pages, slots):
+        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(1, 16384), i32(1), i32(16384 // PSZ), i32(1)).compile()
+    text = compiled.as_text()
+    assert "swa_prefill_flash" in text and "flash_fwd" in text
+    assert "f32[128,4096,8192]" not in text and "f32[128,16384,16384]" not in text and "16384,16384]" not in text.replace("bf16[1,16384,16384]", "").replace("bf16[16384,16384]", "")
+    assert "bf16[16384,65536]" not in text  # the shared block's gate and up over the whole prompt
+    for leaf in ("bf16[3,8,65,32,128,128]", "bf16[1,8,3072,128,128]"):
+        assert not [ln for ln in text.splitlines() if " copy(" in ln and leaf in ln], leaf
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
