@@ -53,7 +53,7 @@ from areal_tpu.parallel import mesh as mesh_lib
 from jax import set_mesh, shard_map
 from areal_tpu.utils import logging as alog
 from areal_tpu.utils import perf_tracer
-from areal_tpu.utils.compile_cache import FirstCall
+from areal_tpu.utils import compile_cache
 from areal_tpu.utils.data import TensorDict, seqlens_of
 from areal_tpu.utils.grid import Grid, pack_grid
 from areal_tpu.utils.data import round_up_to_bucket
@@ -242,13 +242,8 @@ class JaxTrainEngine(TrainEngine):
         # persistent compile cache, TPU-only, and the listener that counts
         # compilations and writes them into the span record
         # (utils/compile_cache.py)
-        from areal_tpu.utils.compile_cache import (
-            enable_persistent_cache,
-            install_compile_counters,
-        )
-
-        install_compile_counters()
-        enable_persistent_cache()
+        compile_cache.install_compile_counters()
+        compile_cache.enable_persistent_cache()
         self.mesh = kwargs.get("mesh") or mesh_lib.make_mesh(cfg.mesh)
         mcfg = self._model_config
         if mcfg is None:
@@ -951,6 +946,22 @@ class JaxTrainEngine(TrainEngine):
             outputs["moe_aux"] = moe_aux
         return outputs
 
+    def _first_call(self, key: tuple, named: tuple) -> compile_cache.FirstCall:
+        """The program just cached under ``key`` for its first call, with what
+        the program store names it by: everything the step programs close over
+        (the model's and the engine's configuration, the mesh, the schedule's
+        length, the logit temperature, the value head) and ``named``, the key with the loss
+        function or hook ITSELF where the key has its ``id()``: described by
+        module, name, closure and source (``compile_cache.describe``), or the
+        store leaves the program alone. Parameters, optimizer state and the
+        batch are arguments of every program."""
+        closed_over = (
+            self.model_cfg, self.config, self.mesh, self.ft_spec, self._logit_temperature, self.value_head, named,
+        )
+        return compile_cache.FirstCall(
+            self._fn_cache, key, compile_cache.default_store(), compile_cache.describe(closed_over)
+        )
+
     def _get_grad_fn(self, loss_fn: Callable, shape: tuple, kind: str = "packed"):
         key = ("grad", kind, shape, id(loss_fn))
         if key not in self._fn_cache:
@@ -971,7 +982,7 @@ class JaxTrainEngine(TrainEngine):
             # free as the forward consumes them instead of surviving the
             # whole fwd/bwd
             self._fn_cache[key] = jax.jit(compute, donate_argnums=(1,))
-            return FirstCall(self._fn_cache[key], key)
+            return self._first_call(key, ("grad", kind, shape, loss_fn))
         return self._fn_cache[key]
 
     def _get_forward_fn(self, shape: tuple, post_hook: Callable | None = None):
@@ -985,7 +996,7 @@ class JaxTrainEngine(TrainEngine):
                 return outputs
 
             self._fn_cache[key] = jax.jit(compute)
-            return FirstCall(self._fn_cache[key], key)
+            return self._first_call(key, ("fwd", shape, post_hook))
         return self._fn_cache[key]
 
     def _get_accum_fn(self):
@@ -998,7 +1009,7 @@ class JaxTrainEngine(TrainEngine):
             self._fn_cache[key] = jax.jit(
                 lambda a, b: jax.tree.map(jnp.add, a, b), donate_argnums=(0, 1)
             )
-            return FirstCall(self._fn_cache[key], key)
+            return self._first_call(key, key)
         return self._fn_cache[key]
 
     def _get_fused_step_fn(
@@ -1029,7 +1040,7 @@ class JaxTrainEngine(TrainEngine):
             # params/opt_state are rebound by every caller (DON001 contract)
             # and the batch is single-use — donate all three
             self._fn_cache[key] = jax.jit(step, donate_argnums=(0, 1, 2))
-            return FirstCall(self._fn_cache[key], key)
+            return self._first_call(key, ("fused", kind, shape, loss_fn))
         return self._fn_cache[key]
 
     def _get_apply_fn(self):
@@ -1049,7 +1060,7 @@ class JaxTrainEngine(TrainEngine):
             # third params-sized transient (DON burn-down; the HBM ledger's
             # step_transient component accounts for exactly this)
             self._fn_cache[key] = jax.jit(apply, donate_argnums=(0, 1, 2))
-            return FirstCall(self._fn_cache[key], key)
+            return self._first_call(key, key)
         return self._fn_cache[key]
 
     # -- tree training ----------------------------------------------------

@@ -79,6 +79,7 @@ from areal_tpu.observability import kernel_probe
 from areal_tpu.observability import timeline as tl_mod
 from areal_tpu.parallel import mesh as mesh_lib
 from jax import set_mesh
+from areal_tpu.utils import compile_cache
 from areal_tpu.utils import logging as alog
 from areal_tpu.utils import perf_tracer
 
@@ -277,17 +278,11 @@ class DecodeEngine:
         cfg = self.config
         # serving-side compile visibility: a recompile storm (drifting
         # chunk/scatter shape keys) shows as areal_xla_compiles_total climb
-        from areal_tpu.utils.compile_cache import (
-            enable_persistent_cache,
-            install_compile_counters,
-        )
-
-        install_compile_counters()
-        # before the first compile: precompile() warms via AOT
-        # lower().compile() and the serving path replays those programs
-        # through the persistent compile cache (TPU-only gating and the
-        # placement rule live in utils/compile_cache.py)
-        enable_persistent_cache()
+        compile_cache.install_compile_counters()
+        # before the first compile: the persistent compile cache, and the
+        # program store beside it (TPU-only gating and the placement rule
+        # live in utils/compile_cache.py)
+        compile_cache.enable_persistent_cache()
         if self.mesh is None:
             self.mesh = mesh_lib.make_mesh(cfg.mesh)
         if self.params is None:
@@ -524,24 +519,27 @@ class DecodeEngine:
         round-2 profiling showed cold prefill variants alone cost ~25% of
         measured decode throughput on the first request waves. Servers call
         this at startup (``ServerConfig.precompile``) — the role SGLang's
-        warmup phase plays for the reference's launchers. Warming uses
-        ``jit(f).lower(...).compile()`` — compile cost only, no device
-        execution (ADVICE r02 #1/#2). The runtime path re-traces on first
-        hit and replays from the in-process/persistent compile cache.
+        warmup phase plays for the reference's launchers. Warming builds
+        each program from abstract arguments through the function its first
+        call would go through (``compile_cache.FirstCall``: the program
+        store's executable loaded, or the program traced, lowered, compiled
+        and written there) — no device execution (ADVICE r02 #1/#2). The
+        runtime path then calls the loaded executable: it traces nothing.
 
         ``budget_s`` bounds wall-clock: compilation stops (with a log of the
         skipped count) once the budget is spent. Programs are ordered hot
         loop first, so an out-of-budget stop costs admission-wave stalls,
         never mid-decode stalls. Fresh compiles land in the persistent
-        cache, so a budget-truncated run completes further on the next start.
+        cache and the program store, so a budget-truncated run completes
+        further on the next start.
         """
         assert self.initialized, "initialize() first"
         t0 = time.monotonic()
 
         def sds(x):
-            # WITH the live array's sharding: the runtime call lowers from
-            # committed arrays, and a program lowered from unplaced shapes
-            # is a different cache key — it would be compiled twice
+            # WITH the live array's sharding: the runtime call passes
+            # committed arrays, and a program built from unplaced shapes is
+            # another program (and another entry of the store)
             return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
 
         shapes = jax.tree.map(sds, (self.params, self.cache, self._dev_state, self._rng))
@@ -560,11 +558,11 @@ class DecodeEngine:
                         "to lazy compile"
                     )
                     break
-                self.programs.lower(key, *shapes).compile()
+                self.programs.build(key, *shapes)
                 n_prog += 1
         logger.info(
             f"precompiled {n_prog}/{len(keys)} serving programs in "
-            f"{time.monotonic() - t0:.1f}s"
+            f"{time.monotonic() - t0:.1f}s; {compile_cache.store_summary()}"
         )
 
     def start(self) -> None:

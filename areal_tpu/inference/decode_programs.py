@@ -46,7 +46,7 @@ import numpy as np
 from areal_tpu.api.config import ServerConfig
 from areal_tpu.inference import paged_kv
 from areal_tpu.utils import logging as alog
-from areal_tpu.utils.compile_cache import FirstCall
+from areal_tpu.utils import compile_cache
 from areal_tpu.utils.data import round_up_to_bucket
 
 # one component, one logger: the three owners log as the decode engine
@@ -276,7 +276,7 @@ def _sample_step(logits, rng, state, capped: bool, greedy_any: bool = True, use_
 class DecodePrograms:
     """The jitted programs of one model replica and the cache they live in."""
 
-    def __init__(self, model, model_cfg, config: ServerConfig, mesh):
+    def __init__(self, model, model_cfg, config: ServerConfig, mesh, store: compile_cache.ProgramStore | None = None):
         self.model = model  # the module of model_cfg's family (models.family_of)
         self.model_cfg = model_cfg
         # read for its shape fields only: slots, context, page size, steps a
@@ -284,6 +284,9 @@ class DecodePrograms:
         self.config = config
         self.mesh = mesh
         self._fn_cache: dict[tuple, Callable] = {}
+        # where a program's first call finds its executable, or leaves it
+        # (utils/compile_cache.py: off everywhere but on a TPU)
+        self.store = compile_cache.default_store() if store is None else store
         psz = config.page_size
         self._maxp = -(-config.max_seq_len // psz)  # pages per sequence (ceil)
         # the Pallas paged kernels run single-device; under TP the engine
@@ -323,6 +326,21 @@ class DecodePrograms:
     def keys(self) -> set[tuple]:
         """The keys of the programs built so far."""
         return set(self._fn_cache)
+
+    def _first_call(self, key: tuple) -> compile_cache.FirstCall:
+        """The program just cached under ``key`` for its first call, with what
+        the store names it by: everything the builders close over (the
+        model's module and configuration, the shape fields of the server's,
+        the mesh, which kernels are compiled) and the key. Weights, cache and
+        slot state are ARGUMENTS of every program: one closed over would be a
+        constant of its executable, and the store refuses such a program."""
+        cfg = self.config
+        closed_over = (
+            self.model, self.model_cfg, self.mesh, self.use_kernel, self.sample_kernel,
+            cfg.max_batch_size, cfg.max_seq_len, cfg.page_size, cfg.decode_steps_per_call, cfg.attn_window_step,
+            cfg.enable_frequency_penalty, cfg.kv_quantization, key,
+        )
+        return compile_cache.FirstCall(self._fn_cache, key, self.store, compile_cache.describe(closed_over))
 
     # prompt buckets above this warm only if on the round_up_to_bucket
     # 2^k/3*2^k series — the exact-reachable set at T=32K would otherwise be
@@ -422,7 +440,7 @@ class DecodePrograms:
                 )
 
             self._fn_cache[key] = jax.jit(prefill, donate_argnames=("cache",))
-            return FirstCall(self._fn_cache[key], key)
+            return self._first_call(key)
         return self._fn_cache[key]
 
     def prefill_paged_fn(self, n_prompts: int, bucket: int, wp: int):
@@ -453,7 +471,7 @@ class DecodePrograms:
                     return paged_kv.scatter_prefill(cache, ks, vs, flat_pages, psz)
 
             self._fn_cache[key] = jax.jit(prefill, donate_argnames=("cache",))
-            return FirstCall(self._fn_cache[key], key)
+            return self._first_call(key)
         return self._fn_cache[key]
 
     def chunk_fn(
@@ -591,7 +609,7 @@ class DecodePrograms:
                 return cache, out_state, rng, packed
 
             self._fn_cache[key] = jax.jit(chunk, donate_argnames=("cache", "state"))
-            return FirstCall(self._fn_cache[key], key)
+            return self._first_call(key)
         return self._fn_cache[key]
 
     def spec_fn(self, B: int, wp: int, capped: bool, greedy_any: bool = True):
@@ -741,7 +759,7 @@ class DecodePrograms:
                 return cache, out_state, rng, packed
 
             self._fn_cache[key] = jax.jit(spec, donate_argnames=("cache", "state"))
-            return FirstCall(self._fn_cache[key], key)
+            return self._first_call(key)
         return self._fn_cache[key]
 
     def update_fn(self, n: int):
@@ -778,7 +796,7 @@ class DecodePrograms:
                 return state
 
             self._fn_cache[key] = jax.jit(apply, donate_argnames=("state",))
-            return FirstCall(self._fn_cache[key], key)
+            return self._first_call(key)
         return self._fn_cache[key]
 
     def pagecopy_fn(self, n: int):
@@ -789,7 +807,7 @@ class DecodePrograms:
             self._fn_cache[key] = jax.jit(
                 paged_kv.copy_pages, donate_argnames=("cache",)
             )
-            return FirstCall(self._fn_cache[key], key)
+            return self._first_call(key)
         return self._fn_cache[key]
 
     def clamp_fn(self, n: int):
@@ -819,7 +837,7 @@ class DecodePrograms:
                 return state
 
             self._fn_cache[key] = jax.jit(clamp, donate_argnames=("state",))
-            return FirstCall(self._fn_cache[key], key)
+            return self._first_call(key)
         return self._fn_cache[key]
 
     def suffix_kernel(self) -> bool:
@@ -1035,13 +1053,14 @@ class DecodePrograms:
                 keys.append(("prefill", A, bucket, False))
         return keys
 
-    def lower(self, key: tuple, params_s, cache_s, state_s, rng_s):
-        """Lower the program of one of ``warm_keys()`` from abstract
-        arguments: the trees of ``jax.ShapeDtypeStruct`` the caller derives
+    def _abstract_call(self, key: tuple, params_s, cache_s, state_s, rng_s):
+        """(program, abstract arguments) of one of ``warm_keys()``: the
+        arguments are the trees of ``jax.ShapeDtypeStruct`` the caller derives
         from its live weights, cache, slot state and rng, WITH their
-        shardings (a program lowered from unplaced shapes is a different
-        cache key from the runtime call on committed arrays: it would be
-        compiled twice). Builds the program if the key is new."""
+        shardings (a program lowered from unplaced shapes is another program
+        than the runtime call on committed arrays makes: it would be compiled
+        twice, and stored under another name). Builds the program if the key
+        is new."""
         cfg = self.config
 
         def i32(*shape):
@@ -1049,30 +1068,37 @@ class DecodePrograms:
 
         kind, *rest = key
         if kind == "chunk":
-            wp = rest[1]
-            return self.chunk_fn(*rest).lower(
-                params_s, cache_s, i32(cfg.max_batch_size, wp), state_s, rng_s
-            )
+            return self.chunk_fn(*rest), (params_s, cache_s, i32(cfg.max_batch_size, rest[1]), state_s, rng_s)
         if kind == "upd":
             (n,) = rest
-            return self.update_fn(n).lower(
-                state_s, jax.ShapeDtypeStruct((n, UPDATE_COLS), jnp.float32)
-            )
+            return self.update_fn(n), (state_s, jax.ShapeDtypeStruct((n, UPDATE_COLS), jnp.float32))
         if kind == "clamp":
             (n,) = rest
-            return self.clamp_fn(n).lower(state_s, i32(n, 2))
+            return self.clamp_fn(n), (state_s, i32(n, 2))
         if kind == "pagecopy":
             (n,) = rest
-            return self.pagecopy_fn(n).lower(cache_s, *[i32(n)] * 4)
+            return self.pagecopy_fn(n), (cache_s, *[i32(n)] * 4)
         if kind == "prefill":
             A, bucket, with_images = rest
             assert not with_images, key
-            return self.prefill_fn(A, bucket).lower(
-                params_s,
-                cache_s,
-                i32(A, bucket),
-                i32(A),
-                i32(A * -(-bucket // cfg.page_size)),
-                i32(A),
+            return self.prefill_fn(A, bucket), (
+                params_s, cache_s, i32(A, bucket), i32(A), i32(A * -(-bucket // cfg.page_size)), i32(A),
             )
         raise KeyError(f"no start-up warms a program of kind {kind!r}")
+
+    def lower(self, key: tuple, params_s, cache_s, state_s, rng_s):
+        """Lower the program of one of ``warm_keys()`` from abstract arguments
+        (``_abstract_call``): the jitted function's own ``lower``, for a look
+        at the module or a compile for a described chip."""
+        fn, args = self._abstract_call(key, params_s, cache_s, state_s, rng_s)
+        return fn.lower(*args)
+
+    def build(self, key: tuple, params_s, cache_s, state_s, rng_s) -> None:
+        """Build the program of one of ``warm_keys()`` from abstract
+        arguments, as its first call would and through the same function
+        (``compile_cache.FirstCall``: the store's entry read, or the program
+        traced, lowered, compiled and written), executing nothing. The
+        runtime call then finds the loaded executable and traces nothing."""
+        fn, args = self._abstract_call(key, params_s, cache_s, state_s, rng_s)
+        if isinstance(fn, compile_cache.FirstCall):
+            fn(*args)

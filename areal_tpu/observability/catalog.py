@@ -659,6 +659,25 @@ def train_obs_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "Compilations served from the persistent XLA compile cache "
             "instead of a fresh backend compile.",
         ),
+        program_store_hits=r.counter(
+            "areal_program_store_hits_total",
+            "Programs whose first call loaded their executable from the "
+            "program store (utils/compile_cache.py): no trace, no lowering.",
+        ),
+        program_store_misses=r.counter(
+            "areal_program_store_misses_total",
+            "Programs the store (on) had no entry for: traced, lowered and "
+            "compiled (or loaded by jax's persistent cache) at their first "
+            "call, then written to the store.",
+        ),
+        program_store_refused=r.counter(
+            "areal_program_store_refused_total",
+            "Programs the store will not hold (their traced form closes "
+            "over array constants, or what their builder closes over has no "
+            "process-independent description) and later calls that did not "
+            "match a built program's executable and went to the jitted "
+            "function.",
+        ),
         attn_tiles_run=r.counter(
             "areal_train_attn_tiles_run_total",
             "(query tile, key tile) pairs the train step's flash kernels "

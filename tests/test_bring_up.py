@@ -3,6 +3,7 @@ the compile cache goes, which peaks a device resolves to, that a benchmark
 or a smoke run without a chip fails instead of reporting CPU numbers."""
 
 import os
+import re
 import subprocess
 import sys
 from types import SimpleNamespace
@@ -60,7 +61,10 @@ def test_compile_cache_has_no_private_knob():
     from areal_tpu.utils import compile_cache
 
     assert not inspect.signature(compile_cache.enable_persistent_cache).parameters
-    assert "os.environ" not in inspect.getsource(compile_cache)
+    assert not inspect.signature(compile_cache.default_store).parameters  # nor the program store beside it
+    # the environment is read for what a program is a function of (the store's key), and for nothing else
+    src = inspect.getsource(compile_cache)
+    assert re.findall(r"os\.environ[^ ]*", src) == ['os.environ.get("XLA_FLAGS"),', 'os.environ.get("LIBTPU_INIT_ARGS"),\n']
 
 
 # -- chip peaks (observability/hw_accounting.py) -----------------------------

@@ -30,7 +30,7 @@ import chipbench_hybrid_util as hu  # noqa: E402
 from areal_tpu.api.config import PrefixCacheConfig, SpeculativeConfig  # noqa: E402
 from areal_tpu.api.io_struct import GenerationHyperparameters, ModelRequest, StopReason  # noqa: E402
 from areal_tpu.inference.decode_engine import DecodeEngine  # noqa: E402
-from areal_tpu.utils import compile_cache  # noqa: E402
+from areal_tpu.utils import compile_cache, perf_tracer  # noqa: E402
 
 from tpu_testing import TINY_QWEN2, tiny_decode_engine  # noqa: E402
 
@@ -171,11 +171,17 @@ def test_nothing_compiles_after_precompile(case, compiled_names):
     eng = _engine(case)
     try:
         eng.precompile()
-        # the warm set: plain jitted callables under the names a device trace
-        # shows (jit_chunk, jit_prefill: PERF.md section 3); 1 window x 4
-        # (capped, greedy) chunks, 2 scatter + 2 clamp sizes, 1 page-copy size,
-        # 2 prompt buckets x 4 group sizes
-        assert all(type(fn) is JITTED for fn in eng.programs._fn_cache.values())
+        # the warm set: each program's loaded executable, built as its first
+        # call would build it (``compile_cache.FirstCall``), over the jitted
+        # callable under the name a device trace shows (jit_chunk,
+        # jit_prefill: PERF.md section 3); 1 window x 4 (capped, greedy)
+        # chunks, 2 scatter + 2 clamp sizes, 1 page-copy size, 2 prompt
+        # buckets x 4 group sizes
+        built = eng.programs._fn_cache.values()
+        assert all(type(fn) is compile_cache.BuiltProgram and type(fn._fn) is JITTED for fn in built)
+        assert all(type(fn._compiled) is jax.stages.Compiled for fn in built)
+        builds = [e for e in perf_tracer.get_tracer().record().entries if e.name == "areal.program.build"][-len(built):]
+        assert len(builds) == 17 and {b.args["served"] for b in builds} == {"jit"}  # no store on a CPU
         assert {(key[0], fn.__name__) for key, fn in eng.programs._fn_cache.items()} == {
             ("chunk", "chunk"), ("prefill", "prefill"), ("upd", "apply"), ("clamp", "clamp"), ("pagecopy", "copy_pages"),
         }
@@ -189,5 +195,7 @@ def test_nothing_compiles_after_precompile(case, compiled_names):
         compiled = collections.Counter(compiled_names)
         assert dict(compiled) == ALLOWED[case]
         assert compile_cache.compile_stats()["compiles"] - before == sum(ALLOWED[case].values())
+        # and the runtime path traced none of the warm set again: its calls went through the executables
+        assert all(type(eng.programs._fn_cache[k]) is compile_cache.BuiltProgram for k in eng.programs.warm_keys())
     finally:
         eng.stop()

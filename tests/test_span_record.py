@@ -132,7 +132,7 @@ def test_the_first_call_of_a_program_is_a_build_span_with_the_compile_events_ins
         key = ("double", n)
         if key not in cache:
             cache[key] = jax.jit(lambda x: x * 2 + n)
-            return compile_cache.FirstCall(cache[key], key)
+            return compile_cache.FirstCall(cache, key)
         return cache[key]
 
     fn = builder(3)
@@ -140,16 +140,44 @@ def test_the_first_call_of_a_program_is_a_build_span_with_the_compile_events_ins
     assert float(fn(jnp.ones(3))[0]) == 5.0 and float(builder(3)(jnp.ones(3))[1]) == 5.0
     assert fn.lower(jnp.zeros(3)).as_text()  # everything but the call is the jitted function's own
     (build,) = _named(tracer, "areal.program.build")
-    assert build.args == {"program": "double", "key": "(3,)"}
-    assert type(cache[("double", 3)]).__name__ == "PjitFunction"  # the cache keeps the plain jitted function
+    # no store on a CPU: traced, lowered and compiled here, once and explicitly
+    assert build.args == {"program": "double", "key": "(3,)", "served": "jit"}
+    # the cache keeps the loaded executable, which no call traces, with the jitted function behind it
+    built = cache[("double", 3)]
+    assert type(built).__name__ == "BuiltProgram" and type(built._compiled).__name__ == "Compiled"
+    assert built.lower(jnp.zeros(3)).as_text() and type(built._fn).__name__ == "PjitFunction"
     for kind in ("trace", "lower", "compile"):
         inside = [e for e in _named(tracer, "areal.xla." + kind) if build.start_ns <= e.start_ns and e.end_ns <= build.end_ns]
         assert inside and all(e.thread == build.thread and e.ph == "X" for e in inside), kind
         assert any("lambda" in str((e.args or {}).get("fun")) for e in inside), kind
-    # the second call of the same shapes compiled nothing
-    n = len(_named(tracer, "areal.xla.compile"))
+    assert _named(tracer, "areal.xla.cache_load") == []
+    # the second call of the same shapes traced and compiled nothing
+    n = len(_named(tracer, "areal.xla.trace")), len(_named(tracer, "areal.xla.compile"))
     builder(3)(jnp.ones(3))
-    assert len(_named(tracer, "areal.xla.compile")) == n
+    assert (len(_named(tracer, "areal.xla.trace")), len(_named(tracer, "areal.xla.compile"))) == n
+
+
+def test_a_program_the_store_serves_is_a_build_with_a_cache_load_inside_and_no_trace(tracer, tmp_path):
+    assert compile_cache.install_compile_counters()
+    store = compile_cache.ProgramStore(str(tmp_path))
+
+    def first_call(cache):
+        cache["triple", 3] = jax.jit(lambda x: x * 3)
+        return compile_cache.FirstCall(cache, ("triple", 3), store, compile_cache.describe(("test_span_record", "triple", 3)))
+
+    x = jnp.ones(3)  # made before either build: its own trace is nobody's
+    assert float(first_call({})(x)[0]) == 3.0  # a miss: built here, written
+    cache: dict = {}
+    assert float(first_call(cache)(x)[0]) == 3.0  # the hit
+    missed, hit = _named(tracer, "areal.program.build")
+    compile_cache._say_when_settled(False)  # the line that says what set-up built comes seconds later: the stream is pytest's
+    assert (missed.args["served"], hit.args["served"]) == ("jit", "store")
+    (load,) = _named(tracer, "areal.xla.cache_load")  # the store's read and load: the act the persistent cache's hit is
+    assert hit.start_ns <= load.start_ns and load.end_ns <= hit.end_ns and load.thread == hit.thread
+    assert load.args == {"fun": "triple", "from": "program_store"}
+    for kind in ("trace", "lower", "compile"):
+        assert [e for e in _named(tracer, "areal.xla." + kind) if hit.start_ns <= e.start_ns and e.end_ns <= hit.end_ns] == [], kind
+    assert type(cache["triple", 3]._compiled).__name__ == "Compiled"
 
 
 class _Ended:
