@@ -1472,6 +1472,8 @@ class DecodeEngine:
             self._obs.gdn_state_updates.inc(int(counts["gdn_updates"].sum()))
         if "kda_updates" in counts:
             self._obs.kda_state_updates.inc(int(counts["kda_updates"].sum()))
+        if "mhc_row_sublayers" in counts:
+            self._obs.mhc_row_sublayers.inc(int(counts["mhc_row_sublayers"].sum()))
         if "latent_tokens_read" in counts:
             self._obs.latent_tokens_read.inc(int(counts["latent_tokens_read"].sum()))
         for leaf, counter in (
@@ -1569,6 +1571,17 @@ class DecodeEngine:
         first = self.model_cfg.expert_first
         held = [first, first + self.model_cfg.num_experts]
         return {"load": load.tolist(), **({"held": held} if held != [0, load.shape[1]] else {})}
+
+    def residual_status(self) -> dict | None:
+        """/statusz ``residual``: the form of the model's residual path where
+        it is not one vector a token and a sum (``mhc``: ``streams`` residual
+        streams mixed before and after every sublayer, the stream-to-stream
+        matrix made doubly stochastic by ``sinkhorn_iters`` rounds); None
+        for every other model."""
+        cfg = self.model_cfg
+        if cfg is None or getattr(cfg, "residual_form", "sum") == "sum":
+            return None
+        return {"form": cfg.residual_form, "streams": int(cfg.hc_mult), "sinkhorn_iters": int(cfg.hc_sinkhorn_iters)}
 
     # -- prefix cache (cross-request radix reuse) --------------------------
     def prefix_cache_stats(self) -> dict:
@@ -2155,6 +2168,10 @@ class DecodeEngine:
             from areal_tpu.inference.paged_kv import band_pairs
 
             self._obs.window_prompt_pairs.inc(len(rings["writers"]) * sum(band_pairs(int(n), rings["keeps"]) for n in plens))
+        if getattr(self.model_cfg, "residual_form", "sum") == "mhc":
+            # (prompt token, sublayer) stream mixes the prompt pass made: two a layer, from the rows' lengths,
+            # whatever implements the mix
+            self._obs.prefill_mhc_token_sublayers.inc(2 * self.model_cfg.num_layers * prompt_tokens)
         rebuilt = self.slots.readmitted(t.req.rid for t, _ in admitted)
         if self.model_cfg.has_slot_tenant:
             self._obs.state_prefills.inc(rebuilt)

@@ -255,6 +255,10 @@ class InferenceServer:
             # expert-load counts of a model with sparse experts
             # (docs/observability.md): one nested list, not a series a cell
             out["moe"] = moe_view
+        rp = getattr(self.engine, "residual_status", None)
+        if rp is not None and (residual_view := rp()) is not None:
+            # a residual path of several streams: its form and their number
+            out["residual"] = residual_view
         sa = getattr(self.engine, "sparse_attention_status", None)
         if sa is not None and (sparse_view := sa()) is not None:
             # a learned index's selection and the form its rows are read in

@@ -45,8 +45,10 @@ not fit (17 GB at 128 heads and 4,096), under ONE Pallas launch whose grid IS
 the band (``ops/window_prefill_attention.py``, chosen from the shapes:
 ``swa_prefill_launch``). The always-active block beside the routed experts
 may be several shared experts side by side whose outputs are AVERAGED
-(``moe_shared_mean_of``). Seven published families are built from these
-(``from_hf_dict``): ``cohere2_moe`` (command-a-plus: the parallel block under
+(``moe_shared_mean_of``). Nine published families are built from these
+(``from_hf_dict``): ``xing4_0`` (the ``deepseek_v3`` block through a low-rank
+query under a YaRN-scaled rotary key on a residual path of four streams:
+below), ``cohere2_moe`` (command-a-plus: the parallel block under
 a LayerNorm with a weight and NO bias, ``norm_bias`` False; ``swa`` layers
 with the rotary embedding beside ``attention`` layers with none, 3:1, 128
 query heads over 8; in EVERY layer sigmoid-routed experts WITHOUT a selection
@@ -169,6 +171,46 @@ holds tokens and the unselected rows meet a probability of 0
 (tests/test_glm5_model.py holds both forms to the reference; PERF.md has what
 a gather of the selected rows costs on the chip).
 
+The residual path is data of the configuration too (``residual_form``), beside
+``norm_placement`` and ``block_form``. ``sum``: ONE vector a token, ``x +
+Sublayer(norm(x))``, every family above. ``mhc`` (``xing4_0``, Xing4.0-29B-A4B:
+manifold-constrained hyper-connections, arXiv:2512.24880, over
+hyper-connections, arXiv:2409.19606; n = ``hc_mult`` = 4, D = hidden): the
+carry of both entry points is n STREAMS ``X [rows, n D]``, side by side on the
+lanes. ``X_0[j] = E[id]`` for every j (the embedding copied); every sublayer
+``F`` (the mixer, then the feed-forward block: two a layer, each with leaves
+``Phi``, ``a``, ``b`` of its own): ``x' = vec(X) rsqrt(mean(vec(X)^2) +
+rms_norm_eps)`` (no weight), ``m = x' Phi`` in R^{2n + n^2}; ``H_pre =
+sigmoid(a_pre m[:n] + b_pre)``, ``H_post = 2 sigmoid(a_post m[n:2n] +
+b_post)``, ``Z = clip(a_res mat(m[2n:]) + B_res, hc_res_clamp)``, ``M =
+exp(Z)``, then ``hc_sinkhorn_iters`` = 20 times ``M <- M / (rowsum(M) +
+hc_eps)``, ``M <- M / (colsum(M) + hc_eps)`` = ``H_res``, doubly stochastic to
+the rounds' precision; ``u = sum_j H_pre[j] X[j]``, ``X'[i] = sum_j H_res[i, j]
+X[j] + H_post[i] F(norm(u))``; after the last layer ``h = sum_j X[j]``, the
+final norm, the head. The coefficients are float32, the streams the served
+type (``mhc_coefficients``, ``mhc_pre``, ``mhc_post``, ``_block_in``,
+``_block_ffn``, ``_final_norm``; scopes MHC_SCOPES). How the streams are born
+and merged, that each sublayer has coefficients of its own, the norm without a
+weight, where ``hc_eps`` enters and the coefficients' type are ASSUMPTIONS the
+configuration file lists (``_xing4_fields`` refuses every other reading). A
+``sum``-form model reaches none of this and traces as it did.
+
+The ``mla`` mixer's rotary embedding may be scaled by YaRN (``rope_scaling``
+type ``yarn``, the DeepSeek-V3 family's published form; any other type is
+refused): rotary pair i of ``qk_rope_head_dim`` / 2 turns by ``inv_freq_i =
+f_i (1 - r_i) + (f_i / factor) r_i``, ``f_i = rope_theta^(-2i / dim)``, ``r_i =
+clip((i - lo) / (hi - lo), 0, 1)``, ``lo = floor(d(beta_fast))``, ``hi =
+ceil(d(beta_slow))``, ``d(b) = dim ln(original / (2 pi b)) / (2 ln
+rope_theta)`` (``yarn_inv_freq``: lo 10, hi 23 of 32 pairs at Xing4.0's
+constants); cos and sin stay unscaled (mscale = mscale_all_dim) and the softmax
+scale takes ``(0.1 mscale_all_dim ln(factor) + 1)^2`` (2.0047) in all three
+attending forms (``sm_scale``: the prompt launch, the XLA loop, the absorbed
+decode read); a key goes into its page rotated by the same table
+(``_rope_latent``). The family's multi-token-prediction layer
+(``num_nextn_predict_layers``) is NOT implemented: served as a self-draft it
+needs verification over latent pages (ROADMAP Reach A.5) and a step that yields
+more than one token a row; a configuration that has one is refused.
+
 What a slot's recurrent state is, and who may write it, is in
 ``inference/paged_kv.py`` (STATE_LEAVES); what a page row is, in ``kv_pools``.
 """
@@ -189,7 +231,7 @@ from jax.sharding import PartitionSpec as P
 from areal_tpu.models import moe, qwen
 from areal_tpu.models.qwen import _embed_lookup, _proj, _rms_norm, _rope
 
-MODEL_TYPES = ("granitemoehybrid", "lfm2_moe", "olmo_hybrid", "deepseek_v3", "glm_moe_dsa", "phi4flash", "solar_open2", "cohere2_moe")
+MODEL_TYPES = ("granitemoehybrid", "lfm2_moe", "olmo_hybrid", "deepseek_v3", "glm_moe_dsa", "phi4flash", "solar_open2", "cohere2_moe", "xing4_0")
 # mixers; ``s6`` is Mamba-1's selective scan, ``swa`` attention over the last ``sliding_window`` tokens, ``cross``
 # queries of its own over the pages of the model's ONE ``attention`` layer, ``gmu`` a gated memory unit, ``kda`` a
 # delta rule whose state decays by a factor of its own every key channel
@@ -214,6 +256,10 @@ SAMBAY_SCOPES = ("attn_window", "attn_cross", "attn_diff", "gmu")
 # a parallel block with rotary window layers: the rotary embedding of the window layers' q and k (both entry points),
 # and the block's one residual sum ``x + Attn(u) + MoE(u)``
 PARALLEL_SCOPES = ("attn_rope", "block_sum")
+# a residual path of several streams (``residual_form`` "mhc"): a sublayer's coefficients from the streams (their
+# norm, ``Phi``, the sigmoids, the clamp), the Sinkhorn rounds, the mix a sublayer reads, the mix it writes back
+# through, and the streams' sum before the final norm
+MHC_SCOPES = ("mhc_coeff", "mhc_sinkhorn", "mhc_pre", "mhc_post", "mhc_merge")
 # tokens a chunk of the delta rule's prefill scan: 16 x 2^2, as ``_unit_lower_inverse`` builds its inverse
 GDN_CHUNK = 64
 # the per-channel-decay delta rule's: the same chunk, in sub-blocks of 16 tokens each referred to its own first
@@ -233,17 +279,41 @@ _KDA_BLOCK_TOKENS = 1024
 # ... and of every model with K and V pages under the page table, one number each a chunk: the blocks of pages its
 # attention launches' work list would hold at one item a (live slot, block), and the items it holds (a block that
 # several slots' rows name is fetched once: ops/paged_attention_q8.py shared_decode_schedule)
+# ... and of a model whose residual path is several streams, one number a chunk: live slots x sublayers mixed
 COUNT_LEAVES = (
     "moe_load", "moe_touched", "moe_streamed", "gdn_updates", "latent_tokens_read",
     "index_tokens_scored", "latent_tokens_selected",
     "shared_kv_tokens_read", "window_tokens_read", "s6_updates",
-    "attn_blocks_listed", "attn_blocks_fetched", "kda_updates",
+    "attn_blocks_listed", "attn_blocks_fetched", "kda_updates", "mhc_row_sublayers",
 )
 
 
 def stack_name(kind: str, ffn: str) -> str:
     """The params stack of layers with this mixer and this FFN."""
     return kind if ffn == "dense" else f"{kind}_{ffn}"
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor: ``0.1 mscale ln(factor) + 1`` (1 at a factor of 1 or less)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: float, beta_fast: float, beta_slow: float):
+    """(the inverse frequency of each of the ``dim // 2`` rotary pairs, lo, hi) under YaRN as the DeepSeek-V3 family
+    publishes it: ``f_i = theta^(-2i / dim)``; a pair that turns more than ``beta_fast`` times over the ``original``
+    length keeps ``f_i``, one that turns fewer than ``beta_slow`` times takes ``f_i / factor``, and between ``lo =
+    floor(d(beta_fast))`` and ``hi = ceil(d(beta_slow))``, ``d(b) = dim ln(original / (2 pi b)) / (2 ln theta)``, a
+    linear ramp ``r_i = clip((i - lo) / (hi - lo), 0, 1)`` blends them: ``f_i (1 - r_i) + (f_i / factor) r_i``."""
+    def pair_of(turns: float) -> float:
+        return dim * math.log(original / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    lo, hi = max(math.floor(pair_of(beta_fast)), 0), min(math.ceil(pair_of(beta_slow)), dim - 1)
+    span = (hi - lo) or 0.001  # the published code's guard against a ramp of no width
+    out = []
+    for i in range(dim // 2):
+        f, r = theta ** (-2.0 * i / dim), min(max((i - lo) / span, 0.0), 1.0)
+        out.append(f * (1.0 - r) + (f / factor) * r)
+    return tuple(out), lo, hi
 
 
 @dataclasses.dataclass(frozen=True)
@@ -378,6 +448,18 @@ class HybridConfig:
     # the always-active block is this many shared experts side by side whose outputs are AVERAGED: the
     # down-projection's sum over all of their columns, divided by it (1: summed, one block)
     moe_shared_mean_of: int = 1
+    # the residual path: "sum" (ONE vector a token, ``x + Sublayer(norm(x))``) or "mhc" (manifold-constrained
+    # hyper-connections: ``hc_mult`` streams a token, mixed before and after every sublayer by per-token coefficients;
+    # the stream-to-stream matrix ``exp`` of a logit clamped to ``hc_res_clamp``, made doubly stochastic by
+    # ``hc_sinkhorn_iters`` rounds of row then column normalisation with ``hc_eps`` in both denominators)
+    residual_form: str = "sum"
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 1e-6
+    hc_res_clamp: tuple[float, float] = (-30.0, 30.0)
+    # the ``mla`` mixer's rotary embedding scaled by YaRN (the DeepSeek-V3 family's form): (factor, original length,
+    # beta_fast, beta_slow, mscale, mscale_all_dim); None: every pair turns by ``rope_theta`` alone
+    rope_yarn: tuple[float, ...] | None = None
 
     @property
     def num_layers(self) -> int:
@@ -411,8 +493,25 @@ class HybridConfig:
         if self.attention_multiplier is not None:
             return float(self.attention_multiplier)
         if self.count("mla"):
-            return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+            base = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+            # YaRN's factor on the softmax scale: mscale(factor, mscale_all_dim)^2 (cos and sin stay unscaled)
+            return base * yarn_mscale(self.rope_yarn[0], self.rope_yarn[5]) ** 2 if self.rope_yarn else base
         return self.head_dim_**-0.5
+
+    @property
+    def rope_inv_freq(self) -> tuple[float, ...] | None:
+        """The ``mla`` mixer's inverse frequency a rotary pair where YaRN scales them (``yarn_inv_freq``); None
+        where every pair turns by ``rope_theta ** (-2i / qk_rope_head_dim)``."""
+        if self.rope_yarn is None:
+            return None
+        factor, original, fast, slow = self.rope_yarn[:4]
+        return yarn_inv_freq(self.qk_rope_head_dim, self.rope_theta, factor, original, fast, slow)[0]
+
+    @property
+    def stream_width(self) -> int:
+        """Values a token carries through the layers: ``hc_mult`` streams of ``hidden_size`` side by side on the
+        lanes (stream j is lanes j * hidden_size ..), or the one residual vector."""
+        return self.hc_mult * self.hidden_size if self.residual_form == "mhc" else self.hidden_size
 
     @property
     def router_width(self) -> int:
@@ -567,6 +666,8 @@ class HybridConfig:
             out["attn_blocks_listed"] = out["attn_blocks_fetched"] = (1,)
         if n := self.count("kda"):
             out["kda_updates"] = (n,)
+        if self.residual_form == "mhc":
+            out["mhc_row_sublayers"] = (1,)
         return out
 
     @property
@@ -770,8 +871,19 @@ class HybridConfig:
                 "linear_conv_kernel_dim": self.gdn_d_conv,
                 "linear_allow_neg_eigval": self.gdn_neg_eigval,
             }
-        if self.model_type in ("deepseek_v3", "glm_moe_dsa"):
+        if self.model_type in ("deepseek_v3", "glm_moe_dsa", "xing4_0"):
             n_dense = sum(1 for f in self.ffns if f == "dense")
+            yarn = None
+            if self.rope_yarn:
+                yarn_keys = ("factor", "original_max_position_embeddings", "beta_fast", "beta_slow", "mscale", "mscale_all_dim")
+                yarn = {"type": "yarn", **dict(zip(yarn_keys, self.rope_yarn)), "original_max_position_embeddings": int(self.rope_yarn[1])}
+            streams = {
+                "hc_mult": self.hc_mult,
+                "hc_sinkhorn_iters": self.hc_sinkhorn_iters,
+                "hc_eps": self.hc_eps,
+                "mhc_h_res_clamp_min": self.hc_res_clamp[0],
+                "mhc_h_res_clamp_max": self.hc_res_clamp[1],
+            }
             index = {
                 "index_n_heads": self.index_n_heads,
                 "index_head_dim": self.index_head_dim,
@@ -785,8 +897,9 @@ class HybridConfig:
                 "hidden_act": "silu",
                 "attention_bias": False,
                 "rope_theta": self.rope_theta,
-                "rope_scaling": None,
+                "rope_scaling": yarn,
                 "rope_interleave": self.rope_interleave,
+                **(streams if self.residual_form == "mhc" else {}),
                 "q_lora_rank": self.q_lora_rank or None,
                 **(index if self.index_topk else {}),
                 "kv_lora_rank": self.kv_lora_rank,
@@ -981,8 +1094,24 @@ def _deepseek_v3_fields(d: dict[str, Any]) -> dict[str, Any]:
     rope = d.get("rope_parameters") or {}
     if int(d.get("n_group") or 1) != 1 or int(d.get("topk_group") or 1) != 1:
         raise ValueError(f"{d['model_type']} with group-limited routing (n_group / topk_group other than 1) is not implemented")
-    if d.get("rope_scaling") is not None or rope.get("rope_type", "default") != "default":
-        raise ValueError(f"{d['model_type']} with a scaled rotary embedding (rope_scaling / rope_type) is not implemented")
+    if rope.get("rope_type", "default") != "default":
+        raise ValueError(f"{d['model_type']} with a scaled rotary embedding under rope_parameters (rope_type) is not implemented")
+    yarn = None
+    if (scaling := d.get("rope_scaling")) is not None:
+        # YaRN as the family publishes it (``yarn_inv_freq``; its factor on the softmax scale, ``HybridConfig.sm_scale``)
+        if scaling.get("type", scaling.get("rope_type")) != "yarn":
+            raise ValueError(f"{d['model_type']} with rope_scaling {scaling!r} is not implemented: only type 'yarn'")
+        if d.get("yarn_form", "deepseek_v3") != "deepseek_v3":
+            raise ValueError(f"yarn_form {d['yarn_form']!r} is not implemented: only the DeepSeek-V3 family's published form")
+        yarn = tuple(
+            float(scaling.get(k, default))
+            for k, default in (
+                ("factor", 1.0), ("original_max_position_embeddings", d.get("max_position_embeddings", 4096)),
+                ("beta_fast", 32.0), ("beta_slow", 1.0), ("mscale", 1.0), ("mscale_all_dim", 0.0),
+            )
+        )
+        if yarn_mscale(yarn[0], yarn[4]) != yarn_mscale(yarn[0], yarn[5]):
+            raise ValueError("rope_scaling with mscale other than mscale_all_dim (scaled cos and sin) is not implemented")
     if d.get("attention_bias"):
         raise ValueError("projection biases are not implemented for the hybrid family")
     if d.get("scoring_func", "sigmoid") != "sigmoid" or d.get("topk_method", "noaux_tc") != "noaux_tc":
@@ -1010,6 +1139,7 @@ def _deepseek_v3_fields(d: dict[str, Any]) -> dict[str, Any]:
         layer_types=("mla",) * n,
         rms_norm_eps=d.get("rms_norm_eps", 1e-6),
         rope_theta=float(d.get("rope_theta") or rope.get("rope_theta") or 10000.0),
+        rope_yarn=yarn,
         rope_interleave=bool(d.get("rope_interleave", True)),
         q_lora_rank=int(d.get("q_lora_rank") or 0),
         kv_lora_rank=int(d["kv_lora_rank"]),
@@ -1029,6 +1159,35 @@ def _deepseek_v3_fields(d: dict[str, Any]) -> dict[str, Any]:
         router_score="sigmoid",
         router_bias=True,
         router_norm_eps=1e-20,
+    )
+
+
+def _xing4_fields(d: dict[str, Any]) -> dict[str, Any]:
+    """``xing4_0``: the DeepSeek-V3 block (``_deepseek_v3_fields``: latent attention through a low-rank query, a
+    YaRN-scaled rotary key, leading dense FFNs, then sigmoid-routed experts beside a shared one) on a residual path
+    of ``hc_mult`` STREAMS (manifold-constrained hyper-connections, arXiv:2512.24880; the module's docstring has the
+    equations). The published ``config.json`` names the sizes (``hc_mult``, ``hc_sinkhorn_iters``, ``hc_eps``,
+    ``mhc_h_res_clamp_min/max``); how the streams are born and merged, where ``hc_eps`` enters, the stream norm's
+    weight and the coefficients' type are ASSUMPTIONS a configuration file lists: each is read here under its key
+    and any reading but the implemented one is refused, never ignored. No index, no multi-token-prediction layer."""
+    for key, want in (
+        ("stream_init", "embedding_copied"), ("stream_merge", "sum"), ("hc_per_sublayer", True), ("hc_norm_weight", "none"),
+        ("hc_eps_in", "both_denominators"), ("hc_coeff_dtype", "float32"),
+    ):
+        if d.get(key, want) != want:
+            raise ValueError(f"xing4_0 with {key} {d[key]!r} is not implemented (only {want!r})")
+    if any(d.get(k) for k in ("index_n_heads", "index_head_dim", "index_topk")):
+        raise ValueError("xing4_0 with a learned index is not implemented")
+    n, rounds = int(d.get("hc_mult", 1)), int(d.get("hc_sinkhorn_iters", 0))
+    if n < 1 or rounds < 1:
+        raise ValueError(f"xing4_0 needs hc_mult >= 1 and hc_sinkhorn_iters >= 1: {n}, {rounds}")
+    return dict(
+        _deepseek_v3_fields(d),
+        residual_form="mhc",
+        hc_mult=n,
+        hc_sinkhorn_iters=rounds,
+        hc_eps=float(d.get("hc_eps", 1e-6)),
+        hc_res_clamp=(float(d.get("mhc_h_res_clamp_min", -30.0)), float(d.get("mhc_h_res_clamp_max", 30.0))),
     )
 
 
@@ -1217,6 +1376,7 @@ _FIELDS = {
     "olmo_hybrid": _olmo_hybrid_fields,
     "deepseek_v3": _deepseek_v3_fields,
     "glm_moe_dsa": _deepseek_v3_fields,
+    "xing4_0": _xing4_fields,
 }
 
 
@@ -1229,6 +1389,10 @@ def prefill_row_bytes(cfg: HybridConfig, bucket: int) -> int:
     model then goes through alone: ONE program a bucket, and the scan's
     temporaries once."""
     stream = qwen.prefill_row_bytes(cfg, bucket)
+    if cfg.residual_form == "mhc":
+        # ``hc_mult`` streams a token, and a sublayer's post-mix writes the new ones beside the old (a 16k prompt's
+        # are 470 MB where one stream is 117 MB): a long prompt goes through alone
+        return 2 * cfg.hc_mult * stream
     if cfg.block_form == "parallel":
         # ONE norm's output feeds both sublayers, so it lives through the layer beside the stream; the queries, their
         # rotated copy and the attention's output are each [bucket, heads * head_dim] (0.5 GB at 16k tokens of 128
@@ -1268,6 +1432,12 @@ def _layer_shapes(cfg: HybridConfig) -> dict[str, dict[str, tuple[int, ...]]]:
     norms = {"input_norm": (D,)} if cfg.block_form == "parallel" else {"input_norm": (D,), "post_norm": (D,)}
     if cfg.norm_kind == "layer" and cfg.norm_bias:
         norms.update({f"{n}_bias": (D,) for n in tuple(norms)})
+    if cfg.residual_form == "mhc":
+        # a sublayer's stream coefficients (``mhc_coefficients``): ``Phi`` over the flattened streams, the three gains
+        # (pre, post, res) and the biases [pre (n) | post (n) | res (n x n, row-major)]; the mixer's and the FFN's own
+        n_coeff = cfg.hc_mult * (2 + cfg.hc_mult)
+        for tag in ("attn", "ffn"):
+            norms.update({f"hc_{tag}_phi": (cfg.stream_width, n_coeff), f"hc_{tag}_alpha": (3,), f"hc_{tag}_bias": (n_coeff,)})
     di = cfg.s6_d_inner
     # an attending layer's query and output side, and its key and value side (a ``cross`` layer has the first alone)
     bias = cfg.attn_bias
@@ -1461,6 +1631,8 @@ def init_params(rng: jax.Array, cfg: HybridConfig, dtype=None) -> dict:
                 stack[leaf] = jnp.ones(full, dtype)
             elif leaf in ("conv_b", "wi_k_norm_bias") or leaf.endswith(("_norm_bias", "_b")):
                 stack[leaf] = jnp.zeros(full, dtype)
+            elif leaf.startswith("hc_") and leaf.endswith("_alpha"):  # the hyper-connections papers start the gains near 0
+                stack[leaf] = jnp.full(full, 0.01, dtype)
             elif leaf in ("lq1", "lk1", "lq2", "lk2"):  # lambda's vectors: N(0, 0.1), as the Differential Transformer draws them
                 stack[leaf] = (0.1 * jax.random.normal(next(keys), full, jnp.float32)).astype(dtype)
             elif leaf == "A_log":
@@ -1686,7 +1858,21 @@ _HF_LAYER_MAPS["cohere2_moe"] = {
     "ws_up": ("mlp.shared_experts.{s}.up_proj.weight", True),
     "ws_down": ("mlp.shared_experts.{s}.down_proj.weight", True),
 }
+# ``xing4_0``: UNCHECKED against a checkpoint (no network, no ``config.json``, weights or modelling code of the family
+# on this machine, and the installed transformers has no ``xing4_0``). The block's names are the DeepSeek-V3 lineage's,
+# as the configuration's keys are; the stream coefficients' names (``attn_hc`` / ``mlp_hc`` with ``phi``, ``alpha``,
+# ``bias``) are this module's GUESS at a layout and nothing published: a loader for a real checkpoint starts here.
+_HF_LAYER_MAPS["xing4_0"] = {
+    **{k: v for k, v in _HF_LAYER_MAPS["deepseek_v3"].items() if not k.startswith("wi_")},
+    "hc_attn_phi": ("attn_hc.phi.weight", True),
+    "hc_attn_alpha": ("attn_hc.alpha", False),
+    "hc_attn_bias": ("attn_hc.bias", False),
+    "hc_ffn_phi": ("mlp_hc.phi.weight", True),
+    "hc_ffn_alpha": ("mlp_hc.alpha", False),
+    "hc_ffn_bias": ("mlp_hc.bias", False),
+}
 _HF_TOP = {
+    "xing4_0": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "cohere2_moe": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "solar_open2": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
     "deepseek_v3": {"embed": "model.embed_tokens.weight", "final_norm": "model.norm.weight"},
@@ -2464,6 +2650,20 @@ def _pairs_to_halves(x):
     return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
 
 
+def _rope_latent(cfg: HybridConfig, x, positions):
+    """The latent-attention mixer's rotary embedding of x [..., heads, rope] (halves) at ``positions`` [...]: every
+    pair by ``rope_theta`` alone, or, where the configuration scales it (``rope_yarn``), pair i by YaRN's table
+    ``rope_inv_freq[i]``, cos and sin unscaled: YaRN's factor stands on the softmax scale (``sm_scale``). A key goes
+    into its page rotated by the same table, so a read takes the page as it lies."""
+    if cfg.rope_yarn is None:
+        return _rope(x, positions, cfg.rope_theta)
+    half = x.shape[-1] // 2
+    angles = positions[..., None].astype(jnp.float32) * jnp.asarray(cfg.rope_inv_freq, jnp.float32)
+    cos, sin = jnp.cos(angles)[..., None, :], jnp.sin(angles)[..., None, :]
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
 def _mla_in(cfg: HybridConfig, layer: dict, h, positions, query: bool = True):
     """The projections of h [..., D] at ``positions`` [...]: (q_nope
     [..., H, nope], q_rope [..., H, rope] rotated, the normed latent c
@@ -2492,7 +2692,7 @@ def _mla_in(cfg: HybridConfig, layer: dict, h, positions, query: bool = True):
         k_r = kva[..., r:][..., None, :]  # one "head"
         if cfg.rope_interleave:
             k_r = _pairs_to_halves(k_r)
-        k_r = _rope(k_r, positions, cfg.rope_theta)[..., 0, :]
+        k_r = _rope_latent(cfg, k_r, positions)[..., 0, :]
     return q_nope, q_rope, c, k_r, q_r
 
 
@@ -2504,7 +2704,7 @@ def _split_query(cfg: HybridConfig, q, positions):
         q_nope, q_rope = q[..., :dn], q[..., dn:]
         if cfg.rope_interleave:
             q_rope = _pairs_to_halves(q_rope)
-        return q_nope, _rope(q_rope, positions, cfg.rope_theta)
+        return q_nope, _rope_latent(cfg, q_rope, positions)
 
 
 def mla_query(cfg: HybridConfig, layer: dict, q_r, positions):
@@ -2519,7 +2719,7 @@ def _rope_head(cfg: HybridConfig, x, positions):
     x [..., heads, index_head_dim] turn, the rest pass."""
     dr = cfg.qk_rope_head_dim
     turn = _pairs_to_halves(x[..., :dr]) if cfg.index_rope_interleave else x[..., :dr]
-    return jnp.concatenate([_rope(turn, positions, cfg.rope_theta), x[..., dr:]], axis=-1)
+    return jnp.concatenate([_rope_latent(cfg, turn, positions), x[..., dr:]], axis=-1)
 
 
 def index_key(cfg: HybridConfig, layer: dict, h, positions):
@@ -3107,6 +3307,10 @@ def _norm(cfg: HybridConfig, x, w, b=None):
 
 
 def _final_norm(params: dict, cfg: HybridConfig, x):
+    """The norm before the head; a residual path of several streams ends here: their sum (in float32) is normed."""
+    if cfg.residual_form == "mhc":
+        with jax.named_scope("mhc_merge"):
+            x = functools.reduce(jnp.add, _streams(cfg, x)).astype(x.dtype)
     return _norm(cfg, x, params["final_norm"], params.get("final_norm_bias"))
 
 
@@ -3120,6 +3324,105 @@ def _norm_out(cfg: HybridConfig, layer: dict, name: str, out):
     """What a sublayer adds to the residual stream: its output, normed where
     the block's norms stand there (``h = x + rmsnorm(sublayer(x))``)."""
     return out if cfg.norm_placement == "pre" else _rms_norm(out, layer[name], cfg.rms_norm_eps)
+
+
+# rows from which a sublayer's stream coefficients are fenced in their batch-on-lanes layout (``mhc_coefficients``)
+_MHC_FENCE_ROWS = 1024
+
+
+def sinkhorn_rows(m: list[list[jax.Array]], rounds: int, eps: float, unrolled: bool | None = None) -> list[list[jax.Array]]:
+    """``rounds`` times ``M <- M / (rowsum(M) + eps)``, ``M <- M / (colsum(M) + eps)`` on an n x n matrix a row of
+    the batch, handed over as n x n float32 vectors ``m[i][j]`` over the batch. On a TPU a static loop of
+    elementwise ops on those vectors (the sums written out, no reduce op): XLA fuses the 2 x ``rounds`` dependent
+    passes into one kernel, where a ``lax.fori_loop`` would be a device loop of ``rounds`` trips a sublayer.
+    Elsewhere the same round is the body of a ``lax.fori_loop`` (``unrolled`` says which, for a test that holds the
+    two to each other to the last bit or two): XLA:CPU takes half a minute to compile the 1,100 ops of the unrolled chain."""
+    n = len(m)
+
+    def one_round(m):
+        m = [list(row) for row in m]
+        for i in range(n):
+            s = functools.reduce(jnp.add, m[i]) + eps
+            m[i] = [v / s for v in m[i]]
+        for j in range(n):
+            s = functools.reduce(jnp.add, [m[i][j] for i in range(n)]) + eps
+            for i in range(n):
+                m[i][j] = m[i][j] / s
+        return m
+
+    if jax.default_backend() == "tpu" if unrolled is None else unrolled:
+        for _ in range(rounds):
+            m = one_round(m)
+        return m
+    return jax.lax.fori_loop(0, rounds, lambda _, m: one_round(m), m)
+
+
+def mhc_coefficients(cfg: HybridConfig, layer: dict, tag: str, X):
+    """One sublayer's stream coefficients from the streams X [..., n * D] (stream j on lanes j * D ..), all float32:
+    ``x' = x * rsqrt(mean(x^2) + eps)`` over all n * D values (no weight), ``m = x' Phi`` [2n + n^2]; ``H_pre =
+    sigmoid(a_pre m[:n] + b_pre)`` [..., n], ``H_post = 2 sigmoid(a_post m[n:2n] + b_post)`` [..., n], ``H_res =
+    sinkhorn(exp(clip(a_res m[2n:] + B_res, hc_res_clamp)))`` [..., n * n] row-major (``sinkhorn_rows``). ``tag`` is
+    the sublayer (``attn``, ``ffn``): each has leaves of its own. The norm's scale is a number a row, so it is
+    taken out of the product: ``m = (x Phi) * rsqrt(...)``, and no normed copy of the streams exists. Past the
+    product the batch lies on the LANES (24 vectors over the rows), where the rounds' 1,100 small ops cost
+    rows / 1,024 registers each and not rows / 8."""
+    n = cfg.hc_mult
+    lead = X.shape[:-1]
+    with jax.named_scope("mhc_coeff"):
+        ms = jnp.mean(jnp.square(X.astype(jnp.float32)), axis=-1, keepdims=True)
+        m = jnp.dot(X, layer[f"hc_{tag}_phi"], preferred_element_type=jnp.float32) * jax.lax.rsqrt(ms + cfg.rms_norm_eps)
+        cols = m.reshape(-1, m.shape[-1]).T  # [2n + n^2, rows]
+        alpha, bias = layer[f"hc_{tag}_alpha"].astype(jnp.float32), layer[f"hc_{tag}_bias"].astype(jnp.float32)
+        pre = [jax.nn.sigmoid(alpha[0] * cols[j] + bias[j]) for j in range(n)]
+        post = [2.0 * jax.nn.sigmoid(alpha[1] * cols[n + j] + bias[n + j]) for j in range(n)]
+        lo, hi = cfg.hc_res_clamp
+        z = [[jnp.clip(alpha[2] * cols[2 * n + i * n + j] + bias[2 * n + i * n + j], lo, hi) for j in range(n)] for i in range(n)]
+    with jax.named_scope("mhc_sinkhorn"):
+        res = sinkhorn_rows([[jnp.exp(v) for v in row] for row in z], cfg.hc_sinkhorn_iters, cfg.hc_eps)
+        res = [v for row in res for v in row]
+    if math.prod(lead) < _MHC_FENCE_ROWS:
+        return tuple(jnp.stack(vs, axis=-1).reshape(*lead, len(vs)) for vs in (pre, post, res))
+    # a prompt pass's rows: fenced with the batch still on the lanes. Left to itself XLA:TPU moves the mixes' reshape
+    # up through the whole chain and computes every vector of the rounds as the mixes read them, [rows, 1] (one lane
+    # of 128 in use, 2 ms a sublayer at 16k rows: compiled for a described v5e, tests/test_tpu_compile.py). A decode
+    # step's 64 rows stay unfenced: there the fence splits the rounds into a fusion each (25 launches a sublayer for 7)
+    out = jax.lax.optimization_barrier(tuple(jnp.stack(vs, axis=0) for vs in (pre, post, res)))
+    return tuple(o.T.reshape(*lead, o.shape[0]) for o in out)
+
+
+def _streams(cfg: HybridConfig, X) -> list[jax.Array]:
+    """The streams of X [..., n * D] as n float32 views [..., D]."""
+    D = cfg.hidden_size
+    return [X[..., j * D : (j + 1) * D].astype(jnp.float32) for j in range(cfg.hc_mult)]
+
+
+def mhc_pre(cfg: HybridConfig, X, pre):
+    """What a sublayer reads of the streams: ``u = sum_j H_pre[j] X[j]`` [..., D], summed in float32."""
+    with jax.named_scope("mhc_pre"):
+        xs = _streams(cfg, X)
+        return functools.reduce(jnp.add, [pre[..., j : j + 1] * xs[j] for j in range(cfg.hc_mult)]).astype(X.dtype)
+
+
+def mhc_post(cfg: HybridConfig, X, post, res, out):
+    """What a sublayer leaves: ``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] out`` [..., n * D], summed in float32."""
+    n = cfg.hc_mult
+    with jax.named_scope("mhc_post"):
+        xs, o = _streams(cfg, X), out.astype(jnp.float32)
+        new = [
+            functools.reduce(jnp.add, [res[..., i * n + j : i * n + j + 1] * xs[j] for j in range(n)]) + post[..., i : i + 1] * o
+            for i in range(n)
+        ]
+        return jnp.concatenate(new, axis=-1).astype(X.dtype)
+
+
+def _block_in(cfg: HybridConfig, layer: dict, x):
+    """What a block's mixer sublayer reads of the carry ``x``, and what the carry's update will need: (x, None) on
+    a residual path of one vector; on one of several streams (the pre-mix of the streams under the mixer's own
+    coefficients, (the streams, H_post, H_res))."""
+    if cfg.residual_form != "mhc":
+        return x, None
+    pre, post, res = mhc_coefficients(cfg, layer, "attn", x)
+    return mhc_pre(cfg, x, pre), (x, post, res)
 
 
 # bytes of a feed-forward block's widest activations over all rows at once: the
@@ -3142,7 +3445,7 @@ def ffn_block_rows(cfg: HybridConfig, ffn: str, rows: int) -> int:
     return block if rows % block == 0 else rows
 
 
-def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None, u=None):
+def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None, u=None, residual: bool = True):
     """x + the layer's feed-forward block, its RMSNorm where the block has
     it (on the input, or on the output); for an expert block also the rows
     of ``live`` (default: all) each expert got, [E] int32. Where the expert
@@ -3151,14 +3454,17 @@ def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None, u=None):
     (``ffn_block_rows``): the block is row-wise, so the result is the same.
     ``u`` is a PARALLEL block's one normed input: the FFN reads it and no
     norm of its own, and ``x`` (the residual stream with the mixer's output
-    already on it) takes the FFN's output in the block's one sum."""
+    already on it) takes the FFN's output in the block's one sum.
+    ``residual`` False returns the block's output ALONE, ``FFN(norm(x))``:
+    what a residual path of several streams writes back through its own mix
+    (``_block_ffn``)."""
     rm = cfg.residual_multiplier
     n_rows = x.size // x.shape[-1]
     block = ffn_block_rows(cfg, ffn, n_rows)
     if block < n_rows:
         lv = jnp.ones((n_rows,), bool) if live is None else live.reshape(-1)
         rows = (x.reshape(-1, block, x.shape[-1]), lv.reshape(-1, block)) + (() if u is None else (u.reshape(-1, block, u.shape[-1]),))
-        out, load = jax.lax.map(lambda a: _ffn(cfg, ffn, layer, *a), rows)
+        out, load = jax.lax.map(lambda a: _ffn(cfg, ffn, layer, *a, residual=residual), rows)
         return out.reshape(x.shape), (None if load is None else load.sum(0))
     if ffn == "dense":
         assert u is None, "a parallel block with a dense FFN is not implemented"
@@ -3168,7 +3474,8 @@ def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None, u=None):
                 g, u = jnp.split(_proj(cfg, layer, "w_gate_up", h), 2, axis=-1)
             else:
                 g, u = _proj(cfg, layer, "w_gate", h), _proj(cfg, layer, "w_up", h)
-            return x + rm * _norm_out(cfg, layer, "post_norm", _proj(cfg, layer, "w_down", jax.nn.silu(g) * u)), None
+            out = _norm_out(cfg, layer, "post_norm", _proj(cfg, layer, "w_down", jax.nn.silu(g) * u))
+            return (x + rm * out if residual else out), None
     with jax.named_scope("moe_router"):
         h = _norm_in(cfg, layer, "post_norm", x) if u is None else u
     rows = h.reshape(-1, h.shape[-1])
@@ -3185,16 +3492,26 @@ def _ffn(cfg: HybridConfig, ffn: str, layer: dict, x, live=None, u=None):
         with jax.named_scope("block_sum"):  # x + Mixer(u) came in as ``x``: the block's one sum
             return x + rm * out.reshape(x.shape).astype(x.dtype), load
     with jax.named_scope("moe_combine"):
-        return x + rm * _norm_out(cfg, layer, "post_norm", out.reshape(x.shape).astype(x.dtype)), load
+        out = _norm_out(cfg, layer, "post_norm", out.reshape(x.shape).astype(x.dtype))
+        return (x + rm * out if residual else out), load
 
 
 # the scope a mixer's norm counts under (its projections')
-def _block_ffn(cfg: HybridConfig, ffn: str, layer: dict, x, out, h, live):
+def _block_ffn(cfg: HybridConfig, ffn: str, layer: dict, x, out, h, live, streams=None):
     """A block's second half: ``x`` the block's input, ``out`` its mixer's
     output, ``h`` what the mixer read. Serial: ``h' = x + out``, ``h' +
     FFN(norm(h'))``. Parallel: ``x + out + FFN(h)``, ``h`` the block's ONE
-    normed input."""
+    normed input. On a residual path of several streams (``streams`` = what
+    ``_block_in`` kept: the streams X, H_post, H_res; ``x`` is then the
+    mixer's pre-mix): ``X' = H_res X + H_post out``, then the FFN sublayer
+    under coefficients of its own, ``X'' = H_res' X' + H_post' FFN(norm(sum_j
+    H_pre'[j] X'[j]))``."""
     rm = cfg.residual_multiplier
+    if streams is not None:
+        X = mhc_post(cfg, *streams, out)
+        pre, post, res = mhc_coefficients(cfg, layer, "ffn", X)
+        f, load = _ffn(cfg, ffn, layer, mhc_pre(cfg, X, pre), live, residual=False)
+        return mhc_post(cfg, X, post, res, f), load
     if cfg.block_form == "parallel":
         return _ffn(cfg, ffn, layer, x + rm * out, live, u=h)
     return _ffn(cfg, ffn, layer, x + rm * out, live)
@@ -3298,6 +3615,8 @@ def _embed(params: dict, cfg: HybridConfig, ids):
         x = _embed_lookup(params["embed"], ids, cfg.jax_dtype, batch_sharded=False)
         if cfg.embedding_multiplier != 1.0:
             x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+        if cfg.residual_form == "mhc":  # the streams are born as copies of the embedding, side by side on the lanes
+            x = jnp.concatenate([x] * cfg.hc_mult, axis=-1)
         return x
 
 
@@ -3514,6 +3833,7 @@ def forward_prefill(
 
     def step(kind, ffn, carry, layer, j, f, narrow=False):
         x, ks, vs, arr = carry
+        x, streams = _block_in(cfg, layer, x)  # several residual streams: the mixer reads their pre-mix
         q_pos = last_at[:, None] if narrow else positions  # where the rows of x stand
         if kind == "mamba":
             h = _norm_in(cfg, layer, "input_norm", x)
@@ -3631,7 +3951,7 @@ def forward_prefill(
                 out = _proj(cfg, layer, "wo", attn)
         with jax.named_scope(_MIXER_SCOPE[kind]):
             out = _norm_out(cfg, layer, "input_norm", out)
-        x, _ = _block_ffn(cfg, ffn, layer, x, out, h, None if narrow else live)
+        x, _ = _block_ffn(cfg, ffn, layer, x, out, h, None if narrow else live, streams)
         return x, ks, vs, arr
 
     x = _embed(params, cfg, input_ids)
@@ -3986,6 +4306,7 @@ def forward_decode_paged(
     def step(kind, ffn, carry, layer, j, f):
         x, c = carry
         c = dict(c)
+        x, streams = _block_in(cfg, layer, x)  # several residual streams: the mixer reads their pre-mix
         if kind == "mamba":
             h = _norm_in(cfg, layer, "input_norm", x)
             out, state = mamba_decode(cfg, layer, h, {k: c[k] for k in ("ssm", "conv")}, j, active, live)
@@ -4138,7 +4459,10 @@ def forward_decode_paged(
                 out = _proj(cfg, layer, "wo", attn)
         with jax.named_scope(_MIXER_SCOPE[kind]):
             out = _norm_out(cfg, layer, "input_norm", out)
-        x, load = _block_ffn(cfg, ffn, layer, x, out, h, active)
+        x, load = _block_ffn(cfg, ffn, layer, x, out, h, active, streams)
+        if "mhc_row_sublayers" in c:  # the mixer's and the FFN's mix, of every live row
+            with jax.named_scope("mhc_post"):
+                c["mhc_row_sublayers"] = c["mhc_row_sublayers"] + 2 * jnp.sum(active, dtype=jnp.int32)
         if load is not None and "moe_load" in c:
             with jax.named_scope("moe_router"):
                 c["moe_load"] = c["moe_load"].at[f].add(load)

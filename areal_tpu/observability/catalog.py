@@ -208,6 +208,22 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "state by decode steps: live slots x kda layers (each reads and "
             "writes the slot's state of that layer once).",
         ),
+        # a model whose residual path is several streams (models/hybrid.py
+        # ``residual_form`` "mhc"): the first counted on the device inside
+        # the decode chunk, the second where the engine dispatches a prefill
+        mhc_row_sublayers=r.counter(
+            "areal_decode_mhc_row_sublayers_total",
+            "(live slot, sublayer) stream mixes made by decode steps: live "
+            "slots x 2 sublayers a layer (each computes its coefficients "
+            "from the streams, pre-mixes them and writes them back through "
+            "the post/res-mix).",
+        ),
+        prefill_mhc_token_sublayers=r.counter(
+            "areal_prefill_mhc_token_sublayers_total",
+            "(prompt token, sublayer) stream mixes made by prompt passes: "
+            "prompt tokens x 2 sublayers a layer, from the rows' lengths at "
+            "the prefill, whatever implements the mix.",
+        ),
         # a latent-attention model (models/hybrid.py ``mla``); counted on the
         # device inside the decode chunk as the counts around it are
         latent_tokens_read=r.counter(

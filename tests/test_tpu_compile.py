@@ -1279,3 +1279,114 @@ def test_cohere2_moe_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypa
     for leaf in ("bf16[3,8,65,32,128,128]", "bf16[1,8,3072,128,128]"):
         assert not [ln for ln in text.splitlines() if " copy(" in ln and leaf in ln], leaf
     assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
+
+
+def _results_outside_fusions(text: str) -> list[str]:
+    """The instructions of a compiled program's text that stand outside fused computations: what lives in HBM."""
+    out, inside = [], None
+    for ln in text.splitlines():
+        if ln and not ln.startswith(" ") and ln.rstrip().endswith("{"):
+            inside = (ln.split()[1] if ln.startswith("ENTRY") else ln.split()[0]).lstrip("%")
+        elif inside and "fused" not in inside and re.match(r"^  \S+ = ", ln):
+            out.append(ln)
+    return out
+
+
+def _xing4(chip, monkeypatch, layers: int = 10, kv_gb: float = 4.5):
+    """The ``xing4_0`` family at the benchmark's published widths (hidden
+    3584 in FOUR residual streams, 32 heads, latent rows of 576 in 640 lanes,
+    16 held experts of [3584, 1024] under a router of 64, a vocabulary of
+    32,768), the cell's ten layers (two scan bodies: a program's temporaries
+    are one layer's) and 64 slots; the page pool is the cell's 2,949 pages."""
+    import json
+
+    from areal_tpu import models
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.models import hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "chip", "configs", "xing4.0-29b-a4b-ep4-d10.json")) as f:
+        cfg = json.load(f)
+    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "reduced_from", "assumed", "assumed_notes", "stands_for")}
+    keep = ("router_experts", "expert_first", "latent_row_lanes", "rope_interleave")
+    hf.update({k: cfg["assumed"][k] for k in keep}, num_hidden_layers=layers, dtype="bfloat16")
+    mcfg = models.config_from_hf_dict(hf)
+    n_pages = paged_kv.n_pages_for_budget(int(kv_gb * 2**30), 10, 1, PSZ, 640, 2, pools=mcfg.kv_pools)
+    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
+    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, n_pages, PSZ, slots=64))
+    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return mcfg, place(params), place(cache), n_pages
+
+
+def test_xing4_decode_steps_compile_for_v5e_at_the_whole_window(chip, monkeypatch):
+    """Two decode steps as the engine's chunk runs them at the cell's ONE
+    window (160 pages: 20,480 tokens, 64 slots), all ten layers: the latent
+    launch over every cached token (no index), the pool written by one launch
+    a layer, the expert matmuls as the touched-expert launch on the stacks (64
+    rows x top-4 over 64: 4 assignments an expert) with an expert of [3584,
+    1024] through the ring whole; the 20 Sinkhorn rounds of a sublayer a
+    STATIC chain (no while loop but the layers' two scans and the steps'), the
+    four streams [64, 14336] on the lanes. No page pool copied, no layer of
+    the expert stacks sliced out."""
+    from areal_tpu.models import hybrid
+
+    mcfg, params, cache, n_pages = _xing4(chip, monkeypatch)
+    assert n_pages == 2949 and {k: v.shape for k, v in cache.items()} == {"k": (10, 1, 2949, PSZ, 640)}
+
+    def two_steps(params, cache, pt, ids, pos, active):
+        cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
+
+        def step(c, _):
+            ids, pos, cache = c
+            h, cache = hybrid.forward_decode_paged(params, mcfg, ids, pos, cache, pt, page_size=PSZ, active=active, use_kernel=True)
+            return (jnp.argmax(hybrid.compute_logits(params, mcfg, h), -1).astype(jnp.int32), pos + 1, cache), None
+
+        (ids, _, cache), _ = jax.lax.scan(step, (ids, pos, cache), None, length=2)
+        return ids, cache
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(64, 160), i32(64), i32(64), chip((64,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    for name in ("paged_latent_attn", "paged_kv_write", "moe_touched_experts"):
+        assert name in text, name
+    for pool in ("bf16[10,1,2949,128,640]", "bf16[1,2949,128,640]"):
+        assert not [ln for ln in text.splitlines() if " copy(" in ln and pool in ln], pool
+    made = re.compile(r"= bf16\[(1,)?16,3584,1024\]\S* (?!parameter|get-tuple-element)")  # (a down matrix has the shape of 16 query blocks of the attention output)
+    assert not [ln for ln in text.splitlines() if made.search(ln)]
+    assert text.count(" while(") == 3  # the steps' scan and the layers' two: the rounds are no device loop
+    assert "bf16[64,14336]" in text  # the streams side by side on the lanes
+    rounds = [ln for ln in _results_outside_fusions(text) if "mhc_sinkhorn" in ln and " fusion(" in ln]
+    assert 0 < len(rounds) <= 4 * 8  # two scan bodies x two sublayers: a handful of launches each, not one a round
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+def test_xing4_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypatch):
+    """ONE prompt of 16,384 tokens, the cell's longest bucket, into the latent
+    pool: four streams of 117 MB each beside their successors, the
+    coefficients' 24 columns a token with the batch on the lanes, attention
+    blocked over 1,024 queries under ``mla_prefill_flash``, the expert rows
+    8,192 at a time on the stack. Under 4 GB of temporaries: with 4.45 GB of
+    weights and a pool of 4.5 GiB, under 15 GB."""
+    from areal_tpu.models import hybrid
+
+    mcfg, params, cache, _ = _xing4(chip, monkeypatch, layers=4)
+    assert hybrid.prefill_takes_launch(mcfg, 16384) and hybrid.prefill_blocks(mcfg, 16384, launch=True) == (1024, 1024)
+    assert hybrid.ffn_block_rows(mcfg, "moe", 16384) == 8192
+
+    def prefill(params, cache, ids, plens, flat_pages, slots):
+        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
+
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(1, 16384), i32(1), i32(16384 // PSZ), i32(1)).compile()
+    text = compiled.as_text()
+    assert "mla_prefill_flash" in text and "bf16[1,16384,14336]" in text
+    made = _results_outside_fusions(text)
+    # no float32 copy of the streams and no stream axis next to the minor one (a bfloat16 axis of 4 is padded to 16) in HBM
+    assert not [ln for ln in made if re.search(r"= f32\[(1,)?16384,14336\]|= \S+\[(1,)?16384,4,3584\]", ln)]
+    # the Sinkhorn rounds' vectors keep the batch on the lanes: none is laid out [rows, 1] as the mixes read them
+    rounds = [ln for ln in made if "mhc_sinkhorn" in ln and " fusion(" in ln]
+    assert rounds and not [ln for ln in rounds if "f32[16384,1]" in ln.split(" fusion(")[0]]
+    made = re.compile(r"= bf16\[(1,)?16,3584,1024\]\S* (?!parameter|get-tuple-element)")  # (a down matrix has the shape of 16 query blocks of the attention output)
+    assert not [ln for ln in text.splitlines() if made.search(ln)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
