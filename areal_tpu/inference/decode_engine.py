@@ -1525,8 +1525,9 @@ class DecodeEngine:
         tokens), and the blocks they fetched (a block that several slots'
         table rows name is one item), a step, since the engine started;
         ``fetched_share`` of 1 says no live slots alias pages. None for a
-        model without K and V pages under the page table, and before a
-        chunk has run on the kernel path."""
+        model without K and V pages (or latent rows without a learned
+        index) under the page table, and before a chunk has run on the
+        kernel path."""
         listed, fetched = getattr(self, "_attn_blocks", (0, 0))
         if not listed:
             return None

@@ -270,13 +270,14 @@ _KDA_BLOCK_TOKENS = 1024
 # cache the engine keeps): rows of live slots each expert got, [expert
 # layers, experts], and experts with at least one such row, [expert layers];
 # live slots whose delta-rule state a step advanced, [gdn layers] ([kda layers] for ``kda_updates``); cached
-# tokens of live slots a latent-attention layer read, [mla layers]; where the
+# tokens a latent-attention layer's read FETCHED, [mla layers] (the live slots' distinct ones where the launch names a
+# block several slots hold once, every live slot's on the gather path and under a selection); where the
 # layer has an index, the cached tokens it scored and the tokens the
 # mathematics selects of them (min(index_topk, cached) a live slot), [mla layers]
 # ... and of a decoder-hybrid-decoder, one number each a chunk: cached tokens of live slots x the layers that read
 # the shared pages; tokens a window layer read (at most ``sliding_window`` a live slot and layer); selective-scan
 # states advanced (live slots x s6 layers)
-# ... and of every model with K and V pages under the page table, one number each a chunk: the blocks of pages its
+# ... and of every model with K and V pages (or latent rows without an index) under the page table, one number each a chunk: the blocks of pages its
 # attention launches' work list would hold at one item a (live slot, block), and the items it holds (a block that
 # several slots' rows name is fetched once: ops/paged_attention_q8.py shared_decode_schedule)
 # ... and of a model whose residual path is several streams, one number a chunk: live slots x sublayers mixed
@@ -662,7 +663,7 @@ class HybridConfig:
             out["window_tokens_read"] = (1,)
         if self.count("s6"):
             out["s6_updates"] = (1,)
-        if self.count("attention") + self.count("cross"):
+        if self.count("attention") + self.count("cross") or (self.count("mla") and not self.index_topk):
             out["attn_blocks_listed"] = out["attn_blocks_fetched"] = (1,)
         if n := self.count("kda"):
             out["kda_updates"] = (n,)
@@ -4237,14 +4238,18 @@ def forward_decode_paged(
         ppcb = paged_kv.choose_ppcb(page_table.shape[1])
         if cfg.count("mla"):
             from areal_tpu.ops.paged_latent_attention import paged_latent_attention_stacked
-
-            # the latent launch's work list and the index's: every block of a slot's row for that slot alone
+        if cfg.index_topk:
+            # the index's launch scores every block of a slot's row for that slot alone, and the latent launch reads
+            # under the selection it makes: a shared block would need every reader's mask rows, so the same list
             schedule = decode_schedule(attn_lengths, page_table.shape[1], page_size, ppcb)
+            latent_items = DecodeItems.private(schedule)
         else:
-            # K and V pages: each distinct block once, with the slots whose rows name it (qwen.forward_decode_paged)
+            # K and V pages, or latent rows: each distinct block once, with the slots whose rows name it
+            # (qwen.forward_decode_paged)
             with jax.named_scope("attn"):
                 schedule, fetch = shared_decode_schedule(attn_lengths, page_table, page_size, ppcb)
                 cache = fetch.counted(cache)
+            latent_items = schedule
         # the state kernel's work list, made once a step
         state_launch = cfg.count("mamba") or cfg.count("gdn") or (cfg.count("kda") and kda_takes_launch(cfg))
         live = live_order(active) if state_launch else None
@@ -4412,16 +4417,17 @@ def forward_decode_paged(
                 if use_kernel:
                     o_lat = paged_latent_attention_stacked(
                         q, c["k"], j, attn_lengths, page_table, value_lanes=cfg.kv_lora_rank,
-                        pages_per_compute_block=ppcb, schedule=schedule, sm_scale=cfg.sm_scale, select=chosen,
+                        pages_per_compute_block=ppcb, schedule=latent_items, sm_scale=cfg.sm_scale, select=chosen,
                     )
                 else:
                     pool = jax.lax.dynamic_index_in_dim(c["k"], j, 0, keepdims=False)
                     o_lat = paged_kv.paged_attention_xla(q, pool, pool, lengths, page_table, sm_scale=cfg.sm_scale, select=chosen)
                     o_lat = o_lat[..., : cfg.kv_lora_rank]
                 if "latent_tokens_read" in c:
-                    # the rows the read fetched: every cached row of a live slot (the unselected are fetched and masked)
+                    # every cached row of a live slot (under a selection the unselected are fetched and masked) ...
                     n_cached = jnp.sum(jnp.where(active, lengths, 0), dtype=jnp.int32)
-                    c["latent_tokens_read"] = c["latent_tokens_read"].at[j].add(n_cached)
+                    # ... and the rows the read FETCHED: the distinct ones where the launch's list names a block several slots hold once
+                    c["latent_tokens_read"] = c["latent_tokens_read"].at[j].add(n_cached if fetch is None else fetch.tokens)
                     if cfg.index_topk:
                         c["index_tokens_scored"] = c["index_tokens_scored"].at[j].add(n_cached)
                         c["latent_tokens_selected"] = c["latent_tokens_selected"].at[j].add(

@@ -228,9 +228,12 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
         # device inside the decode chunk as the counts around it are
         latent_tokens_read=r.counter(
             "areal_decode_latent_tokens_read_total",
-            "(cached token, layer) latent rows read by decode steps: the "
-            "cached tokens of live slots x latent-attention layers (each "
-            "row is fetched once a step and layer).",
+            "(cached token, layer) latent rows fetched by decode steps, each "
+            "once a step and layer: the distinct cached tokens of the slots "
+            "that hold pages x latent-attention layers where the launch "
+            "names a block several slots hold once; every live slot's "
+            "cached tokens on the gather path and under an index's "
+            "selection.",
         ),
         # a decoder-hybrid-decoder (models/hybrid.py ``s6`` / ``swa`` / ``cross``
         # / ``gmu``); the first three counted on the device inside the decode
@@ -255,9 +258,9 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "1, sliding_window), x window layers; from the rows' lengths at "
             "the prefill, whatever computed the product.",
         ),
-        # every model with K and V pages under the page table; counted on the
-        # device inside the decode chunk, once a step (the work list is the
-        # same for every layer)
+        # every model with K and V pages (or latent rows without an index) under
+        # the page table; counted on the device inside the decode chunk, once a
+        # step (the work list is the same for every layer)
         attn_blocks_listed=r.counter(
             "areal_decode_attn_blocks_listed_total",
             "Blocks of pages (pages_per_compute_block pages each) the live "

@@ -139,12 +139,14 @@ def decode_schedule(
     pages_per_compute_block: int,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The work list of a launch that fetches every slot's blocks for that
-    slot alone (ops/paged_latent_attention.py's two launches; this module's
-    over a table that aliases nothing, such as a window layer's rings), the
-    same for every layer of a decode step (so a step computes it once and
-    hands it to each launch): one item per (live slot, block of its tokens),
-    slot-major. Returns (slot of item t, block of item t, [number of items]);
-    entries past the count are never read."""
+    slot alone (ops/paged_latent_attention.py's index launch, and its latent
+    launch where it reads under the index's selection; this module's over a
+    table that aliases nothing, a window layer's rings), the same for every
+    layer of a decode step (so a step computes it once and hands it to each
+    launch): one item per (live slot, block of its tokens), slot-major.
+    Returns (slot of item t, block of item t, [number of items]); entries
+    past the count are never read. ``DecodeItems.private`` puts it in the
+    form the attention launches walk."""
     ppcb = pages_per_compute_block
     bk = ppcb * page_size
     num_slots = lengths.shape[0]
@@ -157,7 +159,9 @@ def decode_schedule(
 
 
 class DecodeItems(NamedTuple):
-    """``paged_decode_attn``'s work list: each DISTINCT block of pages once.
+    """The attention launches' work list (``paged_decode_attn``, and
+    ``paged_latent_attn`` of ops/paged_latent_attention.py): each DISTINCT
+    block of pages once.
     Items [0, count[0]) are blocks that several slots read (block-major);
     items [count[0], count[1]) are blocks of one slot, slot-major."""
 

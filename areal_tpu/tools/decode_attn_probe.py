@@ -35,9 +35,18 @@ the roofline of the bytes IT fetches, and the list's own microseconds a step.
 cell's launch, 32 query rows of 640 lanes over ONE stacked pool of 1,280 B
 rows (576 published values in 640 lanes), 48 layers, by live slots (16 / 24 /
 48 of 64) and by cached tokens (the ``mix`` lengths, and every live slot at
-1k / 4k), against the bytes and operations of the PUBLISHED row (1,152 B and
+1k / 4k; at 24 live slots 300 and 600 too: partly filled last blocks), against the bytes and operations of the PUBLISHED row (1,152 B and
 69,632 operations a cached token and layer), and the row write of the same
-pool (one ``paged_kv_write`` launch a layer over the live slots).
+pool (one ``paged_kv_write`` launch a layer over the live slots). With
+``--group 1,2,4,8`` (``--heads 32,64``): the launch alone over the
+long-context cells' table (160 pages a row, 24 of 64 slots live in groups of
+that many samples of one prompt of 4k-16k tokens, as the K/V ``--group`` rows):
+us a launch over ``shared_decode_schedule()``'s list (a shared block fetched
+once, its readers' query rows stacked a pass) and over one item a (slot,
+block), each with its share of the time of the published rows' bytes it
+fetches, blocks fetched of listed, and ``max_abs_diff`` between the two
+outputs; ``--group 1`` and the ``mix`` rows above say what a table without
+aliases costs.
 
 ``--only dsa``: the sparse read of a latent-attention layer with a learned
 index, at the long-context cell's shapes (64 slots, 64 query heads, 6 layers,
@@ -161,14 +170,12 @@ GROUP_SHAPES = {
 }
 
 
-def probe_group(name: str, group: int, *, seed: int, reps: int, ppcb: int) -> dict:
-    import jax
-    import jax.numpy as jnp
-
-    from areal_tpu.ops.paged_attention_q8 import decode_schedule, paged_attention_stacked, shared_decode_schedule
-
-    shape = GROUP_SHAPES[name]
-    S, KH, G, L, wp = (shape[k] for k in ("S", "KH", "G", "L", "wp"))
+def group_table(shape: dict, group: int, seed: int) -> tuple[np.ndarray, np.ndarray, int]:
+    """(lengths [S], page table [S, wp], pages used + 1) of a batch whose
+    live slots are groups of ``group`` samples of one prompt: every member's
+    row holds the first one's full prompt pages and pages of its own from
+    there, as ``SlotCache.alias`` leaves them."""
+    S, wp = shape["S"], shape["wp"]
     rng = np.random.default_rng(seed)
     live = rng.permutation(S)[: round(shape["live"] * S) // group * group]
     lengths, table = np.zeros(S, np.int32), np.zeros((S, wp), np.int32)
@@ -183,6 +190,19 @@ def probe_group(name: str, group: int, *, seed: int, reps: int, ppcb: int) -> di
             n = -(-int(lengths[b]) // PSZ) - shared
             table[b, shared : shared + n] = np.arange(free, free + n)
             free += n
+    return lengths, table, free
+
+
+def probe_group(name: str, group: int, *, seed: int, reps: int, ppcb: int) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops.paged_attention_q8 import decode_schedule, paged_attention_stacked, shared_decode_schedule
+
+    shape = GROUP_SHAPES[name]
+    S, KH, G, L, wp = (shape[k] for k in ("S", "KH", "G", "L", "wp"))
+    lengths, table, free = group_table(shape, group, seed)
+    live = np.flatnonzero(lengths)
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(kq, (S, KH * G, HD), jnp.dtype(shape.get("q_dtype", "bfloat16")))
     k = jax.random.normal(kk, (L, KH, free, PSZ, HD), jnp.bfloat16)
@@ -287,8 +307,9 @@ def probe_latent(*, seed: int, reps: int, ppcb: int, pages: int = 409) -> list[d
     write = jax.jit(write, donate_argnums=0)
     mix = draw_lengths(S, seed)
     sets = {"mix": mix, "empty": np.zeros_like(mix)}
-    for n_live in (16, 24, 48):
-        for tokens in (1024, 4000):
+    # 300 and 600: a slot's last block holds 3 pages or 1, so the copies are short and the item's own time shows
+    for n_live, sizes in ((16, (1024, 4000)), (24, (300, 600, 1024, 4000)), (48, (1024, 4000))):
+        for tokens in sizes:
             lengths = np.zeros(S, np.int32)
             lengths[rng.permutation(S)[:n_live]] = tokens
             sets[f"{n_live}x{tokens}"] = lengths
@@ -315,6 +336,69 @@ def probe_latent(*, seed: int, reps: int, ppcb: int, pages: int = 409) -> list[d
             "row_write_us": write_us,
         })
     return out
+
+
+# the long-context cells' table (rollout-xing4.0-29b-a4b-ep4-d10-longctx-grpo, 32 heads; 64: a GLM-5 without its index)
+LATENT_LONG = dict(S=64, L=4, wp=160, prompt=(4096, 16384), live=24 / 64, lanes=640, row=576, value=512)
+
+
+def probe_latent_group(H: int, group: int, *, seed: int, reps: int, ppcb: int) -> dict:
+    """us a launch of ``paged_latent_attn`` over a table whose live slots are
+    groups of ``group`` samples of one prompt: over the list that names each
+    distinct block once (its readers' query rows stacked) and over one item a
+    (slot, block), each with its share of the time of the bytes IT fetches
+    (the published row's), and the largest difference between the outputs."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops.paged_attention_q8 import DecodeItems, decode_schedule, shared_decode_schedule
+    from areal_tpu.ops.paged_latent_attention import paged_latent_attention_stacked
+
+    shape = LATENT_LONG
+    S, L, wp, lanes, row, value = (shape[k] for k in ("S", "L", "wp", "lanes", "row", "value"))
+    lengths, table, free = group_table(shape, group, seed)
+    kq, kp = jax.random.split(jax.random.PRNGKey(seed))
+    q = jax.random.normal(kq, (S, H, lanes), jnp.bfloat16)
+    pool = jax.random.normal(kp, (L, 1, free, PSZ, lanes), jnp.bfloat16)
+    lengths, table = jnp.asarray(lengths), jnp.asarray(table)
+
+    def lists(lengths, table):
+        return {
+            "shared": shared_decode_schedule(lengths, table, PSZ, ppcb)[0],
+            "a_slot": DecodeItems.private(decode_schedule(lengths, wp, PSZ, ppcb)),
+        }
+
+    def step(which, q, pool, lengths, table):
+        schedule = lists(lengths, table)[which]  # once a step
+
+        def layer(acc, li):
+            out = paged_latent_attention_stacked(
+                q * (1 + li).astype(q.dtype), pool, li % L, lengths, table,
+                value_lanes=value, pages_per_compute_block=ppcb, schedule=schedule, sm_scale=192**-0.5,
+            )
+            return acc + out, None
+
+        return jax.lax.scan(layer, jnp.zeros((S, H, value), jnp.float32), jnp.arange(2 * L))[0]
+
+    _, fetch = jax.jit(lambda le, t: shared_decode_schedule(le, t, PSZ, ppcb))(lengths, table)
+    res = {
+        "kernel": "paged_latent_attn", "heads": H, "group": group, "live_slots": int((np.asarray(lengths) > 0).sum()),
+        "cached_tokens": int(lengths.sum()), "blocks_listed": int(fetch.blocks_listed), "blocks_fetched": int(fetch.blocks),
+        "tokens_fetched": int(fetch.tokens),
+    }
+    outs = {}
+    steps = {"shared": jax.jit(functools.partial(step, "shared")), "a_slot": jax.jit(functools.partial(step, "a_slot"))}
+    for which, fn in steps.items():
+        outs[which] = fn(q, pool, lengths, table).block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out = fn(q, pool, lengths, table)
+        out.block_until_ready()
+        res[f"{which}_us"] = us = (time.perf_counter() - t0) / (reps * 2 * L) * 1e6
+        tokens = res["tokens_fetched"] if which == "shared" else res["cached_tokens"]
+        res[f"{which}_fetched_bytes_pct"] = 100 * (row * 2 * tokens / HBM_BYTES_S * 1e6) / us
+    res["max_abs_diff"] = float(jnp.max(jnp.abs(outs["shared"] - outs["a_slot"])))
+    return res
 
 
 DSA = dict(S=64, H=64, L=6, lanes=640, row=576, value=512, Hi=32, d=128, topk=2048, WP=160)  # rollout-glm-5-ep16-d6-longctx-grpo
@@ -573,13 +657,18 @@ def main() -> int:
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--ppcb", type=int, default=4, help="pages a compute block (the decode step's choice at this table: 4)")
     ap.add_argument("--only", choices=("latent", "dsa", "latent-prefill"), help="the latent-attention cell's launches alone, the sparse read's pieces, or the prompt pass's attention")
-    ap.add_argument("--group", help="readers a prompt, e.g. 1,2,4,8: paged_decode_attn alone over a table whose live slots are such groups")
+    ap.add_argument("--group", help="readers a prompt, e.g. 1,2,4,8: paged_decode_attn alone (with --only latent: paged_latent_attn) over a table whose live slots are such groups")
     ap.add_argument("--tokens", default="1024,4096,8192,16384", help="latent-prefill: the prompt lengths")
-    ap.add_argument("--heads", default="32,64", help="latent-prefill: 32 (the kanana cell's heads) and / or 64 (the GLM-5 cell's)")
+    ap.add_argument("--heads", default="32,64", help="latent-prefill, and latent with --group: 32 (the kanana and xing4 cells' heads) and / or 64 (the GLM-5 cell's)")
     args = ap.parse_args()
     if jax.default_backend() != "tpu":
         print("decode_attn_probe: needs a TPU (a CPU time is no speed)")
         return 2
+    if args.group and args.only == "latent":
+        for H in args.heads.split(","):
+            for group in args.group.split(","):
+                print(json.dumps(probe_latent_group(int(H), int(group), seed=args.seed, reps=args.reps, ppcb=args.ppcb)), flush=True)
+        return 0
     if args.group:
         for name in GROUP_SHAPES:
             for group in args.group.split(","):

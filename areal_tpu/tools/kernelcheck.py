@@ -289,16 +289,21 @@ def _cases_paged_latent(compiled: bool = False) -> Iterator[dict]:
     S, H, lanes, value, psz, wp, ppcb = (64, 32, 640, 512, 128, 32, 4) if compiled else (5, 4, 256, 128, 8, 4, 2)
     N = S * wp + 1
 
-    def case(label, layer, pages, seed=5):
+    def case(label, layer, pages, seed=5, group=1):
         def build():
             rng = np.random.default_rng(seed)
             lengths = rng.integers(1, wp * psz + 1, S).astype(np.int32)
             lengths[0], lengths[-1] = 0, wp * psz  # a slot no item names, and a full window
+            pt = 1 + rng.permutation(N - 1)[: S * wp].reshape(S, wp)
+            if group > 1:  # slots 1.. in groups of samples of one prompt: the first member's first half of the row in every row
+                lengths[1:-1] = rng.integers(wp // 2 * psz + 1, wp * psz + 1, S - 2)
+                for b in range(1, S - 1):
+                    pt[b, : wp // 2] = pt[1 + (b - 1) // group * group, : wp // 2]
             return {
                 "q": _normal(seed, (S, H, lanes), pages),
                 "pool": _normal(seed + 1, (L, 1, N, psz, lanes), pages),
                 "lengths": jnp.asarray(lengths),
-                "pt": jnp.asarray(1 + rng.permutation(N - 1)[: S * wp].reshape(S, wp), jnp.int32),
+                "pt": jnp.asarray(pt, jnp.int32),
             }
 
         def kernel(inp):
@@ -320,6 +325,9 @@ def _cases_paged_latent(compiled: bool = False) -> Iterator[dict]:
 
     for layer in (0, L - 1):
         yield case(f"latent-bf16-layer{layer}", layer, jnp.bfloat16)
+    # groups that share the first half of a row: the launch fetches those blocks once (3 readers: a pass of 2 and a
+    # pass of 1 beside an empty place at 32 heads; the compiled grid's 11: an item of 8 and one of 3)
+    yield case("latent-bf16-groups", 1, jnp.bfloat16, group=11 if compiled else 3)
     if not compiled:
         yield case("latent-f32-layer1", 1, jnp.float32)
 

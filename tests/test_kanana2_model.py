@@ -95,9 +95,11 @@ def test_prefill_then_decode_through_latent_pages_agrees_with_the_reference(mode
         for slot, row in ((0, 0), (2, 1)):
             if active[slot]:
                 np.testing.assert_allclose(logits[slot], want[row][pos[slot]], atol=TOL, rtol=0)
-        read += int(((pos + 1) * active).sum())
+        # the gather path counts the live slots' cached tokens; the launch what it FETCHES: every slot's that holds pages,
+        # an ended one's among them until its pages go (since PR 54: ops/paged_attention_q8.py DecodeFetch.tokens)
+        read += int(((pos + 1) * (pt[:, 0] != 0 if use_kernel else active)).sum())
         pos = pos + active
-    # every latent layer read the live slots' cached tokens, this step's own row among them
+    # every latent layer read those cached tokens, this step's own row among them
     assert np.asarray(cache["latent_tokens_read"]).tolist() == [read] * 4
     assert np.asarray(cache["k"])[:, 0, 0].any() == 0 or not use_kernel  # the kernel's writer never touches the trash page
 

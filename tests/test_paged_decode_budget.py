@@ -87,6 +87,60 @@ def test_the_launch_lowers_for_a_tpu_from_the_cpu():
     assert "tpu_custom_call" in text and "paged_decode_attn" in text
 
 
+# ``paged_latent_attn`` since PR 54 (a shared block of latent rows fetched once, its readers' query rows stacked a
+# pass, in passes of two sizes): cell's shape -> (slots, heads, pages a row, with a selection), equations of the
+# launch at PR 53 (one item a (slot, block), a body of jnp operators: 20 jitted calls inside the kernel) and of its
+# kernel alone, and a ceiling a dozen over this PR's own 689 / 689 / 229, all with the work list made inside the call.
+# What a launch SITE traces is the kernel (491 against 335: under ISSUE 54's 1.6 x); the list is made once a step
+# (``shared_decode_schedule``: 198 equations where ``decode_schedule`` is 34), so the whole call is held to 2 x
+LATENT_SHAPES = {
+    "xing4.0-29b-a4b": ((64, 32, 160, False), (369, 335), 700),
+    "kanana-2-30b-a3b": ((64, 32, 32, False), (369, 335), 700),
+    "glm-5": ((64, 64, 160, True), (379, 344), 240),
+}
+
+
+def latent_launch(shape):
+    from areal_tpu.ops.paged_latent_attention import paged_latent_attention_stacked
+
+    S, H, wp, selected = shape
+    sds = jax.ShapeDtypeStruct
+    args = [sds((S, H, 640), jnp.bfloat16), sds((2, 1, 65, PSZ, 640), jnp.bfloat16), sds((), jnp.int32), sds((S,), jnp.int32), sds((S, wp), jnp.int32)]
+    if selected:
+        args.append(sds((S, wp * PSZ), jnp.bool_))
+
+    def fn(q, pool, li, lengths, table, select=None):
+        return paged_latent_attention_stacked(
+            q, pool, li, lengths, table, value_lanes=512, pages_per_compute_block=4, sm_scale=0.07, select=select
+        )
+
+    return fn, args
+
+
+@pytest.mark.parametrize("cell", LATENT_SHAPES)
+def test_the_latent_launch_holds_its_equation_budget(cell):
+    shape, (parent, parent_kernel), ceiling = LATENT_SHAPES[cell]
+    fn, args = latent_launch(shape)
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    n = count(jaxpr)
+    assert n <= 2 * parent, f"{n} equations, over 2 x the {parent} of the launch that fetched a block a slot"
+    assert n <= ceiling, f"{n} equations: the launch has grown past what PR 54 pinned ({ceiling})"
+    # QK and PV in three shapes (an item of one slot, the two sizes of a pass of stacked readers) and the work list's
+    # two small products; under a selection the launch walks no shared item
+    assert count(jaxpr, "dot_general") == (2 if shape[3] else 8)
+    assert count(jaxpr, "pallas_call") == 1
+    kernel = next(e for e in jaxpr.eqns if e.primitive.name == "pallas_call").params["jaxpr"]
+    assert count(kernel) <= 1.6 * parent_kernel, f"{count(kernel)} equations a launch site, over 1.6 x the parent kernel's {parent_kernel}"
+    assert count(kernel, "jit") + count(kernel, "pjit") == 0  # ``jax.lax`` primitives only in the body
+
+
+@pytest.mark.parametrize("cell", LATENT_SHAPES)
+def test_the_latent_launch_lowers_for_a_tpu_from_the_cpu(cell):
+    fn, args = latent_launch(LATENT_SHAPES[cell][0])
+    text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text and "paged_latent_attn" in text
+
+
 # ``kda_prompt_scan`` (PR 48) at the published sizes, a block of 1,024 tokens of 64 heads of 128 x 128: the XLA form
 # it stands in for is 577 equations a launch site (``hybrid.kda_chunked_scan``); the launch's walk over the 16 key
 # offsets is ONE traced body (written out 16 times, with 4 heads a grid step, it was 4,239)
