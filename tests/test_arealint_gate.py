@@ -8,6 +8,8 @@ written reason (``python -m areal_tpu.tools.arealint --write-baseline``
 then fill in the reason field).
 """
 
+import functools
+
 import pytest
 
 from areal_tpu.analysis import (
@@ -24,6 +26,12 @@ def package_result():
     return run_analysis(
         [default_package_root()], baseline_path=default_baseline_path()
     )
+
+
+@functools.lru_cache(maxsize=None)
+def _scoped_result(*rules: str):
+    """One whole-package scan a rule scope, shared by the scope's two tests."""
+    return run_analysis([default_package_root()], rules=list(rules), baseline_path=default_baseline_path())
 
 
 def test_package_is_clean_against_baseline(package_result):
@@ -70,11 +78,7 @@ def test_wire_lck_enforced_repo_wide():
     """ISSUE 15: the distributed control plane's wire contract and lock
     ordering are tier-1-clean — a scoped run so a WIRE/LCK regression
     names the family even if another family also broke."""
-    res = run_analysis(
-        [default_package_root()],
-        rules=["WIRE", "LCK"],
-        baseline_path=default_baseline_path(),
-    )
+    res = _scoped_result("WIRE", "LCK")
     assert res.files_checked > 100
     assert not res.findings, "WIRE/LCK findings:\n" + "\n".join(
         f.render() for f in res.findings
@@ -85,11 +89,7 @@ def test_wire_lck_suppressions_carry_written_reasons():
     """No blanket burn-down: every inline WIRE/LCK suppression in the
     package must say WHY the finding is acceptable (e.g. the etcd /v3/*
     routes belong to an external server)."""
-    res = run_analysis(
-        [default_package_root()],
-        rules=["WIRE", "LCK"],
-        baseline_path=default_baseline_path(),
-    )
+    res = _scoped_result("WIRE", "LCK")
     from areal_tpu.analysis.core import SourceFile
 
     bare = []
@@ -125,11 +125,7 @@ def test_krn_pvt_msh_enforced_repo_wide():
     items 2-3). PVT here re-verifies every pinned private-API signature
     against the INSTALLED jax, so this test is also the early-warning
     trip-wire for the next jax bump."""
-    res = run_analysis(
-        [default_package_root()],
-        rules=["KRN", "PVT", "MSH"],
-        baseline_path=default_baseline_path(),
-    )
+    res = _scoped_result("KRN", "PVT", "MSH")
     assert res.files_checked > 100
     assert not res.findings, "KRN/PVT/MSH findings:\n" + "\n".join(
         f.render() for f in res.findings
@@ -140,11 +136,7 @@ def test_krn_pvt_msh_suppressions_carry_written_reasons():
     """No blanket burn-down: every inline KRN/PVT/MSH suppression in the
     package must say WHY (e.g. jax_compat's raw constraint IS the shim
     the MSH003 rule tells everyone else to route through)."""
-    res = run_analysis(
-        [default_package_root()],
-        rules=["KRN", "PVT", "MSH"],
-        baseline_path=default_baseline_path(),
-    )
+    res = _scoped_result("KRN", "PVT", "MSH")
     from areal_tpu.analysis.core import SourceFile
 
     bare = []

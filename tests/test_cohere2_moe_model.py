@@ -31,44 +31,14 @@ from benchlib import cohere2_moe_reference as ref  # noqa: E402
 from benchlib import cohere2_moe_weights  # noqa: E402
 
 from areal_tpu import models  # noqa: E402
-from areal_tpu.inference import paged_kv  # noqa: E402
 from areal_tpu.models import hybrid  # noqa: E402
+from tests.family_harness import program_logits, through_the_cache  # noqa: E402
 from areal_tpu.ops.window_prefill_attention import band_tiles, swa_prefill_flash  # noqa: E402
 
 PSZ = 8
 W = cu.WINDOW
 TOL = 2e-5
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-
-
-def _through_the_cache(mcfg, params, ids, n_prompt, bucket, slot=1, slots=3):
-    """Prefill ``ids[:n_prompt]`` padded to ``bucket`` into slot ``slot`` of a
-    fresh cache, then decode the rest one token a step through the paged
-    path (gather form). Returns (logits of every decode step, the cache)."""
-    wp = max(-(-len(ids) // PSZ), bucket // PSZ)
-    cache = paged_kv.init_paged_cache(mcfg, slots * wp + 1, PSZ, slots=slots)
-    pt = jnp.asarray(1 + np.arange(slots * wp).reshape(slots, wp), jnp.int32)
-    row = np.zeros((1, bucket), np.int32)
-    row[0, :n_prompt] = ids[:n_prompt]
-    row[0, n_prompt:] = 7  # the padding is real tokens: only the masks keep it out
-    cache = hybrid.prefill_into_cache(
-        params, mcfg, cache, jnp.asarray(row), jnp.asarray([n_prompt], jnp.int32),
-        pt[slot, : bucket // PSZ], jnp.asarray([slot], jnp.int32), page_size=PSZ,
-    )
-    active = jnp.arange(slots) == slot
-    logits = []
-
-    @jax.jit
-    def step(tok, pos, cache):
-        hid, cache = hybrid.forward_decode_paged(params, mcfg, tok, pos, cache, pt, page_size=PSZ, active=active, use_kernel=False)
-        return hybrid.compute_logits(params, mcfg, hid), cache
-
-    for t in range(n_prompt - 1, len(ids)):  # decode feeds the prompt's last token first
-        tok = jnp.zeros((slots,), jnp.int32).at[slot].set(int(ids[t]))
-        pos = jnp.zeros((slots,), jnp.int32).at[slot].set(t)
-        out, cache = step(tok, pos, cache)
-        logits.append(np.asarray(out)[slot])
-    return np.stack(logits), cache
 
 
 def test_the_layernorm_has_a_weight_and_no_bias():
@@ -97,7 +67,7 @@ def test_one_parallel_block_against_the_reference(kind):
     params = cu.make_params(cfg, 2)
     ids = np.random.default_rng(0).integers(0, cfg["vocab_size"], 40)
     want = ref.logits(params, cfg, ids)
-    assert np.abs(cu.program_logits(cfg, params, ids) - want).max() < TOL and np.abs(want).max() > 0.3
+    assert np.abs(program_logits(cu.model_config(cfg), params, ids) - want).max() < TOL and np.abs(want).max() > 0.3
     x = params["embed"][ids].astype(jnp.float32)
     lp = ref.layer_params(params, cfg, 0)
     d = ref.dims(cfg)
@@ -115,7 +85,7 @@ def test_full_forward_matches_reference(n):
     params = cu.make_params(cfg, 11)
     ids = np.random.default_rng(n).integers(0, cfg["vocab_size"], n)
     want = ref.logits(params, cfg, ids)
-    assert np.abs(cu.program_logits(cfg, params, ids) - want).max() < TOL and np.abs(want).max() > 0.3
+    assert np.abs(program_logits(cu.model_config(cfg), params, ids) - want).max() < TOL and np.abs(want).max() > 0.3
     if n > W:  # the mechanisms are there to be lost: no rotary embedding, a window one token short
         assert np.abs(ref.logits(params, cfg, ids, rope=False) - want).max() > 1e-2
         assert np.abs(ref.logits(params, cfg, ids, window=W - 1) - want).max() > 1e-3
@@ -134,7 +104,7 @@ def test_prefill_then_paged_decode_through_the_rings_matches_the_reference(n_pro
     assert mcfg.layer_types == ("swa", "swa", "swa", "attention") and set(mcfg.ffns) == {"moe"}
     ids = np.random.default_rng(1).integers(0, cfg["vocab_size"], total)
     want = ref.logits(params, cfg, ids)
-    got, cache = _through_the_cache(mcfg, params, ids, n_prompt, bucket)
+    got, cache = through_the_cache(mcfg, params, ids, n_prompt, bucket, page_size=PSZ)
     assert np.abs(got - want[n_prompt - 1 :]).max() < TOL
     assert cache["ring_k"].shape == (3, 4, 4, 2, PSZ, 128) and set(cache) == {"k", "v", "ring_k", "ring_v"}
     assert not np.asarray(cache["ring_k"][:, :, 0]).any() and not np.asarray(cache["ring_k"][:, :, 2]).any()  # the other slots' rings
@@ -334,5 +304,5 @@ def test_the_scaled_output_projection_moves_no_other_leaf():
             assert same == (leaf != "wo"), (stack, leaf)
         np.testing.assert_allclose(np.asarray(scaled[stack]["wo"]), 0.125 * np.asarray(drawn[stack]["wo"]), rtol=1e-6)
     seq = np.random.default_rng(3).integers(0, cfg["vocab_size"], 40)
-    assert np.abs(cu.program_logits(cfg, scaled, seq) - ref.logits(scaled, cfg, seq)).max() < TOL
+    assert np.abs(program_logits(cu.model_config(cfg), scaled, seq) - ref.logits(scaled, cfg, seq)).max() < TOL
     assert cu.model_config(cfg) == cu.model_config(plain)

@@ -1,6 +1,7 @@
 """DecodeEngine correctness: incremental KV decode == full forward; abort /
 pause / weight-update protocol (replaces reference test_inference_engines.py)."""
 
+import functools
 import threading
 import time
 
@@ -35,15 +36,24 @@ def _make_engine(n_slots=4, max_len=256, steps=8, mesh=None):
     return eng
 
 
+@functools.lru_cache(maxsize=None)
+def _full_forward(cfg):
+    return jax.jit(lambda params, ids, seg, pos: qwen.compute_logits(params, cfg, qwen.forward(params, cfg, ids, seg, pos)))
+
+
 def _naive_greedy(params, cfg, prompt, n_new):
+    """Greedy decoding by a full forward over the tokens so far, a token a
+    forward. Every forward is ONE program: the row is padded to its final
+    length (padding is segment 0, after every real token of a causal pass) and
+    the logits are read at the last real token."""
+    total = len(prompt) + n_new
+    pos = np.arange(total, dtype=np.int32)[None]
     ids = list(prompt)
     for _ in range(n_new):
-        a = np.asarray(ids, np.int32)[None]
-        seg = np.ones_like(a)
-        pos = np.arange(len(ids), dtype=np.int32)[None]
-        h = qwen.forward(params, cfg, a, seg, pos)
-        logits = qwen.compute_logits(params, cfg, h)
-        ids.append(int(np.argmax(np.asarray(logits)[0, -1])))
+        a = np.zeros((1, total), np.int32)
+        a[0, : len(ids)] = ids
+        logits = _full_forward(cfg)(params, a, (pos < len(ids)).astype(np.int32), pos)
+        ids.append(int(np.argmax(np.asarray(logits)[0, len(ids) - 1])))
     return ids[len(prompt):]
 
 

@@ -28,8 +28,8 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "benchmark_harness"))
 import chipbench_glm5_util as gu  # noqa: E402
 
-from areal_tpu.inference import paged_kv  # noqa: E402
 from areal_tpu.models import hybrid  # noqa: E402
+from tests.family_harness import decode_step, fresh_cache, prefill_into_slot, program_logits, with_counts  # noqa: E402
 
 TOL = 2e-5
 PSZ, WP = 8, 12
@@ -63,7 +63,7 @@ def test_prefill_forward_agrees_with_the_reference_where_the_selection_prunes(mo
     ref = _reference()
     want = ref.logits(params, cfg, ids)
     assert want.shape == (77, 500) and want.std() > 0.05
-    got = gu.program_logits(cfg, params, ids)
+    got = program_logits(mcfg, params, ids)
     np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
     # the selection is no formality here: with index_topk past the context (everything selected) the logits differ
     loose = ref.logits(params, {**cfg, "index_topk": 128}, ids)
@@ -116,27 +116,17 @@ def test_prefill_then_decode_through_both_pools_agrees_with_the_reference(model,
     want = [ref.logits(params, cfg, s) for s in seqs]
     S = 3
     assert mcfg.kv_pools == {"k": (1, 256), "idx": (1, 128)} and mcfg.index_topk == 16
-    cache = paged_kv.init_paged_cache(mcfg, S * WP + 1, PSZ, slots=S)
+    cache, pt = fresh_cache(mcfg, S, WP, PSZ)
     assert {n: a.shape for n, a in cache.items()} == {"k": (3, 1, S * WP + 1, PSZ, 256), "idx": (3, 1, S * WP + 1, PSZ, 128)}
-    pt = np.zeros((S, WP), np.int32)
-    pt[0], pt[1] = np.arange(1, WP + 1), np.arange(WP + 1, 2 * WP + 1)
-    bucket = 40
-    ids = np.zeros((2, bucket), np.int32)
-    for i, p in enumerate(plens):
-        ids[i, :p] = seqs[i][:p]
-    flat = np.concatenate([pt[i, : bucket // PSZ] for i in range(2)])
-    cache = hybrid.prefill_into_cache(
-        params, mcfg, cache, jnp.asarray(ids), jnp.asarray(plens), jnp.asarray(flat), jnp.asarray([0, 1]), page_size=PSZ
-    )
-    cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
-    step = jax.jit(functools.partial(hybrid.forward_decode_paged, page_size=PSZ, use_kernel=use_kernel), static_argnums=1)
-    active = jnp.array([True, True, False])
+    pt[2] = 0
+    cache = with_counts(mcfg, prefill_into_slot(mcfg, params, cache, pt, [(i, seqs[i][:p]) for i, p in enumerate(plens)], 40, PSZ))
+    step, pt, active = decode_step(mcfg, PSZ, use_kernel), jnp.asarray(pt), jnp.array([True, True, False])
     worst = 0.0
     for t in range(new):
         tok = jnp.array([seqs[0][plens[0] - 1 + t], seqs[1][plens[1] - 1 + t], 0])
         pos = jnp.array([plens[0] - 1 + t, plens[1] - 1 + t, 0])
-        hidden, cache = step(params, mcfg, tok, pos, cache, jnp.asarray(pt), active=active)
-        logits = np.asarray(hybrid.compute_logits(params, mcfg, hidden))
+        logits, cache = step(params, tok, pos, cache, pt, active)
+        logits = np.asarray(logits)
         for i in range(2):
             worst = max(worst, np.abs(logits[i] - want[i][plens[i] - 1 + t]).max())
     assert worst < TOL, worst

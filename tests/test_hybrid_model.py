@@ -25,6 +25,7 @@ import chipbench_hybrid_util as hu  # noqa: E402
 
 from areal_tpu import models  # noqa: E402
 from areal_tpu.models import hybrid, qwen  # noqa: E402
+from tests.family_harness import prefill_forward  # noqa: E402
 
 IRREGULAR = ("mamba", "mamba", "attention", "mamba", "attention")
 
@@ -85,14 +86,14 @@ def test_prefill_state_is_the_state_before_the_masked_tokens():
     ids = rng.integers(0, cfg["vocab_size"], (4, 48))
     seg = (np.arange(48)[None] < np.asarray(lens)[:, None]).astype(np.int32)
     n_state = jnp.asarray(lens, jnp.int32) - 1
-    _, ks, _, st = hybrid.forward_prefill(params, mcfg, jnp.asarray(ids), jnp.asarray(seg), n_state=n_state)
+    _, (ks, _, st) = prefill_forward(mcfg)(params, jnp.asarray(ids), jnp.asarray(seg), n_state)
     assert ks.shape == (1, 4, 48, 2, 128) and not np.asarray(ks[..., 16:]).any()  # lane padding
     for j, n in enumerate(lens):
         if n == 1:
             assert not np.asarray(st["ssm"][:, j]).any() and not np.asarray(st["conv"][:, j]).any()
             continue
         alone = jnp.asarray(ids[j : j + 1, : n - 1])
-        _, _, _, want = hybrid.forward_prefill(params, mcfg, alone, jnp.ones_like(alone))
+        _, (_, _, want) = prefill_forward(mcfg)(params, alone, jnp.ones_like(alone))
         np.testing.assert_allclose(st["ssm"][:, j], want["ssm"][:, 0], rtol=1e-4, atol=1e-6)
         np.testing.assert_allclose(st["conv"][:, j], want["conv"][:, 0], rtol=1e-4, atol=1e-6)
 

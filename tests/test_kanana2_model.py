@@ -25,6 +25,7 @@ import chipbench_kanana2_util as ku  # noqa: E402
 
 from areal_tpu.inference import paged_kv  # noqa: E402
 from areal_tpu.models import hybrid  # noqa: E402
+from tests.family_harness import decode_step, fresh_cache, prefill_into_slot, program_logits, with_counts  # noqa: E402
 
 TOL = 2e-5
 PSZ, WP = 8, 8
@@ -56,7 +57,7 @@ def test_prefill_forward_agrees_with_the_reference(model):
     ids = np.random.default_rng(0).integers(0, cfg["vocab_size"], 45)
     want = _reference().logits(params, cfg, ids)
     assert want.shape == (45, 500) and want.std() > 0.05
-    np.testing.assert_allclose(ku.program_logits(cfg, params, ids), want, atol=TOL, rtol=0)
+    np.testing.assert_allclose(program_logits(mcfg, params, ids), want, atol=TOL, rtol=0)
 
 
 @pytest.mark.parametrize("use_kernel", [False, True], ids=["gather", "kernel"])
@@ -73,25 +74,18 @@ def test_prefill_then_decode_through_latent_pages_agrees_with_the_reference(mode
     T, plens, S = 41, (13, 24), 3
     ids = rng.integers(0, cfg["vocab_size"], (2, T)).astype(np.int32)
     want = [_reference().logits(params, cfg, row) for row in ids]
-    cache = paged_kv.init_paged_cache(mcfg, S * WP + 1, PSZ, slots=S)
+    cache, pt = fresh_cache(mcfg, S, WP, PSZ)
     assert set(cache) == {"k"} and cache["k"].shape == (4, 1, S * WP + 1, PSZ, 256)  # one latent row, no V pool, no state
-    pt = np.zeros((S, WP), np.int32)
-    pt[0], pt[2] = np.arange(1, WP + 1), np.arange(WP + 1, 2 * WP + 1)
-    bucket = 24
-    pids = np.zeros((2, bucket), np.int32)
-    for r, n in enumerate(plens):
-        pids[r, :n] = ids[r, :n]
-    flat = np.concatenate([pt[0, : bucket // PSZ], pt[2, : bucket // PSZ]])
-    cache = hybrid.prefill_into_cache(params, mcfg, cache, jnp.asarray(pids), jnp.asarray(plens), jnp.asarray(flat), jnp.asarray([0, 2]), page_size=PSZ)
-    cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
-    step = jax.jit(functools.partial(hybrid.forward_decode_paged, page_size=PSZ, use_kernel=use_kernel), static_argnums=1)
+    pt[1] = 0
+    cache = with_counts(mcfg, prefill_into_slot(mcfg, params, cache, pt, [(0, ids[0, : plens[0]]), (2, ids[1, : plens[1]])], 24, PSZ))
+    step = decode_step(mcfg, PSZ, use_kernel)
     pos = np.array([plens[0] - 1, 0, plens[1] - 1])
     read = 0
     while (pos[[0, 2]] < T - 1).any():
         active = np.array([pos[0] < T - 1, False, pos[2] < T - 1])
         cur = np.array([ids[0, min(pos[0], T - 1)], 0, ids[1, min(pos[2], T - 1)]], np.int32)
-        hidden, cache = step(params, mcfg, jnp.asarray(cur), jnp.asarray(pos), cache, jnp.asarray(pt), active=jnp.asarray(active))
-        logits = np.asarray(hybrid.compute_logits(params, mcfg, hidden))
+        logits, cache = step(params, jnp.asarray(cur), jnp.asarray(pos), cache, jnp.asarray(pt), jnp.asarray(active))
+        logits = np.asarray(logits)
         for slot, row in ((0, 0), (2, 1)):
             if active[slot]:
                 np.testing.assert_allclose(logits[slot], want[row][pos[slot]], atol=TOL, rtol=0)

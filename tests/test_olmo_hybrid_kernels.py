@@ -27,6 +27,7 @@ from areal_tpu.inference import paged_kv  # noqa: E402
 from areal_tpu.models import hybrid  # noqa: E402
 from areal_tpu.ops import gdn_state_update as gsu  # noqa: E402
 from areal_tpu.ops.paged_attention_q8 import live_order  # noqa: E402
+from tests.family_harness import decode_step  # noqa: E402
 
 
 @pytest.mark.parametrize("dtype,H,K,V", [("float32", 4, 24, 64), ("float32", 2, 16, 128), ("float32", 6, 8, 192), ("bfloat16", 4, 24, 64)])
@@ -88,8 +89,8 @@ def test_decode_step_with_the_kernels_matches_the_gather_path(monkeypatch):
     outs = {}
     for uk in (True, False):
         c = {**cache, "gdn_updates": jnp.zeros((6,), jnp.int32)}
-        hid, new = hybrid.forward_decode_paged(params, mcfg, ids, pos, c, pt, page_size=psz, active=active, use_kernel=uk)
-        outs[uk] = (np.asarray(hybrid.compute_logits(params, mcfg, hid)), jax.tree.map(np.asarray, new))
+        logits, new = decode_step(mcfg, psz, uk)(params, ids, pos, c, pt, active)
+        outs[uk] = (np.asarray(logits), jax.tree.map(np.asarray, new))
     live = np.asarray(active)
     np.testing.assert_allclose(outs[True][0][live], outs[False][0][live], rtol=1e-4, atol=1e-5)
     for name in ("gdn", "conv"):

@@ -25,35 +25,10 @@ load_run()
 from benchlib import olmo_hybrid_reference as ref  # noqa: E402
 
 from areal_tpu import models  # noqa: E402
-from areal_tpu.inference import paged_kv  # noqa: E402
 from areal_tpu.models import hybrid  # noqa: E402
+from tests.family_harness import prefill_forward, through_the_cache  # noqa: E402
 
 PSZ = 16
-
-
-def _through_the_cache(cfg, mcfg, params, ids, n_prompt, bucket, slot=1, slots=3):
-    """Prefill ``ids[:n_prompt]`` padded to ``bucket`` into slot ``slot`` of a
-    fresh cache, then decode the rest one token a step through the paged
-    path (gather form). Returns (logits of every decode step [n - n_prompt +
-    1, V], the cache)."""
-    wp = max(-(-len(ids) // PSZ), bucket // PSZ)
-    cache = paged_kv.init_paged_cache(mcfg, slots * wp + 1, PSZ, slots=slots)
-    pt = jnp.asarray(1 + np.arange(slots * wp).reshape(slots, wp), jnp.int32)
-    row = np.zeros((1, bucket), np.int32)
-    row[0, :n_prompt] = ids[:n_prompt]
-    row[0, n_prompt:] = 7  # the padding is real tokens: only the masks keep it out
-    cache = hybrid.prefill_into_cache(
-        params, mcfg, cache, jnp.asarray(row), jnp.asarray([n_prompt], jnp.int32),
-        pt[slot, : bucket // PSZ], jnp.asarray([slot], jnp.int32), page_size=PSZ,
-    )
-    active = jnp.arange(slots) == slot
-    logits = []
-    for t in range(n_prompt - 1, len(ids)):  # decode feeds the prompt's last token first
-        tok = jnp.zeros((slots,), jnp.int32).at[slot].set(int(ids[t]))
-        pos = jnp.zeros((slots,), jnp.int32).at[slot].set(t)
-        hid, cache = hybrid.forward_decode_paged(params, mcfg, tok, pos, cache, pt, page_size=PSZ, active=active, use_kernel=False)
-        logits.append(np.asarray(hybrid.compute_logits(params, mcfg, hid))[slot])
-    return np.stack(logits), cache
 
 
 @pytest.mark.parametrize("n", [5, 64, 150])
@@ -65,8 +40,8 @@ def test_full_forward_matches_reference(n):
     ids = np.random.default_rng(n).integers(0, cfg["vocab_size"], n)
     want = ref.token_logits(params, cfg, ids)
     x = jnp.asarray(ids)[None]
-    hidden, _, _, state = hybrid.forward_prefill(params, mcfg, x, jnp.ones_like(x))
-    got = np.asarray(hybrid.compute_logits(params, mcfg, hidden))[0]
+    logits, (_, _, state) = prefill_forward(mcfg)(params, x, jnp.ones_like(x))
+    got = np.asarray(logits)[0]
     assert np.abs(got - want).max() < 2e-4 and np.abs(want).max() > 0.3
     s_ref = ref.first_layer_state(params, cfg, ids, pad_to=256)
     assert ou.rel(ou.first_state(mcfg, state, 0), s_ref) < 1e-5
@@ -81,11 +56,11 @@ def test_prefill_then_paged_decode_matches_reference():
     mcfg, params = ou.model_config(cfg), ou.make_params(cfg, 3)
     ids = np.random.default_rng(1).integers(0, cfg["vocab_size"], 67)
     want = ref.token_logits(params, cfg, ids)
-    got, cache = _through_the_cache(cfg, mcfg, params, ids, 37, 64)
+    got, cache = through_the_cache(mcfg, params, ids, 37, 64, page_size=PSZ)
     assert np.abs(got - want[36:]).max() < 2e-4
     assert ou.rel(ou.first_state(mcfg, cache, 1), ref.first_layer_state(params, cfg, ids, pad_to=256)) < 1e-5
     assert not np.asarray(cache["gdn"][:, 0]).any() and not np.asarray(cache["gdn"][:, 2]).any()  # the other slots' rows
-    _, cache0 = _through_the_cache(cfg, mcfg, params, ids[:37], 37, 64)  # one step: the prompt's last token
+    _, cache0 = through_the_cache(mcfg, params, ids[:37], 37, 64, page_size=PSZ)  # one step: the prompt's last token
     assert ou.rel(ou.first_state(mcfg, cache0, 1), ref.first_layer_state(params, cfg, ids[:37], pad_to=256)) < 1e-5
 
 
@@ -93,7 +68,7 @@ def test_a_bfloat16_state_fails_the_state_tolerance():
     cfg = ou.tiny_model()
     mcfg, params = ou.model_config(cfg, gdn_state_dtype="bfloat16"), ou.make_params(cfg, 3)
     ids = np.random.default_rng(1).integers(0, cfg["vocab_size"], 67)
-    _, cache = _through_the_cache(cfg, mcfg, params, ids, 37, 64)
+    _, cache = through_the_cache(mcfg, params, ids, 37, 64, page_size=PSZ)
     assert cache["gdn"].dtype == jnp.bfloat16
     assert ou.rel(ou.first_state(mcfg, cache, 1), ref.first_layer_state(params, cfg, ids, pad_to=256)) > 1e-3
 

@@ -18,6 +18,7 @@ load_run()
 from benchlib import olmo_hybrid_reference as ref  # noqa: E402
 
 from areal_tpu.models import hybrid  # noqa: E402
+from tests.family_harness import prefill_forward  # noqa: E402
 
 
 @pytest.mark.parametrize("n_prompt,bucket", [(2, 16), (33, 64), (64, 64)])
@@ -29,11 +30,11 @@ def test_a_padded_prompt_leaves_the_state_of_its_last_real_token(n_prompt, bucke
     mcfg, params = ou.model_config(cfg), ou.make_params(cfg, 7)
     ids = np.random.default_rng(n_prompt).integers(0, cfg["vocab_size"], n_prompt)
     x = jnp.asarray(ids[: n_prompt - 1])[None]
-    _, _, _, exact = hybrid.forward_prefill(params, mcfg, x, jnp.ones_like(x))
+    _, (_, _, exact) = prefill_forward(mcfg)(params, x, jnp.ones_like(x))
     row = np.full((1, bucket), 7, np.int32)
     row[0, :n_prompt] = ids
     seg = (np.arange(bucket)[None] < n_prompt).astype(np.int32)
-    _, _, _, padded = hybrid.forward_prefill(params, mcfg, jnp.asarray(row), jnp.asarray(seg), n_state=jnp.asarray([n_prompt - 1]))
+    _, (_, _, padded) = prefill_forward(mcfg)(params, jnp.asarray(row), jnp.asarray(seg), jnp.asarray([n_prompt - 1]))
     for leaf in ("gdn", "conv"):  # every layer's, to the rounding of another chunk split carried down 6 layers (1e-5 measured)
         assert ou.rel(np.asarray(padded[leaf], np.float64), np.asarray(exact[leaf], np.float64)) < 5e-5
     assert ou.rel(ou.first_state(mcfg, padded, 0), ref.first_layer_state(params, cfg, ids[: n_prompt - 1], pad_to=256)) < 1e-5

@@ -4,8 +4,16 @@ serving dtypes (bf16 queries on bf16, int8 and fp8 pages, bf16 MXU operands
 with f32 accumulation), the benchmark cells' head groupings (2 x 6, 4 x 7 and
 olmo's 30 KV heads of one query row), a narrow and a wide page table, and
 every length at which the block walk changes shape.
+
+Every launch of this file and its two neighbours (test_paged_decode_launch.py,
+test_paged_decode_shared.py) goes through ``LAUNCH``, one ``jax.jit`` of the
+entry point: called eagerly a Pallas launch is traced, lowered and compiled
+anew at EVERY call (3-7 s under the interpreter, 2 ms to run), jitted once a
+shape, as the engine's programs hold it. Cases of one shape then share one
+compile, so the work lists are padded with ended slots to one batch size.
 """
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -19,6 +27,7 @@ from areal_tpu.ops.paged_attention_q8 import paged_attention_stacked
 ATOL = 1e-2
 PSZ, HD, L = 16, 128, 2
 PAGES = {"bf16": jnp.bfloat16, "int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
+LAUNCH = jax.jit(paged_attention_stacked, static_argnames=("pages_per_compute_block", "sm_scale", "interpret"))
 
 
 def edge_lengths(wp: int, bk: int) -> np.ndarray:
@@ -62,7 +71,7 @@ def reference(inp, layer):
 
 
 def check(inp, ppcb, layer=1, atol=ATOL):
-    out = paged_attention_stacked(
+    out = LAUNCH(
         inp["q"], inp["k"], inp["v"], jnp.int32(layer), inp["lengths"], inp["pt"],
         pages_per_compute_block=ppcb, interpret=True, **inp["scales"],
     )
@@ -96,7 +105,11 @@ WALKS = {
 
 
 def walk_lengths(walk: str, wp: int, bk: int) -> np.ndarray:
-    return np.asarray(WALKS[walk](wp, bk), np.int32)
+    """The walk's lengths, then ended slots up to the nine of ``edges``: an
+    ended slot adds no item to the list, and every walk of one block size is
+    one traced launch."""
+    lengths = np.asarray(WALKS[walk](wp, bk), np.int32)
+    return np.pad(lengths, (0, 9 - len(lengths)))
 
 
 @pytest.mark.parametrize("pages,G,KH", [("bf16", 6, 2), ("int8", 7, 4)])

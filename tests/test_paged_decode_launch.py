@@ -10,13 +10,13 @@ import jax.numpy as jnp
 import pytest
 
 from areal_tpu.inference import paged_kv
-from areal_tpu.ops.paged_attention_q8 import decode_schedule, paged_attention_stacked
-from tests.test_paged_decode_kernel import PAGES, PSZ, WALKS, build, check, edge_lengths, walk_lengths
+from areal_tpu.ops.paged_attention_q8 import decode_schedule
+from tests.test_paged_decode_kernel import LAUNCH, PAGES, PSZ, WALKS, build, check, edge_lengths, walk_lengths
 
 
 def test_all_empty_launch_returns_zeros():
     inp = build(6, 2, 4, jnp.bfloat16, np.zeros(5, np.int32))
-    out = paged_attention_stacked(
+    out = LAUNCH(
         inp["q"], inp["k"], inp["v"], jnp.int32(0), inp["lengths"], inp["pt"],
         pages_per_compute_block=2, interpret=True,
     )
@@ -68,7 +68,7 @@ def test_a_given_schedule_must_fit_and_changes_nothing():
     wp = 4
     inp = build(6, 2, wp, jnp.int8, edge_lengths(wp, 2 * PSZ), seed=3)
     call = functools.partial(
-        paged_attention_stacked, inp["q"], inp["k"], inp["v"], jnp.int32(0),
+        LAUNCH, inp["q"], inp["k"], inp["v"], jnp.int32(0),
         inp["lengths"], inp["pt"], pages_per_compute_block=2, interpret=True,
         **inp["scales"],
     )

@@ -14,19 +14,23 @@ from areal_tpu.ops.paged_attention_q8 import (
     MAX_READERS,
     DecodeItems,
     decode_schedule,
-    paged_attention_stacked,
     shared_decode_schedule,
 )
-from tests.test_paged_decode_kernel import HD, L, PAGES, PSZ, reference
+from tests.test_paged_decode_kernel import HD, L, LAUNCH, PAGES, PSZ, reference
+
+SLOTS = 11  # of the widest table (``eleven_readers``): what ``aliased(..., slots=SLOTS)`` pads every table's batch to
 
 
-def aliased(groups, wp, G, KH, pages=jnp.bfloat16, q_dtype=jnp.bfloat16, seed=0):
+def aliased(groups, wp, G, KH, pages=jnp.bfloat16, q_dtype=jnp.bfloat16, seed=0, slots=0):
     """Inputs whose table the pool could have made. ``groups``: a list of
     (prompt tokens, [cached tokens of each member]); every member after the
     first holds the first's ``prompt // PSZ`` full prompt pages and pages of
     its own from there (its boundary page is a private copy). A member of
-    length 0 has ended: its row points at page 0."""
+    length 0 has ended: its row points at page 0. ``slots``: ended slots are
+    appended up to that batch size (they add no item: the launches of one
+    block size and head grouping are then one traced program)."""
     rng = np.random.default_rng(seed)
+    groups = list(groups) + [(0, [0])] * (slots - sum(len(members) for _, members in groups))
     lengths = np.asarray([n for _, members in groups for n in members], np.int32)
     S = len(lengths)
     N = S * wp + 1
@@ -95,7 +99,7 @@ def test_every_block_of_a_live_slot_is_read_once(table, ppcb):
     ends inside a block leaves its last pages in every sibling's own block;
     a group past an item's readers is fetched once an item)."""
     wp, groups = TABLES[table]
-    inp = aliased(groups, wp, 1, 1)
+    inp = aliased(groups, wp, 1, 1, slots=SLOTS)
     lengths, pt = np.asarray(inp["lengths"]), np.asarray(inp["pt"])
     nb, bk = wp // ppcb, ppcb * PSZ
     items, fetch = shared_decode_schedule(inp["lengths"], inp["pt"], PSZ, ppcb)
@@ -141,7 +145,7 @@ def test_a_table_without_aliases_gives_the_slot_major_list(ppcb):
 
 def launch(inp, ppcb, schedule=None, layer=1):
     return np.asarray(
-        paged_attention_stacked(
+        LAUNCH(
             inp["q"], inp["k"], inp["v"], jnp.int32(layer), inp["lengths"], inp["pt"],
             pages_per_compute_block=ppcb, schedule=schedule, interpret=True, **inp["scales"],
         ),
@@ -151,24 +155,26 @@ def launch(inp, ppcb, schedule=None, layer=1):
 
 def check_shared(inp, wp, ppcb, atol=1e-2, rtol=2.0**-7):
     """The launch over the shared list against the float32 reference, and
-    against the launch that fetches every block a slot. A slot meets its
-    blocks in the same order under both lists, and a row of the stacked
-    matmul holds the products the single reader's row holds; the CPU's matmul
-    sums them in another order when more rows are stacked, so the outputs
-    agree to float32 rounding and not bit for bit: here to one step of the
-    output's type (``rtol``: bfloat16's 2^-7)."""
-    out = launch(inp, ppcb)
+    against the launch that fetches every block a slot (both lists handed over
+    as ``DecodeItems``: one traced program). A slot meets its blocks in the
+    same order under both lists, and a row of the stacked matmul holds the
+    products the single reader's row holds; the CPU's matmul sums them in
+    another order when more rows are stacked, so the outputs agree to float32
+    rounding and not bit for bit: here to one step of the output's type
+    (``rtol``: bfloat16's 2^-7). Returns the shared list's outputs."""
+    out = launch(inp, ppcb, shared_decode_schedule(inp["lengths"], inp["pt"], PSZ, ppcb)[0])
     live = np.asarray(inp["lengths"]) > 0
     np.testing.assert_allclose(out[live], reference(inp, 1)[live], atol=atol)
     assert not out[~live].any()
-    alone = launch(inp, ppcb, schedule=decode_schedule(inp["lengths"], wp, PSZ, ppcb))
+    alone = launch(inp, ppcb, DecodeItems.private(decode_schedule(inp["lengths"], wp, PSZ, ppcb)))
     np.testing.assert_allclose(out, alone, rtol=rtol, atol=1e-6)
+    return out
 
 
 @pytest.mark.parametrize("table,ppcb", [("group_of_8", 2), ("group_of_8", 1), ("prefix_ends_inside_a_block", 2), ("ended_and_survivor", 4), ("mixed", 2), ("eleven_readers", 4)])
 def test_shared_blocks_give_the_outputs_of_blocks_fetched_a_slot(table, ppcb):
     wp, groups = TABLES[table]
-    check_shared(aliased(groups, wp, 4, 2, seed=ppcb), wp, ppcb)
+    check_shared(aliased(groups, wp, 4, 2, seed=ppcb, slots=SLOTS), wp, ppcb)
 
 
 @pytest.mark.parametrize("G,KH,pages", [(1, 30, "bf16"), (7, 4, "bf16"), (6, 2, "int8"), (2, 2, "fp8")])
@@ -183,4 +189,7 @@ def test_shared_blocks_by_head_grouping_and_page_type(G, KH, pages):
 def test_float32_queries_over_shared_blocks():
     wp, groups = TABLES["group_of_8"]
     inp = aliased(groups, wp, 4, 2, pages=jnp.float32, q_dtype=jnp.float32)
-    check_shared(inp, wp, 2, atol=1e-5, rtol=1e-5)
+    out = check_shared(inp, wp, 2, atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(out, launch(inp, 2))  # the list made inside the call is the shared one
+    slot_major = decode_schedule(inp["lengths"], wp, PSZ, 2)  # handed over as it is: made ``DecodeItems`` inside the call
+    np.testing.assert_array_equal(launch(inp, 2, slot_major), launch(inp, 2, DecodeItems.private(slot_major)))

@@ -27,6 +27,11 @@ def interpreted(monkeypatch):
     monkeypatch.setattr(kvw, "paged_kv_write", functools.partial(kvw.paged_kv_write, interpret=True))
 
 
+# ONE jit for the launch and one for the scatters (the layer is an argument): called eagerly, a Pallas launch is
+# traced, lowered and compiled anew at every call
+WRITE = jax.jit(paged_kv.write_decode_rows)
+
+
 def make_cache(kh: int, n_pages: int, quant: bool, seed: int = 0) -> dict:
     ks = jax.random.split(jax.random.PRNGKey(seed), 2)
     cache = {n: jax.random.normal(k, (L, kh, n_pages, PSZ, HD), jnp.float32) for n, k in zip("kv", ks)}
@@ -70,8 +75,8 @@ def test_writer_leaves_the_bits_the_scatters_leave(kh, quant):
     assert int(live[1]) == 4
     for layer in (0, L - 1):
         k, v = rows(kh, 6, seed=layer + 1)
-        got = paged_kv.write_decode_rows(cache, jnp.int32(layer), k, v, page, off, live)
-        want = paged_kv.write_decode_rows(cache, jnp.int32(layer), k, v, page, off)
+        got = WRITE(cache, jnp.int32(layer), k, v, page, off, live)
+        want = WRITE(cache, jnp.int32(layer), k, v, page, off)
         assert_same_pool(got, want, first_page=1)  # the scatters put the ended slots' rows in the trash page
         for n in cache:
             assert np.array_equal(bits(got[n][:, :, 0]), bits(cache[n][:, :, 0])), f"{n}: trash page written"
@@ -98,8 +103,8 @@ def test_last_row_of_a_page_then_the_next_pages_first_row():
         pos = np.asarray(pos)
         page, off = jnp.asarray(table[np.arange(2), pos // PSZ]), jnp.asarray(pos % PSZ, jnp.int32)
         k, v = rows(2, 2, seed=10 + step)
-        got = paged_kv.write_decode_rows(got, jnp.int32(1), k, v, page, off, live)
-        want = paged_kv.write_decode_rows(want, jnp.int32(1), k, v, page, off)
+        got = WRITE(got, jnp.int32(1), k, v, page, off, live)
+        want = WRITE(want, jnp.int32(1), k, v, page, off)
     assert_same_pool(got, want)
     assert not np.array_equal(bits(got["k"][1, :, 2, 0]), bits(cache["k"][1, :, 2, 0]))  # the next page's row 0
 
@@ -108,5 +113,5 @@ def test_no_live_slot_returns_the_pool_as_it_is():
     cache = make_cache(2, 4, quant=True)
     k, v = rows(2, 3, seed=3)
     page, off = jnp.asarray([1, 2, 3], jnp.int32), jnp.asarray([0, 1, 2], jnp.int32)
-    got = paged_kv.write_decode_rows(cache, jnp.int32(0), k, v, page, off, live_order(jnp.zeros(3, bool)))
+    got = WRITE(cache, jnp.int32(0), k, v, page, off, live_order(jnp.zeros(3, bool)))
     assert_same_pool(got, cache)

@@ -7,6 +7,7 @@ alone (tests/test_paged_decode_shared.py is the K/V twin, and tests the list
 itself).
 """
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -22,6 +23,8 @@ SCALE = LANES**-0.5
 # stands against the last shared block: past it and in its own first block, a token past it, AT its end (block 2 is
 # then the slot's last, so its own), INSIDE it, and further along by other amounts, one at the table's end
 PROMPT = 3 * BK + 5
+# one ``jax.jit`` of the entry point, and every group padded with ended slots to one batch: see tests/test_paged_decode_kernel.py
+LAUNCH = jax.jit(paged_latent_attention_stacked, static_argnames=("value_lanes", "pages_per_compute_block", "sm_scale", "interpret"))
 MEMBERS = [PROMPT + 1, 3 * BK + 1, 3 * BK, 2 * BK + 8, 4 * BK, 4 * BK + 1, 5 * BK, WP * PSZ, PROMPT + 4, 4 * BK + 8, 5 * BK + 1]
 
 
@@ -51,7 +54,7 @@ def aliased(groups, heads, pages, seed=0):
 
 def launch(inp, schedule=None, select=None, layer=1):
     return np.asarray(
-        paged_latent_attention_stacked(
+        LAUNCH(
             inp["q"], inp["pool"], jnp.int32(layer), inp["lengths"], inp["pt"], value_lanes=VALUE,
             pages_per_compute_block=PPCB, sm_scale=SCALE, schedule=schedule, select=select, interpret=True,
         )
@@ -80,7 +83,8 @@ def test_shared_latent_blocks_give_the_outputs_of_blocks_fetched_a_slot(readers,
     the products the single reader's row holds; the CPU's matmul sums them in
     another order when more rows are stacked, so float32 pages agree to 1e-6
     and bfloat16 ones to a step of their type."""
-    inp = aliased([(0, [0]), (PROMPT, MEMBERS[:readers]), (0, [2 * BK + 3])], heads, pages, seed=readers)
+    group = MEMBERS[:readers] + [0] * (len(MEMBERS) - readers)  # the samples that have ended: every case is one batch of 13
+    inp = aliased([(0, [0]), (PROMPT, group), (0, [2 * BK + 3])], heads, pages, seed=readers)
     items, fetch = shared_decode_schedule(inp["lengths"], inp["pt"], PSZ, PPCB)
     n_shared, n_items = (int(c) for c in items.count)
     # blocks 0-2 are shared by the members past them; 11 readers of block 0 are two items
@@ -100,7 +104,7 @@ def test_a_table_without_aliases_walks_the_slot_major_list(pages):
     """No shared item: ``decode_schedule()``'s items in its order, and the
     outputs of the launch handed that list; the list made inside the call is
     the same one."""
-    inp = aliased([(0, [n]) for n in (1, BK, 0, BK + 1, WP * PSZ, 3)], 32, pages)
+    inp = aliased([(0, [n]) for n in (1, BK, 0, BK + 1, WP * PSZ, 3) + (0,) * 7], 32, pages)  # a batch of 13, as above
     items, fetch = shared_decode_schedule(inp["lengths"], inp["pt"], PSZ, PPCB)
     slot, block, n = decode_schedule(inp["lengths"], WP, PSZ, PPCB)
     n = int(n[0])

@@ -32,6 +32,7 @@ load_run()
 from areal_tpu import models  # noqa: E402
 from areal_tpu.inference import paged_kv  # noqa: E402
 from areal_tpu.models import hybrid  # noqa: E402
+from tests.family_harness import decode_step, fresh_cache, prefill_into_slot, program_logits, with_counts  # noqa: E402
 
 TOL = 2e-5
 W, PSZ = 8, 4
@@ -45,12 +46,6 @@ def tiny():
 
 def _ids(n, seed=0, vocab=500):
     return np.random.default_rng(seed).integers(0, vocab, n).astype(np.int32)
-
-
-def _full_logits(params, mcfg, ids, **kw):
-    x = jnp.asarray(ids)[None]
-    hidden, *rest = hybrid.forward_prefill(params, mcfg, x, jnp.ones_like(x), **kw)
-    return np.asarray(hybrid.compute_logits(params, mcfg, hidden)[0]), rest
 
 
 # -- the configuration ----------------------------------------------------------
@@ -158,7 +153,7 @@ def test_full_forward_matches_the_plain_reference(tiny, n):
     more than two of its lengths."""
     cfg, mcfg, params = tiny
     ids = _ids(n, seed=n)
-    got, _ = _full_logits(params, mcfg, ids)
+    got = program_logits(mcfg, params, ids)
     want = pu.reference().token_logits(params, cfg, ids)
     assert np.abs(got - want).max() < TOL
 
@@ -231,23 +226,6 @@ def test_the_scan_over_a_prompt_equals_the_decode_steps(tiny):
 # -- through the cache --------------------------------------------------------------
 
 
-def _cache_and_tables(mcfg, slots=3, maxp=12):
-    cache = paged_kv.init_paged_cache(mcfg, slots * maxp + 1, PSZ, slots=slots)
-    table = 1 + np.arange(slots * maxp, dtype=np.int32).reshape(slots, maxp)
-    return cache, table
-
-
-def _prefill(params, mcfg, cache, table, rows, bucket):
-    """rows: [(slot, prompt ids)] -> the cache after the engine's prefill program."""
-    ids = np.zeros((len(rows), bucket), np.int32)
-    for i, (_, p) in enumerate(rows):
-        ids[i, : len(p)] = p
-    npg = bucket // PSZ
-    flat = np.concatenate([table[s, :npg] if s < len(table) else np.zeros(npg, np.int32) for s, _ in rows])  # a padding row: the trash page
-    fn = jax.jit(functools.partial(hybrid.prefill_into_cache, params, mcfg, page_size=PSZ))
-    return fn(cache, jnp.asarray(ids), jnp.asarray([len(p) for _, p in rows], jnp.int32), jnp.asarray(flat), jnp.asarray([s for s, _ in rows], jnp.int32))
-
-
 @pytest.mark.parametrize("plen", [3, 7, 11, 19])
 def test_prefill_then_decode_through_the_cache_matches_the_reference(tiny, plen):
     """A prompt below, just under, across and two windows past the window,
@@ -257,14 +235,13 @@ def test_prefill_then_decode_through_the_cache_matches_the_reference(tiny, plen)
     total = 44
     seqs = {0: _ids(total, seed=plen), 2: _ids(total, seed=100 + plen)}
     want = {s: pu.reference().token_logits(params, cfg, ids) for s, ids in seqs.items()}
-    cache, table = _cache_and_tables(mcfg)
-    cache = _prefill(params, mcfg, cache, table, [(s, ids[:plen]) for s, ids in seqs.items()], bucket=-(-plen // PSZ) * PSZ)
-    step = jax.jit(lambda c, i, pos, act: hybrid.forward_decode_paged(params, mcfg, i, pos, c, jnp.asarray(table), page_size=PSZ, active=act, use_kernel=False))
-    active = jnp.asarray([True, False, True])
+    cache, table = fresh_cache(mcfg, 3, 12, PSZ)
+    cache = prefill_into_slot(mcfg, params, cache, table, [(s, ids[:plen]) for s, ids in seqs.items()], -(-plen // PSZ) * PSZ, PSZ)
+    step, table, active = decode_step(mcfg, PSZ, False), jnp.asarray(table), jnp.asarray([True, False, True])
     idle = {k: np.asarray(v) for k, v in cache.items()}
     for t in range(plen - 1, total):
-        hidden, cache = step(cache, jnp.asarray([seqs[0][t], 0, seqs[2][t]]), jnp.asarray([t, 0, t]), active)
-        logits = np.asarray(hybrid.compute_logits(params, mcfg, hidden))
+        logits, cache = step(params, jnp.asarray([seqs[0][t], 0, seqs[2][t]]), jnp.asarray([t, 0, t]), cache, table, active)
+        logits = np.asarray(logits)
         assert max(np.abs(logits[s] - want[s][t]).max() for s in seqs) < TOL, t
     for k in ("ssm", "conv"):  # a slot that is not active keeps its state bit for bit
         assert np.array_equal(idle[k][:, 1], np.asarray(cache[k])[:, 1])
@@ -275,16 +252,13 @@ def test_prefill_then_decode_through_the_cache_matches_the_reference(tiny, plen)
 
 def test_an_active_slot_changes_nothing_of_its_neighbours_and_writes_one_row(tiny):
     _, mcfg, params = tiny
-    cache, table = _cache_and_tables(mcfg)
+    cache, table = fresh_cache(mcfg, 3, 12, PSZ)
     rng = jax.random.split(jax.random.PRNGKey(5), len(cache))
     cache = {k: jax.random.normal(r, v.shape, v.dtype) for r, (k, v) in zip(rng, cache.items())}
     before = {k: np.asarray(v) for k, v in cache.items()}
     pos = 13  # ring position 5: page 1, row 1; the full layer's page 3, row 1
     table[[0, 2]] = 0  # as the engine leaves a slot that holds no request: its row of the table at the trash page
-    _, after = hybrid.forward_decode_paged(
-        params, mcfg, jnp.asarray([7, 8, 9]), jnp.asarray([2, pos, 4]), cache, jnp.asarray(table), page_size=PSZ,
-        active=jnp.asarray([False, True, False]), use_kernel=False,
-    )
+    _, after = decode_step(mcfg, PSZ, False)(params, jnp.asarray([7, 8, 9]), jnp.asarray([2, pos, 4]), cache, jnp.asarray(table), jnp.asarray([False, True, False]))
     after = {k: np.asarray(v) for k, v in after.items()}
     for k in paged_kv.RING_LEAVES:  # the block past the last slot takes the rows of the slots that are not live
         changed = np.argwhere((after[k] != before[k])[:, :, :3].any(axis=(0, 1, 5)))  # [slot block, page, row]
@@ -304,9 +278,9 @@ def test_a_ring_holds_exactly_the_window_after_a_prompt(tiny, plen):
     _, mcfg, params = tiny
     ids = _ids(plen, seed=plen)
     bucket = -(-plen // PSZ) * PSZ
-    cache, table = _cache_and_tables(mcfg)
+    cache, table = fresh_cache(mcfg, 3, 12, PSZ)
     marked = {k: (v + 7.0 if k in paged_kv.RING_LEAVES else v) for k, v in cache.items()}
-    out = _prefill(params, mcfg, marked, table, [(2, ids), (3, ids[:1])], bucket)  # slot 3 does not exist: a padding row
+    out = prefill_into_slot(mcfg, params, marked, table, [(2, ids), (3, ids[:1])], bucket, PSZ)  # slot 3 does not exist: a padding row
     x = jnp.asarray(np.pad(ids, (0, bucket - plen)))[None]
     _, _, _, rows = hybrid.forward_prefill(
         params, mcfg, x, (jnp.arange(bucket) < plen).astype(jnp.int32)[None], n_state=jnp.asarray([plen - 1]),  # as the program: the last token is decode's
@@ -323,7 +297,7 @@ def test_a_ring_holds_exactly_the_window_after_a_prompt(tiny, plen):
 
 def test_copy_pages_gives_a_sibling_the_primarys_rings_and_state(tiny):
     _, mcfg, _ = tiny
-    cache, _ = _cache_and_tables(mcfg)
+    cache, _ = fresh_cache(mcfg, 3, 12, PSZ)
     rng = jax.random.split(jax.random.PRNGKey(8), len(cache))
     cache = {k: jax.random.normal(r, v.shape, v.dtype) for r, (k, v) in zip(rng, cache.items())}
     before = {k: np.asarray(v) for k, v in cache.items()}
@@ -339,12 +313,9 @@ def test_copy_pages_gives_a_sibling_the_primarys_rings_and_state(tiny):
 
 def test_a_chunks_counts_are_the_live_slots(tiny):
     _, mcfg, params = tiny
-    cache, table = _cache_and_tables(mcfg)
-    cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
-    _, out = hybrid.forward_decode_paged(
-        params, mcfg, jnp.asarray([1, 2, 3]), jnp.asarray([2, 30, 11]), cache, jnp.asarray(table), page_size=PSZ,
-        active=jnp.asarray([True, True, False]), use_kernel=False,
-    )
+    cache, table = fresh_cache(mcfg, 3, 12, PSZ)
+    cache = with_counts(mcfg, cache)
+    _, out = decode_step(mcfg, PSZ, False)(params, jnp.asarray([1, 2, 3]), jnp.asarray([2, 30, 11]), cache, jnp.asarray(table), jnp.asarray([True, True, False]))
     assert int(out["shared_kv_tokens_read"][0]) == (3 + 31) * 3  # the full layer and two cross layers
     assert int(out["window_tokens_read"][0]) == (3 + W) * 3  # at most a window a live slot and window layer
     assert int(out["s6_updates"][0]) == 2 * 4
@@ -366,9 +337,9 @@ def test_the_kernel_path_in_interpret_mode_equals_the_gather_path(tiny, monkeypa
     cache = {k: jax.random.normal(r, v.shape, v.dtype) for r, (k, v) in zip(rng, cache.items())}
     table = jnp.asarray(1 + np.arange(S * maxp, dtype=np.int32).reshape(S, maxp)).at[1].set(0)  # slot 1 ended: trash page
     args = (params, mcfg, jnp.asarray([4, 5, 6]), jnp.asarray([3, 9, 21]), cache, table)
-    kw = dict(page_size=psz, active=jnp.asarray([True, False, True]))
-    h_x, c_x = hybrid.forward_decode_paged(*args, use_kernel=False, **kw)
-    h_k, c_k = hybrid.forward_decode_paged(*args, use_kernel=True, **kw)
+    step = jax.jit(functools.partial(hybrid.forward_decode_paged, page_size=psz), static_argnums=1, static_argnames="use_kernel")  # the HIDDEN rows of both paths
+    h_x, c_x = step(*args, active=jnp.asarray([True, False, True]), use_kernel=False)
+    h_k, c_k = step(*args, active=jnp.asarray([True, False, True]), use_kernel=True)
     assert np.abs(np.asarray(h_x - h_k))[[0, 2]].max() < 1e-4
     for name in c_x:
         live = [0, 2]

@@ -33,6 +33,7 @@ from benchlib import solar_open2_weights  # noqa: E402
 from areal_tpu import models  # noqa: E402
 from areal_tpu.inference import paged_kv  # noqa: E402
 from areal_tpu.models import hybrid  # noqa: E402
+from tests.family_harness import program_logits, through_the_cache  # noqa: E402
 from areal_tpu.ops.kda_state_update import kda_state_update_stacked  # noqa: E402
 from areal_tpu.ops.paged_attention_q8 import live_order  # noqa: E402
 
@@ -133,43 +134,13 @@ def test_state_kernel_under_interpret_matches_the_step(dtype):
     assert "pjit" not in jaxpr.split("pallas_call", 1)[1].split("name=kda_state_update")[0]  # lax primitives only in the body
 
 
-def _through_the_cache(mcfg, params, ids, n_prompt, bucket, slot=1, slots=3):
-    """Prefill ``ids[:n_prompt]`` padded to ``bucket`` into slot ``slot`` of a
-    fresh cache, then decode the rest one token a step through the paged
-    path (gather form). Returns (logits of every decode step, the cache)."""
-    wp = max(-(-len(ids) // PSZ), bucket // PSZ)
-    cache = paged_kv.init_paged_cache(mcfg, slots * wp + 1, PSZ, slots=slots)
-    pt = jnp.asarray(1 + np.arange(slots * wp).reshape(slots, wp), jnp.int32)
-    row = np.zeros((1, bucket), np.int32)
-    row[0, :n_prompt] = ids[:n_prompt]
-    row[0, n_prompt:] = 7  # the padding is real tokens: only the masks keep it out
-    cache = hybrid.prefill_into_cache(
-        params, mcfg, cache, jnp.asarray(row), jnp.asarray([n_prompt], jnp.int32),
-        pt[slot, : bucket // PSZ], jnp.asarray([slot], jnp.int32), page_size=PSZ,
-    )
-    active = jnp.arange(slots) == slot
-    logits = []
-
-    @jax.jit
-    def step(tok, pos, cache):
-        hid, cache = hybrid.forward_decode_paged(params, mcfg, tok, pos, cache, pt, page_size=PSZ, active=active, use_kernel=False)
-        return hybrid.compute_logits(params, mcfg, hid), cache
-
-    for t in range(n_prompt - 1, len(ids)):  # decode feeds the prompt's last token first
-        tok = jnp.zeros((slots,), jnp.int32).at[slot].set(int(ids[t]))
-        pos = jnp.zeros((slots,), jnp.int32).at[slot].set(t)
-        out, cache = step(tok, pos, cache)
-        logits.append(np.asarray(out)[slot])
-    return np.stack(logits), cache
-
-
 @pytest.mark.parametrize("n", [5, 150])
 def test_full_forward_matches_reference(n):
     cfg = su.tiny_model()
     params = su.make_params(cfg, 11)
     ids = np.random.default_rng(n).integers(0, cfg["vocab_size"], n)
     want = ref.logits(params, cfg, ids)
-    assert np.abs(su.program_logits(cfg, params, ids) - want).max() < 3e-4 and np.abs(want).max() > 0.3
+    assert np.abs(program_logits(su.model_config(cfg), params, ids) - want).max() < 3e-4 and np.abs(want).max() > 0.3
 
 
 def test_prefill_then_paged_decode_and_the_slot_state_match_the_reference():
@@ -184,16 +155,16 @@ def test_prefill_then_paged_decode_and_the_slot_state_match_the_reference():
     assert mcfg.layer_types == ("attention", "kda", "kda", "kda") * 2 and set(mcfg.ffns) == {"moe"}
     ids = np.random.default_rng(1).integers(0, cfg["vocab_size"], 67)
     want = ref.logits(params, cfg, ids)
-    got, cache = _through_the_cache(mcfg, params, ids, 37, 64)
+    got, cache = through_the_cache(mcfg, params, ids, 37, 64, page_size=PSZ)
     assert np.abs(got - want[36:]).max() < 3e-4
     assert cache["kda"].shape == (6, 3, 8, 16, 16) and cache["conv"].shape == (6, 3, 3 * 3 * 128)
     view = hybrid.slot_state_view(mcfg, "kda", cache["kda"][0])
     assert rel(view[1], ref.first_layer_state(params, cfg, ids, pad_to=256)) < 1e-5
     assert not np.asarray(cache["kda"][:, 0]).any() and not np.asarray(cache["kda"][:, 2]).any()
-    _, cache0 = _through_the_cache(mcfg, params, ids[:37], 37, 64)  # one step: the prompt's last token
+    _, cache0 = through_the_cache(mcfg, params, ids[:37], 37, 64, page_size=PSZ)  # one step: the prompt's last token
     assert rel(cache0["kda"][0, 1], ref.first_layer_state(params, cfg, ids[:37], pad_to=256)) < 1e-5
     low = su.model_config(cfg, kda_state_dtype="bfloat16")
-    _, cache_low = _through_the_cache(low, params, ids, 37, 64)
+    _, cache_low = through_the_cache(low, params, ids, 37, 64, page_size=PSZ)
     assert cache_low["kda"].dtype == jnp.bfloat16
     assert rel(cache_low["kda"][0, 1].astype(jnp.float32), ref.first_layer_state(params, cfg, ids, pad_to=256)) > 1e-3
 

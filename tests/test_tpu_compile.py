@@ -1,4 +1,5 @@
-"""Compile the main-path Pallas kernels for a DESCRIBED TPU v5e.
+"""Compile the main-path Pallas kernels, and every served family's two
+engine programs, for a DESCRIBED TPU v5e.
 
 No chip is attached: the TPU compiler that ships with jaxlib compiles for a
 topology description (rehearsal 3 of the on-chip-measurement guide), and
@@ -7,17 +8,22 @@ to the tiling, more VMEM than a kernel may use, a block shape the lowering
 refuses. Interpret mode sees none of these; every failure this file guards
 against passed its interpret-mode tests first.
 
-Each case lowers with ``interpret=False`` at Qwen2.5-1.5B head shapes (12
-heads / 2 KV heads / head_dim 128, 28 layers, 128-token pages) and asserts
-the compiled module holds a ``tpu_custom_call``. About two seconds a case;
-the one whole-model program compiled here is two decode steps over scanned
-layers (4 s): tier-1 has no room for an engine's programs.
+``CASES``: one launch each with ``interpret=False`` at a benchmark cell's
+shapes (by default Qwen2.5-1.5B's: 12 heads / 2 KV heads / head_dim 128, 28
+layers, 128-token pages), held to a ``tpu_custom_call`` in the compiled
+module; about two seconds a case. ``FAMILIES``: a row a family of
+``models/hybrid.py``, its decode step and its prefill at the cell's published
+widths, slots, window and longest bucket, cut to ONE period of its layer
+pattern (10-35 s a program); a new family adds a row. The Qwen path's decode
+steps are compiled whole at 28 scanned layers (4 s).
 """
 
 import functools
 import math
 import os
 import re
+import sys
+import typing
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -25,6 +31,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "benchmark_harness"))
+from chipbench_util import CHIP, load_run  # noqa: E402
 
 L, KH, H, HD, PSZ = 28, 2, 12, 128, 128
 SLOTS, WP = 128, 16  # decode: 128 slots x 2048-token windows
@@ -384,11 +393,16 @@ CASES = {
 }
 
 
+@functools.lru_cache(maxsize=None)
+def _compiled_text(chip, name):
+    """The compiled module of a case, once a worker process whichever test asks."""
+    fn, args = CASES[name]()
+    return jax.jit(fn).lower(*args(chip)).compile().as_text()
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(chip, name):
-    fn, args = CASES[name]()
-    compiled = jax.jit(fn).lower(*args(chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "tpu_custom_call" in _compiled_text(chip, name)
 
 
 def _pallas_call(jaxpr):
@@ -452,8 +466,7 @@ KERNEL_NAMES = {
 
 @pytest.mark.parametrize("name", sorted(KERNEL_NAMES))
 def test_kernel_carries_its_name_on_v5e(chip, name):
-    fn, args = CASES[name]()
-    text = jax.jit(fn).lower(*args(chip)).compile().as_text()
+    text = _compiled_text(chip, name)
     for kernel in KERNEL_NAMES[name]:
         assert kernel in text, f"{kernel} not in the compiled {name}"
 
@@ -576,80 +589,6 @@ def test_static_shape_rule_matches_the_compiler(chip):
         ), (hd, psz, dt)
 
 
-def _lfm2(chip, monkeypatch):
-    """The ``lfm2_moe`` family at the benchmark's published widths (32
-    experts of [2048, 1792], heads of 64 padded to 128 lanes, 65k
-    vocabulary) and 128 slots, cut to one layer of each kind it has (conv +
-    dense FFN, attention + experts, conv + experts) so that tier-1 can hold
-    the compile; weights and cache are shapes on the described chip."""
-    import json
-
-    from areal_tpu import models
-    from areal_tpu.inference import paged_kv
-    from areal_tpu.models import hybrid
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "chip", "configs", "lfm2-8b-a1b-d14.json")) as f:
-        cfg = json.load(f)
-    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "reduced_from", "assumed", "assumed_notes", "stands_for")}
-    hf.update(cfg["assumed"], num_hidden_layers=3, num_dense_layers=1, layer_types=["conv", "full_attention", "conv"], dtype="bfloat16")
-    mcfg = models.config_from_hf_dict(hf)
-    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
-    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, 2225, PSZ, slots=SLOTS))
-    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
-    # the kernels and gmm ask the platform whether to compile or interpret: the described chip is a TPU
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    return mcfg, place(params), place(cache)
-
-
-def test_lfm2_decode_steps_compile_for_v5e(chip, monkeypatch):
-    """Two decode steps as the engine's chunk runs them (the expert layer's
-    dense form at 128 rows, the paged kernels on padded heads, the load
-    counts in the carry): no copy of a whole expert stack into another
-    layout (XLA:TPU made one of 5 GB for the un-batched einsum)."""
-    from areal_tpu.models import hybrid
-
-    mcfg, params, cache = _lfm2(chip, monkeypatch)
-
-    def two_steps(params, cache, pt, ids, pos, active):
-        cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.moe_count_shapes.items()}}
-
-        def step(c, _):
-            ids, pos, cache = c
-            h, cache = hybrid.forward_decode_paged(params, mcfg, ids, pos, cache, pt, page_size=PSZ, active=active, use_kernel=True)
-            return (jnp.argmax(hybrid.compute_logits(params, mcfg, h), -1).astype(jnp.int32), pos + 1, cache), None
-
-        (ids, _, cache), _ = jax.lax.scan(step, (ids, pos, cache), None, length=2)
-        return ids, cache
-
-    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(SLOTS, 32), i32(SLOTS), i32(SLOTS), chip((SLOTS,), jnp.bool_)).compile()
-    text = compiled.as_text()
-    assert "paged_decode_attn" in text and "paged_kv_write" in text
-    assert not [ln for ln in text.splitlines() if " copy(" in ln and "bf16[1,32,2048,1792]" in ln]
-    assert compiled.memory_analysis().temp_size_in_bytes < 200e6
-    # 128 rows x top-4 over 32 experts: 16 assignments an expert, every expert touched: XLA's matmuls, not the touched-expert launch
-    assert "moe_touched_experts" not in text
-
-
-def test_lfm2_prefill_compiles_for_v5e(chip, monkeypatch):
-    """A batched prefill of 4 x 1024 tokens: the routed expert form (16k
-    assignment rows through ``megablox.gmm`` at ``moe.gmm_tiles``, which the
-    chip's compiler must accept inside its default scoped VMEM), the masked
-    conv window, the state's slot writes and the KV scatter."""
-    from areal_tpu.models import hybrid, moe
-
-    mcfg, params, cache = _lfm2(chip, monkeypatch)
-    assert not moe.takes_dense_form(4 * 1024, 32)
-
-    def prefill(params, cache, ids, plens, flat_pages, slots):
-        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
-
-    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(4, 1024), i32(4), i32(4 * 1024 // PSZ), i32(4)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3  # the three grouped matmuls of the expert layer
-
-
 def _window_relayouts(text, taps):
     """The compiled ops that lay a bfloat16 window out again: a ``reshape``
     or ``copy`` whose result has the tap count (or the window's K-1) among
@@ -702,168 +641,6 @@ def test_conv_window_step_stays_in_the_rows_tiling(chip, layers, channels):
     assert _window_relayouts(program(as_it_was), taps)
 
 
-def _olmo(chip, monkeypatch):
-    """The ``olmo_hybrid`` family at the benchmark's published widths (30
-    delta-rule heads of 96 x 192, 30 / 30 attention heads of 128, hidden
-    3840, 100k vocabulary) and 64 slots, cut to one period (three
-    delta-rule layers and one attention layer) so that tier-1 can hold the
-    compile; weights and cache are shapes on the described chip."""
-    import json
-
-    from areal_tpu import models
-    from areal_tpu.inference import paged_kv
-    from areal_tpu.models import hybrid
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "chip", "configs", "olmo-hybrid-7b-d16.json")) as f:
-        cfg = json.load(f)
-    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "reduced_from", "assumed", "assumed_notes", "stands_for")}
-    assumed = {k: v for k, v in cfg["assumed"].items() if k not in ("initializer_range", "linear_attention_form")}
-    hf.update(assumed, num_hidden_layers=4, layer_types=cfg["layer_types"][:4], dtype="bfloat16")
-    mcfg = models.config_from_hf_dict(hf)
-    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
-    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, 1900, PSZ, slots=64))
-    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels ask the platform whether to compile or interpret
-    return mcfg, place(params), place(cache)
-
-
-def test_olmo_decode_steps_compile_for_v5e(chip, monkeypatch):
-    """Two decode steps as the engine's chunk runs them: the delta-rule
-    state kernel on the packed float32 state in place, the paged kernels at
-    30 KV heads and a query group of 1, the update counts in the carry."""
-    from areal_tpu.models import hybrid
-
-    mcfg, params, cache = _olmo(chip, monkeypatch)
-    assert cache["gdn"].shape == (3, 64, 15, 96, 384) and cache["gdn"].dtype == jnp.float32
-
-    def two_steps(params, cache, pt, ids, pos, active):
-        cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
-
-        def step(c, _):
-            ids, pos, cache = c
-            h, cache = hybrid.forward_decode_paged(params, mcfg, ids, pos, cache, pt, page_size=PSZ, active=active, use_kernel=True)
-            return (jnp.argmax(hybrid.compute_logits(params, mcfg, h), -1).astype(jnp.int32), pos + 1, cache), None
-
-        (ids, _, cache), _ = jax.lax.scan(step, (ids, pos, cache), None, length=2)
-        return ids, cache
-
-    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(64, 32), i32(64), i32(64), chip((64,), jnp.bool_)).compile()
-    text = compiled.as_text()
-    assert "gdn_state_update" in text and "paged_decode_attn" in text and "paged_kv_write" in text
-    # the state is advanced where it lies: no second copy of the stacked state (3 x 64 x 2.2 MB = 425 MB here) among the temporaries
-    assert compiled.memory_analysis().temp_size_in_bytes < 200e6
-
-
-def test_olmo_prefill_compiles_for_v5e(chip, monkeypatch):
-    """A batched prefill of 4 x 1024 tokens: the chunked delta-rule scan one
-    row at a time, the masked conv windows, the state's slot writes and the
-    KV scatter, within the memory the cell leaves beside its weights."""
-    from areal_tpu.models import hybrid
-
-    mcfg, params, cache = _olmo(chip, monkeypatch)
-
-    def prefill(params, cache, ids, plens, flat_pages, slots):
-        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
-
-    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(4, 1024), i32(4), i32(4 * 1024 // PSZ), i32(4)).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
-
-
-def _kanana2(chip, monkeypatch, layers: int = 3):
-    """The ``deepseek_v3`` family at the benchmark's published widths (latent
-    rows of 576 in 640 lanes, 32 heads, 16 held experts of [2048, 768] under
-    a router of 128, the shared block, a vocabulary of 16,032) and 64 slots,
-    cut to the leading dense layer and two expert layers so that tier-1 can
-    hold the compile; weights and cache are shapes on the described chip."""
-    import json
-
-    from areal_tpu import models
-    from areal_tpu.inference import paged_kv
-    from areal_tpu.models import hybrid
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "chip", "configs", "kanana-2-30b-a3b-ep8.json")) as f:
-        cfg = json.load(f)
-    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "reduced_from", "assumed", "assumed_notes", "stands_for")}
-    hf.update({k: v for k, v in cfg["assumed"].items() if k not in ("initializer_range", "latent_page_dtype")}, num_hidden_layers=layers, dtype="bfloat16")
-    mcfg = models.config_from_hf_dict(hf)
-    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
-    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, 477, PSZ, slots=64))
-    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    return mcfg, place(params), place(cache)
-
-
-def test_kanana2_decode_steps_compile_for_v5e(chip, monkeypatch):
-    """Two decode steps as the engine's chunk runs them: the latent kernel
-    and the row writer as custom calls on the stacked pool where it lies (no
-    copy of the page pool, no layer slice of it), the absorbed products
-    without a second copy of ``W_kvb``'s stack, the counts in the carry; the
-    expert matmuls as the touched-expert launch on the expert STACKS (64 rows
-    x top-6 over a router of 128: 3 assignments an expert), with no layer of
-    them sliced or copied out for it (151 MB a layer)."""
-    from areal_tpu.models import hybrid
-
-    mcfg, params, cache = _kanana2(chip, monkeypatch)
-    assert set(cache) == {"k"} and cache["k"].shape == (3, 1, 477, PSZ, 640)
-
-    def two_steps(params, cache, pt, ids, pos, active):
-        cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
-
-        def step(c, _):
-            ids, pos, cache = c
-            h, cache = hybrid.forward_decode_paged(params, mcfg, ids, pos, cache, pt, page_size=PSZ, active=active, use_kernel=True)
-            return (jnp.argmax(hybrid.compute_logits(params, mcfg, h), -1).astype(jnp.int32), pos + 1, cache), None
-
-        (ids, _, cache), _ = jax.lax.scan(step, (ids, pos, cache), None, length=2)
-        return ids, cache
-
-    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(64, 32), i32(64), i32(64), chip((64,), jnp.bool_)).compile()
-    text = compiled.as_text()
-    assert "paged_latent_attn" in text and "paged_kv_write" in text and "paged_decode_attn" not in text
-    pool = "bf16[3,1,477,128,640]"
-    assert not [ln for ln in text.splitlines() if " copy(" in ln and (pool in ln or "bf16[1,477,128,640]" in ln)]
-    assert "moe_touched_experts" in text
-    # no op's RESULT is a layer of an expert stack, or the stack: no copy, dynamic-slice or fusion of them
-    made = re.compile(r"= bf16\[(2,|1,)?16,(2048,768|768,2048)\]\S* (?!parameter|get-tuple-element)")
-    assert not [ln for ln in text.splitlines() if made.search(ln)]
-    # nor of W_q's stack, which XLA re-lays out whole where the projection's output is split without a barrier
-    assert not [ln for ln in text.splitlines() if " copy(" in ln and "bf16[2,2048,6144]" in ln]
-    # XLA copies a layer's W_kvb out of the stack into fast memory and names the copy after the slice: made under the scope that reads it
-    kvb = [ln for ln in text.splitlines() if "= bf16[1,512,8192]" in ln and "dynamic_slice" in ln]
-    assert kvb and all("mla_proj/dynamic_slice" in ln for ln in kvb)
-    # the pool is 234 MB here: nothing of its size among the temporaries
-    assert compiled.memory_analysis().temp_size_in_bytes < 200e6
-
-
-def test_kanana2_prefill_compiles_for_v5e(chip, monkeypatch):
-    """A batched prefill of 2 x 1024 tokens in the plain form: per-head keys
-    and values from the prompt's own latent one row at a time, the routed
-    expert form over the held experts only (the router's other 112 sort past
-    the grouped matmuls), the shared block, the latent rows' scatter."""
-    from areal_tpu.models import hybrid, moe
-
-    mcfg, params, cache = _kanana2(chip, monkeypatch)
-    assert not moe.takes_dense_form(2 * 1024, 16) and moe.takes_dense_form(64, 16)
-
-    def prefill(params, cache, ids, plens, flat_pages, slots):
-        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
-
-    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(2, 1024), i32(2), i32(2 * 1024 // PSZ), i32(2)).compile()
-    text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 4  # the three grouped matmuls of the expert layer, and the prompt pass's attention
-    assert hybrid.prefill_takes_launch(mcfg, 1024) and "mla_prefill_flash" in text and "f32[32,1024,1024]" not in text  # no logits in HBM
-    # W_kvb's stack of 47 layers is not laid out for the launch whole: a layer's slice is, once a layer
-    assert not [ln for ln in text.splitlines() if re.search(r"= bf16\[\d+,512,12288\]\S* (?!parameter|get-tuple-element)", ln)]
-    # the latent rows go into their pages layer by layer: no [layers, 2, 1024, 640] buffer, no pool-sized temporary
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
-
-
 @pytest.mark.parametrize(
     "pools",
     [{"k": (48, 1, 477, PSZ, 640)}, {"k": (14, 4, 1170, PSZ, HD), "v": (14, 4, 1170, PSZ, HD)}],
@@ -885,400 +662,266 @@ def test_page_copy_reserves_no_second_pool_on_v5e(chip, pools):
     assert compiled.memory_analysis().temp_size_in_bytes < 16e6
 
 
-def _glm5(chip, monkeypatch, layers: int = 2, kv_gb: float = 3.0):
-    """The ``glm_moe_dsa`` family at the benchmark's published widths (hidden
-    6144, 64 heads, latent rows of 576 in 640 lanes beside index keys of 128,
-    16 held experts of [6144, 2048] under a router of 256, a vocabulary of
-    19,360) and 64 slots, cut to the leading dense layer and one expert layer
-    so that tier-1 can hold the compile (the layers are scanned: a program's
-    temporaries are one layer's); the page pools are the cell's 2,730 pages."""
-    import json
-
-    from areal_tpu import models
-    from areal_tpu.inference import paged_kv
-    from areal_tpu.models import hybrid
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "chip", "configs", "glm-5-ep16-d6.json")) as f:
-        cfg = json.load(f)
-    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "reduced_from", "assumed", "assumed_notes", "stands_for")}
-    keep = ("router_experts", "expert_first", "latent_row_lanes", "index_norm_eps")
-    hf.update({k: cfg["assumed"][k] for k in keep}, num_hidden_layers=layers, dtype="bfloat16")
-    mcfg = models.config_from_hf_dict(hf)
-    n_pages = paged_kv.n_pages_for_budget(int(kv_gb * 2**30), 6, 1, PSZ, 640, 2, pools=mcfg.kv_pools)
-    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
-    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, n_pages, PSZ, slots=64))
-    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    return mcfg, place(params), place(cache), n_pages
+def _no_copy_of(text: str, *shapes: str):
+    """No ``copy`` in the compiled text touches a leaf of these shapes."""
+    for shape in shapes:
+        assert not [ln for ln in text.splitlines() if " copy(" in ln and shape in ln], shape
 
 
-def test_glm5_decode_steps_compile_for_v5e_at_the_longest_window(chip, monkeypatch):
-    """Two decode steps as the engine's chunk runs them at the cell's ONE
-    window (160 pages: 20,480 tokens, 64 slots): the index's launch over the
-    pool of index keys, the selection's 32 counting passes over [64, 20480],
-    the latent launch under the selection, both pools written by one launch a
-    layer, the expert matmuls as the touched-expert launch on the stacks (64
-    rows x top-8 over 256: 2 assignments an expert) with an expert of [6144,
-    2048] going through the ring in 4 parts. No page pool copied, no layer of
-    the expert stacks sliced out, the low-rank query's and the index's
-    matrices not re-laid out whole, 0.25 GB of temporaries."""
-    from areal_tpu.models import hybrid
-
-    mcfg, params, cache, n_pages = _glm5(chip, monkeypatch)
-    assert n_pages == 2730 and {k: v.shape for k, v in cache.items()} == {"k": (2, 1, 2730, PSZ, 640), "idx": (2, 1, 2730, PSZ, 128)}
-
-    def two_steps(params, cache, pt, ids, pos, active):
-        cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
-
-        def step(c, _):
-            ids, pos, cache = c
-            h, cache = hybrid.forward_decode_paged(params, mcfg, ids, pos, cache, pt, page_size=PSZ, active=active, use_kernel=True)
-            return (jnp.argmax(hybrid.compute_logits(params, mcfg, h), -1).astype(jnp.int32), pos + 1, cache), None
-
-        (ids, _, cache), _ = jax.lax.scan(step, (ids, pos, cache), None, length=2)
-        return ids, cache
-
-    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(64, 160), i32(64), i32(64), chip((64,), jnp.bool_)).compile()
-    text = compiled.as_text()
-    for name in ("paged_latent_attn", "paged_index_scores", "paged_kv_write", "moe_touched_experts"):
-        assert name in text, name
-    for pool in ("bf16[2,1,2730,128,640]", "bf16[2,1,2730,128,128]", "bf16[1,2730,128,640]", "bf16[1,2730,128,128]"):
-        assert not [ln for ln in text.splitlines() if " copy(" in ln and pool in ln], pool
-    # no op's RESULT is a layer of an expert stack: no copy, dynamic-slice or fusion of 75 MB matrices
-    made = re.compile(r"= bf16\[(1,)?16,(6144,2048|2048,6144)\]\S* (?!parameter|get-tuple-element)")
-    assert not [ln for ln in text.splitlines() if made.search(ln)]
-    # W_qb's and W^I_qb's stacks are not re-laid out whole (their outputs are split behind a barrier)
-    assert not [ln for ln in text.splitlines() if " copy(" in ln and ("bf16[2,2048,16384]" in ln or "bf16[1,2048,16384]" in ln or "bf16[1,2048,4096]" in ln)]
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+def _no_result_shaped(text: str, shape: str):
+    """No op's RESULT has this shape (a regex): no copy, dynamic-slice or fusion makes it."""
+    made = re.compile(r"= " + shape + r"\S* (?!parameter|get-tuple-element)")
+    assert not [ln for ln in text.splitlines() if made.search(ln)], shape
 
 
-def test_glm5_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypatch):
-    """ONE prompt of 16,384 tokens, the cell's longest bucket, into both
-    pools: attention blocked over 512 queries, ONE ``mla_prefill_flash``
-    launch a block over key blocks of 1,024 (no [H, L, L]: that would be 69
-    GB; no [H, queries, keys] float32 logits in HBM either: the XLA loop's
-    were 134 MB a step), the selection made a query block at a time and
-    handed to the launch as a mask, every head's keys and values of the
-    prompt made once a layer in the launch's lane layout (1.07 GB), the
-    expert rows through the grouped matmuls 2,048 at a time ON THE STACK (a
-    layer's three matrices copied out for them were 1.15 GB; 16k rows
-    gathered at once 1.6 GB). 2.31 GB of temporaries by this count, where
-    the XLA loop's program counts 2.45 (which over-counts a donated program:
-    the compiler's own for that one was 2.12 GB): with 9.46 GB of weights
-    and a pool of 3.22 GB, under 15 GB."""
+def _lfm2_decode(mcfg, cache, text, temp):
+    """The expert layer's dense form at 128 rows, the paged kernels on padded
+    heads, the load counts in the carry: no copy of a whole expert stack into
+    another layout (XLA:TPU made one of 5 GB for the un-batched einsum)."""
+    assert "paged_decode_attn" in text and "paged_kv_write" in text
+    _no_copy_of(text, "bf16[1,32,2048,1792]")
+    assert temp < 200e6
+    # 128 rows x top-4 over 32 experts: 16 assignments an expert, every expert touched: XLA's matmuls, not the touched-expert launch
+    assert "moe_touched_experts" not in text
+
+
+def _lfm2_prefill(mcfg, cache, text, temp):
+    """4 x 1024 tokens: the routed expert form (16k assignment rows through
+    ``megablox.gmm`` at ``moe.gmm_tiles``, which the chip's compiler must
+    accept inside its default scoped VMEM), the masked conv window, the
+    state's slot writes and the KV scatter."""
+    from areal_tpu.models import moe
+
+    assert not moe.takes_dense_form(4 * 1024, 32)
+    assert text.count("tpu_custom_call") >= 3  # the three grouped matmuls of the expert layer
+
+
+def _olmo_decode(mcfg, cache, text, temp):
+    """The delta-rule state kernel on the packed float32 state in place, the
+    paged kernels at 30 KV heads and a query group of 1, the update counts in
+    the carry."""
+    assert cache["gdn"].shape == (3, 64, 15, 96, 384) and cache["gdn"].dtype == jnp.float32
+    assert "gdn_state_update" in text and "paged_decode_attn" in text and "paged_kv_write" in text
+    # the state is advanced where it lies: no second copy of the stacked state (3 x 64 x 2.2 MB = 425 MB here) among the temporaries
+    assert temp < 200e6
+
+
+def _olmo_prefill(mcfg, cache, text, temp):
+    """4 x 1024 tokens: the chunked delta-rule scan one row at a time, the
+    masked conv windows, the state's slot writes and the KV scatter, within
+    the memory the cell leaves beside its weights."""
+    assert temp < 1.5e9
+
+
+def _kanana2_decode(mcfg, cache, text, temp):
+    """The latent kernel and the row writer as custom calls on the stacked
+    pool where it lies (no copy of the page pool, no layer slice of it), the
+    absorbed products without a second copy of ``W_kvb``'s stack, the counts
+    in the carry; the expert matmuls as the touched-expert launch on the
+    expert STACKS (64 rows x top-6 over a router of 128: 3 assignments an
+    expert), with no layer of them sliced or copied out for it (151 MB a
+    layer)."""
+    assert set(cache) == {"k"} and cache["k"].shape == (3, 1, 477, PSZ, 640)
+    assert "paged_latent_attn" in text and "paged_kv_write" in text and "paged_decode_attn" not in text
+    _no_copy_of(text, "bf16[3,1,477,128,640]", "bf16[1,477,128,640]")
+    assert "moe_touched_experts" in text
+    _no_result_shaped(text, r"bf16\[(2,|1,)?16,(2048,768|768,2048)\]")  # a layer of an expert stack, or the stack
+    # nor of W_q's stack, which XLA re-lays out whole where the projection's output is split without a barrier
+    _no_copy_of(text, "bf16[2,2048,6144]")
+    # XLA copies a layer's W_kvb out of the stack into fast memory and names the copy after the slice: made under the scope that reads it
+    kvb = [ln for ln in text.splitlines() if "= bf16[1,512,8192]" in ln and "dynamic_slice" in ln]
+    assert kvb and all("mla_proj/dynamic_slice" in ln for ln in kvb)
+    assert temp < 200e6  # the pool is 234 MB here: nothing of its size among the temporaries
+
+
+def _kanana2_prefill(mcfg, cache, text, temp):
+    """2 x 1024 tokens in the plain form: per-head keys and values from the
+    prompt's own latent one row at a time, the routed expert form over the
+    held experts only (the router's other 112 sort past the grouped matmuls),
+    the shared block, the latent rows' scatter."""
     from areal_tpu.models import hybrid, moe
 
-    # three layers: the last one's feed-forward block feeds no page and is compiled away, the middle one's stays
-    mcfg, params, cache, _ = _glm5(chip, monkeypatch, layers=3)
+    assert not moe.takes_dense_form(2 * 1024, 16) and moe.takes_dense_form(64, 16)
+    assert text.count("tpu_custom_call") >= 4  # the three grouped matmuls of the expert layer, and the prompt pass's attention
+    assert hybrid.prefill_takes_launch(mcfg, 1024) and "mla_prefill_flash" in text and "f32[32,1024,1024]" not in text  # no logits in HBM
+    # W_kvb's stack of 47 layers is not laid out for the launch whole: a layer's slice is, once a layer
+    _no_result_shaped(text, r"bf16\[\d+,512,12288\]")
+    # the latent rows go into their pages layer by layer: no [layers, 2, 1024, 640] buffer, no pool-sized temporary
+    assert temp < 0.4e9
+
+
+def _glm5_decode(mcfg, cache, text, temp):
+    """160 pages (20,480 tokens): the index's launch over the pool of index
+    keys, the selection's 32 counting passes over [64, 20480], the latent
+    launch under the selection, both pools written by one launch a layer, the
+    expert matmuls as the touched-expert launch on the stacks (64 rows x top-8
+    over 256: 2 assignments an expert) with an expert of [6144, 2048] going
+    through the ring in 4 parts. No page pool copied, no layer of the expert
+    stacks sliced out, the low-rank query's and the index's matrices not
+    re-laid out whole, 0.25 GB of temporaries."""
+    assert {k: v.shape for k, v in cache.items()} == {"k": (2, 1, 2730, PSZ, 640), "idx": (2, 1, 2730, PSZ, 128)}
+    for name in ("paged_latent_attn", "paged_index_scores", "paged_kv_write", "moe_touched_experts"):
+        assert name in text, name
+    _no_copy_of(text, "bf16[2,1,2730,128,640]", "bf16[2,1,2730,128,128]", "bf16[1,2730,128,640]", "bf16[1,2730,128,128]")
+    _no_result_shaped(text, r"bf16\[(1,)?16,(6144,2048|2048,6144)\]")  # a layer of an expert stack: 75 MB matrices
+    # W_qb's and W^I_qb's stacks are not re-laid out whole (their outputs are split behind a barrier)
+    _no_copy_of(text, "bf16[2,2048,16384]", "bf16[1,2048,16384]", "bf16[1,2048,4096]")
+    assert temp < 0.3e9
+
+
+def _glm5_prefill(mcfg, cache, text, temp):
+    """ONE prompt of 16,384 tokens into both pools: attention blocked over 512
+    queries, ONE ``mla_prefill_flash`` launch a block over key blocks of 1,024
+    (no [H, L, L]: that would be 69 GB; no [H, queries, keys] float32 logits
+    in HBM either: the XLA loop's were 134 MB a step), the selection made a
+    query block at a time and handed to the launch as a mask, every head's
+    keys and values of the prompt made once a layer in the launch's lane
+    layout (1.07 GB), the expert rows through the grouped matmuls 2,048 at a
+    time ON THE STACK (a layer's three matrices copied out for them were 1.15
+    GB; 16k rows gathered at once 1.6 GB). 2.31 GB of temporaries by this
+    count, where the XLA loop's program counts 2.45 (which over-counts a
+    donated program: the compiler's own for that one was 2.12 GB): with 9.46
+    GB of weights and a pool of 3.22 GB, under 15 GB. Three layers: the last
+    one's feed-forward block feeds no page and is compiled away, the middle
+    one's stays."""
+    from areal_tpu.models import hybrid, moe
+
     assert hybrid.prefill_blocks(mcfg, 16384) == (256, 2048) and hybrid.ffn_block_rows(mcfg, "moe", 16384) == 2048
     assert hybrid.prefill_takes_launch(mcfg, 16384) and hybrid.prefill_blocks(mcfg, 16384, launch=True) == (512, 1024)
     assert not moe.takes_dense_form(2048, 16)
-
-    def prefill(params, cache, ids, plens, flat_pages, slots):
-        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
-
-    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(1, 16384), i32(1), i32(16384 // PSZ), i32(1)).compile()
-    text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 4  # the three grouped matmuls of the expert layer, and the prompt pass's attention
     assert "mla_prefill_flash" in text and "f32[64,256,2048]" not in text and "f32[64,512,1024]" not in text  # no logits in HBM
-    made = re.compile(r"= bf16\[(1,)?16,(6144,2048|2048,6144)\]\S* (?!parameter|get-tuple-element)")
-    assert not [ln for ln in text.splitlines() if made.search(ln)]  # the grouped matmuls read the stacks where they lie
+    _no_result_shaped(text, r"bf16\[(1,)?16,(6144,2048|2048,6144)\]")  # the grouped matmuls read the stacks where they lie
     assert "bf16[131072,6144]" not in text  # 16k rows x top-8 are never gathered at once
     # W_kvb's stack is not laid out for the launch whole: a layer's slice is, once a layer (29 MB)
-    assert not [ln for ln in text.splitlines() if re.search(r"= bf16\[3,512,(28672|32768)\]\S* (?!parameter|get-tuple-element)", ln)]
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.4e9  # under the XLA loop's 2.45
-
-
-def _phi4flash(chip, monkeypatch, kv_gb: float = 3.5):
-    """The ``phi4flash`` family at every published width and ALL 32 layers
-    (the layers are scanned in four bodies: a program's temporaries are one
-    pair's), 64 slots, the cell's pool of 5,734 pages under the one full
-    layer and 65 ring blocks of 4 pages under the eight window layers."""
-    import json
-
-    from areal_tpu import models
-    from areal_tpu.inference import paged_kv
-    from areal_tpu.models import hybrid
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "chip", "configs", "phi-4-mini-flash-reasoning.json")) as f:
-        cfg = json.load(f)
-    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "assumed", "assumed_notes", "stands_for")}
-    hf.update({k: v for k, v in cfg["assumed"].items() if not isinstance(v, str) or k.endswith("dtype")}, dtype="bfloat16")
-    mcfg = models.config_from_hf_dict(hf)
-    n_pages = paged_kv.n_pages_for_budget(int(kv_gb * 2**30), 1, 10, PSZ, 128, 2, pools=mcfg.kv_pools)
-    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
-    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, n_pages, PSZ, slots=64))
-    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    return mcfg, place(params), place(cache), n_pages
+    _no_result_shaped(text, r"bf16\[3,512,(28672|32768)\]")
+    assert temp < 2.4e9  # under the XLA loop's 2.45
 
 
 _PHI_POOLS = ("bf16[8,10,65,4,128,128]", "bf16[8,10,260,128,128]", "bf16[1,10,5734,128,128]", "f32[9,64,16,5120]")
 
 
-def test_phi4flash_decode_steps_compile_for_v5e_at_the_whole_window(chip, monkeypatch):
-    """Two decode steps as the engine's chunk runs them at the cell's ONE
-    window (160 pages, 64 slots): ``paged_decode_attn`` over the rings (a
-    4-page table) and over the full layer's pages, by that layer and by the
-    seven cross layers; ``paged_kv_write`` into the rings (the 6-axis leaf
-    merged to the pools' own layout and back: no copy) and into the pages;
-    the selective-scan state advanced by XLA in place. Five launches in the
-    text (the 32 layers are four scan bodies), no pool, ring or state copied
-    or re-laid out, 0.1 GB of temporaries."""
-    from areal_tpu.models import hybrid
-
-    mcfg, params, cache, n_pages = _phi4flash(chip, monkeypatch)
-    assert n_pages == 5734 and {k: v.shape for k, v in cache.items()} == {
+def _phi4flash_decode(mcfg, cache, text, temp):
+    """160 pages: ``paged_decode_attn`` over the rings (a 4-page table) and
+    over the full layer's pages, by that layer and by the seven cross layers;
+    ``paged_kv_write`` into the rings (the 6-axis leaf merged to the pools'
+    own layout and back: no copy) and into the pages; the selective-scan
+    state advanced by XLA in place. Five launches in the text (the 32 layers
+    are four scan bodies), no pool, ring or state copied or re-laid out, 0.1
+    GB of temporaries."""
+    assert {k: v.shape for k, v in cache.items()} == {
         "k": (1, 10, 5734, PSZ, 128), "v": (1, 10, 5734, PSZ, 128), "ssm": (9, 64, 16, 5120), "conv": (9, 64, 3 * 5120),
         "ring_k": (8, 10, 65, 4, PSZ, 128), "ring_v": (8, 10, 65, 4, PSZ, 128),
     }
-
-    def two_steps(params, cache, pt, ids, pos, active):
-        cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
-
-        def step(c, _):
-            ids, pos, cache = c
-            h, cache = hybrid.forward_decode_paged(params, mcfg, ids, pos, cache, pt, page_size=PSZ, active=active, use_kernel=True)
-            return (jnp.argmax(hybrid.compute_logits(params, mcfg, h), -1).astype(jnp.int32), pos + 1, cache), None
-
-        (ids, _, cache), _ = jax.lax.scan(step, (ids, pos, cache), None, length=2)
-        return ids, cache
-
-    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(64, 160), i32(64), i32(64), chip((64,), jnp.bool_)).compile()
-    text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 5 and "paged_decode_attn" in text and "paged_kv_write" in text
-    for pool in _PHI_POOLS:
-        assert not [ln for ln in text.splitlines() if " copy(" in ln and pool in ln], pool
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+    _no_copy_of(text, *_PHI_POOLS)
+    assert temp < 0.1e9
 
 
-def test_phi4flash_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypatch):
-    """ONE prompt of 16,384 tokens, the cell's longest bucket: 17 of the 32
-    layers and layer 17's K and V (no cross layer, no memory unit, no full
-    attention: the decode step that follows is the prompt's one row of
-    those), the selective scan a token a step over a carried [1, 16, 5120]
-    (nothing of [16384, 5120, 16]: 5.4 GB), window attention a block of 512
-    queries against 1,024 keys (no [40, 16384, 16384]), the rings written a
-    head an update (a window over heads and lanes re-lays the carried ring
-    out: 2 x 1.36 GB copied in and out of the loop). 1.93 GB of temporaries:
-    with 7.70 GB of weights and 5.33 GB of cache, 14.96 of 15.75 GB. Two
-    prompts of 4,096 are never batched (``prefill_row_bytes``)."""
+def _phi4flash_prefill(mcfg, cache, text, temp):
+    """ONE prompt of 16,384 tokens: 17 of the 32 layers and layer 17's K and V
+    (no cross layer, no memory unit, no full attention: the decode step that
+    follows is the prompt's one row of those), the selective scan a token a
+    step over a carried [1, 16, 5120] (nothing of [16384, 5120, 16]: 5.4 GB),
+    window attention a block of 512 queries against 1,024 keys (no [40,
+    16384, 16384]), the rings written a head an update (a window over heads
+    and lanes re-lays the carried ring out: 2 x 1.36 GB copied in and out of
+    the loop). 1.93 GB of temporaries: with 7.70 GB of weights and 5.33 GB of
+    cache, 14.96 of 15.75 GB. Two prompts of 4,096 are never batched
+    (``prefill_row_bytes``)."""
     from areal_tpu.inference.decode_programs import _PREFILL_STREAM_BYTES
     from areal_tpu.models import hybrid
 
-    mcfg, params, cache, _ = _phi4flash(chip, monkeypatch)
     assert hybrid.prefill_row_bytes(mcfg, 4096) > _PREFILL_STREAM_BYTES > hybrid.prefill_row_bytes(mcfg, 256) * 4
     assert hybrid.ffn_block_rows(mcfg, "dense", 16384) == 8192
-
-    def prefill(params, cache, ids, plens, flat_pages, slots):
-        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
-
-    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(1, 16384), i32(1), i32(16384 // PSZ), i32(1)).compile()
-    text = compiled.as_text()
-    for pool in _PHI_POOLS:
-        assert not [ln for ln in text.splitlines() if " copy(" in ln and pool in ln], pool
+    _no_copy_of(text, *_PHI_POOLS)
     assert "f32[16384,16,5120]" not in text and "f32[16384,5120,16]" not in text and "16384,16384" not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.1e9
+    assert temp < 2.1e9
 
 
-def _solar_open2(chip, monkeypatch, kv_gb: float = 2.75):
-    """The ``solar_open2`` family at every published width, ONE whole period
-    G K K K of the cell's two (the layers are scanned, a run a body: a
-    program's temporaries are one layer's), 20 of 320 experts, 64 slots and
-    the cell's pool of 2,816 pages (sized for its two attention layers)."""
-    import json
-
-    from areal_tpu import models
-    from areal_tpu.inference import paged_kv
-    from areal_tpu.models import hybrid
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "chip", "configs", "solar-open2-250b-ep16-d8.json")) as f:
-        cfg = json.load(f)
-    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "reduced_from", "assumed", "assumed_notes", "stands_for")}
-    keep = ("router_experts", "expert_first", "kda_state_dtype", "conv_state_dtype")
-    hf.update({k: cfg["assumed"][k] for k in keep}, num_hidden_layers=4, gqa_layers=[0], dtype="bfloat16")
-    mcfg = models.config_from_hf_dict(hf)
-    n_pages = paged_kv.n_pages_for_budget(int(kv_gb * 2**30), 2, 8, PSZ, 128, 2, pools=mcfg.kv_pools)
-    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
-    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, n_pages, PSZ, slots=64))
-    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    return mcfg, place(params), place(cache), n_pages
-
-
-def test_solar_open2_decode_steps_compile_for_v5e_at_the_whole_window(chip, monkeypatch):
-    """Two decode steps as the engine's chunk runs them at the cell's ONE
-    window (160 pages, 64 slots): the kda state advanced by the
-    ``kda_state_update`` launch in place (no copy of the 0.8 GB leaf, nor of
-    a layer of it), the attention layer on ``paged_decode_attn`` /
-    ``paged_kv_write``, the expert matmuls as the touched-expert launch on the
-    stacks (64 rows x top-8 over 320: 1.6 assignments an expert; an expert of
-    [4096, 1280] through the ring in 2 parts), 0.3 GB of temporaries."""
+def _solar_open2_decode(mcfg, cache, text, temp):
+    """160 pages: the kda state advanced by the ``kda_state_update`` launch in
+    place (no copy of the 0.8 GB leaf, nor of a layer of it), the attention
+    layer on ``paged_decode_attn`` / ``paged_kv_write``, the expert matmuls as
+    the touched-expert launch on the stacks (64 rows x top-8 over 320: 1.6
+    assignments an expert; an expert of [4096, 1280] through the ring in 2
+    parts), 0.3 GB of temporaries."""
     from areal_tpu.models import hybrid, moe
     from areal_tpu.ops.moe_touched_experts import width_parts
 
-    mcfg, params, cache, n_pages = _solar_open2(chip, monkeypatch)
-    assert n_pages == 2816 and {k: v.shape for k, v in cache.items()} == {
+    assert {k: v.shape for k, v in cache.items()} == {
         "k": (1, 8, 2816, PSZ, 128), "v": (1, 8, 2816, PSZ, 128), "kda": (3, 64, 64, 128, 128), "conv": (3, 64, 3 * 24576),
     }
     assert moe.takes_touched_form(64, 8, 320, 20) and width_parts(4096, 1280, 2) == 2 and hybrid.kda_takes_launch(mcfg)
-
-    def two_steps(params, cache, pt, ids, pos, active):
-        cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
-
-        def step(c, _):
-            ids, pos, cache = c
-            h, cache = hybrid.forward_decode_paged(params, mcfg, ids, pos, cache, pt, page_size=PSZ, active=active, use_kernel=True)
-            return (jnp.argmax(hybrid.compute_logits(params, mcfg, h), -1).astype(jnp.int32), pos + 1, cache), None
-
-        (ids, _, cache), _ = jax.lax.scan(step, (ids, pos, cache), None, length=2)
-        return ids, cache
-
-    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(64, 160), i32(64), i32(64), chip((64,), jnp.bool_)).compile()
-    text = compiled.as_text()
     for name in ("kda_state_update", "paged_decode_attn", "paged_kv_write", "moe_touched_experts"):
         assert name in text, name
-    for leaf in ("f32[3,64,64,128,128]", "f32[64,64,128,128]", "bf16[1,8,2816,128,128]", "bf16[3,64,73728]"):
-        assert not [ln for ln in text.splitlines() if " copy(" in ln and leaf in ln], leaf
-    made = re.compile(r"= bf16\[(1,)?20,(4096,1280|1280,4096)\]\S* (?!parameter|get-tuple-element)")
-    assert not [ln for ln in text.splitlines() if made.search(ln)]  # no layer of an expert stack sliced out
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+    _no_copy_of(text, "f32[3,64,64,128,128]", "f32[64,64,128,128]", "bf16[1,8,2816,128,128]", "bf16[3,64,73728]")
+    _no_result_shaped(text, r"bf16\[(1,)?20,(4096,1280|1280,4096)\]")  # no layer of an expert stack sliced out
+    assert temp < 0.3e9
 
 
-def test_solar_open2_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypatch):
-    """ONE prompt of 16,384 tokens, the cell's longest bucket: the attention
-    layer under ``flash_fwd`` a KV head at a time (no [64, 16384, 16384]: 68
-    GB; no K or V of 64 replicated heads either), its gate a block of 2,048
-    rows at a time, the kda layers a block of 1,024 tokens at a time with the
-    float32 state carried (nothing of [16384, 64, 128] float32), the expert
-    rows through the grouped matmuls 4,096 at a time on the stacks. Under 2.5
-    GB of temporaries (2.36 by this count at the cell's two periods): with
-    7.80 GB of weights and 4.62 GB of cache, 14.8 of 15.75 GB."""
+def _solar_open2_prefill(mcfg, cache, text, temp):
+    """ONE prompt of 16,384 tokens: the attention layer under ``flash_fwd`` a
+    KV head at a time (no [64, 16384, 16384]: 68 GB; no K or V of 64
+    replicated heads either), its gate a block of 2,048 rows at a time, the
+    kda layers a block of 1,024 tokens at a time with the float32 state
+    carried (nothing of [16384, 64, 128] float32), the expert rows through
+    the grouped matmuls 4,096 at a time on the stacks. Under 2.5 GB of
+    temporaries (2.36 by this count at the cell's two periods): with 7.80 GB
+    of weights and 4.62 GB of cache, 14.8 of 15.75 GB."""
     from areal_tpu.inference.decode_programs import _PREFILL_STREAM_BYTES
     from areal_tpu.models import hybrid
 
-    mcfg, params, cache, _ = _solar_open2(chip, monkeypatch)
     assert hybrid.gqa_prefill_launch(mcfg, 16384) and hybrid.gqa_prefill_launch(mcfg, 4096) and not hybrid.gqa_prefill_launch(mcfg, 1024)
     assert hybrid.prefill_row_bytes(mcfg, 256) > _PREFILL_STREAM_BYTES and hybrid.ffn_block_rows(mcfg, "moe", 16384) == 4096
-
-    def prefill(params, cache, ids, plens, flat_pages, slots):
-        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
-
-    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(1, 16384), i32(1), i32(16384 // PSZ), i32(1)).compile()
-    text = compiled.as_text()
     assert "flash_fwd" in text and "f32[64,16384,16384]" not in text
     # a block's scan is the launch: the XLA form's keys as each sub-block sees them and its chunk matrices are gone
     assert hybrid.kda_prefill_launch(mcfg, 16384) and "kda_prompt_scan" in text
     assert "f32[16,64,4,64,128]" not in text and "f32[16,64,64,64]" not in text
     assert "f32[16384,64,128]" not in text and "f32[16384,8192]" not in text  # neither the scan's inputs nor the gate over the whole prompt
-    for leaf in ("f32[3,64,64,128,128]", "bf16[1,8,2816,128,128]"):
-        assert not [ln for ln in text.splitlines() if " copy(" in ln and leaf in ln], leaf
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+    _no_copy_of(text, "f32[3,64,64,128,128]", "bf16[1,8,2816,128,128]")
+    assert temp < 2.5e9
 
 
-def _cohere2_moe(chip, monkeypatch, kv_gb: float = 1.5):
-    """The ``cohere2_moe`` family as the cell serves it: every published
-    width, ONE whole period S S S F (the cell's four layers), 8 of 128
-    experts, 64 slots with their rings and the cell's pool of 3,072 pages
-    (sized for its one full layer)."""
-    import json
-
-    from areal_tpu import models
-    from areal_tpu.inference import paged_kv
-    from areal_tpu.models import hybrid
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "chip", "configs", "command-a-plus-ep16-d4.json")) as f:
-        cfg = json.load(f)
-    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "reduced_from", "assumed", "assumed_notes", "stands_for")}
-    hf.update({k: cfg["assumed"][k] for k in ("router_experts", "expert_first")}, dtype="bfloat16")
-    mcfg = models.config_from_hf_dict(hf)
-    n_pages = paged_kv.n_pages_for_budget(int(kv_gb * 2**30), 1, 8, PSZ, 128, 2, pools=mcfg.kv_pools)
-    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
-    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, n_pages, PSZ, slots=64))
-    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    return mcfg, place(params), place(cache), n_pages
-
-
-def test_cohere2_moe_decode_steps_compile_for_v5e_at_the_whole_window(chip, monkeypatch):
-    """Two decode steps as the engine's chunk runs them at the cell's ONE
-    window (160 pages, 64 slots): the window layers read their rings (32
-    pages a slot) and the full layer the page table through
-    ``paged_decode_attn`` at a query group of 16, both written by
-    ``paged_kv_write`` in place (no copy of the 1.6 GB ring leaves, nor of a
-    layer of them), the expert matmuls as the touched-expert launch on the
-    stacks (64 rows x top-8 over 128: 4 assignments an expert; an expert of
-    [4096, 4096]), under 0.4 GB of temporaries."""
+def _cohere2_moe_decode(mcfg, cache, text, temp):
+    """160 pages: the window layers read their rings (32 pages a slot) and
+    the full layer the page table through ``paged_decode_attn`` at a query
+    group of 16, both written by ``paged_kv_write`` in place (no copy of the
+    1.6 GB ring leaves, nor of a layer of them), the expert matmuls as the
+    touched-expert launch on the stacks (64 rows x top-8 over 128: 4
+    assignments an expert; an expert of [4096, 4096]), under 0.4 GB of
+    temporaries."""
     from areal_tpu.models import moe
 
-    mcfg, params, cache, n_pages = _cohere2_moe(chip, monkeypatch)
-    assert n_pages == 3072 and {k: v.shape for k, v in cache.items()} == {
+    assert {k: v.shape for k, v in cache.items()} == {
         "k": (1, 8, 3072, PSZ, 128), "v": (1, 8, 3072, PSZ, 128), "ring_k": (3, 8, 65, 32, PSZ, 128), "ring_v": (3, 8, 65, 32, PSZ, 128),
     }
     assert moe.takes_touched_form(64, 8, 128, 8)
-    from areal_tpu.models import hybrid
-
-    def two_steps(params, cache, pt, ids, pos, active):
-        cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
-
-        def step(c, _):
-            ids, pos, cache = c
-            h, cache = hybrid.forward_decode_paged(params, mcfg, ids, pos, cache, pt, page_size=PSZ, active=active, use_kernel=True)
-            return (jnp.argmax(hybrid.compute_logits(params, mcfg, h), -1).astype(jnp.int32), pos + 1, cache), None
-
-        (ids, _, cache), _ = jax.lax.scan(step, (ids, pos, cache), None, length=2)
-        return ids, cache
-
-    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(64, 160), i32(64), i32(64), chip((64,), jnp.bool_)).compile()
-    text = compiled.as_text()
     for name in ("paged_decode_attn", "paged_kv_write", "moe_touched_experts"):
         assert name in text, name
-    for leaf in ("bf16[3,8,65,32,128,128]", "bf16[3,8,2080,128,128]", "bf16[8,2080,128,128]", "bf16[1,8,3072,128,128]"):
-        assert not [ln for ln in text.splitlines() if " copy(" in ln and leaf in ln], leaf
-    made = re.compile(r"= bf16\[(1,)?8,4096,4096\]\S* (?!parameter|get-tuple-element)")
-    assert not [ln for ln in text.splitlines() if made.search(ln)]  # no layer of an expert stack sliced out
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
+    _no_copy_of(text, "bf16[3,8,65,32,128,128]", "bf16[3,8,2080,128,128]", "bf16[8,2080,128,128]", "bf16[1,8,3072,128,128]")
+    _no_result_shaped(text, r"bf16\[(1,)?8,4096,4096\]")  # no layer of an expert stack sliced out
+    assert temp < 0.4e9
 
 
-def test_cohere2_moe_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypatch):
-    """ONE prompt of 16,384 tokens, the cell's longest bucket: the window
-    layers inside their band under ``swa_prefill_flash`` (nothing of [128,
-    4096, 8192] float32: 17 GB a block of the XLA form; no query transposed),
-    the full layer under ``flash_fwd`` a KV head at a time, the expert rows
-    4,096 at a time (the shared block's gate and up of 16k rows x 16,384
-    columns are 1 GB), the rings written a head at a time in place. Under 3.2
-    GB of temporaries: with 6.25 GB of weights and 4.88 GB of cache, 14.3 of
-    15.75 GB."""
+def _cohere2_moe_prefill(mcfg, cache, text, temp):
+    """ONE prompt of 16,384 tokens: the window layers inside their band under
+    ``swa_prefill_flash`` (nothing of [128, 4096, 8192] float32: 17 GB a
+    block of the XLA form; no query transposed), the full layer under
+    ``flash_fwd`` a KV head at a time, the expert rows 4,096 at a time (the
+    shared block's gate and up of 16k rows x 16,384 columns are 1 GB), the
+    rings written a head at a time in place. Under 3.2 GB of temporaries:
+    with 6.25 GB of weights and 4.88 GB of cache, 14.3 of 15.75 GB."""
     from areal_tpu.inference.decode_programs import _PREFILL_STREAM_BYTES
     from areal_tpu.models import hybrid
 
-    mcfg, params, cache, _ = _cohere2_moe(chip, monkeypatch)
     assert hybrid.swa_prefill_launch(mcfg, 16384) and hybrid.gqa_prefill_launch(mcfg, 16384) and hybrid.swa_prefill_launch(mcfg, 4096)
     assert 2 * hybrid.prefill_row_bytes(mcfg, 256) > _PREFILL_STREAM_BYTES and hybrid.ffn_block_rows(mcfg, "moe", 16384) == 4096  # every prompt goes alone
-
-    def prefill(params, cache, ids, plens, flat_pages, slots):
-        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
-
-    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(1, 16384), i32(1), i32(16384 // PSZ), i32(1)).compile()
-    text = compiled.as_text()
     assert "swa_prefill_flash" in text and "flash_fwd" in text
     assert "f32[128,4096,8192]" not in text and "f32[128,16384,16384]" not in text and "16384,16384]" not in text.replace("bf16[1,16384,16384]", "").replace("bf16[16384,16384]", "")
     assert "bf16[16384,65536]" not in text  # the shared block's gate and up over the whole prompt
-    for leaf in ("bf16[3,8,65,32,128,128]", "bf16[1,8,3072,128,128]"):
-        assert not [ln for ln in text.splitlines() if " copy(" in ln and leaf in ln], leaf
-    assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
+    _no_copy_of(text, "bf16[3,8,65,32,128,128]", "bf16[1,8,3072,128,128]")
+    assert temp < 3.2e9
 
 
 def _results_outside_fusions(text: str) -> list[str]:
@@ -1292,47 +935,124 @@ def _results_outside_fusions(text: str) -> list[str]:
     return out
 
 
-def _xing4(chip, monkeypatch, layers: int = 10, kv_gb: float = 4.5):
-    """The ``xing4_0`` family at the benchmark's published widths (hidden
-    3584 in FOUR residual streams, 32 heads, latent rows of 576 in 640 lanes,
-    16 held experts of [3584, 1024] under a router of 64, a vocabulary of
-    32,768), the cell's ten layers (two scan bodies: a program's temporaries
-    are one layer's) and 64 slots; the page pool is the cell's 2,949 pages."""
-    import json
-
-    from areal_tpu import models
-    from areal_tpu.inference import paged_kv
-    from areal_tpu.models import hybrid
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "chip", "configs", "xing4.0-29b-a4b-ep4-d10.json")) as f:
-        cfg = json.load(f)
-    hf = {k: v for k, v in cfg.items() if k not in ("source", "reduced", "reduced_from", "assumed", "assumed_notes", "stands_for")}
-    keep = ("router_experts", "expert_first", "latent_row_lanes", "rope_interleave")
-    hf.update({k: cfg["assumed"][k] for k in keep}, num_hidden_layers=layers, dtype="bfloat16")
-    mcfg = models.config_from_hf_dict(hf)
-    n_pages = paged_kv.n_pages_for_budget(int(kv_gb * 2**30), 10, 1, PSZ, 640, 2, pools=mcfg.kv_pools)
-    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
-    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, n_pages, PSZ, slots=64))
-    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    return mcfg, place(params), place(cache), n_pages
-
-
-def test_xing4_decode_steps_compile_for_v5e_at_the_whole_window(chip, monkeypatch):
-    """Two decode steps as the engine's chunk runs them at the cell's ONE
-    window (160 pages: 20,480 tokens, 64 slots), all ten layers: the latent
+def _xing4_decode(mcfg, cache, text, temp):
+    """160 pages (20,480 tokens), the two dense layers and an expert layer
+    (two scan bodies: a program's temporaries are one layer's): the latent
     launch over every cached token (no index), the pool written by one launch
     a layer, the expert matmuls as the touched-expert launch on the stacks (64
     rows x top-4 over 64: 4 assignments an expert) with an expert of [3584,
     1024] through the ring whole; the 20 Sinkhorn rounds of a sublayer a
-    STATIC chain (no while loop but the layers' two scans and the steps'), the
-    four streams [64, 14336] on the lanes. No page pool copied, no layer of
-    the expert stacks sliced out."""
+    STATIC chain (no while loop but the layers' two scans and the steps'),
+    the four streams [64, 14336] on the lanes. No page pool copied, no layer
+    of the expert stacks sliced out."""
+    layers = mcfg.num_layers
+    assert {k: v.shape for k, v in cache.items()} == {"k": (layers, 1, 2949, PSZ, 640)}
+    for name in ("paged_latent_attn", "paged_kv_write", "moe_touched_experts"):
+        assert name in text, name
+    _no_copy_of(text, f"bf16[{layers},1,2949,128,640]", "bf16[1,2949,128,640]")
+    _no_result_shaped(text, r"bf16\[(1,)?16,3584,1024\]")  # (a down matrix has the shape of 16 query blocks of the attention output)
+    assert text.count(" while(") == 3  # the steps' scan and the layers' two: the rounds are no device loop
+    assert "bf16[64,14336]" in text  # the streams side by side on the lanes
+    rounds = [ln for ln in _results_outside_fusions(text) if "mhc_sinkhorn" in ln and " fusion(" in ln]
+    assert 0 < len(rounds) <= 4 * 8  # two scan bodies x two sublayers: a handful of launches each, not one a round
+    assert temp < 0.3e9
+
+
+def _xing4_prefill(mcfg, cache, text, temp):
+    """ONE prompt of 16,384 tokens into the latent pool: four streams of 117
+    MB each beside their successors, the coefficients' 24 columns a token
+    with the batch on the lanes, attention blocked over 1,024 queries under
+    ``mla_prefill_flash``, the expert rows 8,192 at a time on the stack.
+    Under 4 GB of temporaries: with 4.45 GB of weights and a pool of 4.5 GiB,
+    under 15 GB."""
     from areal_tpu.models import hybrid
 
-    mcfg, params, cache, n_pages = _xing4(chip, monkeypatch)
-    assert n_pages == 2949 and {k: v.shape for k, v in cache.items()} == {"k": (10, 1, 2949, PSZ, 640)}
+    assert hybrid.prefill_takes_launch(mcfg, 16384) and hybrid.prefill_blocks(mcfg, 16384, launch=True) == (1024, 1024)
+    assert hybrid.ffn_block_rows(mcfg, "moe", 16384) == 8192
+    assert "mla_prefill_flash" in text and "bf16[1,16384,14336]" in text
+    made = _results_outside_fusions(text)
+    # no float32 copy of the streams and no stream axis next to the minor one (a bfloat16 axis of 4 is padded to 16) in HBM
+    assert not [ln for ln in made if re.search(r"= f32\[(1,)?16384,14336\]|= \S+\[(1,)?16384,4,3584\]", ln)]
+    # the Sinkhorn rounds' vectors keep the batch on the lanes: none is laid out [rows, 1] as the mixes read them
+    rounds = [ln for ln in made if "mhc_sinkhorn" in ln and " fusion(" in ln]
+    assert rounds and not [ln for ln in rounds if "f32[16384,1]" in ln.split(" fusion(")[0]]
+    _no_result_shaped(text, r"bf16\[(1,)?16,3584,1024\]")  # (a down matrix has the shape of 16 query blocks of the attention output)
+    assert temp < 4e9
+
+
+class Family(typing.NamedTuple):
+    """A family's two engine programs at its cell's published widths: what the
+    cell's configuration is cut to so that tier-1 can hold the compile, the
+    cell's pool, slots, window and longest bucket, and what the compiled
+    programs are held to. The layers are scanned, a run of one kind a body (a
+    program's temporaries are one layer's), so ``cut`` keeps ONE whole period
+    of the layer pattern and every layer kind, no more."""
+
+    util: str  # the module of tests/benchmark_harness that builds the configuration as the cell does (its family's ``not_the_programs`` dropped from ``assumed``)
+    config: str  # benchmarks/chip/configs/<config>.json
+    cut: dict  # the published keys the depth is cut by
+    pages: int  # of the cell's pool
+    slots: int
+    window: int  # pages of the decode step's table
+    prompts: tuple[int, int]  # the prefill's (rows, bucket)
+    decode: typing.Callable  # (mcfg, cache, the compiled text, bytes of temporaries): the family's own assertions
+    prefill: typing.Callable
+    budget: tuple | None = None  # what the cell sizes that pool from: (GiB, KV layers, heads, lanes)
+    prefill_cut: dict = {}  # where the prompt pass needs another depth than the decode step
+
+
+FAMILIES = {
+    # one layer of each kind it has: conv + dense FFN, attention + experts, conv + experts
+    "lfm2": Family("chipbench_lfm2_util", "lfm2-8b-a1b-d14", dict(num_hidden_layers=3, num_dense_layers=1, layer_types=["conv", "full_attention", "conv"]), 2225, 128, 32, (4, 1024), _lfm2_decode, _lfm2_prefill),
+    # one period: three delta-rule layers and one attention layer
+    "olmo": Family("chipbench_olmo_util", "olmo-hybrid-7b-d16", dict(num_hidden_layers=4, layer_types=["linear_attention"] * 3 + ["full_attention"]), 1900, 64, 32, (4, 1024), _olmo_decode, _olmo_prefill),
+    # the leading dense layer and two expert layers (one expert layer is no stack: the scan over it is folded away)
+    "kanana2": Family("chipbench_kanana2_util", "kanana-2-30b-a3b-ep8", dict(num_hidden_layers=3), 477, 64, 32, (2, 1024), _kanana2_decode, _kanana2_prefill),
+    # the leading dense layer and one expert layer; the prompt pass one more (its last layer's feed-forward block is compiled away)
+    "glm5": Family("chipbench_glm5_util", "glm-5-ep16-d6", dict(num_hidden_layers=2), 2730, 64, 160, (1, 16384), _glm5_decode, _glm5_prefill, (3.0, 6, 1, 640), dict(num_hidden_layers=3)),
+    # ALL 32 layers: the five kinds come in an order of their own, not in periods
+    "phi4flash": Family("chipbench_phi4flash_util", "phi-4-mini-flash-reasoning", {}, 5734, 64, 160, (1, 16384), _phi4flash_decode, _phi4flash_prefill, (3.5, 1, 10, 128)),
+    # one whole period G K K K of the cell's two
+    "solar_open2": Family("chipbench_solar_open2_util", "solar-open2-250b-ep16-d8", dict(num_hidden_layers=4, gqa_layers=[0]), 2816, 64, 160, (1, 16384), _solar_open2_decode, _solar_open2_prefill, (2.75, 2, 8, 128)),
+    # one whole period S S S F: the cell's four layers
+    "cohere2_moe": Family("chipbench_cohere2_moe_util", "command-a-plus-ep16-d4", {}, 3072, 64, 160, (1, 16384), _cohere2_moe_decode, _cohere2_moe_prefill, (1.5, 1, 8, 128)),
+    # the two dense layers and two expert layers of the cell's ten (one expert layer is no stack, and the prompt pass compiles its last
+    # layer's feed-forward block away); the 16,384 prefill's 40 s: 14 the dense body, 24 the expert body, 14 of them the bucket over 4,096
+    "xing4": Family("chipbench_xing4_util", "xing4.0-29b-a4b-ep4-d10", dict(num_hidden_layers=4), 2949, 64, 160, (1, 16384), _xing4_decode, _xing4_prefill, (4.5, 10, 1, 640)),
+}
+
+
+def _family(chip, monkeypatch, row: Family, cut: dict):
+    """(the program's configuration of the row's file under ``cut``, its
+    weights and its cache as shapes on the described chip)."""
+    import importlib
+    import json
+
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.models import hybrid
+
+    load_run()  # puts the benchmark's ``benchlib`` on the path: the util modules build a configuration through the cell's kind
+    with open(os.path.join(CHIP, "configs", row.config + ".json")) as f:
+        mcfg = importlib.import_module(row.util).model_config({**json.load(f), **cut}, "bfloat16")
+    if row.budget:
+        gib, kv_layers, heads, lanes = row.budget
+        assert paged_kv.n_pages_for_budget(int(gib * 2**30), kv_layers, heads, PSZ, lanes, 2, pools=mcfg.kv_pools) == row.pages
+    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), mcfg))
+    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, row.pages, PSZ, slots=row.slots))
+    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
+    # the kernels and gmm ask the platform whether to compile or interpret: the described chip is a TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return mcfg, place(params), place(cache)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_decode_steps_compile_for_v5e_at_the_cells_window(chip, monkeypatch, family):
+    """Two decode steps as the engine's chunk runs them, at the cell's slots
+    and table, the counts in the carry, the cache donated."""
+    from areal_tpu.models import hybrid
+
+    row = FAMILIES[family]
+    mcfg, params, cache = _family(chip, monkeypatch, row, row.cut)
 
     def two_steps(params, cache, pt, ids, pos, active):
         cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
@@ -1346,47 +1066,22 @@ def test_xing4_decode_steps_compile_for_v5e_at_the_whole_window(chip, monkeypatc
         return ids, cache
 
     i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(64, 160), i32(64), i32(64), chip((64,), jnp.bool_)).compile()
-    text = compiled.as_text()
-    for name in ("paged_latent_attn", "paged_kv_write", "moe_touched_experts"):
-        assert name in text, name
-    for pool in ("bf16[10,1,2949,128,640]", "bf16[1,2949,128,640]"):
-        assert not [ln for ln in text.splitlines() if " copy(" in ln and pool in ln], pool
-    made = re.compile(r"= bf16\[(1,)?16,3584,1024\]\S* (?!parameter|get-tuple-element)")  # (a down matrix has the shape of 16 query blocks of the attention output)
-    assert not [ln for ln in text.splitlines() if made.search(ln)]
-    assert text.count(" while(") == 3  # the steps' scan and the layers' two: the rounds are no device loop
-    assert "bf16[64,14336]" in text  # the streams side by side on the lanes
-    rounds = [ln for ln in _results_outside_fusions(text) if "mhc_sinkhorn" in ln and " fusion(" in ln]
-    assert 0 < len(rounds) <= 4 * 8  # two scan bodies x two sublayers: a handful of launches each, not one a round
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+    S = row.slots
+    compiled = jax.jit(two_steps, donate_argnums=(1,)).lower(params, cache, i32(S, row.window), i32(S), i32(S), chip((S,), jnp.bool_)).compile()
+    row.decode(mcfg, cache, compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes)
 
 
-def test_xing4_prefill_compiles_for_v5e_at_the_16384_bucket(chip, monkeypatch):
-    """ONE prompt of 16,384 tokens, the cell's longest bucket, into the latent
-    pool: four streams of 117 MB each beside their successors, the
-    coefficients' 24 columns a token with the batch on the lanes, attention
-    blocked over 1,024 queries under ``mla_prefill_flash``, the expert rows
-    8,192 at a time on the stack. Under 4 GB of temporaries: with 4.45 GB of
-    weights and a pool of 4.5 GiB, under 15 GB."""
+@pytest.mark.parametrize("family", FAMILIES)
+def test_prefill_compiles_for_v5e_at_the_cells_longest_bucket(chip, monkeypatch, family):
+    """The engine's prefill program over the cell's longest bucket (the
+    first three cells: a batch of 1,024-token prompts), the cache donated."""
     from areal_tpu.models import hybrid
 
-    mcfg, params, cache, _ = _xing4(chip, monkeypatch, layers=4)
-    assert hybrid.prefill_takes_launch(mcfg, 16384) and hybrid.prefill_blocks(mcfg, 16384, launch=True) == (1024, 1024)
-    assert hybrid.ffn_block_rows(mcfg, "moe", 16384) == 8192
+    row = FAMILIES[family]
+    mcfg, params, cache = _family(chip, monkeypatch, row, {**row.cut, **row.prefill_cut})
+    rows, bucket = row.prompts
 
-    def prefill(params, cache, ids, plens, flat_pages, slots):
-        return hybrid.prefill_into_cache(params, mcfg, cache, ids, plens, flat_pages, slots, page_size=PSZ)
-
+    prefill = lambda params, cache, *rest: hybrid.prefill_into_cache(params, mcfg, cache, *rest, page_size=PSZ)  # noqa: E731
     i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
-    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(1, 16384), i32(1), i32(16384 // PSZ), i32(1)).compile()
-    text = compiled.as_text()
-    assert "mla_prefill_flash" in text and "bf16[1,16384,14336]" in text
-    made = _results_outside_fusions(text)
-    # no float32 copy of the streams and no stream axis next to the minor one (a bfloat16 axis of 4 is padded to 16) in HBM
-    assert not [ln for ln in made if re.search(r"= f32\[(1,)?16384,14336\]|= \S+\[(1,)?16384,4,3584\]", ln)]
-    # the Sinkhorn rounds' vectors keep the batch on the lanes: none is laid out [rows, 1] as the mixes read them
-    rounds = [ln for ln in made if "mhc_sinkhorn" in ln and " fusion(" in ln]
-    assert rounds and not [ln for ln in rounds if "f32[16384,1]" in ln.split(" fusion(")[0]]
-    made = re.compile(r"= bf16\[(1,)?16,3584,1024\]\S* (?!parameter|get-tuple-element)")  # (a down matrix has the shape of 16 query blocks of the attention output)
-    assert not [ln for ln in text.splitlines() if made.search(ln)]
-    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(rows, bucket), i32(rows), i32(rows * bucket // PSZ), i32(rows)).compile()
+    row.prefill(mcfg, cache, compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes)

@@ -30,6 +30,7 @@ from chipbench_util import CHIP  # noqa: E402
 
 from areal_tpu.inference import paged_kv  # noqa: E402
 from areal_tpu.models import hybrid  # noqa: E402
+from tests.family_harness import decode_step, fresh_cache, prefill_into_slot, program_logits, with_counts  # noqa: E402
 
 TOL = 2e-5
 PSZ, WP = 8, 16
@@ -97,7 +98,7 @@ def test_prefill_logits_agree_with_the_reference(model, n_tokens):
     ref = xu.reference()
     want = ref.logits(params, cfg, ids)
     assert want.shape == (n_tokens, 500) and want.std() > 0.05
-    np.testing.assert_allclose(xu.program_logits(cfg, params, ids), want, atol=TOL, rtol=0)
+    np.testing.assert_allclose(program_logits(mcfg, params, ids), want, atol=TOL, rtol=0)
     # the mechanisms are no formality at these weights: the reference without each of them reads elsewhere
     res_is_identity = jax.tree.map(lambda a: a, params)
     for stack in ("mla", "mla_moe"):
@@ -143,28 +144,19 @@ def test_prefill_then_decode_through_the_latent_pages_agrees_with_the_reference(
     want = [ref.logits(params, cfg, s) for s in seqs]
     S = 3
     assert mcfg.kv_pools == {"k": (1, 256)} and mcfg.kv_groups["full"]["writers"] == tuple(range(6))
-    cache = paged_kv.init_paged_cache(mcfg, S * WP + 1, PSZ, slots=S)
+    cache, pt = fresh_cache(mcfg, S, WP, PSZ)
     assert {n: a.shape for n, a in cache.items()} == {"k": (6, 1, S * WP + 1, PSZ, 256)}
-    pt = np.zeros((S, WP), np.int32)
-    pt[0], pt[1] = np.arange(1, WP + 1), np.arange(WP + 1, 2 * WP + 1)
-    bucket = 48
-    ids = np.zeros((2, bucket), np.int32)
-    for i, p in enumerate(plens):
-        ids[i, :p] = seqs[i][:p]
-    flat = np.concatenate([pt[i, : bucket // PSZ] for i in range(2)])
-    cache = hybrid.prefill_into_cache(
-        params, mcfg, cache, jnp.asarray(ids), jnp.asarray(plens), jnp.asarray(flat), jnp.asarray([0, 1]), page_size=PSZ
-    )
-    cache = {**cache, **{k: jnp.zeros(s, jnp.int32) for k, s in mcfg.count_shapes.items()}}
-    step = jax.jit(functools.partial(hybrid.forward_decode_paged, page_size=PSZ, use_kernel=use_kernel), static_argnums=1)
-    active = jnp.array([True, True, False])
+    pt[2] = 0
+    cache = with_counts(mcfg, prefill_into_slot(mcfg, params, cache, pt, [(i, seqs[i][:p]) for i, p in enumerate(plens)], 48, PSZ))
+    step, pt, active = decode_step(mcfg, PSZ, use_kernel), jnp.asarray(pt), jnp.array([True, True, False])
+    hidden, _ = jax.eval_shape(lambda c: hybrid.forward_decode_paged(params, mcfg, pt[:, 0], pt[:, 0], c, pt, page_size=PSZ, active=active, use_kernel=False), cache)
+    assert hidden.shape == (S, D)  # four streams go in, ONE vector a row comes out
     worst = 0.0
     for t in range(new):
         tok = jnp.array([seqs[0][plens[0] - 1 + t], seqs[1][plens[1] - 1 + t], 0])
         pos = jnp.array([plens[0] - 1 + t, plens[1] - 1 + t, 0])
-        hidden, cache = step(params, mcfg, tok, pos, cache, jnp.asarray(pt), active=active)
-        assert hidden.shape == (S, D)  # four streams go in, ONE vector a row comes out
-        logits = np.asarray(hybrid.compute_logits(params, mcfg, hidden))
+        logits, cache = step(params, tok, pos, cache, pt, active)
+        logits = np.asarray(logits)
         for i in range(2):
             worst = max(worst, np.abs(logits[i] - want[i][plens[i] - 1 + t]).max())
     assert worst < TOL, worst
