@@ -57,11 +57,23 @@ first call holds WITHOUT tracing:
     calls one) and its key (kind and shape fields); and the arguments' tree
     structure, shapes, dtypes, weak types and shardings.
 
+A host constant of the traced form (numpy: a tile list, a rotary table, jax's
+``TypedNdArray`` in ``traced.jaxpr.consts``) is in that key already: the
+traced code MADE it from the source, the configuration and the arguments'
+shapes, so such constants pass up to ``_MAX_HOST_CONST_BYTES`` in all. A
+device array (``jax.Array``) was made outside the trace, by some process, and
+its values are in no key: more than ``_MAX_CONST_ELEMS`` elements of them
+refuse the program (``constants_not_in_the_key``).
+
 The first call computes that key and, on a HIT, reads the entry and loads its
 executable (``jax.experimental.serialize_executable``): no trace, no
 lowering. The read and load is an ``areal.xla.cache_load`` span inside the
 build: the same act as the persistent cache's hit, an executable read from
-disk and loaded. On a MISS (and wherever the store is off: every CPU run) it
+disk and loaded. It stays AT the first call, on the caller's thread: a loader
+thread fed at ``initialize()`` was built and measured (PERF.md, PR 56) and
+gave 1 s of 26 where the warm-up asks for every program at once, because two
+loads do not overlap and a load waits for the program running on the chip.
+On a MISS (and wherever the store is off: every CPU run) it
 traces, lowers and compiles once, explicitly (``trace().lower().compile()``:
 jax's persistent cache still serves the compile), runs the executable, and
 writes the entry (packed as jax's cache packs an executable: zstandard, else
@@ -72,9 +84,9 @@ later call traces either. ``areal.program.build`` says which in ``served``:
     store    read from the store
     jit      traced, lowered and compiled (or loaded by jax's cache) here
     refused  as ``jit``, and the store will not hold it: its traced form
-             closes over array constants (their values are in no key), or
-             what its builder closes over has no process-independent
-             description
+             closes over device arrays or too many bytes of host constants
+             (values in no key), or what its builder closes over has no
+             process-independent description
 
 A later call whose arguments the executable does not take (another tree,
 shape, dtype or sharding: an error before execution, so nothing was donated)
@@ -207,7 +219,8 @@ def install_compile_counters() -> bool:
 # -- the program store --------------------------------------------------------
 _PACKAGE_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _PROGRAMS = "programs"  # the store's directory inside the XLA cache's
-_MAX_CONST_ELEMS = 16  # what a stored program's traced form may close over: a few scalars
+_MAX_CONST_ELEMS = 16  # the DEVICE array elements a stored program's traced form may close over: a few scalars
+_MAX_HOST_CONST_BYTES = 8 << 10  # and the bytes of host tables its traced code made (tile lists, a rotary table: under 1 KiB a program today)
 _SETTLE_S = 5.0  # no program built for this long: set-up has ended, say what it built
 _ADDRESS = re.compile(r" at 0x[0-9a-f]+")
 _STORE_STATS = {"built": 0, "hits": 0, "misses": 0, "refused": 0, "load_seconds": 0.0, "write_seconds": 0.0}
@@ -500,6 +513,27 @@ def store_summary() -> str:
     )
 
 
+def constants_not_in_the_key(consts) -> str | None:
+    """Why a program whose traced form closes over ``consts``
+    (``traced.jaxpr.consts``) may not be stored, or None. A device array
+    (``jax.Array``) was made OUTSIDE the trace (a weight, a table some process
+    computed): its values are in no key, and more than a few scalars of them
+    refuse the program. A host constant (numpy: jax's ``TypedNdArray``) was
+    made BY the traced code from the configuration and the arguments' shapes,
+    all in the key with the source; the byte bound keeps a weight handed in as
+    numpy out."""
+    import jax
+
+    device = [c for c in consts if isinstance(c, jax.Array)]
+    closed = sum(int(np.size(c)) for c in device)
+    host = sum(int(np.size(c)) * np.dtype(getattr(c, "dtype", np.float64)).itemsize for c in consts if not isinstance(c, jax.Array))
+    if closed <= _MAX_CONST_ELEMS and host <= _MAX_HOST_CONST_BYTES:
+        return None
+    over, what = (device, f"{closed} array elements") if closed > _MAX_CONST_ELEMS else (consts, f"{host} bytes of host constants (over {_MAX_HOST_CONST_BYTES})")
+    shapes = ", ".join(f"{getattr(c, 'dtype', type(c).__name__)}{list(np.shape(c))}" for c in over)
+    return f"its traced form closes over {what}, whose values are in no key (pass arrays as arguments): {shapes}"
+
+
 class BuiltProgram:
     """A program after its first call, as its builder's cache keeps it: the
     loaded executable (``jax.stages.Compiled``, jax's C++ call path), which no
@@ -597,13 +631,11 @@ class FirstCall:
     def _refused(self, entry: str | None, consts) -> str | None:
         """``refused`` (counted, one WARNING) where the store may not hold the
         program just built; else None, and the miss is counted."""
-        closed = sum(int(np.size(c)) for c in consts)
         if entry is None:
             why = "what its builder closes over, or an argument, has no process-independent description"
-        elif closed > _MAX_CONST_ELEMS:
-            shapes = ", ".join(f"{getattr(c, 'dtype', type(c).__name__)}{list(np.shape(c))}" for c in consts)
-            why = f"its traced form closes over {closed} array elements, whose values are in no key (pass arrays as arguments): {shapes}"
         else:
+            why = constants_not_in_the_key(consts)
+        if why is None:
             _count("misses")
             return None
         _count("refused")

@@ -177,6 +177,66 @@ def test_a_program_that_closes_over_an_array_is_refused_and_counted(tmp_path, sa
     assert _served(n0)[-1] == "jit" and len(os.listdir(tmp_path)) == 1
 
 
+def _table(n, dtype=np.float32):
+    """A traced function that makes a host table of ``n`` elements (as the flash kernels' tile lists and the scaled
+    rotary table are made: numpy, from the configuration and the shapes) and reads its first rows."""
+    return lambda x, cache: (x + jnp.asarray(np.arange(n, dtype=dtype))[: x.shape[0]], {**cache, "n": cache["n"] + 1})
+
+
+def _closed_over(n):
+    device = jnp.arange(float(n))  # made outside the trace, on the device: some process's values
+    return lambda x, cache: (x + device[: x.shape[0]], {**cache, "n": cache["n"] + 1})
+
+
+def _tile_tables(nb):
+    """The flash launch's tables as ``ops/flash_kernels.py`` makes them (which key tiles a query tile runs, and the inner
+    index), at two call sites: ``bool[1, nb, nb]`` and ``int32[nb]`` twice."""
+
+    def site(x):
+        runs, inner = np.tril(np.ones((1, nb, nb), bool)), np.arange(nb, dtype=np.int32)
+        return 0.5 * jnp.where(jnp.asarray(runs)[0, nb - 1, : x.shape[0]], jnp.asarray(inner)[: x.shape[0]], 0)
+
+    return lambda x, cache: (x + site(x) + site(x), {**cache, "n": cache["n"] + 1})
+
+
+def _both(f, g):
+    return lambda x, cache: f(*g(x, cache))
+
+
+_BOUND = compile_cache._MAX_HOST_CONST_BYTES
+CONSTANTS = {
+    # name -> (the traced function, stored?, what the WARNING says, the output over arange(4))
+    "host_table_112": (lambda: _table(112), True, None, 2.0),  # the train step's tile tables at 3 x 4,096
+    "host_table_2048": (lambda: _table(2048), True, None, 2.0),
+    "host_table_16": (lambda: _table(16), True, None, 2.0),
+    "host_tile_tables_bool_and_int32": (lambda: _tile_tables(16), True, None, 2.0),  # a 16,384 prompt's, as cells 10 and 11 trace them
+    "host_table_at_the_byte_bound": (lambda: _table(_BOUND, np.int8), True, None, 2.0),
+    "device_array_16": (lambda: _closed_over(16), True, None, 2.0),  # as before: a few scalars
+    "device_array_17": (lambda: _closed_over(17), False, "closes over 17 array elements", 2.0),
+    "device_array_beside_a_host_table": (lambda: _both(_closed_over(17), _table(112)), False, "closes over 17 array elements", 3.0),
+    "host_constant_over_the_byte_bound": (lambda: _table(_BOUND // 4 + 1), False, "bytes of host constants", 2.0),  # a weight handed in as numpy
+    "host_tables_over_the_bound_together": (lambda: _both(_table(_BOUND // 8 + 1), _table(_BOUND // 8)), False, "bytes of host constants", 3.0),
+}
+
+
+@pytest.mark.parametrize("case", list(CONSTANTS))
+def test_host_tables_the_traced_code_made_are_stored_and_device_arrays_are_not(tmp_path, said, case):
+    make, stored, warning, times = CONSTANTS[case]
+    n0, refused0 = _n_builds(), compile_cache.store_stats()["refused"]
+    outs = []
+    for _ in range(2):  # two builders, as two processes would be
+        y, c = _first_call(ProgramStore(str(tmp_path)), fn=make())[1](*_args())
+        outs.append(np.asarray(y))
+    assert all(np.array_equal(y, times * np.arange(4.0)) for y in outs)
+    if stored:
+        assert _served(n0) == ["jit", "store"] and len(os.listdir(tmp_path)) == 1 and said == []
+        assert compile_cache.store_stats()["refused"] == refused0
+    else:
+        assert _served(n0) == ["refused", "refused"] and os.listdir(tmp_path) == []
+        assert len(said) == 2 and all(warning in line for line in said)
+        assert compile_cache.store_stats()["refused"] - refused0 == 2
+
+
 def test_what_has_no_description_is_refused_and_an_unstored_program_still_runs(tmp_path, said):
     n0 = _n_builds()
     assert compile_cache.describe(object()) is None and compile_cache.describe(jnp.ones(2)) is None
