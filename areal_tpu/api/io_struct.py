@@ -34,6 +34,13 @@ class GenerationHyperparameters:
     ignore_eos: bool = False
     # detokenization control applied by workflows when rendering completions
     skip_special_tokens: bool = True
+    # a block-diffusion model's generation (docs/serving.md "Block diffusion"); None = the model's default:
+    # denoise passes a block (``block_length / denoising_steps`` positions committed a pass under the counted rules),
+    # the rule (``sequential`` | ``low_confidence_static`` | ``low_confidence_dynamic``), the dynamic rule's threshold.
+    # Every other model ignores them
+    denoising_steps: int | None = None
+    remasking_strategy: str | None = None
+    confidence_threshold: float | None = None
 
     def new(self, **kwargs) -> "GenerationHyperparameters":
         return dataclasses.replace(self, **kwargs)
@@ -97,6 +104,9 @@ class ModelResponse:
     output_tokens: list[int] = dataclasses.field(default_factory=list)
     output_logprobs: list[float] = dataclasses.field(default_factory=list)
     output_versions: list[int] = dataclasses.field(default_factory=list)
+    # a block-diffusion model: the denoise pass of its block (0-based) that committed each output token, under whose
+    # block state ``output_logprobs[i]`` and ``output_versions[i]`` were taken; empty for every other model
+    output_denoise_pass: list[int] = dataclasses.field(default_factory=list)
     stop_reason: str = StopReason.STOP.value
     # lifecycle truncation flag: "" (normal), "deadline" (reaped at its
     # deadline between decode chunks), "watchdog" (no-progress abort), or

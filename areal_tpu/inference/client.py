@@ -461,6 +461,7 @@ class RemoteJaxEngine(InferenceEngine):
         accumulated: list[int] = []
         logprobs: list[float] = []
         versions: list[int] = []
+        denoise_pass: list[int] = []  # a block-diffusion model's commit pass of each token
         remaining = g.max_new_tokens
         start = time.monotonic()
         ttft = None
@@ -530,6 +531,9 @@ class RemoteJaxEngine(InferenceEngine):
                         "max_tokens": g.max_tokens,
                         "ignore_eos": g.ignore_eos,
                         "frequency_penalty": g.frequency_penalty,
+                        "denoising_steps": g.denoising_steps,
+                        "remasking_strategy": g.remasking_strategy,
+                        "confidence_threshold": g.confidence_threshold,
                         # abort-resume aware: tokens already accumulated across
                         # attempts count toward the minimum
                         "min_new_tokens": max(
@@ -568,6 +572,7 @@ class RemoteJaxEngine(InferenceEngine):
                 accumulated.extend(toks)
                 logprobs.extend(data["output_logprobs"])
                 versions.extend(data["output_versions"])
+                denoise_pass.extend(data.get("output_denoise_pass") or [])
                 cached_prefix_tokens += int(
                     data.get("cached_prefix_tokens") or 0
                 )
@@ -646,6 +651,7 @@ class RemoteJaxEngine(InferenceEngine):
             output_tokens=accumulated,
             output_logprobs=logprobs,
             output_versions=versions,
+            output_denoise_pass=denoise_pass,
             stop_reason=stop_reason,
             truncated_by=truncated_by,
             latency=time.monotonic() - start,

@@ -117,6 +117,8 @@ class _Task:
     out_tokens: list[int] = field(default_factory=list)
     out_logprobs: list[float] = field(default_factory=list)
     out_versions: list[int] = field(default_factory=list)
+    # a block model's: the pass of its block (0-based) that committed each token
+    out_passes: list[int] = field(default_factory=list)
     first_token_time: float | None = None
     # lifecycle truncation flag carried into the response: "deadline",
     # "watchdog", or "cancelled" ("" = normal termination)
@@ -369,12 +371,12 @@ class DecodeEngine:
         # last time each slot made progress (admission or token emission);
         # the per-slot watchdog compares against lifecycle.watchdog_s
         self._slot_progress: list[float] = [0.0] * S
-        self._state = slot_state(S)
+        self._state = slot_state(S, self.programs.block)
         # per-slot generated-token counts (OpenAI frequency_penalty
         # semantics) live DEVICE-ONLY — the host never reads them back, so
         # no [S, V] host mirror. uint16 with saturating updates. Config-
         # gated so default fleets pay neither the memory nor new variants.
-        self._freq_enabled = bool(cfg.enable_frequency_penalty)
+        self._freq_enabled = bool(cfg.enable_frequency_penalty) and "frequency_penalty" not in self._model_limits()
         self._pending_count_restore: list[tuple[int, np.ndarray]] = []
         # COMMITTED to the mesh (replicated) from the start: every jitted
         # serving fn hands its state/rng outputs back committed, and an
@@ -505,6 +507,7 @@ class DecodeEngine:
         load_shape = mcfg.moe_count_shapes.get("moe_load")
         self._moe_load = np.zeros(load_shape, np.int64) if load_shape else None
         self._attn_blocks = (0, 0)  # (listed, fetched) by the decode steps' attention launches: decode_attention_status
+        self._block_passes = (0, 0)  # (denoise, commit) slot-passes of a block model's drained chunks
 
     def precompile(
         self,
@@ -1468,6 +1471,14 @@ class DecodeEngine:
             self._obs.moe_assignments.inc(int(counts["moe_load"].sum()))
             self._obs.moe_experts_touched.inc(int(counts["moe_touched"].sum()))
             self._obs.moe_experts_streamed.inc(int(counts["moe_streamed"].sum()))
+        if "blk_denoise_passes" in counts:
+            den, com = int(counts["blk_denoise_passes"].sum()), int(counts["blk_commit_passes"].sum())
+            self._obs.block_denoise_passes.inc(den)
+            self._obs.block_commit_passes.inc(com)
+            self._obs.blocks.inc(int(counts["blk_blocks"].sum()))
+            self._obs.block_attn_tokens_read.inc(int(counts["blk_attn_tokens_read"].sum()))
+            # arealint: disable-next=THR001 single writer (the decode loop, at a drain); the pass's span reads the pair after it
+            self._block_passes = (self._block_passes[0] + den, self._block_passes[1] + com)
         if "gdn_updates" in counts:
             self._obs.gdn_state_updates.inc(int(counts["gdn_updates"].sum()))
         if "kda_updates" in counts:
@@ -1730,6 +1741,18 @@ class DecodeEngine:
                 f"{TOPK_CAP}; clamping (rid={task.req.rid})"
             )
             top_k = TOPK_CAP
+        block = None
+        if (B := self.programs.block) > 1:
+            # a block model's slot starts at the first position past the whole blocks its pages hold: the prompt's
+            # tokens beyond them (none after a resume: a request's emitted blocks end on a boundary) are the clean
+            # positions its first block starts with. The request may override the model's generation defaults
+            ids = task.req.input_ids
+            pos = len(ids) // B * B
+            mc = self.model_cfg
+            steps = g.denoising_steps or mc.denoising_steps
+            rule = g.remasking_strategy if g.remasking_strategy in qwen.REMASKING_RULES else mc.remasking_strategy
+            thresh = mc.confidence_threshold if g.confidence_threshold is None else g.confidence_threshold
+            block = (max(1, B // max(1, min(int(steps), B))), qwen.REMASKING_RULES.index(rule), float(thresh), list(ids[pos:]))
         return pack_row(
             self._state,
             slot,
@@ -1750,6 +1773,7 @@ class DecodeEngine:
                 remaining - max(0, g.min_new_tokens - len(task.out_tokens)),
             ),
             freq_pen=self._effective_freq_pen(task),
+            block=block,
         )
 
     def _effective_freq_pen(self, task: _Task) -> float:
@@ -2098,7 +2122,7 @@ class DecodeEngine:
         # their next chunks (one in flight, one ahead) stays free: a wave of
         # long prompts that took the pool's last page would have the first
         # decode step preempt one of them for it
-        per_slot = -(-(2 * self.config.decode_steps_per_call + 1) // psz)
+        per_slot = -(-(2 * self.programs.chunk_ahead + 1) // psz)
         decoding = int(np.count_nonzero(self._state["active"]))
         for task, slot in group:
             plen = len(task.req.input_ids)
@@ -2227,6 +2251,7 @@ class DecodeEngine:
             output_tokens=task.out_tokens,
             output_logprobs=task.out_logprobs,
             output_versions=task.out_versions,
+            output_denoise_pass=task.out_passes,
             stop_reason=reason,
             truncated_by=task.truncated_by,
             latency=time.monotonic() - task.submit_time,
@@ -2296,7 +2321,7 @@ class DecodeEngine:
         RETRACT_DECODE preemption plays)."""
         st = self._state
         psz = self.config.page_size
-        n_steps = self.config.decode_steps_per_call
+        n_steps = self.programs.chunk_ahead  # positions a chunk may write (a block model: blocks, not steps)
         if ahead is None:
             ahead = 2 * n_steps
         deact_rows: list[np.ndarray] = []
@@ -2384,19 +2409,23 @@ class DecodeEngine:
             return None
         n_steps = cfg.decode_steps_per_call
         # host pos can be one in-flight chunk stale -> widen by 2 chunks
-        wp = self.programs.window_pages(int(st["pos"][active].max()), 2 * n_steps)
+        wp = self.programs.window_pages(int(st["pos"][active].max()), 2 * self.programs.chunk_ahead)
         capped = bool(((st["top_k"] > 0) | (st["top_p"] < 1.0))[active].any())
         greedy_any = bool(st["greedy"][active].any())
         freq_any = self._freq_enabled and bool(
             (st["freq_pen"] != 0.0)[active].any()
         )
         chunk = self.programs.chunk_fn(n_steps, wp, capped, greedy_any, freq_any)
+        # a block model's chunk stamps every position it commits with the weights of that pass
+        block = self.programs.block > 1
+        version = (jnp.asarray(self._version, jnp.int32),) if block else ()
         with set_mesh(self.mesh):
             pt = jnp.asarray(self.slots.page_table(wp))
             self.cache, self._dev_state, self._rng, packed = chunk(
-                self.params, self.cache, pt, self._dev_state, self._rng
+                self.params, self.cache, pt, self._dev_state, self._rng, *version
             )
         return {
+            "block": block,
             "packed": packed,
             "n_steps": n_steps,
             "version": self._version,
@@ -2584,13 +2613,25 @@ class DecodeEngine:
             n_steps = pending["n_steps"]
             version = pending["version"]
             was_active = pending["was_active"]
-            toks = packed[:n_steps]
-            logps = packed[n_steps : 2 * n_steps].view(np.float32)
-            emit_count = packed[2 * n_steps]
-            active = packed[2 * n_steps + 1].astype(bool)
-            pos = packed[2 * n_steps + 2]
-            if packed.shape[0] > 2 * n_steps + 3:  # a chunk's counts (a speculative round brings none)
-                self._credit_counts(packed[2 * n_steps + 3 :].reshape(-1))
+            # a block model's chunk (``DecodePrograms._block_chunk``): a pass emits 0 to B tokens a slot, by block
+            B = self.programs.block if pending.get("block") else 1
+            rows = n_steps * B
+            toks = packed[:rows]
+            logps = packed[rows : 2 * rows].view(np.float32)
+            if B > 1:
+                passes, vers = packed[2 * rows : 3 * rows], packed[3 * rows : 4 * rows]
+                emit_n = packed[4 * rows : 4 * rows + n_steps]  # [pass, slot]
+                emit_count = emit_n.sum(0)
+                # [pass, place, slot]: the places a slot's pass emitted, in sequence order
+                took = (np.arange(B)[None, :, None] < emit_n[:, None, :]).reshape(rows, -1)
+                tail = 4 * rows + n_steps
+            else:
+                emit_count = packed[2 * n_steps]
+                tail = 2 * n_steps + 1
+            active = packed[tail].astype(bool)
+            pos = packed[tail + 1]
+            if packed.shape[0] > tail + 2:  # a chunk's counts (a speculative round brings none)
+                self._credit_counts(packed[tail + 2 :].reshape(-1))
             st = self._state
             now = time.monotonic()
             for slot, task in enumerate(pending["tasks"]):
@@ -2614,13 +2655,21 @@ class DecodeEngine:
                     self._slot_progress[slot] = now  # watchdog: progress seen
                     # .tolist() converts in C — a genexpr of int()/float() costs
                     # ~S*n_steps Python calls per chunk on the serving hot loop
-                    task.out_tokens.extend(toks[:c, slot].tolist())
-                    task.out_logprobs.extend(logps[:c, slot].tolist())
-                    task.out_versions.extend([version] * c)
+                    if B > 1:
+                        at = took[:, slot]
+                        task.out_tokens.extend(toks[at, slot].tolist())
+                        task.out_logprobs.extend(logps[at, slot].tolist())
+                        task.out_versions.extend(vers[at, slot].tolist())  # the weights of the pass that committed each
+                        task.out_passes.extend(passes[at, slot].tolist())
+                    else:
+                        task.out_tokens.extend(toks[:c, slot].tolist())
+                        task.out_logprobs.extend(logps[:c, slot].tolist())
+                        task.out_versions.extend([version] * c)
                     self.stats["generated_tokens"] += c
                     self._obs.generated_tokens.inc(c)
                 st["pos"][slot] = int(pos[slot])
-                st["ids"][slot] = int(toks[c - 1, slot]) if c else st["ids"][slot]
+                if B == 1:
+                    st["ids"][slot] = int(toks[c - 1, slot]) if c else st["ids"][slot]
                 st["remaining"][slot] -= c
                 st["active"][slot] = bool(active[slot])
                 if not active[slot]:
@@ -2645,7 +2694,7 @@ class DecodeEngine:
             # for a request gone by now (preempted, turned over). A
             # speculative round is one step whose rows emit several tokens
             emitted = int(emit_count[was_active].sum())
-            rows = int(was_active.sum())
+            rows = int(was_active.sum()) * B  # a pass steps a block model's slot for B rows
             steps, spent = (1, 0) if pending.get("spec") else (n_steps, rows * n_steps - emitted)
             led = self._row_steps
             led["steps"] += steps
@@ -2774,6 +2823,7 @@ class DecodeEngine:
         t_pass = self._pace_clock()
         self._pull_s = 0.0
         ledger = dict(self._row_steps)  # before the pass's drain
+        block_passes = self._block_passes
         # the chunk in flight has only just begun: commit the next one's
         # batch part-way through it, with everything that arrives until then
         held = self._hold_for_commit(pending)
@@ -2844,6 +2894,12 @@ class DecodeEngine:
                 held_us=int(held * 1e6),
                 **{k: self._row_steps[k] - ledger[k] for k in LEDGER_KEYS},  # of the chunk this pass drained
                 **({"spec": 1} if spec_on else {}),
+                # a block model: the slot-passes of the drained chunk that denoised and that committed
+                **(
+                    {"denoising": self._block_passes[0] - block_passes[0], "committing": self._block_passes[1] - block_passes[1]}
+                    if self.programs.block > 1
+                    else {}
+                ),
                 admitted=len(rows),  # requests given a slot
                 prompt_tokens=self.stats["prefill_tokens"] - prefilled,
                 queued=queued,

@@ -12,7 +12,8 @@ is the caller's to own and to thread through the donated arguments.
 
 - **the programs**: ``prefill`` (a group of prompts into their pages),
   its suffix-only form over cached prefix pages, ``chunk`` (n decode steps
-  for all slots), ``spec`` (one speculative verify-and-accept round),
+  for all slots; for a block-diffusion model n PASSES over every slot's
+  block in flight, under the same name and key: ``_block_chunk``), ``spec`` (one speculative verify-and-accept round),
   ``apply`` / ``clamp`` (slot-state scatters), ``copy_pages`` (a GRPO
   group's private pages) and the vision tower. Each is a plain ``jax.jit``
   callable under its Python name, which is the name a device trace shows
@@ -37,6 +38,7 @@ is the caller's to own and to thread through the donated arguments.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Callable
 
 import jax
@@ -54,6 +56,7 @@ logger = alog.getLogger("decode_engine")
 
 MAX_STOP = 8  # stop-token-id slots per request (padded with -1)
 UPDATE_COLS = 11 + MAX_STOP  # columns of a packed slot-update row (``update_fn``)
+BLOCK_COLS = 4  # and, for a block model, after them: positions a pass, the rule, its threshold, clean positions; then the block's ids
 TOPK_CAP = 1024  # static candidate-set size for per-slot top-k/top-p
 PREFILL_SIZES = (8, 4, 2, 1)  # batched-prefill group sizes (compile variants)
 # bytes of residual stream (rows x bucket x hidden) a prefill program may hold:
@@ -64,12 +67,37 @@ PREFILL_SIZES = (8, 4, 2, 1)  # batched-prefill group sizes (compile variants)
 _PREFILL_STREAM_BYTES = 64 << 20
 
 
-def slot_state(n_slots: int) -> dict[str, np.ndarray]:
+def slot_state(n_slots: int, block_length: int = 1) -> dict[str, np.ndarray]:
     """The per-slot decode state every program reads and hands back, all
     slots idle: the host mirror's first value, and (put on the device) the
-    ``state`` argument of ``chunk``, ``spec``, ``apply`` and ``clamp``."""
-    S = n_slots
+    ``state`` argument of ``chunk``, ``spec``, ``apply`` and ``clamp``.
+
+    A block model (``block_length`` > 1) adds the slot's block in flight,
+    which lives here and in no page until it is clean: ``pos`` is then the
+    block's first position, ``blk_ids`` its committed ids, ``blk_masked`` the
+    flags of the positions not committed yet (a FLAG, never ``id ==
+    mask_token_id``: that id is an ordinary token too), and for each
+    committed position the pass of the block that committed it (-1: a prompt
+    token), its candidate's log-probability and the weights of that pass;
+    ``blk_n`` counts the block's denoise passes so far. ``blk_k``,
+    ``blk_rule`` and ``blk_thresh`` are the request's: positions a counted
+    rule commits a pass, the rule (index into ``REMASKING_RULES``) and the
+    dynamic rule's threshold. The host mirrors none of the ``blk_`` leaves
+    after admission."""
+    S, B = n_slots, block_length
+    block = {} if B == 1 else {
+        "blk_ids": np.zeros((S, B), np.int32),
+        "blk_masked": np.zeros((S, B), bool),
+        "blk_pass": np.zeros((S, B), np.int32),
+        "blk_logp": np.zeros((S, B), np.float32),
+        "blk_ver": np.zeros((S, B), np.int32),
+        "blk_n": np.zeros(S, np.int32),
+        "blk_k": np.ones(S, np.int32),
+        "blk_rule": np.zeros(S, np.int32),
+        "blk_thresh": np.ones(S, np.float32),
+    }
     return {
+        **block,
         "ids": np.zeros(S, np.int32),
         "pos": np.zeros(S, np.int32),
         "active": np.zeros(S, bool),
@@ -101,12 +129,16 @@ def pack_row(
     stops: list[int] | None = None,
     min_rem: int | None = None,
     freq_pen: float = 0.0,
+    block: tuple[int, int, float, list[int]] | None = None,
 ) -> np.ndarray:
     """The ONE place that knows the packed scatter-row column order (it
     matches ``apply`` of ``update_fn``): update the host mirror ``state`` and
     build the fp32 row. ``min_rem``: stops fire only once remaining-1 <=
     min_rem (the min_new_tokens gate); default = remaining, i.e. always
-    allowed."""
+    allowed. A block model's row ends with ``block``: (positions a pass, the
+    rule's index, its threshold, the clean ids the slot's first block starts
+    with: the prompt's tokens past its last whole block); ``pos`` is then that
+    block's first position."""
     stops = (list(stops or []) + [-1] * MAX_STOP)[:MAX_STOP]
     if min_rem is None:
         min_rem = remaining
@@ -122,8 +154,13 @@ def pack_row(
     st["min_rem"][slot] = min_rem
     st["freq_pen"][slot] = freq_pen
     st["stop_ids"][slot] = stops
+    tail: list = []
+    if "blk_ids" in st:
+        B = st["blk_ids"].shape[1]
+        k, rule, thresh, clean = block or (1, 0, 1.0, [])
+        tail = [k, rule, thresh, len(clean), *clean, *[0] * (B - len(clean))]
     return np.asarray(
-        [slot, last_id, pos, active, remaining, top_k, greedy, temp, top_p, min_rem, freq_pen, *stops],
+        [slot, last_id, pos, active, remaining, top_k, greedy, temp, top_p, min_rem, freq_pen, *stops, *tail],
         np.float32,
     )
 
@@ -273,6 +310,27 @@ def _sample_step(logits, rng, state, capped: bool, greedy_any: bool = True, use_
     return next_ids, logp
 
 
+def select_positions(masked, logp, k, rule, thresh):
+    """Which masked positions of each slot's block a denoise pass commits
+    (``models/qwen.py REMASKING_RULES``, by index in ``rule`` [S]):
+    ``sequential`` the first ``k`` [S] masked positions in sequence order;
+    ``low_confidence_static`` the ``k`` whose candidates are the most probable
+    (``logp`` [S, B], the candidate's log-probability under the request's
+    temperature; ties to the earlier position); ``low_confidence_dynamic``
+    every one whose candidate's probability is over ``thresh`` [S], and the
+    most probable one whatever it is. ``masked`` [S, B] bool; fewer than
+    ``k`` masked positions are all taken. Returns [S, B] bool."""
+    B = masked.shape[1]
+    at = jnp.arange(B)
+    by_place = jnp.cumsum(masked, axis=-1) - 1  # rank among the masked, in sequence order
+    conf = jnp.where(masked, logp, -jnp.inf)
+    ahead = (conf[:, None, :] > conf[:, :, None]) | ((conf[:, None, :] == conf[:, :, None]) & (at[None, None, :] < at[None, :, None]))
+    by_conf = jnp.sum(ahead & masked[:, None, :], axis=-1)  # masked positions that go before this one
+    counted = jnp.where((rule == 0)[:, None], by_place, by_conf) < k[:, None]
+    dynamic = (jnp.exp(logp) > thresh[:, None]) | (by_conf == 0)
+    return masked & jnp.where((rule == 2)[:, None], dynamic, counted)
+
+
 class DecodePrograms:
     """The jitted programs of one model replica and the cache they live in."""
 
@@ -289,6 +347,12 @@ class DecodePrograms:
         self.store = compile_cache.default_store() if store is None else store
         psz = config.page_size
         self._maxp = -(-config.max_seq_len // psz)  # pages per sequence (ceil)
+        # positions a slot's decode step covers: 1, or a block model's block
+        # (models/qwen.py ``block_length``), whose blocks lie whole inside a page
+        self.block = int(getattr(model_cfg, "block_length", 1))
+        if self.block > 1 and (psz % self.block or config.max_seq_len % self.block):
+            raise ValueError(f"page_size {psz} and max_seq_len {config.max_seq_len} must be whole blocks of {self.block} positions")
+        self.update_cols = UPDATE_COLS + (BLOCK_COLS + self.block if self.block > 1 else 0)
         # the Pallas paged kernels run single-device; under TP the engine
         # takes the gather+einsum path, which GSPMD shards over the KV-head
         # axis like the dense engine did. Kernel or gather is decided HERE,
@@ -326,6 +390,14 @@ class DecodePrograms:
     def keys(self) -> set[tuple]:
         """The keys of the programs built so far."""
         return set(self._fn_cache)
+
+    @property
+    def chunk_ahead(self) -> int:
+        """Positions past a slot's own that one chunk can write: a step each;
+        for a block model a block every second pass (a block takes a denoise
+        pass and a commit pass at least) and the one a chunk may find clean."""
+        n = self.config.decode_steps_per_call
+        return n if self.block == 1 else (n // 2 + 1) * self.block
 
     def _first_call(self, key: tuple) -> compile_cache.FirstCall:
         """The program just cached under ``key`` for its first call, with what
@@ -402,7 +474,7 @@ class DecodePrograms:
         """Window page counts a decode chunk can ask for — exact up to
         ``_WARM_DENSE_CAP`` rows, then the sparse bucket-series tail."""
         T = self.config.max_seq_len
-        ahead = 2 * self.config.decode_steps_per_call
+        ahead = 2 * self.chunk_ahead
         wps = {self.window_pages(p, ahead) for p in range(min(T, self._WARM_DENSE_CAP))}
         b = self._WARM_DENSE_CAP
         while b < T:
@@ -495,6 +567,10 @@ class DecodePrograms:
         happen between chunks), so per-slot counts fully describe the
         emit mask."""
         key = ("chunk", n_steps, wp, capped, greedy_any, freq_any)
+        if key not in self._fn_cache and self.block > 1:
+            assert not freq_any, "a block model serves no frequency penalty (models/qwen.py serving_limits)"
+            self._fn_cache[key] = jax.jit(self._block_chunk(n_steps, wp, capped, greedy_any), donate_argnames=("cache", "state"))
+            return self._first_call(key)
         if key not in self._fn_cache:
             mcfg = self.model_cfg
             T = self.config.max_seq_len
@@ -611,6 +687,161 @@ class DecodePrograms:
             self._fn_cache[key] = jax.jit(chunk, donate_argnames=("cache", "state"))
             return self._first_call(key)
         return self._fn_cache[key]
+
+    def _block_chunk(self, n_steps: int, wp: int, capped: bool, greedy_any: bool):
+        """The chunk program of a block-diffusion model: ``n_steps`` PASSES
+        for all slots, each over a slot's current block of B positions
+        (``model.forward_block_paged``), the slots at whatever phase of their
+        blocks they are at.
+
+        One pass: a slot whose block still has masked positions DENOISES: the
+        head and the sampler give a candidate and its log-probability at
+        every masked position FROM THAT POSITION's own row (no shift), and
+        the request's rule commits some of them (``block_select``): they are
+        clean from then on, with the pass's number, log-probability and
+        weights kept beside them. A slot whose block has no masked position
+        COMMITS: its rows' keys and values go to its pages, the block's
+        generated tokens are emitted in sequence order (none after a stop
+        token), and the next block starts, all masked. A request's last block
+        is cut to its budget: the positions past the cut stay masked through
+        every pass and are never committed, so every state a token was
+        committed in can be told from the reply. Nothing of a block reaches a page before that pass. (A
+        committing slot's rows pass the head and the sampler too and are
+        discarded: one program, static shapes.)
+
+        ``program(params, cache, page_table, state, rng, version)``;
+        ``packed`` is int32 [4 * n_steps * B + n_steps + 2, S]: a pass's B
+        token rows, the emitted ones first, then as many rows each of
+        log-probability bits, commit passes and weight versions, the count a
+        slot emitted in each pass, final-active and final-pos, then the
+        model's counts as in the token chunk."""
+        mcfg, model = self.model_cfg, self.model
+        T, psz, B = self.config.max_seq_len, self.config.page_size, self.block
+        use_kernel, sample_kernel = self.suffix_kernel(), self.sample_kernel
+        counts_of = dict(mcfg.count_shapes)
+        offs = jnp.arange(B, dtype=jnp.int32)
+        from areal_tpu.ops.paged_attention_q8 import live_order
+
+        def chunk(params, cache, page_table, state, rng, version):
+            S = state["pos"].shape[0]
+            rows_of = {k: jnp.repeat(state[k], B) for k in ("temp", "greedy", "top_k", "top_p")}  # the sampler's, a row
+            fetched = partial(model.block_attn_tokens_fetched, wp=wp, page_size=psz, use_kernel=use_kernel)
+
+            def one_pass(carry, _):
+                pos, active, remaining, bids, bmask, bpass, blogp, bver, bn, counts, cache, rng = carry
+                # the positions the request's budget still reaches: a last block is CUT to it, and what lies past the
+                # cut holds the mask through every pass of the block, the commit pass too, and is never committed
+                first = jnp.sum(bpass < 0, axis=-1, dtype=jnp.int32)  # a prompt's tail lies before the generated ones
+                open_ = bmask & (offs[None] < (first + remaining)[:, None])
+                masked_any = open_.any(-1)
+                denoising, committing = active & masked_any, active & ~masked_any
+                start = jnp.minimum(pos, T - B)  # an idle slot's stale position stays inside the table
+                ids_in = jnp.where(bmask, mcfg.mask_token_id, bids)
+                hidden, ks, vs, *loads = model.forward_block_paged(
+                    params, mcfg, ids_in, start, active, cache, page_table, use_kernel=use_kernel
+                )
+                read = fetched(jnp.where(active, start, 0))  # what that forward's launches fetched, a layer and KV head
+                with jax.named_scope("lm_head"):
+                    logits = model.compute_logits(params, mcfg, hidden.reshape(S * B, -1))
+                with jax.named_scope("sampler"):
+                    rng, sub = jax.random.split(rng)
+                    cand, logp = _sample_step(logits, sub, rows_of, capped, greedy_any, sample_kernel)
+                with jax.named_scope("block_select"):
+                    cand, logp = cand.reshape(S, B), logp.reshape(S, B)
+                    take = select_positions(open_, logp, state["blk_k"], state["blk_rule"], state["blk_thresh"])
+                    take = take & denoising[:, None]
+                    bids = jnp.where(take, cand, bids)
+                    bpass = jnp.where(take, bn[:, None], bpass)
+                    blogp = jnp.where(take, logp, blogp)
+                    bver = jnp.where(take, version, bver)
+                    bmask = bmask & ~take
+                    bn = bn + denoising.astype(jnp.int32)
+                    # a clean block's generated tokens, in sequence order from the first one, and none after a stop
+                    # token that may fire
+                    order = jnp.minimum(offs[None] + first[:, None], B - 1)
+                    etok, elogp, epass, ever = (jnp.take_along_axis(x, order, axis=1) for x in (bids, blogp, bpass, bver))
+                    room = jnp.minimum(B - first, remaining)
+                    stops = jnp.any(etok[:, :, None] == state["stop_ids"][:, None, :], axis=-1) & (
+                        remaining[:, None] - offs[None] - 1 <= state["min_rem"][:, None]
+                    ) & (offs[None] < room[:, None])
+                    n_emit = jnp.where(stops.any(-1), jnp.argmax(stops, axis=-1).astype(jnp.int32) + 1, room)
+                    n_emit = jnp.where(committing, n_emit, 0)
+                with jax.named_scope("kv_write"):
+                    # a clean block's rows into its pages, a layer and a position of the block at a time: the decode
+                    # step's writer (one row a slot a launch; the committing slots alone on the kernel path, every
+                    # other slot's rows to the trash page on the scatter path). ONE scatter over [S x B] rows and all
+                    # KV heads re-lays the whole pool out and copies it twice a pass (PERF.md, PR 58: 2.8 of 8.7 s)
+                    at = start[:, None] + offs[None]
+                    pages = jnp.take_along_axis(page_table, jnp.clip(at // psz, 0, wp - 1), axis=1)
+                    pages, rows = jnp.where(committing[:, None], pages, 0), at % psz
+                    kv_live = live_order(committing) if use_kernel else None
+
+                    def write_layer(li, c):
+                        for j in range(B):
+                            c = paged_kv.write_decode_rows(c, li, ks[li, :, j], vs[li, :, j], pages[:, j], rows[:, j], kv_live)
+                        return c
+
+                    cache = jax.lax.fori_loop(0, ks.shape[0], write_layer, dict(cache))
+                with jax.named_scope("block_select"):
+                    remaining = remaining - n_emit
+                    new_pos = pos + B
+                    ends = stops.any(-1) | (remaining <= 0) | (new_pos + B > T)
+                    active = active & ~(committing & ends)
+                    fresh = committing[:, None]
+                    pos = jnp.where(committing, new_pos, pos)
+                    bmask = bmask | fresh
+                    bids, bpass = jnp.where(fresh, 0, bids), jnp.where(fresh, 0, bpass)
+                    bn = jnp.where(committing, 0, bn)
+                    counts = dict(counts)
+                    for name, n in (
+                        ("blk_denoise_passes", jnp.sum(denoising, dtype=jnp.int32)),
+                        ("blk_commit_passes", jnp.sum(committing, dtype=jnp.int32)),
+                        ("blk_blocks", jnp.sum(n_emit > 0, dtype=jnp.int32)),
+                        ("blk_attn_tokens_read", read),
+                    ):
+                        counts[name] = counts[name] + n
+                    if loads:
+                        counts["moe_load"] = counts["moe_load"] + loads[0]
+                        counts["moe_touched"] = counts["moe_touched"] + jnp.sum(loads[0] > 0, axis=-1, dtype=jnp.int32)
+                        counts["moe_streamed"] = counts["moe_streamed"] + mcfg.num_experts  # every form here reads them all
+                return (pos, active, remaining, bids, bmask, bpass, blogp, bver, bn, counts, cache, rng), (
+                    etok, elogp, epass, ever, n_emit,
+                )
+
+            carry = (
+                state["pos"], state["active"], state["remaining"],
+                state["blk_ids"], state["blk_masked"], state["blk_pass"], state["blk_logp"], state["blk_ver"], state["blk_n"],
+                {k: jnp.zeros(shp, jnp.int32) for k, shp in counts_of.items()}, cache, rng,
+            )
+            (pos, active, remaining, bids, bmask, bpass, blogp, bver, bn, counts, cache, rng), (
+                toks, logps, passes, vers, n_emit,
+            ) = jax.lax.scan(one_pass, carry, None, length=n_steps)
+            out_state = dict(state)
+            out_state.update(
+                pos=pos, active=active, remaining=remaining,
+                blk_ids=bids, blk_masked=bmask, blk_pass=bpass, blk_logp=blogp, blk_ver=bver, blk_n=bn,
+            )
+            flat = jnp.concatenate([counts[k].reshape(-1) for k in counts_of])
+
+            def rows(x):  # [n, S, B] -> a row a (pass, place)
+                return jnp.transpose(x, (0, 2, 1)).reshape(n_steps * B, S)
+
+            packed = jnp.concatenate(
+                [
+                    rows(toks.astype(jnp.int32)),
+                    rows(jax.lax.bitcast_convert_type(logps.astype(jnp.float32), jnp.int32)),
+                    rows(passes),
+                    rows(vers),
+                    n_emit,  # [n_steps, S]
+                    active.astype(jnp.int32)[None],
+                    pos.astype(jnp.int32)[None],
+                    jnp.pad(flat, (0, -flat.size % S)).reshape(-1, S),
+                ],
+                axis=0,
+            )
+            return cache, out_state, rng, packed
+
+        return chunk
 
     def spec_fn(self, B: int, wp: int, capped: bool, greedy_any: bool = True):
         """One speculative verify+accept round in a single jitted call.
@@ -793,6 +1024,24 @@ class DecodePrograms:
                 state["stop_ids"] = (
                     state["stop_ids"].at[sl].set(upd[:, 11 : 11 + MAX_STOP].astype(jnp.int32))
                 )
+                if "blk_ids" in state:
+                    # a block model's columns (``pack_row``): the slot starts a block with its first ``clean``
+                    # positions the prompt's tail and the rest masked, before its first pass
+                    blk = upd[:, UPDATE_COLS:]
+                    B = state["blk_ids"].shape[1]
+                    masked = jnp.arange(B)[None] >= blk[:, 3:4].astype(jnp.int32)
+                    for name, val in (
+                        ("blk_k", blk[:, 0].astype(jnp.int32)),
+                        ("blk_rule", blk[:, 1].astype(jnp.int32)),
+                        ("blk_thresh", blk[:, 2]),
+                        ("blk_ids", blk[:, BLOCK_COLS:].astype(jnp.int32)),
+                        ("blk_masked", masked),
+                        ("blk_pass", jnp.where(masked, 0, -1)),
+                        ("blk_logp", jnp.zeros(masked.shape, jnp.float32)),
+                        ("blk_ver", jnp.zeros(masked.shape, jnp.int32)),
+                        ("blk_n", jnp.zeros(masked.shape[0], jnp.int32)),
+                    ):
+                        state[name] = state[name].at[sl].set(val)
                 return state
 
             self._fn_cache[key] = jax.jit(apply, donate_argnames=("state",))
@@ -1068,10 +1317,11 @@ class DecodePrograms:
 
         kind, *rest = key
         if kind == "chunk":
-            return self.chunk_fn(*rest), (params_s, cache_s, i32(cfg.max_batch_size, rest[1]), state_s, rng_s)
+            version = (i32(),) if self.block > 1 else ()  # as the page table: a host value, placed by the call
+            return self.chunk_fn(*rest), (params_s, cache_s, i32(cfg.max_batch_size, rest[1]), state_s, rng_s, *version)
         if kind == "upd":
             (n,) = rest
-            return self.update_fn(n), (state_s, jax.ShapeDtypeStruct((n, UPDATE_COLS), jnp.float32))
+            return self.update_fn(n), (state_s, jax.ShapeDtypeStruct((n, self.update_cols), jnp.float32))
         if kind == "clamp":
             (n,) = rest
             return self.clamp_fn(n), (state_s, i32(n, 2))

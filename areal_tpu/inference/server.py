@@ -45,6 +45,9 @@ def _req_from_json(d: dict) -> ModelRequest:
         ignore_eos=bool(g.get("ignore_eos", False)),
         frequency_penalty=float(g.get("frequency_penalty", 0.0)),
         min_new_tokens=int(g.get("min_new_tokens", 0)),
+        denoising_steps=g.get("denoising_steps"),
+        remasking_strategy=g.get("remasking_strategy"),
+        confidence_threshold=g.get("confidence_threshold"),
     )
     image_data = None
     if d.get("image_data"):
@@ -446,6 +449,7 @@ class InferenceServer:
                 "output_tokens": resp.output_tokens,
                 "output_logprobs": resp.output_logprobs,
                 "output_versions": resp.output_versions,
+                "output_denoise_pass": resp.output_denoise_pass,  # a block-diffusion model's; else empty
                 "stop_reason": resp.stop_reason,
                 "truncated_by": resp.truncated_by,
                 "latency": resp.latency,

@@ -11,7 +11,7 @@ from areal_tpu.models.qwen import (  # noqa: F401
 def family_of(model_cfg):
     """The module that implements ``model_cfg``'s model family: the one place
     where the serving stack picks between them (``models/qwen.py``: the
-    Qwen2/3 and llama decoders; ``models/hybrid.py``: ``granitemoehybrid``,
+    Qwen2/3 and llama decoders and ``sdar_moe``, which generates by blocks; ``models/hybrid.py``: ``granitemoehybrid``,
     ``lfm2_moe``, ``olmo_hybrid``, ``deepseek_v3``, ``glm_moe_dsa`` and ``phi4flash``). Both have the entry points
     the decode engine calls (``param_partition_specs``, ``hf_name_map``,
     ``prefill_into_cache``, ``prefill_row_bytes``, ``forward_prefill_paged``,

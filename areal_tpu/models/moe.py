@@ -410,8 +410,12 @@ def expert_ffn(x, layer: dict, cfg, *, live=None, e0=0):
     return out, scores, top_e, load
 
 
-def moe_ffn_dropless(h: jax.Array, layer: dict, cfg) -> tuple[jax.Array, jax.Array]:
+def moe_ffn_dropless(h: jax.Array, layer: dict, cfg, live: jax.Array | None = None) -> tuple[jax.Array, ...]:
     """Dropless MoE over the mesh ``expert`` axis.
+
+    With ``live`` [G, L] bool (a serving pass: the rows that hold a request)
+    a third value comes back, the rows of ``live`` that chose each expert
+    [E] int32, and the others take no part where that is free.
 
     Inside a shard_map block (token shard x expert shard), ``expert_ffn``
     computes this shard's experts for its rows (``megablox.gmm`` in
@@ -456,15 +460,19 @@ def moe_ffn_dropless(h: jax.Array, layer: dict, cfg) -> tuple[jax.Array, jax.Arr
         # tokens. Loud, because on a big mesh this is a real perf cliff.
         _warn_replicated_once((G, L, d_sz, s_sz, e_sz))
     bias = layer.get("router_bias")
+    if live is not None and orig_GL is not None:
+        live = live.reshape(G, L)
 
-    def block(h_blk, wr, wg, wu, wd):
+    def block(h_blk, wr, wg, wu, wd, live_blk=None):
         # h_blk [G_, L_, D]; wg/wu [E_loc, D, F]; wd [E_loc, F, D]
         G_, L_, _ = h_blk.shape
         blk = {"w_router": wr, "we_gate": wg, "we_up": wu, "we_down": wd}
         if bias is not None:
             blk["router_bias"] = bias
         e0 = jax.lax.axis_index("expert") * wg.shape[0] if in_mesh else 0
-        out, probs, top_e, _ = expert_ffn(h_blk.reshape(G_ * L_, D), blk, cfg, e0=e0)
+        out, probs, top_e, load = expert_ffn(
+            h_blk.reshape(G_ * L_, D), blk, cfg, e0=e0, live=None if live_blk is None else live_blk.reshape(-1)
+        )
         if in_mesh:
             out = jax.lax.psum(out, "expert")
         # switch-style aux from the (replicated-over-expert) global routing
@@ -472,18 +480,18 @@ def moe_ffn_dropless(h: jax.Array, layer: dict, cfg) -> tuple[jax.Array, jax.Arr
         aux = (frac * probs.mean(0)).sum() * E
         if in_mesh:
             aux = jax.lax.pmean(aux, ("data", "fsdp", "seq"))
-        return out.reshape(G_, L_, D).astype(h_blk.dtype), aux
+        out = out.reshape(G_, L_, D).astype(h_blk.dtype)
+        if live_blk is None:
+            return out, aux
+        # every expert shard scores the whole router: the token shards' counts add up
+        return out, aux, (jax.lax.psum(load, ("data", "fsdp", "seq")) if in_mesh else load)
 
+    weights = (layer["w_router"], layer["we_gate"], layer["we_up"], layer["we_down"])
+    rows = () if live is None else (live,)
     if not in_mesh:
-        out, aux = block(
-            h,
-            layer["w_router"],
-            layer["we_gate"],
-            layer["we_up"],
-            layer["we_down"],
-        )
+        out, aux, *load = block(h, *weights, *rows)
     else:
-        out, aux = shard_map(
+        out, aux, *load = shard_map(
             block,
             in_specs=(
                 P(BATCH_AXES, "seq", None),
@@ -491,16 +499,17 @@ def moe_ffn_dropless(h: jax.Array, layer: dict, cfg) -> tuple[jax.Array, jax.Arr
                 P("expert", None, None),
                 P("expert", None, None),
                 P("expert", None, None),
+                *[P(BATCH_AXES, "seq")] * len(rows),
             ),
-            out_specs=(P(BATCH_AXES, "seq", None), P()),
+            out_specs=(P(BATCH_AXES, "seq", None), P(), *[P()] * len(rows)),
             # gmm's inner pallas_call carries no vma annotations; the variance
             # checker can't see through it — the psum/pmean above implement the
             # replication the out_specs promise
             check_vma=False,
-        )(h, layer["w_router"], layer["we_gate"], layer["we_up"], layer["we_down"])
+        )(h, *weights, *rows)
     if orig_GL is not None:
         out = out.reshape(*orig_GL, D)
-    return out, aux.astype(jnp.float32)
+    return (out, aux.astype(jnp.float32), *load)
 
 
 _SMALL_TILE_WARNED: set = set()

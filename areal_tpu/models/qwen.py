@@ -29,7 +29,7 @@ import dataclasses
 import json
 import os
 from functools import partial
-from typing import Any, ClassVar
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +49,12 @@ BATCH_AXES = ("data", "fsdp")
 SCOPES = ("embed", "attn_proj", "kv_write", "attn", "mlp", "lm_head")
 
 # ``model_type``s of a published config.json that this module implements
-MODEL_TYPES = ("qwen2", "qwen3", "qwen3_moe", "qwen2_vl", "qwen2_5_vl", "llama")
+MODEL_TYPES = ("qwen2", "qwen3", "qwen3_moe", "qwen2_vl", "qwen2_5_vl", "llama", "sdar_moe")
+
+# how a denoise pass of a block picks the masked positions it commits (``ModelConfig.remasking_strategy``; the decode
+# chunk's ``block_select``): the first k in sequence order, the k whose candidates are the most probable, or every one
+# whose candidate is over ``confidence_threshold`` and at least the best one
+REMASKING_RULES = ("sequential", "low_confidence_static", "low_confidence_dynamic")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -99,6 +104,19 @@ class ModelConfig:
     image_token_id: int = -1
     vision: Any = None  # vision.VisionConfig | None
     router_aux_coef: float = 0.0  # load-balance aux loss weight
+    # generation by diffusion over blocks (``sdar_moe``): positions go in blocks of ``block_length`` by ABSOLUTE
+    # position (position j is in block j // block_length) and a query sees every key of the blocks up to its own, its
+    # own both ways. 1 = an autoregressive model: every mask, program and count below is what it was. Above 1 a decode
+    # step is a PASS over a slot's current block (``forward_block_paged``; inference/decode_programs.py): a denoise
+    # pass scores the block with its uncommitted positions holding ``mask_token_id``'s embedding and commits some of
+    # them, a commit pass runs the clean block again and writes its keys and values. The other three are a request's
+    # defaults: passes a block (``block_length / denoising_steps`` positions a pass under the counted rules), the rule
+    # (``REMASKING_RULES``) and the dynamic rule's threshold
+    block_length: int = 1
+    mask_token_id: int = -1
+    denoising_steps: int = 1
+    remasking_strategy: str = "sequential"
+    confidence_threshold: float = 0.9
 
     @property
     def head_dim_(self) -> int:
@@ -134,10 +152,27 @@ class ModelConfig:
 
     has_recurrent_state = False
     has_slot_tenant = False  # nothing of a slot's but its pages: no state, no window ring (models/hybrid.py)
-    # expert-load counts a decode chunk would hand back (models/hybrid.py): none
-    moe_count_shapes: ClassVar[dict] = {}
-    # what a decode chunk hands back beside its tokens: the blocks of pages its attention launches listed and fetched
-    count_shapes: ClassVar[dict] = {"attn_blocks_listed": (1,), "attn_blocks_fetched": (1,)}
+    expert_first = 0  # every expert the router scores is held here (models/hybrid.py holds a share of them)
+
+    @property
+    def moe_count_shapes(self) -> dict[str, tuple[int, ...]]:
+        """{leaf: shape} of the expert-load counts a decode chunk hands back (models/hybrid.py has what each counts):
+        a block model's with experts; none for a token-a-step model of this module, whose chunk never carried them."""
+        if self.block_length == 1 or not self.num_experts:
+            return {}
+        n = self.num_layers
+        return {"moe_load": (n, self.num_experts), "moe_touched": (n,), "moe_streamed": (n,)}
+
+    @property
+    def count_shapes(self) -> dict[str, tuple[int, ...]]:
+        """{leaf: shape} of every int32 count a decode chunk hands back beside its tokens: the blocks of pages a
+        token step's attention launches listed and fetched; for a block model the slot-passes that denoised and that
+        committed, the blocks emitted and the cached tokens the in-block attention launches fetched (a pass, not a
+        layer), and its expert loads."""
+        if self.block_length == 1:
+            return {"attn_blocks_listed": (1,), "attn_blocks_fetched": (1,)}
+        blk = dict.fromkeys(("blk_denoise_passes", "blk_commit_passes", "blk_blocks", "blk_attn_tokens_read"), (1,))
+        return {**blk, **self.moe_count_shapes}
 
     def state_shapes(self, slots: int) -> dict:
         return {}
@@ -202,7 +237,7 @@ class ModelConfig:
             tie_word_embeddings=td.get("tie_word_embeddings", False),
             # explicit key wins (our own from-scratch exports carry it);
             # else the qwen3-family heuristic
-            qk_norm=d.get("qk_norm", mt.startswith("qwen3")),
+            qk_norm=d.get("qk_norm", mt.startswith("qwen3") or mt == "sdar_moe"),
             attention_bias=td.get("attention_bias", mt.startswith("qwen2")),
             # qwen2_moe / qwen3_moe checkpoints (HF key names)
             num_experts=td.get("num_experts", 0),
@@ -211,12 +246,37 @@ class ModelConfig:
             norm_topk_prob=td.get("norm_topk_prob", True),
             image_token_id=image_token_id,
             vision=vision,
+            **_block_fields(td, mt),
         )
 
     @classmethod
     def from_hf_path(cls, path: str) -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
             return cls.from_hf_dict(json.load(f))
+
+
+def _block_fields(td: dict, mt: str) -> dict:
+    """The block-diffusion fields of a ``sdar_moe`` configuration (none for any other type). The published
+    config.json has none of them: the family's generation script takes them as arguments, so a caller that serves the
+    model states them beside the published keys (benchmarks/chip/configs: ``assumed``)."""
+    if mt != "sdar_moe":
+        return {}
+    out = {
+        "block_length": int(td.get("block_length", 4)),
+        "mask_token_id": int(td["mask_token_id"]),
+        "denoising_steps": int(td.get("denoising_steps", td.get("block_length", 4))),
+        "remasking_strategy": str(td.get("remasking_strategy", "low_confidence_dynamic")),
+        "confidence_threshold": float(td.get("confidence_threshold", 0.9)),
+        **({"dtype": str(td["dtype"])} if "dtype" in td else {}),  # the type it is served in, where the caller states one
+    }
+    B, steps = out["block_length"], out["denoising_steps"]
+    if B < 1 or not 1 <= steps <= B or B % steps:
+        raise ValueError(f"denoising_steps {steps} does not divide block_length {B}")
+    if out["remasking_strategy"] not in REMASKING_RULES:
+        raise ValueError(f"remasking_strategy {out['remasking_strategy']!r} is none of {REMASKING_RULES}")
+    if not 0 <= out["mask_token_id"] < td["vocab_size"]:
+        raise ValueError(f"mask_token_id {out['mask_token_id']} outside the vocabulary")
+    return out
 
 
 def serving_config(cfg: ModelConfig, dtype: str) -> ModelConfig:
@@ -355,9 +415,17 @@ def serving_limits(cfg) -> dict[str, str]:
     """What this module does not implement for ``cfg``, for the decode engine
     to refuse when it is configured ({feature: why}, ``models/hybrid.py``):
     nothing. Radix reuse with suffix prefill, speculative verification, int8
-    weights and pages and tensor parallelism all serve this family."""
-    del cfg
-    return {}
+    weights and pages and tensor parallelism all serve this family. A block
+    model's step is no token step: the speculative round (one accepted path
+    of single tokens) and the frequency penalty's per-token counts have no
+    form for it."""
+    if getattr(cfg, "block_length", 1) == 1:
+        return {}
+    return {
+        "reason": "block_diffusion",
+        "speculative": "speculative decoding drafts single next tokens; a block-diffusion model commits positions of a block in any order",
+        "frequency_penalty": "the frequency penalty counts tokens as they are sampled one a step; a block pass samples candidates it may discard",
+    }
 
 
 def prefill_row_bytes(cfg, bucket: int) -> int:
@@ -634,15 +702,19 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def _attention_mask(segment_ids: jax.Array) -> jax.Array:
+def _attention_mask(segment_ids: jax.Array, block: int = 1) -> jax.Array:
     """[G, L] segment ids (0 = pad) -> [G, 1, L, L] bool mask.
 
     Causality is by *row position* (packed rows concatenate sequences, each
     with its own restarting rope positions), matching the reference's varlen
-    flash-attn semantics.
+    flash-attn semantics. ``block`` > 1 (a serving prompt pass of a block
+    model, whose row starts on a block boundary): causal over blocks of that
+    many positions, both ways inside one.
     """
     L = segment_ids.shape[-1]
     idx = jnp.arange(L)
+    if block > 1:
+        idx = idx // block
     causal = idx[:, None] >= idx[None, :]
     same_seg = segment_ids[:, :, None] == segment_ids[:, None, :]
     not_pad = (segment_ids != 0)[:, :, None]
@@ -656,14 +728,19 @@ def _sdpa(q, k, v, mask, head_dim: int):
     return sdpa_xla(q, k, v, mask, head_dim)
 
 
-def _ffn(cfg: ModelConfig, h: jax.Array, layer: dict) -> jax.Array:
+def _ffn(cfg: ModelConfig, h: jax.Array, layer: dict, live: jax.Array | None = None):
     """Feed-forward for the cache paths (prefill/decode): dense SwiGLU or
-    MoE. Accepts [..., D]; MoE internally needs [G, L, D]."""
+    MoE. Accepts [..., D]; MoE internally needs [G, L, D]. With ``live``
+    (bool, h's shape less D: the rows that hold a request) the experts'
+    load comes back too, (out, load [E] int32), counted over those rows."""
     if cfg.num_experts > 0:
-        from areal_tpu.models.moe import moe_ffn
+        from areal_tpu.models.moe import moe_ffn, moe_ffn_dropless
 
         squeeze = h.ndim == 2
         h3 = h[:, None] if squeeze else h
+        if live is not None:
+            out, _, load = moe_ffn_dropless(h3, layer, cfg, live=live[:, None] if squeeze else live)
+            return (out[:, 0] if squeeze else out), load
         out, _ = moe_ffn(h3, layer, cfg)
         return out[:, 0] if squeeze else out
     return _proj(
@@ -1001,7 +1078,7 @@ def forward_prefill(
         if image_embeds is not None and cfg.image_token_id >= 0:
             img_pos = (input_ids == cfg.image_token_id)[..., None]
             x = jnp.where(img_pos, image_embeds.astype(cfg.jax_dtype), x)
-    mask = _attention_mask(seg)
+    mask = _attention_mask(seg, cfg.block_length)
     H, KH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
 
     def body(x, layer):
@@ -1109,7 +1186,7 @@ def forward_prefill_paged(
     """
     with jax.named_scope("embed"):
         x = _embed_lookup(params["embed"], input_ids, cfg.jax_dtype, batch_sharded=False)
-    suf_mask = _attention_mask(seg)  # [A, 1, B, B] causal-within-suffix
+    suf_mask = _attention_mask(seg, cfg.block_length)  # [A, 1, B, B] causal-within-suffix (the suffix starts on a page, so on a block)
     H, KH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     G = H // KH
     A, B = input_ids.shape
@@ -1223,7 +1300,8 @@ def forward_verify_paged(
     page_table: jax.Array,  # [S, wp] int32 pages holding the cached context
     prefix_lens: jax.Array,  # [S] int32 tokens already in pages (= root pos)
     use_kernel: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
+    live: jax.Array | None = None,  # [S] bool: with it, a model with experts also returns their loads
+) -> tuple[jax.Array, ...]:
     """Speculative-verify forward: score every slot's draft token tree in
     ONE pass over the paged KV pool — the step that used to produce one
     token per slot produces logits for B tree nodes per slot.
@@ -1242,12 +1320,27 @@ def forward_verify_paged(
     sets every node's self bit (inference/speculative.py), so the kernel's
     diagonal row-validity rule admits every row to the committed prefix,
     matching this function's broadcast ``pre_valid`` exactly.
+
+    A block pass of a block-diffusion model is this forward with the mask
+    all ones (``forward_block_paged``). Given ``live``, the rows of the
+    slots it marks count as the experts' load and a fourth value comes
+    back, the loads [n_layers, E] int32 (nothing of a slot ``live`` leaves
+    out is read: its rows are computed and discarded, as an ended slot's
+    are in a decode step).
     """
     with jax.named_scope("embed"):
         x = _embed_lookup(params["embed"], input_ids, cfg.jax_dtype, batch_sharded=False)
     H, KH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     G = H // KH
     S, B = input_ids.shape
+    with_load = live is not None and cfg.num_experts > 0
+    row_live = jnp.broadcast_to(live[:, None], (S, B)) if with_load else None
+
+    def ffn(h, layer):
+        if with_load:
+            return _ffn(cfg, h, layer, row_live)
+        return _ffn(cfg, h, layer), None
+
     wp = page_table.shape[1]
     psz = cache["k"].shape[3]
     W = wp * psz
@@ -1304,8 +1397,9 @@ def forward_verify_paged(
                 x = x + _proj(cfg, layer, "wo", attn.astype(x.dtype))
             with jax.named_scope("mlp"):
                 h = _rms_norm(x, layer["post_attn_norm"], cfg.rms_norm_eps)
-                x = x + _ffn(cfg, h, layer)
-            return x, (k_cache, v_cache)
+                out, load = ffn(h, layer)
+                x = x + out
+            return x, (k_cache, v_cache, load)
         with jax.named_scope("attn"):
             kp = gather("k", li)  # [S, W, KH, hd]
             vp = gather("v", li)
@@ -1329,15 +1423,59 @@ def forward_verify_paged(
             x = x + _proj(cfg, layer, "wo", attn)
         with jax.named_scope("mlp"):
             h = _rms_norm(x, layer["post_attn_norm"], cfg.rms_norm_eps)
-            x = x + _ffn(cfg, h, layer)
-        return x, (k_cache, v_cache)
+            out, load = ffn(h, layer)
+            x = x + out
+        return x, (k_cache, v_cache, load)
 
-    x, (ks, vs) = jax.lax.scan(
+    x, (ks, vs, loads) = jax.lax.scan(
         body, x, (params["layers"], jnp.arange(cfg.num_layers))
     )
     with jax.named_scope("lm_head"):
         hidden = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    if with_load:
+        return hidden, ks, vs, loads
     return hidden, ks, vs
+
+
+def block_attn_tokens_fetched(prefix_lens: jax.Array, wp: int, page_size: int, use_kernel: bool) -> jax.Array:
+    """Cached tokens the in-block attention of one block pass fetches, a layer
+    and KV head, summed over the slots (int32): the Pallas launch reads whole
+    blocks of ``default_ppcb(wp)`` pages up to each slot's ``prefix_lens``
+    (ops/paged_suffix_attention.py); the gather path reads every slot's whole
+    window of ``wp`` pages."""
+    from areal_tpu.ops.paged_suffix_attention import default_ppcb
+
+    if not use_kernel:
+        return jnp.int32(prefix_lens.shape[0] * wp * page_size)
+    bs = default_ppcb(wp) * page_size
+    return jnp.sum(-(-prefix_lens // bs) * bs, dtype=jnp.int32)
+
+
+def forward_block_paged(
+    params: dict,
+    cfg: ModelConfig,
+    input_ids: jax.Array,  # [S, B] the block's clean ids, ``mask_token_id`` where a position is not committed
+    start: jax.Array,  # [S] absolute position of the block's first token (a multiple of B)
+    live: jax.Array,  # [S] bool slots that hold a request
+    cache: dict,
+    page_table: jax.Array,  # [S, wp]
+    use_kernel: bool = False,
+) -> tuple[jax.Array, ...]:
+    """One pass of a block-diffusion model over every slot's current block:
+    its B rows attend the committed blocks before it (``start`` tokens, from
+    the slot's pages) and each other, every pair allowed. It is the verify
+    forward under an all-ones in-flight mask, one body: (hidden [S, B, D],
+    the rows' keys and values [n_layers, S, B, KH, hd], and the experts'
+    loads over the live slots' rows). Nothing is written: the caller puts a
+    block's keys and values into its pages on the pass that finds it clean.
+    A slot ``live`` leaves out reads no page (its prefix counts 0 tokens)."""
+    S, B = input_ids.shape
+    positions = start[:, None] + jnp.arange(B, dtype=jnp.int32)[None]
+    return forward_verify_paged(
+        params, cfg, input_ids, positions, jnp.ones((S, B, B), bool), cache, page_table,
+        jnp.where(live, start, 0), use_kernel=use_kernel, live=live,
+    )
+
 
 
 def forward_decode_paged(

@@ -322,6 +322,32 @@ def engine_metrics(reg: Registry | None = None) -> SimpleNamespace:
             "summed over steps and expert layers: every held expert under "
             "XLA's matmuls, the touched ones under the touched-expert kernel.",
         ),
+        # a block-diffusion model (models/qwen.py ``block_length`` > 1): a
+        # decode step is a PASS over a slot's block, and areal_decode_steps_total
+        # counts passes. Counted on the device inside the chunk, live slots only
+        block_denoise_passes=r.counter(
+            "areal_decode_block_denoise_passes_total",
+            "(slot, pass) pairs in which a live slot's block still had masked "
+            "positions: candidates sampled at them, some committed.",
+        ),
+        block_commit_passes=r.counter(
+            "areal_decode_block_commit_passes_total",
+            "(slot, pass) pairs in which a live slot's block was clean: its "
+            "keys and values written to its pages, its tokens emitted.",
+        ),
+        blocks=r.counter(
+            "areal_decode_blocks_total",
+            "Blocks emitted by the decode loop (each with 1 to block_length "
+            "of areal_decode_generated_tokens_total).",
+        ),
+        block_attn_tokens_read=r.counter(
+            "areal_decode_block_attn_tokens_read_total",
+            "Cached tokens the in-block attention launches fetched for live "
+            "slots' passes, a pass (x 2 pools x KV heads x head size x "
+            "bytes, a layer): whole blocks of pages up to the slot's "
+            "committed length on the kernel path, the whole window on the "
+            "gather path.",
+        ),
     )
 
 

@@ -77,6 +77,12 @@ def _interp(interpret: bool | None) -> bool:
     return interpret
 
 
+def default_ppcb(wp: int) -> int:
+    """Pages a prefix block of the launch holds when the caller names none:
+    the largest divisor of the window's ``wp`` pages up to 8."""
+    return next(d for d in range(min(wp, 8), 0, -1) if wp % d == 0)
+
+
 def _tiles(B: int, G: int) -> tuple[int, int, int]:
     """(Bp, bq, bk): the suffix length padded to the tiling (masked rows
     and columns, sliced off again), suffix rows per query tile and suffix
@@ -275,7 +281,7 @@ def paged_suffix_attention(
         )
     ppcb = pages_per_compute_block
     if ppcb is None:
-        ppcb = next(d for d in range(min(wp, 8), 0, -1) if wp % d == 0)
+        ppcb = default_ppcb(wp)
     if wp % ppcb:
         raise ValueError(f"wp={wp} not divisible by ppcb={ppcb}")
 

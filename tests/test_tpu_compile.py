@@ -1085,3 +1085,82 @@ def test_prefill_compiles_for_v5e_at_the_cells_longest_bucket(chip, monkeypatch,
     i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
     compiled = jax.jit(prefill, donate_argnums=(1,)).lower(params, cache, i32(rows, bucket), i32(rows), i32(rows * bucket // PSZ), i32(rows)).compile()
     row.prefill(mcfg, cache, compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes)
+
+
+# The engine's OWN programs of a family of ``models/qwen.py`` whose decode step is a block pass (``block_length`` > 1):
+# (the module of tests/benchmark_harness that builds the configuration as the cell does, the configuration's file, the
+# depth it is cut to (the layers are one scanned body), the cell's pool, slots, context and passes a chunk, the prefill's
+# (rows, bucket))
+BLOCK_FAMILIES = {
+    "sdar": ("chipbench_sdar_util", "sdar-30b-a3b-d7", dict(num_hidden_layers=2), 1755, 64, 4096, 32, (8, 1024)),
+}
+
+
+def _block_programs(chip, monkeypatch, family: str):
+    """(the family's ``DecodePrograms`` at its cell's server shapes, the
+    abstract weights, cache, slot state and rng on the described chip)."""
+    import importlib
+    import json
+
+    from areal_tpu import models
+    from areal_tpu.api.config import MeshConfig, PrefixCacheConfig, ServerConfig
+    from areal_tpu.inference import paged_kv
+    from areal_tpu.inference.decode_programs import DecodePrograms, slot_state
+    from areal_tpu.parallel import mesh as mesh_lib
+    from areal_tpu.utils import compile_cache
+
+    util, config, cut, pages, slots, context, steps, _ = BLOCK_FAMILIES[family]
+    load_run()
+    with open(os.path.join(CHIP, "configs", config + ".json")) as f:
+        mcfg = importlib.import_module(util).model_config({**json.load(f), **cut}, "bfloat16")
+    scfg = ServerConfig(
+        dtype="bfloat16", max_batch_size=slots, max_seq_len=context, page_size=PSZ, decode_steps_per_call=steps, attn_window_step=context, seed=0,
+        mesh=MeshConfig(data=-1, fsdp=1, seq=1, model=1), prefix_cache=PrefixCacheConfig(enabled=True),
+    )
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels' launches are compiled, as on the chip
+    model = models.family_of(mcfg)
+    progs = DecodePrograms(model, mcfg, scfg, mesh_lib.make_mesh(scfg.mesh, devices=jax.devices()[:1]), store=compile_cache.ProgramStore(None))
+    assert progs.use_kernel and progs.block == mcfg.block_length > 1
+    place = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)  # noqa: E731
+    shapes = (
+        place(jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0), mcfg))),
+        place(jax.eval_shape(lambda: paged_kv.init_paged_cache(mcfg, pages, PSZ, slots=slots))),
+        place(jax.eval_shape(lambda: jax.tree.map(jnp.asarray, slot_state(slots, mcfg.block_length)))),
+        place(jax.eval_shape(lambda: jax.random.PRNGKey(0))),
+    )
+    return progs, mcfg, shapes
+
+
+@pytest.mark.parametrize("family", BLOCK_FAMILIES)
+def test_block_chunk_compiles_for_v5e_at_the_cells_slots_and_window(chip, monkeypatch, family):
+    """The engine's chunk program of a block model: 32 passes over 64 slots x
+    4 rows, the in-block Pallas launch over a window of 32 pages, the head and
+    the sampler's launches over 256 rows of 151,936, a clean block's rows
+    through the row writer; the cache and the slot state donated. The pool is
+    written in place: no result of the program has a pool's shape (one scatter
+    over every row and KV head copied both pools twice a pass, PERF.md PR 58)."""
+    progs, mcfg, (params, cache, state, rng) = _block_programs(chip, monkeypatch, family)
+    _, _, _, pages, slots, context, steps, _ = BLOCK_FAMILIES[family]
+    key = ("chunk", steps, context // PSZ, False, False, False)
+    assert key in progs.warm_keys()
+    progs.chunk_fn(*key[1:])
+    compiled = progs._fn_cache[key].lower(params, cache, chip((slots, context // PSZ), jnp.int32), state, rng, chip((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "paged_suffix_attn" in text and "paged_kv_write" in text and "vocab_block" in text
+    made = _results_outside_fusions(text)
+    pool = rf"bf16\[{mcfg.num_layers},{mcfg.num_kv_heads},{pages},{PSZ},{mcfg.head_dim_}\]"
+    assert not [ln for ln in made if re.search(rf"= {pool}\S* (copy|fusion|scatter)", ln)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9  # 256 rows of float32 logits are 156 MB
+
+
+@pytest.mark.parametrize("family", BLOCK_FAMILIES)
+def test_block_causal_prefill_compiles_for_v5e_at_the_cells_longest_bucket(chip, monkeypatch, family):
+    """The engine's prefill program under the block-causal mask: 8 prompts of
+    1,024 tokens, 8,192 rows through the grouped expert matmuls; beside 9.97 GB
+    of weights and 3.22 GB of pages its temporaries must stay under 2 GB."""
+    progs, _, (params, cache, _, _) = _block_programs(chip, monkeypatch, family)
+    rows, bucket = BLOCK_FAMILIES[family][-1]
+    progs.prefill_fn(rows, bucket)
+    i32 = lambda *s: chip(s, jnp.int32)  # noqa: E731
+    compiled = progs._fn_cache[("prefill", rows, bucket, False)].lower(params, cache, i32(rows, bucket), i32(rows), i32(rows * bucket // PSZ), i32(rows)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
